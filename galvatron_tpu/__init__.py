@@ -20,10 +20,6 @@ The reference loop `profile -> search -> train` is preserved:
 
 __version__ = "0.1.0"
 
-# jax 0.4.x compat shims (jax.shard_map, jax.sharding.get_abstract_mesh) must
-# install before any module referencing the modern API surface imports.
-from galvatron_tpu.utils import jax_compat as _jax_compat  # noqa: F401
-
 from galvatron_tpu.config.strategy import HybridParallelConfig, LayerStrategy
 
 __all__ = ["HybridParallelConfig", "LayerStrategy", "__version__"]

@@ -7,12 +7,9 @@ A pure-AST pass (no execution of the linted code) over Python sources:
   every ``from jax.x import y`` is resolved against the jax actually
   *installed in this environment* — introspected, not hard-coded — so an
   upgrade/downgrade that removes an API is caught at lint time instead of at
-  import/trace time on a TPU pod. (This is exactly the
-  ``jax.shard_map``/``get_abstract_mesh`` class of breakage that took out
-  ring attention, both 1F1B engines and the hardware profiler on jax
-  0.4.37.) Because `galvatron_tpu.utils.jax_compat` installs its shims at
-  package import, chains the shim provides resolve — the linter validates
-  the *effective* API surface.
+  import/trace time on a TPU pod (the ``jax.shard_map``/
+  ``get_abstract_mesh`` class of breakage: ring attention, both 1F1B
+  engines and the hardware profiler all hang off those names).
 - **GLC002 — host numpy inside jit**: calls to a ``numpy`` alias inside a
   jit-compiled function. `np.asarray(x)` on a tracer either fails or silently
   constant-folds; dtype/constant accesses (``np.float32``, ``np.pi``) are
@@ -285,7 +282,7 @@ class _ModuleLint:
             if not isinstance(node, ast.Attribute) or id(node) in inner:
                 continue
             if not isinstance(node.ctx, ast.Load):
-                continue  # `jax.shard_map = shim` in jax_compat is a Store
+                continue  # only reads can hit a missing attribute
             chain = _attr_chain(node)
             if chain is None:
                 continue

@@ -85,14 +85,9 @@ CODES: Dict[str, Tuple[str, str]] = {
     "GLT101": (WARNING, "traced collectives contradict the cost model's predicted comm"),
     "GLT102": (WARNING, "traced-program audit skipped or limited"),
     # ---- jax-workaround inventory (WA0xx, utils/jax_compat.py registry) ----
-    "WA001": (WARNING, "shard_map modern-signature shim (axis_names/check_vma)"),
-    "WA002": (WARNING, "jax.sharding.get_abstract_mesh fallback shim"),
-    "WA003": (WARNING, "partial-manual shard_map compile gate (out-of-process probe)"),
     "WA004": (WARNING, "jnp.stack (not concat+reshape) in stack_layer_run scan stacking"),
     "WA005": (WARNING, "explicit sharding constraints on the pipeline microbatch split"),
     "WA006": (WARNING, "host-side per-layer init + stack outside jit under pp shardings"),
-    "WA007": (WARNING, "persistent-cache bypass on XLA:CPU (deserialized-executable corruption)"),
-    "WA008": (WARNING, "no manual psum of tp cotangents (legacy shard_map auto-psum contract)"),
 }
 
 

@@ -35,8 +35,9 @@ sharding-propagation pass:
   ``axis_index`` eqn (all outputs DropVars) next to the
   ``custom_vjp_call_jaxpr``;
 - **GLT006** warns on psum-of-psum over the same axis inside a manual region
-  (the cotangent double-count shape — the legacy shard_map transpose already
-  psums over unmentioned manual axes, see parallel/tp_shard_map.py).
+  (the cotangent double-count shape — autodiff already psums a cotangent
+  over the manual axes its primal is invariant over, see
+  parallel/tp_shard_map.py).
 
 The collective audit (GLT101/GLT102) extracts every explicit collective
 (psum/ppermute/all_gather/reduce_scatter/all_to_all) with its wire bytes
@@ -72,8 +73,12 @@ from galvatron_tpu.analysis import diagnostics as D
 DimSpec = Optional[Tuple[str, ...]]
 Spec = Tuple[DimSpec, ...]
 
-_COLLECTIVES = ("psum", "ppermute", "all_gather", "reduce_scatter",
-                "all_to_all", "pmax", "pmin")
+# psum_invariant / all_gather_invariant: what psum / all_gather trace to under
+# shard_map's varying-axes typing when the result is invariant over the axes
+_COLLECTIVES = ("psum", "psum_invariant", "ppermute", "all_gather",
+                "all_gather_invariant", "reduce_scatter", "all_to_all",
+                "pmax", "pmin")
+_PSUMS = ("psum", "psum_invariant")
 
 # single-output ops through which a value keeps its shape and layout intent
 _SHAPE_PRESERVING = frozenset({
@@ -86,7 +91,7 @@ _SHAPE_PRESERVING = frozenset({
     "erfc", "erf_inv", "square", "integer_pow", "is_finite", "real",
     "imag", "conj", "clamp", "select_n", "convert_element_type",
     "stop_gradient", "copy", "reduce_precision", "eq", "ne", "lt", "le",
-    "gt", "ge",
+    "gt", "ge", "pvary",
 })
 
 
@@ -112,7 +117,7 @@ def _src(eqn) -> Tuple[Optional[str], Optional[int]]:
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             return frame.file_name, frame.start_line
     except Exception:
@@ -289,7 +294,7 @@ def _walk(jaxpr, env, taint, ctx: _Ctx, st: _State) -> None:
                     env[ov] = env[iv]
                 if iv in taint:
                     taint[ov] = taint[iv]
-        elif prim == "pjit":
+        elif prim == "jit":
             _do_pjit(eqn, env, taint, ctx, st)
         elif prim == "scan":
             _do_scan(eqn, env, taint, ctx, st)
@@ -598,8 +603,8 @@ def _do_cond(eqn, env, taint, ctx: _Ctx, st: _State) -> None:
 def _do_shard_map(eqn, ctx: _Ctx, st: _State) -> None:
     mesh = eqn.params.get("mesh")
     axis_names = tuple(getattr(mesh, "axis_names", ()) or ())
-    auto = eqn.params.get("auto") or frozenset()
-    manual = tuple(a for a in axis_names if a not in auto)
+    manual_set = eqn.params.get("manual_axes") or frozenset()
+    manual = tuple(a for a in axis_names if a in manual_set)
     body = _open(eqn.params["jaxpr"])
     ctx2 = _Ctx(in_loop=ctx.in_loop, trip=ctx.trip,
                 in_shard_map=True, manual_axes=manual)
@@ -652,20 +657,20 @@ def _do_collective(eqn, produced, ctx: _Ctx, st: _State) -> None:
         "file": f,
         "line": line,
     })
-    if eqn.primitive.name == "psum":
+    if eqn.primitive.name in _PSUMS:
         for iv in eqn.invars:
             if _is_literal(iv):
                 continue
             src_eqn = produced.get(iv)
-            if src_eqn is not None and src_eqn.primitive.name == "psum":
+            if src_eqn is not None and src_eqn.primitive.name in _PSUMS:
                 inner_axes = set(_axes_of_collective(src_eqn))
                 if inner_axes & set(axes):
                     st.emit(
                         "GLT006",
                         "psum over %s consumes the result of another psum "
-                        "over the same axis in one manual region — with the "
-                        "legacy shard_map's automatic cotangent psum over "
-                        "unmentioned manual axes this is the gradient "
+                        "over the same axis in one manual region — with "
+                        "autodiff's own cotangent psum over the axes a "
+                        "primal is invariant over this is the gradient "
                         "double-count shape (see parallel/tp_shard_map.py "
                         "autodiff note)" % (sorted(inner_axes & set(axes)),),
                         eqn,

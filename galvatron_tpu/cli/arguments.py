@@ -108,16 +108,6 @@ def _add_parallel_args(p: argparse.ArgumentParser):
     g.add_argument("--world_size", type=int, default=None, help="devices to use (default: all)")
 
 
-def _add_compile_args(p: argparse.ArgumentParser):
-    g = p.add_argument_group("compilation")
-    g.add_argument("--compile_cache", type=int, default=0,
-                   help="1 => enable jax's persistent compilation cache so "
-                        "re-launches with unchanged step HLO skip XLA "
-                        "entirely (per-host cache; see utils/compile_cache.py)")
-    g.add_argument("--compile_cache_dir", type=str, default=None,
-                   help="cache location (default ~/.cache/galvatron_tpu/xla)")
-
-
 def _add_train_args(p: argparse.ArgumentParser):
     g = p.add_argument_group("training")
     g.add_argument("--train_iters", type=int, default=20)
@@ -561,7 +551,6 @@ def build_parser(mode: str, extra_args_provider: Optional[Callable] = None) -> a
     _add_model_args(p)
     if mode in ("train", "train_dist"):
         _add_parallel_args(p)
-        _add_compile_args(p)
         _add_train_args(p)
         _add_profile_args(p)  # train runs double as profiling runs (reference model_profiler launches train_dist)
     elif mode == "search":
@@ -574,7 +563,6 @@ def build_parser(mode: str, extra_args_provider: Optional[Callable] = None) -> a
         _add_hardware_args(p)
     elif mode == "serve":
         _add_parallel_args(p)
-        _add_compile_args(p)
         _add_serve_args(p)
     if extra_args_provider is not None:
         extra_args_provider(p)

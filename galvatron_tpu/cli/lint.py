@@ -19,8 +19,8 @@ Usage:
         --model_type gpt --hidden_size 64 --num_heads 4 --seq_length 64 \
         --vocab_size 128
 
-    # jax-workaround inventory: probe every pinned 0.4.37 workaround
-    # against the installed jax (--deep runs the out-of-process probes):
+    # jax-workaround inventory: the GSPMD-hazard workarounds still carried
+    # in the code, probed against the installed jax:
     python -m galvatron_tpu.cli lint --compat
 
 Exit-code contract: 0 = clean (warnings allowed), 1 = at least one error
@@ -93,10 +93,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "devices are forced host devices, nothing compiles")
     p.add_argument("--compat", action="store_true",
                    help="jax-workaround inventory (WA codes): probe every "
-                        "pinned 0.4.37 workaround against the installed jax "
-                        "and report ACTIVE/RETIRABLE/UNKNOWN with its "
-                        "pinning tests; --deep also runs the expensive "
-                        "out-of-process probes")
+                        "carried workaround against the installed jax and "
+                        "report ACTIVE/RETIRABLE/UNKNOWN with its pinning "
+                        "tests")
     t = p.add_argument_group(
         "model-dim overrides (model-aware GLS checks and --trace)")
     t.add_argument("--num_layers", type=int, default=None,
@@ -271,7 +270,7 @@ def run(argv: Optional[List[str]] = None) -> int:
     if args.compat:
         from galvatron_tpu.utils.jax_compat import workaround_inventory
 
-        inventory = workaround_inventory(deep=args.deep)
+        inventory = workaround_inventory()
         for row in inventory:
             if row["active"] is False:
                 report.add(D.make(

@@ -11,11 +11,13 @@ device, SURVEY.md §7), so one driver call does the whole sweep.
 from __future__ import annotations
 
 from galvatron_tpu.cli.arguments import initialize_galvatron, model_config_from_args
+from galvatron_tpu.utils.compile_cache import enable_persistent_cache
 
 
 def profile_model(args) -> dict:
     from galvatron_tpu.profiler.model import ModelProfileArgs, ModelProfiler
 
+    enable_persistent_cache()
     fam, cfg = model_config_from_args(args)
     pargs = ModelProfileArgs(
         profile_type=args.profile_type,
@@ -45,6 +47,7 @@ def profile_model(args) -> dict:
 def profile_hardware(args) -> dict:
     from galvatron_tpu.profiler.hardware import HardwareProfileArgs, HardwareProfiler
 
+    enable_persistent_cache()
     pargs = HardwareProfileArgs(
         start_mb=args.start_mb,
         end_mb=args.end_mb,

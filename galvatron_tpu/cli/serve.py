@@ -45,6 +45,7 @@ from galvatron_tpu.cli.arguments import (
     model_config_from_args,
 )
 from galvatron_tpu.obs import telemetry
+from galvatron_tpu.utils.compile_cache import enable_persistent_cache
 
 
 def serve(args) -> dict:
@@ -66,6 +67,7 @@ def serve(args) -> dict:
 
 
 def _serve(args) -> dict:
+    enable_persistent_cache()
     fam, cfg = model_config_from_args(args)
     world = args.world_size or len(jax.devices())
     hp = hp_config_from_args(args, cfg.num_layers, world)

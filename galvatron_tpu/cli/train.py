@@ -39,46 +39,40 @@ from galvatron_tpu.runtime.dataloader import get_train_iterator
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
 from galvatron_tpu.runtime.prefetch import PrefetchIterator, PrefetchStalledError
+from galvatron_tpu.utils.compile_cache import enable_persistent_cache
 
 
 # In-process memo of AOT-compiled train-step executables, keyed by (device
 # ids, sha256 of the lowered StableHLO). Repeated train() calls in one
 # interpreter (search trials, resume-after-rollback rebuilds, test suites)
-# re-trace cheaply and then REUSE the executable instead of re-running XLA.
-# This is deliberately NOT the persistent compilation cache: on jaxlib
-# 0.4.37, deserializing an XLA:CPU executable corrupts the allocator heap
-# (see tests/conftest.py — two reverts' worth of history), while same-
-# process reuse of the live executable object involves no serialization at
-# all. The HLO text embeds input/output shardings and donation aliasing, so
+# re-trace cheaply and then REUSE the live executable instead of asking XLA
+# (or the persistent cache, utils/compile_cache.py, which serves re-launches)
+# again. The HLO text embeds input/output shardings and donation aliasing, so
 # an exact-text hit on the same devices is semantically the same program.
 _STEP_EXECUTABLES: "OrderedDict" = OrderedDict()
 _STEP_EXECUTABLES_MAX = 16
 
 
 def _step_exec_key(mesh, lowered):
-    try:
-        text = lowered.as_text()
-        devs = tuple(int(d.id) for d in mesh.devices.flat)
-    except Exception:
-        return None
-    return (devs, hashlib.sha256(text.encode()).hexdigest())
+    devs = tuple(int(d.id) for d in mesh.devices.flat)
+    return (devs, hashlib.sha256(lowered.as_text().encode()).hexdigest())
 
 
-def _compile_uncached(lowered):
-    """Compile with the persistent compilation cache bypassed. On jaxlib
-    0.4.37 a deserialized XLA:CPU executable coming back through the cache
-    corrupts the allocator heap when executed via the AOT fast path
-    (deterministic SIGSEGV/abort on the third train() of a process — see
-    tests/conftest.py history). In-process reuse goes through
-    _STEP_EXECUTABLES instead, which never serializes."""
-    prev = jax.config.jax_compilation_cache_dir
-    if prev is None:
-        return lowered.compile()
+def _compile_step(lowered):
+    """Compile the lowered step; returns (executable, persistent_cache_hit).
+    The hit is read off jax's own monitoring event, fired when the backend
+    compile was answered from the persistent compilation cache."""
+    hits = []
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            hits.append(event)
+
+    jax.monitoring.register_event_listener(on_event)
     try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        return lowered.compile()
+        return lowered.compile(), bool(hits)
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        jax.monitoring.unregister_event_listener(on_event)
 
 
 def optimizer_args_from(args) -> OptimizerArgs:
@@ -182,12 +176,9 @@ def _parse_trace_steps(spec) -> tuple:
 
 
 def _train(args) -> dict:
-    if getattr(args, "compile_cache", 0):
-        from galvatron_tpu.utils.compile_cache import enable_persistent_cache
-
-        cache_path = enable_persistent_cache(getattr(args, "compile_cache_dir", None))
-        if jax.process_index() == 0:
-            print("persistent compilation cache: %s" % cache_path)
+    cache_path = enable_persistent_cache()
+    if jax.process_index() == 0:
+        print("persistent compilation cache: %s" % cache_path)
     fam, cfg = model_config_from_args(args)
     world = args.world_size or len(jax.devices())
     # elastic degraded-mesh resume: when the device count no longer matches
@@ -244,7 +235,9 @@ def _train(args) -> dict:
     # families the analytic model cannot describe — MFU is then omitted.
     step_flops = obs_flops.train_step_flops(cfg, hp.global_bsz)
     device_kind = getattr(jax.devices()[0], "device_kind", None)
-    peak_flops = obs_flops.peak_flops_for(device_kind)
+    # the registry's row is ONE chip; the step's FLOPs are spread over the mesh
+    chip_peak = obs_flops.peak_flops_for(device_kind)
+    peak_flops = chip_peak * hp.world_size if chip_peak else None
     autotune_mode = getattr(args, "autotune", "off") or "off"
     predictions = None
     if telemetry.active_sink() is not None or autotune_mode != "off":
@@ -488,62 +481,49 @@ def _train(args) -> dict:
     # steady-state step time: AOT-lower and compile at the first batch with
     # explicit timing (profiler trace_ms/compile_ms — under scan-over-layer-
     # runs these are depth-constant), then drive the loop with the compiled
-    # step. Wrapped step fns (fault hooks) and anything whose jit surface
-    # doesn't lower cleanly fall back to the plain jitted call, whose first
-    # invocation then includes the compile as before.
+    # step. A lowering or compile failure (out of memory, a kernel the
+    # compiler refuses) raises here with its cause; so does a later call
+    # whose inputs the executable was not compiled for — the step pins its
+    # output shardings (model_api.make_train_step), so that is a bug, not a
+    # reason to compile again. Only step fns wrapped by fault hooks, which
+    # have no jit surface to lower, are called as they are.
     _aot = {"fn": None}
 
     def compiled_step(*step_args):
+        if not hasattr(step_fn, "lower"):
+            return step_fn(*step_args)
         if _aot["fn"] is None:
-            try:
-                t0 = time.perf_counter()
-                lowered = step_fn.lower(*step_args)
-                t1 = time.perf_counter()
-                key = _step_exec_key(model.mesh, lowered)
-                compiled = _STEP_EXECUTABLES.get(key) if key is not None else None
-                memo_hit = compiled is not None
-                if compiled is None:
-                    compiled = _compile_uncached(lowered)
-                    if key is not None:
-                        _STEP_EXECUTABLES[key] = compiled
-                        while len(_STEP_EXECUTABLES) > _STEP_EXECUTABLES_MAX:
-                            _STEP_EXECUTABLES.popitem(last=False)
-                else:
-                    _STEP_EXECUTABLES.move_to_end(key)
-                t2 = time.perf_counter()
-                # an executable-memo hit reports compile_ms ~0 — true: this
-                # process did not run XLA again for this program
-                prof.record_compile(trace_ms=(t1 - t0) * 1e3,
-                                    compile_ms=(t2 - t1) * 1e3)
-                try:
-                    prof.compiled_memory_mb = compiled_step_memory_mb(compiled) or None
-                except Exception:
-                    prof.compiled_memory_mb = None
-                telemetry.emit(
-                    "compile",
-                    trace_ms=(t1 - t0) * 1e3,
-                    compile_ms=(t2 - t1) * 1e3,
-                    compiled_memory_mb=prof.compiled_memory_mb,
-                    xla_flops_per_step=obs_flops.xla_flops(compiled),
-                    cache_hit=memo_hit or None,
-                )
-                _aot["fn"] = compiled
-            except Exception:
-                _aot["fn"] = step_fn
-        if _aot["fn"] is not step_fn:
-            try:
-                return _aot["fn"](*step_args)
-            except ValueError:
-                # GSPMD may give the step's OUTPUT params shardings that
-                # differ from the input shardings the executable was compiled
-                # for (e.g. a replicated norm scale comes back dp-sharded);
-                # the AOT executable then refuses the next call's inputs,
-                # where plain jit would quietly recompile. Input validation
-                # precedes donation, so the buffers are intact — fall back to
-                # the jitted step from here on (same compile count as the
-                # pre-AOT driver; trace_ms/compile_ms stay measured).
-                _aot["fn"] = step_fn
-        return step_fn(*step_args)
+            t0 = time.perf_counter()
+            lowered = step_fn.lower(*step_args)
+            t1 = time.perf_counter()
+            key = _step_exec_key(model.mesh, lowered)
+            compiled = _STEP_EXECUTABLES.get(key)
+            memo_hit = compiled is not None
+            cache_hit = False
+            if memo_hit:
+                _STEP_EXECUTABLES.move_to_end(key)
+            else:
+                compiled, cache_hit = _compile_step(lowered)
+                _STEP_EXECUTABLES[key] = compiled
+                while len(_STEP_EXECUTABLES) > _STEP_EXECUTABLES_MAX:
+                    _STEP_EXECUTABLES.popitem(last=False)
+            t2 = time.perf_counter()
+            # a memo or persistent-cache hit reports compile_ms ~0 — true:
+            # this process did not run XLA again for this program
+            prof.record_compile(trace_ms=(t1 - t0) * 1e3,
+                                compile_ms=(t2 - t1) * 1e3,
+                                cache_hit=cache_hit)
+            prof.compiled_memory_mb = compiled_step_memory_mb(compiled) or None
+            telemetry.emit(
+                "compile",
+                trace_ms=(t1 - t0) * 1e3,
+                compile_ms=(t2 - t1) * 1e3,
+                compiled_memory_mb=prof.compiled_memory_mb,
+                xla_flops_per_step=obs_flops.xla_flops(compiled),
+                cache_hit=(memo_hit or cache_hit) or None,
+            )
+            _aot["fn"] = compiled
+        return _aot["fn"](*step_args)
 
     # deterministic resume: streams are stateless functions of the step index
     # (the reference keeps Megatron dataset cursors in the optimizer checkpoint)

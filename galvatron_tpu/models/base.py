@@ -32,7 +32,7 @@ from galvatron_tpu.config.strategy import (
     LayerStrategy,
     layer_runs,
 )
-from galvatron_tpu.ops.attention import core_attention
+from galvatron_tpu.ops.attention import KernelSharding, core_attention
 from galvatron_tpu.ops.norms import layer_norm, rms_norm
 from galvatron_tpu.ops.rope import apply_rotary
 from galvatron_tpu.parallel import spec as S
@@ -254,6 +254,7 @@ def layer_forward(
     axes: Optional[LayerAxes] = None,
     attn_bias: Optional[jax.Array] = None,
     return_kv: bool = False,
+    attn_sharding: Optional[KernelSharding] = None,
 ):
     """One transformer block on (B, S_local, H) activations.
 
@@ -266,8 +267,14 @@ def layer_forward(
     ``return_kv`` additionally returns this layer's post-rope (k, v)
     projections — the serving prefill's cache-write side outputs
     (serve/engine.py). Unsupported under ring context parallelism, whose
-    blockwise k/v never materialise per-layer."""
+    blockwise k/v never materialise per-layer.
+
+    ``attn_sharding`` is the attention kernel's layout for callers that run
+    this body with ``mesh=None`` under their own mapping (the GPipe stage
+    vmap); with a mesh and axes it is derived here."""
     dtype = cfg.compute_dtype
+    if mesh is not None and axes is not None:
+        attn_sharding = KernelSharding.for_layer(mesh, axes)
 
     residual = x
     y = _norm(x, p["ln1"], cfg) if cfg.pre_norm else x
@@ -307,7 +314,8 @@ def layer_forward(
         # the generic tree's attn_bias is always padding_attn_bias output, so
         # the flash path may lower it to segment ids instead of falling back
         attn = core_attention(q, k, v, causal=cfg.causal, bias=attn_bias,
-                              impl=cfg.attn_impl, bias_type="key_padding")
+                              impl=cfg.attn_impl, bias_type="key_padding",
+                              sharding=attn_sharding)
     attn = attn.reshape(attn.shape[0], attn.shape[1], cfg.num_heads * cfg.head_dim)
     o = _dense(attn, p["wo"], dtype)
     if mesh is not None and axes is not None:
@@ -390,6 +398,8 @@ def decode_layer_forward(
     attn = core_attention(
         q, k_cache.astype(dtype), v_cache.astype(dtype), causal=False,
         bias=attn_bias, impl=cfg.attn_impl,
+        sharding=(KernelSharding.for_layer(mesh, axes)
+                  if mesh is not None and axes is not None else None),
     )
     attn = attn.reshape(attn.shape[0], attn.shape[1], cfg.num_heads * cfg.head_dim)
     o = _dense(attn, p["wo"], dtype)
@@ -566,9 +576,10 @@ def stack_layer_run(layer_params: List[Params]) -> Params:
     `jnp.stack` (expand_dims per layer + one concatenate along the NEW,
     never-sharded axis) and not the cheaper concatenate-then-reshape trick:
     reshape-splitting a dim that is tp-sharded (the row-parallel `wo` /
-    `wo_mlp` kernels, P(tp, ...)) MISCOMPILES in the GSPMD partitioner
-    inside a scan on jax 0.4.37 XLA:CPU — silently wrong layer outputs, not
-    an error. The per-layer expand_dims are pure layout equations; XLA
+    `wo_mlp` kernels, P(tp, ...)) MISCOMPILED in the GSPMD partitioner
+    inside a scan (WA004; seen on jax 0.4.37 XLA:CPU, not ruled out on the
+    installed jax) — silently wrong layer outputs, not an error. The
+    per-layer expand_dims are pure layout equations; XLA
     compile time stays governed by the per-RUN body, which is what the
     trace-cost test asserts (tests/models/test_scan_layers.py)."""
     if len(layer_params) == 1:

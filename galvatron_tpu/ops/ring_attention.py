@@ -336,7 +336,7 @@ def ring_attention(
     # already-manual axes are typed Manual) and only make the within-stage
     # axes manual here.
     ctx = jax.sharding.get_abstract_mesh()
-    use_mesh = ctx if (ctx is not None and not ctx.empty) else mesh
+    use_mesh = mesh if ctx.empty else ctx
     return jax.shard_map(
         body,
         mesh=use_mesh,

@@ -64,8 +64,10 @@ def build_mesh(
 ) -> Mesh:
     """Mesh with axes ``("pp", "m0", ..., "m{k-1}")``.
 
-    On real hardware, prefer `mesh_utils.create_device_mesh` so minor axes map
-    to contiguous ICI; on CPU/test backends fall back to a plain reshape."""
+    `mesh_utils.create_device_mesh` maps minor axes to contiguous ICI. Only
+    single-host CPU (test) devices, which have no topology to respect, fall
+    back to a plain reshape when it cannot place a shape; on TPU, and across
+    hosts, its refusal is raised."""
     if devices is None:
         devices = jax.devices()
     if len(devices) < config.world_size:
@@ -82,9 +84,10 @@ def build_mesh(
     try:
         dev_array = device_mesh_for(shape, devices)
     except Exception:
-        if dcn_granule_count(devices) > 1:
-            # never silently downgrade a multi-host run to a locality-blind
-            # reshape: tp/cp would span DCN and cripple every collective
+        if devices[0].platform != "cpu" or dcn_granule_count(devices) > 1:
+            # never silently downgrade to a locality-blind reshape: tp/cp
+            # would leave their ICI rings (or span DCN) and cripple every
+            # collective
             raise
         dev_array = np.array(devices).reshape(shape)
     return Mesh(dev_array, names)

@@ -155,6 +155,7 @@ def pipeline_apply(
 ) -> jax.Array:
     """Run the scan pipeline; returns (num_mb, mb, S, H) last-stage outputs."""
     from galvatron_tpu.models.base import layer_forward
+    from galvatron_tpu.ops.attention import KernelSharding
 
     pp, num_mb = hp.pp, hp.chunks
     lps = layers_per_stage(hp)
@@ -166,13 +167,21 @@ def pipeline_apply(
 
     def stage_body(stage_layers: List[Params], x, pos, bias=None):
         for j in range(lps):
-            fwd = partial(layer_forward, cfg=cfg, mesh=None, axes=None, attn_bias=bias)
+            # mesh=None: GSPMD lays the vmapped body out from the stacked
+            # weights alone. The flash kernel is the exception — it cannot
+            # be partitioned, so it gets its layout explicitly; the vmap's
+            # spmd_axis_name below puts the stage dim into its manual region
+            fwd = partial(
+                layer_forward, cfg=cfg, mesh=None, axes=None, attn_bias=bias,
+                attn_sharding=KernelSharding.for_layer(mesh, layer_axes(hp, j)),
+            )
             if hp.layers[j].checkpoint:
                 fwd = jax.checkpoint(fwd)
             x = fwd(stage_layers[j], x, pos)
         return x
 
-    vstage = jax.vmap(stage_body, in_axes=(0, 0, 0, 0) if use_bias else (0, 0, 0))
+    vstage = jax.vmap(stage_body, in_axes=(0, 0, 0, 0) if use_bias else (0, 0, 0),
+                      spmd_axis_name=PP_AXIS)
 
     ax0 = layer_axes(hp, 0)
     buf_spec = P(PP_AXIS, S._ax(ax0.batch_axes), S._ax(ax0.seq_axes), None)
@@ -230,7 +239,8 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
         B = x.shape[0]
         mb = B // num_mb
 
-        # jax 0.4.37 GSPMD hazard (sibling of the stack_layer_run finding in
+        # GSPMD hazard (WA005; seen on jax 0.4.37, not ruled out on the
+        # installed jax; sibling of the stack_layer_run finding in
         # models/base.py): reshaping a dp-SHARDED batch dim into
         # (num_mb, mb, ...) and feeding the result straight into the tick
         # scan MISCOMPILES — silently wrong values, no error, and only when

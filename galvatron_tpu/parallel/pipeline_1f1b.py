@@ -47,7 +47,6 @@ the ``pp`` mesh axis and *auto* (GSPMD) over the within-stage axes:
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Any, Dict, List, NamedTuple
 
@@ -120,23 +119,16 @@ class Schedule(NamedTuple):
     inject_mb: np.ndarray  # (T,) microbatch embedded for stage-0 injection
 
 
-def use_masked_path(has_cp: bool = False) -> bool:
+def use_masked_path(mesh: Mesh, has_cp: bool = False) -> bool:
     """Mask-vs-branch path selection for the 1F1B engines (shared by the
-    enc-dec and swin variants). Default: CPU masks (divergent branch
-    collectives deadlock the single-process mesh), TPU branches (collectives
-    match statically per replica group). cp>1 always masks — the ring's
+    enc-dec and swin variants): CPU masks (divergent branch collectives
+    deadlock the single-process mesh), TPU branches (collectives match
+    statically per replica group). cp>1 always masks — the ring's
     collective-permutes need every participant every tick on any backend.
-    GALVATRON_1F1B_PATH=branch|masked overrides the backend default — used
-    by the AOT tests that compile the TPU branch path for an abstract
-    topology from a CPU host (tests/parallel/test_branch_path_aot.py)."""
-    if has_cp:
-        return True
-    force = os.environ.get("GALVATRON_1F1B_PATH", "")
-    if force == "branch":
-        return False
-    if force == "masked":
-        return True
-    return jax.default_backend() == "cpu"
+    The platform is the MESH's devices', not the process default backend, so
+    a compile for a described TPU topology from a CPU host takes the branch
+    the chip takes (tests/parallel/test_branch_path_aot.py)."""
+    return has_cp or mesh.devices.flat[0].platform == "cpu"
 
 
 def build_schedule(pp: int, chunks: int) -> Schedule:
@@ -319,7 +311,7 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
     # participant every tick on any backend, so cp>1 forces the masked path
     # (validate_1f1b_config already required stage-uniform strategies).
     has_cp = any(s.cp > 1 for s in hp.layers)
-    mask_not_branch = use_masked_path(has_cp)
+    mask_not_branch = use_masked_path(mesh, has_cp)
 
     # ------------------------------------------------------- vocab fwd pieces
     def embed_fwd(vparams, inputs, positions, token_types):

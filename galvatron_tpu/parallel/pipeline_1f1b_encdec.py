@@ -188,7 +188,7 @@ def make_encdec_loss_and_grad(cfg, hp: HybridParallelConfig, mesh):
     # encoder and decoder bodies always differ, so the lax.switch can never
     # collapse to a single body the way the generic engine's does
     uniform_stages = False
-    mask_not_branch = use_masked_path()
+    mask_not_branch = use_masked_path(mesh)
 
     # ------------------------------------------------- per-stage forward body
     def stage_body(s: int, Sq: int):

@@ -224,7 +224,7 @@ def make_swin_loss_and_grad(cfg, hp: HybridParallelConfig, mesh):
     N = L0 * C0  # flat channel width (largest activation; halves per merge)
     ch_spec = P(S._ax(vax.batch_axes), None)
 
-    mask_not_branch = use_masked_path()
+    mask_not_branch = use_masked_path(mesh)
 
     # ------------------------------------------------- per-stage forward body
     def stage_body(s: int):

@@ -40,10 +40,8 @@ quantized payloads carry a bounded relative error per block (<= 1/(2*qmax)
 of the block absmax per wire hop), pinned by
 tests/parallel/test_quant_collectives.py.
 
-jax 0.4.37 notes (inherited from PR 8, pinned in memory + tests): the
-shard_map here is manual over the dp axes with the size-1 'pp' axis auto
-(compiles fine; true partial-manual does not); custom_vjp bodies compute
-`lax.axis_index` inside the traced function, never close over it.
+custom_vjp bodies compute `lax.axis_index` inside the traced function, never
+close over it.
 """
 
 from __future__ import annotations
@@ -498,12 +496,22 @@ def make_quant_loss_and_grads(model) -> Callable:
 
     def loss_and_grads(params, batch):
         batch_specs = model.batch_specs(batch)
+        # check_vma=False is what this body MEANS, not a way around a check:
+        # under the varying-axes typing, value_and_grad w.r.t. a replicated
+        # (dp-invariant) leaf would already psum its cotangent over dp in
+        # full precision — the very collective this module replaces — and
+        # the ring's result, equal on every shard by construction, has no
+        # cast back to invariant. Untyped, the grads stay per-shard until
+        # the explicit ring sums them. Manual over every mesh axis: the
+        # layouts accepted here are pure dp, so what is left is the size-1
+        # 'pp' axis.
         return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(p_specs, batch_specs),
             out_specs=(P(), p_specs),
-            axis_names=set(dp_axes),
+            axis_names=set(mesh.axis_names),
+            check_vma=False,
         )(params, batch)
 
     return loss_and_grads

@@ -33,19 +33,21 @@ knob ``tp_comm_mode``:
 Numerics contract: both manual modes compute the same mathematical layer as
 GSPMD (parity-tested to tolerance — reduction orders differ); configs the
 manual path cannot express are REFUSED with a GLS012 diagnostic, never
-silently approximated. It also sidesteps the jax 0.4.37 GSPMD
-sharded-reshape miscompile class entirely: inside the manual region every
-reshape is a plain local op.
+silently approximated. Inside the manual region every reshape is a plain
+local op, which also keeps it clear of the GSPMD sharded-reshape miscompile
+class (WA004).
 
-Autodiff note (jax 0.4.37): the legacy shard_map the compat shim lowers to
-PSUMS cotangents over unmentioned manual axes at the region boundary on its
-own (verified empirically: an extra in-body psum over-counts grads by
-exactly the axis-group size), so parameter leaves entering with their dp
-axes dropped from the in_spec (replicated and ZeRO-3-gathered operands) get
-correct batch-summed gradients with no manual psum — the parity suite
-(tests/models/test_tp_comm_mode.py) pins loss AND grads against GSPMD for
-every supported tp/zero3/scan combination to keep that contract honest
-across jax upgrades.
+Autodiff note: `jax.shard_map` types every value by the manual axes it varies
+over. Parameter leaves enter with their dp axes dropped from the in_spec
+(replicated and ZeRO-3-gathered operands), so they are dp-INVARIANT while the
+activations vary over dp: wherever the two meet, jax inserts the cast to
+varying whose transpose is the psum of the cotangent over dp — the
+data-parallel gradient sum, with no manual psum in the body. A custom_vjp
+hides its inside from that mechanism and must return cotangents of its
+inputs' own type, so the ring matmuls make the cast themselves, outside the
+custom_vjp (`_vary_like`). The parity suite (tests/models/test_tp_comm_mode.py)
+pins loss AND grads against GSPMD for every supported tp/zero3/scan
+combination.
 """
 
 from __future__ import annotations
@@ -145,8 +147,7 @@ def _ring_perm(n: int) -> List[Tuple[int, int]]:
 
 def _flat_axis_index(axis_names: Tuple[str, ...], sizes: Tuple[int, ...]):
     """Flattened (row-major, major->minor — the order ppermute/all_gather
-    flatten a tuple of axis names) index of this device along `axis_names`.
-    jax 0.4.x `lax.axis_index` takes one name at a time."""
+    flatten a tuple of axis names) index of this device along `axis_names`."""
     idx = jnp.int32(0)
     for name, size in zip(axis_names, sizes):
         idx = idx * size + jax.lax.axis_index(name)
@@ -331,6 +332,18 @@ def _row_bwd_chunks(x, w, g, *, tp_axes, n, sizes):
     return dx, dw
 
 
+def _vary_like(w, x):
+    """Cast the weight shard to vary over every manual axis the activation
+    varies over (the dp axes: the kernel enters the region replicated over
+    them). A custom_vjp's bwd rule must hand back cotangents of its inputs'
+    own type, and dw = x^T g varies wherever x does; with the cast made
+    OUTSIDE the custom_vjp, its transpose is the psum of dw over those axes —
+    the data-parallel gradient sum, placed by autodiff where the typing says
+    it belongs."""
+    missing = tuple(sorted(jax.typeof(x).vma - jax.typeof(w).vma))
+    return jax.lax.pcast(w, missing, to="varying") if missing else w
+
+
 def make_col_matmul(tp_axes: Tuple[str, ...], n: int, sizes: Tuple[int, ...], *,
                     mode: str, use_custom_vjp: bool = True, quant=None):
     """(x_shard (B,s,H), w_shard (H,...)) -> (B,S,...). With `use_custom_vjp`
@@ -352,7 +365,7 @@ def make_col_matmul(tp_axes: Tuple[str, ...], n: int, sizes: Tuple[int, ...], *,
 
     col.defvjp(lambda x, w: (_col_matmul_chunks(x, w, **kw), (x, w)),
                lambda res, g: _col_bwd_chunks(*res, g, **bkw))
-    return col
+    return lambda x, w: col(x, _vary_like(w, x))
 
 
 def make_row_matmul(tp_axes: Tuple[str, ...], n: int, sizes: Tuple[int, ...], *,
@@ -370,7 +383,7 @@ def make_row_matmul(tp_axes: Tuple[str, ...], n: int, sizes: Tuple[int, ...], *,
 
     row.defvjp(lambda x, w: (_row_matmul_chunks(x, w, **kw), (x, w)),
                lambda res, g: _row_bwd_chunks(*res, g, **bkw))
-    return row
+    return lambda x, w: row(x, _vary_like(w, x))
 
 
 # -------------------------------------------------------------- layer body
@@ -510,13 +523,17 @@ def manual_layer_forward(
         body_fn = body
         operands = (p, x, positions, attn_bias)
     ctx = jax.sharding.get_abstract_mesh()
-    use_mesh = ctx if (ctx is not None and not ctx.empty) else mesh
+    use_mesh = mesh if ctx.empty else ctx
+    # manual over EVERY mesh axis, not dp+tp alone: what is left is 'pp',
+    # which has size 1 wherever this path runs (run_layers, pp=1). An axis
+    # left auto makes jax annotate shardings inside the psum reduction
+    # bodies, which XLA:CPU's bf16 all-reduce promotion aborts on.
     return jax.shard_map(
         body_fn,
         mesh=use_mesh,
         in_specs=in_specs,
         out_specs=x_spec,
-        axis_names=set(axes.dp) | set(axes.tp),
+        axis_names=set(use_mesh.axis_names) - set(use_mesh.manual_axes),
     )(*operands)
 
 

@@ -79,7 +79,11 @@ class RuntimeProfiler:
     # per-iteration start stamps keyed by iteration: the dispatch-ahead loop
     # keeps a window of steps in flight, so start(N+2) can precede end(N)
     _t0s: Dict[int, float] = field(default_factory=dict)
-    _wall_t0: Optional[float] = None  # first post-warmup start (loop_fence)
+    _wall_t0: Optional[float] = None  # start of the fenced post-warmup window:
+    # the moment the last warmup step DRAINED (first post-warmup start when
+    # there is no warmup). The first post-warmup dispatch would be too early
+    # under dispatch-ahead: warmup steps still in flight then would be
+    # executed inside the window without being counted in it
     _started: int = 0  # post-warmup dispatches (rollback replays count)
     iter_times_ms: List[float] = field(default_factory=list)
     all_times_ms: List[float] = field(default_factory=list)
@@ -96,6 +100,8 @@ class RuntimeProfiler:
     # skipped, rollbacks, I/O retries, emergency saves, torn checkpoints
     trace_ms: Optional[float] = None  # step-fn trace (lower) walltime
     compile_ms: Optional[float] = None  # XLA compile walltime of the step
+    compile_cache_hit: Optional[bool] = None  # step answered from the
+    # persistent compilation cache (utils/compile_cache.py)
     # MFU accounting (obs/flops.py): the driver sets the per-step model
     # FLOPs and the chip's peak so the summary can report MFU and
     # model-FLOPs/s next to every timing number
@@ -147,6 +153,8 @@ class RuntimeProfiler:
             self.iter_times_ms.append(dt)
             self.samples.append(n_samples)
             self.host_blocked_ms.append((now - tb) * 1e3)
+        elif iteration == self.warmup - 1 and not self.iter_times_ms:
+            self._wall_t0 = now  # (a rollback replay must not move it)
         return dt
 
     def loop_fence(self, outputs=None):
@@ -167,7 +175,8 @@ class RuntimeProfiler:
         self.comm_hidden_ms[int(run)] = float(hidden_ms)
 
     def record_compile(self, trace_ms: Optional[float] = None,
-                       compile_ms: Optional[float] = None):
+                       compile_ms: Optional[float] = None,
+                       cache_hit: Optional[bool] = None):
         """Record the one-off trace/compile cost of the jitted train step
         (cli/train.py AOT-lowers and compiles the step explicitly), so the
         summary separates program-build cost from steady-state step time —
@@ -177,6 +186,8 @@ class RuntimeProfiler:
             self.trace_ms = float(trace_ms)
         if compile_ms is not None:
             self.compile_ms = float(compile_ms)
+        if cache_hit is not None:
+            self.compile_cache_hit = bool(cache_hit)
 
     # ------------------------------------------------------------------ memory
     def profile_memory(self, iteration: int, stage: str = ""):
@@ -227,6 +238,8 @@ class RuntimeProfiler:
             out["trace_ms"] = self.trace_ms
         if self.compile_ms is not None:
             out["compile_ms"] = self.compile_ms
+        if self.compile_cache_hit is not None:
+            out["compile_cache_hit"] = self.compile_cache_hit
         if self.compiled_memory_mb is not None:
             out["compiled_step_memory_mb"] = self.compiled_memory_mb
         if self.model_flops:

@@ -37,22 +37,6 @@ import numpy as np
 import jax
 
 
-def _distributed_is_initialized() -> bool:
-    """jax.distributed.is_initialized() appeared after 0.4.37; on older jax
-    the equivalent signal is whether the distributed client exists. Neither
-    path touches jax.devices()/process_count(), so the backend stays
-    uninitialized (the constraint documented in initialize_distributed)."""
-    checker = getattr(jax.distributed, "is_initialized", None)
-    if checker is not None:
-        return bool(checker())
-    try:
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-    except Exception:
-        return False
-
-
 def initialize_distributed(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -68,7 +52,7 @@ def initialize_distributed(
     short-circuit must NOT touch jax.process_count()/jax.devices(): those
     initialize the local backend, after which jax.distributed.initialize
     raises — the bootstrap must run before any backend exists."""
-    if _distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return jax.process_count() > 1
     coordinator_address = coordinator_address or os.environ.get("GALVATRON_COORDINATOR")
     env_np = os.environ.get("GALVATRON_NUM_PROCESSES")

@@ -101,7 +101,8 @@ class HybridParallelModel:
         only its shard (the analogue of meta-device init + shard streaming,
         reference runtime/initialize.py:8-112)."""
         if self.init_fn is None and self.hp.pp > 1:
-            # jax 0.4.37 GSPMD hazard: fusing the per-layer init with the
+            # GSPMD hazard (WA006; seen on jax 0.4.37, not ruled out on the
+            # installed jax): fusing the per-layer init with the
             # jnp.stack into `stages` in ONE jitted program whose
             # out_shardings put the pp axis on the new stacked dim produces
             # silently wrong values in some stacked entries (eager init is
@@ -370,12 +371,24 @@ class HybridParallelModel:
             return new_params, new_opt_state, metrics
 
         donate_argnums = (0, 1) if donate else ()
+        # The state comes back in the shardings it went in with. Left to
+        # GSPMD, an output may pick another layout (a replicated norm scale
+        # comes back dp-sharded): the next call then sees new input
+        # shardings, which an AOT executable refuses and plain jit answers
+        # with a silent second compile, and the donated buffer is not reused.
+        out_shardings = (
+            self.shardings(),
+            self.opt_state_shardings(tx, self.abstract_params()),
+            None,
+        )
         if not guard_anomalies:
             def plain_step(params, opt_state, batch):
                 return train_step(params, opt_state, batch)
 
-            return jax.jit(plain_step, donate_argnums=donate_argnums)
-        return jax.jit(train_step, donate_argnums=donate_argnums)
+            return jax.jit(plain_step, donate_argnums=donate_argnums,
+                           out_shardings=out_shardings)
+        return jax.jit(train_step, donate_argnums=donate_argnums,
+                       out_shardings=out_shardings)
 
     def opt_state_shardings(self, tx: optax.GradientTransformation, params: Params):
         state_shape = jax.eval_shape(tx.init, params)

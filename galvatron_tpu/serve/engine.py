@@ -14,10 +14,8 @@ Execution model
   `kv_cache.length_bias`), appends the new k/v in place, and samples.
 - **Buckets**: context lengths are quantised to `page_size` pages; each
   (kind, page-count) pair gets ONE executable, AOT-compiled through an
-  in-process memo with the persistent compile cache BYPASSED — executing a
-  DESERIALIZED XLA:CPU executable through the AOT fast path corrupts the
-  allocator heap on jaxlib 0.4.37 (see cli/train.py `_compile_uncached` and
-  tests/conftest.py), so serve reuses live executable objects only.
+  in-process memo of live executables (re-launches are served by the
+  persistent compile cache, utils/compile_cache.py).
 - **Continuous batching**: slot-based admission in strict arrival (FIFO)
   order; a slot frees the moment its request hits `max_new_tokens`, and the
   next pending request is admitted at the following scheduler tick, so batch
@@ -75,21 +73,9 @@ def _cache_constrainer(cfg, hp, mesh, max_slots=None):
 
 # ------------------------------------------------------------- AOT executables
 # In-process memo of live compiled executables, keyed on (mesh device ids,
-# HLO digest) — the cli/train.py `_STEP_EXECUTABLES` discipline. Entries are
-# never serialized; `_compile_uncached` additionally keeps the compile itself
-# out of the persistent cache so no deserialized executable can ever reach
-# the AOT fast path (the jaxlib 0.4.37 heap-corruption hazard).
+# HLO digest) — the cli/train.py `_STEP_EXECUTABLES` discipline.
 _SERVE_EXECUTABLES: "OrderedDict[Tuple, Any]" = OrderedDict()
 _SERVE_EXECUTABLES_MAX = 32
-
-
-def _compile_uncached(lowered):
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        return lowered.compile()
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def _exec_key(mesh: Optional[Mesh], lowered) -> Optional[Tuple]:
@@ -117,7 +103,7 @@ def _aot_executable(jitted, mesh, *args):
         _SERVE_EXECUTABLES.move_to_end(key)
         return _SERVE_EXECUTABLES[key]
     try:
-        compiled = _compile_uncached(lowered)
+        compiled = lowered.compile()
     except ValueError:
         return jitted
     if key is not None:

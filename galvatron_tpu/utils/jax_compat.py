@@ -1,136 +1,26 @@
-"""jax 0.4.x compatibility shims.
+"""jax workaround inventory (the `lint --compat` registry).
 
-The codebase targets the modern jax surface (`jax.shard_map` with
-``axis_names=``/``check_vma=``, `jax.sharding.get_abstract_mesh`), but the
-pinned environment ships jax 0.4.37 where those live elsewhere or do not
-exist:
+The codebase runs on the installed jax's native surface (`jax.shard_map` with
+``axis_names=``/``check_vma=``, `jax.sharding.get_abstract_mesh`); nothing is
+patched in. What remains here is the list of *GSPMD-hazard workarounds* that
+were pinned on an older jax and are still carried in the code: each has a
+stable WA*** id, the module that carries it, and the pytest ids of the tests
+that pin the behaviour it protects. A workaround leaves this list (and the
+code) only after its hazard has been shown gone on the installed jax — the
+pinning tests pass WITH the workaround in place, so they cannot show that by
+themselves; the GLT detectors (analysis/trace_lint.py) keep the hazard
+*classes* flagged either way.
 
-- ``jax.shard_map``            -> ``jax.experimental.shard_map.shard_map``,
-  translating ``axis_names`` (the axes to make Manual) into the old ``auto=``
-  complement and ``check_vma`` into ``check_rep``.
-- ``jax.sharding.get_abstract_mesh`` -> no thread-local mesh context exists on
-  0.4.37 (``jax._src.mesh`` tracks an empty tuple); the fallback returns
-  ``None``, which callers treat as "no context mesh" (see
-  ops/ring_attention.py).
-
-`install()` is idempotent, patches only the *missing* names, and is invoked
-from the package ``__init__`` so every entry point (CLI, tests, notebooks)
-sees a uniform API. On a jax that already provides these names the shim is a
-no-op. The static code linter (analysis/code_lint.py GLC001) resolves
-attribute chains against the *patched* module, so `jax.shard_map` call sites
-lint clean exactly when this shim (or a modern jax) provides them.
+Probes return ``(active, detail)`` where active is True (the installed jax
+still needs the workaround), False (retirable) or None (not decided).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import wraps
 from typing import Callable, List, Optional, Tuple
 
 import jax
-
-
-def _shard_map_shim():
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    @wraps(_legacy_shard_map)
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  axis_names=None, check_vma=None, check_rep=None,
-                  auto=None, **kwargs):
-        """Modern-signature `jax.shard_map` on top of the 0.4.x experimental
-        API. ``axis_names`` lists the mesh axes the body is *manual* over;
-        the legacy API instead takes ``auto`` — the complement."""
-        if auto is None:
-            if axis_names is not None and mesh is not None:
-                auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            else:
-                auto = frozenset()
-        if check_rep is None:
-            check_rep = bool(check_vma) if check_vma is not None else True
-        if auto:
-            # 0.4.x cannot run the replication checker over partially-auto
-            # meshes (it raises); the modern default is equivalent to off.
-            check_rep = False
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_rep, auto=frozenset(auto), **kwargs,
-        )
-
-    shard_map._galvatron_shim = True  # the WA001 inventory probe
-    return shard_map
-
-
-def _get_abstract_mesh_shim():
-    def get_abstract_mesh():
-        """0.4.x has no use_mesh/abstract-mesh context; report "none" so
-        callers fall back to their explicit concrete mesh."""
-        return None
-
-    get_abstract_mesh._galvatron_shim = True  # the WA002 inventory probe
-    return get_abstract_mesh
-
-
-def install() -> None:
-    """Patch the missing modern APIs into the installed jax. Idempotent."""
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = _shard_map_shim()
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        jax.sharding.get_abstract_mesh = _get_abstract_mesh_shim()
-
-
-_PARTIAL_MANUAL: dict = {}
-
-
-def supports_partial_manual_shard_map() -> bool:
-    """Whether this jax can compile a shard_map that is manual over a SUBSET
-    of the mesh axes with a collective inside (the 1F1B engines' shape:
-    manual over 'pp', GSPMD-auto within the stage). jax 0.4.x's legacy
-    ``auto=`` lowering emits a PartitionId op that SPMD partitioning rejects
-    at compile time; modern jax handles it. Probed once per process by
-    compiling a 4-device toy (device_count permitting), not version-matched,
-    so a backport or partial fix flips the answer automatically."""
-    if "ok" in _PARTIAL_MANUAL:
-        return _PARTIAL_MANUAL["ok"]
-    # The probe MUST run out-of-process: on jax 0.4.x some partial-manual
-    # lowerings die in a fatal XLA CHECK (spmd_partitioner.cc
-    # IsManualSubgroup), which would abort the probing process itself.
-    import subprocess
-    import sys
-
-    code = (
-        "import os\n"
-        "os.environ['XLA_FLAGS'] = os.environ.get('XLA_FLAGS', '') "
-        "+ ' --xla_force_host_platform_device_count=4'\n"
-        "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
-        "import numpy as np, jax, jax.numpy as jnp\n"
-        "from jax.experimental.shard_map import shard_map\n"
-        "from jax.sharding import Mesh, PartitionSpec as P\n"
-        "mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ('pp', 'dp'))\n"
-        "f = shard_map(lambda x: jax.lax.ppermute(x, 'pp', [(0, 1), (1, 0)]),\n"
-        "              mesh=mesh, in_specs=P('pp'), out_specs=P('pp'),\n"
-        "              check_rep=False, auto=frozenset({'dp'}))\n"
-        "jax.jit(f).lower(jnp.zeros((4, 4))).compile()\n"
-    )
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", code], capture_output=True, timeout=180,
-        )
-        _PARTIAL_MANUAL["ok"] = proc.returncode == 0
-    except Exception:  # noqa: BLE001 - any probe failure means "no"
-        _PARTIAL_MANUAL["ok"] = False
-    return _PARTIAL_MANUAL["ok"]
-
-
-# --------------------------------------------------------------------------
-# Workaround inventory (the `lint --compat` registry, ROADMAP item 5's
-# retirement checklist). Every pinned jax-0.4.37 workaround in the codebase
-# gets a stable WA*** id, an installed-jax probe and the pytest ids of the
-# tests that pin its behaviour, so the upgrade PR is mechanical: bump jax,
-# run `lint --compat --deep`, retire whatever reports RETIRABLE, and keep
-# whatever the pinning tests still demand. Probes return
-# ``(active, detail)`` where active is True (the installed jax still needs
-# the workaround), False (retirable) or None (cannot be decided cheaply —
-# rerun with deep=True or rerun the pinning tests on the new jax).
 
 
 @dataclass(frozen=True)
@@ -140,116 +30,28 @@ class WorkaroundEntry:
     where: str  # the module carrying the workaround
     pinning_tests: Tuple[str, ...]  # pytest ids that pin the behaviour
     probe: Callable[[], Tuple[Optional[bool], str]]
-    deep_probe: Optional[Callable[[], Tuple[Optional[bool], str]]] = None
 
 
-def _jax_version_tuple() -> Tuple[int, ...]:
-    out = []
-    for part in jax.__version__.split("."):
-        digits = "".join(ch for ch in part if ch.isdigit())
-        if not digits:
-            break
-        out.append(int(digits))
-    return tuple(out)
-
-
-def _probe_shim(attr_chain: str):
-    def probe() -> Tuple[Optional[bool], str]:
-        obj = jax
-        for name in attr_chain.split("."):
-            obj = getattr(obj, name, None)
-            if obj is None:
-                return None, "%s missing from the installed jax" % attr_chain
-        if getattr(obj, "_galvatron_shim", False):
-            return True, "shim installed (jax %s lacks the native API)" % jax.__version__
-        return False, "jax %s provides %s natively — shim retirable" % (
-            jax.__version__, attr_chain)
-
-    return probe
-
-
-def _probe_miscompile_range(detail_active: str):
-    """The three GSPMD miscompile classes and the XLA:CPU cache corruption
-    are pinned on the 0.4.x line; no cheap in-process probe can prove a
-    newer jax fixed them, so outside that range the answer is 'unverified —
-    rerun the pinning tests' rather than a guess."""
-
-    def probe() -> Tuple[Optional[bool], str]:
-        v = _jax_version_tuple()
-        if v[:2] <= (0, 4):
-            return True, "jax %s is in the pinned 0.4.x hazard range: %s" % (
-                jax.__version__, detail_active)
-        return None, ("unverified on jax %s — rerun the pinning tests "
-                      "before retiring" % jax.__version__)
-
-    return probe
-
-
-def _probe_partial_manual_cheap() -> Tuple[Optional[bool], str]:
-    if "ok" in _PARTIAL_MANUAL:  # a deep run already paid for the answer
-        return _probe_partial_manual_deep()
-    v = _jax_version_tuple()
-    if v[:2] <= (0, 4):
-        return True, ("jax %s: legacy auto= lowering emits PartitionId ops "
-                      "SPMD partitioning rejects (fatal XLA CHECK); probe "
-                      "with --deep to compile the 4-device toy" % jax.__version__)
-    return None, "needs the out-of-process compile probe (run with --deep)"
-
-
-def _probe_partial_manual_deep() -> Tuple[Optional[bool], str]:
-    ok = supports_partial_manual_shard_map()
-    if ok:
-        return False, ("installed jax compiles the partial-manual toy — the "
-                       "compile gate is retirable")
-    return True, "partial-manual shard_map still fails to compile (probed)"
+def _probe_unverified() -> Tuple[Optional[bool], str]:
+    """The GSPMD miscompile classes produce silently wrong values, not
+    errors; no cheap in-process probe can prove the installed jax free of
+    them, so the answer is 'unverified' rather than a guess."""
+    return None, ("unverified on jax %s — reproduce the hazard without the "
+                  "workaround before retiring" % jax.__version__)
 
 
 WORKAROUNDS: Tuple[WorkaroundEntry, ...] = (
     WorkaroundEntry(
-        code="WA001",
-        title="jax.shard_map modern-signature shim "
-              "(axis_names/check_vma -> legacy auto/check_rep)",
-        where="utils/jax_compat.py:_shard_map_shim",
-        pinning_tests=(
-            "tests/analysis/test_jax_compat.py::test_shim_installed_by_package_import",
-            "tests/analysis/test_jax_compat.py::test_shard_map_full_manual_runs",
-        ),
-        probe=_probe_shim("shard_map"),
-    ),
-    WorkaroundEntry(
-        code="WA002",
-        title="jax.sharding.get_abstract_mesh fallback (no thread-local "
-              "mesh context on 0.4.x)",
-        where="utils/jax_compat.py:_get_abstract_mesh_shim",
-        pinning_tests=(
-            "tests/analysis/test_jax_compat.py::test_get_abstract_mesh_contract",
-        ),
-        probe=_probe_shim("sharding.get_abstract_mesh"),
-    ),
-    WorkaroundEntry(
-        code="WA003",
-        title="partial-manual shard_map compile gate (out-of-process probe; "
-              "1F1B engines skip on unsupported jax)",
-        where="utils/jax_compat.py:supports_partial_manual_shard_map",
-        pinning_tests=(
-            "tests/analysis/test_jax_compat.py::test_partial_manual_probe_is_cached_and_boolean",
-            "tests/analysis/test_jax_compat.py::test_shard_map_axis_names_accepts_partial_manual_tracing",
-        ),
-        probe=_probe_partial_manual_cheap,
-        deep_probe=_probe_partial_manual_deep,
-    ),
-    WorkaroundEntry(
         code="WA004",
         title="jnp.stack (never concat+reshape) when stacking layer params "
-              "for the scan runs — GSPMD miscompiles a sharded-dim reshape "
+              "for the scan runs — GSPMD miscompiled a sharded-dim reshape "
               "inside a scan",
         where="models/base.py:stack_layer_run",
         pinning_tests=(
             "tests/models/test_tp_comm_mode.py::test_sharded_paths_match_unsharded_reference",
             "tests/analysis/test_trace_lint.py::test_glt001_sharded_reshape_in_scan_flagged",
         ),
-        probe=_probe_miscompile_range(
-            "sharded-dim reshape inside scan corrupts the stacked values"),
+        probe=_probe_unverified,
     ),
     WorkaroundEntry(
         code="WA005",
@@ -260,8 +62,7 @@ WORKAROUNDS: Tuple[WorkaroundEntry, ...] = (
             "tests/parallel/test_pipeline.py::test_pipeline_matches_dp",
             "tests/analysis/test_trace_lint.py::test_glt002_unconstrained_microbatch_split_flagged",
         ),
-        probe=_probe_miscompile_range(
-            "unconstrained dp-sharded split under the tick scan miscompiles"),
+        probe=_probe_unverified,
     ),
     WorkaroundEntry(
         code="WA006",
@@ -272,49 +73,18 @@ WORKAROUNDS: Tuple[WorkaroundEntry, ...] = (
             "tests/parallel/test_pipeline.py::test_pipelined_bert_mlm_matches_single_stage",
             "tests/analysis/test_trace_lint.py::test_glt003_stacked_init_under_out_shardings_flagged",
         ),
-        probe=_probe_miscompile_range(
-            "fused stacked init under pp out_shardings yields wrong entries"),
-    ),
-    WorkaroundEntry(
-        code="WA007",
-        title="persistent compilation cache bypassed for the AOT step; "
-              "in-process executable memo instead (XLA:CPU deserialized "
-              "executables corrupt the allocator heap)",
-        where="cli/train.py:_compile_uncached/_STEP_EXECUTABLES",
-        pinning_tests=(
-            "tests/analysis/test_compat_inventory.py::test_wa007_compile_uncached_bypasses_persistent_cache",
-        ),
-        # no deep probe on purpose: the failure mode is heap corruption in
-        # the probing process (see tests/conftest.py KNOWN HAZARD)
-        probe=_probe_miscompile_range(
-            "deserialized XLA:CPU executables SIGSEGV on the AOT fast path"),
-    ),
-    WorkaroundEntry(
-        code="WA008",
-        title="manual-TP bwd never psums cotangents over the tp axes — the "
-              "legacy shard_map transpose auto-psums unmentioned manual "
-              "axes at the region boundary",
-        where="parallel/tp_shard_map.py (autodiff note)",
-        pinning_tests=(
-            "tests/models/test_tp_comm_mode.py::test_manual_path_matches_gspmd",
-        ),
-        probe=_probe_shim("shard_map"),
+        probe=_probe_unverified,
     ),
 )
 
 
-def workaround_inventory(deep: bool = False) -> List[dict]:
+def workaround_inventory() -> List[dict]:
     """Probe every registered workaround against the installed jax.
     Each row: ``{code, title, where, active, detail, pinning_tests}`` with
-    ``active`` True/False/None (see module comment). ``deep=True`` runs the
-    expensive probes (out-of-process compiles) where one exists."""
+    ``active`` True/False/None (see module comment)."""
     rows = []
     for wa in WORKAROUNDS:
-        probe = wa.deep_probe if (deep and wa.deep_probe is not None) else wa.probe
-        try:
-            active, detail = probe()
-        except Exception as e:  # a broken probe must not take down the CLI
-            active, detail = None, "probe failed: %s" % e
+        active, detail = wa.probe()
         rows.append({
             "code": wa.code,
             "title": wa.title,
@@ -336,6 +106,3 @@ def render_inventory(rows: List[dict]) -> str:
         lines.append("         probe: %s" % r["detail"])
         lines.append("         pinned by: %s" % ", ".join(r["pinning_tests"]))
     return "\n".join(lines)
-
-
-install()

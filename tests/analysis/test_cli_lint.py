@@ -87,7 +87,7 @@ def test_explain_prints_code_table(capsys):
     assert run(["--explain"]) == 0
     out = capsys.readouterr().out
     for code in ("GLS001", "GLS014", "GLS101", "GLC001", "GLC004",
-                 "GLC007", "GLT001", "GLT003", "GLT101", "WA001", "WA008"):
+                 "GLC007", "GLT001", "GLT003", "GLT101", "WA004", "WA006"):
         assert code in out
 
 
@@ -123,8 +123,7 @@ def test_trace_and_compat_json_additive(capsys, devices8):
     assert payload["summary"]["errors"] == 0
     # ...and the new families ride along additively
     assert [r["code"] for r in payload["compat_inventory"]] == [
-        "WA001", "WA002", "WA003", "WA004", "WA005", "WA006", "WA007",
-        "WA008"]
+        "WA004", "WA005", "WA006"]
     assert all(r["pinning_tests"] for r in payload["compat_inventory"])
     assert payload["trace_audit"][0]["target"].startswith("<uniform")
 
@@ -133,7 +132,7 @@ def test_compat_human_output_lists_workarounds(capsys):
     assert run(["--compat"]) == 0
     out = capsys.readouterr().out
     assert "jax workaround inventory" in out
-    for code in ("WA001", "WA007"):
+    for code in ("WA004", "WA006"):
         assert code in out
 
 

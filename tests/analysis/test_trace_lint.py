@@ -154,7 +154,7 @@ def test_glt004_matched_donation_clean():
 
 # ------------------------------------- GLT005 (manual-region vjp closure)
 def _ring_region(mesh, close_over):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def outer(x):
         def body(xb):
@@ -181,7 +181,7 @@ def _ring_region(mesh, close_over):
             return f(xb)
 
         sm = shard_map(body, mesh=mesh, in_specs=P(None, "tp"),
-                       out_specs=P(None, "tp"), check_rep=False)
+                       out_specs=P(None, "tp"), check_vma=False)
         return jax.grad(lambda v: sm(v).sum())(x)
 
     return jax.make_jaxpr(jax.jit(outer))(_sds((8, 8)))

@@ -85,7 +85,7 @@ def test_compilation_flags_default_and_plumbing(tmp_path):
 
     args = initialize_galvatron(mode="train_dist", argv=[])
     assert args.scan_layers is True and args.remat_policy == "full"
-    assert args.compile_cache == 0
+    assert not hasattr(args, "compile_cache")  # always on; placed by env
     hp = hp_config_from_args(args, num_layers=2, world_size=8)
     assert hp.scan_layers is True and hp.remat_policy == "full"
 
@@ -181,26 +181,35 @@ def test_tp_comm_mode_validated():
         HybridParallelConfig.uniform(8, 2, tp_comm_mode="bogus")
 
 
-def test_persistent_compile_cache_opt_in(tmp_path):
-    """enable_persistent_cache points jax at the requested dir (created if
-    missing). EVERY touched config knob is restored afterwards: leaking the
-    0.0 min-compile-time threshold into the session made later suite
-    compiles round-trip through the persistent cache, which 0.4.37's
-    XLA:CPU executable deserialization answers with a segfault mid-suite
-    (the same hazard class tests/conftest.py documents — it pins the
-    threshold at 1.0s for a reason)."""
+@pytest.mark.parametrize("from_env", [True, False])
+def test_persistent_compile_cache_dir_contract(tmp_path, monkeypatch, from_env):
+    """Where the cache lives is decided from outside: with
+    JAX_COMPILATION_CACHE_DIR set the program sets no directory of its own;
+    unset, it is the one fixed path inside the checkout (never a temporary
+    name, never under $HOME). Every touched config knob is restored."""
+    import os
+
     import jax
 
-    from galvatron_tpu.utils.compile_cache import enable_persistent_cache
+    from galvatron_tpu.utils import compile_cache as CC
 
     old_dir = jax.config.jax_compilation_cache_dir
     old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert CC.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_compile_cache")
     try:
-        target = tmp_path / "xla_cache"
-        got = enable_persistent_cache(str(target))
-        assert got == str(target)
-        assert target.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(target)
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+            jax.config.update("jax_compilation_cache_dir", "sentinel-set-by-jax")
+            assert CC.enable_persistent_cache() == str(tmp_path / "outside")
+            # untouched: jax took the variable itself at start-up
+            assert jax.config.jax_compilation_cache_dir == "sentinel-set-by-jax"
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            monkeypatch.setattr(CC, "DEFAULT_CACHE_DIR", str(tmp_path / "fixed"))
+            assert CC.enable_persistent_cache() == str(tmp_path / "fixed")
+            assert (tmp_path / "fixed").is_dir()
+            assert jax.config.jax_compilation_cache_dir == str(tmp_path / "fixed")
     finally:
         jax.config.update("jax_compilation_cache_dir", old_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)

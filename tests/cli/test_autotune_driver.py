@@ -29,6 +29,16 @@ TINY = [
 ]
 
 
+# Iteration budget of the apply leg. A settle needs `window` (3) consecutive
+# drained steps within the tolerance, and under the dispatch-ahead window
+# the step after a (re)compile drains at about twice the steady latency (it
+# queues behind the compile step), so an epoch settles at its 5th drained
+# step and plans two dispatches later: first plan at iteration 7, second at
+# 14 or 15. 14 iterations left no room for the second, and on a noisy box a
+# window fails the tolerance and the settle comes later still.
+APPLY_ITERS = 22
+
+
 def _run(extra, tele):
     from galvatron_tpu.cli.arguments import initialize_galvatron
     from galvatron_tpu.cli.train import train
@@ -36,6 +46,9 @@ def _run(extra, tele):
     args = initialize_galvatron(
         mode="train_dist", argv=TINY + extra + ["--telemetry", tele])
     args.autotune_window = 3  # settle within the short test run
+    # ...and on a box whose step times jitter: these tests are about what the
+    # planner decides once settled, not about when a noisy series settles
+    args.autotune_rel_std = 0.5
     summary = train(args)
     with open(tele) as f:
         events = [json.loads(line) for line in f]
@@ -57,7 +70,7 @@ def apply_run(tmp_path_factory, devices8):
         world_size=8, num_layers=2, pp=1, tp=1, checkpoint=1, global_bsz=8,
     ).save(start)
     summary, events = _run(
-        ["--train_iters", "14", "--autotune", "apply",
+        ["--train_iters", str(APPLY_ITERS), "--autotune", "apply",
          "--galvatron_config_path", start],
         str(tmp / "apply.jsonl"))
     return summary, events, tmp
@@ -87,7 +100,7 @@ def test_swap_goes_through_live_migration_not_restart(apply_run):
     # training continued in-process across the swap: the step series covers
     # every iteration exactly once, no run_start restart
     iters = [e["iter"] for e in events if e["type"] == "step"]
-    assert iters == list(range(14))
+    assert iters == list(range(APPLY_ITERS))
     assert len([e for e in events if e["type"] == "run_start"]) == 1
     assert sw["iter"] in iters
 
@@ -119,7 +132,7 @@ def test_post_swap_plan_converges_without_thrash(apply_run):
 
 def test_losses_stay_finite_across_swap(apply_run):
     summary, events, _ = apply_run
-    assert len(summary["losses"]) == 14
+    assert len(summary["losses"]) == APPLY_ITERS
     assert all(math.isfinite(l) for l in summary["losses"])
 
 
@@ -132,7 +145,7 @@ def test_optimal_start_never_swaps(apply_run, tmp_path):
     with open(winner, "w") as f:
         json.dump(sw["to_strategy"], f)
     summary, ev2 = _run(
-        ["--train_iters", "7", "--autotune", "apply",
+        ["--train_iters", "14", "--autotune", "apply",
          "--galvatron_config_path", winner],
         str(tmp_path / "noop.jsonl"))
     plans = _plans(ev2)

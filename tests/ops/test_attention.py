@@ -333,6 +333,36 @@ def test_flash_segment_ids_match_xla_padding(causal):
                                atol=3e-5)
 
 
+def test_sharded_flash_kernel_matches_xla(devices8):
+    """The kernel under sharding (KernelSharding: one manual region, batch
+    over m0, heads over m1) against XLA attention on the same sharded
+    operands — pallas interpret mode on a 2x2 CPU mesh (forward; the
+    backward under sharding is compiled for the chip in test_tpu_compile.py
+    and run by chip_smoke.py). What GSPMD cannot partition on the chip is
+    computed per device here."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    from galvatron_tpu.ops import attention as A
+
+    mesh = Mesh(np.array(devices8[:4]).reshape(1, 2, 2), ("pp", "m0", "m1"))
+    b, s, nh, hd = 2, 256, 2, 128
+    sh = NamedSharding(mesh, P("m0", None, "m1", None))
+    q, k, v = (jax.device_put(t, sh)
+               for t in _rand_qkv(jax.random.PRNGKey(34), b=b, s=s, nh=nh, hd=hd))
+    shd = A.KernelSharding(mesh, ("m0",), ("m1",))
+
+    def flash(q_):
+        return A.core_attention(q_, k, v, causal=True, impl="flash", sharding=shd)
+
+    def xla(q_):
+        return A._xla_attention(q_, k, v, causal=True, sm_scale=hd**-0.5)
+
+    with pltpu.force_tpu_interpret_mode():
+        out = jax.jit(flash)(q)
+    assert out.sharding.is_equivalent_to(sh, out.ndim)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(xla(q)), atol=3e-5)
+
+
 def test_core_attention_padding_dispatch_stays_flash_eligible():
     """Dispatch logic: a key-padding bias keeps flash eligibility (lowered to
     segment ids) while a generic additive bias (T5 relative positions) and

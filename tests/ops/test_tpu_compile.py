@@ -1,0 +1,159 @@
+"""Compile-only checks against a DESCRIBED TPU v5e 2x2 (no chip attached).
+
+libtpu compiles for a topology that is described, not attached
+(`jax.experimental.topologies`), so what the chip's compiler would refuse —
+a kernel it cannot tile, a Mosaic call GSPMD would have to partition, a step
+that does not fit HBM — is refused here, on the CPU box, at no chip time.
+Nothing runs: these say nothing about results or speed (chip_smoke.py does).
+The persistent compilation cache is off around them: an entry written for a
+described device cannot be read back without one, and the next compile would
+warn and compile again.
+"""
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from galvatron_tpu.ops import attention as A
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+V5E_HBM_BYTES = 15.75 * 2**30
+
+B, S, NH, HD = 2, 2048, 32, 128  # LLaMA-7B attention, batch cut to 2
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this host
+        pytest.skip("cannot describe a TPU topology here: %s" % e)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _qkv(sharding):
+    return jax.ShapeDtypeStruct((B, S, NH, HD), jnp.bfloat16, sharding=sharding)
+
+
+def _attn_loss(sharding):
+    """Causal flash attention loss; an optional 4th operand is a key-padding
+    bias, which rides the kernel as segment ids."""
+    def loss(q, k, v, *b):
+        out = A.core_attention(q, k, v, causal=True, impl="flash", sharding=sharding,
+                               bias=b[0] if b else None, bias_type="key_padding")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    return loss
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_kernel_compiles_for_v5e(v5e_2x2, backward):
+    """The repo's block sizes (1024 x 512) at 7B attention shapes, causal."""
+    one = SingleDeviceSharding(v5e_2x2[0])
+    fn = _attn_loss(A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("x",))))
+    if backward:
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(_qkv(one), _qkv(one), _qkv(one)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_flash_segment_id_form_compiles_for_v5e(v5e_2x2):
+    """A key-padding bias rides the kernel as segment ids (forward+backward)."""
+    one = SingleDeviceSharding(v5e_2x2[0])
+    bias = jax.ShapeDtypeStruct((B, 1, 1, S), jnp.float32, sharding=one)
+    fn = jax.grad(_attn_loss(A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("x",)))),
+                  argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(_qkv(one), _qkv(one), _qkv(one), bias).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_sharded_flash_kernel_compiles_on_2x2_mesh(v5e_2x2):
+    """Batch over 2, heads over 2: under GSPMD alone this raises 'Mosaic
+    kernels cannot be automatically partitioned'; the manual region
+    (ops/attention.KernelSharding) gives each chip its own rows and heads,
+    and needs no collective to do so."""
+    mesh = Mesh(np.array(v5e_2x2).reshape(1, 2, 2), ("pp", "m0", "m1"))
+    sh = NamedSharding(mesh, P("m0", None, "m1", None))
+    fn = jax.grad(_attn_loss(A.KernelSharding(mesh, ("m0",), ("m1",))), argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(_qkv(sh), _qkv(sh), _qkv(sh)).compile().as_text()
+    assert "tpu_custom_call" in text
+    for collective in ("all-gather", "all-reduce", "all-to-all", "collective-permute"):
+        assert collective not in text, collective
+
+
+def test_auto_dispatch_reads_the_platform_off_the_mesh(v5e_2x2):
+    """impl='auto' on a described-TPU mesh takes the kernel although this
+    process's default backend is the CPU — the branch the chip takes."""
+    assert jax.default_backend() == "cpu"
+    mesh = Mesh(np.array(v5e_2x2).reshape(1, 4), ("pp", "m0"))
+    sh = NamedSharding(mesh, P("m0", None, None, None))
+    shd = A.KernelSharding(mesh, ("m0",), ())
+
+    def fwd(q, k, v):
+        return A.core_attention(q, k, v, causal=True, sharding=shd)
+
+    q = jax.ShapeDtypeStruct((4, S, NH, HD), jnp.bfloat16, sharding=sh)
+    assert "tpu_custom_call" in jax.jit(fwd).lower(q, q, q).compile().as_text()
+
+
+def test_one_chip_7b_width_step_fits_v5e_hbm(v5e_2x2):
+    """The train step chip_smoke.py runs (LLaMA-7B width, 2 layers, batch 2,
+    seq 2048, bf16 compute, fp32 params + Adam) compiles for one v5e chip,
+    holds the kernel, and its program fits the chip's 15.75 GiB."""
+    from galvatron_tpu.config.strategy import HybridParallelConfig
+    from galvatron_tpu.models.llama import llama_config
+    from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
+    from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
+
+    cfg = llama_config("llama-7b", num_layers=2, compute_dtype=jnp.bfloat16)
+    hp = HybridParallelConfig.uniform(1, 2, global_bsz=2, mixed_precision="bf16")
+    m = construct_hybrid_parallel_model(cfg, hp, v5e_2x2[:1])
+    tx, _ = get_optimizer_and_scheduler(OptimizerArgs(lr=1e-4, warmup_steps=0, total_steps=8))
+
+    def sds(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings)
+
+    params = m.abstract_params()
+    opt = jax.eval_shape(tx.init, params)
+    tok = jax.ShapeDtypeStruct((2, cfg.max_seq_len), jnp.int32)
+    batch = {k: jax.ShapeDtypeStruct(tok.shape, tok.dtype,
+                                     sharding=NamedSharding(m.mesh, m._batch_spec_for(tok)))
+             for k in ("tokens", "positions", "labels")}
+    compiled = m.make_train_step(tx).lower(
+        sds(params, m.shardings()), sds(opt, m.opt_state_shardings(tx, params)), batch,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+             + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, "%.2f GiB" % (total / 2**30)
+
+
+def test_chip_smoke_refuses_without_a_tpu():
+    """chip_smoke.py on the CPU exits non-zero before any work and prints no
+    verdict — a measurement path that finds no chip fails, it does not fall
+    back."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert proc.returncode != 0, proc.stdout
+    assert '"ok": true' not in proc.stdout
+    assert proc.stdout.strip() == "", proc.stdout

@@ -30,13 +30,10 @@ from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
 
 pytestmark = [pytest.mark.parallel, pytest.mark.distributed]
+# The 1F1B engines compile and run on the installed jax. One parity case per
+# engine (generic, enc-dec, Swin) stays in tier-1; the other compile-heavy
+# cases are `slow`, so that tier-1 still ends inside its clock.
 
-from tests.conftest import requires_partial_manual_shard_map
-
-# jax 0.4.x cannot compile the engines' partial-manual shard_map regions
-# (see tests/conftest.py); probed once per session, auto-re-enables on a
-# capable jax
-_PARTIAL_MANUAL = requires_partial_manual_shard_map()
 
 EXTENDED = bool(os.environ.get("GALVATRON_EXTENDED_TESTS"))
 
@@ -68,7 +65,7 @@ def _compile_step(m, batch):
     return compiled, params, opt_state
 
 
-@_PARTIAL_MANUAL
+@pytest.mark.slow
 def test_multichip_gate_config(devices8):
     """The EXACT __graft_entry__.dryrun_multichip(8) config, executed: the
     round-2 deadlock (MULTICHIP_r02.json ok=false). Whatever the external gate
@@ -80,7 +77,7 @@ def test_multichip_gate_config(devices8):
     assert np.isfinite(float(metrics["loss"]))
 
 
-@_PARTIAL_MANUAL
+@pytest.mark.slow
 def test_gpt_learned_positions_with_sp(devices8):
     """GPT (learned positions, biases, fused qkv) through the 1F1B schedule
     with a ulysses-sp layer — the composition that exposed the round-3
@@ -115,7 +112,7 @@ def test_gpt_learned_positions_with_sp(devices8):
     assert losses[-1] < losses[0], losses
 
 
-@_PARTIAL_MANUAL
+@pytest.mark.slow
 def test_cp_ring_inside_1f1b(devices8):
     """Ring-attention context parallelism INSIDE the pipeline (cp=2 x pp=2) —
     rejected in rounds 1-2 (pipeline.py:69-71 / pipeline_1f1b.py:72-74). The
@@ -134,7 +131,7 @@ def test_cp_ring_inside_1f1b(devices8):
     assert np.isfinite(losses[-1]) and losses[-1] < losses[0], losses
 
 
-@_PARTIAL_MANUAL
+@pytest.mark.slow
 def test_ulysses_cp_compose_inside_1f1b(devices8):
     """Ulysses SP composed with ring CP inside the pipeline (tp=2/sp=1 x cp=2
     x pp=2, dp=1): the all-to-all head scatter and the ring's every-tick
@@ -147,7 +144,7 @@ def test_ulysses_cp_compose_inside_1f1b(devices8):
     assert np.isfinite(float(metrics["loss"]))
 
 
-@_PARTIAL_MANUAL
+@pytest.mark.slow
 def test_bisect_probe_sp_without_fsdp(devices8):
     """Bisection probe: sp kept, fsdp+ckpt removed — this variant deadlocked
     pre-fix, refuting the 'ZeRO-3 + remat on one layer' diagnosis."""
@@ -176,7 +173,6 @@ def test_bisect_probe_sp_without_fsdp(devices8):
          {"default_dp_type": "zero3"}),
     ],
 )
-@_PARTIAL_MANUAL
 def test_composition_matrix(devices8, name, stage, kw):
     """Extended matrix: compile + divergence guard + one executed step for every
     composition the search can emit under 1F1B."""
@@ -186,7 +182,7 @@ def test_composition_matrix(devices8, name, stage, kw):
     assert np.isfinite(float(metrics["loss"]))
 
 
-@_PARTIAL_MANUAL
+@pytest.mark.slow
 def test_gate_matrix_mirrors_pytest(devices8):
     """Every config the external dryrun_multichip gate cycles must be a
     pytest first (round-2 postmortem rule). Runs the gate's own builders."""

@@ -15,12 +15,6 @@ from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_sch
 
 pytestmark = [pytest.mark.parallel, pytest.mark.distributed]
 
-from tests.conftest import requires_partial_manual_shard_map
-
-# jax 0.4.x cannot compile the engines' partial-manual shard_map regions
-# (see tests/conftest.py); probed once per session, auto-re-enables on a
-# capable jax
-_PARTIAL_MANUAL = requires_partial_manual_shard_map()
 
 B = 8
 
@@ -61,7 +55,6 @@ def _traj(cfg, hp, devices, steps=3):
     return out
 
 
-@_PARTIAL_MANUAL
 def test_swin_1f1b_matches_single_stage(cfg, devices8):
     ref_hp = HybridParallelConfig.uniform(8, cfg.num_layers, global_bsz=B)
     ref = _traj(cfg, ref_hp, devices8)
@@ -80,7 +73,6 @@ _EXT = pytest.mark.skipif(
 )
 
 
-@_PARTIAL_MANUAL
 @_EXT
 def test_swin_1f1b_tp2_ckpt_trains(cfg, devices8):
     """pp=2 x tp=2 with remat on the deeper blocks: loss drops while

@@ -14,12 +14,6 @@ from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_sch
 
 pytestmark = [pytest.mark.parallel, pytest.mark.distributed]
 
-from tests.conftest import requires_partial_manual_shard_map
-
-# jax 0.4.x cannot compile the engines' partial-manual shard_map regions
-# (see tests/conftest.py); probed once per session, auto-re-enables on a
-# capable jax
-_PARTIAL_MANUAL = requires_partial_manual_shard_map()
 
 B = 8
 
@@ -62,7 +56,6 @@ def _traj(cfg, hp, devices, steps=3):
     return out
 
 
-@_PARTIAL_MANUAL
 def test_t5_1f1b_matches_single_stage(cfg, devices8):
     """pp=2 (1 enc stage + 1 dec stage) trajectory parity vs pp=1. The pp=1
     reference is padded identically (t5_pad_batch is the engine's contract)."""
@@ -98,7 +91,6 @@ _EXT = pytest.mark.skipif(
 )
 
 
-@_PARTIAL_MANUAL
 @_EXT
 def test_t5_1f1b_tp2_trains(cfg, devices8):
     """pp=2 x tp=2 (megatron-sp default) + ckpt on the decoder stage: loss
