@@ -1,0 +1,159 @@
+"""The reduction from trace events to metrics: on hand-made events whose
+answers can be worked out on paper, on the small trace recorded on the chip
+(benchmarks/fixtures/), and stage 1 on a trace taken here on the CPU."""
+
+import os
+
+import pytest
+
+from benchmarks import trace
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+STEP = ("train_step",)
+
+
+def test_self_times_take_the_children_out_of_a_while():
+    events = [("while.1", 0, 100), ("fusion.a", 10, 30), ("fusion.b", 50, 40), ("copy.c", 120, 5)]
+    got = {label: self_ns for label, _, _, self_ns in trace.self_times(events)}
+    assert got == {"while.1": 30, "fusion.a": 30, "fusion.b": 40, "copy.c": 5}
+
+
+def test_union_merges_overlaps():
+    assert trace.union([(5, 9), (0, 3), (2, 4), (9, 10)]) == [(0, 4), (5, 10)]
+
+
+def handmade():
+    """Four runs of the step, 1000 ns each with 100 ns between; the first and
+    last are dropped as possibly cut. In each: a while (900) around a matmul
+    fusion (400), an all-reduce (200) and a kernel (250), then 50 ns of
+    nothing before the while ends."""
+    ops, modules = [], []
+    for i in range(4):
+        t = 1100 * i
+        modules.append(("jit_train_step(123)", t, 1000))
+        ops += [("while.7:body", t, 900), ("fusion.1:dot_general", t, 400),
+                ("all-reduce.3:psum", t + 400, 200),
+                ("custom-call.9:_flash_attention_kernel", t + 600, 250),
+                ("copy.2:tail", t + 900, 100)]
+    modules.append(("jit_other(5)", 50, 10))
+    host = [("$train.py:1251 compiled_step", 0, 5000), ("$_api.py:3108 try_to_block", 1900, 400)]
+    return {"devices": {0: {"ops": ops, "modules": modules}}, "host": host}
+
+
+def test_reduce_on_handmade_events():
+    r = trace.reduce(handmade(), STEP)
+    assert r["steps"] == 2 and r["devices"] == 1
+    assert r["window_s"] == pytest.approx(2100e-9)
+    # a step: 400 + 200 + 250 + 100 busy; the while's own 50 ns count as idle
+    assert r["busy_s"] == pytest.approx(2 * 950e-9)
+    assert r["collective_s_a_step"] == pytest.approx(200e-9)
+    assert r["collective_exposed_s_a_step"] == pytest.approx(200e-9)  # one op at a time
+    assert trace.ops_matching(r, r"_flash_attention_kernel") == (pytest.approx(250e-9), 1.0)
+    assert r["device_ops"][0] == ["fusion.1:dot_general", pytest.approx(800e-9)]
+    # the gap between the two steps falls under the host's block_until_ready
+    assert r["idle_gaps"][0] == ["$_api.py:3108 try_to_block", pytest.approx(100e-9)]
+
+
+def test_a_collective_under_compute_is_not_exposed():
+    t = handmade()
+    t["devices"][0]["ops"] += [("fusion.5:overlapping", 1100 * i + 500, 150) for i in range(4)]
+    r = trace.reduce(t, STEP)
+    assert r["collective_s_a_step"] == pytest.approx(200e-9)
+    assert r["collective_exposed_s_a_step"] == pytest.approx(100e-9)
+
+
+def test_no_whole_step_reduces_to_nothing():
+    assert trace.reduce({"devices": {0: {"ops": [], "modules": []}}, "host": []}, STEP) is None
+    assert trace.reduce({"devices": {}, "host": []}, STEP) is None
+
+
+def test_events_round_trip_through_the_fixture_format(tmp_path):
+    path = str(tmp_path / "events.json.gz")
+    trace.save_events(handmade(), path, STEP)
+    again = trace.reduce(trace.load_events(path), STEP)
+    direct = trace.reduce(handmade(), STEP)
+    assert again == direct
+
+
+def test_stage_one_reads_a_trace_taken_here(tmp_path):
+    """`load` on a real `.xplane.pb`: a CPU trace has no TPU plane, and its
+    host line is the thread that stopped the trace."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.profiler.start_trace(str(tmp_path))
+    jax.block_until_ready(jax.jit(lambda x: x @ x)(jnp.ones((64, 64))))
+    jax.profiler.stop_trace()
+    path = trace.find_xplane(str(tmp_path))
+    assert path is not None
+    loaded = trace.load(path)
+    assert loaded["devices"] == {}
+    assert trace.reduce(loaded, STEP) is None
+
+
+HLO = '''
+%fused_computation.3 (param_0: bf16[8,16]) -> bf16[8,16] {
+  %param_0 = bf16[8,16]{1,0} parameter(0)
+  %dot.1 = bf16[8,16]{1,0} dot(%param_0, %param_0), metadata={op_name="jit(plain_step)/jvp()/while/body/dot_general" stack_frame_id=3}
+  ROOT %convert.2 = bf16[8,16]{1,0} convert(%dot.1), metadata={op_name="jit(plain_step)/jvp()/while/body/convert_element_type"}
+}
+
+%bitcast_fusion.5.clone (bitcast_input.1: bf16[8,16]) -> bf16[16,8] {
+  %bitcast_input.1 = bf16[8,16]{1,0} parameter(0)
+  ROOT %bitcast.9 = bf16[16,8]{0,1} bitcast(%bitcast_input.1)
+}
+
+ENTRY %main.1 (p: bf16[8,16]) -> bf16[16,8] {
+  %p = bf16[8,16]{1,0} parameter(0)
+  %fusion.412 = bf16[8,16]{1,0} fusion(%p), kind=kOutput, calls=%fused_computation.3, metadata={}
+  %flash_attention.16 = bf16[8,16]{1,0} custom-call(%fusion.412), custom_call_target="tpu_custom_call", metadata={op_name="jit(plain_step)/jvp()/jit(flash_attention)/pallas_call"}
+  ROOT %fusion.387 = bf16[16,8]{0,1} fusion(%flash_attention.16), kind=kLoop, calls=%bitcast_fusion.5.clone
+}
+'''
+
+
+def test_labels_put_the_jax_op_beside_the_instruction():
+    origins = trace.origins_from_hlo(HLO)
+    # a fusion without a name of its own takes its computation's matmul
+    assert trace._label("%fusion.412 = bf16[8,16]{1,0} fusion(...)", origins) == \
+        "fusion.412:jvp_/while/body/dot_general"
+    assert trace._label("%flash_attention.16 = bf16[8,16] custom-call(...)", origins) == \
+        "flash_attention.16:jvp_/jit_flash_attention_/pallas_call"
+    assert trace._label("%fusion.387 = bf16[16,8] fusion(...)", origins) == \
+        "fusion.387:bitcast_fusion.5.clone"
+    assert trace._label("%copy-start.3 = (bf16[4]) copy-start(...)", origins) == "copy-start.3"
+
+
+def test_reduction_of_the_trace_recorded_on_the_chip():
+    """The fixture is device 0's events of four traced steps of qwen7-c1-s2k
+    on a v5e; the expected numbers are what that run itself reported."""
+    import json
+
+    from benchmarks import cells
+
+    fixtures = os.path.join(REPO, "benchmarks", "fixtures")
+    expected = json.load(open(os.path.join(fixtures, "qwen7-c1-s2k.expected.json")))
+    r = trace.reduce(trace.load_events(
+        os.path.join(fixtures, "qwen7-c1-s2k.trace_events.json.gz")), ("plain_step", "train_step"))
+    assert r["steps"] == expected["steps"] == 4
+    assert r["busy_s"] == pytest.approx(expected["busy_s"], rel=1e-9)
+    assert r["window_s"] == pytest.approx(expected["window_s"], rel=1e-9)
+    assert r["step_s"] == pytest.approx(expected["step_s"])
+    assert r["device_ops"][:3] == [[n, pytest.approx(s)] for n, s in expected["device_ops"]]
+    assert r["idle_gaps"][0] == [expected["idle_gaps"][0][0],
+                                 pytest.approx(expected["idle_gaps"][0][1])]
+    assert r["collective_s_a_step"] == 0.0  # one chip
+    # the per-layer readers on the same reduction
+    cell = cells.load_cell(REPO, "qwen7-c1-s2k")
+    run = {"trace": r, "cell": cell, "peak": cells.load_json(REPO, "benchmarks/peaks.json")[
+        "TPU v5 lite"]}
+    read = lambda name: cells.load_module(  # noqa: E731
+        REPO, "benchmarks/layer_metrics/%s.py" % name).read(run)
+    assert read("flash_ms") == pytest.approx(expected["flash_ms"])
+    assert read("flash_roofline") == pytest.approx(expected["flash_roofline"])
+    assert 0 < read("flash_roofline") < 100
+    assert read("device_idle_pct") == pytest.approx(expected["device_idle_pct"])
+    # full recomputation: two forwards a layer, one of each backward kernel
+    calls = {k: c for k, (_, c) in cells.load_module(
+        REPO, "benchmarks/layer_metrics/flash_ms.py").per_kernel(run).items()}
+    assert calls == {"fwd": 4.0, "dkv": 2.0, "dq": 2.0}
