@@ -30,6 +30,13 @@ TRACED_STEPS = 3
 # after stop_trace the host has lost its two-step lead: three iterations
 # refill it, and the rest is margin before the window's first stamp
 SETTLE_AFTER_TRACE = 6
+# --trace 2: the traced tail after the window fills about this long, and holds
+# at least this many steps (the reduction drops the first and the last run of
+# the step, tracing_on_slowdown_pct the first three intervals). No more: the
+# reduction's exposed-collective pass is quadratic in the traced steps, 10 s
+# for 6 steps of the four-chip cell and 22 s for 8
+TAIL_SECONDS = 3.0
+TAIL_MIN_STEPS = 6
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 LAYOUT_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter")
 
@@ -105,7 +112,7 @@ class CompileLog:
         return [str(name) for t, name, _ in self.events if start < t <= end]
 
 
-def out_dir_for(root: str, cell_name: str, seed: int, traced: bool) -> str:
+def out_dir_for(root: str, cell_name: str, seed: int, traced: int) -> str:
     base = os.path.join(root, "chiprun_out", "benchmarks", cell_name)
     n = 0
     while True:
@@ -186,14 +193,55 @@ def per_layer_values(cell: cells.Cell, run: Mapping[str, Any]) -> Dict[str, floa
     return values
 
 
-def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: bool,
+class TracedTail:
+    """`--trace 2`: what happens at the window's closing stamp, inside
+    `on_step`, once the window's numbers are fixed. A telemetry sink goes
+    in, the profiler is started and stopped once and that trace thrown away
+    (so that the cost of its first start falls into no number), and the
+    trainer's own trace control is asked for the next n steps, which the
+    run is extended by."""
+
+    def __init__(self, args, clock: window.WindowClock, trace_dir: str):
+        self.args, self.clock, self.trace_dir = args, clock, trace_dir
+        self.sink = None
+        self.steps = 0
+        self.first_start_s = 0.0
+
+    def __call__(self, it: int) -> int:
+        import jax
+
+        from galvatron_tpu.obs import telemetry
+
+        median = window.estimate(self.clock.window_stamps(), 1.0)["median_step_s"]
+        self.steps = max(TAIL_MIN_STEPS, math.ceil(TAIL_SECONDS / median))
+        self.sink = telemetry.install(telemetry.MemorySink())
+        first, t = self.trace_dir + ".first", time.perf_counter()
+        jax.profiler.start_trace(first)
+        jax.profiler.stop_trace()
+        shutil.rmtree(first, ignore_errors=True)
+        self.first_start_s = time.perf_counter() - t
+        if not self.args.trace_control.request(self.trace_dir, it, it + self.steps - 1):
+            raise RuntimeError("the trainer's trace control is busy at the window's end")
+        self.args.train_iters = it + self.steps
+        return self.steps
+
+    def close(self) -> None:
+        if self.sink is not None:
+            from galvatron_tpu.obs import telemetry
+
+            telemetry.uninstall(self.sink)
+
+
+def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: int,
              peaks: Mapping[str, Any], t0: float, out_dir: str,
              say: Callable[..., None], marks: Optional[Mapping[str, float]] = None,
              chip_start_s: Optional[float] = None) -> Dict[str, Any]:
     """Runs the cell once and returns the result object of the last line.
     `say(**obj)` prints an earlier line; `t0` is the process's start on
     `time.perf_counter`, `marks` the consecutive parts of set-up the caller
-    has timed, `chip_start_s` what `jax.devices()` took beside them."""
+    has timed, `chip_start_s` what `jax.devices()` took beside them.
+    `traced` is `--trace`: 0 the measured window alone, 1 a traced run of its
+    own, 2 the measured window of 0 followed by a traced tail."""
     import jax
 
     from galvatron_tpu.cli import train as T
@@ -205,14 +253,15 @@ def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: bool,
     peak = peaks[devices[0].device_kind]
     traffic, tol = cell.traffic, cell.config["checks"]
     warmup = int(traffic["warmup_steps"])
-    trace_dir = trace_steps = None
-    if traced:
-        trace_dir = os.path.join(out_dir, "xla_trace")
+    trace_dir = os.path.join(out_dir, "xla_trace") if traced else None
+    trace_steps = None
+    if traced == 1:
         trace_steps = (warmup, warmup + TRACED_STEPS - 1)
         warmup = trace_steps[1] + 1 + SETTLE_AFTER_TRACE
     cells.register_family(cell)
     args = initialize_galvatron(
-        mode="train_dist", argv=cells.train_argv(cell, seed, trace_dir, trace_steps))
+        mode="train_dist",
+        argv=cells.train_argv(cell, seed, trace_dir if traced == 1 else None, trace_steps))
 
     # the plain reference is the benchmark's own work, not the program's
     # set-up: it is timed apart and taken out of setup_s
@@ -223,11 +272,14 @@ def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: bool,
 
     clock = window.WindowClock(
         seconds, warmup, end_run=lambda it: setattr(args, "train_iters", it))
+    tail = None
+    if traced == 2:
+        tail = clock.end_run = TracedTail(args, clock, trace_dir)
     # the trainer's per-step observation seam; the step itself is untouched
     args.fault_hooks = types.SimpleNamespace(
         on_step=clock.on_step, wrap_step_fn=None, wrap_data_iter=None)
     compiles = CompileLog()
-    sink = telemetry.MemorySink() if traced else None
+    sink = telemetry.MemorySink() if traced == 1 else None
     before = set(T._STEP_EXECUTABLES)
     jax.monitoring.register_event_duration_secs_listener(compiles)
     if sink is not None:
@@ -237,10 +289,17 @@ def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: bool,
         with contextlib.redirect_stdout(sys.stderr):
             summary = T.train(args)
     finally:
+        t_trained = time.perf_counter()
         if sink is not None:
             telemetry.uninstall(sink)
+        if tail is not None:
+            tail.close()
         jax.monitoring.unregister_event_duration_listener(compiles)
     stamps = clock.window_stamps()
+    window_steps = (clock.warmup, clock.last)
+    if tail is not None:
+        # no sink stood in the window: the readers of telemetry get the tail's
+        sink, window_steps = tail.sink, (clock.last, clock.last + tail.steps)
     parts["to_first_step_s"] = clock.stamps[1] - t_train
     parts["warmup_s"] = stamps[0] - clock.stamps[1]
     setup_s = stamps[0] - t0 - reference_s
@@ -283,9 +342,14 @@ def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: bool,
         "cell": cell, "peak": peak, "summary": summary, "window": est,
         "memory": memory, "device": device, "flops_a_token": flops_a_token,
         "events": sink.events if sink is not None else [],
-        "window_steps": (clock.warmup, clock.last), "trace": None,
+        "window_steps": window_steps, "trace": None,
         "setup_parts_s": parts, "chip_start_s": chip_start_s,
+        # the stamp intervals of the traced tail (--trace 2), else None
+        "tail_intervals_s": None,
     }
+    if tail is not None:
+        after = clock.tail_stamps()
+        run["tail_intervals_s"] = [b - a for a, b in zip(after, after[1:])]
     values = {
         "tokens_per_s_chip": tokens_per_s_chip,
         "mfu": flops.mfu_pct(tokens_per_s_chip, flops_a_token, peak["bf16_flops_per_s"]),
@@ -294,12 +358,15 @@ def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: bool,
     }
     breakdown = None
     if traced:
+        t = time.perf_counter()
         run["trace"] = read_trace(trace_dir, hlo, out_dir)
+        reduce_s = time.perf_counter() - t
         device["busy_s"] = run["trace"]["busy_s"]
         device["window_s"] = run["trace"]["window_s"]
         breakdown = {"device_ops": run["trace"]["device_ops"],
                      "idle_gaps": run["trace"]["idle_gaps"]}
-        values = per_layer_values(cell, run)
+        layers = per_layer_values(cell, run)
+        values = {**values, **layers} if traced == 2 else layers
 
     units = {m["name"]: m["unit"] for g in ("end_to_end", "per_layer")
              for m in cell.manifest[g]}
@@ -314,7 +381,7 @@ def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: bool,
     if breakdown is not None:
         result["breakdown"] = breakdown
     detail = {
-        "workload": cell.name, "seed": seed, "seconds": seconds, "traced": traced,
+        "workload": cell.name, "seed": seed, "seconds": seconds, "traced": bool(traced),
         "checks": checks, "compilations_in_window": in_window,
         "first_loss": losses[0], "expected_first_loss": expected,
         "reference_loss": ref_loss, "last_loss": losses[-1],
@@ -328,11 +395,19 @@ def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: bool,
         "trainer_dispatch_ms": summary.get("dispatch_ms"),
         "memory": memory, "flops_a_token": flops_a_token,
     }
+    if tail is not None:
+        # what a --trace 2 run spends after its window: the profiler's first
+        # start and stop, the traced steps up to the trainer's return (the
+        # trace is stopped and written in there), and reducing the trace
+        detail["tail"] = {
+            "steps": tail.steps, "first_profiler_start_s": tail.first_start_s,
+            "window_end_to_return_s": t_trained - stamps[-1], "reduce_s": reduce_s}
     say(**detail)
     with open(os.path.join(out_dir, "run.json"), "w") as f:
         json.dump({**detail, "intervals_s": est["intervals_s"],
                    "warmup_intervals_s": [b - a for a, b in zip(
                        clock.stamps[:clock.warmup], clock.stamps[1:clock.warmup + 1])],
+                   "tail_intervals_s": run["tail_intervals_s"],
                    "losses": losses, "result": result,
                    "trace": run["trace"]}, f)
     return result

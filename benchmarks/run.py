@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """One run of one benchmark cell on the chip.
 
-    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 A new process a run. It refuses to start (non-zero exit, nothing on stdout)
 unless jax's devices are TPUs whose device_kind is in benchmarks/peaks.json
@@ -36,7 +36,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     opts = ap.parse_args(argv)
 
     sys.path.insert(0, ROOT)
@@ -74,8 +74,9 @@ def main(argv=None) -> int:
         print("benchmarks/run.py: %s" % why, file=sys.stderr)
         return 1
     result = harness.run_cell(
-        cell, seed=opts.seed, seconds=opts.seconds, traced=bool(opts.trace), peaks=peaks,
-        t0=T0, marks=marks, chip_start_s=start.seconds, out_dir=harness.out_dir_for(ROOT, cell.name, opts.seed, bool(opts.trace)),
+        cell, seed=opts.seed, seconds=opts.seconds, traced=opts.trace, peaks=peaks,
+        t0=T0, marks=marks, chip_start_s=start.seconds,
+        out_dir=harness.out_dir_for(ROOT, cell.name, opts.seed, opts.trace),
         say=say)
     say(**result)
     return 0

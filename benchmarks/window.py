@@ -41,7 +41,9 @@ def estimate(stamps: Sequence[float], tokens_a_step: float) -> dict:
 class WindowClock:
     """The `on_step` hook. Stamps every iteration and ends the run, through
     `end_run(it)` (the harness sets `args.train_iters` there), at the first
-    stamp `seconds` past the window's start."""
+    stamp `seconds` past the window's start. `end_run` may return a number
+    of steps that follow the window (the traced tail of `--trace 2`): their
+    stamps are kept apart (`tail_stamps`) and are no part of the window."""
 
     def __init__(self, seconds: float, warmup: int, end_run: Callable[[int], None],
                  clock: Callable[[], float] = time.perf_counter):
@@ -54,17 +56,23 @@ class WindowClock:
         self.clock = clock
         self.stamps: List[float] = []
         self.last: Optional[int] = None  # the iteration whose stamp closes the window
+        self.tail = 0  # steps after the window that `end_run` announced
 
     def on_step(self, it: int) -> None:
-        if it != len(self.stamps) or self.last is not None:
+        if it != len(self.stamps) or (self.last is not None and it >= self.last + self.tail):
             raise RuntimeError("on_step(%d) after %d stamps: the loop replayed, skipped "
                                "or outran an iteration" % (it, len(self.stamps)))
         self.stamps.append(self.clock())
-        if it > self.warmup and self.stamps[it] - self.stamps[self.warmup] >= self.seconds:
+        if self.last is None and it > self.warmup \
+                and self.stamps[it] - self.stamps[self.warmup] >= self.seconds:
             self.last = it
-            self.end_run(it)
+            self.tail = int(self.end_run(it) or 0)
 
     def window_stamps(self) -> List[float]:
         if self.last is None:
             raise RuntimeError("the run ended before the window did")
-        return self.stamps[self.warmup:]
+        return self.stamps[self.warmup:self.last + 1]
+
+    def tail_stamps(self) -> List[float]:
+        """The window's closing stamp and those of the steps after it."""
+        return self.stamps[self.last:] if self.last is not None else []

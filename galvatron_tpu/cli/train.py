@@ -26,7 +26,7 @@ from galvatron_tpu.cli.arguments import (
     model_config_from_args,
 )
 from galvatron_tpu.obs import flops as obs_flops
-from galvatron_tpu.obs import telemetry
+from galvatron_tpu.obs import telemetry, tracing
 from galvatron_tpu.profiler.runtime import (
     RuntimeProfiler,
     compiled_step_memory_mb,
@@ -264,6 +264,15 @@ def _train(args) -> dict:
     )
     # fault-injection seam (tests/runtime/fault_injection.py); None in prod
     hooks = getattr(args, "fault_hooks", None)
+    # the run's one trace control (obs/tracing.py): whoever holds `args` (an
+    # on_step hook, a test, the benchmark) may request a trace of coming
+    # steps through it at any time; --xla_trace is one request made here
+    control = getattr(args, "trace_control", None)
+    if control is None:
+        control = args.trace_control = tracing.TraceControl()
+    if getattr(args, "xla_trace", None):
+        control.request(args.xla_trace,
+                        *_parse_trace_steps(getattr(args, "trace_steps", None)))
     guard = None
     if getattr(args, "anomaly_guard", 0):
         guard = rsl.AnomalyGuard(rsl.AnomalyGuardConfig(
@@ -493,21 +502,22 @@ def _train(args) -> dict:
         if not hasattr(step_fn, "lower"):
             return step_fn(*step_args)
         if _aot["fn"] is None:
-            t0 = time.perf_counter()
-            lowered = step_fn.lower(*step_args)
-            t1 = time.perf_counter()
-            key = _step_exec_key(model.mesh, lowered)
-            compiled = _STEP_EXECUTABLES.get(key)
-            memo_hit = compiled is not None
-            cache_hit = False
-            if memo_hit:
-                _STEP_EXECUTABLES.move_to_end(key)
-            else:
-                compiled, cache_hit = _compile_step(lowered)
-                _STEP_EXECUTABLES[key] = compiled
-                while len(_STEP_EXECUTABLES) > _STEP_EXECUTABLES_MAX:
-                    _STEP_EXECUTABLES.popitem(last=False)
-            t2 = time.perf_counter()
+            with control.span(tracing.COMPILE):
+                t0 = time.perf_counter()
+                lowered = step_fn.lower(*step_args)
+                t1 = time.perf_counter()
+                key = _step_exec_key(model.mesh, lowered)
+                compiled = _STEP_EXECUTABLES.get(key)
+                memo_hit = compiled is not None
+                cache_hit = False
+                if memo_hit:
+                    _STEP_EXECUTABLES.move_to_end(key)
+                else:
+                    compiled, cache_hit = _compile_step(lowered)
+                    _STEP_EXECUTABLES[key] = compiled
+                    while len(_STEP_EXECUTABLES) > _STEP_EXECUTABLES_MAX:
+                        _STEP_EXECUTABLES.popitem(last=False)
+                t2 = time.perf_counter()
             # a memo or persistent-cache hit reports compile_ms ~0 — true:
             # this process did not run XLA again for this program
             prof.record_compile(trace_ms=(t1 - t0) * 1e3,
@@ -684,45 +694,6 @@ def _train(args) -> dict:
     if getattr(args, "emergency_save", 0):
         preempt = rsl.PreemptionHandler().install()
 
-    # ------------------------------------------------------------ XLA trace
-    # opt-in jax.profiler capture (Perfetto/TensorBoard) around a small step
-    # window: started when the window's first step is DISPATCHED, stopped
-    # when its last step has DRAINED (so the captured device timeline
-    # contains the windowed steps' execution, not just their dispatch).
-    # Backends that cannot trace skip gracefully and say so.
-    trace_dir = getattr(args, "xla_trace", None)
-    trace_lo, trace_hi = _parse_trace_steps(getattr(args, "trace_steps", None))
-    trace_state = {"active": False, "done": trace_dir is None}
-
-    def maybe_start_trace(iteration):
-        if trace_state["done"] or trace_state["active"] or iteration < trace_lo:
-            return
-        try:
-            jax.profiler.start_trace(trace_dir)
-            trace_state["active"] = True
-            telemetry.emit("trace", action="start", dir=trace_dir,
-                           first_step=trace_lo, last_step=trace_hi)
-        except Exception as e:
-            trace_state["done"] = True
-            telemetry.emit("trace", action="error", error=str(e))
-            if jax.process_index() == 0:
-                print("xla trace skipped (%s): %s" % (type(e).__name__, e))
-
-    def maybe_stop_trace(iteration=None):
-        if not trace_state["active"]:
-            return
-        if iteration is not None and iteration < trace_hi:
-            return
-        trace_state["active"] = False
-        trace_state["done"] = True
-        try:
-            jax.profiler.stop_trace()
-            telemetry.emit("trace", action="stop", dir=trace_dir)
-        except Exception as e:
-            telemetry.emit("trace", action="error", error=str(e))
-            if jax.process_index() == 0:
-                print("xla trace stop failed (%s): %s" % (type(e).__name__, e))
-
     # every save — periodic, final, rollback re-save AND the emergency save a
     # preemption triggers — carries provenance, so the NEXT resume can
     # re-plan for whatever hardware survives
@@ -752,18 +723,20 @@ def _train(args) -> dict:
     losses = []
     loss_iters = []  # iteration of each accepted loss (rollback truncation)
     valid_losses = []  # (iteration, mean valid loss)
-    inflight = deque()  # (iteration, metrics) dispatched but not yet drained
+    # (iteration, metrics, dispatch_ms, data_wait_ms) dispatched, not yet drained
+    inflight = deque()
     interrupted = None
     last_save = None
     it = start_iter
 
-    def emit_step_event(d_it, metrics, loss, disp_ms):
+    def emit_step_event(d_it, metrics, loss, disp_ms, wait_ms):
         """One schema-valid ``step`` event per drained iteration. Costs a
         device memory-stats read plus one enqueue — only paid when a
         telemetry sink is installed (the ≤2%% steps/s overhead budget).
-        `disp_ms` travels with the step through the in-flight window —
-        ``prof.dispatch_ms[-1]`` would belong to the latest DISPATCHED
-        iteration, several ahead of the one draining here."""
+        `disp_ms` and `wait_ms` (the gt/next_batch span) travel with the
+        step through the in-flight window — ``prof.dispatch_ms[-1]`` would
+        belong to the latest DISPATCHED iteration, several ahead of the one
+        draining here."""
         if telemetry.active_sink() is None:
             return
         iter_ms = prof.all_times_ms[-1] if prof.all_times_ms else None
@@ -780,6 +753,7 @@ def _train(args) -> dict:
             loss=loss if np.isfinite(loss) else None,
             iter_ms=iter_ms,
             dispatch_ms=disp_ms,
+            data_wait_ms=wait_ms,
             host_blocked_ms=blocked,
             hbm_in_use_mb=mem["bytes_in_use"] / 2**20 or None,
             hbm_peak_mb=mem["peak_bytes_in_use"] / 2**20 or None,
@@ -793,8 +767,9 @@ def _train(args) -> dict:
         host-side bookkeeping the synchronous loop did inline (iteration
         log, anomaly accounting, telemetry). Returns (iteration,
         rollback_needed)."""
-        d_it, metrics, disp_ms = inflight.popleft()
-        prof.end(d_it, n_samples=hp.global_bsz, outputs=metrics["loss"])
+        d_it, metrics, disp_ms, wait_ms = inflight.popleft()
+        with control.span(tracing.DRAIN):  # the blocking read
+            prof.end(d_it, n_samples=hp.global_bsz, outputs=metrics["loss"])
         if wd is not None:
             # a drain is the loop's liveness signal AND the deadline's
             # training data (the learned budget tracks the steady step time)
@@ -807,8 +782,8 @@ def _train(args) -> dict:
         if args.profile or d_it % max(args.log_interval, 1) == 0:
             prof.log_iteration(d_it, metrics)
         loss = float(metrics["loss"])
-        emit_step_event(d_it, metrics, loss, disp_ms)
-        maybe_stop_trace(d_it)
+        emit_step_event(d_it, metrics, loss, disp_ms, wait_ms)
+        control.after_drain(d_it)
         if sdc_ladder is not None and isinstance(metrics, dict) \
                 and metrics.get("sdc_mismatch") is not None \
                 and bool(metrics["sdc_mismatch"]):
@@ -1181,7 +1156,8 @@ def _train(args) -> dict:
         while True:
             if interrupted is None and it < args.train_iters:
                 if hooks is not None and hooks.on_step:
-                    hooks.on_step(it)
+                    with control.span(tracing.ON_STEP):
+                        hooks.on_step(it)
                 if preempt is not None and preempt.triggered:
                     interrupted = preempt.signal_name
                     telemetry.emit("preemption", signal=interrupted, iter=it)
@@ -1238,19 +1214,23 @@ def _train(args) -> dict:
                 break
             if wd is not None:
                 wd.arm(it, "fetch", inflight=len(inflight))
-            batch = next_batch()
-            maybe_start_trace(it)
+            # a requested trace starts here, before the fetch, so that the
+            # first traced step's gt/next_batch is in it
+            control.before_dispatch(it)
+            with control.span(tracing.NEXT_BATCH) as fetch:
+                batch = next_batch()
             prof.start(it)
-            if guard is not None:
-                # NB deferred metrics: the spike cap is computed from losses
-                # drained so far, i.e. it lags the dispatched step by at most
-                # `inflight_steps` (NaN/Inf gating is in-jit and exact)
-                params, opt_state, metrics = compiled_step(
-                    params, opt_state, batch, np.float32(guard.spike_cap()))
-            else:
-                params, opt_state, metrics = compiled_step(params, opt_state, batch)
+            with control.span(tracing.DISPATCH, step_num=it):
+                if guard is not None:
+                    # NB deferred metrics: the spike cap is computed from losses
+                    # drained so far, i.e. it lags the dispatched step by at most
+                    # `inflight_steps` (NaN/Inf gating is in-jit and exact)
+                    params, opt_state, metrics = compiled_step(
+                        params, opt_state, batch, np.float32(guard.spike_cap()))
+                else:
+                    params, opt_state, metrics = compiled_step(params, opt_state, batch)
             disp_ms = prof.dispatched(it)
-            inflight.append((it, metrics, disp_ms))
+            inflight.append((it, metrics, disp_ms, fetch.ms))
             if wd is not None:
                 wd.arm(it, "inflight", inflight=len(inflight))
             it += 1
@@ -1261,7 +1241,8 @@ def _train(args) -> dict:
                     continue
                 if wd is not None:
                     wd.disarm()  # eval passes are legitimately slow
-                vloss = evaluate(params, "valid")
+                with control.span(tracing.EVAL):
+                    vloss = evaluate(params, "valid")
                 valid_losses.append((it, vloss))
                 telemetry.emit("eval", iter=it, split="valid", loss=vloss)
                 if jax.process_index() == 0:
@@ -1271,7 +1252,8 @@ def _train(args) -> dict:
                     continue
                 if wd is not None:
                     wd.disarm()  # checkpoint I/O has its own retry containment
-                save_now(it)
+                with control.span(tracing.SAVE):
+                    save_now(it)
                 last_save = it
         if interrupted is not None and args.save and last_save != it:
             # preemption: commit the state reached so far at the step boundary
@@ -1288,7 +1270,7 @@ def _train(args) -> dict:
         prof.loop_fence((params, opt_state))
     finally:
         close_stream()
-        maybe_stop_trace()
+        control.close()
         prof.close()
         if preempt is not None:
             preempt.uninstall()

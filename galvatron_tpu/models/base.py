@@ -32,6 +32,7 @@ from galvatron_tpu.config.strategy import (
     LayerStrategy,
     layer_runs,
 )
+from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding, core_attention
 from galvatron_tpu.ops.norms import layer_norm, rms_norm
 from galvatron_tpu.ops.rope import apply_rotary
@@ -656,15 +657,9 @@ def run_layers(
             x = fwd(lp, x, positions)
         return x
 
-    if use_hp:
-        runs = layer_runs(hp)
-    else:
-        # no strategy info: the whole stack is one homogeneous run
-        runs = [LayerRun(start=0, stop=len(layers), strategy=LayerStrategy())]
-    for run in runs:
+    def one_run(x, run):
         if not scan or run.length < 2:
-            x = unrolled(x, run.layer_indices)
-            continue
+            return unrolled(x, run.layer_indices)
         axes = layer_axes(hp, run.start) if use_hp else None
         stacked = stack_layer_run([layers[i] for i in run.layer_indices])
         if use_hp:
@@ -685,7 +680,7 @@ def run_layers(
             x, kv_stacked = jax.lax.scan(step_kv, x, stacked)
             for j in range(run.length):
                 kvs.append(jax.tree.map(lambda t, _j=j: t[_j], kv_stacked))
-            continue
+            return x
         body = _layer_fwd_fn(cfg, hp if use_hp else None, mesh, axes,
                              attn_bias, run.strategy if use_hp else None)
         if use_hp:
@@ -703,6 +698,17 @@ def run_layers(
             return _body(lp, carry, positions), None
 
         x, _ = jax.lax.scan(step, x, stacked)
+        return x
+
+    if use_hp:
+        runs = layer_runs(hp)
+    else:
+        # no strategy info: the whole stack is one homogeneous run
+        runs = [LayerRun(start=0, stop=len(layers), strategy=LayerStrategy())]
+    for k, run in enumerate(runs):
+        # one scope a run, scanned or unrolled; k is the `layer_run` event's
+        with jax.named_scope(tracing.layers_scope(k)):
+            x = one_run(x, run)
     if collect_kv:
         return x, kvs
     return x
@@ -727,22 +733,26 @@ def model_forward(
     in parallel/pipeline.py)."""
     use_hp = hp is not None and mesh is not None
     vax = vocab_axes(hp) if use_hp else None
-    if cfg.input_type == "patches":
-        x = embed_patches(params["embed"], tokens, cfg)
-    else:
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
-        x = embed_tokens(params["embed"], tokens, positions, cfg, mesh, vax,
-                         token_type_ids=token_type_ids)
+    if positions is None and cfg.input_type != "patches":
+        positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+    with jax.named_scope(tracing.EMBED):
+        if cfg.input_type == "patches":
+            x = embed_patches(params["embed"], tokens, cfg)
+        else:
+            x = embed_tokens(params["embed"], tokens, positions, cfg, mesh, vax,
+                             token_type_ids=token_type_ids)
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
     bias = padding_attn_bias(attn_mask) if attn_mask is not None else None
     x = run_layers(params, x, positions, cfg, hp, mesh, attn_bias=bias)
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
-    logits = model_head(params, x, cfg)
-    if use_hp and cfg.head_type in ("lm", "mlm"):
-        logits = S.constrain(logits, mesh, S.logits_spec(vax))
+    # the head is the first half of gt.head_loss; the loss functions below
+    # put their cross entropy under the same name
+    with jax.named_scope(tracing.HEAD_LOSS):
+        logits = model_head(params, x, cfg)
+        if use_hp and cfg.head_type in ("lm", "mlm"):
+            logits = S.constrain(logits, mesh, S.logits_spec(vax))
     return logits
 
 
@@ -753,7 +763,8 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None):
         params, batch["tokens"], batch["positions"], cfg, hp, mesh,
         token_type_ids=batch.get("token_type_ids"), attn_mask=batch.get("attn_mask"),
     )
-    return vocab_parallel_cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
+    with jax.named_scope(tracing.HEAD_LOSS):
+        return vocab_parallel_cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
 
 
 def softmax_nll(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -769,7 +780,8 @@ def classification_loss_fn(params, batch, cfg, hp=None, mesh=None):
     inputs = batch.get("pixels", batch.get("tokens"))
     logits = model_forward(params, inputs, batch.get("positions"), cfg, hp, mesh,
                            attn_mask=batch.get("attn_mask"))
-    return softmax_nll(logits, batch["labels"])
+    with jax.named_scope(tracing.HEAD_LOSS):
+        return softmax_nll(logits, batch["labels"])
 
 
 # ============================================================== param specs

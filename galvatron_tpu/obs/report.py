@@ -288,6 +288,8 @@ def analyze(
             "median_dispatch_ms": _median([e.get("dispatch_ms") for e in steps]),
             "median_host_blocked_ms": _median(
                 [e.get("host_blocked_ms") for e in steps]),
+            "median_data_wait_ms": _median(
+                [e.get("data_wait_ms") for e in steps]),
         },
         "steady": steady,
         "compile": {k: v for k, v in compile_ev.items()

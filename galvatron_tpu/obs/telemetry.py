@@ -74,7 +74,9 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
     # loop; iter_ms is dispatch->drain latency, which overlaps across steps)
     "step": (
         ("iter",),
-        ("loss", "iter_ms", "dispatch_ms", "host_blocked_ms",
+        # data_wait_ms: the loop's wait in next_batch() for this step
+        # (obs/tracing.py gt/next_batch span)
+        ("loss", "iter_ms", "dispatch_ms", "data_wait_ms", "host_blocked_ms",
          "hbm_in_use_mb", "hbm_peak_mb", "mfu", "model_flops_per_s",
          "grad_norm"),
     ),
@@ -213,7 +215,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
          "swap_cost_ms", "swapped", "from_strategy", "to_strategy",
          "step_ms_before", "step_ms_after", "realized_saving_ms"),
     ),
-    # jax.profiler start/stop_trace bracketing (--xla_trace)
+    # jax.profiler start/stop_trace bracketing (obs/tracing.TraceControl)
     "trace": (("action",), ("dir", "first_step", "last_step", "error")),
     "log": (("message",), ()),
     "run_end": ((), ("summary",)),

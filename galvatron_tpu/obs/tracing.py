@@ -1,0 +1,170 @@
+"""What a profiler trace of a training run is named by: the scopes inside the
+compiled step, the host loop's spans, and the one control that starts and
+stops `jax.profiler` in a running process.
+
+**Scopes** (`jax.named_scope`, device side). Trace-time metadata: a scope
+changes the `op_name` of the HLO instructions traced under it and nothing
+the chip executes. The transforms wrap the name, so the label alone tells a
+scope's forward (`jvp(gt.layers.r0)`), its backward
+(`transpose(jvp(gt.layers.r0))`) and its recomputation
+(`.../checkpoint/rematted_computation/...`) apart. The names are constants
+defined here and nowhere else; `benchmarks/layer_metrics/` reads them by
+regex from the trace.
+
+**Spans** (`TraceControl.span`, host side). While a trace runs a span is a
+`jax.profiler.TraceAnnotation` on the host plane of the same `.xplane.pb` as
+the device ops: one clock, so a gap on the device can be laid against the
+phase the host was in. While a telemetry sink is installed a span is timed
+(`.ms`), which is how `data_wait_ms` reaches the `step` event. With neither,
+`span()` returns the shared do-nothing `OFF` after one attribute read.
+
+**TraceControl**. One object a training run (`args.trace_control`, made by
+`cli/train._train` if absent). Any host code of the process that holds the
+run may `request()` a trace of some steps, at any time and more than once,
+one trace at a time; the loop tells the control when a step is about to be
+dispatched and when one has drained, and the profiler starts when the first
+requested step is dispatched and stops when the last has drained.
+"""
+
+from __future__ import annotations
+
+import time
+from typing import Optional, Tuple
+
+import jax
+
+from galvatron_tpu.obs import telemetry
+
+# ------------------------------------------------------------------ scopes
+EMBED = "gt.embed"  # embed_tokens / embed_patches: gather, positions
+LAYERS = "gt.layers.r%d"  # run k of config/strategy.layer_runs(hp)
+HEAD_LOSS = "gt.head_loss"  # final norm, logits, cross entropy
+OPTIMIZER = "gt.optimizer"  # tx.update, apply_updates, global_norm
+GUARD = "gt.guard"  # the anomaly guard's and the SDC vote's keep-old selects
+GRAD_ACCUM = "gt.grad_accum"  # the microbatch loop's weighting and adds
+
+
+def layers_scope(run_index: int) -> str:
+    """Scope of the k-th layer run: the same k as the `layer_run` telemetry
+    event and `obs/attribution.predict_layer_runs`."""
+    return LAYERS % run_index
+
+
+# ------------------------------------------------------------------- spans
+NEXT_BATCH = "gt/next_batch"
+DISPATCH = "gt/dispatch"  # a StepTraceAnnotation: carries step_num
+DRAIN = "gt/drain"
+ON_STEP = "gt/on_step"
+EVAL = "gt/eval"
+SAVE = "gt/save"
+COMPILE = "gt/compile"
+
+
+class _Off:
+    """The span that does nothing; `ms` is None."""
+
+    ms = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc_info):
+        return False
+
+
+OFF = _Off()
+
+
+class _Span:
+    """A timed span, and an annotation on the profiler's host plane while a
+    trace runs."""
+
+    def __init__(self, name: str, annotate: bool, step_num: Optional[int]):
+        self.ms: Optional[float] = None
+        self._annotation = None
+        if annotate:
+            self._annotation = (
+                jax.profiler.TraceAnnotation(name) if step_num is None
+                else jax.profiler.StepTraceAnnotation(name, step_num=step_num))
+
+    def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc_info):
+        self.ms = (time.perf_counter() - self._t0) * 1e3
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc_info)
+        return False
+
+
+class TraceControl:
+    """Starts and stops `jax.profiler` around requested steps of a running
+    training loop."""
+
+    def __init__(self):
+        self._pending: Optional[Tuple[str, int, int]] = None
+        self._running: Optional[Tuple[str, int, int]] = None
+        # whether span() does anything; refreshed once an iteration
+        self.spans_on = False
+
+    # ------------------------------------------------------------- callers
+    def request(self, directory: str, first_step: int, last_step: int) -> bool:
+        """Ask for a trace of steps `first_step`..`last_step` (inclusive)
+        into `directory`. Callable from `on_step` or any host code in the
+        process, at any time. False, and nothing changes, while another
+        request is pending or running: one trace at a time. A request for
+        steps already dispatched starts at the next dispatch."""
+        if self._pending is not None or self._running is not None:
+            return False
+        if last_step < first_step:
+            raise ValueError("trace request for steps %d:%d" % (first_step, last_step))
+        self._pending = (str(directory), int(first_step), int(last_step))
+        return True
+
+    def span(self, name: str, step_num: Optional[int] = None):
+        """A context around one phase of the host loop (module docstring)."""
+        if not self.spans_on:
+            return OFF
+        return _Span(name, self._running is not None, step_num)
+
+    # ------------------------------------------------------------ the loop
+    def before_dispatch(self, iteration: int) -> None:
+        """The loop is about to fetch and dispatch step `iteration`."""
+        if self._pending is not None and iteration >= self._pending[1]:
+            request, self._pending = self._pending, None
+            directory, first, last = request
+            try:
+                jax.profiler.start_trace(directory)
+            except Exception as e:  # a backend that cannot trace carries on
+                telemetry.emit("trace", action="error", error=str(e))
+                telemetry.runtime_log("xla trace skipped (%s): %s" % (type(e).__name__, e))
+            else:
+                self._running = request
+                telemetry.emit("trace", action="start", dir=directory,
+                               first_step=first, last_step=last)
+        self.spans_on = self._running is not None or telemetry.active_sink() is not None
+
+    def after_drain(self, iteration: int) -> None:
+        """Step `iteration` has drained: its device work is in the trace."""
+        if self._running is not None and iteration >= self._running[2]:
+            self._stop()
+
+    def close(self) -> None:
+        """The run ends: a running trace stops, a pending request is dropped."""
+        self._pending = None
+        self._stop()
+        self.spans_on = False
+
+    def _stop(self) -> None:
+        if self._running is None:
+            return
+        directory, self._running = self._running[0], None
+        try:
+            jax.profiler.stop_trace()
+            telemetry.emit("trace", action="stop", dir=directory)
+        except Exception as e:
+            telemetry.emit("trace", action="error", error=str(e))
+            telemetry.runtime_log("xla trace stop failed (%s): %s" % (type(e).__name__, e))

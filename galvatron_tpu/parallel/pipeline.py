@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
+from galvatron_tpu.obs import tracing
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import PP_AXIS, layer_axes, vocab_axes
 
@@ -227,15 +228,16 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
 
     def loss_fn(params, batch):
         num_mb = hp.chunks
-        if cfg.input_type == "patches":
-            inputs = batch["pixels"]
-            x = M.embed_patches(params["embed"], inputs, cfg)
-            positions = jnp.zeros(x.shape[:2], jnp.int32)
-        else:
-            inputs = batch["tokens"]
-            positions = batch["positions"]
-            x = M.embed_tokens(params["embed"], inputs, positions, cfg, mesh, vax,
-                               token_type_ids=batch.get("token_type_ids"))
+        with jax.named_scope(tracing.EMBED):
+            if cfg.input_type == "patches":
+                inputs = batch["pixels"]
+                x = M.embed_patches(params["embed"], inputs, cfg)
+                positions = jnp.zeros(x.shape[:2], jnp.int32)
+            else:
+                inputs = batch["tokens"]
+                positions = batch["positions"]
+                x = M.embed_tokens(params["embed"], inputs, positions, cfg, mesh, vax,
+                                   token_type_ids=batch.get("token_type_ids"))
         B = x.shape[0]
         mb = B // num_mb
 
@@ -268,10 +270,12 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
                               attn_bias_mb=bias_mb)
         h = outs.reshape((B,) + x.shape[1:])
         h = S.constrain(h, mesh, S.act_spec(vax))
-        logits = M.model_head(params, h, cfg)
-        if cfg.head_type == "classification":
-            return M.softmax_nll(logits, batch["labels"])
-        logits = S.constrain(logits, mesh, S.logits_spec(vax))
-        return M.vocab_parallel_cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
+        with jax.named_scope(tracing.HEAD_LOSS):
+            logits = M.model_head(params, h, cfg)
+            if cfg.head_type == "classification":
+                return M.softmax_nll(logits, batch["labels"])
+            logits = S.constrain(logits, mesh, S.logits_spec(vax))
+            return M.vocab_parallel_cross_entropy(
+                logits, batch["labels"], batch.get("loss_mask"))
 
     return loss_fn

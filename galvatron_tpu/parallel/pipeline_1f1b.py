@@ -58,6 +58,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
+from galvatron_tpu.obs import tracing
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import PP_AXIS, layer_axes, vocab_axes
 
@@ -314,6 +315,7 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
     mask_not_branch = use_masked_path(mesh, has_cp)
 
     # ------------------------------------------------------- vocab fwd pieces
+    @jax.named_scope(tracing.EMBED)
     def embed_fwd(vparams, inputs, positions, token_types):
         """Vocab-parallel embedding on the within-stage gathered tables (see
         the vparams gather in loss_and_grad): the one-hot einsum partitions
@@ -343,6 +345,7 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
             x = M._norm(x, emb["norm"], cfg)
         return S.constrain(x, mesh, mb_spec)
 
+    @jax.named_scope(tracing.HEAD_LOSS)
     def head_loss(vparams, y, labels, loss_mask, weight):
         h = S.constrain(y, mesh, mb_spec)
         logits = M.model_head(vparams, h, cfg)

@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
 from galvatron_tpu.models import base as M
+from galvatron_tpu.obs import tracing
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import build_mesh, layer_axes, vocab_axes
 from galvatron_tpu.runtime.optimizer import opt_state_specs
@@ -298,9 +299,10 @@ class HybridParallelModel:
                 )
             elif chunks == 1:
                 loss, grads = jax.value_and_grad(mb_loss)(params, batch)
-                grads = jax.tree.map(
-                    lambda g, s: jax.lax.with_sharding_constraint(g, s), grads, accum_shardings
-                )
+                with jax.named_scope(tracing.GRAD_ACCUM):
+                    grads = jax.tree.map(
+                        lambda g, s: jax.lax.with_sharding_constraint(g, s), grads, accum_shardings
+                    )
             else:
                 # microbatch loop: python-unrolled so XLA can overlap each
                 # microbatch's reduce-scatter with the next one's compute
@@ -325,16 +327,18 @@ class HybridParallelModel:
                     mb = jax.tree.map(lambda x: x[c], mbs)
                     l, g = jax.value_and_grad(mb_loss)(params, mb)
                     w = weights[c]
-                    g = jax.tree.map(
-                        lambda gi, s: jax.lax.with_sharding_constraint(gi * w, s),
-                        g,
-                        accum_shardings,
-                    )
-                    grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
+                    with jax.named_scope(tracing.GRAD_ACCUM):
+                        g = jax.tree.map(
+                            lambda gi, s: jax.lax.with_sharding_constraint(gi * w, s),
+                            g,
+                            accum_shardings,
+                        )
+                        grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
                     loss = loss + l * w
-            updates, new_opt_state = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            grad_norm = optax.global_norm(grads)
+            with jax.named_scope(tracing.OPTIMIZER):
+                updates, new_opt_state = tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
+                grad_norm = optax.global_norm(grads)
             metrics = {"loss": loss, "grad_norm": grad_norm}
             if guard_anomalies:
                 bad = jnp.logical_or(
@@ -342,10 +346,11 @@ class HybridParallelModel:
                     loss > spike_cap,
                 )
                 keep = lambda new, old: jnp.where(bad, old, new)  # noqa: E731
-                new_params = jax.tree.map(keep, new_params, params)
-                # the skipped step also must not advance the optimizer (adam
-                # moments AND the schedule counter stay put)
-                new_opt_state = jax.tree.map(keep, new_opt_state, opt_state)
+                with jax.named_scope(tracing.GUARD):
+                    new_params = jax.tree.map(keep, new_params, params)
+                    # the skipped step also must not advance the optimizer (adam
+                    # moments AND the schedule counter stay put)
+                    new_opt_state = jax.tree.map(keep, new_opt_state, opt_state)
                 metrics["anomalous"] = bad
             if vote_fn is not None:
                 # per-replica digests of the INPUT params: the dp redundancy
@@ -357,8 +362,9 @@ class HybridParallelModel:
                 votes = vote_fn(params)
                 mismatch = jnp.any(votes != jnp.ravel(votes)[0])
                 keep_sdc = lambda new, old: jnp.where(mismatch, old, new)  # noqa: E731
-                new_params = jax.tree.map(keep_sdc, new_params, params)
-                new_opt_state = jax.tree.map(keep_sdc, new_opt_state, opt_state)
+                with jax.named_scope(tracing.GUARD):
+                    new_params = jax.tree.map(keep_sdc, new_params, params)
+                    new_opt_state = jax.tree.map(keep_sdc, new_opt_state, opt_state)
                 metrics["sdc_votes"] = votes
                 metrics["sdc_mismatch"] = mismatch
             if sdc_check != "off":

@@ -1,0 +1,257 @@
+"""obs/tracing.py: the scopes in the compiled step, the host loop's spans, and
+the one trace control a running training process is traced through."""
+
+import glob
+import os
+import re
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding
+
+from galvatron_tpu import HybridParallelConfig, LayerStrategy
+from galvatron_tpu.cli.arguments import initialize_galvatron
+from galvatron_tpu.cli.train import train
+from galvatron_tpu.models.gpt import gpt_config
+from galvatron_tpu.models.llama import llama_config
+from galvatron_tpu.obs import telemetry, tracing
+from galvatron_tpu.runtime import construct_hybrid_parallel_model, get_optimizer_and_scheduler
+from galvatron_tpu.runtime.optimizer import OptimizerArgs
+
+ITERS = 12
+
+
+def tiny_args(*extra):
+    return initialize_galvatron(mode="train_dist", argv=[
+        "--model_type", "llama", "--set_model_config_manually", "1",
+        "--hidden_size", "64", "--num_attention_heads", "4", "--num_layers", "2",
+        "--vocab_size", "128", "--seq_length", "32", "--mixed_precision", "fp32",
+        "--global_train_batch_size", "4", "--train_iters", str(ITERS),
+        "--world_size", "1", *extra])
+
+
+def hooked(args, on_step):
+    args.fault_hooks = types.SimpleNamespace(
+        on_step=on_step, wrap_step_fn=None, wrap_data_iter=None)
+    return args
+
+
+def run_with_sink(args):
+    sink = telemetry.install(telemetry.MemorySink())
+    try:
+        summary = train(args)
+    finally:
+        telemetry.uninstall(sink)
+    return summary, sink.events
+
+
+@pytest.fixture
+def profiler_log(monkeypatch):
+    """`jax.profiler.start_trace` / `stop_trace` replaced by a log."""
+    log = []
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: log.append(("start", d)))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: log.append(("stop",)))
+    return log
+
+
+# ----------------------------------------------------------------- control
+@pytest.fixture(scope="module")
+def requested_run(devices8, tmp_path_factory):
+    """One tiny run started WITHOUT --xla_trace, traced twice through its
+    control from `on_step` (the real profiler, on the CPU)."""
+    tmp = tmp_path_factory.mktemp("requested")
+    args = tiny_args()
+    answers = {}
+
+    def on_step(it):
+        if it == 3:
+            answers["first"] = args.trace_control.request(str(tmp / "a"), 3, 4)
+            answers["busy"] = args.trace_control.request(str(tmp / "x"), 3, 4)
+        if it == 8:  # the first has ended: steps 3 and 4 drained long ago
+            answers["second"] = args.trace_control.request(str(tmp / "b"), 8, 9)
+
+    summary, events = run_with_sink(hooked(args, on_step))
+    return tmp, answers, summary, events
+
+
+def test_a_request_from_on_step_traces_those_steps_and_a_second_follows(requested_run):
+    tmp, answers, summary, events = requested_run
+    assert answers == {"first": True, "busy": False, "second": True}
+    assert len(summary["losses"]) == ITERS
+    trace_events = [(e["action"], e.get("first_step"), e.get("last_step"), e.get("dir"))
+                    for e in events if e["type"] == "trace"]
+    assert trace_events == [
+        ("start", 3, 4, str(tmp / "a")), ("stop", None, None, str(tmp / "a")),
+        ("start", 8, 9, str(tmp / "b")), ("stop", None, None, str(tmp / "b"))]
+    # the trace stops when its last step has drained: right after step 4's
+    # (and step 9's) `step` event
+    kinds = [(e["type"], e.get("iter", e.get("action"))) for e in events
+             if e["type"] in ("trace", "step")]
+    assert kinds[kinds.index(("trace", "stop")) - 1] == ("step", 4)
+    for name in ("a", "b"):
+        assert glob.glob(str(tmp / name / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    assert not os.path.exists(tmp / "x")
+
+
+def test_the_hosts_spans_are_in_the_trace_with_the_step_number(requested_run):
+    from jax.profiler import ProfileData
+
+    tmp, _, _, _ = requested_run
+    path = glob.glob(str(tmp / "a" / "plugins" / "profile" / "*" / "*.xplane.pb"))[0]
+    spans, step_nums = set(), set()
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("gt/"):
+                    spans.add(e.name)
+                    if e.name == tracing.DISPATCH:
+                        step_nums.add(dict(e.stats).get("step_num"))
+    assert {tracing.NEXT_BATCH, tracing.DISPATCH, tracing.DRAIN, tracing.ON_STEP} <= spans
+    assert {3, 4} <= step_nums  # the traced steps' dispatches, by number
+
+
+def test_data_wait_ms_is_in_the_schema_and_in_a_run_with_a_sink(requested_run):
+    _, _, _, events = requested_run
+    assert "data_wait_ms" in telemetry.EVENT_SCHEMAS["step"][1]
+    steps = [e for e in events if e["type"] == "step"]
+    assert [e["iter"] for e in steps] == list(range(ITERS))
+    assert all(e["data_wait_ms"] >= 0 for e in steps)
+    assert all(e["dispatch_ms"] > 0 for e in steps)
+
+
+def test_nothing_requested_never_starts_the_profiler(devices8, profiler_log):
+    args = tiny_args()
+    summary = train(args)
+    assert len(summary["losses"]) == ITERS and profiler_log == []
+    assert isinstance(args.trace_control, tracing.TraceControl)
+    assert args.trace_control.span("gt/anything") is tracing.OFF
+
+
+def test_xla_trace_and_trace_steps_still_bracket_k_to_n(devices8, profiler_log, tmp_path):
+    args = hooked(tiny_args("--xla_trace", str(tmp_path), "--trace_steps", "5:6"),
+                  lambda it: profiler_log.append(("on_step", it)))
+    _, events = run_with_sink(args)
+    # started when step 5 is dispatched, stopped once step 6 has drained (two
+    # steps stay in flight, so that is during iteration 8), and only once
+    assert profiler_log.index(("start", str(tmp_path))) == profiler_log.index(("on_step", 5)) + 1
+    assert profiler_log.index(("on_step", 8)) < profiler_log.index(("stop",)) \
+        < profiler_log.index(("on_step", 9))
+    assert sum(1 for e in profiler_log if e[0] in ("start", "stop")) == 2
+    order = [(e["type"], e.get("iter", e.get("action"))) for e in events
+             if e["type"] in ("trace", "step")]
+    assert order[order.index(("trace", "stop")) - 1] == ("step", 6)
+    assert order.index(("trace", "start")) == order.index(("step", 2)) + 1
+
+
+def test_a_window_the_run_never_reaches_is_dropped_and_one_it_ends_in_is_closed(
+        devices8, profiler_log, tmp_path):
+    train(tiny_args("--xla_trace", str(tmp_path), "--trace_steps", "50:60"))
+    assert profiler_log == []
+    train(tiny_args("--xla_trace", str(tmp_path), "--trace_steps", "10:60"))
+    assert profiler_log == [("start", str(tmp_path)), ("stop",)]
+
+
+def test_a_backend_that_cannot_trace_says_so_and_carries_on(devices8, monkeypatch, tmp_path):
+    def refuse(directory, **kw):
+        raise RuntimeError("no profiler here")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: pytest.fail("nothing to stop"))
+    summary, events = run_with_sink(tiny_args("--xla_trace", str(tmp_path), "--trace_steps", "2:3"))
+    assert len(summary["losses"]) == ITERS
+    assert [(e["action"], e.get("error")) for e in events if e["type"] == "trace"] == [
+        ("error", "no profiler here")]
+
+
+def test_control_alone(profiler_log):
+    c = tracing.TraceControl()
+    assert c.span("x") is tracing.OFF and tracing.OFF.ms is None
+    with pytest.raises(ValueError):
+        c.request("/d", 5, 4)
+    assert c.request("/d", 5, 6) and not c.request("/e", 9, 9)
+    c.before_dispatch(4)
+    assert profiler_log == [] and c.span("x") is tracing.OFF
+    c.before_dispatch(7)  # steps 5 and 6 are gone: the next dispatch starts it
+    assert profiler_log == [("start", "/d")]
+    assert not c.request("/e", 9, 9)  # one trace at a time
+    with c.span(tracing.DISPATCH, step_num=7) as span:
+        pass
+    assert span is not tracing.OFF and span.ms >= 0
+    c.after_drain(5)
+    assert profiler_log == [("start", "/d")]
+    c.after_drain(7)
+    assert profiler_log == [("start", "/d"), ("stop",)]
+    assert c.request("/e", 9, 9)
+    c.close()  # a pending request dies with the run
+    c.before_dispatch(9)
+    assert profiler_log == [("start", "/d"), ("stop",)] and c.span("x") is tracing.OFF
+    # a sink alone turns the spans on, timed and not annotated
+    sink = telemetry.install(telemetry.MemorySink())
+    try:
+        c.before_dispatch(10)
+        with c.span(tracing.NEXT_BATCH) as span:
+            pass
+        assert span.ms >= 0 and span._annotation is None
+    finally:
+        telemetry.uninstall(sink)
+
+
+# ------------------------------------------------------------------ scopes
+FAMILIES = {
+    "gpt": lambda: gpt_config("gpt-0.3b", num_layers=3, hidden_size=64, num_heads=4,
+                              vocab_size=256, max_seq_len=32, compute_dtype=jnp.float32),
+    "llama": lambda: llama_config("llama-0.3b", num_layers=3, hidden_size=64, num_heads=4,
+                                  ffn_hidden=128, vocab_size=256, max_seq_len=32,
+                                  compute_dtype=jnp.float32),
+}
+
+
+@pytest.mark.parametrize("chunks", [1, 2])
+@pytest.mark.parametrize("guard", [False, True], ids=["plain", "guard"])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "no_scan_layers"])
+def test_every_scope_is_in_the_compiled_steps_op_names(devices8, scan, family, guard, chunks):
+    """Two recomputed layers and one that is not: two layer runs, r0 scanned
+    (or unrolled under --no_scan_layers) and r1 always unrolled."""
+    cfg = FAMILIES[family]()
+    hp = HybridParallelConfig(
+        world_size=1, pp=1, layers=[LayerStrategy(checkpoint=1)] * 2 + [LayerStrategy()],
+        global_bsz=4, chunks=chunks, scan_layers=scan)
+    model = construct_hybrid_parallel_model(cfg, hp)
+    tx, _ = get_optimizer_and_scheduler(OptimizerArgs(lr=1e-3, warmup_steps=2, total_steps=20))
+
+    def sds(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings)
+
+    abstract = model.abstract_params()
+    shape = jax.ShapeDtypeStruct((4, 32), jnp.int32)
+    batch = {k: jax.ShapeDtypeStruct(shape.shape, shape.dtype, sharding=NamedSharding(
+        model.mesh, model._batch_spec_for(shape))) for k in ("tokens", "positions", "labels")}
+    step_args = [sds(abstract, model.shardings()),
+                 sds(jax.eval_shape(tx.init, abstract), model.opt_state_shardings(tx, abstract)),
+                 batch] + ([jnp.float32(1e9)] if guard else [])
+    hlo = model.make_train_step(tx, guard_anomalies=guard).lower(*step_args).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+
+    def under(*parts):
+        return [n for n in names if all(p in n for p in parts)]
+
+    r0, r1 = tracing.layers_scope(0), tracing.layers_scope(1)
+    for scope in (tracing.EMBED, r0, r1, tracing.HEAD_LOSS):
+        assert under("jvp(%s)" % scope), scope  # its forward
+        assert under("transpose(jvp(%s))" % scope), scope  # its backward
+    assert under(tracing.OPTIMIZER)
+    assert bool(under(tracing.GUARD)) == guard
+    assert under(tracing.GUARD, "select_n") or not guard
+    assert bool(under(tracing.GRAD_ACCUM)) == (chunks > 1)
+    # recomputation is named under the run it recomputes, and only there
+    assert under("transpose(jvp(%s))" % r0, "rematted_computation")
+    assert not under(r1, "rematted_computation")
+    assert bool(under(r0 + ")/while/body")) == scan
+    # the names are defined once, in obs/tracing.py
+    assert {tracing.EMBED, r0, tracing.HEAD_LOSS, tracing.OPTIMIZER, tracing.GUARD,
+            tracing.GRAD_ACCUM} == {"gt.embed", "gt.layers.r0", "gt.head_loss", "gt.optimizer",
+                                    "gt.guard", "gt.grad_accum"}
