@@ -37,7 +37,7 @@ from galvatron_tpu.ops.attention import KernelSharding, core_attention
 from galvatron_tpu.ops.norms import layer_norm, rms_norm
 from galvatron_tpu.ops.rope import apply_rotary
 from galvatron_tpu.parallel import spec as S
-from galvatron_tpu.parallel.mesh import LayerAxes, layer_axes, vocab_axes
+from galvatron_tpu.parallel.mesh import LayerAxes, layer_axes, mesh_axis_size, vocab_axes
 
 Params = Dict[str, Any]
 
@@ -429,18 +429,51 @@ def decode_layer_forward(
 
 
 # ============================================================== model forward
+def vocab_parallel_lookup(wte: jax.Array, tokens: jax.Array, dtype, mesh: Mesh,
+                          vax: LayerAxes) -> jax.Array:
+    """Rows of a (vocab, hidden) table whose vocabulary is split over
+    ``vax.tp``: Megatron's VocabParallelEmbedding (reference
+    GPTModel_tensor_parallel.py:84-132), written out. Each device shifts the
+    ids by its first row, gathers the rows it holds, has zeros for the ids it
+    does not hold, and the partial results are summed over the tp axes.
+
+    A manual region, not ``wte[tokens]`` left to GSPMD: there the gather and
+    its scatter-add are device-local and the psum is the only collective,
+    where GSPMD runs a one-hot matmul as a matmul and partitions the
+    scatter-add of a sharded gather with collective-permutes
+    (parallel/pipeline_1f1b.py embed_fwd). The rows are gathered from the
+    stored shard and cast afterwards, so the table's gradient accumulates
+    over repeated ids in the parameter's dtype. The result is whole over tp;
+    under Megatron-SP the caller's constraint to `act_spec` slices it into
+    sequence shards (the compiler makes a reduce-scatter of sum and slice)."""
+    tp = tuple(vax.tp)
+    rows = wte.shape[0] // mesh_axis_size(mesh, tp)
+
+    # serve hands in (1, ctx) and (slots, 1): rows the dp axes do not divide stay whole
+    split_rows = tokens.shape[0] % mesh_axis_size(mesh, vax.batch_axes) == 0
+    tok_spec = P(S._ax(vax.batch_axes) if split_rows else None, S._ax(vax.cp))
+
+    def local(table, tok):
+        idx = tok - jax.lax.axis_index(tp) * rows
+        # an id of another device's rows goes out of bounds: the gather fills
+        # it with zeros, and its transpose drops the update
+        idx = jnp.where((idx >= 0) & (idx < rows), idx, rows)
+        return jax.lax.psum(table.at[idx].get(mode="fill", fill_value=0).astype(dtype), tp)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(P(S._ax(tp), None), tok_spec), out_specs=P(*tok_spec, None),
+    )(wte, tokens)
+
+
 def embed_tokens(p_embed: Params, tokens: jax.Array, positions: jax.Array, cfg: TransformerConfig,
                  mesh: Optional[Mesh] = None, vax: Optional[LayerAxes] = None,
                  token_type_ids: Optional[jax.Array] = None) -> jax.Array:
-    """Vocab-parallel embedding. With the table sharded on vocab, the one-hot
-    einsum partitions into masked local lookup + psum — exactly Megatron's
-    VocabParallelEmbedding (reference GPTModel_tensor_parallel.py:84-132),
-    derived by the compiler."""
+    """Token (+ position, + token-type) embedding. A table split over the
+    vocabulary (vocab_tp > 1, not ulysses) is read by `vocab_parallel_lookup`;
+    any other table is whole on the vocab dim and read by a plain gather."""
     wte = p_embed["wte"]
-    vocab_sharded = vax is not None and len(vax.tp) > 0 and not vax.ulysses
-    if vocab_sharded:
-        onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.compute_dtype)
-        x = jnp.einsum("bsv,vh->bsh", onehot, wte.astype(cfg.compute_dtype))
+    if vax is not None and len(vax.tp) > 0 and not vax.ulysses:
+        x = vocab_parallel_lookup(wte, tokens, cfg.compute_dtype, mesh, vax)
     else:
         x = wte.astype(cfg.compute_dtype)[tokens]
     if cfg.position_type == "learned":

@@ -318,15 +318,17 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
     @jax.named_scope(tracing.EMBED)
     def embed_fwd(vparams, inputs, positions, token_types):
         """Vocab-parallel embedding on the within-stage gathered tables (see
-        the vparams gather in loss_and_grad): the one-hot einsum partitions
-        into masked local lookup + psum over the within-stage vocab_tp group
-        (cf. base.py embed_tokens).
+        the vparams gather in loss_and_grad), as one-hot matmuls.
 
         ALL table lookups here are one-hot matmuls, not gathers: the vjp of a
         gather is a scatter-add, which GSPMD partitions with index-operand
         collective-permutes outside any dataflow ordering — the deadlock found
         by driving GPT (learned positions) through the 1F1B schedule. A
-        matmul's vjp is a matmul: dense, orderable, and MXU-friendly."""
+        matmul's vjp is a matmul: dense and orderable, but it is run as a
+        matmul over the whole vocabulary. Outside this schedule the split
+        table is read by models/base.vocab_parallel_lookup (a manual region:
+        local gather, local scatter-add, one psum, no permute); moving this
+        copy onto it waits for a pp cell to measure it in."""
         emb = vparams["embed"]
         dtype = cfg.compute_dtype
         if cfg.input_type == "patches":

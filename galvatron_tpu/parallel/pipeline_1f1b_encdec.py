@@ -234,8 +234,9 @@ def make_encdec_loss_and_grad(cfg, hp: HybridParallelConfig, mesh):
 
     # ------------------------------------------------------- vocab fwd pieces
     def embed_fwd(vparams, tokens):
-        """One-hot wte lookup (see pipeline_1f1b.embed_fwd for why matmul,
-        not gather)."""
+        """One-hot wte lookup (see pipeline_1f1b.embed_fwd for why matmul, not
+        gather, and for models/base.vocab_parallel_lookup, which replaces it
+        outside the 1F1B schedule)."""
         dtype = cfg.compute_dtype
         onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=dtype)
         x = jnp.einsum("bsv,vh->bsh", onehot, vparams["embed"]["wte"].astype(dtype))

@@ -1,0 +1,124 @@
+"""The vocabulary-split embedding (models/base.vocab_parallel_lookup): a
+masked local gather and one sum over the vocabulary's tp axes must give the
+unsplit ``wte[tokens]`` and its table gradient, whatever the layout around
+it, and the compiled program must hold the lookup and not a one-hot matmul."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding
+
+from galvatron_tpu.config.strategy import HybridParallelConfig
+from galvatron_tpu.models import base as M
+from galvatron_tpu.parallel import spec as S
+from galvatron_tpu.parallel.mesh import build_mesh, vocab_axes
+from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
+
+pytestmark = [pytest.mark.parallel, pytest.mark.distributed]
+
+V, H, B, SEQ = 64, 16, 4, 8
+
+
+def _layout(devices8, vtp, dp, **kw):
+    hp = HybridParallelConfig.uniform(
+        vtp * dp * kw.get("pp", 1) * kw.get("vocab_cp", 1), 2, tp=vtp, vocab_tp=vtp,
+        cp=kw.get("vocab_cp", 1), global_bsz=B, **kw)
+    return build_mesh(hp, devices8[: hp.world_size]), vocab_axes(hp)
+
+
+def _tokens(vtp, shape=(B, SEQ)):
+    """Random ids, then both ends of every shard's rows, then one id repeated
+    over a whole row (the gradient's accumulation)."""
+    tok = np.array(jax.random.randint(jax.random.PRNGKey(1), shape, 0, V))
+    flat = tok.reshape(-1)
+    rows = V // vtp
+    ends = [e for r in range(vtp) for e in (r * rows, (r + 1) * rows - 1)]
+    flat[: len(ends)] = ends[: flat.size]
+    tok = flat.reshape(shape)
+    if shape[0] > 1:
+        tok[1] = rows + 3
+    return jnp.asarray(tok)
+
+
+def _check(mesh, vax, tokens, dtype):
+    wte = jax.random.normal(jax.random.PRNGKey(0), (V, H), jnp.float32)
+    ct = jax.random.normal(jax.random.PRNGKey(2), tokens.shape + (H,), jnp.float32)
+    w_sh = jax.device_put(wte, NamedSharding(mesh, S.vocab_embed_spec(vax)))
+
+    def split(w, t):
+        return M.vocab_parallel_lookup(w, t, dtype, mesh, vax)
+
+    def whole(w, t):  # gather, then cast: the gradient accumulates in float32
+        return w[t].astype(dtype)
+
+    def pulled(f):
+        return lambda w, t: jnp.sum(f(w, t).astype(jnp.float32) * ct)
+
+    out, ref = jax.jit(split)(w_sh, tokens), whole(wte, tokens)
+    assert out.dtype == dtype and out.shape == ref.shape
+    np.testing.assert_array_equal(np.asarray(out, np.float32), np.asarray(ref, np.float32))
+    grad_fn = jax.jit(jax.grad(pulled(split))).lower(w_sh, tokens).compile()
+    grad = grad_fn(w_sh, tokens)
+    assert grad.dtype == jnp.float32
+    np.testing.assert_allclose(grad, jax.grad(pulled(whole))(wte, tokens), rtol=1e-5, atol=1e-5)
+    return grad_fn.as_text()
+
+
+def _count(hlo, op):
+    return len(re.findall(r" %s(?:-start)?\(" % re.escape(op), hlo))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("dp", [1, 2])
+@pytest.mark.parametrize("vtp", [2, 4])
+def test_split_lookup_matches_whole_table(devices8, vtp, dp, dtype):
+    mesh, vax = _layout(devices8, vtp, dp, sequence_parallel=False)
+    hlo = _check(mesh, vax, _tokens(vtp), dtype)
+    # the lookup it stands for: no matmul, one local scatter-add, and the only
+    # collectives are sums (tp forward, dp for the table), never a permute
+    assert _count(hlo, "dot") == 0 and _count(hlo, "scatter") == 1
+    assert _count(hlo, "collective-permute") == 0 and _count(hlo, "all-to-all") == 0
+
+
+LAYOUTS = {
+    "megatron_sp": dict(sequence_parallel=True),  # sum lands in sequence shards
+    "embed_sdp": dict(embed_sdp=1),               # ZeRO-3 on the table's hidden dim
+    "vocab_cp2": dict(vocab_cp=2),                # tokens split over the sequence too
+    "pp2": dict(pp=2, chunks=2),                  # GPipe embeds on the full mesh
+}
+
+
+@pytest.mark.parametrize("name", list(LAYOUTS))
+def test_split_lookup_under_layout(devices8, name):
+    mesh, vax = _layout(devices8, 2, 2, **LAYOUTS[name])
+    hlo = _check(mesh, vax, _tokens(2), jnp.bfloat16)
+    assert _count(hlo, "dot") == 0 and _count(hlo, "collective-permute") == 0
+
+
+@pytest.mark.parametrize("shape", [(4, 1), (1, 8), (1, 1)],
+                         ids=["decode_slots", "prefill_one_row", "one_token"])
+def test_split_lookup_takes_serve_shapes(devices8, shape):
+    """serve/engine.py embeds (slots, 1) and (1, ctx): rows the batch axes do
+    not divide stay whole on every device."""
+    mesh, vax = _layout(devices8, 2, 2)
+    _check(mesh, vax, _tokens(2, shape), jnp.float32)
+
+
+@pytest.mark.parametrize("kw", [dict(), dict(embed_sdp=1), dict(default_dp_type="zero2")],
+                         ids=["ddp", "embed_sdp", "zero2"])
+def test_lm_loss_and_table_gradient_match_single_device(devices8, gpt_cfg, gpt_params, kw):
+    from tests.conftest import gpt_batch
+
+    batch = gpt_batch(0)
+    batch["tokens"] = batch["tokens"].at[0].set(5)  # one id, a whole row
+    want, want_g = jax.value_and_grad(M.lm_loss_fn)(gpt_params, batch, gpt_cfg)
+    hp = HybridParallelConfig.uniform(8, gpt_cfg.num_layers, tp=2, vocab_tp=2,
+                                      global_bsz=batch["tokens"].shape[0], **kw)
+    m = construct_hybrid_parallel_model(gpt_cfg, hp, devices8)
+    got, got_g = jax.jit(jax.value_and_grad(m.loss_fn))(
+        jax.device_put(gpt_params, m.shardings()), m.shard_batch(batch))
+    assert abs(float(got) - float(want)) < 2e-5
+    np.testing.assert_allclose(got_g["embed"]["wte"], want_g["embed"]["wte"], atol=2e-6)

@@ -11,6 +11,7 @@ warn and compile again.
 """
 
 import os
+import re
 import subprocess
 import sys
 
@@ -111,18 +112,12 @@ def test_auto_dispatch_reads_the_platform_off_the_mesh(v5e_2x2):
     assert "tpu_custom_call" in jax.jit(fwd).lower(q, q, q).compile().as_text()
 
 
-def test_one_chip_7b_width_step_fits_v5e_hbm(v5e_2x2):
-    """The train step chip_smoke.py runs (LLaMA-7B width, 2 layers, batch 2,
-    seq 2048, bf16 compute, fp32 params + Adam) compiles for one v5e chip,
-    holds the kernel, and its program fits the chip's 15.75 GiB."""
-    from galvatron_tpu.config.strategy import HybridParallelConfig
-    from galvatron_tpu.models.llama import llama_config
+def _compile_train_step(cfg, hp, devices, batch_rows):
+    """The model's train step (Adam) compiled for `devices` from shapes alone."""
     from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
     from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
 
-    cfg = llama_config("llama-7b", num_layers=2, compute_dtype=jnp.bfloat16)
-    hp = HybridParallelConfig.uniform(1, 2, global_bsz=2, mixed_precision="bf16")
-    m = construct_hybrid_parallel_model(cfg, hp, v5e_2x2[:1])
+    m = construct_hybrid_parallel_model(cfg, hp, devices)
     tx, _ = get_optimizer_and_scheduler(OptimizerArgs(lr=1e-4, warmup_steps=0, total_steps=8))
 
     def sds(tree, shardings):
@@ -131,18 +126,75 @@ def test_one_chip_7b_width_step_fits_v5e_hbm(v5e_2x2):
 
     params = m.abstract_params()
     opt = jax.eval_shape(tx.init, params)
-    tok = jax.ShapeDtypeStruct((2, cfg.max_seq_len), jnp.int32)
+    tok = jax.ShapeDtypeStruct((batch_rows, cfg.max_seq_len), jnp.int32)
     batch = {k: jax.ShapeDtypeStruct(tok.shape, tok.dtype,
                                      sharding=NamedSharding(m.mesh, m._batch_spec_for(tok)))
              for k in ("tokens", "positions", "labels")}
-    compiled = m.make_train_step(tx).lower(
+    return m.make_train_step(tx).lower(
         sds(params, m.shardings()), sds(opt, m.opt_state_shardings(tx, params)), batch,
     ).compile()
+
+
+def test_one_chip_7b_width_step_fits_v5e_hbm(v5e_2x2):
+    """The train step chip_smoke.py runs (LLaMA-7B width, 2 layers, batch 2,
+    seq 2048, bf16 compute, fp32 params + Adam) compiles for one v5e chip,
+    holds the kernel, and its program fits the chip's 15.75 GiB."""
+    from galvatron_tpu.config.strategy import HybridParallelConfig
+    from galvatron_tpu.models.llama import llama_config
+
+    cfg = llama_config("llama-7b", num_layers=2, compute_dtype=jnp.bfloat16)
+    hp = HybridParallelConfig.uniform(1, 2, global_bsz=2, mixed_precision="bf16")
+    compiled = _compile_train_step(cfg, hp, v5e_2x2[:1], batch_rows=2)
     assert "tpu_custom_call" in compiled.as_text()
     ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
              + ma.output_size_in_bytes - ma.alias_size_in_bytes)
     assert total < V5E_HBM_BYTES, "%.2f GiB" % (total / 2**30)
+
+
+@pytest.fixture(scope="module")
+def tp2dp2_step_hlo(v5e_2x2):
+    """The train step of a narrow LLaMA lowered for the described 2x2 under
+    `--global_tp_deg 2 --vocab_tp 2 --default_dp_type zero2` (the layout of
+    the four-chip benchmark cell), by Megatron-SP on or off."""
+    from galvatron_tpu.config.strategy import HybridParallelConfig
+    from galvatron_tpu.models.llama import llama_config
+
+    def lowered(sequence_parallel: bool) -> str:
+        cfg = llama_config("llama-0.3b", num_layers=2, hidden_size=512, num_heads=4,
+                           ffn_hidden=1024, vocab_size=32000, max_seq_len=256,
+                           compute_dtype=jnp.bfloat16)
+        hp = HybridParallelConfig.uniform(
+            4, 2, tp=2, vocab_tp=2, default_dp_type="zero2", global_bsz=4,
+            mixed_precision="bf16", sequence_parallel=sequence_parallel)
+        return _compile_train_step(cfg, hp, v5e_2x2, batch_rows=4).as_text()
+
+    return {sp: lowered(sp) for sp in (False, True)}
+
+
+@pytest.mark.parametrize("sequence_parallel,summed_by",
+                         [(False, "all-reduce"), (True, "all-reduce-scatter")],
+                         ids=["all_reduce", "megatron_sp_sum_and_slice"])
+def test_vocab_split_embedding_is_a_lookup_on_v5e(tp2dp2_step_hlo, sequence_parallel, summed_by):
+    """Under `vocab_tp 2` the embedding is a masked local gather and one sum
+    over tp (models/base.vocab_parallel_lookup), not a one-hot matmul: no
+    `dot_general` carries the `gt.embed` scope, the forward holds one
+    collective there (an all-reduce; under Megatron-SP the compiler fuses it
+    with the slice into sequence shards, a `fusion` that calls
+    `%all-reduce-scatter`), and nothing is permuted."""
+    ops = []  # (opcode, op_name) of every instruction under the gt.embed scope
+    for line in tp2dp2_step_hlo[sequence_parallel].splitlines():
+        name = re.search(r'op_name="([^"]*gt\.embed[^"]*)"', line)
+        code = re.search(r" ([a-z][a-z0-9-]*)\(", line.partition(" = ")[2])
+        if name and code:
+            fused_sum = "calls=%all-reduce-scatter" in line
+            ops.append(("all-reduce-scatter" if fused_sum else code.group(1), name.group(1)))
+    assert any(code in ("gather", "scatter") for code, _ in ops), ops
+    assert not [o for o in ops if "dot_general" in o[1] or o[0] in ("dot", "convolution")], ops
+    assert not [o for o in ops if o[0].startswith("collective-permute")], ops
+    forward_sums = [code for code, name in ops if "transpose(" not in name
+                    and re.fullmatch(r"(all-reduce|reduce-scatter|all-reduce-scatter)(-start)?", code)]
+    assert forward_sums == [summed_by], ops
 
 
 def test_chip_smoke_refuses_without_a_tpu():

@@ -35,6 +35,9 @@ def layout_hp(cfg, kind):
         "dp8": mk(),
         "zero3": mk(sdp=1),
         "tp2_zero3": mk(tp=2, sdp=1),
+        # the table split over the vocabulary: prefill (1, ctx) and decode
+        # (slots, 1) go through models/base.vocab_parallel_lookup
+        "tp2_vtp2": mk(tp=2, vocab_tp=2),
     }[kind]
 
 
@@ -91,6 +94,12 @@ def test_decode_matches_full_forward_tp2(devices8):
     """Two concurrent slots under tp=2 (the searched-layout archetype):
     every decode step's logits match the full-sequence recompute."""
     run_parity(devices8, "tp2", [[5, 9, 2], [17, 3, 44, 8, 1]])
+
+
+def test_decode_matches_full_forward_vocab_split(devices8):
+    """The same under `vocab_tp 2` (tp2 x dp4): one prompt row and two slots
+    do not divide over dp, and stay whole on every replica."""
+    run_parity(devices8, "tp2_vtp2", [[5, 9, 2], [17, 3, 44, 8, 1]])
 
 
 @pytest.mark.slow
