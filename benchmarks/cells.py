@@ -5,8 +5,10 @@ Nothing here knows a cell, a configuration, a mix or a metric by name: a
 workload entry names `benchmarks/configs/<config>.json` and
 `benchmarks/traffic/<traffic>.json`, a per-layer metric names
 `benchmarks/layer_metrics/<name>.py`, a configuration names its plain
-reference `benchmarks/references/<reference>.py`. A later PR adds files and
-entries and edits nothing.
+reference `benchmarks/references/<reference>.py` and, where its block is not
+the dense decoder `benchmarks/flops.py` counts, its own count of model FLOPs
+`benchmarks/model_flops/<flops>.py`. A later PR adds files and entries and
+edits nothing.
 
 A configuration reaches the program through `models/registry.register`, the
 program's own seam for a model family: one family per configuration file,
@@ -23,9 +25,14 @@ import importlib.util
 import json
 import os
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Mapping
+from typing import Any, Callable, Dict, List, Mapping, Tuple
+
+from benchmarks import flops
 
 MANIFEST = "BENCHMARK.json"
+# what tp x dp under ZeRO-2 must show in the step; a mix of another layout
+# states its own under `collectives`
+DEFAULT_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter")
 SEED_MODULUS = 2**31  # the trainer's numpy streams take seeds below 2**32
 
 
@@ -68,6 +75,11 @@ class Cell:
     def tokens_a_step(self) -> int:
         return int(self.traffic["global_batch"]) * int(self.traffic["seq_length"])
 
+    @property
+    def collectives(self) -> Tuple[str, ...]:
+        """The collectives this mix's layout must show in the step's HLO."""
+        return tuple(self.traffic.get("collectives", DEFAULT_COLLECTIVES))
+
     def metrics(self, group: str) -> List[Dict[str, Any]]:
         """The manifest's `end_to_end` or `per_layer` metrics this cell reports."""
         return [m for m in self.manifest[group]
@@ -104,6 +116,16 @@ def config_fields(config: Mapping) -> Dict[str, Any]:
             value = config[value[1:]]
         out[field] = value
     return out
+
+
+def flops_a_token(cell: Cell) -> float:
+    """Forward + backward model FLOPs a token of this cell, in the convention
+    of `benchmarks/flops.py`: from the module the configuration names under
+    `flops` (`benchmarks/model_flops/<name>.py`), else from `flops.py`."""
+    name = cell.config.get("flops")
+    module = flops if name is None else load_module(
+        cell.root, "benchmarks/model_flops/%s.py" % name)
+    return float(module.train_flops_a_token(cell.fields, int(cell.traffic["seq_length"])))
 
 
 def import_attr(spec: str) -> Callable:

@@ -32,13 +32,10 @@ TRACED_STEPS = 3
 SETTLE_AFTER_TRACE = 6
 # --trace 2: the traced tail after the window fills about this long, and holds
 # at least this many steps (the reduction drops the first and the last run of
-# the step, tracing_on_slowdown_pct the first three intervals). No more: the
-# reduction's exposed-collective pass is quadratic in the traced steps, 10 s
-# for 6 steps of the four-chip cell and 22 s for 8
+# the step, tracing_on_slowdown_pct the first three intervals)
 TAIL_SECONDS = 3.0
 TAIL_MIN_STEPS = 6
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-LAYOUT_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter")
 
 
 class DeviceStart:
@@ -148,9 +145,12 @@ def reference_loss(cell: cells.Cell, args) -> float:
 def expected_first_loss(cell: cells.Cell) -> float:
     """ln V + hidden x init_std^2 / 2: the final norm hands the head unit-RMS
     rows and the head is N(0, init_std^2), so the untrained logits are
-    N(0, hidden x init_std^2) and E[CE] = ln V + sigma^2 / 2."""
+    N(0, hidden x init_std^2) and E[CE] = ln V + sigma^2 / 2. Plus what the
+    configuration's objective adds to the cross entropy at initialisation
+    (`checks.first_loss.plus`: router losses, derived in its `plus_why`)."""
     f = cell.fields
-    return math.log(f["vocab_size"]) + f["hidden_size"] * f["init_std"] ** 2 / 2
+    cross_entropy = math.log(f["vocab_size"]) + f["hidden_size"] * f["init_std"] ** 2 / 2
+    return cross_entropy + cell.config["checks"]["first_loss"].get("plus", 0.0)
 
 
 def step_memory(compiled) -> Dict[str, float]:
@@ -307,7 +307,7 @@ def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: int,
 
     est = window.estimate(stamps, cell.tokens_a_step)
     tokens_per_s_chip = est["rate"] / cell.chips
-    flops_a_token = flops.train_flops_a_token(cell.fields, int(traffic["seq_length"]))
+    flops_a_token = cells.flops_a_token(cell)
     new = [k for k in T._STEP_EXECUTABLES if k not in before]
     compiled = T._STEP_EXECUTABLES[new[0]] if len(new) == 1 else None
     memory = step_memory(compiled) if compiled is not None else {}
@@ -330,7 +330,7 @@ def run_cell(cell: cells.Cell, *, seed: int, seconds: float, traced: int,
         leaves = jax.tree.leaves(compiled.input_shardings[0][0]) if compiled is not None else []
         checks["params_span_all_chips"] = bool(leaves) and all(
             len(s.device_set) == cell.chips for s in leaves)
-        checks["layout_collectives"] = all(c in hlo for c in LAYOUT_COLLECTIVES)
+        checks["layout_collectives"] = all(c in hlo for c in cell.collectives)
 
     stats = [d.memory_stats() or {} for d in devices]
     device = {

@@ -63,7 +63,7 @@ def rehearse(workload: str, topo_devices) -> dict:
     return {
         "workload": workload, "compile_only": True, **harness.step_memory(step),
         "tpu_custom_calls": hlo.count("tpu_custom_call"),
-        "collectives": {c: hlo.count(c) for c in harness.LAYOUT_COLLECTIVES},
+        "collectives": {c: hlo.count(c) for c in cell.collectives},
         "reference_hbm_gib": ref_mem["step_hbm_gib"],
     }
 

@@ -153,6 +153,19 @@ def union(intervals: Sequence[Tuple[int, int]]) -> List[Tuple[int, int]]:
     return [(a, b) for a, b in merged]
 
 
+def overlap(xs: Sequence[Tuple[int, int]], ys: Sequence[Tuple[int, int]]) -> int:
+    """The length two unions share (each sorted and disjoint, as `union`
+    gives them), in one sweep over both."""
+    total = i = j = 0
+    while i < len(xs) and j < len(ys):
+        total += max(0, min(xs[i][1], ys[j][1]) - max(xs[i][0], ys[j][0]))
+        if xs[i][1] <= ys[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
 def _clip(events: Sequence[Event], lo: int, hi: int) -> List[Event]:
     return [e for e in events if e[1] >= lo and e[1] + e[2] <= hi]
 
@@ -201,10 +214,8 @@ def reduce_device(ops: Sequence[Event], modules: Sequence[Event], host: Sequence
     coll = [(label, start, dur) for label, start, dur in leaves if COLLECTIVE.search(label)]
     other = union([(start, start + dur) for label, start, dur in leaves
                    if not COLLECTIVE.search(label)])
-    exposed_ns = 0
-    for a, b in union([(start, start + dur) for _, start, dur in coll]):
-        covered = sum(max(0, min(b, d) - max(a, c)) for c, d in other)
-        exposed_ns += (b - a) - covered
+    coll_union = union([(start, start + dur) for _, start, dur in coll])
+    exposed_ns = sum(b - a for a, b in coll_union) - overlap(coll_union, other)
     calls: Dict[str, int] = {}
     for label, _, _ in leaves:
         calls[label] = calls.get(label, 0) + 1

@@ -62,6 +62,93 @@ def test_a_collective_under_compute_is_not_exposed():
     assert r["collective_exposed_s_a_step"] == pytest.approx(100e-9)
 
 
+def quadratic_exposed(coll, other):
+    """The pass as it was before the sweep: every collective interval against
+    every other interval."""
+    exposed = 0
+    for a, b in coll:
+        exposed += (b - a) - sum(max(0, min(b, d) - max(a, c)) for c, d in other)
+    return exposed
+
+
+def swept_exposed(coll, other):
+    return sum(b - a for a, b in coll) - trace.overlap(coll, other)
+
+
+def test_the_sweep_gives_the_double_loops_nanoseconds_on_thousands_of_intervals():
+    import random
+
+    rng = random.Random(26)
+
+    def intervals(n):
+        out, t = [], 0
+        for _ in range(n):
+            t += rng.randrange(0, 60)  # 0: one starts where the last began
+            out.append((t, t + rng.randrange(1, 40)))
+        return trace.union(out)
+
+    coll, other = intervals(3000), intervals(4000)
+    assert len(coll) > 1000 and len(other) > 1000
+    assert swept_exposed(coll, other) == quadratic_exposed(coll, other)
+    assert 0 < trace.overlap(coll, other) == trace.overlap(other, coll) < sum(b - a for a, b in coll)
+    assert trace.overlap(coll, coll) == sum(b - a for a, b in coll)
+    assert trace.overlap(coll, []) == trace.overlap([], other) == 0
+    # touching ends share nothing
+    assert trace.overlap([(0, 5), (9, 12)], [(5, 9), (12, 20)]) == 0
+
+
+@pytest.mark.parametrize("fixture,stand_in", [
+    ("qwen7-c1-s2k", r"flash|copy|select"), ("qwen7-c1-s2k-scoped", r"flash|copy|select"),
+    ("qwen7-c4-tp2dp2", None)], ids=["one_chip", "one_chip_scoped", "four_chips"])
+def test_the_sweep_gives_the_double_loops_nanoseconds_on_the_recorded_traces(
+        fixture, stand_in, monkeypatch):
+    """The old pass and the new on the same events, and `reduce` through the
+    new. One chip has no collective, so there the kernels, the copies and the
+    update's selects stand in for them; the four-chip trace has its own."""
+    import re
+
+    events = trace.load_events(os.path.join(
+        REPO, "benchmarks", "fixtures", fixture + ".trace_events.json.gz"))
+    if stand_in is not None:
+        monkeypatch.setattr(trace, "COLLECTIVE", re.compile(stand_in))
+    names = ("plain_step", "train_step")
+    r = trace.reduce(events, names)
+    lines = events["devices"]["0"]
+    steps = trace.steps_of([tuple(e) for e in lines["modules"]], names)
+    lo, hi = steps[0][1], steps[-1][1] + steps[-1][2]
+    leaves = [(label, start, dur) for label, start, dur, self_ns in trace.self_times(
+        trace._clip([tuple(e) for e in lines["ops"]], lo, hi)) if self_ns == dur]
+    coll = trace.union([(s, s + d) for label, s, d in leaves if trace.COLLECTIVE.search(label)])
+    other = trace.union([(s, s + d) for label, s, d in leaves
+                         if not trace.COLLECTIVE.search(label)])
+    assert len(coll) > 50 and len(other) > 50
+    old = quadratic_exposed(coll, other)
+    assert swept_exposed(coll, other) == old > 0
+    assert r["collective_exposed_s_a_step"] == old / 1e9 / len(steps)
+
+
+def test_reduction_of_the_four_chip_trace_recorded_on_the_chip():
+    """Device 0's events of five traced steps of qwen7-c4-tp2dp2 on a v5e
+    2x2; the expected numbers are what that run itself reported."""
+    import json
+
+    from benchmarks import cells
+
+    fixtures = os.path.join(REPO, "benchmarks", "fixtures")
+    expected = json.load(open(os.path.join(fixtures, "qwen7-c4-tp2dp2.expected.json")))
+    r = trace.reduce(trace.load_events(
+        os.path.join(fixtures, "qwen7-c4-tp2dp2.trace_events.json.gz")), ("plain_step", "train_step"))
+    assert r["steps"] == expected["steps"] == 5
+    run = {"trace": r, "cell": cells.load_cell(REPO, "qwen7-c4-tp2dp2"),
+           "peak": cells.load_json(REPO, "benchmarks/peaks.json")["TPU v5 lite"]}
+    for name, value in expected.items():
+        if name not in ("recorded", "steps"):
+            reader = cells.load_module(REPO, "benchmarks/layer_metrics/%s.py" % name)
+            assert reader.read(run) == pytest.approx(value, rel=1e-9), name
+    # nothing runs beside a collective in this step: all of it is exposed
+    assert expected["collective_exposed_ms"] == expected["collective_ms"] > 90
+
+
 def test_no_whole_step_reduces_to_nothing():
     assert trace.reduce({"devices": {0: {"ops": [], "modules": []}}, "host": []}, STEP) is None
     assert trace.reduce({"devices": {}, "host": []}, STEP) is None
