@@ -19,6 +19,10 @@ def profile_model(args) -> dict:
 
     enable_persistent_cache()
     fam, cfg = model_config_from_args(args)
+    if getattr(cfg, "routed", False):
+        from galvatron_tpu.models.base import refuse_expert_layout
+
+        refuse_expert_layout("profile (the layer profiler times the dense block)")
     pargs = ModelProfileArgs(
         profile_type=args.profile_type,
         profile_mode=args.profile_mode,

@@ -760,6 +760,9 @@ def _train(args) -> dict:
             mfu=obs_flops.mfu(step_flops, iter_ms, peak_flops),
             model_flops_per_s=obs_flops.flops_per_s(step_flops, iter_ms),
             grad_norm=grad_norm if grad_norm is None or np.isfinite(grad_norm) else None,
+            # a routed-experts config's step hands these back beside the loss
+            **{k: float(metrics[k]) for k in telemetry.EXPERT_STEP_FIELDS
+               if isinstance(metrics, dict) and k in metrics},
         )
 
     def drain_one():

@@ -18,6 +18,7 @@ models/t5.py).
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
@@ -34,6 +35,7 @@ from galvatron_tpu.config.strategy import (
 )
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding, core_attention
+from galvatron_tpu.ops.moe import moe_ffn, swiglu
 from galvatron_tpu.ops.norms import layer_norm, rms_norm
 from galvatron_tpu.ops.rope import apply_rotary
 from galvatron_tpu.parallel import spec as S
@@ -79,6 +81,14 @@ class TransformerConfig:
     patch_size: int = 16
     num_channels: int = 3
     use_cls_token: bool = False
+    # --- what a published sparse-expert config states (OLMoE); the defaults
+    # are the dense model, whose step none of these touches ---
+    num_experts: int = 0  # > 0: the MLP half is routed experts of width ffn_hidden
+    experts_per_token: int = 0
+    norm_topk_prob: bool = False  # renormalise the chosen experts' weights
+    router_aux_loss_coef: float = 0.0  # x the load-balancing loss
+    router_z_loss_coef: float = 0.0  # x the router z-loss
+    qk_norm: bool = False  # a norm over the whole projected q and the whole k
 
     def __post_init__(self):
         if self.num_kv_heads is None:
@@ -100,6 +110,11 @@ class TransformerConfig:
         """MLP input-projection kernel trailing dims: (2, ffn) for swiglu
         (fused gate+up, split on an unsharded leading dim) else (ffn,)."""
         return (2, self.ffn_hidden) if self.activation == "swiglu" else (self.ffn_hidden,)
+
+    @property
+    def routed(self) -> bool:
+        """Whether the MLP half is routed experts (ops/moe.py)."""
+        return self.num_experts > 0
 
 
 # ===================================================================== init
@@ -135,6 +150,20 @@ def init_layer_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
     p["wo"] = {"kernel": _dense_init(ks[1], (nh * hd, h), proj_std, cfg.param_dtype)}
     if cfg.out_bias:
         p["wo"]["bias"] = jnp.zeros((h,), cfg.param_dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = {"scale": jnp.ones((nh * hd,), cfg.param_dtype)}
+        p["k_norm"] = {"scale": jnp.ones((nkv * hd,), cfg.param_dtype)}
+    if cfg.routed:
+        # one kernel a matrix with the experts leading: (E, h, 2F) the gate's
+        # columns beside the up projection's (flat: a TPU tiles the minor
+        # dims, and a (2, F) pair there costs a copy a use), (E, F, h) down;
+        # the router (h, E) stays float32 in the forward
+        e, fan_in = cfg.num_experts, math.prod(cfg.mlp_fan_in)
+        kr = jax.random.fold_in(ks[2], 1)
+        p["router"] = {"kernel": _dense_init(kr, (h, e), cfg.init_std, cfg.param_dtype)}
+        p["wi"] = {"kernel": _dense_init(ks[2], (e, h, fan_in), cfg.init_std, cfg.param_dtype)}
+        p["wo_mlp"] = {"kernel": _dense_init(ks[3], (e, cfg.ffn_hidden, h), proj_std, cfg.param_dtype)}
+        return p
     p["wi"] = {"kernel": _dense_init(ks[2], (h,) + cfg.mlp_fan_in, cfg.init_std, cfg.param_dtype)}
     if cfg.mlp_bias:
         p["wi"]["bias"] = jnp.zeros(cfg.mlp_fan_in, cfg.param_dtype)
@@ -244,6 +273,87 @@ def qkv_projection(p: Params, y: jax.Array, cfg: TransformerConfig, dtype):
     return q, kv[:, :, 0], kv[:, :, 1]
 
 
+def dense_mlp(p: Params, y: jax.Array, cfg: TransformerConfig, dtype) -> jax.Array:
+    """The dense MLP half on normed activations (B, S, H)."""
+    wi_out = jnp.einsum("bsh,h...->bs...", y, p["wi"]["kernel"].astype(dtype))
+    if "bias" in p["wi"]:
+        wi_out = wi_out + p["wi"]["bias"].astype(dtype)
+    if cfg.activation == "swiglu":
+        hmid = jax.nn.silu(wi_out[:, :, 0]) * wi_out[:, :, 1]
+    else:
+        hmid = _activation(wi_out, cfg)
+    return _dense(hmid, p["wo_mlp"], dtype)
+
+
+def qk_normed(p: Params, q: jax.Array, k: jax.Array, cfg: TransformerConfig):
+    """OLMoE's q_norm / k_norm: an RMSNorm over the WHOLE projected q
+    (nh x hd) and the whole projected k, before rope. Taken over the last two
+    dims in place: flattening them would merge the heads dim, which tp shards."""
+    def whole(t, scale):
+        x32 = t.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=(-2, -1), keepdims=True)
+        y = x32 * jnp.reciprocal(jnp.sqrt(var + cfg.layernorm_eps))
+        return (y * scale.astype(jnp.float32).reshape(t.shape[-2:])).astype(t.dtype)
+
+    return whole(q, p["q_norm"]["scale"]), whole(k, p["k_norm"]["scale"])
+
+
+# ------------------------------------------------ layouts of routed experts
+def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional[str] = None,
+                         autotune: Optional[str] = None) -> Optional[str]:
+    """Why this layout (or driver mode) cannot run a routed-experts config,
+    or None. Experts are ordinary parameters under dp and ZeRO-1/2/3; no
+    other axis has an expert form yet (`ep` is the next step), and what has
+    none is refused by name (GLS018) at lint time and at trace time, not run
+    wrong or priced as dense."""
+    if not getattr(cfg, "routed", False):
+        return None
+    if mode == "serve":
+        return "serve: the decode engine has no expert form"
+    if (autotune or "off") != "off":
+        return "autotune=%s: the re-search would price the block as dense" % autotune
+    if hp is None:
+        return None
+    if hp.pp > 1:
+        return "pp=%d: the pipeline engines carry no router losses between stages" % hp.pp
+    for i, s in enumerate(hp.layers):
+        if s.tp > 1 or s.cp > 1 or s.sp:
+            return ("layer %d: tp=%d cp=%d sp=%d: the experts' kernels and the dropless "
+                    "dispatch have no tensor-, context- or sequence-parallel form"
+                    % (i, s.tp, s.cp, int(s.sp)))
+    if hp.vocab_tp > 1:
+        return "vocab_tp=%d: tensor parallelism of any layer is unsupported" % hp.vocab_tp
+    if hp.tp_comm_mode != "gspmd":
+        return "tp_comm_mode=%r: the manual TP path has no expert form" % hp.tp_comm_mode
+    from galvatron_tpu.parallel import quant_collectives as QC
+
+    if QC.wants_quant_comm(hp):
+        return "quantized grad/param collectives run a local loss with no router statistics"
+    return None
+
+
+def expert_layout_diagnostic(reason: str):
+    """The GLS018 diagnostic for a reason of `expert_layout_reason`."""
+    from galvatron_tpu.analysis import diagnostics as D
+
+    return D.make(
+        "GLS018", "routed experts (num_experts > 0) refused: %s; such a config runs on one "
+        "chip and under dp with ZeRO-1/2/3" % reason, key="num_experts")
+
+
+def refuse_expert_layout(reason: str):
+    from galvatron_tpu.analysis.diagnostics import DiagnosticError
+
+    raise DiagnosticError([expert_layout_diagnostic(reason)])
+
+
+def assert_expert_layout_supported(cfg, hp: Optional[HybridParallelConfig]):
+    """Trace-time half of GLS018 (strategy_lint.lint_hp reports it pre-trace)."""
+    reason = expert_layout_reason(cfg, hp)
+    if reason is not None:
+        refuse_expert_layout(reason)
+
+
 # ============================================================== layer forward
 def layer_forward(
     p: Params,
@@ -272,14 +382,21 @@ def layer_forward(
 
     ``attn_sharding`` is the attention kernel's layout for callers that run
     this body with ``mesh=None`` under their own mapping (the GPipe stage
-    vmap); with a mesh and axes it is derived here."""
+    vmap); with a mesh and axes it is derived here.
+
+    A routed-experts config (``cfg.routed``) returns ``(x, aux)``: the
+    block's output and its router's auxiliary terms (ops/moe.py)."""
     dtype = cfg.compute_dtype
+    if cfg.routed and return_kv:
+        refuse_expert_layout("serving (the prefill's k/v outputs)")
     if mesh is not None and axes is not None:
         attn_sharding = KernelSharding.for_layer(mesh, axes)
 
     residual = x
     y = _norm(x, p["ln1"], cfg) if cfg.pre_norm else x
     q, k, v = qkv_projection(p, y, cfg, dtype)
+    if cfg.qk_norm:
+        q, k = qk_normed(p, q, k, cfg)
     if cfg.position_type == "rope":
         if mesh is not None and axes is not None:
             # Pin positions to THIS layer's sharding so each layer derives its
@@ -327,14 +444,14 @@ def layer_forward(
 
     residual = x
     y = _norm(x, p["ln2"], cfg) if cfg.pre_norm else x
-    wi_out = jnp.einsum("bsh,h...->bs...", y, p["wi"]["kernel"].astype(dtype))
-    if "bias" in p["wi"]:
-        wi_out = wi_out + p["wi"]["bias"].astype(dtype)
-    if cfg.activation == "swiglu":
-        hmid = jax.nn.silu(wi_out[:, :, 0]) * wi_out[:, :, 1]
+    if cfg.routed:
+        out, aux = moe_ffn(
+            y, p["router"]["kernel"], p["wi"]["kernel"], p["wo_mlp"]["kernel"],
+            experts_per_token=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
+            activate=swiglu if cfg.activation == "swiglu" else partial(_activation, cfg=cfg),
+            dtype=dtype, sharding=attn_sharding)
     else:
-        hmid = _activation(wi_out, cfg)
-    out = _dense(hmid, p["wo_mlp"], dtype)
+        out, aux = dense_mlp(p, y, cfg, dtype), None
     if mesh is not None and axes is not None:
         out = S.constrain(out, mesh, S.act_spec(axes))
     x = residual + out
@@ -342,6 +459,8 @@ def layer_forward(
         x = _norm(x, p["ln2"], cfg)
     if return_kv:
         return x, kv_out
+    if aux is not None:
+        return x, aux
     return x
 
 
@@ -379,10 +498,14 @@ def decode_layer_forward(
     exactly, so incremental decode reproduces the full-forward logits within
     float tolerance (tests/serve/test_decode_parity.py)."""
     dtype = cfg.compute_dtype
+    if cfg.routed:
+        refuse_expert_layout("serving (single-token decode)")
 
     residual = x
     y = _norm(x, p["ln1"], cfg) if cfg.pre_norm else x
     q, k, v = qkv_projection(p, y, cfg, dtype)
+    if cfg.qk_norm:
+        q, k = qk_normed(p, q, k, cfg)
     if cfg.position_type == "rope":
         q = apply_rotary(q, positions, cfg.rope_theta)
         k = apply_rotary(k, positions, cfg.rope_theta)
@@ -412,14 +535,7 @@ def decode_layer_forward(
 
     residual = x
     y = _norm(x, p["ln2"], cfg) if cfg.pre_norm else x
-    wi_out = jnp.einsum("bsh,h...->bs...", y, p["wi"]["kernel"].astype(dtype))
-    if "bias" in p["wi"]:
-        wi_out = wi_out + p["wi"]["bias"].astype(dtype)
-    if cfg.activation == "swiglu":
-        hmid = jax.nn.silu(wi_out[:, :, 0]) * wi_out[:, :, 1]
-    else:
-        hmid = _activation(wi_out, cfg)
-    out = _dense(hmid, p["wo_mlp"], dtype)
+    out = dense_mlp(p, y, cfg, dtype)
     if mesh is not None and axes is not None:
         out = S.constrain(out, mesh, P(S._ax(axes.batch_axes), None, None))
     x = residual + out
@@ -658,12 +774,18 @@ def run_layers(
     emit them as stacked side outputs of the SAME scan, so prefill keeps the
     depth-constant trace. The collecting path is GSPMD-only and forward-only
     (no manual-TP shard_map body, no remat): serve lints away the layouts
-    that would need either."""
+    that would need either.
+
+    A routed-experts config returns ``(x, aux)``, the routers' auxiliary
+    terms over the layers: the mean of each loss, the worst layer's load
+    (`_fold_aux`). A dense config carries nothing and traces what it did."""
     use_hp = hp is not None and mesh is not None
+    assert_expert_layout_supported(cfg, hp)
     layers = params["layers"]
     if scan is None:
         scan = hp.scan_layers if hp is not None else True
     kvs: List[Tuple[jax.Array, jax.Array]] = []
+    auxs: List[Dict[str, jax.Array]] = []  # routed: a layer's, or a scanned run's stacked
 
     def unrolled(x, indices):
         for i in indices:
@@ -688,6 +810,9 @@ def run_layers(
                 if pol != "none":
                     fwd = _remat(fwd, pol)
             x = fwd(lp, x, positions)
+            if cfg.routed:
+                x, aux = x
+                auxs.append(aux)
         return x
 
     def one_run(x, run):
@@ -728,9 +853,12 @@ def run_layers(
         def step(carry, lp, _body=body, _axes=axes):
             if use_hp:
                 carry = S.constrain(carry, mesh, S.act_spec(_axes))
-            return _body(lp, carry, positions), None
+            out = _body(lp, carry, positions)
+            return out if cfg.routed else (out, None)
 
-        x, _ = jax.lax.scan(step, x, stacked)
+        x, run_aux = jax.lax.scan(step, x, stacked)
+        if cfg.routed:
+            auxs.append(run_aux)
         return x
 
     if use_hp:
@@ -744,7 +872,23 @@ def run_layers(
             x = one_run(x, run)
     if collect_kv:
         return x, kvs
+    if cfg.routed:
+        return x, _fold_aux(auxs, len(layers))
     return x
+
+
+def _fold_aux(auxs: List[Dict[str, jax.Array]], num_layers: int) -> Dict[str, jax.Array]:
+    """The layers' router terms as one: each loss the mean over layers, the
+    load the worst layer's. An entry is a layer's scalars or a scanned
+    run's, stacked along the layer axis."""
+    def total(name, reduce):
+        return reduce(jnp.stack([reduce(jnp.atleast_1d(a[name])) for a in auxs]))
+
+    return {
+        "load_balance": total("load_balance", jnp.sum) / num_layers,
+        "router_z": total("router_z", jnp.sum) / num_layers,
+        "load_max_over_mean": total("load_max_over_mean", jnp.max),
+    }
 
 
 def padding_attn_bias(attn_mask: jax.Array) -> jax.Array:
@@ -752,7 +896,13 @@ def padding_attn_bias(attn_mask: jax.Array) -> jax.Array:
     return (1.0 - attn_mask.astype(jnp.float32))[:, None, None, :] * -1e9
 
 
-def model_forward(
+def model_forward(params, tokens, positions, cfg, hp=None, mesh=None, **inputs) -> jax.Array:
+    """Full forward to logits (single pipeline stage; pipelined execution lives
+    in parallel/pipeline.py)."""
+    return forward_with_aux(params, tokens, positions, cfg, hp, mesh, **inputs)[0]
+
+
+def forward_with_aux(
     params: Params,
     tokens: jax.Array,
     positions: jax.Array,
@@ -761,9 +911,9 @@ def model_forward(
     mesh: Optional[Mesh] = None,
     token_type_ids: Optional[jax.Array] = None,
     attn_mask: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Full forward to logits (single pipeline stage; pipelined execution lives
-    in parallel/pipeline.py)."""
+):
+    """`model_forward`'s logits, and the routers' auxiliary terms
+    (`run_layers`) or None for a dense config."""
     use_hp = hp is not None and mesh is not None
     vax = vocab_axes(hp) if use_hp else None
     if positions is None and cfg.input_type != "patches":
@@ -778,6 +928,7 @@ def model_forward(
         x = S.constrain(x, mesh, S.act_spec(vax))
     bias = padding_attn_bias(attn_mask) if attn_mask is not None else None
     x = run_layers(params, x, positions, cfg, hp, mesh, attn_bias=bias)
+    x, aux = x if cfg.routed else (x, None)
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
     # the head is the first half of gt.head_loss; the loss functions below
@@ -786,18 +937,38 @@ def model_forward(
         logits = model_head(params, x, cfg)
         if use_hp and cfg.head_type in ("lm", "mlm"):
             logits = S.constrain(logits, mesh, S.logits_spec(vax))
-    return logits
+    return logits, aux
 
 
-def lm_loss_fn(params, batch, cfg, hp=None, mesh=None):
+EXPERT_LOAD = "expert_load_max_over_mean"  # the fullest expert's tokens over the mean
+
+
+def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False):
     """batch: dict(tokens, positions, labels, loss_mask?, token_type_ids?,
-    attn_mask?). Serves lm and mlm heads (token-level CE)."""
-    logits = model_forward(
+    attn_mask?). Serves lm and mlm heads (token-level CE).
+
+    A routed-experts config's loss is the cross entropy plus
+    `router_aux_loss_coef` x load balancing plus `router_z_loss_coef` x router
+    z-loss (each the mean over layers, over all tokens); `with_parts` returns
+    `(loss, parts)` with the three terms and the worst layer's expert load,
+    the `step` event's counters."""
+    logits, aux = forward_with_aux(
         params, batch["tokens"], batch["positions"], cfg, hp, mesh,
         token_type_ids=batch.get("token_type_ids"), attn_mask=batch.get("attn_mask"),
     )
     with jax.named_scope(tracing.HEAD_LOSS):
-        return vocab_parallel_cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
+        loss = vocab_parallel_cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
+    if aux is None:
+        return loss
+    parts = {
+        "loss_ce": loss,
+        "loss_load_balance": aux["load_balance"],
+        "loss_router_z": aux["router_z"],
+        EXPERT_LOAD: aux["load_max_over_mean"],
+    }
+    loss = (loss + cfg.router_aux_loss_coef * aux["load_balance"]
+            + cfg.router_z_loss_coef * aux["router_z"])
+    return (loss, parts) if with_parts else loss
 
 
 def softmax_nll(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -840,6 +1011,16 @@ def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     sp["wo"] = {"kernel": P(tp, z3)}
     if cfg.out_bias:
         sp["wo"]["bias"] = r1
+    if cfg.qk_norm:
+        sp["q_norm"] = {"scale": r1}
+        sp["k_norm"] = {"scale": r1}
+    if cfg.routed:
+        # ordinary leaves (tp is refused, GLS018): ZeRO-3 splits the experts
+        # over dp, and they enter the block whole (ops/moe.moe_ffn)
+        sp["router"] = {"kernel": P(None, None)}
+        sp["wi"] = {"kernel": P(z3, None, None)}
+        sp["wo_mlp"] = {"kernel": P(z3, None, None)}
+        return sp
     if cfg.activation == "swiglu":
         sp["wi"] = {"kernel": P(z3, None, tp)}
         if cfg.mlp_bias:

@@ -92,8 +92,17 @@ def _ensure_builtin():
     if _LOADED:
         return
     _LOADED = True
-    from galvatron_tpu.models import gpt, llama
+    from galvatron_tpu.models import gpt, llama, olmoe
 
+    register(
+        ModelFamily(
+            name="olmoe",
+            config_fn=olmoe.olmoe_config,
+            meta_configs=olmoe.META_CONFIGS,
+            default_size="olmoe-1b-7b",
+            config_from_hf=olmoe.olmoe_config_from_hf,
+        )
+    )
     register(
         ModelFamily(
             name="gpt",

@@ -79,10 +79,15 @@ def layer_fwd_flops(
     causal: bool = True,
     swiglu: bool = False,
     tokens: Optional[float] = None,
+    num_experts: int = 0,
+    experts_per_token: int = 0,
 ) -> float:
     """Forward model FLOPs of ONE transformer block over `tokens` tokens
     (default: one sequence). Matmul terms only (2 FLOPs per MAC); norms and
-    elementwise activations are O(tokens*hidden) noise next to these."""
+    elementwise activations are O(tokens*hidden) noise next to these. A
+    routed block (`num_experts` > 0, `ffn_hidden` the width of one expert)
+    counts the experts a token is SENT to, not the experts held, plus the
+    router's matmul."""
     tokens = float(seq_len if tokens is None else tokens)
     ffn = ffn_hidden or 4 * hidden
     hd = head_dim or hidden // num_heads
@@ -96,6 +101,8 @@ def layer_fwd_flops(
     # MLP: swiglu projects to 2*ffn (gate+up) then back; gelu/relu ffn both ways
     mlp = (2.0 * hidden * (2 * ffn) + 2.0 * ffn * hidden) if swiglu \
         else (2.0 * hidden * ffn + 2.0 * ffn * hidden)
+    if num_experts:
+        mlp = experts_per_token * mlp + 2.0 * hidden * num_experts
     return tokens * (proj + attn + mlp)
 
 
@@ -118,6 +125,8 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         causal=bool(getattr(cfg, "causal", True)),
         swiglu=getattr(cfg, "activation", "gelu") == "swiglu",
         tokens=tokens,
+        num_experts=getattr(cfg, "num_experts", 0),
+        experts_per_token=getattr(cfg, "experts_per_token", 0),
     )
 
 

@@ -46,6 +46,12 @@ SCHEMA_VERSION = 1
 # Envelope keys stamped onto every event by the sink.
 ENVELOPE_KEYS = ("v", "t", "seq", "type")
 
+# a routed-experts config's loss by its terms (before their coefficients) and
+# the fullest expert's tokens over the mean, worst layer: keys of the step's
+# `metrics` (models/base.lm_loss_fn) and optional fields of the `step` event
+EXPERT_STEP_FIELDS = ("loss_ce", "loss_load_balance", "loss_router_z",
+                      "expert_load_max_over_mean")
+
 # type -> (required field names, optional field names). Unknown types and
 # unknown keys are rejected; None-valued optional fields are dropped at emit
 # so readers never see explicit nulls.
@@ -76,9 +82,11 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         ("iter",),
         # data_wait_ms: the loop's wait in next_batch() for this step
         # (obs/tracing.py gt/next_batch span)
+        # EXPERT_STEP_FIELDS: what a routed-experts config's step hands back
+        # beside the loss, fetched with it
         ("loss", "iter_ms", "dispatch_ms", "data_wait_ms", "host_blocked_ms",
          "hbm_in_use_mb", "hbm_peak_mb", "mfu", "model_flops_per_s",
-         "grad_norm"),
+         "grad_norm") + EXPERT_STEP_FIELDS,
     ),
     "eval": (("iter", "split", "loss"), ()),
     # lifecycle: checkpointing

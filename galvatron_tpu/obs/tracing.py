@@ -42,6 +42,13 @@ HEAD_LOSS = "gt.head_loss"  # final norm, logits, cross entropy
 OPTIMIZER = "gt.optimizer"  # tx.update, apply_updates, global_norm
 GUARD = "gt.guard"  # the anomaly guard's and the SDC vote's keep-old selects
 GRAD_ACCUM = "gt.grad_accum"  # the microbatch loop's weighting and adds
+# the parts of a routed-experts block (ops/moe.py), inside gt.layers.r<k>
+MOE_ROUTER = "gt.moe.router"  # float32 logits, softmax, top-k, the auxiliary terms
+MOE_DISPATCH = "gt.moe.dispatch"  # sort the assignments by expert, gather the rows
+MOE_EXPERTS = "gt.moe.experts"  # the grouped matmuls and SwiGLU
+MOE_GMM_IN = "gmm_in"  # inside MOE_EXPERTS: rows x (hidden, 2 x width), gate and up
+MOE_GMM_OUT = "gmm_out"  # inside MOE_EXPERTS: rows x (width, hidden)
+MOE_COMBINE = "gt.moe.combine"  # back into token order, the weighted sum of k
 
 
 def layers_scope(run_index: int) -> str:

@@ -223,6 +223,7 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
     stages, GPTModel_sequential.py:201-215)."""
     from galvatron_tpu.models import base as M
 
+    M.assert_expert_layout_supported(cfg, hp)  # GLS018: no expert form under pp
     validate_pipeline_config(hp)
     vax = vocab_axes(hp)
 

@@ -245,6 +245,8 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
 
     from galvatron_tpu.parallel.pipeline import stage_layer_offsets
 
+    M.assert_expert_layout_supported(cfg, hp)  # GLS018: no expert form under pp
+
     validate_1f1b_config(hp)
     pp, chunks = hp.pp, hp.chunks
     offs = stage_layer_offsets(hp)

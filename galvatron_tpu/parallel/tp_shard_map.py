@@ -97,6 +97,9 @@ def manual_tp_reason(cfg, hp: HybridParallelConfig,
     if num_heads is None:
         return "model family without a flat num_heads (t5/swin custom " \
                "trees) is not wired through the manual TP path"
+    if getattr(cfg, "qk_norm", False):
+        return "qk_norm (a norm over all heads of q and of k) is not wired " \
+               "through the manual TP path"
     if num_heads % tp != 0:
         return "num_heads=%d not divisible by tp=%d (GSPMD pads; the " \
                "manual path refuses)" % (num_heads, tp)

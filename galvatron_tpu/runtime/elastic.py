@@ -66,6 +66,13 @@ DEFAULT_MEMORY_GB = 16.0  # matches the search CLI's --memory_constraint default
 # choices (the manifest's spec_digest machinery already handles a dtype
 # change), not model identity
 _DIGEST_EXCLUDE = ("compute_dtype", "param_dtype", "attn_impl")
+# fields newer than checkpoints in the field, with the value under which the
+# model is the one those checkpoints hold: left out at that value, so that a
+# dense model's digest is what it was before the field existed
+_DIGEST_DEFAULTS = {
+    "num_experts": 0, "experts_per_token": 0, "norm_topk_prob": False,
+    "router_aux_loss_coef": 0.0, "router_z_loss_coef": 0.0, "qk_norm": False,
+}
 
 
 def _stable_json(obj: Any) -> str:
@@ -81,7 +88,8 @@ def model_config_digest(model_cfg: Any) -> str:
         fields = dataclasses.asdict(model_cfg)
     else:  # duck-typed configs (tests)
         fields = {k: v for k, v in vars(model_cfg).items() if not k.startswith("_")}
-    fields = {k: str(v) for k, v in fields.items() if k not in _DIGEST_EXCLUDE}
+    fields = {k: str(v) for k, v in fields.items() if k not in _DIGEST_EXCLUDE
+              and not (k in _DIGEST_DEFAULTS and v == _DIGEST_DEFAULTS[k])}
     return hashlib.sha256(_stable_json(fields).encode()).hexdigest()
 
 

@@ -63,6 +63,11 @@ class HybridParallelModel:
     # each dp shard computes grads on its local batch with no
     # with_sharding_constraint in scope. Base families only; None refuses
     # the quantized path with GLS013.
+    loss_parts_fn: Optional[Callable] = None  # (params, batch) -> (loss,
+    # parts): a routed-experts config's objective with its terms and the
+    # expert load (models/base.lm_loss_fn with_parts), which the step hands
+    # back in `metrics` beside the loss; None (a dense config) leaves the
+    # step as it is
     # memoized NamedSharding trees per batch signature (key set + ranks), so
     # the per-step shard_batch is ONE device_put of the whole tree with no
     # per-key NamedSharding construction on the hot path
@@ -263,9 +268,11 @@ class HybridParallelModel:
                                            anomaly_guard=guard_anomalies)
             quant_fn = QC.make_quant_loss_and_grads(self)
 
+        with_parts = self.loss_parts_fn is not None and self.grad_fn is None and quant_fn is None
+
         def train_step(params, opt_state, batch, spike_cap=None):
-            def mb_loss(p, mb):
-                return self.loss_fn(p, mb)
+            mb_loss = self.loss_parts_fn if with_parts else self.loss_fn
+            parts = {}
 
             if self.grad_fn is not None:
                 # 1f1b pipeline: loss and grads come out of the hand-written
@@ -298,7 +305,9 @@ class HybridParallelModel:
                     lambda g, s: jax.lax.with_sharding_constraint(g, s), grads, accum_shardings
                 )
             elif chunks == 1:
-                loss, grads = jax.value_and_grad(mb_loss)(params, batch)
+                loss, grads = jax.value_and_grad(mb_loss, has_aux=with_parts)(params, batch)
+                if with_parts:
+                    loss, parts = loss
                 with jax.named_scope(tracing.GRAD_ACCUM):
                     grads = jax.tree.map(
                         lambda g, s: jax.lax.with_sharding_constraint(g, s), grads, accum_shardings
@@ -325,8 +334,16 @@ class HybridParallelModel:
                 loss = 0.0
                 for c in range(chunks):
                     mb = jax.tree.map(lambda x: x[c], mbs)
-                    l, g = jax.value_and_grad(mb_loss)(params, mb)
+                    l, g = jax.value_and_grad(mb_loss, has_aux=with_parts)(params, mb)
                     w = weights[c]
+                    if with_parts:
+                        # the terms weighted as the loss is; the load of the
+                        # fullest microbatch
+                        l, mp = l
+                        mp = {k: v if k == M.EXPERT_LOAD else v * w for k, v in mp.items()}
+                        parts = mp if not parts else {
+                            k: jnp.maximum(parts[k], v) if k == M.EXPERT_LOAD else parts[k] + v
+                            for k, v in mp.items()}
                     with jax.named_scope(tracing.GRAD_ACCUM):
                         g = jax.tree.map(
                             lambda gi, s: jax.lax.with_sharding_constraint(gi * w, s),
@@ -339,7 +356,7 @@ class HybridParallelModel:
                 updates, new_opt_state = tx.update(grads, opt_state, params)
                 new_params = optax.apply_updates(params, updates)
                 grad_norm = optax.global_norm(grads)
-            metrics = {"loss": loss, "grad_norm": grad_norm}
+            metrics = {"loss": loss, "grad_norm": grad_norm, **parts}
             if guard_anomalies:
                 bad = jnp.logical_or(
                     jnp.logical_or(~jnp.isfinite(loss), ~jnp.isfinite(grad_norm)),
@@ -412,9 +429,11 @@ def construct_hybrid_parallel_model(
     devices=None,
     loss_fn=None,
 ) -> HybridParallelModel:
+    M.assert_expert_layout_supported(cfg, hp)
     mesh = build_mesh(hp, devices)
     specs = M.model_param_specs(cfg, hp)
     grad_fn = None
+    loss_parts = None
     eval_loss = None
     local_loss = None
     if hp.pp > 1 and hp.pipeline_type == "pipedream_flush":
@@ -460,6 +479,8 @@ def construct_hybrid_parallel_model(
             token_type_ids=b.get("token_type_ids"), attn_mask=b.get("attn_mask"),
         )
         local_loss = lambda p, b: M.lm_loss_fn(p, b, cfg)
+        if loss_fn is None and getattr(cfg, "routed", False):
+            loss_parts = lambda p, b: M.lm_loss_fn(p, b, cfg, hp, mesh, with_parts=True)
     if hp.pp > 1 or loss_fn is not None:
         # custom losses have no constraint-free local form; pp>1 never takes
         # the quantized path (GLS013)
@@ -474,4 +495,5 @@ def construct_hybrid_parallel_model(
         grad_fn=grad_fn,
         eval_loss_fn=None if loss_fn is not None else eval_loss,
         local_loss_fn=local_loss,
+        loss_parts_fn=loss_parts,
     )

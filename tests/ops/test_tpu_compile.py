@@ -209,3 +209,62 @@ def test_chip_smoke_refuses_without_a_tpu():
     assert proc.returncode != 0, proc.stdout
     assert '"ok": true' not in proc.stdout
     assert proc.stdout.strip() == "", proc.stdout
+
+
+# ------------------------------------------------------------ routed experts
+MOE_TOKENS, MOE_H, MOE_F, MOE_E, MOE_K = 8192, 2048, 1024, 64, 8  # OLMoE-1B-7B, 2 x 4096
+
+
+MEGABLOX_CALL = r"%t?gmm[.\d]* = "  # the grouped matmul and its kernels' gradient
+
+
+def _moe_loss(sharding):
+    from galvatron_tpu.ops.moe import moe_ffn
+
+    def loss(y, router, wi, wo):
+        out, aux = moe_ffn(y, router, wi, wo, experts_per_token=MOE_K, dtype=y.dtype,
+                           sharding=sharding)
+        return jnp.sum(out.astype(jnp.float32) ** 2) + aux["load_balance"] + aux["router_z"]
+
+    return loss
+
+
+def _moe_operands(batch, tokens_sharding, whole, dtype=jnp.bfloat16):
+    f32 = jnp.float32
+    return (jax.ShapeDtypeStruct((batch, MOE_TOKENS // 2, MOE_H), dtype, sharding=tokens_sharding),
+            jax.ShapeDtypeStruct((MOE_H, MOE_E), f32, sharding=whole),
+            jax.ShapeDtypeStruct((MOE_E, MOE_H, 2 * MOE_F), f32, sharding=whole),
+            jax.ShapeDtypeStruct((MOE_E, MOE_F, MOE_H), f32, sharding=whole))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "float32"])
+def test_the_routed_experts_block_compiles_for_v5e(v5e_2x2, dtype):
+    """ops/moe.py at OLMoE's widths, forward and backward, one chip: on a TPU
+    (read off the mesh, as the flash kernel's dispatch) the grouped matmuls are
+    the megablox kernels at the measured tiling, which the chip's compiler
+    takes (float32 operands at half the K and N tiles: the whole ones exceed
+    the scoped VMEM); off it, `ragged_dot`."""
+    one = SingleDeviceSharding(v5e_2x2[0])
+    on_chip = A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("x",)))
+    fn = jax.grad(_moe_loss(on_chip), argnums=(0, 1, 2, 3))
+    text = jax.jit(fn).lower(*_moe_operands(2, one, one, dtype)).compile().as_text()
+    assert len(re.findall(MEGABLOX_CALL, text)) == 6  # 2 forward, 4 backward
+    assert "ragged-dot" not in text
+    if dtype == jnp.float32:
+        return
+    off_chip = jax.jit(_moe_loss(None)).lower(*_moe_operands(2, one, one)).compile().as_text()
+    assert "ragged-dot" in off_chip and not re.findall(MEGABLOX_CALL, off_chip)
+
+
+def test_the_routed_experts_block_is_a_manual_region_on_a_dp4_mesh(v5e_2x2):
+    """Under dp the block runs per device on its own batch rows against whole
+    experts (a region manual over every axis, as the flash kernel's), and the
+    only collectives are the sums of the router's statistics and of the
+    parameters' gradients."""
+    mesh = Mesh(np.array(v5e_2x2).reshape(1, 4), ("pp", "dp"))
+    sharding = A.KernelSharding(mesh, batch_axes=("dp",))
+    fn = jax.grad(_moe_loss(sharding), argnums=(0, 1, 2, 3))
+    text = jax.jit(fn).lower(*_moe_operands(
+        8, NamedSharding(mesh, P("dp", None, None)), NamedSharding(mesh, P()))).compile().as_text()
+    assert len(re.findall(MEGABLOX_CALL, text)) == 6
+    assert "all-reduce" in text and "all-to-all" not in text and "all-gather" not in text
