@@ -47,7 +47,12 @@ def _add_parallel_args(p: argparse.ArgumentParser):
     g.add_argument("--global_train_batch_size", type=int, default=8)
     g.add_argument("--chunks", type=int, default=1, help="number of microbatches")
     g.add_argument("--pipeline_type", type=str, default="gpipe", choices=("gpipe", "pipedream_flush"))
-    g.add_argument("--default_dp_type", type=str, default="ddp", choices=("ddp", "zero2", "zero3"))
+    g.add_argument("--default_dp_type", type=str, default="ddp", choices=("ddp", "zero2", "zero3"),
+                   help="how a layer's state is held over dp. ddp: whole on every replica. zero2: "
+                        "Adam's moments, the accumulated gradient and the float32 parameters split "
+                        "over dp; the step gathers a compute-dtype (bf16) copy of the parameters "
+                        "once, and float32 only what the model reads in float32. zero3: parameters "
+                        "split over dp and gathered at each use (config/strategy.py DP_TYPES)")
     g.add_argument("--embed_sdp", type=int, default=0)
     g.add_argument("--vocab_tp", type=int, default=1)
     g.add_argument("--vocab_sp", type=int, default=0)

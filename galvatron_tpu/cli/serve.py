@@ -120,6 +120,10 @@ def _serve(args) -> dict:
         print("restored %s at iteration %s into the serve layout"
               % (args.load, meta.get("iteration")))
 
+    # a train state may store leaves split over dp (ZeRO-2); decode reads
+    # them at every token, so they are placed as the forward lays them out
+    params = jax.device_put(params, model.shardings(model.param_specs))
+
     # cache geometry: CLI flags win, then the strategy JSON's serve knobs,
     # then defaults; pages default to covering the model's max_seq_len
     max_slots = args.serve_max_concurrency or hp.serve_max_concurrency or 8

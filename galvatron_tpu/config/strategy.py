@@ -40,6 +40,19 @@ from typing import List, Optional, Sequence
 from galvatron_tpu.utils.jsonio import read_json_config, write_json_config
 from galvatron_tpu.utils.strategy_utils import array2str, str2array
 
+# How a layer's state is held over its dp axes (runtime/model_api.py):
+# "ddp": float32 parameters and Adam's moments whole on every replica, the
+#   gradient all-reduced.
+# "zero2": the moments, the accumulated gradient (a reduce-scatter) AND the
+#   float32 parameters split over dp; once a step the step gathers a copy in
+#   the compute dtype (`compute_params`, scope gt.param_gather) of every leaf
+#   the model reads only through a cast to it. A leaf read in float32 (norm
+#   scales, the router, the `vocab_tp` table's rows) stays whole over dp and
+#   is gathered in float32 after the update, as is every leaf under float32
+#   compute, pp > 1, the manual TP path or the quantized grad sync
+#   (`HybridParallelModel.copied_leaves` has the conditions and why).
+# "zero3": parameters split over dp in `param_specs` itself and gathered at
+#   each use (`param_comm_dtype` is the wire dtype of THAT gather only).
 DP_TYPES = ("ddp", "zero2", "zero3")
 PIPELINE_TYPES = ("gpipe", "pipedream_flush")
 CP_MODES = ("ring", "zigzag")

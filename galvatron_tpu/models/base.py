@@ -581,6 +581,12 @@ def vocab_parallel_lookup(wte: jax.Array, tokens: jax.Array, dtype, mesh: Mesh,
     )(wte, tokens)
 
 
+def table_is_looked_up(vax: Optional[LayerAxes]) -> bool:
+    """Whether `embed_tokens` reads the token table by `vocab_parallel_lookup`
+    (from the stored shard, cast afterwards) and not as `wte.astype(dtype)`."""
+    return vax is not None and len(vax.tp) > 0 and not vax.ulysses
+
+
 def embed_tokens(p_embed: Params, tokens: jax.Array, positions: jax.Array, cfg: TransformerConfig,
                  mesh: Optional[Mesh] = None, vax: Optional[LayerAxes] = None,
                  token_type_ids: Optional[jax.Array] = None) -> jax.Array:
@@ -588,7 +594,7 @@ def embed_tokens(p_embed: Params, tokens: jax.Array, positions: jax.Array, cfg: 
     vocabulary (vocab_tp > 1, not ulysses) is read by `vocab_parallel_lookup`;
     any other table is whole on the vocab dim and read by a plain gather."""
     wte = p_embed["wte"]
-    if vax is not None and len(vax.tp) > 0 and not vax.ulysses:
+    if table_is_looked_up(vax):
         x = vocab_parallel_lookup(wte, tokens, cfg.compute_dtype, mesh, vax)
     else:
         x = wte.astype(cfg.compute_dtype)[tokens]

@@ -594,11 +594,13 @@ def construct_swin_model(cfg: SwinConfig, hp: HybridParallelConfig, devices=None
             grad_fn=grad_fn,
             eval_loss_fn=eval_loss,
         )
+    specs = swin_param_specs(cfg, hp)
     return HybridParallelModel(
         cfg=cfg,
         hp=hp,
         mesh=mesh,
-        param_specs=swin_param_specs(cfg, hp),
+        param_specs=specs,
+        cast_first=S.cast_first_tree(specs, table_stored=False),
         loss_fn=lambda p, b: swin_loss_fn(p, b, cfg, hp, mesh),
         forward_fn=lambda p, b: swin_forward(p, b["pixels"], cfg, hp, mesh),
         init_fn=lambda rng: init_swin_params(rng, cfg),

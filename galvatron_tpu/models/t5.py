@@ -573,11 +573,14 @@ def construct_t5_model(cfg: T5Config, hp: HybridParallelConfig, devices=None):
             grad_fn=grad_fn,
             eval_loss_fn=eval_loss,
         )
+    specs = t5_param_specs(cfg, hp)
     return HybridParallelModel(
         cfg=cfg,
         hp=hp,
         mesh=mesh,
-        param_specs=t5_param_specs(cfg, hp),
+        param_specs=specs,
+        # the table feeds the encoder and the decoder, and a tied head
+        cast_first=S.cast_first_tree(specs, table_stored=True),
         loss_fn=lambda p, b: t5_loss_fn(p, b, cfg, hp, mesh),
         forward_fn=lambda p, b: t5_forward(
             p, b["tokens"], b["dec_tokens"], cfg, hp, mesh, enc_attn_mask=b.get("attn_mask")

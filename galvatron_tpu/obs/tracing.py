@@ -42,6 +42,7 @@ HEAD_LOSS = "gt.head_loss"  # final norm, logits, cross entropy
 OPTIMIZER = "gt.optimizer"  # tx.update, apply_updates, global_norm
 GUARD = "gt.guard"  # the anomaly guard's and the SDC vote's keep-old selects
 GRAD_ACCUM = "gt.grad_accum"  # the microbatch loop's weighting and adds
+PARAM_GATHER = "gt.param_gather"  # ZeRO-2's compute-dtype copy of its dp-split parameters: cast, all-gather
 # the parts of a routed-experts block (ops/moe.py), inside gt.layers.r<k>
 MOE_ROUTER = "gt.moe.router"  # float32 logits, softmax, top-k, the auxiliary terms
 MOE_DISPATCH = "gt.moe.dispatch"  # sort the assignments by expert, gather the rows

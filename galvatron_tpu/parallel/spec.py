@@ -109,6 +109,46 @@ def vocab_embed_spec(ax: LayerAxes) -> P:
     return P(_ax(ax.tp), _ax(_zero3_axes(ax) or ()))
 
 
+# ------------------------------------------------- how the models read a leaf
+def cast_first_tree(param_specs, *, table_stored: bool):
+    """Tree of bools shaped like `param_specs`: True where a model reads the
+    leaf ONCE, in code GSPMD partitions, and only through
+    `.astype(compute_dtype)` that comes first, so that a copy rounded
+    beforehand gives the forward the same values and the backward the same
+    cotangent, summed over dp as before (runtime/model_api.compute_params:
+    what ZeRO-2 gathers over dp is then that copy, not the float32 leaf).
+
+    Every family's dense kernels and biases, position and type tables are
+    such leaves, and the token table read once as `wte.astype(dtype)[tokens]`.
+    Read in the dtype they are STORED in, and so False: a norm's scale and
+    bias (any dict that holds a "scale"); the relative-position tables of T5
+    and Swin; of a routed block (a dict that holds a "router") the router's
+    kernel (float32 logits) and the experts' kernels, which enter
+    `ops/moe.moe_ffn`'s manual region whole, so that its boundary sums their
+    cotangents over dp in the dtype they came in; and with `table_stored` the
+    token table: one that `vocab_parallel_lookup` takes into its manual
+    region (rows gathered from the stored shard, the gradient scatter-added
+    in the stored dtype), or a tied one, whose uses' cotangents are each
+    widened before they are summed.
+    tests/models/test_compute_copy.py holds this to every family's traced
+    loss."""
+
+    def walk(node, stored):
+        if isinstance(node, P):
+            return not stored
+        if isinstance(node, dict):
+            norm = "scale" in node
+            routed = ("router", "wi", "wo_mlp") if "router" in node else ()
+            return {
+                k: walk(v, stored or norm or k in routed or k.endswith("rel_bias")
+                        or (table_stored and k == "wte"))
+                for k, v in node.items()
+            }
+        return [walk(v, stored) for v in node]
+
+    return walk(param_specs, False)
+
+
 # ------------------------------------------------------------------- utilities
 def named(mesh: Mesh, spec: P) -> NamedSharding:
     return NamedSharding(mesh, spec)

@@ -68,23 +68,90 @@ class HybridParallelModel:
     # expert load (models/base.lm_loss_fn with_parts), which the step hands
     # back in `metrics` beside the loss; None (a dense config) leaves the
     # step as it is
+    cast_first: Optional[Params] = None  # tree of bools like param_specs:
+    # the leaves this family's loss reads only through a cast to the compute
+    # dtype that comes first (parallel/spec.cast_first_tree); None (a custom
+    # loss, whose reads nobody declared) gives no leaf a compute copy
     # memoized NamedSharding trees per batch signature (key set + ranks), so
     # the per-step shard_batch is ONE device_put of the whole tree with no
     # per-key NamedSharding construction on the hot path
     _batch_shardings: Dict[Tuple, Dict[str, NamedSharding]] = field(
         default_factory=dict, repr=False)
+    _copied: Optional[Params] = field(default=None, repr=False)  # memo of copied_leaves()
 
     @property
     def eval_loss(self) -> Callable:
-        """The loss to use for evaluation: forward-only when available."""
-        return self.eval_loss_fn or self.loss_fn
+        """The loss to use for evaluation: forward-only when available. It
+        takes the parameters as the state holds them and reads the copy the
+        train step reads."""
+        loss = self.eval_loss_fn or self.loss_fn
+        if not any(jax.tree.leaves(self.copied_leaves())):
+            return loss
+        return lambda params, batch: loss(self.compute_params(params), batch)
 
     # ------------------------------------------------------------------ params
     def shardings(self, specs=None):
+        """NamedShardings of a spec tree; of the parameters as the train
+        state STORES them (`state_specs`) when none is given."""
         return jax.tree.map(
-            lambda s: NamedSharding(self.mesh, s), specs if specs is not None else self.param_specs,
+            lambda s: NamedSharding(self.mesh, s), specs if specs is not None else self.state_specs(),
             is_leaf=_is_spec,
         )
+
+    def copied_leaves(self) -> Params:
+        """Tree of bools like param_specs: the leaves ZeRO-2 stores split
+        over dp, in the layout of Adam's moments and the accumulated
+        gradient, and of which the step gathers a compute-dtype copy once
+        (`compute_params`). Such a leaf has ZeRO axes that split it further
+        (not ddp, not dp = 1, not a ZeRO-3 leaf, which is dp-sharded
+        already), is wider than the compute dtype, and is read by the model
+        only through a cast to it (`cast_first`). Every other leaf is stored
+        as `param_specs` lays it out for the forward, and gathered after the
+        update in its own dtype. No leaf is copied where the model's code
+        sums a leaf's gradient itself, in the dtype the leaf comes in:
+        GPipe's scan (pp > 1) over the microbatches, the manual TP path's
+        regions over dp, the 1F1B engines (`grad_fn`) and the quantized grad
+        sync, whose regions are also written for the `param_specs` layout."""
+        if self._copied is None:
+            from galvatron_tpu.parallel import quant_collectives as QC
+
+            if (self.cast_first is None or self.hp.pp > 1 or self.grad_fn is not None
+                    or self.hp.tp_comm_mode != "gspmd" or QC.wants_quant_comm(self.hp)):
+                self._copied = jax.tree.map(lambda _: False, self.param_specs, is_leaf=_is_spec)
+            else:
+                narrow = jnp.dtype(self.cfg.compute_dtype).itemsize
+                self._copied = jax.tree.map(
+                    lambda spec, split, shp, cast: bool(
+                        cast and split != spec and shp.dtype.itemsize > narrow),
+                    self.param_specs, self.grad_accum_specs(), self.abstract_params(),
+                    self.cast_first, is_leaf=_is_spec,
+                )
+        return self._copied
+
+    def state_specs(self) -> Params:
+        """The layout the parameters are stored in: what the step takes and
+        returns, `init_params` produces and a checkpoint restores into."""
+        return jax.tree.map(
+            lambda spec, split, copied: split if copied else spec,
+            self.param_specs, self.grad_accum_specs(), self.copied_leaves(),
+            is_leaf=_is_spec,
+        )
+
+    def compute_params(self, params: Params) -> Params:
+        """What forward, recomputation and backward read: of a copied leaf
+        its value in the compute dtype, whole over dp as `param_specs` lays
+        it out (ZeRO-2's parameter all-gather, at the compute dtype's bytes);
+        every other leaf as it is. The model's own `.astype(compute_dtype)`
+        of a copied leaf is then the identity."""
+        copied = self.copied_leaves()
+        if not any(jax.tree.leaves(copied)):
+            return params
+        dtype = self.cfg.compute_dtype
+        with jax.named_scope(tracing.PARAM_GATHER):
+            return jax.tree.map(
+                lambda c, p, s: jax.lax.with_sharding_constraint(p.astype(dtype), s) if c else p,
+                copied, params, self.shardings(self.param_specs),
+            )
 
     def _init_fn(self, rng) -> Params:
         if self.init_fn is not None:
@@ -270,9 +337,17 @@ class HybridParallelModel:
 
         with_parts = self.loss_parts_fn is not None and self.grad_fn is None and quant_fn is None
 
+        def to_accum(g, p, s):
+            # a copied leaf's cotangent comes in the compute dtype: widened
+            # here as the cast's own transpose widened it, before any sum
+            return jax.lax.with_sharding_constraint(g.astype(p.dtype), s)
+
         def train_step(params, opt_state, batch, spike_cap=None):
             mb_loss = self.loss_parts_fn if with_parts else self.loss_fn
             parts = {}
+            # once a step, whatever `chunks` is; `params` stay what Adam,
+            # the guard and the digest read
+            read = self.compute_params(params)
 
             if self.grad_fn is not None:
                 # 1f1b pipeline: loss and grads come out of the hand-written
@@ -305,13 +380,11 @@ class HybridParallelModel:
                     lambda g, s: jax.lax.with_sharding_constraint(g, s), grads, accum_shardings
                 )
             elif chunks == 1:
-                loss, grads = jax.value_and_grad(mb_loss, has_aux=with_parts)(params, batch)
+                loss, grads = jax.value_and_grad(mb_loss, has_aux=with_parts)(read, batch)
                 if with_parts:
                     loss, parts = loss
                 with jax.named_scope(tracing.GRAD_ACCUM):
-                    grads = jax.tree.map(
-                        lambda g, s: jax.lax.with_sharding_constraint(g, s), grads, accum_shardings
-                    )
+                    grads = jax.tree.map(to_accum, grads, params, accum_shardings)
             else:
                 # microbatch loop: python-unrolled so XLA can overlap each
                 # microbatch's reduce-scatter with the next one's compute
@@ -334,7 +407,7 @@ class HybridParallelModel:
                 loss = 0.0
                 for c in range(chunks):
                     mb = jax.tree.map(lambda x: x[c], mbs)
-                    l, g = jax.value_and_grad(mb_loss, has_aux=with_parts)(params, mb)
+                    l, g = jax.value_and_grad(mb_loss, has_aux=with_parts)(read, mb)
                     w = weights[c]
                     if with_parts:
                         # the terms weighted as the loss is; the load of the
@@ -346,8 +419,10 @@ class HybridParallelModel:
                             for k, v in mp.items()}
                     with jax.named_scope(tracing.GRAD_ACCUM):
                         g = jax.tree.map(
-                            lambda gi, s: jax.lax.with_sharding_constraint(gi * w, s),
+                            lambda gi, p, s: jax.lax.with_sharding_constraint(
+                                gi.astype(p.dtype) * w, s),
                             g,
+                            params,
                             accum_shardings,
                         )
                         grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
@@ -394,11 +469,12 @@ class HybridParallelModel:
             return new_params, new_opt_state, metrics
 
         donate_argnums = (0, 1) if donate else ()
-        # The state comes back in the shardings it went in with. Left to
-        # GSPMD, an output may pick another layout (a replicated norm scale
-        # comes back dp-sharded): the next call then sees new input
-        # shardings, which an AOT executable refuses and plain jit answers
-        # with a silent second compile, and the donated buffer is not reused.
+        # The state comes back in the shardings it went in with (the
+        # parameters' `state_specs`). Left to GSPMD, an output may pick
+        # another layout (a replicated norm scale comes back dp-sharded): the
+        # next call then sees new input shardings, which an AOT executable
+        # refuses and plain jit answers with a silent second compile, and the
+        # donated buffer is not reused.
         out_shardings = (
             self.shardings(),
             self.opt_state_shardings(tx, self.abstract_params()),
@@ -496,4 +572,6 @@ def construct_hybrid_parallel_model(
         eval_loss_fn=None if loss_fn is not None else eval_loss,
         local_loss_fn=local_loss,
         loss_parts_fn=loss_parts,
+        cast_first=None if loss_fn is not None else S.cast_first_tree(
+            specs, table_stored=M.table_is_looked_up(vocab_axes(hp)) or cfg.tie_embeddings),
     )

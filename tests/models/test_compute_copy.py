@@ -1,0 +1,365 @@
+"""ZeRO-2's compute copy (runtime/model_api.compute_params): the float32
+parameters are stored split over dp, and forward, recomputation and backward
+read a copy in the compute dtype that the step gathers once.
+
+Held here, on four virtual CPU devices at tiny widths: the copy changes no
+value (against `ddp`, whose state is whole on every replica); which leaves
+take it, for every family in the zoo, against the family's own traced loss;
+that a layout with nothing to copy lowers to the text it lowered to before
+there was a copy; that a checkpoint crosses between the two layouts of the
+state."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.extend import core as jcore
+
+from galvatron_tpu.config.strategy import HybridParallelConfig
+from galvatron_tpu.models.bert import bert_config
+from galvatron_tpu.models.gpt import gpt_config
+from galvatron_tpu.models.llama import llama_config
+from galvatron_tpu.models.olmoe import olmoe_config
+from galvatron_tpu.models.swin import construct_swin_model, swin_config
+from galvatron_tpu.models.t5 import construct_t5_model, t5_config
+from galvatron_tpu.models.vit import vit_config
+from galvatron_tpu.parallel.mesh import vocab_axes
+from galvatron_tpu.runtime import checkpoint as ckpt
+from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
+from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
+
+pytestmark = [pytest.mark.parallel, pytest.mark.distributed]
+
+B, S, V = 8, 32, 256
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def devices4(devices8):
+    return devices8[:4]
+
+
+def tiny_llama(dtype=BF16, **kw):
+    return llama_config("llama-0.3b", num_layers=2, hidden_size=64, num_heads=4, ffn_hidden=128,
+                        vocab_size=V, max_seq_len=S, compute_dtype=dtype, **kw)
+
+
+def layout(dp_type, *, tp=2, pp=1, chunks=1, vocab_tp=None, layers=2, world=4, **kw):
+    return HybridParallelConfig.uniform(
+        world, layers, tp=tp, pp=pp, chunks=chunks, vocab_tp=tp if vocab_tp is None else vocab_tp,
+        default_dp_type=dp_type, global_bsz=B, mixed_precision="bf16", **kw)
+
+
+def lm_batch(seed=1):
+    tokens = jax.random.randint(jax.random.PRNGKey(seed), (B, S), 0, V)
+    return dict(tokens=tokens, positions=jnp.broadcast_to(jnp.arange(S), (B, S)),
+                labels=jnp.roll(tokens, -1, 1))
+
+
+def adam():
+    return get_optimizer_and_scheduler(OptimizerArgs(lr=3e-3, warmup_steps=1, total_steps=20))[0]
+
+
+def leaf_paths(tree):
+    return {jax.tree_util.keystr(p): x for p, x in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def train(cfg, hp, devices, steps=3):
+    """`steps` Adam steps from one seed: the model, the losses, the state."""
+    m = construct_hybrid_parallel_model(cfg, hp, devices)
+    params = m.init_params(jax.random.PRNGKey(0))
+    tx = adam()
+    opt = m.init_opt_state(tx, params)
+    step = m.make_train_step(tx)
+    batch = m.shard_batch(lm_batch())
+    losses = []
+    for _ in range(steps):
+        params, opt, mets = step(params, opt, batch)
+        losses.append(float(mets["loss"]))
+    assert step._cache_size() == 1  # the state goes out in the layout it came in
+    return m, losses, params
+
+
+# ------------------------------------------------------------- no value changes
+@pytest.mark.parametrize("dp_type,kw", [
+    ("zero2", dict()), ("zero2", dict(chunks=2)), ("zero2", dict(tp=1, pp=2, chunks=2)),
+    ("zero3", dict(tp=1))],
+    ids=["tp2dp2", "tp2dp2_chunks2", "gpipe_pp2dp2_chunks2", "dp4_zero3"])
+def test_zero_trains_as_ddp_does(dp_type, kw, devices4):
+    """bf16 compute, three steps, one seed: ZeRO with the copy against ddp.
+    Tolerance: tests/models/test_parallel_correctness.py's. GPipe (pp > 1)
+    takes no copy: its scan sums a stage's gradient over the microbatches in
+    the dtype the stage reads, float32 only while the cast is inside it.
+    Under `zero3` the layers' leaves are split over dp as ZeRO-3 has them
+    and the vocabulary layers' (ZeRO-2 there without `embed_sdp`) are copied."""
+    cfg = tiny_llama()
+    m, losses, params = train(cfg, layout(dp_type, **kw), devices4)
+    _, want_losses, want = train(cfg, layout("ddp", **kw), devices4)
+    assert losses[-1] < losses[0]
+    assert max(abs(a - b) for a, b in zip(losses, want_losses)) < 5e-5, (losses, want_losses)
+    worst = max(float(jnp.max(jnp.abs(np.asarray(a) - np.asarray(b))))
+                for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(want)))
+    # (ZeRO-3's table, split over dp on the vocabulary, ends 1.6e-4 from ddp's
+    # with or without the copy: another partitioning of its scatter-add)
+    assert worst < (5e-4 if dp_type == "zero3" else 5e-5), worst
+
+    # the state: every leaf float32, in `state_specs`; a copied leaf, and no
+    # other, leaves `param_specs` for a split over dp
+    copied, specs, state = (leaf_paths(t) for t in (m.copied_leaves(), m.param_specs, m.state_specs()))
+    dp = set(vocab_axes(m.hp).dp)
+    assert any(copied.values()) == (m.hp.pp == 1) and dp
+    for path, leaf in leaf_paths(params).items():
+        assert leaf.dtype == jnp.float32, path
+        assert leaf.sharding.is_equivalent_to(
+            jax.sharding.NamedSharding(m.mesh, state[path]), leaf.ndim), (path, leaf.sharding)
+        assert (state[path] != specs[path]) == copied[path], path
+        if copied[path]:
+            assert dp & {a for e in state[path] if e is not None
+                         for a in (e if isinstance(e, tuple) else (e,))}, (path, state[path])
+
+
+def test_eval_loss_reads_the_copy(devices4):
+    """Outside the step the loss meets the state as it is stored: the eval
+    loss makes the step's copy and gives the step's loss."""
+    cfg = tiny_llama()
+    m = construct_hybrid_parallel_model(cfg, layout("zero2"), devices4)
+    params = m.init_params(jax.random.PRNGKey(0))
+    batch = m.shard_batch(lm_batch())
+    tx = adam()
+    _, _, mets = m.make_train_step(tx, donate=False)(params, m.init_opt_state(tx, params), batch)
+    assert abs(float(jax.jit(m.eval_loss)(params, batch)) - float(mets["loss"])) < 5e-5
+    ddp = construct_hybrid_parallel_model(cfg, layout("ddp"), devices4)
+    assert ddp.eval_loss is ddp.loss_fn
+
+
+# ------------------------------------------- nothing to copy: the parent's step
+# sha256 of `make_train_step(adam).lower(...).as_text()` (StableHLO) at the
+# commit before the compute copy (9c3c713), for layouts in which no leaf is
+# copied: the step is to stay that text. A PR that changes the step on purpose
+# prints the new digests with `pytest -k lowers_to -s` and replaces these.
+PARENT_STEP_SHA256 = {
+    "one_chip": "cacbc2da26f1dcf9bab4cf9d0a1c13d0a528e14d7eed6571ea0bcf61e6508f3c",
+    "dp4_ddp": "35559b8d800daf5719d58c382997350c47f33a045bfed7573f71a2b659a753fb",
+    "tp2dp2_ddp_chunks2": "309dc4edbf6099d97c39b524ebf6ed89ad14664c8b341f609198f76b861a6be9",
+    "tp4_zero2_dp1": "ef7174dc141430af51927308ed519fc56efd55c73fbdfb633c5507e2291931ef",
+    "tp2dp2_zero2_fp32": "40743af26eb231f6c61ea94fb5a7abe8d5b6b33cb50d338646e5efe0d0a11ee2",
+    "gpipe_pp2dp2_zero2": "b504dc645c3423e80b8c25d48f41fd47f054d04a9ae1636ec289599e9afd0f18",
+    "tp2dp2_zero2_manual_tp": "4814a103f2c1126a3ba1e220997fda3bfe517afce5dda80f985958ed4107597b",
+}
+
+
+def uncopied_layout(name):
+    """(cfg, hp, device count) of a layout in which nothing is copied."""
+    if name == "one_chip":
+        return tiny_llama(), layout("zero2", tp=1, world=1), 1
+    if name == "dp4_ddp":
+        return tiny_llama(), layout("ddp", tp=1), 4
+    if name == "tp2dp2_ddp_chunks2":
+        return tiny_llama(), layout("ddp", chunks=2), 4
+    if name == "tp4_zero2_dp1":
+        return tiny_llama(), layout("zero2", tp=4), 4
+    if name == "tp2dp2_zero2_fp32":
+        return tiny_llama(jnp.float32), layout("zero2"), 4
+    if name == "gpipe_pp2dp2_zero2":
+        return tiny_llama(), layout("zero2", tp=1, pp=2, chunks=2), 4
+    if name == "tp2dp2_zero2_manual_tp":
+        return tiny_llama(), layout("zero2", tp_comm_mode="shard_map"), 4
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", list(PARENT_STEP_SHA256))
+def test_a_layout_with_nothing_to_copy_lowers_to_the_parents_step(name, devices8):
+    """dp = 1, ddp, float32 compute, GPipe and the manual TP path
+    give no leaf a copy, and the step lowers to the text it lowered to
+    before: what the one-chip cells of the benchmark run."""
+    cfg, hp, n = uncopied_layout(name)
+    m = construct_hybrid_parallel_model(cfg, hp, devices8[:n])
+    assert not any(jax.tree.leaves(m.copied_leaves()))
+    params = m.abstract_params()
+    assert jax.tree.map(lambda s: s.spec, m.shardings()) == m.param_specs
+    assert m.compute_params(params) is params
+    tx = adam()
+
+    def sds(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings)
+
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=jax.sharding.NamedSharding(
+        m.mesh, m._batch_spec_for(v))) for k, v in lm_batch().items()}
+    text = m.make_train_step(tx).lower(
+        sds(params, m.shardings()),
+        sds(jax.eval_shape(tx.init, params), m.opt_state_shardings(tx, params)), batch).as_text()
+    digest = hashlib.sha256(text.encode()).hexdigest()
+    print("%s: %s" % (name, digest))
+    assert digest == PARENT_STEP_SHA256[name]
+
+
+# ------------------------------------------------- a checkpoint between layouts
+def test_a_checkpoint_crosses_between_zero2_and_ddp(tmp_path, devices4):
+    """Saved under zero2 (float32 shards over dp), restored under ddp (whole
+    on every replica) and back: the same global float32 arrays."""
+    cfg = tiny_llama()
+    zero2, _, params = train(cfg, layout("zero2"), devices4, steps=1)
+    ddp = construct_hybrid_parallel_model(cfg, layout("ddp"), devices4)
+    want = jax.tree.map(np.asarray, params)
+
+    ckpt.save_checkpoint(str(tmp_path / "a"), 1, params, None, hp=zero2.hp)
+    as_ddp, _, _ = ckpt.load_checkpoint(str(tmp_path / "a"), 1, target=ddp, tx=None)
+    for leaf, sh in zip(jax.tree.leaves(as_ddp), jax.tree.leaves(ddp.shardings())):
+        assert leaf.sharding.is_equivalent_to(sh, leaf.ndim)
+    ckpt.save_checkpoint(str(tmp_path / "b"), 1, as_ddp, None, hp=ddp.hp)
+    back, _, _ = ckpt.load_checkpoint(str(tmp_path / "b"), 1, target=zero2, tx=None)
+    for got, a, b, sh in zip(jax.tree.leaves(back), jax.tree.leaves(as_ddp), jax.tree.leaves(want),
+                             jax.tree.leaves(zero2.shardings())):
+        assert got.sharding.is_equivalent_to(sh, got.ndim)
+        np.testing.assert_array_equal(np.asarray(got), b)
+        np.testing.assert_array_equal(np.asarray(a), b)
+
+
+# ---------------------------------------------- which leaves: every family's rule
+# primitives that hand a leaf on as it is (a view, a slice of a stacked leaf,
+# a constraint): the value that comes out is still the leaf's
+VIEWS = {"reshape", "transpose", "squeeze", "slice", "dynamic_slice", "broadcast_in_dim",
+         "expand_dims", "copy", "copy_p", "sharding_constraint", "rev", "optimization_barrier"}
+JAXPRS = (jcore.Jaxpr, jcore.ClosedJaxpr)
+
+
+def reads(jaxpr, tracked, wide, uses):
+    """Walk `jaxpr` and every jaxpr nested in it. `tracked` maps a variable
+    that holds parameter leaves (one, or the layers' stacked into one) to
+    their paths; `wide` collects the leaves a `convert_element_type` widens,
+    `uses` counts the places that read a leaf. A loop's constant and an
+    operand of a manual region count once more: the loop sums the leaf's
+    cotangents over its iterations, the region's boundary over the devices,
+    in the dtype the leaf has there, as those of two uses are summed."""
+    def count(paths, n=1):
+        for path in paths:
+            uses[path] = uses.get(path, 0) + n
+
+    for eqn in jaxpr.eqns:
+        held = {i: tracked[v] for i, v in enumerate(eqn.invars)
+                if not isinstance(v, jcore.Literal) and v in tracked}
+        if not held:
+            continue
+        name = eqn.primitive.name
+        if name in VIEWS:
+            if 0 in held:
+                tracked[eqn.outvars[0]] = held[0]
+            continue
+        if name == "concatenate":  # `jnp.stack` of a run's layers
+            tracked[eqn.outvars[0]] = frozenset().union(*held.values())
+            continue
+        inner = [v for v in eqn.params.values() if isinstance(v, JAXPRS)]
+        inner += [b for v in eqn.params.values() if isinstance(v, (tuple, list))
+                  for b in v if isinstance(b, JAXPRS)]
+        if not inner:
+            count(p for paths in held.values() for p in paths)
+            if name == "convert_element_type" and (
+                    jnp.dtype(eqn.params["new_dtype"]).itemsize > eqn.invars[0].aval.dtype.itemsize):
+                wide.update(held[0])
+            continue
+        consts = {"scan": eqn.params.get("num_consts", 0),
+                  "while": eqn.params.get("cond_nconsts", 0) + eqn.params.get("body_nconsts", 0),
+                  "shard_map": len(eqn.invars)}
+        count(p for i, paths in held.items() if i < consts.get(name, 0) for p in paths)
+        for sub in inner:
+            sub = sub.jaxpr if isinstance(sub, jcore.ClosedJaxpr) else sub
+            skip = len(eqn.invars) - len(sub.invars)  # cond's index, while's cond consts
+            assert skip == 0 or (skip > 0 and name in ("cond", "while")), (name, skip)
+            reads(sub, {sub.invars[i - skip]: p for i, p in held.items() if i >= skip}, wide, uses)
+    return wide, uses
+
+
+def traced_reads(model, params, batch):
+    closed = jax.make_jaxpr(model.loss_fn)(params, batch)
+    paths = list(leaf_paths(params))
+    tracked = {v: frozenset([p]) for v, p in zip(closed.jaxpr.invars, paths)}
+    return reads(closed.jaxpr, tracked, set(), {})
+
+
+def lm(cfg, hp, devices):
+    return construct_hybrid_parallel_model(cfg, hp, devices), lm_batch()
+
+
+def family(name, devices):
+    """(model, batch) of a family at tiny widths under dp with ZeRO-2."""
+    if name == "gpt_vocab_tp2":  # tied table, looked up under vocab_tp: stored twice over
+        return lm(gpt_config("gpt-0.3b", num_layers=2, hidden_size=64, num_heads=4, vocab_size=V,
+                             max_seq_len=S, compute_dtype=BF16), layout("zero2"), devices)
+    if name == "gpt":  # tied table, whole: `wte.astype(dtype)` twice, so stored
+        return lm(gpt_config("gpt-0.3b", num_layers=2, hidden_size=64, num_heads=4, vocab_size=V,
+                             max_seq_len=S, compute_dtype=BF16), layout("zero2", vocab_tp=1), devices)
+    if name == "llama_qwen":  # qkv bias, untied head, RMSNorm
+        return lm(tiny_llama(qkv_bias=True), layout("zero2"), devices)
+    if name == "llama_whole_table":  # untied, not split: the one copied table
+        return lm(tiny_llama(), layout("zero2", vocab_tp=1), devices)
+    if name == "bert":
+        cfg = bert_config("bert-base", num_layers=2, hidden_size=64, num_heads=4, ffn_hidden=128,
+                          vocab_size=V, max_seq_len=S, compute_dtype=BF16)
+        return lm(cfg, layout("zero2", vocab_tp=1), devices)
+    if name == "vit":
+        cfg = vit_config("vit-base", hidden_size=64, num_heads=4, num_layers=2, ffn_hidden=128,
+                         image_size=32, patch_size=8, num_classes=10, compute_dtype=BF16)
+        m = construct_hybrid_parallel_model(cfg, layout("zero2", vocab_tp=1), devices)
+        return m, dict(pixels=jnp.zeros((B, 32, 32, 3)), labels=jnp.zeros((B,), jnp.int32))
+    if name == "olmoe":  # experts have no tp form: dp 4
+        cfg = olmoe_config("olmoe-1b-7b", hidden_size=64, num_heads=4, num_kv_heads=4, head_dim=16,
+                           ffn_hidden=32, num_layers=2, vocab_size=V, max_seq_len=S,
+                           num_experts=8, experts_per_token=2, compute_dtype=BF16)
+        return lm(cfg, layout("zero2", tp=1), devices)
+    if name == "t5":
+        cfg = t5_config("t5-base", hidden_size=64, num_heads=4, head_dim=16, ffn_hidden=128,
+                        num_enc_layers=2, num_dec_layers=2, vocab_size=V, compute_dtype=BF16)
+        m = construct_t5_model(cfg, layout("zero2", layers=4))
+        tok = jnp.zeros((B, 16), jnp.int32)
+        return m, dict(tokens=tok, dec_tokens=tok, labels=tok)
+    if name == "swin":
+        cfg = swin_config("swin-tiny", embed_dim=16, depths=(2, 2), num_heads=(2, 4), image_size=32,
+                          patch_size=4, window=4, mlp_ratio=2.0, num_classes=10, compute_dtype=BF16)
+        m = construct_swin_model(cfg, layout("zero2", layers=4, vocab_tp=1))
+        return m, dict(pixels=jnp.zeros((B, 32, 32, 3)), labels=jnp.zeros((B,), jnp.int32))
+    raise KeyError(name)
+
+
+FAMILIES = ["gpt", "gpt_vocab_tp2", "llama_qwen", "llama_whole_table", "bert", "vit", "olmoe", "t5", "swin"]
+
+
+@pytest.mark.parametrize("name", FAMILIES)
+def test_the_leaf_rule_holds_for_the_family(name, devices4):
+    """`parallel/spec.cast_first_tree` against the family's own loss, traced
+    with EVERY leaf handed over in bf16. The leaves the rule keeps off the
+    copy must be exactly those the loss widens again (read in float32: norm
+    scales and biases, the router, relative-position tables), reads at more
+    than one place (a tied table: the cotangents of its uses are summed
+    wide), or hands to a manual region (the experts' kernels, the table
+    `vocab_parallel_lookup` gathers from: the region's boundary sums their
+    cotangents over dp in the stored dtype). So no copied leaf is ever
+    widened back to float32, and none has its gradient summed narrower."""
+    m, batch = family(name, devices4)
+    assert m.mesh.devices.size == 4
+    cast_first, copied = leaf_paths(m.cast_first), leaf_paths(m.copied_leaves())
+    assert any(copied.values()) and all(cast_first[p] for p, c in copied.items() if c)
+
+    narrow = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, BF16), m.abstract_params())
+    wide, uses = traced_reads(m, narrow, batch)
+    assert set(uses) == set(cast_first), set(cast_first) - set(uses)  # every leaf was followed
+    stored = {p for p, c in cast_first.items() if not c}
+    found = wide | {p for p, n in uses.items() if n > 1}
+    assert found == stored, (sorted(found - stored), sorted(stored - found))
+    assert any("scale" in p for p in wide)
+    table = "['embed']['wte']"
+    assert (table in stored) == (name in ("gpt", "gpt_vocab_tp2", "llama_qwen", "bert", "t5")), name
+    if name == "olmoe":
+        assert any("router" in p for p in wide)
+        assert all(uses[p] > 1 for p in uses if "['wi']" in p or "['wo_mlp']" in p)
+    if name in ("t5", "swin"):
+        assert any("rel_bias" in p for p in wide)
+
+    # and with the step's own copy in place the loss widens no copied leaf
+    as_read = jax.eval_shape(m.compute_params, m.abstract_params())
+    assert {leaf.dtype for p, leaf in leaf_paths(as_read).items() if copied[p]} == {jnp.dtype(BF16)}
+    wide, uses = traced_reads(m, as_read, batch)
+    assert not {p for p in wide if copied[p]}, wide
+    assert {uses[p] for p, c in copied.items() if c} == {1}

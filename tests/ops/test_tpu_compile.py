@@ -114,6 +114,10 @@ def test_auto_dispatch_reads_the_platform_off_the_mesh(v5e_2x2):
 
 def _compile_train_step(cfg, hp, devices, batch_rows):
     """The model's train step (Adam) compiled for `devices` from shapes alone."""
+    return _model_and_compiled_step(cfg, hp, devices, batch_rows)[1]
+
+
+def _model_and_compiled_step(cfg, hp, devices, batch_rows):
     from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
     from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
 
@@ -130,7 +134,7 @@ def _compile_train_step(cfg, hp, devices, batch_rows):
     batch = {k: jax.ShapeDtypeStruct(tok.shape, tok.dtype,
                                      sharding=NamedSharding(m.mesh, m._batch_spec_for(tok)))
              for k in ("tokens", "positions", "labels")}
-    return m.make_train_step(tx).lower(
+    return m, m.make_train_step(tx).lower(
         sds(params, m.shardings()), sds(opt, m.opt_state_shardings(tx, params)), batch,
     ).compile()
 
@@ -153,23 +157,28 @@ def test_one_chip_7b_width_step_fits_v5e_hbm(v5e_2x2):
 
 
 @pytest.fixture(scope="module")
-def tp2dp2_step_hlo(v5e_2x2):
-    """The train step of a narrow LLaMA lowered for the described 2x2 under
+def tp2dp2_step(v5e_2x2):
+    """The train step of a narrow LLaMA compiled for the described 2x2 under
     `--global_tp_deg 2 --vocab_tp 2 --default_dp_type zero2` (the layout of
-    the four-chip benchmark cell), by Megatron-SP on or off."""
+    the four-chip benchmark cell), by Megatron-SP on or off: (model, step)."""
     from galvatron_tpu.config.strategy import HybridParallelConfig
     from galvatron_tpu.models.llama import llama_config
 
-    def lowered(sequence_parallel: bool) -> str:
+    def compiled(sequence_parallel: bool):
         cfg = llama_config("llama-0.3b", num_layers=2, hidden_size=512, num_heads=4,
                            ffn_hidden=1024, vocab_size=32000, max_seq_len=256,
                            compute_dtype=jnp.bfloat16)
         hp = HybridParallelConfig.uniform(
             4, 2, tp=2, vocab_tp=2, default_dp_type="zero2", global_bsz=4,
             mixed_precision="bf16", sequence_parallel=sequence_parallel)
-        return _compile_train_step(cfg, hp, v5e_2x2, batch_rows=4).as_text()
+        return _model_and_compiled_step(cfg, hp, v5e_2x2, batch_rows=4)
 
-    return {sp: lowered(sp) for sp in (False, True)}
+    return {sp: compiled(sp) for sp in (False, True)}
+
+
+@pytest.fixture(scope="module")
+def tp2dp2_step_hlo(tp2dp2_step):
+    return {sp: step.as_text() for sp, (_, step) in tp2dp2_step.items()}
 
 
 @pytest.mark.parametrize("sequence_parallel,summed_by",
@@ -195,6 +204,78 @@ def test_vocab_split_embedding_is_a_lookup_on_v5e(tp2dp2_step_hlo, sequence_para
     forward_sums = [code for code, name in ops if "transpose(" not in name
                     and re.fullmatch(r"(all-reduce|reduce-scatter|all-reduce-scatter)(-start)?", code)]
     assert forward_sums == [summed_by], ops
+
+
+def _replica_groups(line):
+    """The replica groups of an HLO collective, as sets of device positions:
+    `{{0,2},{1,3}}`, or the iota form `[2,2]<=[2,2]T(1,0)` (reshape `arange`
+    to the dims after `<=`, transpose, reshape to groups x members)."""
+    iota = r"\[[\d,]+\]<=\[[\d,]+\](?:T\([\d,]+\))?"
+    text = re.search(r"replica_groups=(\{\{[\d,{}]*\}\}|%s)" % iota, line).group(1)
+    if text.startswith("{"):
+        return {frozenset(int(i) for i in g.split(",")) for g in re.findall(r"\{([\d,]+)\}", text)}
+    groups, dims, perm = re.fullmatch(r"\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?", text).groups()
+    ints = lambda t: [int(i) for i in t.split(",")]  # noqa: E731
+    ids = np.arange(np.prod(ints(dims))).reshape(ints(dims))
+    if perm:
+        ids = ids.transpose(ints(perm))
+    return {frozenset(row.tolist()) for row in ids.reshape(ints(groups))}
+
+
+@pytest.mark.parametrize("sequence_parallel", [False, True], ids=["tp2dp2", "tp2dp2_megatron_sp"])
+def test_zero2_gathers_a_bf16_copy_and_stores_float32_shards_on_v5e(tp2dp2_step, sequence_parallel):
+    """ZeRO-2's compute copy in the compiled step (runtime/model_api
+    compute_params). Over the dp groups, every bf16 all-gather carries
+    `gt.param_gather` and gathers a copied leaf, each copied leaf at least
+    once; the float32 all-gathers left are the `vocab_tp` table's (looked up
+    from the stored shard) and the norm scales', after the update and under no
+    scope; nothing under `gt.param_gather` is float32. The parameters go in
+    and come out in one layout, leaf by leaf: one compilation, donated
+    buffers reused."""
+    from galvatron_tpu.parallel.mesh import vocab_axes
+
+    model, step = tp2dp2_step[sequence_parallel]
+    vax = vocab_axes(model.hp)
+    positions = np.arange(model.mesh.devices.size).reshape(model.mesh.devices.shape)
+    dp_dims = [model.mesh.axis_names.index(a) for a in vax.dp]
+    dp_groups = {frozenset(row.tolist()) for row in
+                 np.moveaxis(positions, dp_dims, range(-len(dp_dims), 0)).reshape(
+                     -1, int(np.prod([positions.shape[d] for d in dp_dims])))}
+    assert dp_groups == {frozenset({0, 2}), frozenset({1, 3})}
+
+    gathered = {"bf16": [], "f32": []}  # (elements a chip, op_name) of the dp all-gathers
+    for line in step.as_text().splitlines():
+        out = re.search(r" = \(?(?:(?:bf16|f32)\[[\d,]*\]\S*(?:, )?)+\)? all-gather(?:-start)?\(", line)
+        if not out or _replica_groups(line) != dp_groups:
+            continue
+        shapes = re.findall(r"(bf16|f32)\[([\d,]*)\]", out.group(0))
+        name = re.search(r'op_name="([^"]*)"', line)
+        for dtype, dims in shapes[len(shapes) // 2 if "all-gather-start" in out.group(0) else 0:]:
+            gathered[dtype].append((int(np.prod([int(d) for d in dims.split(",")])),
+                                    name.group(1) if name else ""))
+
+    tp = int(np.prod([model.mesh.shape[a] for a in vax.tp]))
+    shapes = model.abstract_params()
+    sizes = {True: [], False: []}  # elements a chip of the leaves ZeRO-2 splits, copied or not
+    jax.tree.map(
+        lambda copied, spec, split, a: sizes[copied].append(
+            a.size // (tp if any(e is not None for e in spec) else 1)) if split != spec else None,
+        model.copied_leaves(), model.param_specs, model.grad_accum_specs(), shapes,
+        is_leaf=lambda x: isinstance(x, P))
+    assert sizes[True] and all("gt.param_gather" in name for _, name in gathered["bf16"])
+    assert sorted({n for n, _ in gathered["bf16"]}) == sorted(set(sizes[True]))
+    assert sum(n for n, _ in gathered["bf16"]) >= sum(sizes[True])
+    # float32: the table's rows a chip and the norm scales, under no scope
+    table = shapes["embed"]["wte"].size // tp
+    assert {n for n, _ in gathered["f32"]} == {table, model.cfg.hidden_size}
+    assert sorted(set(sizes[False])) == sorted({table, model.cfg.hidden_size})
+    assert not [name for _, name in gathered["f32"] if "gt." in name]
+
+    ins, outs = jax.tree.leaves(step.input_shardings[0][0]), jax.tree.leaves(step.output_shardings[0])
+    wanted = jax.tree.leaves(model.shardings())
+    assert len(ins) == len(outs) == len(wanted)
+    for a, i, o, w in zip(jax.tree.leaves(shapes), ins, outs, wanted):
+        assert i.is_equivalent_to(o, a.ndim) and i.is_equivalent_to(w, a.ndim), (a.shape, i, o, w)
 
 
 def test_chip_smoke_refuses_without_a_tpu():
