@@ -40,7 +40,6 @@ def _add_parallel_args(p: argparse.ArgumentParser):
     g = p.add_argument_group("parallel")
     g.add_argument("--pp_deg", type=int, default=1)
     g.add_argument("--global_tp_deg", type=int, default=1)
-    g.add_argument("--global_tp_consec", type=int, default=1)
     g.add_argument("--global_cp_deg", type=int, default=1)
     g.add_argument("--cp_mode", type=str, default="zigzag", choices=("ring", "zigzag"))
     g.add_argument("--sdp", type=int, default=0, help="1 => ZeRO-3 on every layer")
@@ -137,21 +136,10 @@ def _add_train_args(p: argparse.ArgumentParser):
                    "test-split eval)")
     # dispatch-ahead input pipeline / deferred host sync (runtime/prefetch.py
     # + the cli/train.py drain window): see README "Steady-state throughput"
-    g.add_argument("--no_async_loop", dest="async_loop", action="store_false",
-                   default=True,
-                   help="escape hatch: fully host-serialized training loop "
-                        "(no prefetch thread, metrics drained every step); "
-                        "losses are bit-identical either way")
     g.add_argument("--prefetch_batches", type=int, default=2,
                    help="batches the background prefetcher prepares and "
                         "device_puts ahead of the step consuming them "
                         "(0 => prepare batches on the critical path)")
-    g.add_argument("--donate_step", type=int, default=1,
-                   help="donate params/opt_state buffers to the jitted step "
-                        "(halves resident model state). XLA:CPU executes a "
-                        "call with donated in-flight inputs synchronously, "
-                        "so CPU host-overlap measurements set 0; TPU "
-                        "runtimes dispatch donated futures asynchronously")
     g.add_argument("--inflight_steps", type=int, default=2,
                    help="dispatched steps whose metrics may stay undrained, "
                         "so the host dispatches ahead of the device; anomaly "
@@ -179,17 +167,14 @@ def _add_train_args(p: argparse.ArgumentParser):
     o.add_argument("--trace_steps", type=str, default="3:5",
                    help="K:N (inclusive) iteration window for --xla_trace; "
                         "keep it a few steps wide — traces are large")
-    g.add_argument("--profile_forward", type=int, default=0)
     g.add_argument("--save_profiled_memory", type=int, default=0)
     g.add_argument("--profile_type", type=str, default="computation", choices=("computation", "memory"))
-    g.add_argument("--exit_after_profiling", type=int, default=1)
-    # checkpointing (reference runtime/arguments.py --distributed_checkpoint,
-    # --load_iteration; llama_hf/LlamaModel_checkpoint.py save/load)
+    # checkpointing (reference runtime/arguments.py --load_iteration;
+    # llama_hf/LlamaModel_checkpoint.py save/load)
     g.add_argument("--save", type=str, default=None, help="checkpoint output dir")
     g.add_argument("--load", type=str, default=None, help="checkpoint dir to resume from")
     g.add_argument("--load_iteration", type=int, default=None)
     g.add_argument("--save_interval", type=int, default=0, help="0 => only at end")
-    g.add_argument("--distributed_checkpoint", type=int, default=1)
     g.add_argument("--log_interval", type=int, default=1)
     # resilience (runtime/resilience.py): preemption-safe checkpointing,
     # anomaly guard, retry/retention around checkpoint and dataloader I/O
@@ -335,7 +320,6 @@ def _add_profile_args(p: argparse.ArgumentParser):
     g.add_argument("--layernum_min", type=int, default=1)
     g.add_argument("--layernum_max", type=int, default=2)
     g.add_argument("--max_tp_deg", type=int, default=8)
-    g.add_argument("--profile_dp_type", type=str, default="zero3")
     g.add_argument("--profile_remat", action="store_true", default=False,
                    help="also measure the per-remat-policy backward "
                         "recompute fraction (remat_recompute_frac in the "
@@ -375,8 +359,6 @@ def _add_search_args(p: argparse.ArgumentParser):
     g.add_argument("--settle_chunk", type=int, default=None)
     g.add_argument("--fine_grained_mode", type=int, default=1)
     g.add_argument("--use_pipeline_costmodel", type=int, default=0)
-    g.add_argument("--time_profile_mode", type=str, default="static", choices=("static", "batch", "sequence"))
-    g.add_argument("--memory_profile_mode", type=str, default="static", choices=("static", "batch", "sequence"))
     g.add_argument("--parallel_search", type=int, default=0)
     g.add_argument("--log_dir", type=str, default="logs")
     g.add_argument("--output_config_path", type=str, default=None)

@@ -476,10 +476,7 @@ def _train(args) -> dict:
                     "sdc_check=vote downgraded to digest: %s" % reason)
                 sdc_mode = "digest"
         fn = model.make_train_step(
-            tx, guard_anomalies=guard is not None,
-            donate=bool(getattr(args, "donate_step", 1)),
-            sdc_check=sdc_mode,
-        )
+            tx, guard_anomalies=guard is not None, sdc_check=sdc_mode)
         if hooks is not None and hooks.wrap_step_fn:
             fn = hooks.wrap_step_fn(fn)
         return fn
@@ -547,18 +544,13 @@ def _train(args) -> dict:
         return it_
 
     # --------------------------------------------------- dispatch-ahead knobs
-    # --no_async_loop is the escape hatch back to the fully host-serialized
-    # loop: no prefetch thread, no deferred metrics (every step drains
-    # immediately). With the async loop (default), a background thread runs
-    # batch prep + the sharded device_put for the next `prefetch_batches`
-    # batches, and the host keeps up to `inflight_steps` dispatched steps'
-    # metrics undrained so it can issue step N+1..N+W while N executes.
-    async_loop = bool(getattr(args, "async_loop", 1))
+    # A background thread runs batch prep + the sharded device_put for the
+    # next `prefetch_batches` batches, and the host keeps up to
+    # `inflight_steps` dispatched steps' metrics undrained so it can issue
+    # step N+1..N+W while N executes. Both 0 is the fully host-serialized
+    # loop: no prefetch thread, every step drained at once.
     prefetch_depth = max(int(getattr(args, "prefetch_batches", 2) or 0), 0)
     inflight_window = max(int(getattr(args, "inflight_steps", 2) or 0), 0)
-    if not async_loop:
-        prefetch_depth = 0
-        inflight_window = 0
 
     # -------------------------------------------------------- self-healing
     # Watchdog (runtime/health.py): a monitor thread armed around every

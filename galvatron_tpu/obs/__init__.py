@@ -7,7 +7,7 @@ is the measurement substrate that closes it at runtime:
   lifecycle events), buffered off the critical path like runtime/prefetch.py.
 - ``obs.flops``       — analytic model-FLOPs accounting + a per-device-kind
   peak-FLOPs registry, so every timing surface (profiler summary, telemetry,
-  bench sections) can report MFU and model-FLOPs/s.
+  ``cli report``) can report MFU and model-FLOPs/s.
 - ``obs.attribution`` — the predicted-vs-measured divergence table: the
   search engine's TimeCostModel/MemoryCostModel prediction per LayerRun next
   to measured steady-state step time and compiled-step memory.

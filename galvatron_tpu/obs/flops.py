@@ -13,26 +13,26 @@ Two validation hooks keep the analytic numbers honest:
   program where the backend reports flops (XLA:CPU does), and
   tests/obs/test_flops.py pins the analytic forward count against it on a
   tiny model;
-- every consumer (RuntimeProfiler.summary, per-step telemetry, bench
-  sections) reports model-FLOPs/s alongside MFU, so a wrong peak entry
+- every consumer (RuntimeProfiler.summary, the per-step telemetry, ``cli
+  report``) reports model-FLOPs/s alongside MFU, so a wrong peak entry
   shifts MFU but never the throughput trend.
 
-Import-light on purpose: math/os only at module scope — the bench
-orchestrator (which must never import jax) reads the registry directly; jax
-is touched only inside :func:`xla_flops`, which receives an already-built
-jax object.
+No jax at module scope: ``cli report`` reads a finished run's log through
+this module on a machine with no accelerator stack; jax is touched only
+inside :func:`xla_flops`, which receives an already-built jax object.
+
+``benchmarks/flops.py`` is a second copy of these counts BY DESIGN: the
+yardstick shares no code with the program it measures, so a change here
+cannot move a cell's ``mfu``. Do not merge the two.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, List, Optional
 
 # Peak dense matmul throughput per chip, FLOP/s, by device_kind prefix
-# (jax Device.device_kind). bf16 for the TPU generations; the "cpu" entry is
-# a NOMINAL single-host figure (a few GFLOP/s/core class) so CPU test runs
-# still produce a well-defined MFU — treat absolute CPU MFU as a label, not
-# a measurement. Extend via GALVATRON_PEAK_FLOPS (overrides everything).
+# (jax Device.device_kind), bf16. A kind the table does not hold has no peak
+# and a run on it reports no MFU: there is no row for a CPU.
 PEAK_FLOPS_BY_KIND: Dict[str, float] = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -42,20 +42,12 @@ PEAK_FLOPS_BY_KIND: Dict[str, float] = {
     "TPU v6 lite": 918e12,
     "TPU v6e": 918e12,
     "TPU7x": 2307e12,
-    "cpu": 5e10,
 }
 
 
 def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
     """Peak FLOP/s for a device kind (longest-prefix match, case-insensitive);
-    None when unknown. $GALVATRON_PEAK_FLOPS overrides the registry — the
-    escape hatch for new chips and for declaring an honest CPU peak."""
-    override = os.environ.get("GALVATRON_PEAK_FLOPS")
-    if override:
-        try:
-            return float(override)
-        except ValueError:
-            pass
+    None for a kind the table does not hold."""
     if not device_kind:
         return None
     kind = device_kind.lower()
@@ -175,15 +167,6 @@ def train_step_flops(cfg: Any, global_bsz: int) -> Optional[float]:
     if fwd is None:
         return None
     return fwd * (1.0 + BWD_FWD_RATIO)
-
-
-def train_flops_from_params(n_params: float, tokens: float, num_layers: int,
-                            seq_len: int, hidden: int, causal: bool = True) -> float:
-    """The 6*N*T parameter-count convention (+ attention term), for callers
-    that have a live param tree instead of a config (bench.py's layer-stack
-    sections)."""
-    attn = 12.0 * num_layers * seq_len * hidden * tokens * (0.5 if causal else 1.0)
-    return 6.0 * float(n_params) * float(tokens) + attn
 
 
 def run_fwd_flops(cfg: Any, hp: Any) -> Optional[List[float]]:

@@ -18,8 +18,8 @@ Two entry points:
   trailing window that meets the tolerance, which is the same index the
   batch scan would find on the series so far.
 
-stdlib-only: this module is imported by the report CLI and the bench
-orchestrator's children and must never pull in jax.
+stdlib-only: this module is imported by the report CLI and must never pull
+in jax.
 """
 
 from __future__ import annotations

@@ -28,8 +28,8 @@ for the online autotuner (ROADMAP item 5) or the MFU-regression gate
   library runtime code (lint rule GLC006): prints through an injectable
   ``print_fn`` AND records the same line as a ``log`` event.
 
-stdlib-only on purpose (no jax, no numpy): the bench orchestrator and the
-offline report CLI import this module without touching an accelerator stack.
+stdlib-only on purpose (no jax, no numpy): the offline report CLI imports
+this module without touching an accelerator stack.
 """
 
 from __future__ import annotations

@@ -66,10 +66,11 @@ def _flash_divisor(s: int, cap: int) -> int:
 
 
 def _flash_block_sizes(sq: int, sk: int):
-    """Measured on the bench chip (bench.py shapes, h=4096 s=2048 b=8):
-    1024-query x 512-key blocks beat the kernel's defaults by ~25% and XLA's
-    fused attention by ~20% at the layer level (5.46 vs 6.62 ms/layer/sample)
-    — one KV stripe stays resident in VMEM per query block."""
+    """1024-query x 512-key blocks (the largest divisors of the sequence up
+    to those): one KV stripe stays resident in VMEM per query block. What
+    they reach on a v5e is the benchmark's ``flash_roofline``: 40.7 % of the
+    kernels' compute roofline at 2048 tokens, 58.2 % at 8192 (ledger, PR 24:
+    qwen7-c1-s2k, qwen7-c1-s8k). No sweep of other sizes is on record."""
     from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
 
     bq = _flash_divisor(sq, 1024)
@@ -240,11 +241,10 @@ def core_attention(
             q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and q.shape[3] >= 128
             and (bias is None or seg_flash_ok) and splits
         )
-        # measured on the bench chip with the tuned 512x512 block sizes
-        # (_flash_block_sizes): flash beats XLA's fused attention at every
-        # profiled seq (512: 0.79 vs 1.20, 1024: 2.57 vs 2.78, 2048: 5.45 vs
-        # 6.62 ms/layer/sample at h=4096) — it never materialises the
-        # (b, nh, s, s) fp32 logits.
+        # on a TPU the kernel (blocks: _flash_block_sizes) wherever the
+        # shapes allow it: it never materialises the (b, nh, s, s) fp32
+        # logits. XLA's fused attention has not been timed against it on
+        # the chip; every benchmark cell runs the kernel.
         impl = "flash" if (on_tpu and ok_shapes) else "xla"
     if impl == "flash":
         if bias is not None and (not seg_flash_ok or not on_tpu):

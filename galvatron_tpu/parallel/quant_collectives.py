@@ -521,8 +521,8 @@ def make_quant_loss_and_grads(model) -> Callable:
 def bytes_on_wire_mb(hp: HybridParallelConfig, param_mb_per_layer: float) -> Dict[str, float]:
     """Estimated per-step gradient-sync traffic in MB (sum over layers of
     ring volume x wire bytes), fp32-grads baseline vs the strategy's comm
-    dtypes — the bench's bytes-on-wire estimate and the README's worked
-    numbers come from here."""
+    dtypes — the ``quant_comm`` event's wire estimate (cli/train.py)
+    and the README's worked numbers come from here."""
     out = {"fp32": 0.0, "configured": 0.0}
     for i, s in enumerate(hp.layers):
         d = hp.dp(i)

@@ -557,7 +557,7 @@ def measure_comm_hidden(
     ``comm_hidden_ms = max(serial - overlap, 0)`` is the comm the chunked
     schedule moved off the critical path. One small jitted program per
     (run, mode) on synthetic activations — a profiling helper (driver
-    --profile / bench), never on the training hot path."""
+    --profile / --telemetry), never on the training hot path."""
     import time as _time
 
     bsz = batch_size or hp.global_bsz

@@ -334,30 +334,35 @@ def classify_world(expected_ids: Sequence[int], live_devices: Sequence[Any]) -> 
     }
 
 
-def probe_collective(mesh, timeout_s: float = 5.0) -> Dict[str, Any]:
-    """A tiny jitted collective across every device of `mesh`, run under a
-    bounded timeout: one float per device, sharded over all mesh axes,
-    summed to a replicated scalar (an all-reduce on any multi-device mesh).
-    A healthy mesh answers in milliseconds; a wedged interconnect leaves
-    the worker blocked and the probe reports ``ok=False`` with
-    ``timed_out=True`` instead of hanging the driver."""
+def _sum_of_ones(mesh) -> float:
+    """One float per device, sharded over all mesh axes, summed to a
+    replicated scalar (an all-reduce on any multi-device mesh)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
 
+    x = jax.device_put(
+        np.ones((mesh.devices.size,), np.float32),
+        NamedSharding(mesh, PartitionSpec(tuple(mesh.shape.keys()))))
+    total = jax.jit(jnp.sum, out_shardings=NamedSharding(mesh, PartitionSpec()))(x)
+    return float(jax.device_get(total))
+
+
+def probe_collective(mesh, timeout_s: float = 5.0,
+                     collective: Callable[[Any], float] = _sum_of_ones) -> Dict[str, Any]:
+    """`collective` (a tiny jitted sum across every device of `mesh`, which
+    answers the device count; a test passes one that blocks) run under a
+    bounded timeout. A healthy mesh answers in milliseconds; a wedged
+    interconnect leaves the worker blocked and the probe reports ``ok=False``
+    with ``timed_out=True`` instead of hanging the driver."""
     result: Dict[str, Any] = {"ok": False, "timed_out": False, "elapsed_s": None}
     n = int(mesh.devices.size)
-    axes = tuple(mesh.shape.keys())
 
     def run():
         try:
             t0 = time.perf_counter()
-            x = jax.device_put(
-                np.ones((n,), np.float32), NamedSharding(mesh, PartitionSpec(axes)))
-            total = jax.jit(
-                jnp.sum, out_shardings=NamedSharding(mesh, PartitionSpec()))(x)
-            value = float(jax.device_get(total))
+            value = collective(mesh)
             result["elapsed_s"] = time.perf_counter() - t0
             result["ok"] = value == float(n)
             if not result["ok"]:

@@ -276,12 +276,8 @@ class HybridParallelModel:
         host cannot keep the old state around to retry with.
 
         `donate=False` keeps params/opt_state un-donated (two resident
-        copies of the model state). It exists for the dispatch-ahead loop on
-        XLA:CPU, whose runtime executes a call synchronously whenever a
-        donated input buffer is still being produced by the previous call —
-        donation there serializes host and device no matter how far ahead
-        the host dispatches. TPU runtimes handle donated futures
-        asynchronously, so production keeps the default.
+        copies of the model state), for a caller that runs the step again
+        on the same inputs (parity tests). The training loop always donates.
 
         `sdc_check` (runtime/sdc.py) adds silent-corruption side-outputs.
         "digest": metrics gain the layout-invariant integrity fold +

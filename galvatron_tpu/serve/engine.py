@@ -344,7 +344,7 @@ def synthetic_requests(
     max_new_tokens: int = 8,
 ) -> List[Request]:
     """Poisson arrivals (`rate_rps` > 0; 0 = a t=0 backlog) with uniform
-    prompt lengths — the synthetic open-loop load for cli/serve and bench."""
+    prompt lengths — the synthetic open-loop load for cli/serve."""
     rnd = random.Random(seed)
     t = 0.0
     out = []

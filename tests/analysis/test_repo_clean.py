@@ -82,3 +82,25 @@ def test_lint_sh_json_contract():
     assert proc.returncode == 0, proc.stdout + proc.stderr
     payload = json.loads(proc.stdout)
     assert payload["summary"]["errors"] == 0, proc.stdout
+
+
+def test_no_mention_of_what_was_removed():
+    """The benchmark under benchmarks/ and the ledger are the one way this
+    repo measures (PR 29). The CPU measuring stack, the options only it
+    needed and the environment's peak override are gone, and nothing that
+    describes or drives the program names them any more; the records
+    (CHANGES.md, PERF.md, ROADMAP.md) may, as history."""
+    gone = ("bench.py", "_bench_util", "--no_async_loop", "--donate_step",
+            "GALVATRON_PEAK_FLOPS")
+    files = [os.path.join(REPO, "README.md"), os.path.join(REPO, "COVERAGE.md")]
+    for top in (PACKAGE, os.path.join(REPO, "scripts"), os.path.join(REPO, ".claude")):
+        for ext in ("py", "md", "sh"):
+            files += glob.glob(os.path.join(top, "**", "*." + ext), recursive=True)
+    assert len(files) > 50, "the scan found too little to mean anything"
+    hits = []
+    for path in files:
+        with open(path, errors="replace") as f:
+            for n, line in enumerate(f, 1):
+                hits += ["%s:%d: %s" % (os.path.relpath(path, REPO), n, word)
+                         for word in gone if word in line]
+    assert hits == [], "\n".join(hits)

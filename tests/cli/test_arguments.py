@@ -1,8 +1,13 @@
 """Argument-system tests (reference arg plumbing, core/arguments.py:8-30)."""
 
+import glob
+import os
+import re
+
 import pytest
 
 from galvatron_tpu.cli.arguments import (
+    MODES,
     build_parser,
     hp_config_from_args,
     initialize_galvatron,
@@ -213,3 +218,30 @@ def test_persistent_compile_cache_dir_contract(tmp_path, monkeypatch, from_env):
     finally:
         jax.config.update("jax_compilation_cache_dir", old_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
+
+@pytest.fixture(scope="module")
+def package_source():
+    """Every line of the package, less what arguments.py spends on declaring
+    an option: its ``--flag`` strings (in help texts too) and ``dest=``."""
+    from galvatron_tpu.cli import arguments
+
+    root = os.path.dirname(os.path.dirname(arguments.__file__))
+    chunks = []
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            text = f.read()
+        if os.path.samefile(path, arguments.__file__):
+            text = re.sub(r"--\w+|dest=\"\w+\"", "", text)
+        chunks.append(text)
+    return "\n".join(chunks)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_every_option_is_read(mode, package_source):
+    """An option that is accepted and read by nothing is worse than an error:
+    the user believes they chose something. Every ``dest`` a mode's parser
+    declares is named somewhere in the package beside its declaration."""
+    dests = {a.dest for a in build_parser(mode)._actions if a.dest != "help"}
+    unread = sorted(d for d in dests
+                    if not re.search(r"\b%s\b" % re.escape(d), package_source))
+    assert unread == [], "parsed and read by nothing: %s" % unread

@@ -1,5 +1,5 @@
 """Dispatch-ahead training loop (ISSUE 4): bitwise loss parity against the
-synchronous escape hatch, deferred anomaly-guard decisions, forced drains at
+host-serialised loop (``--prefetch_batches 0 --inflight_steps 0``), deferred anomaly-guard decisions, forced drains at
 save/eval/preemption boundaries, and the overlap metrics in the profiler
 summary. The keep/skip select lives inside the jitted step, so the two loops
 run the identical device program — only host bookkeeping timing differs,
@@ -29,6 +29,11 @@ RES_TINY = [
 ]
 
 
+# the host-serialised loop, the reference of every parity case below: no
+# prefetch thread, every step's metrics drained before the next is dispatched
+SYNC = ["--prefetch_batches", "0", "--inflight_steps", "0"]
+
+
 def run(extra, hooks=None, base=TINY):
     args = initialize_galvatron(mode="train_dist", argv=base + extra)
     if hooks is not None:
@@ -38,11 +43,11 @@ def run(extra, hooks=None, base=TINY):
 
 def test_dispatch_ahead_matches_sync_bitwise(devices8):
     """Same seed => the async loop (prefetch + deferred drains, the default)
-    and --no_async_loop produce bit-identical train/valid/test losses,
+    and the host-serialised loop produce bit-identical train/valid/test losses,
     including across the forced drain at every eval boundary."""
     common = ["--train_iters", "6", "--eval_interval", "3", "--eval_iters", "2"]
     a = run(common)
-    b = run(common + ["--no_async_loop"])
+    b = run(common + SYNC)
     np.testing.assert_array_equal(a["losses"], b["losses"])
     assert a["valid_losses"] == b["valid_losses"]
     assert a["test_loss"] == b["test_loss"]
@@ -59,7 +64,7 @@ def test_dispatch_ahead_parity_chunks_and_guard(devices8):
     argument; with spike detection off the cap is +inf in both modes)."""
     common = ["--train_iters", "4", "--chunks", "2", "--anomaly_guard", "1"]
     a = run(common)
-    b = run(common + ["--no_async_loop"])
+    b = run(common + SYNC)
     np.testing.assert_array_equal(a["losses"], b["losses"])
 
 
@@ -70,7 +75,7 @@ def test_deferred_guard_decisions_match_sync(devices8):
     common = ["--train_iters", "4"]
     hooks = fi.nan_batch_hooks([1])
     a = run(common, hooks=fi.nan_batch_hooks([1]), base=RES_TINY)
-    b = run(common + ["--no_async_loop"], hooks=hooks, base=RES_TINY)
+    b = run(common + SYNC, hooks=hooks, base=RES_TINY)
     for s in (a, b):
         assert s["resilience"]["anomalies_skipped"] == 1
         assert s["resilience"]["rollbacks"] == 0
@@ -94,10 +99,11 @@ def test_forced_drain_before_emergency_save(devices8, tmp_path):
 
 def test_prefetch_and_window_knobs(devices8):
     """--prefetch_batches 0 (no thread) and --inflight_steps 0 (drain every
-    step) are independently valid points of the knob space."""
+    step) are independently valid points of the knob space; both together
+    are the host-serialised loop."""
     a = run(["--train_iters", "3", "--prefetch_batches", "0"])
     b = run(["--train_iters", "3", "--inflight_steps", "0"])
-    c = run(["--train_iters", "3", "--no_async_loop"])
+    c = run(["--train_iters", "3"] + SYNC)
     np.testing.assert_array_equal(a["losses"], c["losses"])
     np.testing.assert_array_equal(b["losses"], c["losses"])
 
@@ -109,7 +115,7 @@ def test_deferred_rollback_matches_sync(devices8, tmp_path):
     discarded with the abandoned trajectory, and the replayed stream
     reproduces the synchronous loop's decisions and losses exactly."""
     results = {}
-    for mode, extra in (("ahead", []), ("sync", ["--no_async_loop"])):
+    for mode, extra in (("ahead", []), ("sync", SYNC)):
         d = str(tmp_path / ("ck_" + mode))
         results[mode] = run(
             ["--train_iters", "7", "--save", d, "--save_interval", "2",
@@ -130,9 +136,7 @@ def test_dispatch_ahead_overlaps_input_latency(devices8):
     """The throughput property the loop exists for: with per-batch input
     latency (emulated I/O wait through the FaultHooks seam) the dispatch-
     ahead loop hides compute under the wait — strictly less host-blocked
-    time and higher steps/s than the synchronous loop. Donation is disabled
-    because XLA:CPU executes donated-in-flight calls synchronously (see
-    model_api.make_train_step)."""
+    time and higher steps/s than the host-serialised loop."""
     import time
 
     from galvatron_tpu.runtime.resilience import FaultHooks
@@ -145,14 +149,13 @@ def test_dispatch_ahead_overlaps_input_latency(devices8):
 
         return FaultHooks(wrap_data_iter=wrap)
 
-    common = ["--train_iters", "8", "--donate_step", "0", "--world_size", "1",
-              "--log_interval", "1000"]
+    common = ["--train_iters", "8", "--world_size", "1", "--log_interval", "1000"]
     # calibrate: the emulated input wait must dominate the (machine- and
     # flag-dependent) step time for the overlap to be unambiguous
-    probe = run(common + ["--no_async_loop"], base=RES_TINY)
+    probe = run(common + SYNC, base=RES_TINY)
     latency = max(3.0 * probe["steady_step_ms"], 50.0)
     a = run(common, hooks=latency_hooks(latency), base=RES_TINY)
-    b = run(common + ["--no_async_loop"], hooks=latency_hooks(latency),
+    b = run(common + SYNC, hooks=latency_hooks(latency),
             base=RES_TINY)
     np.testing.assert_array_equal(a["losses"], b["losses"])
     # sync blocks ~a full step per iteration; dispatch-ahead hides the step

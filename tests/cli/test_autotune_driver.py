@@ -8,9 +8,9 @@ One training process per leg; the apply leg is module-scoped and shared.
 Layers are unrolled (--no_scan_layers): under scan, XLA:CPU prices the
 non-checkpointed path's stacked activation storage above the recompute it
 saves, so the cost model's preferred winner would not also be the
-wall-clock winner (same reasoning as bench.py's autotune section; steps/s
-itself is asserted there under the regression gate, not here — single-host
-medians are too noisy for a hard inequality in CI)."""
+wall-clock winner. steps/s itself is not asserted: single-host medians are
+too noisy for a hard inequality in CI, and a CPU rate says nothing of the
+chip's."""
 
 import json
 import math

@@ -19,14 +19,28 @@ def tiny_cfg(**kw):
     return M.TransformerConfig(**d)
 
 
-def test_peak_registry_prefix_match_and_override(monkeypatch):
+def test_peak_registry_prefix_match():
     assert F.peak_flops_for("TPU v5 lite") == 197e12
     assert F.peak_flops_for("TPU v5p chip") == 459e12  # longest prefix wins
-    assert F.peak_flops_for("cpu") == F.PEAK_FLOPS_BY_KIND["cpu"]
+    assert F.peak_flops_for("tpu V5 LITE") == 197e12  # case-insensitive
     assert F.peak_flops_for("quantum-npu-9000") is None
     assert F.peak_flops_for(None) is None
+
+
+def test_no_peak_off_the_table(monkeypatch, devices8):
+    """A device the table does not hold has no peak, whatever the environment
+    says, and a run on it reports no MFU: a CPU number is never written under
+    the name of a device metric."""
+    from tests.cli.test_async_loop import RES_TINY, run
+
     monkeypatch.setenv("GALVATRON_PEAK_FLOPS", "123e9")
-    assert F.peak_flops_for("anything") == 123e9
+    assert F.peak_flops_for("cpu") is None
+    assert F.peak_flops_for("anything") is None
+    assert F.peak_flops_for("TPU v5 lite") == 197e12
+    summary = run(["--train_iters", "3"], base=RES_TINY)
+    assert "mfu" not in summary
+    # the throughput that needs no peak is still there
+    assert summary["model_flops_per_s"] > 0
 
 
 def test_layer_flops_scaling_laws():

@@ -1,7 +1,7 @@
 """AOT-compile the TPU (lax.cond) branch path of all three 1F1B engines
 against an abstract 8-device TPU topology and run the divergent-collective
 guard on the RESULTING HLO (VERDICT r3 item 2: until round 4 every CPU test,
-dryrun, and single-chip bench took the masked path, so the branch path a real
+dryrun, and single-chip run took the masked path, so the branch path a real
 multi-chip TPU run takes had never even been compiled).
 
 The lowering targets `jax.experimental.topologies.get_topology_desc`'s

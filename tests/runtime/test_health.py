@@ -177,14 +177,25 @@ def test_probe_collective_on_live_mesh(devices8):
 
 
 def test_probe_collective_zero_timeout_reports_timed_out(devices8):
-    """timeout 0 cannot wait for even the fastest collective: the probe
-    must report a (non-hanging) timeout instead of blocking the caller."""
+    """A collective that does not answer within the timeout: the probe
+    must report a (non-hanging) timeout instead of blocking the caller. The
+    collective blocks on an event until the assertion is made, so no warm
+    jit can beat a zero-second join."""
     import numpy as np
     from jax.sharding import Mesh
 
     mesh = Mesh(np.array(devices8[:2]).reshape(2), ("dp",))
-    out = H.probe_collective(mesh, timeout_s=0.0)
-    assert out["timed_out"] is True and out["ok"] is False
+    release = threading.Event()
+
+    def wedged(mesh):
+        release.wait(timeout=60.0)
+        return float(mesh.devices.size)
+
+    try:
+        out = H.probe_collective(mesh, timeout_s=0.0, collective=wedged)
+        assert out["timed_out"] is True and out["ok"] is False
+    finally:
+        release.set()
 
 
 def test_mesh_monitor_interval_and_simulated_device_loss(devices8):
