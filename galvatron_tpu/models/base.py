@@ -635,14 +635,56 @@ def embed_patches(p_embed: Params, pixels: jax.Array, cfg: TransformerConfig) ->
     return x
 
 
+def _times_kernel(x: jax.Array, kernel: jax.Array, tied: bool) -> jax.Array:
+    return x @ (kernel.T if tied else kernel)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _head_matmul(x: jax.Array, kernel: jax.Array, tied: bool) -> jax.Array:
+    """`x @ kernel` (`x @ kernel.T` for the tied table) for the head's kernel
+    in the compute dtype. Evaluated, it is just that. Differentiated, its two
+    barriers keep apart what the TPU compiler otherwise fuses into the
+    backward's matmuls, to their cost (PERF.md, PR 30):
+
+    - a kernel cast from a wider parameter is made once and forward, input
+      gradient and kernel gradient read that one array; folded into each
+      matmul, the (hidden, V) cast is redone for every tile of tokens (one
+      that arrives in the compute dtype has no cast, and the barrier holds
+      the array as it came);
+    - the input gradient is written before the final norm's backward reads
+      it; as the matmul's epilogue a LayerNorm's reductions held it at 79 %
+      of the MXU."""
+    return _times_kernel(x, kernel, tied)
+
+
+def _head_matmul_fwd(x, kernel, tied):
+    kernel = jax.lax.optimization_barrier(kernel)
+    return _times_kernel(x, kernel, tied), (x, kernel)
+
+
+def _head_matmul_bwd(tied, res, g):
+    x, kernel = res
+    lead = tuple(range(x.ndim - 1))
+    dx = jax.lax.optimization_barrier(_times_kernel(g, kernel, not tied))
+    dkernel = jax.lax.dot_general(*((g, x) if tied else (x, g)), ((lead, lead), ((), ())))
+    return dx, dkernel
+
+
+_head_matmul.defvjp(_head_matmul_fwd, _head_matmul_bwd)
+
+
+def head_logits(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """`x` times the vocabulary kernel (`lm_head.kernel`, or the tied table
+    transposed) in the compute dtype."""
+    tied = cfg.tie_embeddings
+    stored = params["embed"]["wte"] if tied else params["lm_head"]["kernel"]
+    return _head_matmul(x, stored.astype(cfg.compute_dtype), tied)
+
+
 def lm_logits(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
     if cfg.pre_norm:
         x = _norm(x, params["final_norm"], cfg)
-    if cfg.tie_embeddings:
-        kernel = params["embed"]["wte"].astype(cfg.compute_dtype).T
-    else:
-        kernel = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
-    return x @ kernel
+    return head_logits(params, x, cfg)
 
 
 def model_head(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
@@ -657,17 +699,53 @@ def model_head(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Arra
         y = _dense(x, hp_["transform"], cfg.compute_dtype)
         y = jax.nn.gelu(y, approximate=False)
         y = _norm(y, hp_["norm"], cfg)
-        if cfg.tie_embeddings:
-            kernel = params["embed"]["wte"].astype(cfg.compute_dtype).T
-        else:
-            kernel = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
-        return y @ kernel + hp_["bias"].astype(cfg.compute_dtype)
+        return head_logits(params, y, cfg) + hp_["bias"].astype(cfg.compute_dtype)
     if cfg.head_type == "classification":
         if cfg.pre_norm:
             x = _norm(x, params["final_norm"], cfg)
         pooled = x[:, 0] if cfg.pool_type == "cls" else jnp.mean(x, axis=1)
         return _dense(pooled, params["head"], cfg.compute_dtype)
     raise ValueError(cfg.head_type)
+
+
+def _label_mask(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    """Where a row's label sits. A compare against an iota and never a gather,
+    so each vocabulary shard answers for its own columns and XLA inserts the
+    psum of what is reduced over it."""
+    vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    return vocab_iota == labels[..., None]
+
+
+@jax.custom_vjp
+def _token_nll(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    """Float32 cross entropy a token, `lse(logits) - logits[label]`, with a
+    written backward: `(softmax - onehot) * g`, formed once from the logits as
+    they came and the row's maximum and sum, rounded once to the logits' dtype.
+    Autodiff of the forward also differentiates the row maximum, whose
+    gradient is zero by algebra, and pays a second sweep of the logits with
+    its own `exp` to find that out."""
+    return _token_nll_fwd(logits, labels)[0]
+
+
+def _token_nll_fwd(logits, labels):
+    # one maximum, then one sweep for the sum of exponentials and the label's logit
+    logits32 = logits.astype(jnp.float32)
+    m = jnp.max(logits32, axis=-1, keepdims=True)
+    s = jnp.sum(jnp.exp(logits32 - m), axis=-1)
+    label_logit = jnp.sum(jnp.where(_label_mask(logits, labels), logits32, 0.0), axis=-1)
+    return jnp.log(s) + m[..., 0] - label_logit, (logits, m, s, labels)
+
+
+def _token_nll_bwd(res, g):
+    # term by term what autodiff forms with the maximum held constant: a
+    # column that is not its row's maximum gets autodiff's own float
+    logits, m, s, labels = res
+    p_g = jnp.exp(logits.astype(jnp.float32) - m) * (g / s)[..., None]
+    dlogits = p_g - jnp.where(_label_mask(logits, labels), g[..., None], 0.0)
+    return dlogits.astype(logits.dtype), None
+
+
+_token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
 
 
 def vocab_parallel_cross_entropy(logits: jax.Array, labels: jax.Array,
@@ -679,15 +757,7 @@ def vocab_parallel_cross_entropy(logits: jax.Array, labels: jax.Array,
     and XLA inserts the psum — the compiler-derived form of the reference's
     vocab_parallel_cross_entropy (site_package/megatron/core/tensor_parallel/
     cross_entropy.py:174-219)."""
-    v = logits.shape[-1]
-    logits32 = logits.astype(jnp.float32)
-    m = jnp.max(logits32, axis=-1, keepdims=True)
-    lse = jnp.log(jnp.sum(jnp.exp(logits32 - m), axis=-1)) + m[..., 0]
-    vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
-    label_logit = jnp.sum(
-        jnp.where(vocab_iota == labels[..., None], logits32, 0.0), axis=-1
-    )
-    losses = lse - label_logit
+    losses = _token_nll(logits, labels)
     if loss_mask is None:
         return jnp.mean(losses)
     loss_mask = loss_mask.astype(jnp.float32)

@@ -135,18 +135,21 @@ def test_eval_loss_reads_the_copy(devices4):
 
 
 # ------------------------------------------- nothing to copy: the parent's step
-# sha256 of `make_train_step(adam).lower(...).as_text()` (StableHLO) at the
-# commit before the compute copy (9c3c713), for layouts in which no leaf is
-# copied: the step is to stay that text. A PR that changes the step on purpose
-# prints the new digests with `pytest -k lowers_to -s` and replaces these.
+# sha256 of `make_train_step(adam).lower(...).as_text()` (StableHLO) for
+# layouts in which no leaf is copied: the step is to stay that text. The
+# parent is PR 30's step (the head and its loss under their written backward,
+# models/base._head_matmul and _token_nll; before it, from the commit before
+# the compute copy, 9c3c713, to PR 29, the text was one other). A PR that
+# changes the step on purpose prints the new digests with
+# `pytest -k lowers_to -s` and replaces these.
 PARENT_STEP_SHA256 = {
-    "one_chip": "cacbc2da26f1dcf9bab4cf9d0a1c13d0a528e14d7eed6571ea0bcf61e6508f3c",
-    "dp4_ddp": "35559b8d800daf5719d58c382997350c47f33a045bfed7573f71a2b659a753fb",
-    "tp2dp2_ddp_chunks2": "309dc4edbf6099d97c39b524ebf6ed89ad14664c8b341f609198f76b861a6be9",
-    "tp4_zero2_dp1": "ef7174dc141430af51927308ed519fc56efd55c73fbdfb633c5507e2291931ef",
-    "tp2dp2_zero2_fp32": "40743af26eb231f6c61ea94fb5a7abe8d5b6b33cb50d338646e5efe0d0a11ee2",
-    "gpipe_pp2dp2_zero2": "b504dc645c3423e80b8c25d48f41fd47f054d04a9ae1636ec289599e9afd0f18",
-    "tp2dp2_zero2_manual_tp": "4814a103f2c1126a3ba1e220997fda3bfe517afce5dda80f985958ed4107597b",
+    "one_chip": "333bcf1c3f20e1196bff40153aefb0add16e16644225a23e4ed80dd85ad1b4d6",
+    "dp4_ddp": "70995ecd487ae2884bbf396bf49771cba3a7ac9bbcefc2b5870fa7933dd6a908",
+    "tp2dp2_ddp_chunks2": "5b6cd9e54b8928e4dd99a65f9ba070bf576b707a8f9da29745c241ae8be70815",
+    "tp4_zero2_dp1": "3c537facf5b745a4add1697b6547b8e81cd6332accea54f6bac0b3945fd14a09",
+    "tp2dp2_zero2_fp32": "43cd4a705cf689de5ffd06efef9ee69e6eac2ffded20dee61b23cbd98874b19e",
+    "gpipe_pp2dp2_zero2": "59b8a51df20da0d2597b18a0c2b563aadb02a74d9a6f42afbbe132a129d0d597",
+    "tp2dp2_zero2_manual_tp": "e72629bd031daad6af1e4a377f78d497457a3631b97ed8e6ae58b28847749727",
 }
 
 

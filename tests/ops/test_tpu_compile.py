@@ -278,6 +278,56 @@ def test_zero2_gathers_a_bf16_copy_and_stores_float32_shards_on_v5e(tp2dp2_step,
         assert i.is_equivalent_to(o, a.ndim) and i.is_equivalent_to(w, a.ndim), (a.shape, i, o, w)
 
 
+@pytest.fixture(scope="module")
+def one_chip_head_ops(v5e_2x2):
+    """The operations under `gt.head_loss` of a narrow LLaMA's train step
+    (float32 parameters, bf16 compute, an untied (512, 32000) head) compiled
+    for one described chip, as `scripts/head_fusions.py` lists them."""
+    import importlib.util
+
+    from galvatron_tpu.config.strategy import HybridParallelConfig
+    from galvatron_tpu.models.llama import llama_config
+
+    spec = importlib.util.spec_from_file_location(
+        "head_fusions", os.path.join(REPO, "scripts", "head_fusions.py"))
+    head_fusions = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(head_fusions)
+    cfg = llama_config("llama-0.3b", num_layers=2, hidden_size=512, num_heads=4, ffn_hidden=1024,
+                       vocab_size=32000, max_seq_len=256, compute_dtype=jnp.bfloat16)
+    hp = HybridParallelConfig.uniform(1, 2, global_bsz=4, mixed_precision="bf16")
+    return head_fusions.head_ops(_compile_train_step(cfg, hp, v5e_2x2[:1], batch_rows=4).as_text())
+
+
+def test_the_heads_matmuls_read_one_bf16_kernel_on_v5e(one_chip_head_ops):
+    """models/base._head_matmul in the compiled step: one operation under
+    `gt.head_loss` reads the float32 head kernel, the cast, which no matmul
+    holds; forward, input gradient and kernel gradient read or write the bf16
+    (hidden, V) copy; and the input gradient's fusion writes the input
+    gradient alone, the final norm's backward reading it afterwards. Without
+    the rule the compiler folds the cast into each matmul's fusion, redoing
+    it a tile of tokens, and the norm's reductions into the input gradient's
+    (PERF.md, PR 30)."""
+    wide, narrow = "f32[512,32000]", "bf16[512,32000]"
+    readers = [o for o in one_chip_head_ops if wide in o["operands"]]
+    assert len(readers) == 1 and not readers[0]["matmul"] and readers[0]["out"] == [narrow], readers
+    matmuls = [o for o in one_chip_head_ops if o["matmul"]]
+    assert [o["backward"] for o in matmuls] == [False, True, True], matmuls
+    assert all(narrow in o["operands"] + o["out"] for o in matmuls), matmuls
+    assert [o["out"] for o in matmuls if o["backward"] and narrow in o["operands"]] == [["bf16[4,256,512]"]]
+
+
+def test_the_cross_entropy_sweeps_the_logits_once_each_way_on_v5e(one_chip_head_ops):
+    """models/base._token_nll in the compiled step: `exp` runs in the
+    forward's one sweep of the logits (sum of exponentials and the label's
+    logit together) and where the backward's two matmuls form the softmax
+    gradient as they read the logits; no pass of the backward exists only to
+    differentiate the row maximum (autodiff's second sweep: a fourth `exp`)."""
+    with_exp = [o for o in one_chip_head_ops if o["exp"]]
+    assert len(with_exp) <= 3 and sum(o["exp"] for o in with_exp) <= 3, with_exp
+    assert [o["matmul"] for o in with_exp if not o["backward"]] == [False], with_exp
+    assert all(o["matmul"] for o in with_exp if o["backward"]), with_exp
+
+
 def test_chip_smoke_refuses_without_a_tpu():
     """chip_smoke.py on the CPU exits non-zero before any work and prints no
     verdict — a measurement path that finds no chip fails, it does not fall
