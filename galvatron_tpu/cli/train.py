@@ -753,7 +753,8 @@ def _train(args) -> dict:
             model_flops_per_s=obs_flops.flops_per_s(step_flops, iter_ms),
             grad_norm=grad_norm if grad_norm is None or np.isfinite(grad_norm) else None,
             # a routed-experts config's step hands these back beside the loss
-            **{k: float(metrics[k]) for k in telemetry.EXPERT_STEP_FIELDS
+            **{k: float(metrics[k])
+               for k in telemetry.EXPERT_STEP_FIELDS + telemetry.SHARE_STEP_FIELDS
                if isinstance(metrics, dict) and k in metrics},
         )
 

@@ -302,8 +302,15 @@ class LayerRun:
         return range(self.start, self.stop)
 
 
-def layer_runs(config: "HybridParallelConfig") -> List[LayerRun]:
+def layer_runs(config: "HybridParallelConfig",
+               kinds: Optional[Sequence[str]] = None) -> List[LayerRun]:
     """Partition ``config.layers`` into maximal scannable runs.
+
+    ``kinds``: the kind of each layer's block where a model has more than
+    one (`TransformerConfig.layer_kinds()`: a leading dense layer before
+    routed ones); a scanned run stacks one kind's parameter trees, so runs
+    split on kind as they do on layout. `model_layer_kinds` reads them off a
+    model config.
 
     Layers are grouped by the *realised* strategy — the LayerAxes their
     LayerStrategy maps to on this mesh — not by raw LayerStrategy equality,
@@ -322,13 +329,22 @@ def layer_runs(config: "HybridParallelConfig") -> List[LayerRun]:
     prev_key = None
     for i in range(config.num_layers):
         key = (layer_axes(config, i),
-               config.layers[i].effective_remat_policy, stage_of[i])
+               config.layers[i].effective_remat_policy, stage_of[i],
+               kinds[i] if kinds is not None else None)
         if out and key == prev_key:
             out[-1] = dataclasses.replace(out[-1], stop=i + 1)
         else:
             out.append(LayerRun(start=i, stop=i + 1, strategy=config.layers[i]))
         prev_key = key
     return out
+
+
+def model_layer_kinds(model_cfg) -> Optional[Sequence[str]]:
+    """`layer_runs`' ``kinds`` for a model config, or None for one that has
+    a single kind of layer or does not say (T5, Swin, duck-typed configs)."""
+    kinds = getattr(model_cfg, "layer_kinds", None)
+    kinds = kinds() if callable(kinds) else None
+    return kinds if kinds and len(set(kinds)) > 1 else None
 
 
 def even_pp_division(total_layers: int, pp: int) -> List[int]:

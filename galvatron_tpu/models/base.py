@@ -17,6 +17,7 @@ models/t5.py).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from dataclasses import dataclass
@@ -32,6 +33,7 @@ from galvatron_tpu.config.strategy import (
     LayerRun,
     LayerStrategy,
     layer_runs,
+    model_layer_kinds,
 )
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding, core_attention
@@ -89,14 +91,55 @@ class TransformerConfig:
     router_aux_loss_coef: float = 0.0  # x the load-balancing loss
     router_z_loss_coef: float = 0.0  # x the router z-loss
     qk_norm: bool = False  # a norm over the whole projected q and the whole k
+    # --- what GLM-4.7-Flash's published config adds (glm4_moe_lite, the
+    # DeepSeek-V3 block); again the defaults are the model without them ---
+    # latent attention (MLA): q and k/v are projected down to a low rank,
+    # normed there and projected up a head; a head's q and k are `qk_nope`
+    # dims without positions beside `qk_rope` rotated ones, and the rotated
+    # half of k is ONE vector shared by all heads. head_dim = qk_nope + qk_rope
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0  # > 0: latent attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    first_dense_layers: int = 0  # leading layers whose MLP half is dense, of width
+    dense_ffn_hidden: Optional[int] = None  # this (ffn_hidden is then ONE expert's)
+    num_shared_experts: int = 0  # dense SwiGLU(s) of the experts' width beside the routed ones
+    router_score: str = "softmax"  # softmax | sigmoid (scores an expert independently)
+    routed_scaling_factor: float = 1.0  # x the chosen experts' weights
+    # `noaux_tc`: the choice of experts adds a bias to the scores that no
+    # gradient moves; once a step it moves by this much against the sign of
+    # each expert's load (arXiv:2412.19437 2.1.2). 0.0 holds the bias still
+    router_bias: bool = False
+    router_bias_update_rate: float = 0.0
+    # a chip's share of the experts: the router ranks all `num_experts`, this
+    # program holds `experts_held` of them from `experts_held_start` on and
+    # computes their part of the result (0: all of them)
+    experts_held: int = 0
+    experts_held_start: int = 0
+    mtp_layers: int = 0  # multi-token-prediction modules (0 or 1) after the stack
+    mtp_loss_weight: float = 0.0  # x the cross entropy of the token after next
 
     def __post_init__(self):
         if self.num_kv_heads is None:
             self.num_kv_heads = self.num_heads
         if self.ffn_hidden is None:
             self.ffn_hidden = 4 * self.hidden_size
+        if self.latent_attention:
+            if self.head_dim is None:
+                self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
+            if not (self.head_dim == self.qk_nope_head_dim + self.qk_rope_head_dim
+                    == self.v_head_dim and self.q_lora_rank > 0):
+                raise ValueError(
+                    "latent attention runs as ONE attention call of equal q/k and v head "
+                    "dims: head_dim %r = qk_nope %d + qk_rope %d = v_head_dim %d is asked, "
+                    "and q_lora_rank > 0" % (self.head_dim, self.qk_nope_head_dim,
+                                             self.qk_rope_head_dim, self.v_head_dim))
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.num_heads
+        if self.mtp_layers not in (0, 1):
+            raise ValueError("mtp_layers=%d: one multi-token-prediction module at most"
+                             % self.mtp_layers)
         if self.input_type == "patches":
             n_patches = (self.image_size // self.patch_size) ** 2
             self.max_seq_len = n_patches + (1 if self.use_cls_token else 0)
@@ -113,11 +156,49 @@ class TransformerConfig:
 
     @property
     def routed(self) -> bool:
-        """Whether the MLP half is routed experts (ops/moe.py)."""
+        """Whether the model has layers whose MLP half is routed experts
+        (ops/moe.py): all of them but the `first_dense_layers`."""
         return self.num_experts > 0
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The kind of each layer's MLP half, "dense" or "routed": what
+        `config/strategy.layer_runs` splits runs on beside the layout."""
+        if not self.routed:
+            return ("dense",) * self.num_layers
+        lead = min(self.first_dense_layers, self.num_layers)
+        return ("dense",) * lead + ("routed",) * (self.num_layers - lead)
+
+    def layer_config(self, kind: str) -> "TransformerConfig":
+        """The config ONE layer of this kind is built and run from: a dense
+        layer of a model that also has routed ones is the same block with no
+        experts and the dense width. `init_layer_params`, `layer_forward`
+        and `layer_param_specs` take a layer's config."""
+        if kind == "routed" or not self.routed:
+            return self
+        return dataclasses.replace(
+            self, num_experts=0, experts_held=0, num_shared_experts=0, router_bias=False,
+            ffn_hidden=self.dense_ffn_hidden or self.ffn_hidden)
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the experts this program holds."""
+        return (self.experts_held_start, self.experts_held) if self.experts_held \
+            else (0, self.num_experts)
+
+    @property
+    def routed_layers(self) -> int:
+        """Routed blocks a step runs: the stack's and the MTP module's."""
+        return self.layer_kinds().count("routed") + (self.mtp_layers if self.routed else 0)
 
 
 # ===================================================================== init
+ROUTER_BIAS = "e_score_correction_bias"  # HF's name: (num_experts,) float32, no gradient
+
+
 def _dense_init(rng, shape, std, dtype):
     return (jax.random.normal(rng, shape, jnp.float32) * std).astype(dtype)
 
@@ -136,7 +217,21 @@ def init_layer_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         norm["bias"] = jnp.zeros((h,), cfg.param_dtype)
     p["ln1"] = jax.tree.map(jnp.copy, norm)
     p["ln2"] = jax.tree.map(jnp.copy, norm)
-    if cfg.fused_qkv:
+    if cfg.latent_attention:
+        # HF's names: q_a_proj, q_a_layernorm, q_b_proj, kv_a_proj_with_mqa,
+        # kv_a_layernorm, kv_b_proj; the up projections head-major, so that a
+        # head's [nope | rope] and [k_nope | v] split an unsharded minor dim
+        ql, kvl, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        kq = jax.random.split(ks[0], 2)
+        kkv = jax.random.split(ks[4], 2)
+        p["wq_a"] = {"kernel": _dense_init(kq[0], (h, ql), cfg.init_std, cfg.param_dtype)}
+        p["q_a_norm"] = {"scale": jnp.ones((ql,), cfg.param_dtype)}
+        p["wq_b"] = {"kernel": _dense_init(kq[1], (ql, nh, hd), cfg.init_std, cfg.param_dtype)}
+        p["wkv_a"] = {"kernel": _dense_init(kkv[0], (h, kvl + rope), cfg.init_std, cfg.param_dtype)}
+        p["kv_a_norm"] = {"scale": jnp.ones((kvl,), cfg.param_dtype)}
+        p["wkv_b"] = {"kernel": _dense_init(
+            kkv[1], (kvl, nh, cfg.qk_nope_head_dim + cfg.v_head_dim), cfg.init_std, cfg.param_dtype)}
+    elif cfg.fused_qkv:
         p["wqkv"] = {"kernel": _dense_init(ks[0], (h, 3, nh, hd), cfg.init_std, cfg.param_dtype)}
         if cfg.qkv_bias:
             p["wqkv"]["bias"] = jnp.zeros((3, nh, hd), cfg.param_dtype)
@@ -161,8 +256,19 @@ def init_layer_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         e, fan_in = cfg.num_experts, math.prod(cfg.mlp_fan_in)
         kr = jax.random.fold_in(ks[2], 1)
         p["router"] = {"kernel": _dense_init(kr, (h, e), cfg.init_std, cfg.param_dtype)}
-        p["wi"] = {"kernel": _dense_init(ks[2], (e, h, fan_in), cfg.init_std, cfg.param_dtype)}
-        p["wo_mlp"] = {"kernel": _dense_init(ks[3], (e, cfg.ffn_hidden, h), proj_std, cfg.param_dtype)}
+        if cfg.router_bias:
+            p["router"][ROUTER_BIAS] = jnp.zeros((e,), jnp.float32)
+        held = cfg.held_experts[1]  # the router ranks all e; these are held
+        p["wi"] = {"kernel": _dense_init(ks[2], (held, h, fan_in), cfg.init_std, cfg.param_dtype)}
+        p["wo_mlp"] = {"kernel": _dense_init(ks[3], (held, cfg.ffn_hidden, h), proj_std, cfg.param_dtype)}
+        if cfg.num_shared_experts:
+            wide = cfg.num_shared_experts * cfg.ffn_hidden
+            ksh = jax.random.split(jax.random.fold_in(ks[3], 1), 2)
+            shared_in = (h, 2, wide) if cfg.activation == "swiglu" else (h, wide)
+            p["shared"] = {
+                "wi": {"kernel": _dense_init(ksh[0], shared_in, cfg.init_std, cfg.param_dtype)},
+                "wo_mlp": {"kernel": _dense_init(ksh[1], (wide, h), proj_std, cfg.param_dtype)},
+            }
         return p
     p["wi"] = {"kernel": _dense_init(ks[2], (h,) + cfg.mlp_fan_in, cfg.init_std, cfg.param_dtype)}
     if cfg.mlp_bias:
@@ -205,8 +311,19 @@ def init_model_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         embed["norm"] = _norm_params(cfg)
     params: Params = {
         "embed": embed,
-        "layers": [init_layer_params(ks[2 + i], cfg) for i in range(n)],
+        "layers": [init_layer_params(ks[2 + i], cfg.layer_config(kind))
+                   for i, kind in enumerate(cfg.layer_kinds())],
     }
+    if cfg.mtp_layers:
+        # HF's names: enorm, hnorm, eh_proj, the block, shared_head.norm; the
+        # embedding and the head are the model's own
+        km = jax.random.split(jax.random.fold_in(rng, n), 2)
+        params["mtp"] = {
+            "enorm": _norm_params(cfg), "hnorm": _norm_params(cfg),
+            "eh_proj": {"kernel": _dense_init(km[0], (2 * h, h), cfg.init_std, cfg.param_dtype)},
+            "block": init_layer_params(km[1], cfg.layer_config(cfg.layer_kinds()[-1])),
+            "norm": _norm_params(cfg),
+        }
     # post-LN models (BERT) normalise inside each block; no final norm
     if cfg.pre_norm:
         params["final_norm"] = _norm_params(cfg)
@@ -298,6 +415,34 @@ def qk_normed(p: Params, q: jax.Array, k: jax.Array, cfg: TransformerConfig):
     return whole(q, p["q_norm"]["scale"]), whole(k, p["k_norm"]["scale"])
 
 
+def latent_qkv_projection(p: Params, y: jax.Array, positions: jax.Array,
+                          cfg: TransformerConfig, dtype):
+    """Latent attention's q, k, v (B, S, nh, head_dim) from normed
+    activations (B, S, H), rope applied (DeepSeek-V2's MLA as GLM-4.7-Flash
+    configures it; HF `Glm4MoeLiteAttention`):
+
+        cq = RMSNorm(y Wqa);  q_h = cq Wqb_h = [q_nope_h | q_rope_h]
+        [ckv | kr] = y Wkva;  [k_nope_h | v_h] = RMSNorm(ckv) Wkvb_h
+        q_h = [q_nope_h | rope(q_rope_h)],  k_h = [k_nope_h | rope(kr)]
+
+    The rotated half of k is one vector a token, the same for every head.
+    q/k dims (nope + rope) equal v's, so one attention call of that head_dim
+    serves, at the default scale 1/sqrt(nope + rope)."""
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    eps, theta = cfg.layernorm_eps, cfg.rope_theta
+    cq = rms_norm(_dense(y, p["wq_a"], dtype), p["q_a_norm"]["scale"], eps)
+    q = jnp.einsum("bsr,rnd->bsnd", cq, p["wq_b"]["kernel"].astype(dtype))
+    ckv_kr = _dense(y, p["wkv_a"], dtype)
+    ckv = rms_norm(ckv_kr[..., :cfg.kv_lora_rank], p["kv_a_norm"]["scale"], eps)
+    kv = jnp.einsum("bsr,rnd->bsnd", ckv, p["wkv_b"]["kernel"].astype(dtype))
+    q_rope = apply_rotary(q[..., nope:], positions, theta)
+    k_rope = apply_rotary(ckv_kr[:, :, None, cfg.kv_lora_rank:], positions, theta)
+    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_rope, k_rope.shape[:2] + (cfg.num_heads, rope))], axis=-1)
+    return q, k, kv[..., nope:]
+
+
 # ------------------------------------------------ layouts of routed experts
 def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional[str] = None,
                          autotune: Optional[str] = None) -> Optional[str]:
@@ -305,26 +450,34 @@ def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional
     or None. Experts are ordinary parameters under dp and ZeRO-1/2/3; no
     other axis has an expert form yet (`ep` is the next step), and what has
     none is refused by name (GLS018) at lint time and at trace time, not run
-    wrong or priced as dense."""
-    if not getattr(cfg, "routed", False):
+    wrong or priced as dense. The same holds of latent attention (and of the
+    multi-token-prediction module that comes with it), whose low-rank
+    projections have no tensor-, context- or sequence-parallel form and no
+    cache in the decode engine: where it is the reason, it is named."""
+    latent = bool(getattr(cfg, "latent_attention", False) or getattr(cfg, "mtp_layers", 0))
+    if not (getattr(cfg, "routed", False) or latent):
         return None
+    also = " (nor has latent attention, MLA: kv_lora_rank > 0)" if latent else ""
     if mode == "serve":
-        return "serve: the decode engine has no expert form"
+        return "serve: the decode engine has no expert form" + (
+            ", and no cache of latent attention's compressed k/v" if latent else "")
     if (autotune or "off") != "off":
-        return "autotune=%s: the re-search would price the block as dense" % autotune
+        return "autotune=%s: the re-search would price the block as dense" % autotune + (
+            ", and latent attention as full-rank" if latent else "")
     if hp is None:
         return None
     if hp.pp > 1:
-        return "pp=%d: the pipeline engines carry no router losses between stages" % hp.pp
+        return "pp=%d: the pipeline engines carry no router losses between stages" % hp.pp + (
+            " and no multi-token-prediction module after the last" if latent else "")
     for i, s in enumerate(hp.layers):
         if s.tp > 1 or s.cp > 1 or s.sp:
             return ("layer %d: tp=%d cp=%d sp=%d: the experts' kernels and the dropless "
-                    "dispatch have no tensor-, context- or sequence-parallel form"
-                    % (i, s.tp, s.cp, int(s.sp)))
+                    "dispatch have no tensor-, context- or sequence-parallel form%s"
+                    % (i, s.tp, s.cp, int(s.sp), also))
     if hp.vocab_tp > 1:
         return "vocab_tp=%d: tensor parallelism of any layer is unsupported" % hp.vocab_tp
     if hp.tp_comm_mode != "gspmd":
-        return "tp_comm_mode=%r: the manual TP path has no expert form" % hp.tp_comm_mode
+        return "tp_comm_mode=%r: the manual TP path has no expert form%s" % (hp.tp_comm_mode, also)
     from galvatron_tpu.parallel import quant_collectives as QC
 
     if QC.wants_quant_comm(hp):
@@ -387,28 +540,35 @@ def layer_forward(
     A routed-experts config (``cfg.routed``) returns ``(x, aux)``: the
     block's output and its router's auxiliary terms (ops/moe.py)."""
     dtype = cfg.compute_dtype
-    if cfg.routed and return_kv:
+    if (cfg.routed or cfg.latent_attention) and return_kv:
         refuse_expert_layout("serving (the prefill's k/v outputs)")
     if mesh is not None and axes is not None:
         attn_sharding = KernelSharding.for_layer(mesh, axes)
 
     residual = x
     y = _norm(x, p["ln1"], cfg) if cfg.pre_norm else x
-    q, k, v = qkv_projection(p, y, cfg, dtype)
-    if cfg.qk_norm:
-        q, k = qk_normed(p, q, k, cfg)
-    if cfg.position_type == "rope":
-        if mesh is not None and axes is not None:
-            # Pin positions to THIS layer's sharding so each layer derives its
-            # own rope cos/sin tables in its own layout. Without this, XLA CSEs
-            # the identical table computation across adjacent layers with
-            # different strategies and reshards the shared result — under the
-            # 1F1B schedule's divergent branches that reshard can be a
-            # collective-permute, which deadlocks across stages (see
-            # parallel/pipeline_1f1b.py divergence-safety invariant).
-            positions = S.constrain(positions, mesh, S.act_spec(axes, ndim=2))
-        q = apply_rotary(q, positions, cfg.rope_theta)
-        k = apply_rotary(k, positions, cfg.rope_theta)
+    if cfg.position_type == "rope" and mesh is not None and axes is not None:
+        # Pin positions to THIS layer's sharding so each layer derives its
+        # own rope cos/sin tables in its own layout. Without this, XLA CSEs
+        # the identical table computation across adjacent layers with
+        # different strategies and reshards the shared result — under the
+        # 1F1B schedule's divergent branches that reshard can be a
+        # collective-permute, which deadlocks across stages (see
+        # parallel/pipeline_1f1b.py divergence-safety invariant).
+        pin = lambda pos: S.constrain(pos, mesh, S.act_spec(axes, ndim=2))  # noqa: E731
+    else:
+        pin = lambda pos: pos  # noqa: E731
+    if cfg.latent_attention:
+        with jax.named_scope(tracing.ATTN_LATENT):
+            q, k, v = latent_qkv_projection(p, y, pin(positions), cfg, dtype)
+    else:
+        q, k, v = qkv_projection(p, y, cfg, dtype)
+        if cfg.qk_norm:
+            q, k = qk_normed(p, q, k, cfg)
+        if cfg.position_type == "rope":
+            positions = pin(positions)
+            q = apply_rotary(q, positions, cfg.rope_theta)
+            k = apply_rotary(k, positions, cfg.rope_theta)
     if mesh is not None and axes is not None and len(axes.tp) + len(axes.cp) > 0:
         # (B, S/x, nh, hd) -> (B, S/cp, nh/tp, hd): XLA inserts the all-to-all
         # (ulysses) or all-gather+split (megatron-sp) when seq was tp-sharded.
@@ -435,7 +595,10 @@ def layer_forward(
                               impl=cfg.attn_impl, bias_type="key_padding",
                               sharding=attn_sharding)
     attn = attn.reshape(attn.shape[0], attn.shape[1], cfg.num_heads * cfg.head_dim)
-    o = _dense(attn, p["wo"], dtype)
+    # the output projection counts as latent attention's too: the scope is
+    # everything of it but the attention call
+    with jax.named_scope(tracing.ATTN_LATENT) if cfg.latent_attention else contextlib.nullcontext():
+        o = _dense(attn, p["wo"], dtype)
     if mesh is not None and axes is not None:
         o = S.constrain(o, mesh, S.act_spec(axes))
     x = residual + o
@@ -449,7 +612,13 @@ def layer_forward(
             y, p["router"]["kernel"], p["wi"]["kernel"], p["wo_mlp"]["kernel"],
             experts_per_token=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
             activate=swiglu if cfg.activation == "swiglu" else partial(_activation, cfg=cfg),
-            dtype=dtype, sharding=attn_sharding)
+            dtype=dtype, sharding=attn_sharding, score=cfg.router_score,
+            bias=p["router"].get(ROUTER_BIAS), scale=cfg.routed_scaling_factor,
+            held=cfg.held_experts if cfg.experts_held else None)
+        if "shared" in p:
+            # every chip of the deployment computes it alike, whole
+            with jax.named_scope(tracing.MOE_SHARED):
+                out = out + dense_mlp(p["shared"], y, cfg, dtype)
     else:
         out, aux = dense_mlp(p, y, cfg, dtype), None
     if mesh is not None and axes is not None:
@@ -498,7 +667,7 @@ def decode_layer_forward(
     exactly, so incremental decode reproduces the full-forward logits within
     float tolerance (tests/serve/test_decode_parity.py)."""
     dtype = cfg.compute_dtype
-    if cfg.routed:
+    if cfg.routed or cfg.latent_attention:
         refuse_expert_layout("serving (single-token decode)")
 
     residual = x
@@ -863,20 +1032,23 @@ def run_layers(
     kvs: List[Tuple[jax.Array, jax.Array]] = []
     auxs: List[Dict[str, jax.Array]] = []  # routed: a layer's, or a scanned run's stacked
 
+    kinds = cfg.layer_kinds()
+
     def unrolled(x, indices):
         for i in indices:
             lp = layers[i]
+            lcfg = cfg.layer_config(kinds[i])
             axes = layer_axes(hp, i) if use_hp else None
             if use_hp:
                 x = S.constrain(x, mesh, S.act_spec(axes))
             if collect_kv:
                 x, kv = layer_forward(
-                    lp, x, positions, cfg, mesh=mesh, axes=axes,
+                    lp, x, positions, lcfg, mesh=mesh, axes=axes,
                     attn_bias=attn_bias, return_kv=True,
                 )
                 kvs.append(kv)
                 continue
-            fwd = _layer_fwd_fn(cfg, hp if use_hp else None, mesh, axes,
+            fwd = _layer_fwd_fn(lcfg, hp if use_hp else None, mesh, axes,
                                 attn_bias, hp.layers[i] if use_hp else None)
             # the per-layer serialized policy decides (checkpoint=1 layers
             # default to "full"); the global --remat_policy flag was folded
@@ -886,7 +1058,7 @@ def run_layers(
                 if pol != "none":
                     fwd = _remat(fwd, pol)
             x = fwd(lp, x, positions)
-            if cfg.routed:
+            if lcfg.routed:
                 x, aux = x
                 auxs.append(aux)
         return x
@@ -894,15 +1066,16 @@ def run_layers(
     def one_run(x, run):
         if not scan or run.length < 2:
             return unrolled(x, run.layer_indices)
+        lcfg = cfg.layer_config(kinds[run.start])  # a run is of one kind
         axes = layer_axes(hp, run.start) if use_hp else None
         stacked = stack_layer_run([layers[i] for i in run.layer_indices])
         if use_hp:
             stacked = jax.tree.map(
                 lambda t, sp: S.constrain(t, mesh, sp),
-                stacked, stacked_layer_param_specs(cfg, axes),
+                stacked, stacked_layer_param_specs(lcfg, axes),
             )
         if collect_kv:
-            body = partial(layer_forward, cfg=cfg, mesh=mesh, axes=axes,
+            body = partial(layer_forward, cfg=lcfg, mesh=mesh, axes=axes,
                            attn_bias=attn_bias, return_kv=True)
 
             def step_kv(carry, lp, _body=body, _axes=axes):
@@ -915,10 +1088,10 @@ def run_layers(
             for j in range(run.length):
                 kvs.append(jax.tree.map(lambda t, _j=j: t[_j], kv_stacked))
             return x
-        body = _layer_fwd_fn(cfg, hp if use_hp else None, mesh, axes,
+        body = _layer_fwd_fn(lcfg, hp if use_hp else None, mesh, axes,
                              attn_bias, run.strategy if use_hp else None)
         if use_hp:
-            # a run is maximal over (axes, effective policy, stage) —
+            # a run is maximal over (axes, effective policy, stage, kind) —
             # config/strategy.layer_runs splits on differing remat_policy
             # exactly like the checkpoint flag, so one policy wraps the
             # whole scanned body
@@ -930,18 +1103,20 @@ def run_layers(
             if use_hp:
                 carry = S.constrain(carry, mesh, S.act_spec(_axes))
             out = _body(lp, carry, positions)
-            return out if cfg.routed else (out, None)
+            return out if lcfg.routed else (out, None)
 
         x, run_aux = jax.lax.scan(step, x, stacked)
-        if cfg.routed:
+        if lcfg.routed:
             auxs.append(run_aux)
         return x
 
     if use_hp:
-        runs = layer_runs(hp)
+        runs = layer_runs(hp, model_layer_kinds(cfg))
     else:
-        # no strategy info: the whole stack is one homogeneous run
-        runs = [LayerRun(start=0, stop=len(layers), strategy=LayerStrategy())]
+        # no strategy info: one homogeneous run a kind of layer
+        starts = [i for i in range(len(layers)) if i == 0 or kinds[i] != kinds[i - 1]]
+        runs = [LayerRun(start=a, stop=b, strategy=LayerStrategy())
+                for a, b in zip(starts, starts[1:] + [len(layers)])]
     for k, run in enumerate(runs):
         # one scope a run, scanned or unrolled; k is the `layer_run` event's
         with jax.named_scope(tracing.layers_scope(k)):
@@ -949,22 +1124,29 @@ def run_layers(
     if collect_kv:
         return x, kvs
     if cfg.routed:
-        return x, _fold_aux(auxs, len(layers))
+        return x, auxs
     return x
 
 
-def _fold_aux(auxs: List[Dict[str, jax.Array]], num_layers: int) -> Dict[str, jax.Array]:
-    """The layers' router terms as one: each loss the mean over layers, the
-    load the worst layer's. An entry is a layer's scalars or a scanned
-    run's, stacked along the layer axis."""
+def _fold_aux(auxs: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
+    """The routed blocks' router terms as one: each loss the mean over the
+    blocks, the load and the bias the worst block's, the rows held their sum,
+    and `counts` a row a block, in the blocks' order (`router_bias_leaves`').
+    An entry is a block's values or a scanned run's, stacked along the layer
+    axis."""
     def total(name, reduce):
         return reduce(jnp.stack([reduce(jnp.atleast_1d(a[name])) for a in auxs]))
 
-    return {
-        "load_balance": total("load_balance", jnp.sum) / num_layers,
-        "router_z": total("router_z", jnp.sum) / num_layers,
-        "load_max_over_mean": total("load_max_over_mean", jnp.max),
+    blocks = sum(jnp.atleast_1d(a["load_max_over_mean"]).shape[0] for a in auxs)
+    fold = {
+        "load_balance": lambda n: total(n, jnp.sum) / blocks,
+        "router_z": lambda n: total(n, jnp.sum) / blocks,
+        "load_max_over_mean": lambda n: total(n, jnp.max),
+        "bias_abs_max": lambda n: total(n, jnp.max),
+        "rows_held": lambda n: total(n, jnp.sum),
+        "counts": lambda n: jnp.concatenate([jnp.atleast_2d(a[n]) for a in auxs]),
     }
+    return {name: fold[name](name) for name in auxs[0]}
 
 
 def padding_attn_bias(attn_mask: jax.Array) -> jax.Array:
@@ -975,10 +1157,10 @@ def padding_attn_bias(attn_mask: jax.Array) -> jax.Array:
 def model_forward(params, tokens, positions, cfg, hp=None, mesh=None, **inputs) -> jax.Array:
     """Full forward to logits (single pipeline stage; pipelined execution lives
     in parallel/pipeline.py)."""
-    return forward_with_aux(params, tokens, positions, cfg, hp, mesh, **inputs)[0]
+    return _forward(params, tokens, positions, cfg, hp, mesh, **inputs)[0]
 
 
-def forward_with_aux(
+def _forward(
     params: Params,
     tokens: jax.Array,
     positions: jax.Array,
@@ -988,8 +1170,8 @@ def forward_with_aux(
     token_type_ids: Optional[jax.Array] = None,
     attn_mask: Optional[jax.Array] = None,
 ):
-    """`model_forward`'s logits, and the routers' auxiliary terms
-    (`run_layers`) or None for a dense config."""
+    """-> (logits, the last layer's output before the final norm, the routed
+    blocks' auxiliary terms as `run_layers` lists them or None)."""
     use_hp = hp is not None and mesh is not None
     vax = vocab_axes(hp) if use_hp else None
     if positions is None and cfg.input_type != "patches":
@@ -1004,7 +1186,7 @@ def forward_with_aux(
         x = S.constrain(x, mesh, S.act_spec(vax))
     bias = padding_attn_bias(attn_mask) if attn_mask is not None else None
     x = run_layers(params, x, positions, cfg, hp, mesh, attn_bias=bias)
-    x, aux = x if cfg.routed else (x, None)
+    x, auxs = x if cfg.routed else (x, None)
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
     # the head is the first half of gt.head_loss; the loss functions below
@@ -1013,10 +1195,56 @@ def forward_with_aux(
         logits = model_head(params, x, cfg)
         if use_hp and cfg.head_type in ("lm", "mlm"):
             logits = S.constrain(logits, mesh, S.logits_spec(vax))
+    return logits, x, auxs
+
+
+def mtp_logits(params: Params, hidden: jax.Array, batch, cfg: TransformerConfig,
+               hp: Optional[HybridParallelConfig] = None, mesh: Optional[Mesh] = None):
+    """The multi-token-prediction module (DeepSeek-V3's, depth 1; arXiv:2412.19437
+    2.2): for position i of a sequence t,
+
+        m_i = [RMSNorm_h(hidden_i) ; RMSNorm_e(Emb(t_{i+1}))] Weh,  m'_i = Block(m_i)
+
+    and the logits of t_{i+2} are the model's own head on RMSNorm(m'_i).
+    `hidden` is the last layer's output before the final norm, `Emb` the
+    model's own table, and t_{i+1} is `batch["labels"]`. The block is one
+    more layer of the last layer's kind, in its layout and under its
+    recomputation policy. -> (logits, the block's auxiliary terms or None)."""
+    mp, dtype = params["mtp"], cfg.compute_dtype
+    use_hp = hp is not None and mesh is not None
+    vax = vocab_axes(hp) if use_hp else None
+    last = cfg.num_layers - 1
+    lcfg = cfg.layer_config(cfg.layer_kinds()[last])
+    axes = layer_axes(hp, last) if use_hp else None
+    positions = batch["positions"]
+    with jax.named_scope(tracing.MTP):
+        e = embed_tokens(params["embed"], batch["labels"], positions, cfg, mesh, vax)
+        m = jnp.concatenate([_norm(hidden, mp["hnorm"], cfg), _norm(e, mp["enorm"], cfg)], axis=-1)
+        m = _dense(m, mp["eh_proj"], dtype)
+        if use_hp:
+            m = S.constrain(m, mesh, S.act_spec(axes))
+        attn_bias = padding_attn_bias(batch["attn_mask"]) if "attn_mask" in batch else None
+        block = partial(layer_forward, cfg=lcfg, mesh=mesh, axes=axes, attn_bias=attn_bias)
+        policy = hp.layers[last].effective_remat_policy if use_hp else "none"
+        if policy != "none":
+            block = _remat(block, policy)
+        m = block(mp["block"], m, positions)
+        m, aux = m if lcfg.routed else (m, None)
+        if use_hp:
+            m = S.constrain(m, mesh, S.act_spec(vax))
+    with jax.named_scope(tracing.HEAD_LOSS):
+        logits = head_logits(params, _norm(m, mp["norm"], cfg), cfg)
+        if use_hp:
+            logits = S.constrain(logits, mesh, S.logits_spec(vax))
     return logits, aux
 
 
 EXPERT_LOAD = "expert_load_max_over_mean"  # the fullest expert's tokens over the mean
+ROUTER_COUNTS = "router_counts"  # (routed blocks, E): the step's; no metric
+# how the microbatch loop folds a part that is not a loss term (those are
+# weighted as the loss is)
+PART_FOLDS = {EXPERT_LOAD: jnp.maximum, "router_bias_abs_max": jnp.maximum,
+              ROUTER_COUNTS: jnp.add, "expert_rows_held": jnp.add}
 
 
 def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False):
@@ -1025,26 +1253,79 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False)
 
     A routed-experts config's loss is the cross entropy plus
     `router_aux_loss_coef` x load balancing plus `router_z_loss_coef` x router
-    z-loss (each the mean over layers, over all tokens); `with_parts` returns
-    `(loss, parts)` with the three terms and the worst layer's expert load,
-    the `step` event's counters."""
-    logits, aux = forward_with_aux(
+    z-loss (each the mean over the routed blocks, over all tokens; a sigmoid
+    router has neither), and with a multi-token-prediction module plus
+    `mtp_loss_weight` x the cross entropy of the token after next (labels
+    shifted by one more; a sequence's last position has none). `with_parts`
+    returns `(loss, parts)`: the terms, the worst block's expert load and
+    the other counters of the `step` event (`telemetry.EXPERT_STEP_FIELDS`),
+    and for a router with a bias the blocks' assignment counts
+    (`ROUTER_COUNTS`), which the train step moves the bias by."""
+    logits, hidden, auxs = _forward(
         params, batch["tokens"], batch["positions"], cfg, hp, mesh,
         token_type_ids=batch.get("token_type_ids"), attn_mask=batch.get("attn_mask"),
     )
+    labels, mask = batch["labels"], batch.get("loss_mask")
     with jax.named_scope(tracing.HEAD_LOSS):
-        loss = vocab_parallel_cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
-    if aux is None:
-        return loss
-    parts = {
-        "loss_ce": loss,
-        "loss_load_balance": aux["load_balance"],
-        "loss_router_z": aux["router_z"],
-        EXPERT_LOAD: aux["load_max_over_mean"],
-    }
-    loss = (loss + cfg.router_aux_loss_coef * aux["load_balance"]
-            + cfg.router_z_loss_coef * aux["router_z"])
+        loss = vocab_parallel_cross_entropy(logits, labels, mask)
+    parts = {"loss_ce": loss}
+    if cfg.mtp_layers:
+        logits2, aux = mtp_logits(params, hidden, batch, cfg, hp, mesh)
+        auxs = auxs + [aux] if aux is not None else auxs
+        # the label of position i + 1, where it is one; none at a sequence's end
+        ahead = jnp.ones(labels.shape, jnp.float32) if mask is None else mask.astype(jnp.float32)
+        ahead = jnp.roll(ahead, -1, axis=1).at[:, -1].set(0.0)
+        with jax.named_scope(tracing.HEAD_LOSS):
+            parts["loss_mtp"] = vocab_parallel_cross_entropy(
+                logits2, jnp.roll(labels, -1, axis=1), ahead)
+        loss = loss + cfg.mtp_loss_weight * parts["loss_mtp"]
+    if not auxs:
+        return (loss, parts) if with_parts else loss
+    aux = _fold_aux(auxs)
+    if "load_balance" in aux:
+        parts["loss_load_balance"] = aux["load_balance"]
+        parts["loss_router_z"] = aux["router_z"]
+        loss = (loss + cfg.router_aux_loss_coef * aux["load_balance"]
+                + cfg.router_z_loss_coef * aux["router_z"])
+    parts[EXPERT_LOAD] = aux["load_max_over_mean"]
+    if "rows_held" in aux:
+        even = (cfg.routed_layers * labels.size * cfg.experts_per_token
+                * cfg.held_experts[1] / cfg.num_experts)
+        parts["expert_rows_held"] = aux["rows_held"]
+        parts["expert_rows_held_over_even"] = aux["rows_held"] / even
+    if "counts" in aux:
+        parts["router_bias_abs_max"] = aux["bias_abs_max"]
+        parts[ROUTER_COUNTS] = aux["counts"]
     return (loss, parts) if with_parts else loss
+
+
+def router_bias_leaves(params: Params) -> List[Dict[str, jax.Array]]:
+    """The `router` dicts that hold a bias, in the order of `ROUTER_COUNTS`'
+    rows: the stack's routed layers, then the MTP module's block."""
+    blocks = list(params["layers"]) + ([params["mtp"]["block"]] if "mtp" in params else [])
+    return [b["router"] for b in blocks if ROUTER_BIAS in b.get("router", {})]
+
+
+def update_router_bias(params: Params, counts: jax.Array, rate: float) -> Params:
+    """`b_e += rate x sign(mean(c) - c_e)` a routed block, `c` the block's row
+    of `counts`: the auxiliary-loss-free balancing rule (arXiv:2412.19437
+    2.1.2). An expert with more than its share of the batch's assignments is
+    ranked lower next step, one with fewer higher. -> params, new dicts on
+    the way to each bias and every other leaf as it was."""
+    rows = iter(counts)
+
+    def block(b):
+        if ROUTER_BIAS not in b.get("router", {}):
+            return b
+        c = next(rows)
+        bias = b["router"][ROUTER_BIAS]
+        moved = bias + rate * jnp.sign(jnp.mean(c) - c).astype(bias.dtype)
+        return {**b, "router": {**b["router"], ROUTER_BIAS: moved}}
+
+    out = {**params, "layers": [block(b) for b in params["layers"]]}
+    if "mtp" in params:
+        out["mtp"] = {**params["mtp"], "block": block(params["mtp"]["block"])}
+    return out
 
 
 def softmax_nll(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -1074,7 +1355,15 @@ def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     r1 = S.replicated_1d_spec(axes)
     norm = {"scale": r1} if cfg.norm_type == "rmsnorm" else {"scale": r1, "bias": r1}
     sp: Params = {"ln1": dict(norm), "ln2": dict(norm)}
-    if cfg.fused_qkv:
+    if cfg.latent_attention:
+        # ordinary leaves (tp is refused, GLS018): ZeRO-3 splits the input dim
+        sp["wq_a"] = {"kernel": P(z3, None)}
+        sp["q_a_norm"] = {"scale": r1}
+        sp["wq_b"] = {"kernel": P(z3, None, None)}
+        sp["wkv_a"] = {"kernel": P(z3, None)}
+        sp["kv_a_norm"] = {"scale": r1}
+        sp["wkv_b"] = {"kernel": P(z3, None, None)}
+    elif cfg.fused_qkv:
         sp["wqkv"] = {"kernel": P(z3, None, tp, None)}
         if cfg.qkv_bias:
             sp["wqkv"]["bias"] = P(None, tp, None)
@@ -1094,8 +1383,15 @@ def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
         # ordinary leaves (tp is refused, GLS018): ZeRO-3 splits the experts
         # over dp, and they enter the block whole (ops/moe.moe_ffn)
         sp["router"] = {"kernel": P(None, None)}
+        if cfg.router_bias:
+            sp["router"][ROUTER_BIAS] = P(None)
         sp["wi"] = {"kernel": P(z3, None, None)}
         sp["wo_mlp"] = {"kernel": P(z3, None, None)}
+        if cfg.num_shared_experts:
+            sp["shared"] = {
+                "wi": {"kernel": P(z3, None, None) if cfg.activation == "swiglu" else P(z3, None)},
+                "wo_mlp": {"kernel": P(None, z3)},
+            }
         return sp
     if cfg.activation == "swiglu":
         sp["wi"] = {"kernel": P(z3, None, tp)}
@@ -1129,8 +1425,15 @@ def model_param_specs(cfg: TransformerConfig, hp: HybridParallelConfig) -> Param
         embed["norm"] = dict(norm_spec)
     specs: Params = {
         "embed": embed,
-        "layers": [layer_param_specs(cfg, layer_axes(hp, i)) for i in range(cfg.num_layers)],
+        "layers": [layer_param_specs(cfg.layer_config(kind), layer_axes(hp, i))
+                   for i, kind in enumerate(cfg.layer_kinds())],
     }
+    if cfg.mtp_layers:
+        specs["mtp"] = {
+            "enorm": dict(norm_spec), "hnorm": dict(norm_spec),
+            "eh_proj": {"kernel": P(S._ax(vax.dp) if vax.zero3 else None, None)},
+            "block": specs["layers"][-1], "norm": dict(norm_spec),
+        }
     if cfg.pre_norm:
         specs["final_norm"] = dict(norm_spec)
     vocab_col = P(None, None) if vax.ulysses else P(None, S._ax(vax.tp))

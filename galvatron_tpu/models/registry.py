@@ -92,8 +92,17 @@ def _ensure_builtin():
     if _LOADED:
         return
     _LOADED = True
-    from galvatron_tpu.models import gpt, llama, olmoe
+    from galvatron_tpu.models import glm4_moe_lite, gpt, llama, olmoe
 
+    register(
+        ModelFamily(
+            name="glm4_moe_lite",
+            config_fn=glm4_moe_lite.glm4_moe_lite_config,
+            meta_configs=glm4_moe_lite.META_CONFIGS,
+            default_size="glm-4.7-flash",
+            config_from_hf=glm4_moe_lite.glm4_moe_lite_config_from_hf,
+        )
+    )
     register(
         ModelFamily(
             name="olmoe",

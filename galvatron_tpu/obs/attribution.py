@@ -26,7 +26,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
-from galvatron_tpu.config.strategy import HybridParallelConfig, LayerStrategy, layer_runs
+from galvatron_tpu.config.strategy import (HybridParallelConfig, LayerStrategy, layer_runs,
+                                           model_layer_kinds)
 from galvatron_tpu.obs import flops as F
 
 HEAD_RUN = -1  # pseudo-run index for the embed/head share row
@@ -148,7 +149,7 @@ def predict_layer_runs(
         remat_recompute_frac=(time_config or {}).get("remat_recompute_frac"),
     )
 
-    runs = layer_runs(hp)
+    runs = layer_runs(hp, model_layer_kinds(cfg))
     run_flops = F.run_fwd_flops(cfg, hp)  # len(runs)+1 (head), or None
     total_flops = sum(run_flops) if run_flops else None
     tp_comm_mode = getattr(hp, "tp_comm_mode", "gspmd")

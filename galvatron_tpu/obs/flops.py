@@ -28,7 +28,7 @@ cannot move a cell's ``mfu``. Do not merge the two.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Mapping, Optional, Sequence
 
 # Peak dense matmul throughput per chip, FLOP/s, by device_kind prefix
 # (jax Device.device_kind), bf16. A kind the table does not hold has no peak
@@ -73,20 +73,36 @@ def layer_fwd_flops(
     tokens: Optional[float] = None,
     num_experts: int = 0,
     experts_per_token: int = 0,
+    experts_held: int = 0,
+    num_shared_experts: int = 0,
+    latent: Optional[Mapping[str, int]] = None,
 ) -> float:
     """Forward model FLOPs of ONE transformer block over `tokens` tokens
     (default: one sequence). Matmul terms only (2 FLOPs per MAC); norms and
     elementwise activations are O(tokens*hidden) noise next to these. A
     routed block (`num_experts` > 0, `ffn_hidden` the width of one expert)
     counts the experts a token is SENT to, not the experts held, plus the
-    router's matmul."""
+    router's matmul and the shared experts; where only `experts_held` of the
+    experts are held here, the even share of a token's experts that falls to
+    them (experts_per_token x held / num_experts: a constant, whatever the
+    routing). `latent` (q_lora_rank, kv_lora_rank, qk_nope_head_dim,
+    qk_rope_head_dim, v_head_dim): latent attention's five projections by
+    their shapes in place of q, k/v and out."""
     tokens = float(seq_len if tokens is None else tokens)
     ffn = ffn_hidden or 4 * hidden
     hd = head_dim or hidden // num_heads
     nkv = num_kv_heads or num_heads
     q_dim = num_heads * hd
-    # per-token projection matmuls: q, fused kv (GQA-scaled), out
-    proj = 2.0 * hidden * q_dim + 2.0 * hidden * (2 * nkv * hd) + 2.0 * q_dim * hidden
+    if latent:
+        ql, kvl = latent["q_lora_rank"], latent["kv_lora_rank"]
+        nope, rope, vd = (latent["qk_nope_head_dim"], latent["qk_rope_head_dim"],
+                          latent["v_head_dim"])
+        proj = (2.0 * hidden * ql + 2.0 * ql * num_heads * (nope + rope)
+                + 2.0 * hidden * (kvl + rope) + 2.0 * kvl * num_heads * (nope + vd)
+                + 2.0 * num_heads * vd * hidden)
+    else:
+        # per-token projection matmuls: q, fused kv (GQA-scaled), out
+        proj = 2.0 * hidden * q_dim + 2.0 * hidden * (2 * nkv * hd) + 2.0 * q_dim * hidden
     # per-token attention arithmetic: scores (q·kᵀ) + weighted sum (p·v),
     # each 2*S*q_dim; causal masks half the score matrix
     attn = 2.0 * (2.0 * seq_len * q_dim) * (0.5 if causal else 1.0)
@@ -94,7 +110,8 @@ def layer_fwd_flops(
     mlp = (2.0 * hidden * (2 * ffn) + 2.0 * ffn * hidden) if swiglu \
         else (2.0 * hidden * ffn + 2.0 * ffn * hidden)
     if num_experts:
-        mlp = experts_per_token * mlp + 2.0 * hidden * num_experts
+        sent = experts_per_token * (experts_held or num_experts) / num_experts
+        mlp = (sent + num_shared_experts) * mlp + 2.0 * hidden * num_experts
     return tokens * (proj + attn + mlp)
 
 
@@ -107,6 +124,10 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
     seq = seq_len or getattr(cfg, "max_seq_len", None)
     if not hidden or not heads or not seq:
         return None
+    latent = None
+    if getattr(cfg, "kv_lora_rank", 0):
+        latent = {k: getattr(cfg, k) for k in (
+            "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")}
     return layer_fwd_flops(
         hidden=hidden,
         num_heads=heads,
@@ -119,6 +140,9 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         tokens=tokens,
         num_experts=getattr(cfg, "num_experts", 0),
         experts_per_token=getattr(cfg, "experts_per_token", 0),
+        experts_held=getattr(cfg, "experts_held", 0),
+        num_shared_experts=getattr(cfg, "num_shared_experts", 0),
+        latent=latent,
     )
 
 
@@ -149,10 +173,40 @@ def model_fwd_flops(cfg: Any, batch_size: int = 1) -> Optional[float]:
     if not seq or not layers:
         return None
     tokens = float(batch_size) * seq
-    per_layer = layer_fwd_flops_from_config(cfg, tokens=tokens)
-    if per_layer is None:
+    per_kind = layer_kind_fwd_flops(cfg, tokens)
+    if per_kind is None:
         return None
-    return layers * per_layer + head_fwd_flops_from_config(cfg, tokens=tokens)
+    kinds = _layer_kinds(cfg)
+    return (sum(per_kind[k] * kinds.count(k) for k in sorted(per_kind))
+            + _after_layers_fwd_flops(cfg, tokens, per_kind))
+
+
+def _after_layers_fwd_flops(cfg: Any, tokens: float, per_kind: Mapping[str, float]) -> float:
+    """The head, and with a multi-token-prediction module its (2h, h)
+    projection, its block (one more layer of the last layer's kind) and the
+    head a second time."""
+    head = head_fwd_flops_from_config(cfg, tokens=tokens)
+    if not getattr(cfg, "mtp_layers", 0):
+        return head
+    hidden = cfg.hidden_size
+    return 2 * head + tokens * 2.0 * (2 * hidden) * hidden + per_kind[_layer_kinds(cfg)[-1]]
+
+
+def _layer_kinds(cfg: Any) -> Sequence[str]:
+    kinds = getattr(cfg, "layer_kinds", None)
+    return tuple(kinds()) if callable(kinds) else ("dense",) * cfg.num_layers
+
+
+def layer_kind_fwd_flops(cfg: Any, tokens: float) -> Optional[Dict[str, float]]:
+    """Forward FLOPs of one layer of each kind the model has (a model of one
+    kind: {"dense": ...} or {"routed": ...}), over `tokens` tokens."""
+    out = {}
+    for kind in set(_layer_kinds(cfg)):
+        layer_cfg = cfg.layer_config(kind) if hasattr(cfg, "layer_config") else cfg
+        out[kind] = layer_fwd_flops_from_config(layer_cfg, tokens=tokens)
+        if out[kind] is None:
+            return None
+    return out
 
 
 # backward ~= 2x forward (dL/dx and dL/dW each re-run every matmul)
@@ -174,14 +228,16 @@ def run_fwd_flops(cfg: Any, hp: Any) -> Optional[List[float]]:
     layer_runs partitioning); None when the model is not analytically
     describable. The head/embed share is appended as a final pseudo-run so
     shares over the step sum to 1."""
-    from galvatron_tpu.config.strategy import layer_runs
+    from galvatron_tpu.config.strategy import layer_runs, model_layer_kinds
 
     tokens = float(hp.global_bsz) * (getattr(cfg, "max_seq_len", 0) or 0)
-    per_layer = layer_fwd_flops_from_config(cfg, tokens=tokens)
-    if per_layer is None or not tokens:
+    per_kind = layer_kind_fwd_flops(cfg, tokens) if tokens else None
+    if per_kind is None:
         return None
-    out = [per_layer * run.length for run in layer_runs(hp)]
-    out.append(head_fwd_flops_from_config(cfg, tokens=tokens))
+    kinds = _layer_kinds(cfg)
+    out = [per_kind[kinds[run.start]] * run.length
+           for run in layer_runs(hp, model_layer_kinds(cfg))]
+    out.append(_after_layers_fwd_flops(cfg, tokens, per_kind))
     return out
 
 

@@ -51,6 +51,14 @@ ENVELOPE_KEYS = ("v", "t", "seq", "type")
 # `metrics` (models/base.lm_loss_fn) and optional fields of the `step` event
 EXPERT_STEP_FIELDS = ("loss_ce", "loss_load_balance", "loss_router_z",
                       "expert_load_max_over_mean")
+# beside them, where the config has the thing counted: the cross entropy of
+# the token after next (a multi-token-prediction module, before its weight);
+# the rows a step sends through the grouped matmuls of the experts HELD here,
+# over all routed blocks and devices, and that over the even share (tokens x
+# experts a token x held / experts, a block); the largest |bias| of a router
+# that is balanced by one (models/base.lm_loss_fn's parts)
+SHARE_STEP_FIELDS = ("loss_mtp", "expert_rows_held", "expert_rows_held_over_even",
+                     "router_bias_abs_max")
 
 # type -> (required field names, optional field names). Unknown types and
 # unknown keys are rejected; None-valued optional fields are dropped at emit
@@ -86,7 +94,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         # beside the loss, fetched with it
         ("loss", "iter_ms", "dispatch_ms", "data_wait_ms", "host_blocked_ms",
          "hbm_in_use_mb", "hbm_peak_mb", "mfu", "model_flops_per_s",
-         "grad_norm") + EXPERT_STEP_FIELDS,
+         "grad_norm") + EXPERT_STEP_FIELDS + SHARE_STEP_FIELDS,
     ),
     "eval": (("iter", "split", "loss"), ()),
     # lifecycle: checkpointing

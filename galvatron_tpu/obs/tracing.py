@@ -50,6 +50,15 @@ MOE_EXPERTS = "gt.moe.experts"  # the grouped matmuls and SwiGLU
 MOE_GMM_IN = "gmm_in"  # inside MOE_EXPERTS: rows x (hidden, 2 x width), gate and up
 MOE_GMM_OUT = "gmm_out"  # inside MOE_EXPERTS: rows x (width, hidden)
 MOE_COMBINE = "gt.moe.combine"  # back into token order, the weighted sum of k
+MOE_SHARED = "gt.moe.shared"  # the shared expert(s): a dense SwiGLU beside the routed ones
+# latent attention (models/base.latent_qkv_projection), inside gt.layers.r<k>:
+# the low-rank projections, their norms, rope and the output projection,
+# everything of the attention half but the attention call itself
+ATTN_LATENT = "gt.attn.latent"
+# the multi-token-prediction module, top level: its norms, the (2h, h)
+# projection and its block; its pass through the head and its cross entropy
+# run under HEAD_LOSS, beside the main ones
+MTP = "gt.mtp"
 
 
 def layers_scope(run_index: int) -> str:

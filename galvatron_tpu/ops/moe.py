@@ -23,6 +23,20 @@ splits the tokens over devices can add them up first:
 
 Dispatch and combine are permutations, so their transposes are gathers too
 (`_take_rows`, `_dispatch`), not the scatter-adds autodiff would derive.
+
+A second router (`score="sigmoid"`: DeepSeek-V3's, as GLM-4.7-Flash
+configures it, `topk_method: noaux_tc`): each expert's score is the sigmoid
+of its own logit, the `k` experts are chosen by score PLUS a bias that takes
+no gradient, and the weights are the chosen experts' scores alone,
+renormalised and scaled. That router has no auxiliary loss; it hands back the
+assignments an expert got, which the train step moves the bias by.
+
+**A share of the experts** (`held=(first, count)`): the router ranks all the
+experts, this program holds the kernels of `count` of them and computes their
+part of the result for the tokens sent to them; what the experts held
+elsewhere would add is left out. Every assignment is still sorted and
+gathered (the shapes are static), and the grouped matmul runs over the held
+experts' rows alone, so its time follows the routing.
 """
 
 from __future__ import annotations
@@ -36,7 +50,17 @@ from jax.sharding import PartitionSpec as P
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding
 
-Aux = Dict[str, jax.Array]  # load_balance, router_z, load_max_over_mean
+# load_max_over_mean always; load_balance, router_z (the softmax router's
+# losses); counts (E,) and bias_abs_max (a router with a bias); rows_held (a
+# share of the experts): moe_aux_names says which for a configuration
+Aux = Dict[str, jax.Array]
+
+
+def moe_aux_names(score: str, bias: bool, held: bool) -> Tuple[str, ...]:
+    return (("load_max_over_mean",)
+            + (("load_balance", "router_z") if score == "softmax" else ())
+            + (("counts", "bias_abs_max") if bias else ())
+            + (("rows_held",) if held else ()))
 
 
 # (rows, K, N) tiles of the megablox kernels, measured on a v5e at OLMoE's
@@ -49,19 +73,29 @@ GMM_TILING = (512, 1024, 1024)
 
 
 def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array,
-                   on_tpu: bool = False) -> jax.Array:
+                   on_tpu: bool = False, first_group: Optional[int] = None) -> jax.Array:
     """(M, K) rows sorted by group x (G, K, N) kernels -> (M, N): row i is
     multiplied by the kernel of the group it falls in. Groups may be empty.
     On a TPU, where the rows fill whole tiles, the megablox kernels (their
     names carry the caller's scope into a trace; XLA's `ragged-dot` custom
-    call carries none); otherwise `jax.lax.ragged_dot`."""
+    call carries none); otherwise `jax.lax.ragged_dot`.
+
+    `first_group`: the kernels are those of groups `first_group` to
+    `first_group + G` of more groups than G (`group_sizes` counts them all);
+    the rows of the other groups come back zero, and send no gradient. The
+    megablox kernels visit the held groups' tiles alone (`group_offset`,
+    their own form of a sharded expert dim)."""
     if on_tpu and rows.shape[0] % GMM_TILING[0] == 0:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
         tm, tk, tn = GMM_TILING
         wide = rows.dtype.itemsize // 2  # 1 for bf16, 2 for float32
-        return gmm(rows, kernels, group_sizes, preferred_element_type=rows.dtype,
-                   tiling=(tm, tk // wide, tn // wide))
+        offset = None if first_group is None else jnp.int32(first_group)
+        return gmm(rows, kernels, group_sizes, rows.dtype, (tm, tk // wide, tn // wide), offset)
+    if first_group is not None:
+        # ragged_dot has no such form: zero kernels stand in the other groups' places
+        after = group_sizes.shape[0] - first_group - kernels.shape[0]
+        kernels = jnp.pad(kernels, ((first_group, after), (0, 0), (0, 0)))
     return jax.lax.ragged_dot(rows, kernels, group_sizes)
 
 
@@ -111,20 +145,31 @@ def router_logits(y: jax.Array, router_kernel: jax.Array) -> jax.Array:
                    precision=jax.lax.Precision.HIGHEST)
 
 
-def _local_moe(y, router_kernel, wi, wo, *, k: int, norm_topk_prob: bool, activate, dtype,
-               on_tpu: bool, stat_axes: Tuple[str, ...] = ()):
+def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, activate, dtype,
+               on_tpu: bool, score: str = "softmax", scale: float = 1.0,
+               held: Optional[Tuple[int, int]] = None, stat_axes: Tuple[str, ...] = ()):
     """The block on the tokens one device holds; `stat_axes` are the mesh
     axes the router's statistics are summed over (the batch's)."""
     tokens, hidden = y.shape
     num_experts = router_kernel.shape[-1]
     with jax.named_scope(tracing.MOE_ROUTER):
         logits = router_logits(y, router_kernel)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, k)  # (T, k)
-        if norm_topk_prob:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-        z_sum = jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-        prob_sum = jnp.sum(probs, axis=0)  # (E,)
+        if score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+            weights, experts = jax.lax.top_k(probs, k)  # (T, k)
+            if norm_topk_prob:
+                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            z_sum = jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+            prob_sum = jnp.sum(probs, axis=0)  # (E,)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            ranked = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+            experts = jax.lax.top_k(ranked, k)[1]
+            weights = jnp.take_along_axis(scores, experts, axis=-1)  # of the scores alone
+            if norm_topk_prob:
+                weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        if scale != 1.0:
+            weights = weights * scale
     with jax.named_scope(tracing.MOE_DISPATCH):
         flat = experts.reshape(-1)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
@@ -133,26 +178,39 @@ def _local_moe(y, router_kernel, wi, wo, *, k: int, norm_topk_prob: bool, activa
         counts = jnp.sum(flat[:, None] == jnp.arange(num_experts, dtype=flat.dtype),
                          axis=0, dtype=jnp.int32)
         rows = _dispatch(y, order, inv_order)  # (T*k, H), sorted by expert
+    share = {} if held is None else {"first_group": held[0]}
     with jax.named_scope(tracing.MOE_EXPERTS):
         with jax.named_scope(tracing.MOE_GMM_IN):
-            mid = grouped_matmul(rows, wi.astype(dtype), counts, on_tpu)
+            mid = grouped_matmul(rows, wi.astype(dtype), counts, on_tpu, **share)
         mid = activate(mid)
         with jax.named_scope(tracing.MOE_GMM_OUT):
-            out = grouped_matmul(mid, wo.astype(dtype), counts, on_tpu)
+            out = grouped_matmul(mid, wo.astype(dtype), counts, on_tpu, **share)
     with jax.named_scope(tracing.MOE_COMBINE):
         out = _take_rows(out, inv_order, order).reshape(tokens, k, hidden)
         out = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1).astype(dtype)
     with jax.named_scope(tracing.MOE_ROUTER):
         total = jnp.float32(tokens)
         counts_f = counts.astype(jnp.float32)
-        if stat_axes:
-            z_sum, prob_sum, counts_f, total = jax.lax.psum(
-                (z_sum, prob_sum, counts_f, total), stat_axes)
-        aux = {
-            "load_balance": num_experts * jnp.sum(counts_f / total * (prob_sum / total)),
-            "router_z": z_sum / total,
-            "load_max_over_mean": jnp.max(counts_f) / jnp.mean(counts_f),
-        }
+        if score == "softmax":
+            if stat_axes:
+                z_sum, prob_sum, counts_f, total = jax.lax.psum(
+                    (z_sum, prob_sum, counts_f, total), stat_axes)
+            aux = {
+                "load_balance": num_experts * jnp.sum(counts_f / total * (prob_sum / total)),
+                "router_z": z_sum / total,
+            }
+        else:
+            if stat_axes:
+                counts_f = jax.lax.psum(counts_f, stat_axes)
+            aux = {}
+        aux["load_max_over_mean"] = jnp.max(counts_f) / jnp.mean(counts_f)
+        if bias is not None:
+            # the whole batch's assignments an expert: what the step moves the bias by
+            aux["counts"] = counts_f
+            aux["bias_abs_max"] = jnp.max(jnp.abs(bias))
+        if held is not None:
+            aux["rows_held"] = jnp.sum(
+                jax.lax.dynamic_slice_in_dim(counts_f, held[0], held[1]))
     return out, aux
 
 
@@ -164,8 +222,13 @@ def swiglu(mid: jax.Array) -> jax.Array:
 
 def moe_ffn(y: jax.Array, router_kernel: jax.Array, wi: jax.Array, wo: jax.Array, *,
             experts_per_token: int, norm_topk_prob: bool = False, activate=swiglu,
-            dtype=jnp.bfloat16, sharding: Optional[KernelSharding] = None) -> Tuple[jax.Array, Aux]:
-    """y (B, S, H) -> (B, S, H) and the router's auxiliary terms.
+            dtype=jnp.bfloat16, sharding: Optional[KernelSharding] = None,
+            score: str = "softmax", bias: Optional[jax.Array] = None, scale: float = 1.0,
+            held: Optional[Tuple[int, int]] = None) -> Tuple[jax.Array, Aux]:
+    """y (B, S, H) -> (B, S, H) and the router's auxiliary terms
+    (`moe_aux_names`). `score`, `bias` (E,), `scale` and `held`: the second
+    router and a share of the experts, as the module's docstring has them;
+    with `held`, `wi` and `wo` lead with the held experts' count.
 
     `router_kernel` (H, E); `wi` (E, H, W) with W = 2F for SwiGLU, the gate's
     F columns beside the up projection's (one matmul, and no reshape of a
@@ -180,26 +243,27 @@ def moe_ffn(y: jax.Array, router_kernel: jax.Array, wi: jax.Array, wo: jax.Array
     b, s, h = y.shape
     on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
     kw = dict(k=experts_per_token, norm_topk_prob=norm_topk_prob, activate=activate,
-              dtype=dtype, on_tpu=on_tpu)
+              dtype=dtype, on_tpu=on_tpu, score=score, scale=scale, held=held)
     if sharding is None or sharding.mesh.size == 1:
-        out, aux = _local_moe(y.reshape(b * s, h), router_kernel, wi, wo, **kw)
+        out, aux = _local_moe(y.reshape(b * s, h), router_kernel, bias, wi, wo, **kw)
         return out.reshape(b, s, h), aux
 
     batch_axes = tuple(sharding.batch_axes)
 
-    def body(y, router_kernel, wi, wo):
+    def body(y, router_kernel, wi, wo, *bias):
         lb, ls, _ = y.shape
-        out, aux = _local_moe(y.reshape(lb * ls, h), router_kernel, wi, wo,
-                              stat_axes=batch_axes, **kw)
+        out, aux = _local_moe(y.reshape(lb * ls, h), router_kernel, bias[0] if bias else None,
+                              wi, wo, stat_axes=batch_axes, **kw)
         return out.reshape(lb, ls, h), aux
 
     ctx = jax.sharding.get_abstract_mesh()
     use_mesh = sharding.mesh if ctx.empty else ctx
     tokens = P(batch_axes or None, None, None)
+    operands = (y, router_kernel, wi, wo) + (() if bias is None else (bias,))
+    names = moe_aux_names(score, bias is not None, held is not None)
     return jax.shard_map(
-        body, mesh=use_mesh, in_specs=(tokens, P(), P(), P()),
-        out_specs=(tokens, {name: P() for name in ("load_balance", "router_z",
-                                                   "load_max_over_mean")}),
+        body, mesh=use_mesh, in_specs=(tokens,) + (P(),) * (len(operands) - 1),
+        out_specs=(tokens, {name: P() for name in names}),
         axis_names=set(use_mesh.axis_names) - set(use_mesh.manual_axes),
         check_vma=False,
-    )(y, router_kernel, wi, wo)
+    )(*operands)

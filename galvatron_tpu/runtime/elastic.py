@@ -72,6 +72,11 @@ _DIGEST_EXCLUDE = ("compute_dtype", "param_dtype", "attn_impl")
 _DIGEST_DEFAULTS = {
     "num_experts": 0, "experts_per_token": 0, "norm_topk_prob": False,
     "router_aux_loss_coef": 0.0, "router_z_loss_coef": 0.0, "qk_norm": False,
+    "q_lora_rank": 0, "kv_lora_rank": 0, "qk_nope_head_dim": 0, "qk_rope_head_dim": 0,
+    "v_head_dim": 0, "first_dense_layers": 0, "dense_ffn_hidden": None,
+    "num_shared_experts": 0, "router_score": "softmax", "routed_scaling_factor": 1.0,
+    "router_bias": False, "router_bias_update_rate": 0.0, "experts_held": 0,
+    "experts_held_start": 0, "mtp_layers": 0, "mtp_loss_weight": 0.0,
 }
 
 

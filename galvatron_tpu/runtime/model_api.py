@@ -407,12 +407,11 @@ class HybridParallelModel:
                     w = weights[c]
                     if with_parts:
                         # the terms weighted as the loss is; the load of the
-                        # fullest microbatch
+                        # fullest microbatch, the counts of all (PART_FOLDS)
                         l, mp = l
-                        mp = {k: v if k == M.EXPERT_LOAD else v * w for k, v in mp.items()}
+                        mp = {k: v if k in M.PART_FOLDS else v * w for k, v in mp.items()}
                         parts = mp if not parts else {
-                            k: jnp.maximum(parts[k], v) if k == M.EXPERT_LOAD else parts[k] + v
-                            for k, v in mp.items()}
+                            k: M.PART_FOLDS.get(k, jnp.add)(parts[k], v) for k, v in mp.items()}
                     with jax.named_scope(tracing.GRAD_ACCUM):
                         g = jax.tree.map(
                             lambda gi, p, s: jax.lax.with_sharding_constraint(
@@ -427,6 +426,14 @@ class HybridParallelModel:
                 updates, new_opt_state = tx.update(grads, opt_state, params)
                 new_params = optax.apply_updates(params, updates)
                 grad_norm = optax.global_norm(grads)
+                if M.ROUTER_COUNTS in parts:
+                    # the one piece of state no gradient moves: the routers'
+                    # bias steps against the sign of each expert's load over
+                    # the whole batch (the counts arrive summed over dp and
+                    # over the microbatches); Adam holds no state of it
+                    # (runtime/optimizer.NO_GRADIENT_KEYS)
+                    new_params = M.update_router_bias(
+                        new_params, parts.pop(M.ROUTER_COUNTS), self.cfg.router_bias_update_rate)
             metrics = {"loss": loss, "grad_norm": grad_norm, **parts}
             if guard_anomalies:
                 bad = jnp.logical_or(

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import PartitionSpec as P
 
+from galvatron_tpu.models.base import ROUTER_BIAS
+
 
 @dataclass
 class OptimizerArgs:
@@ -50,10 +52,46 @@ def _no_weight_decay(path, _leaf) -> bool:
     return not ({"bias", "scale"} & {k for k in keys if isinstance(k, str)})
 
 
+# Leaves of the parameter tree that no gradient moves, by their key: a
+# sigmoid router's `e_score_correction_bias` (models/base.ROUTER_BIAS), which
+# the train step itself steps once a step (models/base.update_router_bias).
+# The optimizer never sees them: no clipping share, no Adam moments, no decay.
+NO_GRADIENT_KEYS = (ROUTER_BIAS,)
+
+
+def _without_no_gradient_leaves(tree):
+    """`tree` with the NO_GRADIENT_KEYS leaves replaced by None (an empty
+    subtree): a tree without any is returned leaf for leaf as it came."""
+    def keep(path, leaf):
+        return None if getattr(path[-1], "key", None) in NO_GRADIENT_KEYS else leaf
+
+    return jax.tree_util.tree_map_with_path(keep, tree, is_leaf=lambda x: isinstance(x, P))
+
+
+def on_gradient_leaves(inner: optax.GradientTransformation) -> optax.GradientTransformation:
+    """`inner` run on the tree without its NO_GRADIENT_KEYS leaves, which get
+    a zero update and no place in `inner`'s state. For a tree that has none
+    (every model but one with such a router) state and updates are `inner`'s
+    own, structure and values."""
+    def init(params):
+        return inner.init(_without_no_gradient_leaves(params))
+
+    def update(updates, state, params=None):
+        pruned = _without_no_gradient_leaves(updates)
+        out, state = inner.update(
+            pruned, state, None if params is None else _without_no_gradient_leaves(params))
+        # a pruned leaf's place holds None in `out`
+        out = jax.tree.map(lambda g, u: jnp.zeros_like(g) if u is None else u,
+                           updates, out, is_leaf=lambda x: x is None)
+        return out, state
+
+    return optax.GradientTransformation(init, update)
+
+
 def get_optimizer_and_scheduler(args: Optional[OptimizerArgs] = None):
     a = args or OptimizerArgs()
     schedule = make_schedule(a)
-    tx = optax.chain(
+    tx = on_gradient_leaves(optax.chain(
         optax.clip_by_global_norm(a.clip_grad) if a.clip_grad and a.clip_grad > 0 else optax.identity(),
         optax.scale_by_adam(b1=a.adam_beta1, b2=a.adam_beta2, eps=a.adam_eps),
         optax.add_decayed_weights(
@@ -63,7 +101,7 @@ def get_optimizer_and_scheduler(args: Optional[OptimizerArgs] = None):
         if a.weight_decay
         else optax.identity(),
         optax.scale_by_learning_rate(schedule),
-    )
+    ))
     return tx, schedule
 
 
@@ -105,11 +143,11 @@ def opt_state_specs(tx_state, param_specs, param_shapes, zero_axes_tree, mesh):
 
     def map_state(state):
         if isinstance(state, optax.ScaleByAdamState):
-            mu = jax.tree.map(moment_spec, param_specs, param_shapes, zero_axes_tree,
-                              is_leaf=lambda x: isinstance(x, P))
-            nu = jax.tree.map(moment_spec, param_specs, param_shapes, zero_axes_tree,
-                              is_leaf=lambda x: isinstance(x, P))
-            return optax.ScaleByAdamState(count=P(), mu=mu, nu=nu)
+            # the moments' own tree: without the leaves Adam never sees
+            mu = _without_no_gradient_leaves(jax.tree.map(
+                moment_spec, param_specs, param_shapes, zero_axes_tree,
+                is_leaf=lambda x: isinstance(x, P)))
+            return optax.ScaleByAdamState(count=P(), mu=mu, nu=mu)
         if isinstance(state, tuple) and type(state) is not tuple:
             # other NamedTuple states: replicate scalars, param-like trees get param specs
             return jax.tree.map(lambda _: P(), state)

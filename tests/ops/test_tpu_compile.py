@@ -399,3 +399,49 @@ def test_the_routed_experts_block_is_a_manual_region_on_a_dp4_mesh(v5e_2x2):
         8, NamedSharding(mesh, P("dp", None, None)), NamedSharding(mesh, P()))).compile().as_text()
     assert len(re.findall(MEGABLOX_CALL, text)) == 6
     assert "all-reduce" in text and "all-to-all" not in text and "all-gather" not in text
+
+
+# ------------------------- GLM-4.7-Flash: the kernels' shapes new with PR 32
+def test_flash_kernel_compiles_at_head_dim_256_for_v5e(v5e_2x2):
+    """Latent attention calls the kernel once at q/k = v = 256 dims a head (20
+    heads, 8192 tokens): the repo's 1024 x 512 blocks, which every other cell
+    runs at 128, still fit the scoped VMEM at twice the width in bf16 (in
+    float32 they do not: a float32 program of this family takes XLA's path)."""
+    one = SingleDeviceSharding(v5e_2x2[0])
+    operand = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16, sharding=one)
+    fn = jax.grad(_attn_loss(A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("x",)))),
+                  argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(operand, operand, operand).compile().as_text()
+    assert text.count("tpu_custom_call") == 3  # forward, dkv, dq
+    assert "block_q_1024" in text and "block_k_512" in text.replace("block_k_major_512", "block_k_512")
+
+
+def test_a_held_share_of_the_experts_compiles_for_v5e(v5e_2x2):
+    """ops/moe.py at GLM-4.7-Flash's widths with 8 of the 64 experts held,
+    forward and backward: the sigmoid router with its bias ranks all 64, the
+    megablox kernels take the held groups' offset (`group_offset`) and the
+    kernels of 8 experts, and the counters come back."""
+    from galvatron_tpu.ops.moe import moe_ffn
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    on_chip = A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("x",)))
+    h, width, experts, held, k = 2048, 1536, 64, 8, 4
+
+    def loss(y, router, bias, wi, wo):
+        out, aux = moe_ffn(y, router, wi, wo, experts_per_token=k, norm_topk_prob=True,
+                           dtype=y.dtype, sharding=on_chip, score="sigmoid", bias=bias,
+                           scale=1.8, held=(16, held))
+        return jnp.sum(out.astype(jnp.float32) ** 2), aux
+
+    f32 = jnp.float32
+    operands = (jax.ShapeDtypeStruct((1, 8192, h), jnp.bfloat16, sharding=one),
+                jax.ShapeDtypeStruct((h, experts), f32, sharding=one),
+                jax.ShapeDtypeStruct((experts,), f32, sharding=one),
+                jax.ShapeDtypeStruct((held, h, 2 * width), f32, sharding=one),
+                jax.ShapeDtypeStruct((held, width, h), f32, sharding=one))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4), has_aux=True)).lower(*operands).compile()
+    text = compiled.as_text()
+    assert len(re.findall(MEGABLOX_CALL, text)) == 6 and "ragged-dot" not in text
+    aux = jax.eval_shape(loss, *operands)[1]
+    assert set(aux) == {"load_max_over_mean", "counts", "bias_abs_max", "rows_held"}
+    assert aux["counts"].shape == (experts,)
