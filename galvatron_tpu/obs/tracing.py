@@ -49,7 +49,10 @@ MOE_DISPATCH = "gt.moe.dispatch"  # sort the assignments by expert, gather the r
 MOE_EXPERTS = "gt.moe.experts"  # the grouped matmuls and SwiGLU
 MOE_GMM_IN = "gmm_in"  # inside MOE_EXPERTS: rows x (hidden, 2 x width), gate and up
 MOE_GMM_OUT = "gmm_out"  # inside MOE_EXPERTS: rows x (width, hidden)
-MOE_COMBINE = "gt.moe.combine"  # back into token order, the weighted sum of k
+# back into token order as k slabs of (tokens, hidden) (k-major: a TPU tiles an
+# array's two minor dimensions by 8 x 128, so k stays out of them), their
+# weighted sum; backward, the cotangent gathered into expert order and weighted
+MOE_COMBINE = "gt.moe.combine"
 MOE_SHARED = "gt.moe.shared"  # the shared expert(s): a dense SwiGLU beside the routed ones
 # latent attention (models/base.latent_qkv_projection), inside gt.layers.r<k>:
 # the low-rank projections, their norms, rope and the output projection,

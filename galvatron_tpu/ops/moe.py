@@ -21,8 +21,20 @@ splits the tokens over devices can add them up first:
                                          P_e = mean router probability of e
     router z-loss    mean_t logsumexp(logits_t)^2
 
+**The layout is k-major**: assignment `j x tokens + t` is token `t`'s `j`-th
+choice, so whatever is in token order is `k` slabs of `(tokens, hidden)` and
+the sums over `k` (the combine's forward, the dispatch's backward) add slabs.
+A TPU tiles an array's two minor dimensions by 8 x 128: with `k` there, as in
+`(tokens, k, hidden)`, every `k` that is not a multiple of 8 is padded to one,
+each reshape to `(tokens x k, hidden)` moves every row, and the compiler
+fuses nothing across it (float32 copies of all rows; PERF.md, PR 34). The ROWS
+enter the grouped matmuls as they always did, expert by expert and token by
+token within one: the sort's key is `expert x tokens + t`.
+
 Dispatch and combine are permutations, so their transposes are gathers too
-(`_take_rows`, `_dispatch`), not the scatter-adds autodiff would derive.
+(`_dispatch`, `_combine`), not the scatter-adds autodiff would derive, and the
+combine's backward works in expert order, where the rows and their cotangent
+live: it keeps the bf16 rows and never a float32 array of every row.
 
 A second router (`score="sigmoid"`: DeepSeek-V3's, as GLM-4.7-Flash
 configures it, `topk_method: noaux_tc`): each expert's score is the sigmoid
@@ -99,31 +111,20 @@ def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array,
     return jax.lax.ragged_dot(rows, kernels, group_sizes)
 
 
-@jax.custom_vjp
-def _take_rows(x, perm, inv_perm):
-    """x[perm] for a permutation `perm` whose inverse is `inv_perm`; the
-    cotangent is gathered back by the inverse, not scattered."""
-    return x[perm]
-
-
-def _take_rows_fwd(x, perm, inv_perm):
-    return x[perm], (perm, inv_perm)
-
-
-def _take_rows_bwd(res, g):
-    perm, inv_perm = res
-    return g[inv_perm], None, None
-
-
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _k_major(x):
+    """(tokens, k) -> (k x tokens,): entry j x tokens + t is x[t, j]. Columns
+    laid end to end: a transpose and a reshape would be a relayout on a TPU
+    wherever k does not fill a tile."""
+    return jnp.concatenate([x[:, j] for j in range(x.shape[1])])
 
 
 @jax.custom_vjp
 def _dispatch(y, order, inv_order):
-    """Row order[i] // k of y, for every assignment i in expert order: each
-    token's row k times over. The cotangent of token t is the sum of its k
-    assignments' cotangents, gathered back into token order."""
-    return y[order // (order.shape[0] // y.shape[0])]
+    """Row order[i] % tokens of y, for every assignment i in expert order:
+    each token's row k times over. The cotangent of token t is the sum of its
+    k assignments' cotangents, gathered back into token order and summed over
+    the major axis."""
+    return y[order % y.shape[0]]
 
 
 def _dispatch_fwd(y, order, inv_order):
@@ -132,11 +133,58 @@ def _dispatch_fwd(y, order, inv_order):
 
 def _dispatch_bwd(res, g):
     inv_order, tokens = res
-    g = g[inv_order].reshape(tokens, -1, g.shape[-1])
-    return jnp.sum(g.astype(jnp.float32), axis=1).astype(g.dtype), None, None
+    return _sum_over_k(g, inv_order, tokens, None), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def _sum_over_k(rows, inv_order, tokens, weights):
+    """sum_j rows[inv_order[j x tokens + t]] (x weights[t, j]) in float32, j
+    = 0 .. k-1 in that order, rounded once to the rows' dtype: (k x tokens,
+    H) rows in expert order -> (tokens, H). One gather into token order, then
+    its k slabs of `tokens` rows as slices, and no reshape between the gather
+    and the sum: a reshape there the TPU compiler moves off the gather and
+    then fuses nothing across (a float32 copy of every row, written and read
+    again). A gather a slab instead: 1 % faster at k = 4 in the one routed
+    cell, 1 % slower at k = 8 in the other and 2 % more memory there (PERF.md,
+    PR 34)."""
+    total = None
+    rows = rows[inv_order]
+    for j in range(inv_order.shape[0] // tokens):
+        term = rows[j * tokens:(j + 1) * tokens].astype(jnp.float32)
+        if weights is not None:
+            term = term * weights[:, j, None]
+        total = term if total is None else term + total
+    return total.astype(rows.dtype)
+
+
+@jax.custom_vjp
+def _combine(out, weights, order, inv_order):
+    """(k x tokens, H) rows in expert order, float32 (tokens, k) weights ->
+    (tokens, H): token t's k rows, weighted and summed in float32."""
+    return _sum_over_k(out, inv_order, weights.shape[0], weights)
+
+
+def _combine_fwd(out, weights, order, inv_order):
+    return _combine(out, weights, order, inv_order), (out, weights, order, inv_order)
+
+
+def _combine_bwd(res, g):
+    # in EXPERT order, where the rows and their cotangent live: the token's
+    # cotangent gathered to each of its assignments (as `_dispatch` gathers
+    # the token's row), then one pass over it and the rows
+    out, weights, order, inv_order = res
+    tokens, k = weights.shape
+    g = g[order % tokens].astype(jnp.float32)
+    w = _k_major(weights)[order]
+    d_out = (g * w[:, None]).astype(out.dtype)
+    d_w = jnp.sum(out.astype(jnp.float32) * g, axis=-1)[inv_order]
+    d_w = jnp.stack([d_w[j * tokens:(j + 1) * tokens] for j in range(k)], axis=1)
+    return d_out, d_w, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def router_logits(y: jax.Array, router_kernel: jax.Array) -> jax.Array:
@@ -150,7 +198,7 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
                held: Optional[Tuple[int, int]] = None, stat_axes: Tuple[str, ...] = ()):
     """The block on the tokens one device holds; `stat_axes` are the mesh
     axes the router's statistics are summed over (the batch's)."""
-    tokens, hidden = y.shape
+    tokens = y.shape[0]
     num_experts = router_kernel.shape[-1]
     with jax.named_scope(tracing.MOE_ROUTER):
         logits = router_logits(y, router_kernel)
@@ -171,10 +219,13 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
         if scale != 1.0:
             weights = weights * scale
     with jax.named_scope(tracing.MOE_DISPATCH):
-        flat = experts.reshape(-1)
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        inv_order = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32), unique_indices=True)
+        # k-major: assignment j x tokens + t is token t's j-th choice
+        flat = _k_major(experts)
+        assert num_experts * tokens < 2**31
+        slot = jnp.arange(k * tokens, dtype=jnp.int32)
+        # a token's k experts are distinct, so the keys are: by expert, by token within one
+        order = jnp.argsort(flat * tokens + slot % tokens).astype(jnp.int32)
+        inv_order = jnp.zeros_like(order).at[order].set(slot, unique_indices=True)
         counts = jnp.sum(flat[:, None] == jnp.arange(num_experts, dtype=flat.dtype),
                          axis=0, dtype=jnp.int32)
         rows = _dispatch(y, order, inv_order)  # (T*k, H), sorted by expert
@@ -186,8 +237,7 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
         with jax.named_scope(tracing.MOE_GMM_OUT):
             out = grouped_matmul(mid, wo.astype(dtype), counts, on_tpu, **share)
     with jax.named_scope(tracing.MOE_COMBINE):
-        out = _take_rows(out, inv_order, order).reshape(tokens, k, hidden)
-        out = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1).astype(dtype)
+        out = _combine(out, weights, order, inv_order).astype(dtype)
     with jax.named_scope(tracing.MOE_ROUTER):
         total = jnp.float32(tokens)
         counts_f = counts.astype(jnp.float32)

@@ -108,7 +108,7 @@ def main() -> int:
     wo = jax.random.normal(key, (EXPERTS, WIDTH, HIDDEN), jnp.float32) * 0.02
 
     def block_loss(y, router, wi, wo):
-        out, aux = moe._local_moe(y, router, wi, wo, k=K, norm_topk_prob=False,
+        out, aux = moe._local_moe(y, router, None, wi, wo, k=K, norm_topk_prob=False,
                                   activate=moe.swiglu, dtype=jnp.bfloat16, on_tpu=True)
         return jnp.sum(out.astype(jnp.float32) ** 2) + aux["load_balance"] + aux["router_z"]
 
@@ -120,12 +120,13 @@ def main() -> int:
         print(label, json.dumps(results["block"][label]), flush=True)
 
     measure_block("as_committed")
-    committed = moe._take_rows, moe._dispatch
+    committed = moe._dispatch, moe._combine
     # what autodiff derives: the gathers' transposes as scatter-adds
-    moe._take_rows = lambda x, perm, inv_perm: x[perm]
-    moe._dispatch = lambda y, order, inv_order: y[order // (order.shape[0] // y.shape[0])]
+    moe._dispatch = lambda y, order, inv_order: y[order % y.shape[0]]
+    moe._combine = lambda out, weights, order, inv_order: moe._sum_over_k(
+        out, inv_order, weights.shape[0], weights)
     measure_block("autodiff_scatter_transposes")
-    moe._take_rows, moe._dispatch = committed
+    moe._dispatch, moe._combine = committed
 
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)

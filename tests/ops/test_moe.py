@@ -1,0 +1,172 @@
+"""ops/moe.py against a plain per-token loop, float32 on the CPU.
+
+The loop below sorts nothing and gathers nothing: for every token it walks
+the token's `k` chosen experts, multiplies the token's row by that expert's
+two kernels and adds the result at the router's weight. The block must give
+the same output, the same gradient to every operand and the same counters,
+whatever `k` is (a TPU tiles an array's two minor dimensions by 8 x 128, so
+the block keeps its `tokens x k` assignments k-major: `ops/moe.py`).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from galvatron_tpu.ops import moe
+
+TOKENS, HIDDEN, WIDTH, EXPERTS = 24, 16, 8, 16
+HELD = (5, 6)  # experts 5 to 10 of the 16
+KS = (1, 2, 4, 6, 8)
+ROUTERS = {
+    "softmax": dict(score="softmax", norm_topk_prob=False, scale=1.0),
+    "sigmoid": dict(score="sigmoid", norm_topk_prob=True, scale=1.8),
+}
+
+
+def _operands(seed, held):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    n = EXPERTS if held is None else held[1]
+    return dict(
+        y=jax.random.normal(keys[0], (TOKENS, HIDDEN), jnp.float32),
+        router=jax.random.normal(keys[1], (HIDDEN, EXPERTS), jnp.float32) * 0.3,
+        wi=jax.random.normal(keys[2], (n, HIDDEN, 2 * WIDTH), jnp.float32) * 0.2,
+        wo=jax.random.normal(keys[3], (n, WIDTH, HIDDEN), jnp.float32) * 0.2,
+        bias=jax.random.normal(keys[4], (EXPERTS,), jnp.float32) * 0.05,
+        cot=jax.random.normal(keys[5], (TOKENS, HIDDEN), jnp.float32),
+    )
+
+
+def _scores(y, router, score):
+    logits = jnp.dot(y, router, precision=jax.lax.Precision.HIGHEST)
+    return logits, (jax.nn.softmax(logits, axis=-1) if score == "softmax" else jax.nn.sigmoid(logits))
+
+
+def _choices(ops, k, score):
+    """(tokens, k) numpy: the k highest experts a token, ties to the lower
+    index, by the score (plus the bias for the sigmoid router)."""
+    ranked = np.asarray(_scores(ops["y"], ops["router"], score)[1])
+    if score == "sigmoid":
+        ranked = ranked + np.asarray(ops["bias"])
+    return np.argsort(-ranked, axis=-1, kind="stable")[:, :k]
+
+
+def _loop(y, router, wi, wo, choices, *, score, norm_topk_prob, scale, held):
+    """The block as a loop over tokens and their choices; `choices` concrete."""
+    first, count = (0, EXPERTS) if held is None else held
+    logits, scores = _scores(y, router, score)
+    out = []
+    for t in range(TOKENS):
+        w = scores[t, choices[t]]
+        if norm_topk_prob:
+            w = w / (jnp.sum(w) + (1e-20 if score == "sigmoid" else 0.0))
+        w = w * scale
+        row = jnp.zeros((HIDDEN,), jnp.float32)
+        for j, e in enumerate(choices[t] - first):
+            if 0 <= e < count:
+                mid = y[t] @ wi[e]
+                row = row + w[j] * ((jax.nn.silu(mid[:WIDTH]) * mid[WIDTH:]) @ wo[e])
+        out.append(row)
+    counts = np.bincount(choices.reshape(-1), minlength=EXPERTS).astype(np.float32)
+    aux = {"load_max_over_mean": counts.max() / counts.mean()}
+    if score == "softmax":
+        aux["load_balance"] = EXPERTS * jnp.sum(counts / TOKENS * jnp.mean(scores, axis=0))
+        aux["router_z"] = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    else:
+        aux["counts"] = counts
+    if held is not None:
+        aux["rows_held"] = counts[first:first + count].sum()
+    return jnp.stack(out), aux
+
+
+def _block(y, router, wi, wo, bias, k, router_kw, held):
+    out, aux = moe.moe_ffn(y[None], router, wi, wo, experts_per_token=k, dtype=jnp.float32,
+                           bias=bias if router_kw["score"] == "sigmoid" else None, held=held,
+                           **router_kw)
+    return out[0], aux
+
+
+def _objective(out, aux, cot, score):
+    return jnp.sum(out * cot) + (aux["load_balance"] + aux["router_z"] if score == "softmax" else 0.0)
+
+
+@pytest.mark.parametrize("held", [None, HELD], ids=["all_held", "a_share"])
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+@pytest.mark.parametrize("k", KS)
+def test_block_equals_a_per_token_loop(k, router, held):
+    kw = ROUTERS[router]
+    ops = _operands(100 * k + len(router), held)
+    choices = _choices(ops, k, kw["score"])
+    if held is not None:  # the case means something: some rows held, some not
+        inside = (choices >= held[0]) & (choices < held[0] + held[1])
+        assert 0 < inside.sum() < inside.size
+
+    def ours(y, router_kernel, wi, wo, bias):
+        out, aux = _block(y, router_kernel, wi, wo, bias, k, kw, held)
+        return _objective(out, aux, ops["cot"], kw["score"]), (out, aux)
+
+    def plain(y, router_kernel, wi, wo, bias):
+        out, aux = _loop(y, router_kernel, wi, wo, choices, held=held, **kw)
+        return _objective(out, aux, ops["cot"], kw["score"]), (out, aux)
+
+    args = tuple(ops[n] for n in ("y", "router", "wi", "wo", "bias"))
+    (_, (out, aux)), grads = jax.value_and_grad(ours, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    (_, (want, want_aux)), want_grads = jax.value_and_grad(
+        plain, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6)
+    for name, got, ref in zip(("y", "router", "wi", "wo", "bias"), grads, want_grads):
+        np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-6, err_msg="gradient of " + name)
+    assert not np.any(np.asarray(grads[4]))  # no gradient moves the bias
+    assert set(aux) == set(moe.moe_aux_names(kw["score"], kw["score"] == "sigmoid", held is not None))
+    for name, ref in want_aux.items():
+        np.testing.assert_allclose(aux[name], ref, rtol=1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("k", KS)
+def test_rows_enter_the_grouped_matmul_by_expert_then_by_token(k, monkeypatch):
+    """What `gmm` / `tgmm` see: expert by expert, and within an expert token
+    by token, each group as long as the expert's count."""
+    kw = ROUTERS["sigmoid"]
+    ops = _operands(7 + k, None)
+    choices = _choices(ops, k, "sigmoid")
+    seen = []
+    committed = moe.grouped_matmul
+
+    def recording(rows, kernels, group_sizes, *a, **kwargs):
+        seen.append((np.asarray(rows), np.asarray(group_sizes)))
+        return committed(rows, kernels, group_sizes, *a, **kwargs)
+
+    monkeypatch.setattr(moe, "grouped_matmul", recording)
+    _block(*(ops[n] for n in ("y", "router", "wi", "wo", "bias")), k, kw, None)
+
+    pairs = sorted((e, t) for t in range(TOKENS) for e in choices[t])
+    rows, group_sizes = seen[0]
+    np.testing.assert_array_equal(rows, np.asarray(ops["y"])[[t for _, t in pairs]])
+    np.testing.assert_array_equal(group_sizes, np.bincount([e for e, _ in pairs], minlength=EXPERTS))
+    np.testing.assert_array_equal(seen[1][1], group_sizes)
+
+
+@pytest.mark.parametrize("k", KS)
+def test_a_share_sends_no_gradient_through_rows_it_does_not_hold(k):
+    """A token none of whose experts is held gets a zero row back and sends
+    exactly nothing to any operand; a token with a held expert does."""
+    kw = ROUTERS["sigmoid"]
+    held = (5, 3)  # few enough that at k = 8 some token still has none of them
+    ops = _operands(31 + k, held)
+    choices = _choices(ops, k, "sigmoid")
+    holds = ((choices >= held[0]) & (choices < held[0] + held[1])).any(axis=1)
+    assert holds.any() and not holds.all()
+
+    def through(token_mask):
+        def f(y, router_kernel, wi, wo):
+            out, _ = _block(y, router_kernel, wi, wo, ops["bias"], k, kw, held)
+            return jnp.sum(out * ops["cot"] * token_mask[:, None])
+        return jax.grad(f, argnums=(0, 1, 2, 3))(*(ops[n] for n in ("y", "router", "wi", "wo")))
+
+    out, _ = _block(*(ops[n] for n in ("y", "router", "wi", "wo", "bias")), k, kw, held)
+    assert not np.any(np.asarray(out)[~holds]) and np.all(np.any(np.asarray(out)[holds] != 0, axis=1))
+    d_y = np.asarray(through(jnp.ones((TOKENS,)))[0])
+    assert not np.any(d_y[~holds]) and np.all(np.any(d_y[holds] != 0, axis=1))
+    for name, g in zip(("y", "router", "wi", "wo"), through(jnp.asarray(~holds, jnp.float32))):
+        assert not np.any(np.asarray(g)), name

@@ -10,6 +10,7 @@ described device cannot be read back without one, and the next compile would
 warn and compile again.
 """
 
+import functools
 import os
 import re
 import subprocess
@@ -416,32 +417,74 @@ def test_flash_kernel_compiles_at_head_dim_256_for_v5e(v5e_2x2):
     assert "block_q_1024" in text and "block_k_512" in text.replace("block_k_major_512", "block_k_512")
 
 
-def test_a_held_share_of_the_experts_compiles_for_v5e(v5e_2x2):
-    """ops/moe.py at GLM-4.7-Flash's widths with 8 of the 64 experts held,
-    forward and backward: the sigmoid router with its bias ranks all 64, the
-    megablox kernels take the held groups' offset (`group_offset`) and the
-    kernels of 8 experts, and the counters come back."""
+GLM_TOKENS, GLM_H, GLM_WIDTH, GLM_EXPERTS, GLM_HELD = 8192, 2048, 1536, 64, 8  # the cell glm47f-c1-s8k
+
+
+def _held_share(v5e_2x2, k):
+    """ops/moe.py at GLM-4.7-Flash's widths with 8 of the 64 experts held and
+    `k` a token: the loss, its operands' shapes, forward + backward compiled."""
     from galvatron_tpu.ops.moe import moe_ffn
 
     one = SingleDeviceSharding(v5e_2x2[0])
     on_chip = A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("x",)))
-    h, width, experts, held, k = 2048, 1536, 64, 8, 4
 
     def loss(y, router, bias, wi, wo):
         out, aux = moe_ffn(y, router, wi, wo, experts_per_token=k, norm_topk_prob=True,
                            dtype=y.dtype, sharding=on_chip, score="sigmoid", bias=bias,
-                           scale=1.8, held=(16, held))
+                           scale=1.8, held=(16, GLM_HELD))
         return jnp.sum(out.astype(jnp.float32) ** 2), aux
 
     f32 = jnp.float32
-    operands = (jax.ShapeDtypeStruct((1, 8192, h), jnp.bfloat16, sharding=one),
-                jax.ShapeDtypeStruct((h, experts), f32, sharding=one),
-                jax.ShapeDtypeStruct((experts,), f32, sharding=one),
-                jax.ShapeDtypeStruct((held, h, 2 * width), f32, sharding=one),
-                jax.ShapeDtypeStruct((held, width, h), f32, sharding=one))
+    operands = (jax.ShapeDtypeStruct((1, GLM_TOKENS, GLM_H), jnp.bfloat16, sharding=one),
+                jax.ShapeDtypeStruct((GLM_H, GLM_EXPERTS), f32, sharding=one),
+                jax.ShapeDtypeStruct((GLM_EXPERTS,), f32, sharding=one),
+                jax.ShapeDtypeStruct((GLM_HELD, GLM_H, 2 * GLM_WIDTH), f32, sharding=one),
+                jax.ShapeDtypeStruct((GLM_HELD, GLM_WIDTH, GLM_H), f32, sharding=one))
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4), has_aux=True)).lower(*operands).compile()
-    text = compiled.as_text()
+    return loss, operands, compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def held_share(v5e_2x2):
+    return functools.cache(lambda k: _held_share(v5e_2x2, k))  # one compile a k
+
+
+def test_a_held_share_of_the_experts_compiles_for_v5e(held_share):
+    """Forward and backward: the sigmoid router with its bias ranks all 64,
+    the megablox kernels take the held groups' offset (`group_offset`) and the
+    kernels of 8 experts, and the counters come back."""
+    loss, operands, text = held_share(4)
     assert len(re.findall(MEGABLOX_CALL, text)) == 6 and "ragged-dot" not in text
     aux = jax.eval_shape(loss, *operands)[1]
     assert set(aux) == {"load_max_over_mean", "counts", "bias_abs_max", "rows_held"}
-    assert aux["counts"].shape == (experts,)
+    assert aux["counts"].shape == (GLM_EXPERTS,)
+
+
+@pytest.mark.parametrize("k", [4, 6, 8])
+def test_the_routed_block_keeps_k_out_of_the_tiles_on_v5e(held_share, k):
+    """A TPU tiles an array's two minor dimensions by 8 x 128, so a (tokens, k,
+    hidden) array whose k is not a multiple of 8 is padded to one, every
+    reshape to or from (tokens x k, hidden) moves every row, and the compiler
+    stops fusing across it: float32 copies of all rows, a broadcast of the
+    cotangent written out (PERF.md, PR 34). `ops/moe.py` keeps the assignments
+    k-major and sums over k slab by slab, so between the gathers and the sums
+    of dispatch and combine nothing of the kind is left, whatever k is. A
+    reshape that survives to the compiled text is a physical one."""
+    from galvatron_tpu.obs import tracing
+
+    text = held_share(k)[2]
+    assert len(re.findall(MEGABLOX_CALL, text)) == 6
+    every_row = GLM_TOKENS * k * GLM_H
+    ops = [line.strip() for line in text[text.index("ENTRY "):].splitlines()
+           if re.search(r'op_name="[^"]*(%s|%s)' % (re.escape(tracing.MOE_COMBINE),
+                                                     re.escape(tracing.MOE_DISPATCH)), line)]
+    assert len(ops) > 10 and any("transpose(jvp(%s))" % tracing.MOE_COMBINE in op for op in ops)
+    offenders = []
+    for op in ops:
+        name, result, kind = re.match(r"(\S+) = (.*?[})]) ([a-z\-]+)\(", op).groups()
+        sizes = [(dtype, int(np.prod([int(d) for d in dims.split(",") if d])))
+                 for dtype, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]*)\]", result)]
+        if (kind == "reshape" or (kind == "broadcast" and every_row in [n for _, n in sizes])
+                or ("f32", every_row) in sizes):
+            offenders.append("%s = %s %s" % (name, result, kind))
+    assert not offenders, "\n".join(offenders)
