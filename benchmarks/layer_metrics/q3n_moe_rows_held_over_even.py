@@ -1,0 +1,11 @@
+"""`moe_rows_held_over_even` in the Qwen3-Next cell: the rows a step sent
+through the 32 held experts' grouped matmuls over the even share (8192 x 10 x
+32 / 512 = 5120 a block), the `step` counter `expert_rows_held_over_even`. 1
+is what the model FLOPs count. The GLM cell's reader, whose entry lists its
+own cell."""
+
+from benchmarks.layer_metrics import moe_rows_held_over_even
+
+
+def read(run):
+    return moe_rows_held_over_even.read(run)

@@ -754,7 +754,8 @@ def _train(args) -> dict:
             grad_norm=grad_norm if grad_norm is None or np.isfinite(grad_norm) else None,
             # a routed-experts config's step hands these back beside the loss
             **{k: float(metrics[k])
-               for k in telemetry.EXPERT_STEP_FIELDS + telemetry.SHARE_STEP_FIELDS
+               for k in (telemetry.EXPERT_STEP_FIELDS + telemetry.SHARE_STEP_FIELDS
+                         + telemetry.LINEAR_STEP_FIELDS)
                if isinstance(metrics, dict) and k in metrics},
         )
 

@@ -37,6 +37,7 @@ from galvatron_tpu.config.strategy import (
 )
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding, core_attention
+from galvatron_tpu.ops.linear_attention import causal_conv, gated_delta_rule
 from galvatron_tpu.ops.moe import moe_ffn, swiglu
 from galvatron_tpu.ops.norms import layer_norm, rms_norm
 from galvatron_tpu.ops.rope import apply_rotary
@@ -90,7 +91,9 @@ class TransformerConfig:
     norm_topk_prob: bool = False  # renormalise the chosen experts' weights
     router_aux_loss_coef: float = 0.0  # x the load-balancing loss
     router_z_loss_coef: float = 0.0  # x the router z-loss
-    qk_norm: bool = False  # a norm over the whole projected q and the whole k
+    # a norm of q and of k before rope: True over the WHOLE projection (OLMoE),
+    # "head" over each head's dims with one scale for all heads (Qwen3-Next)
+    qk_norm: Any = False
     # --- what GLM-4.7-Flash's published config adds (glm4_moe_lite, the
     # DeepSeek-V3 block); again the defaults are the model without them ---
     # latent attention (MLA): q and k/v are projected down to a low rank,
@@ -119,6 +122,23 @@ class TransformerConfig:
     experts_held_start: int = 0
     mtp_layers: int = 0  # multi-token-prediction modules (0 or 1) after the stack
     mtp_loss_weight: float = 0.0  # x the cross entropy of the token after next
+    # --- what Qwen3-Next's published config adds (qwen3_next): layers whose
+    # token mixer is a gated-DeltaNet linear attention (`linear_mixer`,
+    # ops/linear_attention.py) among layers of gated softmax attention ---
+    # > 0: layer i attends where (i + 1) % this == 0 and is linear elsewhere
+    full_attention_interval: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0  # each key head serves value / key heads
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 0  # taps of the causal convolution on q, k and v
+    partial_rotary_factor: float = 1.0  # rope on this share of a head's leading dims
+    attn_output_gate: bool = False  # q is projected beside a gate: attn x sigmoid(gate)
+    norm_zero_centered: bool = False  # RMSNorm scales by (1 + w), w from 0
+    shared_expert_gate: bool = False  # the shared expert x sigmoid(y w), w (hidden, 1)
+    # which `MIXERS` entry ONE layer runs. `layer_config(kind)` sets it; a
+    # model's own config leaves it and states the pattern above
+    mixer: str = "attention"
 
     def __post_init__(self):
         if self.num_kv_heads is None:
@@ -140,13 +160,28 @@ class TransformerConfig:
         if self.mtp_layers not in (0, 1):
             raise ValueError("mtp_layers=%d: one multi-token-prediction module at most"
                              % self.mtp_layers)
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError("qk_norm=%r: False, True (the whole projection) or \"head\""
+                             % (self.qk_norm,))
+        if self.full_attention_interval or self.mixer == "linear":
+            heads = (self.linear_num_key_heads, self.linear_num_value_heads)
+            if (min(heads + (self.linear_key_head_dim, self.linear_value_head_dim,
+                             self.linear_conv_kernel)) < 1 or heads[1] % heads[0]
+                    or self.mtp_layers or self.latent_attention):
+                raise ValueError(
+                    "linear-attention layers (full_attention_interval=%d) want linear_num_key_heads "
+                    "dividing linear_num_value_heads, head dims and a convolution kernel of 1 or "
+                    "more, and neither latent attention nor a multi-token-prediction module; got "
+                    "heads %r, dims (%d, %d), kernel %d" % (
+                        self.full_attention_interval, heads, self.linear_key_head_dim,
+                        self.linear_value_head_dim, self.linear_conv_kernel))
         if self.input_type == "patches":
             n_patches = (self.image_size // self.patch_size) ** 2
             self.max_seq_len = n_patches + (1 if self.use_cls_token else 0)
 
     @property
     def fused_qkv(self) -> bool:
-        return self.num_kv_heads == self.num_heads
+        return self.num_kv_heads == self.num_heads and not self.attn_output_gate
 
     @property
     def mlp_fan_in(self) -> tuple:
@@ -165,23 +200,47 @@ class TransformerConfig:
         return self.kv_lora_rank > 0
 
     def layer_kinds(self) -> Tuple[str, ...]:
-        """The kind of each layer's MLP half, "dense" or "routed": what
-        `config/strategy.layer_runs` splits runs on beside the layout."""
+        """The kind of each layer, what `config/strategy.layer_runs` splits
+        runs on beside the layout. A kind names the layer's two halves: its
+        MLP half, "dense" or "routed", after its token mixer where that is
+        not softmax attention ("linear.routed": `MIXERS`)."""
         if not self.routed:
-            return ("dense",) * self.num_layers
-        lead = min(self.first_dense_layers, self.num_layers)
-        return ("dense",) * lead + ("routed",) * (self.num_layers - lead)
+            mlp = ("dense",) * self.num_layers
+        else:
+            lead = min(self.first_dense_layers, self.num_layers)
+            mlp = ("dense",) * lead + ("routed",) * (self.num_layers - lead)
+        every = self.full_attention_interval
+        if not every:
+            return mlp
+        return tuple(m if (i + 1) % every == 0 else "linear." + m for i, m in enumerate(mlp))
 
     def layer_config(self, kind: str) -> "TransformerConfig":
         """The config ONE layer of this kind is built and run from: a dense
         layer of a model that also has routed ones is the same block with no
-        experts and the dense width. `init_layer_params`, `layer_forward`
-        and `layer_param_specs` take a layer's config."""
-        if kind == "routed" or not self.routed:
-            return self
-        return dataclasses.replace(
-            self, num_experts=0, experts_held=0, num_shared_experts=0, router_bias=False,
-            ffn_hidden=self.dense_ffn_hidden or self.ffn_hidden)
+        experts and the dense width, and a layer of a model that mixes its
+        token mixers names its own (`mixer`) and no pattern.
+        `init_layer_params`, `layer_forward` and `layer_param_specs` take a
+        layer's config."""
+        mixer, _, mlp = kind.rpartition(".")
+        cfg = self
+        if mlp != "routed" and self.routed:
+            cfg = dataclasses.replace(
+                cfg, num_experts=0, experts_held=0, num_shared_experts=0, router_bias=False,
+                ffn_hidden=self.dense_ffn_hidden or self.ffn_hidden)
+        if self.full_attention_interval:
+            cfg = dataclasses.replace(cfg, mixer=mixer or "attention", full_attention_interval=0)
+        return cfg
+
+    @property
+    def layer_aux(self) -> bool:
+        """Whether a layer hands back auxiliary terms beside its output (a
+        router's losses and loads, a linear mixer's counters): of a layer's
+        config its own layer, of a model's config any of its layers."""
+        return self.routed or self.mixer == "linear" or self.full_attention_interval > 0
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def held_experts(self) -> Tuple[int, int]:
@@ -192,7 +251,8 @@ class TransformerConfig:
     @property
     def routed_layers(self) -> int:
         """Routed blocks a step runs: the stack's and the MTP module's."""
-        return self.layer_kinds().count("routed") + (self.mtp_layers if self.routed else 0)
+        return (sum(kind.endswith("routed") for kind in self.layer_kinds())
+                + (self.mtp_layers if self.routed else 0))
 
 
 # ===================================================================== init
@@ -203,20 +263,16 @@ def _dense_init(rng, shape, std, dtype):
     return (jax.random.normal(rng, shape, jnp.float32) * std).astype(dtype)
 
 
-def init_layer_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
-    """QKV kernels are stored head-major — (h, 3, nh, hd) fused, or separate
-    (h, nh, hd) + (h, 2, nkv, hd) for GQA — so the tp sharding sits on the
-    *heads* dim and the q/k/v split slices an unsharded dim (no resharding).
-    This replaces Megatron's interleaved fused-QKV layout (reference
-    transformer.py:512-900, checkpoint QKV re-layout GPTModel_checkpoint.py:17-140)."""
-    ks = jax.random.split(rng, 5)
+def _init_attention(ks, cfg: TransformerConfig) -> Params:
+    """The softmax-attention mixer's leaves. QKV kernels are stored
+    head-major — (h, 3, nh, hd) fused, or separate (h, nh, hd) + (h, 2, nkv,
+    hd) for GQA — so the tp sharding sits on the *heads* dim and the q/k/v
+    split slices an unsharded dim (no resharding). This replaces Megatron's
+    interleaved fused-QKV layout (reference transformer.py:512-900,
+    checkpoint QKV re-layout GPTModel_checkpoint.py:17-140). With an output
+    gate a head's query dims lie beside its gate dims: (h, nh, 2 hd)."""
     h, hd, nh, nkv = cfg.hidden_size, cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
     p: Params = {}
-    norm = {"scale": jnp.ones((h,), cfg.param_dtype)}
-    if cfg.norm_type == "layernorm":
-        norm["bias"] = jnp.zeros((h,), cfg.param_dtype)
-    p["ln1"] = jax.tree.map(jnp.copy, norm)
-    p["ln2"] = jax.tree.map(jnp.copy, norm)
     if cfg.latent_attention:
         # HF's names: q_a_proj, q_a_layernorm, q_b_proj, kv_a_proj_with_mqa,
         # kv_a_layernorm, kv_b_proj; the up projections head-major, so that a
@@ -236,18 +292,77 @@ def init_layer_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         if cfg.qkv_bias:
             p["wqkv"]["bias"] = jnp.zeros((3, nh, hd), cfg.param_dtype)
     else:
-        p["wq"] = {"kernel": _dense_init(ks[0], (h, nh, hd), cfg.init_std, cfg.param_dtype)}
+        q_dims = 2 * hd if cfg.attn_output_gate else hd
+        p["wq"] = {"kernel": _dense_init(ks[0], (h, nh, q_dims), cfg.init_std, cfg.param_dtype)}
         p["wkv"] = {"kernel": _dense_init(ks[4], (h, 2, nkv, hd), cfg.init_std, cfg.param_dtype)}
         if cfg.qkv_bias:
-            p["wq"]["bias"] = jnp.zeros((nh, hd), cfg.param_dtype)
+            p["wq"]["bias"] = jnp.zeros((nh, q_dims), cfg.param_dtype)
             p["wkv"]["bias"] = jnp.zeros((2, nkv, hd), cfg.param_dtype)
-    proj_std = cfg.init_std / (2 * cfg.num_layers) ** 0.5
-    p["wo"] = {"kernel": _dense_init(ks[1], (nh * hd, h), proj_std, cfg.param_dtype)}
+    p["wo"] = {"kernel": _dense_init(ks[1], (nh * hd, h), _proj_std(cfg), cfg.param_dtype)}
     if cfg.out_bias:
         p["wo"]["bias"] = jnp.zeros((h,), cfg.param_dtype)
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":
+        p["q_norm"] = {"scale": _norm_scale((hd,), cfg)}
+        p["k_norm"] = {"scale": _norm_scale((hd,), cfg)}
+    elif cfg.qk_norm:
         p["q_norm"] = {"scale": jnp.ones((nh * hd,), cfg.param_dtype)}
         p["k_norm"] = {"scale": jnp.ones((nkv * hd,), cfg.param_dtype)}
+    return p
+
+
+def _init_linear(ks, cfg: TransformerConfig) -> Params:
+    """The gated-DeltaNet mixer's leaves, under `linear` (HF
+    `Qwen3NextGatedDeltaNet`: in_proj_qkvz, in_proj_ba, conv1d, A_log,
+    dt_bias, norm, out_proj). `wqkvz`'s columns lie [q | k | v | z], `wba`'s
+    [b | a], heads in order within each (HF groups them a key head: on random
+    weights a permutation of columns). The gate starts as the Gated DeltaNet
+    reference does: A = exp(A_log) ~ U(0, 16) and dt = softplus(dt_bias)
+    log-uniform in [0.001, 0.1], so that exp(g) spans 0.2 to 1 a token and
+    state crosses chunks; the taps U(-1, 1) / sqrt(taps), PyTorch's default
+    for a convolution of that fan-in."""
+    h, taps = cfg.hidden_size, cfg.linear_conv_kernel
+    nv = cfg.linear_num_value_heads
+    key_dim = cfg.linear_num_key_heads * cfg.linear_key_head_dim
+    value_dim = nv * cfg.linear_value_head_dim
+    kin = jax.random.split(ks[0], 2)
+    kgate = jax.random.split(ks[4], 3)
+    step = jnp.exp(jax.random.uniform(kgate[2], (nv,), jnp.float32, math.log(1e-3), math.log(0.1)))
+    return {"linear": {
+        "wqkvz": {"kernel": _dense_init(
+            kin[0], (h, 2 * key_dim + 2 * value_dim), cfg.init_std, cfg.param_dtype)},
+        "wba": {"kernel": _dense_init(kin[1], (h, 2 * nv), cfg.init_std, cfg.param_dtype)},
+        "conv": jax.random.uniform(kgate[0], (2 * key_dim + value_dim, taps), jnp.float32,
+                                   -1.0, 1.0).astype(cfg.param_dtype) / taps ** 0.5,
+        "A_log": jnp.log(jax.random.uniform(kgate[1], (nv,), jnp.float32, 1e-6, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+        "norm": {"scale": jnp.ones((cfg.linear_value_head_dim,), cfg.param_dtype)},
+        "wout": {"kernel": _dense_init(ks[1], (value_dim, h), _proj_std(cfg), cfg.param_dtype)},
+    }}
+
+
+def _proj_std(cfg: TransformerConfig) -> float:
+    return cfg.init_std / (2 * cfg.num_layers) ** 0.5
+
+
+def _norm_scale(shape, cfg: TransformerConfig) -> jax.Array:
+    """An RMSNorm's or LayerNorm's scale as the model starts it: 1, or 0
+    where the norm multiplies by (1 + w)."""
+    return (jnp.zeros if cfg.norm_zero_centered else jnp.ones)(shape, cfg.param_dtype)
+
+
+def init_layer_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
+    """One layer's tree: its two norms, its token mixer's leaves (`MIXERS`)
+    and its MLP half's."""
+    ks = jax.random.split(rng, 5)
+    h = cfg.hidden_size
+    p: Params = {}
+    norm = {"scale": _norm_scale((h,), cfg)}
+    if cfg.norm_type == "layernorm":
+        norm["bias"] = jnp.zeros((h,), cfg.param_dtype)
+    p["ln1"] = jax.tree.map(jnp.copy, norm)
+    p["ln2"] = jax.tree.map(jnp.copy, norm)
+    p.update(MIXERS[cfg.mixer].init(ks, cfg))
+    proj_std = _proj_std(cfg)
     if cfg.routed:
         # one kernel a matrix with the experts leading: (E, h, 2F) the gate's
         # columns beside the up projection's (flat: a TPU tiles the minor
@@ -269,6 +384,9 @@ def init_layer_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
                 "wi": {"kernel": _dense_init(ksh[0], shared_in, cfg.init_std, cfg.param_dtype)},
                 "wo_mlp": {"kernel": _dense_init(ksh[1], (wide, h), proj_std, cfg.param_dtype)},
             }
+            if cfg.shared_expert_gate:
+                p["shared"]["gate"] = {"kernel": _dense_init(
+                    jax.random.fold_in(ks[3], 2), (h, 1), cfg.init_std, cfg.param_dtype)}
         return p
     p["wi"] = {"kernel": _dense_init(ks[2], (h,) + cfg.mlp_fan_in, cfg.init_std, cfg.param_dtype)}
     if cfg.mlp_bias:
@@ -280,7 +398,7 @@ def init_layer_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
 
 
 def _norm_params(cfg: TransformerConfig) -> Params:
-    p = {"scale": jnp.ones((cfg.hidden_size,), cfg.param_dtype)}
+    p = {"scale": _norm_scale((cfg.hidden_size,), cfg)}
     if cfg.norm_type == "layernorm":
         p["bias"] = jnp.zeros((cfg.hidden_size,), cfg.param_dtype)
     return p
@@ -351,7 +469,8 @@ def init_model_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
 # ================================================================ primitives
 def _norm(x, p, cfg: TransformerConfig):
     if cfg.norm_type == "rmsnorm":
-        return rms_norm(x, p["scale"], cfg.layernorm_eps)
+        scale = 1.0 + p["scale"] if cfg.norm_zero_centered else p["scale"]
+        return rms_norm(x, scale, cfg.layernorm_eps)
     return layer_norm(x, p["scale"], p["bias"], cfg.layernorm_eps)
 
 
@@ -405,7 +524,12 @@ def dense_mlp(p: Params, y: jax.Array, cfg: TransformerConfig, dtype) -> jax.Arr
 def qk_normed(p: Params, q: jax.Array, k: jax.Array, cfg: TransformerConfig):
     """OLMoE's q_norm / k_norm: an RMSNorm over the WHOLE projected q
     (nh x hd) and the whole projected k, before rope. Taken over the last two
-    dims in place: flattening them would merge the heads dim, which tp shards."""
+    dims in place: flattening them would merge the heads dim, which tp shards.
+    `qk_norm == "head"` (Qwen3-Next): the model's own norm over each head's
+    dims, one (hd,) scale for every head."""
+    if cfg.qk_norm == "head":
+        return _norm(q, p["q_norm"], cfg), _norm(k, p["k_norm"], cfg)
+
     def whole(t, scale):
         x32 = t.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=(-2, -1), keepdims=True)
@@ -453,22 +577,33 @@ def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional
     wrong or priced as dense. The same holds of latent attention (and of the
     multi-token-prediction module that comes with it), whose low-rank
     projections have no tensor-, context- or sequence-parallel form and no
-    cache in the decode engine: where it is the reason, it is named."""
+    cache in the decode engine: where it is the reason, it is named. And of
+    gated-DeltaNet linear-attention layers among attention layers
+    (`full_attention_interval` > 0): the recurrence has no tp, sp, cp or pp
+    form, the decode engine no recurrent state, the cost models no row."""
     latent = bool(getattr(cfg, "latent_attention", False) or getattr(cfg, "mtp_layers", 0))
-    if not (getattr(cfg, "routed", False) or latent):
+    linear = linear_layers_reason(cfg) is not None
+    if not (getattr(cfg, "routed", False) or latent or linear):
         return None
-    also = " (nor has latent attention, MLA: kv_lora_rank > 0)" if latent else ""
+    also = (" (nor has latent attention, MLA: kv_lora_rank > 0)" if latent else "") + (
+        " (nor have linear-attention layers, full_attention_interval > 0: the delta rule's "
+        "state runs along the whole sequence of all a layer's heads)" if linear else "")
     if mode == "serve":
         return "serve: the decode engine has no expert form" + (
-            ", and no cache of latent attention's compressed k/v" if latent else "")
+            ", and no cache of latent attention's compressed k/v" if latent else "") + (
+            ", and no recurrent state of a linear-attention layer (serve/kv_cache.py holds "
+            "keys and values)" if linear else "")
     if (autotune or "off") != "off":
         return "autotune=%s: the re-search would price the block as dense" % autotune + (
-            ", and latent attention as full-rank" if latent else "")
+            ", and latent attention as full-rank" if latent else "") + (
+            ", and a linear-attention layer as softmax attention" if linear else "")
     if hp is None:
         return None
     if hp.pp > 1:
         return "pp=%d: the pipeline engines carry no router losses between stages" % hp.pp + (
-            " and no multi-token-prediction module after the last" if latent else "")
+            " and no multi-token-prediction module after the last" if latent else "") + (
+            " and stack one kind of layer a stage, not linear-attention layers among "
+            "attention layers" if linear else "")
     for i, s in enumerate(hp.layers):
         if s.tp > 1 or s.cp > 1 or s.sp:
             return ("layer %d: tp=%d cp=%d sp=%d: the experts' kernels and the dropless "
@@ -483,6 +618,14 @@ def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional
     if QC.wants_quant_comm(hp):
         return "quantized grad/param collectives run a local loss with no router statistics"
     return None
+
+
+def linear_layers_reason(cfg) -> Optional[str]:
+    """What `search` and `profile` say of a config with linear-attention
+    layers, or None for one without."""
+    if not (getattr(cfg, "full_attention_interval", 0) or getattr(cfg, "mixer", "") == "linear"):
+        return None
+    return "linear-attention layers (full_attention_interval > 0) have no row in the cost models"
 
 
 def expert_layout_diagnostic(reason: str):
@@ -508,45 +651,15 @@ def assert_expert_layout_supported(cfg, hp: Optional[HybridParallelConfig]):
 
 
 # ============================================================== layer forward
-def layer_forward(
-    p: Params,
-    x: jax.Array,
-    positions: jax.Array,
-    cfg: TransformerConfig,
-    *,
-    mesh: Optional[Mesh] = None,
-    axes: Optional[LayerAxes] = None,
-    attn_bias: Optional[jax.Array] = None,
-    return_kv: bool = False,
-    attn_sharding: Optional[KernelSharding] = None,
-):
-    """One transformer block on (B, S_local, H) activations.
-
-    Under GSPMD the parallel form is implied by weight shardings plus the two
-    activation constraints below: seq-sharded activations (megatron-sp /
-    ulysses) are re-gathered into head-sharded full-sequence tensors for
-    attention (all-gather or all-to-all inserted by XLA — the hand-written
-    collectives of reference transformer.py:1928-2177).
-
-    ``return_kv`` additionally returns this layer's post-rope (k, v)
-    projections — the serving prefill's cache-write side outputs
-    (serve/engine.py). Unsupported under ring context parallelism, whose
-    blockwise k/v never materialise per-layer.
-
-    ``attn_sharding`` is the attention kernel's layout for callers that run
-    this body with ``mesh=None`` under their own mapping (the GPipe stage
-    vmap); with a mesh and axes it is derived here.
-
-    A routed-experts config (``cfg.routed``) returns ``(x, aux)``: the
-    block's output and its router's auxiliary terms (ops/moe.py)."""
+def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, *,
+                    mesh, axes, attn_bias, attn_sharding, return_kv: bool):
+    """Softmax attention on normed activations (B, S_local, H) -> the
+    output projection's result, the post-rope (k, v) where asked, and no
+    counters. Seq-sharded activations (megatron-sp / ulysses) are re-gathered
+    into head-sharded full-sequence tensors for attention (all-gather or
+    all-to-all inserted by XLA — the hand-written collectives of reference
+    transformer.py:1928-2177)."""
     dtype = cfg.compute_dtype
-    if (cfg.routed or cfg.latent_attention) and return_kv:
-        refuse_expert_layout("serving (the prefill's k/v outputs)")
-    if mesh is not None and axes is not None:
-        attn_sharding = KernelSharding.for_layer(mesh, axes)
-
-    residual = x
-    y = _norm(x, p["ln1"], cfg) if cfg.pre_norm else x
     if cfg.position_type == "rope" and mesh is not None and axes is not None:
         # Pin positions to THIS layer's sharding so each layer derives its
         # own rope cos/sin tables in its own layout. Without this, XLA CSEs
@@ -558,17 +671,20 @@ def layer_forward(
         pin = lambda pos: S.constrain(pos, mesh, S.act_spec(axes, ndim=2))  # noqa: E731
     else:
         pin = lambda pos: pos  # noqa: E731
+    gate = None
     if cfg.latent_attention:
         with jax.named_scope(tracing.ATTN_LATENT):
             q, k, v = latent_qkv_projection(p, y, pin(positions), cfg, dtype)
     else:
         q, k, v = qkv_projection(p, y, cfg, dtype)
+        if cfg.attn_output_gate:
+            q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
         if cfg.qk_norm:
             q, k = qk_normed(p, q, k, cfg)
         if cfg.position_type == "rope":
             positions = pin(positions)
-            q = apply_rotary(q, positions, cfg.rope_theta)
-            k = apply_rotary(k, positions, cfg.rope_theta)
+            q = apply_rotary(q, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
+            k = apply_rotary(k, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
     if mesh is not None and axes is not None and len(axes.tp) + len(axes.cp) > 0:
         # (B, S/x, nh, hd) -> (B, S/cp, nh/tp, hd): XLA inserts the all-to-all
         # (ulysses) or all-gather+split (megatron-sp) when seq was tp-sharded.
@@ -594,11 +710,118 @@ def layer_forward(
         attn = core_attention(q, k, v, causal=cfg.causal, bias=attn_bias,
                               impl=cfg.attn_impl, bias_type="key_padding",
                               sharding=attn_sharding)
+    if gate is not None:
+        attn = attn * jax.nn.sigmoid(gate)
     attn = attn.reshape(attn.shape[0], attn.shape[1], cfg.num_heads * cfg.head_dim)
     # the output projection counts as latent attention's too: the scope is
     # everything of it but the attention call
     with jax.named_scope(tracing.ATTN_LATENT) if cfg.latent_attention else contextlib.nullcontext():
         o = _dense(attn, p["wo"], dtype)
+    return o, kv_out, None
+
+
+def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, **_):
+    """Gated DeltaNet on normed activations (B, S, H) (HF
+    `Qwen3NextGatedDeltaNet`; arXiv:2412.06464), p the layer's tree:
+
+        [q, k, v, z] = y Wqkvz;  [b, a] = y Wba
+        [q, k, v] = silu(conv([q, k, v]))             causal, depthwise, a channel
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)   float32, <= 0
+        q, k L2-normalised a head, q / sqrt(d_k); each key head serves
+        value / key heads
+        o = gated_delta_rule(q, k, v, g, beta)         ops/linear_attention.py
+        out = (RMSNorm(o; w) silu(z)) Wout             a head; the norm BEFORE the gate
+
+    -> out, None, and the layer's counters: the mean gate `exp(g)` (how much
+    state a token keeps) and the largest magnitude in any head's final state.
+    Scopes: the core under `gt.attn.delta`, all else under `gt.attn.linear`.
+    No position enters: the order is the recurrence's."""
+    p, dtype = p["linear"], cfg.compute_dtype
+    nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    key_dim, value_dim = nk * dk, nv * dv
+    b, s, _ = y.shape
+
+    def unit(t):  # L2 over a head's dims, as HF's l2norm
+        t32 = t.astype(jnp.float32)
+        return t32 * jax.lax.rsqrt(jnp.sum(jnp.square(t32), axis=-1, keepdims=True) + 1e-6)
+
+    with jax.named_scope(tracing.ATTN_LINEAR):
+        qkvz = _dense(y, p["wqkvz"], dtype)
+        ba = _dense(y, p["wba"], dtype).astype(jnp.float32)
+        qkv = jax.nn.silu(causal_conv(qkvz[..., :2 * key_dim + value_dim], p["conv"]))
+        z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, s, nv, dv)
+        beta = jax.nn.sigmoid(ba[..., :nv])
+        g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            ba[..., nv:] + p["dt_bias"].astype(jnp.float32))
+        q = (unit(qkv[..., :key_dim].reshape(b, s, nk, dk)) * dk ** -0.5).astype(dtype)
+        k = unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, nk, dk)).astype(dtype)
+        v = qkv[..., 2 * key_dim:].reshape(b, s, nv, dv)
+    with jax.named_scope(tracing.ATTN_DELTA):
+        o, state = gated_delta_rule(q, k, v, g, beta)
+    with jax.named_scope(tracing.ATTN_LINEAR):
+        o = rms_norm(o.astype(jnp.float32), p["norm"]["scale"], cfg.layernorm_eps)
+        o = (o * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
+        out = _dense(o.reshape(b, s, value_dim), p["wout"], dtype)
+        stats = {"decay_mean": jnp.mean(jnp.exp(g)), "state_abs_max": jnp.max(jnp.abs(state))}
+    return out, None, stats
+
+
+@dataclass(frozen=True)
+class TokenMixer:
+    """What a kind of token mixer brings to a layer (ROADMAP D6, at the size
+    the zoo needs): its leaves, its forward on normed activations, their
+    PartitionSpecs, its forward FLOPs a token (the name of the function in
+    `obs/flops.py`, which imports no jax) and the scopes its ops carry beside
+    the layer run's."""
+    init: Any  # (keys, cfg) -> the mixer's entries of the layer's tree
+    forward: Any  # (p, y, positions, cfg, mesh=, axes=, ...) -> (out, kv | None, counters | None)
+    specs: Any  # (cfg, axes) -> their PartitionSpecs
+    flops: str
+    scopes: Tuple[str, ...]
+
+
+def layer_forward(
+    p: Params,
+    x: jax.Array,
+    positions: jax.Array,
+    cfg: TransformerConfig,
+    *,
+    mesh: Optional[Mesh] = None,
+    axes: Optional[LayerAxes] = None,
+    attn_bias: Optional[jax.Array] = None,
+    return_kv: bool = False,
+    attn_sharding: Optional[KernelSharding] = None,
+):
+    """One transformer block on (B, S_local, H) activations: x + Mixer(norm
+    x), then + MLP(norm x), the mixer `MIXERS[cfg.mixer]`'s.
+
+    Under GSPMD the parallel form is implied by weight shardings plus the
+    activation constraints here and in the mixer.
+
+    ``return_kv`` additionally returns this layer's post-rope (k, v)
+    projections — the serving prefill's cache-write side outputs
+    (serve/engine.py). Unsupported under ring context parallelism, whose
+    blockwise k/v never materialise per-layer.
+
+    ``attn_sharding`` is the attention kernel's layout for callers that run
+    this body with ``mesh=None`` under their own mapping (the GPipe stage
+    vmap); with a mesh and axes it is derived here.
+
+    A config with ``layer_aux`` (routed experts, a linear mixer) returns
+    ``(x, aux)``: the block's output, and its router's auxiliary terms
+    (ops/moe.py) and its mixer's counters in one dict."""
+    dtype = cfg.compute_dtype
+    if (cfg.layer_aux or cfg.latent_attention) and return_kv:
+        refuse_expert_layout("serving (the prefill's k/v outputs)")
+    if mesh is not None and axes is not None:
+        attn_sharding = KernelSharding.for_layer(mesh, axes)
+
+    residual = x
+    y = _norm(x, p["ln1"], cfg) if cfg.pre_norm else x
+    o, kv_out, counters = MIXERS[cfg.mixer].forward(
+        p, y, positions, cfg, mesh=mesh, axes=axes, attn_bias=attn_bias,
+        attn_sharding=attn_sharding, return_kv=return_kv)
     if mesh is not None and axes is not None:
         o = S.constrain(o, mesh, S.act_spec(axes))
     x = residual + o
@@ -618,7 +841,10 @@ def layer_forward(
         if "shared" in p:
             # every chip of the deployment computes it alike, whole
             with jax.named_scope(tracing.MOE_SHARED):
-                out = out + dense_mlp(p["shared"], y, cfg, dtype)
+                shared = dense_mlp(p["shared"], y, cfg, dtype)
+                if "gate" in p["shared"]:
+                    shared = shared * jax.nn.sigmoid(_dense(y, p["shared"]["gate"], dtype))
+                out = out + shared
     else:
         out, aux = dense_mlp(p, y, cfg, dtype), None
     if mesh is not None and axes is not None:
@@ -628,8 +854,8 @@ def layer_forward(
         x = _norm(x, p["ln2"], cfg)
     if return_kv:
         return x, kv_out
-    if aux is not None:
-        return x, aux
+    if cfg.layer_aux:
+        return x, {**(aux or {}), **(counters or {})}
     return x
 
 
@@ -667,7 +893,7 @@ def decode_layer_forward(
     exactly, so incremental decode reproduces the full-forward logits within
     float tolerance (tests/serve/test_decode_parity.py)."""
     dtype = cfg.compute_dtype
-    if cfg.routed or cfg.latent_attention:
+    if cfg.layer_aux or cfg.latent_attention:
         refuse_expert_layout("serving (single-token decode)")
 
     residual = x
@@ -676,8 +902,8 @@ def decode_layer_forward(
     if cfg.qk_norm:
         q, k = qk_normed(p, q, k, cfg)
     if cfg.position_type == "rope":
-        q = apply_rotary(q, positions, cfg.rope_theta)
-        k = apply_rotary(k, positions, cfg.rope_theta)
+        q = apply_rotary(q, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
+        k = apply_rotary(k, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
     k_cache = _append_token_kv(k_cache, k.astype(k_cache.dtype), write_index)
     v_cache = _append_token_kv(v_cache, v.astype(v_cache.dtype), write_index)
     if mesh is not None and axes is not None and len(axes.tp) > 0:
@@ -1021,9 +1247,10 @@ def run_layers(
     (no manual-TP shard_map body, no remat): serve lints away the layouts
     that would need either.
 
-    A routed-experts config returns ``(x, aux)``, the routers' auxiliary
-    terms over the layers: the mean of each loss, the worst layer's load
-    (`_fold_aux`). A dense config carries nothing and traces what it did."""
+    A config with ``layer_aux`` returns ``(x, auxs)``, the layers' auxiliary
+    terms (the routers', the linear mixers' counters) a layer or a scanned
+    run, for `_fold_aux`. Any other config carries nothing and traces what
+    it did."""
     use_hp = hp is not None and mesh is not None
     assert_expert_layout_supported(cfg, hp)
     layers = params["layers"]
@@ -1058,7 +1285,7 @@ def run_layers(
                 if pol != "none":
                     fwd = _remat(fwd, pol)
             x = fwd(lp, x, positions)
-            if lcfg.routed:
+            if lcfg.layer_aux:
                 x, aux = x
                 auxs.append(aux)
         return x
@@ -1103,10 +1330,10 @@ def run_layers(
             if use_hp:
                 carry = S.constrain(carry, mesh, S.act_spec(_axes))
             out = _body(lp, carry, positions)
-            return out if lcfg.routed else (out, None)
+            return out if lcfg.layer_aux else (out, None)
 
         x, run_aux = jax.lax.scan(step, x, stacked)
-        if lcfg.routed:
+        if lcfg.layer_aux:
             auxs.append(run_aux)
         return x
 
@@ -1123,30 +1350,34 @@ def run_layers(
             x = one_run(x, run)
     if collect_kv:
         return x, kvs
-    if cfg.routed:
+    if cfg.layer_aux:
         return x, auxs
     return x
 
 
 def _fold_aux(auxs: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
-    """The routed blocks' router terms as one: each loss the mean over the
-    blocks, the load and the bias the worst block's, the rows held their sum,
-    and `counts` a row a block, in the blocks' order (`router_bias_leaves`').
-    An entry is a block's values or a scanned run's, stacked along the layer
-    axis."""
+    """The layers' auxiliary terms as one, each over the layers that have
+    it: a loss and the linear mixers' gate the mean, the load, the bias and
+    the state's magnitude the worst layer's, the rows held their sum, and
+    `counts` a row a routed block, in the blocks' order
+    (`router_bias_leaves`'). An entry is a layer's values or a scanned run's,
+    stacked along the layer axis."""
     def total(name, reduce):
-        return reduce(jnp.stack([reduce(jnp.atleast_1d(a[name])) for a in auxs]))
+        return reduce(jnp.stack([reduce(jnp.atleast_1d(a[name])) for a in auxs if name in a]))
 
-    blocks = sum(jnp.atleast_1d(a["load_max_over_mean"]).shape[0] for a in auxs)
+    def mean(name):
+        layers = sum(jnp.atleast_1d(a[name]).shape[0] for a in auxs if name in a)
+        return total(name, jnp.sum) / layers
+
     fold = {
-        "load_balance": lambda n: total(n, jnp.sum) / blocks,
-        "router_z": lambda n: total(n, jnp.sum) / blocks,
+        "load_balance": mean, "router_z": mean, "decay_mean": mean,
         "load_max_over_mean": lambda n: total(n, jnp.max),
         "bias_abs_max": lambda n: total(n, jnp.max),
+        "state_abs_max": lambda n: total(n, jnp.max),
         "rows_held": lambda n: total(n, jnp.sum),
-        "counts": lambda n: jnp.concatenate([jnp.atleast_2d(a[n]) for a in auxs]),
+        "counts": lambda n: jnp.concatenate([jnp.atleast_2d(a[n]) for a in auxs if n in a]),
     }
-    return {name: fold[name](name) for name in auxs[0]}
+    return {name: fold[name](name) for name in dict.fromkeys(n for a in auxs for n in a)}
 
 
 def padding_attn_bias(attn_mask: jax.Array) -> jax.Array:
@@ -1186,7 +1417,7 @@ def _forward(
         x = S.constrain(x, mesh, S.act_spec(vax))
     bias = padding_attn_bias(attn_mask) if attn_mask is not None else None
     x = run_layers(params, x, positions, cfg, hp, mesh, attn_bias=bias)
-    x, auxs = x if cfg.routed else (x, None)
+    x, auxs = x if cfg.layer_aux else (x, None)
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
     # the head is the first half of gt.head_loss; the loss functions below
@@ -1244,7 +1475,8 @@ ROUTER_COUNTS = "router_counts"  # (routed blocks, E): the step's; no metric
 # how the microbatch loop folds a part that is not a loss term (those are
 # weighted as the loss is)
 PART_FOLDS = {EXPERT_LOAD: jnp.maximum, "router_bias_abs_max": jnp.maximum,
-              ROUTER_COUNTS: jnp.add, "expert_rows_held": jnp.add}
+              ROUTER_COUNTS: jnp.add, "expert_rows_held": jnp.add,
+              "linear_state_abs_max": jnp.maximum}
 
 
 def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False):
@@ -1258,7 +1490,8 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False)
     `mtp_loss_weight` x the cross entropy of the token after next (labels
     shifted by one more; a sequence's last position has none). `with_parts`
     returns `(loss, parts)`: the terms, the worst block's expert load and
-    the other counters of the `step` event (`telemetry.EXPERT_STEP_FIELDS`),
+    the other counters of the `step` event (`telemetry.EXPERT_STEP_FIELDS`,
+    `LINEAR_STEP_FIELDS` for linear-attention layers),
     and for a router with a bias the blocks' assignment counts
     (`ROUTER_COUNTS`), which the train step moves the bias by."""
     logits, hidden, auxs = _forward(
@@ -1287,7 +1520,11 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False)
         parts["loss_router_z"] = aux["router_z"]
         loss = (loss + cfg.router_aux_loss_coef * aux["load_balance"]
                 + cfg.router_z_loss_coef * aux["router_z"])
-    parts[EXPERT_LOAD] = aux["load_max_over_mean"]
+    if "decay_mean" in aux:  # the linear mixers' counters (telemetry.LINEAR_STEP_FIELDS)
+        parts["linear_decay_mean"] = aux["decay_mean"]
+        parts["linear_state_abs_max"] = aux["state_abs_max"]
+    if "load_max_over_mean" in aux:  # a router (linear layers over dense MLPs have none)
+        parts[EXPERT_LOAD] = aux["load_max_over_mean"]
     if "rows_held" in aux:
         even = (cfg.routed_layers * labels.size * cfg.experts_per_token
                 * cfg.held_experts[1] / cfg.num_experts)
@@ -1346,15 +1583,11 @@ def classification_loss_fn(params, batch, cfg, hp=None, mesh=None):
 
 
 # ============================================================== param specs
-def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
-    """PartitionSpec tree matching init_layer_params output. The tp axes sit on
-    the heads / ffn dim; ZeRO-3 shards the other large dim over dp. Ulysses
-    layers keep dense (non-tp-sharded) weights (reference transformer.py:2065-2177)."""
+def _attention_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     tp = None if axes.ulysses else S._ax(axes.tp)
     z3 = S._ax(axes.dp) if axes.zero3 else None
     r1 = S.replicated_1d_spec(axes)
-    norm = {"scale": r1} if cfg.norm_type == "rmsnorm" else {"scale": r1, "bias": r1}
-    sp: Params = {"ln1": dict(norm), "ln2": dict(norm)}
+    sp: Params = {}
     if cfg.latent_attention:
         # ordinary leaves (tp is refused, GLS018): ZeRO-3 splits the input dim
         sp["wq_a"] = {"kernel": P(z3, None)}
@@ -1379,6 +1612,41 @@ def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     if cfg.qk_norm:
         sp["q_norm"] = {"scale": r1}
         sp["k_norm"] = {"scale": r1}
+    return sp
+
+
+def _linear_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
+    # ordinary leaves (tp, sp, cp are refused, GLS018): ZeRO-3 splits the
+    # projections' input dim; the small leaves are whole everywhere
+    z3 = S._ax(axes.dp) if axes.zero3 else None
+    r1 = S.replicated_1d_spec(axes)
+    return {"linear": {
+        "wqkvz": {"kernel": P(z3, None)}, "wba": {"kernel": P(z3, None)},
+        "conv": P(None, None), "A_log": r1, "dt_bias": r1, "norm": {"scale": r1},
+        "wout": {"kernel": P(z3, None)},
+    }}
+
+
+# a layer's kind (`TransformerConfig.layer_kinds`) names its mixer before its
+# MLP half; softmax attention, every model's but one, goes unnamed
+MIXERS = {
+    "attention": TokenMixer(_init_attention, attention_mixer, _attention_specs,
+                            "attention_fwd_flops_a_token", (tracing.ATTN_LATENT,)),
+    "linear": TokenMixer(_init_linear, linear_mixer, _linear_specs,
+                         "linear_fwd_flops_a_token", (tracing.ATTN_LINEAR, tracing.ATTN_DELTA)),
+}
+
+
+def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
+    """PartitionSpec tree matching init_layer_params output. The tp axes sit on
+    the heads / ffn dim; ZeRO-3 shards the other large dim over dp. Ulysses
+    layers keep dense (non-tp-sharded) weights (reference transformer.py:2065-2177)."""
+    tp = None if axes.ulysses else S._ax(axes.tp)
+    z3 = S._ax(axes.dp) if axes.zero3 else None
+    r1 = S.replicated_1d_spec(axes)
+    norm = {"scale": r1} if cfg.norm_type == "rmsnorm" else {"scale": r1, "bias": r1}
+    sp: Params = {"ln1": dict(norm), "ln2": dict(norm)}
+    sp.update(MIXERS[cfg.mixer].specs(cfg, axes))
     if cfg.routed:
         # ordinary leaves (tp is refused, GLS018): ZeRO-3 splits the experts
         # over dp, and they enter the block whole (ops/moe.moe_ffn)
@@ -1392,6 +1660,8 @@ def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
                 "wi": {"kernel": P(z3, None, None) if cfg.activation == "swiglu" else P(z3, None)},
                 "wo_mlp": {"kernel": P(None, z3)},
             }
+            if cfg.shared_expert_gate:
+                sp["shared"]["gate"] = {"kernel": P(None, None)}
         return sp
     if cfg.activation == "swiglu":
         sp["wi"] = {"kernel": P(z3, None, tp)}

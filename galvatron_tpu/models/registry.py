@@ -92,8 +92,17 @@ def _ensure_builtin():
     if _LOADED:
         return
     _LOADED = True
-    from galvatron_tpu.models import glm4_moe_lite, gpt, llama, olmoe
+    from galvatron_tpu.models import glm4_moe_lite, gpt, llama, olmoe, qwen3_next
 
+    register(
+        ModelFamily(
+            name="qwen3_next",
+            config_fn=qwen3_next.qwen3_next_config,
+            meta_configs=qwen3_next.META_CONFIGS,
+            default_size="qwen3-next-80b-a3b",
+            config_from_hf=qwen3_next.qwen3_next_config_from_hf,
+        )
+    )
     register(
         ModelFamily(
             name="glm4_moe_lite",

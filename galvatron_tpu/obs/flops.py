@@ -60,6 +60,42 @@ def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
 
 
 # ------------------------------------------------------------ analytic FLOPs
+# A token mixer's forward FLOPs a token, (projections, core): the functions
+# `models/base.MIXERS` names.
+def attention_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, num_kv_heads: int,
+                                seq_len: int, causal: bool = True, gated: bool = False,
+                                latent: Optional[Mapping[str, int]] = None):
+    """Softmax attention: q, fused kv (GQA-scaled) and out projections (q
+    twice as wide beside an output gate; latent attention's five by their
+    shapes), and scores (q k^T) + weighted sum (p v), each 2 S q_dim, half of
+    it under a causal mask."""
+    q_dim = num_heads * head_dim
+    if latent:
+        ql, kvl = latent["q_lora_rank"], latent["kv_lora_rank"]
+        nope, rope, vd = (latent["qk_nope_head_dim"], latent["qk_rope_head_dim"],
+                          latent["v_head_dim"])
+        proj = (2.0 * hidden * ql + 2.0 * ql * num_heads * (nope + rope)
+                + 2.0 * hidden * (kvl + rope) + 2.0 * kvl * num_heads * (nope + vd)
+                + 2.0 * num_heads * vd * hidden)
+    else:
+        proj = (2.0 * hidden * q_dim * (2 if gated else 1)
+                + 2.0 * hidden * (2 * num_kv_heads * head_dim) + 2.0 * q_dim * hidden)
+    return proj, 2.0 * (2.0 * seq_len * q_dim) * (0.5 if causal else 1.0)
+
+
+def linear_fwd_flops_a_token(*, hidden: int, num_key_heads: int, num_value_heads: int,
+                             key_head_dim: int, value_head_dim: int):
+    """A gated-DeltaNet mixer: hidden -> [q | k | v | z] and [b | a], value ->
+    hidden; and the core as the RECURRENCE needs it, three (d_k, d_v) products
+    a value head a token (S^T k, k u^T, S^T q: 6 d_k d_v), whatever chunk an
+    implementation cuts the sequence into and at any sequence length. The
+    convolution's taps are no matmul."""
+    key_dim, value_dim = num_key_heads * key_head_dim, num_value_heads * value_head_dim
+    proj = (2.0 * hidden * (2 * key_dim + 2 * value_dim) + 2.0 * hidden * (2 * num_value_heads)
+            + 2.0 * value_dim * hidden)
+    return proj, 6.0 * num_value_heads * key_head_dim * value_head_dim
+
+
 def layer_fwd_flops(
     *,
     hidden: int,
@@ -76,6 +112,9 @@ def layer_fwd_flops(
     experts_held: int = 0,
     num_shared_experts: int = 0,
     latent: Optional[Mapping[str, int]] = None,
+    attn_gate: bool = False,
+    shared_gate: bool = False,
+    linear: Optional[Mapping[str, int]] = None,
 ) -> float:
     """Forward model FLOPs of ONE transformer block over `tokens` tokens
     (default: one sequence). Matmul terms only (2 FLOPs per MAC); norms and
@@ -87,31 +126,27 @@ def layer_fwd_flops(
     them (experts_per_token x held / num_experts: a constant, whatever the
     routing). `latent` (q_lora_rank, kv_lora_rank, qk_nope_head_dim,
     qk_rope_head_dim, v_head_dim): latent attention's five projections by
-    their shapes in place of q, k/v and out."""
+    their shapes in place of q, k/v and out; `attn_gate`: q projected beside
+    an output gate. `linear` (num_key_heads, num_value_heads, key_head_dim,
+    value_head_dim): the layer's token mixer is a gated DeltaNet, in place of
+    attention. `shared_gate`: the shared expert's (hidden, 1) gate."""
     tokens = float(seq_len if tokens is None else tokens)
     ffn = ffn_hidden or 4 * hidden
-    hd = head_dim or hidden // num_heads
-    nkv = num_kv_heads or num_heads
-    q_dim = num_heads * hd
-    if latent:
-        ql, kvl = latent["q_lora_rank"], latent["kv_lora_rank"]
-        nope, rope, vd = (latent["qk_nope_head_dim"], latent["qk_rope_head_dim"],
-                          latent["v_head_dim"])
-        proj = (2.0 * hidden * ql + 2.0 * ql * num_heads * (nope + rope)
-                + 2.0 * hidden * (kvl + rope) + 2.0 * kvl * num_heads * (nope + vd)
-                + 2.0 * num_heads * vd * hidden)
+    if linear:
+        proj, attn = linear_fwd_flops_a_token(hidden=hidden, **linear)
     else:
-        # per-token projection matmuls: q, fused kv (GQA-scaled), out
-        proj = 2.0 * hidden * q_dim + 2.0 * hidden * (2 * nkv * hd) + 2.0 * q_dim * hidden
-    # per-token attention arithmetic: scores (q·kᵀ) + weighted sum (p·v),
-    # each 2*S*q_dim; causal masks half the score matrix
-    attn = 2.0 * (2.0 * seq_len * q_dim) * (0.5 if causal else 1.0)
+        proj, attn = attention_fwd_flops_a_token(
+            hidden=hidden, num_heads=num_heads, head_dim=head_dim or hidden // num_heads,
+            num_kv_heads=num_kv_heads or num_heads, seq_len=seq_len, causal=causal,
+            gated=attn_gate, latent=latent)
     # MLP: swiglu projects to 2*ffn (gate+up) then back; gelu/relu ffn both ways
     mlp = (2.0 * hidden * (2 * ffn) + 2.0 * ffn * hidden) if swiglu \
         else (2.0 * hidden * ffn + 2.0 * ffn * hidden)
     if num_experts:
         sent = experts_per_token * (experts_held or num_experts) / num_experts
         mlp = (sent + num_shared_experts) * mlp + 2.0 * hidden * num_experts
+        if shared_gate:
+            mlp += 2.0 * hidden
     return tokens * (proj + attn + mlp)
 
 
@@ -128,6 +163,10 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
     if getattr(cfg, "kv_lora_rank", 0):
         latent = {k: getattr(cfg, k) for k in (
             "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")}
+    linear = None
+    if getattr(cfg, "mixer", "attention") == "linear":
+        linear = {k: getattr(cfg, "linear_" + k) for k in (
+            "num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
     return layer_fwd_flops(
         hidden=hidden,
         num_heads=heads,
@@ -143,6 +182,9 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         experts_held=getattr(cfg, "experts_held", 0),
         num_shared_experts=getattr(cfg, "num_shared_experts", 0),
         latent=latent,
+        attn_gate=bool(getattr(cfg, "attn_output_gate", False)),
+        shared_gate=bool(getattr(cfg, "shared_expert_gate", False)),
+        linear=linear,
     )
 
 

@@ -60,6 +60,12 @@ EXPERT_STEP_FIELDS = ("loss_ce", "loss_load_balance", "loss_router_z",
 SHARE_STEP_FIELDS = ("loss_mtp", "expert_rows_held", "expert_rows_held_over_even",
                      "router_bias_abs_max")
 
+# linear-attention layers' counters (models/base.linear_mixer), over the
+# step's tokens, heads and linear layers: the mean gate exp(g), how much of
+# its state a token keeps; the largest magnitude in any head's final state,
+# the delta rule's blow-up alarm
+LINEAR_STEP_FIELDS = ("linear_decay_mean", "linear_state_abs_max")
+
 # type -> (required field names, optional field names). Unknown types and
 # unknown keys are rejected; None-valued optional fields are dropped at emit
 # so readers never see explicit nulls.
@@ -94,7 +100,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         # beside the loss, fetched with it
         ("loss", "iter_ms", "dispatch_ms", "data_wait_ms", "host_blocked_ms",
          "hbm_in_use_mb", "hbm_peak_mb", "mfu", "model_flops_per_s",
-         "grad_norm") + EXPERT_STEP_FIELDS + SHARE_STEP_FIELDS,
+         "grad_norm") + EXPERT_STEP_FIELDS + SHARE_STEP_FIELDS + LINEAR_STEP_FIELDS,
     ),
     "eval": (("iter", "split", "loss"), ()),
     # lifecycle: checkpointing

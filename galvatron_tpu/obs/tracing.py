@@ -58,6 +58,14 @@ MOE_SHARED = "gt.moe.shared"  # the shared expert(s): a dense SwiGLU beside the 
 # the low-rank projections, their norms, rope and the output projection,
 # everything of the attention half but the attention call itself
 ATTN_LATENT = "gt.attn.latent"
+# a gated-DeltaNet linear-attention mixer (models/base.linear_mixer), inside
+# gt.layers.r<k>, in two disjoint scopes that add up to the mixer: the core
+# (ops/linear_attention.gated_delta_rule: the chunks' solves, the carried
+# state, the outputs; forward, recomputed and backward) and everything else
+# of it (projections, the convolution, gates, the gated norm, the output
+# projection)
+ATTN_DELTA = "gt.attn.delta"
+ATTN_LINEAR = "gt.attn.linear"
 # the multi-token-prediction module, top level: its norms, the (2h, h)
 # projection and its block; its pass through the head and its cross entropy
 # run under HEAD_LOSS, beside the main ones

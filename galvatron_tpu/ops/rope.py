@@ -16,12 +16,19 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0):
     return 1.0 / (theta ** exponents)
 
 
-def apply_rotary(x, positions, theta: float = 10000.0, interleaved: bool = False):
+def apply_rotary(x, positions, theta: float = 10000.0, interleaved: bool = False,
+                 rotary_dim=None):
     """Rotate (B, S, n_heads, head_dim) by per-token positions (B, S).
 
     `interleaved=False` is the HF/LLaMA half-split convention
     (rotate_half); `interleaved=True` pairs adjacent dims (GPT-NeoX style).
-    fp32 math, result cast back to x.dtype."""
+    fp32 math, result cast back to x.dtype. `rotary_dim` (HF's
+    `partial_rotary_factor` x head_dim): the leading `rotary_dim` dims of a
+    head are rotated, at the frequencies of a head of that size, and the
+    rest pass as they are."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = apply_rotary(x[..., :rotary_dim], positions, theta, interleaved)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     dtype = x.dtype
     head_dim = x.shape[-1]
     inv_freq = rope_frequencies(head_dim, theta)

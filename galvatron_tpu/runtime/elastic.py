@@ -77,6 +77,10 @@ _DIGEST_DEFAULTS = {
     "num_shared_experts": 0, "router_score": "softmax", "routed_scaling_factor": 1.0,
     "router_bias": False, "router_bias_update_rate": 0.0, "experts_held": 0,
     "experts_held_start": 0, "mtp_layers": 0, "mtp_loss_weight": 0.0,
+    "full_attention_interval": 0, "linear_num_key_heads": 0, "linear_num_value_heads": 0,
+    "linear_key_head_dim": 0, "linear_value_head_dim": 0, "linear_conv_kernel": 0,
+    "partial_rotary_factor": 1.0, "attn_output_gate": False, "norm_zero_centered": False,
+    "shared_expert_gate": False, "mixer": "attention",
 }
 
 

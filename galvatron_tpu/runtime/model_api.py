@@ -558,7 +558,7 @@ def construct_hybrid_parallel_model(
             token_type_ids=b.get("token_type_ids"), attn_mask=b.get("attn_mask"),
         )
         local_loss = lambda p, b: M.lm_loss_fn(p, b, cfg)
-        if loss_fn is None and getattr(cfg, "routed", False):
+        if loss_fn is None and getattr(cfg, "layer_aux", False):
             loss_parts = lambda p, b: M.lm_loss_fn(p, b, cfg, hp, mesh, with_parts=True)
     if hp.pp > 1 or loss_fn is not None:
         # custom losses have no constraint-free local form; pp>1 never takes

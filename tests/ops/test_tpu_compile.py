@@ -488,3 +488,35 @@ def test_the_routed_block_keeps_k_out_of_the_tiles_on_v5e(held_share, k):
                 or ("f32", every_row) in sizes):
             offenders.append("%s = %s %s" % (name, result, kind))
     assert not offenders, "\n".join(offenders)
+
+
+def test_the_delta_rule_keeps_a_state_a_chunk_and_runs_on_the_mxu_on_v5e(v5e_2x2):
+    """The gated delta rule's core at the Qwen3-Next cell's widths (8192
+    tokens, 16 key heads serving 32 value heads, 128 x 128 states), forward
+    and backward, for a described v5e: no array of tokens x heads x d_k x d_v
+    is ever formed (the recurrence token by token would keep one for its
+    backward): the largest is the chunks' starting states, tokens / 64 of
+    them a head; the chunks' products are matmuls and the state is carried by
+    a loop."""
+    from galvatron_tpu.ops import linear_attention as L
+
+    tokens, hk, hv, dk, dv = 8192, 16, 32, 128, 128
+    chip = SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)  # noqa: E731
+    operands = (sds((1, tokens, hk, dk), jnp.bfloat16), sds((1, tokens, hk, dk), jnp.bfloat16),
+                sds((1, tokens, hv, dv), jnp.bfloat16), sds((1, tokens, hv), jnp.float32),
+                sds((1, tokens, hv), jnp.float32))
+
+    def loss(*ops):
+        o, state = L.gated_delta_rule(*ops)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.max(jnp.abs(state))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*operands).compile()
+    hlo = compiled.as_text()
+    sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", hlo)]
+    assert max(sizes) == tokens // L.CHUNK * hv * dk * dv  # the kept chunk-start states
+    assert max(sizes) * L.CHUNK == tokens * hv * dk * dv
+    assert " while(" in hlo and len(re.findall(r" (?:dot|convolution)\(", hlo)) >= 20
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 1.0 * 2**30  # all heads at once: 2.3 GiB
