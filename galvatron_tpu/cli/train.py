@@ -7,6 +7,7 @@ registry; the per-layer strategy comes from GLOBAL flags or a searched JSON
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import os
 import signal
@@ -27,6 +28,7 @@ from galvatron_tpu.cli.arguments import (
 )
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import telemetry, tracing
+from galvatron_tpu.ops import linear_attention
 from galvatron_tpu.profiler.runtime import (
     RuntimeProfiler,
     compiled_step_memory_mb,
@@ -501,7 +503,9 @@ def _train(args) -> dict:
         if _aot["fn"] is None:
             with control.span(tracing.COMPILE):
                 t0 = time.perf_counter()
+                delta_rule_took = collections.Counter(linear_attention.TOOK)
                 lowered = step_fn.lower(*step_args)
+                delta_rule_took = linear_attention.TOOK - delta_rule_took
                 t1 = time.perf_counter()
                 key = _step_exec_key(model.mesh, lowered)
                 compiled = _STEP_EXECUTABLES.get(key)
@@ -528,6 +532,12 @@ def _train(args) -> dict:
                 compiled_memory_mb=prof.compiled_memory_mb,
                 xla_flops_per_step=obs_flops.xla_flops(compiled),
                 cache_hit=(memo_hit or cache_hit) or None,
+                # the linear layers whose delta rule the step runs as Pallas
+                # kernels (ops/linear_attention.py): all of them or none, the
+                # layers being alike; absent where the model has none
+                linear_kernel_layers=(
+                    sum(kind.startswith("linear") for kind in cfg.layer_kinds())
+                    * (not delta_rule_took["xla"]) if delta_rule_took else None),
             )
             _aot["fn"] = compiled
         return _aot["fn"](*step_args)

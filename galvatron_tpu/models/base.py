@@ -720,7 +720,8 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
     return o, kv_out, None
 
 
-def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, **_):
+def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, *,
+                 attn_sharding: Optional[KernelSharding] = None, **_):
     """Gated DeltaNet on normed activations (B, S, H) (HF
     `Qwen3NextGatedDeltaNet`; arXiv:2412.06464), p the layer's tree:
 
@@ -735,7 +736,8 @@ def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
     -> out, None, and the layer's counters: the mean gate `exp(g)` (how much
     state a token keeps) and the largest magnitude in any head's final state.
     Scopes: the core under `gt.attn.delta`, all else under `gt.attn.linear`.
-    No position enters: the order is the recurrence's."""
+    No position enters: the order is the recurrence's. `attn_sharding` tells
+    the core where its operands lie (on TPUs it runs as Pallas kernels)."""
     p, dtype = p["linear"], cfg.compute_dtype
     nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
@@ -758,7 +760,7 @@ def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
         k = unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, nk, dk)).astype(dtype)
         v = qkv[..., 2 * key_dim:].reshape(b, s, nv, dv)
     with jax.named_scope(tracing.ATTN_DELTA):
-        o, state = gated_delta_rule(q, k, v, g, beta)
+        o, state = gated_delta_rule(q, k, v, g, beta, sharding=attn_sharding)
     with jax.named_scope(tracing.ATTN_LINEAR):
         o = rms_norm(o.astype(jnp.float32), p["norm"]["scale"], cfg.layernorm_eps)
         o = (o * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)

@@ -381,6 +381,9 @@ def render(analysis: Dict[str, Any]) -> str:
                _fmt(comp.get("compiled_memory_mb")),
                _fmt(comp.get("xla_flops_per_step")))
         )
+        if "linear_kernel_layers" in comp:
+            lines.append("linear layers whose delta rule runs as Pallas kernels: %d"
+                         % comp["linear_kernel_layers"])
     an = analysis["anomalies"]
     lines.append(
         "resilience: %d anomalies skipped, %d rollbacks, %d retries"

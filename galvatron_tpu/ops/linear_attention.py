@@ -26,8 +26,8 @@ W S_0`, and a whole chunk is an AFFINE map of the state:
 
 Everything but `S_0` is made for all chunks at once in batched matmuls; what
 runs along the sequence is ONE (d_k, d_k) x (d_k, d_v) matmul a chunk and a
-head (`_carry`), and the outputs are read off the chunks' starting states
-afterwards, again all at once. No array of (tokens, heads, d_k, d_v) exists:
+head (`_carry`; the kernels: two smaller ones), and the outputs are read off
+the chunks' starting states afterwards, again all at once. No array of (tokens, heads, d_k, d_v) exists:
 the states kept are the chunks' (tokens / CHUNK of them).
 
 Float32: `g`'s running sums and every exponential of them, `A`, the inverse
@@ -46,14 +46,40 @@ TPU's lanes), and the blocks are merged by matmuls
 powers of `A` grow as binomials where neighbouring keys are alike (a run of one
 repeated token), and cancel in float32 to nothing.
 
-**The backward** is autodiff's, through the scan over the chunks and the
-batched matmuls around it, with a head's chunk matrices recomputed from q, k,
-v, g, beta and the kept chunk-start states (`gated_delta_rule`). A
-written rule for the carried recurrence (the reverse scan `dS_n = M_n^T
-dS_{n+1} + ...` with the `M_n`'s gradients formed in one batched matmul
-afterwards) gave the same gradients to the bit and ran 8 % SLOWER on the chip
-at 8 heads at a time, 46.0 against 42.4 ms a layer forward and backward, and
-within 3 % at 16 and 32 (PERF.md, PR 35), so it is not kept.
+**Two forms of the one rule** (`gated_delta_rule(impl=)`; "auto" takes the
+kernels where the operands lie on TPUs, heads are multiples of 128 wide and
+the chunk is 64, and the XLA form everywhere else, the CPU among it):
+
+*The kernel form* (below the XLA form; what a TPU runs): two Pallas kernels,
+`gdn_fwd` and `gdn_bwd` (`jax.custom_vjp`), on chunks of 128 tokens. A grid
+step holds one value head's (d_k, d_v) float32 state in VMEM scratch and walks
+a block of chunks: the chunks' own matrices (`G`, the decay mask, `A`, `T`,
+`W`, `U0`, `P`) for the whole block at once in VMEM, then the state chunk by
+chunk as `u = U0 - W S_0`, `S_C = e^{G_C} S_0 + Kd^T u` (two products where
+forming `Kd^T W` first would be three), then the outputs. Value head h reads
+key head h // (Hv / Hk) through the blocks' index maps: q and k are never
+repeated. `T` is made IN the kernel: the 16 x 16 diagonal blocks by
+elimination a column at a time (forward substitution), all blocks of a chunk
+side by side in the lanes, then the same merges by matmul. The backward walks
+the chunks from the last with `dS` in VMEM, makes the chunks' matrices again
+from q, k, v, g, beta and what the forward KEPT (every chunk's `T` and the
+state it started from: a rule's residuals are not recomputed, so under a
+layer's `jax.checkpoint` the recomputation runs the forward kernel once and
+the backward kernel finds them), and writes the five gradients once; a key
+head's `dq`, `dk` are summed over its value heads after the kernel. On the
+chip (PERF.md, PR 36) a layer at the Qwen3-Next cell's widths takes 3.3 ms a
+call of `gdn_fwd` and 5.5 ms a call of `gdn_bwd`; with what XLA does around
+them 3.95 ms forward and 11.6 ms forward and backward, where the XLA form
+takes 7.0 and 28.4.
+
+*The XLA form* (`impl="xla"`): the backward is autodiff's, through the scan
+over the chunks and the batched matmuls around it, with a head's chunk
+matrices recomputed from q, k, v, g, beta and the kept chunk-start states
+(`_xla_rule`). A written rule for the carried recurrence (the reverse scan
+`dS_n = M_n^T dS_{n+1} + ...` with the `M_n`'s gradients formed in one batched
+matmul afterwards) gave the same gradients to the bit and ran 8 % SLOWER on
+the chip at 8 heads at a time (PERF.md, PR 35), so it is not kept. It is the
+oracle of the kernels' tests, and what every test of a small head runs.
 
 Sequences are whole rows of the batch: neither the convolution nor the state
 is cut at a document boundary inside a packed row.
@@ -61,13 +87,23 @@ is cut at a document boundary inside a packed row.
 
 from __future__ import annotations
 
-from typing import Tuple
+import collections
+import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from galvatron_tpu.ops.attention import KernelSharding
 
 CHUNK = 64
+# how many calls of `gated_delta_rule` took which form since the process began,
+# counted as they are traced: the trainer's compile report reads the difference
+TOOK = collections.Counter()
 STARTS = "gdn_chunk_starts"  # the residual a head's backward keeps
 _BASE = 16  # the diagonal blocks inverted by forward substitution
 _F32 = jnp.float32
@@ -166,15 +202,9 @@ def _head_core(q, k, v, g, beta):
     return (_mm(q_hat, starts.astype(dt)) + _mm(p, u0)).astype(dt), last
 
 
-def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
-                     *, chunk: int = CHUNK) -> Tuple[jax.Array, jax.Array]:
-    """q, k (B, S, Hk, d_k), L2-normalised and q scaled; v (B, S, Hv, d_v); g
-    (B, S, Hv) the log of the gate, <= 0; beta (B, S, Hv) -> o (B, S, Hv, d_v)
-    in v's dtype, and the final states (B, Hv, d_k, d_v) float32. Each key
-    head serves Hv / Hk consecutive value heads.
-
-    The value heads are worked ONE AFTER THE OTHER (`lax.map`), and a head's
-    backward recomputes its chunks' matrices from q, k, v, g, beta and the
+def _xla_rule(q, k, v, g, beta, chunk):
+    """The XLA form of `gated_delta_rule`. The value heads are worked ONE
+    AFTER THE OTHER (`lax.map`), and a head's backward recomputes its chunks' matrices from q, k, v, g, beta and the
     chunks' starting states, which alone are kept (a sequence's worth of
     float32 (d_k, d_v) a chunk). The matrices of all 32 heads at once are two
     gigabytes at 8192 tokens; a head's fit the chip's fast memory, and on the
@@ -182,9 +212,6 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, bet
     29.9, 38.3, 42.4 and 47.4 ms at 2, 4, 8 and 32 heads at a time (PERF.md,
     PR 35)."""
     b, s, hv, dv = v.shape
-    if s % chunk:
-        raise ValueError("gated_delta_rule: a sequence of %d tokens is no multiple of the "
-                         "chunk of %d" % (s, chunk))
 
     def chunked(x):  # (B, S, H, ...) -> (H, N, B, C, ...)
         x = x.reshape((b, s // chunk, chunk) + x.shape[2:])
@@ -195,3 +222,441 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, bet
     o, last = jax.lax.map(lambda xs: core(*xs),
                           (q, k, chunked(v), chunked(g.astype(_F32)), chunked(beta.astype(_F32))))
     return o.transpose(2, 1, 3, 0, 4).reshape(b, s, hv, dv), jnp.moveaxis(last, 0, 1)
+
+
+# --- the kernel form -------------------------------------------------------
+#
+# The same rule on chunks of TILE = 128 tokens (the chunk's length is free:
+# the mathematics above holds for any; two of the XLA form's chunks make one
+# of the kernels'), so a chunk's matrices (the decay mask, A, T, P) are (128,
+# 128): whole tiles of the vector unit and whole passes of the MXU, and half
+# as many dependent steps along the sequence. A grid step walks `_BLOCK`
+# tiles of one value head of one row of the batch, the head's (d_k, d_v)
+# state in VMEM scratch from the step before; nothing of a tile but `T` and
+# the state it started from (both only for the backward) is written to HBM.
+
+TILE = 2 * CHUNK
+_ROWS = 8  # a tile's per-token scalars, rows of one float32 (8, 128) (`_scalars`)
+_BLOCK = 8  # tiles a grid step walks where its operands are 2 bytes wide
+_VMEM = 64 * 2**20  # what a kernel may hold of the chip's 128 MiB: a block's tiles' matrices at once
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))  # a product's contracted axes
+
+
+def _dot(a, b, dims):
+    """A product accumulated in float32, over a leading batch axis where the
+    operands have one; float32 operands are multiplied as float32 (Mosaic's
+    `contract_precision<fp32>`), as `_mm` does. `dims`: the contracted axes
+    of a matrix, `_NN`, `_NT` or `_TN`."""
+    exact = a.dtype == _F32 or b.dtype == _F32
+    batched = a.ndim == 3
+    contract = tuple((axis + batched,) for (axis,) in dims)
+    return jax.lax.dot_general(a, b, (contract, (((0,), (0,)) if batched else ((), ()))),
+                               preferred_element_type=_F32,
+                               precision=jax.lax.Precision.HIGHEST if exact else None)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _invariants():
+    """Masks of a tile's (128, 128): j <= t and j < t, the identity, and for
+    `_inverses` the lane's 16 x 16 block, the packed identity and the blocks
+    each merge reads (the lower left quarter of every diagonal block of twice
+    the size)."""
+    row, col = _iota((TILE, TILE), 0), _iota((TILE, TILE), 1)
+    below, size = [], _BASE
+    while size < TILE:
+        below.append(((row // (2 * size)) == (col // (2 * size))) & ((row // size) % 2 == 1)
+                     & ((col // size) % 2 == 0))
+        size *= 2
+    return dict(lower=row >= col, strict=row > col, eye=(row == col).astype(_F32), below=below,
+                lane_block=_iota((_BASE, TILE), 1) // _BASE,
+                unit=(_iota((_BASE, TILE), 0) == _iota((_BASE, TILE), 1) % _BASE).astype(_F32))
+
+
+def _inverses(a, inv):
+    """(n, 128, 128) float32, strictly lower -> (I + a)^-1 of each. The 16 x
+    16 diagonal blocks by elimination (`X <- X - a[:, j] X[j, :]`, j
+    ascending: forward substitution, a column at a time) on all of them at
+    once, block b's rows in the lanes 16 b to 16 b + 15 of a tile's (16, 128);
+    then the merges of `unit_lower_inverse`, `t21 = -t22 a21 t11`: a matmul
+    each side on the rows that change (the lower half of every block being
+    merged)."""
+    n, blocks, lane_block = a.shape[0], TILE // _BASE, inv["lane_block"]
+    packed = jnp.zeros((n, _BASE, TILE), _F32)  # packed[., i, 16 b + c] = a[., 16 b + i, 16 b + c]
+    for b in range(blocks):
+        packed = jnp.where(lane_block == b, a[:, b * _BASE:(b + 1) * _BASE, :], packed)
+    flat = packed.reshape(n * _BASE, TILE)
+    lanes = jnp.concatenate([lane_block * _BASE] * n, axis=0)
+    x = jnp.broadcast_to(inv["unit"], packed.shape)
+    for j in range(_BASE - 1):
+        column = jnp.take_along_axis(flat, lanes + j, axis=1).reshape(packed.shape)  # a[:, j], a block
+        x = x - column * x[:, j:j + 1, :]
+    pieces = [jnp.where(lane_block == b, x, 0.0) for b in range(blocks)]  # `size` rows each
+    size = _BASE
+    for below in inv["below"]:
+        lower_halves = jnp.concatenate(pieces[1::2], axis=1)
+        moved = _dot(_dot(lower_halves, jnp.where(below, a, 0.0), _NN),
+                     jnp.concatenate(pieces, axis=1), _NN)
+        pieces = [jnp.concatenate([pieces[2 * b], pieces[2 * b + 1] - moved[:, b * size:(b + 1) * size]],
+                                  axis=1) for b in range(len(pieces) // 2)]
+        size *= 2
+    return pieces[0]
+
+
+def _tiles_of(ref):  # a block's tokens (n x 128, d) -> (n, 128, d)
+    return ref[...].reshape(ref.shape[0] // TILE, TILE, ref.shape[1])
+
+
+def _local(q, k, v, rows, inv, inverse=None):
+    """What a block's n tiles make of q, k, v (n, 128, d), G and beta without
+    their states, all n at once: the products of one tile follow those of
+    another in the program, none waiting for the other's result. rows (n, 8,
+    128) hold G, beta and the tile's last G a token; `inverse` is `T` where it
+    was kept."""
+    n = rows.shape[0]
+    # the rows as columns, (n, 128, 8): the product with the identity is the
+    # transpose (exact: one factor is 1, the others 0), one for the block
+    cols = _dot(inv["eye"], rows.reshape(n * _ROWS, TILE), _NT)
+    cols = jnp.stack([cols[:, tile * _ROWS:(tile + 1) * _ROWS] for tile in range(n)])
+    g_row, g_col, beta, g_end = rows[:, 0:1, :], cols[:, :, 0:1], cols[:, :, 1:2], cols[:, :, 2:3]
+    from_start = jnp.exp(g_col)
+    k32 = k.astype(_F32)
+    decay = jnp.exp(jnp.where(inv["lower"], g_col - g_row, -jnp.inf))
+    kk = _dot(k, k, _NT)
+    if inverse is None:
+        inverse = _inverses(jnp.where(inv["strict"], beta * decay * kk, 0.0), inv)
+    rhs = jnp.concatenate([k32 * (beta * from_start), v.astype(_F32) * beta], axis=2)  # [Rw | Ru]
+    return dict(beta=beta, from_start=from_start, to_end=jnp.exp(g_end - g_col), decay=decay,
+                kk=kk, qk=_dot(q, k, _NT), inverse=inverse, rhs=rhs, wu=_dot(inverse, rhs, _NN),
+                k32=k32)
+
+
+def _keeps(rows_ref, i, width):
+    """e^{G_C} of the block's tile i, a (1, width) row: the rows hold G_C in
+    every lane, so the row that scales the state needs no broadcast."""
+    row = jnp.exp(rows_ref[i][2:3, :])
+    return row if width == TILE else jnp.concatenate([row] * (width // TILE), axis=1)
+
+
+def _here(step, block, tiles):
+    """How many tiles of the block of step `step` exist (the last block of a
+    sequence need not be whole)."""
+    return block if tiles % block == 0 else jnp.minimum(block, tiles - step * block)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, block, tiles, keep):
+    """One grid step of the forward walk over a block of tiles, the head's
+    state in `s_ref` from the step before: the tiles' own matrices all at
+    once, then the state tile by tile (two dependent products each), then the
+    outputs all at once."""
+    if keep:
+        starts_ref, t_ref, last_ref, s_ref, wu_ref, kd_ref, u_ref = rest
+    else:
+        last_ref, s_ref, wu_ref, kd_ref, u_ref, starts_ref = rest
+    step = pl.program_id(2)
+    dt = v_ref.dtype
+    dk = q_ref.shape[1]
+    inv = _invariants()
+    q, k, v = _tiles_of(q_ref), _tiles_of(k_ref), _tiles_of(v_ref)
+    m = _local(q, k, v, rows_ref[...], inv)
+    if keep:
+        t_ref[...] = m["inverse"]
+    wu_ref[...] = m["wu"]
+    kd_ref[...] = m["k32"] * m["to_end"]
+
+    @pl.when(step == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    def tile(i, carry):
+        state = s_ref[...]
+        starts_ref[i] = state
+        u = wu_ref[i][:, dk:] - _dot(wu_ref[i][:, :dk], state, _NN)  # U0 - W S_0
+        u_ref[i] = u
+        s_ref[...] = _keeps(rows_ref, i, state.shape[1]) * state + _dot(kd_ref[i], u, _TN)
+        return carry
+
+    jax.lax.fori_loop(0, _here(step, block, tiles), tile, None)
+    p = (m["decay"] * m["qk"]).astype(dt)
+    o = (_dot((q.astype(_F32) * m["from_start"]).astype(dt), starts_ref[...].astype(dt), _NN)
+         + _dot(p, u_ref[...].astype(dt), _NN))
+    o_ref[...] = o.reshape(o_ref.shape).astype(dt)
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _():
+        last_ref[...] = s_ref[...]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, starts_ref, t_ref, do_ref, dlast_ref,
+                dq_ref, dk_ref, dv_ref, drows_ref,
+                ds_ref, fo_ref, kd_ref, w_ref, qgdo_ref, du_ref, dends_ref, *, block, tiles):
+    """One grid step of the reverse walk: a block's tiles of one value head
+    from the last, `ds_ref` the gradient of the state that the later tiles
+    left. The tiles' matrices are made again from q, k, v, G, beta, the kept
+    `T` and the states the tiles started from, all at once; the state's
+    gradient goes tile by tile (two dependent products each, and each tile's
+    `dS_C` is kept in `dends_ref`); the rest all at once again.
+
+    With `u = U0 - W S_0`, `o = (q e^G) S_0 + P u` and `S_C = e^{G_C} S_0 +
+    Kd^T u`: `du = P^T do + Kd dS_C`, `dS_0 = e^{G_C} dS_C + (q e^G)^T do -
+    W^T du`, `dW = -du S_0^T`, `dU0 = du`; through `[W | U0] = T [Rw | Ru]`:
+    `d[Rw | Ru] = T^T [dW | dU0]`, `dT = [dW | dU0] [Rw | Ru]^T`, and `dA =
+    -T^T dT T^T` on the strictly lower part; the rest is elementwise."""
+    step = pl.program_id(2)
+    dt = v_ref.dtype
+    dk = q_ref.shape[1]
+    inv = _invariants()
+    q, k, v, do = _tiles_of(q_ref), _tiles_of(k_ref), _tiles_of(v_ref), _tiles_of(do_ref)
+    rows = rows_ref[...]
+    m = _local(q, k, v, rows, inv, t_ref[...])
+    beta, from_start, to_end, decay, inverse, rhs, k32 = (
+        m[name] for name in "beta from_start to_end decay inverse rhs k32".split())
+    states = starts_ref[...]
+    qg32 = q.astype(_F32) * from_start
+    kd = k32 * to_end
+    p = (decay * m["qk"]).astype(dt)
+    w = m["wu"][:, :, :dk]
+    u = m["wu"][:, :, dk:] - _dot(w, states, _NN)
+    fo_ref[...] = _dot(p, do, _TN)  # P^T do
+    qgdo_ref[...] = _dot(qg32.astype(dt), do, _TN)
+    kd_ref[...] = kd
+    w_ref[...] = w
+
+    @pl.when(step == 0)
+    def _():
+        ds_ref[...] = dlast_ref[...]
+
+    here = _here(pl.num_programs(2) - 1 - step, block, tiles)  # the reverse walk's block
+
+    def tile(j, carry):
+        i = here - 1 - j
+        dstate = ds_ref[...]
+        dends_ref[i] = dstate
+        du = fo_ref[i] + _dot(kd_ref[i], dstate, _NN)
+        du_ref[i] = du
+        ds_ref[...] = (_keeps(rows_ref, i, dstate.shape[1]) * dstate + qgdo_ref[i]
+                       - _dot(w_ref[i], du, _TN))
+        return carry
+
+    jax.lax.fori_loop(0, here, tile, None)
+
+    def rows_sum(x):  # (n, 128, m) -> (n, 128, 1)
+        return jnp.sum(x, axis=2, keepdims=True)
+
+    def as_row(col):  # (n, 128, 1) -> (n, 1, 128)
+        return jnp.sum(col * inv["eye"], axis=1, keepdims=True)
+
+    def total(x):  # (n, a, b) -> (n, 1, 1)
+        return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=2, keepdims=True)
+
+    du, dends = du_ref[...], dends_ref[...]
+    dkd = _dot(u, dends, _NT)
+    dqg = _dot(do, states.astype(dt), _NT)
+    dp = _dot(do, u.astype(dt), _NT)
+    dwu = jnp.concatenate([-_dot(du, states, _NT), du], axis=2)  # [dW | dU0]
+    drhs = _dot(inverse, dwu, _TN)
+    da = jnp.where(inv["strict"],
+                   -_dot(_dot(inverse, _dot(dwu, rhs, _NT), _TN), inverse, _NT), 0.0)
+    drw, dru = drhs[:, :, :dk], drhs[:, :, dk:]
+    dqk = (dp * decay).astype(dt)
+    dkk = da * (beta * decay)  # k k^T reads k on both sides
+    dq_ref[...] = (dqg * from_start + _dot(dqk, k, _NN)).reshape(dq_ref.shape).astype(dq_ref.dtype)
+    dk_ref[...] = (drw * (beta * from_start) + dkd * to_end + _dot(dqk, q, _TN)
+                   + _dot(dkk, k32, _NN) + _dot(dkk, k32, _TN)).reshape(dk_ref.shape).astype(dk_ref.dtype)
+    dv_ref[...] = (dru * beta).reshape(dv_ref.shape).astype(dv_ref.dtype)
+    # beta and G: the sums over a token's row, and D's two sides
+    dbeta = (rows_sum(dru * v.astype(_F32)) + rows_sum(drw * k32) * from_start
+             + rows_sum(da * decay * m["kk"]))
+    e = (da * beta * m["kk"] + dp * m["qk"]) * decay  # dD D
+    dg = rows_sum(dqg * qg32 + drw * rhs[:, :, :dk] - dkd * kd) + rows_sum(e)
+    # G_C is the tile's last G
+    dend = total(dkd * kd) + jnp.exp(rows[:, 2:3, 0:1]) * total(dends * states)
+    last_lane = _iota((1, TILE), 1) == TILE - 1
+    drows_ref[:, 0:1, :] = (as_row(dg) - jnp.sum(e, axis=1, keepdims=True)
+                            + jnp.where(last_lane, dend, 0.0))
+    drows_ref[:, 1:2, :] = as_row(dbeta)
+
+
+def _scalars(g, beta):
+    """g, beta (B, S, Hv) -> what the kernels read of them, (B, Hv, tiles, 8,
+    128) float32, a tile of tokens a row and a token a lane: G (the running
+    sum of g inside the tile), beta, and the tile's last G in EVERY lane (a
+    row that scales the state needs no broadcast in the kernel)."""
+    b, s, hv = g.shape
+
+    def heads_first(x):
+        return x.astype(_F32).transpose(0, 2, 1).reshape(b, hv, s // TILE, TILE)
+
+    total, beta = jnp.cumsum(heads_first(g), axis=-1), heads_first(beta)
+    rows = jnp.stack([total, beta, jnp.broadcast_to(total[..., -1:], total.shape)], axis=3)
+    return jnp.pad(rows, ((0, 0),) * 3 + ((0, _ROWS - 3), (0, 0)))
+
+
+def _block(v):
+    """Tiles a grid step walks: `_BLOCK` at the cell's widths (bf16, d_v 128),
+    fewer where the operands are wider (the scoped VMEM holds two of every
+    block)."""
+    return max(1, min(_BLOCK * 2 * TILE // (v.dtype.itemsize * v.shape[3]), v.shape[1] // TILE))
+
+
+def _call(kernel, name, q, v, reverse, in_kinds, out_kinds, scratch_kinds, operands):
+    """A walk over (batch, value head, blocks of tiles), the last axis in
+    order, from the sequence's last block with `reverse`. Kinds of blocks: "key" / "value"
+    (a block's tokens of one head, d_k / d_v wide; a key head serves Hv / Hk
+    value heads: the index map sends value head h to key head h // (Hv / Hk),
+    and no q or k is repeated), "keys" (d_k wide a VALUE head), "rows",
+    "starts", "inverse" (a block's tiles), "state" (a head's)."""
+    (b, s, hv, dv), (hk, dk) = v.shape, q.shape[2:]
+    block, tiles = _block(v), s // TILE
+    steps = pl.cdiv(tiles, block)
+
+    def at(step):  # the block a step walks
+        return steps - 1 - step if reverse else step
+
+    def tokens(width, serves):
+        return pl.BlockSpec((None, block * TILE, width), lambda i, h, c: (i, at(c), h // serves))
+
+    def a_tile(*shape):
+        return pl.BlockSpec((None, None, block) + shape, lambda i, h, c: (i, h, at(c), 0, 0))
+
+    specs = {"key": tokens(dk, hv // hk), "keys": tokens(dk, 1), "value": tokens(dv, 1),
+             "rows": a_tile(_ROWS, TILE), "starts": a_tile(dk, dv), "inverse": a_tile(TILE, TILE),
+             "state": pl.BlockSpec((None, None, dk, dv), lambda i, h, c: (i, h, 0, 0))}
+    shapes = {"keys": ((b, s, hv * dk), q.dtype), "value": ((b, s, hv * dv), v.dtype),
+              "rows": ((b, hv, tiles, _ROWS, TILE), _F32), "starts": ((b, hv, tiles, dk, dv), _F32),
+              "inverse": ((b, hv, tiles, TILE, TILE), _F32), "state": ((b, hv, dk, dv), _F32),
+              # scratch alone: a block's tiles by d_k, by d_v, by both side by side
+              "by_k": ((b, hv, tiles, TILE, dk), _F32), "by_v": ((b, hv, tiles, TILE, dv), _F32),
+              "by_kv": ((b, hv, tiles, TILE, dk + dv), _F32)}
+    return pl.pallas_call(
+        functools.partial(kernel, block=block, tiles=tiles),
+        grid=(b, hv, steps),
+        in_specs=[specs[kind] for kind in in_kinds], out_specs=[specs[kind] for kind in out_kinds],
+        out_shape=[jax.ShapeDtypeStruct(*shapes[kind]) for kind in out_kinds],
+        scratch_shapes=[pltpu.VMEM(shapes[kind][0][2:] if kind == "state" else
+                                   (block,) + shapes[kind][0][3:], _F32) for kind in scratch_kinds],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM),
+        name=name,
+    )(*operands)
+
+
+def _flat(x):  # (B, S, H, d) -> (B, S, H d): a head's block is then (tokens, d), whole tiles
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+def _forward(q, k, v, g, beta, keep):
+    """The forward kernel: -> o (B, S, Hv, d_v), the final states, and with
+    `keep` what the backward reads again (the scalars' rows, the states the
+    tiles started from, every tile's `T`)."""
+    rows = _scalars(g, beta)
+    out = _call(functools.partial(_fwd_kernel, keep=keep), "gdn_fwd", q, v, False,
+                ["key", "key", "value", "rows"], ["value"] + ["starts", "inverse"] * keep + ["state"],
+                ["state", "by_kv", "by_k", "by_v"] + ["starts"] * (not keep),
+                (_flat(q), _flat(k), _flat(v), rows))
+    return out[0].reshape(v.shape), out[-1], ((rows,) + tuple(out[1:3]) if keep else None)
+
+
+@jax.custom_vjp
+def _kernel_rule(q, k, v, g, beta):
+    return _forward(q, k, v, g, beta, keep=False)[:2]
+
+
+def _kernel_rule_fwd(q, k, v, g, beta):
+    o, last, kept = _forward(q, k, v, g, beta, keep=True)
+    return (o, last), (q, k, v, g, beta, kept)
+
+
+def _kernel_rule_bwd(residuals, cotangents):
+    q, k, v, g, beta, (rows, starts, inverse) = residuals
+    do, dlast = cotangents
+    (b, s, hv, dv), (hk, dk) = v.shape, q.shape[2:]
+    dq, dk_, dv_, drows = _call(
+        _bwd_kernel, "gdn_bwd", q, v, True,
+        ["key", "key", "value", "rows", "starts", "inverse", "value", "state"],
+        ["keys", "keys", "value", "rows"],
+        ["state", "by_v", "by_k", "by_k", "starts", "by_v", "starts"],
+        (_flat(q), _flat(k), _flat(v), rows, starts, inverse, _flat(do), dlast.astype(_F32)))
+
+    def to_key_heads(x):
+        """A key head's gradient is the sum over the value heads it serves,
+        which lie side by side: slices a whole number of lanes wide, and no
+        axis to reduce over (XLA made that one two relayouts and a reduction)."""
+        x = x.reshape(b, s, hk, (hv // hk) * dk)
+        served = [x[..., at:at + dk].astype(_F32) for at in range(0, x.shape[-1], dk)]
+        return functools.reduce(jnp.add, served).astype(x.dtype)
+
+    def tokens_first(x):  # (B, Hv, tiles, 128) -> (B, S, Hv)
+        return x.reshape(b, hv, s).transpose(0, 2, 1)
+
+    # G is g's running sum inside a tile: g_t reaches every G_i, i >= t
+    dg = jnp.flip(jnp.cumsum(jnp.flip(drows[..., 0, :], -1), axis=-1), -1)
+    return (to_key_heads(dq), to_key_heads(dk_), dv_.reshape(v.shape),
+            tokens_first(dg).astype(g.dtype), tokens_first(drows[..., 1, :]).astype(beta.dtype))
+
+
+_kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
+
+
+def _kernel_form(q, k, v, g, beta):
+    """`_kernel_rule` on whole tiles: an odd number of chunks gets a chunk of
+    zeros behind it (beta 0: it writes nothing; g 0: it forgets nothing)."""
+    s = v.shape[1]
+    if s % TILE == 0:
+        return _kernel_rule(q, k, v, g, beta)
+    q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, TILE - s % TILE)) + ((0, 0),) * (x.ndim - 2))
+                        for x in (q, k, v, g, beta))
+    o, last = _kernel_rule(q, k, v, g, beta)
+    return o[:, :s], last
+
+
+def _sharded_kernel_form(q, k, v, g, beta, sharding: KernelSharding):
+    """`_kernel_form` a device on its own rows of the batch, under a manual
+    region (GSPMD cannot partition a Mosaic kernel; see `KernelSharding` and
+    `ops/attention._sharded_pallas_flash`, whose pattern this is)."""
+    rows = sharding.batch_axes or None
+    ctx = jax.sharding.get_abstract_mesh()
+    use_mesh = sharding.mesh if ctx.empty else ctx
+    return jax.shard_map(
+        _kernel_form, mesh=use_mesh,
+        in_specs=(P(rows, None, None, None),) * 3 + (P(rows, None, None),) * 2,
+        out_specs=(P(rows, None, None, None),) * 2,
+        axis_names=set(use_mesh.axis_names) - set(use_mesh.manual_axes), check_vma=False,
+    )(q, k, v, g, beta)
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
+                     *, chunk: int = CHUNK, impl: str = "auto",
+                     sharding: Optional[KernelSharding] = None) -> Tuple[jax.Array, jax.Array]:
+    """q, k (B, S, Hk, d_k), L2-normalised and q scaled; v (B, S, Hv, d_v); g
+    (B, S, Hv) the log of the gate, <= 0; beta (B, S, Hv) -> o (B, S, Hv, d_v)
+    in v's dtype, and the final states (B, Hv, d_k, d_v) float32. Each key
+    head serves Hv / Hk consecutive value heads.
+
+    `impl`: "pallas" the kernels, "xla" the XLA form, "auto" the kernels where
+    they can run: the operands on TPUs (`sharding`'s mesh says so, as in
+    `core_attention`; with none, the default backend), d_k and d_v multiples
+    of 128, the chunk 64, and the call on one device or, with `sharding`, on
+    whole rows of the batch a device and all heads on each (the linear layers
+    have no other layout). Everything else, the CPU among it, takes the XLA
+    form."""
+    s = v.shape[1]
+    if s % chunk:
+        raise ValueError("gated_delta_rule: a sequence of %d tokens is no multiple of the "
+                         "chunk of %d" % (s, chunk))
+    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
+    if sharding is not None and sharding.mesh.size == 1:
+        sharding = None  # one device: the kernels need no manual region
+    if impl == "auto":
+        fits = (chunk == CHUNK and q.shape[3] % TILE == 0 and v.shape[3] % TILE == 0
+                and (sharding is None or sharding.divides(v.shape[0], 1)))
+        impl = "pallas" if on_tpu and fits else "xla"
+    TOOK[impl] += 1
+    if impl == "xla":
+        return _xla_rule(q, k, v, g, beta, chunk)
+    if sharding is None:
+        return _kernel_form(q, k, v, g, beta)
+    return _sharded_kernel_form(q, k, v, g, beta, sharding)

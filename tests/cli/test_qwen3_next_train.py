@@ -55,6 +55,8 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, tmp_p
     # a run of three linear layers and a run of the attention layer, numbered as gt.layers.r<k>
     runs = [e for e in events if e["type"] == "layer_run" and e["run"] >= 0]
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 3), (1, 3, 4)]
+    # off a TPU none of the three linear layers takes the Pallas kernels, and the compile report says so
+    assert [e["linear_kernel_layers"] for e in events if e["type"] == "compile"] == [0]
 
 
 @pytest.mark.parametrize("flags", [

@@ -7,14 +7,24 @@ arithmetic in another order (a chunk's triangular solve and batched matmuls
 against 16 to 320 dependent rank-one updates): measured worst relative error
 3e-6 of the output's largest magnitude, 5e-6 of a gradient's; the limit is
 5e-5.
+
+The Pallas kernels (the form a TPU takes) run here in interpret mode at the
+widths they need (d_k = d_v = 128), against the XLA form AND the recurrence:
+float32 under the same 5e-5 (measured 2.5e-6); bf16 operands no further from
+the recurrence than the XLA form is on the same operands (the products on the
+way to the output round to bf16 in both), or inside the float32 limit where
+both are (the gates' gradients).
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh
 
 from galvatron_tpu.ops import linear_attention as L
+from galvatron_tpu.ops.attention import KernelSharding
 
 TOL = 5e-5
 B, HK, HV, DK, DV = 2, 2, 4, 16, 8
@@ -37,13 +47,13 @@ def recurrence(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1), state
 
 
-def operands(tokens, seed=0, hv=HV):
+def operands(tokens, seed=0, hv=HV, hk=HK, dk=DK, dv=DV):
     """Unit keys, queries / sqrt(d_k), decays from 1e-3 to 1.6 a token."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(ks[0], (B, tokens, HK, DK))) / DK ** 0.5
-    k = unit(jax.random.normal(ks[1], (B, tokens, HK, DK)))
-    v = jax.random.normal(ks[2], (B, tokens, hv, DV))
+    q = unit(jax.random.normal(ks[0], (B, tokens, hk, dk))) / dk ** 0.5
+    k = unit(jax.random.normal(ks[1], (B, tokens, hk, dk)))
+    v = jax.random.normal(ks[2], (B, tokens, hv, dv))
     g = -jnp.exp(jax.random.uniform(ks[3], (B, tokens, hv), minval=np.log(1e-3), maxval=np.log(1.6)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, tokens, hv)))
     return q, k, v, g, beta
@@ -81,6 +91,86 @@ def test_equal_neighbouring_keys_do_not_break_the_solve():
         o, _ = L.gated_delta_rule(q, k, v, g, beta, chunk=64)
         want, _ = recurrence(q, k, v, g, beta)
     assert float(jnp.max(jnp.abs(o - want))) < 1e-4 * float(jnp.max(jnp.abs(want)))
+
+
+# --- the kernel form, interpreted -------------------------------------------
+
+KERNEL = dict(hv=2, hk=1, dk=128, dv=128)  # one key head serving two value heads, unrepeated
+
+
+def kernel_rule(*ops, **kw):
+    return L.gated_delta_rule(*ops, impl="pallas", **kw)
+
+
+def worst(got, want):
+    return float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)) / jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("chunks", [2, 5])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_kernels_are_the_recurrence_and_the_xla_form(dtype, chunks, monkeypatch):
+    """o, the final states and all five gradients, batch 2. Two tiles a grid
+    step (one in float32): 5 chunks are 3 tiles behind a chunk of zeros, so
+    the last step's block is not whole, and 2 chunks are one tile, less than a
+    block."""
+    monkeypatch.setattr(L, "_BLOCK", 2)
+    ops = operands(64 * chunks, seed=chunks, **KERNEL)
+    cast = tuple(x.astype(dtype) for x in ops[:3]) + ops[3:]
+    exact = tuple(x.astype(jnp.float32) for x in cast)
+
+    def objective(rule):  # the final states' gradient enters too
+        def of(*a):
+            o, states = rule(*a)
+            return jnp.sum(jnp.sin(o.astype(jnp.float32))) + jnp.sum(jnp.cos(states))
+        return of
+
+    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
+        got = kernel_rule(*cast) + jax.grad(objective(kernel_rule), range(5))(*cast)
+        xla = (L.gated_delta_rule(*cast, impl="xla")
+               + jax.grad(objective(lambda *a: L.gated_delta_rule(*a, impl="xla")), range(5))(*cast))
+        want = recurrence(*exact) + jax.grad(objective(recurrence), range(5))(*exact)
+    assert got[0].dtype == dtype and got[0].shape == want[0].shape
+    assert got[1].dtype == jnp.float32 and got[1].shape == (B, 2, 128, 128)
+    for name, g, x, w in zip("o states dq dk dv dg dbeta".split(), got, xla, want):
+        limit = TOL if dtype == jnp.float32 else max(TOL, worst(x, w))
+        assert worst(g, w) <= limit, (name, worst(g, w), worst(x, w))
+        assert worst(g, x.astype(jnp.float32)) <= 2 * limit, name
+
+
+def test_the_kernels_solve_a_run_of_one_repeated_key():
+    """`test_equal_neighbouring_keys_do_not_break_the_solve`, through the
+    kernels' elimination and merges on a tile of 128."""
+    q, k, v, g, beta = operands(128, seed=9, **KERNEL)
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    g, beta = jnp.full_like(g, -1e-6), jnp.full_like(beta, 0.999)
+    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
+        o, _ = kernel_rule(q, k, v, g, beta)
+        want, _ = recurrence(q, k, v, g, beta)
+    assert worst(o, want) < 1e-4
+
+
+def test_the_kernels_run_a_device_on_its_rows_of_the_batch():
+    """Under `sharding` the kernels sit in a manual region over the batch:
+    two devices, a row each, the same numbers as one device on both."""
+    ops = operands(128, seed=3, **KERNEL)
+    sharding = KernelSharding(Mesh(np.array(jax.devices()[:2]), ("dp",)), batch_axes=("dp",))
+    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
+        o, state = jax.jit(lambda *a: kernel_rule(*a, sharding=sharding))(*ops)
+        want_o, want_state = kernel_rule(*ops)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(want_state), atol=1e-6)
+
+
+def test_off_a_tpu_the_choice_is_the_xla_form_and_it_is_counted():
+    ops = operands(64, seed=1)  # heads of 16 x 8: no kernel could take them
+    before = dict(L.TOOK)
+    got = L.gated_delta_rule(*ops)
+    assert L.TOOK["xla"] == before.get("xla", 0) + 1 and L.TOOK["pallas"] == before.get("pallas", 0)
+    wide = operands(64, seed=1, **KERNEL)  # the kernels' widths, but this is a CPU
+    L.gated_delta_rule(*wide)
+    assert L.TOOK["xla"] == before.get("xla", 0) + 2 and L.TOOK["pallas"] == before.get("pallas", 0)
+    np.testing.assert_array_equal(np.asarray(got[0]),
+                                  np.asarray(L.gated_delta_rule(*ops, impl="xla")[0]))
 
 
 def test_a_length_that_is_no_multiple_of_the_chunk_is_refused_by_name():

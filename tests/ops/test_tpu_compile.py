@@ -490,14 +490,24 @@ def test_the_routed_block_keeps_k_out_of_the_tiles_on_v5e(held_share, k):
     assert not offenders, "\n".join(offenders)
 
 
-def test_the_delta_rule_keeps_a_state_a_chunk_and_runs_on_the_mxu_on_v5e(v5e_2x2):
+def _count_instructions(hlo):
+    return len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", hlo, re.M))
+
+
+_DELTA_RULE_INSTRUCTIONS = {}  # impl -> its optimised module's: the kernel case reads the XLA case's
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_delta_rule_keeps_a_state_a_chunk_and_runs_on_the_mxu_on_v5e(v5e_2x2, impl):
     """The gated delta rule's core at the Qwen3-Next cell's widths (8192
     tokens, 16 key heads serving 32 value heads, 128 x 128 states), forward
     and backward, for a described v5e: no array of tokens x heads x d_k x d_v
     is ever formed (the recurrence token by token would keep one for its
-    backward): the largest is the chunks' starting states, tokens / 64 of
-    them a head; the chunks' products are matmuls and the state is carried by
-    a loop."""
+    backward): the largest is the chunks' starting states. The XLA form: 64
+    tokens a chunk; the chunks' products are matmuls and the state is carried
+    by a loop. The kernel form (what the chip takes): the custom calls are
+    there by their names, no loop and no matmul is left to XLA, and the
+    optimised module holds under a tenth of the XLA form's instructions."""
     from galvatron_tpu.ops import linear_attention as L
 
     tokens, hk, hv, dk, dv = 8192, 16, 32, 128, 128
@@ -507,16 +517,29 @@ def test_the_delta_rule_keeps_a_state_a_chunk_and_runs_on_the_mxu_on_v5e(v5e_2x2
                 sds((1, tokens, hv, dv), jnp.bfloat16), sds((1, tokens, hv), jnp.float32),
                 sds((1, tokens, hv), jnp.float32))
 
-    def loss(*ops):
-        o, state = L.gated_delta_rule(*ops)
-        return jnp.sum(o.astype(jnp.float32)) + jnp.max(jnp.abs(state))
+    def compiled_with(form):
+        def loss(*ops):
+            o, state = L.gated_delta_rule(*ops, impl=form)
+            return jnp.sum(o.astype(jnp.float32)) + jnp.max(jnp.abs(state))
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*operands).compile()
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*operands).compile()
+
+    compiled = compiled_with(impl)
     hlo = compiled.as_text()
+    instructions = _DELTA_RULE_INSTRUCTIONS[impl] = _count_instructions(hlo)
     sizes = [int(np.prod([int(d) for d in dims.split(",")]))
              for dims in re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", hlo)]
-    assert max(sizes) == tokens // L.CHUNK * hv * dk * dv  # the kept chunk-start states
-    assert max(sizes) * L.CHUNK == tokens * hv * dk * dv
-    assert " while(" in hlo and len(re.findall(r" (?:dot|convolution)\(", hlo)) >= 20
+    chunk = L.CHUNK if impl == "xla" else L.TILE
+    assert max(sizes) == tokens // chunk * hv * dk * dv  # the kept chunk-start states
+    assert max(sizes) * chunk == tokens * hv * dk * dv
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes < 1.0 * 2**30  # all heads at once: 2.3 GiB
+    dots = len(re.findall(r" (?:dot|convolution)\(", hlo))
+    if impl == "xla":
+        assert " while(" in hlo and dots >= 20 and "tpu_custom_call" not in hlo
+        return
+    assert hlo.count("tpu_custom_call") == 2 and " while(" not in hlo and dots == 0
+    for name in ("gdn_fwd", "gdn_bwd"):  # what a trace's op table will show
+        assert len(re.findall(r'op_name="[^"]*%s' % name, hlo)) >= 1, name
+    assert instructions * 10 < (_DELTA_RULE_INSTRUCTIONS.get("xla")
+                                or _count_instructions(compiled_with("xla").as_text()))
