@@ -17,7 +17,6 @@ models/t5.py).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 from dataclasses import dataclass
@@ -671,20 +670,23 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
         pin = lambda pos: S.constrain(pos, mesh, S.act_spec(axes, ndim=2))  # noqa: E731
     else:
         pin = lambda pos: pos  # noqa: E731
+    # one scope for everything of the mixer but the attention call: a block
+    # before it and a block after it
+    scope = tracing.ATTN_LATENT if cfg.latent_attention else tracing.ATTN_PROJ
     gate = None
-    if cfg.latent_attention:
-        with jax.named_scope(tracing.ATTN_LATENT):
+    with jax.named_scope(scope):
+        if cfg.latent_attention:
             q, k, v = latent_qkv_projection(p, y, pin(positions), cfg, dtype)
-    else:
-        q, k, v = qkv_projection(p, y, cfg, dtype)
-        if cfg.attn_output_gate:
-            q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
-        if cfg.qk_norm:
-            q, k = qk_normed(p, q, k, cfg)
-        if cfg.position_type == "rope":
-            positions = pin(positions)
-            q = apply_rotary(q, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
-            k = apply_rotary(k, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
+        else:
+            q, k, v = qkv_projection(p, y, cfg, dtype)
+            if cfg.attn_output_gate:
+                q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
+            if cfg.qk_norm:
+                q, k = qk_normed(p, q, k, cfg)
+            if cfg.position_type == "rope":
+                positions = pin(positions)
+                q = apply_rotary(q, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
+                k = apply_rotary(k, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
     if mesh is not None and axes is not None and len(axes.tp) + len(axes.cp) > 0:
         # (B, S/x, nh, hd) -> (B, S/cp, nh/tp, hd): XLA inserts the all-to-all
         # (ulysses) or all-gather+split (megatron-sp) when seq was tp-sharded.
@@ -710,12 +712,10 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
         attn = core_attention(q, k, v, causal=cfg.causal, bias=attn_bias,
                               impl=cfg.attn_impl, bias_type="key_padding",
                               sharding=attn_sharding)
-    if gate is not None:
-        attn = attn * jax.nn.sigmoid(gate)
-    attn = attn.reshape(attn.shape[0], attn.shape[1], cfg.num_heads * cfg.head_dim)
-    # the output projection counts as latent attention's too: the scope is
-    # everything of it but the attention call
-    with jax.named_scope(tracing.ATTN_LATENT) if cfg.latent_attention else contextlib.nullcontext():
+    with jax.named_scope(scope):
+        if gate is not None:
+            attn = attn * jax.nn.sigmoid(gate)
+        attn = attn.reshape(attn.shape[0], attn.shape[1], cfg.num_heads * cfg.head_dim)
         o = _dense(attn, p["wo"], dtype)
     return o, kv_out, None
 
@@ -848,7 +848,10 @@ def layer_forward(
                     shared = shared * jax.nn.sigmoid(_dense(y, p["shared"]["gate"], dtype))
                 out = out + shared
     else:
-        out, aux = dense_mlp(p, y, cfg, dtype), None
+        # named here and not inside dense_mlp, which the shared expert calls
+        # under its own scope: an op carries one scope nested in its run's
+        with jax.named_scope(tracing.MLP):
+            out, aux = dense_mlp(p, y, cfg, dtype), None
     if mesh is not None and axes is not None:
         out = S.constrain(out, mesh, S.act_spec(axes))
     x = residual + out
@@ -900,12 +903,13 @@ def decode_layer_forward(
 
     residual = x
     y = _norm(x, p["ln1"], cfg) if cfg.pre_norm else x
-    q, k, v = qkv_projection(p, y, cfg, dtype)
-    if cfg.qk_norm:
-        q, k = qk_normed(p, q, k, cfg)
-    if cfg.position_type == "rope":
-        q = apply_rotary(q, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
-        k = apply_rotary(k, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
+    with jax.named_scope(tracing.ATTN_PROJ):
+        q, k, v = qkv_projection(p, y, cfg, dtype)
+        if cfg.qk_norm:
+            q, k = qk_normed(p, q, k, cfg)
+        if cfg.position_type == "rope":
+            q = apply_rotary(q, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
+            k = apply_rotary(k, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
     k_cache = _append_token_kv(k_cache, k.astype(k_cache.dtype), write_index)
     v_cache = _append_token_kv(v_cache, v.astype(v_cache.dtype), write_index)
     if mesh is not None and axes is not None and len(axes.tp) > 0:
@@ -922,8 +926,9 @@ def decode_layer_forward(
         sharding=(KernelSharding.for_layer(mesh, axes)
                   if mesh is not None and axes is not None else None),
     )
-    attn = attn.reshape(attn.shape[0], attn.shape[1], cfg.num_heads * cfg.head_dim)
-    o = _dense(attn, p["wo"], dtype)
+    with jax.named_scope(tracing.ATTN_PROJ):
+        attn = attn.reshape(attn.shape[0], attn.shape[1], cfg.num_heads * cfg.head_dim)
+        o = _dense(attn, p["wo"], dtype)
     if mesh is not None and axes is not None:
         o = S.constrain(o, mesh, P(S._ax(axes.batch_axes), None, None))
     x = residual + o
@@ -932,7 +937,8 @@ def decode_layer_forward(
 
     residual = x
     y = _norm(x, p["ln2"], cfg) if cfg.pre_norm else x
-    out = dense_mlp(p, y, cfg, dtype)
+    with jax.named_scope(tracing.MLP):
+        out = dense_mlp(p, y, cfg, dtype)
     if mesh is not None and axes is not None:
         out = S.constrain(out, mesh, P(S._ax(axes.batch_axes), None, None))
     x = residual + out
@@ -1633,7 +1639,7 @@ def _linear_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
 # MLP half; softmax attention, every model's but one, goes unnamed
 MIXERS = {
     "attention": TokenMixer(_init_attention, attention_mixer, _attention_specs,
-                            "attention_fwd_flops_a_token", (tracing.ATTN_LATENT,)),
+                            "attention_fwd_flops_a_token", (tracing.ATTN_PROJ, tracing.ATTN_LATENT)),
     "linear": TokenMixer(_init_linear, linear_mixer, _linear_specs,
                          "linear_fwd_flops_a_token", (tracing.ATTN_LINEAR, tracing.ATTN_DELTA)),
 }

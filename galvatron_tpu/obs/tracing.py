@@ -58,6 +58,17 @@ MOE_SHARED = "gt.moe.shared"  # the shared expert(s): a dense SwiGLU beside the 
 # the low-rank projections, their norms, rope and the output projection,
 # everything of the attention half but the attention call itself
 ATTN_LATENT = "gt.attn.latent"
+# the same for every other softmax layer (models/base.attention_mixer without
+# latent attention): the q, k, v projection, the split-off gate, the heads'
+# norms, rope, the gate's product and the output projection, so that a softmax
+# mixer is this scope plus the attention call
+ATTN_PROJ = "gt.attn.proj"
+# the dense MLP half, around its call in models/base.layer_forward and NOT
+# inside dense_mlp, which the shared expert calls under MOE_SHARED: an op
+# carries ONE scope nested in its layer run's, so the parts add up. A run's
+# norms, residual adds, layout constraints and what the scan does with the
+# stacked parameters carry none: they are the run's self time
+MLP = "gt.mlp"
 # a gated-DeltaNet linear-attention mixer (models/base.linear_mixer), inside
 # gt.layers.r<k>, in two disjoint scopes that add up to the mixer: the core
 # (ops/linear_attention.gated_delta_rule: the chunks' solves, the carried

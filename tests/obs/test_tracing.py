@@ -14,8 +14,13 @@ from jax.sharding import NamedSharding
 from galvatron_tpu import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.cli.arguments import initialize_galvatron
 from galvatron_tpu.cli.train import train
+from galvatron_tpu.config.strategy import layer_runs, model_layer_kinds
+from galvatron_tpu.models import base as M
+from galvatron_tpu.models.glm4_moe_lite import glm4_moe_lite_config
 from galvatron_tpu.models.gpt import gpt_config
 from galvatron_tpu.models.llama import llama_config
+from galvatron_tpu.models.olmoe import olmoe_config
+from galvatron_tpu.models.qwen3_next import qwen3_next_config
 from galvatron_tpu.obs import telemetry, tracing
 from galvatron_tpu.runtime import construct_hybrid_parallel_model, get_optimizer_and_scheduler
 from galvatron_tpu.runtime.optimizer import OptimizerArgs
@@ -205,20 +210,54 @@ FAMILIES = {
     "llama": lambda: llama_config("llama-0.3b", num_layers=3, hidden_size=64, num_heads=4,
                                   ffn_hidden=128, vocab_size=256, max_seq_len=32,
                                   compute_dtype=jnp.float32),
+    "glm": lambda: glm4_moe_lite_config(
+        "glm-4.7-flash", hidden_size=64, num_heads=4, num_kv_heads=4, ffn_hidden=32,
+        dense_ffn_hidden=96, num_layers=3, vocab_size=256, max_seq_len=32, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16, num_experts=8,
+        experts_per_token=2, compute_dtype=jnp.float32),
+    "qwen3next": lambda: qwen3_next_config(
+        "qwen3-next-80b-a3b", hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16,
+        ffn_hidden=32, num_layers=4, vocab_size=256, max_seq_len=64, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=8, num_experts=16,
+        experts_per_token=4, compute_dtype=jnp.float32, attn_impl="xla"),
+    "olmoe": lambda: olmoe_config(
+        "olmoe-1b-7b", num_layers=3, hidden_size=64, num_heads=4, num_kv_heads=4, ffn_hidden=32,
+        vocab_size=256, max_seq_len=32, num_experts=8, experts_per_token=2,
+        compute_dtype=jnp.float32),
 }
+ROUTED = (tracing.MOE_ROUTER, tracing.MOE_DISPATCH, tracing.MOE_EXPERTS, tracing.MOE_COMBINE)
+# the scopes nested in a family's layer runs: an op carries at most one of them
+NESTED = {
+    "gpt": {tracing.ATTN_PROJ, tracing.MLP},
+    "llama": {tracing.ATTN_PROJ, tracing.MLP},
+    # a dense layer, then routed ones beside a shared expert, latent attention in all
+    "glm": {tracing.ATTN_LATENT, tracing.MLP, tracing.MOE_SHARED, *ROUTED},
+    # three linear layers to a gated attention layer, every MLP half routed beside a shared expert
+    "qwen3next": {tracing.ATTN_LINEAR, tracing.ATTN_DELTA, tracing.ATTN_PROJ, tracing.MOE_SHARED,
+                  *ROUTED},
+    "olmoe": {tracing.ATTN_PROJ, *ROUTED},
+}
+NESTED_NAME = re.compile(r"gt\.(?:attn\.[a-z]+|mlp|moe\.[a-z]+)")
+CASES = [(scan, family, guard, chunks) for chunks in (1, 2) for guard in (False, True)
+         for family in ("gpt", "llama") for scan in (True, False)]
+CASES += [(True, "glm", False, 1), (False, "glm", False, 1), (True, "qwen3next", False, 1),
+          (True, "olmoe", True, 2)]
 
 
-@pytest.mark.parametrize("chunks", [1, 2])
-@pytest.mark.parametrize("guard", [False, True], ids=["plain", "guard"])
-@pytest.mark.parametrize("family", sorted(FAMILIES))
-@pytest.mark.parametrize("scan", [True, False], ids=["scan", "no_scan_layers"])
+@pytest.mark.parametrize("scan,family,guard,chunks", CASES, ids=[
+    "%s-%s-%s-%d" % ("scan" if scan else "no_scan_layers", family, "guard" if guard else "plain", chunks)
+    for scan, family, guard, chunks in CASES])
 def test_every_scope_is_in_the_compiled_steps_op_names(devices8, scan, family, guard, chunks):
-    """Two recomputed layers and one that is not: two layer runs, r0 scanned
-    (or unrolled under --no_scan_layers) and r1 always unrolled."""
+    """All layers but one recomputed: in the dense families two layer
+    runs, r0 scanned (or unrolled under --no_scan_layers) and r1 always
+    unrolled; where the stack has several kinds of layer the runs split on
+    the kind too."""
     cfg = FAMILIES[family]()
-    hp = HybridParallelConfig(
-        world_size=1, pp=1, layers=[LayerStrategy(checkpoint=1)] * 2 + [LayerStrategy()],
-        global_bsz=4, chunks=chunks, scan_layers=scan)
+    layers = [LayerStrategy(checkpoint=1)] * (cfg.num_layers - 1) + [LayerStrategy()]
+    if family == "qwen3next":  # its one attention layer is the last: recompute that
+        layers.reverse()
+    hp = HybridParallelConfig(world_size=1, pp=1, layers=layers, global_bsz=4, chunks=chunks,
+                              scan_layers=scan)
     model = construct_hybrid_parallel_model(cfg, hp)
     tx, _ = get_optimizer_and_scheduler(OptimizerArgs(lr=1e-3, warmup_steps=2, total_steps=20))
 
@@ -227,7 +266,7 @@ def test_every_scope_is_in_the_compiled_steps_op_names(devices8, scan, family, g
             lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings)
 
     abstract = model.abstract_params()
-    shape = jax.ShapeDtypeStruct((4, 32), jnp.int32)
+    shape = jax.ShapeDtypeStruct((4, cfg.max_seq_len), jnp.int32)
     batch = {k: jax.ShapeDtypeStruct(shape.shape, shape.dtype, sharding=NamedSharding(
         model.mesh, model._batch_spec_for(shape))) for k in ("tokens", "positions", "labels")}
     step_args = [sds(abstract, model.shardings()),
@@ -239,19 +278,44 @@ def test_every_scope_is_in_the_compiled_steps_op_names(devices8, scan, family, g
     def under(*parts):
         return [n for n in names if all(p in n for p in parts)]
 
-    r0, r1 = tracing.layers_scope(0), tracing.layers_scope(1)
-    for scope in (tracing.EMBED, r0, r1, tracing.HEAD_LOSS):
+    runs = layer_runs(hp, model_layer_kinds(cfg))
+    assert len(runs) == {"gpt": 2, "llama": 2, "glm": 3, "qwen3next": 3, "olmoe": 2}[family]
+    scopes = [tracing.layers_scope(k) for k in range(len(runs))]
+    for scope in (tracing.EMBED, *scopes, tracing.HEAD_LOSS):
         assert under("jvp(%s)" % scope), scope  # its forward
         assert under("transpose(jvp(%s))" % scope), scope  # its backward
     assert under(tracing.OPTIMIZER)
     assert bool(under(tracing.GUARD)) == guard
     assert under(tracing.GUARD, "select_n") or not guard
     assert bool(under(tracing.GRAD_ACCUM)) == (chunks > 1)
-    # recomputation is named under the run it recomputes, and only there
-    assert under("transpose(jvp(%s))" % r0, "rematted_computation")
-    assert not under(r1, "rematted_computation")
-    assert bool(under(r0 + ")/while/body")) == scan
+    for run, scope in zip(runs, scopes):
+        # recomputation is named under the run it recomputes, and only there
+        # (the delta rule's XLA form keeps a checkpoint of its own a head)
+        rematted = [n for n in under("transpose(jvp(%s))" % scope, "rematted_computation")
+                    if tracing.ATTN_DELTA not in n]
+        assert bool(rematted) == bool(run.strategy.checkpoint), scope
+        assert bool(under(scope + ")/while/body")) == (scan and run.length > 1), scope
     # the names are defined once, in obs/tracing.py
-    assert {tracing.EMBED, r0, tracing.HEAD_LOSS, tracing.OPTIMIZER, tracing.GUARD,
-            tracing.GRAD_ACCUM} == {"gt.embed", "gt.layers.r0", "gt.head_loss", "gt.optimizer",
-                                    "gt.guard", "gt.grad_accum"}
+    assert {tracing.EMBED, scopes[0], tracing.HEAD_LOSS, tracing.OPTIMIZER, tracing.GUARD,
+            tracing.GRAD_ACCUM, tracing.ATTN_PROJ, tracing.MLP} == {
+                "gt.embed", "gt.layers.r0", "gt.head_loss", "gt.optimizer", "gt.guard",
+                "gt.grad_accum", "gt.attn.proj", "gt.mlp"}
+    # inside the runs: each part of the layer body under its name, forward,
+    # recomputed and backward, and no op under two of them
+    in_layers = [n for n in names if "gt.layers.r" in n]
+    assert {m for n in in_layers for m in NESTED_NAME.findall(n)} == NESTED[family]
+    assert all(len(set(NESTED_NAME.findall(n))) <= 1 for n in names)
+    for nested in NESTED[family]:
+        assert under("jvp(gt.layers.r", nested) and under("transpose(jvp(gt.layers.r", nested), nested
+        # the combine has a written backward that reads no recomputed forward
+        assert bool(under("rematted_computation", nested)) == (nested != tracing.MOE_COMBINE), nested
+    # a mixer's scopes are the ones its table row states
+    mixers = {cfg.layer_config(kind).mixer for kind in cfg.layer_kinds()}
+    stated = {s for m in mixers for s in M.MIXERS[m].scopes}
+    assert {s for s in NESTED[family] if s.startswith("gt.attn.")} <= stated
+    if family == "glm":
+        # the dense layer is run 0; a routed layer's shared expert calls
+        # dense_mlp under gt.moe.shared and under no gt.mlp
+        assert under(scopes[0], tracing.MLP) and not under(scopes[0], "gt.moe.")
+        assert under(scopes[1], tracing.MOE_SHARED, "dot_general")
+        assert not under(scopes[1], tracing.MLP) and not under(scopes[2], tracing.MLP)
