@@ -525,6 +525,9 @@ def _train(args) -> dict:
                                 compile_ms=(t2 - t1) * 1e3,
                                 cache_hit=cache_hit)
             prof.compiled_memory_mb = compiled_step_memory_mb(compiled) or None
+            # (asked only of a model that traced a linear layer: T5's config has no kinds)
+            linear_layers = (sum(kind.startswith("linear") for kind in cfg.layer_kinds())
+                             if delta_rule_took else 0)
             telemetry.emit(
                 "compile",
                 trace_ms=(t1 - t0) * 1e3,
@@ -535,9 +538,12 @@ def _train(args) -> dict:
                 # the linear layers whose delta rule the step runs as Pallas
                 # kernels (ops/linear_attention.py): all of them or none, the
                 # layers being alike; absent where the model has none
-                linear_kernel_layers=(
-                    sum(kind.startswith("linear") for kind in cfg.layer_kinds())
-                    * (not delta_rule_took["xla"]) if delta_rule_took else None),
+                linear_kernel_layers=linear_layers * (not delta_rule_took["xla"]) if delta_rule_took else None,
+                # and those whose convolution, norms and gated norm around it
+                # run as Pallas passes (`linear_attention.mixer_form`)
+                linear_pass_kernel_layers=(
+                    linear_layers * (not (delta_rule_took["conv_norm_xla"] or delta_rule_took["gated_norm_xla"]))
+                    if delta_rule_took else None),
             )
             _aot["fn"] = compiled
         return _aot["fn"](*step_args)

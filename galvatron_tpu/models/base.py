@@ -36,7 +36,8 @@ from galvatron_tpu.config.strategy import (
 )
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding, core_attention
-from galvatron_tpu.ops.linear_attention import causal_conv, gated_delta_rule
+from galvatron_tpu.ops.linear_attention import (Heads, causal_conv, gated_delta_rule, kernel_mixer,
+                                                mixer_form)
 from galvatron_tpu.ops.moe import moe_ffn, swiglu
 from galvatron_tpu.ops.norms import layer_norm, rms_norm
 from galvatron_tpu.ops.rope import apply_rotary
@@ -751,20 +752,28 @@ def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
     with jax.named_scope(tracing.ATTN_LINEAR):
         qkvz = _dense(y, p["wqkvz"], dtype)
         ba = _dense(y, p["wba"], dtype).astype(jnp.float32)
-        qkv = jax.nn.silu(causal_conv(qkvz[..., :2 * key_dim + value_dim], p["conv"]))
-        z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, s, nv, dv)
         beta = jax.nn.sigmoid(ba[..., :nv])
         g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., nv:] + p["dt_bias"].astype(jnp.float32))
-        q = (unit(qkv[..., :key_dim].reshape(b, s, nk, dk)) * dk ** -0.5).astype(dtype)
-        k = unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, nk, dk)).astype(dtype)
-        v = qkv[..., 2 * key_dim:].reshape(b, s, nv, dv)
-    with jax.named_scope(tracing.ATTN_DELTA):
-        o, state = gated_delta_rule(q, k, v, g, beta, sharding=attn_sharding)
+    heads = Heads(nk, dk, nv, dv)
+    if mixer_form(qkvz, p["conv"], heads, sharding=attn_sharding) == "pallas":
+        # the same arithmetic as lane-aligned passes around the core's kernels
+        o, state = kernel_mixer(qkvz, p["conv"], p["norm"]["scale"], g, beta, heads,
+                                eps=cfg.layernorm_eps, sharding=attn_sharding)
+    else:
+        with jax.named_scope(tracing.ATTN_LINEAR):
+            qkv = jax.nn.silu(causal_conv(qkvz[..., :2 * key_dim + value_dim], p["conv"]))
+            z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, s, nv, dv)
+            q = (unit(qkv[..., :key_dim].reshape(b, s, nk, dk)) * dk ** -0.5).astype(dtype)
+            k = unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, nk, dk)).astype(dtype)
+            v = qkv[..., 2 * key_dim:].reshape(b, s, nv, dv)
+        with jax.named_scope(tracing.ATTN_DELTA):
+            o, state = gated_delta_rule(q, k, v, g, beta, sharding=attn_sharding)
+        with jax.named_scope(tracing.ATTN_LINEAR):
+            o = rms_norm(o.astype(jnp.float32), p["norm"]["scale"], cfg.layernorm_eps)
+            o = (o * jax.nn.silu(z.astype(jnp.float32))).astype(dtype).reshape(b, s, value_dim)
     with jax.named_scope(tracing.ATTN_LINEAR):
-        o = rms_norm(o.astype(jnp.float32), p["norm"]["scale"], cfg.layernorm_eps)
-        o = (o * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
-        out = _dense(o.reshape(b, s, value_dim), p["wout"], dtype)
+        out = _dense(o, p["wout"], dtype)
         stats = {"decay_mean": jnp.mean(jnp.exp(g)), "state_abs_max": jnp.max(jnp.abs(state))}
     return out, None, stats
 

@@ -384,6 +384,9 @@ def render(analysis: Dict[str, Any]) -> str:
         if "linear_kernel_layers" in comp:
             lines.append("linear layers whose delta rule runs as Pallas kernels: %d"
                          % comp["linear_kernel_layers"])
+        if "linear_pass_kernel_layers" in comp:
+            lines.append("linear layers whose convolution and norms run as Pallas passes: %d"
+                         % comp["linear_pass_kernel_layers"])
     an = analysis["anomalies"]
     lines.append(
         "resilience: %d anomalies skipped, %d rollbacks, %d retries"

@@ -81,6 +81,17 @@ matmul afterwards) gave the same gradients to the bit and ran 8 % SLOWER on
 the chip at 8 heads at a time (PERF.md, PR 35), so it is not kept. It is the
 oracle of the kernels' tests, and what every test of a small head runs.
 
+**Around the core** (the convolution, SiLU, the L2 norms before it, the gated
+RMSNorm after it: models/base.linear_mixer states them in XLA, which is what
+the CPU runs): where the operands lie on TPUs they run as four lane-aligned
+Pallas passes beside the core's two kernels, all six one `jax.custom_vjp`
+(`mixer_form` decides as `gated_delta_rule` does, `kernel_mixer` is the rule;
+below the core's kernels). On the chip (PERF.md, PR 38) a layer's passes take
+0.55 ms before the core and 0.32 after it forward, 0.95 and 0.52 backward, 3.2
+ms a layer and step where XLA's elementwise passes over (tokens, heads, 128)
+views took about 8, and the two projections' matmuls, their operands and
+results plain arrays now, run at 92 % of the MXU where they ran at 55 to 65.
+
 Sequences are whole rows of the batch: neither the convolution nor the state
 is cut at a document boundary inside a packed row.
 """
@@ -98,6 +109,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding
 
 CHUNK = 64
@@ -494,22 +506,23 @@ def _scalars(g, beta):
     return jnp.pad(rows, ((0, 0),) * 3 + ((0, _ROWS - 3), (0, 0)))
 
 
-def _block(v):
+def _block(s, dv, itemsize):
     """Tiles a grid step walks: `_BLOCK` at the cell's widths (bf16, d_v 128),
     fewer where the operands are wider (the scoped VMEM holds two of every
     block)."""
-    return max(1, min(_BLOCK * 2 * TILE // (v.dtype.itemsize * v.shape[3]), v.shape[1] // TILE))
+    return max(1, min(_BLOCK * 2 * TILE // (itemsize * dv), s // TILE))
 
 
-def _call(kernel, name, q, v, reverse, in_kinds, out_kinds, scratch_kinds, operands):
+def _call(kernel, name, dims, dtypes, reverse, in_kinds, out_kinds, scratch_kinds, operands):
     """A walk over (batch, value head, blocks of tiles), the last axis in
-    order, from the sequence's last block with `reverse`. Kinds of blocks: "key" / "value"
+    order, from the sequence's last block with `reverse`. `dims` = (B, S, Hk,
+    d_k, Hv, d_v), `dtypes` = (q's, v's). Kinds of blocks: "key" / "value"
     (a block's tokens of one head, d_k / d_v wide; a key head serves Hv / Hk
     value heads: the index map sends value head h to key head h // (Hv / Hk),
     and no q or k is repeated), "keys" (d_k wide a VALUE head), "rows",
     "starts", "inverse" (a block's tiles), "state" (a head's)."""
-    (b, s, hv, dv), (hk, dk) = v.shape, q.shape[2:]
-    block, tiles = _block(v), s // TILE
+    b, s, hk, dk, hv, dv = dims
+    block, tiles = _block(s, dv, dtypes[1].itemsize), s // TILE
     steps = pl.cdiv(tiles, block)
 
     def at(step):  # the block a step walks
@@ -524,7 +537,7 @@ def _call(kernel, name, q, v, reverse, in_kinds, out_kinds, scratch_kinds, opera
     specs = {"key": tokens(dk, hv // hk), "keys": tokens(dk, 1), "value": tokens(dv, 1),
              "rows": a_tile(_ROWS, TILE), "starts": a_tile(dk, dv), "inverse": a_tile(TILE, TILE),
              "state": pl.BlockSpec((None, None, dk, dv), lambda i, h, c: (i, h, 0, 0))}
-    shapes = {"keys": ((b, s, hv * dk), q.dtype), "value": ((b, s, hv * dv), v.dtype),
+    shapes = {"keys": ((b, s, hv * dk), dtypes[0]), "value": ((b, s, hv * dv), dtypes[1]),
               "rows": ((b, hv, tiles, _ROWS, TILE), _F32), "starts": ((b, hv, tiles, dk, dv), _F32),
               "inverse": ((b, hv, tiles, TILE, TILE), _F32), "state": ((b, hv, dk, dv), _F32),
               # scratch alone: a block's tiles by d_k, by d_v, by both side by side
@@ -548,16 +561,49 @@ def _flat(x):  # (B, S, H, d) -> (B, S, H d): a head's block is then (tokens, d)
     return x.reshape(x.shape[:2] + (-1,))
 
 
-def _forward(q, k, v, g, beta, keep):
-    """The forward kernel: -> o (B, S, Hv, d_v), the final states, and with
-    `keep` what the backward reads again (the scalars' rows, the states the
-    tiles started from, every tile's `T`)."""
+def _dims(q, v):  # q (B, S, Hk, d_k), v (B, S, Hv, d_v) -> (B, S, Hk, d_k, Hv, d_v)
+    return v.shape[:2] + q.shape[2:] + v.shape[2:]
+
+
+def _flat_forward(dims, q, k, v, g, beta, keep):
+    """The forward kernel on q, k (B, S, Hk d_k) and v (B, S, Hv d_v), the
+    layout a head's block is cut from: -> o (B, S, Hv d_v), the final states,
+    and with `keep` what the backward reads again (the scalars' rows, the
+    states the tiles started from, every tile's `T`)."""
     rows = _scalars(g, beta)
-    out = _call(functools.partial(_fwd_kernel, keep=keep), "gdn_fwd", q, v, False,
+    out = _call(functools.partial(_fwd_kernel, keep=keep), "gdn_fwd", dims, (q.dtype, v.dtype), False,
                 ["key", "key", "value", "rows"], ["value"] + ["starts", "inverse"] * keep + ["state"],
-                ["state", "by_kv", "by_k", "by_v"] + ["starts"] * (not keep),
-                (_flat(q), _flat(k), _flat(v), rows))
-    return out[0].reshape(v.shape), out[-1], ((rows,) + tuple(out[1:3]) if keep else None)
+                ["state", "by_kv", "by_k", "by_v"] + ["starts"] * (not keep), (q, k, v, rows))
+    return out[0], out[-1], ((rows,) + tuple(out[1:3]) if keep else None)
+
+
+def _flat_backward(dims, q, k, v, kept, do, dlast):
+    """The backward kernel on the flat operands and what `_flat_forward`
+    kept: -> dq, dk (B, S, Hv d_k: a VALUE head's share each, the heads a key
+    head serves side by side), dv (B, S, Hv d_v), dg, dbeta (B, S, Hv)
+    float32."""
+    b, s, _, _, hv, _ = dims
+    rows, starts, inverse = kept
+    dq, dk, dv, drows = _call(
+        _bwd_kernel, "gdn_bwd", dims, (q.dtype, v.dtype), True,
+        ["key", "key", "value", "rows", "starts", "inverse", "value", "state"],
+        ["keys", "keys", "value", "rows"],
+        ["state", "by_v", "by_k", "by_k", "starts", "by_v", "starts"],
+        (q, k, v, rows, starts, inverse, do, dlast.astype(_F32)))
+
+    def tokens_first(x):  # (B, Hv, tiles, 128) -> (B, S, Hv)
+        return x.reshape(b, hv, s).transpose(0, 2, 1)
+
+    # G is g's running sum inside a tile: g_t reaches every G_i, i >= t
+    dg = jnp.flip(jnp.cumsum(jnp.flip(drows[..., 0, :], -1), axis=-1), -1)
+    return dq, dk, dv, tokens_first(dg), tokens_first(drows[..., 1, :])
+
+
+def _forward(q, k, v, g, beta, keep):
+    """`_flat_forward` on q, k (B, S, Hk, d_k), v (B, S, Hv, d_v): o comes
+    back as v came."""
+    o, last, kept = _flat_forward(_dims(q, v), _flat(q), _flat(k), _flat(v), g, beta, keep)
+    return o.reshape(v.shape), last, kept
 
 
 @jax.custom_vjp
@@ -571,15 +617,11 @@ def _kernel_rule_fwd(q, k, v, g, beta):
 
 
 def _kernel_rule_bwd(residuals, cotangents):
-    q, k, v, g, beta, (rows, starts, inverse) = residuals
+    q, k, v, g, beta, kept = residuals
     do, dlast = cotangents
     (b, s, hv, dv), (hk, dk) = v.shape, q.shape[2:]
-    dq, dk_, dv_, drows = _call(
-        _bwd_kernel, "gdn_bwd", q, v, True,
-        ["key", "key", "value", "rows", "starts", "inverse", "value", "state"],
-        ["keys", "keys", "value", "rows"],
-        ["state", "by_v", "by_k", "by_k", "starts", "by_v", "starts"],
-        (_flat(q), _flat(k), _flat(v), rows, starts, inverse, _flat(do), dlast.astype(_F32)))
+    dq, dk_, dv_, dg, dbeta = _flat_backward(_dims(q, v), _flat(q), _flat(k), _flat(v), kept,
+                                             _flat(do), dlast)
 
     def to_key_heads(x):
         """A key head's gradient is the sum over the value heads it serves,
@@ -589,13 +631,8 @@ def _kernel_rule_bwd(residuals, cotangents):
         served = [x[..., at:at + dk].astype(_F32) for at in range(0, x.shape[-1], dk)]
         return functools.reduce(jnp.add, served).astype(x.dtype)
 
-    def tokens_first(x):  # (B, Hv, tiles, 128) -> (B, S, Hv)
-        return x.reshape(b, hv, s).transpose(0, 2, 1)
-
-    # G is g's running sum inside a tile: g_t reaches every G_i, i >= t
-    dg = jnp.flip(jnp.cumsum(jnp.flip(drows[..., 0, :], -1), axis=-1), -1)
     return (to_key_heads(dq), to_key_heads(dk_), dv_.reshape(v.shape),
-            tokens_first(dg).astype(g.dtype), tokens_first(drows[..., 1, :]).astype(beta.dtype))
+            dg.astype(g.dtype), dbeta.astype(beta.dtype))
 
 
 _kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
@@ -628,6 +665,454 @@ def _sharded_kernel_form(q, k, v, g, beta, sharding: KernelSharding):
     )(q, k, v, g, beta)
 
 
+# --- around the core, the kernel form ---------------------------------------
+#
+# What a gated-DeltaNet mixer does between its two projections and the core
+# (models/base.linear_mixer is the definition: the XLA form), as passes over
+# (tokens, channels) arrays in which a head is a block of whole 128-lane
+# columns: no (tokens, heads, d) view exists, so nothing is relaid, no norm's
+# scale is broadcast to full size and no slice of the projection's output is
+# written out (a block is read where it lies, through its index map).
+#
+#   before the core  `conv_norm_fwd`: a block of [q | k | v]'s channels of the
+#     projection's (tokens, 2 Hk d_k + 2 Hv d_v) output -> the convolution in
+#     float32 (the tokens before a tile from the block of `_HALO` rows that
+#     ends where the tile starts; zeros before the sequence), rounded where
+#     `causal_conv` rounds, SiLU, rounded, and for q and k the L2 norm over a
+#     head's lanes (q scaled by d_k ** -0.5). One call each for q, k and v.
+#   after the core   `gated_norm_fwd`: RMSNorm of o over a head's lanes times
+#     the norm's scale times SiLU of z's block, float32, rounded once.
+#
+# The backwards (`gated_norm_bwd`, `conv_norm_bwd`) make the forward's cheap
+# arithmetic again from the inputs (the projection's output and the taps; o, z
+# and the scale) and fill ONE cotangent of the projection's output, each its
+# own column blocks (`input_output_aliases` hands the array on), so that no
+# sum of padded parts is left for XLA; the taps' and the scale's gradients
+# leave as a tile's partial sums. A key head's dq, dk are summed over the value
+# heads it serves as `conv_norm_bwd` reads them. `_kernel_mixer` ties the
+# passes and the core's two kernels into one `jax.custom_vjp`.
+
+# On the chip (scripts/linear_passes_sweep.py; PERF.md, PR 38) the passes are
+# bound by the vector unit, not by HBM (a v5e has no bf16 arithmetic: about 40
+# float32 operations an element of `conv_norm_fwd`), and larger steps are
+# faster: tokens x lanes x tokens at once 512 x 512 x 32 -> 1024 x 1024 x 64
+# takes `conv_norm_fwd` 0.99 -> 0.80 ms a layer and `conv_norm_bwd` 1.26 ->
+# 0.93 by the sweep's clock (in the cell's trace 0.75 -> 0.55 and 1.27 ->
+# 0.95); staging the tile as float32 or carrying eight rows costs the same.
+_TOKENS = 1024  # tokens a grid step of a pass holds (a half, a quarter, an eighth where the sequence asks)
+_LANES = 1024  # channels a grid step holds: whole heads
+_HALO = 16  # rows of one bf16 tile: the block of tokens next to a tile's edge
+_AT_ONCE = 64  # tokens a loop step works on, a head at a time: what the registers hold
+_TAPS = 8  # the taps a channel, padded to a float32 tile's rows: at most so many
+
+Heads = collections.namedtuple("Heads", "key_heads d_k value_heads d_v")  # `_call`'s dims after (B, S), in its order
+
+
+def _tokens(s):
+    """The passes' tile of tokens for a sequence of s, or None."""
+    return next((t for t in (_TOKENS, _TOKENS // 2, _TOKENS // 4, _TOKENS // 8) if s % t == 0), None)
+
+
+def _lanes(d, *widths):
+    """The widest block of whole heads of d, at most `_LANES`, that divides
+    every one of `widths` (segments and the columns they start at), or None."""
+    for heads in range(max(1, _LANES // d), 0, -1):
+        if all(width % (heads * d) == 0 for width in widths):
+            return heads * d
+    return None
+
+
+def _stage(buf, x_ref, before_ref, first, after_ref=None):
+    """A tile's tokens as float32 in `buf` from row `_HALO` on, the block
+    before it in the rows before (zeros for a sequence's first tile) and, for
+    the backward, the block after it behind."""
+    t = x_ref.shape[0]
+    buf[0:_HALO] = jnp.where(first, 0.0, before_ref[...].astype(_F32))
+    buf[_HALO:_HALO + t] = x_ref[...].astype(_F32)
+    if after_ref is not None:
+        buf[_HALO + t:] = after_ref[...].astype(_F32)
+
+
+def _conv_rows(buf, r0, rows, lanes, w, taps):
+    """`causal_conv`'s float32 sums for `rows` tokens from the tile's token r0
+    on, a head's lanes: -> them, and x_{t - (taps - 1) + j} for each tap j.
+    The tokens are shifted by rotating the rows with the eight before them."""
+    ext = buf[pl.ds(r0 + _HALO - 8, rows + 8), lanes]
+    shifted = [ext[8:] if j == taps - 1 else pltpu.roll(ext, taps - 1 - j, 0)[8:] for j in range(taps)]
+    out = None
+    for j in range(taps):
+        term = shifted[j] * w[j:j + 1]
+        out = term if out is None else out + term
+    return out, shifted
+
+
+def _dsilu(x, sig):  # d (x sigmoid(x)) / dx, sig = sigmoid(x)
+    return sig * (1.0 + x * (1.0 - sig))
+
+
+def _activate(a, dtype, scale):
+    """The convolution's sums -> c (rounded as `causal_conv` rounds), its
+    sigmoid, s = SiLU (rounded), the L2 norm's factor and the result; `scale`
+    None: no norm."""
+    c = a.astype(dtype).astype(_F32)
+    sig = jax.nn.sigmoid(c)
+    s = (c * sig).astype(dtype).astype(_F32)
+    if scale is None:
+        return c, sig, s, None, s
+    r = jax.lax.rsqrt(jnp.sum(s * s, axis=-1, keepdims=True) + 1e-6)
+    return c, sig, s, r, s * r if scale == 1.0 else s * r * scale
+
+
+def _conv_fwd_kernel(x_ref, before_ref, taps_ref, o_ref, buf, *, taps, d, scale):
+    t, c = x_ref.shape
+    _stage(buf, x_ref, before_ref, pl.program_id(1) == 0)
+
+    def rows(n, carry):
+        r0 = pl.multiple_of(n * _AT_ONCE, _AT_ONCE)
+        for h in range(c // d):
+            lanes = slice(h * d, (h + 1) * d)
+            a, _ = _conv_rows(buf, r0, _AT_ONCE, lanes, taps_ref[:, lanes], taps)
+            o_ref[pl.ds(r0, _AT_ONCE), lanes] = _activate(a, o_ref.dtype, scale)[-1].astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, t // _AT_ONCE, rows, None)
+
+
+def _conv_bwd_kernel(x_ref, before_ref, after_ref, taps_ref, d_ref, dafter_ref, into_ref,
+                     dx_ref, dtaps_ref, buf, dbuf, acc, *, taps, d, serves, scale):
+    """A tile's tokens of a block of channels: the forward's arithmetic again
+    on the tile and on the `_HALO` tokens after it (their convolutions read
+    this tile's last tokens), da = the cotangent of the convolution's sums in
+    `dbuf`, the taps' partial sums over the tile's own tokens, then dx_t =
+    sum_j w_j da_{t + taps - 1 - j}. `serves` value heads' shares of a key
+    head's cotangent lie side by side in `d_ref` and are summed as they are
+    read. `into_ref` is the cotangent array itself, which other calls fill
+    elsewhere: never read."""
+    del into_ref
+    t, c = x_ref.shape
+    tile = pl.program_id(1)
+    _stage(buf, x_ref, before_ref, tile == 0, after_ref)
+    acc[...] = jnp.zeros_like(acc)
+
+    def da_rows(r0, rows, h, from_ref, at):
+        lanes = slice(h * d, (h + 1) * d)
+        a, shifted = _conv_rows(buf, r0, rows, lanes, taps_ref[:, lanes], taps)
+        c_, sig, s, r, _ = _activate(a, x_ref.dtype, scale)
+        got = None
+        for m in range(serves):
+            share = from_ref[pl.ds(at, rows), pl.ds((h * serves + m) * d, d)].astype(_F32)
+            got = share if got is None else got + share
+        if scale is not None:
+            unit = s * r
+            if scale != 1.0:
+                got = got * scale
+            got = r * (got - unit * jnp.sum(unit * got, axis=-1, keepdims=True))
+        return got * _dsilu(c_, sig), shifted
+
+    def first_walk(n, carry):
+        r0 = pl.multiple_of(n * _AT_ONCE, _AT_ONCE)
+        for h in range(c // d):
+            lanes = slice(h * d, (h + 1) * d)
+            da, shifted = da_rows(r0, _AT_ONCE, h, d_ref, r0)
+            dbuf[pl.ds(r0, _AT_ONCE), lanes] = da
+            for j in range(taps):
+                acc[j, :, lanes] += jnp.sum((da * shifted[j]).reshape(_AT_ONCE // 8, 8, d), axis=0)
+        return carry
+
+    jax.lax.fori_loop(0, t // _AT_ONCE, first_walk, None)
+    beyond = tile == pl.num_programs(1) - 1  # nothing lies after the sequence's last tile
+    for h in range(c // d):
+        dbuf[t:t + _HALO, h * d:(h + 1) * d] = jnp.where(beyond, 0.0, da_rows(t, _HALO, h, dafter_ref, 0)[0])
+
+    def second_walk(n, carry):
+        r0 = pl.multiple_of(n * _AT_ONCE, _AT_ONCE)
+        for h in range(c // d):
+            lanes = slice(h * d, (h + 1) * d)
+            ext, w = dbuf[pl.ds(r0, _AT_ONCE + 8), lanes], taps_ref[:, lanes]
+            dx = None
+            for j in range(taps):
+                ahead = taps - 1 - j  # da of the token `ahead` later
+                term = (ext if ahead == 0 else pltpu.roll(ext, _AT_ONCE + 8 - ahead, 0))[:_AT_ONCE] * w[j:j + 1]
+                dx = term if dx is None else dx + term
+            dx_ref[pl.ds(r0, _AT_ONCE), lanes] = dx.astype(dx_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, t // _AT_ONCE, second_walk, None)
+    dtaps_ref[...] = jnp.zeros_like(dtaps_ref)
+    for j in range(taps):
+        dtaps_ref[j:j + 1, :] = jnp.sum(acc[j], axis=0, keepdims=True)
+
+
+def _gated(o, z, w, eps):
+    """o, z a head's lanes of some tokens, w (1, d) -> float32: the unit-rms
+    o, the norm's factor, z, its sigmoid."""
+    o32, z32 = o.astype(_F32), z.astype(_F32)
+    r = jax.lax.rsqrt(jnp.mean(o32 * o32, axis=-1, keepdims=True) + eps)
+    return o32 * r, r, z32, jax.nn.sigmoid(z32)
+
+
+def _gate_fwd_kernel(o_ref, z_ref, scale_ref, out_ref, *, d, eps):
+    t, c = o_ref.shape
+    w = scale_ref[...]
+
+    def rows(n, carry):
+        at = pl.ds(pl.multiple_of(n * _AT_ONCE, _AT_ONCE), _AT_ONCE)
+        for h in range(c // d):
+            lanes = slice(h * d, (h + 1) * d)
+            unit, _, z32, sig = _gated(o_ref[at, lanes], z_ref[at, lanes], w, eps)
+            out_ref[at, lanes] = (unit * w * (z32 * sig)).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, t // _AT_ONCE, rows, None)
+
+
+def _gate_bwd_kernel(o_ref, z_ref, scale_ref, d_ref, dz_ref, do_ref, dscale_ref, *, d, eps):
+    """out = unit w silu(z), unit = o r: dz, do and the scale's partial sum
+    over the step's tokens and heads (eight rows of them: the rows' sum is
+    taken outside)."""
+    t, c = o_ref.shape
+    w = scale_ref[...]
+    dscale_ref[...] = jnp.zeros_like(dscale_ref)
+
+    def rows(n, carry):
+        at = pl.ds(pl.multiple_of(n * _AT_ONCE, _AT_ONCE), _AT_ONCE)
+        for h in range(c // d):
+            lanes = slice(h * d, (h + 1) * d)
+            unit, r, z32, sig = _gated(o_ref[at, lanes], z_ref[at, lanes], w, eps)
+            got = d_ref[at, lanes].astype(_F32)
+            dnormed = got * (z32 * sig)
+            dz_ref[at, lanes] = (got * (unit * w) * _dsilu(z32, sig)).astype(dz_ref.dtype)
+            dscale_ref[...] += jnp.sum((dnormed * unit).reshape(_AT_ONCE // 8, 8, d), axis=0)
+            dunit = dnormed * w
+            do_ref[at, lanes] = (r * (dunit - unit * jnp.mean(dunit * unit, axis=-1, keepdims=True))
+                                 ).astype(do_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, t // _AT_ONCE, rows, None)
+
+
+def _pass(kernel, name, grid, in_specs, out_specs, out_shape, scratch=(), aliases=None):
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=list(scratch), input_output_aliases=aliases or {},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",) * len(grid),
+                                             vmem_limit_bytes=_VMEM),
+        name=name)
+
+
+Segment = collections.namedtuple("Segment", "start width d lanes scale serves")
+
+
+def _segments(heads: Heads):
+    """[q | k | v | z]'s columns of the projection's output, each with its
+    first column, its width, a head's width, the block of channels a pass
+    takes of it (None where no block of whole heads fits), what its L2 norm
+    is scaled by (None: no norm) and how many value heads' shares of its
+    cotangent the core's backward hands on side by side."""
+    keys, values = heads.key_heads * heads.d_k, heads.value_heads * heads.d_v
+    key_lanes, value_lanes = _lanes(heads.d_k, keys), _lanes(heads.d_v, 2 * keys, values)
+    shares = heads.value_heads // heads.key_heads
+    return (Segment(0, keys, heads.d_k, key_lanes, heads.d_k ** -0.5, shares),
+            Segment(keys, keys, heads.d_k, key_lanes, 1.0, shares),
+            Segment(2 * keys, values, heads.d_v, value_lanes, None, 1),
+            Segment(2 * keys + values, values, heads.d_v, value_lanes, None, 1))
+
+
+def _taps_rows(taps):  # (channels, K) -> (_TAPS, channels) float32, a tap a row
+    return jnp.pad(taps.astype(_F32).T, ((0, _TAPS - taps.shape[1]), (0, 0)))
+
+
+def _conv_blocks(seg, s, t):
+    """What both convolution passes read of a segment: its tile of the
+    projection's output, the `_HALO` tokens before it (the tile's own first
+    where there are none: read as zeros) and its taps; and the index of the
+    block of `_HALO` tokens after tile n (the sequence's last where there
+    are none)."""
+    first, halos = seg.start // seg.lanes, t // _HALO
+
+    def after(n):
+        return jnp.minimum((n + 1) * halos, s // _HALO - 1)
+
+    return [pl.BlockSpec((None, t, seg.lanes), lambda i, n, j: (i, n, first + j)),
+            pl.BlockSpec((None, _HALO, seg.lanes), lambda i, n, j: (i, jnp.maximum(n * halos - 1, 0), first + j)),
+            pl.BlockSpec((_TAPS, seg.lanes), lambda i, n, j: (0, first + j))], after
+
+
+def _conv_norm(heads, qkvz, taps):
+    """The pass before the core: qkvz (B, S, 2 Hk d_k + 2 Hv d_v), taps
+    (channels of q, k, v; K) -> q, k (B, S, Hk d_k), v (B, S, Hv d_v)."""
+    b, s, _ = qkvz.shape
+    t, rows = _tokens(s), _taps_rows(taps)
+    return [_pass(
+        functools.partial(_conv_fwd_kernel, taps=taps.shape[1], d=seg.d, scale=seg.scale),
+        "conv_norm_fwd", (b, s // t, seg.width // seg.lanes), _conv_blocks(seg, s, t)[0],
+        pl.BlockSpec((None, t, seg.lanes), lambda i, n, j: (i, n, j)),
+        jax.ShapeDtypeStruct((b, s, seg.width), qkvz.dtype),
+        [pltpu.VMEM((t + _HALO, seg.lanes), _F32)])(qkvz, qkvz, rows) for seg in _segments(heads)[:3]]
+
+
+def _conv_norm_bwd(heads, qkvz, taps, dq, dk, dv, into):
+    """`_conv_norm`'s backward: dq, dk (B, S, Hv d_k: a value head's share
+    each), dv (B, S, Hv d_v), `into` the projection's output's cotangent with
+    z's columns filled -> it with q, k, v's filled too, and the taps'
+    gradient (channels, K) float32."""
+    b, s, _ = qkvz.shape
+    t, rows = _tokens(s), _taps_rows(taps)
+    dtaps = []
+    for seg, got in zip(_segments(heads), (dq, dk, dv)):
+        c, first = seg.lanes, seg.start // seg.lanes
+        (tile, before, taps_block), after = _conv_blocks(seg, s, t)
+        into, partial = _pass(
+            functools.partial(_conv_bwd_kernel, taps=taps.shape[1], d=seg.d, serves=seg.serves, scale=seg.scale),
+            "conv_norm_bwd", (b, s // t, seg.width // c),
+            [tile, before,
+             pl.BlockSpec((None, _HALO, c), lambda i, n, j, after=after, first=first: (i, after(n), first + j)),
+             taps_block,
+             pl.BlockSpec((None, t, seg.serves * c), lambda i, n, j: (i, n, j)),
+             pl.BlockSpec((None, _HALO, seg.serves * c), lambda i, n, j, after=after: (i, after(n), j)),
+             pl.BlockSpec(memory_space=pl.ANY)],
+            [tile, pl.BlockSpec((None, None, _TAPS, c), lambda i, n, j: (i, n, 0, j))],
+            [jax.ShapeDtypeStruct(into.shape, into.dtype),
+             jax.ShapeDtypeStruct((b, s // t, _TAPS, seg.width), _F32)],
+            [pltpu.VMEM((t + 2 * _HALO, c), _F32), pltpu.VMEM((t + _HALO, c), _F32),
+             pltpu.VMEM((_TAPS, 8, c), _F32)],
+            aliases={6: 0})(qkvz, qkvz, qkvz, rows, got, got, into)
+        dtaps.append(jnp.sum(partial, axis=(0, 1))[:taps.shape[1]].T)
+    return into, jnp.concatenate(dtaps, axis=0)
+
+
+def _gated_norm(heads, eps, o, qkvz, scale):
+    """The pass after the core: o (B, S, Hv d_v), z = qkvz's last columns,
+    scale (d_v,) -> RMSNorm(o; scale) a head x SiLU(z), (B, S, Hv d_v)."""
+    b, s, width = o.shape
+    z = _segments(heads)[3]
+    t, c, first = _tokens(s), z.lanes, z.start // z.lanes
+    return _pass(
+        functools.partial(_gate_fwd_kernel, d=heads.d_v, eps=eps), "gated_norm_fwd",
+        (b, s // t, width // c),
+        [pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, j)),
+         pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, first + j)),
+         pl.BlockSpec((1, heads.d_v), lambda i, n, j: (0, 0))],
+        pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, j)),
+        jax.ShapeDtypeStruct(o.shape, o.dtype))(o, qkvz, scale.astype(_F32)[None])
+
+
+def _gated_norm_bwd(heads, eps, o, qkvz, scale, dout):
+    """`_gated_norm`'s backward: -> the projection's output's cotangent with
+    z's columns filled AND NO OTHER (`_conv_norm_bwd` fills the rest), do, the
+    scale's gradient float32."""
+    b, s, width = o.shape
+    z = _segments(heads)[3]
+    t, c, first = _tokens(s), z.lanes, z.start // z.lanes
+    here = pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, j))
+    dqkvz, do, partial = _pass(
+        functools.partial(_gate_bwd_kernel, d=heads.d_v, eps=eps), "gated_norm_bwd",
+        (b, s // t, width // c),
+        [here, pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, first + j)),
+         pl.BlockSpec((1, heads.d_v), lambda i, n, j: (0, 0)), here],
+        [pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, first + j)), here,
+         pl.BlockSpec((None, None, None, 8, heads.d_v), lambda i, n, j: (i, n, j, 0, 0))],
+        [jax.ShapeDtypeStruct(qkvz.shape, qkvz.dtype), jax.ShapeDtypeStruct(o.shape, o.dtype),
+         jax.ShapeDtypeStruct((b, s // t, width // c, 8, heads.d_v), _F32)],
+    )(o, qkvz, scale.astype(_F32)[None], dout)
+    return dqkvz, do, jnp.sum(partial, axis=(0, 1, 2, 3))
+
+
+def _mixer(heads, eps, qkvz, taps, scale, g, beta, keep):
+    """Convolution and norms, the core, the gated norm, each under its scope
+    (the call sits under neither: an op carries one of the two)."""
+    dims = qkvz.shape[:2] + tuple(heads)
+    with jax.named_scope(tracing.ATTN_LINEAR):
+        q, k, v = _conv_norm(heads, qkvz, taps)
+    with jax.named_scope(tracing.ATTN_DELTA):
+        o, last, kept = _flat_forward(dims, q, k, v, g, beta, keep)
+    with jax.named_scope(tracing.ATTN_LINEAR):
+        out = _gated_norm(heads, eps, o, qkvz, scale)
+    return (out, last), (qkvz, taps, scale, q, k, v, kept, o)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _kernel_mixer(heads, eps, qkvz, taps, scale, g, beta):
+    return _mixer(heads, eps, qkvz, taps, scale, g, beta, keep=False)[0]
+
+
+def _kernel_mixer_fwd(heads, eps, qkvz, taps, scale, g, beta):
+    return _mixer(heads, eps, qkvz, taps, scale, g, beta, keep=True)
+
+
+def _kernel_mixer_bwd(heads, eps, residuals, cotangents):
+    qkvz, taps, scale, q, k, v, kept, o = residuals
+    dout, dlast = cotangents
+    dims = qkvz.shape[:2] + tuple(heads)
+    with jax.named_scope(tracing.ATTN_LINEAR):
+        dqkvz, do, dscale = _gated_norm_bwd(heads, eps, o, qkvz, scale, dout)
+    with jax.named_scope(tracing.ATTN_DELTA):
+        dq, dk, dv, dg, dbeta = _flat_backward(dims, q, k, v, kept, do, dlast)
+    with jax.named_scope(tracing.ATTN_LINEAR):
+        dqkvz, dtaps = _conv_norm_bwd(heads, qkvz, taps, dq, dk, dv, dqkvz)
+    return dqkvz, dtaps.astype(taps.dtype), dscale.astype(scale.dtype), dg, dbeta
+
+
+_kernel_mixer.defvjp(_kernel_mixer_fwd, _kernel_mixer_bwd)
+
+
+def _on_kernels(sharding, batch, fits):
+    """("pallas" | "xla", the sharding a manual region needs or None): the
+    kernels where the operands lie on TPUs, the shapes fit and the call sits on
+    one device or, with `sharding`, on whole rows of the batch a device with
+    all heads on each."""
+    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
+    if sharding is not None and sharding.mesh.size == 1:
+        sharding = None  # one device: the kernels need no manual region
+    return on_tpu and fits and (sharding is None or sharding.divides(batch, 1)), sharding
+
+
+def mixer_form(qkvz: jax.Array, taps: jax.Array, heads: Heads, *, impl: str = "auto",
+               sharding: Optional[KernelSharding] = None) -> str:
+    """The form the passes around the core take for this projection's output
+    (B, S, 2 Hk d_k + 2 Hv d_v) and these taps (channels, K): "pallas"
+    (`kernel_mixer`: with the core, one rule) or "xla" (the caller's own
+    arithmetic around `gated_delta_rule`). `impl` "auto": the kernels where
+    `gated_delta_rule` would take its own (TPUs, heads multiples of 128 wide,
+    one device or whole rows of the batch a device) and the sequence is a
+    multiple of the passes' smallest tile of tokens, the taps at most `_TAPS`
+    and a block of whole heads divides every segment. Counted in `TOOK`,
+    a pass a key."""
+    if impl == "auto":
+        fits = (heads.d_k % TILE == 0 and heads.d_v % TILE == 0 and _tokens(qkvz.shape[1]) is not None
+                and taps.shape[1] <= _TAPS and heads.value_heads % heads.key_heads == 0
+                and all(seg.lanes is not None for seg in _segments(heads)))
+        impl = "pallas" if _on_kernels(sharding, qkvz.shape[0], fits)[0] else "xla"
+    TOOK["conv_norm_" + impl] += 1
+    TOOK["gated_norm_" + impl] += 1
+    return impl
+
+
+def kernel_mixer(qkvz: jax.Array, taps: jax.Array, scale: jax.Array, g: jax.Array, beta: jax.Array,
+                 heads: Heads, *, eps: float, sharding: Optional[KernelSharding] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """A gated-DeltaNet mixer between its two projections, the kernel form
+    (where `mixer_form` says "pallas"): qkvz (B, S, [q | k | v | z]), taps
+    (channels of q, k, v; K), scale (d_v,) the gated norm's, g, beta (B, S,
+    Hv) float32 -> RMSNorm(o) x SiLU(z) (B, S, Hv d_v) in qkvz's dtype and the
+    final states (B, Hv, d_k, d_v) float32. Its ops carry `gt.attn.linear`
+    or, the core's, `gt.attn.delta`: call it under neither."""
+    TOOK["pallas"] += 1  # the core's form
+    sharding = _on_kernels(sharding, qkvz.shape[0], True)[1]
+    rule = functools.partial(_kernel_mixer, heads, eps)
+    if sharding is None:
+        return rule(qkvz, taps, scale, g, beta)
+    rows = sharding.batch_axes or None
+    ctx = jax.sharding.get_abstract_mesh()
+    use_mesh = sharding.mesh if ctx.empty else ctx
+    return jax.shard_map(
+        rule, mesh=use_mesh,
+        in_specs=(P(rows, None, None), P(None, None), P(None), P(rows, None, None), P(rows, None, None)),
+        out_specs=(P(rows, None, None), P(rows, None, None, None)),
+        axis_names=set(use_mesh.axis_names) - set(use_mesh.manual_axes), check_vma=False,
+    )(qkvz, taps, scale, g, beta)
+
+
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
                      *, chunk: int = CHUNK, impl: str = "auto",
                      sharding: Optional[KernelSharding] = None) -> Tuple[jax.Array, jax.Array]:
@@ -647,13 +1132,10 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, bet
     if s % chunk:
         raise ValueError("gated_delta_rule: a sequence of %d tokens is no multiple of the "
                          "chunk of %d" % (s, chunk))
-    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
-    if sharding is not None and sharding.mesh.size == 1:
-        sharding = None  # one device: the kernels need no manual region
+    kernels, sharding = _on_kernels(
+        sharding, v.shape[0], chunk == CHUNK and q.shape[3] % TILE == 0 and v.shape[3] % TILE == 0)
     if impl == "auto":
-        fits = (chunk == CHUNK and q.shape[3] % TILE == 0 and v.shape[3] % TILE == 0
-                and (sharding is None or sharding.divides(v.shape[0], 1)))
-        impl = "pallas" if on_tpu and fits else "xla"
+        impl = "pallas" if kernels else "xla"
     TOOK[impl] += 1
     if impl == "xla":
         return _xla_rule(q, k, v, g, beta, chunk)

@@ -27,8 +27,19 @@ float32 reference on the same weights and batch. A seed reads:
   the float32 recurrence on the chip is itself 3e-4 off where a head forgets
   least, its `exp` reading low 8192 times in a row, which four seeds of PR 35
   did not show and two fresh ones of PR 36 did);
+- **the passes around the core** (PR 38: `conv_norm_*`, `gated_norm_*`, the
+  convolution, SiLU, the L2 norms and the gated RMSNorm as Pallas passes):
+  layer 0's whole mixer through them against the XLA form of the same
+  arithmetic (`mixer_form` held to "xla"; the core is the kernels' in both)
+  on the same weights and the same normed activations: the relative error of
+  the mixer's output and, of a probe's gradient, the worst leaf's (the
+  mixer's seven leaves and its input);
 - every leaf's gradient twice, against the reference as it routes itself and
   against the reference HELD TO THE PROGRAM'S ROUTING (`forced_experts`).
+
+**A control that breaks the passes, on the first seed, which must FAIL**: the
+same mixer through the kernels with the convolution's first tap dropped (a
+three-tap convolution), against the XLA form with all four.
 
 **Two controls in the next lower precision, on the first seed, each of which
 must FAIL at least one limit**: the router's matmul in bf16 (the whole
@@ -81,8 +92,12 @@ CELL = "qwen3next-c1-s8k"
 # does, so the other limits cannot lie between two readings: they stand at
 # about 1.4 times the program's largest, the loss at the cell's own
 # `reference_loss.abs`
+#   passes_out               7.1e-3   dropped tap 0.79    (PR 38, call 4, seeds 32, 7: layer 0's mixer through the passes' kernels against
+#   passes_worst_leaf        9.8e-3   dropped tap 0.90     the XLA form on the same weights, both in bf16; limits 4 x and 5 x the program's,
+#                                                          1 / 26 and 1 / 18 of the control's)
 LIMITS = {"loss": 2e-3, "router": 1e-5, "core_state": 4e-4, "core_o": 4e-3, "core_o_last_chunk": 4e-3,
-          "tokens_flipped_share": 0.85, "worst_leaf_same_routing": 0.08, "worst_leaf": 0.29}
+          "tokens_flipped_share": 0.85, "worst_leaf_same_routing": 0.08, "worst_leaf": 0.29,
+          "passes_out": 0.03, "passes_worst_leaf": 0.05}
 
 
 def main(argv=None) -> int:
@@ -118,7 +133,10 @@ def main(argv=None) -> int:
     model = construct_hybrid_parallel_model(cfg, hp)
     k = cfg.experts_per_token
     committed = moe.router_logits
-    committed_core = M.gated_delta_rule
+    committed_core, committed_form = M.gated_delta_rule, M.mixer_form
+
+    def xla_form(*_, **__):
+        return "xla"
 
     def reference_loss(p, given):
         parts = ref.loss_parts(p, given, fields)
@@ -159,12 +177,56 @@ def main(argv=None) -> int:
             box["operands"] = operands
             return committed_core(*operands, **where)
 
-        M.gated_delta_rule = spy
+        M.gated_delta_rule, M.mixer_form = spy, xla_form  # the form that hands the core its operands
         try:
             M.linear_mixer(lp, M._norm(x, lp["ln1"], lcfg), None, lcfg)
         finally:
-            M.gated_delta_rule = committed_core
+            M.gated_delta_rule, M.mixer_form = committed_core, committed_form
         return box["operands"]
+
+    def mixer_errors(params, tokens, with_control):
+        """Layer 0's mixer (both projections, the passes, the core) on the
+        normed activations the program hands it, through the passes' kernels
+        and through the XLA form, each a program of its own: the output, and
+        every leaf's gradient of a fixed probe of it."""
+        lcfg = cfg.layer_config(cfg.layer_kinds()[0])
+        lp = params["layers"][0]
+        y = jax.jit(lambda: M._norm(M.embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg),
+                                    lp["ln1"], lcfg))()
+        probe = jax.random.normal(jax.random.PRNGKey(17), y.shape, jnp.float32)
+
+        def run(form, taps_dropped=0):
+            def of(linear, y):
+                linear = dict(linear, conv=linear["conv"].at[:, :taps_dropped].set(0.0))
+                M.mixer_form = form
+                try:
+                    out = M.linear_mixer({"linear": linear}, y, None, lcfg)[0]
+                finally:
+                    M.mixer_form = committed_form
+                return jnp.sum(out.astype(jnp.float32) * probe), out
+
+            fn = jax.jit(jax.value_and_grad(of, argnums=(0, 1), has_aux=True))
+            if form is committed_form:
+                text = fn.lower(lp["linear"], y).as_text()
+                assert "conv_norm_fwd" in text and "gated_norm_bwd" in text, (
+                    "on the chip the passes' form is the kernels'")
+            (_, out), grads = fn(lp["linear"], y)
+            return jax.device_get((out, grads))
+
+        rel = lambda g, r: float(np.linalg.norm(np.asarray(g, np.float64) - np.asarray(r, np.float64))  # noqa: E731
+                                 / np.linalg.norm(np.asarray(r, np.float64)))
+        want_out, want_grads = run(xla_form)
+
+        def against_the_xla_form(out, grads):
+            leaves = {jax.tree_util.keystr(path): rel(g, r) for (path, g), r in zip(
+                jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(want_grads))}
+            return {"passes_out": rel(out, want_out), "passes_worst_leaf": max(leaves.values()),
+                    "passes_worst_leaf_name": max(leaves, key=leaves.get), "leaves": leaves}
+
+        errors = {"program": against_the_xla_form(*run(committed_form))}
+        if with_control:
+            errors["control_dropped_tap"] = against_the_xla_form(*run(committed_form, taps_dropped=1))
+        return errors
 
     @jax.jit
     def recurrence(q, kk, v, g, beta):
@@ -295,6 +357,7 @@ def main(argv=None) -> int:
 
         out = {"seed": seed}
         out["core"] = core_errors(params, tokens)
+        out["passes"] = mixer_errors(params, tokens, with_control)
         ref_parts, ref_grads, ref_picks = reference()
         ref_sets = as_sets(ref_picks)
         out["reference"] = ref_parts
@@ -321,6 +384,8 @@ def main(argv=None) -> int:
                 "core_o": out["core"]["program"][0],
                 "core_o_last_chunk": out["core"]["program"][1],
                 "core_state": out["core"]["program"][2],
+                "passes_out": out["passes"]["program"]["passes_out"],
+                "passes_worst_leaf": out["passes"]["program"]["passes_worst_leaf"],
                 "tokens_flipped_share": flipped["tokens_flipped_share"],
                 "worst_leaf_same_routing": max(same.values()),
                 "worst_leaf": max(free.values()),
@@ -347,9 +412,16 @@ def main(argv=None) -> int:
             verdicts["control_bf16_state"] = not outside
             print("seed %d" % seed, "control_bf16_state", "PASS" if not outside else "FAIL",
                   json.dumps(out["control_bf16_state"]), flush=True)
+            measured = {n: out["passes"]["control_dropped_tap"][n] for n in ("passes_out", "passes_worst_leaf")}
+            outside = {n: [v, LIMITS[n]] for n, v in measured.items() if v > LIMITS[n]}
+            out["control_dropped_tap"] = {"measured": measured, "outside_limits": outside}
+            verdicts["control_dropped_tap"] = not outside
+            print("seed %d" % seed, "control_dropped_tap", "PASS" if not outside else "FAIL",
+                  json.dumps(out["control_dropped_tap"]), flush=True)
         return out, verdicts
 
-    runs, sound, controls_fail = [], True, {"control_bf16_router": False, "control_bf16_state": False}
+    runs, sound, controls_fail = [], True, {"control_bf16_router": False, "control_bf16_state": False,
+                                            "control_dropped_tap": False}
     for i, seed in enumerate(seeds):
         out, verdicts = one_seed(seed, with_control=i == 0)
         runs.append(out)
@@ -357,21 +429,22 @@ def main(argv=None) -> int:
         for name in controls_fail:
             controls_fail[name] = controls_fail[name] or not verdicts.get(name, True)
     largest = {n: max(r["program"]["measured"][n] for r in runs) for n in LIMITS}
-    xla_form = [max(r["core"]["xla_form"][i] for r in runs) for i in range(3)]
+    xla_core = [max(r["core"]["xla_form"][i] for r in runs) for i in range(3)]
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "qwen3next_chip_check.json"), "w") as f:
         json.dump({"device": jax.devices()[0].device_kind, "tokens": seq, "limits": LIMITS,
                    "largest_over_seeds": largest, "runs": runs,
-                   "xla_form_core_o_last_state_largest": xla_form}, f, indent=1)
+                   "xla_form_core_o_last_state_largest": xla_core}, f, indent=1)
     ok = sound and all(controls_fail.values())
     print("VERDICT %s: the program within its limits on seeds %s: %s; the controls outside: %s; "
           "largest reading [limit]: %s; the XLA form's core (o, last 64, state): %s; "
-          "the bf16-router control: %s; the bf16-state control: %s" % (
+          "the bf16-router control: %s; the bf16-state control: %s; the dropped-tap control: %s" % (
               "PASS" if ok else "FAIL", seeds, sound, json.dumps(controls_fail),
-              json.dumps({n: [largest[n], LIMITS[n]] for n in LIMITS}), json.dumps(xla_form),
+              json.dumps({n: [largest[n], LIMITS[n]] for n in LIMITS}), json.dumps(xla_core),
               json.dumps(runs[0]["control_bf16_router"]["measured"]),
-              json.dumps(runs[0]["control_bf16_state"]["measured"])), flush=True)
+              json.dumps(runs[0]["control_bf16_state"]["measured"]),
+              json.dumps(runs[0]["control_dropped_tap"]["measured"])), flush=True)
     return 0 if ok else 1
 
 

@@ -57,6 +57,7 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, tmp_p
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 3), (1, 3, 4)]
     # off a TPU none of the three linear layers takes the Pallas kernels, and the compile report says so
     assert [e["linear_kernel_layers"] for e in events if e["type"] == "compile"] == [0]
+    assert [e["linear_pass_kernel_layers"] for e in events if e["type"] == "compile"] == [0]  # nor the passes around it
 
 
 @pytest.mark.parametrize("flags", [

@@ -14,6 +14,12 @@ float32 under the same 5e-5 (measured 2.5e-6); bf16 operands no further from
 the recurrence than the XLA form is on the same operands (the products on the
 way to the output round to bf16 in both), or inside the float32 limit where
 both are (the gates' gradients).
+
+The passes around the core (`conv_norm_*`, `gated_norm_*`) run interpreted
+too, against the XLA form of models/base.linear_mixer written here in a few
+lines from `causal_conv`, SiLU, `unit`, `rms_norm`: float32 under the same
+5e-5 (measured 4e-7), bf16 no further from the float32 XLA form than the
+bf16 XLA form is.
 """
 
 import jax
@@ -25,6 +31,7 @@ from jax.sharding import Mesh
 
 from galvatron_tpu.ops import linear_attention as L
 from galvatron_tpu.ops.attention import KernelSharding
+from galvatron_tpu.ops.norms import rms_norm
 
 TOL = 5e-5
 B, HK, HV, DK, DV = 2, 2, 4, 16, 8
@@ -200,3 +207,188 @@ def test_the_convolution_is_shifted_adds(taps):
     # causal: a later token moves no earlier output
     moved = L.causal_conv(x.at[:, 7].add(1.0), w)
     np.testing.assert_array_equal(np.asarray(moved[:, :7]), np.asarray(L.causal_conv(x, w)[:, :7]))
+
+
+# --- the passes around the core, interpreted ---------------------------------
+
+QWEN3_NEXT = L.Heads(16, 128, 32, 128)  # 16 key heads serving 32 value heads, 128 wide
+SMALL = L.Heads(1, 128, 2, 128)
+EPS = 1e-6
+
+
+def around(heads, tokens, dtype, seed=0, batch=1):
+    """A projection's output [q | k | v | z], the taps, the gated norm's
+    scale, a core's output, and cotangents for q, k, v and the gated result."""
+    keys, values = heads.key_heads * heads.d_k, heads.value_heads * heads.d_v
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    normal = lambda key, width: jax.random.normal(key, (batch, tokens, width)).astype(dtype)  # noqa: E731
+    return dict(qkvz=normal(ks[0], 2 * keys + 2 * values),
+                taps=jax.random.uniform(ks[1], (2 * keys + values, 4), minval=-0.5, maxval=0.5),
+                scale=1.0 + 0.1 * jax.random.normal(ks[2], (heads.d_v,)), o=normal(ks[3], values),
+                dq=normal(ks[4], keys), dk=normal(ks[5], keys), dv=normal(ks[6], values),
+                dout=normal(ks[7], values))
+
+
+def xla_before(heads, qkvz, taps):
+    """models/base.linear_mixer before the core: -> q, k, v, flat."""
+    b, s, _ = qkvz.shape
+    keys, values = heads.key_heads * heads.d_k, heads.value_heads * heads.d_v
+
+    def unit(t):
+        t32 = t.astype(jnp.float32).reshape(b, s, heads.key_heads, heads.d_k)
+        return (t32 * jax.lax.rsqrt(jnp.sum(jnp.square(t32), axis=-1, keepdims=True) + 1e-6)).reshape(t.shape)
+
+    qkv = jax.nn.silu(L.causal_conv(qkvz[..., :2 * keys + values], taps))
+    return ((unit(qkv[..., :keys]) * heads.d_k ** -0.5).astype(qkvz.dtype),
+            unit(qkv[..., keys:2 * keys]).astype(qkvz.dtype), qkv[..., 2 * keys:])
+
+
+def xla_after(heads, o, qkvz, scale):
+    """models/base.linear_mixer after the core: RMSNorm(o) a head x SiLU(z)."""
+    b, s, values = o.shape
+    z = qkvz[..., -values:].reshape(b, s, heads.value_heads, heads.d_v)
+    normed = rms_norm(o.reshape(z.shape).astype(jnp.float32), scale, EPS)
+    return (normed * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype).reshape(o.shape)
+
+
+def value_heads_shares(heads, x):
+    """A key head's cotangent cut into its value heads' unequal shares, side
+    by side, as the core's backward kernel hands dq and dk on."""
+    serves = heads.value_heads // heads.key_heads
+    weights = jnp.arange(1.0, serves + 1) / sum(range(1, serves + 1))
+    x5 = x.astype(jnp.float32).reshape(x.shape[:2] + (heads.key_heads, 1, heads.d_k))
+    return (x5 * weights[:, None]).reshape(x.shape[:2] + (-1,)).astype(x.dtype)
+
+
+def passes_against_the_xla_form(heads, tokens, dtype, monkeypatch, **kw):
+    """Both passes, forward and backward: the worst leaf of each kind,
+    kernels against the float32 XLA form, and the XLA form in `dtype`
+    against it."""
+    monkeypatch.setattr(L, "_TOKENS", 128)
+    given = around(heads, tokens, dtype, **kw)
+    exact = {name: x.astype(jnp.float32) for name, x in given.items()}
+    cut = given["qkvz"].shape[-1] - given["o"].shape[-1]  # where z starts
+
+    def xla(x):
+        before, pull_before = jax.vjp(lambda a, b: xla_before(heads, a, b), x["qkvz"], x["taps"])
+        after, pull_after = jax.vjp(lambda a, b, c: xla_after(heads, a, b, c), x["o"], x["qkvz"], x["scale"])
+        dqkv, dtaps = pull_before((x["dq"], x["dk"], x["dv"]))
+        do, dz, dscale = pull_after(x["dout"])
+        return dict(zip("q k v".split(), before), gated=after, dqkv=dqkv[..., :cut], dtaps=dtaps, do=do,
+                    dz=dz[..., cut:], dscale=dscale)
+
+    with pltpu.force_tpu_interpret_mode():
+        q, k, v = L._conv_norm(heads, given["qkvz"], given["taps"])
+        dqkvz, do, dscale = L._gated_norm_bwd(heads, EPS, given["o"], given["qkvz"], given["scale"], given["dout"])
+        assert dqkvz.shape == given["qkvz"].shape and dqkvz.dtype == dtype
+        dz = dqkvz[..., cut:]
+        dqkvz, dtaps = L._conv_norm_bwd(
+            heads, given["qkvz"], given["taps"], value_heads_shares(heads, given["dq"]),
+            value_heads_shares(heads, given["dk"]), given["dv"], dqkvz)
+        got = dict(q=q, k=k, v=v, gated=L._gated_norm(heads, EPS, given["o"], given["qkvz"], given["scale"]),
+                   dqkv=dqkvz[..., :cut], dtaps=dtaps, do=do, dz=dz, dscale=dscale)
+    np.testing.assert_array_equal(np.asarray(dqkvz[..., cut:], np.float32), np.asarray(dz, np.float32))
+    want, rounded = xla(exact), xla(given)
+    for name in want:
+        assert got[name].shape == want[name].shape, name
+        limit = TOL if dtype == jnp.float32 else max(TOL, 1.25 * worst(rounded[name], want[name]))
+        assert worst(got[name], want[name]) <= limit, (name, worst(got[name], want[name]), limit)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_passes_around_the_core_are_the_xla_form_at_qwen3_nexts_heads(dtype, monkeypatch):
+    """Three tiles of 128 tokens, so the convolution's halo is crossed twice
+    forward and twice backward: q, k, v, the gated result, and the gradients
+    to the projection's output (q, k, v's columns and z's, filled into one
+    array by two backwards), the taps, o and the scale."""
+    passes_against_the_xla_form(QWEN3_NEXT, 384, dtype, monkeypatch)
+
+
+def test_a_sequences_first_tile_sees_zeros_before_it(monkeypatch):
+    """One tile alone, two rows of the batch: the block before the tile is
+    the tile itself (the index map stops at 0) and must read as zeros; the
+    block after it likewise."""
+    passes_against_the_xla_form(SMALL, 128, jnp.float32, monkeypatch, batch=2, seed=4)
+
+
+def xla_mixer(heads, qkvz, taps, scale, g, beta):
+    b, s, _ = qkvz.shape
+    q, k, v = xla_before(heads, qkvz, taps)
+    o, state = L.gated_delta_rule(q.reshape(b, s, heads.key_heads, heads.d_k),
+                                  k.reshape(b, s, heads.key_heads, heads.d_k),
+                                  v.reshape(b, s, heads.value_heads, heads.d_v), g, beta, impl="xla")
+    return xla_after(heads, o.reshape(b, s, -1), qkvz, scale), state
+
+
+def mixer_operands(tokens, seed=0):
+    given = around(SMALL, tokens, jnp.float32, seed=seed, batch=2)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 100), 2)
+    g = -jnp.exp(jax.random.uniform(ks[0], (2, tokens, 2), minval=np.log(1e-3), maxval=np.log(1.6)))
+    return given["qkvz"], given["taps"], given["scale"], g, jax.nn.sigmoid(jax.random.normal(ks[1], (2, tokens, 2)))
+
+
+def mixer_objective(rule):
+    def of(*a):
+        out, states = rule(*a)
+        return jnp.sum(jnp.sin(out)) + jnp.sum(jnp.cos(states))
+    return of
+
+
+def test_the_kernel_mixer_is_the_xla_form_through_the_core(monkeypatch):
+    """Convolution and norms, the core's kernels, the gated norm as ONE rule
+    (`kernel_mixer`): the result, the final states and the gradients to all
+    five operands, the cotangent of the projection's output written once."""
+    monkeypatch.setattr(L, "_TOKENS", 128)
+    ops = mixer_operands(256)
+    kernel = lambda *a: L.kernel_mixer(*a, SMALL, eps=EPS)  # noqa: E731
+    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
+        got = kernel(*ops) + jax.grad(mixer_objective(kernel), range(5))(*ops)
+        xla = lambda *a: xla_mixer(SMALL, *a)  # noqa: E731
+        want = xla(*ops) + jax.grad(mixer_objective(xla), range(5))(*ops)
+    for name, g, w in zip("out states dqkvz dtaps dscale dg dbeta".split(), got, want):
+        assert g.shape == w.shape and worst(g, w) <= TOL, (name, worst(g, w))
+
+
+def test_the_kernel_mixer_runs_a_device_on_its_rows_of_the_batch(monkeypatch):
+    """Under `sharding` the whole rule sits in one manual region over the
+    batch; the taps' and the scale's gradients are summed over the devices."""
+    monkeypatch.setattr(L, "_TOKENS", 128)
+    ops = mixer_operands(128, seed=2)
+    sharding = KernelSharding(Mesh(np.array(jax.devices()[:2]), ("dp",)), batch_axes=("dp",))
+    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
+        sharded = lambda *a: L.kernel_mixer(*a, SMALL, eps=EPS, sharding=sharding)  # noqa: E731
+        got = jax.jit(lambda *a: sharded(*a) + jax.grad(mixer_objective(sharded), (0, 1, 2))(*a))(*ops)
+        alone = lambda *a: L.kernel_mixer(*a, SMALL, eps=EPS)  # noqa: E731
+        want = alone(*ops) + jax.grad(mixer_objective(alone), (0, 1, 2))(*ops)
+    for name, g, w in zip("out states dqkvz dtaps dscale".split(), got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-6, err_msg=name)
+
+
+def test_off_a_tpu_and_at_heads_of_64_the_passes_take_the_xla_form_and_it_is_counted():
+    given = around(L.Heads(2, 64, 4, 64), 128, jnp.float32)  # no block of whole lanes holds a head of 64
+    before = dict(L.TOOK)
+    assert L.mixer_form(given["qkvz"], given["taps"], L.Heads(2, 64, 4, 64)) == "xla"
+    wide = around(SMALL, 128, jnp.float32)  # the kernels' widths, but this is a CPU
+    assert L.mixer_form(wide["qkvz"], wide["taps"], SMALL) == "xla"
+    for name in ("conv_norm", "gated_norm"):
+        assert L.TOOK[name + "_xla"] == before.get(name + "_xla", 0) + 2
+        assert L.TOOK[name + "_pallas"] == before.get(name + "_pallas", 0)
+    assert L.mixer_form(wide["qkvz"], wide["taps"], SMALL, impl="pallas") == "pallas"
+    assert L.TOOK["conv_norm_pallas"] == before.get("conv_norm_pallas", 0) + 1
+
+
+def test_what_the_passes_cannot_tile_is_left_to_the_xla_form():
+    """On a TPU (told so by a mesh of one) the kernels take whole heads of
+    128 lanes, tiles of 128 tokens and at most eight taps; anything else is
+    the XLA form's."""
+    class OnTpu(KernelSharding):
+        on_tpu = True
+
+    sharding = OnTpu(Mesh(np.array(jax.devices()[:1]), ("dp",)), batch_axes=("dp",))
+    fits = around(SMALL, 128, jnp.float32)
+    assert L.mixer_form(fits["qkvz"], fits["taps"], SMALL, sharding=sharding) == "pallas"
+    assert L.mixer_form(fits["qkvz"][:, :64], fits["taps"], SMALL, sharding=sharding) == "xla"  # half a tile
+    assert L.mixer_form(fits["qkvz"], jnp.zeros((fits["taps"].shape[0], 9)), SMALL, sharding=sharding) == "xla"
+    narrow = L.Heads(2, 64, 4, 64)
+    given = around(narrow, 128, jnp.float32)
+    assert L.mixer_form(given["qkvz"], given["taps"], narrow, sharding=sharding) == "xla"

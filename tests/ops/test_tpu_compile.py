@@ -543,3 +543,80 @@ def test_the_delta_rule_keeps_a_state_a_chunk_and_runs_on_the_mxu_on_v5e(v5e_2x2
         assert len(re.findall(r'op_name="[^"]*%s' % name, hlo)) >= 1, name
     assert instructions * 10 < (_DELTA_RULE_INSTRUCTIONS.get("xla")
                                 or _count_instructions(compiled_with("xla").as_text()))
+
+
+@pytest.fixture(scope="module")
+def qwen3_next_linear_layer(v5e_2x2):
+    """One linear layer's mixer of the Qwen3-Next cell (8192 tokens, hidden
+    2048, 16 key heads serving 32 value heads of 128, bf16) under the cell's
+    recomputation, forward and backward, compiled for one described chip:
+    -> (the optimised module's text, the forms its parts took)."""
+    from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.qwen3_next import qwen3_next_config
+    from galvatron_tpu.ops import linear_attention as L
+
+    tokens = 8192
+    cfg = qwen3_next_config(num_layers=4, max_seq_len=tokens, compute_dtype=jnp.bfloat16)
+    lcfg = cfg.layer_config(cfg.layer_kinds()[0])
+    chip = SingleDeviceSharding(v5e_2x2[0])
+    # a mesh of the one described chip says where the operands lie (the
+    # default backend here is the CPU)
+    where = A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("dp",)), batch_axes=("dp",))
+    shapes = jax.eval_shape(lambda: M.init_layer_params(jax.random.PRNGKey(0), lcfg))
+    operands = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+                            ({"linear": shapes["linear"]},
+                             jax.ShapeDtypeStruct((1, tokens, cfg.hidden_size), jnp.bfloat16)))
+
+    def loss(p, y):
+        mixer = jax.checkpoint(lambda p, y: M.linear_mixer(p, y, None, lcfg, attn_sharding=where))
+        out, _, counters = mixer(p, y)
+        return jnp.sum(out.astype(jnp.float32)) + counters["state_abs_max"]
+
+    before = dict(L.TOOK)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*operands).compile().as_text()
+    return text, {name: count - before.get(name, 0) for name, count in L.TOOK.items()
+                  if count - before.get(name, 0)}
+
+
+def test_the_linear_layers_surround_is_lane_aligned_passes_on_v5e(qwen3_next_linear_layer):
+    """Between the two projections and the core a linear layer runs as
+    Pallas passes over (tokens, channels) arrays, a head a block of 128
+    lanes (ops/linear_attention.py): the four kernels are there under
+    `gt.attn.linear` by their names, the core's two still under
+    `gt.attn.delta`, and what the XLA form cost on a TPU's 8 x 128 tiling
+    (PERF.md, PR 38) is gone from that scope: no view of the activations by
+    (tokens, heads, 128) at all, so no norm's scale broadcast to full size,
+    no physical reshape or relayout copy of a float32 (tokens, 4096) or
+    (tokens, 2048) array; no slice of the projection's output written out
+    and no padded parts of its cotangent summed."""
+    from galvatron_tpu.obs import tracing
+
+    text, took = qwen3_next_linear_layer
+    assert took == {"conv_norm_pallas": 1, "gated_norm_pallas": 1, "pallas": 1}
+    tokens, keys = 8192, 2048
+
+    def calls(kernel, scope):
+        return len(re.findall(r'custom-call\(.*op_name="[^"]*%s/%s[/"]' % (re.escape(scope), kernel), text))
+
+    # a call each for q, k and v; the forward and its recomputation are one here (no scan between them)
+    assert calls("conv_norm_fwd", tracing.ATTN_LINEAR) == 3 and calls("conv_norm_bwd", tracing.ATTN_LINEAR) == 3
+    assert calls("gated_norm_fwd", tracing.ATTN_LINEAR) == 1 and calls("gated_norm_bwd", tracing.ATTN_LINEAR) == 1
+    assert calls("gdn_fwd", tracing.ATTN_DELTA) == 1 and calls("gdn_bwd", tracing.ATTN_DELTA) == 1
+    assert text.count("tpu_custom_call") == 10
+    for kernel in ("conv_norm", "gated_norm"):  # never under the core's scope, whose roofline reads it alone
+        assert not calls(kernel + "_fwd", tracing.ATTN_DELTA) and not calls(kernel + "_bwd", tracing.ATTN_DELTA)
+    assert not re.search(r"\[(?:1,)?%d,(?:32|16),128\]" % tokens, text)  # no view by heads
+    offenders = []
+    for line in text.splitlines():
+        found = re.match(r"\s+(?:ROOT )?(\S+) = (.*?[})]) ([a-z\-]+)\(", line)
+        if not found or tracing.ATTN_LINEAR not in line:
+            continue
+        name, result, kind = found.groups()
+        # the result's arrays over all tokens, at least (tokens, 2048) large: activations, not weights
+        over_tokens = {dtype for dtype, dims in re.findall(r"\b(f32|bf16)\[([\d,]*)\]", result)
+                       if str(tokens) in dims.split(",")
+                       and np.prod([int(d) for d in dims.split(",")]) >= tokens * keys}
+        if (("f32" in over_tokens and kind in ("reshape", "copy", "transpose", "broadcast"))
+                or (over_tokens and kind in ("slice", "dynamic-slice", "pad", "concatenate"))):
+            offenders.append("%s = %s %s" % (name, result[:80], kind))
+    assert not offenders, "\n".join(offenders)
