@@ -771,7 +771,7 @@ def _train(args) -> dict:
             # a routed-experts config's step hands these back beside the loss
             **{k: float(metrics[k])
                for k in (telemetry.EXPERT_STEP_FIELDS + telemetry.SHARE_STEP_FIELDS
-                         + telemetry.LINEAR_STEP_FIELDS)
+                         + telemetry.LINEAR_STEP_FIELDS + telemetry.SSM_STEP_FIELDS)
                if isinstance(metrics, dict) and k in metrics},
         )
 

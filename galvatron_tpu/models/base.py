@@ -40,6 +40,7 @@ from galvatron_tpu.ops.linear_attention import (Heads, causal_conv, gated_delta_
                                                 mixer_form)
 from galvatron_tpu.ops.moe import moe_ffn, swiglu
 from galvatron_tpu.ops.norms import layer_norm, rms_norm
+from galvatron_tpu.ops.ssd import ssd_scan
 from galvatron_tpu.ops.rope import apply_rotary
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import LayerAxes, layer_axes, mesh_axis_size, vocab_axes
@@ -136,6 +137,25 @@ class TransformerConfig:
     attn_output_gate: bool = False  # q is projected beside a gate: attn x sigmoid(gate)
     norm_zero_centered: bool = False  # RMSNorm scales by (1 + w), w from 0
     shared_expert_gate: bool = False  # the shared expert x sigmoid(y w), w (hidden, 1)
+    # --- what Granite-4.0-H's published config adds (granitemoehybrid):
+    # Mamba-2 state-space layers (`ssm_mixer`, ops/ssd.py) among layers of
+    # softmax attention without positions, and four multipliers ---
+    # the token mixer of each layer in HF's words, "mamba" (the mixer "ssm")
+    # or "attention", where the pattern is a LIST (HF `layer_types`) and no
+    # interval says it. A model cut in depth runs the list's first `num_layers`
+    # entries, so the published list may stay whole
+    layer_types: Optional[List[str]] = None
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_dim: int = 0  # a head's state is (ssm_head_dim, ssm_state_dim); B and C one group
+    ssm_conv_kernel: int = 0  # taps of the causal convolution on [x | B | C], with a bias
+    # each a Python float whose default is the model without it: a factor of
+    # 1.0 is not multiplied by, so every other model's arithmetic is bit for
+    # bit what it was
+    embedding_multiplier: float = 1.0  # x the embedding's rows
+    residual_multiplier: float = 1.0  # x each half's output before it joins the residual stream
+    attention_multiplier: Optional[float] = None  # the softmax's scale in place of 1 / sqrt(head_dim)
+    logits_scaling: float = 1.0  # the head's logits are divided by it
     # which `MIXERS` entry ONE layer runs. `layer_config(kind)` sets it; a
     # model's own config leaves it and states the pattern above
     mixer: str = "attention"
@@ -175,6 +195,22 @@ class TransformerConfig:
                     "heads %r, dims (%d, %d), kernel %d" % (
                         self.full_attention_interval, heads, self.linear_key_head_dim,
                         self.linear_value_head_dim, self.linear_conv_kernel))
+        if self.layer_types is not None:
+            self.layer_types = list(self.layer_types)
+            if (len(self.layer_types) < self.num_layers or self.full_attention_interval
+                    or set(self.layer_types) - {"mamba", "attention"}):
+                raise ValueError(
+                    "layer_types names the mixer, \"mamba\" or \"attention\", of each of "
+                    "the %d layers (or more: the first so many are run), and no "
+                    "full_attention_interval beside it; got %r" % (self.num_layers, self.layer_types))
+        if self.mixer == "ssm" or "ssm" in (self.mixers() or ()):
+            if (min(self.ssm_num_heads, self.ssm_head_dim, self.ssm_state_dim, self.ssm_conv_kernel) < 1
+                    or self.routed or self.mtp_layers or self.latent_attention):
+                raise ValueError(
+                    "state-space layers want ssm_num_heads, ssm_head_dim, ssm_state_dim and a "
+                    "convolution kernel of 1 or more, a dense MLP half, and neither latent attention "
+                    "nor a multi-token-prediction module; got heads %d x %d, state %d, kernel %d"
+                    % (self.ssm_num_heads, self.ssm_head_dim, self.ssm_state_dim, self.ssm_conv_kernel))
         if self.input_type == "patches":
             n_patches = (self.image_size // self.patch_size) ** 2
             self.max_seq_len = n_patches + (1 if self.use_cls_token else 0)
@@ -199,16 +235,26 @@ class TransformerConfig:
     def latent_attention(self) -> bool:
         return self.kv_lora_rank > 0
 
+    def mixers(self) -> Optional[Tuple[str, ...]]:
+        """The `MIXERS` key of each layer where `layer_types` lists them, else None."""
+        if self.layer_types is None:
+            return None
+        return tuple("ssm" if t == "mamba" else t for t in self.layer_types[:self.num_layers])
+
     def layer_kinds(self) -> Tuple[str, ...]:
         """The kind of each layer, what `config/strategy.layer_runs` splits
         runs on beside the layout. A kind names the layer's two halves: its
         MLP half, "dense" or "routed", after its token mixer where that is
-        not softmax attention ("linear.routed": `MIXERS`)."""
+        not softmax attention ("linear.routed", "ssm.dense": `MIXERS`). Which
+        layers attend is said by `full_attention_interval` (every so many)
+        or, layer by layer, by the list `layer_types`."""
         if not self.routed:
             mlp = ("dense",) * self.num_layers
         else:
             lead = min(self.first_dense_layers, self.num_layers)
             mlp = ("dense",) * lead + ("routed",) * (self.num_layers - lead)
+        if self.layer_types is not None:
+            return tuple(m if t == "attention" else t + "." + m for t, m in zip(self.mixers(), mlp))
         every = self.full_attention_interval
         if not every:
             return mlp
@@ -227,16 +273,19 @@ class TransformerConfig:
             cfg = dataclasses.replace(
                 cfg, num_experts=0, experts_held=0, num_shared_experts=0, router_bias=False,
                 ffn_hidden=self.dense_ffn_hidden or self.ffn_hidden)
-        if self.full_attention_interval:
-            cfg = dataclasses.replace(cfg, mixer=mixer or "attention", full_attention_interval=0)
+        if self.full_attention_interval or self.layer_types is not None:
+            cfg = dataclasses.replace(cfg, mixer=mixer or "attention", full_attention_interval=0,
+                                      layer_types=None)
         return cfg
 
     @property
     def layer_aux(self) -> bool:
         """Whether a layer hands back auxiliary terms beside its output (a
-        router's losses and loads, a linear mixer's counters): of a layer's
-        config its own layer, of a model's config any of its layers."""
-        return self.routed or self.mixer == "linear" or self.full_attention_interval > 0
+        router's losses and loads, a linear or state-space mixer's counters):
+        of a layer's config its own layer, of a model's config any of its
+        layers."""
+        return (self.routed or self.mixer in ("linear", "ssm") or self.full_attention_interval > 0
+                or "ssm" in (self.mixers() or ()))
 
     @property
     def rotary_dim(self) -> int:
@@ -338,6 +387,34 @@ def _init_linear(ks, cfg: TransformerConfig) -> Params:
         "norm": {"scale": jnp.ones((cfg.linear_value_head_dim,), cfg.param_dtype)},
         "wout": {"kernel": _dense_init(ks[1], (value_dim, h), _proj_std(cfg), cfg.param_dtype)},
     }}
+
+
+def _init_ssm(ks, cfg: TransformerConfig) -> Params:
+    """The Mamba-2 mixer's leaves, under `ssm` (HF `GraniteMoeHybridMambaLayer`:
+    in_proj, conv1d, dt_bias, A_log, D, norm, out_proj). `win`'s columns lie
+    [z | x | B | C | dt] as HF's. Initialised as the Mamba-2 reference does: A
+    = exp(A_log) ~ U(1, 16), dt = softplus(dt_bias) log-uniform in [0.001,
+    0.1], D = 1, so that exp(dt A) spans 0.2 to 0.999 a token and state
+    crosses chunks; the taps and their bias U(-1, 1) / sqrt(taps), PyTorch's
+    default for a convolution of that fan-in."""
+    h, taps, nh = cfg.hidden_size, cfg.ssm_conv_kernel, cfg.ssm_num_heads
+    inner = nh * cfg.ssm_head_dim
+    conv_dim = inner + 2 * cfg.ssm_state_dim
+    kgate = jax.random.split(ks[4], 4)
+    step = jnp.exp(jax.random.uniform(kgate[2], (nh,), jnp.float32, math.log(1e-3), math.log(0.1)))
+    p = {
+        "win": {"kernel": _dense_init(ks[0], (h, inner + conv_dim + nh), cfg.init_std, cfg.param_dtype)},
+        "conv": {"kernel": jax.random.uniform(kgate[0], (conv_dim, taps), jnp.float32,
+                                              -1.0, 1.0).astype(cfg.param_dtype) / taps ** 0.5,
+                 "bias": jax.random.uniform(kgate[3], (conv_dim,), jnp.float32,
+                                            -1.0, 1.0).astype(cfg.param_dtype) / taps ** 0.5},
+        "A_log": jnp.log(jax.random.uniform(kgate[1], (nh,), jnp.float32, 1.0, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+        "D": jnp.ones((nh,), jnp.float32),
+        "norm": {"scale": jnp.ones((inner,), cfg.param_dtype)},
+        "wout": {"kernel": _dense_init(ks[1], (inner, h), _proj_std(cfg), cfg.param_dtype)},
+    }
+    return {"ssm": p}
 
 
 def _proj_std(cfg: TransformerConfig) -> float:
@@ -580,30 +657,40 @@ def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional
     cache in the decode engine: where it is the reason, it is named. And of
     gated-DeltaNet linear-attention layers among attention layers
     (`full_attention_interval` > 0): the recurrence has no tp, sp, cp or pp
-    form, the decode engine no recurrent state, the cost models no row."""
+    form, the decode engine no recurrent state, the cost models no row. And,
+    alike, of Mamba-2 state-space layers (`layer_types` naming "ssm"): the
+    scan's state runs along the whole sequence, the gated norm over all of a
+    layer's channels."""
     latent = bool(getattr(cfg, "latent_attention", False) or getattr(cfg, "mtp_layers", 0))
-    linear = linear_layers_reason(cfg) is not None
-    if not (getattr(cfg, "routed", False) or latent or linear):
+    linear, ssm = _has_linear(cfg), _has_ssm(cfg)
+    if not (getattr(cfg, "routed", False) or latent or linear or ssm):
         return None
     also = (" (nor has latent attention, MLA: kv_lora_rank > 0)" if latent else "") + (
         " (nor have linear-attention layers, full_attention_interval > 0: the delta rule's "
-        "state runs along the whole sequence of all a layer's heads)" if linear else "")
+        "state runs along the whole sequence of all a layer's heads)" if linear else "") + (
+        " (nor have state-space layers, layer_types naming \"ssm\": the scan's state runs along "
+        "the whole sequence and the gated norm over all of a layer's channels)" if ssm else "")
     if mode == "serve":
         return "serve: the decode engine has no expert form" + (
             ", and no cache of latent attention's compressed k/v" if latent else "") + (
             ", and no recurrent state of a linear-attention layer (serve/kv_cache.py holds "
-            "keys and values)" if linear else "")
+            "keys and values)" if linear else "") + (
+            ", and no convolution window or scan state of a state-space layer (serve/kv_cache.py "
+            "holds keys and values)" if ssm else "")
     if (autotune or "off") != "off":
         return "autotune=%s: the re-search would price the block as dense" % autotune + (
             ", and latent attention as full-rank" if latent else "") + (
-            ", and a linear-attention layer as softmax attention" if linear else "")
+            ", and a linear-attention layer as softmax attention" if linear else "") + (
+            ", and a state-space layer as softmax attention" if ssm else "")
     if hp is None:
         return None
     if hp.pp > 1:
         return "pp=%d: the pipeline engines carry no router losses between stages" % hp.pp + (
             " and no multi-token-prediction module after the last" if latent else "") + (
             " and stack one kind of layer a stage, not linear-attention layers among "
-            "attention layers" if linear else "")
+            "attention layers" if linear else "") + (
+            " and stack one kind of layer a stage, not state-space layers among attention "
+            "layers" if ssm else "")
     for i, s in enumerate(hp.layers):
         if s.tp > 1 or s.cp > 1 or s.sp:
             return ("layer %d: tp=%d cp=%d sp=%d: the experts' kernels and the dropless "
@@ -620,12 +707,23 @@ def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional
     return None
 
 
+def _has_linear(cfg) -> bool:
+    return bool(getattr(cfg, "full_attention_interval", 0) or getattr(cfg, "mixer", "") == "linear")
+
+
+def _has_ssm(cfg) -> bool:
+    mixers = getattr(cfg, "mixers", None)
+    return getattr(cfg, "mixer", "") == "ssm" or "ssm" in ((mixers() if callable(mixers) else None) or ())
+
+
 def linear_layers_reason(cfg) -> Optional[str]:
-    """What `search` and `profile` say of a config with linear-attention
-    layers, or None for one without."""
-    if not (getattr(cfg, "full_attention_interval", 0) or getattr(cfg, "mixer", "") == "linear"):
-        return None
-    return "linear-attention layers (full_attention_interval > 0) have no row in the cost models"
+    """What `search` and `profile` say of a config with linear-attention or
+    state-space layers, or None for one without."""
+    if _has_ssm(cfg):
+        return "state-space layers (layer_types naming \"ssm\") have no row in the cost models"
+    if _has_linear(cfg):
+        return "linear-attention layers (full_attention_interval > 0) have no row in the cost models"
+    return None
 
 
 def expert_layout_diagnostic(reason: str):
@@ -633,8 +731,9 @@ def expert_layout_diagnostic(reason: str):
     from galvatron_tpu.analysis import diagnostics as D
 
     return D.make(
-        "GLS018", "routed experts (num_experts > 0) refused: %s; such a config runs on one "
-        "chip and under dp with ZeRO-1/2/3" % reason, key="num_experts")
+        "GLS018", "routed experts (num_experts > 0), latent attention, linear-attention or "
+        "state-space layers refused: %s; such a config runs on one chip and under dp with "
+        "ZeRO-1/2/3" % reason, key="num_experts")
 
 
 def refuse_expert_layout(reason: str):
@@ -712,7 +811,7 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
         # the flash path may lower it to segment ids instead of falling back
         attn = core_attention(q, k, v, causal=cfg.causal, bias=attn_bias,
                               impl=cfg.attn_impl, bias_type="key_padding",
-                              sharding=attn_sharding)
+                              sharding=attn_sharding, sm_scale=cfg.attention_multiplier)
     with jax.named_scope(scope):
         if gate is not None:
             attn = attn * jax.nn.sigmoid(gate)
@@ -778,6 +877,45 @@ def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
     return out, None, stats
 
 
+def ssm_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, **_):
+    """Mamba-2 on normed activations (B, S, H) (HF `GraniteMoeHybridMambaLayer`;
+    arXiv:2405.21060), p the layer's tree:
+
+        [z | xBC | dt] = y Win
+        xBC = silu(conv(xBC) + b)                     causal, depthwise, a channel
+        [x | B | C] = xBC;  dt = softplus(dt + dt_bias);  A = -exp(A_log)   float32
+        h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T;  y_t = h_t C_t + D x_t   ops/ssd.py
+        out = (RMSNorm(y silu(z); w)) Wout            the gate BEFORE the norm, the
+                                                      norm over ALL the mixer's channels
+
+    B and C are one group's: every head reads the same. -> out, None, and the
+    layer's counter: the largest magnitude of any head's state at any chunk's
+    end. Scopes: the scan under `gt.attn.ssd`, all else under `gt.attn.ssm`.
+    No position enters: the order is the recurrence's. The convolution and
+    the gated norm are XLA's (`causal_conv`; the Pallas passes of
+    ops/linear_attention.py norm a head's 128 lanes and know no bias)."""
+    p, dtype = p["ssm"], cfg.compute_dtype
+    nh, hd, ds = cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_dim
+    inner = nh * hd
+    b, s, _ = y.shape
+    with jax.named_scope(tracing.ATTN_SSM):
+        zxbcdt = _dense(y, p["win"], dtype)
+        z = zxbcdt[..., :inner]
+        xbc = causal_conv(zxbcdt[..., inner:2 * inner + 2 * ds], p["conv"]["kernel"])
+        xbc = jax.nn.silu((xbc.astype(jnp.float32) + p["conv"]["bias"].astype(jnp.float32)).astype(dtype))
+        dt = jax.nn.softplus(zxbcdt[..., 2 * inner + 2 * ds:].astype(jnp.float32)
+                             + p["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(p["A_log"].astype(jnp.float32))
+    with jax.named_scope(tracing.ATTN_SSD):
+        o, _, peak = ssd_scan(xbc[..., :inner].reshape(b, s, nh, hd), dt, a,
+                              xbc[..., inner:inner + ds], xbc[..., inner + ds:], p["D"])
+    with jax.named_scope(tracing.ATTN_SSM):
+        o = o.reshape(b, s, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        o = rms_norm(o, p["norm"]["scale"], cfg.layernorm_eps).astype(dtype)
+        out = _dense(o, p["wout"], dtype)
+    return out, None, {"ssm_state_abs_max": peak}
+
+
 @dataclass(frozen=True)
 class TokenMixer:
     """What a kind of token mixer brings to a layer (ROADMAP D6, at the size
@@ -835,6 +973,8 @@ def layer_forward(
         attn_sharding=attn_sharding, return_kv=return_kv)
     if mesh is not None and axes is not None:
         o = S.constrain(o, mesh, S.act_spec(axes))
+    if cfg.residual_multiplier != 1.0:
+        o = o * cfg.residual_multiplier
     x = residual + o
     if not cfg.pre_norm:
         x = _norm(x, p["ln1"], cfg)
@@ -863,6 +1003,8 @@ def layer_forward(
             out, aux = dense_mlp(p, y, cfg, dtype), None
     if mesh is not None and axes is not None:
         out = S.constrain(out, mesh, S.act_spec(axes))
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
     x = residual + out
     if not cfg.pre_norm:
         x = _norm(x, p["ln2"], cfg)
@@ -1017,6 +1159,8 @@ def embed_tokens(p_embed: Params, tokens: jax.Array, positions: jax.Array, cfg: 
         x = x + p_embed["tte"].astype(cfg.compute_dtype)[tti]
     if cfg.embed_norm:
         x = _norm(x, p_embed["norm"], cfg)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     return x
 
 
@@ -1090,7 +1234,10 @@ def head_logits(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Arr
     transposed) in the compute dtype."""
     tied = cfg.tie_embeddings
     stored = params["embed"]["wte"] if tied else params["lm_head"]["kernel"]
-    return _head_matmul(x, stored.astype(cfg.compute_dtype), tied)
+    logits = _head_matmul(x, stored.astype(cfg.compute_dtype), tied)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def lm_logits(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
@@ -1391,6 +1538,7 @@ def _fold_aux(auxs: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
         "load_max_over_mean": lambda n: total(n, jnp.max),
         "bias_abs_max": lambda n: total(n, jnp.max),
         "state_abs_max": lambda n: total(n, jnp.max),
+        "ssm_state_abs_max": lambda n: total(n, jnp.max),
         "rows_held": lambda n: total(n, jnp.sum),
         "counts": lambda n: jnp.concatenate([jnp.atleast_2d(a[n]) for a in auxs if n in a]),
     }
@@ -1493,7 +1641,7 @@ ROUTER_COUNTS = "router_counts"  # (routed blocks, E): the step's; no metric
 # weighted as the loss is)
 PART_FOLDS = {EXPERT_LOAD: jnp.maximum, "router_bias_abs_max": jnp.maximum,
               ROUTER_COUNTS: jnp.add, "expert_rows_held": jnp.add,
-              "linear_state_abs_max": jnp.maximum}
+              "linear_state_abs_max": jnp.maximum, "ssm_state_abs_max": jnp.maximum}
 
 
 def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False):
@@ -1508,7 +1656,8 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False)
     shifted by one more; a sequence's last position has none). `with_parts`
     returns `(loss, parts)`: the terms, the worst block's expert load and
     the other counters of the `step` event (`telemetry.EXPERT_STEP_FIELDS`,
-    `LINEAR_STEP_FIELDS` for linear-attention layers),
+    `LINEAR_STEP_FIELDS` for linear-attention layers, `SSM_STEP_FIELDS` for
+    state-space layers),
     and for a router with a bias the blocks' assignment counts
     (`ROUTER_COUNTS`), which the train step moves the bias by."""
     logits, hidden, auxs = _forward(
@@ -1540,6 +1689,8 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False)
     if "decay_mean" in aux:  # the linear mixers' counters (telemetry.LINEAR_STEP_FIELDS)
         parts["linear_decay_mean"] = aux["decay_mean"]
         parts["linear_state_abs_max"] = aux["state_abs_max"]
+    if "ssm_state_abs_max" in aux:  # the state-space mixers' counter (telemetry.SSM_STEP_FIELDS)
+        parts["ssm_state_abs_max"] = aux["ssm_state_abs_max"]
     if "load_max_over_mean" in aux:  # a router (linear layers over dense MLPs have none)
         parts[EXPERT_LOAD] = aux["load_max_over_mean"]
     if "rows_held" in aux:
@@ -1644,6 +1795,18 @@ def _linear_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     }}
 
 
+def _ssm_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
+    # ordinary leaves (tp, sp, cp are refused, GLS018): ZeRO-3 splits the
+    # projections' input dim; the small leaves are whole everywhere
+    z3 = S._ax(axes.dp) if axes.zero3 else None
+    r1 = S.replicated_1d_spec(axes)
+    return {"ssm": {
+        "win": {"kernel": P(z3, None)}, "conv": {"kernel": P(None, None), "bias": r1},
+        "A_log": r1, "dt_bias": r1, "D": r1,
+        "norm": {"scale": r1}, "wout": {"kernel": P(z3, None)},
+    }}
+
+
 # a layer's kind (`TransformerConfig.layer_kinds`) names its mixer before its
 # MLP half; softmax attention, every model's but one, goes unnamed
 MIXERS = {
@@ -1651,6 +1814,8 @@ MIXERS = {
                             "attention_fwd_flops_a_token", (tracing.ATTN_PROJ, tracing.ATTN_LATENT)),
     "linear": TokenMixer(_init_linear, linear_mixer, _linear_specs,
                          "linear_fwd_flops_a_token", (tracing.ATTN_LINEAR, tracing.ATTN_DELTA)),
+    "ssm": TokenMixer(_init_ssm, ssm_mixer, _ssm_specs,
+                      "ssm_fwd_flops_a_token", (tracing.ATTN_SSM, tracing.ATTN_SSD)),
 }
 
 

@@ -92,8 +92,17 @@ def _ensure_builtin():
     if _LOADED:
         return
     _LOADED = True
-    from galvatron_tpu.models import glm4_moe_lite, gpt, llama, olmoe, qwen3_next
+    from galvatron_tpu.models import glm4_moe_lite, gpt, granite_hybrid, llama, olmoe, qwen3_next
 
+    register(
+        ModelFamily(
+            name="granite_hybrid",
+            config_fn=granite_hybrid.granite_hybrid_config,
+            meta_configs=granite_hybrid.META_CONFIGS,
+            default_size="granite-4.0-h-micro",
+            config_from_hf=granite_hybrid.granite_hybrid_config_from_hf,
+        )
+    )
     register(
         ModelFamily(
             name="qwen3_next",

@@ -96,6 +96,18 @@ def linear_fwd_flops_a_token(*, hidden: int, num_key_heads: int, num_value_heads
     return proj, 6.0 * num_value_heads * key_head_dim * value_head_dim
 
 
+def ssm_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, state_dim: int):
+    """A Mamba-2 mixer: hidden -> [z | x | B | C | dt] (2 x inner + 2 x state
+    + heads), inner -> hidden; and the scan as the RECURRENCE needs it, two
+    (d_head, d_state) products a head a token (dt x B^T into the state, h C
+    out of it: 4 d_head d_state), whatever chunk an implementation cuts the
+    sequence into and at any sequence length. The convolution's taps, the
+    decay and the D skip are no matmul."""
+    inner = num_heads * head_dim
+    proj = 2.0 * hidden * (2 * inner + 2 * state_dim + num_heads) + 2.0 * inner * hidden
+    return proj, 4.0 * num_heads * head_dim * state_dim
+
+
 def layer_fwd_flops(
     *,
     hidden: int,
@@ -115,6 +127,7 @@ def layer_fwd_flops(
     attn_gate: bool = False,
     shared_gate: bool = False,
     linear: Optional[Mapping[str, int]] = None,
+    ssm: Optional[Mapping[str, int]] = None,
 ) -> float:
     """Forward model FLOPs of ONE transformer block over `tokens` tokens
     (default: one sequence). Matmul terms only (2 FLOPs per MAC); norms and
@@ -129,10 +142,13 @@ def layer_fwd_flops(
     their shapes in place of q, k/v and out; `attn_gate`: q projected beside
     an output gate. `linear` (num_key_heads, num_value_heads, key_head_dim,
     value_head_dim): the layer's token mixer is a gated DeltaNet, in place of
-    attention. `shared_gate`: the shared expert's (hidden, 1) gate."""
+    attention; `ssm` (num_heads, head_dim, state_dim): a Mamba-2 state-space
+    mixer. `shared_gate`: the shared expert's (hidden, 1) gate."""
     tokens = float(seq_len if tokens is None else tokens)
     ffn = ffn_hidden or 4 * hidden
-    if linear:
+    if ssm:
+        proj, attn = ssm_fwd_flops_a_token(hidden=hidden, **ssm)
+    elif linear:
         proj, attn = linear_fwd_flops_a_token(hidden=hidden, **linear)
     else:
         proj, attn = attention_fwd_flops_a_token(
@@ -167,6 +183,9 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
     if getattr(cfg, "mixer", "attention") == "linear":
         linear = {k: getattr(cfg, "linear_" + k) for k in (
             "num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
+    ssm = None
+    if getattr(cfg, "mixer", "attention") == "ssm":
+        ssm = {k: getattr(cfg, "ssm_" + k) for k in ("num_heads", "head_dim", "state_dim")}
     return layer_fwd_flops(
         hidden=hidden,
         num_heads=heads,
@@ -185,6 +204,7 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         attn_gate=bool(getattr(cfg, "attn_output_gate", False)),
         shared_gate=bool(getattr(cfg, "shared_expert_gate", False)),
         linear=linear,
+        ssm=ssm,
     )
 
 

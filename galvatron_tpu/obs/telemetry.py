@@ -65,6 +65,9 @@ SHARE_STEP_FIELDS = ("loss_mtp", "expert_rows_held", "expert_rows_held_over_even
 # its state a token keeps; the largest magnitude in any head's final state,
 # the delta rule's blow-up alarm
 LINEAR_STEP_FIELDS = ("linear_decay_mean", "linear_state_abs_max")
+# state-space layers' counter (models/base.ssm_mixer): the largest magnitude
+# of any head's state at any chunk's end, the worst layer's
+SSM_STEP_FIELDS = ("ssm_state_abs_max",)
 
 # type -> (required field names, optional field names). Unknown types and
 # unknown keys are rejected; None-valued optional fields are dropped at emit
@@ -100,7 +103,8 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         # beside the loss, fetched with it
         ("loss", "iter_ms", "dispatch_ms", "data_wait_ms", "host_blocked_ms",
          "hbm_in_use_mb", "hbm_peak_mb", "mfu", "model_flops_per_s",
-         "grad_norm") + EXPERT_STEP_FIELDS + SHARE_STEP_FIELDS + LINEAR_STEP_FIELDS,
+         "grad_norm") + EXPERT_STEP_FIELDS + SHARE_STEP_FIELDS + LINEAR_STEP_FIELDS
+        + SSM_STEP_FIELDS,
     ),
     "eval": (("iter", "split", "loss"), ()),
     # lifecycle: checkpointing

@@ -77,6 +77,13 @@ MLP = "gt.mlp"
 # projection)
 ATTN_DELTA = "gt.attn.delta"
 ATTN_LINEAR = "gt.attn.linear"
+# a Mamba-2 state-space mixer (models/base.ssm_mixer), inside gt.layers.r<k>,
+# in two disjoint scopes that add up to the mixer, as the linear mixer's: the
+# scan (ops/ssd.ssd_scan: the chunks' masks and products, the carried state;
+# forward, recomputed and backward) and everything else of it (the
+# projections, the convolution and its bias, dt, the gated norm)
+ATTN_SSD = "gt.attn.ssd"
+ATTN_SSM = "gt.attn.ssm"
 # the multi-token-prediction module, top level: its norms, the (2h, h)
 # projection and its block; its pass through the head and its cross entropy
 # run under HEAD_LOSS, beside the main ones

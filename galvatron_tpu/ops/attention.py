@@ -18,6 +18,7 @@ path transposes to its (batch, heads, seq, head_dim) convention.
 
 from __future__ import annotations
 
+import logging
 import math
 from typing import NamedTuple, Optional, Tuple
 
@@ -172,6 +173,26 @@ def padding_bias_to_segment_ids(bias: jax.Array):
     return SegmentIds(q=valid, kv=valid)
 
 
+_FALLBACKS_SAID = set()
+
+
+def _say_fallback_once(q_shape, sk: int, biased: bool, splits: bool) -> None:
+    """One line a shape when `impl="auto"` leaves the kernel on a TPU although
+    the sequence is in whole tiles: what XLA's form costs there."""
+    key = (tuple(q_shape), sk, biased, splits)
+    if key in _FALLBACKS_SAID:
+        return
+    _FALLBACKS_SAID.add(key)
+    b, sq, nh, hd = q_shape
+    why = ("a bias the kernel cannot take as segment ids" if biased and splits else
+           "batch or heads the mesh does not divide" if not splits else
+           "head_dim %d (the kernel compiles at 64 and from 128 up)" % hd)
+    logging.getLogger(__name__).warning(
+        "core_attention: XLA attention on a TPU at q %s, %d keys (%s): it materialises float32 "
+        "logits of (%d, %d, %d, %d), %.2f GiB a call, where the flash kernel holds a block",
+        tuple(q_shape), sk, why, b, nh, sq, sk, b * nh * sq * sk * 4 / 2**30)
+
+
 def core_attention(
     q: jax.Array,
     k: jax.Array,
@@ -236,9 +257,12 @@ def core_attention(
     # under a manual region the kernel sees whole batch rows and heads only
     splits = sharding is None or sharding.divides(q.shape[0], q.shape[2])
     if impl == "auto":
-        # pallas flash path needs seq/head tiling-friendly shapes
+        # the kernel's blocks are whole 128-token tiles of the sequence, and
+        # Mosaic compiles its three kernels at heads 128 wide or wider and at
+        # 64 (Granite's; tests/ops/test_tpu_compile.py), nothing narrower tried
+        tileable = q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
         ok_shapes = (
-            q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and q.shape[3] >= 128
+            tileable and (q.shape[3] >= 128 or q.shape[3] == 64)
             and (bias is None or seg_flash_ok) and splits
         )
         # on a TPU the kernel (blocks: _flash_block_sizes) wherever the
@@ -246,6 +270,8 @@ def core_attention(
         # logits. XLA's fused attention has not been timed against it on
         # the chip; every benchmark cell runs the kernel.
         impl = "flash" if (on_tpu and ok_shapes) else "xla"
+        if on_tpu and tileable and impl == "xla":
+            _say_fallback_once(q.shape, k.shape[1], bias is not None, splits)
     if impl == "flash":
         if bias is not None and (not seg_flash_ok or not on_tpu):
             # the pallas flash kernel takes no generic additive bias; fall

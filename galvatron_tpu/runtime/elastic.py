@@ -81,6 +81,9 @@ _DIGEST_DEFAULTS = {
     "linear_key_head_dim": 0, "linear_value_head_dim": 0, "linear_conv_kernel": 0,
     "partial_rotary_factor": 1.0, "attn_output_gate": False, "norm_zero_centered": False,
     "shared_expert_gate": False, "mixer": "attention",
+    "layer_types": None, "ssm_num_heads": 0, "ssm_head_dim": 0, "ssm_state_dim": 0,
+    "ssm_conv_kernel": 0, "embedding_multiplier": 1.0,
+    "residual_multiplier": 1.0, "attention_multiplier": None, "logits_scaling": 1.0,
 }
 
 

@@ -1,0 +1,205 @@
+#!/usr/bin/env python3
+"""Granite-4.0-H at its published widths and the timed sizes on the chip,
+program against plain reference, outside any timed window (the `model-configs`
+guide's section 3, item 3):
+
+    chiprun -- python3 scripts/granite4h_chip_check.py [--seeds N,N,...]
+
+One seeded 4096-token sequence a seed through the benchmark's own configuration
+(benchmarks/configs/granite-4.0-h-micro-d10-v8.json: nine Mamba-2 layers and
+one attention layer, 1/8 of the tied vocabulary) and the cell's own layout (one
+chip, `--checkpoint 1`, three scanned runs) against the float32 reference on
+the same weights and batch (its `jax.grad` computed in blocks: a layer, a block
+of 64 tokens of the recurrence and a block of 1024 queries recomputed at a
+time). A seed reads:
+
+- the loss;
+- every leaf's gradient, relative by the Frobenius norm: the worst leaf of
+  all, and the worst layer's reading for EACH Mamba leaf (`win`, `conv.kernel`,
+  `conv.bias`, `dt_bias`, `A_log`, `D`, `norm.scale`, `wout`);
+- **the scan's core**: layer 0's x, dt, A, B, C, D as the program makes them
+  (bf16 operands, float32 dt), through `ops/ssd.ssd_scan` as the step runs it:
+  the relative error of y over the whole sequence and over the LAST 128
+  tokens, where 4096 tokens of carried state have piled up, against the
+  reference's token-by-token recurrence in float32 on the chip, and of the
+  final states against the same recurrence in FLOAT64 ON THE HOST (the
+  float32 recurrence on a chip is itself off where a head forgets least, its
+  `exp` reading low thousands of times in a row: PERF.md, PR 36).
+
+**A control in the next lower precision, on the first seed, which must FAIL at
+least one limit**: the same core with its carried state rounded to bfloat16
+after every chunk (`state_dtype`, by `jax.lax.reduce_precision`: a cast there
+and back the TPU compiler takes out). Writes
+`chiprun_out/granite4h_chip_check.json`; its LAST line of output is the
+verdict with each measure's largest reading over the seeds beside its limit;
+exits 1 unless the program passes on every seed and the control fails. Refuses
+to run where jax finds no TPU.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+CELL = "granite4h-c1-s4k"
+MAMBA_LEAVES = ("win", "conv']['kernel", "conv']['bias", "dt_bias", "A_log", "['D']", "norm", "wout")
+# measure -> most allowed, for the program as the cell runs it (bf16 compute).
+# Two readings each (my chip runs, PR 39, call 2: seeds 32, 7, 2024; the control
+# on seed 32): the largest the program gave over the seeds, and the control's.
+#   loss               2.5e-5   bf16 state: not run (the whole step has no such switch)
+#   core_state         3.2e-6   bf16 state 1.68e-3  (final states against float64 on the host; the float32
+#                               recurrence token by token ON THE CHIP reads 2.6e-5 to 6.6e-5 there: its own error)
+#   core_y             1.701e-3 bf16 state 1.701e-3 (bf16 operands on the way to the output: 2^-9)
+#   core_y_last_chunk  1.709e-3 bf16 state 1.709e-3
+#   worst_leaf         0.0288   (layer 2's A_log; the median leaf 0.016: what a bf16 stream does to a gradient)
+#   worst_mamba_leaf   0.0288   (win 0.0162, conv.kernel 0.0164, conv.bias 0.0148, dt_bias 0.0237, A_log 0.0288,
+#                               D 0.0207, norm.scale 0.0153, wout 0.0148: the worst layer's, over the seeds)
+# `core_state` tells a bf16 state from a float32 one by nearly three orders of
+# magnitude: its limit lies between the two readings, 22 x over the one and
+# 1 / 24 of the other. The control moves neither the core's output nor (so)
+# any gradient further than the bf16 stream they read already does, so the
+# other limits cannot lie between two readings: they stand at about 1.5 times
+# the program's largest, the loss at the cell's own `reference_loss.abs`.
+LIMITS = {"loss": 2e-3, "core_state": 7e-5, "core_y": 2.6e-3, "core_y_last_chunk": 2.6e-3,
+          "worst_leaf": 0.045, "worst_mamba_leaf": 0.045}
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--seeds", default="32,7,2024", help="comma-separated; the control runs on the first")
+    args = parser.parse_args(argv)
+    seeds = [int(n) for n in args.seeds.split(",")]
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    if jax.devices()[0].platform != "tpu":
+        print("granite4h_chip_check needs a TPU; found %s" % jax.devices()[0].platform, file=sys.stderr)
+        return 2
+    from benchmarks import cells
+    from galvatron_tpu import HybridParallelConfig
+    from galvatron_tpu.models import base as M
+    from galvatron_tpu.ops import ssd
+    from galvatron_tpu.runtime import construct_hybrid_parallel_model
+
+    cell = cells.load_cell(ROOT, CELL)
+    ref = cells.load_module(ROOT, "benchmarks/references/%s.py" % cell.config["reference"])
+    build = cells.import_attr(cell.config["program"]["config_fn"])
+    seq = cell.traffic["seq_length"]
+    cfg = build(cell.config["program"]["preset"],
+                **{**cell.fields, "max_seq_len": seq, "compute_dtype": jnp.bfloat16})
+    fields = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+    hp = HybridParallelConfig.uniform(1, cfg.num_layers, global_bsz=1, checkpoint=1)
+    model = construct_hybrid_parallel_model(cfg, hp)
+    committed_scan = M.ssd_scan
+    rel = lambda d, e: float(np.linalg.norm(np.asarray(d, np.float64)) / np.linalg.norm(np.asarray(e, np.float64)))  # noqa: E731
+
+    @jax.jit
+    def core_operands(params, tokens):
+        """Layer 0's x, dt, A, B, C, D as the program makes them."""
+        lcfg = cfg.layer_config(cfg.layer_kinds()[0])
+        lp = params["layers"][0]
+        x = M.embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg)
+        box = {}
+
+        def spy(*operands, **kw):
+            box["operands"] = operands
+            return committed_scan(*operands, **kw)
+
+        M.ssd_scan = spy
+        try:
+            M.ssm_mixer(lp, M._norm(x, lp["ln1"], lcfg), None, lcfg)
+        finally:
+            M.ssd_scan = committed_scan
+        return box["operands"]
+
+    @jax.jit
+    def recurrence(x, dt, a, bm, cm, d):
+        with jax.default_matmul_precision("highest"):
+            return ref.ssm_scan(*(t.astype(jnp.float32) for t in (x[0], dt[0], a, bm[0], cm[0], d)))
+
+    def final_states_float64(x, dt, a, bm, cm, d):
+        x, dt, a, bm = (np.asarray(t.astype(jnp.float32), np.float64) for t in (x[0], dt[0], a, bm[0]))
+        state = np.zeros((x.shape[1], x.shape[2], bm.shape[1]))
+        for t in range(x.shape[0]):
+            state *= np.exp(dt[t] * a)[:, None, None]
+            state += (dt[t][:, None] * x[t])[:, :, None] * bm[t]
+        return state
+
+    def core_errors(params, tokens, with_control):
+        operands = core_operands(params, tokens)
+        exact, state_on_chip = recurrence(*operands)
+        exact_state = final_states_float64(*operands)
+
+        def error(**kw):
+            y, state, peak = jax.jit(lambda *o: ssd.ssd_scan(*o, **kw))(*operands)
+            diff = y[0].astype(jnp.float32) - exact
+            return {"core_y": rel(diff, exact), "core_y_last_chunk": rel(diff[-ssd.CHUNK:], exact[-ssd.CHUNK:]),
+                    "core_state": rel(np.asarray(state[0], np.float64) - exact_state, exact_state),
+                    "state_abs_max": float(peak)}
+
+        out = {"program": error(),
+               "recurrence_float32_on_chip_state": rel(np.asarray(state_on_chip, np.float64) - exact_state,
+                                                       exact_state),
+               "decay_mean": float(jnp.mean(jnp.exp(operands[1] * operands[2]))),
+               "y_rms": float(jnp.sqrt(jnp.mean(exact * exact)))}
+        if with_control:
+            out["control_bf16_state"] = error(state_dtype=jnp.bfloat16)
+        return out
+
+    reference_grad = jax.jit(jax.value_and_grad(lambda p, b: ref.loss(p, b, fields)))
+
+    def one_seed(seed, with_control):
+        params = model.init_params(jax.random.PRNGKey(seed))
+        tokens = jax.random.randint(jax.random.PRNGKey(seed + 1), (1, seq), 0, cfg.vocab_size)
+        batch = model.shard_batch(dict(
+            tokens=tokens, positions=jnp.arange(seq)[None], labels=jnp.roll(tokens, -1, 1),
+            loss_mask=jnp.ones((1, seq), jnp.float32).at[:, -1].set(0.0)))
+        step = jax.jit(jax.value_and_grad(model.loss_parts_fn, has_aux=True))
+        text = step.lower(params, batch).as_text()
+        (loss, parts), grads = step(params, batch)
+        grads = jax.device_get(grads)
+        ref_loss, ref_grads = reference_grad(params, batch)
+        ref_grads = jax.device_get(ref_grads)
+        leaves = {jax.tree_util.keystr(path): rel(np.asarray(g, np.float64) - np.asarray(r, np.float64), r)
+                  for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                                          jax.tree_util.tree_leaves(ref_grads))}
+        mamba = {name: max(v for k, v in leaves.items() if "['ssm']" in k and name in k)
+                 for name in MAMBA_LEAVES}
+        row = {"seed": seed, "loss": float(loss), "reference_loss": float(ref_loss),
+               "ssm_state_abs_max": float(parts["ssm_state_abs_max"]),
+               "flash_kernels_in_step": text.count("flash_attention") > 0 or "tpu_custom_call" in text,
+               "worst_leaf_name": max(leaves, key=leaves.get), "mamba_leaves": mamba,
+               "median_leaf": float(np.median(list(leaves.values()))),
+               "core": core_errors(params, tokens, with_control)}
+        row["measures"] = {"loss": abs(row["loss"] - row["reference_loss"]),
+                           "worst_leaf": max(leaves.values()), "worst_mamba_leaf": max(mamba.values()),
+                           **{k: row["core"]["program"][k] for k in ("core_state", "core_y", "core_y_last_chunk")}}
+        row["passes"] = all(v <= LIMITS[k] for k, v in row["measures"].items())
+        if with_control:
+            control = {k: row["core"]["control_bf16_state"][k] for k in ("core_state", "core_y", "core_y_last_chunk")}
+            row["control_fails"] = [k for k, v in control.items() if v > LIMITS[k]]
+        print(json.dumps(row), flush=True)
+        return row
+
+    rows = [one_seed(seed, i == 0) for i, seed in enumerate(seeds)]
+    largest = {k: max(r["measures"][k] for r in rows) for k in LIMITS}
+    verdict = {"cell": CELL, "seeds": seeds, "device": jax.devices()[0].device_kind,
+               "largest": largest, "limits": LIMITS, "program_passes": all(r["passes"] for r in rows),
+               "control_fails": rows[0]["control_fails"],
+               "ok": all(r["passes"] for r in rows) and bool(rows[0]["control_fails"])}
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "granite4h_chip_check.json"), "w") as f:
+        json.dump({"rows": rows, "verdict": verdict}, f, indent=1)
+    print(json.dumps(verdict), flush=True)
+    return 0 if verdict["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -18,6 +18,7 @@ from galvatron_tpu.config.strategy import layer_runs, model_layer_kinds
 from galvatron_tpu.models import base as M
 from galvatron_tpu.models.glm4_moe_lite import glm4_moe_lite_config
 from galvatron_tpu.models.gpt import gpt_config
+from galvatron_tpu.models.granite_hybrid import granite_hybrid_config
 from galvatron_tpu.models.llama import llama_config
 from galvatron_tpu.models.olmoe import olmoe_config
 from galvatron_tpu.models.qwen3_next import qwen3_next_config
@@ -220,6 +221,10 @@ FAMILIES = {
         ffn_hidden=32, num_layers=4, vocab_size=256, max_seq_len=64, linear_num_key_heads=2,
         linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=8, num_experts=16,
         experts_per_token=4, compute_dtype=jnp.float32, attn_impl="xla"),
+    "granite": lambda: granite_hybrid_config(
+        "granite-4.0-h-micro", hidden_size=64, num_heads=4, num_kv_heads=2, ffn_hidden=96,
+        num_layers=10, vocab_size=256, max_seq_len=32, ssm_num_heads=4, ssm_head_dim=32,
+        ssm_state_dim=16, compute_dtype=jnp.float32, attn_impl="xla"),
     "olmoe": lambda: olmoe_config(
         "olmoe-1b-7b", num_layers=3, hidden_size=64, num_heads=4, num_kv_heads=4, ffn_hidden=32,
         vocab_size=256, max_seq_len=32, num_experts=8, experts_per_token=2,
@@ -235,13 +240,15 @@ NESTED = {
     # three linear layers to a gated attention layer, every MLP half routed beside a shared expert
     "qwen3next": {tracing.ATTN_LINEAR, tracing.ATTN_DELTA, tracing.ATTN_PROJ, tracing.MOE_SHARED,
                   *ROUTED},
+    # five Mamba-2 layers, an attention layer, four Mamba-2 layers, every MLP half dense
+    "granite": {tracing.ATTN_SSM, tracing.ATTN_SSD, tracing.ATTN_PROJ, tracing.MLP},
     "olmoe": {tracing.ATTN_PROJ, *ROUTED},
 }
 NESTED_NAME = re.compile(r"gt\.(?:attn\.[a-z]+|mlp|moe\.[a-z]+)")
 CASES = [(scan, family, guard, chunks) for chunks in (1, 2) for guard in (False, True)
          for family in ("gpt", "llama") for scan in (True, False)]
 CASES += [(True, "glm", False, 1), (False, "glm", False, 1), (True, "qwen3next", False, 1),
-          (True, "olmoe", True, 2)]
+          (True, "olmoe", True, 2), (True, "granite", False, 1)]
 
 
 @pytest.mark.parametrize("scan,family,guard,chunks", CASES, ids=[
@@ -279,7 +286,7 @@ def test_every_scope_is_in_the_compiled_steps_op_names(devices8, scan, family, g
         return [n for n in names if all(p in n for p in parts)]
 
     runs = layer_runs(hp, model_layer_kinds(cfg))
-    assert len(runs) == {"gpt": 2, "llama": 2, "glm": 3, "qwen3next": 3, "olmoe": 2}[family]
+    assert len(runs) == {"gpt": 2, "llama": 2, "glm": 3, "qwen3next": 3, "olmoe": 2, "granite": 4}[family]
     scopes = [tracing.layers_scope(k) for k in range(len(runs))]
     for scope in (tracing.EMBED, *scopes, tracing.HEAD_LOSS):
         assert under("jvp(%s)" % scope), scope  # its forward
@@ -290,9 +297,10 @@ def test_every_scope_is_in_the_compiled_steps_op_names(devices8, scan, family, g
     assert bool(under(tracing.GRAD_ACCUM)) == (chunks > 1)
     for run, scope in zip(runs, scopes):
         # recomputation is named under the run it recomputes, and only there
-        # (the delta rule's XLA form keeps a checkpoint of its own a head)
+        # (the delta rule's XLA form keeps a checkpoint of its own a head, the
+        # state-space scan one a group of heads)
         rematted = [n for n in under("transpose(jvp(%s))" % scope, "rematted_computation")
-                    if tracing.ATTN_DELTA not in n]
+                    if tracing.ATTN_DELTA not in n and tracing.ATTN_SSD not in n]
         assert bool(rematted) == bool(run.strategy.checkpoint), scope
         assert bool(under(scope + ")/while/body")) == (scan and run.length > 1), scope
     # the names are defined once, in obs/tracing.py

@@ -488,3 +488,68 @@ def test_key_padding_cross_attention_lengths_fail_loudly():
     with pytest.raises(ValueError, match="SELF-attention"):
         A.core_attention(q, k, v, causal=False, bias=bias,
                          bias_type="key_padding")
+
+
+# ------------------------------------------------- 64-wide heads (Granite-4.0-H)
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_the_kernel_at_head_dim_64_is_the_xla_form(grad):
+    """GQA 4 on 2 heads of 64 at Granite's scale (NOT 1 / sqrt(64)): the flash
+    kernels take the 64-wide heads as they are (interpret mode), forward and
+    the three gradients against `_xla_attention` in float32."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    from galvatron_tpu.ops import attention as A
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(64), b=1, s=256, nh=4, nkv=2, hd=64)
+    scale = 0.3  # neither Granite's 0.015625 (a nearly uniform softmax) nor 1 / sqrt(64) = 0.125
+
+    def loss(impl):
+        def f(q, k, v):
+            out = A.core_attention(q, k, v, causal=True, sm_scale=scale, impl=impl)
+            return jnp.sum(jnp.sin(out)) if grad else out
+        return jax.grad(f, argnums=(0, 1, 2)) if grad else f
+
+    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
+        got = loss("flash")(q, k, v)
+        want = loss("xla")(q, k, v)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=3e-5)
+    # and the scale is the one passed, not one derived from the head's width
+    other = A.core_attention(q, k, v, causal=True, impl="xla")
+    assert float(jnp.max(jnp.abs(other - (want[0] if grad else want)))) > 1e-3 or grad
+
+
+def test_auto_dispatch_takes_the_kernel_at_head_dim_64_on_a_tpu_and_says_a_fallback_once(caplog):
+    """On a TPU `impl="auto"` no longer sends 64-wide heads to XLA's float32
+    (b, nh, s, s) logits; what still falls back at a tileable length (heads of
+    32) is logged, once a shape, with what it costs."""
+    import logging
+    import unittest.mock as mock
+
+    from galvatron_tpu.ops import attention as A
+
+    calls = []
+
+    def spy(q_, k_, v_, **kw):
+        calls.append((q_.shape[-1], kw["sm_scale"]))
+        return A._xla_attention(q_, k_, v_, causal=kw["causal"], sm_scale=kw["sm_scale"])
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(5), b=1, s=256, nh=4, nkv=2, hd=64)
+    narrow = _rand_qkv(jax.random.PRNGKey(6), b=1, s=256, nh=4, hd=32)
+    A._FALLBACKS_SAID.clear()
+    with mock.patch.object(A, "_pallas_flash", spy), \
+         mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+         caplog.at_level(logging.WARNING, logger=A.__name__):
+        out = A.core_attention(q, k, v, causal=True, sm_scale=0.015625)
+        for _ in range(2):
+            A.core_attention(*narrow, causal=True)
+    assert calls == [(64, 0.015625)]
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(A.core_attention(q, k, v, causal=True, sm_scale=0.015625, impl="xla")),
+        atol=2e-5)
+    said = [r.getMessage() for r in caplog.records if "XLA attention on a TPU" in r.getMessage()]
+    assert len(said) == 1 and "head_dim 32" in said[0] and "(1, 4, 256, 256)" in said[0]
+    # off a TPU nothing is said: the CPU's tests and the serve path fall back by design
+    caplog.clear()
+    A.core_attention(*narrow, causal=True)
+    assert not caplog.records

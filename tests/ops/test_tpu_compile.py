@@ -74,6 +74,24 @@ def test_flash_kernel_compiles_for_v5e(v5e_2x2, backward):
     assert "tpu_custom_call" in text
 
 
+def test_flash_kernel_compiles_at_granites_64_wide_heads_for_v5e(v5e_2x2):
+    """32 query heads on 8 KV heads of 64 at 4096 tokens, forward and the two
+    backward kernels, through `impl="auto"`: Mosaic takes the 64-wide heads as
+    they are, so the dispatch pads nothing and never falls to XLA's (b, nh, s,
+    s) float32 logits (2 GiB at these shapes)."""
+    one = SingleDeviceSharding(v5e_2x2[0])
+    shd = A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("x",)))
+
+    def loss(q, k, v):
+        out = A.core_attention(q, k, v, causal=True, sm_scale=0.015625, sharding=shd)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 64), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 4096, 8, 64), jnp.bfloat16, sharding=one)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3 and "f32[1,32,4096,4096]" not in text
+
+
 def test_flash_segment_id_form_compiles_for_v5e(v5e_2x2):
     """A key-padding bias rides the kernel as segment ids (forward+backward)."""
     one = SingleDeviceSharding(v5e_2x2[0])
