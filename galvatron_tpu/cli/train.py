@@ -28,7 +28,7 @@ from galvatron_tpu.cli.arguments import (
 )
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import telemetry, tracing
-from galvatron_tpu.ops import linear_attention
+from galvatron_tpu.ops import linear_attention, moe
 from galvatron_tpu.profiler.runtime import (
     RuntimeProfiler,
     compiled_step_memory_mb,
@@ -504,8 +504,10 @@ def _train(args) -> dict:
             with control.span(tracing.COMPILE):
                 t0 = time.perf_counter()
                 delta_rule_took = collections.Counter(linear_attention.TOOK)
+                moe_rows_took = collections.Counter(moe.ROWS_TOOK)
                 lowered = step_fn.lower(*step_args)
                 delta_rule_took = linear_attention.TOOK - delta_rule_took
+                moe_rows_took = moe.ROWS_TOOK - moe_rows_took
                 t1 = time.perf_counter()
                 key = _step_exec_key(model.mesh, lowered)
                 compiled = _STEP_EXECUTABLES.get(key)
@@ -544,6 +546,11 @@ def _train(args) -> dict:
                 linear_pass_kernel_layers=(
                     linear_layers * (not (delta_rule_took["conv_norm_xla"] or delta_rule_took["gated_norm_xla"]))
                     if delta_rule_took else None),
+                # the routed blocks whose rows the step moves with the Pallas
+                # row movers (`moe.rows_form`): all of them or none, the blocks
+                # being alike in width and length; absent where the model has none
+                moe_row_kernel_blocks=(cfg.routed_layers * (not moe_rows_took["xla"])
+                                       if moe_rows_took else None),
             )
             _aot["fn"] = compiled
         return _aot["fn"](*step_args)

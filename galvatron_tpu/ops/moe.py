@@ -34,7 +34,14 @@ token within one: the sort's key is `expert x tokens + t`.
 Dispatch and combine are permutations, so their transposes are gathers too
 (`_dispatch`, `_combine`), not the scatter-adds autodiff would derive, and the
 combine's backward works in expert order, where the rows and their cotangent
-live: it keeps the bf16 rows and never a float32 array of every row.
+live: it keeps the bf16 rows and never a float32 array of every row. **On a
+TPU three of a block's five moves of `k x tokens` rows are Pallas kernels that
+copy row by row with queued DMAs** (`rows_form`, "The row movers" below: the
+sums over k of the combine's forward and of the dispatch's backward, and the
+combine's backward); the XLA gathers stay as the CPU's path, the path of every
+shape the kernels do not take, the dispatch's own forward, and the tests'
+oracle. Permutations of `k x tokens` SCALARS (the inverse order, the weights
+in expert order, their gradient back in token order) are sorts (`_permuted`).
 
 A second router (`score="sigmoid"`: DeepSeek-V3's, as GLM-4.7-Flash
 configures it, `topk_method: noaux_tc`): each expert's score is the sigmoid
@@ -53,10 +60,14 @@ experts' rows alone, so its time follows the routing.
 
 from __future__ import annotations
 
+import collections
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from galvatron_tpu.obs import tracing
@@ -111,6 +122,14 @@ def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array,
     return jax.lax.ragged_dot(rows, kernels, group_sizes)
 
 
+def _permuted(values, inverse):
+    """values[index] for a permutation `index` given its INVERSE: entry i is
+    the value whose slot the inverse sends to i, so a sort of (inverse,
+    values) by the first lays them out. A TPU sorts 81920 pairs in 0.1 ms and
+    gathers or scatters as many scalars in 0.4 to 0.7 (PERF.md, PR 40)."""
+    return jax.lax.sort((inverse, values), num_keys=1)[1]
+
+
 def _k_major(x):
     """(tokens, k) -> (k x tokens,): entry j x tokens + t is x[t, j]. Columns
     laid end to end: a transpose and a reshape would be a relayout on a TPU
@@ -118,37 +137,321 @@ def _k_major(x):
     return jnp.concatenate([x[:, j] for j in range(x.shape[1])])
 
 
-@jax.custom_vjp
-def _dispatch(y, order, inv_order):
+# ---------------------------------------------------------- the row movers
+# A TPU's DMA engine moves whole (8, 128) tiles of 32-bit words, and Mosaic
+# slices an array in HBM by whole tiles only: a row of a (rows, hidden) bf16
+# array is 16 pieces of 256 B, each interleaved with its neighbour row's, and
+# cannot be copied alone. So a source of rows is first PACKED (`_pack_rows`,
+# one streaming pass): column c beside column c + hidden / 2 in one uint32, a
+# row's hidden / 2 words as `hidden / 256` sublane rows of 128: with hidden a
+# multiple of 2048 a row is whole tiles, contiguous in HBM (4 KB at 2048), and
+# one DMA moves it. The movers queue one such copy a row into a VMEM buffer,
+# a whole grid step's rows ahead of the arithmetic (the next step's copies are
+# issued between the current step's vector work), read the buffer back with a
+# sublane stride (lane tile q of 16 rows at once: the (rows, hidden) layout
+# again, so the gathered rows are never written to HBM), and unpack a word's
+# halves with a shift and a mask.
+# The three kernels are jitted and inlined: a step calls each from several
+# traces (a `custom_vjp`'s primal and its forward rule, every scanned run) and
+# is traced anew at every start; a plain function traces its kernel's body
+# each time, 0.1 s a call here and 0.3 on a chip's host, where jit's cache
+# hands the one jaxpr of a shape to every site, under the site's own scope.
+# The tiles are arguments so that they are in its key.
+ROWS_BACK_TILE = 64  # tokens a grid step of `moe_rows_back`: k x 64 copies in flight
+ROWS_OUT_TILE = 512  # assignments a grid step of `moe_rows_out`
+PACK_TILE = 512  # rows a grid step of `moe_rows_pack`
+# the most a block may be for the movers to take it: every assignment's index
+# is prefetched into SMEM, 1 MiB on a v5e, of which these are three quarters;
+# hidden 8192 does not fit the packing pass's scoped VMEM, and nothing between
+# was measured (tests/ops/test_tpu_compile.py compiles the kernels AT the bounds)
+ROWS_MAX_ASSIGNMENTS = 196608
+ROWS_MAX_HIDDEN = 4096
+_GROUP = 16  # rows the arithmetic takes at a time: a bf16 tile's sublanes
+_LANES = 128
+_HIGH = 0xFFFF0000
+# how many routed blocks took which form of the row movers since the process
+# began, counted as they are traced: the trainer's compile report reads the
+# difference (as `linear_attention.TOOK`)
+ROWS_TOOK = collections.Counter()
+
+
+def rows_form(on_tpu: bool, dtype, hidden: int, tokens: int, k: int) -> str:
+    """"kernel" where the movers take a block's shape: on a TPU, bf16 rows
+    that pack into whole tiles (hidden a multiple of 2048), whole grid steps
+    of tokens and of assignments, no more of either than the kernels hold.
+    "xla" everywhere else: the CPU, float32 rows (8 KB: the packing is
+    bf16's), every other width and length."""
+    takes = (on_tpu and dtype == jnp.bfloat16 and hidden % (16 * _LANES) == 0
+             and hidden <= ROWS_MAX_HIDDEN and k * tokens <= ROWS_MAX_ASSIGNMENTS
+             and tokens % ROWS_BACK_TILE == 0 and (k * tokens) % ROWS_OUT_TILE == 0
+             and tokens % PACK_TILE == 0)
+    return "kernel" if takes else "xla"
+
+
+def _words(x):
+    """bf16 -> uint32 with the value's 16 bits in the high half."""
+    return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+
+
+def _halves(word):
+    """A packed word -> its two bf16 values as float32, exactly."""
+    return (jax.lax.bitcast_convert_type(word << 16, jnp.float32),
+            jax.lax.bitcast_convert_type(word & jnp.uint32(_HIGH), jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnames="tile", inline=True)
+def _pack_rows(x: jax.Array, tile: int) -> jax.Array:
+    """(R, H) bf16 -> (R x H / 256, 128) uint32: row r is sublane rows
+    r x H / 256 onward, word (q, l) of it holds column q x 128 + l (low half)
+    and column H / 2 + q x 128 + l (high half). `tile` (`PACK_TILE`): rows a
+    grid step."""
+    rows, hidden = x.shape
+    sub, half = hidden // (2 * _LANES), hidden // 2
+    tile = min(tile, rows)
+
+    def kernel(x_ref, out_ref):
+        for q in range(sub):
+            at = q * _LANES
+            low = _words(x_ref[:, at:at + _LANES]) >> 16
+            high = _words(x_ref[:, half + at:half + at + _LANES]) & jnp.uint32(_HIGH)
+            out_ref[pl.ds(q, tile, stride=sub), :] = high | low
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((rows * sub, _LANES), jnp.uint32),
+        grid=(rows // tile,),
+        in_specs=[pl.BlockSpec((tile, hidden), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((tile * sub, _LANES), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        name="moe_rows_pack")(x)
+
+
+def _mover(index_ref, src_ref, buf, sem, *, tile: int, sub: int, slabs: int = 1, stride: int = 0):
+    """What the two movers share: `fetch(step, slot, first, count)` queues the
+    copies of rows [first, first + count) of a grid step's tile into `slot`,
+    slab by slab (slab j reads its index at `j x stride + step x tile + row`),
+    a group's 16 descriptors a turn of ONE loop. Unrolled, the k x 16
+    descriptors of a group were most of the kernels' text: they doubled the
+    seconds a routed step takes to trace and lower, which a warm start pays
+    every time. A site's call with a turn of 8 reads 4 % slower than with one
+    of 16, and that 1 to 5 % slower than unrolled (PERF.md, PR 40).
+    `wait(slot)` blocks until a whole tile has landed."""
+    def fetch(step, slot, first=0, count=tile):
+        turns = count // _GROUP
+
+        def group(n, carry):
+            j, row = n // turns, first + n % turns * _GROUP
+            at = j * stride + step * tile + row
+            for r in range(_GROUP):
+                src = pl.multiple_of(index_ref[at + r] * sub, sub)
+                pltpu.make_async_copy(
+                    src_ref.at[pl.ds(src, sub)],
+                    buf.at[slot, j, pl.ds(pl.multiple_of((row + r) * sub, sub), sub)],
+                    sem.at[slot]).start()
+            return carry
+
+        jax.lax.fori_loop(0, slabs * turns, group, 0)
+
+    def wait(slot):
+        def slab(j, carry):
+            pltpu.make_async_copy(src_ref.at[pl.ds(0, tile * sub)], buf.at[slot, j],
+                                  sem.at[slot]).wait()
+            return carry
+
+        jax.lax.fori_loop(0, slabs, slab, 0)
+
+    return fetch, wait
+
+
+def _pipelined(fetch, wait, steps: int, tile: int, work):
+    """A grid step: the rows of this step's tile were queued a step ago (the
+    first step queues its own); wait for them, then for each group of rows
+    queue the NEXT step's copies of that group and do this step's `work(slot,
+    first row)` on it, so the scalar core's descriptors and the vector work
+    share the instruction stream. The last step queues the first tile again
+    (no branch in the loop) and drains it."""
+    i = pl.program_id(0)
+    slot = i % 2
+    ahead = jnp.where(i + 1 < steps, i + 1, 0)
+
+    @pl.when(i == 0)
+    def _():
+        fetch(0, 0)
+
+    wait(slot)
+
+    def group(g, carry):
+        first = pl.multiple_of(g * _GROUP, _GROUP)
+        fetch(ahead, 1 - slot, first, _GROUP)
+        work(slot, first)
+        return carry
+
+    jax.lax.fori_loop(0, tile // _GROUP, group, 0)
+
+    @pl.when(i == steps - 1)
+    def _():
+        wait(1 - slot)
+
+
+@functools.partial(jax.jit, static_argnames=("tokens", "hidden", "dtype", "tile"), inline=True)
+def _rows_back(packed: jax.Array, inv_order: jax.Array, weights: Optional[jax.Array],
+               tokens: int, hidden: int, dtype, tile: int) -> jax.Array:
+    """`_sum_over_k` as a kernel (`moe_rows_back`): packed rows in expert
+    order -> (tokens, hidden). A grid step is a tile of `tile` tokens
+    (`ROWS_BACK_TILE`); token t's k rows `inv_order[j x tokens + t]` arrive by
+    DMA, slab j of the buffer each, and are summed in float32 in that order of
+    j (times `weights[t, j]`), rounded once."""
+    k = inv_order.shape[0] // tokens
+    sub, half = hidden // (2 * _LANES), hidden // 2
+    steps = tokens // tile
+
+    def kernel(inv_ref, src_ref, *refs):
+        w_ref = refs[0] if weights is not None else None
+        out_ref, buf, sem = refs[-3:]
+        fetch, wait = _mover(inv_ref, src_ref, buf, sem, tile=tile, sub=sub, slabs=k, stride=tokens)
+
+        def work(slot, first):
+            rows = pl.ds(first, _GROUP)
+            if w_ref is not None:
+                w = [jnp.broadcast_to(w_ref[rows, j:j + 1], (_GROUP, _LANES)) for j in range(k)]
+
+            def lane_tile(q, carry):
+                low = high = None
+                for j in range(k):
+                    lo, hi = _halves(buf[slot, j, pl.ds(first * sub + q, _GROUP, stride=sub), :])
+                    if w_ref is not None:
+                        lo, hi = lo * w[j], hi * w[j]
+                    low = lo if low is None else lo + low
+                    high = hi if high is None else hi + high
+                at = pl.multiple_of(q * _LANES, _LANES)
+                out_ref[rows, pl.ds(at, _LANES)] = low.astype(dtype)
+                out_ref[rows, pl.ds(pl.multiple_of(half + at, _LANES), _LANES)] = high.astype(dtype)
+                return carry
+
+            jax.lax.fori_loop(0, sub, lane_tile, 0)  # unrolled: 1 to 3 % faster, a second more to trace a step
+
+        _pipelined(fetch, wait, steps, tile, work)
+
+    operands = (inv_order, packed) + (() if weights is None else (weights,))
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    if weights is not None:
+        in_specs.append(pl.BlockSpec((tile, k), lambda i, inv: (i, 0)))
+    need = 2 * k * tile * hidden * 2 + 4 * tile * hidden * 2
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((tokens, hidden), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(steps,), in_specs=in_specs,
+            out_specs=pl.BlockSpec((tile, hidden), lambda i, inv: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((2, k, tile * sub, _LANES), jnp.uint32),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=need + (8 << 20)),
+        name="moe_rows_back")(*operands)
+
+
+def _lanes_to_sublanes(row):
+    """(1, 128) -> (128, 128), entry (r, c) = row[0, r]: a row of per-row
+    scalars as the column the vector unit multiplies by, through the
+    transpose unit."""
+    return jnp.broadcast_to(row, (_LANES, _LANES)).T
+
+
+@functools.partial(jax.jit, static_argnames="tile", inline=True)
+def _rows_out(packed: jax.Array, token_of: jax.Array, out: jax.Array, w: jax.Array, tile: int):
+    """The combine's backward in one pass (`moe_rows_out`): packed cotangent
+    rows in token order, `out` the block's rows in expert order and `w` their
+    weights (float32) -> `(g x w)` in the rows' dtype and `sum(out x g)` a row
+    in float32, where g, row i, is the source's row `token_of[i]`; `tile`
+    (`ROWS_OUT_TILE`) assignments a grid step. The gathered rows themselves
+    never leave VMEM. (The dispatch's forward, the same move with no
+    arithmetic, stays XLA's gather: in the step its 33 MB source sits in fast
+    memory and XLA is the faster; `scripts/moe_rows_sweep.py` keeps that form
+    of the kernel to measure it; PERF.md, PR 40.)"""
+    count, hidden = out.shape
+    dtype = out.dtype
+    sub, half = hidden // (2 * _LANES), hidden // 2
+    steps, blocks = count // tile, tile // _LANES
+
+    def kernel(tok_ref, src_ref, out_ref, w_ref, rows_ref, sums_ref, buf, sem, w_col, sums_col):
+        fetch, wait = _mover(tok_ref, src_ref, buf, sem, tile=tile, sub=sub)
+        for b in range(blocks):
+            at = b * _LANES
+            w_col[at:at + _LANES, :] = _lanes_to_sublanes(w_ref[:, at:at + _LANES])
+
+        def work(slot, first):
+            rows = pl.ds(first, _GROUP)
+            partial = None  # a row's products, lane by lane: (16, 128) partial sums
+            weight = w_col[rows, :]
+            for q in range(sub):
+                lo, hi = _halves(buf[slot, 0, pl.ds(first * sub + q, _GROUP, stride=sub), :])
+                at = q * _LANES
+                products = (out_ref[rows, at:at + _LANES].astype(jnp.float32) * lo
+                            + out_ref[rows, half + at:half + at + _LANES].astype(jnp.float32) * hi)
+                partial = products if partial is None else partial + products
+                rows_ref[rows, at:at + _LANES] = (lo * weight).astype(dtype)
+                rows_ref[rows, half + at:half + at + _LANES] = (hi * weight).astype(dtype)
+            sums_col[rows, :] = partial
+
+        _pipelined(fetch, wait, steps, tile, work)
+        # a row's 128 partial sums added up, 128 rows' sums laid along the lanes
+        for b in range(blocks):
+            at = b * _LANES
+            sums_ref[:, at:at + _LANES] = jnp.sum(sums_col[at:at + _LANES, :].T, axis=0, keepdims=True)
+
+    tiles = pl.BlockSpec((tile, hidden), lambda i, tok: (i, 0))
+    a_row = pl.BlockSpec((None, 1, tile), lambda i, tok: (i, 0, 0))
+    d_out, sums = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((count, hidden), dtype),
+                   jax.ShapeDtypeStruct((steps, 1, tile), jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(steps,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), tiles, a_row], out_specs=(tiles, a_row),
+            scratch_shapes=[pltpu.VMEM((2, 1, tile * sub, _LANES), jnp.uint32),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((tile, _LANES), jnp.float32),
+                            pltpu.VMEM((tile, _LANES), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=8 * tile * hidden * 2 + (8 << 20)),
+        name="moe_rows_out")(token_of, packed, out, w.reshape(steps, 1, tile))
+    return d_out, sums.reshape(count)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(form, y, order, inv_order):
     """Row order[i] % tokens of y, for every assignment i in expert order:
     each token's row k times over. The cotangent of token t is the sum of its
     k assignments' cotangents, gathered back into token order and summed over
-    the major axis."""
+    the major axis. `form` (`rows_form`): the XLA gathers or the row movers."""
     return y[order % y.shape[0]]
 
 
-def _dispatch_fwd(y, order, inv_order):
-    return _dispatch(y, order, inv_order), (inv_order, y.shape[0])
+def _dispatch_fwd(form, y, order, inv_order):
+    return _dispatch(form, y, order, inv_order), (inv_order, y.shape[0])
 
 
-def _dispatch_bwd(res, g):
+def _dispatch_bwd(form, res, g):
     inv_order, tokens = res
-    return _sum_over_k(g, inv_order, tokens, None), None, None
+    return _sum_over_k(form, g, inv_order, tokens, None), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _sum_over_k(rows, inv_order, tokens, weights):
+def _sum_over_k(form, rows, inv_order, tokens, weights):
     """sum_j rows[inv_order[j x tokens + t]] (x weights[t, j]) in float32, j
     = 0 .. k-1 in that order, rounded once to the rows' dtype: (k x tokens,
-    H) rows in expert order -> (tokens, H). One gather into token order, then
+    H) rows in expert order -> (tokens, H). The kernel form: `_rows_back`.
+    The XLA form: one gather into token order, then
     its k slabs of `tokens` rows as slices, and no reshape between the gather
     and the sum: a reshape there the TPU compiler moves off the gather and
     then fuses nothing across (a float32 copy of every row, written and read
     again). A gather a slab instead: 1 % faster at k = 4 in the one routed
     cell, 1 % slower at k = 8 in the other and 2 % more memory there (PERF.md,
     PR 34)."""
+    if form == "kernel":
+        return _rows_back(_pack_rows(rows, PACK_TILE), inv_order, weights, tokens, rows.shape[1],
+                          rows.dtype, ROWS_BACK_TILE)
     total = None
     rows = rows[inv_order]
     for j in range(inv_order.shape[0] // tokens):
@@ -159,27 +462,31 @@ def _sum_over_k(rows, inv_order, tokens, weights):
     return total.astype(rows.dtype)
 
 
-@jax.custom_vjp
-def _combine(out, weights, order, inv_order):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(form, out, weights, order, inv_order):
     """(k x tokens, H) rows in expert order, float32 (tokens, k) weights ->
     (tokens, H): token t's k rows, weighted and summed in float32."""
-    return _sum_over_k(out, inv_order, weights.shape[0], weights)
+    return _sum_over_k(form, out, inv_order, weights.shape[0], weights)
 
 
-def _combine_fwd(out, weights, order, inv_order):
-    return _combine(out, weights, order, inv_order), (out, weights, order, inv_order)
+def _combine_fwd(form, out, weights, order, inv_order):
+    return _combine(form, out, weights, order, inv_order), (out, weights, order, inv_order)
 
 
-def _combine_bwd(res, g):
+def _combine_bwd(form, res, g):
     # in EXPERT order, where the rows and their cotangent live: the token's
     # cotangent gathered to each of its assignments (as `_dispatch` gathers
     # the token's row), then one pass over it and the rows
     out, weights, order, inv_order = res
     tokens, k = weights.shape
-    g = g[order % tokens].astype(jnp.float32)
-    w = _k_major(weights)[order]
-    d_out = (g * w[:, None]).astype(out.dtype)
-    d_w = jnp.sum(out.astype(jnp.float32) * g, axis=-1)[inv_order]
+    w = _permuted(_k_major(weights), inv_order)  # = _k_major(weights)[order]
+    if form == "kernel":
+        d_out, d_w = _rows_out(_pack_rows(g, PACK_TILE), order % tokens, out, w, ROWS_OUT_TILE)
+    else:
+        g = g[order % tokens].astype(jnp.float32)
+        d_out = (g * w[:, None]).astype(out.dtype)
+        d_w = jnp.sum(out.astype(jnp.float32) * g, axis=-1)
+    d_w = _permuted(d_w, order)  # = d_w[inv_order]
     d_w = jnp.stack([d_w[j * tokens:(j + 1) * tokens] for j in range(k)], axis=1)
     return d_out, d_w, None, None
 
@@ -200,6 +507,8 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
     axes the router's statistics are summed over (the batch's)."""
     tokens = y.shape[0]
     num_experts = router_kernel.shape[-1]
+    form = rows_form(on_tpu and y.dtype == dtype, y.dtype, y.shape[1], tokens, k)
+    ROWS_TOOK[form] += 1
     with jax.named_scope(tracing.MOE_ROUTER):
         logits = router_logits(y, router_kernel)
         if score == "softmax":
@@ -225,10 +534,10 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
         slot = jnp.arange(k * tokens, dtype=jnp.int32)
         # a token's k experts are distinct, so the keys are: by expert, by token within one
         order = jnp.argsort(flat * tokens + slot % tokens).astype(jnp.int32)
-        inv_order = jnp.zeros_like(order).at[order].set(slot, unique_indices=True)
+        inv_order = _permuted(slot, order)
         counts = jnp.sum(flat[:, None] == jnp.arange(num_experts, dtype=flat.dtype),
                          axis=0, dtype=jnp.int32)
-        rows = _dispatch(y, order, inv_order)  # (T*k, H), sorted by expert
+        rows = _dispatch(form, y, order, inv_order)  # (T*k, H), sorted by expert
     share = {} if held is None else {"first_group": held[0]}
     with jax.named_scope(tracing.MOE_EXPERTS):
         with jax.named_scope(tracing.MOE_GMM_IN):
@@ -237,7 +546,7 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
         with jax.named_scope(tracing.MOE_GMM_OUT):
             out = grouped_matmul(mid, wo.astype(dtype), counts, on_tpu, **share)
     with jax.named_scope(tracing.MOE_COMBINE):
-        out = _combine(out, weights, order, inv_order).astype(dtype)
+        out = _combine(form, out, weights, order, inv_order).astype(dtype)
     with jax.named_scope(tracing.MOE_ROUTER):
         total = jnp.float32(tokens)
         counts_f = counts.astype(jnp.float32)

@@ -508,6 +508,80 @@ def test_the_routed_block_keeps_k_out_of_the_tiles_on_v5e(held_share, k):
     assert not offenders, "\n".join(offenders)
 
 
+def _scope(op_name):
+    """The innermost `gt.` scope of an op's name."""
+    return re.findall(r"gt\.[a-z_.]+", op_name)[-1]
+
+
+def test_the_routed_blocks_rows_move_by_dma_on_v5e(held_share):
+    """PR 40: on a TPU, at bf16 rows of 2048 and whole grid steps, the sum
+    over k of the combine's forward and of the dispatch's backward and the
+    combine's backward are the row movers (`ops/moe.rows_form`): under
+    `gt.moe.combine` and `gt.moe.dispatch` the step has their custom calls,
+    each fed by a packing pass, and XLA gathers (k x tokens, hidden) rows
+    ONCE, in the dispatch's own forward, whose small source it keeps in fast
+    memory (PERF.md, PR 40: the sweep)."""
+    from galvatron_tpu.obs import tracing
+
+    text = held_share(4)[2]
+    entry = text[text.index("ENTRY "):]
+    calls = dict.fromkeys(("moe_rows_pack", "moe_rows_back", "moe_rows_out"), ())
+    for line in entry.splitlines():
+        found = re.search(r'custom_call_target="tpu_custom_call".*op_name="([^"]*)/(moe_rows_\w+)/pallas_call"', line)
+        if found:
+            calls[found.group(2)] += (found.group(1),)
+    combine, dispatch = tracing.MOE_COMBINE, tracing.MOE_DISPATCH
+    assert sorted(_scope(op) for op in calls["moe_rows_back"]) == [combine, dispatch], calls
+    assert [_scope(op) for op in calls["moe_rows_out"]] == [combine], calls
+    assert sorted(_scope(op) for op in calls["moe_rows_pack"]) == [combine, combine, dispatch], calls
+    every_row = r"bf16\[%d,%d\]" % (4 * GLM_TOKENS, GLM_H)
+    gathers = [line for line in text.splitlines()
+               if re.search(r"= %s\S* gather\(" % every_row, line)
+               and re.search(r'op_name="[^"]*(%s|%s)' % (re.escape(combine), re.escape(dispatch)), line)]
+    assert len(gathers) == 1 and 'op_name="jit(loss)/jvp(%s)/gather"' % dispatch in gathers[0], gathers
+
+
+def _mover_calls(k, tokens, hidden, one):
+    """The three row movers alone, jitted, and their operands at a block of
+    `tokens` x `k` assignments of `hidden` bf16."""
+    from galvatron_tpu.ops import moe
+
+    rows, words = k * tokens, hidden // 256
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    return {
+        "moe_rows_pack": (lambda x: moe._pack_rows(x, moe.PACK_TILE), shaped((rows, hidden), jnp.bfloat16)),
+        "moe_rows_back": (lambda packed, inv, w: moe._rows_back(packed, inv, w, tokens, hidden, jnp.bfloat16,
+                                                                 moe.ROWS_BACK_TILE),
+                          shaped((rows * words, 128), jnp.uint32), shaped((rows,), jnp.int32),
+                          shaped((tokens, k), jnp.float32)),
+        "moe_rows_out": (lambda *operands: moe._rows_out(*operands, moe.ROWS_OUT_TILE), shaped((tokens * words, 128), jnp.uint32), shaped((rows,), jnp.int32),
+                         shaped((rows, hidden), jnp.bfloat16), shaped((rows,), jnp.float32)),
+    }
+
+
+def test_the_row_movers_compile_at_the_largest_block_they_take_on_v5e(v5e_2x2):
+    """`ops/moe.rows_form` has upper bounds, and they are what Mosaic was
+    seen to take: every assignment's index is prefetched into SMEM (1 MiB on
+    a v5e), so at `ROWS_MAX_ASSIGNMENTS` x `ROWS_MAX_HIDDEN` the three kernels
+    compile, and a block a third longer (32768 tokens x 8: all of SMEM) is
+    refused BY THE COMPILER, which is why `rows_form` hands it to XLA, as the
+    parent did, before it gets there."""
+    from galvatron_tpu.ops import moe
+
+    one, bf16 = SingleDeviceSharding(v5e_2x2[0]), jnp.bfloat16
+    k, hidden = 8, moe.ROWS_MAX_HIDDEN
+    tokens = moe.ROWS_MAX_ASSIGNMENTS // k
+    assert moe.rows_form(True, bf16, hidden, tokens, k) == "kernel"
+    for name, (fn, *operands) in _mover_calls(k, tokens, hidden, one).items():
+        assert "tpu_custom_call" in jax.jit(fn).lower(*operands).compile().as_text(), name
+    longer = 32768
+    assert moe.rows_form(True, bf16, hidden, longer, k) == "xla"
+    assert moe.rows_form(True, bf16, 2 * hidden, tokens, k) == "xla"
+    fn, *operands = _mover_calls(k, longer, hidden, one)["moe_rows_back"]
+    with pytest.raises(Exception, match="smem"):
+        jax.jit(fn).lower(*operands).compile()
+
+
 def _count_instructions(hlo):
     return len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", hlo, re.M))
 
