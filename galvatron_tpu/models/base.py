@@ -36,8 +36,8 @@ from galvatron_tpu.config.strategy import (
 )
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding, core_attention
-from galvatron_tpu.ops.linear_attention import (Heads, causal_conv, gated_delta_rule, kernel_mixer,
-                                                mixer_form)
+from galvatron_tpu.ops.linear_attention import (Heads, causal_conv, gated_delta_rule, kda_rule,
+                                                kernel_mixer, mixer_form)
 from galvatron_tpu.ops.moe import moe_ffn, swiglu
 from galvatron_tpu.ops.norms import layer_norm, rms_norm
 from galvatron_tpu.ops.ssd import ssd_scan
@@ -100,7 +100,11 @@ class TransformerConfig:
     # latent attention (MLA): q and k/v are projected down to a low rank,
     # normed there and projected up a head; a head's q and k are `qk_nope`
     # dims without positions beside `qk_rope` rotated ones, and the rotated
-    # half of k is ONE vector shared by all heads. head_dim = qk_nope + qk_rope
+    # half of k is ONE vector shared by all heads. `q_lora_rank` 0: q is
+    # projected a head straight from the hidden state (Kimi-Linear), and
+    # `position_type` "none" leaves the `qk_rope` dims unrotated. `head_dim` is
+    # the width of the ONE attention call, qk_nope + qk_rope or wider: q and k
+    # (at 1 / sqrt(qk_nope + qk_rope)) and a narrower v are padded to it with zeros
     q_lora_rank: int = 0
     kv_lora_rank: int = 0  # > 0: latent attention
     qk_nope_head_dim: int = 0
@@ -140,8 +144,10 @@ class TransformerConfig:
     # --- what Granite-4.0-H's published config adds (granitemoehybrid):
     # Mamba-2 state-space layers (`ssm_mixer`, ops/ssd.py) among layers of
     # softmax attention without positions, and four multipliers ---
-    # the token mixer of each layer in HF's words, "mamba" (the mixer "ssm")
-    # or "attention", where the pattern is a LIST (HF `layer_types`) and no
+    # the token mixer of each layer in HF's words, "mamba" (the mixer "ssm"),
+    # "kda" (Kimi Delta Attention, `kda_mixer`: its heads and convolution are
+    # the `linear_*` fields above) or "attention", where the pattern is a LIST
+    # (HF `layer_types`; Kimi-Linear's two lists of layer numbers) and no
     # interval says it. A model cut in depth runs the list's first `num_layers`
     # entries, so the published list may stay whole
     layer_types: Optional[List[str]] = None
@@ -166,15 +172,17 @@ class TransformerConfig:
         if self.ffn_hidden is None:
             self.ffn_hidden = 4 * self.hidden_size
         if self.latent_attention:
+            widest = max(self.qk_nope_head_dim + self.qk_rope_head_dim, self.v_head_dim)
             if self.head_dim is None:
-                self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
-            if not (self.head_dim == self.qk_nope_head_dim + self.qk_rope_head_dim
-                    == self.v_head_dim and self.q_lora_rank > 0):
+                self.head_dim = widest
+            if (self.head_dim < widest or self.q_lora_rank < 0
+                    or min(self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim) < 1):
                 raise ValueError(
-                    "latent attention runs as ONE attention call of equal q/k and v head "
-                    "dims: head_dim %r = qk_nope %d + qk_rope %d = v_head_dim %d is asked, "
-                    "and q_lora_rank > 0" % (self.head_dim, self.qk_nope_head_dim,
-                                             self.qk_rope_head_dim, self.v_head_dim))
+                    "latent attention runs as ONE attention call at head_dim, to which q and k "
+                    "(qk_nope + qk_rope dims) and v are padded: head_dim %r >= qk_nope %d + "
+                    "qk_rope %d and >= v_head_dim %d is asked, each of the three 1 or more, and "
+                    "q_lora_rank %d >= 0" % (self.head_dim, self.qk_nope_head_dim,
+                                             self.qk_rope_head_dim, self.v_head_dim, self.q_lora_rank))
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.num_heads
         if self.mtp_layers not in (0, 1):
@@ -183,33 +191,35 @@ class TransformerConfig:
         if self.qk_norm not in (False, True, "head"):
             raise ValueError("qk_norm=%r: False, True (the whole projection) or \"head\""
                              % (self.qk_norm,))
-        if self.full_attention_interval or self.mixer == "linear":
-            heads = (self.linear_num_key_heads, self.linear_num_value_heads)
-            if (min(heads + (self.linear_key_head_dim, self.linear_value_head_dim,
-                             self.linear_conv_kernel)) < 1 or heads[1] % heads[0]
-                    or self.mtp_layers or self.latent_attention):
-                raise ValueError(
-                    "linear-attention layers (full_attention_interval=%d) want linear_num_key_heads "
-                    "dividing linear_num_value_heads, head dims and a convolution kernel of 1 or "
-                    "more, and neither latent attention nor a multi-token-prediction module; got "
-                    "heads %r, dims (%d, %d), kernel %d" % (
-                        self.full_attention_interval, heads, self.linear_key_head_dim,
-                        self.linear_value_head_dim, self.linear_conv_kernel))
         if self.layer_types is not None:
             self.layer_types = list(self.layer_types)
             if (len(self.layer_types) < self.num_layers or self.full_attention_interval
-                    or set(self.layer_types) - {"mamba", "attention"}):
+                    or set(self.layer_types) - {"mamba", "kda", "attention"}):
                 raise ValueError(
-                    "layer_types names the mixer, \"mamba\" or \"attention\", of each of "
+                    "layer_types names the mixer, \"mamba\", \"kda\" or \"attention\", of each of "
                     "the %d layers (or more: the first so many are run), and no "
                     "full_attention_interval beside it; got %r" % (self.num_layers, self.layer_types))
+        kda = self.mixer == "kda" or "kda" in (self.mixers() or ())
+        if self.full_attention_interval or self.mixer == "linear" or kda:
+            # (the module after the stack takes a softmax layer's outputs: `mtp_logits`)
+            heads = (self.linear_num_key_heads, self.linear_num_value_heads)
+            if (min(heads + (self.linear_key_head_dim, self.linear_value_head_dim,
+                             self.linear_conv_kernel)) < 1 or heads[1] % heads[0]
+                    or self.mtp_layers or (kda and heads[0] != heads[1])):
+                raise ValueError(
+                    "linear-attention layers (full_attention_interval=%d, or layer_types naming "
+                    "\"kda\") want linear_num_key_heads dividing linear_num_value_heads (equal "
+                    "under \"kda\"), head dims and a convolution kernel of 1 or more, and no "
+                    "multi-token-prediction module; got heads %r, dims (%d, %d), kernel %d" % (
+                        self.full_attention_interval, heads, self.linear_key_head_dim,
+                        self.linear_value_head_dim, self.linear_conv_kernel))
         if self.mixer == "ssm" or "ssm" in (self.mixers() or ()):
             if (min(self.ssm_num_heads, self.ssm_head_dim, self.ssm_state_dim, self.ssm_conv_kernel) < 1
-                    or self.routed or self.mtp_layers or self.latent_attention):
+                    or self.routed or self.mtp_layers):
                 raise ValueError(
                     "state-space layers want ssm_num_heads, ssm_head_dim, ssm_state_dim and a "
-                    "convolution kernel of 1 or more, a dense MLP half, and neither latent attention "
-                    "nor a multi-token-prediction module; got heads %d x %d, state %d, kernel %d"
+                    "convolution kernel of 1 or more, a dense MLP half and no "
+                    "multi-token-prediction module; got heads %d x %d, state %d, kernel %d"
                     % (self.ssm_num_heads, self.ssm_head_dim, self.ssm_state_dim, self.ssm_conv_kernel))
         if self.input_type == "patches":
             n_patches = (self.image_size // self.patch_size) ** 2
@@ -245,7 +255,7 @@ class TransformerConfig:
         """The kind of each layer, what `config/strategy.layer_runs` splits
         runs on beside the layout. A kind names the layer's two halves: its
         MLP half, "dense" or "routed", after its token mixer where that is
-        not softmax attention ("linear.routed", "ssm.dense": `MIXERS`). Which
+        not softmax attention ("linear.routed", "ssm.dense", "kda.routed": `MIXERS`). Which
         layers attend is said by `full_attention_interval` (every so many)
         or, layer by layer, by the list `layer_types`."""
         if not self.routed:
@@ -284,8 +294,8 @@ class TransformerConfig:
         router's losses and loads, a linear or state-space mixer's counters):
         of a layer's config its own layer, of a model's config any of its
         layers."""
-        return (self.routed or self.mixer in ("linear", "ssm") or self.full_attention_interval > 0
-                or "ssm" in (self.mixers() or ()))
+        return (self.routed or self.mixer != "attention" or self.full_attention_interval > 0
+                or any(m != "attention" for m in self.mixers() or ()))
 
     @property
     def rotary_dim(self) -> int:
@@ -326,12 +336,17 @@ def _init_attention(ks, cfg: TransformerConfig) -> Params:
         # HF's names: q_a_proj, q_a_layernorm, q_b_proj, kv_a_proj_with_mqa,
         # kv_a_layernorm, kv_b_proj; the up projections head-major, so that a
         # head's [nope | rope] and [k_nope | v] split an unsharded minor dim
+        # (with no low-rank q, `q_lora_rank` 0: HF's q_proj, `wq` a head)
         ql, kvl, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        qk = cfg.qk_nope_head_dim + rope
         kq = jax.random.split(ks[0], 2)
         kkv = jax.random.split(ks[4], 2)
-        p["wq_a"] = {"kernel": _dense_init(kq[0], (h, ql), cfg.init_std, cfg.param_dtype)}
-        p["q_a_norm"] = {"scale": jnp.ones((ql,), cfg.param_dtype)}
-        p["wq_b"] = {"kernel": _dense_init(kq[1], (ql, nh, hd), cfg.init_std, cfg.param_dtype)}
+        if ql:
+            p["wq_a"] = {"kernel": _dense_init(kq[0], (h, ql), cfg.init_std, cfg.param_dtype)}
+            p["q_a_norm"] = {"scale": jnp.ones((ql,), cfg.param_dtype)}
+            p["wq_b"] = {"kernel": _dense_init(kq[1], (ql, nh, qk), cfg.init_std, cfg.param_dtype)}
+        else:
+            p["wq"] = {"kernel": _dense_init(ks[0], (h, nh, qk), cfg.init_std, cfg.param_dtype)}
         p["wkv_a"] = {"kernel": _dense_init(kkv[0], (h, kvl + rope), cfg.init_std, cfg.param_dtype)}
         p["kv_a_norm"] = {"scale": jnp.ones((kvl,), cfg.param_dtype)}
         p["wkv_b"] = {"kernel": _dense_init(
@@ -347,7 +362,8 @@ def _init_attention(ks, cfg: TransformerConfig) -> Params:
         if cfg.qkv_bias:
             p["wq"]["bias"] = jnp.zeros((nh, q_dims), cfg.param_dtype)
             p["wkv"]["bias"] = jnp.zeros((2, nkv, hd), cfg.param_dtype)
-    p["wo"] = {"kernel": _dense_init(ks[1], (nh * hd, h), _proj_std(cfg), cfg.param_dtype)}
+    out_dim = cfg.v_head_dim if cfg.latent_attention else hd  # a head's width into `wo`
+    p["wo"] = {"kernel": _dense_init(ks[1], (nh * out_dim, h), _proj_std(cfg), cfg.param_dtype)}
     if cfg.out_bias:
         p["wo"]["bias"] = jnp.zeros((h,), cfg.param_dtype)
     if cfg.qk_norm == "head":
@@ -385,6 +401,38 @@ def _init_linear(ks, cfg: TransformerConfig) -> Params:
         "A_log": jnp.log(jax.random.uniform(kgate[1], (nv,), jnp.float32, 1e-6, 16.0)),
         "dt_bias": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
         "norm": {"scale": jnp.ones((cfg.linear_value_head_dim,), cfg.param_dtype)},
+        "wout": {"kernel": _dense_init(ks[1], (value_dim, h), _proj_std(cfg), cfg.param_dtype)},
+    }}
+
+
+def _init_kda(ks, cfg: TransformerConfig) -> Params:
+    """The Kimi-Delta-Attention mixer's leaves, under `kda` (HF
+    `KimiDeltaAttention`: q_proj, k_proj, v_proj, their three conv1d,
+    f_a_proj / f_b_proj, b_proj, A_log, dt_bias, g_a_proj / g_b_proj, o_norm,
+    o_proj). The three projections are ONE kernel `wqkv` whose columns lie
+    [q | k | v], heads in order within each, and the three convolutions one
+    `conv` over those columns (on random weights, HF's three of each side by
+    side); the gate's and the output gate's low-rank pairs `wf_a`, `wf_b` and
+    `wg_a`, `wg_b` of rank d_v, no bias. The gate starts as the linear
+    mixer's does, `A_log` a head and `dt_bias` a head AND channel: exp(g)
+    spans 0.2 to 1 a token, so that state crosses chunks."""
+    h, taps, nh = cfg.hidden_size, cfg.linear_conv_kernel, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    key_dim, value_dim = nh * dk, nh * dv
+    kin = jax.random.split(ks[0], 6)
+    kgate = jax.random.split(ks[4], 3)
+    step = jnp.exp(jax.random.uniform(kgate[2], (key_dim,), jnp.float32, math.log(1e-3), math.log(0.1)))
+    dense = lambda key, shape: {"kernel": _dense_init(key, shape, cfg.init_std, cfg.param_dtype)}  # noqa: E731
+    return {"kda": {
+        "wqkv": dense(kin[0], (h, 2 * key_dim + value_dim)),
+        "wf_a": dense(kin[1], (h, dv)), "wf_b": dense(kin[2], (dv, key_dim)),
+        "wg_a": dense(kin[3], (h, dv)), "wg_b": dense(kin[4], (dv, value_dim)),
+        "wb": dense(kin[5], (h, nh)),
+        "conv": jax.random.uniform(kgate[0], (2 * key_dim + value_dim, taps), jnp.float32,
+                                   -1.0, 1.0).astype(cfg.param_dtype) / taps ** 0.5,
+        "A_log": jnp.log(jax.random.uniform(kgate[1], (nh,), jnp.float32, 1e-6, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+        "norm": {"scale": jnp.ones((dv,), cfg.param_dtype)},
         "wout": {"kernel": _dense_init(ks[1], (value_dim, h), _proj_std(cfg), cfg.param_dtype)},
     }}
 
@@ -627,18 +675,26 @@ def latent_qkv_projection(p: Params, y: jax.Array, positions: jax.Array,
         q_h = [q_nope_h | rope(q_rope_h)],  k_h = [k_nope_h | rope(kr)]
 
     The rotated half of k is one vector a token, the same for every head.
-    q/k dims (nope + rope) equal v's, so one attention call of that head_dim
-    serves, at the default scale 1/sqrt(nope + rope)."""
+    Kimi-Linear's (HF `KimiMLAAttention`) has no low-rank q (`q_lora_rank` 0:
+    q_h = y Wq_h) and no positions (`position_type` "none": q_rope_h and kr
+    enter as they are). q and k are (nope + rope) wide, v `v_head_dim`: the
+    caller pads them to the one attention call's `head_dim`."""
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     eps, theta = cfg.layernorm_eps, cfg.rope_theta
-    cq = rms_norm(_dense(y, p["wq_a"], dtype), p["q_a_norm"]["scale"], eps)
-    q = jnp.einsum("bsr,rnd->bsnd", cq, p["wq_b"]["kernel"].astype(dtype))
+    if cfg.q_lora_rank:
+        cq = rms_norm(_dense(y, p["wq_a"], dtype), p["q_a_norm"]["scale"], eps)
+        q = jnp.einsum("bsr,rnd->bsnd", cq, p["wq_b"]["kernel"].astype(dtype))
+    else:
+        q = jnp.einsum("bsh,hnd->bsnd", y, p["wq"]["kernel"].astype(dtype))
     ckv_kr = _dense(y, p["wkv_a"], dtype)
     ckv = rms_norm(ckv_kr[..., :cfg.kv_lora_rank], p["kv_a_norm"]["scale"], eps)
     kv = jnp.einsum("bsr,rnd->bsnd", ckv, p["wkv_b"]["kernel"].astype(dtype))
-    q_rope = apply_rotary(q[..., nope:], positions, theta)
-    k_rope = apply_rotary(ckv_kr[:, :, None, cfg.kv_lora_rank:], positions, theta)
-    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+    if cfg.position_type == "rope":
+        q_rope = apply_rotary(q[..., nope:], positions, theta)
+        k_rope = apply_rotary(ckv_kr[:, :, None, cfg.kv_lora_rank:], positions, theta)
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+    else:
+        k_rope = ckv_kr[:, :, None, cfg.kv_lora_rank:]
     k = jnp.concatenate(
         [kv[..., :nope], jnp.broadcast_to(k_rope, k_rope.shape[:2] + (cfg.num_heads, rope))], axis=-1)
     return q, k, kv[..., nope:]
@@ -660,14 +716,17 @@ def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional
     form, the decode engine no recurrent state, the cost models no row. And,
     alike, of Mamba-2 state-space layers (`layer_types` naming "ssm"): the
     scan's state runs along the whole sequence, the gated norm over all of a
-    layer's channels."""
+    layer's channels. And of Kimi-Delta-Attention layers (`layer_types` naming
+    "kda"): the per-channel delta rule is a recurrence as the scalar one is."""
     latent = bool(getattr(cfg, "latent_attention", False) or getattr(cfg, "mtp_layers", 0))
-    linear, ssm = _has_linear(cfg), _has_ssm(cfg)
-    if not (getattr(cfg, "routed", False) or latent or linear or ssm):
+    linear, ssm, kda = _has_linear(cfg), _has_mixer(cfg, "ssm"), _has_mixer(cfg, "kda")
+    if not (getattr(cfg, "routed", False) or latent or linear or ssm or kda):
         return None
     also = (" (nor has latent attention, MLA: kv_lora_rank > 0)" if latent else "") + (
         " (nor have linear-attention layers, full_attention_interval > 0: the delta rule's "
         "state runs along the whole sequence of all a layer's heads)" if linear else "") + (
+        " (nor have Kimi-Delta-Attention layers, layer_types naming \"kda\": the per-channel "
+        "delta rule's state runs along the whole sequence of all a layer's heads)" if kda else "") + (
         " (nor have state-space layers, layer_types naming \"ssm\": the scan's state runs along "
         "the whole sequence and the gated norm over all of a layer's channels)" if ssm else "")
     if mode == "serve":
@@ -675,12 +734,15 @@ def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional
             ", and no cache of latent attention's compressed k/v" if latent else "") + (
             ", and no recurrent state of a linear-attention layer (serve/kv_cache.py holds "
             "keys and values)" if linear else "") + (
+            ", and no recurrent state of a Kimi-Delta-Attention layer, d_k rows a head that "
+            "forget separately (serve/kv_cache.py holds keys and values)" if kda else "") + (
             ", and no convolution window or scan state of a state-space layer (serve/kv_cache.py "
             "holds keys and values)" if ssm else "")
     if (autotune or "off") != "off":
         return "autotune=%s: the re-search would price the block as dense" % autotune + (
             ", and latent attention as full-rank" if latent else "") + (
             ", and a linear-attention layer as softmax attention" if linear else "") + (
+            ", and a Kimi-Delta-Attention layer as softmax attention" if kda else "") + (
             ", and a state-space layer as softmax attention" if ssm else "")
     if hp is None:
         return None
@@ -689,6 +751,8 @@ def expert_layout_reason(cfg, hp: Optional[HybridParallelConfig], mode: Optional
             " and no multi-token-prediction module after the last" if latent else "") + (
             " and stack one kind of layer a stage, not linear-attention layers among "
             "attention layers" if linear else "") + (
+            " and stack one kind of layer a stage, not Kimi-Delta-Attention layers among "
+            "attention layers" if kda else "") + (
             " and stack one kind of layer a stage, not state-space layers among attention "
             "layers" if ssm else "")
     for i, s in enumerate(hp.layers):
@@ -711,16 +775,19 @@ def _has_linear(cfg) -> bool:
     return bool(getattr(cfg, "full_attention_interval", 0) or getattr(cfg, "mixer", "") == "linear")
 
 
-def _has_ssm(cfg) -> bool:
+def _has_mixer(cfg, name: str) -> bool:
+    """Whether a layer of the config (a model's or ONE layer's) runs this `MIXERS` key."""
     mixers = getattr(cfg, "mixers", None)
-    return getattr(cfg, "mixer", "") == "ssm" or "ssm" in ((mixers() if callable(mixers) else None) or ())
+    return getattr(cfg, "mixer", "") == name or name in ((mixers() if callable(mixers) else None) or ())
 
 
 def linear_layers_reason(cfg) -> Optional[str]:
-    """What `search` and `profile` say of a config with linear-attention or
-    state-space layers, or None for one without."""
-    if _has_ssm(cfg):
+    """What `search` and `profile` say of a config with linear-attention,
+    Kimi-Delta-Attention or state-space layers, or None for one without."""
+    if _has_mixer(cfg, "ssm"):
         return "state-space layers (layer_types naming \"ssm\") have no row in the cost models"
+    if _has_mixer(cfg, "kda"):
+        return "Kimi-Delta-Attention layers (layer_types naming \"kda\") have no row in the cost models"
     if _has_linear(cfg):
         return "linear-attention layers (full_attention_interval > 0) have no row in the cost models"
     return None
@@ -731,9 +798,9 @@ def expert_layout_diagnostic(reason: str):
     from galvatron_tpu.analysis import diagnostics as D
 
     return D.make(
-        "GLS018", "routed experts (num_experts > 0), latent attention, linear-attention or "
-        "state-space layers refused: %s; such a config runs on one chip and under dp with "
-        "ZeRO-1/2/3" % reason, key="num_experts")
+        "GLS018", "routed experts (num_experts > 0), latent attention, linear-attention, "
+        "Kimi-Delta-Attention or state-space layers refused: %s; such a config runs on one "
+        "chip and under dp with ZeRO-1/2/3" % reason, key="num_experts")
 
 
 def refuse_expert_layout(reason: str):
@@ -773,10 +840,15 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
     # one scope for everything of the mixer but the attention call: a block
     # before it and a block after it
     scope = tracing.ATTN_LATENT if cfg.latent_attention else tracing.ATTN_PROJ
-    gate = None
+    gate, sm_scale = None, cfg.attention_multiplier
     with jax.named_scope(scope):
         if cfg.latent_attention:
             q, k, v = latent_qkv_projection(p, y, pin(positions), cfg, dtype)
+            if q.shape[-1] != cfg.head_dim:  # zeros add nothing to a score
+                sm_scale = sm_scale or q.shape[-1] ** -0.5
+            # (a v padded with zeros gives zeros in the dims cut off below: exact)
+            q, k, v = (t if t.shape[-1] == cfg.head_dim else jnp.pad(
+                t, ((0, 0),) * 3 + ((0, cfg.head_dim - t.shape[-1]),)) for t in (q, k, v))
         else:
             q, k, v = qkv_projection(p, y, cfg, dtype)
             if cfg.attn_output_gate:
@@ -811,13 +883,21 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
         # the flash path may lower it to segment ids instead of falling back
         attn = core_attention(q, k, v, causal=cfg.causal, bias=attn_bias,
                               impl=cfg.attn_impl, bias_type="key_padding",
-                              sharding=attn_sharding, sm_scale=cfg.attention_multiplier)
+                              sharding=attn_sharding, sm_scale=sm_scale)
     with jax.named_scope(scope):
         if gate is not None:
             attn = attn * jax.nn.sigmoid(gate)
-        attn = attn.reshape(attn.shape[0], attn.shape[1], cfg.num_heads * cfg.head_dim)
+        if cfg.latent_attention and cfg.v_head_dim != cfg.head_dim:
+            attn = attn[..., :cfg.v_head_dim]
+        attn = attn.reshape(attn.shape[0], attn.shape[1], -1)
         o = _dense(attn, p["wo"], dtype)
     return o, kv_out, None
+
+
+def _unit(t: jax.Array) -> jax.Array:
+    """L2-normalised over a head's dims in float32, as HF's l2norm (the linear mixers' q and k)."""
+    t32 = t.astype(jnp.float32)
+    return t32 * jax.lax.rsqrt(jnp.sum(jnp.square(t32), axis=-1, keepdims=True) + 1e-6)
 
 
 def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, *,
@@ -844,10 +924,6 @@ def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
     key_dim, value_dim = nk * dk, nv * dv
     b, s, _ = y.shape
 
-    def unit(t):  # L2 over a head's dims, as HF's l2norm
-        t32 = t.astype(jnp.float32)
-        return t32 * jax.lax.rsqrt(jnp.sum(jnp.square(t32), axis=-1, keepdims=True) + 1e-6)
-
     with jax.named_scope(tracing.ATTN_LINEAR):
         qkvz = _dense(y, p["wqkvz"], dtype)
         ba = _dense(y, p["wba"], dtype).astype(jnp.float32)
@@ -863,8 +939,8 @@ def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
         with jax.named_scope(tracing.ATTN_LINEAR):
             qkv = jax.nn.silu(causal_conv(qkvz[..., :2 * key_dim + value_dim], p["conv"]))
             z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, s, nv, dv)
-            q = (unit(qkv[..., :key_dim].reshape(b, s, nk, dk)) * dk ** -0.5).astype(dtype)
-            k = unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, nk, dk)).astype(dtype)
+            q = (_unit(qkv[..., :key_dim].reshape(b, s, nk, dk)) * dk ** -0.5).astype(dtype)
+            k = _unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, nk, dk)).astype(dtype)
             v = qkv[..., 2 * key_dim:].reshape(b, s, nv, dv)
         with jax.named_scope(tracing.ATTN_DELTA):
             o, state = gated_delta_rule(q, k, v, g, beta, sharding=attn_sharding)
@@ -872,6 +948,49 @@ def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
             o = rms_norm(o.astype(jnp.float32), p["norm"]["scale"], cfg.layernorm_eps)
             o = (o * jax.nn.silu(z.astype(jnp.float32))).astype(dtype).reshape(b, s, value_dim)
     with jax.named_scope(tracing.ATTN_LINEAR):
+        out = _dense(o, p["wout"], dtype)
+        stats = {"decay_mean": jnp.mean(jnp.exp(g)), "state_abs_max": jnp.max(jnp.abs(state))}
+    return out, None, stats
+
+
+def kda_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, **_):
+    """Kimi Delta Attention on normed activations (B, S, H) (HF
+    `KimiDeltaAttention`; arXiv:2510.26692), p the layer's tree:
+
+        [q, k, v] = silu(conv(y Wqkv))                causal, depthwise, a channel
+        q, k L2-normalised a head, q / sqrt(d_k)
+        g = -exp(A_log) softplus((y Wfa) Wfb + dt_bias)   (heads, d_k) a token, float32, <= 0
+        beta = sigmoid(y Wb)
+        o = kda_rule(q, k, v, g, beta)                ops/linear_attention.py
+        out = (RMSNorm(o; w) sigmoid((y Wga) Wgb)) Wout   a head; the norm BEFORE the gate
+
+    The delta rule whose gate is a vector over the key's channels, each row of
+    a head's (d_k, d_v) state forgetting at its own rate. -> out, None, and
+    the linear mixer's counters: the mean gate `exp(g)` and the largest
+    magnitude in any head's final state. Scopes: the core under
+    `gt.attn.kda_rule`, all else under `gt.attn.kda_mixer`. No position enters. All
+    of it is XLA's: the linear mixer's Pallas passes are cut to `Wqkvz`'s
+    columns and SiLU's gate, its core's kernels to a scalar gate."""
+    p, dtype = p["kda"], cfg.compute_dtype
+    nh, dk, dv = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    key_dim = nh * dk
+    b, s, _ = y.shape
+
+    with jax.named_scope(tracing.ATTN_KDA):
+        qkv = jax.nn.silu(causal_conv(_dense(y, p["wqkv"], dtype), p["conv"]))
+        q = (_unit(qkv[..., :key_dim].reshape(b, s, nh, dk)) * dk ** -0.5).astype(dtype)
+        k = _unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, nh, dk)).astype(dtype)
+        v = qkv[..., 2 * key_dim:].reshape(b, s, nh, dv)
+        f = _dense(_dense(y, p["wf_a"], dtype), p["wf_b"], dtype).astype(jnp.float32)
+        g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+            f + p["dt_bias"].astype(jnp.float32)).reshape(b, s, nh, dk)
+        beta = jax.nn.sigmoid(_dense(y, p["wb"], dtype).astype(jnp.float32))
+        gate = _dense(_dense(y, p["wg_a"], dtype), p["wg_b"], dtype).reshape(b, s, nh, dv)
+    with jax.named_scope(tracing.ATTN_KDA_RULE):
+        o, state = kda_rule(q, k, v, g, beta)
+    with jax.named_scope(tracing.ATTN_KDA):
+        o = rms_norm(o.astype(jnp.float32), p["norm"]["scale"], cfg.layernorm_eps)
+        o = (o * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype).reshape(b, s, nh * dv)
         out = _dense(o, p["wout"], dtype)
         stats = {"decay_mean": jnp.mean(jnp.exp(g)), "state_abs_max": jnp.max(jnp.abs(state))}
     return out, None, stats
@@ -1758,9 +1877,12 @@ def _attention_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     sp: Params = {}
     if cfg.latent_attention:
         # ordinary leaves (tp is refused, GLS018): ZeRO-3 splits the input dim
-        sp["wq_a"] = {"kernel": P(z3, None)}
-        sp["q_a_norm"] = {"scale": r1}
-        sp["wq_b"] = {"kernel": P(z3, None, None)}
+        if cfg.q_lora_rank:
+            sp["wq_a"] = {"kernel": P(z3, None)}
+            sp["q_a_norm"] = {"scale": r1}
+            sp["wq_b"] = {"kernel": P(z3, None, None)}
+        else:
+            sp["wq"] = {"kernel": P(z3, None, None)}
         sp["wkv_a"] = {"kernel": P(z3, None)}
         sp["kv_a_norm"] = {"scale": r1}
         sp["wkv_b"] = {"kernel": P(z3, None, None)}
@@ -1795,6 +1917,18 @@ def _linear_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     }}
 
 
+def _kda_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
+    # ordinary leaves, as the linear mixer's
+    z3 = S._ax(axes.dp) if axes.zero3 else None
+    r1 = S.replicated_1d_spec(axes)
+    wide = {"kernel": P(z3, None)}
+    return {"kda": {
+        "wqkv": wide, "wf_a": wide, "wf_b": {"kernel": P(None, None)}, "wg_a": wide,
+        "wg_b": {"kernel": P(None, None)}, "wb": wide,
+        "conv": P(None, None), "A_log": r1, "dt_bias": r1, "norm": {"scale": r1}, "wout": wide,
+    }}
+
+
 def _ssm_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     # ordinary leaves (tp, sp, cp are refused, GLS018): ZeRO-3 splits the
     # projections' input dim; the small leaves are whole everywhere
@@ -1816,6 +1950,8 @@ MIXERS = {
                          "linear_fwd_flops_a_token", (tracing.ATTN_LINEAR, tracing.ATTN_DELTA)),
     "ssm": TokenMixer(_init_ssm, ssm_mixer, _ssm_specs,
                       "ssm_fwd_flops_a_token", (tracing.ATTN_SSM, tracing.ATTN_SSD)),
+    "kda": TokenMixer(_init_kda, kda_mixer, _kda_specs,
+                      "kda_fwd_flops_a_token", (tracing.ATTN_KDA, tracing.ATTN_KDA_RULE)),
 }
 
 

@@ -92,8 +92,18 @@ def _ensure_builtin():
     if _LOADED:
         return
     _LOADED = True
-    from galvatron_tpu.models import glm4_moe_lite, gpt, granite_hybrid, llama, olmoe, qwen3_next
+    from galvatron_tpu.models import (glm4_moe_lite, gpt, granite_hybrid, kimi_linear, llama, olmoe,
+                                      qwen3_next)
 
+    register(
+        ModelFamily(
+            name="kimi_linear",
+            config_fn=kimi_linear.kimi_linear_config,
+            meta_configs=kimi_linear.META_CONFIGS,
+            default_size="kimi-linear-48b-a3b",
+            config_from_hf=kimi_linear.kimi_linear_config_from_hf,
+        )
+    )
     register(
         ModelFamily(
             name="granite_hybrid",

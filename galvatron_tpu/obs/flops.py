@@ -67,19 +67,22 @@ def attention_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, n
                                 latent: Optional[Mapping[str, int]] = None):
     """Softmax attention: q, fused kv (GQA-scaled) and out projections (q
     twice as wide beside an output gate; latent attention's five by their
-    shapes), and scores (q k^T) + weighted sum (p v), each 2 S q_dim, half of
-    it under a causal mask."""
+    shapes, or four where q has no low rank), and scores (q k^T) + weighted
+    sum (p v), each 2 S q_dim, half of it under a causal mask; latent
+    attention's scores at its q/k width (nope + rope) and its sum at v's,
+    whatever width the one attention call pads them to."""
     q_dim = num_heads * head_dim
     if latent:
         ql, kvl = latent["q_lora_rank"], latent["kv_lora_rank"]
         nope, rope, vd = (latent["qk_nope_head_dim"], latent["qk_rope_head_dim"],
                           latent["v_head_dim"])
-        proj = (2.0 * hidden * ql + 2.0 * ql * num_heads * (nope + rope)
-                + 2.0 * hidden * (kvl + rope) + 2.0 * kvl * num_heads * (nope + vd)
+        q_proj = (2.0 * hidden * ql + 2.0 * ql * num_heads * (nope + rope) if ql
+                  else 2.0 * hidden * num_heads * (nope + rope))
+        proj = (q_proj + 2.0 * hidden * (kvl + rope) + 2.0 * kvl * num_heads * (nope + vd)
                 + 2.0 * num_heads * vd * hidden)
-    else:
-        proj = (2.0 * hidden * q_dim * (2 if gated else 1)
-                + 2.0 * hidden * (2 * num_kv_heads * head_dim) + 2.0 * q_dim * hidden)
+        return proj, 2.0 * seq_len * num_heads * ((nope + rope) + vd) * (0.5 if causal else 1.0)
+    proj = (2.0 * hidden * q_dim * (2 if gated else 1)
+            + 2.0 * hidden * (2 * num_kv_heads * head_dim) + 2.0 * q_dim * hidden)
     return proj, 2.0 * (2.0 * seq_len * q_dim) * (0.5 if causal else 1.0)
 
 
@@ -93,6 +96,22 @@ def linear_fwd_flops_a_token(*, hidden: int, num_key_heads: int, num_value_heads
     key_dim, value_dim = num_key_heads * key_head_dim, num_value_heads * value_head_dim
     proj = (2.0 * hidden * (2 * key_dim + 2 * value_dim) + 2.0 * hidden * (2 * num_value_heads)
             + 2.0 * value_dim * hidden)
+    return proj, 6.0 * num_value_heads * key_head_dim * value_head_dim
+
+
+def kda_fwd_flops_a_token(*, hidden: int, num_key_heads: int, num_value_heads: int,
+                          key_head_dim: int, value_head_dim: int):
+    """A Kimi-Delta-Attention mixer: hidden -> [q | k | v], the gate's and the
+    output gate's low-rank pairs (hidden -> d_v -> key dims, hidden -> d_v ->
+    value dims), hidden -> beta a head, value -> hidden; and the core as the
+    RECURRENCE needs it, the linear mixer's three (d_k, d_v) products a head a
+    token: a gate that is a vector scales the state's rows, which is no
+    matmul."""
+    key_dim, value_dim = num_key_heads * key_head_dim, num_value_heads * value_head_dim
+    proj = (2.0 * hidden * (2 * key_dim + value_dim)
+            + 2.0 * hidden * value_head_dim + 2.0 * value_head_dim * key_dim
+            + 2.0 * hidden * value_head_dim + 2.0 * value_head_dim * value_dim
+            + 2.0 * hidden * num_value_heads + 2.0 * value_dim * hidden)
     return proj, 6.0 * num_value_heads * key_head_dim * value_head_dim
 
 
@@ -128,6 +147,7 @@ def layer_fwd_flops(
     shared_gate: bool = False,
     linear: Optional[Mapping[str, int]] = None,
     ssm: Optional[Mapping[str, int]] = None,
+    kda: Optional[Mapping[str, int]] = None,
 ) -> float:
     """Forward model FLOPs of ONE transformer block over `tokens` tokens
     (default: one sequence). Matmul terms only (2 FLOPs per MAC); norms and
@@ -143,11 +163,14 @@ def layer_fwd_flops(
     an output gate. `linear` (num_key_heads, num_value_heads, key_head_dim,
     value_head_dim): the layer's token mixer is a gated DeltaNet, in place of
     attention; `ssm` (num_heads, head_dim, state_dim): a Mamba-2 state-space
-    mixer. `shared_gate`: the shared expert's (hidden, 1) gate."""
+    mixer; `kda` (the keys of `linear`): a Kimi-Delta-Attention mixer.
+    `shared_gate`: the shared expert's (hidden, 1) gate."""
     tokens = float(seq_len if tokens is None else tokens)
     ffn = ffn_hidden or 4 * hidden
     if ssm:
         proj, attn = ssm_fwd_flops_a_token(hidden=hidden, **ssm)
+    elif kda:
+        proj, attn = kda_fwd_flops_a_token(hidden=hidden, **kda)
     elif linear:
         proj, attn = linear_fwd_flops_a_token(hidden=hidden, **linear)
     else:
@@ -179,10 +202,11 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
     if getattr(cfg, "kv_lora_rank", 0):
         latent = {k: getattr(cfg, k) for k in (
             "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")}
-    linear = None
-    if getattr(cfg, "mixer", "attention") == "linear":
-        linear = {k: getattr(cfg, "linear_" + k) for k in (
+    linear = kda = None
+    if getattr(cfg, "mixer", "attention") in ("linear", "kda"):
+        heads = {k: getattr(cfg, "linear_" + k) for k in (
             "num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
+        linear, kda = (heads, None) if cfg.mixer == "linear" else (None, heads)
     ssm = None
     if getattr(cfg, "mixer", "attention") == "ssm":
         ssm = {k: getattr(cfg, "ssm_" + k) for k in ("num_heads", "head_dim", "state_dim")}
@@ -205,6 +229,7 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         shared_gate=bool(getattr(cfg, "shared_expert_gate", False)),
         linear=linear,
         ssm=ssm,
+        kda=kda,
     )
 
 

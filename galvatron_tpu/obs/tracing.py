@@ -84,6 +84,14 @@ ATTN_LINEAR = "gt.attn.linear"
 # projections, the convolution and its bias, dt, the gated norm)
 ATTN_SSD = "gt.attn.ssd"
 ATTN_SSM = "gt.attn.ssm"
+# a Kimi-Delta-Attention mixer (models/base.kda_mixer), inside gt.layers.r<k>,
+# in two disjoint scopes that add up to the mixer, as the linear mixer's: the
+# core (ops/linear_attention.kda_rule: the chunks' decayed products and
+# solves, the carried state, the outputs; forward, recomputed and backward)
+# and everything else of it (the projections, the convolution, the two
+# low-rank gates, the gated norm). Neither name begins the other
+ATTN_KDA_RULE = "gt.attn.kda_rule"
+ATTN_KDA = "gt.attn.kda_mixer"
 # the multi-token-prediction module, top level: its norms, the (2h, h)
 # projection and its block; its pass through the head and its cross entropy
 # run under HEAD_LOSS, beside the main ones

@@ -186,7 +186,7 @@ def _say_fallback_once(q_shape, sk: int, biased: bool, splits: bool) -> None:
     b, sq, nh, hd = q_shape
     why = ("a bias the kernel cannot take as segment ids" if biased and splits else
            "batch or heads the mesh does not divide" if not splits else
-           "head_dim %d (the kernel compiles at 64 and from 128 up)" % hd)
+           "head_dim %d (the kernel compiles at 64 and at multiples of 128)" % hd)
     logging.getLogger(__name__).warning(
         "core_attention: XLA attention on a TPU at q %s, %d keys (%s): it materialises float32 "
         "logits of (%d, %d, %d, %d), %.2f GiB a call, where the flash kernel holds a block",
@@ -258,11 +258,12 @@ def core_attention(
     splits = sharding is None or sharding.divides(q.shape[0], q.shape[2])
     if impl == "auto":
         # the kernel's blocks are whole 128-token tiles of the sequence, and
-        # Mosaic compiles its three kernels at heads 128 wide or wider and at
-        # 64 (Granite's; tests/ops/test_tpu_compile.py), nothing narrower tried
+        # Mosaic compiles its three kernels at heads of whole 128-lane tiles
+        # (the kernel refuses a wider head that is none: 192) and at 64
+        # (Granite's; tests/ops/test_tpu_compile.py), nothing narrower tried
         tileable = q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
         ok_shapes = (
-            tileable and (q.shape[3] >= 128 or q.shape[3] == 64)
+            tileable and (q.shape[3] % 128 == 0 or q.shape[3] == 64)
             and (bias is None or seg_flash_ok) and splits
         )
         # on a TPU the kernel (blocks: _flash_block_sizes) wherever the

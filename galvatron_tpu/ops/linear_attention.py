@@ -1,7 +1,8 @@
 """The gated delta rule in its chunked form, and the short causal convolution
 that comes before it: the core of a gated-DeltaNet linear-attention layer
 (Gated Delta Networks, arXiv:2412.06464; HF `modeling_qwen3_next.py`
-`torch_chunk_gated_delta_rule`).
+`torch_chunk_gated_delta_rule`), and of a Kimi-Delta-Attention layer, whose
+gate is a vector a head (the last section but one).
 
 A head carries a state `S` (d_k x d_v) along the sequence, `S_0 = 0`:
 
@@ -91,6 +92,23 @@ below the core's kernels). On the chip (PERF.md, PR 38) a layer's passes take
 ms a layer and step where XLA's elementwise passes over (tokens, heads, 128)
 views took about 8, and the two projections' matmuls, their operands and
 results plain arrays now, run at 92 % of the MXU where they ran at 55 to 65.
+
+**The per-channel rule** (`kda_rule`: Kimi Delta Attention, arXiv:2510.26692).
+The gate is a VECTOR over the key's channels, `g_t in R^{d_k}` a head: `S' =
+Diag(exp(g_t)) S_{t-1}`, a ROW of the state forgetting at its own rate, and the
+rest as above. `G` is then (tokens, d_k) and everything above holds with every
+`e^G` a per-channel scaling of k or q: `W = T (beta (K . e^G))`, `Kd_j = k_j .
+e^{G_C - G_j}`, `S_C = Diag(e^{G_C}) S_0 + Kd^T (U0 - W S_0)`, `O = ((Q . e^G) -
+P W) S_0 + P U0`. What changes is that the decay enters the contraction: `A_tj
+= beta_t sum_c k_tc k_jc e^{G_tc - G_jc}` and `P_tj` alike with q. It no longer
+factors out as a mask; `(k_t e^{G_t}) . (k_j e^{-G_j})` overflows float32 as
+soon as a channel forgets fast; and `e^{G_t - G_j}` whole is (chunk, chunk, d_k)
+a chunk and head. `_channel_products` cuts the chunk into sub-blocks of 16
+tokens: between blocks the later block's first token is the reference and both
+factors' exponents are <= 0, a plain matmul; inside a diagonal block the (16,
+16, d_k) decays are formed whole. `_head_core` runs either rule by the gate's
+rank, sharing the triangular solve, the carry and the read-out. The XLA form
+alone: the kernels above are built on a scalar mask outside the dot product.
 
 Sequences are whole rows of the batch: neither the convolution nor the state
 is cut at a document boundary inside a packed row.
@@ -185,31 +203,82 @@ def _carry(m: jax.Array, b: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return starts, last
 
 
+def _channel_products(q, k, total):
+    """The decayed products of a chunk whose gate is a vector a token (KDA): q,
+    k (..., C, d_k), total = G (..., C, d_k) float32, the gate's running sums ->
+    (kk, qk), each (..., C, C) float32 with `sum_c x_tc k_jc e^{G_tc - G_jc}`
+    (x = k, x = q) where j <= t and 0 above the diagonal.
+
+    The decay does not factor out of the sum over c, `(x_t e^{G_t}) . (k_j
+    e^{-G_j})` overflows float32 as soon as a channel forgets fast, and
+    `e^{G_t - G_j}` whole is (C, C, d_k) a chunk. So the chunk is cut into
+    blocks of `_BASE` tokens. BETWEEN blocks the later block's first token r
+    is the reference: `(x_t e^{G_t - G_r}) . (k_j e^{G_r - G_j})`, both
+    exponents <= 0 (r <= t, j < r), a plain matmul; an exponential that
+    underflows stands for a product that is smaller still. INSIDE a diagonal
+    block the (16, 16, d_k) decays are formed whole, masked before the
+    exponential. `kk` feeds the triangular solve: float32 operands. `qk` is
+    on the way to the output: its matmul takes operands of q's dtype."""
+    c, dk = k.shape[-2:]
+    lead, dt, blocks = k.shape[:-2], q.dtype, c // _BASE
+    k32, q32 = k.astype(_F32), q.astype(_F32)
+    g_sub, k_sub, q_sub = (x.reshape(lead + (blocks, _BASE, dk)) for x in (total, k32, q32))
+    lower = jnp.tril(jnp.ones((_BASE, _BASE), bool))[..., None]
+    kj = k_sub[..., None, :, :] * jnp.exp(
+        jnp.where(lower, g_sub[..., :, None, :] - g_sub[..., None, :, :], -jnp.inf))  # (.., t, j, d_k)
+    same = jnp.eye(blocks, dtype=_F32)[:, None, :, None]
+
+    def whole(diagonal, between):  # (.., blocks, 16, 16), (.., blocks, 16, C) -> (.., C, C)
+        return (diagonal[..., None, :] * same).reshape(lead + (c, c)) + between.reshape(lead + (c, c))
+
+    ref = g_sub[..., :1, :]  # G_r, (.., blocks, 1, d_k)
+    rows = jnp.exp(g_sub - ref)
+    earlier = (jnp.arange(c) // _BASE)[None, :] < jnp.arange(blocks)[:, None]  # token j before block I
+    kc = k32[..., None, :, :] * jnp.exp(
+        jnp.where(earlier[..., None], ref - total[..., None, :, :], -jnp.inf))  # (.., blocks, C, d_k)
+    kk = whole(jnp.sum(k_sub[..., :, None, :] * kj, axis=-1), _mm(k_sub * rows, _t(kc)))
+    qk = whole(jnp.sum(q_sub[..., :, None, :] * kj, axis=-1),
+               _mm((q_sub * rows).astype(dt), _t(kc.astype(dt))))
+    return kk, qk
+
+
 def _head_core(q, k, v, g, beta):
     """The rule for one value head, chunked: q, k (N, B, C, d_k), v (N, B, C,
-    d_v), g, beta (N, B, C) float32 -> o (N, B, C, d_v) in v's dtype and the
-    final states (B, d_k, d_v)."""
+    d_v), beta (N, B, C) float32, and g float32 either (N, B, C), a scalar a
+    token (gated DeltaNet), or (N, B, C, d_k), a channel of the key its own
+    (KDA: every `e^G` below then scales k's or q's channels, and the state's
+    rows) -> o (N, B, C, d_v) in v's dtype and the final states (B, d_k, d_v)."""
     chunk, dt = v.shape[-2], v.dtype
-    total = jnp.cumsum(g, axis=-1)  # G, (N, B, C)
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    # masked BEFORE the exponential: above the diagonal the difference is
-    # positive, and grows with the chunk
-    decay = jnp.exp(jnp.where(lower, total[..., :, None] - total[..., None, :], -jnp.inf))
-    a = jnp.where(lower & ~jnp.eye(chunk, dtype=bool), beta[..., None] * decay * _mm(k, _t(k)), 0.0)
+    strict = lower & ~jnp.eye(chunk, dtype=bool)
+    eye = jnp.eye(k.shape[-1], dtype=_F32)
+    if g.ndim == k.ndim:
+        total = jnp.cumsum(g, axis=-2)  # G, (N, B, C, d_k)
+        kk, p = _channel_products(q, k, total)
+        a = jnp.where(strict, beta[..., None] * kk, 0.0)
+        from_start = jnp.exp(total)  # e^G
+        to_end = jnp.exp(total[..., -1:, :] - total)  # e^{G_C - G}
+        keep = jnp.exp(total[..., -1, :])[..., None] * eye
+    else:
+        total = jnp.cumsum(g, axis=-1)  # G, (N, B, C)
+        # masked BEFORE the exponential: above the diagonal the difference is
+        # positive, and grows with the chunk
+        decay = jnp.exp(jnp.where(lower, total[..., :, None] - total[..., None, :], -jnp.inf))
+        a = jnp.where(strict, beta[..., None] * decay * _mm(k, _t(k)), 0.0)
+        p = decay * _mm(q, _t(k))
+        from_start = jnp.exp(total)[..., None]  # e^G
+        to_end = jnp.exp(total[..., -1:] - total)[..., None]  # e^{G_C - G}
+        keep = jnp.exp(total[..., -1])[..., None, None] * eye
     inverse = unit_lower_inverse(a)
-    from_start = jnp.exp(total)[..., None]  # e^G
-    to_end = jnp.exp(total[..., -1:] - total)[..., None]  # e^{G_C - G}
     # the chunk's affine map of the state, float32 operands: what is carried
     k32 = k.astype(_F32)
     u0 = _mm(inverse, v.astype(_F32) * beta[..., None])
     w = _mm(inverse, k32 * (beta[..., None] * from_start))
     kd_t = _t(k32 * to_end)
-    eye = jnp.eye(k.shape[-1], dtype=_F32)
-    starts, last = _carry(jnp.exp(total[..., -1])[..., None, None] * eye - _mm(kd_t, w),
-                          _mm(kd_t, u0))
+    starts, last = _carry(keep - _mm(kd_t, w), _mm(kd_t, u0))
     starts = checkpoint_name(starts, STARTS)
     # the outputs, read off the chunks' starting states: operands in v's dtype
-    p, w, u0 = ((decay * _mm(q, _t(k))).astype(dt), w.astype(dt), u0.astype(dt))
+    p, w, u0 = p.astype(dt), w.astype(dt), u0.astype(dt)
     q_hat = (q.astype(_F32) * from_start - _mm(p, w)).astype(dt)
     return (_mm(q_hat, starts.astype(dt)) + _mm(p, u0)).astype(dt), last
 
@@ -1142,3 +1211,25 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, bet
     if sharding is None:
         return _kernel_form(q, k, v, g, beta)
     return _sharded_kernel_form(q, k, v, g, beta, sharding)
+
+
+def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
+             *, chunk: int = CHUNK) -> Tuple[jax.Array, jax.Array]:
+    """Kimi Delta Attention's rule (arXiv:2510.26692): the delta rule whose
+    gate is a VECTOR a head, a row of the (d_k, d_v) state forgetting at its
+    own rate, `S' = Diag(exp(g_t)) S_{t-1}` and the rest as above. q, k (B, S,
+    H, d_k), L2-normalised and q scaled; v (B, S, H, d_v); g (B, S, H, d_k)
+    the log of the gate, <= 0; beta (B, S, H) -> o (B, S, H, d_v) in v's dtype,
+    and the final states (B, H, d_k, d_v) float32.
+
+    The XLA form alone (`_xla_rule`: a head at a time, the chunks' starting
+    states kept, autodiff's backward; the decayed products by
+    `_channel_products`): the kernels above know a scalar gate. Any length: a
+    rest is padded with tokens that neither forget nor write (g = beta = 0)."""
+    s = v.shape[1]
+    rest = -s % chunk
+    if rest:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, rest)) + ((0, 0),) * (x.ndim - 2))
+                            for x in (q, k, v, g, beta))
+    o, last = _xla_rule(q, k, v, g, beta, chunk)
+    return o[:, :s], last

@@ -1,0 +1,124 @@
+#!/usr/bin/env python3
+"""The cells' compiled steps of two checkouts, compared instruction for
+instruction, with no chip: what a PR that adds a model shows of the cells it
+must not move.
+
+    JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python3 scripts/compiled_steps.py dump <checkout> <out_dir> [<cell> ...]
+    python3 scripts/compiled_steps.py diff <out_dir_a> <out_dir_b>
+
+`dump` compiles each cell's step (default: every cell of the checkout's
+BENCHMARK.json) for a described v5e 2x2, as `benchmarks/rehearse.py` does, from
+the checkout given (`git archive <parent> | tar -x -C _parent`; lay this PR's
+benchmark files over it so that both sides read the same cells), and writes the
+optimized HLO a cell. `diff` compares two such directories after taking out what
+names the SOURCE and not the program: the tables of files, functions and
+locations and the `stack_frame_id`s (a line added above a function moves
+them), `metadata={...}`, the checkout's path, and a Mosaic kernel's serialized
+body (its MLIR carries source locations too; a PR that edits a kernel compares
+kernels on the chip). Exit 1 where a cell differs. One process a checkout: the
+program is imported from it."""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+
+
+def dump(root: str, out: str, workloads) -> None:
+    root = os.path.abspath(root)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.chdir(root)
+    sys.path.insert(0, root)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    from benchmarks import cells
+    from galvatron_tpu.cli.arguments import hp_config_from_args, initialize_galvatron, model_config_from_args
+    from galvatron_tpu.cli.train import optimizer_args_from
+    from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
+    from galvatron_tpu.runtime.optimizer import get_optimizer_and_scheduler
+
+    topo = list(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices)
+    workloads = workloads or [w["name"] for w in cells.load_json(root, cells.MANIFEST)["workloads"]]
+    os.makedirs(out, exist_ok=True)
+    for workload in workloads:
+        cell = cells.load_cell(root, workload)
+        cells.register_family(cell)
+        args = initialize_galvatron(mode="train_dist", argv=cells.train_argv(cell, 0))
+        _, cfg = model_config_from_args(args)
+        hp = hp_config_from_args(args, cfg.num_layers, cell.chips)
+        model = construct_hybrid_parallel_model(cfg, hp, topo[:cell.chips])
+        tx, _ = get_optimizer_and_scheduler(optimizer_args_from(args))
+
+        def sds(tree, shardings):
+            return jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings)
+
+        params = model.abstract_params()
+        shape = (cell.traffic["global_batch"], cell.traffic["seq_length"])
+        batch = {k: jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(
+            model.mesh, model._batch_spec_for(jax.ShapeDtypeStruct(shape, dt))))
+            for k, dt in (("tokens", jnp.int32), ("positions", jnp.int32),
+                          ("labels", jnp.int32), ("loss_mask", jnp.float32))}
+        step = model.make_train_step(tx).lower(
+            sds(params, model.shardings()),
+            sds(jax.eval_shape(tx.init, params), model.opt_state_shardings(tx, params)), batch).compile()
+        with open(os.path.join(out, workload + ".hlo.txt"), "w") as f:
+            f.write(step.as_text())
+        print(json.dumps({"workload": workload, "root": root, "instructions": step.as_text().count(" = ")}),
+              flush=True)
+
+
+TABLES = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\b")
+
+
+def instructions(path: str):
+    """The HLO's lines without what names the source."""
+    out, in_table = [], False
+    for line in open(path):
+        if TABLES.match(line):
+            in_table = True
+        elif in_table:
+            in_table = bool(line.strip())
+        else:
+            line = re.sub(r",? ?stack_frame_id=\d+", "", line)
+            line = re.sub(r", metadata=\{[^}]*\}", "", line)
+            line = re.sub(r'backend_config=\{[^\n]*"serialization_format"[^\n]*', "backend_config=<kernel>", line)
+            out.append(line)
+    return out
+
+
+def diff(a: str, b: str) -> int:
+    differing = 0
+    for name in sorted(set(os.listdir(a)) | set(os.listdir(b))):
+        paths = [os.path.join(d, name) for d in (a, b)]
+        if not all(os.path.exists(p) for p in paths):
+            print("%s: on one side only" % name)
+            differing += 1
+            continue
+        left, right = (instructions(p) for p in paths)
+        # a checkout's path is in no instruction once the tables are out; lengths first
+        pairs = [(x, y) for x, y in zip(left, right) if x != y]
+        if len(left) == len(right) and not pairs:
+            print("%s: identical, %d lines" % (name, len(left)))
+        else:
+            differing += 1
+            print("%s: DIFFERS (%d against %d lines, %d differing)" % (name, len(left), len(right), len(pairs)))
+            for x, y in pairs[:3]:
+                print("  < %s\n  > %s" % (x.strip()[:200], y.strip()[:200]))
+    return 1 if differing else 0
+
+
+if __name__ == "__main__":
+    if len(sys.argv) >= 4 and sys.argv[1] == "dump":
+        dump(sys.argv[2], sys.argv[3], sys.argv[4:])
+        sys.exit(0)
+    if len(sys.argv) == 4 and sys.argv[1] == "diff":
+        sys.exit(diff(sys.argv[2], sys.argv[3]))
+    print(__doc__, file=sys.stderr)
+    sys.exit(2)

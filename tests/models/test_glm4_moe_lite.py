@@ -123,7 +123,11 @@ def test_the_config_is_the_published_one_cut_by_the_tests_sizes():
     with pytest.raises(ValueError, match="n_group"):
         G.glm4_moe_lite_config_from_hf(type("C", (), {**pub, "n_group": 8}))
     with pytest.raises(ValueError, match="v_head_dim"):
-        tiny(v_head_dim=8)
+        tiny(v_head_dim=0)
+    with pytest.raises(ValueError, match="head_dim 8 >= qk_nope"):
+        tiny(head_dim=8)
+    # a v narrower than q/k is padded to the one attention call's width since PR 42 (Kimi-Linear's)
+    assert tiny(v_head_dim=8).head_dim == tiny().head_dim
 
 
 def test_the_tree_has_a_dense_layer_then_routed_ones_and_the_mtp_module(case):

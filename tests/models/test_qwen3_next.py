@@ -345,7 +345,7 @@ def test_the_other_families_kinds_are_what_they_were():
 
 
 def test_one_table_maps_a_mixer_to_what_it_brings():
-    assert set(M.MIXERS) == {"attention", "linear", "ssm"}
+    assert set(M.MIXERS) == {"attention", "linear", "ssm", "kda"}
     for name, mixer in M.MIXERS.items():
         assert callable(getattr(obs_flops, mixer.flops))
     assert M.MIXERS["linear"].scopes == (tracing.ATTN_LINEAR, tracing.ATTN_DELTA)
