@@ -527,9 +527,11 @@ def _train(args) -> dict:
                                 compile_ms=(t2 - t1) * 1e3,
                                 cache_hit=cache_hit)
             prof.compiled_memory_mb = compiled_step_memory_mb(compiled) or None
-            # (asked only of a model that traced a linear layer: T5's config has no kinds)
-            linear_layers = (sum(kind.startswith("linear") for kind in cfg.layer_kinds())
-                             if delta_rule_took else 0)
+            # (asked only of a model that traced such a layer: T5's config has no kinds)
+            scalar_rule = delta_rule_took["xla"] or delta_rule_took["pallas"]
+            kda_rule = delta_rule_took["kda_xla"] or delta_rule_took["kda_pallas"]
+            linear_layers = sum(kind.startswith("linear") for kind in cfg.layer_kinds()) if scalar_rule else 0
+            kda_layers = sum(kind.startswith("kda") for kind in cfg.layer_kinds()) if kda_rule else 0
             telemetry.emit(
                 "compile",
                 trace_ms=(t1 - t0) * 1e3,
@@ -540,12 +542,16 @@ def _train(args) -> dict:
                 # the linear layers whose delta rule the step runs as Pallas
                 # kernels (ops/linear_attention.py): all of them or none, the
                 # layers being alike; absent where the model has none
-                linear_kernel_layers=linear_layers * (not delta_rule_took["xla"]) if delta_rule_took else None,
+                linear_kernel_layers=linear_layers * (not delta_rule_took["xla"]) if scalar_rule else None,
+                # the Kimi-Delta-Attention layers whose per-channel rule the
+                # step runs as Pallas kernels (`linear_attention.kda_rule`):
+                # all or none; absent where the model has none
+                kda_kernel_layers=kda_layers * (not delta_rule_took["kda_xla"]) if kda_rule else None,
                 # and those whose convolution, norms and gated norm around it
                 # run as Pallas passes (`linear_attention.mixer_form`)
                 linear_pass_kernel_layers=(
                     linear_layers * (not (delta_rule_took["conv_norm_xla"] or delta_rule_took["gated_norm_xla"]))
-                    if delta_rule_took else None),
+                    if scalar_rule else None),
                 # the routed blocks whose rows the step moves with the Pallas
                 # row movers (`moe.rows_form`): all of them or none, the blocks
                 # being alike in width and length; absent where the model has none

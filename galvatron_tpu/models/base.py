@@ -953,7 +953,8 @@ def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
     return out, None, stats
 
 
-def kda_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, **_):
+def kda_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, *,
+              attn_sharding: Optional[KernelSharding] = None, **_):
     """Kimi Delta Attention on normed activations (B, S, H) (HF
     `KimiDeltaAttention`; arXiv:2510.26692), p the layer's tree:
 
@@ -968,9 +969,10 @@ def kda_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerCon
     a head's (d_k, d_v) state forgetting at its own rate. -> out, None, and
     the linear mixer's counters: the mean gate `exp(g)` and the largest
     magnitude in any head's final state. Scopes: the core under
-    `gt.attn.kda_rule`, all else under `gt.attn.kda_mixer`. No position enters. All
-    of it is XLA's: the linear mixer's Pallas passes are cut to `Wqkvz`'s
-    columns and SiLU's gate, its core's kernels to a scalar gate."""
+    `gt.attn.kda_rule`, all else under `gt.attn.kda_mixer`. No position enters.
+    `attn_sharding` tells the core where its operands lie: on TPUs it runs as
+    two Pallas kernels (`kda_fwd`, `kda_bwd`). All around it is XLA's: the
+    linear mixer's Pallas passes are cut to `Wqkvz`'s columns and SiLU's gate."""
     p, dtype = p["kda"], cfg.compute_dtype
     nh, dk, dv = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
     key_dim = nh * dk
@@ -987,7 +989,7 @@ def kda_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerCon
         beta = jax.nn.sigmoid(_dense(y, p["wb"], dtype).astype(jnp.float32))
         gate = _dense(_dense(y, p["wg_a"], dtype), p["wg_b"], dtype).reshape(b, s, nh, dv)
     with jax.named_scope(tracing.ATTN_KDA_RULE):
-        o, state = kda_rule(q, k, v, g, beta)
+        o, state = kda_rule(q, k, v, g, beta, sharding=attn_sharding)
     with jax.named_scope(tracing.ATTN_KDA):
         o = rms_norm(o.astype(jnp.float32), p["norm"]["scale"], cfg.layernorm_eps)
         o = (o * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype).reshape(b, s, nh * dv)

@@ -387,6 +387,9 @@ def render(analysis: Dict[str, Any]) -> str:
         if "linear_pass_kernel_layers" in comp:
             lines.append("linear layers whose convolution and norms run as Pallas passes: %d"
                          % comp["linear_pass_kernel_layers"])
+        if "kda_kernel_layers" in comp:
+            lines.append("Kimi-Delta-Attention layers whose rule runs as Pallas kernels: %d"
+                         % comp["kda_kernel_layers"])
         if "moe_row_kernel_blocks" in comp:
             lines.append("routed blocks whose rows move through the Pallas row movers: %d"
                          % comp["moe_row_kernel_blocks"])

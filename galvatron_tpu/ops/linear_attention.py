@@ -107,8 +107,19 @@ a chunk and head. `_channel_products` cuts the chunk into sub-blocks of 16
 tokens: between blocks the later block's first token is the reference and both
 factors' exponents are <= 0, a plain matmul; inside a diagonal block the (16,
 16, d_k) decays are formed whole. `_head_core` runs either rule by the gate's
-rank, sharing the triangular solve, the carry and the read-out. The XLA form
-alone: the kernels above are built on a scalar mask outside the dot product.
+rank, sharing the triangular solve, the carry and the read-out. That is the
+XLA form (`kda_rule(impl="xla")`; the CPU's path and the tests' oracle). On a
+TPU the rule has kernels of its own, `kda_fwd` and `kda_bwd` (below the scalar
+rule's, whose walk, solve and carried state they share; `impl="auto"` decides
+as `gated_delta_rule` does): the reference token is taken by HALVING, a tile's
+later half against its earlier half and so on down to pairs of tokens, so
+every decayed product is a plain matmul on the MXU and every exponent a sum of
+g's, <= 0; the forward keeps every tile's `T`, `kk` and starting state, so a
+layer's recomputation runs it once and the backward not at all. On the chip
+(PERF.md, PR 43) a layer at the Kimi-Linear cell's widths takes 6.5 ms a call
+of `kda_fwd` and 10.6 ms a call of `kda_bwd`, 23.6 ms a layer and step under
+the layer's recomputation where the XLA form took 74.6 (its backward runs its
+forward again, a head at a time).
 
 Sequences are whole rows of the batch: neither the convolution nor the state
 is cut at a document boundary inside a packed row.
@@ -390,17 +401,22 @@ def _tiles_of(ref):  # a block's tokens (n x 128, d) -> (n, 128, d)
     return ref[...].reshape(ref.shape[0] // TILE, TILE, ref.shape[1])
 
 
+def _columns(rows, inv):
+    """A block's rows (n, 8, 128) as columns, (n, 128, 8): the product with the
+    identity is the transpose (exact: one factor is 1, the others 0), one for
+    the block."""
+    n = rows.shape[0]
+    cols = _dot(inv["eye"], rows.reshape(n * _ROWS, TILE), _NT)
+    return jnp.stack([cols[:, tile * _ROWS:(tile + 1) * _ROWS] for tile in range(n)])
+
+
 def _local(q, k, v, rows, inv, inverse=None):
     """What a block's n tiles make of q, k, v (n, 128, d), G and beta without
     their states, all n at once: the products of one tile follow those of
     another in the program, none waiting for the other's result. rows (n, 8,
     128) hold G, beta and the tile's last G a token; `inverse` is `T` where it
     was kept."""
-    n = rows.shape[0]
-    # the rows as columns, (n, 128, 8): the product with the identity is the
-    # transpose (exact: one factor is 1, the others 0), one for the block
-    cols = _dot(inv["eye"], rows.reshape(n * _ROWS, TILE), _NT)
-    cols = jnp.stack([cols[:, tile * _ROWS:(tile + 1) * _ROWS] for tile in range(n)])
+    cols = _columns(rows, inv)
     g_row, g_col, beta, g_end = rows[:, 0:1, :], cols[:, :, 0:1], cols[:, :, 1:2], cols[:, :, 2:3]
     from_start = jnp.exp(g_col)
     k32 = k.astype(_F32)
@@ -427,6 +443,37 @@ def _here(step, block, tiles):
     return block if tiles % block == 0 else jnp.minimum(block, tiles - step * block)
 
 
+def _walk(here, dk, keeps, s_ref, starts_ref, wu_ref, kd_ref, u_ref):
+    """The state through a block's `here` tiles, two dependent products each:
+    `u = U0 - W S_0`, `S_C = keeps(i) S_0 + Kd^T u`; every tile's start and u
+    are left in `starts_ref`, `u_ref`."""
+    def tile(i, carry):
+        state = s_ref[...]
+        starts_ref[i] = state
+        u = wu_ref[i][:, dk:] - _dot(wu_ref[i][:, :dk], state, _NN)  # U0 - W S_0
+        u_ref[i] = u
+        s_ref[...] = keeps(i, state) * state + _dot(kd_ref[i], u, _TN)
+        return carry
+
+    jax.lax.fori_loop(0, here, tile, None)
+
+
+def _walk_back(here, keeps, ds_ref, dends_ref, fo_ref, kd_ref, du_ref, qgdo_ref, w_ref):
+    """`_walk`'s gradient from the block's last tile: `du = P^T do + Kd dS_C`,
+    `dS_0 = keeps(i) dS_C + (q e^G)^T do - W^T du`; every tile's `dS_C` and du
+    are left in `dends_ref`, `du_ref`."""
+    def tile(j, carry):
+        i = here - 1 - j
+        dstate = ds_ref[...]
+        dends_ref[i] = dstate
+        du = fo_ref[i] + _dot(kd_ref[i], dstate, _NN)
+        du_ref[i] = du
+        ds_ref[...] = keeps(i, dstate) * dstate + qgdo_ref[i] - _dot(w_ref[i], du, _TN)
+        return carry
+
+    jax.lax.fori_loop(0, here, tile, None)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, block, tiles, keep):
     """One grid step of the forward walk over a block of tiles, the head's
     state in `s_ref` from the step before: the tiles' own matrices all at
@@ -451,15 +498,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, block, tiles, keep)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    def tile(i, carry):
-        state = s_ref[...]
-        starts_ref[i] = state
-        u = wu_ref[i][:, dk:] - _dot(wu_ref[i][:, :dk], state, _NN)  # U0 - W S_0
-        u_ref[i] = u
-        s_ref[...] = _keeps(rows_ref, i, state.shape[1]) * state + _dot(kd_ref[i], u, _TN)
-        return carry
-
-    jax.lax.fori_loop(0, _here(step, block, tiles), tile, None)
+    _walk(_here(step, block, tiles), dk, lambda i, state: _keeps(rows_ref, i, state.shape[1]),
+          s_ref, starts_ref, wu_ref, kd_ref, u_ref)
     p = (m["decay"] * m["qk"]).astype(dt)
     o = (_dot((q.astype(_F32) * m["from_start"]).astype(dt), starts_ref[...].astype(dt), _NN)
          + _dot(p, u_ref[...].astype(dt), _NN))
@@ -511,17 +551,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, starts_ref, t_ref, do_ref, dlast_
 
     here = _here(pl.num_programs(2) - 1 - step, block, tiles)  # the reverse walk's block
 
-    def tile(j, carry):
-        i = here - 1 - j
-        dstate = ds_ref[...]
-        dends_ref[i] = dstate
-        du = fo_ref[i] + _dot(kd_ref[i], dstate, _NN)
-        du_ref[i] = du
-        ds_ref[...] = (_keeps(rows_ref, i, dstate.shape[1]) * dstate + qgdo_ref[i]
-                       - _dot(w_ref[i], du, _TN))
-        return carry
-
-    jax.lax.fori_loop(0, here, tile, None)
+    _walk_back(here, lambda i, dstate: _keeps(rows_ref, i, dstate.shape[1]),
+               ds_ref, dends_ref, fo_ref, kd_ref, du_ref, qgdo_ref, w_ref)
 
     def rows_sum(x):  # (n, 128, m) -> (n, 128, 1)
         return jnp.sum(x, axis=2, keepdims=True)
@@ -588,7 +619,8 @@ def _call(kernel, name, dims, dtypes, reverse, in_kinds, out_kinds, scratch_kind
     d_k, Hv, d_v), `dtypes` = (q's, v's). Kinds of blocks: "key" / "value"
     (a block's tokens of one head, d_k / d_v wide; a key head serves Hv / Hk
     value heads: the index map sends value head h to key head h // (Hv / Hk),
-    and no q or k is repeated), "keys" (d_k wide a VALUE head), "rows",
+    and no q or k is repeated), "keys" (d_k wide a VALUE head), "gate" (the
+    same block float32: the per-channel rule's g and its gradient), "rows",
     "starts", "inverse" (a block's tiles), "state" (a head's)."""
     b, s, hk, dk, hv, dv = dims
     block, tiles = _block(s, dv, dtypes[1].itemsize), s // TILE
@@ -603,10 +635,11 @@ def _call(kernel, name, dims, dtypes, reverse, in_kinds, out_kinds, scratch_kind
     def a_tile(*shape):
         return pl.BlockSpec((None, None, block) + shape, lambda i, h, c: (i, h, at(c), 0, 0))
 
-    specs = {"key": tokens(dk, hv // hk), "keys": tokens(dk, 1), "value": tokens(dv, 1),
+    specs = {"key": tokens(dk, hv // hk), "keys": tokens(dk, 1), "gate": tokens(dk, 1), "value": tokens(dv, 1),
              "rows": a_tile(_ROWS, TILE), "starts": a_tile(dk, dv), "inverse": a_tile(TILE, TILE),
              "state": pl.BlockSpec((None, None, dk, dv), lambda i, h, c: (i, h, 0, 0))}
-    shapes = {"keys": ((b, s, hv * dk), dtypes[0]), "value": ((b, s, hv * dv), dtypes[1]),
+    shapes = {"keys": ((b, s, hv * dk), dtypes[0]), "gate": ((b, s, hv * dk), _F32),
+              "value": ((b, s, hv * dv), dtypes[1]),
               "rows": ((b, hv, tiles, _ROWS, TILE), _F32), "starts": ((b, hv, tiles, dk, dv), _F32),
               "inverse": ((b, hv, tiles, TILE, TILE), _F32), "state": ((b, hv, dk, dv), _F32),
               # scratch alone: a block's tiles by d_k, by d_v, by both side by side
@@ -708,30 +741,318 @@ _kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
 
 
 def _kernel_form(q, k, v, g, beta):
-    """`_kernel_rule` on whole tiles: an odd number of chunks gets a chunk of
-    zeros behind it (beta 0: it writes nothing; g 0: it forgets nothing)."""
+    """The kernels on whole tiles, the scalar rule's or the per-channel
+    rule's by the gate's rank: a rest gets tokens of zeros behind it (beta 0:
+    they write nothing; g 0: they forget nothing)."""
+    rule = _kda_kernel_rule if g.ndim == k.ndim else _kernel_rule
     s = v.shape[1]
     if s % TILE == 0:
-        return _kernel_rule(q, k, v, g, beta)
+        return rule(q, k, v, g, beta)
     q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, TILE - s % TILE)) + ((0, 0),) * (x.ndim - 2))
                         for x in (q, k, v, g, beta))
-    o, last = _kernel_rule(q, k, v, g, beta)
+    o, last = rule(q, k, v, g, beta)
     return o[:, :s], last
 
 
-def _sharded_kernel_form(q, k, v, g, beta, sharding: KernelSharding):
+def _sharded_kernel_form(q, k, v, g, beta, sharding: Optional[KernelSharding]):
     """`_kernel_form` a device on its own rows of the batch, under a manual
     region (GSPMD cannot partition a Mosaic kernel; see `KernelSharding` and
-    `ops/attention._sharded_pallas_flash`, whose pattern this is)."""
+    `ops/attention._sharded_pallas_flash`, whose pattern this is); as it is
+    where `_on_kernels` found one device (`sharding` None)."""
+    if sharding is None:
+        return _kernel_form(q, k, v, g, beta)
     rows = sharding.batch_axes or None
     ctx = jax.sharding.get_abstract_mesh()
     use_mesh = sharding.mesh if ctx.empty else ctx
     return jax.shard_map(
         _kernel_form, mesh=use_mesh,
-        in_specs=(P(rows, None, None, None),) * 3 + (P(rows, None, None),) * 2,
+        in_specs=tuple(P(rows, *(None,) * (x.ndim - 1)) for x in (q, k, v, g, beta)),
         out_specs=(P(rows, None, None, None),) * 2,
         axis_names=set(use_mesh.axis_names) - set(use_mesh.manual_axes), check_vma=False,
     )(q, k, v, g, beta)
+
+
+# --- the per-channel rule, the kernel form ----------------------------------
+#
+# `kda_fwd` and `kda_bwd`: the walk, the solve and the carried state of the
+# kernels above (`_call`, `_inverses`, the loop over a block's tiles), on the
+# same tiles of 128 tokens. What differs is where the decay sits. It is inside
+# the contraction over d_k, so a tile's `kk` and `qk` are no product times a
+# mask; and it scales the state's ROWS, so `e^{G_C}` is wanted as a column.
+#
+# The decayed products by halving. Cut the tile into two halves with the later
+# half's first token r the reference: for t >= r > j, `e^{G_t - G_j} = e^{G_t -
+# G_r} e^{G_r - G_j}`, both exponents <= 0, so that quarter of the tile is ONE
+# plain product of `x . e^{G - G_r}` (the later half's rows) with `k . e^{G_r -
+# G}` (the earlier half's). The two halves' own lower triangles are cut the
+# same way, and so on down to pairs of tokens: log2(128) = 7 levels, each one
+# (128, d_k) x (d_k, 128) product of which the level's quarter-blocks are kept
+# (`_levels`' `pairs`); every t > j is kept at exactly one level, the one at
+# which t and j part. A level's exponents are partial sums of g, `sum_{r < i <=
+# t} g_i` in the later half and `sum_{j < i <= r} g_i` in the earlier: ROWS of
+# one 0/1 matrix times g, never a difference of two running sums, and one
+# array `E = exp(.)` (128, d_k) serves both sides (`x . E` the rows, `k . E`
+# the columns; which half a token lies in says which it is). `e^G` and `e^{G_C
+# - G}` are two more such matrices (j <= t; j > t), so ONE product `sums @ g`
+# (9 x 128, 128) x (128, d_k) makes every exponent of a tile: exact, the 0/1
+# matrix in bf16 and g as three bf16 parts (`_thirds`). No exponent is
+# positive, for any g <= 0, at any level: nothing can overflow, and an
+# exponential that underflows stands for a product that is smaller still.
+# `_channel_products` (the XLA form) does the same between its 16-token blocks
+# and forms the (16, 16, d_k) decays whole inside them; here those are four
+# more levels of the same product: a float32 and a bf16 product each on the
+# MXU, where the decays whole are 2 x 262144 multiply-adds and 4096 lane
+# reductions a tile on the vector unit.
+#
+# Kept for the backward: every tile's `T`, the state it started from and its
+# `kk` (which only dbeta reads: seven float32 products to make again, 64 KB to
+# read). The backward makes `E`, `qk`, `W`, `u` again, walks `dS` from the last
+# tile, and sends each level's `dkk`, `dqk` back through its product to q, k
+# and `E`; g's gradient is `sums^T` times the levels' `dX` stacked, one product
+# with a contraction over 9 x 128.
+
+
+def _levels(backward=False):
+    """-> sums (9 x 128, 128) bf16 of 0 and 1, and the seven levels' `pairs`
+    (128, 128) bool; with `backward` their transposes behind them. Rows 128 l
+    .. 128 l + 127 of `sums` times g (128, d_k) are level l's exponents
+    (halves of 64, 32, .. 1 tokens; see above), the last two blocks G =
+    `sum_{i <= t} g_i` and G_C - G = `sum_{i > t} g_i`. `pairs[l]`[t, j]: t
+    and j part at level l (the same block of twice the half, t in its later
+    half, j in its earlier)."""
+    row, col = _iota((TILE, TILE), 0), _iota((TILE, TILE), 1)
+    halves = [TILE >> level for level in range(1, TILE.bit_length())]
+
+    def terms(t, i):  # which g_i token t's exponents sum, a level a block
+        out = []
+        for half in halves:
+            ref = t // (2 * half) * (2 * half) + half
+            later = t >= ref
+            out.append((later & (i > ref) & (i <= t)) | (~later & (i > t) & (i <= ref)))
+        return [x.astype(_F32).astype(jnp.bfloat16) for x in out + [i <= t, i > t]]
+
+    def parted(t, j):
+        return [(t >= ref) & (j < ref) & (j // (2 * half) == t // (2 * half))
+                for half in halves for ref in [t // (2 * half) * (2 * half) + half]]
+
+    out = (jnp.concatenate(terms(row, col), axis=0), parted(row, col))
+    return out + (jnp.concatenate(terms(col, row), axis=1), parted(col, row)) if backward else out
+
+
+def _thirds(x):
+    """float32 -> three bf16 side by side in the lanes whose sum is x to the
+    last bit (8 + 8 + 8 bits of mantissa): a product of x with a 0/1 matrix is
+    then three bf16 passes of the MXU and exact, where `_dot` on float32
+    operands splits BOTH sides and takes six."""
+    parts, rest = [], x
+    for _ in range(3):
+        parts.append(rest.astype(jnp.bfloat16))
+        rest = rest - parts[-1].astype(_F32)
+    return jnp.concatenate(parts, axis=-1)
+
+
+def _whole(x):  # the three partial products of `_thirds` -> their sum
+    d = x.shape[-1] // 3
+    return x[..., :d] + x[..., d:2 * d] + x[..., 2 * d:]
+
+
+def _level(e, l):  # level l's block of the stacked exponentials (n, 9 x 128, d_k)
+    return e[:, l * TILE:(l + 1) * TILE]
+
+
+def _kda_local(q, k, v, g, rows, inv, sums, pairs, inverse=None, kk=None):
+    """`_local` for a gate (n, 128, d_k): what a block's n tiles make of q, k,
+    v, g and beta without their states. `inverse`, `kk`: `T` and the decayed
+    `k k^T` where they were kept."""
+    n, dt, dv = g.shape[0], v.dtype, v.shape[2]
+    beta = _columns(rows, inv)[:, :, 1:2]
+    parts = _thirds(g)
+    e = jnp.exp(jnp.stack([_whole(_dot(sums, parts[tile], _NN)) for tile in range(n)]))
+    k32, q32 = k.astype(_F32), q.astype(_F32)
+    qk = inv["eye"] * jnp.sum(q32 * k32, axis=2, keepdims=True)  # t = j: no decay
+    made = jnp.zeros((n, TILE, TILE), _F32) if kk is None else None
+    for l, pair in enumerate(pairs):
+        ke = k32 * _level(e, l)
+        qk = qk + jnp.where(pair, _dot((q32 * _level(e, l)).astype(dt), ke.astype(dt), _NT), 0.0)
+        if kk is None:
+            made = made + jnp.where(pair, _dot(ke, ke, _NT), 0.0)
+    kk = made if kk is None else kk
+    from_start, to_end = _level(e, len(pairs)), _level(e, len(pairs) + 1)
+    if inverse is None:
+        inverse = _inverses(beta * kk, inv)
+    rhs = jnp.concatenate([k32 * (beta * from_start), v.astype(_F32) * beta], axis=2)  # [Rw | Ru]
+    # e^{G_C} as the state's rows want it, (n, d_k, d_v): g's sum over a tile's tokens in every column
+    ones, dk = jnp.ones((n, TILE, dv), jnp.bfloat16), g.shape[2]
+    keep = jnp.exp(sum(_dot(parts[:, :, at:at + dk], ones, _TN) for at in range(0, 3 * dk, dk)))
+    return dict(beta=beta, e=e, from_start=from_start, to_end=to_end, kk=kk, qk=qk, inverse=inverse,
+                rhs=rhs, wu=_dot(inverse, rhs, _NN), k32=k32, q32=q32, keep=keep)
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, o_ref, *rest, block, tiles, keep):
+    """`_fwd_kernel` for the per-channel rule: g_ref a block's (tokens, d_k)
+    float32 of the head, rows_ref beta alone (row 1)."""
+    if keep:
+        starts_ref, t_ref, kk_ref, last_ref, s_ref, wu_ref, kd_ref, u_ref, keep_ref = rest
+    else:
+        last_ref, s_ref, wu_ref, kd_ref, u_ref, keep_ref, starts_ref = rest
+    step = pl.program_id(2)
+    dt = v_ref.dtype
+    dk = q_ref.shape[1]
+    sums, pairs = _levels()
+    q, k, v = _tiles_of(q_ref), _tiles_of(k_ref), _tiles_of(v_ref)
+    m = _kda_local(q, k, v, _tiles_of(g_ref), rows_ref[...], _invariants(), sums, pairs)
+    if keep:
+        t_ref[...] = m["inverse"]
+        kk_ref[...] = m["kk"]
+    wu_ref[...] = m["wu"]
+    kd_ref[...] = m["k32"] * m["to_end"]
+    keep_ref[...] = m["keep"]
+
+    @pl.when(step == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    _walk(_here(step, block, tiles), dk, lambda i, _: keep_ref[i], s_ref, starts_ref, wu_ref, kd_ref, u_ref)
+    o = (_dot((m["q32"] * m["from_start"]).astype(dt), starts_ref[...].astype(dt), _NN)
+         + _dot(m["qk"].astype(dt), u_ref[...].astype(dt), _NN))
+    o_ref[...] = o.reshape(o_ref.shape).astype(dt)
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _():
+        last_ref[...] = s_ref[...]
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, t_ref, kk_ref, do_ref, dlast_ref,
+                    dq_ref, dk_ref, dv_ref, dg_ref, drows_ref,
+                    ds_ref, fo_ref, kd_ref, w_ref, qgdo_ref, du_ref, dends_ref, keep_ref, *, block, tiles):
+    """`_bwd_kernel` for the per-channel rule. The state's walk and the solve
+    are the scalar rule's with `P = qk`, `A = beta kk` (the decay is inside
+    them) and `e^{G_C}` a row of the state its own. Behind them `dqk = dP` and
+    `dkk = beta dA` go back level by level: with `KE = k . E`, `QE = q . E`,
+    `dKE = dkk KE + dkk^T KE + dqk^T QE`, `dQE = dqk KE` on the level's pairs,
+    `dE = dKE . k + dQE . q`, and the exponents' `dX = dE . E` of all levels,
+    of `e^G` and of `e^{G_C - G}` stacked go through `sums^T` to g in one
+    product; `e^{G_C}`'s share reaches every token of its tile alike."""
+    step = pl.program_id(2)
+    dt = v_ref.dtype
+    dk, dv = q_ref.shape[1], v_ref.shape[1]
+    inv = _invariants()
+    sums, pairs, sums_t, pairs_t = _levels(backward=True)
+    q, k, v, do = _tiles_of(q_ref), _tiles_of(k_ref), _tiles_of(v_ref), _tiles_of(do_ref)
+    m = _kda_local(q, k, v, _tiles_of(g_ref), rows_ref[...], inv, sums, pairs, t_ref[...], kk_ref[...])
+    beta, e, from_start, to_end, inverse, rhs, k32, q32 = (
+        m[name] for name in "beta e from_start to_end inverse rhs k32 q32".split())
+    n = e.shape[0]
+    states = starts_ref[...]
+    qg32 = q32 * from_start
+    kd = k32 * to_end
+    w = m["wu"][:, :, :dk]
+    u = m["wu"][:, :, dk:] - _dot(w, states, _NN)
+    fo_ref[...] = _dot(m["qk"].astype(dt), do, _TN)  # P^T do
+    qgdo_ref[...] = _dot(qg32.astype(dt), do, _TN)
+    kd_ref[...] = kd
+    w_ref[...] = w
+    keep_ref[...] = m["keep"]
+
+    @pl.when(step == 0)
+    def _():
+        ds_ref[...] = dlast_ref[...]
+
+    here = _here(pl.num_programs(2) - 1 - step, block, tiles)  # the reverse walk's block
+
+    _walk_back(here, lambda i, _: keep_ref[i], ds_ref, dends_ref, fo_ref, kd_ref, du_ref, qgdo_ref, w_ref)
+
+    def rows_sum(x):  # (n, 128, m) -> (n, 128, 1)
+        return jnp.sum(x, axis=2, keepdims=True)
+
+    du, dends = du_ref[...], dends_ref[...]
+    dkd = _dot(u, dends, _NT)
+    dqg = _dot(do, states.astype(dt), _NT)
+    dp = _dot(do, u.astype(dt), _NT)
+    dwu = jnp.concatenate([-_dot(du, states, _NT), du], axis=2)  # [dW | dU0]
+    drhs = _dot(inverse, dwu, _TN)
+    da = jnp.where(inv["strict"],
+                   -_dot(_dot(inverse, _dot(dwu, rhs, _NT), _TN), inverse, _NT), 0.0)
+    drw, dru = drhs[:, :, :dk], drhs[:, :, dk:]
+    dkk = da * beta
+    diagonal = rows_sum(dp * inv["eye"])  # qk's t = j
+    dq = dqg * from_start + diagonal * k32
+    dk_ = drw * (beta * from_start) + dkd * to_end + diagonal * q32
+    dx = []
+    dkk_t = jnp.swapaxes(dkk, 1, 2)
+    for l, (pair, pair_t) in enumerate(zip(pairs, pairs_t)):
+        scale = _level(e, l)
+        qe, ke = q32 * scale, k32 * scale
+        # the pair's later tokens read `dkk`'s rows, its earlier tokens the columns: one product
+        both = jnp.where(pair, dkk, 0.0) + jnp.where(pair_t, dkk_t, 0.0)
+        dqk_l = jnp.where(pair, dp, 0.0).astype(dt)
+        dke = _dot(both, ke, _NN) + _dot(dqk_l, qe.astype(dt), _TN)
+        dqe = _dot(dqk_l, ke.astype(dt), _NN)
+        dq = dq + dqe * scale
+        dk_ = dk_ + dke * scale
+        dx.append(dke * ke + dqe * qe)
+    dx += [dqg * qg32 + drw * rhs[:, :, :dk], dkd * kd]  # e^G's, e^{G_C - G}'s
+    dx = jnp.concatenate(dx, axis=1)
+    # e^{G_C} scales the state's rows: its exponent is the sum of ALL the tile's g
+    dend = _dot(jnp.ones((n, _ROWS, dv), _F32), dends * states * m["keep"], _NT)[:, 0:1, :]
+    dx = _thirds(dx)
+    dg = jnp.stack([_whole(_dot(sums_t, dx[tile], _NN)) for tile in range(n)]) + dend
+    dq_ref[...] = dq.reshape(dq_ref.shape).astype(dq_ref.dtype)
+    dk_ref[...] = dk_.reshape(dk_ref.shape).astype(dk_ref.dtype)
+    dv_ref[...] = (dru * beta).reshape(dv_ref.shape).astype(dv_ref.dtype)
+    dg_ref[...] = dg.reshape(dg_ref.shape)
+    dbeta = rows_sum(dru * v.astype(_F32)) + rows_sum(drw * k32 * from_start) + rows_sum(da * m["kk"])
+    drows_ref[:, 1:2, :] = jnp.sum(dbeta * inv["eye"], axis=1, keepdims=True)
+
+
+def _beta_rows(beta):
+    """beta (B, S, H) -> (B, H, tiles, 8, 128) float32, `_scalars`' layout
+    with beta alone (row 1): the per-channel rule's g is an operand of its own."""
+    b, s, h = beta.shape
+    rows = beta.astype(_F32).transpose(0, 2, 1).reshape(b, h, s // TILE, 1, TILE)
+    return jnp.pad(rows, ((0, 0),) * 3 + ((1, _ROWS - 2), (0, 0)))
+
+
+def _kda_forward(q, k, v, g, beta, keep):
+    """`kda_fwd` on q, k, g (B, S, H, d_k), v (B, S, H, d_v): -> o as v came,
+    the final states, and with `keep` what `kda_bwd` reads again (beta's rows,
+    the states the tiles started from, every tile's `T` and `kk`)."""
+    rows = _beta_rows(beta)
+    out = _call(functools.partial(_kda_fwd_kernel, keep=keep), "kda_fwd", _dims(q, v), (q.dtype, v.dtype),
+                False, ["key", "key", "value", "gate", "rows"],
+                ["value"] + ["starts", "inverse", "inverse"] * keep + ["state"],
+                ["state", "by_kv", "by_k", "by_v", "starts"] + ["starts"] * (not keep),
+                (_flat(q), _flat(k), _flat(v), _flat(g.astype(_F32)), rows))
+    return out[0].reshape(v.shape), out[-1], ((rows,) + tuple(out[1:4]) if keep else None)
+
+
+@jax.custom_vjp
+def _kda_kernel_rule(q, k, v, g, beta):
+    return _kda_forward(q, k, v, g, beta, keep=False)[:2]
+
+
+def _kda_kernel_rule_fwd(q, k, v, g, beta):
+    o, last, kept = _kda_forward(q, k, v, g, beta, keep=True)
+    return (o, last), (q, k, v, g, beta, kept)
+
+
+def _kda_kernel_rule_bwd(residuals, cotangents):
+    q, k, v, g, beta, (rows, starts, inverse, kk) = residuals
+    do, dlast = cotangents
+    b, s, h, _ = v.shape
+    dq, dk, dv, dg, drows = _call(
+        _kda_bwd_kernel, "kda_bwd", _dims(q, v), (q.dtype, v.dtype), True,
+        ["key", "key", "value", "gate", "rows", "starts", "inverse", "inverse", "value", "state"],
+        ["keys", "keys", "value", "gate", "rows"],
+        ["state", "by_v", "by_k", "by_k", "starts", "by_v", "starts", "starts"],
+        (_flat(q), _flat(k), _flat(v), _flat(g.astype(_F32)), rows, starts, inverse, kk,
+         _flat(do), dlast.astype(_F32)))
+    dbeta = drows[..., 1, :].reshape(b, h, s).transpose(0, 2, 1)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dg.reshape(g.shape).astype(g.dtype), dbeta.astype(beta.dtype))
+
+
+_kda_kernel_rule.defvjp(_kda_kernel_rule_fwd, _kda_kernel_rule_bwd)
 
 
 # --- around the core, the kernel form ---------------------------------------
@@ -1208,13 +1529,12 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, bet
     TOOK[impl] += 1
     if impl == "xla":
         return _xla_rule(q, k, v, g, beta, chunk)
-    if sharding is None:
-        return _kernel_form(q, k, v, g, beta)
     return _sharded_kernel_form(q, k, v, g, beta, sharding)
 
 
 def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
-             *, chunk: int = CHUNK) -> Tuple[jax.Array, jax.Array]:
+             *, chunk: int = CHUNK, impl: str = "auto",
+             sharding: Optional[KernelSharding] = None) -> Tuple[jax.Array, jax.Array]:
     """Kimi Delta Attention's rule (arXiv:2510.26692): the delta rule whose
     gate is a VECTOR a head, a row of the (d_k, d_v) state forgetting at its
     own rate, `S' = Diag(exp(g_t)) S_{t-1}` and the rest as above. q, k (B, S,
@@ -1222,10 +1542,22 @@ def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
     the log of the gate, <= 0; beta (B, S, H) -> o (B, S, H, d_v) in v's dtype,
     and the final states (B, H, d_k, d_v) float32.
 
-    The XLA form alone (`_xla_rule`: a head at a time, the chunks' starting
-    states kept, autodiff's backward; the decayed products by
-    `_channel_products`): the kernels above know a scalar gate. Any length: a
-    rest is padded with tokens that neither forget nor write (g = beta = 0)."""
+    `impl` as in `gated_delta_rule`: "pallas" the kernels (`kda_fwd`,
+    `kda_bwd`), "xla" the XLA form (`_xla_rule`: a head at a time, the chunks'
+    starting states kept, autodiff's backward; the decayed products by
+    `_channel_products`), "auto" the kernels where the operands lie on TPUs
+    (`sharding`'s mesh says so; with none, the default backend), d_k and d_v
+    are multiples of 128 and the call sits on one device or, with `sharding`,
+    on whole rows of the batch a device; everything else, the CPU among it,
+    the XLA form. Counted in `TOOK` as "kda_pallas" / "kda_xla". Any length: a
+    rest (of a tile; of `chunk` in the XLA form) is padded with tokens that
+    neither forget nor write (g = beta = 0)."""
+    kernels, sharding = _on_kernels(sharding, v.shape[0], q.shape[3] % TILE == 0 and v.shape[3] % TILE == 0)
+    if impl == "auto":
+        impl = "pallas" if kernels else "xla"
+    TOOK["kda_" + impl] += 1
+    if impl == "pallas":
+        return _sharded_kernel_form(q, k, v, g, beta, sharding)
     s = v.shape[1]
     rest = -s % chunk
     if rest:

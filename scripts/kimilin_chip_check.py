@@ -31,7 +31,8 @@ reads:
   against the reference HELD TO THE PROGRAM'S ROUTING (`forced_experts`).
 
 **A control in the next lower precision, on the first seed, which must FAIL at
-least one limit**: layer 0's rule with its carried state rounded to bf16 after
+least one limit**: layer 0's rule, in its XLA form (the program's own is the
+kernels', `kda_fwd`: their state never leaves VMEM), with its carried state rounded to bf16 after
 every chunk (`_carry` replaced by one that rounds with
 `jax.lax.reduce_precision`: a cast there and back the TPU compiler takes out;
 compiled as a program of its own). Writes `chiprun_out/kimilin_chip_check.json`;
@@ -73,6 +74,13 @@ CELL = "kimilin-c1-s8k"
 # other limits cannot lie between two readings: `loss` is the cell's own
 # `reference_loss.abs` (3.4 x the largest gap seen), `router` float32's own
 # rounding with room, the rest about 1.4 times the program's largest.
+# Since PR 43 the rule's core runs as the kernels `kda_fwd` / `kda_bwd` (my chip
+# runs, PR 43, the same seeds): `core_state` 2.8e-6 (every exponent a sum of
+# g's, no difference of running sums: nearer float64 than the XLA form's 5.0e-6),
+# `core_o` 3.47e-3 and `core_o_last_chunk` 3.73e-3 (the kernels round `q . E`
+# and `k . E` to bf16 at every level of the halving, the XLA form kept its 16 x
+# 16 diagonal blocks float32: 2^-9 twice where it was once; twelve readings from
+# 3.42e-3 to 3.73e-3); the limits are as they were, the control the XLA form's.
 # (The first version of the REFERENCE read every leaf 15 % off and the loss up
 # to 1.9e-3 off: XLA:TPU shifted its convolution within 1024-row tiles; PERF.md
 # section 6, PR 42. These limits would have caught it: 0.35 and 0.52 then.)
@@ -134,7 +142,7 @@ def main(argv=None) -> int:
         head_core = L._head_core
         L._carry, L._head_core = carry_bf16, lambda *a: head_core(*a)
         try:
-            return L.kda_rule(*operands)
+            return L.kda_rule(*operands, impl="xla")  # the form whose carry can be rounded from outside
         finally:
             L._carry, L._head_core = committed_carry, head_core
 

@@ -70,6 +70,8 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, count
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 1), (1, 1, 3), (2, 3, 4), (3, 4, 5)]
     # the scalar rule's kernels are not this model's: the compile report says nothing of them
     assert all("linear_kernel_layers" not in e for e in events if e["type"] == "compile")
+    # its own are: off a TPU none of the four KDA layers takes `kda_fwd` / `kda_bwd`, and the report says so
+    assert [e["kda_kernel_layers"] for e in events if e["type"] == "compile"] == [0]
 
 
 @pytest.mark.parametrize("flags", [

@@ -712,3 +712,59 @@ def test_the_linear_layers_surround_is_lane_aligned_passes_on_v5e(qwen3_next_lin
                 or (over_tokens and kind in ("slice", "dynamic-slice", "pad", "concatenate"))):
             offenders.append("%s = %s %s" % (name, result[:80], kind))
     assert not offenders, "\n".join(offenders)
+
+
+@pytest.fixture(scope="module")
+def kimi_kda_layer(v5e_2x2):
+    """One Kimi-Delta-Attention mixer at the Kimi-Linear cell's widths (8192
+    tokens, hidden 2304, 32 heads of 128, bf16) under the cell's
+    recomputation, forward and backward, compiled for one described chip:
+    -> (the optimised module's text, the forms its core took)."""
+    from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.kimi_linear import kimi_linear_config
+    from galvatron_tpu.ops import linear_attention as L
+
+    tokens = 8192
+    cfg = kimi_linear_config(num_layers=4, max_seq_len=tokens, compute_dtype=jnp.bfloat16)
+    lcfg = cfg.layer_config(next(kind for kind in cfg.layer_kinds() if kind.startswith("kda")))
+    chip = SingleDeviceSharding(v5e_2x2[0])
+    where = A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("dp",)), batch_axes=("dp",))
+    shapes = jax.eval_shape(lambda: M.init_layer_params(jax.random.PRNGKey(0), lcfg))
+    operands = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+                            ({"kda": shapes["kda"]},
+                             jax.ShapeDtypeStruct((1, tokens, cfg.hidden_size), jnp.bfloat16)))
+
+    def loss(p, y):
+        mixer = jax.checkpoint(lambda p, y: M.kda_mixer(p, y, None, lcfg, attn_sharding=where))
+        out, _, counters = mixer(p, y)
+        return jnp.sum(out.astype(jnp.float32)) + counters["state_abs_max"]
+
+    before = dict(L.TOOK)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*operands).compile().as_text()
+    return text, {name: count - before.get(name, 0) for name, count in L.TOOK.items()
+                  if count - before.get(name, 0)}
+
+
+def test_the_kda_layers_core_is_two_kernels_once_each_on_v5e(kimi_kda_layer):
+    """The per-channel rule's core on a TPU: `kda_fwd` and `kda_bwd` under
+    `gt.attn.kda_rule`, ONCE each under the layer's `jax.checkpoint` (the
+    rule keeps its own residuals: the backward does not run the forward again;
+    the first forward and the recomputation are one here, no scan between
+    them), nothing of them under the surround's scope, which its own readers
+    read, and no view of the activations by (tokens, 32, 128) under the
+    core's: a head is a block of 128 lanes of a (tokens, 4096) array."""
+    from galvatron_tpu.obs import tracing
+
+    text, took = kimi_kda_layer
+    assert took == {"kda_pallas": 1}
+    tokens = 8192
+
+    def calls(kernel, scope):
+        return len(re.findall(r'custom-call\(.*op_name="[^"]*%s/%s[/"]' % (re.escape(scope), kernel), text))
+
+    assert calls("kda_fwd", tracing.ATTN_KDA_RULE) == 1 and calls("kda_bwd", tracing.ATTN_KDA_RULE) == 1
+    assert text.count("tpu_custom_call") == 2
+    assert not calls("kda_fwd", tracing.ATTN_KDA) and not calls("kda_bwd", tracing.ATTN_KDA)
+    for line in text.splitlines():
+        if tracing.ATTN_KDA_RULE in line:
+            assert not re.search(r"\[(?:1,)?%d,32,128\]" % tokens, line), line[:200]
