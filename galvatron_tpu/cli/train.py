@@ -552,6 +552,11 @@ def _train(args) -> dict:
                 linear_pass_kernel_layers=(
                     linear_layers * (not (delta_rule_took["conv_norm_xla"] or delta_rule_took["gated_norm_xla"]))
                     if scalar_rule else None),
+                # and the Kimi-Delta-Attention layers' (with the per-channel gate's pass)
+                kda_pass_kernel_layers=(
+                    kda_layers * (not any(delta_rule_took["kda_%s_xla" % name]
+                                          for name in ("conv_norm", "gate", "gated_norm")))
+                    if kda_rule else None),
                 # the routed blocks whose rows the step moves with the Pallas
                 # row movers (`moe.rows_form`): all of them or none, the blocks
                 # being alike in width and length; absent where the model has none

@@ -36,8 +36,8 @@ from galvatron_tpu.config.strategy import (
 )
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding, core_attention
-from galvatron_tpu.ops.linear_attention import (Heads, causal_conv, gated_delta_rule, kda_rule,
-                                                kernel_mixer, mixer_form)
+from galvatron_tpu.ops.linear_attention import (Heads, causal_conv, gated_delta_rule, kda_kernel_mixer, kda_layout,
+                                                kda_rule, kernel_mixer, linear_layout, mixer_form)
 from galvatron_tpu.ops.moe import moe_ffn, swiglu
 from galvatron_tpu.ops.norms import layer_norm, rms_norm
 from galvatron_tpu.ops.ssd import ssd_scan
@@ -930,10 +930,10 @@ def linear_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
         beta = jax.nn.sigmoid(ba[..., :nv])
         g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., nv:] + p["dt_bias"].astype(jnp.float32))
-    heads = Heads(nk, dk, nv, dv)
-    if mixer_form(qkvz, p["conv"], heads, sharding=attn_sharding) == "pallas":
+    layout = linear_layout(Heads(nk, dk, nv, dv))
+    if mixer_form(qkvz, p["conv"], layout, sharding=attn_sharding) == "pallas":
         # the same arithmetic as lane-aligned passes around the core's kernels
-        o, state = kernel_mixer(qkvz, p["conv"], p["norm"]["scale"], g, beta, heads,
+        o, state = kernel_mixer(qkvz, p["conv"], p["norm"]["scale"], g, beta, layout,
                                 eps=cfg.layernorm_eps, sharding=attn_sharding)
     else:
         with jax.named_scope(tracing.ATTN_LINEAR):
@@ -970,31 +970,45 @@ def kda_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerCon
     the linear mixer's counters: the mean gate `exp(g)` and the largest
     magnitude in any head's final state. Scopes: the core under
     `gt.attn.kda_rule`, all else under `gt.attn.kda_mixer`. No position enters.
-    `attn_sharding` tells the core where its operands lie: on TPUs it runs as
-    two Pallas kernels (`kda_fwd`, `kda_bwd`). All around it is XLA's: the
-    linear mixer's Pallas passes are cut to `Wqkvz`'s columns and SiLU's gate."""
+    `attn_sharding` tells the kernels where their operands lie. On TPUs the
+    matmuls alone are XLA's: the core runs as two Pallas kernels (`kda_fwd`,
+    `kda_bwd`) and what lies between the projections and the core as
+    lane-aligned Pallas passes over the projections' (B, S, channels) results
+    (`conv_norm_*`, `kda_gate_*`, `gated_norm_*`; `kda_kernel_mixer`: one
+    rule with the core), no (tokens, heads, 128) view of an activation
+    anywhere. The arithmetic written out below is the definition: what the
+    CPU runs, and the passes' oracle."""
     p, dtype = p["kda"], cfg.compute_dtype
     nh, dk, dv = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
     key_dim = nh * dk
     b, s, _ = y.shape
 
     with jax.named_scope(tracing.ATTN_KDA):
-        qkv = jax.nn.silu(causal_conv(_dense(y, p["wqkv"], dtype), p["conv"]))
-        q = (_unit(qkv[..., :key_dim].reshape(b, s, nh, dk)) * dk ** -0.5).astype(dtype)
-        k = _unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, nh, dk)).astype(dtype)
-        v = qkv[..., 2 * key_dim:].reshape(b, s, nh, dv)
-        f = _dense(_dense(y, p["wf_a"], dtype), p["wf_b"], dtype).astype(jnp.float32)
-        g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
-            f + p["dt_bias"].astype(jnp.float32)).reshape(b, s, nh, dk)
+        qkv = _dense(y, p["wqkv"], dtype)
+        f = _dense(_dense(y, p["wf_a"], dtype), p["wf_b"], dtype)
         beta = jax.nn.sigmoid(_dense(y, p["wb"], dtype).astype(jnp.float32))
-        gate = _dense(_dense(y, p["wg_a"], dtype), p["wg_b"], dtype).reshape(b, s, nh, dv)
-    with jax.named_scope(tracing.ATTN_KDA_RULE):
-        o, state = kda_rule(q, k, v, g, beta, sharding=attn_sharding)
+        gate = _dense(_dense(y, p["wg_a"], dtype), p["wg_b"], dtype)
+    layout = kda_layout(Heads(nh, dk, nh, dv))
+    if mixer_form(qkv, p["conv"], layout, sharding=attn_sharding) == "pallas":
+        o, state, decay = kda_kernel_mixer(qkv, p["conv"], p["norm"]["scale"], f, p["dt_bias"], p["A_log"], gate,
+                                           beta, layout, eps=cfg.layernorm_eps, sharding=attn_sharding)
+    else:
+        with jax.named_scope(tracing.ATTN_KDA):
+            qkv = jax.nn.silu(causal_conv(qkv, p["conv"]))
+            q = (_unit(qkv[..., :key_dim].reshape(b, s, nh, dk)) * dk ** -0.5).astype(dtype)
+            k = _unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, nh, dk)).astype(dtype)
+            v = qkv[..., 2 * key_dim:].reshape(b, s, nh, dv)
+            g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+                f.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)).reshape(b, s, nh, dk)
+        with jax.named_scope(tracing.ATTN_KDA_RULE):
+            o, state = kda_rule(q, k, v, g, beta, sharding=attn_sharding)
+        with jax.named_scope(tracing.ATTN_KDA):
+            o = rms_norm(o.astype(jnp.float32), p["norm"]["scale"], cfg.layernorm_eps)
+            o = (o * jax.nn.sigmoid(gate.reshape(b, s, nh, dv).astype(jnp.float32))).astype(dtype)
+            o, decay = o.reshape(b, s, nh * dv), jnp.exp(g)
     with jax.named_scope(tracing.ATTN_KDA):
-        o = rms_norm(o.astype(jnp.float32), p["norm"]["scale"], cfg.layernorm_eps)
-        o = (o * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype).reshape(b, s, nh * dv)
         out = _dense(o, p["wout"], dtype)
-        stats = {"decay_mean": jnp.mean(jnp.exp(g)), "state_abs_max": jnp.max(jnp.abs(state))}
+        stats = {"decay_mean": jnp.mean(decay), "state_abs_max": jnp.max(jnp.abs(state))}
     return out, None, stats
 
 

@@ -390,6 +390,9 @@ def render(analysis: Dict[str, Any]) -> str:
         if "kda_kernel_layers" in comp:
             lines.append("Kimi-Delta-Attention layers whose rule runs as Pallas kernels: %d"
                          % comp["kda_kernel_layers"])
+        if "kda_pass_kernel_layers" in comp:
+            lines.append("Kimi-Delta-Attention layers whose convolution, gate and norms run as Pallas passes: %d"
+                         % comp["kda_pass_kernel_layers"])
         if "moe_row_kernel_blocks" in comp:
             lines.append("routed blocks whose rows move through the Pallas row movers: %d"
                          % comp["moe_row_kernel_blocks"])

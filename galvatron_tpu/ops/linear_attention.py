@@ -755,21 +755,8 @@ def _kernel_form(q, k, v, g, beta):
 
 
 def _sharded_kernel_form(q, k, v, g, beta, sharding: Optional[KernelSharding]):
-    """`_kernel_form` a device on its own rows of the batch, under a manual
-    region (GSPMD cannot partition a Mosaic kernel; see `KernelSharding` and
-    `ops/attention._sharded_pallas_flash`, whose pattern this is); as it is
-    where `_on_kernels` found one device (`sharding` None)."""
-    if sharding is None:
-        return _kernel_form(q, k, v, g, beta)
-    rows = sharding.batch_axes or None
-    ctx = jax.sharding.get_abstract_mesh()
-    use_mesh = sharding.mesh if ctx.empty else ctx
-    return jax.shard_map(
-        _kernel_form, mesh=use_mesh,
-        in_specs=tuple(P(rows, *(None,) * (x.ndim - 1)) for x in (q, k, v, g, beta)),
-        out_specs=(P(rows, None, None, None),) * 2,
-        axis_names=set(use_mesh.axis_names) - set(use_mesh.manual_axes), check_vma=False,
-    )(q, k, v, g, beta)
+    """`_kernel_form` a device on its own rows of the batch (`_rows_a_device`)."""
+    return _rows_a_device(_kernel_form, sharding, (q, k, v, g, beta), (), (4, 4))
 
 
 # --- the per-channel rule, the kernel form ----------------------------------
@@ -1005,6 +992,23 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, t_ref, kk_
     drows_ref[:, 1:2, :] = jnp.sum(dbeta * inv["eye"], axis=1, keepdims=True)
 
 
+def _traced_once(*static):
+    """A caller of kernels -> one traced, and lowered, once for each value of
+    its `static` arguments, its operands' shapes and the passes' tile sizes. A
+    step calls it with the same shapes in every run of layers, in the first
+    forward and in the recomputation, and tracing a kernel's unrolled body
+    again each time is what a start pays for the kernels: 0.6 s a call on the
+    chip's host, 28 s a start of the Kimi cell (PERF.md section 6, PR 44)."""
+    def wrap(fn):
+        def once(sizes, *args):
+            return fn(*args)
+
+        once.__name__ = fn.__name__
+        jitted = jax.jit(once, static_argnums=(0,) + tuple(i + 1 for i in static))
+        return functools.wraps(fn)(lambda *args: jitted((_TOKENS, _LANES, _AT_ONCE), *args))
+    return wrap
+
+
 def _beta_rows(beta):
     """beta (B, S, H) -> (B, H, tiles, 8, 128) float32, `_scalars`' layout
     with beta alone (row 1): the per-channel rule's g is an operand of its own."""
@@ -1013,17 +1017,41 @@ def _beta_rows(beta):
     return jnp.pad(rows, ((0, 0),) * 3 + ((1, _ROWS - 2), (0, 0)))
 
 
-def _kda_forward(q, k, v, g, beta, keep):
-    """`kda_fwd` on q, k, g (B, S, H, d_k), v (B, S, H, d_v): -> o as v came,
-    the final states, and with `keep` what `kda_bwd` reads again (beta's rows,
-    the states the tiles started from, every tile's `T` and `kk`)."""
+@_traced_once(0, 6)
+def _kda_flat_forward(dims, q, k, v, g, beta, keep):
+    """`kda_fwd` on q, k (B, S, H d_k), g the same float32 and v (B, S, H d_v),
+    the layout a head's block is cut from: -> o (B, S, H d_v), the final
+    states, and with `keep` what `kda_bwd` reads again (beta's rows, the states
+    the tiles started from, every tile's `T` and `kk`)."""
     rows = _beta_rows(beta)
-    out = _call(functools.partial(_kda_fwd_kernel, keep=keep), "kda_fwd", _dims(q, v), (q.dtype, v.dtype),
+    out = _call(functools.partial(_kda_fwd_kernel, keep=keep), "kda_fwd", dims, (q.dtype, v.dtype),
                 False, ["key", "key", "value", "gate", "rows"],
                 ["value"] + ["starts", "inverse", "inverse"] * keep + ["state"],
-                ["state", "by_kv", "by_k", "by_v", "starts"] + ["starts"] * (not keep),
-                (_flat(q), _flat(k), _flat(v), _flat(g.astype(_F32)), rows))
-    return out[0].reshape(v.shape), out[-1], ((rows,) + tuple(out[1:4]) if keep else None)
+                ["state", "by_kv", "by_k", "by_v", "starts"] + ["starts"] * (not keep), (q, k, v, g, rows))
+    return out[0], out[-1], ((rows,) + tuple(out[1:4]) if keep else None)
+
+
+@_traced_once(0)
+def _kda_flat_backward(dims, q, k, v, g, kept, do, dlast):
+    """`kda_bwd` on the flat operands and what `_kda_flat_forward` kept: ->
+    dq, dk (B, S, H d_k), dv (B, S, H d_v), dg (B, S, H d_k) float32, dbeta
+    (B, S, H) float32."""
+    b, s, _, _, h, _ = dims
+    rows, starts, inverse, kk = kept
+    dq, dk, dv, dg, drows = _call(
+        _kda_bwd_kernel, "kda_bwd", dims, (q.dtype, v.dtype), True,
+        ["key", "key", "value", "gate", "rows", "starts", "inverse", "inverse", "value", "state"],
+        ["keys", "keys", "value", "gate", "rows"],
+        ["state", "by_v", "by_k", "by_k", "starts", "by_v", "starts", "starts"],
+        (q, k, v, g, rows, starts, inverse, kk, do, dlast.astype(_F32)))
+    return dq, dk, dv, dg, drows[..., 1, :].reshape(b, h, s).transpose(0, 2, 1)
+
+
+def _kda_forward(q, k, v, g, beta, keep):
+    """`_kda_flat_forward` on q, k, g (B, S, H, d_k), v (B, S, H, d_v): o
+    comes back as v came."""
+    o, last, kept = _kda_flat_forward(_dims(q, v), _flat(q), _flat(k), _flat(v), _flat(g.astype(_F32)), beta, keep)
+    return o.reshape(v.shape), last, kept
 
 
 @jax.custom_vjp
@@ -1037,17 +1065,10 @@ def _kda_kernel_rule_fwd(q, k, v, g, beta):
 
 
 def _kda_kernel_rule_bwd(residuals, cotangents):
-    q, k, v, g, beta, (rows, starts, inverse, kk) = residuals
+    q, k, v, g, beta, kept = residuals
     do, dlast = cotangents
-    b, s, h, _ = v.shape
-    dq, dk, dv, dg, drows = _call(
-        _kda_bwd_kernel, "kda_bwd", _dims(q, v), (q.dtype, v.dtype), True,
-        ["key", "key", "value", "gate", "rows", "starts", "inverse", "inverse", "value", "state"],
-        ["keys", "keys", "value", "gate", "rows"],
-        ["state", "by_v", "by_k", "by_k", "starts", "by_v", "starts", "starts"],
-        (_flat(q), _flat(k), _flat(v), _flat(g.astype(_F32)), rows, starts, inverse, kk,
-         _flat(do), dlast.astype(_F32)))
-    dbeta = drows[..., 1, :].reshape(b, h, s).transpose(0, 2, 1)
+    dq, dk, dv, dg, dbeta = _kda_flat_backward(_dims(q, v), _flat(q), _flat(k), _flat(v), _flat(g.astype(_F32)),
+                                               kept, _flat(do), dlast)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             dg.reshape(g.shape).astype(g.dtype), dbeta.astype(beta.dtype))
 
@@ -1057,30 +1078,43 @@ _kda_kernel_rule.defvjp(_kda_kernel_rule_fwd, _kda_kernel_rule_bwd)
 
 # --- around the core, the kernel form ---------------------------------------
 #
-# What a gated-DeltaNet mixer does between its two projections and the core
-# (models/base.linear_mixer is the definition: the XLA form), as passes over
-# (tokens, channels) arrays in which a head is a block of whole 128-lane
-# columns: no (tokens, heads, d) view exists, so nothing is relaid, no norm's
-# scale is broadcast to full size and no slice of the projection's output is
-# written out (a block is read where it lies, through its index map).
+# What a gated-DeltaNet mixer and a Kimi-Delta-Attention mixer do between
+# their projections and the core (models/base.linear_mixer and kda_mixer are
+# the definitions: the XLA forms), as passes over (tokens, channels) arrays in
+# which a head is a block of whole 128-lane columns: no (tokens, heads, d) view
+# exists, so nothing is relaid, no norm's scale is broadcast to full size and
+# no slice of the projection's output is written out (a block is read where it
+# lies, through its index map). ONE set of kernels for both mixers; what
+# differs between them is a `Layout`, static at trace time: where q, k, v lie
+# in the projection's output (`Wqkvz`'s [q | k | v | z] with a key head
+# serving several value heads, or `Wqkv`'s [q | k | v] with one each), which
+# array the output gate z lies in (the projection's output, or one of its own)
+# and the gate's activation (SiLU, or the sigmoid).
 #
-#   before the core  `conv_norm_fwd`: a block of [q | k | v]'s channels of the
-#     projection's (tokens, 2 Hk d_k + 2 Hv d_v) output -> the convolution in
-#     float32 (the tokens before a tile from the block of `_HALO` rows that
-#     ends where the tile starts; zeros before the sequence), rounded where
-#     `causal_conv` rounds, SiLU, rounded, and for q and k the L2 norm over a
-#     head's lanes (q scaled by d_k ** -0.5). One call each for q, k and v.
+#   before the core  `conv_norm_fwd`: a block of q's, k's or v's channels of
+#     the projection's (tokens, channels) output -> the convolution in float32
+#     (the tokens before a tile from the block of `_HALO` rows that ends where
+#     the tile starts; zeros before the sequence), rounded where `causal_conv`
+#     rounds, SiLU, rounded, and for q and k the L2 norm over a head's lanes
+#     (q scaled by d_k ** -0.5). One call each for q, k and v.
+#   the per-channel rule's gate  `kda_gate_fwd`: f = (y Wfa) Wfb as it leaves
+#     the matmul, `dt_bias` a channel and `A_log` a head -> g = -exp(A_log)
+#     softplus(f + dt_bias), written once as the float32 (tokens, H d_k) array
+#     `kda_fwd` reads. The scalar rule's g is (tokens, heads): XLA's.
 #   after the core   `gated_norm_fwd`: RMSNorm of o over a head's lanes times
-#     the norm's scale times SiLU of z's block, float32, rounded once.
+#     the norm's scale times the activation of z's block, float32, rounded once.
 #
-# The backwards (`gated_norm_bwd`, `conv_norm_bwd`) make the forward's cheap
-# arithmetic again from the inputs (the projection's output and the taps; o, z
-# and the scale) and fill ONE cotangent of the projection's output, each its
-# own column blocks (`input_output_aliases` hands the array on), so that no
-# sum of padded parts is left for XLA; the taps' and the scale's gradients
-# leave as a tile's partial sums. A key head's dq, dk are summed over the value
-# heads it serves as `conv_norm_bwd` reads them. `_kernel_mixer` ties the
-# passes and the core's two kernels into one `jax.custom_vjp`.
+# The backwards (`gated_norm_bwd`, `conv_norm_bwd`, `kda_gate_bwd`) make the
+# forward's cheap arithmetic again from the inputs (the projection's output and
+# the taps; o, z and the scale; f, `dt_bias` and `A_log`) and fill ONE
+# cotangent of the projection's output, each its own column blocks
+# (`input_output_aliases` hands the array on), so that no sum of padded parts
+# is left for XLA; z's cotangent goes into the array z lies in. The taps', the
+# scale's, `dt_bias`'s and `A_log`'s gradients leave as a tile's partial sums.
+# A key head's dq, dk are summed over the value heads it serves as
+# `conv_norm_bwd` reads them. `_kernel_mixer` ties the passes and the scalar
+# core's two kernels into one `jax.custom_vjp`, `_kda_kernel_mixer` the passes
+# and the per-channel core's.
 
 # On the chip (scripts/linear_passes_sweep.py; PERF.md, PR 38) the passes are
 # bound by the vector unit, not by HBM (a v5e has no bf16 arithmetic: about 40
@@ -1168,17 +1202,17 @@ def _conv_fwd_kernel(x_ref, before_ref, taps_ref, o_ref, buf, *, taps, d, scale)
     jax.lax.fori_loop(0, t // _AT_ONCE, rows, None)
 
 
-def _conv_bwd_kernel(x_ref, before_ref, after_ref, taps_ref, d_ref, dafter_ref, into_ref,
-                     dx_ref, dtaps_ref, buf, dbuf, acc, *, taps, d, serves, scale):
+def _conv_bwd_kernel(x_ref, before_ref, after_ref, taps_ref, d_ref, dafter_ref, *rest,
+                     taps, d, serves, scale):
     """A tile's tokens of a block of channels: the forward's arithmetic again
     on the tile and on the `_HALO` tokens after it (their convolutions read
     this tile's last tokens), da = the cotangent of the convolution's sums in
     `dbuf`, the taps' partial sums over the tile's own tokens, then dx_t =
     sum_j w_j da_{t + taps - 1 - j}. `serves` value heads' shares of a key
     head's cotangent lie side by side in `d_ref` and are summed as they are
-    read. `into_ref` is the cotangent array itself, which other calls fill
-    elsewhere: never read."""
-    del into_ref
+    read. Before the outputs in `rest`, where an earlier call began it: the
+    cotangent array itself, which other calls fill elsewhere: never read."""
+    dx_ref, dtaps_ref, buf, dbuf, acc = rest[-5:]
     t, c = x_ref.shape
     tile = pl.program_id(1)
     _stage(buf, x_ref, before_ref, tile == 0, after_ref)
@@ -1233,6 +1267,11 @@ def _conv_bwd_kernel(x_ref, before_ref, after_ref, taps_ref, d_ref, dafter_ref, 
         dtaps_ref[j:j + 1, :] = jnp.sum(acc[j], axis=0, keepdims=True)
 
 
+# the output gate's activation of z given sigmoid(z), and its derivative
+_GATES = {"silu": (lambda z, sig: z * sig, _dsilu),
+          "sigmoid": (lambda z, sig: sig, lambda z, sig: sig * (1.0 - sig))}
+
+
 def _gated(o, z, w, eps):
     """o, z a head's lanes of some tokens, w (1, d) -> float32: the unit-rms
     o, the norm's factor, z, its sigmoid."""
@@ -1241,27 +1280,27 @@ def _gated(o, z, w, eps):
     return o32 * r, r, z32, jax.nn.sigmoid(z32)
 
 
-def _gate_fwd_kernel(o_ref, z_ref, scale_ref, out_ref, *, d, eps):
+def _gate_fwd_kernel(o_ref, z_ref, scale_ref, out_ref, *, d, eps, gate):
     t, c = o_ref.shape
-    w = scale_ref[...]
+    w, (act, _) = scale_ref[...], _GATES[gate]
 
     def rows(n, carry):
         at = pl.ds(pl.multiple_of(n * _AT_ONCE, _AT_ONCE), _AT_ONCE)
         for h in range(c // d):
             lanes = slice(h * d, (h + 1) * d)
             unit, _, z32, sig = _gated(o_ref[at, lanes], z_ref[at, lanes], w, eps)
-            out_ref[at, lanes] = (unit * w * (z32 * sig)).astype(out_ref.dtype)
+            out_ref[at, lanes] = (unit * w * act(z32, sig)).astype(out_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, t // _AT_ONCE, rows, None)
 
 
-def _gate_bwd_kernel(o_ref, z_ref, scale_ref, d_ref, dz_ref, do_ref, dscale_ref, *, d, eps):
-    """out = unit w silu(z), unit = o r: dz, do and the scale's partial sum
+def _gate_bwd_kernel(o_ref, z_ref, scale_ref, d_ref, dz_ref, do_ref, dscale_ref, *, d, eps, gate):
+    """out = unit w act(z), unit = o r: dz, do and the scale's partial sum
     over the step's tokens and heads (eight rows of them: the rows' sum is
     taken outside)."""
     t, c = o_ref.shape
-    w = scale_ref[...]
+    w, (act, dact) = scale_ref[...], _GATES[gate]
     dscale_ref[...] = jnp.zeros_like(dscale_ref)
 
     def rows(n, carry):
@@ -1270,12 +1309,58 @@ def _gate_bwd_kernel(o_ref, z_ref, scale_ref, d_ref, dz_ref, do_ref, dscale_ref,
             lanes = slice(h * d, (h + 1) * d)
             unit, r, z32, sig = _gated(o_ref[at, lanes], z_ref[at, lanes], w, eps)
             got = d_ref[at, lanes].astype(_F32)
-            dnormed = got * (z32 * sig)
-            dz_ref[at, lanes] = (got * (unit * w) * _dsilu(z32, sig)).astype(dz_ref.dtype)
+            dnormed = got * act(z32, sig)
+            dz_ref[at, lanes] = (got * (unit * w) * dact(z32, sig)).astype(dz_ref.dtype)
             dscale_ref[...] += jnp.sum((dnormed * unit).reshape(_AT_ONCE // 8, 8, d), axis=0)
             dunit = dnormed * w
             do_ref[at, lanes] = (r * (dunit - unit * jnp.mean(dunit * unit, axis=-1, keepdims=True))
                                  ).astype(do_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, t // _AT_ONCE, rows, None)
+
+
+def _softplus(x):
+    """-> softplus(x) as `jax.nn.softplus` sums it (max(x, 0) + log1p(e^-|x|))
+    and sigmoid(x), its derivative, from the same exponential."""
+    e = jnp.exp(-jnp.abs(x))
+    return jnp.maximum(x, 0.0) + jnp.log1p(e), jnp.where(x >= 0, 1.0, e) / (1.0 + e)
+
+
+def _channel_gate_fwd_kernel(f_ref, rows_ref, g_ref, *, d):
+    """The per-channel rule's gate, float32: rows_ref's row 0 is -exp(A_log)
+    (a head's in each of its lanes), row 1 `dt_bias`."""
+    t, c = f_ref.shape
+
+    def rows(n, carry):
+        at = pl.ds(pl.multiple_of(n * _AT_ONCE, _AT_ONCE), _AT_ONCE)
+        for h in range(c // d):
+            lanes = slice(h * d, (h + 1) * d)
+            g_ref[at, lanes] = rows_ref[0:1, lanes] * _softplus(f_ref[at, lanes].astype(_F32) + rows_ref[1:2, lanes])[0]
+        return carry
+
+    jax.lax.fori_loop(0, t // _AT_ONCE, rows, None)
+
+
+def _channel_gate_bwd_kernel(f_ref, rows_ref, dg_ref, df_ref, sums_ref, *, d):
+    """g = rate softplus(x), x = f + dt_bias: df = dx rounded, and the step's
+    partial sums over its tokens, eight rows each (the rows' sums are taken
+    outside): of dx (`dt_bias`'s gradient) and of dg g (`A_log`'s: dg / dA_log
+    = g; a head's lanes still to be summed)."""
+    t, c = f_ref.shape
+    sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    def rows(n, carry):
+        at = pl.ds(pl.multiple_of(n * _AT_ONCE, _AT_ONCE), _AT_ONCE)
+        for h in range(c // d):
+            lanes = slice(h * d, (h + 1) * d)
+            rate = rows_ref[0:1, lanes]
+            sp, sig = _softplus(f_ref[at, lanes].astype(_F32) + rows_ref[1:2, lanes])
+            dg = dg_ref[at, lanes]
+            dx = dg * (rate * sig)
+            df_ref[at, lanes] = dx.astype(df_ref.dtype)
+            sums_ref[0:8, lanes] += jnp.sum(dx.reshape(_AT_ONCE // 8, 8, d), axis=0)
+            sums_ref[8:16, lanes] += jnp.sum((dg * (rate * sp)).reshape(_AT_ONCE // 8, 8, d), axis=0)
         return carry
 
     jax.lax.fori_loop(0, t // _AT_ONCE, rows, None)
@@ -1290,22 +1375,42 @@ def _pass(kernel, name, grid, in_specs, out_specs, out_shape, scratch=(), aliase
         name=name)
 
 
+# Columns of an array of (tokens, channels): the first, how many, a head's
+# width, the block of channels a pass takes of them (None where no block of
+# whole heads fits), what their L2 norm is scaled by (None: no norm) and how
+# many value heads' shares of their cotangent the core's backward hands on
+# side by side.
 Segment = collections.namedtuple("Segment", "start width d lanes scale serves")
+# What the passes are told of the mixer that calls them: its heads, q's, k's
+# and v's columns of the projection's output, z's columns of the array the
+# output gate lies in, the gate's activation (a key of `_GATES`) and the names
+# `TOOK` counts the passes under.
+Layout = collections.namedtuple("Layout", "heads qkv z gate counted")
 
 
-def _segments(heads: Heads):
-    """[q | k | v | z]'s columns of the projection's output, each with its
-    first column, its width, a head's width, the block of channels a pass
-    takes of it (None where no block of whole heads fits), what its L2 norm
-    is scaled by (None: no norm) and how many value heads' shares of its
-    cotangent the core's backward hands on side by side."""
+def _qkv(heads: Heads):
+    """q's, k's, v's columns of a projection's output that starts [q | k | v]."""
     keys, values = heads.key_heads * heads.d_k, heads.value_heads * heads.d_v
-    key_lanes, value_lanes = _lanes(heads.d_k, keys), _lanes(heads.d_v, 2 * keys, values)
-    shares = heads.value_heads // heads.key_heads
+    key_lanes, shares = _lanes(heads.d_k, keys), heads.value_heads // heads.key_heads
     return (Segment(0, keys, heads.d_k, key_lanes, heads.d_k ** -0.5, shares),
             Segment(keys, keys, heads.d_k, key_lanes, 1.0, shares),
-            Segment(2 * keys, values, heads.d_v, value_lanes, None, 1),
-            Segment(2 * keys + values, values, heads.d_v, value_lanes, None, 1))
+            Segment(2 * keys, values, heads.d_v, _lanes(heads.d_v, 2 * keys, values), None, 1))
+
+
+def linear_layout(heads: Heads) -> Layout:
+    """A gated-DeltaNet mixer's: `Wqkvz`'s output [q | k | v | z], a key head
+    serving value / key heads, SiLU(z) the output gate."""
+    qkv = _qkv(heads)
+    v = qkv[2]  # z lies behind v, as wide and cut into the same blocks
+    return Layout(heads, qkv, v._replace(start=v.start + v.width), "silu", ("conv_norm", "gated_norm"))
+
+
+def kda_layout(heads: Heads) -> Layout:
+    """A Kimi-Delta-Attention mixer's: `Wqkv`'s output [q | k | v], the output
+    gate an array of its own, sigmoid(z); and the per-channel gate's pass."""
+    values = heads.value_heads * heads.d_v
+    return Layout(heads, _qkv(heads), Segment(0, values, heads.d_v, _lanes(heads.d_v, values), None, 1),
+                  "sigmoid", ("kda_conv_norm", "kda_gate", "kda_gated_norm"))
 
 
 def _taps_rows(taps):  # (channels, K) -> (_TAPS, channels) float32, a tap a row
@@ -1328,30 +1433,33 @@ def _conv_blocks(seg, s, t):
             pl.BlockSpec((_TAPS, seg.lanes), lambda i, n, j: (0, first + j))], after
 
 
-def _conv_norm(heads, qkvz, taps):
-    """The pass before the core: qkvz (B, S, 2 Hk d_k + 2 Hv d_v), taps
-    (channels of q, k, v; K) -> q, k (B, S, Hk d_k), v (B, S, Hv d_v)."""
-    b, s, _ = qkvz.shape
+def _conv_norm(segments, x, taps):
+    """The pass before the core: x (B, S, channels) the projection's output,
+    `segments` q's, k's and v's columns of it, taps (their channels; K) -> q, k
+    (B, S, Hk d_k), v (B, S, Hv d_v)."""
+    b, s, _ = x.shape
     t, rows = _tokens(s), _taps_rows(taps)
     return [_pass(
         functools.partial(_conv_fwd_kernel, taps=taps.shape[1], d=seg.d, scale=seg.scale),
         "conv_norm_fwd", (b, s // t, seg.width // seg.lanes), _conv_blocks(seg, s, t)[0],
         pl.BlockSpec((None, t, seg.lanes), lambda i, n, j: (i, n, j)),
-        jax.ShapeDtypeStruct((b, s, seg.width), qkvz.dtype),
-        [pltpu.VMEM((t + _HALO, seg.lanes), _F32)])(qkvz, qkvz, rows) for seg in _segments(heads)[:3]]
+        jax.ShapeDtypeStruct((b, s, seg.width), x.dtype),
+        [pltpu.VMEM((t + _HALO, seg.lanes), _F32)])(x, x, rows) for seg in segments]
 
 
-def _conv_norm_bwd(heads, qkvz, taps, dq, dk, dv, into):
-    """`_conv_norm`'s backward: dq, dk (B, S, Hv d_k: a value head's share
-    each), dv (B, S, Hv d_v), `into` the projection's output's cotangent with
-    z's columns filled -> it with q, k, v's filled too, and the taps'
-    gradient (channels, K) float32."""
-    b, s, _ = qkvz.shape
+def _conv_norm_bwd(segments, x, taps, cotangents, into=None):
+    """`_conv_norm`'s backward: `cotangents` dq, dk (B, S, Hv d_k: a value
+    head's share each) and dv (B, S, Hv d_v); `into` the projection's output's
+    cotangent where another pass has filled columns of it (z's), None where q,
+    k, v are all of it -> it with q's, k's, v's filled, and the taps' gradient
+    (channels, K) float32."""
+    b, s, _ = x.shape
     t, rows = _tokens(s), _taps_rows(taps)
     dtaps = []
-    for seg, got in zip(_segments(heads), (dq, dk, dv)):
+    for seg, got in zip(segments, cotangents):
         c, first = seg.lanes, seg.start // seg.lanes
         (tile, before, taps_block), after = _conv_blocks(seg, s, t)
+        begun = into is not None  # the array is handed on from call to call
         into, partial = _pass(
             functools.partial(_conv_bwd_kernel, taps=taps.shape[1], d=seg.d, serves=seg.serves, scale=seg.scale),
             "conv_norm_bwd", (b, s // t, seg.width // c),
@@ -1359,91 +1467,184 @@ def _conv_norm_bwd(heads, qkvz, taps, dq, dk, dv, into):
              pl.BlockSpec((None, _HALO, c), lambda i, n, j, after=after, first=first: (i, after(n), first + j)),
              taps_block,
              pl.BlockSpec((None, t, seg.serves * c), lambda i, n, j: (i, n, j)),
-             pl.BlockSpec((None, _HALO, seg.serves * c), lambda i, n, j, after=after: (i, after(n), j)),
-             pl.BlockSpec(memory_space=pl.ANY)],
+             pl.BlockSpec((None, _HALO, seg.serves * c), lambda i, n, j, after=after: (i, after(n), j))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * begun,
             [tile, pl.BlockSpec((None, None, _TAPS, c), lambda i, n, j: (i, n, 0, j))],
-            [jax.ShapeDtypeStruct(into.shape, into.dtype),
+            [jax.ShapeDtypeStruct(x.shape, x.dtype),
              jax.ShapeDtypeStruct((b, s // t, _TAPS, seg.width), _F32)],
             [pltpu.VMEM((t + 2 * _HALO, c), _F32), pltpu.VMEM((t + _HALO, c), _F32),
              pltpu.VMEM((_TAPS, 8, c), _F32)],
-            aliases={6: 0})(qkvz, qkvz, qkvz, rows, got, got, into)
+            aliases={6: 0} if begun else None)(x, x, x, rows, got, got, *([into] * begun))
         dtaps.append(jnp.sum(partial, axis=(0, 1))[:taps.shape[1]].T)
     return into, jnp.concatenate(dtaps, axis=0)
 
 
-def _gated_norm(heads, eps, o, qkvz, scale):
-    """The pass after the core: o (B, S, Hv d_v), z = qkvz's last columns,
-    scale (d_v,) -> RMSNorm(o; scale) a head x SiLU(z), (B, S, Hv d_v)."""
+def _gated_norm(layout, eps, o, within, scale):
+    """The pass after the core: o (B, S, Hv d_v), z = `layout.z`'s columns of
+    `within`, scale (d_v,) -> RMSNorm(o; scale) a head x the gate's activation
+    of z, (B, S, Hv d_v)."""
     b, s, width = o.shape
-    z = _segments(heads)[3]
-    t, c, first = _tokens(s), z.lanes, z.start // z.lanes
+    d = layout.heads.d_v
+    t, c, first = _tokens(s), layout.z.lanes, layout.z.start // layout.z.lanes
     return _pass(
-        functools.partial(_gate_fwd_kernel, d=heads.d_v, eps=eps), "gated_norm_fwd",
+        functools.partial(_gate_fwd_kernel, d=d, eps=eps, gate=layout.gate), "gated_norm_fwd",
         (b, s // t, width // c),
         [pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, j)),
          pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, first + j)),
-         pl.BlockSpec((1, heads.d_v), lambda i, n, j: (0, 0))],
+         pl.BlockSpec((1, d), lambda i, n, j: (0, 0))],
         pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, j)),
-        jax.ShapeDtypeStruct(o.shape, o.dtype))(o, qkvz, scale.astype(_F32)[None])
+        jax.ShapeDtypeStruct(o.shape, o.dtype))(o, within, scale.astype(_F32)[None])
 
 
-def _gated_norm_bwd(heads, eps, o, qkvz, scale, dout):
-    """`_gated_norm`'s backward: -> the projection's output's cotangent with
-    z's columns filled AND NO OTHER (`_conv_norm_bwd` fills the rest), do, the
-    scale's gradient float32."""
+def _gated_norm_bwd(layout, eps, o, within, scale, dout):
+    """`_gated_norm`'s backward: -> `within`'s cotangent with z's columns
+    filled AND NO OTHER (where that is the projection's output,
+    `_conv_norm_bwd` fills the rest), do, the scale's gradient float32."""
     b, s, width = o.shape
-    z = _segments(heads)[3]
-    t, c, first = _tokens(s), z.lanes, z.start // z.lanes
+    d = layout.heads.d_v
+    t, c, first = _tokens(s), layout.z.lanes, layout.z.start // layout.z.lanes
     here = pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, j))
-    dqkvz, do, partial = _pass(
-        functools.partial(_gate_bwd_kernel, d=heads.d_v, eps=eps), "gated_norm_bwd",
+    dwithin, do, partial = _pass(
+        functools.partial(_gate_bwd_kernel, d=d, eps=eps, gate=layout.gate), "gated_norm_bwd",
         (b, s // t, width // c),
         [here, pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, first + j)),
-         pl.BlockSpec((1, heads.d_v), lambda i, n, j: (0, 0)), here],
+         pl.BlockSpec((1, d), lambda i, n, j: (0, 0)), here],
         [pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, first + j)), here,
-         pl.BlockSpec((None, None, None, 8, heads.d_v), lambda i, n, j: (i, n, j, 0, 0))],
-        [jax.ShapeDtypeStruct(qkvz.shape, qkvz.dtype), jax.ShapeDtypeStruct(o.shape, o.dtype),
-         jax.ShapeDtypeStruct((b, s // t, width // c, 8, heads.d_v), _F32)],
-    )(o, qkvz, scale.astype(_F32)[None], dout)
-    return dqkvz, do, jnp.sum(partial, axis=(0, 1, 2, 3))
+         pl.BlockSpec((None, None, None, 8, d), lambda i, n, j: (i, n, j, 0, 0))],
+        [jax.ShapeDtypeStruct(within.shape, within.dtype), jax.ShapeDtypeStruct(o.shape, o.dtype),
+         jax.ShapeDtypeStruct((b, s // t, width // c, 8, d), _F32)],
+    )(o, within, scale.astype(_F32)[None], dout)
+    return dwithin, do, jnp.sum(partial, axis=(0, 1, 2, 3))
 
 
-def _mixer(heads, eps, qkvz, taps, scale, g, beta, keep):
+def _gate_rows(heads, dt_bias, a_log):
+    """-> (8, H d_k) float32: row 0 -exp(A_log), a head's in each of its
+    lanes, row 1 `dt_bias`."""
+    rate = jnp.repeat(-jnp.exp(a_log.astype(_F32)), heads.d_k)
+    return jnp.pad(jnp.stack([rate, dt_bias.astype(_F32)]), ((0, _ROWS - 2), (0, 0)))
+
+
+@_traced_once(0)
+def _channel_gate(layout, f, dt_bias, a_log):
+    """The per-channel rule's gate: f (B, S, H d_k) as the matmul left it,
+    dt_bias (H d_k,), a_log (H,) -> g = -exp(A_log) softplus(f + dt_bias),
+    (B, S, H d_k) float32."""
+    b, s, width = f.shape
+    t, c = _tokens(s), layout.qkv[0].lanes
+    here = pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, j))
+    return _pass(
+        functools.partial(_channel_gate_fwd_kernel, d=layout.heads.d_k), "kda_gate_fwd", (b, s // t, width // c),
+        [here, pl.BlockSpec((_ROWS, c), lambda i, n, j: (0, j))], here,
+        jax.ShapeDtypeStruct(f.shape, _F32))(f, _gate_rows(layout.heads, dt_bias, a_log))
+
+
+@_traced_once(0)
+def _channel_gate_bwd(layout, f, dt_bias, a_log, dg):
+    """`_channel_gate`'s backward: dg (B, S, H d_k) float32 -> df in f's
+    dtype, `dt_bias`'s gradient (H d_k,) and `A_log`'s (H,), float32."""
+    b, s, width = f.shape
+    heads = layout.heads
+    t, c = _tokens(s), layout.qkv[0].lanes
+    here = pl.BlockSpec((None, t, c), lambda i, n, j: (i, n, j))
+    df, partial = _pass(
+        functools.partial(_channel_gate_bwd_kernel, d=heads.d_k), "kda_gate_bwd", (b, s // t, width // c),
+        [here, pl.BlockSpec((_ROWS, c), lambda i, n, j: (0, j)), here],
+        [here, pl.BlockSpec((None, None, 2 * _ROWS, c), lambda i, n, j: (i, n, 0, j))],
+        [jax.ShapeDtypeStruct(f.shape, f.dtype), jax.ShapeDtypeStruct((b, s // t, 2 * _ROWS, width), _F32)],
+    )(f, _gate_rows(heads, dt_bias, a_log), dg)
+    sums = jnp.sum(partial.reshape(b * (s // t), 2, _ROWS, width), axis=(0, 2))
+    return df, sums[0], jnp.sum(sums[1].reshape(-1, heads.d_k), axis=1)
+
+
+def _mixer(layout, eps, qkvz, taps, scale, g, beta, keep):
     """Convolution and norms, the core, the gated norm, each under its scope
     (the call sits under neither: an op carries one of the two)."""
-    dims = qkvz.shape[:2] + tuple(heads)
+    dims = qkvz.shape[:2] + tuple(layout.heads)
     with jax.named_scope(tracing.ATTN_LINEAR):
-        q, k, v = _conv_norm(heads, qkvz, taps)
+        q, k, v = _conv_norm(layout.qkv, qkvz, taps)
     with jax.named_scope(tracing.ATTN_DELTA):
         o, last, kept = _flat_forward(dims, q, k, v, g, beta, keep)
     with jax.named_scope(tracing.ATTN_LINEAR):
-        out = _gated_norm(heads, eps, o, qkvz, scale)
+        out = _gated_norm(layout, eps, o, qkvz, scale)
     return (out, last), (qkvz, taps, scale, q, k, v, kept, o)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _kernel_mixer(heads, eps, qkvz, taps, scale, g, beta):
-    return _mixer(heads, eps, qkvz, taps, scale, g, beta, keep=False)[0]
+def _kernel_mixer(layout, eps, qkvz, taps, scale, g, beta):
+    return _mixer(layout, eps, qkvz, taps, scale, g, beta, keep=False)[0]
 
 
-def _kernel_mixer_fwd(heads, eps, qkvz, taps, scale, g, beta):
-    return _mixer(heads, eps, qkvz, taps, scale, g, beta, keep=True)
+def _kernel_mixer_fwd(layout, eps, qkvz, taps, scale, g, beta):
+    return _mixer(layout, eps, qkvz, taps, scale, g, beta, keep=True)
 
 
-def _kernel_mixer_bwd(heads, eps, residuals, cotangents):
+def _kernel_mixer_bwd(layout, eps, residuals, cotangents):
     qkvz, taps, scale, q, k, v, kept, o = residuals
     dout, dlast = cotangents
-    dims = qkvz.shape[:2] + tuple(heads)
+    dims = qkvz.shape[:2] + tuple(layout.heads)
     with jax.named_scope(tracing.ATTN_LINEAR):
-        dqkvz, do, dscale = _gated_norm_bwd(heads, eps, o, qkvz, scale, dout)
+        dqkvz, do, dscale = _gated_norm_bwd(layout, eps, o, qkvz, scale, dout)
     with jax.named_scope(tracing.ATTN_DELTA):
         dq, dk, dv, dg, dbeta = _flat_backward(dims, q, k, v, kept, do, dlast)
     with jax.named_scope(tracing.ATTN_LINEAR):
-        dqkvz, dtaps = _conv_norm_bwd(heads, qkvz, taps, dq, dk, dv, dqkvz)
+        dqkvz, dtaps = _conv_norm_bwd(layout.qkv, qkvz, taps, (dq, dk, dv), dqkvz)
     return dqkvz, dtaps.astype(taps.dtype), dscale.astype(scale.dtype), dg, dbeta
 
 
 _kernel_mixer.defvjp(_kernel_mixer_fwd, _kernel_mixer_bwd)
+
+
+# The shared passes as the per-channel mixer calls them (`_traced_once`: the
+# Kimi cell's KDA layers lie in three runs of layers, so a step would trace
+# each pass nine times; the linear mixer's calls stay as they were, and its
+# cell's compiled step the parent's to the last instruction's number).
+_kda_conv_norm, _kda_conv_norm_bwd = _traced_once(0)(_conv_norm), _traced_once(0)(_conv_norm_bwd)
+_kda_gated_norm, _kda_gated_norm_bwd = _traced_once(0, 1)(_gated_norm), _traced_once(0, 1)(_gated_norm_bwd)
+
+
+def _kda_mixer(layout, eps, qkv, taps, scale, f, dt_bias, a_log, z, beta, keep):
+    """`_mixer` for the per-channel rule: the gate's pass beside the
+    convolution's, the core's two kernels and beta's rows alone under
+    `gt.attn.kda_rule` (its roofline reads that scope), all else under
+    `gt.attn.kda_mixer`. The third result is the counter's mean of exp(g) a
+    row of the batch: no gradient flows through it."""
+    dims = qkv.shape[:2] + tuple(layout.heads)
+    with jax.named_scope(tracing.ATTN_KDA):
+        q, k, v = _kda_conv_norm(layout.qkv, qkv, taps)
+        g = _channel_gate(layout, f, dt_bias, a_log)
+    with jax.named_scope(tracing.ATTN_KDA_RULE):
+        o, last, kept = _kda_flat_forward(dims, q, k, v, g, beta, keep)
+    with jax.named_scope(tracing.ATTN_KDA):
+        out = _kda_gated_norm(layout, eps, o, z, scale)
+        decay = jnp.mean(jnp.exp(g), axis=(1, 2))
+    return (out, last, decay), (qkv, taps, scale, f, dt_bias, a_log, z, q, k, v, g, kept, o)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _kda_kernel_mixer(layout, eps, qkv, taps, scale, f, dt_bias, a_log, z, beta):
+    return _kda_mixer(layout, eps, qkv, taps, scale, f, dt_bias, a_log, z, beta, keep=False)[0]
+
+
+def _kda_kernel_mixer_fwd(layout, eps, qkv, taps, scale, f, dt_bias, a_log, z, beta):
+    return _kda_mixer(layout, eps, qkv, taps, scale, f, dt_bias, a_log, z, beta, keep=True)
+
+
+def _kda_kernel_mixer_bwd(layout, eps, residuals, cotangents):
+    qkv, taps, scale, f, dt_bias, a_log, z, q, k, v, g, kept, o = residuals
+    dout, dlast, _ = cotangents
+    dims = qkv.shape[:2] + tuple(layout.heads)
+    with jax.named_scope(tracing.ATTN_KDA):
+        dz, do, dscale = _kda_gated_norm_bwd(layout, eps, o, z, scale, dout)
+    with jax.named_scope(tracing.ATTN_KDA_RULE):
+        dq, dk, dv, dg, dbeta = _kda_flat_backward(dims, q, k, v, g, kept, do, dlast)
+    with jax.named_scope(tracing.ATTN_KDA):
+        dqkv, dtaps = _kda_conv_norm_bwd(layout.qkv, qkv, taps, (dq, dk, dv))
+        df, dbias, da_log = _channel_gate_bwd(layout, f, dt_bias, a_log, dg)
+    return (dqkv, dtaps.astype(taps.dtype), dscale.astype(scale.dtype), df, dbias.astype(dt_bias.dtype),
+            da_log.astype(a_log.dtype), dz, dbeta)
+
+
+_kda_kernel_mixer.defvjp(_kda_kernel_mixer_fwd, _kda_kernel_mixer_bwd)
 
 
 def _on_kernels(sharding, batch, fits):
@@ -1457,50 +1658,80 @@ def _on_kernels(sharding, batch, fits):
     return on_tpu and fits and (sharding is None or sharding.divides(batch, 1)), sharding
 
 
-def mixer_form(qkvz: jax.Array, taps: jax.Array, heads: Heads, *, impl: str = "auto",
+def mixer_form(x: jax.Array, taps: jax.Array, layout: Layout, *, impl: str = "auto",
                sharding: Optional[KernelSharding] = None) -> str:
     """The form the passes around the core take for this projection's output
-    (B, S, 2 Hk d_k + 2 Hv d_v) and these taps (channels, K): "pallas"
-    (`kernel_mixer`: with the core, one rule) or "xla" (the caller's own
-    arithmetic around `gated_delta_rule`). `impl` "auto": the kernels where
-    `gated_delta_rule` would take its own (TPUs, heads multiples of 128 wide,
-    one device or whole rows of the batch a device) and the sequence is a
-    multiple of the passes' smallest tile of tokens, the taps at most `_TAPS`
-    and a block of whole heads divides every segment. Counted in `TOOK`,
-    a pass a key."""
+    (B, S, channels), these taps (channels of q, k, v; K) and the calling
+    mixer's `layout`: "pallas" (`kernel_mixer` / `kda_kernel_mixer`: with the
+    core, one rule) or "xla" (the caller's own arithmetic around
+    `gated_delta_rule` / `kda_rule`). `impl` "auto": the kernels where the
+    core would take its own (TPUs, heads multiples of 128 wide, one device or
+    whole rows of the batch a device) and the sequence is a multiple of the
+    passes' smallest tile of tokens, the taps at most `_TAPS` and a block of
+    whole heads divides every segment. Counted in `TOOK`, a pass a key
+    (`layout.counted`)."""
     if impl == "auto":
-        fits = (heads.d_k % TILE == 0 and heads.d_v % TILE == 0 and _tokens(qkvz.shape[1]) is not None
+        heads = layout.heads
+        fits = (heads.d_k % TILE == 0 and heads.d_v % TILE == 0 and _tokens(x.shape[1]) is not None
                 and taps.shape[1] <= _TAPS and heads.value_heads % heads.key_heads == 0
-                and all(seg.lanes is not None for seg in _segments(heads)))
-        impl = "pallas" if _on_kernels(sharding, qkvz.shape[0], fits)[0] else "xla"
-    TOOK["conv_norm_" + impl] += 1
-    TOOK["gated_norm_" + impl] += 1
+                and all(seg.lanes is not None for seg in layout.qkv + (layout.z,)))
+        impl = "pallas" if _on_kernels(sharding, x.shape[0], fits)[0] else "xla"
+    for name in layout.counted:
+        TOOK[name + "_" + impl] += 1
     return impl
 
 
-def kernel_mixer(qkvz: jax.Array, taps: jax.Array, scale: jax.Array, g: jax.Array, beta: jax.Array,
-                 heads: Heads, *, eps: float, sharding: Optional[KernelSharding] = None
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """A gated-DeltaNet mixer between its two projections, the kernel form
-    (where `mixer_form` says "pallas"): qkvz (B, S, [q | k | v | z]), taps
-    (channels of q, k, v; K), scale (d_v,) the gated norm's, g, beta (B, S,
-    Hv) float32 -> RMSNorm(o) x SiLU(z) (B, S, Hv d_v) in qkvz's dtype and the
-    final states (B, Hv, d_k, d_v) float32. Its ops carry `gt.attn.linear`
-    or, the core's, `gt.attn.delta`: call it under neither."""
-    TOOK["pallas"] += 1  # the core's form
-    sharding = _on_kernels(sharding, qkvz.shape[0], True)[1]
-    rule = functools.partial(_kernel_mixer, heads, eps)
+def _rows_a_device(rule, sharding, operands, weights, out_ranks):
+    """`rule(*operands)` a device on its own rows of the batch, under a manual
+    region (GSPMD cannot partition a Mosaic kernel; see `KernelSharding` and
+    `ops/attention._sharded_pallas_flash`, whose pattern this is); as it is
+    where `_on_kernels` found one device (`sharding` None). `weights`: which
+    operands lie whole on every device; `out_ranks`: the results' ranks, each
+    over the batch."""
     if sharding is None:
-        return rule(qkvz, taps, scale, g, beta)
+        return rule(*operands)
     rows = sharding.batch_axes or None
     ctx = jax.sharding.get_abstract_mesh()
     use_mesh = sharding.mesh if ctx.empty else ctx
     return jax.shard_map(
         rule, mesh=use_mesh,
-        in_specs=(P(rows, None, None), P(None, None), P(None), P(rows, None, None), P(rows, None, None)),
-        out_specs=(P(rows, None, None), P(rows, None, None, None)),
+        in_specs=tuple(P(*(None,) * x.ndim) if i in weights else P(rows, *(None,) * (x.ndim - 1))
+                       for i, x in enumerate(operands)),
+        out_specs=tuple(P(rows, *(None,) * (rank - 1)) for rank in out_ranks),
         axis_names=set(use_mesh.axis_names) - set(use_mesh.manual_axes), check_vma=False,
-    )(qkvz, taps, scale, g, beta)
+    )(*operands)
+
+
+def kernel_mixer(qkvz: jax.Array, taps: jax.Array, scale: jax.Array, g: jax.Array, beta: jax.Array,
+                 layout: Layout, *, eps: float, sharding: Optional[KernelSharding] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """A gated-DeltaNet mixer between its two projections, the kernel form
+    (where `mixer_form` says "pallas" of `linear_layout`): qkvz (B, S, [q | k |
+    v | z]), taps (channels of q, k, v; K), scale (d_v,) the gated norm's, g,
+    beta (B, S, Hv) float32 -> RMSNorm(o) x SiLU(z) (B, S, Hv d_v) in qkvz's
+    dtype and the final states (B, Hv, d_k, d_v) float32. Its ops carry
+    `gt.attn.linear` or, the core's, `gt.attn.delta`: call it under neither."""
+    TOOK["pallas"] += 1  # the core's form
+    return _rows_a_device(functools.partial(_kernel_mixer, layout, eps), _on_kernels(sharding, qkvz.shape[0], True)[1],
+                          (qkvz, taps, scale, g, beta), (1, 2), (3, 4))
+
+
+def kda_kernel_mixer(qkv: jax.Array, taps: jax.Array, scale: jax.Array, f: jax.Array, dt_bias: jax.Array,
+                     a_log: jax.Array, z: jax.Array, beta: jax.Array, layout: Layout, *, eps: float,
+                     sharding: Optional[KernelSharding] = None) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A Kimi-Delta-Attention mixer between its projections, the kernel form
+    (where `mixer_form` says "pallas" of `kda_layout`): qkv (B, S, [q | k |
+    v]), taps (their channels; K), scale (d_v,) the gated norm's, f (B, S, H
+    d_k) the gate's low-rank projection and z (B, S, H d_v) the output gate's
+    as the matmuls left them, dt_bias (H d_k,), a_log (H,), beta (B, S, H)
+    float32 -> RMSNorm(o) x sigmoid(z) (B, S, H d_v) in qkv's dtype, the final
+    states (B, H, d_k, d_v) float32 and the mean of exp(g) a row of the batch
+    (a counter: no gradient). Its ops carry `gt.attn.kda_mixer` or, the
+    core's, `gt.attn.kda_rule`: call it under neither."""
+    TOOK["kda_pallas"] += 1  # the core's form
+    return _rows_a_device(functools.partial(_kda_kernel_mixer, layout, eps),
+                          _on_kernels(sharding, qkv.shape[0], True)[1],
+                          (qkv, taps, scale, f, dt_bias, a_log, z, beta), (1, 2, 4, 5), (3, 4, 1))
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,

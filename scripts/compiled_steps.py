@@ -13,13 +13,16 @@ benchmark files over it so that both sides read the same cells), and writes the
 optimized HLO a cell. `diff` compares two such directories after taking out what
 names the SOURCE and not the program: the tables of files, functions and
 locations and the `stack_frame_id`s (a line added above a function moves
-them), `metadata={...}`, the checkout's path, and a Mosaic kernel's serialized
-body (its MLIR carries source locations too; a PR that edits a kernel compares
-kernels on the chip). Exit 1 where a cell differs. One process a checkout: the
+them), `metadata={...}`, the checkout's path, and the source locations inside
+a Mosaic kernel's serialized body (the body is read back as MLIR and printed
+without them: a kernel whose program changed differs, one whose file only
+moved does not). Exit 1 where a cell differs. One process a checkout: the
 program is imported from it."""
 
 from __future__ import annotations
 
+import base64
+import hashlib
 import json
 import os
 import re
@@ -77,6 +80,22 @@ def dump(root: str, out: str, workloads) -> None:
 TABLES = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\b")
 
 
+def kernel(config: "re.Match") -> str:
+    """A Mosaic kernel's `backend_config` -> a digest of its MLIR printed
+    without source locations (and of what else the config holds)."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    fields, end = json.JSONDecoder().raw_decode(config.group(1))
+    body = base64.b64decode(fields["custom_call_config"].pop("body"))
+    context = mlir.make_ir_context()
+    context.allow_unregistered_dialects = True  # the serialized form is a versioned dialect of its own
+    with context:
+        text = ir.Module.parse(body).operation.get_asm(enable_debug_info=False)
+    digest = hashlib.sha1((text + json.dumps(fields, sort_keys=True)).encode()).hexdigest()
+    return "backend_config=<kernel %s>%s" % (digest, config.group(1)[end:])
+
+
 def instructions(path: str):
     """The HLO's lines without what names the source."""
     out, in_table = [], False
@@ -88,7 +107,7 @@ def instructions(path: str):
         else:
             line = re.sub(r",? ?stack_frame_id=\d+", "", line)
             line = re.sub(r", metadata=\{[^}]*\}", "", line)
-            line = re.sub(r'backend_config=\{[^\n]*"serialization_format"[^\n]*', "backend_config=<kernel>", line)
+            line = re.sub(r'backend_config=(\{[^\n]*"serialization_format"[^\n]*)', kernel, line)
             out.append(line)
     return out
 

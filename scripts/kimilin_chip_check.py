@@ -18,8 +18,9 @@ reads:
   compared, by a `jax.debug.callback` around the router), and the share of
   tokens whose pick differs from the float32 reference's in any block;
 - **the per-channel delta rule, EVERY KDA layer**: the layer's q, k, v, g, beta
-  as the program makes them (bf16 operands, the float32 gate a head and
-  channel), handed out of one forward through the stack, through
+  as the mixer's XLA form makes them (bf16 operands, the float32 gate a head
+  and channel; `mixer_form` held to "xla" for that one forward through the
+  stack: the kernel form hands its core flat arrays inside one rule), through
   `ops/linear_attention.kda_rule` and through the reference's token-by-token
   recurrence in float32 on the chip (`kda_recurrence`): the relative error of
   `o` over the whole sequence and over the LAST 64 tokens, where 8192 tokens of
@@ -27,8 +28,20 @@ reads:
   against the same recurrence in FLOAT64 ON THE HOST (`final_states_float64`:
   the float32 recurrence on the chip is itself off where a channel forgets
   least, its `exp` reading low 8192 times in a row: PERF.md, PR 36);
+- **the passes around the core** (PR 44: `conv_norm_*`, `kda_gate_*`,
+  `gated_norm_*`: the convolution, SiLU, the L2 norms, the per-channel gate's
+  softplus and the gated RMSNorm x sigmoid as Pallas passes): layer 0's
+  whole mixer through them against the XLA form of the same
+  arithmetic (`mixer_form` held to "xla"; the core is the kernels' in both) on
+  the same weights and the same normed activations: the relative error of the
+  mixer's output and, of a probe's gradient, the worst leaf's (the mixer's
+  twelve leaves and its input);
 - every leaf's gradient twice, against the reference as it routes itself and
   against the reference HELD TO THE PROGRAM'S ROUTING (`forced_experts`).
+
+**A control that breaks the passes, on the first seed, which must FAIL**: the
+same mixer through the kernels with the convolution's first tap dropped (a
+three-tap convolution), against the XLA form with all four.
 
 **A control in the next lower precision, on the first seed, which must FAIL at
 least one limit**: layer 0's rule, in its XLA form (the program's own is the
@@ -38,7 +51,7 @@ every chunk (`_carry` replaced by one that rounds with
 compiled as a program of its own). Writes `chiprun_out/kimilin_chip_check.json`;
 its LAST line of output is the verdict with each measure's largest reading over
 the seeds beside its limit; exits 1 unless the program passes on every seed and
-the control fails. Refuses to run where jax finds no TPU.
+both controls fail. Refuses to run where jax finds no TPU.
 
 Why two gradient comparisons: scripts/olmoe_chip_check.py's docstring.
 """
@@ -81,11 +94,22 @@ CELL = "kimilin-c1-s8k"
 # and `k . E` to bf16 at every level of the halving, the XLA form kept its 16 x
 # 16 diagonal blocks float32: 2^-9 twice where it was once; twelve readings from
 # 3.42e-3 to 3.73e-3); the limits are as they were, the control the XLA form's.
+# Since PR 44 what lies between the projections and the core runs as Pallas
+# passes, and layer 0's mixer through them is held to its XLA form (my chip
+# run, PR 44, call 2, the same seeds; the control on seed 32):
+#   passes_out               7.17e-3  dropped tap 0.762   (7.02e-3 to 7.17e-3 over the seeds: both forms in bf16)
+#   passes_worst_leaf        8.26e-3  dropped tap 0.883   (`wf_a`'s kernel on every seed; the twelve leaves 6.8e-3 to 8.3e-3)
+# each limit between its two readings: 4.2 x and 6.1 x the program's largest,
+# 1 / 25 and 1 / 18 of the control's (the Qwen3-Next check's limits for the same
+# passes, which read 7.1e-3 and 9.8e-3 there). The eight older limits did not
+# move: `core_state` 2.84e-6, `core_o` 3.47e-3, `core_o_last_chunk` 3.73e-3,
+# `loss` 4.4e-4, `worst_leaf_same_routing` 0.186, `worst_leaf` 0.354.
 # (The first version of the REFERENCE read every leaf 15 % off and the loss up
 # to 1.9e-3 off: XLA:TPU shifted its convolution within 1024-row tiles; PERF.md
 # section 6, PR 42. These limits would have caught it: 0.35 and 0.52 then.)
 LIMITS = {"loss": 2e-3, "router": 1e-5, "core_state": 1e-4, "core_o": 4.3e-3, "core_o_last_chunk": 4.3e-3,
-          "tokens_flipped_share": 0.70, "worst_leaf_same_routing": 0.22, "worst_leaf": 0.45}
+          "tokens_flipped_share": 0.70, "worst_leaf_same_routing": 0.22, "worst_leaf": 0.45,
+          "passes_out": 0.03, "passes_worst_leaf": 0.05}
 
 
 def main(argv=None) -> int:
@@ -121,6 +145,10 @@ def main(argv=None) -> int:
     kinds = cfg.layer_kinds()
     kda_layers = [i for i, kind in enumerate(kinds) if kind.startswith("kda")]
     committed_router, committed_rule, committed_carry = moe.router_logits, M.kda_rule, L._carry
+    committed_form = M.mixer_form
+
+    def xla_form(*_, **__):
+        return "xla"
 
     def reference_loss(p, given):
         parts = ref.loss_parts(p, given, fields)
@@ -148,8 +176,9 @@ def main(argv=None) -> int:
 
     @jax.jit
     def rule_operands(params, tokens):
-        """Every KDA layer's (q, k, v, g, beta) as the program makes them, in
-        the layers' order: one unrolled forward through the stack."""
+        """Every KDA layer's (q, k, v, g, beta) as the mixer's XLA form makes
+        them (the form that hands the core its operands), in the layers'
+        order: one unrolled forward through the stack."""
         x = M.embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg)
         handed = []
 
@@ -157,13 +186,56 @@ def main(argv=None) -> int:
             handed.append(operands)
             return committed_rule(*operands, **where)
 
-        M.kda_rule = spy
+        M.kda_rule, M.mixer_form = spy, xla_form
         try:
             for lp, kind in zip(params["layers"], kinds):
                 x = M.layer_forward(lp, x, jnp.arange(seq)[None], cfg.layer_config(kind))[0]
         finally:
-            M.kda_rule = committed_rule
+            M.kda_rule, M.mixer_form = committed_rule, committed_form
         return handed
+
+    def mixer_errors(params, tokens, with_control):
+        """Layer 0's mixer (the projections, the passes, the core)
+        on the normed activations the program hands it, through the passes'
+        kernels and through the XLA form, each a program of its own: the
+        output, and every leaf's gradient of a fixed probe of it."""
+        lcfg, lp = cfg.layer_config(kinds[0]), params["layers"][0]  # the stack's first layer is a KDA layer
+        y = jax.jit(lambda: M._norm(M.embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg),
+                                    lp["ln1"], lcfg))()
+        probe = jax.random.normal(jax.random.PRNGKey(17), y.shape, jnp.float32)
+
+        def run(form, taps_dropped=0):
+            def of(kda, y):
+                kda = dict(kda, conv=kda["conv"].at[:, :taps_dropped].set(0.0))
+                M.mixer_form = form
+                try:
+                    out = M.kda_mixer({"kda": kda}, y, None, lcfg)[0]
+                finally:
+                    M.mixer_form = committed_form
+                return jnp.sum(out.astype(jnp.float32) * probe), out
+
+            fn = jax.jit(jax.value_and_grad(of, argnums=(0, 1), has_aux=True))
+            if form is committed_form:
+                text = fn.lower(lp["kda"], y).as_text()
+                assert all(name in text for name in ("conv_norm_fwd", "kda_gate_bwd", "gated_norm_bwd")), (
+                    "on the chip the passes' form is the kernels'")
+            (_, out), grads = fn(lp["kda"], y)
+            return jax.device_get((out, grads))
+
+        rel = lambda g, r: float(np.linalg.norm(np.asarray(g, np.float64) - np.asarray(r, np.float64))  # noqa: E731
+                                 / np.linalg.norm(np.asarray(r, np.float64)))
+        want_out, want_grads = run(xla_form)
+
+        def against_the_xla_form(out, grads):
+            leaves = {jax.tree_util.keystr(path): rel(g, r) for (path, g), r in zip(
+                jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(want_grads))}
+            return {"passes_out": rel(out, want_out), "passes_worst_leaf": max(leaves.values()),
+                    "passes_worst_leaf_name": max(leaves, key=leaves.get), "leaves": leaves}
+
+        errors = {"program": against_the_xla_form(*run(committed_form))}
+        if with_control:
+            errors["control_dropped_tap"] = against_the_xla_form(*run(committed_form, taps_dropped=1))
+        return errors
 
     @jax.jit
     def recurrence(q, kk, v, g, beta):
@@ -283,7 +355,8 @@ def main(argv=None) -> int:
                                                  / np.mean(exact ** 2))))
             return worst
 
-        out = {"seed": seed, "core": core_errors(params, tokens, with_control)}
+        out = {"seed": seed, "passes": mixer_errors(params, tokens, with_control),
+               "core": core_errors(params, tokens, with_control)}
         ref_parts, ref_grads, ref_picks = reference()
         ref_sets = as_sets(ref_picks)
         out["reference"] = ref_parts
@@ -299,6 +372,8 @@ def main(argv=None) -> int:
             "loss": abs(parts["loss"] - ref_parts["loss"]),
             "router": router_error(seen),  # on the very rows it was given
             "core_o": worst(0), "core_o_last_chunk": worst(1), "core_state": worst(2),
+            "passes_out": out["passes"]["program"]["passes_out"],
+            "passes_worst_leaf": out["passes"]["program"]["passes_worst_leaf"],
             "tokens_flipped_share": float(np.mean(np.any(differs, axis=0))),
             "worst_leaf_same_routing": max(same.values()),
             "worst_leaf": max(free.values()),
@@ -318,7 +393,7 @@ def main(argv=None) -> int:
         verdicts = {"program": not out["program"]["outside_limits"]}
         print("seed %d" % seed, "program", "PASS" if verdicts["program"] else "FAIL", json.dumps(
             {n: v for n, v in out["program"].items() if not n.startswith("leaves")}),
-            "core", json.dumps(out["core"]), flush=True)
+            "core", json.dumps(out["core"]), "passes", json.dumps(out["passes"]), flush=True)
         if with_control:
             measured = dict(zip(("core_o", "core_o_last_chunk", "core_state"),
                                 out["core"][kda_layers[0]]["control_bf16_state"]))
@@ -327,26 +402,33 @@ def main(argv=None) -> int:
             verdicts["control_bf16_state"] = not outside
             print("seed %d" % seed, "control_bf16_state", "PASS" if not outside else "FAIL",
                   json.dumps(out["control_bf16_state"]), flush=True)
+            measured = {n: out["passes"]["control_dropped_tap"][n] for n in ("passes_out", "passes_worst_leaf")}
+            outside = {n: [v, LIMITS[n]] for n, v in measured.items() if v > LIMITS[n]}
+            out["control_dropped_tap"] = {"measured": measured, "outside_limits": outside}
+            verdicts["control_dropped_tap"] = not outside
+            print("seed %d" % seed, "control_dropped_tap", "PASS" if not outside else "FAIL",
+                  json.dumps(out["control_dropped_tap"]), flush=True)
         return out, verdicts
 
-    runs, sound, control_fails = [], True, False
+    runs, sound, controls_fail = [], True, {"control_bf16_state": False, "control_dropped_tap": False}
     for i, seed in enumerate(seeds):
         out, verdicts = one_seed(seed, with_control=i == 0)
         runs.append(out)
         sound = sound and verdicts["program"]
-        control_fails = control_fails or not verdicts.get("control_bf16_state", True)
+        controls_fail = {name: failed or not verdicts.get(name, True) for name, failed in controls_fail.items()}
     largest = {n: max(r["program"]["measured"][n] for r in runs) for n in LIMITS}
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "kimilin_chip_check.json"), "w") as f:
         json.dump({"device": jax.devices()[0].device_kind, "tokens": seq, "limits": LIMITS,
                    "largest_over_seeds": largest, "runs": runs}, f, indent=1)
-    ok = sound and control_fails
-    print("VERDICT %s: the program within its limits on seeds %s: %s; the bf16-state control outside: %s; "
-          "largest reading [limit]: %s; the bf16-state control: %s" % (
-              "PASS" if ok else "FAIL", seeds, sound, control_fails,
+    ok = sound and all(controls_fail.values())
+    print("VERDICT %s: the program within its limits on seeds %s: %s; the controls outside: %s; "
+          "largest reading [limit]: %s; the bf16-state control: %s; the dropped-tap control: %s" % (
+              "PASS" if ok else "FAIL", seeds, sound, json.dumps(controls_fail),
               json.dumps({n: [largest[n], LIMITS[n]] for n in LIMITS}),
-              json.dumps(runs[0]["control_bf16_state"]["measured"])), flush=True)
+              json.dumps(runs[0]["control_bf16_state"]["measured"]),
+              json.dumps(runs[0]["control_dropped_tap"]["measured"])), flush=True)
     return 0 if ok else 1
 
 

@@ -72,6 +72,7 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, count
     assert all("linear_kernel_layers" not in e for e in events if e["type"] == "compile")
     # its own are: off a TPU none of the four KDA layers takes `kda_fwd` / `kda_bwd`, and the report says so
     assert [e["kda_kernel_layers"] for e in events if e["type"] == "compile"] == [0]
+    assert [e["kda_pass_kernel_layers"] for e in events if e["type"] == "compile"] == [0]  # nor the passes around it
 
 
 @pytest.mark.parametrize("flags", [

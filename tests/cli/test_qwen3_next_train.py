@@ -58,7 +58,8 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, tmp_p
     # off a TPU none of the three linear layers takes the Pallas kernels, and the compile report says so
     assert [e["linear_kernel_layers"] for e in events if e["type"] == "compile"] == [0]
     assert [e["linear_pass_kernel_layers"] for e in events if e["type"] == "compile"] == [0]  # nor the passes around it
-    assert all("kda_kernel_layers" not in e for e in events if e["type"] == "compile")  # no KDA layer: nothing said
+    assert all("kda_kernel_layers" not in e and "kda_pass_kernel_layers" not in e
+               for e in events if e["type"] == "compile")  # no KDA layer: nothing said
     assert [e["moe_row_kernel_blocks"] for e in events if e["type"] == "compile"] == [0]  # nor do the four routed blocks' rows move by DMA
 
 

@@ -80,6 +80,7 @@ def test_lifecycle_events_present(telemetry_run):
     assert comp["compiled_memory_mb"] > 0
     assert "linear_kernel_layers" not in comp  # a model without linear-attention layers says nothing of them
     assert "linear_pass_kernel_layers" not in comp and "kda_kernel_layers" not in comp
+    assert "kda_pass_kernel_layers" not in comp
     assert "moe_row_kernel_blocks" not in comp  # nor, without a routed block, of the row movers
     assert t["checkpoint_save"][0]["iteration"] == ITERS
     assert t["layer_run"], "per-LayerRun predictions missing"

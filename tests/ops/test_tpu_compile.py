@@ -637,6 +637,12 @@ def test_the_delta_rule_keeps_a_state_a_chunk_and_runs_on_the_mxu_on_v5e(v5e_2x2
                                 or _count_instructions(compiled_with("xla").as_text()))
 
 
+def _calls(text, kernel, scope):
+    """Custom calls of `kernel` whose op carries `scope` right above the
+    kernel's name (or above the jit its caller is traced once under)."""
+    return len(re.findall(r'custom-call\(.*op_name="[^"]*%s/(?:jit\([^)]*\)/)?%s[/"]' % (re.escape(scope), kernel), text))
+
+
 @pytest.fixture(scope="module")
 def qwen3_next_linear_layer(v5e_2x2):
     """One linear layer's mixer of the Qwen3-Next cell (8192 tokens, hidden
@@ -687,9 +693,7 @@ def test_the_linear_layers_surround_is_lane_aligned_passes_on_v5e(qwen3_next_lin
     assert took == {"conv_norm_pallas": 1, "gated_norm_pallas": 1, "pallas": 1}
     tokens, keys = 8192, 2048
 
-    def calls(kernel, scope):
-        return len(re.findall(r'custom-call\(.*op_name="[^"]*%s/%s[/"]' % (re.escape(scope), kernel), text))
-
+    calls = functools.partial(_calls, text)
     # a call each for q, k and v; the forward and its recomputation are one here (no scan between them)
     assert calls("conv_norm_fwd", tracing.ATTN_LINEAR) == 3 and calls("conv_norm_bwd", tracing.ATTN_LINEAR) == 3
     assert calls("gated_norm_fwd", tracing.ATTN_LINEAR) == 1 and calls("gated_norm_bwd", tracing.ATTN_LINEAR) == 1
@@ -719,7 +723,7 @@ def kimi_kda_layer(v5e_2x2):
     """One Kimi-Delta-Attention mixer at the Kimi-Linear cell's widths (8192
     tokens, hidden 2304, 32 heads of 128, bf16) under the cell's
     recomputation, forward and backward, compiled for one described chip:
-    -> (the optimised module's text, the forms its core took)."""
+    -> (the optimised module's text, the forms its core and its passes took)."""
     from galvatron_tpu.models import base as M
     from galvatron_tpu.models.kimi_linear import kimi_linear_config
     from galvatron_tpu.ops import linear_attention as L
@@ -751,20 +755,54 @@ def test_the_kda_layers_core_is_two_kernels_once_each_on_v5e(kimi_kda_layer):
     rule keeps its own residuals: the backward does not run the forward again;
     the first forward and the recomputation are one here, no scan between
     them), nothing of them under the surround's scope, which its own readers
-    read, and no view of the activations by (tokens, 32, 128) under the
-    core's: a head is a block of 128 lanes of a (tokens, 4096) array."""
+    read, no other kernel under the core's (its roofline divides a fixed cost
+    by all that scope holds), and no view of the activations by (tokens, 32,
+    128) under it: a head is a block of 128 lanes of a (tokens, 4096) array."""
     from galvatron_tpu.obs import tracing
 
     text, took = kimi_kda_layer
-    assert took == {"kda_pallas": 1}
+    assert took["kda_pallas"] == 1 and "kda_xla" not in took
     tokens = 8192
-
-    def calls(kernel, scope):
-        return len(re.findall(r'custom-call\(.*op_name="[^"]*%s/%s[/"]' % (re.escape(scope), kernel), text))
-
-    assert calls("kda_fwd", tracing.ATTN_KDA_RULE) == 1 and calls("kda_bwd", tracing.ATTN_KDA_RULE) == 1
-    assert text.count("tpu_custom_call") == 2
-    assert not calls("kda_fwd", tracing.ATTN_KDA) and not calls("kda_bwd", tracing.ATTN_KDA)
+    assert _calls(text, "kda_fwd", tracing.ATTN_KDA_RULE) == 1 and _calls(text, "kda_bwd", tracing.ATTN_KDA_RULE) == 1
+    assert len(re.findall(r'custom-call\(.*op_name="[^"]*%s/' % re.escape(tracing.ATTN_KDA_RULE), text)) == 2
+    assert not _calls(text, "kda_fwd", tracing.ATTN_KDA) and not _calls(text, "kda_bwd", tracing.ATTN_KDA)
     for line in text.splitlines():
         if tracing.ATTN_KDA_RULE in line:
             assert not re.search(r"\[(?:1,)?%d,32,128\]" % tokens, line), line[:200]
+
+
+def test_the_kda_layers_surround_is_lane_aligned_passes_on_v5e(kimi_kda_layer):
+    """Between its projections and the core a Kimi-Delta-Attention layer runs
+    as Pallas passes over (tokens, channels) arrays, a head a block of 128
+    lanes (ops/linear_attention.py: the linear layers' kernels under another
+    `Layout`, and the per-channel gate's pair): every pass is there under
+    `gt.attn.kda_mixer` by its name and none under `gt.attn.kda_rule`; no view
+    of an activation by (tokens, 32, 128) is left ANYWHERE in the module; and
+    under the mixer's scope no float32 (tokens, 4096) or (tokens, 12288) array
+    is reshaped, copied, transposed or broadcast and no slice, pad or
+    concatenation of an activation is written out."""
+    from galvatron_tpu.obs import tracing
+
+    text, took = kimi_kda_layer
+    assert took == {"kda_pallas": 1, "kda_conv_norm_pallas": 1, "kda_gate_pallas": 1, "kda_gated_norm_pallas": 1}
+    tokens, smallest = 8192, 2048
+    passes = {"conv_norm_fwd": 3, "conv_norm_bwd": 3, "kda_gate_fwd": 1, "kda_gate_bwd": 1,
+              "gated_norm_fwd": 1, "gated_norm_bwd": 1}  # a call each for q, k and v; forward and recomputation are one here
+    for kernel, count in passes.items():
+        assert _calls(text, kernel, tracing.ATTN_KDA) == count, kernel
+        assert not _calls(text, kernel, tracing.ATTN_KDA_RULE), kernel
+    assert text.count("tpu_custom_call") == 2 + sum(passes.values())
+    assert not re.search(r"\[(?:1,)?%d,32,128\]" % tokens, text)  # no view by heads
+    offenders = []
+    for line in text.splitlines():
+        found = re.match(r"\s+(?:ROOT )?(\S+) = (.*?[})]) ([a-z\-]+)\(", line)
+        if not found or tracing.ATTN_KDA not in line:
+            continue
+        name, result, kind = found.groups()
+        over_tokens = {dtype for dtype, dims in re.findall(r"\b(f32|bf16)\[([\d,]*)\]", result)
+                       if str(tokens) in dims.split(",")
+                       and np.prod([int(d) for d in dims.split(",")]) >= tokens * smallest}
+        if (("f32" in over_tokens and kind in ("reshape", "copy", "transpose", "broadcast"))
+                or (over_tokens and kind in ("slice", "dynamic-slice", "pad", "concatenate"))):
+            offenders.append("%s = %s %s" % (name, result[:80], kind))
+    assert not offenders, "\n".join(offenders)
