@@ -48,8 +48,7 @@ CODES: Dict[str, Tuple[str, str]] = {
     "GLS015": (ERROR, "serve world infeasible after mesh degradation"),
     "GLS016": (ERROR, "state motion changed the layout-invariant integrity digest"),
     "GLS017": (ERROR, "online autotuner fighting a pinned strategy"),
-    "GLS018": (ERROR, "routed-experts, latent-attention, linear-attention, Kimi-Delta-Attention or state-space config "
-                      "under a layout or mode with no form of it"),
+    "GLS018": (ERROR, "a part of the model (models/parts) has no form under this layout, mode or tool"),
     # ---- strategy linter (GLS1xx cost-model-backed warnings) ----
     "GLS101": (WARNING, "estimated per-device memory exceeds the HBM budget"),
     "GLS102": (WARNING, "expensive cross-layer redistribution between adjacent layers"),

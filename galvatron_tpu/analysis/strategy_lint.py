@@ -197,16 +197,6 @@ def _tp_comm_mode_diagnostics(hp: HybridParallelConfig, model_cfg: Any) -> List[
     return out
 
 
-def _expert_layout_diagnostics(hp: HybridParallelConfig, model_cfg: Any,
-                               mode: Optional[str], autotune: Optional[str]) -> List[D.Diagnostic]:
-    """GLS018, pre-trace: the reason models/base.run_layers and
-    construct_hybrid_parallel_model raise with at trace time."""
-    from galvatron_tpu.models.base import expert_layout_diagnostic, expert_layout_reason
-
-    reason = expert_layout_reason(model_cfg, hp, mode, autotune)
-    return [] if reason is None else [expert_layout_diagnostic(reason)]
-
-
 # ----------------------------------------------------- cost-model warnings
 
 
@@ -538,7 +528,13 @@ def lint_hp(
     if model_cfg is not None:
         report.extend(_model_aware_diagnostics(hp, model_cfg))
     report.extend(_tp_comm_mode_diagnostics(hp, model_cfg))
-    report.extend(_expert_layout_diagnostics(hp, model_cfg, mode, autotune))
+    from galvatron_tpu.models.parts import unsupported_reason
+    from galvatron_tpu.parallel.quant_collectives import wants_quant_comm
+
+    # GLS018, pre-trace: what construct_hybrid_parallel_model raises at trace time
+    reason = unsupported_reason(model_cfg, hp, mode, autotune, quant=wants_quant_comm(hp))
+    if reason is not None:
+        report.add(D.make("GLS018", reason))
     report.extend(_comm_quant_diagnostics(hp, model_cfg, anomaly_guard))
     report.extend(_warning_diagnostics(hp, model_cfg, memory_budget_gb, memory_profile))
     if mode == "serve":

@@ -667,13 +667,3 @@ def model_config_from_args(args):
             "family config_fn" % (sorted(overrides), fam.name, e)
         ) from None
     return fam, cfg
-
-
-def uniform_strategy_args_sanity(args, world_size: int):
-    per_stage = world_size // max(args.pp_deg, 1)
-    need = args.global_tp_deg * args.global_cp_deg
-    if per_stage % need != 0:
-        raise ValueError(
-            "tp*cp=%d does not divide per-stage devices %d (world=%d pp=%d)"
-            % (need, per_stage, world_size, args.pp_deg)
-        )

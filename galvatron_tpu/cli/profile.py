@@ -19,12 +19,9 @@ def profile_model(args) -> dict:
 
     enable_persistent_cache()
     fam, cfg = model_config_from_args(args)
-    from galvatron_tpu.models.base import linear_layers_reason, refuse_expert_layout
+    from galvatron_tpu.models.base import refuse_unsupported
 
-    linear = linear_layers_reason(cfg)
-    if getattr(cfg, "routed", False) or linear:
-        refuse_expert_layout("profile (the layer profiler times the dense block)"
-                             + ("; %s" % linear if linear else ""))
+    refuse_unsupported(cfg, asker="profile")
     pargs = ModelProfileArgs(
         profile_type=args.profile_type,
         profile_mode=args.profile_mode,

@@ -82,13 +82,10 @@ def _model_paths(args, fam, cfg) -> dict:
 
 def search(args, world_size: Optional[int] = None) -> dict:
     fam, cfg = model_config_from_args(args)
-    from galvatron_tpu.models.base import linear_layers_reason, refuse_expert_layout
+    from galvatron_tpu.models.base import refuse_unsupported
 
-    linear = linear_layers_reason(cfg)
-    if getattr(cfg, "routed", False) or linear:
-        # the cost models know the dense block alone: refuse, do not price it as dense
-        refuse_expert_layout("search (the cost models have no expert layer)"
-                             + ("; %s" % linear if linear else ""))
+    # the cost models know the dense block alone: refuse, do not price a part as dense
+    refuse_unsupported(cfg, asker="search")
     world_size = world_size or int(os.environ.get("GALVATRON_WORLD_SIZE", "8"))
     if fam.layer_configs_fn is not None:
         # multi-layer-type families (t5 enc/dec, swin per stage): the DP

@@ -670,12 +670,6 @@ class HybridParallelConfig:
     def max_cp(self) -> int:
         return max([s.cp for s in self.layers] + [self.vocab_cp])
 
-    @property
-    def microbatch_size(self) -> int:
-        if self.global_bsz % self.chunks != 0:
-            raise ValueError("global_bsz must divide evenly into chunks (pad upstream)")
-        return self.global_bsz // self.chunks
-
     # ------------------------------------------------------------ constructors
     @classmethod
     def uniform(

@@ -14,7 +14,8 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.registry import ModelFamily, register
 
 META_CONFIGS = {
     "bert-base": dict(hidden_size=768, num_heads=12, num_layers=12, max_seq_len=512),
@@ -159,20 +160,14 @@ def export_hf_bert(params: Dict[str, Any], cfg: TransformerConfig) -> Dict[str, 
     return out
 
 
-def _register():
-    from galvatron_tpu.models.registry import ModelFamily, register
-
-    register(
-        ModelFamily(
-            name="bert",
-            config_fn=bert_config,
-            meta_configs=META_CONFIGS,
-            default_size="bert-base",
-            convert_from_hf=convert_hf_bert,
-            export_to_hf=export_hf_bert,
-            config_from_hf=bert_config_from_hf,
-        )
+register(
+    ModelFamily(
+        name="bert",
+        config_fn=bert_config,
+        meta_configs=META_CONFIGS,
+        default_size="bert-base",
+        convert_from_hf=convert_hf_bert,
+        export_to_hf=export_hf_bert,
+        config_from_hf=bert_config_from_hf,
     )
-
-
-_register()
+)

@@ -1,0 +1,270 @@
+"""`TransformerConfig`: what a published model states, and which parts its layers are built of.
+
+One config covers the reference's model zoo: GPT (learned pos, pre-LN, gelu),
+LLaMA (rope, rmsnorm, swiglu, GQA), BERT/ViT (bidirectional, post-LN), T5
+(relative bias, enc-dec glue in models/t5.py), and the sparse-expert,
+latent-attention, linear-attention and state-space families. A layer is two
+entries of the tables in `models/parts`, a token mixer and an MLP half:
+`mixers()` and `mlp_halves()` name them a layer, and what an entry asks of the
+config (`validate`) and hands back (`counters`) is the entry's to say."""
+
+from __future__ import annotations
+
+import dataclasses
+from dataclasses import dataclass
+from typing import Any, List, Optional, Tuple
+
+import jax.numpy as jnp
+
+
+@dataclass
+class TransformerConfig:
+    hidden_size: int
+    num_heads: int
+    num_layers: int
+    vocab_size: int
+    max_seq_len: int = 2048
+    num_kv_heads: Optional[int] = None
+    ffn_hidden: Optional[int] = None
+    head_dim: Optional[int] = None
+    norm_type: str = "layernorm"  # layernorm | rmsnorm
+    activation: str = "gelu"  # gelu | swiglu | relu
+    position_type: str = "learned"  # learned | rope | none
+    causal: bool = True
+    pre_norm: bool = True
+    tie_embeddings: bool = True
+    qkv_bias: bool = True
+    mlp_bias: bool = True
+    out_bias: bool = True
+    layernorm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    compute_dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    attn_impl: str = "auto"
+    # initializer scales
+    init_std: float = 0.02
+    # --- encoder-family extensions (bert_hf / vit_hf, SURVEY.md §2.4) ---
+    type_vocab_size: int = 0  # BERT token-type embeddings
+    embed_norm: bool = False  # LayerNorm after the embedding sum (BERT)
+    head_type: str = "lm"  # lm | mlm | classification
+    num_classes: int = 0
+    pool_type: str = "cls"  # cls | mean (classification pooling)
+    input_type: str = "tokens"  # tokens | patches (vision)
+    image_size: int = 224
+    patch_size: int = 16
+    num_channels: int = 3
+    use_cls_token: bool = False
+    # --- what a published sparse-expert config states (OLMoE); the defaults
+    # are the dense model, whose step none of these touches ---
+    num_experts: int = 0  # > 0: the MLP half is routed experts of width ffn_hidden
+    experts_per_token: int = 0
+    norm_topk_prob: bool = False  # renormalise the chosen experts' weights
+    router_aux_loss_coef: float = 0.0  # x the load-balancing loss
+    router_z_loss_coef: float = 0.0  # x the router z-loss
+    # a norm of q and of k before rope: True over the WHOLE projection (OLMoE),
+    # "head" over each head's dims with one scale for all heads (Qwen3-Next)
+    qk_norm: Any = False
+    # --- what GLM-4.7-Flash's published config adds (glm4_moe_lite, the
+    # DeepSeek-V3 block); again the defaults are the model without them ---
+    # latent attention (MLA): q and k/v are projected down to a low rank,
+    # normed there and projected up a head; a head's q and k are `qk_nope`
+    # dims without positions beside `qk_rope` rotated ones, and the rotated
+    # half of k is ONE vector shared by all heads. `q_lora_rank` 0: q is
+    # projected a head straight from the hidden state (Kimi-Linear), and
+    # `position_type` "none" leaves the `qk_rope` dims unrotated. `head_dim` is
+    # the width of the ONE attention call, qk_nope + qk_rope or wider: q and k
+    # (at 1 / sqrt(qk_nope + qk_rope)) and a narrower v are padded to it with zeros
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0  # > 0: latent attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    first_dense_layers: int = 0  # leading layers whose MLP half is dense, of width
+    dense_ffn_hidden: Optional[int] = None  # this (ffn_hidden is then ONE expert's)
+    num_shared_experts: int = 0  # dense SwiGLU(s) of the experts' width beside the routed ones
+    router_score: str = "softmax"  # softmax | sigmoid (scores an expert independently)
+    routed_scaling_factor: float = 1.0  # x the chosen experts' weights
+    # `noaux_tc`: the choice of experts adds a bias to the scores that no
+    # gradient moves; once a step it moves by this much against the sign of
+    # each expert's load (arXiv:2412.19437 2.1.2). 0.0 holds the bias still
+    router_bias: bool = False
+    router_bias_update_rate: float = 0.0
+    # a chip's share of the experts: the router ranks all `num_experts`, this
+    # program holds `experts_held` of them from `experts_held_start` on and
+    # computes their part of the result (0: all of them)
+    experts_held: int = 0
+    experts_held_start: int = 0
+    mtp_layers: int = 0  # multi-token-prediction modules (0 or 1) after the stack
+    mtp_loss_weight: float = 0.0  # x the cross entropy of the token after next
+    # --- what Qwen3-Next's published config adds (qwen3_next): layers whose
+    # token mixer is a gated-DeltaNet linear attention (`linear_mixer`,
+    # ops/linear_attention.py) among layers of gated softmax attention ---
+    # > 0: layer i attends where (i + 1) % this == 0 and is linear elsewhere
+    full_attention_interval: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0  # each key head serves value / key heads
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 0  # taps of the causal convolution on q, k and v
+    partial_rotary_factor: float = 1.0  # rope on this share of a head's leading dims
+    attn_output_gate: bool = False  # q is projected beside a gate: attn x sigmoid(gate)
+    norm_zero_centered: bool = False  # RMSNorm scales by (1 + w), w from 0
+    shared_expert_gate: bool = False  # the shared expert x sigmoid(y w), w (hidden, 1)
+    # --- what Granite-4.0-H's published config adds (granitemoehybrid):
+    # Mamba-2 state-space layers (`ssm_mixer`, ops/ssd.py) among layers of
+    # softmax attention without positions, and four multipliers ---
+    # the token mixer of each layer in HF's words, "mamba" (the mixer "ssm"),
+    # "kda" (Kimi Delta Attention, `kda_mixer`: its heads and convolution are
+    # the `linear_*` fields above) or "attention", where the pattern is a LIST
+    # (HF `layer_types`; Kimi-Linear's two lists of layer numbers) and no
+    # interval says it. A model cut in depth runs the list's first `num_layers`
+    # entries, so the published list may stay whole
+    layer_types: Optional[List[str]] = None
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_dim: int = 0  # a head's state is (ssm_head_dim, ssm_state_dim); B and C one group
+    ssm_conv_kernel: int = 0  # taps of the causal convolution on [x | B | C], with a bias
+    # each a Python float whose default is the model without it: a factor of
+    # 1.0 is not multiplied by, so every other model's arithmetic is bit for
+    # bit what it was
+    embedding_multiplier: float = 1.0  # x the embedding's rows
+    residual_multiplier: float = 1.0  # x each half's output before it joins the residual stream
+    attention_multiplier: Optional[float] = None  # the softmax's scale in place of 1 / sqrt(head_dim)
+    logits_scaling: float = 1.0  # the head's logits are divided by it
+    # which `MIXERS` entry ONE layer runs. `layer_config(kind)` sets it; a
+    # model's own config leaves it and states the pattern above
+    mixer: str = "attention"
+
+
+    def __post_init__(self):
+        if self.num_kv_heads is None:
+            self.num_kv_heads = self.num_heads
+        if self.ffn_hidden is None:
+            self.ffn_hidden = 4 * self.hidden_size
+        if self.mtp_layers not in (0, 1):
+            raise ValueError("mtp_layers=%d: one multi-token-prediction module at most"
+                             % self.mtp_layers)
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError("qk_norm=%r: False, True (the whole projection) or \"head\""
+                             % (self.qk_norm,))
+        if self.layer_types is not None:
+            self.layer_types = list(self.layer_types)
+            if (len(self.layer_types) < self.num_layers or self.full_attention_interval
+                    or set(self.layer_types) - {"mamba", "kda", "attention"}):
+                raise ValueError(
+                    "layer_types names the mixer, \"mamba\", \"kda\" or \"attention\", of each of "
+                    "the %d layers (or more: the first so many are run), and no "
+                    "full_attention_interval beside it; got %r" % (self.num_layers, self.layer_types))
+        for part in self.parts():  # each part's own clause (latent attention's may set head_dim)
+            part.validate(self)
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_heads
+        if self.input_type == "patches":
+            n_patches = (self.image_size // self.patch_size) ** 2
+            self.max_seq_len = n_patches + (1 if self.use_cls_token else 0)
+
+    @property
+    def fused_qkv(self) -> bool:
+        return self.num_kv_heads == self.num_heads and not self.attn_output_gate
+
+    @property
+    def mlp_fan_in(self) -> tuple:
+        """MLP input-projection kernel trailing dims: (2, ffn) for swiglu
+        (fused gate+up, split on an unsharded leading dim) else (ffn,)."""
+        return (2, self.ffn_hidden) if self.activation == "swiglu" else (self.ffn_hidden,)
+
+    @property
+    def routed(self) -> bool:
+        """Whether the model has layers whose MLP half is routed experts
+        (ops/moe.py): all of them but the `first_dense_layers`."""
+        return self.num_experts > 0
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def mixers(self) -> Tuple[str, ...]:
+        """The `MIXERS` key of each layer, however the pattern is stated: the
+        list `layer_types` (HF's "mamba" is the mixer "ssm"), every so many
+        (`full_attention_interval`: layer i attends where (i + 1) % it == 0
+        and is linear elsewhere), or one mixer for all (`mixer`). Nothing
+        else reads the two statements."""
+        if self.layer_types is not None:
+            return tuple("ssm" if t == "mamba" else t for t in self.layer_types[:self.num_layers])
+        every = self.full_attention_interval
+        if every:
+            return tuple("attention" if (i + 1) % every == 0 else "linear"
+                         for i in range(self.num_layers))
+        return (self.mixer,) * self.num_layers
+
+    def mlp_halves(self) -> Tuple[str, ...]:
+        """The `MLP_HALVES` key of each layer: "routed" but for the leading
+        `first_dense_layers` of a model with experts, "dense" without."""
+        lead = min(self.first_dense_layers, self.num_layers) if self.routed else self.num_layers
+        return ("dense",) * lead + ("routed",) * (self.num_layers - lead)
+
+    def parts(self) -> tuple:
+        """The table entries this config's layers are built of, each once,
+        the MLP halves' before the mixers'; and the entry of what the config
+        states, experts or latent attention, whether or not a layer of this
+        depth runs it."""
+        # looked up on use: the parts' modules import this one for the config they read
+        from galvatron_tpu.models.parts import MIXERS, MLP_HALVES
+
+        halves = self.mlp_halves() + (("routed",) if self.routed else ())
+        mixers = self.mixers() + (("attention",) if self.latent_attention else ())
+        return tuple(MLP_HALVES[h] for h in dict.fromkeys(halves)) + tuple(MIXERS[m] for m in dict.fromkeys(mixers))
+
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The kind of each layer, what `config/strategy.layer_runs` splits
+        runs on beside the layout. A kind names the layer's two halves: its
+        MLP half, "dense" or "routed", after its token mixer where that is
+        not softmax attention ("linear.routed", "ssm.dense", "kda.routed")."""
+        return tuple(h if m == "attention" else m + "." + h
+                     for m, h in zip(self.mixers(), self.mlp_halves()))
+
+    def layer_config(self, kind: str) -> "TransformerConfig":
+        """The config ONE layer of this kind is built and run from: a dense
+        layer of a model that also has routed ones is the same block with no
+        experts and the dense width, and a layer of a model that mixes its
+        token mixers names its own (`mixer`) and no pattern.
+        `init_layer_params`, `layer_forward` and `layer_param_specs` take a
+        layer's config."""
+        mixer, _, mlp = kind.rpartition(".")
+        cfg = self
+        if mlp != "routed" and self.routed:
+            cfg = dataclasses.replace(
+                cfg, num_experts=0, experts_held=0, num_shared_experts=0, router_bias=False,
+                ffn_hidden=self.dense_ffn_hidden or self.ffn_hidden)
+        if self.mixers() != (self.mixer,) * self.num_layers:
+            cfg = dataclasses.replace(cfg, mixer=mixer or "attention", full_attention_interval=0,
+                                      layer_types=None)
+        return cfg
+
+    @property
+    def mlp_half(self) -> str:
+        """The `MLP_HALVES` key of ONE layer's config (`layer_config`), beside its `mixer`."""
+        return "routed" if self.routed else "dense"
+
+    @property
+    def layer_aux(self) -> bool:
+        """Whether a layer hands back auxiliary terms beside its output (a
+        router's losses and loads, a linear or state-space mixer's counters):
+        of a layer's config its own layer, of a model's config any of its
+        layers."""
+        return any(part.counters for part in self.parts())
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the experts this program holds."""
+        return (self.experts_held_start, self.experts_held) if self.experts_held \
+            else (0, self.num_experts)
+
+    @property
+    def routed_layers(self) -> int:
+        """Routed blocks a step runs: the stack's and the MTP module's."""
+        return self.mlp_halves().count("routed") + (self.mtp_layers if self.routed else 0)

@@ -32,7 +32,9 @@ from __future__ import annotations
 
 from types import SimpleNamespace
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.hf_utils import decoder_fields
+from galvatron_tpu.models.registry import ModelFamily, register
 
 GLM_47_FLASH_SOURCE = "https://huggingface.co/zai-org/GLM-4.7-Flash/blob/main/config.json"
 
@@ -70,26 +72,15 @@ def glm4_moe_lite_config_from_hf(hf_config, **overrides) -> TransformerConfig:
             raise ValueError("%s=%r is not modelled (the published GLM-4.7-Flash has %r)"
                              % (key, getattr(hf_config, key), modelled))
     fields = dict(
-        hidden_size=hf_config.hidden_size,
-        num_heads=hf_config.num_attention_heads,
-        num_kv_heads=getattr(hf_config, "num_key_value_heads", hf_config.num_attention_heads),
-        num_layers=hf_config.num_hidden_layers,
+        **decoder_fields(hf_config, INITIALIZER_RANGE),
         ffn_hidden=hf_config.moe_intermediate_size,  # the width of ONE expert
         dense_ffn_hidden=hf_config.intermediate_size,
-        vocab_size=hf_config.vocab_size,
         max_seq_len=hf_config.max_position_embeddings,
-        norm_type="rmsnorm",
-        activation="swiglu",
         position_type="rope",
-        causal=True,
-        pre_norm=True,
         tie_embeddings=getattr(hf_config, "tie_word_embeddings", False),
         qkv_bias=getattr(hf_config, "attention_bias", False),
-        mlp_bias=False,
         out_bias=getattr(hf_config, "attention_bias", False),
-        layernorm_eps=hf_config.rms_norm_eps,
         rope_theta=float(hf_config.rope_theta),
-        init_std=getattr(hf_config, "initializer_range", INITIALIZER_RANGE),
         q_lora_rank=hf_config.q_lora_rank,
         kv_lora_rank=hf_config.kv_lora_rank,
         qk_nope_head_dim=hf_config.qk_nope_head_dim,
@@ -118,3 +109,6 @@ def glm4_moe_lite_config(model_size: str = "glm-4.7-flash", **overrides) -> Tran
 
 
 META_CONFIGS = PUBLISHED  # the registry's presets: the published keys, with their source
+
+register(ModelFamily(name="glm4_moe_lite", config_fn=glm4_moe_lite_config, meta_configs=META_CONFIGS,
+                     default_size="glm-4.7-flash", config_from_hf=glm4_moe_lite_config_from_hf))

@@ -15,7 +15,8 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.registry import ModelFamily, flash_variant, register
 
 META_CONFIGS = {
     "gpt-0.3b": dict(hidden_size=1024, num_heads=16, num_layers=24, max_seq_len=1024),
@@ -133,3 +134,8 @@ def export_hf_gpt2(params: Dict[str, Any], cfg: TransformerConfig) -> Dict[str, 
         out[pre + "mlp.c_proj.weight"] = np.asarray(lp["wo_mlp"]["kernel"], np.float32)
         out[pre + "mlp.c_proj.bias"] = np.asarray(lp["wo_mlp"]["bias"], np.float32)
     return out
+
+
+register(flash_variant(register(ModelFamily(
+    name="gpt", config_fn=gpt_config, meta_configs=META_CONFIGS, default_size="gpt-0.3b",
+    convert_from_hf=convert_hf_gpt2, export_to_hf=export_hf_gpt2, config_from_hf=gpt_config_from_hf))))

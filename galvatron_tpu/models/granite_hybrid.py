@@ -8,7 +8,7 @@ MLP is the only one and no router exists), no biases, a head tied to the
 embedding. **Which layers attend is a LIST** (`layer_types`, "mamba" or
 "attention" a layer; `TransformerConfig.layer_types` takes it as it is and
 reads "mamba" as the mixer "ssm"): the published Micro attends at layers 5,
-15, 25, 35 of 40, which no interval says. The **state-space** layers (`models/base.ssm_mixer`, the
+15, 25, 35 of 40, which no interval says. The **state-space** layers (`models/parts/ssm.ssm_mixer`, the
 kind "ssm.dense"): `[z | x B C | dt]` from one projection, a causal depthwise
 convolution of `mamba_d_conv` taps WITH a bias and SiLU on `[x | B | C]`,
 Mamba-2's scan over `mamba_n_heads` states of `mamba_d_head` x `mamba_d_state`
@@ -31,7 +31,9 @@ from __future__ import annotations
 
 from types import SimpleNamespace
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.hf_utils import decoder_fields
+from galvatron_tpu.models.registry import ModelFamily, register
 
 GRANITE_4_H_MICRO_SOURCE = "https://huggingface.co/ibm-granite/granite-4.0-h-micro/blob/main/config.json"
 _MICRO_LAYER_TYPES = ["attention" if i % 10 == 5 else "mamba" for i in range(40)]
@@ -75,24 +77,13 @@ def granite_hybrid_config_from_hf(hf_config, **overrides) -> TransformerConfig:
                          % (hf_config.mamba_n_heads, hf_config.mamba_d_head,
                             hf_config.mamba_expand, hf_config.hidden_size))
     fields = dict(
-        hidden_size=hf_config.hidden_size,
-        num_heads=hf_config.num_attention_heads,
-        num_kv_heads=hf_config.num_key_value_heads,
-        num_layers=hf_config.num_hidden_layers,
+        **decoder_fields(hf_config, INITIALIZER_RANGE),
         ffn_hidden=hf_config.shared_intermediate_size,
-        vocab_size=hf_config.vocab_size,
         max_seq_len=hf_config.max_position_embeddings,
-        norm_type="rmsnorm",
-        activation="swiglu",
         position_type="none",
-        causal=True,
-        pre_norm=True,
         tie_embeddings=getattr(hf_config, "tie_word_embeddings", True),
         qkv_bias=False,
-        mlp_bias=False,
         out_bias=False,
-        layernorm_eps=hf_config.rms_norm_eps,
-        init_std=getattr(hf_config, "initializer_range", INITIALIZER_RANGE),
         layer_types=list(hf_config.layer_types),
         ssm_num_heads=hf_config.mamba_n_heads,
         ssm_head_dim=hf_config.mamba_d_head,
@@ -112,3 +103,6 @@ def granite_hybrid_config(model_size: str = "granite-4.0-h-micro", **overrides) 
 
 
 META_CONFIGS = PUBLISHED  # the registry's presets: the published keys, with their source
+
+register(ModelFamily(name="granite_hybrid", config_fn=granite_hybrid_config, meta_configs=META_CONFIGS,
+                     default_size="granite-4.0-h-micro", config_from_hf=granite_hybrid_config_from_hf))

@@ -8,7 +8,7 @@ SwiGLU, no biases, an untied head, **no position anywhere**. **Which layers
 attend is two LISTS of 1-indexed layer numbers** (`linear_attn_config`'s
 `kda_layers` and `full_attn_layers`; the last period of the published 27 is
 short, so no interval says it): here ONE list, `TransformerConfig.layer_types`,
-"kda" or "attention" a layer. The **KDA** layers (`models/base.kda_mixer`, the
+"kda" or "attention" a layer. The **KDA** layers (`models/parts/kda.kda_mixer`, the
 kinds "kda.dense" and "kda.routed"): q, k and v each through a causal depthwise
 convolution of `short_conv_kernel_size` taps and SiLU, L2-normalised q and k,
 the delta rule whose gate is a VECTOR over the key's channels
@@ -42,7 +42,9 @@ from __future__ import annotations
 
 from types import SimpleNamespace
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.hf_utils import decoder_fields
+from galvatron_tpu.models.registry import ModelFamily, register
 
 KIMI_LINEAR_SOURCE = "https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json"
 
@@ -108,26 +110,15 @@ def kimi_linear_config_from_hf(hf_config, **overrides) -> TransformerConfig:
         raise ValueError("kda_layers and full_attn_layers name %d layers, num_hidden_layers is %d"
                          % (len(layer_types), hf_config.num_hidden_layers))
     fields = dict(
-        hidden_size=hf_config.hidden_size,
-        num_heads=hf_config.num_attention_heads,
-        num_kv_heads=getattr(hf_config, "num_key_value_heads", hf_config.num_attention_heads),
+        **decoder_fields(hf_config, INITIALIZER_RANGE),
         head_dim=-(-widest // 128) * 128,
-        num_layers=hf_config.num_hidden_layers,
         ffn_hidden=hf_config.moe_intermediate_size,  # the width of ONE expert
         dense_ffn_hidden=hf_config.intermediate_size,
-        vocab_size=hf_config.vocab_size,
         max_seq_len=hf_config.model_max_length,
-        norm_type="rmsnorm",
-        activation="swiglu",
         position_type="none",
-        causal=True,
-        pre_norm=True,
         tie_embeddings=getattr(hf_config, "tie_word_embeddings", False),
         qkv_bias=False,
-        mlp_bias=False,
         out_bias=False,
-        layernorm_eps=hf_config.rms_norm_eps,
-        init_std=getattr(hf_config, "initializer_range", INITIALIZER_RANGE),
         kv_lora_rank=hf_config.kv_lora_rank,
         qk_nope_head_dim=hf_config.qk_nope_head_dim,
         qk_rope_head_dim=hf_config.qk_rope_head_dim,
@@ -157,3 +148,6 @@ def kimi_linear_config(model_size: str = "kimi-linear-48b-a3b", **overrides) -> 
 
 
 META_CONFIGS = PUBLISHED  # the registry's presets: the published keys, with their source
+
+register(ModelFamily(name="kimi_linear", config_fn=kimi_linear_config, meta_configs=META_CONFIGS,
+                     default_size="kimi-linear-48b-a3b", config_from_hf=kimi_linear_config_from_hf))

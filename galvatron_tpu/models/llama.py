@@ -12,7 +12,8 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.registry import ModelFamily, flash_variant, register
 
 META_CONFIGS = {
     "llama-0.3b": dict(hidden_size=1024, num_heads=16, num_layers=24, max_seq_len=1024),
@@ -153,3 +154,8 @@ def export_hf_llama(params: Dict[str, Any], cfg: TransformerConfig) -> Dict[str,
         out[pre + "input_layernorm.weight"] = a(lp["ln1"]["scale"])
         out[pre + "post_attention_layernorm.weight"] = a(lp["ln2"]["scale"])
     return out
+
+
+register(flash_variant(register(ModelFamily(
+    name="llama", config_fn=llama_config, meta_configs=META_CONFIGS, default_size="llama-0.3b",
+    convert_from_hf=convert_hf_llama, export_to_hf=export_hf_llama, config_from_hf=llama_config_from_hf))))

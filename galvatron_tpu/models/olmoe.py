@@ -18,7 +18,9 @@ from __future__ import annotations
 
 from types import SimpleNamespace
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.hf_utils import decoder_fields
+from galvatron_tpu.models.registry import ModelFamily, register
 
 OLMOE_1B_7B_SOURCE = "https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json"
 
@@ -48,25 +50,14 @@ def olmoe_config_from_hf(hf_config, **overrides) -> TransformerConfig:
         raise ValueError("clip_qkv=%r is not modelled (the published OLMoE-1B-7B has null)"
                          % hf_config.clip_qkv)
     fields = dict(
-        hidden_size=hf_config.hidden_size,
-        num_heads=hf_config.num_attention_heads,
-        num_kv_heads=getattr(hf_config, "num_key_value_heads", hf_config.num_attention_heads),
-        num_layers=hf_config.num_hidden_layers,
+        **decoder_fields(hf_config, INITIALIZER_RANGE),
         ffn_hidden=hf_config.intermediate_size,  # the width of ONE expert
-        vocab_size=hf_config.vocab_size,
         max_seq_len=hf_config.max_position_embeddings,
-        norm_type="rmsnorm",
-        activation="swiglu",
         position_type="rope",
-        causal=True,
-        pre_norm=True,
         tie_embeddings=getattr(hf_config, "tie_word_embeddings", False),
         qkv_bias=getattr(hf_config, "attention_bias", False),
-        mlp_bias=False,
         out_bias=getattr(hf_config, "attention_bias", False),
-        layernorm_eps=hf_config.rms_norm_eps,
         rope_theta=getattr(hf_config, "rope_theta", 10000.0),
-        init_std=getattr(hf_config, "initializer_range", INITIALIZER_RANGE),
         qk_norm=True,
         num_experts=hf_config.num_experts,
         experts_per_token=hf_config.num_experts_per_tok,
@@ -83,3 +74,6 @@ def olmoe_config(model_size: str = "olmoe-1b-7b", **overrides) -> TransformerCon
 
 
 META_CONFIGS = PUBLISHED  # the registry's presets: the published keys, with their source
+
+register(ModelFamily(name="olmoe", config_fn=olmoe_config, meta_configs=META_CONFIGS,
+                     default_size="olmoe-1b-7b", config_from_hf=olmoe_config_from_hf))

@@ -1,0 +1,78 @@
+"""The parts a layer is built of, in two tables of one shape, and the one
+function that says whether a (config, layout, asker) has a form of them.
+
+A layer is `norms + MIXERS[m] + MLP_HALVES[h]`; its kind
+(`TransformerConfig.layer_kinds`) names the two, "<m>.<h>", softmax attention
+going unnamed. Adding a part is adding one module with one entry
+(`common.LayerPart`) and its line here: the stack (`models/base.py`), the
+config's validation and the refusals read the tables and name no part."""
+
+from __future__ import annotations
+
+from typing import Any, Iterator, Optional, Tuple
+
+from galvatron_tpu.models.parts.attention import ATTENTION
+from galvatron_tpu.models.parts.kda import KDA
+from galvatron_tpu.models.parts.linear import LINEAR
+from galvatron_tpu.models.parts.mlp import DENSE, ROUTED
+from galvatron_tpu.models.parts.ssm import SSM
+
+MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA}
+MLP_HALVES = {"dense": DENSE, "routed": ROUTED}
+
+# how an asker's sentence starts, and what joins the parts' statements in it
+_SAYS = {
+    "serve": ("serve: the decode engine has ", ", and "),
+    "autotune": ("autotune=%s: the re-search would price ", ", and "),
+    "pp": ("pp=%d: the pipeline engines ", " and "),
+    "tp": ("layer %d: tp=%d cp=%d sp=%d: no tensor-, context- or sequence-parallel form of ", "; nor of "),
+    "vocab_tp": ("vocab_tp=%d: tensor parallelism of any layer is unsupported beside ", ", "),
+    "tp_comm": ("tp_comm_mode=%r: the manual TP path has no form of ", ", "),
+    "quant": ("quantized grad/param collectives run a local loss without ", ", "),
+    "search": ("search: the cost models have no row for ", ", "),
+    "profile": ("profile: the layer profiler times a dense block under softmax attention, not ", ", "),
+}
+
+
+def _layout_askers(hp, quant: bool) -> Iterator[Tuple[str, Any]]:
+    """(asker, the numbers of its sentence) for each thing this layout (a
+    `HybridParallelConfig`) asks of a part; `quant`: it asks for quantized collectives."""
+    if hp.pp > 1:
+        yield "pp", hp.pp
+    for i, s in enumerate(hp.layers):
+        if s.tp > 1 or s.cp > 1 or s.sp:
+            yield "tp", (i, s.tp, s.cp, int(s.sp))
+            break
+    if hp.vocab_tp > 1:
+        yield "vocab_tp", hp.vocab_tp
+    if hp.tp_comm_mode != "gspmd":
+        yield "tp_comm", hp.tp_comm_mode
+    if quant:
+        yield "quant", ()
+
+
+def unsupported_reason(cfg, hp=None, asker: Optional[str] = None, autotune: Optional[str] = None,
+                       quant: bool = False) -> Optional[str]:
+    """Why this layout, driver mode (`asker` "serve") or tool (`asker`
+    "search", "profile") cannot run this config, or None: the statements of
+    the config's OWN parts to the first asker any of them has no form for.
+    What has none is refused by name, not run wrong or priced as dense: the
+    answer is GLS018's message, which `base.refuse_unsupported` raises at
+    trace time and `strategy_lint.lint_hp` reports before it. `quant`:
+    `quant_collectives.wants_quant_comm(hp)`, which the caller asks."""
+    parts = getattr(cfg, "parts", None)
+    if not callable(parts):  # T5's, Swin's and duck-typed configs are built of none of them
+        return None
+    says = [part.unsupported(cfg) for part in parts()]
+    askers = [(asker, ())] if asker in ("serve", "search", "profile") else []
+    if (autotune or "off") != "off":
+        askers.append(("autotune", autotune))
+    if hp is not None and any(says):
+        askers.extend(_layout_askers(hp, quant))
+    for name, numbers in askers:
+        said = [s[name] for s in says if name in s]
+        if said:
+            start, glue = _SAYS[name]
+            return (start % numbers + glue.join(said)
+                    + "; such a config runs on one chip and under dp with ZeRO-1/2/3")
+    return None

@@ -1,0 +1,152 @@
+"""The MLP half of a layer: dense, or routed experts (ops/moe.py) with the
+shared expert and its gate beside them."""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.parts.common import (LayerPart, Params, _activation, _dense, _dense_init, _proj_std,
+                                               no_form)
+from galvatron_tpu.obs import tracing
+from galvatron_tpu.ops.moe import moe_ffn, swiglu
+from galvatron_tpu.parallel import spec as S
+from galvatron_tpu.parallel.mesh import LayerAxes
+
+ROUTER_BIAS = "e_score_correction_bias"  # HF's name: (num_experts,) float32, no gradient
+
+
+# ===================================================================== dense
+def _init_dense(ks, cfg: TransformerConfig) -> Params:
+    h = cfg.hidden_size
+    p = {"wi": {"kernel": _dense_init(ks[2], (h,) + cfg.mlp_fan_in, cfg.init_std, cfg.param_dtype)},
+         "wo_mlp": {"kernel": _dense_init(ks[3], (cfg.ffn_hidden, h), _proj_std(cfg), cfg.param_dtype)}}
+    if cfg.mlp_bias:
+        p["wi"]["bias"] = jnp.zeros(cfg.mlp_fan_in, cfg.param_dtype)
+        p["wo_mlp"]["bias"] = jnp.zeros((h,), cfg.param_dtype)
+    return p
+
+
+def dense_mlp(p: Params, y: jax.Array, cfg: TransformerConfig, dtype) -> jax.Array:
+    """The dense MLP half on normed activations (B, S, H)."""
+    wi_out = jnp.einsum("bsh,h...->bs...", y, p["wi"]["kernel"].astype(dtype))
+    if "bias" in p["wi"]:
+        wi_out = wi_out + p["wi"]["bias"].astype(dtype)
+    if cfg.activation == "swiglu":
+        hmid = jax.nn.silu(wi_out[:, :, 0]) * wi_out[:, :, 1]
+    else:
+        hmid = _activation(wi_out, cfg)
+    return _dense(hmid, p["wo_mlp"], dtype)
+
+
+def _dense_forward(p: Params, y: jax.Array, positions, cfg: TransformerConfig, **_):
+    # named here and not inside dense_mlp, which the shared expert calls
+    # under its own scope: an op carries one scope nested in its run's
+    with jax.named_scope(tracing.MLP):
+        return dense_mlp(p, y, cfg, cfg.compute_dtype), None, None
+
+
+def _dense_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
+    """The tp axes sit on the ffn dim; ZeRO-3 shards the other large dim over
+    dp. Ulysses layers keep dense (non-tp-sharded) weights (reference
+    transformer.py:2065-2177)."""
+    tp = None if axes.ulysses else S._ax(axes.tp)
+    z3 = S._ax(axes.dp) if axes.zero3 else None
+    swi = cfg.activation == "swiglu"
+    sp: Params = {"wi": {"kernel": P(z3, None, tp) if swi else P(z3, tp)},
+                  "wo_mlp": {"kernel": P(tp, z3)}}
+    if cfg.mlp_bias:
+        sp["wi"]["bias"] = P(None, tp) if swi else P(tp)
+        sp["wo_mlp"]["bias"] = S.replicated_1d_spec(axes)
+    return sp
+
+
+# ==================================================================== routed
+def _init_routed(ks, cfg: TransformerConfig) -> Params:
+    # one kernel a matrix with the experts leading: (E, h, 2F) the gate's
+    # columns beside the up projection's (flat: a TPU tiles the minor
+    # dims, and a (2, F) pair there costs a copy a use), (E, F, h) down;
+    # the router (h, E) stays float32 in the forward
+    h, proj_std = cfg.hidden_size, _proj_std(cfg)
+    e, fan_in = cfg.num_experts, math.prod(cfg.mlp_fan_in)
+    kr = jax.random.fold_in(ks[2], 1)
+    p: Params = {"router": {"kernel": _dense_init(kr, (h, e), cfg.init_std, cfg.param_dtype)}}
+    if cfg.router_bias:
+        p["router"][ROUTER_BIAS] = jnp.zeros((e,), jnp.float32)
+    held = cfg.held_experts[1]  # the router ranks all e; these are held
+    p["wi"] = {"kernel": _dense_init(ks[2], (held, h, fan_in), cfg.init_std, cfg.param_dtype)}
+    p["wo_mlp"] = {"kernel": _dense_init(ks[3], (held, cfg.ffn_hidden, h), proj_std, cfg.param_dtype)}
+    if cfg.num_shared_experts:
+        wide = cfg.num_shared_experts * cfg.ffn_hidden
+        ksh = jax.random.split(jax.random.fold_in(ks[3], 1), 2)
+        shared_in = (h, 2, wide) if cfg.activation == "swiglu" else (h, wide)
+        p["shared"] = {
+            "wi": {"kernel": _dense_init(ksh[0], shared_in, cfg.init_std, cfg.param_dtype)},
+            "wo_mlp": {"kernel": _dense_init(ksh[1], (wide, h), proj_std, cfg.param_dtype)},
+        }
+        if cfg.shared_expert_gate:
+            p["shared"]["gate"] = {"kernel": _dense_init(
+                jax.random.fold_in(ks[3], 2), (h, 1), cfg.init_std, cfg.param_dtype)}
+    return p
+
+
+def _routed_forward(p: Params, y: jax.Array, positions, cfg: TransformerConfig, *, attn_sharding=None, **_):
+    """-> the experts' (and the shared expert's) output, None, and the
+    router's auxiliary terms (ops/moe.py)."""
+    dtype = cfg.compute_dtype
+    out, aux = moe_ffn(
+        y, p["router"]["kernel"], p["wi"]["kernel"], p["wo_mlp"]["kernel"],
+        experts_per_token=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
+        activate=swiglu if cfg.activation == "swiglu" else partial(_activation, cfg=cfg),
+        dtype=dtype, sharding=attn_sharding, score=cfg.router_score,
+        bias=p["router"].get(ROUTER_BIAS), scale=cfg.routed_scaling_factor,
+        held=cfg.held_experts if cfg.experts_held else None)
+    if "shared" in p:
+        # every chip of the deployment computes it alike, whole
+        with jax.named_scope(tracing.MOE_SHARED):
+            shared = dense_mlp(p["shared"], y, cfg, dtype)
+            if "gate" in p["shared"]:
+                shared = shared * jax.nn.sigmoid(_dense(y, p["shared"]["gate"], dtype))
+            out = out + shared
+    return out, None, aux
+
+
+def _routed_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
+    # ordinary leaves (tp is refused, GLS018): ZeRO-3 splits the experts
+    # over dp, and they enter the block whole (ops/moe.moe_ffn)
+    z3 = S._ax(axes.dp) if axes.zero3 else None
+    sp: Params = {"router": {"kernel": P(None, None)},
+                  "wi": {"kernel": P(z3, None, None)}, "wo_mlp": {"kernel": P(z3, None, None)}}
+    if cfg.router_bias:
+        sp["router"][ROUTER_BIAS] = P(None)
+    if cfg.num_shared_experts:
+        sp["shared"] = {
+            "wi": {"kernel": P(z3, None, None) if cfg.activation == "swiglu" else P(z3, None)},
+            "wo_mlp": {"kernel": P(None, z3)},
+        }
+        if cfg.shared_expert_gate:
+            sp["shared"]["gate"] = {"kernel": P(None, None)}
+    return sp
+
+
+# experts are ordinary parameters under dp and ZeRO-1/2/3; no other axis has
+# an expert form yet (`ep` is the next step)
+UNSUPPORTED = no_form(
+    "routed experts",
+    serve="no expert form",
+    autotune="the block as dense",
+    pp="carry no router losses between stages",
+    tp="the experts' kernels and the dropless dispatch",
+    quant="router statistics",
+)
+
+DENSE = LayerPart(_init_dense, _dense_forward, _dense_specs, (tracing.MLP,))
+ROUTED = LayerPart(
+    _init_routed, _routed_forward, _routed_specs,
+    (tracing.MOE_ROUTER, tracing.MOE_DISPATCH, tracing.MOE_EXPERTS, tracing.MOE_COMBINE, tracing.MOE_SHARED),
+    counters=True, unsupported=lambda cfg: UNSUPPORTED)

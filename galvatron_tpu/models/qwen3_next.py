@@ -5,7 +5,7 @@ every MLP half a fine-grained mixture of experts.
 The block is `models/base.py`'s with the config's switches set: RMSNorm scaled
 by `(1 + w)` (`norm_zero_centered`), SwiGLU, no biases, an untied head. Of
 every `full_attention_interval` layers the last attends and the others are
-**linear** (`models/base.linear_mixer`, the kind "linear.routed"): q, k, v and
+**linear** (`models/parts/linear.linear_mixer`, the kind "linear.routed"): q, k, v and
 an output gate z from one projection, a causal depthwise convolution of
 `linear_conv_kernel_dim` taps and SiLU on q, k and v, L2-normalised q and k,
 the **gated delta rule** over `linear_num_value_heads` states of
@@ -36,7 +36,9 @@ from __future__ import annotations
 
 from types import SimpleNamespace
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.hf_utils import decoder_fields
+from galvatron_tpu.models.registry import ModelFamily, register
 
 QWEN3_NEXT_SOURCE = "https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/config.json"
 
@@ -77,28 +79,17 @@ def qwen3_next_config_from_hf(hf_config, **overrides) -> TransformerConfig:
                          % (hf_config.shared_expert_intermediate_size,
                             hf_config.moe_intermediate_size))
     fields = dict(
-        hidden_size=hf_config.hidden_size,
-        num_heads=hf_config.num_attention_heads,
-        num_kv_heads=hf_config.num_key_value_heads,
+        **decoder_fields(hf_config, INITIALIZER_RANGE),
         head_dim=hf_config.head_dim,
-        num_layers=hf_config.num_hidden_layers,
         ffn_hidden=hf_config.moe_intermediate_size,  # the width of ONE expert
-        vocab_size=hf_config.vocab_size,
         max_seq_len=hf_config.max_position_embeddings,
-        norm_type="rmsnorm",
         norm_zero_centered=True,
-        activation="swiglu",
         position_type="rope",
-        causal=True,
-        pre_norm=True,
         tie_embeddings=getattr(hf_config, "tie_word_embeddings", False),
         qkv_bias=False,
-        mlp_bias=False,
         out_bias=False,
-        layernorm_eps=hf_config.rms_norm_eps,
         rope_theta=float(hf_config.rope_theta),
         partial_rotary_factor=hf_config.partial_rotary_factor,
-        init_std=getattr(hf_config, "initializer_range", INITIALIZER_RANGE),
         qk_norm="head",
         attn_output_gate=True,
         full_attention_interval=hf_config.full_attention_interval,
@@ -124,3 +115,6 @@ def qwen3_next_config(model_size: str = "qwen3-next-80b-a3b", **overrides) -> Tr
 
 
 META_CONFIGS = PUBLISHED  # the registry's presets: the published keys, with their source
+
+register(ModelFamily(name="qwen3_next", config_fn=qwen3_next_config, meta_configs=META_CONFIGS,
+                     default_size="qwen3-next-80b-a3b", config_from_hf=qwen3_next_config_from_hf))

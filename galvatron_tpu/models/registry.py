@@ -4,13 +4,15 @@ The reference keeps one directory per family under ``galvatron/models/`` with a
 uniform 5-file integration surface (SURVEY.md §2.4; e.g.
 models/gpt_hf/GPTModel_hybrid_parallel.py:20-79). Here a family is one
 ``ModelFamily`` record: a config constructor plus optional HF state-dict
-conversion hooks. All families share the same functional transformer
-(models/base.py) so "integration" reduces to configuration.
+conversion hooks, registered where the family's module ends. All families
+share the same functional transformer (models/base.py) so "integration"
+reduces to configuration.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import dataclasses
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
 
 
@@ -84,6 +86,24 @@ def family_names():
     return sorted(_REGISTRY)
 
 
+def flash_variant(family: ModelFamily) -> ModelFamily:
+    """The same family pinned to attn_impl="flash", as `<name>_fa` (reference
+    gpt_fa / llama_fa, SURVEY.md §2.4): on TPU the fused-attention choice is
+    the pallas flash kernel."""
+    def pinned(fn):
+        def cfg_fa(*args, **overrides):
+            overrides.setdefault("attn_impl", "flash")
+            return fn(*args, **overrides)
+
+        return cfg_fa
+
+    return dataclasses.replace(family, name=family.name + "_fa", config_fn=pinned(family.config_fn),
+                               config_from_hf=pinned(family.config_from_hf))
+
+
+# every family registers itself where its module ends; a new one adds its name
+_FAMILY_MODULES = ("kimi_linear", "granite_hybrid", "qwen3_next", "glm4_moe_lite", "olmoe", "gpt", "llama",
+                   "bert", "vit", "t5", "swin")
 _LOADED = False
 
 
@@ -92,115 +112,12 @@ def _ensure_builtin():
     if _LOADED:
         return
     _LOADED = True
-    from galvatron_tpu.models import (glm4_moe_lite, gpt, granite_hybrid, kimi_linear, llama, olmoe,
-                                      qwen3_next)
-
-    register(
-        ModelFamily(
-            name="kimi_linear",
-            config_fn=kimi_linear.kimi_linear_config,
-            meta_configs=kimi_linear.META_CONFIGS,
-            default_size="kimi-linear-48b-a3b",
-            config_from_hf=kimi_linear.kimi_linear_config_from_hf,
-        )
-    )
-    register(
-        ModelFamily(
-            name="granite_hybrid",
-            config_fn=granite_hybrid.granite_hybrid_config,
-            meta_configs=granite_hybrid.META_CONFIGS,
-            default_size="granite-4.0-h-micro",
-            config_from_hf=granite_hybrid.granite_hybrid_config_from_hf,
-        )
-    )
-    register(
-        ModelFamily(
-            name="qwen3_next",
-            config_fn=qwen3_next.qwen3_next_config,
-            meta_configs=qwen3_next.META_CONFIGS,
-            default_size="qwen3-next-80b-a3b",
-            config_from_hf=qwen3_next.qwen3_next_config_from_hf,
-        )
-    )
-    register(
-        ModelFamily(
-            name="glm4_moe_lite",
-            config_fn=glm4_moe_lite.glm4_moe_lite_config,
-            meta_configs=glm4_moe_lite.META_CONFIGS,
-            default_size="glm-4.7-flash",
-            config_from_hf=glm4_moe_lite.glm4_moe_lite_config_from_hf,
-        )
-    )
-    register(
-        ModelFamily(
-            name="olmoe",
-            config_fn=olmoe.olmoe_config,
-            meta_configs=olmoe.META_CONFIGS,
-            default_size="olmoe-1b-7b",
-            config_from_hf=olmoe.olmoe_config_from_hf,
-        )
-    )
-    register(
-        ModelFamily(
-            name="gpt",
-            config_fn=gpt.gpt_config,
-            meta_configs=gpt.META_CONFIGS,
-            default_size="gpt-0.3b",
-            convert_from_hf=gpt.convert_hf_gpt2,
-            export_to_hf=gpt.export_hf_gpt2,
-            config_from_hf=gpt.gpt_config_from_hf,
-        )
-    )
-    register(
-        ModelFamily(
-            name="llama",
-            config_fn=llama.llama_config,
-            meta_configs=llama.META_CONFIGS,
-            default_size="llama-0.3b",
-            convert_from_hf=llama.convert_hf_llama,
-            export_to_hf=getattr(llama, "export_hf_llama", None),
-            config_from_hf=llama.llama_config_from_hf,
-        )
-    )
-    # flash-attention-native variants (reference gpt_fa / llama_fa,
-    # SURVEY.md §2.4): on TPU the fused-attention choice is the pallas flash
-    # kernel, so these are the same families pinned to attn_impl="flash"
-    def _fa(fn):
-        def cfg_fa(*args, **overrides):
-            overrides.setdefault("attn_impl", "flash")
-            return fn(*args, **overrides)
-
-        return cfg_fa
-
-    register(
-        ModelFamily(
-            name="gpt_fa",
-            config_fn=_fa(gpt.gpt_config),
-            meta_configs=gpt.META_CONFIGS,
-            default_size="gpt-0.3b",
-            convert_from_hf=gpt.convert_hf_gpt2,
-            export_to_hf=gpt.export_hf_gpt2,
-            config_from_hf=_fa(gpt.gpt_config_from_hf),
-        )
-    )
-    register(
-        ModelFamily(
-            name="llama_fa",
-            config_fn=_fa(llama.llama_config),
-            meta_configs=llama.META_CONFIGS,
-            default_size="llama-0.3b",
-            convert_from_hf=llama.convert_hf_llama,
-            export_to_hf=getattr(llama, "export_hf_llama", None),
-            config_from_hf=_fa(llama.llama_config_from_hf),
-        )
-    )
-    # extended families (bert/vit/t5/swin) self-register on import; a broken
-    # module is recorded (not swallowed) and re-raised at get_family() so a
-    # broken family surfaces at use time instead of vanishing from the registry
+    # a broken module is recorded (not swallowed) and re-raised at get_family()
+    # so a broken family surfaces at use time instead of vanishing from the registry
     import traceback
     import warnings
 
-    for mod in ("bert", "vit", "t5", "swin"):
+    for mod in _FAMILY_MODULES:
         try:
             __import__("galvatron_tpu.models.%s" % mod)
         except Exception:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
+from galvatron_tpu.models.registry import ModelFamily, register
 from galvatron_tpu.ops.norms import layer_norm
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import LayerAxes, layer_axes
@@ -122,7 +123,7 @@ def swin_config_from_hf(hf_config, num_classes: int = 1000, **overrides) -> Swin
 
 
 # ===================================================================== params
-from galvatron_tpu.models.base import _dense_init
+from galvatron_tpu.models.parts.common import _dense_init
 
 
 def _ln_p(dim, dtype):
@@ -306,7 +307,7 @@ def swin_forward(
     hp: Optional[HybridParallelConfig] = None,
     mesh: Optional[Mesh] = None,
 ) -> jax.Array:
-    from galvatron_tpu.models.base import patchify
+    from galvatron_tpu.models.parts.embed_head import patchify
 
     use_hp = hp is not None and mesh is not None
     dtype = cfg.compute_dtype
@@ -335,7 +336,7 @@ def swin_forward(
 
 
 def swin_loss_fn(params, batch, cfg: SwinConfig, hp=None, mesh=None):
-    from galvatron_tpu.models.base import softmax_nll
+    from galvatron_tpu.models.parts.embed_head import softmax_nll
 
     logits = swin_forward(params, batch["pixels"], cfg, hp, mesh)
     return softmax_nll(logits, batch["labels"])
@@ -626,26 +627,20 @@ def _swin_profiler(cfg, model_name, args):
     return SwinModelProfiler(cfg, model_name, args)
 
 
-def _register():
-    from galvatron_tpu.models.registry import ModelFamily, register
-
-    register(
-        ModelFamily(
-            name="swin",
-            config_fn=swin_config,
-            meta_configs=META_CONFIGS,
-            default_size="swin-tiny",
-            data_kind="vision",
-            convert_from_hf=convert_hf_swin,
-            export_to_hf=export_hf_swin,
-            config_from_hf=swin_config_from_hf,
-            build=construct_swin_model,
-            layer_configs_fn=_swin_layer_configs,
-            make_profiler=_swin_profiler,
-            mid_stage_type_boundaries=True,
-            supports_sequence_sharding=False,
-        )
+register(
+    ModelFamily(
+        name="swin",
+        config_fn=swin_config,
+        meta_configs=META_CONFIGS,
+        default_size="swin-tiny",
+        data_kind="vision",
+        convert_from_hf=convert_hf_swin,
+        export_to_hf=export_hf_swin,
+        config_from_hf=swin_config_from_hf,
+        build=construct_swin_model,
+        layer_configs_fn=_swin_layer_configs,
+        make_profiler=_swin_profiler,
+        mid_stage_type_boundaries=True,
+        supports_sequence_sharding=False,
     )
-
-
-_register()
+)

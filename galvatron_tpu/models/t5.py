@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
+from galvatron_tpu.models.registry import ModelFamily, register
 from galvatron_tpu.ops.attention import core_attention
 from galvatron_tpu.ops.norms import rms_norm
 from galvatron_tpu.parallel import spec as S
@@ -111,7 +112,7 @@ def t5_config_from_hf(hf_config, **overrides) -> T5Config:
 
 
 # ===================================================================== params
-from galvatron_tpu.models.base import _dense_init
+from galvatron_tpu.models.parts.common import _dense_init
 
 
 def _attn_params(rng, cfg: T5Config) -> Params:
@@ -327,7 +328,7 @@ def t5_forward(
 
 def t5_loss_fn(params, batch, cfg: T5Config, hp=None, mesh=None):
     """batch: dict(tokens [enc], dec_tokens, labels, loss_mask?, attn_mask?)."""
-    from galvatron_tpu.models.base import vocab_parallel_cross_entropy
+    from galvatron_tpu.models.parts.embed_head import vocab_parallel_cross_entropy
 
     logits = t5_forward(
         params, batch["tokens"], batch["dec_tokens"], cfg, hp, mesh,
@@ -660,24 +661,18 @@ def export_hf_t5(params: Params, cfg: T5Config) -> Dict[str, np.ndarray]:
     return out
 
 
-def _register():
-    from galvatron_tpu.models.registry import ModelFamily, register
-
-    register(
-        ModelFamily(
-            name="t5",
-            config_fn=t5_config,
-            meta_configs=META_CONFIGS,
-            default_size="t5-base",
-            data_kind="seq2seq",
-            convert_from_hf=convert_hf_t5,
-            export_to_hf=export_hf_t5,
-            config_from_hf=t5_config_from_hf,
-            build=construct_t5_model,
-            layer_configs_fn=_t5_layer_configs,
-            make_profiler=_t5_profiler,
-        )
+register(
+    ModelFamily(
+        name="t5",
+        config_fn=t5_config,
+        meta_configs=META_CONFIGS,
+        default_size="t5-base",
+        data_kind="seq2seq",
+        convert_from_hf=convert_hf_t5,
+        export_to_hf=export_hf_t5,
+        config_from_hf=t5_config_from_hf,
+        build=construct_t5_model,
+        layer_configs_fn=_t5_layer_configs,
+        make_profiler=_t5_profiler,
     )
-
-
-_register()
+)

@@ -2,7 +2,7 @@
 
 Pre-LN bidirectional encoder over image patches with a cls token and a
 classification head. The stride-P conv patch embedding becomes a dense on
-patchified pixels (models/base.py `patchify`) — a single MXU matmul.
+patchified pixels (models/parts/embed_head.py `patchify`) — a single MXU matmul.
 `convert_hf_vit` maps a HuggingFace `ViTForImageClassification` state dict
 onto the functional param tree."""
 
@@ -14,7 +14,8 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.registry import ModelFamily, register
 from galvatron_tpu.models.bert import _linear, _np, _stack_qkv
 
 META_CONFIGS = {
@@ -169,21 +170,15 @@ def export_hf_vit(params: Dict[str, Any], cfg: TransformerConfig) -> Dict[str, n
     return out
 
 
-def _register():
-    from galvatron_tpu.models.registry import ModelFamily, register
-
-    register(
-        ModelFamily(
-            name="vit",
-            config_fn=vit_config,
-            meta_configs=META_CONFIGS,
-            default_size="vit-base",
-            data_kind="vision",
-            convert_from_hf=convert_hf_vit,
-            export_to_hf=export_hf_vit,
-            config_from_hf=vit_config_from_hf,
-        )
+register(
+    ModelFamily(
+        name="vit",
+        config_fn=vit_config,
+        meta_configs=META_CONFIGS,
+        default_size="vit-base",
+        data_kind="vision",
+        convert_from_hf=convert_hf_vit,
+        export_to_hf=export_hf_vit,
+        config_from_hf=vit_config_from_hf,
     )
-
-
-_register()
+)

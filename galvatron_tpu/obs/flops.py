@@ -60,8 +60,8 @@ def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
 
 
 # ------------------------------------------------------------ analytic FLOPs
-# A token mixer's forward FLOPs a token, (projections, core): the functions
-# `models/base.MIXERS` names.
+# A token mixer's forward FLOPs a token, (projections, core): a function a key
+# of `models/parts.MIXERS` (`MIXER_FWD_FLOPS` below; this module imports no jax).
 def attention_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, num_kv_heads: int,
                                 seq_len: int, causal: bool = True, gated: bool = False,
                                 latent: Optional[Mapping[str, int]] = None):
@@ -127,6 +127,16 @@ def ssm_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, state_d
     return proj, 4.0 * num_heads * head_dim * state_dim
 
 
+# the row of each `MIXERS` key, and the config fields its keyword arguments read
+_DELTA_DIMS = {k: "linear_" + k for k in ("num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
+MIXER_FWD_FLOPS = {
+    "attention": (attention_fwd_flops_a_token, {}),
+    "linear": (linear_fwd_flops_a_token, _DELTA_DIMS),
+    "kda": (kda_fwd_flops_a_token, _DELTA_DIMS),
+    "ssm": (ssm_fwd_flops_a_token, {k: "ssm_" + k for k in ("num_heads", "head_dim", "state_dim")}),
+}
+
+
 def layer_fwd_flops(
     *,
     hidden: int,
@@ -145,9 +155,8 @@ def layer_fwd_flops(
     latent: Optional[Mapping[str, int]] = None,
     attn_gate: bool = False,
     shared_gate: bool = False,
-    linear: Optional[Mapping[str, int]] = None,
-    ssm: Optional[Mapping[str, int]] = None,
-    kda: Optional[Mapping[str, int]] = None,
+    mixer: str = "attention",
+    mixer_dims: Optional[Mapping[str, int]] = None,
 ) -> float:
     """Forward model FLOPs of ONE transformer block over `tokens` tokens
     (default: one sequence). Matmul terms only (2 FLOPs per MAC); norms and
@@ -160,19 +169,13 @@ def layer_fwd_flops(
     routing). `latent` (q_lora_rank, kv_lora_rank, qk_nope_head_dim,
     qk_rope_head_dim, v_head_dim): latent attention's five projections by
     their shapes in place of q, k/v and out; `attn_gate`: q projected beside
-    an output gate. `linear` (num_key_heads, num_value_heads, key_head_dim,
-    value_head_dim): the layer's token mixer is a gated DeltaNet, in place of
-    attention; `ssm` (num_heads, head_dim, state_dim): a Mamba-2 state-space
-    mixer; `kda` (the keys of `linear`): a Kimi-Delta-Attention mixer.
+    an output gate. `mixer` with `mixer_dims`: the layer's token mixer is
+    that row of `MIXER_FWD_FLOPS` on those sizes, in place of attention.
     `shared_gate`: the shared expert's (hidden, 1) gate."""
     tokens = float(seq_len if tokens is None else tokens)
     ffn = ffn_hidden or 4 * hidden
-    if ssm:
-        proj, attn = ssm_fwd_flops_a_token(hidden=hidden, **ssm)
-    elif kda:
-        proj, attn = kda_fwd_flops_a_token(hidden=hidden, **kda)
-    elif linear:
-        proj, attn = linear_fwd_flops_a_token(hidden=hidden, **linear)
+    if mixer != "attention":
+        proj, attn = MIXER_FWD_FLOPS[mixer][0](hidden=hidden, **mixer_dims)
     else:
         proj, attn = attention_fwd_flops_a_token(
             hidden=hidden, num_heads=num_heads, head_dim=head_dim or hidden // num_heads,
@@ -202,14 +205,7 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
     if getattr(cfg, "kv_lora_rank", 0):
         latent = {k: getattr(cfg, k) for k in (
             "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")}
-    linear = kda = None
-    if getattr(cfg, "mixer", "attention") in ("linear", "kda"):
-        heads = {k: getattr(cfg, "linear_" + k) for k in (
-            "num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
-        linear, kda = (heads, None) if cfg.mixer == "linear" else (None, heads)
-    ssm = None
-    if getattr(cfg, "mixer", "attention") == "ssm":
-        ssm = {k: getattr(cfg, "ssm_" + k) for k in ("num_heads", "head_dim", "state_dim")}
+    mixer = getattr(cfg, "mixer", "attention")
     return layer_fwd_flops(
         hidden=hidden,
         num_heads=heads,
@@ -227,9 +223,8 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         latent=latent,
         attn_gate=bool(getattr(cfg, "attn_output_gate", False)),
         shared_gate=bool(getattr(cfg, "shared_expert_gate", False)),
-        linear=linear,
-        ssm=ssm,
-        kda=kda,
+        mixer=mixer,
+        mixer_dims={k: getattr(cfg, field) for k, field in MIXER_FWD_FLOPS[mixer][1].items()},
     )
 
 

@@ -60,12 +60,12 @@ EXPERT_STEP_FIELDS = ("loss_ce", "loss_load_balance", "loss_router_z",
 SHARE_STEP_FIELDS = ("loss_mtp", "expert_rows_held", "expert_rows_held_over_even",
                      "router_bias_abs_max")
 
-# linear-attention layers' counters (models/base.linear_mixer), over the
+# linear-attention layers' counters (models/parts/linear.linear_mixer), over the
 # step's tokens, heads and linear layers: the mean gate exp(g), how much of
 # its state a token keeps; the largest magnitude in any head's final state,
 # the delta rule's blow-up alarm
 LINEAR_STEP_FIELDS = ("linear_decay_mean", "linear_state_abs_max")
-# state-space layers' counter (models/base.ssm_mixer): the largest magnitude
+# state-space layers' counter (models/parts/ssm.ssm_mixer): the largest magnitude
 # of any head's state at any chunk's end, the worst layer's
 SSM_STEP_FIELDS = ("ssm_state_abs_max",)
 
@@ -337,13 +337,6 @@ class MemorySink(TelemetrySink):
 
     def _write(self, event):
         self.events.append(event)
-
-
-class NullSink(TelemetrySink):
-    """Validates and drops (schema checking without storage)."""
-
-    def _write(self, event):
-        pass
 
 
 def _json_default(obj):

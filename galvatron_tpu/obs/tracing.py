@@ -54,22 +54,22 @@ MOE_GMM_OUT = "gmm_out"  # inside MOE_EXPERTS: rows x (width, hidden)
 # weighted sum; backward, the cotangent gathered into expert order and weighted
 MOE_COMBINE = "gt.moe.combine"
 MOE_SHARED = "gt.moe.shared"  # the shared expert(s): a dense SwiGLU beside the routed ones
-# latent attention (models/base.latent_qkv_projection), inside gt.layers.r<k>:
+# latent attention (models/parts/attention.latent_qkv_projection), inside gt.layers.r<k>:
 # the low-rank projections, their norms, rope and the output projection,
 # everything of the attention half but the attention call itself
 ATTN_LATENT = "gt.attn.latent"
-# the same for every other softmax layer (models/base.attention_mixer without
+# the same for every other softmax layer (models/parts/attention.attention_mixer without
 # latent attention): the q, k, v projection, the split-off gate, the heads'
 # norms, rope, the gate's product and the output projection, so that a softmax
 # mixer is this scope plus the attention call
 ATTN_PROJ = "gt.attn.proj"
-# the dense MLP half, around its call in models/base.layer_forward and NOT
+# the dense MLP half, around its call in models/parts/mlp._dense_forward and NOT
 # inside dense_mlp, which the shared expert calls under MOE_SHARED: an op
 # carries ONE scope nested in its layer run's, so the parts add up. A run's
 # norms, residual adds, layout constraints and what the scan does with the
 # stacked parameters carry none: they are the run's self time
 MLP = "gt.mlp"
-# a gated-DeltaNet linear-attention mixer (models/base.linear_mixer), inside
+# a gated-DeltaNet linear-attention mixer (models/parts/linear.linear_mixer), inside
 # gt.layers.r<k>, in two disjoint scopes that add up to the mixer: the core
 # (ops/linear_attention.gated_delta_rule: the chunks' solves, the carried
 # state, the outputs; forward, recomputed and backward) and everything else
@@ -77,14 +77,14 @@ MLP = "gt.mlp"
 # projection)
 ATTN_DELTA = "gt.attn.delta"
 ATTN_LINEAR = "gt.attn.linear"
-# a Mamba-2 state-space mixer (models/base.ssm_mixer), inside gt.layers.r<k>,
+# a Mamba-2 state-space mixer (models/parts/ssm.ssm_mixer), inside gt.layers.r<k>,
 # in two disjoint scopes that add up to the mixer, as the linear mixer's: the
 # scan (ops/ssd.ssd_scan: the chunks' masks and products, the carried state;
 # forward, recomputed and backward) and everything else of it (the
 # projections, the convolution and its bias, dt, the gated norm)
 ATTN_SSD = "gt.attn.ssd"
 ATTN_SSM = "gt.attn.ssm"
-# a Kimi-Delta-Attention mixer (models/base.kda_mixer), inside gt.layers.r<k>,
+# a Kimi-Delta-Attention mixer (models/parts/kda.kda_mixer), inside gt.layers.r<k>,
 # in two disjoint scopes that add up to the mixer, as the linear mixer's: the
 # core (ops/linear_attention.kda_rule: the chunks' decayed products and
 # solves, the carried state, the outputs; forward, recomputed and backward)

@@ -83,7 +83,7 @@ the chip at 8 heads at a time (PERF.md, PR 35), so it is not kept. It is the
 oracle of the kernels' tests, and what every test of a small head runs.
 
 **Around the core** (the convolution, SiLU, the L2 norms before it, the gated
-RMSNorm after it: models/base.linear_mixer states them in XLA, which is what
+RMSNorm after it: models/parts/linear.linear_mixer states them in XLA, which is what
 the CPU runs): where the operands lie on TPUs they run as four lane-aligned
 Pallas passes beside the core's two kernels, all six one `jax.custom_vjp`
 (`mixer_form` decides as `gated_delta_rule` does, `kernel_mixer` is the rule;
@@ -1079,7 +1079,7 @@ _kda_kernel_rule.defvjp(_kda_kernel_rule_fwd, _kda_kernel_rule_bwd)
 # --- around the core, the kernel form ---------------------------------------
 #
 # What a gated-DeltaNet mixer and a Kimi-Delta-Attention mixer do between
-# their projections and the core (models/base.linear_mixer and kda_mixer are
+# their projections and the core (models/parts/linear.linear_mixer and parts/kda.kda_mixer are
 # the definitions: the XLA forms), as passes over (tokens, channels) arrays in
 # which a head is a block of whole 128-lane columns: no (tokens, heads, d) view
 # exists, so nothing is relaid, no norm's scale is broadcast to full size and

@@ -222,8 +222,9 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
     generic tree (lm / mlm / classification — the reference's per-model `Cls_`
     stages, GPTModel_sequential.py:201-215)."""
     from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.parts.embed_head import (embed_patches, embed_tokens, model_head, softmax_nll,
+                                                       vocab_parallel_cross_entropy)
 
-    M.assert_expert_layout_supported(cfg, hp)  # GLS018: no expert form under pp
     validate_pipeline_config(hp)
     vax = vocab_axes(hp)
 
@@ -232,12 +233,12 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
         with jax.named_scope(tracing.EMBED):
             if cfg.input_type == "patches":
                 inputs = batch["pixels"]
-                x = M.embed_patches(params["embed"], inputs, cfg)
+                x = embed_patches(params["embed"], inputs, cfg)
                 positions = jnp.zeros(x.shape[:2], jnp.int32)
             else:
                 inputs = batch["tokens"]
                 positions = batch["positions"]
-                x = M.embed_tokens(params["embed"], inputs, positions, cfg, mesh, vax,
+                x = embed_tokens(params["embed"], inputs, positions, cfg, mesh, vax,
                                    token_type_ids=batch.get("token_type_ids"))
         B = x.shape[0]
         mb = B // num_mb
@@ -272,11 +273,11 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
         h = outs.reshape((B,) + x.shape[1:])
         h = S.constrain(h, mesh, S.act_spec(vax))
         with jax.named_scope(tracing.HEAD_LOSS):
-            logits = M.model_head(params, h, cfg)
+            logits = model_head(params, h, cfg)
             if cfg.head_type == "classification":
-                return M.softmax_nll(logits, batch["labels"])
+                return softmax_nll(logits, batch["labels"])
             logits = S.constrain(logits, mesh, S.logits_spec(vax))
-            return M.vocab_parallel_cross_entropy(
+            return vocab_parallel_cross_entropy(
                 logits, batch["labels"], batch.get("loss_mask"))
 
     return loss_fn

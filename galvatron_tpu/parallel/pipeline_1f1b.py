@@ -242,10 +242,10 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
     runtime/model_api.py (verified against it in
     tests/parallel/test_pipeline_1f1b.py)."""
     from galvatron_tpu.models import base as M
-
+    from galvatron_tpu.models.parts.common import _norm
+    from galvatron_tpu.models.parts.embed_head import (embed_patches, model_head, softmax_nll,
+                                                       vocab_parallel_cross_entropy)
     from galvatron_tpu.parallel.pipeline import stage_layer_offsets
-
-    M.assert_expert_layout_supported(cfg, hp)  # GLS018: no expert form under pp
 
     validate_1f1b_config(hp)
     pp, chunks = hp.pp, hp.chunks
@@ -328,13 +328,13 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
         by driving GPT (learned positions) through the 1F1B schedule. A
         matmul's vjp is a matmul: dense and orderable, but it is run as a
         matmul over the whole vocabulary. Outside this schedule the split
-        table is read by models/base.vocab_parallel_lookup (a manual region:
+        table is read by models/parts/embed_head.vocab_parallel_lookup (a manual region:
         local gather, local scatter-add, one psum, no permute); moving this
         copy onto it waits for a pp cell to measure it in."""
         emb = vparams["embed"]
         dtype = cfg.compute_dtype
         if cfg.input_type == "patches":
-            x = M.embed_patches(emb, inputs, cfg)
+            x = embed_patches(emb, inputs, cfg)
             return S.constrain(x, mesh, mb_spec)
         onehot = jax.nn.one_hot(inputs, cfg.vocab_size, dtype=dtype)
         x = jnp.einsum("bsv,vh->bsh", onehot, emb["wte"].astype(dtype))
@@ -346,19 +346,19 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
             tti1h = jax.nn.one_hot(tti, cfg.type_vocab_size, dtype=dtype)
             x = x + jnp.einsum("bst,th->bsh", tti1h, emb["tte"].astype(dtype))
         if cfg.embed_norm:
-            x = M._norm(x, emb["norm"], cfg)
+            x = _norm(x, emb["norm"], cfg)
         return S.constrain(x, mesh, mb_spec)
 
     @jax.named_scope(tracing.HEAD_LOSS)
     def head_loss(vparams, y, labels, loss_mask, weight):
         h = S.constrain(y, mesh, mb_spec)
-        logits = M.model_head(vparams, h, cfg)
+        logits = model_head(vparams, h, cfg)
         if cfg.head_type == "classification":
-            return M.softmax_nll(logits, labels) * weight
+            return softmax_nll(logits, labels) * weight
         # within-stage vocab sharding (see the vparams gather in
         # loss_and_grad): the CE psums stay group-scoped inside the scan
         logits = S.constrain(logits, mesh, S.logits_spec(vax))
-        return M.vocab_parallel_cross_entropy(logits, labels, loss_mask) * weight
+        return vocab_parallel_cross_entropy(logits, labels, loss_mask) * weight
 
     def loss_and_grad(params, batch):
         vparams_stored = {k: v for k, v in params.items() if k != "stages"}

@@ -235,7 +235,7 @@ def make_encdec_loss_and_grad(cfg, hp: HybridParallelConfig, mesh):
     # ------------------------------------------------------- vocab fwd pieces
     def embed_fwd(vparams, tokens):
         """One-hot wte lookup (see pipeline_1f1b.embed_fwd for why matmul, not
-        gather, and for models/base.vocab_parallel_lookup, which replaces it
+        gather, and for models/parts/embed_head.vocab_parallel_lookup, which replaces it
         outside the 1F1B schedule)."""
         dtype = cfg.compute_dtype
         onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=dtype)
@@ -243,7 +243,7 @@ def make_encdec_loss_and_grad(cfg, hp: HybridParallelConfig, mesh):
         return S.constrain(x, mesh, mb_spec)
 
     def head_loss(vparams, y, labels, loss_mask, weight):
-        from galvatron_tpu.models.base import vocab_parallel_cross_entropy
+        from galvatron_tpu.models.parts.embed_head import vocab_parallel_cross_entropy
 
         dtype = cfg.compute_dtype
         y = T._rms(S.constrain(y, mesh, mb_spec), vparams["dec_norm"], cfg)

@@ -206,7 +206,7 @@ def make_swin_loss_and_grad(cfg, hp: HybridParallelConfig, mesh):
     schedule. params: {embed, final_norm, head, stages}; batch: pixels
     (B, H, W, C), labels (B,)."""
     from galvatron_tpu.models import swin as SW
-    from galvatron_tpu.models.base import patchify, softmax_nll
+    from galvatron_tpu.models.parts.embed_head import patchify, softmax_nll
     from galvatron_tpu.ops.norms import layer_norm
 
     validate_swin_config(cfg, hp)

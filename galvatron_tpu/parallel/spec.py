@@ -38,13 +38,6 @@ def _ax(axes: Sequence[str]) -> Axes:
     return axes
 
 
-def _merge(*groups: Sequence[str]) -> Axes:
-    out: Tuple[str, ...] = ()
-    for g in groups:
-        out += tuple(g)
-    return _ax(out)
-
-
 # ----------------------------------------------------------------- activations
 def act_spec(ax: LayerAxes, *, seq_dim: int = 1, ndim: int = 3) -> P:
     """Sharding of a (batch, seq, hidden) activation *between* layers.
@@ -86,11 +79,6 @@ def row_kernel_spec(ax: LayerAxes) -> P:
     """Row-parallel kernel (in_dim, out_dim): in over tp; ZeRO-3 shards out."""
     tp = () if ax.ulysses else ax.tp
     return P(_ax(tp), _ax(_zero3_axes(ax) or ()))
-
-
-def col_bias_spec(ax: LayerAxes) -> P:
-    tp = () if ax.ulysses else ax.tp
-    return P(_ax(tp))
 
 
 def replicated_1d_spec(ax: LayerAxes) -> P:

@@ -466,7 +466,7 @@ def manual_layer_forward(
         row = make_row_matmul(tp_axes, n, sizes, mode=mode,
                               use_custom_vjp=use_custom_vjp, quant=quant)
 
-        from galvatron_tpu.models.base import _activation, _norm
+        from galvatron_tpu.models.parts.common import _activation, _norm
         from galvatron_tpu.ops.attention import core_attention
         from galvatron_tpu.ops.rope import apply_rotary
 

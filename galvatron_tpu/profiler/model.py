@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.utils.jsonio import write_json_config
 
 MB = 2.0**20
@@ -121,7 +122,7 @@ class ModelProfiler:
         self.args = args or ModelProfileArgs()
 
     def _check_config(self, cfg):
-        if not isinstance(cfg, M.TransformerConfig):
+        if not isinstance(cfg, TransformerConfig):
             raise TypeError(
                 "ModelProfiler profiles TransformerConfig families; t5 uses "
                 "T5ModelProfiler (two layer types, reference "
@@ -298,7 +299,7 @@ class ModelProfiler:
         from galvatron_tpu.parallel import spec as S
         from galvatron_tpu.parallel.mesh import layer_axes
 
-        if not isinstance(self.cfg, M.TransformerConfig):
+        if not isinstance(self.cfg, TransformerConfig):
             return None
         cfg = dataclasses.replace(self.cfg, num_layers=max(n, 1))
         keys = jax.random.split(jax.random.PRNGKey(0), max(n, 1))

@@ -58,6 +58,7 @@ from typing import Any, Dict, Optional, Tuple
 
 from galvatron_tpu.analysis import diagnostics as D
 from galvatron_tpu.config.strategy import HybridParallelConfig
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.obs import telemetry
 
 DEFAULT_MEMORY_GB = 16.0  # matches the search CLI's --memory_constraint default
@@ -66,25 +67,16 @@ DEFAULT_MEMORY_GB = 16.0  # matches the search CLI's --memory_constraint default
 # choices (the manifest's spec_digest machinery already handles a dtype
 # change), not model identity
 _DIGEST_EXCLUDE = ("compute_dtype", "param_dtype", "attn_impl")
-# fields newer than checkpoints in the field, with the value under which the
-# model is the one those checkpoints hold: left out at that value, so that a
-# dense model's digest is what it was before the field existed
-_DIGEST_DEFAULTS = {
-    "num_experts": 0, "experts_per_token": 0, "norm_topk_prob": False,
-    "router_aux_loss_coef": 0.0, "router_z_loss_coef": 0.0, "qk_norm": False,
-    "q_lora_rank": 0, "kv_lora_rank": 0, "qk_nope_head_dim": 0, "qk_rope_head_dim": 0,
-    "v_head_dim": 0, "first_dense_layers": 0, "dense_ffn_hidden": None,
-    "num_shared_experts": 0, "router_score": "softmax", "routed_scaling_factor": 1.0,
-    "router_bias": False, "router_bias_update_rate": 0.0, "experts_held": 0,
-    "experts_held_start": 0, "mtp_layers": 0, "mtp_loss_weight": 0.0,
-    "full_attention_interval": 0, "linear_num_key_heads": 0, "linear_num_value_heads": 0,
-    "linear_key_head_dim": 0, "linear_value_head_dim": 0, "linear_conv_kernel": 0,
-    "partial_rotary_factor": 1.0, "attn_output_gate": False, "norm_zero_centered": False,
-    "shared_expert_gate": False, "mixer": "attention",
-    "layer_types": None, "ssm_num_heads": 0, "ssm_head_dim": 0, "ssm_state_dim": 0,
-    "ssm_conv_kernel": 0, "embedding_multiplier": 1.0,
-    "residual_multiplier": 1.0, "attention_multiplier": None, "logits_scaling": 1.0,
-}
+# the fields of the config checkpoints in the field were first written from
+# (PR 26's): always digested. Any other field of `TransformerConfig` at its
+# dataclass default is left out, so that a model's digest is what it was before
+# the field existed and a new model's fields need no edit here
+_DIGEST_ALWAYS = frozenset((
+    "hidden_size", "num_heads", "num_layers", "vocab_size", "max_seq_len", "num_kv_heads", "ffn_hidden",
+    "head_dim", "norm_type", "activation", "position_type", "causal", "pre_norm", "tie_embeddings", "qkv_bias",
+    "mlp_bias", "out_bias", "layernorm_eps", "rope_theta",
+    "init_std", "type_vocab_size", "embed_norm", "head_type", "num_classes", "pool_type", "input_type",
+    "image_size", "patch_size", "num_channels", "use_cls_token"))
 
 
 def _stable_json(obj: Any) -> str:
@@ -100,8 +92,10 @@ def model_config_digest(model_cfg: Any) -> str:
         fields = dataclasses.asdict(model_cfg)
     else:  # duck-typed configs (tests)
         fields = {k: v for k, v in vars(model_cfg).items() if not k.startswith("_")}
+    # `TransformerConfig`'s own, whatever the config: T5's and Swin's fields are all digested
+    defaults = {f.name: f.default for f in dataclasses.fields(TransformerConfig) if f.name not in _DIGEST_ALWAYS}
     fields = {k: str(v) for k, v in fields.items() if k not in _DIGEST_EXCLUDE
-              and not (k in _DIGEST_DEFAULTS and v == _DIGEST_DEFAULTS[k])}
+              and not (k in defaults and v == defaults[k])}
     return hashlib.sha256(_stable_json(fields).encode()).hexdigest()
 
 

@@ -28,6 +28,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.parts.embed_head import table_is_looked_up
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import build_mesh, layer_axes, vocab_axes
@@ -42,7 +44,7 @@ def _is_spec(x):
 
 @dataclass
 class HybridParallelModel:
-    cfg: M.TransformerConfig
+    cfg: TransformerConfig
     hp: HybridParallelConfig
     mesh: Mesh
     param_specs: Params
@@ -503,12 +505,12 @@ class HybridParallelModel:
 
 
 def construct_hybrid_parallel_model(
-    cfg: M.TransformerConfig,
+    cfg: TransformerConfig,
     hp: HybridParallelConfig,
     devices=None,
     loss_fn=None,
 ) -> HybridParallelModel:
-    M.assert_expert_layout_supported(cfg, hp)
+    M.refuse_unsupported(cfg, hp)  # GLS018: where a config first meets a layout
     mesh = build_mesh(hp, devices)
     specs = M.model_param_specs(cfg, hp)
     grad_fn = None
@@ -576,5 +578,5 @@ def construct_hybrid_parallel_model(
         local_loss_fn=local_loss,
         loss_parts_fn=loss_parts,
         cast_first=None if loss_fn is not None else S.cast_first_tree(
-            specs, table_stored=M.table_is_looked_up(vocab_axes(hp)) or cfg.tie_embeddings),
+            specs, table_stored=table_is_looked_up(vocab_axes(hp)) or cfg.tie_embeddings),
     )

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import PartitionSpec as P
 
-from galvatron_tpu.models.base import ROUTER_BIAS
+from galvatron_tpu.models.parts.mlp import ROUTER_BIAS
 
 
 @dataclass
@@ -53,7 +53,7 @@ def _no_weight_decay(path, _leaf) -> bool:
 
 
 # Leaves of the parameter tree that no gradient moves, by their key: a
-# sigmoid router's `e_score_correction_bias` (models/base.ROUTER_BIAS), which
+# sigmoid router's `e_score_correction_bias` (models/parts/mlp.ROUTER_BIAS), which
 # the train step itself steps once a step (models/base.update_router_bias).
 # The optimizer never sees them: no clipping share, no Adam moments, no decay.
 NO_GRADIENT_KEYS = (ROUTER_BIAS,)

@@ -38,6 +38,8 @@ from jax.sharding import Mesh
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.parts.embed_head import embed_tokens, lm_logits
 from galvatron_tpu.obs import telemetry as T
 from galvatron_tpu.serve.kv_cache import (
     KVCacheConfig,
@@ -123,7 +125,7 @@ def sample_token(logits: jax.Array, rng: jax.Array, temperature: float) -> jax.A
 
 # ------------------------------------------------------------ step factories
 def make_prefill_step(
-    cfg: M.TransformerConfig,
+    cfg: TransformerConfig,
     hp: Optional[HybridParallelConfig],
     mesh: Optional[Mesh],
     kv_cfg: KVCacheConfig,
@@ -145,7 +147,7 @@ def make_prefill_step(
         positions = jnp.broadcast_to(jnp.arange(ctx_b), (1, ctx_b))
         valid = (jnp.arange(ctx_b) < prompt_len)[None, :]
         bias = M.padding_attn_bias(valid)
-        x = M.embed_tokens(params["embed"], tokens, positions, cfg, mesh, vax)
+        x = embed_tokens(params["embed"], tokens, positions, cfg, mesh, vax)
         x, kvs = M.run_layers(
             params, x, positions, cfg,
             hp if use_hp else None, mesh if use_hp else None,
@@ -154,7 +156,7 @@ def make_prefill_step(
         h_last = jax.lax.dynamic_slice(
             x, (0, prompt_len - 1, 0), (1, 1, x.shape[-1])
         )
-        logits = M.lm_logits(params, h_last, cfg)[:, 0]
+        logits = lm_logits(params, h_last, cfg)[:, 0]
         token = sample_token(logits, rng, temperature)
         cache = constrain_cache(write_prompt_kv(cache, kvs, slot, prompt_len))
         return cache, token, logits
@@ -163,7 +165,7 @@ def make_prefill_step(
 
 
 def make_decode_step(
-    cfg: M.TransformerConfig,
+    cfg: TransformerConfig,
     hp: Optional[HybridParallelConfig],
     mesh: Optional[Mesh],
     kv_cfg: KVCacheConfig,
@@ -184,7 +186,7 @@ def make_decode_step(
     def decode(params, cache, tokens, active, rng):
         lengths = cache["lengths"]
         positions = lengths[:, None]
-        x = M.embed_tokens(params["embed"], tokens[:, None], positions, cfg, mesh, vax)
+        x = embed_tokens(params["embed"], tokens[:, None], positions, cfg, mesh, vax)
         bias = length_bias(lengths, ctx_b)
         k_list, v_list = list(cache["k"]), list(cache["v"])
         for li in range(cfg.num_layers):
@@ -198,7 +200,7 @@ def make_decode_step(
             )
             k_list[li] = jax.lax.dynamic_update_slice(k_list[li], k_c, (0, 0, 0, 0))
             v_list[li] = jax.lax.dynamic_update_slice(v_list[li], v_c, (0, 0, 0, 0))
-        logits = M.lm_logits(params, x, cfg)[:, 0]
+        logits = lm_logits(params, x, cfg)[:, 0]
         next_tok = sample_token(logits, rng, temperature)
         next_tok = jnp.where(active, next_tok, tokens)
         lengths = lengths + active.astype(jnp.int32)
@@ -218,7 +220,7 @@ class ServeEngine:
 
     def __init__(
         self,
-        cfg: M.TransformerConfig,
+        cfg: TransformerConfig,
         params: Any,
         kv_cfg: KVCacheConfig,
         hp: Optional[HybridParallelConfig] = None,

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import layer_axes, mesh_axis_size
 

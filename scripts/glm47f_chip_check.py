@@ -84,6 +84,7 @@ def main(argv=None) -> int:
     from benchmarks import cells
     from galvatron_tpu import HybridParallelConfig
     from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.parts.mlp import ROUTER_BIAS
     from galvatron_tpu.ops import moe
     from galvatron_tpu.runtime import construct_hybrid_parallel_model
 
@@ -112,7 +113,7 @@ def main(argv=None) -> int:
             tokens=tokens, positions=jnp.arange(seq)[None], labels=jnp.roll(tokens, -1, 1),
             loss_mask=jnp.ones((1, seq), jnp.float32).at[:, -1].set(0.0)))
         routers = [r["kernel"] for r in M.router_bias_leaves(params)]
-        biases = [r[M.ROUTER_BIAS] for r in M.router_bias_leaves(params)]
+        biases = [r[ROUTER_BIAS] for r in M.router_bias_leaves(params)]
 
         def bf16_router(y, kernel):
             return (y.astype(jnp.bfloat16) @ kernel.astype(jnp.bfloat16)).astype(jnp.float32)

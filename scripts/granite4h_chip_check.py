@@ -84,7 +84,9 @@ def main(argv=None) -> int:
         return 2
     from benchmarks import cells
     from galvatron_tpu import HybridParallelConfig
-    from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.parts.common import _norm
+    from galvatron_tpu.models.parts.embed_head import embed_tokens
+    from galvatron_tpu.models.parts import ssm as part
     from galvatron_tpu.ops import ssd
     from galvatron_tpu.runtime import construct_hybrid_parallel_model
 
@@ -97,7 +99,7 @@ def main(argv=None) -> int:
     fields = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
     hp = HybridParallelConfig.uniform(1, cfg.num_layers, global_bsz=1, checkpoint=1)
     model = construct_hybrid_parallel_model(cfg, hp)
-    committed_scan = M.ssd_scan
+    committed_scan = part.ssd_scan
     rel = lambda d, e: float(np.linalg.norm(np.asarray(d, np.float64)) / np.linalg.norm(np.asarray(e, np.float64)))  # noqa: E731
 
     @jax.jit
@@ -105,18 +107,18 @@ def main(argv=None) -> int:
         """Layer 0's x, dt, A, B, C, D as the program makes them."""
         lcfg = cfg.layer_config(cfg.layer_kinds()[0])
         lp = params["layers"][0]
-        x = M.embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg)
+        x = embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg)
         box = {}
 
         def spy(*operands, **kw):
             box["operands"] = operands
             return committed_scan(*operands, **kw)
 
-        M.ssd_scan = spy
+        part.ssd_scan = spy
         try:
-            M.ssm_mixer(lp, M._norm(x, lp["ln1"], lcfg), None, lcfg)
+            part.ssm_mixer(lp, _norm(x, lp["ln1"], lcfg), None, lcfg)
         finally:
-            M.ssd_scan = committed_scan
+            part.ssd_scan = committed_scan
         return box["operands"]
 
     @jax.jit

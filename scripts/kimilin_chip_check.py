@@ -128,6 +128,10 @@ def main(argv=None) -> int:
     from benchmarks import cells
     from galvatron_tpu import HybridParallelConfig
     from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.parts.common import _norm
+    from galvatron_tpu.models.parts.embed_head import embed_tokens
+    from galvatron_tpu.models.parts import kda as part
+    from galvatron_tpu.models.parts.mlp import ROUTER_BIAS
     from galvatron_tpu.ops import linear_attention as L
     from galvatron_tpu.ops import moe
     from galvatron_tpu.runtime import construct_hybrid_parallel_model
@@ -144,8 +148,8 @@ def main(argv=None) -> int:
     k = cfg.experts_per_token
     kinds = cfg.layer_kinds()
     kda_layers = [i for i, kind in enumerate(kinds) if kind.startswith("kda")]
-    committed_router, committed_rule, committed_carry = moe.router_logits, M.kda_rule, L._carry
-    committed_form = M.mixer_form
+    committed_router, committed_rule, committed_carry = moe.router_logits, part.kda_rule, L._carry
+    committed_form = part.mixer_form
 
     def xla_form(*_, **__):
         return "xla"
@@ -179,19 +183,19 @@ def main(argv=None) -> int:
         """Every KDA layer's (q, k, v, g, beta) as the mixer's XLA form makes
         them (the form that hands the core its operands), in the layers'
         order: one unrolled forward through the stack."""
-        x = M.embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg)
+        x = embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg)
         handed = []
 
         def spy(*operands, **where):
             handed.append(operands)
             return committed_rule(*operands, **where)
 
-        M.kda_rule, M.mixer_form = spy, xla_form
+        part.kda_rule, part.mixer_form = spy, xla_form
         try:
             for lp, kind in zip(params["layers"], kinds):
                 x = M.layer_forward(lp, x, jnp.arange(seq)[None], cfg.layer_config(kind))[0]
         finally:
-            M.kda_rule, M.mixer_form = committed_rule, committed_form
+            part.kda_rule, part.mixer_form = committed_rule, committed_form
         return handed
 
     def mixer_errors(params, tokens, with_control):
@@ -200,18 +204,18 @@ def main(argv=None) -> int:
         kernels and through the XLA form, each a program of its own: the
         output, and every leaf's gradient of a fixed probe of it."""
         lcfg, lp = cfg.layer_config(kinds[0]), params["layers"][0]  # the stack's first layer is a KDA layer
-        y = jax.jit(lambda: M._norm(M.embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg),
+        y = jax.jit(lambda: _norm(embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg),
                                     lp["ln1"], lcfg))()
         probe = jax.random.normal(jax.random.PRNGKey(17), y.shape, jnp.float32)
 
         def run(form, taps_dropped=0):
             def of(kda, y):
                 kda = dict(kda, conv=kda["conv"].at[:, :taps_dropped].set(0.0))
-                M.mixer_form = form
+                part.mixer_form = form
                 try:
-                    out = M.kda_mixer({"kda": kda}, y, None, lcfg)[0]
+                    out = part.kda_mixer({"kda": kda}, y, None, lcfg)[0]
                 finally:
-                    M.mixer_form = committed_form
+                    part.mixer_form = committed_form
                 return jnp.sum(out.astype(jnp.float32) * probe), out
 
             fn = jax.jit(jax.value_and_grad(of, argnums=(0, 1), has_aux=True))
@@ -292,7 +296,7 @@ def main(argv=None) -> int:
 
         def picks_of(seen):
             """(routed blocks, S, k) as the program picks: the k largest of score + bias."""
-            return jnp.stack([jax.lax.top_k(jax.nn.sigmoid(jnp.asarray(logits)) + router[M.ROUTER_BIAS], k)[1]
+            return jnp.stack([jax.lax.top_k(jax.nn.sigmoid(jnp.asarray(logits)) + router[ROUTER_BIAS], k)[1]
                               for (_, logits), router in zip(seen, routers)])
 
         def program():

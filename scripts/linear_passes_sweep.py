@@ -20,7 +20,7 @@ and read `chiprun_out/linear_passes_sweep.<mixer>.json` (no mixer named: both,
 one after the other). Times are medians of fenced calls on one chip, a pass
 alone (a call of each of its segments), for each setting of (`_TOKENS`,
 `_LANES`, `_AT_ONCE`) given, the committed one first; beside them the XLA form
-of the same arithmetic (models/base.linear_mixer's and kda_mixer's), forward
+of the same arithmetic (models/parts/linear.linear_mixer's and parts/kda.kda_mixer's), forward
 and backward, how far each kernel's results lie from it, and the least the
 bytes allow. Refuses to run where jax finds no TPU.
 """

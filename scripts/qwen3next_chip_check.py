@@ -117,7 +117,9 @@ def main(argv=None) -> int:
         return 2
     from benchmarks import cells
     from galvatron_tpu import HybridParallelConfig
-    from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.parts.common import _norm
+    from galvatron_tpu.models.parts.embed_head import embed_tokens
+    from galvatron_tpu.models.parts import linear as part
     from galvatron_tpu.ops import linear_attention as L
     from galvatron_tpu.ops import moe
     from galvatron_tpu.runtime import construct_hybrid_parallel_model
@@ -133,7 +135,7 @@ def main(argv=None) -> int:
     model = construct_hybrid_parallel_model(cfg, hp)
     k = cfg.experts_per_token
     committed = moe.router_logits
-    committed_core, committed_form = M.gated_delta_rule, M.mixer_form
+    committed_core, committed_form = part.gated_delta_rule, part.mixer_form
 
     def xla_form(*_, **__):
         return "xla"
@@ -170,18 +172,18 @@ def main(argv=None) -> int:
         """Layer 0's q, k, v, g, beta as the program makes them."""
         lcfg = cfg.layer_config(cfg.layer_kinds()[0])
         lp = params["layers"][0]
-        x = M.embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg)
+        x = embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg)
         box = {}
 
         def spy(*operands, **where):
             box["operands"] = operands
             return committed_core(*operands, **where)
 
-        M.gated_delta_rule, M.mixer_form = spy, xla_form  # the form that hands the core its operands
+        part.gated_delta_rule, part.mixer_form = spy, xla_form  # the form that hands the core its operands
         try:
-            M.linear_mixer(lp, M._norm(x, lp["ln1"], lcfg), None, lcfg)
+            part.linear_mixer(lp, _norm(x, lp["ln1"], lcfg), None, lcfg)
         finally:
-            M.gated_delta_rule, M.mixer_form = committed_core, committed_form
+            part.gated_delta_rule, part.mixer_form = committed_core, committed_form
         return box["operands"]
 
     def mixer_errors(params, tokens, with_control):
@@ -191,18 +193,18 @@ def main(argv=None) -> int:
         every leaf's gradient of a fixed probe of it."""
         lcfg = cfg.layer_config(cfg.layer_kinds()[0])
         lp = params["layers"][0]
-        y = jax.jit(lambda: M._norm(M.embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg),
+        y = jax.jit(lambda: _norm(embed_tokens(params["embed"], tokens, jnp.arange(seq)[None], cfg),
                                     lp["ln1"], lcfg))()
         probe = jax.random.normal(jax.random.PRNGKey(17), y.shape, jnp.float32)
 
         def run(form, taps_dropped=0):
             def of(linear, y):
                 linear = dict(linear, conv=linear["conv"].at[:, :taps_dropped].set(0.0))
-                M.mixer_form = form
+                part.mixer_form = form
                 try:
-                    out = M.linear_mixer({"linear": linear}, y, None, lcfg)[0]
+                    out = part.linear_mixer({"linear": linear}, y, None, lcfg)[0]
                 finally:
-                    M.mixer_form = committed_form
+                    part.mixer_form = committed_form
                 return jnp.sum(out.astype(jnp.float32) * probe), out
 
             fn = jax.jit(jax.value_and_grad(of, argnums=(0, 1), has_aux=True))

@@ -89,9 +89,12 @@ def test_no_mention_of_what_was_removed():
     repo measures (PR 29). The CPU measuring stack, the options only it
     needed and the environment's peak override are gone, and nothing that
     describes or drives the program names them any more; the records
-    (CHANGES.md, PERF.md, ROADMAP.md) may, as history."""
+    (CHANGES.md, PERF.md, ROADMAP.md) may, as history. So are the four
+    functions that said which layouts a part supports, the two that asked
+    the layer pattern and the hand-kept digest defaults (PR 45: models/parts)."""
     gone = ("bench.py", "_bench_util", "--no_async_loop", "--donate_step",
-            "GALVATRON_PEAK_FLOPS")
+            "GALVATRON_PEAK_FLOPS", "expert_layout_reason", "linear_layers_reason", "_has_linear",
+            "_has_mixer", "assert_expert_layout_supported", "_DIGEST_DEFAULTS")
     files = [os.path.join(REPO, "README.md"), os.path.join(REPO, "COVERAGE.md")]
     for top in (PACKAGE, os.path.join(REPO, "scripts"), os.path.join(REPO, ".claude")):
         for ext in ("py", "md", "sh"):

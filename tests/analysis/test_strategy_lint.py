@@ -9,7 +9,7 @@ import pytest
 
 from galvatron_tpu.analysis import strategy_lint as S
 from galvatron_tpu.analysis.diagnostics import ERROR, WARNING
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
 WORLD = 8

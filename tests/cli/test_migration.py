@@ -22,7 +22,7 @@ import pytest
 
 from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.config.strategy import HybridParallelConfig
-from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.runtime import checkpoint as ck
 from galvatron_tpu.runtime import elastic as els
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
@@ -45,7 +45,7 @@ def tiny_cfg(**kw):
     kw.setdefault("num_layers", 4)
     kw.setdefault("vocab_size", 64)
     kw.setdefault("max_seq_len", 16)
-    return M.TransformerConfig(**kw)
+    return TransformerConfig(**kw)
 
 
 def make_tx():

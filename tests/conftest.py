@@ -81,9 +81,9 @@ _GPT_B, _GPT_S, _GPT_V = 8, 32, 128
 def gpt_cfg():
     import jax.numpy as jnp
 
-    from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.config import TransformerConfig
 
-    return M.TransformerConfig(
+    return TransformerConfig(
         hidden_size=64, num_heads=4, num_layers=4, vocab_size=_GPT_V,
         max_seq_len=64, compute_dtype=jnp.float32,
     )

@@ -138,7 +138,7 @@ def test_eval_loss_reads_the_copy(devices4):
 # sha256 of `make_train_step(adam).lower(...).as_text()` (StableHLO) for
 # layouts in which no leaf is copied: the step is to stay that text. The
 # parent is PR 30's step (the head and its loss under their written backward,
-# models/base._head_matmul and _token_nll; before it, from the commit before
+# models/parts/embed_head._head_matmul and _token_nll; before it, from the commit before
 # the compute copy, 9c3c713, to PR 29, the text was one other). A PR that
 # changes the step on purpose prints the new digests with
 # `pytest -k lowers_to -s` and replaces these.

@@ -28,10 +28,15 @@ from galvatron_tpu import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.analysis import strategy_lint
 from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.parts import unsupported_reason
+from galvatron_tpu.models.parts.attention import latent_qkv_projection
+from galvatron_tpu.models.parts.embed_head import _token_nll
+from galvatron_tpu.models.parts.mlp import ROUTER_BIAS, dense_mlp
 from galvatron_tpu.models import glm4_moe_lite as G
 from galvatron_tpu.models.registry import get_family
 from galvatron_tpu.obs import telemetry
 from galvatron_tpu.ops import moe
+from galvatron_tpu.ops.attention import core_attention
 from galvatron_tpu.runtime import construct_hybrid_parallel_model
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -66,7 +71,7 @@ def params_of(cfg, seed=0):
     """Seeded weights with a bias that is not zero, so that it is read."""
     params = M.init_model_params(jax.random.PRNGKey(seed), cfg)
     for i, router in enumerate(M.router_bias_leaves(params)):
-        router[M.ROUTER_BIAS] = 0.05 * jax.random.normal(jax.random.PRNGKey(100 + i), (EXPERTS,))
+        router[ROUTER_BIAS] = 0.05 * jax.random.normal(jax.random.PRNGKey(100 + i), (EXPERTS,))
     return params
 
 
@@ -137,7 +142,7 @@ def test_the_tree_has_a_dense_layer_then_routed_ones_and_the_mtp_module(case):
     held = cfg.held_experts[1]
     assert "router" not in dense and dense["wi"]["kernel"].shape == (64, 2, 96)
     assert routed["router"]["kernel"].shape == (64, EXPERTS)  # the router's width is not cut
-    assert routed["router"][M.ROUTER_BIAS].shape == (EXPERTS,)
+    assert routed["router"][ROUTER_BIAS].shape == (EXPERTS,)
     assert routed["wi"]["kernel"].shape == (held, 64, 64)
     assert routed["wo_mlp"]["kernel"].shape == (held, 32, 64)
     assert routed["shared"]["wi"]["kernel"].shape == (64, 2, 32)
@@ -194,8 +199,8 @@ def test_the_counters_count_what_the_reference_picked(case):
 
 # ----------------------------------------------------- latent attention alone
 def _attention_alone(cfg, lp, y, positions):
-    q, k, v = M.latent_qkv_projection(lp, y, positions, cfg, jnp.float32)
-    out = M.core_attention(q, k, v, causal=True, impl="xla")
+    q, k, v = latent_qkv_projection(lp, y, positions, cfg, jnp.float32)
+    out = core_attention(q, k, v, causal=True, impl="xla")
     return out.reshape(out.shape[0], out.shape[1], -1) @ lp["wo"]["kernel"]
 
 
@@ -220,10 +225,10 @@ def test_latent_attention_matches_the_references():
 
     # what it is held against: a key rotated a head at its own dims (not
     # shared), and the scale of the nope dims alone
-    q, k, v = M.latent_qkv_projection(lp, y, positions, cfg, jnp.float32)
+    q, k, v = latent_qkv_projection(lp, y, positions, cfg, jnp.float32)
     assert float(jnp.max(jnp.abs(k[:, :, 0, 12:] - k[:, :, 3, 12:]))) == 0.0
     assert float(jnp.max(jnp.abs(k[:, :, 0, :12] - k[:, :, 3, :12]))) > 0.0
-    wrong_scale = M.core_attention(q, k, v, causal=True, impl="xla", sm_scale=12 ** -0.5)
+    wrong_scale = core_attention(q, k, v, causal=True, impl="xla", sm_scale=12 ** -0.5)
     wrong = wrong_scale.reshape(2, SEQ, -1) @ lp["wo"]["kernel"]
     assert float(jnp.max(jnp.abs(wrong - theirs))) > 1e-3 * scale
 
@@ -242,13 +247,13 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
     with jax.default_matmul_precision("highest"):
         uncut = jnp.stack([REF._swiglu(lp["shared"], y[b]) + REF._routed(lp, y[b], fields)[0]
                            for b in range(BATCH)])
-        shared = M.dense_mlp(lp["shared"], y, whole, jnp.float32)
+        shared = dense_mlp(lp["shared"], y, whole, jnp.float32)
         parts, rows = [], 0.0
         for first in range(EXPERTS):
             out, aux = moe.moe_ffn(
                 y, lp["router"]["kernel"], lp["wi"]["kernel"][first:first + 1],
                 lp["wo_mlp"]["kernel"][first:first + 1], experts_per_token=2, norm_topk_prob=True,
-                dtype=jnp.float32, score="sigmoid", bias=lp["router"][M.ROUTER_BIAS],
+                dtype=jnp.float32, score="sigmoid", bias=lp["router"][ROUTER_BIAS],
                 scale=whole.routed_scaling_factor, held=(first, 1))
             parts.append(out)
             rows += float(aux["rows_held"])
@@ -289,11 +294,11 @@ def test_mtps_labels_are_shifted_by_two():
     params, batch = params_of(cfg), batch_of()
     _, hidden, _ = M._forward(params, batch["tokens"], batch["positions"], cfg)
     logits2, _ = M.mtp_logits(params, hidden, batch, cfg)
-    nll = np.asarray(M._token_nll(logits2, jnp.roll(batch["labels"], -1, axis=1)))
+    nll = np.asarray(_token_nll(logits2, jnp.roll(batch["labels"], -1, axis=1)))
     by_two = nll[:, :-2].mean()  # positions 0 .. S-3: labels[i+1] exists and counts
     (_, parts), _ = program(cfg, params, batch)
     assert float(parts["loss_mtp"]) == pytest.approx(by_two, rel=1e-6)
-    by_one = np.asarray(M._token_nll(logits2, batch["labels"]))[:, :-2].mean()
+    by_one = np.asarray(_token_nll(logits2, batch["labels"]))[:, :-2].mean()
     assert abs(by_one - by_two) > 1e-3
     # t_{i+2} by the tokens themselves
     np.testing.assert_array_equal(np.asarray(jnp.roll(batch["labels"], -1, axis=1))[:, :-2],
@@ -410,5 +415,5 @@ def test_serve_and_the_autotuner_refuse_it_and_name_latent_attention(kwargs, nam
     assert strategy_lint.lint_hp(hp, model_cfg=cfg, mode="train").ok
     # latent attention alone (no experts) is refused the same way
     dense = dataclasses.replace(cfg, num_experts=0, experts_per_token=0, mtp_layers=0)
-    assert M.expert_layout_reason(dense, hp, mode="serve") is not None
-    assert M.expert_layout_reason(dense, hp) is None
+    assert unsupported_reason(dense, hp, "serve") is not None
+    assert unsupported_reason(dense, hp) is None

@@ -36,6 +36,9 @@ from galvatron_tpu.analysis import strategy_lint
 from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.config.strategy import layer_runs, model_layer_kinds
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.parts import unsupported_reason
+from galvatron_tpu.models.parts.attention import attention_mixer
+from galvatron_tpu.models.parts.ssm import ssm_mixer
 from galvatron_tpu.models import granite_hybrid as G
 from galvatron_tpu.models.glm4_moe_lite import glm4_moe_lite_config
 from galvatron_tpu.models.gpt import gpt_config
@@ -258,7 +261,7 @@ def test_the_ssm_mixer_is_its_few_lines():
     y = jax.random.normal(jax.random.PRNGKey(1), (1, SEQ, 64))
     p = lp["ssm"]
     with jax.default_matmul_precision("highest"):
-        got, kv, counters = M.ssm_mixer(lp, y, None, lcfg)
+        got, kv, counters = ssm_mixer(lp, y, None, lcfg)
         zxbcdt = y[0] @ p["win"]["kernel"]
         z, xbc, dt = zxbcdt[:, :128], zxbcdt[:, 128:288], zxbcdt[:, 288:]
         xbc = jax.nn.silu(REF.conv_shifted(xbc, p["conv"]["kernel"], p["conv"]["bias"]))
@@ -287,7 +290,7 @@ def test_the_attention_layer_takes_granites_scale_and_no_positions():
     lp = M.init_layer_params(jax.random.PRNGKey(0), lcfg)
     assert set(lp) == {"ln1", "ln2", "wq", "wkv", "wo", "wi", "wo_mlp"} and "bias" not in lp["wq"]
     y = jax.random.normal(jax.random.PRNGKey(1), (1, SEQ, 64))
-    run = lambda cfg, pos: M.attention_mixer(  # noqa: E731
+    run = lambda cfg, pos: attention_mixer(  # noqa: E731
         lp, y, pos, cfg, mesh=None, axes=None, attn_bias=None, attn_sharding=None, return_kv=False)[0]
     pos = jnp.arange(SEQ)[None]
     with jax.default_matmul_precision("highest"):
@@ -330,7 +333,7 @@ def test_the_other_families_are_what_they_were_under_the_new_fields_defaults(fam
     stated = FAMILIES[family](embedding_multiplier=1.0, residual_multiplier=1.0, attention_multiplier=None,
                               logits_scaling=1.0, layer_types=None)
     assert _first_loss_and_count(cfg) == _first_loss_and_count(stated)
-    assert "ssm" not in " ".join(cfg.layer_kinds()) and cfg.mixers() is None
+    assert "ssm" not in " ".join(cfg.layer_kinds()) and "ssm" not in cfg.mixers()
     params = jax.eval_shape(lambda: M.init_model_params(jax.random.PRNGKey(0), cfg))
     tok = jax.ShapeDtypeStruct((2, 64), jnp.int32)
     batch = dict(tokens=tok, positions=tok, labels=tok)
@@ -343,7 +346,7 @@ def test_the_other_families_are_what_they_were_under_the_new_fields_defaults(fam
 
 def test_one_table_maps_the_mixer_to_what_it_brings():
     assert M.MIXERS["ssm"].scopes == (tracing.ATTN_SSM, tracing.ATTN_SSD) == ("gt.attn.ssm", "gt.attn.ssd")
-    assert callable(getattr(obs_flops, M.MIXERS["ssm"].flops))
+    assert callable(obs_flops.MIXER_FWD_FLOPS["ssm"][0])
     cfg = tiny()
     kinds = obs_flops.layer_kind_fwd_flops(cfg, 1.0)
     proj, core = obs_flops.ssm_fwd_flops_a_token(hidden=64, num_heads=4, head_dim=32, state_dim=16)
@@ -404,8 +407,9 @@ def test_serve_and_the_autotuner_refuse_it_and_name_the_state_space_layers(kwarg
     errors = strategy_lint.lint_hp(hp, model_cfg=cfg, **kwargs).errors
     assert any(d.code == "GLS018" and named in d.message for d in errors)
     assert strategy_lint.lint_hp(hp, model_cfg=cfg, mode="train").ok
-    assert "state-space layers" in M.linear_layers_reason(cfg) and "cost models" in M.linear_layers_reason(cfg)
-    assert M.linear_layers_reason(llama_config("llama-0.3b")) is None
+    assert "state-space layers" in unsupported_reason(cfg, asker="search")
+    assert "cost models" in unsupported_reason(cfg, asker="search")
+    assert unsupported_reason(llama_config("llama-0.3b"), asker="search") is None
 
 
 @pytest.mark.parametrize("surface", ["search", "profile"])

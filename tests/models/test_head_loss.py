@@ -1,4 +1,4 @@
-"""The head and its loss have a written backward (models/base._head_matmul,
+"""The head and its loss have a written backward (models/parts/embed_head._head_matmul,
 _token_nll: PR 30). Held here, on the CPU at tiny widths: the rule against
 `jax.grad` of the plain form it replaced, which this file keeps as the oracle;
 that sharding, accumulation and GPipe reach the same numbers through it; and
@@ -14,6 +14,8 @@ import pytest
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.parts import embed_head
+from galvatron_tpu.models.parts.embed_head import _times_kernel, vocab_parallel_cross_entropy
 from galvatron_tpu.models.bert import bert_config
 from galvatron_tpu.models.gpt import gpt_config
 from galvatron_tpu.models.llama import llama_config
@@ -48,10 +50,10 @@ def plain_cross_entropy(logits, labels, loss_mask=None):
 
 @pytest.fixture
 def plain_form(monkeypatch):
-    """Inside: models/base differentiates head and loss operation by operation."""
+    """Inside: models/parts/embed_head differentiates head and loss operation by operation."""
     def on():
-        monkeypatch.setattr(M, "_head_matmul", M._times_kernel)
-        monkeypatch.setattr(M, "_token_nll", plain_token_nll)
+        monkeypatch.setattr(embed_head, "_head_matmul", _times_kernel)
+        monkeypatch.setattr(embed_head, "_token_nll", plain_token_nll)
     return on
 
 
@@ -86,7 +88,7 @@ def test_cross_entropy_rule_against_autodiff(dtype, mask, vocab):
     labels = jax.random.randint(k2, (2, 16), 0, vocab)
     loss_mask = {"none": None, "some": (jax.random.uniform(k3, (2, 16)) > 0.3).astype(F32),
                  "all_zero": jnp.zeros((2, 16), F32)}[mask]
-    got = jax.value_and_grad(M.vocab_parallel_cross_entropy)(logits, labels, loss_mask)
+    got = jax.value_and_grad(vocab_parallel_cross_entropy)(logits, labels, loss_mask)
     want = jax.value_and_grad(plain_cross_entropy)(logits, labels, loss_mask)
     assert got[1].dtype == logits.dtype
     assert_same(got, want, dtype)

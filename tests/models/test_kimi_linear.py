@@ -1,4 +1,4 @@
-"""Kimi-Linear's layers and objective (models/base.py `kda_mixer` and latent
+"""Kimi-Linear's layers and objective (models/parts/kda.py `kda_mixer` and latent
 attention without positions, ops/linear_attention.py `kda_rule`,
 models/kimi_linear.py) against the plain reference
 (benchmarks/references/kimi_linear_lm.py) on seeded random weights at a small
@@ -40,9 +40,14 @@ from galvatron_tpu.analysis import strategy_lint
 from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.config.strategy import layer_runs, model_layer_kinds
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.parts import unsupported_reason
+from galvatron_tpu.models.parts.attention import attention_mixer
+from galvatron_tpu.models.parts.kda import kda_mixer
+from galvatron_tpu.models.parts.mlp import ROUTER_BIAS, dense_mlp
 from galvatron_tpu.models import kimi_linear as K
 from galvatron_tpu.models.llama import llama_config
 from galvatron_tpu.models.registry import get_family
+from galvatron_tpu.ops.moe import moe_ffn
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import telemetry, tracing
 from galvatron_tpu.runtime import construct_hybrid_parallel_model
@@ -85,7 +90,7 @@ def params_of(cfg, seed=0):
     leaves, tree = jax.tree_util.tree_flatten_with_path(params)
     keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
     moved = [leaf + 0.1 * jax.random.normal(key, leaf.shape)
-             if any(n in jax.tree_util.keystr(path) for n in ("scale", M.ROUTER_BIAS)) else leaf
+             if any(n in jax.tree_util.keystr(path) for n in ("scale", ROUTER_BIAS)) else leaf
              for (path, leaf), key in zip(leaves, keys)]
     return jax.tree_util.tree_unflatten(tree, moved)
 
@@ -231,7 +236,7 @@ def test_every_leafs_gradient_is_the_references(case):
             "['layers'][4]['kda']['wb']['kernel']", "['layers'][1]['kda']['norm']['scale']",
             "['layers'][3]['wq']['kernel']", "['layers'][3]['kv_a_norm']['scale']",
             "['layers'][3]['router']['kernel']", "['lm_head']['kernel']"} <= set(errors)
-    bias = {k: v for k, v in errors.items() if M.ROUTER_BIAS in k}
+    bias = {k: v for k, v in errors.items() if ROUTER_BIAS in k}
     assert len(bias) == 4 and not any(bias.values())  # no gradient moves the bias, in either
     assert max(errors.values()) < F32_TOL, max(errors, key=errors.get)
     matrices = {k: v for k, v in errors.items() if "kernel" in k or "wte" in k}
@@ -273,7 +278,7 @@ def test_latent_attention_padded_with_zeros_is_latent_attention_at_its_true_widt
         lp = M.init_layer_params(jax.random.PRNGKey(0), lcfg)  # no leaf's shape knows the width
         y = jax.random.normal(jax.random.PRNGKey(1), (1, SEQ, 64))
         with jax.default_matmul_precision("highest"):
-            outs[width], _, counters = M.attention_mixer(
+            outs[width], _, counters = attention_mixer(
                 lp, y, batch_of()["positions"][:1], lcfg, mesh=None, axes=None, attn_bias=None,
                 attn_sharding=None, return_kv=False)
             want = REF._latent_attention(lp, y[0], fields_of(lcfg))
@@ -281,7 +286,7 @@ def test_latent_attention_padded_with_zeros_is_latent_attention_at_its_true_widt
         np.testing.assert_allclose(np.asarray(outs[width][0]), np.asarray(want), atol=2e-6)
     # positions do not enter: another order of the same tokens' positions changes nothing
     lcfg = tiny().layer_config("routed")
-    turned, _, _ = M.attention_mixer(lp, y, batch_of()["positions"][:1][:, ::-1], lcfg, mesh=None, axes=None,
+    turned, _, _ = attention_mixer(lp, y, batch_of()["positions"][:1][:, ::-1], lcfg, mesh=None, axes=None,
                                      attn_bias=None, attn_sharding=None, return_kv=False)
     with jax.default_matmul_precision("highest"):
         np.testing.assert_allclose(np.asarray(turned), np.asarray(outs[32]), atol=2e-6)
@@ -293,7 +298,7 @@ def test_the_kda_mixer_is_the_references_and_hands_back_the_linear_counters():
     lp["kda"]["norm"]["scale"] = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (16,))
     y = jax.random.normal(jax.random.PRNGKey(1), (1, SEQ, 64))
     with jax.default_matmul_precision("highest"):
-        out, kv, counters = M.kda_mixer(lp, y, None, lcfg)
+        out, kv, counters = kda_mixer(lp, y, None, lcfg)
         want = REF._kda(lp, y[0], fields_of(lcfg))
         q, k, v, g, beta = REF.kda_inputs(lp["kda"], y[0], fields_of(lcfg))
         _, states = REF.kda_recurrence(q, k, v, g, beta)
@@ -314,18 +319,18 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
     cfg = tiny()
     lcfg = cfg.layer_config("routed")
     lp = M.init_layer_params(jax.random.PRNGKey(0), lcfg)
-    lp["router"][M.ROUTER_BIAS] = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (16,))
+    lp["router"][ROUTER_BIAS] = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (16,))
     y = jax.random.normal(jax.random.PRNGKey(1), (1, SEQ, 64))
     with jax.default_matmul_precision("highest"):
         lp32 = jax.tree.map(lambda a: a.astype(jnp.float32), lp)
         routed, _ = REF._routed(lp32, y[0], fields_of(lcfg))
         whole = routed + REF._swiglu(lp32["shared"], y[0])
-        total, rows = M.dense_mlp(lp["shared"], y, lcfg, jnp.float32)[0], 0.0
+        total, rows = dense_mlp(lp["shared"], y, lcfg, jnp.float32)[0], 0.0
         for rank in range(4):
-            out, aux = M.moe_ffn(
+            out, aux = moe_ffn(
                 y, lp["router"]["kernel"], lp["wi"]["kernel"][4 * rank:4 * rank + 4],
                 lp["wo_mlp"]["kernel"][4 * rank:4 * rank + 4], experts_per_token=4, norm_topk_prob=True,
-                dtype=jnp.float32, score="sigmoid", bias=lp["router"][M.ROUTER_BIAS],
+                dtype=jnp.float32, score="sigmoid", bias=lp["router"][ROUTER_BIAS],
                 scale=cfg.routed_scaling_factor, held=(4 * rank, 4))
             total, rows = total + out[0], rows + float(aux["rows_held"])
     assert rows == SEQ * 4  # every assignment is some share's
@@ -338,7 +343,7 @@ def test_one_table_maps_the_kda_mixer_to_what_it_brings():
     assert M.MIXERS["kda"].scopes == (tracing.ATTN_KDA, tracing.ATTN_KDA_RULE) == (
         "gt.attn.kda_mixer", "gt.attn.kda_rule")
     assert not any(a != b and a.startswith(b) for a in M.MIXERS["kda"].scopes for b in M.MIXERS["kda"].scopes)
-    assert callable(getattr(obs_flops, M.MIXERS["kda"].flops))
+    assert callable(obs_flops.MIXER_FWD_FLOPS["kda"][0])
     proj, core = obs_flops.kda_fwd_flops_a_token(
         hidden=64, num_key_heads=4, num_value_heads=4, key_head_dim=16, value_head_dim=16)
     assert (proj, core) == (2 * 64 * 192 + 2 * (2 * 64 * 16 + 2 * 16 * 64) + 2 * 64 * 4 + 2 * 64 * 64,
@@ -373,7 +378,7 @@ def test_the_step_hands_back_the_counters_and_the_event_takes_them():
     assert float(metrics["linear_state_abs_max"]) > 0.0 and 0.0 < float(metrics["linear_decay_mean"]) < 1.0
     assert set(telemetry.LINEAR_STEP_FIELDS) <= set(telemetry.EVENT_SCHEMAS["step"][1])
     # the step moves the router's bias by the update rate, and no gradient does
-    assert float(jnp.max(jnp.abs(new["layers"][1]["router"][M.ROUTER_BIAS]))) == pytest.approx(1e-3)
+    assert float(jnp.max(jnp.abs(new["layers"][1]["router"][ROUTER_BIAS]))) == pytest.approx(1e-3)
 
 
 # ------------------------------------------------------------ GLS018, by name
@@ -413,9 +418,9 @@ def test_serve_and_the_autotuner_refuse_it_and_name_the_kda_layers(kwargs, named
     errors = strategy_lint.lint_hp(hp, model_cfg=cfg, **kwargs).errors
     assert any(d.code == "GLS018" and named in d.message for d in errors)
     assert strategy_lint.lint_hp(hp, model_cfg=cfg, mode="train").ok
-    assert "Kimi-Delta-Attention layers" in M.linear_layers_reason(cfg)
-    assert "cost models" in M.linear_layers_reason(cfg)
-    assert M.linear_layers_reason(llama_config("llama-0.3b")) is None
+    assert "Kimi-Delta-Attention layers" in unsupported_reason(cfg, asker="search")
+    assert "cost models" in unsupported_reason(cfg, asker="search")
+    assert unsupported_reason(llama_config("llama-0.3b"), asker="search") is None
 
 
 @pytest.mark.parametrize("surface", ["search", "profile"])

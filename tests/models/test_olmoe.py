@@ -393,13 +393,13 @@ def test_step_flops_count_the_experts_a_token_is_sent_to():
 
 
 def test_a_dense_models_checkpoint_digest_is_what_it_was():
-    """The new fields at their dense defaults stay out of the elastic digest."""
+    """The fields newer than the first checkpoints stay out of the elastic digest at their defaults."""
     from galvatron_tpu.models.llama import llama_config
     from galvatron_tpu.runtime import elastic
 
     cfg = llama_config("llama-0.3b")
     fields = {k: str(v) for k, v in dataclasses.asdict(cfg).items()
-              if k not in elastic._DIGEST_EXCLUDE and k not in elastic._DIGEST_DEFAULTS}
+              if k not in elastic._DIGEST_EXCLUDE and k in elastic._DIGEST_ALWAYS}
     import hashlib
 
     assert elastic.model_config_digest(cfg) == hashlib.sha256(

@@ -29,12 +29,18 @@ from galvatron_tpu.analysis import strategy_lint
 from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.config.strategy import layer_runs, model_layer_kinds
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.parts import unsupported_reason
+from galvatron_tpu.models.parts.attention import attention_mixer, qk_normed
+from galvatron_tpu.models.parts.common import _norm
+from galvatron_tpu.models.parts.linear import linear_mixer
+from galvatron_tpu.models.parts.mlp import dense_mlp
 from galvatron_tpu.models import qwen3_next as Q
 from galvatron_tpu.models.glm4_moe_lite import glm4_moe_lite_config
 from galvatron_tpu.models.olmoe import olmoe_config
 from galvatron_tpu.models.registry import get_family
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import telemetry, tracing
+from galvatron_tpu.ops.moe import moe_ffn
 from galvatron_tpu.ops.rope import apply_rotary
 from galvatron_tpu.runtime import construct_hybrid_parallel_model
 
@@ -220,12 +226,12 @@ def test_the_norm_of_q_and_k_runs_a_head_and_scales_by_one_plus_w():
     q = jax.random.normal(jax.random.PRNGKey(0), (1, 5, 4, 16))
     k = jax.random.normal(jax.random.PRNGKey(1), (1, 5, 2, 16))
     w = 0.3 * jax.random.normal(jax.random.PRNGKey(2), (16,))
-    got_q, got_k = M.qk_normed({"q_norm": {"scale": w}, "k_norm": {"scale": -w}}, q, k, cfg)
+    got_q, got_k = qk_normed({"q_norm": {"scale": w}, "k_norm": {"scale": -w}}, q, k, cfg)
     rms0 = lambda x, w: x / np.sqrt(np.mean(x * x, axis=-1, keepdims=True) + 1e-6) * (1 + w)  # noqa: E731
     np.testing.assert_allclose(np.asarray(got_q), rms0(np.asarray(q), np.asarray(w)), atol=1e-6)
     np.testing.assert_allclose(np.asarray(got_k), rms0(np.asarray(k), -np.asarray(w)), atol=1e-6)
     # OLMoE's form runs over the whole projection, with a plain scale
-    whole = M.qk_normed({"q_norm": {"scale": jnp.ones(64)}, "k_norm": {"scale": jnp.ones(32)}}, q, k,
+    whole = qk_normed({"q_norm": {"scale": jnp.ones(64)}, "k_norm": {"scale": jnp.ones(32)}}, q, k,
                         dataclasses.replace(cfg, qk_norm=True, norm_zero_centered=False))[0]
     flat = np.asarray(q).reshape(1, 5, 64)
     np.testing.assert_allclose(np.asarray(whole).reshape(1, 5, 64),
@@ -242,13 +248,13 @@ def _layer(kind, seed=0):
 
 def test_the_attention_gate_multiplies_the_attention_by_its_sigmoid():
     lcfg, lp, x, pos = _layer("routed")
-    y = M._norm(x, lp["ln1"], lcfg)
+    y = _norm(x, lp["ln1"], lcfg)
     with jax.default_matmul_precision("highest"):
-        got, _, _ = M.attention_mixer(lp, y, pos, lcfg, mesh=None, axes=None, attn_bias=None,
+        got, _, _ = attention_mixer(lp, y, pos, lcfg, mesh=None, axes=None, attn_bias=None,
                                       attn_sharding=None, return_kv=False)
         # the same with the gate's columns zeroed is half the ungated attention
         open_gate = dict(lp, wq={"kernel": lp["wq"]["kernel"].at[..., 16:].set(0.0)})
-        half, _, _ = M.attention_mixer(open_gate, y, pos, lcfg, mesh=None, axes=None, attn_bias=None,
+        half, _, _ = attention_mixer(open_gate, y, pos, lcfg, mesh=None, axes=None, attn_bias=None,
                                        attn_sharding=None, return_kv=False)
         gate = jax.nn.sigmoid(jnp.einsum("bsh,hnd->bsnd", y, lp["wq"]["kernel"][..., 16:]))
         attn = 2.0 * jnp.linalg.lstsq(lp["wo"]["kernel"].T, half[0].T)[0].T  # undo Wo: (S, 64)
@@ -264,10 +270,10 @@ def test_the_shared_expert_passes_a_sigmoid_gate():
         out, _ = M.layer_forward(lp, x, pos, lcfg)
         shut = dict(lp, shared=dict(lp["shared"], wo_mlp={"kernel": jnp.zeros_like(lp["shared"]["wo_mlp"]["kernel"])}))
         without, _ = M.layer_forward(shut, x, pos, lcfg)
-        mid = x + M.attention_mixer(lp, M._norm(x, lp["ln1"], lcfg), pos, lcfg, mesh=None, axes=None,
+        mid = x + attention_mixer(lp, _norm(x, lp["ln1"], lcfg), pos, lcfg, mesh=None, axes=None,
                                     attn_bias=None, attn_sharding=None, return_kv=False)[0]
-        y = M._norm(mid, lp["ln2"], lcfg)
-        want = jax.nn.sigmoid(y @ lp["shared"]["gate"]["kernel"]) * M.dense_mlp(lp["shared"], y, lcfg, jnp.float32)
+        y = _norm(mid, lp["ln2"], lcfg)
+        want = jax.nn.sigmoid(y @ lp["shared"]["gate"]["kernel"]) * dense_mlp(lp["shared"], y, lcfg, jnp.float32)
     np.testing.assert_allclose(np.asarray(out - without), np.asarray(want), atol=2e-6)
 
 
@@ -275,14 +281,14 @@ def test_the_linear_mixers_gate_and_norm():
     """g = -exp(A_log) softplus(a + dt_bias) and sigmoid(b), read back through
     the counter; and the output norm scales by a plain w BEFORE silu(z)."""
     lcfg, lp, x, pos = _layer("linear.routed", seed=5)
-    y = M._norm(x, lp["ln1"], lcfg)
+    y = _norm(x, lp["ln1"], lcfg)
     p = lp["linear"]
     with jax.default_matmul_precision("highest"):
-        out, _, stats = M.linear_mixer(lp, y, pos, lcfg)
+        out, _, stats = linear_mixer(lp, y, pos, lcfg)
         a = (y @ p["wba"]["kernel"])[..., 4:]
         want = jnp.mean(jnp.exp(-jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])))
         doubled = dict(lp, linear=dict(p, norm={"scale": 2.0 * p["norm"]["scale"]}))
-        twice, _, _ = M.linear_mixer(doubled, y, pos, lcfg)
+        twice, _, _ = linear_mixer(doubled, y, pos, lcfg)
     assert float(stats["decay_mean"]) == pytest.approx(float(want), rel=1e-6)
     np.testing.assert_allclose(np.asarray(twice), 2.0 * np.asarray(out), atol=1e-6)  # a plain scale, not 1 + w
     assert p["wqkvz"]["kernel"].shape == (64, 2 * 32 + 2 * 32) and p["conv"].shape == (96, 4)
@@ -303,11 +309,11 @@ def test_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
     y = jax.random.normal(jax.random.PRNGKey(1), (1, SEQ, 64))
     with jax.default_matmul_precision("highest"):
         whole, _, _ = REF._moe(jax.tree.map(lambda a: a.astype(jnp.float32), lp), y[0], fields_of(lcfg))
-        shared = (M.dense_mlp(lp["shared"], y, lcfg, jnp.float32)
+        shared = (dense_mlp(lp["shared"], y, lcfg, jnp.float32)
                   * jax.nn.sigmoid(y @ lp["shared"]["gate"]["kernel"]))[0]
         total, rows = shared, 0.0
         for rank in range(16):
-            out, aux = M.moe_ffn(
+            out, aux = moe_ffn(
                 y, lp["router"]["kernel"], lp["wi"]["kernel"][rank:rank + 1],
                 lp["wo_mlp"]["kernel"][rank:rank + 1], experts_per_token=4, norm_topk_prob=True,
                 dtype=jnp.float32, score="softmax", held=(rank, 1))
@@ -346,8 +352,7 @@ def test_the_other_families_kinds_are_what_they_were():
 
 def test_one_table_maps_a_mixer_to_what_it_brings():
     assert set(M.MIXERS) == {"attention", "linear", "ssm", "kda"}
-    for name, mixer in M.MIXERS.items():
-        assert callable(getattr(obs_flops, mixer.flops))
+    assert set(obs_flops.MIXER_FWD_FLOPS) == set(M.MIXERS)  # a FLOPs row a key, and no other
     assert M.MIXERS["linear"].scopes == (tracing.ATTN_LINEAR, tracing.ATTN_DELTA)
     cfg = tiny()
     linear = obs_flops.layer_kind_fwd_flops(cfg, 1.0)
@@ -395,6 +400,7 @@ def test_serve_and_the_autotuner_refuse_it_and_name_the_linear_layers(kwargs, na
     assert strategy_lint.lint_hp(hp, model_cfg=cfg, mode="train").ok
     # linear layers over dense MLPs (no experts) are refused the same way
     dense = dataclasses.replace(cfg, num_experts=0, experts_per_token=0, num_shared_experts=0)
-    assert M.expert_layout_reason(dense, hp, mode="serve") is not None
-    assert M.expert_layout_reason(dense, hp) is None
-    assert "cost models" in M.linear_layers_reason(cfg) and M.linear_layers_reason(olmoe_config()) is None
+    assert unsupported_reason(dense, hp, "serve") is not None
+    assert unsupported_reason(dense, hp) is None
+    assert "cost models" in unsupported_reason(cfg, asker="search")
+    assert "linear-attention" not in unsupported_reason(olmoe_config(), asker="search")

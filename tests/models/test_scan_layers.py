@@ -20,6 +20,7 @@ from galvatron_tpu.config.strategy import (
     layer_runs,
 )
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.parallel.mesh import build_mesh, layer_axes
 
 B, S, H = 8, 32, 64
@@ -27,7 +28,7 @@ B, S, H = 8, 32, 64
 
 def make_cfg(n_layers, **kw):
     kw.setdefault("compute_dtype", jnp.float32)
-    return M.TransformerConfig(
+    return TransformerConfig(
         hidden_size=H, num_heads=4, num_layers=n_layers, vocab_size=128,
         max_seq_len=S, **kw,
     )

@@ -17,6 +17,7 @@ import pytest
 from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.config.strategy import HybridParallelConfig
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.parallel.mesh import build_mesh
 
 # full-layer value_and_grad programs recur identically across tests in this
@@ -35,7 +36,7 @@ def make_cfg(**kw):
     kw.setdefault("num_layers", 2)
     kw.setdefault("vocab_size", 64)
     kw.setdefault("max_seq_len", S)
-    return M.TransformerConfig(**kw)
+    return TransformerConfig(**kw)
 
 
 def make_params(cfg):

@@ -1,4 +1,4 @@
-"""The vocabulary-split embedding (models/base.vocab_parallel_lookup): a
+"""The vocabulary-split embedding (models/parts/embed_head.vocab_parallel_lookup): a
 masked local gather and one sum over the vocabulary's tp axes must give the
 unsplit ``wte[tokens]`` and its table gradient, whatever the layout around
 it, and the compiled program must hold the lookup and not a one-hot matmul."""
@@ -13,6 +13,7 @@ from jax.sharding import NamedSharding
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.parts.embed_head import vocab_parallel_lookup
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import build_mesh, vocab_axes
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
@@ -49,7 +50,7 @@ def _check(mesh, vax, tokens, dtype):
     w_sh = jax.device_put(wte, NamedSharding(mesh, S.vocab_embed_spec(vax)))
 
     def split(w, t):
-        return M.vocab_parallel_lookup(w, t, dtype, mesh, vax)
+        return vocab_parallel_lookup(w, t, dtype, mesh, vax)
 
     def whole(w, t):  # gather, then cast: the gradient accumulates in float32
         return w[t].astype(dtype)

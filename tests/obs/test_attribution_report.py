@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 from galvatron_tpu.config.strategy import HybridParallelConfig, LayerStrategy, layer_runs
-from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.obs import attribution as A
 from galvatron_tpu.obs import report as R
 from galvatron_tpu.obs import telemetry as T
@@ -17,7 +17,7 @@ GOLDEN = os.path.join(os.path.dirname(__file__), "fixtures", "golden_telemetry.j
 
 
 def tiny_cfg(num_layers=4):
-    return M.TransformerConfig(
+    return TransformerConfig(
         hidden_size=64, num_heads=4, num_layers=num_layers, vocab_size=128,
         max_seq_len=32, compute_dtype=jnp.float32, param_dtype=jnp.float32)
 

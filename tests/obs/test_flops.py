@@ -6,6 +6,8 @@ import jax.numpy as jnp
 import pytest
 
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.parts.embed_head import embed_tokens, lm_logits
 from galvatron_tpu.obs import flops as F
 from galvatron_tpu.profiler.runtime import RuntimeProfiler
 
@@ -16,7 +18,7 @@ TINY = dict(hidden_size=64, num_heads=4, num_layers=2, vocab_size=128,
 def tiny_cfg(**kw):
     d = dict(TINY)
     d.update(kw)
-    return M.TransformerConfig(**d)
+    return TransformerConfig(**d)
 
 
 def test_peak_registry_prefix_match():
@@ -163,11 +165,11 @@ def test_decode_step_flops_matches_xla_cost_analysis():
     lengths = jnp.full((slots,), ctx - 1, jnp.int32)
 
     def decode(p, t, kc, vc, ln):
-        x = M.embed_tokens(p["embed"], t[:, None], ln[:, None], cfg)
+        x = embed_tokens(p["embed"], t[:, None], ln[:, None], cfg)
         x, _, _ = M.decode_layer_forward(
             p["layers"][0], x, ln[:, None], cfg, k_cache=kc, v_cache=vc,
             write_index=ln)
-        return M.lm_logits(p, x, cfg)
+        return lm_logits(p, x, cfg)
 
     compiled = jax.jit(decode).lower(params, tokens, k, k, lengths).compile()
     reported = F.xla_flops(compiled)

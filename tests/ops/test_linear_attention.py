@@ -17,7 +17,7 @@ both are (the gates' gradients).
 
 The passes around the core (`conv_norm_*`, `gated_norm_*`, and the per-channel
 rule's `kda_gate_*`) run interpreted too, at both mixers' layouts, against the
-XLA form of models/base.linear_mixer and kda_mixer written here in a few
+XLA form of models/parts/linear.linear_mixer and parts/kda.kda_mixer written here in a few
 lines from `causal_conv`, SiLU, `unit`, `rms_norm`, `softplus`: float32 under
 the same 5e-5 (measured 4e-7), bf16 no further from the float32 XLA form than
 the bf16 XLA form is.
@@ -255,7 +255,7 @@ def around(layout, tokens, dtype, seed=0, batch=1):
 
 
 def xla_before(layout, x, taps):
-    """models/base.linear_mixer and kda_mixer before the core: -> q, k, v, flat."""
+    """models/parts/linear.linear_mixer and parts/kda.kda_mixer before the core: -> q, k, v, flat."""
     heads, (b, s, _) = layout.heads, x.shape
     keys, values = heads.key_heads * heads.d_k, heads.value_heads * heads.d_v
 
@@ -277,7 +277,7 @@ def xla_after(layout, o, within, scale):
 
 
 def xla_gate(layout, f, dt_bias, a_log):
-    """models/base.kda_mixer's gate: -exp(A_log) a head x softplus(f + dt_bias), float32, flat."""
+    """models/parts/kda.kda_mixer's gate: -exp(A_log) a head x softplus(f + dt_bias), float32, flat."""
     heads, (b, s, _) = layout.heads, f.shape
     g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
         f.astype(jnp.float32) + dt_bias.astype(jnp.float32)).reshape(b, s, heads.key_heads, heads.d_k)

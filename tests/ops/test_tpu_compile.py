@@ -205,7 +205,7 @@ def tp2dp2_step_hlo(tp2dp2_step):
                          ids=["all_reduce", "megatron_sp_sum_and_slice"])
 def test_vocab_split_embedding_is_a_lookup_on_v5e(tp2dp2_step_hlo, sequence_parallel, summed_by):
     """Under `vocab_tp 2` the embedding is a masked local gather and one sum
-    over tp (models/base.vocab_parallel_lookup), not a one-hot matmul: no
+    over tp (models/parts/embed_head.vocab_parallel_lookup), not a one-hot matmul: no
     `dot_general` carries the `gt.embed` scope, the forward holds one
     collective there (an all-reduce; under Megatron-SP the compiler fuses it
     with the slice into sequence shards, a `fusion` that calls
@@ -318,7 +318,7 @@ def one_chip_head_ops(v5e_2x2):
 
 
 def test_the_heads_matmuls_read_one_bf16_kernel_on_v5e(one_chip_head_ops):
-    """models/base._head_matmul in the compiled step: one operation under
+    """models/parts/embed_head._head_matmul in the compiled step: one operation under
     `gt.head_loss` reads the float32 head kernel, the cast, which no matmul
     holds; forward, input gradient and kernel gradient read or write the bf16
     (hidden, V) copy; and the input gradient's fusion writes the input
@@ -336,7 +336,7 @@ def test_the_heads_matmuls_read_one_bf16_kernel_on_v5e(one_chip_head_ops):
 
 
 def test_the_cross_entropy_sweeps_the_logits_once_each_way_on_v5e(one_chip_head_ops):
-    """models/base._token_nll in the compiled step: `exp` runs in the
+    """models/parts/embed_head._token_nll in the compiled step: `exp` runs in the
     forward's one sweep of the logits (sum of exponentials and the label's
     logit together) and where the backward's two matmuls form the softmax
     gradient as they read the logits; no pass of the backward exists only to
@@ -650,6 +650,7 @@ def qwen3_next_linear_layer(v5e_2x2):
     recomputation, forward and backward, compiled for one described chip:
     -> (the optimised module's text, the forms its parts took)."""
     from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.parts.linear import linear_mixer
     from galvatron_tpu.models.qwen3_next import qwen3_next_config
     from galvatron_tpu.ops import linear_attention as L
 
@@ -666,7 +667,7 @@ def qwen3_next_linear_layer(v5e_2x2):
                              jax.ShapeDtypeStruct((1, tokens, cfg.hidden_size), jnp.bfloat16)))
 
     def loss(p, y):
-        mixer = jax.checkpoint(lambda p, y: M.linear_mixer(p, y, None, lcfg, attn_sharding=where))
+        mixer = jax.checkpoint(lambda p, y: linear_mixer(p, y, None, lcfg, attn_sharding=where))
         out, _, counters = mixer(p, y)
         return jnp.sum(out.astype(jnp.float32)) + counters["state_abs_max"]
 
@@ -726,6 +727,7 @@ def kimi_kda_layer(v5e_2x2):
     -> (the optimised module's text, the forms its core and its passes took)."""
     from galvatron_tpu.models import base as M
     from galvatron_tpu.models.kimi_linear import kimi_linear_config
+    from galvatron_tpu.models.parts.kda import kda_mixer
     from galvatron_tpu.ops import linear_attention as L
 
     tokens = 8192
@@ -739,7 +741,7 @@ def kimi_kda_layer(v5e_2x2):
                              jax.ShapeDtypeStruct((1, tokens, cfg.hidden_size), jnp.bfloat16)))
 
     def loss(p, y):
-        mixer = jax.checkpoint(lambda p, y: M.kda_mixer(p, y, None, lcfg, attn_sharding=where))
+        mixer = jax.checkpoint(lambda p, y: kda_mixer(p, y, None, lcfg, attn_sharding=where))
         out, _, counters = mixer(p, y)
         return jnp.sum(out.astype(jnp.float32)) + counters["state_abs_max"]
 

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from galvatron_tpu.config.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.ops.ring_attention import inverse_permutation, zigzag_permutation
 from galvatron_tpu.runtime.dataloader import prepare_batch
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
@@ -33,7 +34,7 @@ def test_prepare_batch_zigzag_applied():
 
 def test_zigzag_layout_loss_invariant(devices8):
     """Model loss must be identical in zigzag and linear layouts."""
-    cfg = M.TransformerConfig(
+    cfg = TransformerConfig(
         hidden_size=64, num_heads=4, num_layers=2, vocab_size=V, max_seq_len=64,
         compute_dtype=jnp.float32,
     )
@@ -53,7 +54,7 @@ def test_zigzag_layout_loss_invariant(devices8):
 def test_masked_grad_accum_matches_unchunked(devices8):
     """chunks=2 with an unbalanced loss_mask must match chunks=1 exactly
     (weighted microbatch accumulation)."""
-    cfg = M.TransformerConfig(
+    cfg = TransformerConfig(
         hidden_size=64, num_heads=4, num_layers=2, vocab_size=V, max_seq_len=64,
         compute_dtype=jnp.float32,
     )
@@ -87,7 +88,7 @@ def test_zigzag_padded_attn_mask_loss_invariant(devices8):
     attn_mask with the tokens, so the cp-sharded key bias indexes the
     permuted K/V correctly — the loss must match the cp=1 unpermuted run
     (review finding: the mask previously bypassed the permutation)."""
-    cfg = M.TransformerConfig(
+    cfg = TransformerConfig(
         hidden_size=64, num_heads=4, num_layers=2, vocab_size=V, max_seq_len=64,
         compute_dtype=jnp.float32, causal=False,
     )

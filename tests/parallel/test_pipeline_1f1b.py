@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from galvatron_tpu.config.strategy import HybridParallelConfig, LayerStrategy
-from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.parallel.pipeline_1f1b import build_schedule
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 
@@ -176,7 +176,7 @@ def test_1f1b_peak_memory_below_gpipe(devices8):
     """The 1F1B watermark (bounded stash) must beat the gpipe scan's
     (all-chunks residuals) at pp=4, chunks=8 — the reference's motivation for
     the schedule (pipeline.py:375-701, cost_model.py:85-97)."""
-    cfg = M.TransformerConfig(hidden_size=128, num_heads=4, num_layers=4,
+    cfg = TransformerConfig(hidden_size=128, num_heads=4, num_layers=4,
                               vocab_size=256, max_seq_len=128, compute_dtype=jnp.float32)
     Bm, Sm = 16, 128
 

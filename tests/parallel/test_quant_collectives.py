@@ -204,12 +204,13 @@ def test_ring_reduce_scatter_int8_matches_psum_scatter():
 
 # =============================================== quantized grad-sync step
 from galvatron_tpu.models import base as M  # noqa: E402
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.runtime.dataloader import get_train_iterator  # noqa: E402
 from galvatron_tpu.runtime.model_api import (  # noqa: E402
     construct_hybrid_parallel_model,
 )
 
-CFG = M.TransformerConfig(
+CFG = TransformerConfig(
     hidden_size=32, num_heads=4, num_layers=2, vocab_size=64, max_seq_len=16,
     compute_dtype=jnp.float32, param_dtype=jnp.float32,
 )
@@ -343,7 +344,7 @@ def test_dp1_is_inert_not_refused():
 # ----------------------------------------------------- quantized TP rings
 def _tp_loss_and_grads(quant, mode="overlap"):
     B_, S_, H_, NL = 4, 32, 32, 2
-    cfg = M.TransformerConfig(
+    cfg = TransformerConfig(
         hidden_size=H_, num_heads=4, num_layers=NL, vocab_size=64,
         max_seq_len=S_, compute_dtype=jnp.float32, param_dtype=jnp.float32)
     params = {"layers": [

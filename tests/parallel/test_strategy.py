@@ -284,7 +284,7 @@ def test_comm_dtype_survives_migration_resolution(tmp_path):
     import json
 
     from galvatron_tpu.config.strategy import HybridParallelConfig
-    from galvatron_tpu.models.base import TransformerConfig
+    from galvatron_tpu.models.config import TransformerConfig
     from galvatron_tpu.runtime.elastic import resolve_migration_strategy
 
     cfg = TransformerConfig(hidden_size=64, num_heads=4, num_layers=2,
@@ -403,7 +403,7 @@ def test_remat_plan_survives_migration_resolution(tmp_path):
     import json
 
     from galvatron_tpu.config.strategy import HybridParallelConfig
-    from galvatron_tpu.models.base import TransformerConfig
+    from galvatron_tpu.models.config import TransformerConfig
     from galvatron_tpu.runtime.elastic import resolve_migration_strategy
 
     cfg = TransformerConfig(hidden_size=64, num_heads=4, num_layers=2,

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
-from galvatron_tpu.models.base import TransformerConfig, layer_param_specs
+from galvatron_tpu.models.base import layer_param_specs
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.parallel import tp_shard_map as T
 from galvatron_tpu.parallel.mesh import build_mesh, layer_axes
 from jax.sharding import PartitionSpec as P

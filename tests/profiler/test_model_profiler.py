@@ -6,7 +6,7 @@ profile -> search loop (SURVEY.md §3.5 + §3.3) with a tiny model."""
 import jax.numpy as jnp
 import pytest
 
-from galvatron_tpu.models.base import TransformerConfig
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.profiler.model import ModelProfiler, ModelProfileArgs
 from galvatron_tpu.profiler.runtime import RuntimeProfiler
 

@@ -1,5 +1,5 @@
 """The one piece of model state that no gradient moves: a sigmoid router's
-`e_score_correction_bias` (models/base.ROUTER_BIAS). It takes no gradient,
+`e_score_correction_bias` (models/parts/mlp.ROUTER_BIAS). It takes no gradient,
 the optimizer holds no state of it and does not decay it, and the train step
 moves it once a step by `rate x sign(mean(c) - c)` from the GLOBAL batch's
 assignment counts (models/base.update_router_bias), the same on one device,
@@ -13,6 +13,7 @@ import pytest
 
 from galvatron_tpu import HybridParallelConfig
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.parts.mlp import ROUTER_BIAS
 from galvatron_tpu.models.glm4_moe_lite import glm4_moe_lite_config
 from galvatron_tpu.runtime import construct_hybrid_parallel_model, get_optimizer_and_scheduler
 from galvatron_tpu.runtime import optimizer as O
@@ -38,7 +39,7 @@ def batch_of(seed=1):
 
 
 def biases(params):
-    return np.stack([np.asarray(r[M.ROUTER_BIAS]) for r in M.router_bias_leaves(params)])
+    return np.stack([np.asarray(r[ROUTER_BIAS]) for r in M.router_bias_leaves(params)])
 
 
 def tx_of(weight_decay=0.1):
@@ -68,12 +69,12 @@ def test_the_bias_takes_no_gradient_and_the_choice_reads_it():
     batch = batch_of()
     grads = jax.grad(lambda p: M.lm_loss_fn(p, batch, cfg))(params)
     for router in M.router_bias_leaves(grads):
-        assert not np.any(np.asarray(router[M.ROUTER_BIAS]))
+        assert not np.any(np.asarray(router[ROUTER_BIAS]))
         assert np.any(np.asarray(router["kernel"]))
     # a large bias on expert 5 sends every token there; the weights stay the scores'
     _, parts = M.lm_loss_fn(params, batch, cfg, with_parts=True)
     for router in M.router_bias_leaves(params):
-        router[M.ROUTER_BIAS] = router[M.ROUTER_BIAS].at[5].set(10.0)
+        router[ROUTER_BIAS] = router[ROUTER_BIAS].at[5].set(10.0)
     _, pushed = M.lm_loss_fn(params, batch, cfg, with_parts=True)
     assert np.all(np.asarray(pushed[M.ROUTER_COUNTS])[:, 5] == BATCH * SEQ)
     assert np.any(np.asarray(parts[M.ROUTER_COUNTS])[:, 5] < BATCH * SEQ)
@@ -101,19 +102,19 @@ def test_adam_holds_no_state_of_it_and_nothing_decays_it():
     cfg = tiny(router_bias_update_rate=0.0)
     params = M.init_model_params(jax.random.PRNGKey(0), cfg)
     for router in M.router_bias_leaves(params):
-        router[M.ROUTER_BIAS] = jnp.full((EXPERTS,), 0.5)
+        router[ROUTER_BIAS] = jnp.full((EXPERTS,), 0.5)
     tx = tx_of(weight_decay=0.5)
     state = tx.init(params)
     adam = next(s for s in state if isinstance(s, optax.ScaleByAdamState))
     n_params, n_bias = len(jax.tree.leaves(params)), len(M.router_bias_leaves(params))
     assert len(jax.tree.leaves(adam.mu)) == len(jax.tree.leaves(adam.nu)) == n_params - n_bias
-    assert adam.mu["layers"][1]["router"][M.ROUTER_BIAS] is None
+    assert adam.mu["layers"][1]["router"][ROUTER_BIAS] is None
     assert adam.mu["layers"][1]["router"]["kernel"].shape == (64, EXPERTS)
     grads = jax.tree.map(jnp.ones_like, params)  # even a gradient that is not zero
     _, state = tx.update(grads, state, params)  # the schedule's first step has lr 0
     updates, _ = tx.update(grads, state, params)
     for router in M.router_bias_leaves(updates):
-        assert not np.any(np.asarray(router[M.ROUTER_BIAS]))
+        assert not np.any(np.asarray(router[ROUTER_BIAS]))
         assert np.any(np.asarray(router["kernel"]))
     # a tree without such a leaf: the inner chain's own state, a moment a leaf
     dense = {"w": jnp.ones((4, 4)), "b": {"bias": jnp.ones((4,))}}

@@ -294,11 +294,11 @@ def test_uneven_pp_division_searched_and_trains(devices8):
     import jax.numpy as jnp
     import numpy as np
 
-    from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.config import TransformerConfig
     from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
     from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
 
-    cfg = M.TransformerConfig(hidden_size=64, num_heads=4, num_layers=6,
+    cfg = TransformerConfig(hidden_size=64, num_heads=4, num_layers=6,
                               vocab_size=128, max_seq_len=32,
                               compute_dtype=jnp.float32)
     m = construct_hybrid_parallel_model(cfg, hp, devices8)

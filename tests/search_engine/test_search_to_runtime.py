@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
-from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.runtime.dataloader import prepare_batch
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
@@ -29,7 +29,7 @@ def test_searched_config_trains(tmp_path, devices8):
     # layers per stage, engine._pp_stage_dict snapping), pp>1 routes to the
     # 1F1B engine which takes heterogeneous per-stage strategies — every
     # searched config must construct and train (round-2 weak item #5)
-    cfg = M.TransformerConfig(
+    cfg = TransformerConfig(
         hidden_size=64, num_heads=4, num_layers=4, vocab_size=128, max_seq_len=64,
         compute_dtype=jnp.float32,
     )

@@ -11,6 +11,8 @@ import pytest
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
 from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.parts.embed_head import embed_tokens, lm_logits
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 from galvatron_tpu.serve.engine import ServeEngine
 from galvatron_tpu.serve.kv_cache import KVCacheConfig, bucket_pages
@@ -21,7 +23,7 @@ _ATOL = 2e-5  # fp32 XLA:CPU scan-vs-unrolled reassociation slack
 
 
 def tiny_cfg():
-    return M.TransformerConfig(
+    return TransformerConfig(
         hidden_size=32, num_heads=4, num_layers=2, vocab_size=64,
         max_seq_len=32, compute_dtype=jnp.float32)
 
@@ -36,7 +38,7 @@ def layout_hp(cfg, kind):
         "zero3": mk(sdp=1),
         "tp2_zero3": mk(tp=2, sdp=1),
         # the table split over the vocabulary: prefill (1, ctx) and decode
-        # (slots, 1) go through models/base.vocab_parallel_lookup
+        # (slots, 1) go through models/parts/embed_head.vocab_parallel_lookup
         "tp2_vtp2": mk(tp=2, vocab_tp=2),
     }[kind]
 
@@ -45,9 +47,9 @@ def full_logits(params, cfg, tokens):
     """Reference: the training forward over the whole sequence so far."""
     x = jnp.asarray(tokens, jnp.int32)[None]
     pos = jnp.arange(len(tokens), dtype=jnp.int32)[None]
-    h = M.embed_tokens(params["embed"], x, pos, cfg)
+    h = embed_tokens(params["embed"], x, pos, cfg)
     h = M.run_layers(params, h, pos, cfg)
-    return np.asarray(jax.device_get(M.lm_logits(params, h, cfg)))[0]
+    return np.asarray(jax.device_get(lm_logits(params, h, cfg)))[0]
 
 
 def greedy_reference(params, cfg, prompt, n_new):

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
-from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.parallel.mesh import build_mesh
 from galvatron_tpu.serve import kv_cache as KV
 
@@ -20,7 +20,7 @@ def tiny_cfg(**kw):
     kw.setdefault("vocab_size", 64)
     kw.setdefault("max_seq_len", 32)
     kw.setdefault("compute_dtype", jnp.float32)
-    return M.TransformerConfig(**kw)
+    return TransformerConfig(**kw)
 
 
 def test_kv_cache_config_geometry():

@@ -14,7 +14,7 @@ import pytest
 
 from galvatron_tpu.analysis import diagnostics as D
 from galvatron_tpu.config.strategy import HybridParallelConfig
-from galvatron_tpu.models import base as M
+from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.runtime import elastic as els
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 from galvatron_tpu.serve.engine import ContinuousBatcher, Request, ServeEngine
@@ -33,7 +33,7 @@ class FakeClock:
 
 
 def tiny_cfg():
-    return M.TransformerConfig(
+    return TransformerConfig(
         hidden_size=32, num_heads=4, num_layers=2, vocab_size=64,
         max_seq_len=32, compute_dtype=jnp.float32)
 
