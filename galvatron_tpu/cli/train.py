@@ -562,6 +562,10 @@ def _train(args) -> dict:
                 # being alike in width and length; absent where the model has none
                 moe_row_kernel_blocks=(cfg.routed_layers * (not moe_rows_took["xla"])
                                        if moe_rows_took else None),
+                # the layers whose token mixer is a gated short convolution
+                # (models/parts/conv.py); absent where the step traced none
+                shortconv_layers=(sum(kind.startswith("conv") for kind in cfg.layer_kinds())
+                                  if delta_rule_took["short_conv"] else None),
             )
             _aot["fn"] = compiled
         return _aot["fn"](*step_args)

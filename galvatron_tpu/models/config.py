@@ -3,7 +3,7 @@
 One config covers the reference's model zoo: GPT (learned pos, pre-LN, gelu),
 LLaMA (rope, rmsnorm, swiglu, GQA), BERT/ViT (bidirectional, post-LN), T5
 (relative bias, enc-dec glue in models/t5.py), and the sparse-expert,
-latent-attention, linear-attention and state-space families. A layer is two
+latent-attention, linear-attention, state-space and short-convolution families. A layer is two
 entries of the tables in `models/parts`, a token mixer and an MLP half:
 `mixers()` and `mlp_halves()` name them a layer, and what an entry asks of the
 config (`validate`) and hands back (`counters`) is the entry's to say."""
@@ -15,6 +15,9 @@ from dataclasses import dataclass
 from typing import Any, List, Optional, Tuple
 
 import jax.numpy as jnp
+
+
+_MIXER_ALIASES = {"mamba": "ssm"}  # HF's word in `layer_types` -> the `MIXERS` key
 
 
 @dataclass
@@ -113,11 +116,11 @@ class TransformerConfig:
     # --- what Granite-4.0-H's published config adds (granitemoehybrid):
     # Mamba-2 state-space layers (`ssm_mixer`, ops/ssd.py) among layers of
     # softmax attention without positions, and four multipliers ---
-    # the token mixer of each layer in HF's words, "mamba" (the mixer "ssm"),
-    # "kda" (Kimi Delta Attention, `kda_mixer`: its heads and convolution are
-    # the `linear_*` fields above) or "attention", where the pattern is a LIST
-    # (HF `layer_types`; Kimi-Linear's two lists of layer numbers) and no
-    # interval says it. A model cut in depth runs the list's first `num_layers`
+    # the token mixer of each layer, a key of `MIXERS` or HF's "mamba" (the
+    # mixer "ssm"; "kda", Kimi Delta Attention, takes its heads and convolution
+    # from the `linear_*` fields above), where the pattern is a LIST (HF
+    # `layer_types`; Kimi-Linear's two lists of layer numbers) and no interval
+    # says it. A model cut in depth runs the list's first `num_layers`
     # entries, so the published list may stay whole
     layer_types: Optional[List[str]] = None
     ssm_num_heads: int = 0
@@ -131,6 +134,9 @@ class TransformerConfig:
     residual_multiplier: float = 1.0  # x each half's output before it joins the residual stream
     attention_multiplier: Optional[float] = None  # the softmax's scale in place of 1 / sqrt(head_dim)
     logits_scaling: float = 1.0  # the head's logits are divided by it
+    # --- what LFM2's published config adds (lfm2_moe): layers whose token
+    # mixer is a gated short convolution (`conv_mixer`, the mixer "conv") ---
+    short_conv_kernel: int = 0  # taps of the causal depthwise convolution between the two gates
     # which `MIXERS` entry ONE layer runs. `layer_config(kind)` sets it; a
     # model's own config leaves it and states the pattern above
     mixer: str = "attention"
@@ -148,13 +154,17 @@ class TransformerConfig:
             raise ValueError("qk_norm=%r: False, True (the whole projection) or \"head\""
                              % (self.qk_norm,))
         if self.layer_types is not None:
+            from galvatron_tpu.models.parts import MIXERS  # looked up on use, as `parts()` does
+
             self.layer_types = list(self.layer_types)
+            named = sorted(set(MIXERS) | set(_MIXER_ALIASES))
             if (len(self.layer_types) < self.num_layers or self.full_attention_interval
-                    or set(self.layer_types) - {"mamba", "kda", "attention"}):
+                    or set(self.layer_types) - set(named)):
                 raise ValueError(
-                    "layer_types names the mixer, \"mamba\", \"kda\" or \"attention\", of each of "
+                    "layer_types names the mixer, one of %s, of each of "
                     "the %d layers (or more: the first so many are run), and no "
-                    "full_attention_interval beside it; got %r" % (self.num_layers, self.layer_types))
+                    "full_attention_interval beside it; got %r"
+                    % (", ".join('"%s"' % n for n in named), self.num_layers, self.layer_types))
         for part in self.parts():  # each part's own clause (latent attention's may set head_dim)
             part.validate(self)
         if self.head_dim is None:
@@ -190,7 +200,7 @@ class TransformerConfig:
         and is linear elsewhere), or one mixer for all (`mixer`). Nothing
         else reads the two statements."""
         if self.layer_types is not None:
-            return tuple("ssm" if t == "mamba" else t for t in self.layer_types[:self.num_layers])
+            return tuple(_MIXER_ALIASES.get(t, t) for t in self.layer_types[:self.num_layers])
         every = self.full_attention_interval
         if every:
             return tuple("attention" if (i + 1) % every == 0 else "linear"
@@ -219,7 +229,7 @@ class TransformerConfig:
         """The kind of each layer, what `config/strategy.layer_runs` splits
         runs on beside the layout. A kind names the layer's two halves: its
         MLP half, "dense" or "routed", after its token mixer where that is
-        not softmax attention ("linear.routed", "ssm.dense", "kda.routed")."""
+        not softmax attention ("linear.routed", "ssm.dense", "kda.routed", "conv.dense")."""
         return tuple(h if m == "attention" else m + "." + h
                      for m, h in zip(self.mixers(), self.mlp_halves()))
 

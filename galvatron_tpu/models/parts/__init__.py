@@ -12,12 +12,13 @@ from __future__ import annotations
 from typing import Any, Iterator, Optional, Tuple
 
 from galvatron_tpu.models.parts.attention import ATTENTION
+from galvatron_tpu.models.parts.conv import CONV
 from galvatron_tpu.models.parts.kda import KDA
 from galvatron_tpu.models.parts.linear import LINEAR
 from galvatron_tpu.models.parts.mlp import DENSE, ROUTED
 from galvatron_tpu.models.parts.ssm import SSM
 
-MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA}
+MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA, "conv": CONV}
 MLP_HALVES = {"dense": DENSE, "routed": ROUTED}
 
 # how an asker's sentence starts, and what joins the parts' statements in it

@@ -127,6 +127,13 @@ def ssm_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, state_d
     return proj, 4.0 * num_heads * head_dim * state_dim
 
 
+def conv_fwd_flops_a_token(*, hidden: int):
+    """A gated short-convolution mixer: hidden -> [B | C | u] (3 x hidden),
+    hidden -> hidden; the two gates and the taps are no matmul, so the core
+    counts nothing."""
+    return 2.0 * hidden * (3 * hidden) + 2.0 * hidden * hidden, 0.0
+
+
 # the row of each `MIXERS` key, and the config fields its keyword arguments read
 _DELTA_DIMS = {k: "linear_" + k for k in ("num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
 MIXER_FWD_FLOPS = {
@@ -134,6 +141,7 @@ MIXER_FWD_FLOPS = {
     "linear": (linear_fwd_flops_a_token, _DELTA_DIMS),
     "kda": (kda_fwd_flops_a_token, _DELTA_DIMS),
     "ssm": (ssm_fwd_flops_a_token, {k: "ssm_" + k for k in ("num_heads", "head_dim", "state_dim")}),
+    "conv": (conv_fwd_flops_a_token, {}),
 }
 
 

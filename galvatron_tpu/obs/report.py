@@ -396,6 +396,8 @@ def render(analysis: Dict[str, Any]) -> str:
         if "moe_row_kernel_blocks" in comp:
             lines.append("routed blocks whose rows move through the Pallas row movers: %d"
                          % comp["moe_row_kernel_blocks"])
+        if "shortconv_layers" in comp:
+            lines.append("layers whose token mixer is a gated short convolution: %d" % comp["shortconv_layers"])
     an = analysis["anomalies"]
     lines.append(
         "resilience: %d anomalies skipped, %d rollbacks, %d retries"

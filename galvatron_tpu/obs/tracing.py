@@ -92,6 +92,13 @@ ATTN_SSM = "gt.attn.ssm"
 # low-rank gates, the gated norm). Neither name begins the other
 ATTN_KDA_RULE = "gt.attn.kda_rule"
 ATTN_KDA = "gt.attn.kda_mixer"
+# a gated short-convolution mixer (models/parts/conv.conv_mixer), inside
+# gt.layers.r<k>, in two disjoint scopes that add up to the mixer, as the
+# state-space mixer's: the two projections' matmuls (hidden -> [B | C | u],
+# channels -> hidden), and the memory-bound pass between them (B * u, the
+# taps, C * v; forward, recomputed and backward). Neither name begins the other
+ATTN_CONV_PROJ = "gt.attn.shortconv"
+ATTN_CONV_GATE = "gt.attn.conv_gate"
 # the multi-token-prediction module, top level: its norms, the (2h, h)
 # projection and its block; its pass through the head and its cross entropy
 # run under HEAD_LOSS, beside the main ones

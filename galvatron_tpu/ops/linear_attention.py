@@ -144,6 +144,7 @@ from galvatron_tpu.ops.attention import KernelSharding
 CHUNK = 64
 # how many calls of `gated_delta_rule` took which form since the process began,
 # counted as they are traced: the trainer's compile report reads the difference
+# (and "short_conv": the mixers that are `causal_conv` between two gates)
 TOOK = collections.Counter()
 STARTS = "gdn_chunk_starts"  # the residual a head's backward keeps
 _BASE = 16  # the diagonal blocks inverted by forward substitution

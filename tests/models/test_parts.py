@@ -18,6 +18,7 @@ from galvatron_tpu.models.glm4_moe_lite import glm4_moe_lite_config
 from galvatron_tpu.models.gpt import gpt_config
 from galvatron_tpu.models.granite_hybrid import granite_hybrid_config
 from galvatron_tpu.models.kimi_linear import kimi_linear_config
+from galvatron_tpu.models.lfm2_moe import lfm2_moe_config
 from galvatron_tpu.models.llama import llama_config
 from galvatron_tpu.models.olmoe import olmoe_config
 from galvatron_tpu.models.parts.common import ASKERS, LayerPart
@@ -41,6 +42,7 @@ BUILT_OF = {
     ("MIXERS", "kda"): dict(DENSE, layer_types=["kda", "attention"], **DELTA),
     ("MIXERS", "ssm"): dict(DENSE, layer_types=["mamba", "attention"], ssm_num_heads=4, ssm_head_dim=16,
                             ssm_state_dim=8, ssm_conv_kernel=4),
+    ("MIXERS", "conv"): dict(DENSE, layer_types=["conv", "attention"], short_conv_kernel=3),
     ("MLP_HALVES", "dense"): DENSE,
     ("MLP_HALVES", "routed"): dict(DENSE, num_experts=4, experts_per_token=2),
 }
@@ -106,7 +108,7 @@ def test_a_config_is_told_of_its_own_parts_and_of_no_others():
 
 FAMILIES = {"llama": llama_config, "gpt": gpt_config, "olmoe": olmoe_config, "glm4_moe_lite": glm4_moe_lite_config,
             "qwen3_next": qwen3_next_config, "granite_hybrid": granite_hybrid_config,
-            "kimi_linear": kimi_linear_config}
+            "kimi_linear": kimi_linear_config, "lfm2_moe": lfm2_moe_config}
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -132,6 +134,7 @@ def test_a_configs_validate_clauses_are_exactly_its_entries(family, monkeypatch)
     (("MIXERS", "kda"), dict(linear_num_value_heads=4), "equal under \"kda\""),
     (("MIXERS", "ssm"), dict(num_experts=4, experts_per_token=2), "a dense MLP half"),
     (("MIXERS", "ssm"), dict(ssm_state_dim=0), "state-space layers want"),
+    (("MIXERS", "conv"), dict(short_conv_kernel=0), "short-convolution layers want short_conv_kernel"),
 ], ids=lambda v: "-".join(v) if isinstance(v, tuple) else None)
 def test_an_entrys_clause_raises_at_construction_in_its_words(entry, fields, words):
     with pytest.raises(ValueError, match=re.escape(words)):
@@ -142,7 +145,8 @@ def test_the_pattern_is_asked_one_way_however_it_is_stated():
     """`mixers()` of the interval-stated Qwen3-Next config and of the
     list-stated Granite and Kimi configs is what `layer_kinds()` implies, a
     key of `MIXERS` a layer, and never None."""
-    for cfg in (qwen3_next_config(), granite_hybrid_config(), kimi_linear_config(), llama_config()):
+    for cfg in (qwen3_next_config(), granite_hybrid_config(), kimi_linear_config(), lfm2_moe_config(),
+                llama_config()):
         implied = tuple(kind.rpartition(".")[0] or "attention" for kind in cfg.layer_kinds())
         assert cfg.mixers() == implied and len(implied) == cfg.num_layers and set(implied) <= set(parts.MIXERS)
         assert tuple(kind.rpartition(".")[2] for kind in cfg.layer_kinds()) == cfg.mlp_halves()
@@ -156,16 +160,32 @@ def test_the_pattern_is_asked_one_way_however_it_is_stated():
     assert qwen3_next_config().mixers()[:4] == ("linear", "linear", "linear", "attention")
     assert set(granite_hybrid_config().mixers()) == {"ssm", "attention"}
     assert set(kimi_linear_config().mixers()) == {"kda", "attention"}
+    assert set(lfm2_moe_config().mixers()) == {"conv", "attention"}
+
+
+def test_every_key_of_the_mixers_table_is_a_word_of_layer_types():
+    """The allowed words are read off `MIXERS` (plus HF's "mamba"): a part
+    added to the table is accepted with no clause added, and the refusal names
+    them all."""
+    fields = {**DELTA, "ssm_num_heads": 4, "ssm_head_dim": 16, "ssm_state_dim": 8, "ssm_conv_kernel": 4,
+              "short_conv_kernel": 3}
+    for key in parts.MIXERS:
+        cfg = TransformerConfig(**DENSE, layer_types=[key, "attention"], **fields)
+        assert cfg.mixers() == (key, "attention")
+    assert TransformerConfig(**DENSE, layer_types=["mamba", "attention"], **fields).mixers() == ("ssm", "attention")
+    with pytest.raises(ValueError) as refused:
+        TransformerConfig(**DENSE, layer_types=["window", "attention"])
+    assert all('"%s"' % key in str(refused.value) for key in list(parts.MIXERS) + ["mamba"])
 
 
 def test_the_stack_looks_two_tables_up_and_names_no_part():
     named = re.compile(r"cfg\.(routed|num_experts|kv_lora_rank|latent_attention|experts_held|num_shared_experts)"
-                       r"|\"(attention|linear|kda|ssm|dense|routed)\"")
+                       r"|\"(attention|linear|kda|ssm|conv|dense|routed)\"")
     for fn in (M.init_layer_params, M.layer_forward, M.decode_layer_forward, M.layer_param_specs, M.run_layers):
         assert not named.search(inspect.getsource(fn)), fn.__name__
     assert M.MIXERS is parts.MIXERS and M.MLP_HALVES is parts.MLP_HALVES
     for name in ("config", "parts", "parts.common", "parts.attention", "parts.linear", "parts.kda", "parts.ssm",
-                 "parts.mlp", "parts.embed_head"):
+                 "parts.conv", "parts.mlp", "parts.embed_head"):
         module = __import__("galvatron_tpu.models." + name, fromlist=["_"])
         assert "models.base" not in inspect.getsource(module) and "models import base" not in inspect.getsource(module)
     for table in (parts.MIXERS, parts.MLP_HALVES):  # one shape
@@ -181,6 +201,7 @@ PARENT_DIGESTS = {  # `runtime/elastic.model_config_digest` of each preset at PR
     "qwen3_next": "6f25d6dd98ba915150d8973740f5f853c2d08e227e89c7b86d7903e92a15264c",
     "granite_hybrid": "82647523215eb425ccb9ffb27a38a68a9bdf5fef23e2b4ee0f9a0afb8cc90ce5",
     "kimi_linear": "8496d81e3a950a98fbb9bd903551c061efa97fb682a1b6f1b6b1ed90f15c906c",
+    "lfm2_moe": "de9ed240051e89d77e8e5b02f0ed783e50ee33e889aa3980ea5f0b3f90a0b4e2",  # as PR 46 added it
     # the encoder families, and the two whose config is a dataclass of its own, every field of which is digested
     "bert": "4c98cec753b063daeaaf8da44a24222855ba7c2ec2d4a2fe0221dbaf09a84acd",
     "vit": "88ccd90b3fe6e70442f6dd71d7b08b0f6371acd5f950f9e079856277c18e3319",
