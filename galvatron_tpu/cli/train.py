@@ -505,9 +505,11 @@ def _train(args) -> dict:
                 t0 = time.perf_counter()
                 delta_rule_took = collections.Counter(linear_attention.TOOK)
                 moe_rows_took = collections.Counter(moe.ROWS_TOOK)
+                moe_windows_took = collections.Counter(moe.WINDOWS_TOOK)
                 lowered = step_fn.lower(*step_args)
                 delta_rule_took = linear_attention.TOOK - delta_rule_took
                 moe_rows_took = moe.ROWS_TOOK - moe_rows_took
+                moe_windows_took = moe.WINDOWS_TOOK - moe_windows_took
                 t1 = time.perf_counter()
                 key = _step_exec_key(model.mesh, lowered)
                 compiled = _STEP_EXECUTABLES.get(key)
@@ -562,6 +564,10 @@ def _train(args) -> dict:
                 # being alike in width and length; absent where the model has none
                 moe_row_kernel_blocks=(cfg.routed_layers * (not moe_rows_took["xla"])
                                        if moe_rows_took else None),
+                # the rows of the window that the experts of a share work on
+                # (`moe.window_rows`; the longest, the blocks being alike), 0
+                # where none is built; absent where all experts are held
+                expert_window_rows=max(moe_windows_took) if moe_windows_took else None,
                 # the layers whose token mixer is a gated short convolution
                 # (models/parts/conv.py); absent where the step traced none
                 shortconv_layers=(sum(kind.startswith("conv") for kind in cfg.layer_kinds())

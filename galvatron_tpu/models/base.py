@@ -448,6 +448,7 @@ def _fold_aux(auxs: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
         "state_abs_max": lambda n: total(n, jnp.max),
         "ssm_state_abs_max": lambda n: total(n, jnp.max),
         "rows_held": lambda n: total(n, jnp.sum),
+        "window_fallbacks": lambda n: total(n, jnp.sum),
         "counts": lambda n: jnp.concatenate([jnp.atleast_2d(a[n]) for a in auxs if n in a]),
     }
     return {name: fold[name](name) for name in dict.fromkeys(n for a in auxs for n in a)}
@@ -548,7 +549,7 @@ ROUTER_COUNTS = "router_counts"  # (routed blocks, E): the step's; no metric
 # how the microbatch loop folds a part that is not a loss term (those are
 # weighted as the loss is)
 PART_FOLDS = {EXPERT_LOAD: jnp.maximum, "router_bias_abs_max": jnp.maximum,
-              ROUTER_COUNTS: jnp.add, "expert_rows_held": jnp.add,
+              ROUTER_COUNTS: jnp.add, "expert_rows_held": jnp.add, "expert_window_fallbacks": jnp.add,
               "linear_state_abs_max": jnp.maximum, "ssm_state_abs_max": jnp.maximum}
 
 
@@ -606,6 +607,7 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False)
                 * cfg.held_experts[1] / cfg.num_experts)
         parts["expert_rows_held"] = aux["rows_held"]
         parts["expert_rows_held_over_even"] = aux["rows_held"] / even
+        parts["expert_window_fallbacks"] = aux["window_fallbacks"]
     if "counts" in aux:
         parts["router_bias_abs_max"] = aux["bias_abs_max"]
         parts[ROUTER_COUNTS] = aux["counts"]

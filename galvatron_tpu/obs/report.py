@@ -396,6 +396,9 @@ def render(analysis: Dict[str, Any]) -> str:
         if "moe_row_kernel_blocks" in comp:
             lines.append("routed blocks whose rows move through the Pallas row movers: %d"
                          % comp["moe_row_kernel_blocks"])
+        if "expert_window_rows" in comp:
+            lines.append("rows of the window a share's experts work on (0: the whole range): %d"
+                         % comp["expert_window_rows"])
         if "shortconv_layers" in comp:
             lines.append("layers whose token mixer is a gated short convolution: %d" % comp["shortconv_layers"])
     an = analysis["anomalies"]

@@ -55,10 +55,12 @@ EXPERT_STEP_FIELDS = ("loss_ce", "loss_load_balance", "loss_router_z",
 # the token after next (a multi-token-prediction module, before its weight);
 # the rows a step sends through the grouped matmuls of the experts HELD here,
 # over all routed blocks and devices, and that over the even share (tokens x
-# experts a token x held / experts, a block); the largest |bias| of a router
-# that is balanced by one (models/base.lm_loss_fn's parts)
+# experts a token x held / experts, a block); the routed blocks (and devices)
+# whose held rows outgrew the experts' window and took the whole range
+# (ops/moe.window_rows; 0 where no window is built); the largest |bias| of a
+# router that is balanced by one (models/base.lm_loss_fn's parts)
 SHARE_STEP_FIELDS = ("loss_mtp", "expert_rows_held", "expert_rows_held_over_even",
-                     "router_bias_abs_max")
+                     "expert_window_fallbacks", "router_bias_abs_max")
 
 # linear-attention layers' counters (models/parts/linear.linear_mixer), over the
 # step's tokens, heads and linear layers: the mean gate exp(g), how much of
@@ -92,7 +94,8 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         (),
         ("trace_ms", "compile_ms", "compiled_memory_mb", "xla_flops_per_step",
          "cache_hit", "linear_kernel_layers", "linear_pass_kernel_layers",
-         "kda_kernel_layers", "kda_pass_kernel_layers", "moe_row_kernel_blocks", "shortconv_layers"),
+         "kda_kernel_layers", "kda_pass_kernel_layers", "moe_row_kernel_blocks", "expert_window_rows",
+         "shortconv_layers"),
     ),
     # the per-step record (emitted at drain time under the dispatch-ahead
     # loop; iter_ms is dispatch->drain latency, which overlaps across steps)

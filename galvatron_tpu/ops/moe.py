@@ -53,16 +53,30 @@ assignments an expert got, which the train step moves the bias by.
 **A share of the experts** (`held=(first, count)`): the router ranks all the
 experts, this program holds the kernels of `count` of them and computes their
 part of the result for the tokens sent to them; what the experts held
-elsewhere would add is left out. Every assignment is still sorted and
-gathered (the shapes are static), and the grouped matmul runs over the held
-experts' rows alone, so its time follows the routing.
+elsewhere would add is left out. Every assignment is still sorted, gathered
+and combined (the shapes are static, and under expert parallelism the chip
+that owns a token moves all of its assignments): the router, the dispatch and
+the combine work on all `k x tokens` rows. **The experts work on a WINDOW of
+them** (`window_rows`, `_windowed_block`): the held experts' rows are one
+contiguous range of the sorted assignments, so both grouped matmuls, the
+activation between them, the kernels' fill of the rows they skip and all of
+their backward run over a static number of rows, `WINDOW_OVER_EVEN` times the
+even share, that starts at the row tile below the range's first row; the
+result is laid into zeros for the combine. A block whose held rows outgrow
+the window takes the whole range instead, chosen on the device from the
+counts (`jax.lax.cond`): nothing is dropped and nothing is rounded
+differently, and the `step` event's `expert_window_fallbacks` counts such
+blocks. Where the window would be no shorter than the range none is built.
+What is NOT windowed: the router, the sort, the dispatch's gather of every
+assignment's row, the combine's sum over k and both of their backwards.
 """
 
 from __future__ import annotations
 
 import collections
 import functools
-from typing import Dict, Optional, Tuple
+import math
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +89,9 @@ from galvatron_tpu.ops.attention import KernelSharding
 
 # load_max_over_mean always; load_balance, router_z (the softmax router's
 # losses); counts (E,) and bias_abs_max (a router with a bias); rows_held (a
-# share of the experts): moe_aux_names says which for a configuration
+# share of the experts) and window_fallbacks (its blocks, over the devices, whose
+# experts took the whole range: 0 or 1 here): moe_aux_names says which for a
+# configuration
 Aux = Dict[str, jax.Array]
 
 
@@ -83,7 +99,7 @@ def moe_aux_names(score: str, bias: bool, held: bool) -> Tuple[str, ...]:
     return (("load_max_over_mean",)
             + (("load_balance", "router_z") if score == "softmax" else ())
             + (("counts", "bias_abs_max") if bias else ())
-            + (("rows_held",) if held else ()))
+            + (("rows_held", "window_fallbacks") if held else ()))
 
 
 # (rows, K, N) tiles of the megablox kernels, measured on a v5e at OLMoE's
@@ -120,6 +136,30 @@ def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array,
         after = group_sizes.shape[0] - first_group - kernels.shape[0]
         kernels = jnp.pad(kernels, ((first_group, after), (0, 0), (0, 0)))
     return jax.lax.ragged_dot(rows, kernels, group_sizes)
+
+
+def grouped_matmul_bwd(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array, g: jax.Array,
+                       on_tpu: bool = False, first_group: Optional[int] = None):
+    """The cotangents of `grouped_matmul`'s rows and kernels for the
+    cotangent `g` of its result, for a rule that is written out: on a TPU the
+    two calls megablox's own rule makes (`gmm` with the kernels transposed,
+    `tgmm`), made here so that they carry the caller's scope as the forward's
+    do (a `jax.vjp` taken under a scope names its backward after the scope's
+    TRANSFORM, and the readers of a trace tell kernels by the scope's words);
+    elsewhere `ragged_dot`'s transposes."""
+    if on_tpu and rows.shape[0] % GMM_TILING[0] == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        tm, tk, tn = GMM_TILING
+        wide = rows.dtype.itemsize // 2
+        tiling = (tm, tk // wide, tn // wide)
+        offset = None if first_group is None else jnp.int32(first_group)
+        d_rows = megablox.backend.gmm(g, kernels, group_sizes, rows.dtype, tiling, offset, transpose_rhs=True)
+        d_kernels = megablox.backend.tgmm(rows.swapaxes(0, 1), g, group_sizes, kernels.dtype, tiling, offset,
+                                          kernels.shape[0])
+        return d_rows, d_kernels
+    return jax.vjp(lambda rows, kernels: grouped_matmul(rows, kernels, group_sizes, on_tpu, first_group),
+                   rows, kernels)[1](g)
 
 
 def _permuted(values, inverse):
@@ -500,6 +540,183 @@ def router_logits(y: jax.Array, router_kernel: jax.Array) -> jax.Array:
                    precision=jax.lax.Precision.HIGHEST)
 
 
+# ------------------------------------------------------- a share's window
+# The held experts' rows are rows [start, end) of the sorted assignments,
+# start and end known on the device alone. The window is a STATIC number of
+# rows that holds them at an even routing with room to spare, and begins at
+# the row tile at or below `start`: every held group keeps its offset within
+# the megablox kernels' row tiles, so they partition and accumulate as over
+# the whole range and the results are the same to the bit. What the window
+# buys: megablox fills the rows it skips of every call's result with a select
+# over ALL of it, the activation and its backward run over all of it, and with
+# 8 of 32 experts held three quarters of those rows are another chip's (45 of
+# the experts' 92 ms a step there; PERF.md, PR 47).
+# A `cond` chooses, and a `cond` has a price the block is shaped by. What
+# passes into one waits through both of its branches and what comes out of one
+# is a buffer of its own, and the step's memory is the larger branch's: so
+# the block from the gather of its rows to the sum of the dispatch's backward
+# sits INSIDE the branches (its operands and results are (tokens, hidden) and
+# the kernels), and the whole-range branch, seldom taken, gives up time for
+# memory. Differentiated, a `cond` keeps the residuals of both branches and
+# fills the untaken one's with zeros: so the block has a written rule whose
+# residuals are its inputs, and the backward makes the forward again (under
+# `--checkpoint 1` the layer's recomputation then has nothing of the block
+# left to make; without it the block pays one more forward of its experts).
+WINDOW_OVER_EVEN = 1.5  # x `k x tokens x held / experts`; three cells' steps read 0.91 to 1.08 of it
+# rows of the window -> the blocks of a share traced with it since the process
+# began (0: no window), as `ROWS_TOOK`; the trainer's compile report reads it
+WINDOWS_TOOK = collections.Counter()
+
+
+def window_rows(assignments: int, num_experts: int, held: Optional[Tuple[int, int]]) -> int:
+    """Rows of the window a share's experts work on: `WINDOW_OVER_EVEN` times
+    the even share in whole row tiles, and a tile for the alignment. 0 where
+    none is built: all experts held, or a window no shorter than the range."""
+    if held is None:
+        return 0
+    tile = GMM_TILING[0]
+    rows = (math.ceil(WINDOW_OVER_EVEN * assignments * held[1] / num_experts / tile) + 1) * tile
+    return rows if rows < assignments else 0
+
+
+def _gmm_in(share, rows, wi, sizes):
+    with jax.named_scope(tracing.MOE_GMM_IN):
+        return grouped_matmul(rows, wi, sizes, share.on_tpu, first_group=share.held[0])
+
+
+def _gmm_out(share, mid, wo, sizes):
+    with jax.named_scope(tracing.MOE_GMM_OUT):
+        return grouped_matmul(mid, wo, sizes, share.on_tpu, first_group=share.held[0])
+
+
+class _Share(NamedTuple):
+    """What `_windowed_block` is built from, static at trace time (and the
+    key its two rules are traced once under: the tiles are in it for that)."""
+    activate: object
+    on_tpu: bool
+    form: str  # of the row movers (`rows_form`)
+    held: Tuple[int, int]
+    length: int  # of the window, rows
+    tiling: Tuple[int, int, int]  # `GMM_TILING`
+
+
+def _place_window(counts, held: Tuple[int, int], length: int, tile: int):
+    """Where the window lies for these `counts` (assignments an expert, all
+    the experts): its first row, a multiple of the row tile where the range
+    is one; the rows of each expert inside it, which add up to `length`; and
+    whether the held experts' rows all are."""
+    edges = jnp.concatenate([jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)])
+    first_row = jnp.minimum(edges[held[0]] // tile * tile, edges[-1] - length)
+    inside = jnp.clip(edges, first_row, first_row + length)
+    return first_row, inside[1:] - inside[:-1], edges[held[0] + held[1]] <= first_row + length
+
+
+def _two_ways(share: _Share, counts):
+    """Whether the window holds the held experts' rows, and the two ways over
+    the sorted rows, each as (`take`, `lay`, the rows an expert): the window's
+    rows cut out of an array of all and laid back into zeros, or all the rows
+    as they are."""
+    first_row, sizes, fits = _place_window(counts, share.held, share.length, share.tiling[0])
+
+    def take(x):
+        return jax.lax.dynamic_slice_in_dim(x, first_row, share.length)
+
+    def lay(x, rows):
+        return jax.lax.dynamic_update_slice_in_dim(jnp.zeros((rows, x.shape[1]), x.dtype), x, first_row, 0)
+
+    return fits, (take, lay, sizes), (lambda x: x, lambda x, rows: x, counts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _windowed_block(share: _Share, y, wi, wo, counts, weights, order, inv_order):
+    """A share's block from the gather of its rows to the combine: (tokens,
+    H) -> (tokens, H). The experts work on the window (`_place_window`) where
+    the held experts' rows lie inside it, and on the whole range where they
+    do not, chosen on the device (`jax.lax.cond`); the dispatch and the
+    combine move all the rows either way.
+
+    A written rule whose residuals are its inputs: differentiated, a `cond`
+    hands its backward the residuals of BOTH branches and fills the untaken
+    branch's with zeros, whole-range arrays among them. The backward makes
+    the forward again inside its own `cond` (under `--checkpoint 1` the
+    layer's recomputation then has nothing of this block to make), and
+    everything as long as the rows is made and used up inside a branch:
+    what passes into a `cond` waits through both of its branches, and the
+    step's memory is the larger branch's."""
+    return _windowed_fwd(share, y, wi, wo, counts, weights, order, inv_order)[0]
+
+
+def _gathered(form, y, order, inv_order):
+    with jax.named_scope(tracing.MOE_DISPATCH):
+        return _dispatch(form, y, order, inv_order)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _windowed_fwd(share, y, wi, wo, counts, weights, order, inv_order):
+    # the scopes are opened inside a branch: a reader of the trace tells the
+    # grouped matmuls apart by the word after the experts' scope, and a cond
+    # puts its own path between the two
+    def forward(take, lay, sizes):
+        rows = _gathered(share.form, y, order, inv_order)
+        with jax.named_scope(tracing.MOE_EXPERTS):
+            mid = share.activate(_gmm_in(share, take(rows), wi, sizes))
+            out = lay(_gmm_out(share, mid, wo, sizes), rows.shape[0])
+        with jax.named_scope(tracing.MOE_COMBINE):
+            return _sum_over_k(share.form, out, inv_order, weights.shape[0], weights)
+
+    fits, windowed, whole = _two_ways(share, counts)
+    out = jax.lax.cond(fits, lambda: forward(*windowed), lambda: forward(*whole))
+    return out, (y, wi, wo, counts, weights, order, inv_order)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _windowed_bwd(share, res, g):
+    y, wi, wo, counts, weights, order, inv_order = res
+    form, on_tpu, first = share.form, share.on_tpu, share.held[0]
+
+    def backward(take, lay, sizes, again):
+        """The block's forward up to the experts' result, and back from the
+        combine to the dispatch. `again`, over all the rows, of which (rows,
+        width) is the largest array the block makes: the up projection is made
+        a second time AFTER the combine's backward and does not wait through
+        it (the step's memory is the larger branch's, and that one is seldom
+        taken: a kernel's time is nothing to it); the barrier is what keeps
+        the second from being the first."""
+        rows = _gathered(form, y, order, inv_order)
+        with jax.named_scope(tracing.MOE_EXPERTS):
+            part = take(rows)
+            mid = _gmm_in(share, part, wi, sizes)
+            act = share.activate(mid)
+            out = lay(_gmm_out(share, act, wo, sizes), rows.shape[0])
+        with jax.named_scope(tracing.MOE_COMBINE):
+            d_out, d_weights, _, _ = _combine_bwd(form, (out, weights, order, inv_order), g)
+        if again:
+            sizes, d_out = jax.lax.optimization_barrier((sizes, d_out))
+            with jax.named_scope(tracing.MOE_EXPERTS):
+                mid = _gmm_in(share, part, wi, sizes)
+        activate_bwd = jax.vjp(share.activate, mid)[1]  # taken under no scope: the scope's words stay plain
+        with jax.named_scope(tracing.MOE_EXPERTS):
+            with jax.named_scope(tracing.MOE_GMM_OUT):
+                d_act, d_wo = grouped_matmul_bwd(act, wo, sizes, take(d_out), on_tpu, first)
+            d_mid, = activate_bwd(d_act)
+            with jax.named_scope(tracing.MOE_GMM_IN):
+                d_part, d_wi = grouped_matmul_bwd(part, wi, sizes, d_mid, on_tpu, first)
+            d_rows = lay(d_part, rows.shape[0])
+        with jax.named_scope(tracing.MOE_DISPATCH):
+            return _sum_over_k(form, d_rows, inv_order, y.shape[0], None), d_wi, d_wo, d_weights
+
+    fits, windowed, whole = _two_ways(share, counts)
+    d_y, d_wi, d_wo, d_weights = jax.lax.cond(
+        fits, lambda: backward(*windowed, False), lambda: backward(*whole, True))
+    # held as they are: moved into the branches, the casts to the parameters'
+    # float32 would make the kernels' gradients twice the size there
+    d_wi, d_wo = jax.lax.optimization_barrier((d_wi, d_wo))
+    return d_y, d_wi, d_wo, None, d_weights, None, None
+
+
+_windowed_block.defvjp(_windowed_fwd, _windowed_bwd)
+
+
 def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, activate, dtype,
                on_tpu: bool, score: str = "softmax", scale: float = 1.0,
                held: Optional[Tuple[int, int]] = None, stat_axes: Tuple[str, ...] = ()):
@@ -537,16 +754,25 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
         inv_order = _permuted(slot, order)
         counts = jnp.sum(flat[:, None] == jnp.arange(num_experts, dtype=flat.dtype),
                          axis=0, dtype=jnp.int32)
-        rows = _dispatch(form, y, order, inv_order)  # (T*k, H), sorted by expert
-    share = {} if held is None else {"first_group": held[0]}
-    with jax.named_scope(tracing.MOE_EXPERTS):
-        with jax.named_scope(tracing.MOE_GMM_IN):
-            mid = grouped_matmul(rows, wi.astype(dtype), counts, on_tpu, **share)
-        mid = activate(mid)
-        with jax.named_scope(tracing.MOE_GMM_OUT):
-            out = grouped_matmul(mid, wo.astype(dtype), counts, on_tpu, **share)
-    with jax.named_scope(tracing.MOE_COMBINE):
-        out = _combine(form, out, weights, order, inv_order).astype(dtype)
+    length = window_rows(k * tokens, num_experts, held)
+    if length:
+        share = _Share(activate, on_tpu, form, held, length, GMM_TILING)
+        with jax.named_scope(tracing.MOE_EXPERTS):  # the kernels' casts are the experts', as without a window
+            kernels = wi.astype(dtype), wo.astype(dtype)
+        out = _windowed_block(share, y, *kernels, counts, weights, order, inv_order).astype(dtype)
+    else:
+        rows = _gathered(form, y, order, inv_order)  # (T*k, H), sorted by expert
+        offset = {} if held is None else {"first_group": held[0]}
+        with jax.named_scope(tracing.MOE_EXPERTS):
+            with jax.named_scope(tracing.MOE_GMM_IN):
+                mid = grouped_matmul(rows, wi.astype(dtype), counts, on_tpu, **offset)
+            mid = activate(mid)
+            with jax.named_scope(tracing.MOE_GMM_OUT):
+                out = grouped_matmul(mid, wo.astype(dtype), counts, on_tpu, **offset)
+        with jax.named_scope(tracing.MOE_COMBINE):
+            out = _combine(form, out, weights, order, inv_order).astype(dtype)
+    if held is not None:
+        WINDOWS_TOOK[length] += 1
     with jax.named_scope(tracing.MOE_ROUTER):
         total = jnp.float32(tokens)
         counts_f = counts.astype(jnp.float32)
@@ -570,6 +796,9 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
         if held is not None:
             aux["rows_held"] = jnp.sum(
                 jax.lax.dynamic_slice_in_dim(counts_f, held[0], held[1]))
+            fits = _place_window(counts, held, length, GMM_TILING[0])[2] if length else True
+            fell = 1.0 - jnp.float32(fits)
+            aux["window_fallbacks"] = jax.lax.psum(fell, stat_axes) if stat_axes else fell
     return out, aux
 
 

@@ -72,6 +72,7 @@ def test_dp2_zero2_follows_one_device_and_logs_the_compile_counter(one_device, c
     assert [e["shortconv_layers"] for e in compiles] == [4]  # four of the five layers convolve
     assert [e["moe_row_kernel_blocks"] for e in compiles] == [0]  # off a TPU the rows move by XLA's gathers
     assert all("kda_kernel_layers" not in e and "linear_kernel_layers" not in e for e in compiles)
+    assert all("expert_window_rows" not in e for e in compiles)  # all 32 experts are held: no window to speak of
 
 
 @pytest.mark.parametrize("flags", [
@@ -85,3 +86,31 @@ def test_the_driver_refuses_what_has_no_form_of_the_conv_layers_before_tracing(f
     with pytest.raises(DiagnosticError, match="GLS018") as e:
         run(flags)
     assert "short-convolution" in str(e.value)
+
+
+def test_a_share_of_the_experts_reports_its_window(tmp_path):
+    """With 4 of the 32 experts held (what only a registered family sets: the
+    CLI has no flag for it), 2 x 512 tokens x 4 choices are 4096 assignments,
+    the window over the held experts' rows is 1.5 x 512 of them in 512-row
+    tiles and a tile, and the `compile` event says so; the `step` events count
+    the routed blocks (of three) whose held rows outgrew it, beside the rows."""
+    from galvatron_tpu.models.lfm2_moe import lfm2_moe_config
+    from galvatron_tpu.models.registry import ModelFamily, register
+
+    register(ModelFamily(
+        name="lfm2_moe_share", default_size="lfm2-8b-a1b", meta_configs={"lfm2-8b-a1b": {}},
+        config_fn=lambda size, **overrides: lfm2_moe_config(size, experts_held=4, experts_held_start=8, **overrides)))
+    tele = str(tmp_path / "share.jsonl")
+    argv = [flag for flag in TINY]
+    argv[argv.index("lfm2_moe")] = "lfm2_moe_share"
+    argv[argv.index("--seq_length") + 1] = "512"
+    train(initialize_galvatron(mode="train_dist", argv=argv + [
+        "--train_iters", "2", "--world_size", "1", "--telemetry", tele]))
+    events, errors = T.read_events(tele)
+    assert errors == []
+    assert [e["expert_window_rows"] for e in events if e["type"] == "compile"] == [1536]
+    steps = [e for e in events if e["type"] == "step"]
+    assert len(steps) == 2
+    for e in steps:
+        assert 0 <= e["expert_window_fallbacks"] <= 3 and e["expert_window_fallbacks"] == int(e["expert_window_fallbacks"])
+        assert e["expert_rows_held"] > 0

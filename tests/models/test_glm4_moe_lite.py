@@ -165,7 +165,7 @@ def test_loss_and_its_parts_match_the_reference(case):
     expected = {"loss_ce", "loss_mtp", "expert_load_max_over_mean", "router_bias_abs_max",
                 M.ROUTER_COUNTS}
     if cfg.experts_held:
-        expected |= {"expert_rows_held", "expert_rows_held_over_even"}
+        expected |= {"expert_rows_held", "expert_rows_held_over_even", "expert_window_fallbacks"}
     assert set(parts) == expected
     assert expected - {M.ROUTER_COUNTS} <= set(
         telemetry.EXPERT_STEP_FIELDS + telemetry.SHARE_STEP_FIELDS)

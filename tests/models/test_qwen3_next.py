@@ -169,7 +169,7 @@ def test_loss_and_parts_are_the_references(case):
         float(parts["loss_ce"]) + 0.001 * float(parts["loss_load_balance"]), abs=1e-6)
     want = set(telemetry.EXPERT_STEP_FIELDS) | set(telemetry.LINEAR_STEP_FIELDS)
     if cfg.experts_held:
-        want |= {"expert_rows_held", "expert_rows_held_over_even"}
+        want |= {"expert_rows_held", "expert_rows_held_over_even", "expert_window_fallbacks"}
     assert set(parts) == want
     assert 0.0 < float(parts["linear_decay_mean"]) < 1.0 and float(parts["linear_state_abs_max"]) > 0.0
 

@@ -82,6 +82,7 @@ def test_lifecycle_events_present(telemetry_run):
     assert "linear_pass_kernel_layers" not in comp and "kda_kernel_layers" not in comp
     assert "kda_pass_kernel_layers" not in comp
     assert "moe_row_kernel_blocks" not in comp  # nor, without a routed block, of the row movers
+    assert "expert_window_rows" not in comp  # nor, without a share of the experts, of its window
     assert t["checkpoint_save"][0]["iteration"] == ITERS
     assert t["layer_run"], "per-LayerRun predictions missing"
     assert t["run_end"][0]["summary"]["iters"] >= 1
