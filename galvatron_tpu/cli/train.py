@@ -26,6 +26,7 @@ from galvatron_tpu.cli.arguments import (
     initialize_galvatron,
     model_config_from_args,
 )
+from galvatron_tpu.models.parts import mlp
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import telemetry, tracing
 from galvatron_tpu.ops import linear_attention, moe
@@ -506,10 +507,12 @@ def _train(args) -> dict:
                 delta_rule_took = collections.Counter(linear_attention.TOOK)
                 moe_rows_took = collections.Counter(moe.ROWS_TOOK)
                 moe_windows_took = collections.Counter(moe.WINDOWS_TOOK)
+                kernels_relaid = collections.Counter(mlp.RELAID)
                 lowered = step_fn.lower(*step_args)
                 delta_rule_took = linear_attention.TOOK - delta_rule_took
                 moe_rows_took = moe.ROWS_TOOK - moe_rows_took
                 moe_windows_took = moe.WINDOWS_TOOK - moe_windows_took
+                kernels_relaid = mlp.RELAID - kernels_relaid
                 t1 = time.perf_counter()
                 key = _step_exec_key(model.mesh, lowered)
                 compiled = _STEP_EXECUTABLES.get(key)
@@ -572,6 +575,11 @@ def _train(args) -> dict:
                 # (models/parts/conv.py); absent where the step traced none
                 shortconv_layers=(sum(kind.startswith("conv") for kind in cfg.layer_kinds())
                                   if delta_rule_took["short_conv"] else None),
+                # the gated (hidden, 2, ffn) kernels the step read through
+                # `parts/mlp.grad_as_stored`, as traced (`models/base.run_layers`):
+                # all of a model's or none; 0 off a TPU, where every such layer
+                # is scanned and where there is none
+                kernel_grads_relaid=len(kernels_relaid),
             )
             _aot["fn"] = compiled
         return _aot["fn"](*step_args)

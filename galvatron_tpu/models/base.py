@@ -37,7 +37,7 @@ from galvatron_tpu.models.parts import MIXERS, MLP_HALVES, unsupported_reason
 from galvatron_tpu.models.parts.common import Params, _dense, _dense_init, _norm, _norm_params
 from galvatron_tpu.models.parts.embed_head import (embed_patches, embed_tokens, head_logits, model_head, softmax_nll,
                                                    vocab_parallel_cross_entropy)
-from galvatron_tpu.models.parts.mlp import ROUTER_BIAS
+from galvatron_tpu.models.parts.mlp import RELAID, ROUTER_BIAS, grad_as_stored
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding
 from galvatron_tpu.parallel import spec as S
@@ -290,6 +290,43 @@ def stacked_layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params
     )
 
 
+def _at(tree: Params, path: Tuple[str, ...], fn) -> Params:
+    """`tree` with `fn` applied to the leaf at `path`; the rest shared."""
+    return {**tree, path[0]: _at(tree[path[0]], path[1:], fn) if path[1:] else fn(tree[path[0]])}
+
+
+def _gated_grads_as_stored(layers: List[Params], runs, scanned, cfg: TransformerConfig,
+                           mesh: Optional[Mesh]) -> List[Params]:
+    """The layers with their gated kernels (`LayerPart.gated_kernels`: a dense
+    SwiGLU half's up kernel) read through `parts/mlp.grad_as_stored`, where the
+    stack observes that it pays: on a TPU (read off the mesh), in a model SOME
+    run of which holds such a kernel and is not scanned. That layer's gradient
+    reaches the update straight from the backward's matmul, in the matmul's
+    tiling, and the compiler then moves the STATE of every leaf of that shape
+    into it and back, the scanned layers' too (Granite's one attention layer
+    among nine scanned ones: 60 copies). Where every such run is scanned (the
+    Qwen cells) the compiler lays the scan's gradient buffer out after the
+    state by itself, and the program stays as it is. All of a model's gated
+    kernels or none; `RELAID` counts each, by layer and path, as it is traced."""
+    kinds = cfg.layer_kinds()
+
+    def gated(run):  # a run is of one kind
+        lcfg = cfg.layer_config(kinds[run.start])
+        return MLP_HALVES[lcfg.mlp_half].gated_kernels(lcfg)
+
+    if mesh is None or mesh.devices.flat[0].platform != "tpu" or not any(
+            gated(run) for run in runs if not scanned(run)):
+        return layers
+    layers = list(layers)
+    for run in runs:
+        held_in = None if scanned(run) else cfg.compute_dtype
+        for path in gated(run):
+            for i in run.layer_indices:
+                layers[i] = _at(layers[i], path, partial(grad_as_stored, held_in=held_in))
+                RELAID[(i,) + path] += 1
+    return layers
+
+
 def run_layers(
     params: Params,
     x: jax.Array,
@@ -332,6 +369,18 @@ def run_layers(
     auxs: List[Dict[str, jax.Array]] = []  # routed: a layer's, or a scanned run's stacked
 
     kinds = cfg.layer_kinds()
+    if use_hp:
+        runs = layer_runs(hp, model_layer_kinds(cfg))
+    else:
+        # no strategy info: one homogeneous run a kind of layer
+        starts = [i for i in range(len(layers)) if i == 0 or kinds[i] != kinds[i - 1]]
+        runs = [LayerRun(start=a, stop=b, strategy=LayerStrategy())
+                for a, b in zip(starts, starts[1:] + [len(layers)])]
+
+    def scanned(run):
+        return scan and run.length >= 2
+
+    layers = _gated_grads_as_stored(layers, runs, scanned, cfg, mesh if use_hp else None)
 
     def unrolled(x, indices):
         for i in indices:
@@ -363,7 +412,7 @@ def run_layers(
         return x
 
     def one_run(x, run):
-        if not scan or run.length < 2:
+        if not scanned(run):
             return unrolled(x, run.layer_indices)
         lcfg = cfg.layer_config(kinds[run.start])  # a run is of one kind
         axes = layer_axes(hp, run.start) if use_hp else None
@@ -409,13 +458,6 @@ def run_layers(
             auxs.append(run_aux)
         return x
 
-    if use_hp:
-        runs = layer_runs(hp, model_layer_kinds(cfg))
-    else:
-        # no strategy info: one homogeneous run a kind of layer
-        starts = [i for i in range(len(layers)) if i == 0 or kinds[i] != kinds[i - 1]]
-        runs = [LayerRun(start=a, stop=b, strategy=LayerStrategy())
-                for a, b in zip(starts, starts[1:] + [len(layers)])]
     for k, run in enumerate(runs):
         # one scope a run, scanned or unrolled; k is the `layer_run` event's
         with jax.named_scope(tracing.layers_scope(k)):

@@ -38,6 +38,11 @@ class LayerPart:
     # (p, y, positions, cfg, k_cache=, v_cache=, write_index=, mesh=, axes=, attn_bias=)
     # -> (out, k_cache, v_cache): single-token decode, where the part has it
     decode: Optional[Callable] = None
+    # (cfg) -> the paths, in the layer's parameters, of the part's gated
+    # (hidden, 2, ffn) kernels: leaves whose gradient the matmul yields in
+    # another tiling than the train state stores them in; the stack reads them
+    # through `parts/mlp.grad_as_stored` where that pays (models/base.run_layers)
+    gated_kernels: Callable = lambda cfg: ()
 
 
 def no_form(name: str, **says: str) -> Mapping[str, str]:

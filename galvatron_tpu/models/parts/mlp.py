@@ -3,11 +3,13 @@ shared expert and its gate beside them."""
 
 from __future__ import annotations
 
+import collections
 import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from galvatron_tpu.models.config import TransformerConfig
@@ -30,6 +32,45 @@ def _init_dense(ks, cfg: TransformerConfig) -> Params:
         p["wi"]["bias"] = jnp.zeros(cfg.mlp_fan_in, cfg.param_dtype)
         p["wo_mlp"]["bias"] = jnp.zeros((h,), cfg.param_dtype)
     return p
+
+
+# the kernels read through `grad_as_stored` since the process began, by layer
+# and path, counted as they are traced (`models/base.run_layers`): the
+# trainer's compile report reads how many a step's trace added
+RELAID = collections.Counter()
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1,))
+def grad_as_stored(kernel: jax.Array, held_in=None) -> jax.Array:
+    """The identity on a SwiGLU's up kernel (hidden, 2, ffn), a leaf of the
+    train state, which says where its gradient lies until the update reads it.
+    A TPU stores the float32 leaf and its two Adam moments in tiles of 2 x 128
+    over (2, ffn); the backward's matmul yields the gradient in tiles of 8 x
+    128 over (hidden, ffn), the pair axis major. Where a layer runs unrolled
+    that gradient reaches the fused Adam update as it is, and the compiler
+    settles the mismatch by copying parameter, `mu` and `nu` of EVERY leaf of
+    that shape into the gradient's tiling and back, every step (Granite: 60
+    copies of 134 MB, 8 % of its step; PERF.md section 6, PR 48). The backward
+    here holds the cotangent pair-axis-major, as the matmul yields it and as
+    a scan stacks it (the first constraint, on the transposed array: no data
+    moves), and asks for the leaf's own layout after it (the second): one
+    relayout of the gradient, which the compiler fuses into the update's read,
+    and no transient inside a scanned run.
+
+    `held_in`: the dtype an UNROLLED layer's gradient waits in (the compute
+    dtype: what the matmul's jaxpr yields, and what the compiler without this
+    rule kept of such a layer until the update: the same values at the same
+    bytes); None inside a scanned run, whose float32 stack stays as it is."""
+    return kernel
+
+
+def _grad_as_stored_bwd(held_in, _, g):
+    rows_major = Layout((0, 1, 2))  # of (2, hidden, ffn): the matmul's; of (hidden, 2, ffn): the leaf's
+    held = with_layout_constraint(jnp.transpose(g if held_in is None else g.astype(held_in), (1, 0, 2)), rows_major)
+    return (with_layout_constraint(jnp.transpose(held, (1, 0, 2)), rows_major).astype(g.dtype),)
+
+
+grad_as_stored.defvjp(lambda kernel, held_in: (kernel, None), _grad_as_stored_bwd)
 
 
 def dense_mlp(p: Params, y: jax.Array, cfg: TransformerConfig, dtype) -> jax.Array:
@@ -145,7 +186,8 @@ UNSUPPORTED = no_form(
     quant="router statistics",
 )
 
-DENSE = LayerPart(_init_dense, _dense_forward, _dense_specs, (tracing.MLP,))
+DENSE = LayerPart(_init_dense, _dense_forward, _dense_specs, (tracing.MLP,),
+                  gated_kernels=lambda cfg: (("wi", "kernel"),) if cfg.activation == "swiglu" else ())
 ROUTED = LayerPart(
     _init_routed, _routed_forward, _routed_specs,
     (tracing.MOE_ROUTER, tracing.MOE_DISPATCH, tracing.MOE_EXPERTS, tracing.MOE_COMBINE, tracing.MOE_SHARED),

@@ -5,7 +5,9 @@ TPU-native counterpart of the reference's distributed checkpoint system
 FULL_STATE_DICT save, one file per tp-rank per layer under ``iter_N/`` plus
 per-rank optimizer state and scheduler JSON). Here sharded arrays are written
 through orbax/tensorstore — each host writes exactly its addressable shards,
-and restore re-shards to the current mesh layout.
+and restore re-shards to the current mesh layout. What is written is a leaf's
+logical array: a leaf's physical tiling on the device is the compiler's and no
+part of a checkpoint (runtime/model_api.state_specs).
 
 The reference *asserts the parallel strategy is unchanged on resume* (no
 cross-strategy re-sharding, hybrid_parallel_config.py:112-124). We keep the

@@ -132,7 +132,12 @@ class HybridParallelModel:
 
     def state_specs(self) -> Params:
         """The layout the parameters are stored in: what the step takes and
-        returns, `init_params` produces and a checkpoint restores into."""
+        returns, `init_params` produces and a checkpoint restores into. A
+        leaf of the state has a logical shape, this spec, and the physical
+        tiling the compiler gives an entry parameter of that shape; nobody
+        states another (PERF.md section 3, PR 48: where a gradient lies
+        otherwise it is the gradient that is relaid, `parts/mlp.grad_as_stored`)
+        and a checkpoint holds the first two."""
         return jax.tree.map(
             lambda spec, split, copied: split if copied else spec,
             self.param_specs, self.grad_accum_specs(), self.copied_leaves(),

@@ -5,6 +5,7 @@ must not move.
 
     JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python3 scripts/compiled_steps.py dump <checkout> <out_dir> [<cell> ...]
     python3 scripts/compiled_steps.py diff <out_dir_a> <out_dir_b>
+    python3 scripts/compiled_steps.py copies <out_dir> [<cell> ...]
 
 `dump` compiles each cell's step (default: every cell of the checkout's
 BENCHMARK.json) for a described v5e 2x2, as `benchmarks/rehearse.py` does, from
@@ -17,7 +18,11 @@ them), `metadata={...}`, the checkout's path, and the source locations inside
 a Mosaic kernel's serialized body (the body is read back as MLIR and printed
 without them: a kernel whose program changed differs, one whose file only
 moved does not). Exit 1 where a cell differs. One process a checkout: the
-program is imported from it."""
+program is imported from it. `copies` reads a dumped cell's relayouts: the
+`copy` ops over 4 MB that stand alone (an instruction, or all a fusion does)
+by dtype, shape and the layout copied INTO, their count and bytes read +
+written, how many more are fused into their reader, and `memory_analysis()`'s
+four sizes as `dump` left them in `<cell>.memory.json`: a line of JSON a cell."""
 
 from __future__ import annotations
 
@@ -73,8 +78,84 @@ def dump(root: str, out: str, workloads) -> None:
             sds(jax.eval_shape(tx.init, params), model.opt_state_shardings(tx, params)), batch).compile()
         with open(os.path.join(out, workload + ".hlo.txt"), "w") as f:
             f.write(step.as_text())
+        memory = step.memory_analysis()
+        with open(os.path.join(out, workload + ".memory.json"), "w") as f:
+            json.dump({k: getattr(memory, k + "_size_in_bytes") for k in MEMORY_SIZES}, f)
         print(json.dumps({"workload": workload, "root": root, "instructions": step.as_text().count(" = ")}),
               flush=True)
+
+
+MEMORY_SIZES = ("argument", "output", "alias", "temp")
+ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2,
+            "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8, "u64": 8}
+COPY_OVER = 4 << 20  # bytes of the array a `copy` has to move to be listed
+# `%copy.1 = f32[2048,2,8192]{2,0,1:T(8,128)} copy(%param.1), ...`: the layout is the one copied INTO
+COPY = re.compile(r"= (\w+)\[([\d,]*)\](\{[^ ]*\})? copy\(")
+
+
+# what a fused computation may hold beside a `copy` and still be nothing but that relayout
+MOVES_ONLY = {"parameter", "copy", "bitcast", "tuple", "get-tuple-element"}
+OPCODE = re.compile(r"^\s*(?:ROOT )?%\S+ = .*? ([\w-]+)\(")
+
+
+def copy_ops(text: str):
+    """(dtype, dims, layout copied INTO, bytes, alone) of every `copy` in an
+    optimized HLO. `alone`: the copy is an instruction of its own or all its
+    fusion does, so its bytes are read and written beside everybody else's;
+    not alone, it is a fusion's way of reading an operand that lies otherwise
+    (PR 48: the Adam update reading a gradient in the matmul's tiling) and
+    moves nothing by itself."""
+    fused, found, opcodes = False, [], set()
+    for line in text.splitlines() + ["}"]:
+        if line.startswith(("%", "ENTRY")) and line.rstrip().endswith("{"):
+            fused, found, opcodes = line.startswith("%fused_computation"), [], set()
+        elif line.startswith("}"):
+            for dtype, dims, layout in found:
+                yield dtype, dims, layout or "", _bytes(dtype, dims), not fused or opcodes <= MOVES_ONLY
+            found = []
+        else:
+            opcode, copy = OPCODE.match(line), COPY.search(line)
+            if opcode:
+                opcodes.add(opcode.group(1))
+            if copy:
+                found.append(copy.groups())
+
+
+def copies(out: str, workloads) -> None:
+    """A line a dumped cell: its `copy` ops that stand alone (`copy_ops`) over
+    `COPY_OVER` bytes, grouped; those fused into a reader only counted."""
+    workloads = workloads or sorted(n[:-len(".hlo.txt")] for n in os.listdir(out) if n.endswith(".hlo.txt"))
+    for workload in workloads:
+        groups, every, fused = {}, [0, 0], 0
+        for dtype, dims, layout, size, alone in copy_ops(open(os.path.join(out, workload + ".hlo.txt")).read()):
+            if not alone:
+                fused += size > COPY_OVER
+                continue
+            every[0] += 1
+            every[1] += 2 * size
+            if size > COPY_OVER:
+                group = groups.setdefault("%s[%s]%s" % (dtype, dims, layout), {"copies": 0, "gb": 0.0})
+                group["copies"] += 1
+                group["gb"] += 2 * size / 1e9
+        over = {k: {"copies": v["copies"], "gb": round(v["gb"], 3)} for k, v in sorted(groups.items())}
+        line = {"workload": workload,
+                "f32_copies_over_4mb": sum(v["copies"] for k, v in over.items() if k.startswith("f32")),
+                "f32_gb_over_4mb": round(sum(v["gb"] for k, v in over.items() if k.startswith("f32")), 3),
+                "copies_over_4mb": sum(v["copies"] for v in over.values()),
+                "gb_over_4mb": round(sum(v["gb"] for v in over.values()), 3),
+                "all_copies": every[0], "all_gb": round(every[1] / 1e9, 3),
+                "fused_into_readers_over_4mb": fused, "by_shape": over}
+        memory = os.path.join(out, workload + ".memory.json")
+        if os.path.exists(memory):
+            line.update({k + "_gib": round(v / 2**30, 4) for k, v in json.load(open(memory)).items()})
+        print(json.dumps(line), flush=True)
+
+
+def _bytes(dtype: str, dims: str) -> int:
+    size = ITEMSIZE[dtype]
+    for d in filter(None, dims.split(",")):
+        size *= int(d)
+    return size
 
 
 TABLES = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\b")
@@ -114,7 +195,7 @@ def instructions(path: str):
 
 def diff(a: str, b: str) -> int:
     differing = 0
-    for name in sorted(set(os.listdir(a)) | set(os.listdir(b))):
+    for name in sorted(n for n in set(os.listdir(a)) | set(os.listdir(b)) if n.endswith(".hlo.txt")):
         paths = [os.path.join(d, name) for d in (a, b)]
         if not all(os.path.exists(p) for p in paths):
             print("%s: on one side only" % name)
@@ -139,5 +220,8 @@ if __name__ == "__main__":
         sys.exit(0)
     if len(sys.argv) == 4 and sys.argv[1] == "diff":
         sys.exit(diff(sys.argv[2], sys.argv[3]))
+    if len(sys.argv) >= 3 and sys.argv[1] == "copies":
+        copies(sys.argv[2], sys.argv[3:])
+        sys.exit(0)
     print(__doc__, file=sys.stderr)
     sys.exit(2)

@@ -55,6 +55,8 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counter(one_device, tmp_pa
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 5), (1, 5, 6), (2, 6, 10)]
     # no linear layer: the compile report has nothing to say of the delta rule's kernels
     assert all("linear_kernel_layers" not in e for e in events if e["type"] == "compile")
+    # ten gated kernels, and off a TPU none is read through `grad_as_stored` (models/base.run_layers)
+    assert [e["kernel_grads_relaid"] for e in events if e["type"] == "compile"] == [0]
 
 
 @pytest.mark.parametrize("flags", [

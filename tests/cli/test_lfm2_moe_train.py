@@ -73,6 +73,7 @@ def test_dp2_zero2_follows_one_device_and_logs_the_compile_counter(one_device, c
     assert [e["moe_row_kernel_blocks"] for e in compiles] == [0]  # off a TPU the rows move by XLA's gathers
     assert all("kda_kernel_layers" not in e and "linear_kernel_layers" not in e for e in compiles)
     assert all("expert_window_rows" not in e for e in compiles)  # all 32 experts are held: no window to speak of
+    assert [e["kernel_grads_relaid"] for e in compiles] == [0]  # off a TPU the compiler lays the gradients out
 
 
 @pytest.mark.parametrize("flags", [
