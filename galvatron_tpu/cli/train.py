@@ -29,6 +29,7 @@ from galvatron_tpu.cli.arguments import (
 from galvatron_tpu.models.parts import mlp
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import telemetry, tracing
+from galvatron_tpu.ops import attention as attention_ops
 from galvatron_tpu.ops import linear_attention, moe
 from galvatron_tpu.profiler.runtime import (
     RuntimeProfiler,
@@ -508,7 +509,9 @@ def _train(args) -> dict:
                 moe_rows_took = collections.Counter(moe.ROWS_TOOK)
                 moe_windows_took = collections.Counter(moe.WINDOWS_TOOK)
                 kernels_relaid = collections.Counter(mlp.RELAID)
+                windows_took = collections.Counter(attention_ops.TOOK)
                 lowered = step_fn.lower(*step_args)
+                windows_took = attention_ops.TOOK - windows_took
                 delta_rule_took = linear_attention.TOOK - delta_rule_took
                 moe_rows_took = moe.ROWS_TOOK - moe_rows_took
                 moe_windows_took = moe.WINDOWS_TOOK - moe_windows_took
@@ -575,6 +578,12 @@ def _train(args) -> dict:
                 # (models/parts/conv.py); absent where the step traced none
                 shortconv_layers=(sum(kind.startswith("conv") for kind in cfg.layer_kinds())
                                   if delta_rule_took["short_conv"] else None),
+                # the window attention layers whose band the step runs as Pallas
+                # kernels (`ops/attention._windowed`): all or none, the layers
+                # being alike; absent where the step traced no window layer
+                window_kernel_layers=(
+                    sum(kind.startswith("window") for kind in cfg.layer_kinds()) * (not windows_took["window_xla"])
+                    if windows_took else None),
                 # the gated (hidden, 2, ffn) kernels the step read through
                 # `parts/mlp.grad_as_stored`, as traced (`models/base.run_layers`):
                 # all of a model's or none; 0 off a TPU, where every such layer

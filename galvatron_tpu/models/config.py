@@ -12,12 +12,13 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any, List, Optional, Tuple
+from typing import Any, List, Mapping, Optional, Tuple
 
 import jax.numpy as jnp
 
 
-_MIXER_ALIASES = {"mamba": "ssm"}  # HF's word in `layer_types` -> the `MIXERS` key
+# HF's words in `layer_types` -> the `MIXERS` key
+_MIXER_ALIASES = {"mamba": "ssm", "full_attention": "attention", "sliding_attention": "window"}
 
 
 @dataclass
@@ -137,6 +138,22 @@ class TransformerConfig:
     # --- what LFM2's published config adds (lfm2_moe): layers whose token
     # mixer is a gated short convolution (`conv_mixer`, the mixer "conv") ---
     short_conv_kernel: int = 0  # taps of the causal depthwise convolution between the two gates
+    # --- what Laguna's published config adds (laguna): layers of softmax
+    # attention over a WINDOW (`models/parts/window.py`, the mixer "window";
+    # HF's "sliding_attention" in `layer_types`) among layers of full
+    # attention, each kind of layer with its own head count and rope ---
+    sliding_window: int = 0  # a window layer's query i sees the keys i - this < j <= i
+    # the window layers' heads, rope base and rotary share, where they differ from the full
+    # layers' `num_heads`, `rope_theta`, `partial_rotary_factor` (None: the same); the key
+    # heads and `head_dim` are the model's. `layer_config("window.*")` hands them on as
+    # the ordinary three, with no `rope_scaling`
+    window_num_heads: Optional[int] = None
+    window_rope_theta: Optional[float] = None
+    window_partial_rotary_factor: Optional[float] = None
+    # the full layers' rope scaling: None, or yarn's numbers beside `rope_type`
+    # "yarn" (`ops/rope.YARN_KEYS`); any other `rope_type` is refused by name
+    rope_scaling: Optional[Mapping[str, Any]] = None
+    attn_head_gate: bool = False  # attn x sigmoid(y Wg) a HEAD, Wg (hidden, heads)
     # which `MIXERS` entry ONE layer runs. `layer_config(kind)` sets it; a
     # model's own config leaves it and states the pattern above
     mixer: str = "attention"
@@ -249,6 +266,13 @@ class TransformerConfig:
         if self.mixers() != (self.mixer,) * self.num_layers:
             cfg = dataclasses.replace(cfg, mixer=mixer or "attention", full_attention_interval=0,
                                       layer_types=None)
+        if mixer == "window":  # the window layers' own heads and rope as the fields every part reads
+            cfg = dataclasses.replace(
+                cfg, mixer="window", rope_scaling=None,
+                window_num_heads=None, window_rope_theta=None, window_partial_rotary_factor=None,
+                **{field: value for field, value in (
+                    ("num_heads", self.window_num_heads), ("rope_theta", self.window_rope_theta),
+                    ("partial_rotary_factor", self.window_partial_rotary_factor)) if value is not None})
         return cfg
 
     @property

@@ -17,8 +17,9 @@ from galvatron_tpu.models.parts.kda import KDA
 from galvatron_tpu.models.parts.linear import LINEAR
 from galvatron_tpu.models.parts.mlp import DENSE, ROUTED
 from galvatron_tpu.models.parts.ssm import SSM
+from galvatron_tpu.models.parts.window import WINDOW
 
-MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA, "conv": CONV}
+MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA, "conv": CONV, "window": WINDOW}
 MLP_HALVES = {"dense": DENSE, "routed": ROUTED}
 
 # how an asker's sentence starts, and what joins the parts' statements in it

@@ -3,6 +3,7 @@ attention (MLA) with it: the two share the one attention call."""
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -15,12 +16,16 @@ from galvatron_tpu.models.parts.common import (LayerPart, Params, _dense, _dense
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding, core_attention
 from galvatron_tpu.ops.norms import rms_norm
-from galvatron_tpu.ops.rope import apply_rotary
+from galvatron_tpu.ops.rope import apply_rotary, checked_scaling
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import LayerAxes
 
 
 def _validate(cfg: TransformerConfig) -> None:
+    checked_scaling(cfg.rope_scaling)  # a `rope_type` with no form is refused by name
+    if cfg.attn_head_gate and (cfg.attn_output_gate or cfg.latent_attention):
+        raise ValueError("attn_head_gate (a gate a head, Wg (hidden, heads)) stands alone: not beside "
+                         "attn_output_gate (a gate a head AND dim, projected with q) nor latent attention")
     if not cfg.latent_attention:
         return
     widest = max(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim)
@@ -49,8 +54,13 @@ _LATENT = dict(
     quant="the multi-token-prediction module's term")
 
 
+# the decode path (`attention_decode`) multiplies by no gate
+_HEAD_GATE = dict(serve="no per-head output gate on a decoded token's attention")
+
+
 def _unsupported(cfg: TransformerConfig):
-    return _LATENT if cfg.latent_attention or cfg.mtp_layers else {}
+    said = _LATENT if cfg.latent_attention or cfg.mtp_layers else {}
+    return {**_HEAD_GATE, **said} if cfg.attn_head_gate else said
 
 
 def _init_attention(ks, cfg: TransformerConfig) -> Params:
@@ -103,6 +113,8 @@ def _init_attention(ks, cfg: TransformerConfig) -> Params:
     elif cfg.qk_norm:
         p["q_norm"] = {"scale": jnp.ones((nh * hd,), cfg.param_dtype)}
         p["k_norm"] = {"scale": jnp.ones((nkv * hd,), cfg.param_dtype)}
+    if cfg.attn_head_gate:
+        p["wg"] = {"kernel": _dense_init(jax.random.fold_in(ks[0], 1), (h, nh), cfg.init_std, cfg.param_dtype)}
     return p
 
 
@@ -178,13 +190,16 @@ def latent_qkv_projection(p: Params, y: jax.Array, positions: jax.Array,
 
 
 def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, *,
-                    mesh, axes, attn_bias, attn_sharding, return_kv: bool):
+                    mesh, axes, attn_bias, attn_sharding, return_kv: bool,
+                    scope: Optional[str] = None, window: Optional[int] = None):
     """Softmax attention on normed activations (B, S_local, H) -> the
     output projection's result, the post-rope (k, v) where asked, and no
     counters. Seq-sharded activations (megatron-sp / ulysses) are re-gathered
     into head-sharded full-sequence tensors for attention (all-gather or
     all-to-all inserted by XLA — the hand-written collectives of reference
-    transformer.py:1928-2177)."""
+    transformer.py:1928-2177). `scope`, `window`: the window part's call
+    (`parts/window.py`), everything but the attention call under a scope of
+    its own and the call over a window of so many keys."""
     dtype = cfg.compute_dtype
     if cfg.position_type == "rope" and mesh is not None and axes is not None:
         # Pin positions to THIS layer's sharding so each layer derives its
@@ -199,7 +214,7 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
         pin = lambda pos: pos  # noqa: E731
     # one scope for everything of the mixer but the attention call: a block
     # before it and a block after it
-    scope = tracing.ATTN_LATENT if cfg.latent_attention else tracing.ATTN_PROJ
+    scope = scope or (tracing.ATTN_LATENT if cfg.latent_attention else tracing.ATTN_PROJ)
     gate, sm_scale = None, cfg.attention_multiplier
     with jax.named_scope(scope):
         if cfg.latent_attention:
@@ -213,12 +228,14 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
             q, k, v = qkv_projection(p, y, cfg, dtype)
             if cfg.attn_output_gate:
                 q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
+            elif cfg.attn_head_gate:  # (B, S, nh, 1): one gate for all of a head's dims
+                gate = _dense(y, p["wg"], dtype)[..., None]
             if cfg.qk_norm:
                 q, k = qk_normed(p, q, k, cfg)
             if cfg.position_type == "rope":
                 positions = pin(positions)
-                q = apply_rotary(q, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
-                k = apply_rotary(k, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim)
+                q = apply_rotary(q, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim, scaling=cfg.rope_scaling)
+                k = apply_rotary(k, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim, scaling=cfg.rope_scaling)
     if mesh is not None and axes is not None and len(axes.tp) + len(axes.cp) > 0:
         # (B, S/x, nh, hd) -> (B, S/cp, nh/tp, hd): XLA inserts the all-to-all
         # (ulysses) or all-gather+split (megatron-sp) when seq was tp-sharded.
@@ -241,9 +258,11 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
     else:
         # the generic tree's attn_bias is always padding_attn_bias output, so
         # the flash path may lower it to segment ids instead of falling back
-        attn = core_attention(q, k, v, causal=cfg.causal, bias=attn_bias,
-                              impl=cfg.attn_impl, bias_type="key_padding",
-                              sharding=attn_sharding, sm_scale=sm_scale)
+        # (the flash kernels' calls carry no nested scope: the benchmark finds them by name; a window's do)
+        with jax.named_scope(tracing.ATTN_WINDOW_BAND) if window is not None else contextlib.nullcontext():
+            attn = core_attention(q, k, v, causal=cfg.causal, bias=attn_bias,
+                                  impl=cfg.attn_impl, bias_type="key_padding",
+                                  sharding=attn_sharding, sm_scale=sm_scale, window=window)
     with jax.named_scope(scope):
         if gate is not None:
             attn = attn * jax.nn.sigmoid(gate)
@@ -335,6 +354,8 @@ def _attention_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     if cfg.qk_norm:
         sp["q_norm"] = {"scale": r1}
         sp["k_norm"] = {"scale": r1}
+    if cfg.attn_head_gate:
+        sp["wg"] = {"kernel": P(z3, tp)}
     return sp
 
 

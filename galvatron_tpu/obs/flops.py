@@ -64,9 +64,10 @@ def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
 # of `models/parts.MIXERS` (`MIXER_FWD_FLOPS` below; this module imports no jax).
 def attention_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, num_kv_heads: int,
                                 seq_len: int, causal: bool = True, gated: bool = False,
-                                latent: Optional[Mapping[str, int]] = None):
+                                latent: Optional[Mapping[str, int]] = None, head_gate: bool = False):
     """Softmax attention: q, fused kv (GQA-scaled) and out projections (q
-    twice as wide beside an output gate; latent attention's five by their
+    twice as wide beside an output gate, or a (hidden, heads) gate a head
+    beside it; latent attention's five by their
     shapes, or four where q has no low rank), and scores (q k^T) + weighted
     sum (p v), each 2 S q_dim, half of it under a causal mask; latent
     attention's scores at its q/k width (nope + rope) and its sum at v's,
@@ -81,9 +82,22 @@ def attention_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, n
         proj = (q_proj + 2.0 * hidden * (kvl + rope) + 2.0 * kvl * num_heads * (nope + vd)
                 + 2.0 * num_heads * vd * hidden)
         return proj, 2.0 * seq_len * num_heads * ((nope + rope) + vd) * (0.5 if causal else 1.0)
-    proj = (2.0 * hidden * q_dim * (2 if gated else 1)
+    proj = (2.0 * hidden * q_dim * (2 if gated else 1) + (2.0 * hidden * num_heads if head_gate else 0.0)
             + 2.0 * hidden * (2 * num_kv_heads * head_dim) + 2.0 * q_dim * hidden)
     return proj, 2.0 * (2.0 * seq_len * q_dim) * (0.5 if causal else 1.0)
+
+
+def window_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, num_kv_heads: int,
+                             window: int, head_gate: bool, seq_len: int):
+    """Softmax attention over a window: the attention row's projections at
+    the window layer's heads, and scores + weighted sum over the keys a query
+    SEES, the exact band: query i sees min(i + 1, window) keys, a mean of
+    (W S - W (W - 1) / 2) / S over a sequence (W = min(window, S))."""
+    proj, _ = attention_fwd_flops_a_token(hidden=hidden, num_heads=num_heads, head_dim=head_dim,
+                                          num_kv_heads=num_kv_heads, seq_len=seq_len, head_gate=head_gate)
+    w = min(window, seq_len)
+    keys = (w * seq_len - w * (w - 1) / 2.0) / seq_len
+    return proj, 2.0 * 2.0 * keys * num_heads * head_dim
 
 
 def linear_fwd_flops_a_token(*, hidden: int, num_key_heads: int, num_value_heads: int,
@@ -135,6 +149,7 @@ def conv_fwd_flops_a_token(*, hidden: int):
 
 
 # the row of each `MIXERS` key, and the config fields its keyword arguments read
+# ("seq_len": no field, the sequence length the count is asked at)
 _DELTA_DIMS = {k: "linear_" + k for k in ("num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
 MIXER_FWD_FLOPS = {
     "attention": (attention_fwd_flops_a_token, {}),
@@ -142,6 +157,9 @@ MIXER_FWD_FLOPS = {
     "kda": (kda_fwd_flops_a_token, _DELTA_DIMS),
     "ssm": (ssm_fwd_flops_a_token, {k: "ssm_" + k for k in ("num_heads", "head_dim", "state_dim")}),
     "conv": (conv_fwd_flops_a_token, {}),
+    "window": (window_fwd_flops_a_token, {
+        "num_heads": "num_heads", "head_dim": "head_dim", "num_kv_heads": "num_kv_heads",
+        "window": "sliding_window", "head_gate": "attn_head_gate", "seq_len": "seq_len"}),
 }
 
 
@@ -165,6 +183,7 @@ def layer_fwd_flops(
     shared_gate: bool = False,
     mixer: str = "attention",
     mixer_dims: Optional[Mapping[str, int]] = None,
+    head_gate: bool = False,
 ) -> float:
     """Forward model FLOPs of ONE transformer block over `tokens` tokens
     (default: one sequence). Matmul terms only (2 FLOPs per MAC); norms and
@@ -179,7 +198,8 @@ def layer_fwd_flops(
     their shapes in place of q, k/v and out; `attn_gate`: q projected beside
     an output gate. `mixer` with `mixer_dims`: the layer's token mixer is
     that row of `MIXER_FWD_FLOPS` on those sizes, in place of attention.
-    `shared_gate`: the shared expert's (hidden, 1) gate."""
+    `shared_gate`: the shared expert's (hidden, 1) gate; `head_gate`: the
+    attention output's (hidden, heads) gate."""
     tokens = float(seq_len if tokens is None else tokens)
     ffn = ffn_hidden or 4 * hidden
     if mixer != "attention":
@@ -188,7 +208,7 @@ def layer_fwd_flops(
         proj, attn = attention_fwd_flops_a_token(
             hidden=hidden, num_heads=num_heads, head_dim=head_dim or hidden // num_heads,
             num_kv_heads=num_kv_heads or num_heads, seq_len=seq_len, causal=causal,
-            gated=attn_gate, latent=latent)
+            gated=attn_gate, latent=latent, head_gate=head_gate)
     # MLP: swiglu projects to 2*ffn (gate+up) then back; gelu/relu ffn both ways
     mlp = (2.0 * hidden * (2 * ffn) + 2.0 * ffn * hidden) if swiglu \
         else (2.0 * hidden * ffn + 2.0 * ffn * hidden)
@@ -232,7 +252,9 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         attn_gate=bool(getattr(cfg, "attn_output_gate", False)),
         shared_gate=bool(getattr(cfg, "shared_expert_gate", False)),
         mixer=mixer,
-        mixer_dims={k: getattr(cfg, field) for k, field in MIXER_FWD_FLOPS[mixer][1].items()},
+        mixer_dims={k: seq if field == "seq_len" else getattr(cfg, field)
+                    for k, field in MIXER_FWD_FLOPS[mixer][1].items()},
+        head_gate=bool(getattr(cfg, "attn_head_gate", False)),
     )
 
 

@@ -401,6 +401,8 @@ def render(analysis: Dict[str, Any]) -> str:
                          % comp["expert_window_rows"])
         if "shortconv_layers" in comp:
             lines.append("layers whose token mixer is a gated short convolution: %d" % comp["shortconv_layers"])
+        if "window_kernel_layers" in comp:
+            lines.append("window attention layers whose band runs as Pallas kernels: %d" % comp["window_kernel_layers"])
         if "kernel_grads_relaid" in comp:
             lines.append("gated kernels whose gradient is relaid to the state's layout: %d" % comp["kernel_grads_relaid"])
     an = analysis["anomalies"]

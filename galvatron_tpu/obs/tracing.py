@@ -63,6 +63,16 @@ ATTN_LATENT = "gt.attn.latent"
 # norms, rope, the gate's product and the output projection, so that a softmax
 # mixer is this scope plus the attention call
 ATTN_PROJ = "gt.attn.proj"
+# a softmax layer over a window (models/parts/window.window_mixer: the attention
+# part's code on the window layers' own heads and rope), inside gt.layers.r<k>,
+# in two disjoint scopes that add up to the mixer, as the linear mixer's: the
+# band (`ops/attention.core_attention(window=)`: the two band kernels, their
+# operands' transposes and the sums of the backward's shares of dk and dv; off a
+# TPU the band mask on XLA's logits) and everything else of it (projections,
+# rope, the gate, the output projection), so that a model's full and window
+# layers read apart. No name begins another
+ATTN_WINDOW = "gt.attn.window"
+ATTN_WINDOW_BAND = "gt.attn.band"
 # the dense MLP half, around its call in models/parts/mlp._dense_forward and NOT
 # inside dense_mlp, which the shared expert calls under MOE_SHARED: an op
 # carries ONE scope nested in its layer run's, so the parts add up. A run's

@@ -14,10 +14,16 @@ transformer.py:306,432,860-892). The parallel forms differ structurally:
 
 Layouts here are (batch, seq, heads, head_dim) ("BSNH"); the pallas kernel
 path transposes to its (batch, heads, seq, head_dim) convention.
+
+Attention over a WINDOW (`core_attention(window=)`: query i sees the keys
+`i - window < j <= i`) has two forms: the band mask on XLA's logits, and on a
+TPU the band kernels of `ops/window_attention.py`, whose grid steps load and
+multiply the blocks the band touches and no others (`_pallas_window`).
 """
 
 from __future__ import annotations
 
+import collections
 import logging
 import math
 from typing import NamedTuple, Optional, Tuple
@@ -26,7 +32,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from galvatron_tpu.ops import window_attention
+
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+# how many windowed attention calls were traced in each form since the process
+# began ("window_pallas" | "window_xla"), as `linear_attention.TOOK`; the
+# trainer's compile report reads the difference (`window_kernel_layers`)
+TOOK = collections.Counter()
 
 
 def repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
@@ -38,9 +51,12 @@ def repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
     return jnp.broadcast_to(k[:, :, :, None, :], (b, s, nkv, n_rep, hd)).reshape(b, s, nkv * n_rep, hd)
 
 
-def _xla_attention(q, k, v, *, causal: bool, sm_scale: float, bias=None, q_offset=0):
+def _xla_attention(q, k, v, *, causal: bool, sm_scale: float, bias=None, q_offset=0, window=None):
     """Einsum attention with fp32 softmax; XLA fuses mask+softmax into the MXU
-    matmuls. `q_offset` shifts the causal mask for cross-shard blocks."""
+    matmuls. `q_offset` shifts the causal mask for cross-shard blocks.
+    `window`: a query sees the `window` keys up to its own, the band
+    `q_pos - window < k_pos <= q_pos` (the whole (sq, sk) logits are made and
+    masked: the CPU's path and the tests' oracle)."""
     b, sq, nh, hd = q.shape
     sk = k.shape[1]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
@@ -50,7 +66,8 @@ def _xla_attention(q, k, v, *, causal: bool, sm_scale: float, bias=None, q_offse
     if causal:
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0) + q_offset
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        logits = jnp.where(q_pos >= k_pos, logits, DEFAULT_MASK_VALUE)
+        seen = q_pos >= k_pos if window is None else (q_pos >= k_pos) & (q_pos - k_pos < window)
+        logits = jnp.where(seen, logits, DEFAULT_MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
@@ -94,6 +111,17 @@ def _pallas_flash(q, k, v, *, causal: bool, sm_scale: float, segment_ids=None):
     return out.transpose(0, 2, 1, 3)
 
 
+def _pallas_window(q, k, v, *, window: int, sm_scale: float):
+    """Windowed causal attention of (B, S, nh, hd) queries on (B, S, nkv, hd)
+    keys and values, nkv dividing nh, through the repo's band kernels
+    (`ops/window_attention.py`: a query block beside the key blocks its band
+    touches, one pass of softmax, k and v fetched once a key head and never
+    repeated), in the kernels' (B, heads, S, hd) layout."""
+    block = window_attention.block_for(q.shape[1], window)
+    qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+    return window_attention.window_attention(qt, kt, vt, window, sm_scale, block).transpose(0, 2, 1, 3)
+
+
 class KernelSharding(NamedTuple):
     """How attention's (B, S, nh, hd) operands are laid out over a mesh:
     batch over ``batch_axes``, heads over ``head_axes``, sequence and head_dim
@@ -126,12 +154,12 @@ class KernelSharding(NamedTuple):
                 and heads % math.prod(shape[a] for a in self.head_axes) == 0)
 
 
-def _sharded_pallas_flash(q, k, v, sharding: KernelSharding, *, causal: bool,
-                          sm_scale: float, segment_ids=None):
-    """`_pallas_flash` per device under a manual region (see KernelSharding).
-    Inside an enclosing manual region (the 1F1B schedule is manual over
-    'pp') shard_map must receive the CONTEXT abstract mesh, whose
-    already-manual axes are typed Manual, as ring_attention does."""
+def _sharded_kernel(kernel, q, k, v, sharding: KernelSharding, segment_ids=None):
+    """`kernel(q, k, v, segment_ids)` per device under a manual region (see
+    KernelSharding): `_pallas_flash` or `_pallas_window`, each device on its
+    own batch rows and heads. Inside an enclosing manual region (the 1F1B
+    schedule is manual over 'pp') shard_map must receive the CONTEXT abstract
+    mesh, whose already-manual axes are typed Manual, as ring_attention does."""
     from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds
 
     bd, hd = sharding.batch_axes or None, sharding.head_axes or None
@@ -142,8 +170,7 @@ def _sharded_pallas_flash(q, k, v, sharding: KernelSharding, *, causal: bool,
         in_specs += [P(bd, None)] * 2
 
     def body(q, k, v, *seg):
-        return _pallas_flash(q, k, v, causal=causal, sm_scale=sm_scale,
-                             segment_ids=SegmentIds(*seg) if seg else None)
+        return kernel(q, k, v, SegmentIds(*seg) if seg else None)
 
     ctx = jax.sharding.get_abstract_mesh()
     use_mesh = sharding.mesh if ctx.empty else ctx
@@ -176,21 +203,27 @@ def padding_bias_to_segment_ids(bias: jax.Array):
 _FALLBACKS_SAID = set()
 
 
-def _say_fallback_once(q_shape, sk: int, biased: bool, splits: bool) -> None:
+def _say_fallback_once(q_shape, sk: int, biased: bool, splits: bool, window=None) -> None:
     """One line a shape when `impl="auto"` leaves the kernel on a TPU although
-    the sequence is in whole tiles: what XLA's form costs there."""
-    key = (tuple(q_shape), sk, biased, splits)
+    the sequence is in whole tiles: what XLA's form costs there. `window`: the
+    call is over a window of that many keys, whose kernels take no bias, no
+    other mask than the causal band and heads of whole 128-lane tiles."""
+    key = (tuple(q_shape), sk, biased, splits, window)
     if key in _FALLBACKS_SAID:
         return
     _FALLBACKS_SAID.add(key)
     b, sq, nh, hd = q_shape
-    why = ("a bias the kernel cannot take as segment ids" if biased and splits else
+    why = ("a bias the window kernels cannot take" if biased and window is not None else
+           "a bias the kernel cannot take as segment ids" if biased and splits else
            "batch or heads the mesh does not divide" if not splits else
+           "head_dim %d (the window kernels compile at multiples of 128)" % hd if window is not None and hd % 128 else
+           "a window too wide for the window kernels' few key blocks a step" if window is not None else
            "head_dim %d (the kernel compiles at 64 and at multiples of 128)" % hd)
     logging.getLogger(__name__).warning(
-        "core_attention: XLA attention on a TPU at q %s, %d keys (%s): it materialises float32 "
-        "logits of (%d, %d, %d, %d), %.2f GiB a call, where the flash kernel holds a block",
-        tuple(q_shape), sk, why, b, nh, sq, sk, b * nh * sq * sk * 4 / 2**30)
+        "core_attention: XLA attention on a TPU at q %s, %d keys%s (%s): it materialises float32 "
+        "logits of (%d, %d, %d, %d), %.2f GiB a call, where the %s kernel holds a block",
+        tuple(q_shape), sk, "" if window is None else ", a window of %d" % window, why,
+        b, nh, sq, sk, b * nh * sq * sk * 4 / 2**30, "flash" if window is None else "window")
 
 
 def core_attention(
@@ -204,9 +237,13 @@ def core_attention(
     impl: str = "auto",
     bias_type: str = "additive",
     sharding: Optional[KernelSharding] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-head attention on (B, S, nh, hd) tensors (kv may have fewer heads:
-    GQA is expanded here). bias_type="key_padding" declares `bias` to be the
+    GQA is expanded here). `window`: causal self-attention in which query i
+    sees the keys `i - window < j <= i`, its own among them (Mistral's and
+    HF's `sliding_window`); `_windowed` picks its form, and a window that
+    reaches the whole sequence is plain causal attention. bias_type="key_padding" declares `bias` to be the
     (B, 1, 1, Sk) 0/-1e9 key-padding bias from padding_attn_bias **of a
     SELF-attention call** (the same padding applies to queries and keys —
     the segment-id lowering reuses the key mask for the query side, which is
@@ -236,6 +273,10 @@ def core_attention(
         )
     if k.shape[2] != q.shape[2]:
         assert q.shape[2] % k.shape[2] == 0, "q heads must be a multiple of kv heads"
+    if window is not None:
+        return _windowed(q, k, v, window=window, causal=causal, sm_scale=sm_scale, bias=bias,
+                         impl=impl, sharding=sharding)
+    if k.shape[2] != q.shape[2]:
         n_rep = q.shape[2] // k.shape[2]
         k = repeat_kv(k, n_rep)
         v = repeat_kv(v, n_rep)
@@ -291,8 +332,43 @@ def core_attention(
                 "attn impl='flash': batch %d / heads %d do not divide over "
                 "mesh axes %s / %s — the kernel cannot run sharded"
                 % (q.shape[0], q.shape[2], sharding.batch_axes, sharding.head_axes))
-        return _sharded_pallas_flash(q, k, v, sharding, causal=causal,
-                                     sm_scale=sm_scale, segment_ids=seg)
+        return _sharded_kernel(
+            lambda q, k, v, seg: _pallas_flash(q, k, v, causal=causal, sm_scale=sm_scale, segment_ids=seg),
+            q, k, v, sharding, seg)
     if impl == "xla":
         return _xla_attention(q, k, v, causal=causal, sm_scale=sm_scale, bias=bias)
     raise ValueError("unknown attention impl %r" % impl)
+
+
+def _windowed(q, k, v, *, window: int, causal: bool, sm_scale: float, bias, impl: str,
+              sharding: Optional[KernelSharding]) -> jax.Array:
+    """`core_attention` over a window, k and v at their own heads. On a TPU
+    the window kernels (`_pallas_window`) wherever they have a form: a causal
+    self-attention call without a bias, the sequence in whole 128-token tiles
+    of which a few reach the window (`window_attention.block_for`), heads of
+    whole 128-lane tiles, and whole batch rows, query heads and key heads a
+    device; the band mask on XLA's logits everywhere else (the CPU;
+    `impl="xla"`), said once a shape where a TPU takes it at a tileable
+    length. Decided by what the call observes, counted in `TOOK`."""
+    if not causal or q.shape[1] != k.shape[1] or window < 1:
+        raise ValueError("a window of %d keys is causal self-attention's (query i sees keys i - window < j <= i); "
+                         "got causal=%s, %d queries on %d keys" % (window, causal, q.shape[1], k.shape[1]))
+    if impl not in ("auto", "flash", "xla"):
+        raise ValueError("unknown attention impl %r" % impl)
+    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
+    if sharding is not None and sharding.mesh.size == 1:
+        sharding = None
+    splits = sharding is None or (sharding.divides(q.shape[0], q.shape[2])
+                                  and sharding.divides(q.shape[0], k.shape[2]))
+    tileable = q.shape[1] % 128 == 0
+    kernel = (impl != "xla" and on_tpu and q.shape[3] % 128 == 0 and bias is None and splits
+              and window_attention.block_for(q.shape[1], window) > 0)
+    if on_tpu and tileable and impl == "auto" and not kernel:
+        _say_fallback_once(q.shape, k.shape[1], bias is not None, splits, window)
+    TOOK["window_pallas" if kernel else "window_xla"] += 1
+    if not kernel:
+        n_rep = q.shape[2] // k.shape[2]
+        return _xla_attention(q, repeat_kv(k, n_rep), repeat_kv(v, n_rep), causal=True, sm_scale=sm_scale,
+                              bias=bias, window=window)
+    run = lambda q, k, v, _seg=None: _pallas_window(q, k, v, window=window, sm_scale=sm_scale)  # noqa: E731
+    return run(q, k, v) if sharding is None else _sharded_kernel(run, q, k, v, sharding)

@@ -1685,7 +1685,7 @@ def mixer_form(x: jax.Array, taps: jax.Array, layout: Layout, *, impl: str = "au
 def _rows_a_device(rule, sharding, operands, weights, out_ranks):
     """`rule(*operands)` a device on its own rows of the batch, under a manual
     region (GSPMD cannot partition a Mosaic kernel; see `KernelSharding` and
-    `ops/attention._sharded_pallas_flash`, whose pattern this is); as it is
+    `ops/attention._sharded_kernel`, whose pattern this is); as it is
     where `_on_kernels` found one device (`sharding` None). `weights`: which
     operands lie whole on every device; `out_ranks`: the results' ranks, each
     over the batch."""

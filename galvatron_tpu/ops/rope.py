@@ -7,17 +7,58 @@ array (B, S) through the same shardings as the tokens — each shard then holds
 exactly its global positions, including zigzag CP layouts, with no
 rank-arithmetic in model code."""
 
+import math
+
 import jax.numpy as jnp
 
+# the numbers a yarn scaling states beside `rope_type` (HF `rope_parameters`); theta and the
+# rotary share are the model's own fields
+YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "attention_factor")
 
-def rope_frequencies(head_dim: int, theta: float = 10000.0):
-    """Inverse frequencies, shape (head_dim//2,)."""
+
+def checked_scaling(scaling):
+    """`scaling` as `rope_frequencies` takes it, or a ValueError that names
+    what it has no form of: None, or a mapping whose `rope_type` is "yarn" with
+    yarn's numbers (`YARN_KEYS`) and nothing else."""
+    if scaling is None:
+        return None
+    kind = scaling.get("rope_type")
+    if kind != "yarn":
+        raise ValueError("rope_scaling rope_type=%r has no form here: ops/rope.py knows \"yarn\" "
+                         "(and no scaling at all, rope_scaling=None)" % (kind,))
+    unknown, missing = sorted(set(scaling) - set(YARN_KEYS) - {"rope_type"}), sorted(set(YARN_KEYS) - set(scaling))
+    if unknown or missing:
+        raise ValueError("a yarn rope_scaling states %s; missing %r, not modelled %r"
+                         % (", ".join(YARN_KEYS), missing, unknown))
+    return scaling
+
+
+def rope_frequencies(head_dim: int, theta: float = 10000.0, scaling=None):
+    """Inverse frequencies, shape (head_dim//2,). `scaling`: yarn
+    (arXiv:2309.00071, as HF's `_compute_yarn_parameters`): frequency i is
+    `theta^(-2i/d)` where it turns more than `beta_fast` times over the
+    original context, that divided by `factor` where it turns fewer than
+    `beta_slow` times, and a linear ramp between the two dims in between
+    (floor and ceil of the correction dims)."""
     exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta ** exponents)
+    inv_freq = 1.0 / (theta ** exponents)
+    if checked_scaling(scaling) is None:
+        return inv_freq
+
+    def correction_dim(turns):
+        return (head_dim * math.log(scaling["original_max_position_embeddings"] / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(scaling["beta_slow"])), head_dim - 1)
+    if low == high:
+        high += 0.001  # HF's guard against a ramp of no width
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    return inv_freq * (1.0 - ramp) + inv_freq / scaling["factor"] * ramp
 
 
 def apply_rotary(x, positions, theta: float = 10000.0, interleaved: bool = False,
-                 rotary_dim=None):
+                 rotary_dim=None, scaling=None):
     """Rotate (B, S, n_heads, head_dim) by per-token positions (B, S).
 
     `interleaved=False` is the HF/LLaMA half-split convention
@@ -25,16 +66,20 @@ def apply_rotary(x, positions, theta: float = 10000.0, interleaved: bool = False
     fp32 math, result cast back to x.dtype. `rotary_dim` (HF's
     `partial_rotary_factor` x head_dim): the leading `rotary_dim` dims of a
     head are rotated, at the frequencies of a head of that size, and the
-    rest pass as they are."""
+    rest pass as they are. `scaling` (yarn, `rope_frequencies`): the scaled
+    frequencies, and cos and sin x its `attention_factor`, so the turned dims
+    alone carry that factor."""
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
-        turned = apply_rotary(x[..., :rotary_dim], positions, theta, interleaved)
+        turned = apply_rotary(x[..., :rotary_dim], positions, theta, interleaved, scaling=scaling)
         return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     dtype = x.dtype
     head_dim = x.shape[-1]
-    inv_freq = rope_frequencies(head_dim, theta)
+    inv_freq = rope_frequencies(head_dim, theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # (B,S,hd/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaling is not None:
+        cos, sin = cos * scaling["attention_factor"], sin * scaling["attention_factor"]
     x32 = x.astype(jnp.float32)
     if interleaved:
         x1 = x32[..., 0::2]

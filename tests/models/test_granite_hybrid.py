@@ -154,7 +154,7 @@ def test_a_pattern_given_as_a_list_gives_the_three_runs():
     with pytest.raises(ValueError, match="layer_types"):
         tiny(layer_types=["mamba"] * 3)
     with pytest.raises(ValueError, match="layer_types"):
-        tiny(layer_types=["mamba"] * 9 + ["window"])
+        tiny(layer_types=["mamba"] * 9 + ["windowed"])
     with pytest.raises(ValueError, match="state-space layers"):
         tiny(ssm_state_dim=0)
 

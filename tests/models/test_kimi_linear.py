@@ -167,7 +167,7 @@ def test_the_config_refuses_what_cannot_run_and_no_more():
     with pytest.raises(ValueError, match="layer_types"):
         tiny(layer_types=["kda"] * 3)
     with pytest.raises(ValueError, match="layer_types"):
-        tiny(layer_types=["kda"] * 4 + ["window"])
+        tiny(layer_types=["kda"] * 4 + ["windowed"])
     with pytest.raises(ValueError, match="equal under \"kda\""):
         tiny(linear_num_key_heads=2)
     with pytest.raises(ValueError, match="no multi-token-prediction module"):
@@ -339,7 +339,7 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
 
 # ------------------------------------------------ the table, FLOPs, the counters
 def test_one_table_maps_the_kda_mixer_to_what_it_brings():
-    assert set(M.MIXERS) == {"attention", "linear", "ssm", "kda", "conv"}
+    assert set(M.MIXERS) == {"attention", "linear", "ssm", "kda", "conv", "window"}
     assert M.MIXERS["kda"].scopes == (tracing.ATTN_KDA, tracing.ATTN_KDA_RULE) == (
         "gt.attn.kda_mixer", "gt.attn.kda_rule")
     assert not any(a != b and a.startswith(b) for a in M.MIXERS["kda"].scopes for b in M.MIXERS["kda"].scopes)

@@ -159,7 +159,7 @@ def test_the_config_refuses_what_cannot_run_and_no_more():
     with pytest.raises(ValueError, match="short_conv_kernel, the taps .* of 1 or more; got 0"):
         tiny(short_conv_kernel=0)
     with pytest.raises(ValueError, match="layer_types names the mixer, one of .*\"conv\""):
-        tiny(layer_types=["conv"] * 4 + ["window"])
+        tiny(layer_types=["conv"] * 4 + ["windowed"])
     with pytest.raises(ValueError, match="layer_types"):
         tiny(layer_types=["conv"] * 3)
     # a routed half under a convolution mixer is this model: not refused (the state-space part refuses one)

@@ -18,6 +18,7 @@ from galvatron_tpu.models.glm4_moe_lite import glm4_moe_lite_config
 from galvatron_tpu.models.gpt import gpt_config
 from galvatron_tpu.models.granite_hybrid import granite_hybrid_config
 from galvatron_tpu.models.kimi_linear import kimi_linear_config
+from galvatron_tpu.models.laguna import laguna_config
 from galvatron_tpu.models.lfm2_moe import lfm2_moe_config
 from galvatron_tpu.models.llama import llama_config
 from galvatron_tpu.models.olmoe import olmoe_config
@@ -43,6 +44,8 @@ BUILT_OF = {
     ("MIXERS", "ssm"): dict(DENSE, layer_types=["mamba", "attention"], ssm_num_heads=4, ssm_head_dim=16,
                             ssm_state_dim=8, ssm_conv_kernel=4),
     ("MIXERS", "conv"): dict(DENSE, layer_types=["conv", "attention"], short_conv_kernel=3),
+    ("MIXERS", "window"): dict(DENSE, layer_types=["sliding_attention", "full_attention"], sliding_window=8,
+                               window_num_heads=8, head_dim=16),
     ("MLP_HALVES", "dense"): DENSE,
     ("MLP_HALVES", "routed"): dict(DENSE, num_experts=4, experts_per_token=2),
 }
@@ -108,7 +111,7 @@ def test_a_config_is_told_of_its_own_parts_and_of_no_others():
 
 FAMILIES = {"llama": llama_config, "gpt": gpt_config, "olmoe": olmoe_config, "glm4_moe_lite": glm4_moe_lite_config,
             "qwen3_next": qwen3_next_config, "granite_hybrid": granite_hybrid_config,
-            "kimi_linear": kimi_linear_config, "lfm2_moe": lfm2_moe_config}
+            "kimi_linear": kimi_linear_config, "lfm2_moe": lfm2_moe_config, "laguna": laguna_config}
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -135,6 +138,10 @@ def test_a_configs_validate_clauses_are_exactly_its_entries(family, monkeypatch)
     (("MIXERS", "ssm"), dict(num_experts=4, experts_per_token=2), "a dense MLP half"),
     (("MIXERS", "ssm"), dict(ssm_state_dim=0), "state-space layers want"),
     (("MIXERS", "conv"), dict(short_conv_kernel=0), "short-convolution layers want short_conv_kernel"),
+    (("MIXERS", "window"), dict(sliding_window=0), "window layers want sliding_window"),
+    (("MIXERS", "window"), dict(rope_scaling={"rope_type": "llama3", "factor": 8.0}), "rope_type='llama3' has no form"),
+    (("MIXERS", "window"), dict(rope_scaling={"rope_type": "yarn", "factor": 8.0}), "a yarn rope_scaling states"),
+    (("MIXERS", "window"), dict(attn_head_gate=True, attn_output_gate=True, num_kv_heads=2), "attn_head_gate"),
 ], ids=lambda v: "-".join(v) if isinstance(v, tuple) else None)
 def test_an_entrys_clause_raises_at_construction_in_its_words(entry, fields, words):
     with pytest.raises(ValueError, match=re.escape(words)):
@@ -146,7 +153,7 @@ def test_the_pattern_is_asked_one_way_however_it_is_stated():
     list-stated Granite and Kimi configs is what `layer_kinds()` implies, a
     key of `MIXERS` a layer, and never None."""
     for cfg in (qwen3_next_config(), granite_hybrid_config(), kimi_linear_config(), lfm2_moe_config(),
-                llama_config()):
+                laguna_config(), llama_config()):
         implied = tuple(kind.rpartition(".")[0] or "attention" for kind in cfg.layer_kinds())
         assert cfg.mixers() == implied and len(implied) == cfg.num_layers and set(implied) <= set(parts.MIXERS)
         assert tuple(kind.rpartition(".")[2] for kind in cfg.layer_kinds()) == cfg.mlp_halves()
@@ -161,31 +168,50 @@ def test_the_pattern_is_asked_one_way_however_it_is_stated():
     assert set(granite_hybrid_config().mixers()) == {"ssm", "attention"}
     assert set(kimi_linear_config().mixers()) == {"kda", "attention"}
     assert set(lfm2_moe_config().mixers()) == {"conv", "attention"}
+    assert laguna_config().mixers()[:5] == ("attention", "window", "window", "window", "attention")
+
+
+def test_a_window_layers_config_states_its_own_heads_and_rope_as_the_ordinary_fields():
+    """`layer_config("window.*")` hands the part the fields every part reads:
+    the window layers' heads, rope base and rotary share in `num_heads`,
+    `rope_theta`, `partial_rotary_factor`, and no scaling; the full layers keep
+    the model's."""
+    cfg = laguna_config()
+    window, full = cfg.layer_config("window.routed"), cfg.layer_config("routed")
+    assert (window.num_heads, window.rope_theta, window.rotary_dim, window.rope_scaling) == (64, 1e4, 128, None)
+    assert (full.num_heads, full.rope_theta, full.rotary_dim) == (48, 5e5, 64) and full.rope_scaling["factor"] == 64
+    assert (window.window_num_heads, window.window_rope_theta, window.window_partial_rotary_factor) == (None,) * 3
+    assert window.sliding_window == 512 and window.layer_config("window.routed") == window  # settled: asked again, the same
+    same = TransformerConfig(**{**BUILT_OF[("MIXERS", "window")], "window_num_heads": None})
+    assert same.layer_config("window.dense").num_heads == same.num_heads  # None: the full layers'
 
 
 def test_every_key_of_the_mixers_table_is_a_word_of_layer_types():
-    """The allowed words are read off `MIXERS` (plus HF's "mamba"): a part
-    added to the table is accepted with no clause added, and the refusal names
-    them all."""
+    """The allowed words are read off `MIXERS` (plus HF's "mamba",
+    "full_attention" and "sliding_attention"): a part added to the table is
+    accepted with no clause added, and the refusal names them all."""
     fields = {**DELTA, "ssm_num_heads": 4, "ssm_head_dim": 16, "ssm_state_dim": 8, "ssm_conv_kernel": 4,
-              "short_conv_kernel": 3}
+              "short_conv_kernel": 3, "sliding_window": 8}
     for key in parts.MIXERS:
         cfg = TransformerConfig(**DENSE, layer_types=[key, "attention"], **fields)
         assert cfg.mixers() == (key, "attention")
     assert TransformerConfig(**DENSE, layer_types=["mamba", "attention"], **fields).mixers() == ("ssm", "attention")
+    assert TransformerConfig(**DENSE, layer_types=["sliding_attention", "full_attention"], **fields).mixers() == (
+        "window", "attention")
     with pytest.raises(ValueError) as refused:
-        TransformerConfig(**DENSE, layer_types=["window", "attention"])
-    assert all('"%s"' % key in str(refused.value) for key in list(parts.MIXERS) + ["mamba"])
+        TransformerConfig(**DENSE, layer_types=["windowed", "attention"])
+    assert all('"%s"' % key in str(refused.value)
+               for key in list(parts.MIXERS) + ["mamba", "full_attention", "sliding_attention"])
 
 
 def test_the_stack_looks_two_tables_up_and_names_no_part():
     named = re.compile(r"cfg\.(routed|num_experts|kv_lora_rank|latent_attention|experts_held|num_shared_experts)"
-                       r"|\"(attention|linear|kda|ssm|conv|dense|routed)\"")
+                       r"|\"(attention|linear|kda|ssm|conv|window|dense|routed)\"")
     for fn in (M.init_layer_params, M.layer_forward, M.decode_layer_forward, M.layer_param_specs, M.run_layers):
         assert not named.search(inspect.getsource(fn)), fn.__name__
     assert M.MIXERS is parts.MIXERS and M.MLP_HALVES is parts.MLP_HALVES
     for name in ("config", "parts", "parts.common", "parts.attention", "parts.linear", "parts.kda", "parts.ssm",
-                 "parts.conv", "parts.mlp", "parts.embed_head"):
+                 "parts.conv", "parts.window", "parts.mlp", "parts.embed_head"):
         module = __import__("galvatron_tpu.models." + name, fromlist=["_"])
         assert "models.base" not in inspect.getsource(module) and "models import base" not in inspect.getsource(module)
     for table in (parts.MIXERS, parts.MLP_HALVES):  # one shape
@@ -202,6 +228,7 @@ PARENT_DIGESTS = {  # `runtime/elastic.model_config_digest` of each preset at PR
     "granite_hybrid": "82647523215eb425ccb9ffb27a38a68a9bdf5fef23e2b4ee0f9a0afb8cc90ce5",
     "kimi_linear": "8496d81e3a950a98fbb9bd903551c061efa97fb682a1b6f1b6b1ed90f15c906c",
     "lfm2_moe": "de9ed240051e89d77e8e5b02f0ed783e50ee33e889aa3980ea5f0b3f90a0b4e2",  # as PR 46 added it
+    "laguna": "b640e1d156ab41948e3d265bf1ad4e85342f59441181a4135ed58d26fb95824f",  # as PR 49 added it
     # the encoder families, and the two whose config is a dataclass of its own, every field of which is digested
     "bert": "4c98cec753b063daeaaf8da44a24222855ba7c2ec2d4a2fe0221dbaf09a84acd",
     "vit": "88ccd90b3fe6e70442f6dd71d7b08b0f6371acd5f950f9e079856277c18e3319",

@@ -1,6 +1,9 @@
 """Attention op correctness: ring attention vs dense reference, zigzag layout,
 GQA, rope."""
 
+import collections
+import unittest.mock as mock
+
 import numpy as np
 import pytest
 
@@ -553,3 +556,135 @@ def test_auto_dispatch_takes_the_kernel_at_head_dim_64_on_a_tpu_and_says_a_fallb
     caplog.clear()
     A.core_attention(*narrow, causal=True)
     assert not caplog.records
+
+
+# ------------------------------------------------------- a window of keys (Laguna)
+def _per_token_window(q, k, v, window, scale):
+    """Query i on the keys i - window < j <= i, a loop a batch row, head and token, float64."""
+    q, k, v = (np.asarray(t, np.float64) for t in (q, k, v))
+    b, s, nh, hd = q.shape
+    group = nh // k.shape[2]
+    out = np.zeros_like(q)
+    for row in range(b):
+        for h in range(nh):
+            for i in range(s):
+                first = max(0, i - window + 1)
+                scores = k[row, first:i + 1, h // group] @ q[row, i, h] * scale
+                p = np.exp(scores - scores.max())
+                out[row, i, h] = (p / p.sum()) @ v[row, first:i + 1, h // group]
+    return out
+
+
+@pytest.mark.parametrize("window", [1, 5, 16, 40, 41, 64])
+def test_the_band_is_the_per_token_loop_and_a_window_of_the_whole_sequence_is_causal(window):
+    """GQA 4 on 2 over 40 tokens: the band mask against a loop a token (a
+    window of 1 is the token's own value), and at 40 keys and more plain causal
+    attention, bit for bit the same logits."""
+    from galvatron_tpu.ops import attention as A
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(7), b=2, s=40, nh=4, nkv=2, hd=16)
+    with jax.default_matmul_precision("highest"):
+        got = A.core_attention(q, k, v, window=window, sm_scale=0.4)
+        causal = A.core_attention(q, k, v, causal=True, sm_scale=0.4, impl="xla")
+    np.testing.assert_allclose(np.asarray(got), _per_token_window(q, k, v, window, 0.4), atol=2e-5)
+    if window == 1:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(A.repeat_kv(v, 2)), atol=1e-6)
+    if window >= 40:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(causal))
+    else:
+        assert float(jnp.max(jnp.abs(got - causal))) > 1e-3
+
+
+def test_a_window_is_causal_self_attentions_and_counts_its_form():
+    from galvatron_tpu.ops import attention as A
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(8), b=1, s=32, nh=2, hd=16)
+    with pytest.raises(ValueError, match="a window of 4 keys is causal self-attention's"):
+        A.core_attention(q, k, v, causal=False, window=4)
+    with pytest.raises(ValueError, match="32 queries on 16 keys"):
+        A.core_attention(q, k[:, :16], v[:, :16], window=4)
+    with pytest.raises(ValueError, match="a window of 0 keys"):
+        A.core_attention(q, k, v, window=0)
+    before = collections.Counter(A.TOOK)
+    A.core_attention(q, k, v, window=4)
+    A.core_attention(q, k, v, window=4, impl="flash")  # off a TPU the kernels have no form: the band mask
+    assert A.TOOK - before == {"window_xla": 2}
+    A.core_attention(q, k, v, causal=True)  # no window: not counted
+    assert A.TOOK - before == {"window_xla": 2}
+
+
+@pytest.mark.parametrize("window,block", [(160, 128), (128, 128), (129, 128), (300, 128), (64, 256), (1, 128), (600, 256)])
+def test_the_window_kernels_are_the_band_mask(window, block):
+    """GQA 4 on 2 heads of 128 at 512 tokens: the repo's band kernels
+    (`ops/window_attention.py`, interpret mode), forward and the three
+    gradients against the band mask on XLA's logits in float32, at windows
+    that end on a block's edge (128), one past it (129), inside a block, over
+    three blocks before the query's own (300 at 128) and wider than the
+    sequence (600: plain causal attention)."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    from galvatron_tpu.ops import attention as A
+    from galvatron_tpu.ops import window_attention as W
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(9), b=2, s=512, nh=4, nkv=2, hd=128)
+    scale = 0.05
+    assert W.block_for(512, window, block) == block
+
+    def grads(kernel):
+        def f(q, k, v):
+            if kernel:
+                out = W.window_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)), window, scale, block)
+                return jnp.sum(jnp.sin(out.transpose(0, 2, 1, 3))), out.transpose(0, 2, 1, 3)
+            out = A.core_attention(q, k, v, window=window, sm_scale=scale, impl="xla")
+            return jnp.sum(jnp.sin(out)), out
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
+        got, want = grads(True), grads(False)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=3e-5)
+
+
+def test_the_window_kernels_block_reaches_the_window_in_a_few_key_blocks():
+    from galvatron_tpu.ops import window_attention as W
+
+    assert W.BLOCK == 512 and W.block_for(8192, 512) == 512 and W.block_for(16384, 512) == 512
+    assert W.block_for(8192 + 256, 512) == 256 and W.block_for(8192 + 128, 200) == 128  # the largest that divides
+    assert W.block_for(8192, 512 * 3 + 1) == 512 and W.block_for(8192, 512 * 3 + 2) == 0  # three blocks before its own
+    assert W.block_for(8192 + 128, 512) == 0  # 128-token blocks would need four before their own
+    assert W.block_for(100, 16) == 0  # no whole 128-token tile
+
+
+def test_auto_dispatch_takes_the_window_kernels_on_a_tpu_and_says_a_fallback_once(caplog):
+    """On a TPU at a tileable length and heads of 128 `impl="auto"` takes the
+    window kernels with k and v at their OWN heads; what falls back (heads of
+    64) is logged, once a shape, with the window it names."""
+    import logging
+
+    from galvatron_tpu.ops import attention as A
+    from galvatron_tpu.ops import window_attention
+
+    calls = []
+
+    def spy(q_, k_, v_, **kw):
+        calls.append((q_.shape[2], k_.shape[2], kw["window"]))
+        return A._xla_attention(q_, A.repeat_kv(k_, 2), A.repeat_kv(v_, 2), causal=True, sm_scale=kw["sm_scale"],
+                                window=kw["window"])
+
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(5), b=1, s=256, nh=4, nkv=2, hd=128)
+    narrow = _rand_qkv(jax.random.PRNGKey(6), b=1, s=256, nh=4, nkv=2, hd=64)
+    A._FALLBACKS_SAID.clear()
+    before = collections.Counter(A.TOOK)
+    with mock.patch.object(A, "_pallas_window", spy), \
+         mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+         caplog.at_level(logging.WARNING, logger=A.__name__):
+        out = A.core_attention(q, k, v, window=32)
+        for _ in range(2):
+            A.core_attention(*narrow, window=32)
+        A.core_attention(q, k, v, window=32, impl="xla")  # asked for: not a fallback, nothing said
+    assert calls == [(4, 2, 32)] and A.TOOK - before == {"window_pallas": 1, "window_xla": 3}
+    assert window_attention.block_for(256, 32) == 256
+    np.testing.assert_allclose(np.asarray(out), np.asarray(A.core_attention(q, k, v, window=32, impl="xla")), atol=2e-5)
+    said = [r.getMessage() for r in caplog.records if "XLA attention on a TPU" in r.getMessage()]
+    assert len(said) == 1 and "a window of 32" in said[0] and "head_dim 64" in said[0] and "window kernel" in said[0]

@@ -867,3 +867,66 @@ def test_the_kda_layers_surround_is_lane_aligned_passes_on_v5e(kimi_kda_layer):
                 or (over_tokens and kind in ("slice", "dynamic-slice", "pad", "concatenate"))):
             offenders.append("%s = %s %s" % (name, result[:80], kind))
     assert not offenders, "\n".join(offenders)
+
+
+# ------------------------------------------------- the window kernels (Laguna)
+FLASH_PATTERNS = (r"^flash_attention[.:]", r"^flash_mha_bwd_dkv", r"^flash_mha_bwd_dq")  # benchmarks/layer_metrics/flash_ms.py
+
+
+def _window_loss(sharding):
+    def loss(q, k, v):
+        with jax.named_scope("gt.layers.r1"):  # as in the step: the kernels' calls lie inside a run's scope
+            out = A.core_attention(q, k, v, window=512, sharding=sharding)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    return loss
+
+
+def _custom_calls(text):
+    """The names of a compiled program's Mosaic calls, as the trace labels them."""
+    return sorted(line.split("=")[0].strip().lstrip("%") for line in text.splitlines()
+                  if "custom_call_target=\"tpu_custom_call\"" in line)
+
+
+@pytest.mark.parametrize("tokens", [8192, 16384])
+def test_the_window_kernels_compile_at_the_cells_shapes_for_v5e(v5e_2x2, tokens):
+    """64 query heads on 8 KV heads of 128 under a window of 512, the Laguna
+    cell's window layers (and at twice their tokens, scripts/laguna_chip_check.py's
+    16384), through `impl="auto"`: two Mosaic calls, forward and backward, whose
+    names NONE of `flash_ms`'s three patterns match (or `flash_roofline` would
+    price a band as a causal triangle), each on ONE line of the compiled text
+    with its `op_name` (the benchmark's trace reader reads an instruction's
+    first line: a kernel with `metadata=`, as jax's splash kernels, loses its
+    scope there), k and v at their own 8 heads, and under a tenth of the
+    temporaries a repeat of k and v to 64 heads would take."""
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((1, tokens, 64, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, tokens, 8, 128), jnp.bfloat16, sharding=one)
+    before = collections.Counter(A.TOOK)
+    fn = jax.grad(_window_loss(A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("x",)))), argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    names = _custom_calls(text)
+    assert [n.rsplit(".", 1)[0] for n in names] == ["window_attn_bwd", "window_attn_fwd"]
+    assert not any(re.search(rx, name) for rx in FLASH_PATTERNS for name in names)
+    for line in text.splitlines():
+        if "custom_call_target=\"tpu_custom_call\"" in line:
+            assert "gt.layers.r1" in re.search(r'op_name="([^"]*)"', line).group(1)
+    assert A.TOOK - before == {"window_pallas": 1}
+    # q, its cotangent's float32 square, the transposes and the backward's float32 shares of dk and dv
+    # (4 x 32 MiB at 8192): no 64-head copy of k or v (2 x 128 MiB at 8192)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30 * tokens / 8192
+
+
+def test_the_window_kernels_run_in_a_manual_region_on_a_dp4_mesh(v5e_2x2):
+    """Four sequences over four chips (dp with ZeRO runs the family): each chip
+    its own row through the kernels, no collective."""
+    mesh = Mesh(np.array(v5e_2x2).reshape(1, 4), ("pp", "m0"))
+    sh = NamedSharding(mesh, P("m0", None, None, None))
+    q = jax.ShapeDtypeStruct((4, 2048, 16, 128), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((4, 2048, 4, 128), jnp.bfloat16, sharding=sh)
+    fn = jax.grad(_window_loss(A.KernelSharding(mesh, ("m0",), ())), argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    assert len(_custom_calls(text)) == 2
+    for collective in ("all-gather", "all-reduce", "all-to-all", "collective-permute"):
+        assert collective not in text, collective
