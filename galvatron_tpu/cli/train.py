@@ -540,6 +540,7 @@ def _train(args) -> dict:
             kda_rule = delta_rule_took["kda_xla"] or delta_rule_took["kda_pallas"]
             linear_layers = sum(kind.startswith("linear") for kind in cfg.layer_kinds()) if scalar_rule else 0
             kda_layers = sum(kind.startswith("kda") for kind in cfg.layer_kinds()) if kda_rule else 0
+            window_layers = sum(kind.startswith("window") for kind in cfg.layer_kinds()) if windows_took else 0
             telemetry.emit(
                 "compile",
                 trace_ms=(t1 - t0) * 1e3,
@@ -581,9 +582,12 @@ def _train(args) -> dict:
                 # the window attention layers whose band the step runs as Pallas
                 # kernels (`ops/attention._windowed`): all or none, the layers
                 # being alike; absent where the step traced no window layer
-                window_kernel_layers=(
-                    sum(kind.startswith("window") for kind in cfg.layer_kinds()) * (not windows_took["window_xla"])
-                    if windows_took else None),
+                window_kernel_layers=window_layers * (not windows_took["window_xla"]) if windows_took else None,
+                # and those of them whose kernels read q where the projection
+                # wrote it, rope and the head's gate fused: all or none again;
+                # 0 off a TPU and where the step traced no window layer
+                window_operands_as_projected=window_layers * (
+                    windows_took["window_as_projected"] == windows_took["window_pallas"] > 0),
                 # the gated (hidden, 2, ffn) kernels the step read through
                 # `parts/mlp.grad_as_stored`, as traced (`models/base.run_layers`):
                 # all of a model's or none; 0 off a TPU, where every such layer

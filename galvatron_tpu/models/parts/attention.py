@@ -14,9 +14,9 @@ from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.models.parts.common import (LayerPart, Params, _dense, _dense_init, _norm, _norm_scale,
                                                _proj_std)
 from galvatron_tpu.obs import tracing
-from galvatron_tpu.ops.attention import KernelSharding, core_attention
+from galvatron_tpu.ops.attention import KernelSharding, core_attention, window_takes_kernels
 from galvatron_tpu.ops.norms import rms_norm
-from galvatron_tpu.ops.rope import apply_rotary, checked_scaling
+from galvatron_tpu.ops.rope import apply_rotary, checked_scaling, half_split_tables
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import LayerAxes
 
@@ -118,11 +118,35 @@ def _init_attention(ks, cfg: TransformerConfig) -> Params:
     return p
 
 
-def qkv_projection(p: Params, y: jax.Array, cfg: TransformerConfig, dtype):
-    """y: (B, S, H) -> q (B,S,nh,hd), k/v (B,S,nkv,hd)."""
+@jax.custom_vjp
+def _written_out(x: jax.Array) -> jax.Array:
+    """x, as an array of its own in the forward (an optimization barrier; the
+    cotangent passes as it comes): what reads it cannot fold x's producer into
+    its operand."""
+    return jax.lax.optimization_barrier(x)
 
-    def proj(pk):
-        out = jnp.einsum("bsh,h...->bs...", y, pk["kernel"].astype(dtype))
+
+_written_out.defvjp(lambda x: (jax.lax.optimization_barrier(x), None), lambda _, g: (g,))
+
+
+def qkv_projection(p: Params, y: jax.Array, cfg: TransformerConfig, dtype, flat_q: bool = False):
+    """y: (B, S, H) -> q (B,S,nh,hd), k/v (B,S,nkv,hd). `flat_q`: q's matmul
+    on its kernel as a (H, nh x hd) matrix, the same numbers: what it writes is
+    the (B, S, nh x hd) array the window kernels read as it lies, and their dq
+    enters the backward's matmuls as they wrote it (a contraction over (nh, hd)
+    against the (H, nh, hd) kernel relays a q-sized dq first, on a TPU). The
+    flat bf16 kernel is written out once (32 MiB, 0.09 ms at Laguna's widths):
+    left to itself XLA:TPU folds the kernel's relayout into the operand of the
+    forward's matmul and of dx's, which then run at 71 and 54 % of the MXU
+    where they reach 93 (PERF.md section 6, PR 50)."""
+
+    def proj(pk, flat=False):
+        kernel = pk["kernel"]
+        if flat:  # (the stored kernel is reshaped, then cast: the other order transposes dq for dW, on a TPU)
+            flat_kernel = _written_out(kernel.reshape(kernel.shape[0], -1).astype(dtype))
+            out = jnp.einsum("bsh,hk->bsk", y, flat_kernel).reshape(y.shape[:2] + kernel.shape[1:])
+        else:
+            out = jnp.einsum("bsh,h...->bs...", y, kernel.astype(dtype))
         if "bias" in pk:
             out = out + pk["bias"].astype(dtype)
         return out
@@ -130,7 +154,7 @@ def qkv_projection(p: Params, y: jax.Array, cfg: TransformerConfig, dtype):
     if cfg.fused_qkv:
         qkv = proj(p["wqkv"])  # (B, S, 3, nh, hd)
         return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = proj(p["wq"])
+    q = proj(p["wq"], flat_q)
     kv = proj(p["wkv"])  # (B, S, 2, nkv, hd)
     return q, kv[:, :, 0], kv[:, :, 1]
 
@@ -199,7 +223,11 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
     all-to-all inserted by XLA — the hand-written collectives of reference
     transformer.py:1928-2177). `scope`, `window`: the window part's call
     (`parts/window.py`), everything but the attention call under a scope of
-    its own and the call over a window of so many keys."""
+    its own and the call over a window of so many keys. Where that call runs as
+    the window kernels (`ops/attention.window_takes_kernels`) they read q AS
+    PROJECTED: q's rope, where it is the half-split turn of whole heads at the
+    plain frequencies, and the head's gate are the kernels' (`q_rope`,
+    `head_gate`) and make no pass of their own."""
     dtype = cfg.compute_dtype
     if cfg.position_type == "rope" and mesh is not None and axes is not None:
         # Pin positions to THIS layer's sharding so each layer derives its
@@ -215,7 +243,7 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
     # one scope for everything of the mixer but the attention call: a block
     # before it and a block after it
     scope = scope or (tracing.ATTN_LATENT if cfg.latent_attention else tracing.ATTN_PROJ)
-    gate, sm_scale = None, cfg.attention_multiplier
+    gate, sm_scale, q_rope, head_gate = None, cfg.attention_multiplier, None, None
     with jax.named_scope(scope):
         if cfg.latent_attention:
             q, k, v = latent_qkv_projection(p, y, pin(positions), cfg, dtype)
@@ -225,17 +253,27 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
             q, k, v = (t if t.shape[-1] == cfg.head_dim else jnp.pad(
                 t, ((0, 0),) * 3 + ((0, cfg.head_dim - t.shape[-1]),)) for t in (q, k, v))
         else:
-            q, k, v = qkv_projection(p, y, cfg, dtype)
+            # a window's call as the window kernels: they read q where the matmul wrote it
+            kernels = window is not None and window_takes_kernels(
+                y.shape[:2] + (cfg.num_heads, cfg.head_dim), y.shape[:2] + (cfg.num_kv_heads, cfg.head_dim),
+                window=window, biased=attn_bias is not None, impl=cfg.attn_impl, sharding=attn_sharding)
+            q, k, v = qkv_projection(p, y, cfg, dtype, flat_q=kernels)
             if cfg.attn_output_gate:
                 q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
-            elif cfg.attn_head_gate:  # (B, S, nh, 1): one gate for all of a head's dims
+            elif cfg.attn_head_gate and kernels:  # (B, S, nh) logits: one gate for all of a head's dims
+                head_gate = _dense(y, p["wg"], dtype)
+            elif cfg.attn_head_gate:
                 gate = _dense(y, p["wg"], dtype)[..., None]
             if cfg.qk_norm:
                 q, k = qk_normed(p, q, k, cfg)
             if cfg.position_type == "rope":
                 positions = pin(positions)
-                q = apply_rotary(q, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim, scaling=cfg.rope_scaling)
-                k = apply_rotary(k, positions, cfg.rope_theta, rotary_dim=cfg.rotary_dim, scaling=cfg.rope_scaling)
+                how = dict(rotary_dim=cfg.rotary_dim, scaling=cfg.rope_scaling)
+                if kernels:  # None where the rotation is no product with two tables: q is turned here as ever
+                    q_rope = half_split_tables(positions, cfg.head_dim, cfg.rope_theta, **how)
+                if q_rope is None:
+                    q = apply_rotary(q, positions, cfg.rope_theta, **how)
+                k = apply_rotary(k, positions, cfg.rope_theta, **how)
     if mesh is not None and axes is not None and len(axes.tp) + len(axes.cp) > 0:
         # (B, S/x, nh, hd) -> (B, S/cp, nh/tp, hd): XLA inserts the all-to-all
         # (ulysses) or all-gather+split (megatron-sp) when seq was tp-sharded.
@@ -262,7 +300,8 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
         with jax.named_scope(tracing.ATTN_WINDOW_BAND) if window is not None else contextlib.nullcontext():
             attn = core_attention(q, k, v, causal=cfg.causal, bias=attn_bias,
                                   impl=cfg.attn_impl, bias_type="key_padding",
-                                  sharding=attn_sharding, sm_scale=sm_scale, window=window)
+                                  sharding=attn_sharding, sm_scale=sm_scale, window=window,
+                                  q_rope=q_rope, head_gate=head_gate)
     with jax.named_scope(scope):
         if gate is not None:
             attn = attn * jax.nn.sigmoid(gate)

@@ -403,6 +403,9 @@ def render(analysis: Dict[str, Any]) -> str:
             lines.append("layers whose token mixer is a gated short convolution: %d" % comp["shortconv_layers"])
         if "window_kernel_layers" in comp:
             lines.append("window attention layers whose band runs as Pallas kernels: %d" % comp["window_kernel_layers"])
+        if "window_kernel_layers" in comp and "window_operands_as_projected" in comp:
+            lines.append("window attention layers whose kernels read q as projected, rope and gate fused: %d"
+                         % comp["window_operands_as_projected"])
         if "kernel_grads_relaid" in comp:
             lines.append("gated kernels whose gradient is relaid to the state's layout: %d" % comp["kernel_grads_relaid"])
     an = analysis["anomalies"]

@@ -95,7 +95,8 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         ("trace_ms", "compile_ms", "compiled_memory_mb", "xla_flops_per_step",
          "cache_hit", "linear_kernel_layers", "linear_pass_kernel_layers",
          "kda_kernel_layers", "kda_pass_kernel_layers", "moe_row_kernel_blocks", "expert_window_rows",
-         "shortconv_layers", "kernel_grads_relaid", "window_kernel_layers"),
+         "shortconv_layers", "kernel_grads_relaid", "window_kernel_layers",
+         "window_operands_as_projected"),
     ),
     # the per-step record (emitted at drain time under the dispatch-ahead
     # loop; iter_ms is dispatch->drain latency, which overlaps across steps)

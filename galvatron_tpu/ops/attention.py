@@ -18,7 +18,10 @@ path transposes to its (batch, heads, seq, head_dim) convention.
 Attention over a WINDOW (`core_attention(window=)`: query i sees the keys
 `i - window < j <= i`) has two forms: the band mask on XLA's logits, and on a
 TPU the band kernels of `ops/window_attention.py`, whose grid steps load and
-multiply the blocks the band touches and no others (`_pallas_window`).
+multiply the blocks the band touches and no others (`_pallas_window`), on the
+operands as projected: a head a block of lanes, no transpose, and q's rope
+and the head's gate in the kernels where the caller hands them on
+(`window_takes_kernels`, `q_rope`, `head_gate`).
 """
 
 from __future__ import annotations
@@ -37,8 +40,10 @@ from galvatron_tpu.ops import window_attention
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 # how many windowed attention calls were traced in each form since the process
-# began ("window_pallas" | "window_xla"), as `linear_attention.TOOK`; the
-# trainer's compile report reads the difference (`window_kernel_layers`)
+# began ("window_pallas" | "window_xla"; "window_as_projected": those of the
+# first that read q where the projection wrote it), as `linear_attention.TOOK`;
+# the trainer's compile report reads the difference (`window_kernel_layers`,
+# `window_operands_as_projected`)
 TOOK = collections.Counter()
 
 
@@ -111,15 +116,18 @@ def _pallas_flash(q, k, v, *, causal: bool, sm_scale: float, segment_ids=None):
     return out.transpose(0, 2, 1, 3)
 
 
-def _pallas_window(q, k, v, *, window: int, sm_scale: float):
+def _pallas_window(q, k, v, *, window: int, sm_scale: float, q_rope=None, head_gate=None):
     """Windowed causal attention of (B, S, nh, hd) queries on (B, S, nkv, hd)
     keys and values, nkv dividing nh, through the repo's band kernels
     (`ops/window_attention.py`: a query block beside the key blocks its band
     touches, one pass of softmax, k and v fetched once a key head and never
-    repeated), in the kernels' (B, heads, S, hd) layout."""
+    repeated). The kernels read the operands AS PROJECTED, a head a block of
+    hd lanes of a (B, S, heads x hd) array: the reshapes move nothing. `q_rope`,
+    `head_gate`: `core_attention`'s, the kernels' `tables` and `gates`."""
     block = window_attention.block_for(q.shape[1], window)
-    qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-    return window_attention.window_attention(qt, kt, vt, window, sm_scale, block).transpose(0, 2, 1, 3)
+    q3, k3, v3 = (t.reshape(t.shape[0], t.shape[1], -1) for t in (q, k, v))
+    return window_attention.window_attention(q3, k3, v3, q_rope, head_gate, window, sm_scale, block,
+                                             q.shape[3]).reshape(q.shape)
 
 
 class KernelSharding(NamedTuple):
@@ -154,12 +162,14 @@ class KernelSharding(NamedTuple):
                 and heads % math.prod(shape[a] for a in self.head_axes) == 0)
 
 
-def _sharded_kernel(kernel, q, k, v, sharding: KernelSharding, segment_ids=None):
-    """`kernel(q, k, v, segment_ids)` per device under a manual region (see
-    KernelSharding): `_pallas_flash` or `_pallas_window`, each device on its
-    own batch rows and heads. Inside an enclosing manual region (the 1F1B
-    schedule is manual over 'pp') shard_map must receive the CONTEXT abstract
-    mesh, whose already-manual axes are typed Manual, as ring_attention does."""
+def _sharded_kernel(kernel, q, k, v, sharding: KernelSharding, segment_ids=None, beside=()):
+    """`kernel(q, k, v, segment_ids, *beside's operands)` per device under a
+    manual region (see KernelSharding): `_pallas_flash` or `_pallas_window`,
+    each device on its own batch rows and heads (`beside`: (operand, its spec)
+    pairs, the window kernels' tables and gate logits). Inside an enclosing
+    manual region (the 1F1B schedule is manual over 'pp') shard_map must
+    receive the CONTEXT abstract mesh, whose already-manual axes are typed
+    Manual, as ring_attention does."""
     from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds
 
     bd, hd = sharding.batch_axes or None, sharding.head_axes or None
@@ -168,9 +178,13 @@ def _sharded_kernel(kernel, q, k, v, sharding: KernelSharding, segment_ids=None)
     if segment_ids is not None:
         operands += [segment_ids.q, segment_ids.kv]
         in_specs += [P(bd, None)] * 2
+    ids = len(operands) - 3
+    for operand, spec in beside:
+        operands.append(operand)
+        in_specs.append(spec)
 
-    def body(q, k, v, *seg):
-        return kernel(q, k, v, SegmentIds(*seg) if seg else None)
+    def body(q, k, v, *rest):
+        return kernel(q, k, v, SegmentIds(*rest[:ids]) if ids else None, *rest[ids:])
 
     ctx = jax.sharding.get_abstract_mesh()
     use_mesh = sharding.mesh if ctx.empty else ctx
@@ -238,12 +252,19 @@ def core_attention(
     bias_type: str = "additive",
     sharding: Optional[KernelSharding] = None,
     window: Optional[int] = None,
+    q_rope: Optional[Tuple[jax.Array, jax.Array]] = None,
+    head_gate: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Multi-head attention on (B, S, nh, hd) tensors (kv may have fewer heads:
     GQA is expanded here). `window`: causal self-attention in which query i
     sees the keys `i - window < j <= i`, its own among them (Mistral's and
     HF's `sliding_window`); `_windowed` picks its form, and a window that
-    reaches the whole sequence is plain causal attention. bias_type="key_padding" declares `bias` to be the
+    reaches the whole sequence is plain causal attention. `q_rope`, `head_gate`
+    (a call that `window_takes_kernels` says the window kernels take, and no
+    other): q comes UNTURNED with its rotation's two tables
+    (`ops/rope.half_split_tables`) and the kernels turn it block by block; the
+    output is multiplied, a head, by sigmoid of its column of the (B, S, nh)
+    logits in the kernels' epilogue: neither pass touches HBM. bias_type="key_padding" declares `bias` to be the
     (B, 1, 1, Sk) 0/-1e9 key-padding bias from padding_attn_bias **of a
     SELF-attention call** (the same padding applies to queries and keys —
     the segment-id lowering reuses the key mask for the query side, which is
@@ -275,7 +296,10 @@ def core_attention(
         assert q.shape[2] % k.shape[2] == 0, "q heads must be a multiple of kv heads"
     if window is not None:
         return _windowed(q, k, v, window=window, causal=causal, sm_scale=sm_scale, bias=bias,
-                         impl=impl, sharding=sharding)
+                         impl=impl, sharding=sharding, q_rope=q_rope, head_gate=head_gate)
+    if q_rope is not None or head_gate is not None:
+        raise ValueError("q_rope and head_gate are the window kernels' (core_attention(window=)); without a "
+                         "window the caller turns q and multiplies by the gate")
     if k.shape[2] != q.shape[2]:
         n_rep = q.shape[2] // k.shape[2]
         k = repeat_kv(k, n_rep)
@@ -340,35 +364,66 @@ def core_attention(
     raise ValueError("unknown attention impl %r" % impl)
 
 
+def _whole_heads_a_device(sharding: Optional[KernelSharding], q_shape, k_shape) -> bool:
+    """Under a manual region the window kernels see whole batch rows, query heads and key heads only."""
+    return (sharding is None or sharding.mesh.size == 1
+            or (sharding.divides(q_shape[0], q_shape[2]) and sharding.divides(q_shape[0], k_shape[2])))
+
+
+def window_takes_kernels(q_shape, k_shape, *, window: int, biased: bool = False, impl: str = "auto",
+                         sharding: Optional[KernelSharding] = None) -> bool:
+    """Whether `core_attention(window=)` runs a call of these (B, S, heads, hd)
+    shapes as the window kernels: on a TPU (the mesh's devices, else the
+    default backend), unless `impl="xla"` asks otherwise, a call without a bias,
+    heads of whole 128-lane tiles, whole batch rows, query heads and key heads a
+    device, and a query block that divides the sequence and reaches the window
+    in a few key blocks (`window_attention.block_for`). What such a call may
+    bring: `core_attention`'s `q_rope` and `head_gate`."""
+    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
+    return bool(impl != "xla" and on_tpu and q_shape[3] % 128 == 0 and not biased
+                and _whole_heads_a_device(sharding, q_shape, k_shape)
+                and window_attention.block_for(q_shape[1], window) > 0)
+
+
 def _windowed(q, k, v, *, window: int, causal: bool, sm_scale: float, bias, impl: str,
-              sharding: Optional[KernelSharding]) -> jax.Array:
+              sharding: Optional[KernelSharding], q_rope=None, head_gate=None) -> jax.Array:
     """`core_attention` over a window, k and v at their own heads. On a TPU
-    the window kernels (`_pallas_window`) wherever they have a form: a causal
-    self-attention call without a bias, the sequence in whole 128-token tiles
-    of which a few reach the window (`window_attention.block_for`), heads of
-    whole 128-lane tiles, and whole batch rows, query heads and key heads a
-    device; the band mask on XLA's logits everywhere else (the CPU;
-    `impl="xla"`), said once a shape where a TPU takes it at a tileable
-    length. Decided by what the call observes, counted in `TOOK`."""
+    the window kernels (`_pallas_window`) wherever they have a form
+    (`window_takes_kernels`); the band mask on XLA's logits everywhere else
+    (the CPU; `impl="xla"`), said once a shape where a TPU takes it at a
+    tileable length. Decided by what the call observes, counted in `TOOK`:
+    "window_pallas" or "window_xla" a call, and "window_as_projected" beside
+    the first where q came unturned with its tables (`q_rope`)."""
     if not causal or q.shape[1] != k.shape[1] or window < 1:
         raise ValueError("a window of %d keys is causal self-attention's (query i sees keys i - window < j <= i); "
                          "got causal=%s, %d queries on %d keys" % (window, causal, q.shape[1], k.shape[1]))
     if impl not in ("auto", "flash", "xla"):
         raise ValueError("unknown attention impl %r" % impl)
+    kernel = window_takes_kernels(q.shape, k.shape, window=window, biased=bias is not None, impl=impl,
+                                  sharding=sharding)
+    if not kernel and (q_rope is not None or head_gate is not None):
+        raise ValueError("q_rope and head_gate ride the window kernels alone: ask `window_takes_kernels` first, "
+                         "and turn q and multiply by the gate around a call it refuses")
     on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
-    if sharding is not None and sharding.mesh.size == 1:
-        sharding = None
-    splits = sharding is None or (sharding.divides(q.shape[0], q.shape[2])
-                                  and sharding.divides(q.shape[0], k.shape[2]))
-    tileable = q.shape[1] % 128 == 0
-    kernel = (impl != "xla" and on_tpu and q.shape[3] % 128 == 0 and bias is None and splits
-              and window_attention.block_for(q.shape[1], window) > 0)
-    if on_tpu and tileable and impl == "auto" and not kernel:
-        _say_fallback_once(q.shape, k.shape[1], bias is not None, splits, window)
+    if on_tpu and q.shape[1] % 128 == 0 and impl == "auto" and not kernel:
+        _say_fallback_once(q.shape, k.shape[1], bias is not None, _whole_heads_a_device(sharding, q.shape, k.shape),
+                           window)
     TOOK["window_pallas" if kernel else "window_xla"] += 1
     if not kernel:
         n_rep = q.shape[2] // k.shape[2]
         return _xla_attention(q, repeat_kv(k, n_rep), repeat_kv(v, n_rep), causal=True, sm_scale=sm_scale,
                               bias=bias, window=window)
-    run = lambda q, k, v, _seg=None: _pallas_window(q, k, v, window=window, sm_scale=sm_scale)  # noqa: E731
-    return run(q, k, v) if sharding is None else _sharded_kernel(run, q, k, v, sharding)
+    if q_rope is not None:
+        TOOK["window_as_projected"] += 1
+    if sharding is None or sharding.mesh.size == 1:  # one device: the kernels need no manual region
+        return _pallas_window(q, k, v, window=window, sm_scale=sm_scale, q_rope=q_rope, head_gate=head_gate)
+    bd, hd = sharding.batch_axes or None, sharding.head_axes or None
+    beside = [(t, P(bd, None, None)) for t in q_rope or ()]
+    beside += [] if head_gate is None else [(head_gate, P(bd, None, hd))]
+
+    def run(q, k, v, _seg, *rest):  # the tables first, the gate logits last, as `beside` has them
+        return _pallas_window(q, k, v, window=window, sm_scale=sm_scale,
+                              q_rope=rest[:2] if q_rope is not None else None,
+                              head_gate=rest[-1] if head_gate is not None else None)
+
+    return _sharded_kernel(run, q, k, v, sharding, beside=beside)

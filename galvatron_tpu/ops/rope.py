@@ -94,3 +94,18 @@ def apply_rotary(x, positions, theta: float = 10000.0, interleaved: bool = False
         r2 = x2 * cos + x1 * sin
         out = jnp.concatenate([r1, r2], axis=-1)
     return out.astype(dtype)
+
+
+def half_split_tables(positions, head_dim: int, theta: float = 10000.0, interleaved: bool = False,
+                      rotary_dim=None, scaling=None):
+    """`apply_rotary(x, positions, theta, ...)` of heads `head_dim` wide as two
+    float32 (B, S, head_dim) tables, [cos | cos] and [-sin | sin], under which
+    it reads `x * cos + roll(x, head_dim / 2) * sin` (what the window kernels
+    do to a block of q in VMEM: ops/window_attention.py), or None where the
+    rotation is no such product of whole heads at the plain frequencies:
+    interleaved pairs, a share of a head's dims, scaled frequencies."""
+    if interleaved or rotary_dim not in (None, head_dim) or scaling is not None:
+        return None
+    angles = positions[..., None].astype(jnp.float32) * rope_frequencies(head_dim, theta)  # (B, S, hd/2)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([-sin, sin], axis=-1)

@@ -2,6 +2,21 @@
 whose work follows the BAND (`ops/attention.core_attention(window=)` on a
 TPU): query i sees the keys `i - window < j <= i`.
 
+The operands are read AS PROJECTED: q, k, v, the output and every cotangent
+are (batch, seq, heads x head_dim) arrays, what the projections' matmuls write
+and `wo`'s reads, and a head is a block of head_dim lanes (a multiple of 128)
+of them: no (batch, heads, seq, head_dim) copy of anything is made. What lay
+between the q projection and the kernel, and between the kernel and `wo`, as
+passes over a q-sized array rides the kernels (PR 50): **rope on q** (the
+half-split turn of whole heads: a step turns its block in VMEM from two
+float32 tables, `x * cos + roll(x, head_dim / 2) * sin`, rounds it once, and
+the backward turns dq back by the transposed rotation before it writes it; k
+comes turned, or a step would turn it again for each of its group's query
+heads) and **the head's gate** (`sigmoid` of the head's column of the (batch,
+seq, heads) logits on the float32 sums before the output's one rounding; the
+backward gates `do` in VMEM and yields the logits' cotangent). Both are
+optional operands: a call without them is the band alone.
+
 A grid step is one block of `b` queries of one query head: beside the
 queries' own block of keys it is handed the `ceil((window - 1) / b)` blocks
 before it and no other, so the whole band of its rows lies in VMEM at once
@@ -19,6 +34,9 @@ again from q and k (the rows are whole, so nothing of the forward is kept
 but q, k and v), `delta = sum_j p dp`, and dq, and its share of dk and dv a
 key block: one output array a position of the key block in the step (own,
 one before, ...), which the caller shifts by whole blocks and adds (`_bwd`).
+Under a gate `delta` is also `sum_d (do x gate) x the ungated output`, so the
+logits' cotangent is `delta x (1 - sigmoid)`: the ungated output is neither
+kept nor made again.
 Both custom calls carry their names, `window_attn_fwd` and `window_attn_bwd`,
 which none of the flash kernels' begin with.
 """
@@ -80,28 +98,75 @@ def _probabilities(q, keys, step, *, scale: float, block: int, window: int):
     return p, 1.0 / jnp.sum(p, axis=1, keepdims=True)
 
 
-def _fwd_kernel(q_ref, *refs, scale: float, block: int, window: int, blocks: int):
-    k_refs, v_refs, o_ref = refs[:blocks], refs[blocks:2 * blocks], refs[2 * blocks]
-    p, one_over = _probabilities(q_ref[...], [r[...] for r in k_refs], pl.program_id(2),
+def _head_column(gate_ref, head):
+    """(block, heads) gate logits -> sigmoid of head `head`'s column, (block, 1)
+    float32: the column is picked by a mask and a sum over the lanes (a lane
+    cannot be indexed by a grid position; picking it on the MXU, by a product
+    with a one-hot (heads, 128) matrix, read 0.3 ms a call slower on the chip:
+    PERF.md section 6, PR 50)."""
+    logits = gate_ref[...].astype(jnp.float32)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    return jax.nn.sigmoid(jnp.sum(jnp.where(lanes == head, logits, 0.0), axis=1, keepdims=True))
+
+
+def _operands(refs, blocks: int, rope: bool, gate: bool, leading: int):
+    """A kernel's refs as (the `leading` first, (cos, sin) or None, the gate
+    logits or None, k's blocks, v's blocks, the outputs)."""
+    first, refs = refs[:leading], refs[leading:]
+    tables, refs = (refs[:2], refs[2:]) if rope else (None, refs)
+    gates, refs = (refs[0], refs[1:]) if gate else (None, refs)
+    return first, tables, gates, refs[:blocks], refs[blocks:2 * blocks], refs[2 * blocks:]
+
+
+def _query(q_ref, tables):
+    """The step's queries as the matmuls read them: where the call brought the
+    rotation's tables (`ops/rope.half_split_tables`: [cos | cos], [-sin | sin]),
+    turned in float32, `apply_rotary`'s half-split form with a head's halves
+    swapped by a roll of the lanes, and rounded once."""
+    if tables is None:
+        return q_ref[...]
+    q = q_ref[...].astype(jnp.float32)
+    return (q * tables[0][...] + pltpu.roll(q, q.shape[1] // 2, 1) * tables[1][...]).astype(q_ref.dtype)
+
+
+def _fwd_kernel(*refs, scale: float, block: int, window: int, blocks: int, group: int, rope: bool, gate: bool):
+    (q_ref,), tables, gates, k_refs, v_refs, (o_ref,) = _operands(refs, blocks, rope, gate, 1)
+    p, one_over = _probabilities(_query(q_ref, tables), [r[...] for r in k_refs], pl.program_id(2),
                                  scale=scale, block=block, window=window)
     p = p.astype(v_refs[0].dtype)
     o = sum(jnp.dot(p[:, j * block:(j + 1) * block], v_refs[j][...], preferred_element_type=jnp.float32)
             for j in range(blocks))
+    if gate:  # the head's gate on the float32 sums: one rounding, the output's
+        one_over = one_over * _head_column(gates, pl.program_id(1) * group + pl.program_id(3))
     o_ref[...] = (o * one_over).astype(o_ref.dtype)  # the rows' sums divide (block, head_dim), not (block, keys)
 
 
-def _bwd_kernel(q_ref, do_ref, *refs, scale: float, block: int, window: int, blocks: int):
-    k_refs, v_refs = refs[:blocks], refs[blocks:2 * blocks]
-    dq_ref, dk_refs, dv_refs = refs[2 * blocks], refs[2 * blocks + 1:3 * blocks + 1], refs[3 * blocks + 1:]
-    q, do = q_ref[...], do_ref[...]
+def _bwd_kernel(*refs, scale: float, block: int, window: int, blocks: int, group: int, rope: bool, gate: bool):
+    (q_ref, do_ref), tables, gates, k_refs, v_refs, outs = _operands(refs, blocks, rope, gate, 2)
+    dq_ref, outs = outs[0], outs[1:]
+    dg_ref, outs = (outs[0], outs[1:]) if gate else (None, outs)
+    dk_refs, dv_refs = outs[:blocks], outs[blocks:]
+    q, do = _query(q_ref, tables), do_ref[...]
+    if gate:  # the cotangent of the UNGATED output, rounded where the product after the call rounded it
+        open_ = _head_column(gates, pl.program_id(1) * group + pl.program_id(3))
+        do = (do.astype(jnp.float32) * open_).astype(do.dtype)
     p, one_over = _probabilities(q, [r[...] for r in k_refs], pl.program_id(2), scale=scale, block=block, window=window)
     p = p * one_over
     dp = jnp.concatenate([jax.lax.dot_general(do, r[...], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
                           for r in v_refs], axis=1)
-    ds = (p * (dp - jnp.sum(p * dp, axis=1, keepdims=True)) * scale).astype(q.dtype)
+    delta = jnp.sum(p * dp, axis=1, keepdims=True)  # = sum_d do x the ungated output: nothing of the forward is read
+    ds = (p * (dp - delta) * scale).astype(q.dtype)
     p = p.astype(q.dtype)
-    dq_ref[...] = sum(jnp.dot(ds[:, j * block:(j + 1) * block], k_refs[j][...], preferred_element_type=jnp.float32)
-                      for j in range(blocks)).astype(dq_ref.dtype)
+    dq = sum(jnp.dot(ds[:, j * block:(j + 1) * block], k_refs[j][...], preferred_element_type=jnp.float32)
+             for j in range(blocks))
+    if tables is not None:  # the rotation's transpose: the tables' product first, then the roll
+        dq = dq * tables[0][...] + pltpu.roll(dq * tables[1][...], dq.shape[1] // 2, 1)
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+    if gate:
+        # d logit = sum_d (do x ungated output) x sigmoid' = (delta / sigmoid) x sigmoid (1 - sigmoid); the group's
+        # query heads fill the (block, group) block a lane each
+        lanes = jax.lax.broadcasted_iota(jnp.int32, dg_ref.shape, 1)
+        dg_ref[...] = jnp.where(lanes == pl.program_id(3), delta * (1.0 - open_), dg_ref[...])
 
     @pl.when(pl.program_id(3) == 0)  # the group's first query head: the key head's sums start
     def _():
@@ -116,51 +181,80 @@ def _bwd_kernel(q_ref, do_ref, *refs, scale: float, block: int, window: int, blo
                                                preferred_element_type=jnp.float32)
 
 
-def _pallas(kernel, name: str, q, k, *, window: int, scale: float, block: int, last_axis: str):
-    """(the kernel's `pallas_call` but for its specs and shapes, the block spec
-    of a (batch, heads, seq, head_dim) array at the query heads, those of one
-    at the key heads a position of the step's key blocks, and that of a key
-    head's output a step, the keys' blocks before a step's own)."""
-    b, nh, s, hd = q.shape
-    nkv, before = k.shape[1], math.ceil((window - 1) / block)
-    group, shape = nh // nkv, (None, None, block, hd)
-    at_query = pl.BlockSpec(shape, lambda b, h, i, g: (b, h * group + g, i, 0))
-    at_key = [pl.BlockSpec(shape, lambda b, h, i, g, j=j: (b, h, jnp.maximum(i - before + j, 0), 0))
+def _pallas(kernel, name: str, first, k, v, tables, gates, *, window: int, scale: float, block: int, head_dim: int,
+            last_axis: str):
+    """The kernel's `pallas_call` on `first` (q; q and do), the tables and gate
+    logits the call brought, and k's and v's blocks of a step, as a function of
+    its out_specs and out_shape -> (that, the block spec of a (batch, seq, heads
+    x head_dim) array at a step's query head, that of a key head's output a
+    step, the keys' blocks before a step's own, the query heads a key head)."""
+    q = first[0]
+    nkv, before = k.shape[2] // head_dim, math.ceil((window - 1) / block)
+    group, shape = q.shape[2] // k.shape[2], (None, block, head_dim)
+    at_query = pl.BlockSpec(shape, lambda b, h, i, g: (b, i, h * group + g))
+    at_key = [pl.BlockSpec(shape, lambda b, h, i, g, j=j: (b, jnp.maximum(i - before + j, 0), h))
               for j in range(before + 1)]
-    a_step = pl.BlockSpec(shape, lambda b, h, i, g: (b, h, i, 0))
-    call = functools.partial(
-        pl.pallas_call, functools.partial(kernel, scale=scale, block=block, window=window, blocks=before + 1),
-        grid=(b, nkv, s // block, group), name=name,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",) * 3 + (last_axis,),
-                                             vmem_limit_bytes=_VMEM))
-    return call, at_query, at_key, a_step, before
+    a_step = pl.BlockSpec(shape, lambda b, h, i, g: (b, i, h))
+    rows = lambda width: pl.BlockSpec((None, block, width), lambda b, h, i, g: (b, i, 0))  # noqa: E731
+    brought = list(tables or ()) + ([] if gates is None else [gates])  # all heads' rows of a step, whatever its head
+    body = functools.partial(kernel, scale=scale, block=block, window=window, blocks=before + 1, group=group,
+                             rope=tables is not None, gate=gates is not None)
+
+    def call(out_specs, out_shape):
+        return pl.pallas_call(
+            body, grid=(q.shape[0], nkv, q.shape[1] // block, group), name=name,
+            in_specs=[at_query] * len(first) + [rows(t.shape[2]) for t in brought] + at_key * 2,
+            out_specs=out_specs, out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",) * 3 + (last_axis,),
+                                                 vmem_limit_bytes=_VMEM),
+        )(*first, *brought, *[k] * (before + 1), *[v] * (before + 1))
+
+    return call, at_query, a_step, before, group
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def window_attention(q, k, v, window: int, scale: float, block: int):
-    """q (B, nh, S, hd), k and v (B, nkv, S, hd), nkv dividing nh, S a multiple
-    of `block` (`block_for`) -> (B, nh, S, hd): softmax(q k^T x scale) v over
-    the keys `i - window < j <= i`, query head h on key head h // (nh / nkv)."""
-    return _fwd(q, k, v, window, scale, block)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def window_attention(q, k, v, tables, gates, window: int, scale: float, block: int, head_dim: int):
+    """q (B, S, nh x head_dim), k and v (B, S, nkv x head_dim), AS PROJECTED: a
+    head is a block of `head_dim` lanes (a multiple of 128) of what the matmul
+    wrote; nkv divides nh, S is a multiple of `block` (`block_for`) -> (B, S,
+    nh x head_dim): softmax(q k^T x scale) v over the keys `i - window < j <=
+    i`, query head h on key head h // (nh / nkv). `tables`: None, or (cos, sin)
+    float32 (B, S, head_dim) of `ops/rope.half_split_tables`: q comes UNTURNED
+    and a step turns its block in VMEM, in float32, rounded once (k comes
+    turned: a step would turn it again for each of its group's query heads).
+    `gates`: None, or (B, S, nh) logits: head h's output x sigmoid(its column),
+    on the float32 sums before the output's one rounding."""
+    return _fwd(q, k, v, tables, gates, window, scale, block, head_dim)[0]
 
 
-def _fwd(q, k, v, window: int, scale: float, block: int):
-    call, at_query, at_key, _, before = _pallas(_fwd_kernel, "window_attn_fwd", q, k, window=window, scale=scale,
-                                                block=block, last_axis="parallel")
-    out = call(in_specs=[at_query] + at_key * 2, out_specs=at_query,
-               out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype))(q, *[k] * (before + 1), *[v] * (before + 1))
-    return out, (q, k, v)
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))  # traced once a shape, not once a layer run and phase
+def _forward(q, k, v, tables, gates, window: int, scale: float, block: int, head_dim: int):
+    call, at_query, _, _, _ = _pallas(_fwd_kernel, "window_attn_fwd", (q,), k, v, tables, gates, window=window,
+                                      scale=scale, block=block, head_dim=head_dim, last_axis="parallel")
+    return call(at_query, jax.ShapeDtypeStruct(q.shape, q.dtype))
 
 
-def _bwd(window: int, scale: float, block: int, kept, do):
-    q, k, v = kept
-    call, at_query, at_key, a_step, before = _pallas(_bwd_kernel, "window_attn_bwd", q, k, window=window, scale=scale,
-                                                     block=block, last_axis="arbitrary")  # the group's sums
-    sums = jax.ShapeDtypeStruct(k.shape, jnp.float32)
-    dq, *shares = call(
-        in_specs=[at_query] * 2 + at_key * 2, out_specs=[at_query] + [a_step] * (2 * before + 2),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] + [sums] * (2 * before + 2),
-    )(q, do, *[k] * (before + 1), *[v] * (before + 1))
+def _fwd(q, k, v, tables, gates, window: int, scale: float, block: int, head_dim: int):
+    return _forward(q, k, v, tables, gates, window, scale, block, head_dim), (q, k, v, tables, gates)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _bwd(window: int, scale: float, block: int, head_dim: int, kept, do):
+    q, k, v, tables, gates = kept
+    call, at_query, a_step, before, group = _pallas(
+        _bwd_kernel, "window_attn_bwd", (q, do), k, v, tables, gates, window=window, scale=scale, block=block,
+        head_dim=head_dim, last_axis="arbitrary")  # the group's sums
+    out_specs, out_shape = [at_query], [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    if gates is not None:
+        # the gate logits' cotangent a key head, its group's query heads the lanes: a step's block is revisited by
+        # the group alone (a (block, heads) block of all heads would come back a key head later)
+        out_specs.append(pl.BlockSpec((None, None, block, group), lambda b, h, i, g: (b, h, i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((q.shape[0], k.shape[2] // head_dim, q.shape[1], group), jnp.float32))
+    dq, *shares = call(out_specs + [a_step] * (2 * before + 2),
+                       out_shape + [jax.ShapeDtypeStruct(k.shape, jnp.float32)] * (2 * before + 2))
+    dgates = None
+    if gates is not None:
+        dgates, shares = shares[0].transpose(0, 2, 1, 3).reshape(gates.shape).astype(gates.dtype), shares[1:]
 
     def gathered(parts):
         """Share j of step i belongs to key block i - before + j: moved there by
@@ -169,10 +263,11 @@ def _bwd(window: int, scale: float, block: int, kept, do):
         total = parts[before]
         for j in range(before):
             reach = (before - j) * block
-            total = total + jnp.pad(parts[j], ((0, 0), (0, 0), (0, reach), (0, 0)))[:, :, reach:]
+            total = total + jnp.pad(parts[j], ((0, 0), (0, reach), (0, 0)))[:, reach:]
         return total
 
-    return dq, gathered(shares[:before + 1]).astype(k.dtype), gathered(shares[before + 1:]).astype(v.dtype)
+    return (dq, gathered(shares[:before + 1]).astype(k.dtype), gathered(shares[before + 1:]).astype(v.dtype),
+            None, dgates)  # (nothing flows to the tables: they come from positions)
 
 
 window_attention.defvjp(_fwd, _bwd)

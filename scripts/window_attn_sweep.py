@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Chip measurement behind the window kernels' block size
-(ops/window_attention.py `BLOCK`), at the Laguna cell's
+(ops/window_attention.py `BLOCK`) and their form, at the Laguna cell's
 shapes (1 x 8192 tokens, 64 query heads on 8 KV heads of 128, a window of 512,
 bf16). Not a benchmark cell: run by hand through the chip tool,
 
@@ -16,11 +16,26 @@ twice its 8192 time, one that walks the triangle four times), the flash
 kernels on the whole causal triangle at the same 64 heads (what a mask alone
 would pay), and the committed kernels' output and gradients against the band
 mask on XLA's logits at ONE key head's 8 query heads (2 GiB of logits).
+
+**The surround (PR 50)**: from q as its projection wrote it to what `wo`
+reads, with rope and the head's gate (and back: the gradients of q, k, v and
+the gate logits), by form: `as_projected` (the kernels turn q in VMEM and
+gate their output: what a TPU runs), `rope_in_kernel` and `gate_in_kernel`
+(one of the two, the other a pass of its own), `passes_before_and_after` (the
+same kernels on q turned by `apply_rotary` before the call, the gate's product
+after it: what a rotation without tables pays), and, where the parent's
+checkout lies under `_parent/` (scripts/chip_pairs.sh's place), `parent` (its
+kernels in their (batch, heads, seq, 128) layout behind the transposes) and
+those kernels alone on operands already in that layout. The
+kernels keep nothing of the forward for the gate's backward and make nothing
+again (`delta = sum_j p dp` is `sum_d` of the gated cotangent x the ungated
+output), so there is no kept-against-remade pair to time.
 Refuses to run where jax finds no TPU.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import statistics
@@ -67,9 +82,15 @@ def main(argv) -> int:
     scale = HEAD_DIM ** -0.5
 
     def operands(tokens, q_heads=Q_HEADS, kv_heads=KV_HEADS):
+        """q, k, v as their projections write them: (batch, tokens, heads x 128)."""
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        return tuple(jax.random.normal(key, (BATCH, tokens, heads, HEAD_DIM), jnp.bfloat16)
+        return tuple(jax.random.normal(key, (BATCH, tokens, heads * HEAD_DIM), jnp.bfloat16)
                      for key, heads in zip(ks, (q_heads, kv_heads, kv_heads)))
+
+    by_heads = lambda t: t.reshape(t.shape[:2] + (-1, HEAD_DIM))  # noqa: E731
+    flat = lambda t: t.reshape(t.shape[:2] + (-1,))  # noqa: E731
+    rel = lambda a, b: float(np.linalg.norm(np.asarray(a, np.float64) - np.asarray(b, np.float64))  # noqa: E731
+                             / np.linalg.norm(np.asarray(b, np.float64)))
 
     def least_ms(tokens, kinds):
         return sum(flops.least_time_s(costs.window_kernel_cost(fields, kind, BATCH, tokens), peak)[0]
@@ -77,9 +98,10 @@ def main(argv) -> int:
 
     def window_times(setting, tokens):
         W.BLOCK = setting
-        fwd = jax.jit(lambda q, k, v: A._pallas_window(q, k, v, window=WINDOW, sm_scale=scale))
-        both = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
-            A._pallas_window(q, k, v, window=WINDOW, sm_scale=scale).astype(jnp.float32) ** 2), (0, 1, 2)))
+        alone = lambda q, k, v: W.window_attention(q, k, v, None, None, WINDOW, scale,  # noqa: E731
+                                                   W.block_for(tokens, WINDOW), HEAD_DIM)
+        fwd = jax.jit(alone)
+        both = jax.jit(jax.grad(lambda q, k, v: jnp.sum(alone(q, k, v).astype(jnp.float32) ** 2), (0, 1, 2)))
         qkv = operands(tokens)
         try:
             row = {"block": setting, "tokens": tokens, "fwd_ms": timed(fwd, *qkv),
@@ -101,9 +123,58 @@ def main(argv) -> int:
                        "fwd_bwd_over_8192": long["fwd_bwd_ms"] / short["fwd_bwd_ms"]}
     print(json.dumps(out["at_16384"]), flush=True)
 
+    # the surround: q as projected -> what wo reads, rope and the head's gate with it, three forms
+    from galvatron_tpu.ops import rope as R
+
+    W.BLOCK = committed
+    q, k, v = operands(TOKENS)
+    logits = jax.random.normal(jax.random.PRNGKey(7), (BATCH, TOKENS, Q_HEADS), jnp.bfloat16)
+    positions = jnp.arange(TOKENS)[None]
+    parent_path = os.path.join(ROOT, "_parent", "galvatron_tpu", "ops", "window_attention.py")
+    parent = None
+    if os.path.exists(parent_path):
+        spec = importlib.util.spec_from_file_location("parent_window_attention", parent_path)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+
+    def surround(form):
+        def run(q, k, v, logits):
+            q, k, v = by_heads(q), R.apply_rotary(by_heads(k), positions), by_heads(v)
+            if form == "parent":
+                out = parent.window_attention(*(t.transpose(0, 2, 1, 3) for t in (R.apply_rotary(q, positions), k, v)),
+                                              WINDOW, scale, committed).transpose(0, 2, 1, 3)
+                return flat(out * jax.nn.sigmoid(logits)[..., None])
+            turn, gate = form in ("as_projected", "rope_in_kernel"), form in ("as_projected", "gate_in_kernel")
+            out = A._pallas_window(q if turn else R.apply_rotary(q, positions), k, v, window=WINDOW, sm_scale=scale,
+                                   q_rope=R.half_split_tables(positions, HEAD_DIM) if turn else None,
+                                   head_gate=logits if gate else None)
+            return flat(out if gate else out * jax.nn.sigmoid(logits)[..., None])
+        return run
+
+    squares = lambda form: lambda *a: jnp.sum(surround(form)(*a).astype(jnp.float32) ** 2)  # noqa: E731
+    out["surround"] = {}
+    for form in ["as_projected", "rope_in_kernel", "gate_in_kernel", "passes_before_and_after"] + ["parent"] * bool(parent):
+        out["surround"][form] = {"fwd_ms": timed(jax.jit(surround(form)), q, k, v, logits),
+                                 "fwd_bwd_ms": timed(jax.jit(jax.grad(squares(form), (0, 1, 2, 3))), q, k, v, logits)}
+        print(form, json.dumps(out["surround"][form]), flush=True)
+    if parent:  # the parent's kernels alone on operands already in THEIR layout: what the layout itself costs a kernel
+        alone = lambda q, k, v: parent.window_attention(q, k, v, WINDOW, scale, committed)  # noqa: E731
+        theirs = [by_heads(t).transpose(0, 2, 1, 3) for t in (q, k, v)]
+        out["parent_kernels_alone"] = {
+            "fwd_ms": timed(jax.jit(alone), *theirs),
+            "fwd_bwd_ms": timed(jax.jit(jax.grad(lambda q, k, v: jnp.sum(alone(q, k, v).astype(jnp.float32) ** 2),
+                                                 (0, 1, 2))), *theirs)}
+        print("parent_kernels_alone", json.dumps(out["parent_kernels_alone"]), flush=True)
+    got, want = (jax.jit(jax.value_and_grad(squares(form), (0, 1, 2, 3)))(q, k, v, logits)
+                 for form in ("as_projected", "passes_before_and_after"))
+    out["surround"]["as_projected_against_passes"] = dict(
+        zip(("loss", "dq", "dk", "dv", "dlogits"), [rel(got[0], want[0])] + list(map(rel, got[1], want[1]))))
+    print(json.dumps(out["surround"]["as_projected_against_passes"]), flush=True)
+
     # the whole causal triangle at the same heads: what a mask alone would pay
     q, k, v = operands(TOKENS)
-    tri = lambda q, k, v: A.core_attention(q, k, v, causal=True, impl="flash", sm_scale=scale)  # noqa: E731
+    tri = lambda q, k, v: A.core_attention(  # noqa: E731
+        *map(by_heads, (q, k, v)), causal=True, impl="flash", sm_scale=scale)
     out["flash_triangle"] = {
         "fwd_ms": timed(jax.jit(tri), q, k, v),
         "fwd_bwd_ms": timed(jax.jit(jax.grad(lambda q, k, v: jnp.sum(tri(q, k, v).astype(jnp.float32) ** 2), (0, 1, 2))),
@@ -117,12 +188,11 @@ def main(argv) -> int:
 
     def run(impl):
         def of(q, k, v):
-            o = A.core_attention(q, k, v, window=WINDOW, impl=impl, sm_scale=scale)
+            o = flat(A.core_attention(*map(by_heads, (q, k, v)), window=WINDOW, impl=impl, sm_scale=scale))
             return jnp.sum(o.astype(jnp.float32) * probe), o
         (_, o), grads = jax.jit(jax.value_and_grad(of, (0, 1, 2), has_aux=True))(q, k, v)
-        return [np.asarray(t, np.float64) for t in (o,) + tuple(grads)]
+        return (o,) + tuple(grads)
 
-    rel = lambda a, b: float(np.linalg.norm(a - b) / np.linalg.norm(b))  # noqa: E731
     out["against_xla_band"] = dict(zip(("out", "dq", "dk", "dv"), map(rel, run("flash"), run("xla"))))
     print(json.dumps(out["against_xla_band"]), flush=True)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)

@@ -71,6 +71,7 @@ def test_dp2_zero2_follows_one_device_and_logs_the_compile_counter(one_device, c
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 1), (1, 1, 4), (2, 4, 5)]
     compiles = [e for e in events if e["type"] == "compile"]
     assert [e["window_kernel_layers"] for e in compiles] == [0]  # off a TPU the band is a mask on XLA's logits
+    assert [e["window_operands_as_projected"] for e in compiles] == [0]  # so nothing reads q as projected
     assert [e["moe_row_kernel_blocks"] for e in compiles] == [0]  # and the rows move by XLA's gathers
     assert all("kda_kernel_layers" not in e and "linear_kernel_layers" not in e and "shortconv_layers" not in e
                for e in compiles)
@@ -87,6 +88,7 @@ def test_a_model_without_window_layers_logs_no_window_counter(counting, tmp_path
         "--world_size", "1", "--telemetry", tele] + counting))
     compiles = [e for e in T.read_events(tele)[0] if e["type"] == "compile"]
     assert len(compiles) == 1 and "window_kernel_layers" not in compiles[0]
+    assert compiles[0]["window_operands_as_projected"] == 0  # a count, 0 where there is nothing to count
 
 
 @pytest.mark.parametrize("flags", [

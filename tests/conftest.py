@@ -58,6 +58,36 @@ def disable_persistent_compile_cache():
 
 
 @pytest.fixture(scope="session")
+def window_kernels_as_on_a_tpu():
+    """-> a context under which a window attention call is answered as a TPU
+    would answer it (`ops/attention.window_takes_kernels`, asked by the mixer and
+    by `core_attention`): the default backend is mocked INSIDE that question
+    alone, so nothing else of a CPU run takes a TPU's branch. The kernels
+    themselves want `pltpu.force_tpu_interpret_mode()` or a spy beside it."""
+    import contextlib
+    import unittest.mock as mock
+
+    import jax
+
+    from galvatron_tpu.models.parts import attention as parts
+    from galvatron_tpu.ops import attention as ops
+
+    real = ops.window_takes_kernels
+
+    def asked(*args, **kw):
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            return real(*args, **kw)
+
+    @contextlib.contextmanager
+    def context():
+        with mock.patch.object(ops, "window_takes_kernels", asked), \
+             mock.patch.object(parts, "window_takes_kernels", asked):
+            yield asked
+
+    return context
+
+
+@pytest.fixture(scope="session")
 def devices8():
     devs = jax.devices()
     if len(devs) < 8:

@@ -399,6 +399,54 @@ def test_eight_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(np.asarray(out[0] + shared), np.asarray(whole), atol=1e-5)
 
 
+# ----------------------------- the window kernels on q as projected (PR 50)
+@pytest.fixture(scope="module")
+def two_forms(window_kernels_as_on_a_tpu):
+    """A tiny Laguna whose window layers the kernels have a form of (heads of
+    128, 256 tokens, a window of 160: two key blocks a step at 128-token
+    blocks), float32: loss and every leaf's gradient with the window calls as
+    the CPU runs them (rope, XLA's band, the gate's product) and as a TPU does
+    (`window_takes_kernels` answered as on a TPU, the kernels interpreted: q
+    read where the flat projection wrote it, turned in the kernel, the head's
+    gate in its epilogue) -> {form: ((loss, parts), grads)}, what `TOOK` counted."""
+    import unittest.mock as mock
+
+    import jax.experimental.pallas.tpu as pltpu
+
+    from galvatron_tpu.ops import window_attention
+
+    cfg = tiny(head_dim=128, max_seq_len=256, sliding_window=160, attn_impl="auto")
+    params, batch = params_of(cfg), batch_of(seq=256)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(lambda p: M.lm_loss_fn(p, batch, cfg, with_parts=True), has_aux=True)(params)
+
+    out = {"xla": run()}
+    before = collections.Counter(attention_ops.TOOK)
+    with window_kernels_as_on_a_tpu(), mock.patch.object(window_attention, "BLOCK", 128), \
+         pltpu.force_tpu_interpret_mode():
+        out["kernels"] = run()
+    return out, attention_ops.TOOK - before
+
+
+def test_the_loss_is_the_same_with_the_window_kernels_on_q_as_projected(two_forms):
+    out, took = two_forms
+    assert took["window_pallas"] == took["window_as_projected"] > 0 and "window_xla" not in took
+    (loss, parts), (want, want_parts) = out["kernels"][0], out["xla"][0]
+    assert abs(float(loss) - float(want)) < F32_TOL
+    for name in want_parts:
+        np.testing.assert_allclose(np.asarray(parts[name]), np.asarray(want_parts[name]), atol=F32_TOL, err_msg=name)
+
+
+def test_every_leafs_gradient_is_the_same_with_the_window_kernels_on_q_as_projected(two_forms):
+    out, _ = two_forms
+    errors = leaf_errors(out["kernels"][1], out["xla"][1])
+    assert len(errors) > 20 and max(errors.values()) < F32_TOL, sorted(errors.items(), key=lambda kv: -kv[1])[:5]
+    moved = leaf_errors(out["kernels"][1], jax.tree.map(jnp.zeros_like, out["xla"][1]))
+    assert min(moved.values()) > 0  # no leaf's gradient is lost on the way: the gate's, the window's wq among them
+
+
 # ------------------------------------------------ the table, FLOPs, the counters
 def test_one_table_maps_the_window_mixer_to_what_it_brings():
     assert M.MIXERS["window"].scopes == (tracing.ATTN_WINDOW, tracing.ATTN_WINDOW_BAND) == (
@@ -423,7 +471,7 @@ def test_one_table_maps_the_window_mixer_to_what_it_brings():
     wide = obs_flops.window_fwd_flops_a_token(hidden=64, num_heads=6, head_dim=16, num_kv_heads=2, window=SEQ + 9,
                                               head_gate=True, seq_len=SEQ)[1]
     assert wide == pytest.approx(2 * 2 * (SEQ + 1) / 2 * 6 * 16)
-    assert "window_kernel_layers" in telemetry.EVENT_SCHEMAS["compile"][1]
+    assert {"window_kernel_layers", "window_operands_as_projected"} <= set(telemetry.EVENT_SCHEMAS["compile"][1])
 
 
 # ------------------------------------------------------------ GLS018, by name

@@ -632,9 +632,10 @@ def test_the_window_kernels_are_the_band_mask(window, block):
 
     def grads(kernel):
         def f(q, k, v):
-            if kernel:
-                out = W.window_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)), window, scale, block)
-                return jnp.sum(jnp.sin(out.transpose(0, 2, 1, 3))), out.transpose(0, 2, 1, 3)
+            if kernel:  # as projected: a head a block of 128 lanes of a (batch, seq, heads x 128) array
+                out = W.window_attention(*(t.reshape(2, 512, -1) for t in (q, k, v)), None, None, window, scale,
+                                         block, 128).reshape(q.shape)
+                return jnp.sum(jnp.sin(out)), out
             out = A.core_attention(q, k, v, window=window, sm_scale=scale, impl="xla")
             return jnp.sum(jnp.sin(out)), out
         return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
@@ -688,3 +689,148 @@ def test_auto_dispatch_takes_the_window_kernels_on_a_tpu_and_says_a_fallback_onc
     np.testing.assert_allclose(np.asarray(out), np.asarray(A.core_attention(q, k, v, window=32, impl="xla")), atol=2e-5)
     said = [r.getMessage() for r in caplog.records if "XLA attention on a TPU" in r.getMessage()]
     assert len(said) == 1 and "a window of 32" in said[0] and "head_dim 64" in said[0] and "window kernel" in said[0]
+
+
+# --------------------------------- the window kernels read q as projected (PR 50)
+def _as_projected_cases():
+    """(window, block) pairs the kernels have a form of at 768 tokens (a window
+    of 512 at 128-token blocks would need four blocks before a step's own),
+    each with and without a group, the gate and the rope in the kernel: all
+    eight at a window of 512, and each of the three factors both ways at the
+    other windows."""
+    every = [(g, gate, rope) for g in (1, 8) for gate in (False, True) for rope in (False, True)]
+    some = [(8, True, True), (1, False, True), (8, True, False), (1, True, True), (8, False, False)]
+    return ([(512, 256) + c for c in every] + [(128, 128) + c for c in some] + [(128, 256) + c for c in some[:3]]
+            + [(640, 256) + c for c in some])
+
+
+@pytest.mark.parametrize("window,block,group,gate,rope", _as_projected_cases())
+def test_the_as_projected_window_kernels_are_xlas_band_between_rope_and_gate(window, block, group, gate, rope):
+    """`ops/window_attention.py` on operands as the projections wrote them
+    ((batch, seq, heads x 128), interpret mode), q unturned with its tables
+    (`rope`) or turned before the call, the head's gate in the epilogue (`gate`)
+    or multiplied after the call: output and the gradients of q, k, v and the
+    gate logits against `apply_rotary`, the band mask on XLA's logits and the
+    gate's product, float32, rows at positions that differ by row."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    from galvatron_tpu.ops import attention as A
+    from galvatron_tpu.ops import rope as R
+    from galvatron_tpu.ops import window_attention as W
+
+    b, s, nkv, hd, scale = 2, 768, 1, 128, 0.05
+    q, k, v = _rand_qkv(jax.random.PRNGKey(window + group), b=b, s=s, nh=nkv * group, nkv=nkv, hd=hd)
+    logits = jax.random.normal(jax.random.PRNGKey(3), (b, s, nkv * group))
+    positions = jnp.arange(s)[None] + jnp.array([[0], [11]])
+    assert W.block_for(s, window, block) == block
+
+    def grads(kernel):
+        def f(q, k, v, logits):
+            k = R.apply_rotary(k, positions)
+            if not kernel:
+                out = A.core_attention(R.apply_rotary(q, positions), k, v, window=window, sm_scale=scale, impl="xla")
+                out = out * jax.nn.sigmoid(logits)[..., None]
+                return jnp.sum(jnp.sin(out)), out
+            tables = R.half_split_tables(positions, hd) if rope else None
+            q = q if rope else R.apply_rotary(q, positions)
+            out = W.window_attention(*(t.reshape(b, s, -1) for t in (q, k, v)), tables, logits if gate else None,
+                                     window, scale, block, hd).reshape(q.shape)
+            out = out if gate else out * jax.nn.sigmoid(logits)[..., None]
+            return jnp.sum(jnp.sin(out)), out
+        return jax.grad(f, argnums=(0, 1, 2, 3), has_aux=True)(q, k, v, logits)
+
+    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
+        got, want = grads(True), grads(False)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=3e-5)
+
+
+@pytest.mark.parametrize("case,fields,kernels,tables,gates", [
+    ("whole_head_half_split", {}, True, True, True),
+    ("half_rope", {"window_partial_rotary_factor": 0.5}, True, False, True),
+    ("no_head_gate", {"attn_head_gate": False}, True, True, False),
+    ("head_dim_64", {"head_dim": 64}, False, False, False),
+    ("a_bias", {}, False, False, False),
+    ("impl_xla", {"attn_impl": "xla"}, False, False, False),
+])
+def test_a_window_layer_hands_the_kernels_what_they_fuse_and_keeps_the_rest(window_kernels_as_on_a_tpu, case, fields,
+                                                                            kernels, tables, gates):
+    """What `attention_mixer` hands the window call by what it observes: on a
+    TPU at heads of 128 the kernels take q UNTURNED with the rotation's tables
+    and the gate logits where the layer's rope is the half-split turn of whole
+    heads; a rope on half a head keeps `apply_rotary` before the call (the gate
+    still rides); a bias, heads of 64 or `impl="xla"` keep XLA's band, rope
+    before it and the gate's product after it. Counted in `TOOK`, and the
+    mixer's output the same either way."""
+    from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.laguna import laguna_config
+    from galvatron_tpu.ops import attention as A
+
+    cfg = laguna_config(**{**dict(hidden_size=64, num_heads=2, window_num_heads=4, num_kv_heads=2, head_dim=128,
+                                  ffn_hidden=32, dense_ffn_hidden=32, num_layers=5, vocab_size=128, max_seq_len=256,
+                                  num_experts=8, experts_per_token=2, sliding_window=40, init_std=0.2,
+                                  compute_dtype=jnp.float32), **fields})
+    lcfg = cfg.layer_config("window.routed")
+    lp = M.init_layer_params(jax.random.PRNGKey(0), lcfg)
+    y = jax.random.normal(jax.random.PRNGKey(1), (1, 256, 64))
+    positions = jnp.arange(256)[None] + 3
+    bias = jnp.zeros((1, 1, 1, 256)) if case == "a_bias" else None
+    seen = []
+
+    def spy(q_, k_, v_, **kw):  # the kernels' call, answered by XLA's band on what they would compute
+        seen.append((kw.get("q_rope") is not None, kw.get("head_gate") is not None))
+        if kw.get("q_rope") is not None:
+            q_ = apply_rotary(q_, positions, lcfg.rope_theta)
+        out = A._xla_attention(q_, A.repeat_kv(k_, 2), A.repeat_kv(v_, 2), causal=True, sm_scale=kw["sm_scale"],
+                               window=kw["window"])
+        return out if kw.get("head_gate") is None else out * jax.nn.sigmoid(kw["head_gate"])[..., None]
+
+    run = lambda: M.MIXERS["window"].forward(  # noqa: E731
+        lp, y, positions, lcfg, mesh=None, axes=None, attn_bias=bias, attn_sharding=None, return_kv=False)[0]
+    want = run()  # the CPU's path
+    before = collections.Counter(A.TOOK)
+    with mock.patch.object(A, "_pallas_window", spy), window_kernels_as_on_a_tpu():
+        got = run()
+    assert seen == ([(tables, gates)] if kernels else [])
+    took = {"window_pallas": 1, **({"window_as_projected": 1} if tables else {})} if kernels else {"window_xla": 1}
+    assert A.TOOK - before == took
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("how", [dict(interleaved=True), dict(rotary_dim=64), dict(scaling={
+    "rope_type": "yarn", "factor": 8, "original_max_position_embeddings": 16, "beta_fast": 4, "beta_slow": 1,
+    "attention_factor": 1.2})], ids=["interleaved", "half_rope", "yarn"])
+def test_a_rotation_that_is_no_product_with_two_tables_has_none(how):
+    """`half_split_tables` is `apply_rotary`'s half-split turn of whole heads
+    at the plain frequencies as `x * cos + roll(x, half) * sin`, and None for
+    every other rotation: the caller turns q itself then."""
+    from galvatron_tpu.ops.rope import half_split_tables
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 3, 128))
+    positions = jnp.arange(24)[None] * jnp.array([[1], [5]])
+    cos, sin = half_split_tables(positions, 128, 500.0)
+    assert cos.dtype == sin.dtype == jnp.float32 and cos.shape == sin.shape == (2, 24, 128)
+    turned = x * cos[:, :, None] + jnp.roll(x, 64, axis=-1) * sin[:, :, None]
+    np.testing.assert_allclose(np.asarray(turned), np.asarray(apply_rotary(x, positions, 500.0)), atol=1e-6)
+    assert half_split_tables(positions, 128, 500.0, rotary_dim=128) is not None
+    assert half_split_tables(positions, 128, 500.0, **how) is None
+
+
+def test_tables_and_gate_logits_ride_the_window_kernels_alone(window_kernels_as_on_a_tpu):
+    from galvatron_tpu.ops import attention as A
+    from galvatron_tpu.ops.rope import half_split_tables
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(8), b=1, s=128, nh=2, hd=128)
+    tables, logits = half_split_tables(jnp.arange(128)[None], 128), jnp.zeros((1, 128, 2))
+    assert not A.window_takes_kernels(q.shape, k.shape, window=4)  # off a TPU
+    with pytest.raises(ValueError, match="ride the window kernels alone"):
+        A.core_attention(q, k, v, window=4, q_rope=tables)
+    with pytest.raises(ValueError, match="ride the window kernels alone"):
+        A.core_attention(q, k, v, window=4, head_gate=logits)
+    with pytest.raises(ValueError, match="without a window the caller turns q"):
+        A.core_attention(q, k, v, head_gate=logits)
+    with window_kernels_as_on_a_tpu() as on_a_tpu:
+        pass
+    assert on_a_tpu(q.shape, k.shape, window=4) and not on_a_tpu(q.shape, k.shape, window=4, biased=True)
+    assert not on_a_tpu(q.shape, k.shape, window=4, impl="xla") and not on_a_tpu((1, 100, 2, 128), k.shape, window=4)
+    assert not on_a_tpu((1, 128, 2, 64), (1, 128, 2, 64), window=4)

@@ -918,15 +918,104 @@ def test_the_window_kernels_compile_at_the_cells_shapes_for_v5e(v5e_2x2, tokens)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30 * tokens / 8192
 
 
-def test_the_window_kernels_run_in_a_manual_region_on_a_dp4_mesh(v5e_2x2):
+@pytest.mark.parametrize("as_projected", [False, True], ids=["q_turned_before", "as_projected"])
+def test_the_window_kernels_run_in_a_manual_region_on_a_dp4_mesh(v5e_2x2, as_projected):
     """Four sequences over four chips (dp with ZeRO runs the family): each chip
-    its own row through the kernels, no collective."""
+    its own row through the kernels, its rows of the rotation's tables and of
+    the gate logits with it where the call brings them, no collective."""
+    from galvatron_tpu.ops.rope import half_split_tables
+
     mesh = Mesh(np.array(v5e_2x2).reshape(1, 4), ("pp", "m0"))
     sh = NamedSharding(mesh, P("m0", None, None, None))
     q = jax.ShapeDtypeStruct((4, 2048, 16, 128), jnp.bfloat16, sharding=sh)
     kv = jax.ShapeDtypeStruct((4, 2048, 4, 128), jnp.bfloat16, sharding=sh)
-    fn = jax.grad(_window_loss(A.KernelSharding(mesh, ("m0",), ())), argnums=(0, 1, 2))
-    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    logits = jax.ShapeDtypeStruct((4, 2048, 16), jnp.bfloat16, sharding=NamedSharding(mesh, P("m0", None, None)))
+    where = A.KernelSharding(mesh, ("m0",), ())
+
+    def loss(q, k, v, logits):
+        if not as_projected:
+            return _window_loss(where)(q, k, v)
+        positions = jnp.broadcast_to(jnp.arange(2048), (4, 2048))
+        out = A.core_attention(q, k, v, window=512, sharding=where, q_rope=half_split_tables(positions, 128),
+                               head_gate=logits)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(q, kv, kv, logits).compile().as_text()
     assert len(_custom_calls(text)) == 2
     for collective in ("all-gather", "all-reduce", "all-to-all", "collective-permute"):
         assert collective not in text, collective
+
+
+@pytest.fixture(scope="module")
+def laguna_window_layer(v5e_2x2):
+    """One window layer's mixer of the Laguna cell (8192 tokens, hidden 2048,
+    64 query heads on 8 KV heads of 128, a window of 512, the gate a head,
+    bf16) under the cell's recomputation, forward and backward, compiled for one
+    described chip: -> (the optimised module's text, the forms its call took)."""
+    from galvatron_tpu.models import base as M
+    from galvatron_tpu.models.laguna import laguna_config
+    from galvatron_tpu.models.parts.window import window_mixer
+
+    tokens = 8192
+    cfg = laguna_config(num_layers=5, max_seq_len=tokens, compute_dtype=jnp.bfloat16)
+    lcfg = cfg.layer_config(next(kind for kind in cfg.layer_kinds() if kind.startswith("window")))
+    chip = SingleDeviceSharding(v5e_2x2[0])
+    where = A.KernelSharding(Mesh(np.array(v5e_2x2[:1]), ("dp",)), batch_axes=("dp",))
+    shapes = jax.eval_shape(lambda: M.init_layer_params(jax.random.PRNGKey(0), lcfg))
+    operands = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+                            ({name: shapes[name] for name in ("wq", "wkv", "wo", "wg")},
+                             jax.ShapeDtypeStruct((1, tokens, cfg.hidden_size), jnp.bfloat16),
+                             jax.ShapeDtypeStruct((1, tokens), jnp.int32)))
+
+    def loss(p, y, positions):
+        mixer = jax.checkpoint(lambda p, y: window_mixer(p, y, positions, lcfg, mesh=None, axes=None, attn_bias=None,
+                                                         attn_sharding=where, return_kv=False))
+        return jnp.sum(mixer(p, y)[0].astype(jnp.float32))
+
+    before = collections.Counter(A.TOOK)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*operands).compile().as_text()
+    return text, dict(A.TOOK - before)
+
+
+def test_the_window_layer_reads_q_where_the_projection_wrote_it_on_v5e(laguna_window_layer):
+    """A window layer on a TPU (PR 50): Mosaic compiles both kernels in the
+    as-projected form, `window_attn_fwd` and `window_attn_bwd` once each under
+    `gt.attn.band` (rope's tables and the gate logits among their operands), and
+    nothing else of the layer makes a pass over a q-sized array: NO array by
+    heads ((8192, 64, 128) or (64, 8192, 128), any dtype) is left anywhere in
+    the module, and every (8192, 64 x 128) result of an instruction is a
+    kernel's or a matmul's own (a fusion around a convolution): no transpose,
+    no copy, no elementwise pass between the q projection and the kernel, the
+    kernel and `wo`, `wo`'s backward and the kernel, the kernel and the
+    projection's backward."""
+    from galvatron_tpu.obs import tracing
+
+    text, took = laguna_window_layer
+    assert took == {"window_pallas": 1, "window_as_projected": 1}
+    assert _calls(text, "window_attn_fwd", tracing.ATTN_WINDOW_BAND) == 1
+    assert _calls(text, "window_attn_bwd", tracing.ATTN_WINDOW_BAND) == 1
+    assert text.count("tpu_custom_call") == 2
+    tokens, width = 8192, 64 * 128
+    assert not re.search(r"\[(?:1,)?(?:%d,64|64,%d),128\]" % (tokens, tokens), text)  # no view by heads
+    entry = text[text.index("\nENTRY"):]
+    q_sized = r"(?:bf16|f32)\[(?:1,)?%d,%d\]" % (tokens, width)
+    offenders, matmuls = [], 0
+    for line in entry.splitlines():
+        found = re.match(r"\s+(?:ROOT )?(\S+) = (.*?[})]) ([a-z\-]+)\(", line)
+        if not found or not re.search(q_sized, found.group(2)):
+            continue
+        name, result, kind = found.groups()
+        if kind in ("get-tuple-element", "bitcast", "parameter") or "tpu_custom_call" in line:
+            continue
+        called = re.search(r"calls=(%[\w.\-]+)", line)
+        body = text[text.index("\n" + called.group(1) + " "):].split("\n}\n", 1)[0] if called else ""
+        if kind == "fusion" and " convolution(" in body:
+            matmuls += 1
+        else:
+            offenders.append("%s = %s %s" % (name, result[:80], kind))
+    assert not offenders, "\n".join(offenders)
+    assert matmuls == 2  # the q projection (recomputed: the first forward is the same program here) and wo's backward
+    # the kernels take the flat projection's result and wo's cotangent as they lie, and dq goes to the matmuls so
+    for kernel, operand in (("window_attn_fwd", "convolution"), ("window_attn_bwd", "convolution")):
+        call = next(line for line in entry.splitlines() if "tpu_custom_call" in line and kernel in line.split("=")[0])
+        assert re.search(r"custom-call\(%" + operand, call), call[:200]
