@@ -27,6 +27,8 @@ Subcommands replace the reference's per-model shell scripts
 
 import sys
 
+from galvatron_tpu.obs import launch
+
 
 def main():
     if len(sys.argv) < 2 or sys.argv[1] in ("-h", "--help"):
@@ -50,6 +52,9 @@ def main():
     else:
         print("unknown subcommand %r\n%s" % (cmd, __doc__))
         return 2
+    # no subcommand carries the import record's monitoring into its work
+    # (cli/train.py has closed it itself)
+    launch.IMPORTS.done()
     run(argv)
     return 0
 

@@ -7,7 +7,6 @@ registry; the per-layer strategy comes from GLOBAL flags or a searched JSON
 
 from __future__ import annotations
 
-import collections
 import hashlib
 import os
 import signal
@@ -28,7 +27,7 @@ from galvatron_tpu.cli.arguments import (
 )
 from galvatron_tpu.models.parts import mlp
 from galvatron_tpu.obs import flops as obs_flops
-from galvatron_tpu.obs import telemetry, tracing
+from galvatron_tpu.obs import launch, telemetry, tracing
 from galvatron_tpu.ops import attention as attention_ops
 from galvatron_tpu.ops import linear_attention, moe
 from galvatron_tpu.profiler.runtime import (
@@ -44,6 +43,14 @@ from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
 from galvatron_tpu.runtime.prefetch import PrefetchIterator, PrefetchStalledError
 from galvatron_tpu.utils.compile_cache import enable_persistent_cache
+
+launch.IMPORTS.done()  # the program is imported: the import record closes and gives its monitoring id back
+
+# the ops modules' counters of which form each part of the step took, bumped as
+# the model code is traced; `compiled_step` reads what the lowering added
+KERNEL_FORMS = dict(
+    delta_rule=linear_attention.TOOK, moe_rows=moe.ROWS_TOOK, moe_windows=moe.WINDOWS_TOOK,
+    kernels_relaid=mlp.RELAID, windows=attention_ops.TOOK)
 
 
 # In-process memo of AOT-compiled train-step executables, keyed by (device
@@ -62,21 +69,14 @@ def _step_exec_key(mesh, lowered):
     return (devs, hashlib.sha256(lowered.as_text().encode()).hexdigest())
 
 
-def _compile_step(lowered):
+def _compile_step(lowered, counters: launch.JitCounters):
     """Compile the lowered step; returns (executable, persistent_cache_hit).
     The hit is read off jax's own monitoring event, fired when the backend
-    compile was answered from the persistent compilation cache."""
-    hits = []
-
-    def on_event(event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            hits.append(event)
-
-    jax.monitoring.register_event_listener(on_event)
-    try:
-        return lowered.compile(), bool(hits)
-    finally:
-        jax.monitoring.unregister_event_listener(on_event)
+    compile was answered from the persistent compilation cache: the launch's
+    listeners count it (after the launch they are registered for this call)."""
+    with counters:
+        hits = counters.cache_hits
+        return lowered.compile(), counters.cache_hits > hits
 
 
 def optimizer_args_from(args) -> OptimizerArgs:
@@ -164,9 +164,11 @@ def train(args) -> dict:
             args.telemetry, depth=max(int(getattr(args, "telemetry_buffer", 1024) or 1), 1)
         )
         telemetry.install(sink)
+    started = launch.Launch()
     try:
-        return _train(args)
+        return _train(args, started)
     finally:
+        started.close()  # a run that ended before its first drain still holds the listeners
         if sink is not None:
             telemetry.uninstall(sink)
             sink.close()
@@ -179,7 +181,19 @@ def _parse_trace_steps(spec) -> tuple:
     return lo, int(hi) if hi else lo
 
 
-def _train(args) -> dict:
+def _train(args, started: launch.Launch) -> dict:
+    # the run's one trace control (obs/tracing.py): whoever holds `args` (an
+    # on_step hook, a test, the benchmark) may request a trace of coming
+    # steps through it at any time; --xla_trace is one request made here
+    control = getattr(args, "trace_control", None)
+    if control is None:
+        control = args.trace_control = tracing.TraceControl()
+    if getattr(args, "xla_trace", None):
+        control.request(args.xla_trace,
+                        *_parse_trace_steps(getattr(args, "trace_steps", None)))
+    # the launch's phases (obs/launch.py), consecutive from here to the first
+    # step's drain; what runs between two of them is `launch_unspanned_pct`
+    started.begin(control, tracing.LAUNCH_PLAN)
     cache_path = enable_persistent_cache()
     if jax.process_index() == 0:
         print("persistent compilation cache: %s" % cache_path)
@@ -259,6 +273,7 @@ def _train(args) -> dict:
             telemetry.emit("log", message="layer-run prediction skipped: %s" % e)
         for p in predictions or ():
             telemetry.emit("layer_run", **p)
+    started.end()
 
     # ------------------------------------------------------------- resilience
     res = rsl.ResilienceCounters()
@@ -268,15 +283,6 @@ def _train(args) -> dict:
     )
     # fault-injection seam (tests/runtime/fault_injection.py); None in prod
     hooks = getattr(args, "fault_hooks", None)
-    # the run's one trace control (obs/tracing.py): whoever holds `args` (an
-    # on_step hook, a test, the benchmark) may request a trace of coming
-    # steps through it at any time; --xla_trace is one request made here
-    control = getattr(args, "trace_control", None)
-    if control is None:
-        control = args.trace_control = tracing.TraceControl()
-    if getattr(args, "xla_trace", None):
-        control.request(args.xla_trace,
-                        *_parse_trace_steps(getattr(args, "trace_steps", None)))
     guard = None
     if getattr(args, "anomaly_guard", 0):
         guard = rsl.AnomalyGuard(rsl.AnomalyGuardConfig(
@@ -287,6 +293,7 @@ def _train(args) -> dict:
         ))
     verify_ckpt = bool(getattr(args, "verify_checkpoint", 1))
 
+    started.begin(control, tracing.LAUNCH_BUILD)
     # families with their own param tree (t5/swin) supply a build hook
     model = fam.build(cfg, hp) if fam.build else construct_hybrid_parallel_model(cfg, hp)
     tx, _sched = get_optimizer_and_scheduler(optimizer_args_from(args))
@@ -382,9 +389,11 @@ def _train(args) -> dict:
             wire_mb_fp32=(wire or {}).get("fp32"),
             wire_mb_configured=(wire or {}).get("configured"),
         )
+    started.end()
 
-    params = model.init_params(jax.random.PRNGKey(args.seed))
-    opt_state = model.init_opt_state(tx, params)
+    with started.phase(control, tracing.LAUNCH_INIT_STATE):
+        params = model.init_params(jax.random.PRNGKey(args.seed))
+        opt_state = model.init_opt_state(tx, params)
 
     def load_from(ckpt_dir, iteration):
         # retries live INSIDE load_checkpoint now (around the manifest reads
@@ -414,7 +423,8 @@ def _train(args) -> dict:
     start_iter = 0
     if args.load:
         fresh_opt_state = opt_state
-        params, opt_state, meta = load_from(args.load, args.load_iteration)
+        with started.phase(control, tracing.LAUNCH_RESTORE):
+            params, opt_state, meta = load_from(args.load, args.load_iteration)
         if opt_state is None:
             # params-only checkpoint (h2g conversion): optimizer starts fresh
             opt_state = fresh_opt_state
@@ -485,7 +495,8 @@ def _train(args) -> dict:
             fn = hooks.wrap_step_fn(fn)
         return fn
 
-    step_fn = build_step_fn()
+    with started.phase(control, tracing.LAUNCH_BUILD):
+        step_fn = build_step_fn()
 
     # Separate the one-off program-build cost (trace + XLA compile) from the
     # steady-state step time: AOT-lower and compile at the first batch with
@@ -500,40 +511,39 @@ def _train(args) -> dict:
     _aot = {"fn": None}
 
     def compiled_step(*step_args):
-        if not hasattr(step_fn, "lower"):
+        if not hasattr(step_fn, "trace"):
             return step_fn(*step_args)
         if _aot["fn"] is None:
             with control.span(tracing.COMPILE):
-                t0 = time.perf_counter()
-                delta_rule_took = collections.Counter(linear_attention.TOOK)
-                moe_rows_took = collections.Counter(moe.ROWS_TOOK)
-                moe_windows_took = collections.Counter(moe.WINDOWS_TOOK)
-                kernels_relaid = collections.Counter(mlp.RELAID)
-                windows_took = collections.Counter(attention_ops.TOOK)
-                lowered = step_fn.lower(*step_args)
-                windows_took = attention_ops.TOOK - windows_took
-                delta_rule_took = linear_attention.TOOK - delta_rule_took
-                moe_rows_took = moe.ROWS_TOOK - moe_rows_took
-                moe_windows_took = moe.WINDOWS_TOOK - moe_windows_took
-                kernels_relaid = mlp.RELAID - kernels_relaid
-                t1 = time.perf_counter()
-                key = _step_exec_key(model.mesh, lowered)
-                compiled = _STEP_EXECUTABLES.get(key)
-                memo_hit = compiled is not None
-                cache_hit = False
-                if memo_hit:
-                    _STEP_EXECUTABLES.move_to_end(key)
-                else:
-                    compiled, cache_hit = _compile_step(lowered)
-                    _STEP_EXECUTABLES[key] = compiled
-                    while len(_STEP_EXECUTABLES) > _STEP_EXECUTABLES_MAX:
-                        _STEP_EXECUTABLES.popitem(last=False)
-                t2 = time.perf_counter()
-            # a memo or persistent-cache hit reports compile_ms ~0 — true:
-            # this process did not run XLA again for this program
-            prof.record_compile(trace_ms=(t1 - t0) * 1e3,
-                                compile_ms=(t2 - t1) * 1e3,
-                                cache_hit=cache_hit)
+                with launch.CounterDeltas(**KERNEL_FORMS) as forms:
+                    with started.phase(control, tracing.COMPILE_TRACE) as traced_in:
+                        traced = step_fn.trace(*step_args)
+                    with started.phase(control, tracing.COMPILE_LOWER) as lowered_in:
+                        lowered = traced.lower()
+                with started.phase(control, tracing.COMPILE_KEY) as keyed_in:
+                    key = _step_exec_key(model.mesh, lowered)
+                with started.phase(control, tracing.COMPILE_LOAD) as loaded_in:
+                    compiled = _STEP_EXECUTABLES.get(key)
+                    memo_hit = compiled is not None
+                    cache_hit = False
+                    if memo_hit:
+                        _STEP_EXECUTABLES.move_to_end(key)
+                    else:
+                        compiled, cache_hit = _compile_step(lowered, started.jit)
+                        _STEP_EXECUTABLES[key] = compiled
+                        while len(_STEP_EXECUTABLES) > _STEP_EXECUTABLES_MAX:
+                            _STEP_EXECUTABLES.popitem(last=False)
+            if started.open:
+                started.begin(control, tracing.LAUNCH_FIRST_RUN)
+            # trace_ms is jaxpr and MLIR, compile_ms the memo's key and the
+            # executable's load. A memo or persistent-cache hit reports
+            # compile_ms ~0 — true: this process did not run XLA again for
+            # this program
+            trace_ms, compile_ms = traced_in.ms + lowered_in.ms, keyed_in.ms + loaded_in.ms
+            prof.record_compile(trace_ms=trace_ms, compile_ms=compile_ms, cache_hit=cache_hit)
+            delta_rule_took, moe_rows_took = forms.took["delta_rule"], forms.took["moe_rows"]
+            moe_windows_took, kernels_relaid = forms.took["moe_windows"], forms.took["kernels_relaid"]
+            windows_took = forms.took["windows"]
             prof.compiled_memory_mb = compiled_step_memory_mb(compiled) or None
             # (asked only of a model that traced such a layer: T5's config has no kinds)
             scalar_rule = delta_rule_took["xla"] or delta_rule_took["pallas"]
@@ -543,8 +553,8 @@ def _train(args) -> dict:
             window_layers = sum(kind.startswith("window") for kind in cfg.layer_kinds()) if windows_took else 0
             telemetry.emit(
                 "compile",
-                trace_ms=(t1 - t0) * 1e3,
-                compile_ms=(t2 - t1) * 1e3,
+                trace_ms=trace_ms,
+                compile_ms=compile_ms,
                 compiled_memory_mb=prof.compiled_memory_mb,
                 xla_flops_per_step=obs_flops.xla_flops(compiled),
                 cache_hit=(memo_hit or cache_hit) or None,
@@ -708,7 +718,8 @@ def _train(args) -> dict:
                            description="dataloader")
         return model.shard_batch(b)
 
-    open_stream(start_iter)
+    with started.phase(control, tracing.LAUNCH_DATA):
+        open_stream(start_iter)
 
     eval_interval = getattr(args, "eval_interval", 0) or 0
     eval_iters = max(getattr(args, "eval_iters", 5) or 0, 1)
@@ -832,6 +843,10 @@ def _train(args) -> dict:
         d_it, metrics, disp_ms, wait_ms = inflight.popleft()
         with control.span(tracing.DRAIN):  # the blocking read
             prof.end(d_it, n_samples=hp.global_bsz, outputs=metrics["loss"])
+        if started.open:
+            # the first step has drained: the launch is over, its listeners go
+            prof.launch = started.finish()
+            telemetry.emit("launch", **prof.launch)
         if wd is not None:
             # a drain is the loop's liveness signal AND the deadline's
             # training data (the learned budget tracks the steady step time)
@@ -1279,7 +1294,9 @@ def _train(args) -> dict:
             # a requested trace starts here, before the fetch, so that the
             # first traced step's gt/next_batch is in it
             control.before_dispatch(it)
-            with control.span(tracing.NEXT_BATCH) as fetch:
+            # the wait for the first batch is the launch's; first_run holds the later ones
+            first_fetch = started.phase(control, tracing.LAUNCH_DATA) if started.open else tracing.OFF
+            with first_fetch, control.span(tracing.NEXT_BATCH) as fetch:
                 batch = next_batch()
             prof.start(it)
             with control.span(tracing.DISPATCH, step_num=it):

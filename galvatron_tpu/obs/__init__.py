@@ -11,11 +11,15 @@ is the measurement substrate that closes it at runtime:
 - ``obs.attribution`` — the predicted-vs-measured divergence table: the
   search engine's TimeCostModel/MemoryCostModel prediction per LayerRun next
   to measured steady-state step time and compiled-step memory.
+- ``obs.launch``      — where a start went: the program's import by package,
+  the phases of ``cli/train._train`` up to the first drained step, and jax's
+  trace / lower / compilation-cache counters on the way (the ``launch`` event,
+  the summary's ``launch_ms`` / ``launch_imports`` / ``launch_jit``).
 - ``obs.report``      — offline analysis of a telemetry JSONL
   (``python -m galvatron_tpu.cli report``): steady-state detection, MFU,
   lifecycle timeline, divergence table.
 
-Import-light on purpose: ``telemetry``/``flops``/``report`` are stdlib-only
+Import-light on purpose: ``telemetry``/``flops``/``report``/``launch`` are stdlib-only
 at module scope (jax is touched only inside functions that receive jax
 objects), so the offline report path never initialises an accelerator
 backend.

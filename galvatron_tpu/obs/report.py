@@ -292,6 +292,8 @@ def analyze(
                 [e.get("data_wait_ms") for e in steps]),
         },
         "steady": steady,
+        "launch": {k: v for k, v in (by_type.get("launch") or [{}])[-1].items()
+                   if k not in ("v", "t", "seq", "type")},
         "compile": {k: v for k, v in compile_ev.items()
                     if k not in ("v", "t", "seq", "type")},
         "anomalies": {
@@ -346,6 +348,37 @@ def _fmt(v) -> str:
     return str(v)
 
 
+def _render_launch(launch: Dict[str, Any]) -> List[str]:
+    """The `launch` event as a table: why this (re)launch took what it took."""
+    ms = dict(launch.get("launch_ms") or {})
+    total = ms.pop("total", None)
+    spanned = sum(ms.values())
+    lines = ["launch: %s s to the first drained step, %s %% of it outside the phases" % (
+        _fmt(total / 1e3 if total else None),
+        _fmt(100.0 * (1.0 - spanned / total) if total else None))]
+    lines.extend("  %-24s %10.1f ms" % (name, value) for name, value in ms.items())
+    imports = launch.get("launch_imports")
+    if imports:
+        by_package = sorted(imports["by_package_s"].items(), key=lambda kv: -kv[1])
+        lines.append("  import of the program: %s s, %d modules; self seconds by package: %s" % (
+            _fmt(imports["total_s"]), imports["modules"],
+            ", ".join("%s %.2f" % kv for kv in by_package)))
+        lines.append("  of it galvatron_tpu.runtime.checkpoint (orbax), inclusive: %s s"
+                     % _fmt(imports["checkpoint_s"]))
+    jit = launch.get("launch_jit")
+    if jit:
+        lines.append(
+            "  jax: %d jit traces, %d lowerings (%s s); compilation cache %d requests, %d hits, "
+            "%d misses, read %s s; backend compile %s s" % (
+                jit["jit_traces"], jit["lowerings"], _fmt(jit["lowering_s"]),
+                jit["cache_requests"], jit["cache_hits"], jit["cache_misses"],
+                _fmt(jit["cache_retrieval_s"]), _fmt(jit["backend_compile_s"])))
+        lines.append("  most traced: " + ", ".join(
+            "%s x%d %.2f s" % (row["fun_name"], row["count"], row["trace_s"])
+            for row in jit["top_traced"]))
+    return lines
+
+
 def render(analysis: Dict[str, Any]) -> str:
     run = analysis["run"]
     steps = analysis["steps"]
@@ -372,6 +405,8 @@ def render(analysis: Dict[str, Any]) -> str:
            _fmt(steady.get("steps_per_s")), _fmt(steady.get("model_flops_per_s")),
            _fmt(steady.get("mfu")))
     )
+    if analysis.get("launch"):
+        lines.extend(_render_launch(analysis["launch"]))
     comp = analysis["compile"]
     if comp:
         lines.append(

@@ -98,6 +98,13 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
          "shortconv_layers", "kernel_grads_relaid", "window_kernel_layers",
          "window_operands_as_projected"),
     ),
+    # where the start went, once the first step has drained (obs/launch.py):
+    # `launch_ms` the phases of cli/train._train by name (obs/tracing.py's
+    # gt/launch/* and gt/compile/*) and `total`, its entry to that drain;
+    # `launch_imports` the program's import (total_s, modules, by_package_s,
+    # checkpoint_s); `launch_jit` what jax traced, lowered and asked its
+    # compilation cache for on the way (counts, seconds, top_traced)
+    "launch": ((), ("launch_ms", "launch_imports", "launch_jit")),
     # the per-step record (emitted at drain time under the dispatch-ahead
     # loop; iter_ms is dispatch->drain latency, which overlaps across steps)
     "step": (

@@ -16,7 +16,9 @@ regex from the trace.
 the device ops: one clock, so a gap on the device can be laid against the
 phase the host was in. While a telemetry sink is installed a span is timed
 (`.ms`), which is how `data_wait_ms` reaches the `step` event. With neither,
-`span()` returns the shared do-nothing `OFF` after one attribute read.
+`span()` returns the shared do-nothing `OFF` after one attribute read, unless
+the caller asks for a `timed` one: the phases of a launch (obs/launch.py),
+which are recorded always.
 
 **TraceControl**. One object a training run (`args.trace_control`, made by
 `cli/train._train` if absent). Any host code of the process that holds the
@@ -129,6 +131,26 @@ ON_STEP = "gt/on_step"
 EVAL = "gt/eval"
 SAVE = "gt/save"
 COMPILE = "gt/compile"
+# the phases of a launch (obs/launch.Launch; cli/train._train marks them):
+# consecutive from _train's entry to the first step's drain, each a key of the
+# summary's `launch_ms` and of the `launch` telemetry event
+LAUNCH_PLAN = "gt/launch/plan"  # the cache's path, model and strategy, the strategy lint, FLOPs, predictions
+# the model, the optimizer, --trace_lint, the tp-overlap and quantised-
+# collective probes of an observed run, the step function
+LAUNCH_BUILD = "gt/launch/build"
+# init_params and init_opt_state: host time, their compilations or cache reads
+# in it; what the device still owes falls into the first run
+LAUNCH_INIT_STATE = "gt/launch/init_state"
+LAUNCH_RESTORE = "gt/launch/restore"  # load_checkpoint, under --load alone
+LAUNCH_DATA = "gt/launch/data"  # the iterator, the prefetcher's start, the wait for the first batch
+# the four children of gt/compile: trace_ms is the first two, compile_ms the last two
+COMPILE_TRACE = "gt/compile/trace"  # step_fn.trace(): the jaxpr
+COMPILE_LOWER = "gt/compile/lower"  # .lower(): MLIR, every Pallas body's Mosaic lowering in it
+COMPILE_KEY = "gt/compile/key"  # the memo's key: as_text() of the whole module and its sha256
+COMPILE_LOAD = "gt/compile/load"  # the persistent cache's read on a hit, XLA on a miss; ~0 on a memo hit
+# the end of gt/compile to the first step's drain: the program's load, the
+# first execution, the dispatches and on_step calls of the steps sent behind it
+LAUNCH_FIRST_RUN = "gt/launch/first_run"
 
 
 class _Off:
@@ -152,6 +174,7 @@ class _Span:
 
     def __init__(self, name: str, annotate: bool, step_num: Optional[int]):
         self.ms: Optional[float] = None
+        self.t0: Optional[float] = None  # its start on time.perf_counter
         self._annotation = None
         if annotate:
             self._annotation = (
@@ -161,11 +184,11 @@ class _Span:
     def __enter__(self):
         if self._annotation is not None:
             self._annotation.__enter__()
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc_info):
-        self.ms = (time.perf_counter() - self._t0) * 1e3
+        self.ms = (time.perf_counter() - self.t0) * 1e3
         if self._annotation is not None:
             self._annotation.__exit__(*exc_info)
         return False
@@ -195,9 +218,9 @@ class TraceControl:
         self._pending = (str(directory), int(first_step), int(last_step))
         return True
 
-    def span(self, name: str, step_num: Optional[int] = None):
+    def span(self, name: str, step_num: Optional[int] = None, timed: bool = False):
         """A context around one phase of the host loop (module docstring)."""
-        if not self.spans_on:
+        if not (self.spans_on or timed):
             return OFF
         return _Span(name, self._running is not None, step_num)
 

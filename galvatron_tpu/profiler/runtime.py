@@ -102,6 +102,10 @@ class RuntimeProfiler:
     compile_ms: Optional[float] = None  # XLA compile walltime of the step
     compile_cache_hit: Optional[bool] = None  # step answered from the
     # persistent compilation cache (utils/compile_cache.py)
+    # where the start went (obs/launch.Launch.fields(), set by the driver once
+    # the first step has drained): `launch_ms` by phase, `launch_imports` by
+    # package, `launch_jit`'s counters; the summary carries the three keys
+    launch: Optional[Dict[str, object]] = None
     # MFU accounting (obs/flops.py): the driver sets the per-step model
     # FLOPs and the chip's peak so the summary can report MFU and
     # model-FLOPs/s next to every timing number
@@ -242,6 +246,8 @@ class RuntimeProfiler:
             out["compile_cache_hit"] = self.compile_cache_hit
         if self.compiled_memory_mb is not None:
             out["compiled_step_memory_mb"] = self.compiled_memory_mb
+        if self.launch is not None:
+            out.update(self.launch)
         if self.model_flops:
             # MFU from the honest steady-state rate: fenced wall time per
             # post-warmup dispatch when available (iter_ms latencies overlap

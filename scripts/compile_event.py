@@ -9,7 +9,9 @@ training command with a sink from the start and ends the run after two steps
     chiprun -- python3 scripts/compile_event.py <cell>
 
 and prints the event as one line of JSON, its last line of output (one cell a
-process: a second model does not fit beside the first's state)."""
+process: a second model does not fit beside the first's state). The line
+before it is the run's `launch` event (`launch_ms`, `launch_imports`,
+`launch_jit`: where the start went, obs/launch.py)."""
 
 from __future__ import annotations
 
@@ -38,8 +40,9 @@ def main(workload: str) -> int:
         train(args)
     finally:
         telemetry.uninstall(sink)
-    event = next(e for e in sink.events if e["type"] == "compile")
-    print(json.dumps({"workload": workload, **{k: v for k, v in event.items() if k != "type"}}), flush=True)
+    for kind in ("launch", "compile"):
+        event = next(e for e in sink.events if e["type"] == kind)
+        print(json.dumps({"workload": workload, **{k: v for k, v in event.items() if k != "type"}}), flush=True)
     return 0
 
 

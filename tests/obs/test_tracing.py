@@ -127,6 +127,114 @@ def test_data_wait_ms_is_in_the_schema_and_in_a_run_with_a_sink(requested_run):
     assert all(e["dispatch_ms"] > 0 for e in steps)
 
 
+# ------------------------------------------------------------- the launch
+def jax_listeners():
+    from jax._src import monitoring
+
+    return (len(monitoring.get_event_listeners()), len(monitoring.get_event_duration_listeners()))
+
+
+PHASES = [tracing.LAUNCH_PLAN, tracing.LAUNCH_BUILD, tracing.LAUNCH_INIT_STATE, tracing.LAUNCH_BUILD,
+          tracing.LAUNCH_DATA, tracing.LAUNCH_DATA, tracing.COMPILE_TRACE, tracing.COMPILE_LOWER,
+          tracing.COMPILE_KEY, tracing.COMPILE_LOAD, tracing.LAUNCH_FIRST_RUN]
+
+
+def test_the_launchs_phases_are_consecutive_and_cover_the_start(requested_run):
+    _, _, summary, events = requested_run
+    ms = dict(summary["launch_ms"])
+    total = ms.pop("total")
+    # in the order they were first entered; no restore without --load
+    assert list(ms) == list(dict.fromkeys(PHASES))
+    assert all(v >= 0 for v in ms.values()) and total > 0
+    assert sum(ms.values()) <= total and sum(ms.values()) == pytest.approx(total, rel=0.05)
+
+
+def test_trace_ms_and_compile_ms_are_their_two_halves(requested_run):
+    _, _, summary, events = requested_run
+    ms = summary["launch_ms"]
+    assert summary["trace_ms"] == pytest.approx(ms[tracing.COMPILE_TRACE] + ms[tracing.COMPILE_LOWER], abs=1e-6)
+    assert summary["compile_ms"] == pytest.approx(ms[tracing.COMPILE_KEY] + ms[tracing.COMPILE_LOAD], abs=1e-6)
+    compiled = next(e for e in events if e["type"] == "compile")
+    assert (compiled["trace_ms"], compiled["compile_ms"]) == (summary["trace_ms"], summary["compile_ms"])
+    assert ms[tracing.COMPILE_TRACE] > 0 and ms[tracing.COMPILE_LOWER] > 0 and ms[tracing.COMPILE_KEY] > 0
+
+
+def test_one_launch_event_after_the_first_drain_says_what_the_summary_says(requested_run):
+    _, _, summary, events = requested_run
+    launched = [e for e in events if e["type"] == "launch"]
+    assert len(launched) == 1
+    telemetry.validate_event(launched[0])
+    fields = {k: launched[0][k] for k in ("launch_ms", "launch_imports", "launch_jit")}
+    assert fields == {k: summary[k] for k in fields}
+    kinds = [(e["type"], e.get("iter")) for e in events if e["type"] in ("compile", "launch", "step")]
+    # step 0 drains once step 2 has been sent: the compile, the launch, then step 0's own event
+    assert kinds[:3] == [("compile", None), ("launch", None), ("step", 0)]
+    assert summary["launch_imports"]["total_s"] > 0 and summary["launch_imports"]["modules"] > 0
+
+
+def test_the_launchs_counters_saw_the_step_and_the_initialisers(requested_run):
+    _, _, summary, _ = requested_run
+    jit = summary["launch_jit"]
+    traced = {row["fun_name"]: row for row in jit["top_traced"]}
+    assert traced["train_step"]["count"] == 1 and len(traced) == 10
+    assert traced["train_step"]["trace_s"] * 1e3 <= summary["launch_ms"][tracing.COMPILE_TRACE]
+    assert jit["jit_traces"] >= sum(row["count"] for row in traced.values())
+    assert jit["lowerings"] >= 1 and jit["lowering_s"] > 0  # the step, and what else no test before had run
+    assert jit["cache_requests"] >= jit["cache_hits"] + jit["cache_misses"]
+    assert jit["backend_compile_s"] >= 0 and jit["cache_retrieval_s"] >= 0
+
+
+@pytest.fixture(scope="module")
+def second_run(requested_run):
+    """The same step a second time in the process (the memo holds its
+    executable), and jax's listener lists before, inside and after."""
+    args, seen = tiny_args(), {"before": jax_listeners()}
+
+    def on_step(it):
+        seen[it] = jax_listeners()
+        args.train_iters = 5  # the same program (the schedule is built for ITERS), ended early
+
+    summary = train(hooked(args, on_step))
+    seen["after"] = jax_listeners()
+    return summary, seen
+
+
+def test_a_second_train_in_the_process_has_a_launch_of_its_own(requested_run, second_run):
+    first, (second, _) = requested_run[2], second_run
+    assert list(second["launch_ms"]) == list(first["launch_ms"])
+    assert second["launch_ms"][tracing.COMPILE_LOAD] < 5.0  # the memo's hit: nothing to load
+    assert second["launch_ms"] != first["launch_ms"] and second["launch_jit"] != first["launch_jit"]
+    assert second["compile_ms"] == pytest.approx(
+        second["launch_ms"][tracing.COMPILE_KEY] + second["launch_ms"][tracing.COMPILE_LOAD], abs=1e-6)
+    # the step was traced again and compiled by nobody; the counters started at 0
+    assert second["launch_jit"]["top_traced"][0]["fun_name"] == "train_step"
+    assert second["launch_jit"]["top_traced"][0]["count"] == 1
+    assert second["launch_jit"]["lowerings"] <= first["launch_jit"]["lowerings"]
+    # the import is the process's one
+    assert second["launch_imports"] == first["launch_imports"]
+
+
+def test_the_listener_pair_stands_from_the_entry_to_the_first_drain_and_no_longer(second_run):
+    _, seen = second_run
+    # on_step(0..2) run before step 0 has drained, on_step(3) after it
+    assert seen[0] == seen[1] == seen[2] == (seen["before"][0] + 1, seen["before"][1] + 1)
+    assert seen[3] == seen[4] == seen["after"] == seen["before"]
+
+
+def test_a_train_that_raises_before_its_first_drain_leaves_no_listener(devices8, monkeypatch):
+    from galvatron_tpu.cli import train as T
+
+    def refuse(args):
+        assert jax_listeners() == (before[0] + 1, before[1] + 1)
+        raise RuntimeError("no such model")
+
+    before = jax_listeners()
+    monkeypatch.setattr(T, "model_config_from_args", refuse)
+    with pytest.raises(RuntimeError, match="no such model"):
+        train(tiny_args())
+    assert jax_listeners() == before
+
+
 def test_nothing_requested_never_starts_the_profiler(devices8, profiler_log):
     args = tiny_args()
     summary = train(args)
