@@ -25,7 +25,7 @@ from galvatron_tpu.cli.arguments import (
     initialize_galvatron,
     model_config_from_args,
 )
-from galvatron_tpu.models.parts import mlp
+from galvatron_tpu.models.parts import embed_head, mlp
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import launch, telemetry, tracing
 from galvatron_tpu.ops import attention as attention_ops
@@ -50,7 +50,7 @@ launch.IMPORTS.done()  # the program is imported: the import record closes and g
 # the model code is traced; `compiled_step` reads what the lowering added
 KERNEL_FORMS = dict(
     delta_rule=linear_attention.TOOK, moe_rows=moe.ROWS_TOOK, moe_windows=moe.WINDOWS_TOOK,
-    kernels_relaid=mlp.RELAID, windows=attention_ops.TOOK)
+    kernels_relaid=mlp.RELAID, windows=attention_ops.TOOK, lookups=embed_head.LOOKUPS_TOOK)
 
 
 # In-process memo of AOT-compiled train-step executables, keyed by (device
@@ -603,6 +603,11 @@ def _train(args, started: launch.Launch) -> dict:
                 # all of a model's or none; 0 off a TPU, where every such layer
                 # is scanned and where there is none
                 kernel_grads_relaid=len(kernels_relaid),
+                # 1 where the step looks the vocabulary-split token table up
+                # with ids, rows and cotangents crossing dp and the table
+                # staying split over it (`embed_head.vocab_parallel_lookup`'s
+                # second form), else 0
+                table_rows_over_dp=int(forms.took["lookups"]["rows_over_dp"] > 0),
             )
             _aot["fn"] = compiled
         return _aot["fn"](*step_args)

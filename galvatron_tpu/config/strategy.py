@@ -46,11 +46,13 @@ from galvatron_tpu.utils.strategy_utils import array2str, str2array
 # "zero2": the moments, the accumulated gradient (a reduce-scatter) AND the
 #   float32 parameters split over dp; once a step the step gathers a copy in
 #   the compute dtype (`compute_params`, scope gt.param_gather) of every leaf
-#   the model reads only through a cast to it. A leaf read in float32 (norm
-#   scales, the router, the `vocab_tp` table's rows) stays whole over dp and
-#   is gathered in float32 after the update, as is every leaf under float32
-#   compute, pp > 1, the manual TP path or the quantized grad sync
-#   (`HybridParallelModel.copied_leaves` has the conditions and why).
+#   the model reads only through a cast to it. The looked-up, untied
+#   `vocab_tp` table is split over dp too and never gathered: its lookup
+#   sends ids, rows and cotangents over dp (`state_specs`' third case). Any
+#   other leaf read in float32 (norm scales, the router, a tied table) stays
+#   whole over dp and is gathered in float32 after the update, as is every
+#   leaf under float32 compute, pp > 1, the manual TP path or the quantized
+#   grad sync (`HybridParallelModel.copied_leaves` has the conditions and why).
 # "zero3": parameters split over dp in `param_specs` itself and gathered at
 #   each use (`param_comm_dtype` is the wire dtype of THAT gather only).
 DP_TYPES = ("ddp", "zero2", "zero3")

@@ -503,7 +503,10 @@ def padding_attn_bias(attn_mask: jax.Array) -> jax.Array:
 
 def model_forward(params, tokens, positions, cfg, hp=None, mesh=None, **inputs) -> jax.Array:
     """Full forward to logits (single pipeline stage; pipelined execution lives
-    in parallel/pipeline.py)."""
+    in parallel/pipeline.py). `table_spec`, here and in the loss functions
+    below: the spec the token table is STORED in where that is not
+    `param_specs`' (runtime/model_api.state_specs), which chooses the form of
+    a vocabulary-split table's lookup (embed_head.vocab_parallel_lookup)."""
     return _forward(params, tokens, positions, cfg, hp, mesh, **inputs)[0]
 
 
@@ -516,6 +519,7 @@ def _forward(
     mesh: Optional[Mesh] = None,
     token_type_ids: Optional[jax.Array] = None,
     attn_mask: Optional[jax.Array] = None,
+    table_spec: Optional[P] = None,
 ):
     """-> (logits, the last layer's output before the final norm, the routed
     blocks' auxiliary terms as `run_layers` lists them or None)."""
@@ -528,7 +532,7 @@ def _forward(
             x = embed_patches(params["embed"], tokens, cfg)
         else:
             x = embed_tokens(params["embed"], tokens, positions, cfg, mesh, vax,
-                             token_type_ids=token_type_ids)
+                             token_type_ids=token_type_ids, table_spec=table_spec)
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
     bias = padding_attn_bias(attn_mask) if attn_mask is not None else None
@@ -546,7 +550,8 @@ def _forward(
 
 
 def mtp_logits(params: Params, hidden: jax.Array, batch, cfg: TransformerConfig,
-               hp: Optional[HybridParallelConfig] = None, mesh: Optional[Mesh] = None):
+               hp: Optional[HybridParallelConfig] = None, mesh: Optional[Mesh] = None,
+               table_spec: Optional[P] = None):
     """The multi-token-prediction module (DeepSeek-V3's, depth 1; arXiv:2412.19437
     2.2): for position i of a sequence t,
 
@@ -565,7 +570,8 @@ def mtp_logits(params: Params, hidden: jax.Array, batch, cfg: TransformerConfig,
     axes = layer_axes(hp, last) if use_hp else None
     positions = batch["positions"]
     with jax.named_scope(tracing.MTP):
-        e = embed_tokens(params["embed"], batch["labels"], positions, cfg, mesh, vax)
+        e = embed_tokens(params["embed"], batch["labels"], positions, cfg, mesh, vax,
+                         table_spec=table_spec)
         m = jnp.concatenate([_norm(hidden, mp["hnorm"], cfg), _norm(e, mp["enorm"], cfg)], axis=-1)
         m = _dense(m, mp["eh_proj"], dtype)
         if use_hp:
@@ -595,7 +601,8 @@ PART_FOLDS = {EXPERT_LOAD: jnp.maximum, "router_bias_abs_max": jnp.maximum,
               "linear_state_abs_max": jnp.maximum, "ssm_state_abs_max": jnp.maximum}
 
 
-def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False):
+def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
+               table_spec: Optional[P] = None):
     """batch: dict(tokens, positions, labels, loss_mask?, token_type_ids?,
     attn_mask?). Serves lm and mlm heads (token-level CE).
 
@@ -614,13 +621,14 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False)
     logits, hidden, auxs = _forward(
         params, batch["tokens"], batch["positions"], cfg, hp, mesh,
         token_type_ids=batch.get("token_type_ids"), attn_mask=batch.get("attn_mask"),
+        table_spec=table_spec,
     )
     labels, mask = batch["labels"], batch.get("loss_mask")
     with jax.named_scope(tracing.HEAD_LOSS):
         loss = vocab_parallel_cross_entropy(logits, labels, mask)
     parts = {"loss_ce": loss}
     if cfg.mtp_layers:
-        logits2, aux = mtp_logits(params, hidden, batch, cfg, hp, mesh)
+        logits2, aux = mtp_logits(params, hidden, batch, cfg, hp, mesh, table_spec)
         auxs = auxs + [aux] if aux is not None else auxs
         # the label of position i + 1, where it is one; none at a sequence's end
         ahead = jnp.ones(labels.shape, jnp.float32) if mask is None else mask.astype(jnp.float32)
@@ -685,12 +693,12 @@ def update_router_bias(params: Params, counts: jax.Array, rate: float) -> Params
     return out
 
 
-def classification_loss_fn(params, batch, cfg, hp=None, mesh=None):
+def classification_loss_fn(params, batch, cfg, hp=None, mesh=None, table_spec: Optional[P] = None):
     """batch: dict(pixels | tokens, labels). Mean softmax CE over classes
     (reference vit/swin `Cls_` heads)."""
     inputs = batch.get("pixels", batch.get("tokens"))
     logits = model_forward(params, inputs, batch.get("positions"), cfg, hp, mesh,
-                           attn_mask=batch.get("attn_mask"))
+                           attn_mask=batch.get("attn_mask"), table_spec=table_spec)
     with jax.named_scope(tracing.HEAD_LOSS):
         return softmax_nll(logits, batch["labels"])
 

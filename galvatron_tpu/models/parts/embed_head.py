@@ -3,8 +3,9 @@ lookup, and the head with its written backward and the cross entropies."""
 
 from __future__ import annotations
 
+import collections
 from functools import partial
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -16,8 +17,13 @@ from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import LayerAxes, mesh_axis_size
 
 
-def vocab_parallel_lookup(wte: jax.Array, tokens: jax.Array, dtype, mesh: Mesh,
-                          vax: LayerAxes) -> jax.Array:
+# form ("rows_over_dp", "table_whole") -> the lookups traced in it since the
+# process began: the trainer's compile report reads what a step's trace added
+LOOKUPS_TOOK = collections.Counter()
+
+
+def vocab_parallel_lookup(wte: jax.Array, tokens: jax.Array, dtype, mesh: Mesh, vax: LayerAxes,
+                          table_spec: Optional[P] = None) -> jax.Array:
     """Rows of a (vocab, hidden) table whose vocabulary is split over
     ``vax.tp``: Megatron's VocabParallelEmbedding (reference
     GPTModel_tensor_parallel.py:84-132), written out. Each device shifts the
@@ -25,31 +31,71 @@ def vocab_parallel_lookup(wte: jax.Array, tokens: jax.Array, dtype, mesh: Mesh,
     does not hold, and the partial results are summed over the tp axes.
 
     A manual region, not ``wte[tokens]`` left to GSPMD: there the gather and
-    its scatter-add are device-local and the psum is the only collective,
-    where GSPMD runs a one-hot matmul as a matmul and partitions the
-    scatter-add of a sharded gather with collective-permutes
-    (parallel/pipeline_1f1b.py embed_fwd). The rows are gathered from the
-    stored shard and cast afterwards, so the table's gradient accumulates
-    over repeated ids in the parameter's dtype. The result is whole over tp;
-    under Megatron-SP the caller's constraint to `act_spec` slices it into
-    sequence shards (the compiler makes a reduce-scatter of sum and slice)."""
+    its scatter-add are device-local, where GSPMD runs a one-hot matmul as a
+    matmul and partitions the scatter-add of a sharded gather with
+    collective-permutes (parallel/pipeline_1f1b.py embed_fwd). The rows are
+    gathered from the stored shard and cast afterwards, so the table's
+    gradient accumulates over repeated ids in the parameter's dtype. The
+    result is whole over tp; under Megatron-SP the caller's constraint to
+    `act_spec` slices it into sequence shards (the compiler makes a
+    reduce-scatter of sum and slice).
+
+    Two forms, chosen by `table_spec`, the spec the table is STORED in (None:
+    `vocab_embed_spec(vax)`, as `param_specs` places it):
+
+    - whole over dp, ``P(tp, None)``: the psum over tp is the only collective,
+      and the region's transpose sums the table's cotangent over dp;
+    - its hidden dim split over the ZeRO axes, ``P(tp, dp)`` (a ZeRO-3 table,
+      and the one ZeRO-2's train step stores in the layout of Adam's moments,
+      runtime/model_api.state_specs): the region is manual over those axes
+      too, and what crosses dp is the lookup's, not the table's. The ids are
+      gathered over dp, every replica's rows are read from the (vocab/tp,
+      hidden/dp) shard, and ONE all_to_all hands each replica its token rows
+      whole, (B, S, H/dp) -> (B/dp, S, H); the psum over tp comes last, on the
+      same operand as in the first form. Nothing is written for the backward:
+      the transposes give a scatter-add of every replica's cotangents into a
+      (vocab/tp, hidden/dp) array in the table's dtype, complete on its chip,
+      with no sum over dp. Token rows the dp axes do not divide are whole on
+      every replica already: no id gather and no all_to_all, the rows
+      leave the region split over dp on the hidden dim as the table is, and
+      GSPMD gathers them where the caller asks for them whole."""
     tp = tuple(vax.tp)
     rows = wte.shape[0] // mesh_axis_size(mesh, tp)
+    if table_spec is None:
+        table_spec = S.vocab_embed_spec(vax)
+    over = table_split_axes(table_spec, vax)
+    LOOKUPS_TOOK["rows_over_dp" if over else "table_whole"] += 1
 
     # serve hands in (1, ctx) and (slots, 1): rows the dp axes do not divide stay whole
     split_rows = tokens.shape[0] % mesh_axis_size(mesh, vax.batch_axes) == 0
     tok_spec = P(S._ax(vax.batch_axes) if split_rows else None, S._ax(vax.cp))
 
     def local(table, tok):
+        if over and split_rows:
+            tok = jax.lax.all_gather(tok, over, axis=0, tiled=True)
         idx = tok - jax.lax.axis_index(tp) * rows
         # an id of another device's rows goes out of bounds: the gather fills
         # it with zeros, and its transpose drops the update
         idx = jnp.where((idx >= 0) & (idx < rows), idx, rows)
-        return jax.lax.psum(table.at[idx].get(mode="fill", fill_value=0).astype(dtype), tp)
+        x = table.at[idx].get(mode="fill", fill_value=0).astype(dtype)
+        if over and split_rows:
+            x = jax.lax.all_to_all(x, over, 0, x.ndim - 1, tiled=True)
+        return jax.lax.psum(x, tp)
 
+    # whole token rows leave the region with the hidden dim as the table has
+    # it, and the caller's constraint gathers it
     return jax.shard_map(
-        local, mesh=mesh, in_specs=(P(S._ax(tp), None), tok_spec), out_specs=P(*tok_spec, None),
+        local, mesh=mesh, in_specs=(P(S._ax(tp), S._ax(over)), tok_spec),
+        out_specs=P(*tok_spec, None if split_rows else S._ax(over)),
     )(wte, tokens)
+
+
+def table_split_axes(table_spec: P, vax: LayerAxes) -> Tuple[str, ...]:
+    """The ZeRO axes of the vocabulary's `LayerAxes` where a token table
+    stored as `table_spec` is split over them on its hidden dim, else ()."""
+    hidden = S._entry_axes(table_spec[1]) if len(table_spec) > 1 else ()
+    zero = tuple(vax.dp) if vax.zero_opt else ()
+    return zero if zero and hidden == zero else ()
 
 
 def table_is_looked_up(vax: Optional[LayerAxes]) -> bool:
@@ -60,13 +106,16 @@ def table_is_looked_up(vax: Optional[LayerAxes]) -> bool:
 
 def embed_tokens(p_embed: Params, tokens: jax.Array, positions: jax.Array, cfg: TransformerConfig,
                  mesh: Optional[Mesh] = None, vax: Optional[LayerAxes] = None,
-                 token_type_ids: Optional[jax.Array] = None) -> jax.Array:
+                 token_type_ids: Optional[jax.Array] = None,
+                 table_spec: Optional[P] = None) -> jax.Array:
     """Token (+ position, + token-type) embedding. A table split over the
-    vocabulary (vocab_tp > 1, not ulysses) is read by `vocab_parallel_lookup`;
-    any other table is whole on the vocab dim and read by a plain gather."""
+    vocabulary (vocab_tp > 1, not ulysses) is read by `vocab_parallel_lookup`,
+    in the form the spec it is stored in asks for (`table_spec`; None: as
+    `param_specs` places it); any other table is whole on the vocab dim and
+    read by a plain gather."""
     wte = p_embed["wte"]
     if table_is_looked_up(vax):
-        x = vocab_parallel_lookup(wte, tokens, cfg.compute_dtype, mesh, vax)
+        x = vocab_parallel_lookup(wte, tokens, cfg.compute_dtype, mesh, vax, table_spec)
     else:
         x = wte.astype(cfg.compute_dtype)[tokens]
     if cfg.position_type == "learned":

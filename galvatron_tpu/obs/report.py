@@ -443,6 +443,8 @@ def render(analysis: Dict[str, Any]) -> str:
                          % comp["window_operands_as_projected"])
         if "kernel_grads_relaid" in comp:
             lines.append("gated kernels whose gradient is relaid to the state's layout: %d" % comp["kernel_grads_relaid"])
+        if comp.get("table_rows_over_dp"):
+            lines.append("the token table stays split over dp: its lookup sends ids, rows and cotangents")
     an = analysis["anomalies"]
     lines.append(
         "resilience: %d anomalies skipped, %d rollbacks, %d retries"

@@ -329,8 +329,10 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
         matmul's vjp is a matmul: dense and orderable, but it is run as a
         matmul over the whole vocabulary. Outside this schedule the split
         table is read by models/parts/embed_head.vocab_parallel_lookup (a manual region:
-        local gather, local scatter-add, one psum, no permute); moving this
-        copy onto it waits for a pp cell to measure it in."""
+        local gather, local scatter-add, one psum over tp, no permute; and
+        where the table is stored split over the ZeRO axes too, ids, rows and
+        cotangents exchanged over dp in place of the table); moving this copy
+        onto it waits for a pp cell to measure it in."""
         emb = vparams["embed"]
         dtype = cfg.compute_dtype
         if cfg.input_type == "patches":

@@ -119,7 +119,10 @@ def cast_first_tree(param_specs, *, table_stored: bool):
     token table: one that `vocab_parallel_lookup` takes into its manual
     region (rows gathered from the stored shard, the gradient scatter-added
     in the stored dtype), or a tied one, whose uses' cotangents are each
-    widened before they are summed.
+    widened before they are summed. Kept out of the copy is not gathered
+    after the update: the looked-up, untied table is stored split over dp
+    as well (runtime/model_api.state_specs' third case) and the lookup reads
+    it as it lies; a tied one lies as `param_specs` has it.
     tests/models/test_compute_copy.py holds this to every family's traced
     loss."""
 

@@ -74,12 +74,18 @@ class HybridParallelModel:
     # the leaves this family's loss reads only through a cast to the compute
     # dtype that comes first (parallel/spec.cast_first_tree); None (a custom
     # loss, whose reads nobody declared) gives no leaf a compute copy
+    table_as_stored: bool = False  # loss_fn and forward_fn look an untied
+    # `embed.wte` up by `vocab_parallel_lookup` in the form `table_spec()` asks
+    # for (construct_hybrid_parallel_model's own); False (a family that reads
+    # the table its own way) keeps it as `param_specs` lays it out, as a custom
+    # loss does (`_zero_splits_state`)
     # memoized NamedSharding trees per batch signature (key set + ranks), so
     # the per-step shard_batch is ONE device_put of the whole tree with no
     # per-key NamedSharding construction on the hot path
     _batch_shardings: Dict[Tuple, Dict[str, NamedSharding]] = field(
         default_factory=dict, repr=False)
     _copied: Optional[Params] = field(default=None, repr=False)  # memo of copied_leaves()
+    _stored: Optional[Params] = field(default=None, repr=False)  # memo of state_specs()
 
     @property
     def eval_loss(self) -> Callable:
@@ -100,6 +106,19 @@ class HybridParallelModel:
             is_leaf=_is_spec,
         )
 
+    def _zero_splits_state(self) -> bool:
+        """Whether ZeRO's dp axes may split a leaf of the STATE further than
+        `param_specs` does. Not where nobody declared how the loss reads its
+        leaves (a custom loss), nor where the model's code sums a leaf's
+        gradient itself, in the dtype and the layout the leaf comes in:
+        GPipe's scan (pp > 1) over the microbatches, the manual TP path's
+        regions over dp, the 1F1B engines (`grad_fn`) and the quantized grad
+        sync, whose regions are written for the `param_specs` layout."""
+        from galvatron_tpu.parallel import quant_collectives as QC
+
+        return not (self.cast_first is None or self.hp.pp > 1 or self.grad_fn is not None
+                    or self.hp.tp_comm_mode != "gspmd" or QC.wants_quant_comm(self.hp))
+
     def copied_leaves(self) -> Params:
         """Tree of bools like param_specs: the leaves ZeRO-2 stores split
         over dp, in the layout of Adam's moments and the accumulated
@@ -107,18 +126,13 @@ class HybridParallelModel:
         (`compute_params`). Such a leaf has ZeRO axes that split it further
         (not ddp, not dp = 1, not a ZeRO-3 leaf, which is dp-sharded
         already), is wider than the compute dtype, and is read by the model
-        only through a cast to it (`cast_first`). Every other leaf is stored
-        as `param_specs` lays it out for the forward, and gathered after the
-        update in its own dtype. No leaf is copied where the model's code
-        sums a leaf's gradient itself, in the dtype the leaf comes in:
-        GPipe's scan (pp > 1) over the microbatches, the manual TP path's
-        regions over dp, the 1F1B engines (`grad_fn`) and the quantized grad
-        sync, whose regions are also written for the `param_specs` layout."""
+        only through a cast to it (`cast_first`); and the state may be split
+        at all (`_zero_splits_state`). Of the other leaves the token table
+        may be stored split too, without a copy (`state_specs`); the rest is
+        stored as `param_specs` lays it out for the forward, and gathered
+        after the update in its own dtype."""
         if self._copied is None:
-            from galvatron_tpu.parallel import quant_collectives as QC
-
-            if (self.cast_first is None or self.hp.pp > 1 or self.grad_fn is not None
-                    or self.hp.tp_comm_mode != "gspmd" or QC.wants_quant_comm(self.hp)):
+            if not self._zero_splits_state():
                 self._copied = jax.tree.map(lambda _: False, self.param_specs, is_leaf=_is_spec)
             else:
                 narrow = jnp.dtype(self.cfg.compute_dtype).itemsize
@@ -137,19 +151,39 @@ class HybridParallelModel:
         tiling the compiler gives an entry parameter of that shape; nobody
         states another (PERF.md section 3, PR 48: where a gradient lies
         otherwise it is the gradient that is relaid, `parts/mlp.grad_as_stored`)
-        and a checkpoint holds the first two."""
-        return jax.tree.map(
-            lambda spec, split, copied: split if copied else spec,
-            self.param_specs, self.grad_accum_specs(), self.copied_leaves(),
-            is_leaf=_is_spec,
-        )
+        and a checkpoint holds the first two. Three cases:
+
+        - a copied leaf (`copied_leaves`) lies as `grad_accum_specs` splits it
+          and the step reads a gathered copy in the compute dtype;
+        - the token table of a loss that looks it up as stored
+          (`table_as_stored`) lies so too where the state may be split
+          (`_zero_splits_state`), and NOTHING gathers it: the lookup sends
+          ids, rows and cotangents over dp instead
+          (embed_head.vocab_parallel_lookup), its gradient comes out in this
+          layout, and the update returns it in this layout;
+        - every other leaf lies as `param_specs` has it."""
+        if self._stored is None:
+            split = self.grad_accum_specs()
+            self._stored = jax.tree.map(
+                lambda spec, split, copied: split if copied else spec,
+                self.param_specs, split, self.copied_leaves(), is_leaf=_is_spec,
+            )
+            if self.table_as_stored and self._zero_splits_state():
+                self._stored["embed"] = {**self._stored["embed"], "wte": split["embed"]["wte"]}
+        return self._stored
+
+    def table_spec(self) -> Optional[P]:
+        """The spec the token table is stored in (`state_specs`), which the
+        model's own losses hand to the lookup; None without a table."""
+        return self.state_specs().get("embed", {}).get("wte")
 
     def compute_params(self, params: Params) -> Params:
         """What forward, recomputation and backward read: of a copied leaf
         its value in the compute dtype, whole over dp as `param_specs` lays
         it out (ZeRO-2's parameter all-gather, at the compute dtype's bytes);
-        every other leaf as it is. The model's own `.astype(compute_dtype)`
-        of a copied leaf is then the identity."""
+        every other leaf as it is, a token table stored split among them
+        (`state_specs`). The model's own `.astype(compute_dtype)` of a copied
+        leaf is then the identity."""
         copied = self.copied_leaves()
         if not any(jax.tree.leaves(copied)):
             return params
@@ -552,26 +586,29 @@ def construct_hybrid_parallel_model(
         base_loss = make_pipelined_loss(cfg, hp, mesh)
         fwd = None
     elif cfg.head_type == "classification":
-        base_loss = lambda p, b: M.classification_loss_fn(p, b, cfg, hp, mesh)
+        base_loss = lambda p, b: M.classification_loss_fn(p, b, cfg, hp, mesh, model.table_spec())
         fwd = lambda p, b: M.model_forward(
             p, b.get("pixels", b.get("tokens")), b.get("positions"), cfg, hp, mesh,
-            attn_mask=b.get("attn_mask"),
+            attn_mask=b.get("attn_mask"), table_spec=model.table_spec(),
         )
         local_loss = lambda p, b: M.classification_loss_fn(p, b, cfg)
     else:
-        base_loss = lambda p, b: M.lm_loss_fn(p, b, cfg, hp, mesh)
+        base_loss = lambda p, b: M.lm_loss_fn(p, b, cfg, hp, mesh, table_spec=model.table_spec())
         fwd = lambda p, b: M.model_forward(
             p, b["tokens"], b["positions"], cfg, hp, mesh,
             token_type_ids=b.get("token_type_ids"), attn_mask=b.get("attn_mask"),
+            table_spec=model.table_spec(),
         )
         local_loss = lambda p, b: M.lm_loss_fn(p, b, cfg)
         if loss_fn is None and getattr(cfg, "layer_aux", False):
-            loss_parts = lambda p, b: M.lm_loss_fn(p, b, cfg, hp, mesh, with_parts=True)
+            loss_parts = lambda p, b: M.lm_loss_fn(
+                p, b, cfg, hp, mesh, with_parts=True, table_spec=model.table_spec())
     if hp.pp > 1 or loss_fn is not None:
         # custom losses have no constraint-free local form; pp>1 never takes
         # the quantized path (GLS013)
         local_loss = None
-    return HybridParallelModel(
+    # the pp = 1 losses above read the table in the layout THIS model stores it in
+    model = HybridParallelModel(
         cfg=cfg,
         hp=hp,
         mesh=mesh,
@@ -584,4 +621,7 @@ def construct_hybrid_parallel_model(
         loss_parts_fn=loss_parts,
         cast_first=None if loss_fn is not None else S.cast_first_tree(
             specs, table_stored=table_is_looked_up(vocab_axes(hp)) or cfg.tie_embeddings),
+        table_as_stored=(cfg.input_type != "patches" and table_is_looked_up(vocab_axes(hp))
+                         and not cfg.tie_embeddings),
     )
+    return model

@@ -1,6 +1,7 @@
 """ZeRO-2's compute copy (runtime/model_api.compute_params): the float32
 parameters are stored split over dp, and forward, recomputation and backward
-read a copy in the compute dtype that the step gathers once.
+read a copy in the compute dtype that the step gathers once. The token table
+that `vocab_parallel_lookup` reads is stored split too, and read as it lies.
 
 Held here, on four virtual CPU devices at tiny widths: the copy changes no
 value (against `ddp`, whose state is whole on every replica); which leaves
@@ -22,6 +23,7 @@ from galvatron_tpu.models.bert import bert_config
 from galvatron_tpu.models.gpt import gpt_config
 from galvatron_tpu.models.llama import llama_config
 from galvatron_tpu.models.olmoe import olmoe_config
+from galvatron_tpu.models.parts.embed_head import table_split_axes
 from galvatron_tpu.models.swin import construct_swin_model, swin_config
 from galvatron_tpu.models.t5 import construct_t5_model, t5_config
 from galvatron_tpu.models.vit import vit_config
@@ -58,6 +60,9 @@ def lm_batch(seed=1):
                 labels=jnp.roll(tokens, -1, 1))
 
 
+TABLE = "['embed']['wte']"
+
+
 def adam():
     return get_optimizer_and_scheduler(OptimizerArgs(lr=3e-3, warmup_steps=1, total_steps=20))[0]
 
@@ -66,14 +71,14 @@ def leaf_paths(tree):
     return {jax.tree_util.keystr(p): x for p, x in jax.tree_util.tree_leaves_with_path(tree)}
 
 
-def train(cfg, hp, devices, steps=3):
+def train(cfg, hp, devices, steps=3, model=None, batch=None):
     """`steps` Adam steps from one seed: the model, the losses, the state."""
-    m = construct_hybrid_parallel_model(cfg, hp, devices)
+    m = model or construct_hybrid_parallel_model(cfg, hp, devices)
     params = m.init_params(jax.random.PRNGKey(0))
     tx = adam()
     opt = m.init_opt_state(tx, params)
     step = m.make_train_step(tx)
-    batch = m.shard_batch(lm_batch())
+    batch = m.shard_batch(batch or lm_batch())
     losses = []
     for _ in range(steps):
         params, opt, mets = step(params, opt, batch)
@@ -105,17 +110,21 @@ def test_zero_trains_as_ddp_does(dp_type, kw, devices4):
     # with or without the copy: another partitioning of its scatter-add)
     assert worst < (5e-4 if dp_type == "zero3" else 5e-5), worst
 
-    # the state: every leaf float32, in `state_specs`; a copied leaf, and no
-    # other, leaves `param_specs` for a split over dp
+    # the state: every leaf float32, in `state_specs`; a copied leaf, the
+    # looked-up table of a split state, and no other, leaves `param_specs`
+    # for a split over dp
     copied, specs, state = (leaf_paths(t) for t in (m.copied_leaves(), m.param_specs, m.state_specs()))
     dp = set(vocab_axes(m.hp).dp)
     assert any(copied.values()) == (m.hp.pp == 1) and dp
+    split = {p for p, c in copied.items() if c}
+    if m.hp.vocab_tp > 1 and m.hp.pp == 1:
+        split.add(TABLE)
     for path, leaf in leaf_paths(params).items():
         assert leaf.dtype == jnp.float32, path
         assert leaf.sharding.is_equivalent_to(
             jax.sharding.NamedSharding(m.mesh, state[path]), leaf.ndim), (path, leaf.sharding)
-        assert (state[path] != specs[path]) == copied[path], path
-        if copied[path]:
+        assert (state[path] != specs[path]) == (path in split), path
+        if path in split:
             assert dp & {a for e in state[path] if e is not None
                          for a in (e if isinstance(e, tuple) else (e,))}, (path, state[path])
 
@@ -134,12 +143,130 @@ def test_eval_loss_reads_the_copy(devices4):
     assert ddp.eval_loss is ddp.loss_fn
 
 
+# ------------------------------------------------ the table, by the state's case
+def gpt_tied():
+    return gpt_config("gpt-0.3b", num_layers=2, hidden_size=64, num_heads=4, vocab_size=V,
+                      max_seq_len=S, compute_dtype=BF16)
+
+
+def custom_loss(p, b):
+    return jnp.sum(p["embed"]["wte"]) * 0.0
+
+
+# name -> (config, layout, devices, a custom loss or None, whether the table is stored split)
+TABLE_CASES = {
+    "zero2_looked_up_untied": (tiny_llama, dict(dp_type="zero2"), 4, None, True),
+    "zero2_chunks2": (tiny_llama, dict(dp_type="zero2", chunks=2), 4, None, True),
+    "zero2_dp4_of_two_axes": (tiny_llama, dict(dp_type="zero2", world=8), 8, None, True),
+    "zero3": (tiny_llama, dict(dp_type="zero3"), 4, None, False),  # the same spec, from `param_specs`
+    "embed_sdp": (tiny_llama, dict(dp_type="zero2", embed_sdp=1), 4, None, False),  # `param_specs` has it so
+    "tied": (gpt_tied, dict(dp_type="zero2"), 4, None, False),
+    "whole_vocabulary": (tiny_llama, dict(dp_type="zero2", vocab_tp=1), 4, None, False),  # copied instead
+    "gpipe_pp2": (tiny_llama, dict(dp_type="zero2", pp=2, chunks=2, world=8), 8, None, False),
+    "1f1b_pp2": (tiny_llama, dict(dp_type="zero2", pp=2, chunks=2, world=8,
+                                  pipeline_type="pipedream_flush"), 8, None, False),
+    "ddp": (tiny_llama, dict(dp_type="ddp"), 4, None, False),
+    "dp1": (tiny_llama, dict(dp_type="zero2", tp=4), 4, None, False),
+    "ulysses": (tiny_llama, dict(dp_type="zero2", sp=1, vocab_sp=1), 4, None, False),
+    "quantized_sync": (tiny_llama, dict(dp_type="zero2", grad_comm_dtype="int8"), 4, None, False),
+    "manual_tp": (tiny_llama, dict(dp_type="zero2", tp_comm_mode="shard_map"), 4, None, False),
+    "custom_loss": (tiny_llama, dict(dp_type="zero2"), 4, custom_loss, False),
+}
+
+
+@pytest.mark.parametrize("name", list(TABLE_CASES))
+def test_the_table_is_stored_split_where_the_step_looks_it_up_so(name, devices8):
+    """`state_specs`' third case: a looked-up, untied table under ZeRO axes
+    that split it further lies as `grad_accum_specs` has it, uncopied, and the
+    model's own loss is handed that spec; everywhere else it lies as
+    `param_specs` has it and the lookup is the whole-table form."""
+    make_cfg, kw, n, loss, split = TABLE_CASES[name]
+    kw = dict(kw)
+    m = construct_hybrid_parallel_model(make_cfg(), layout(kw.pop("dp_type"), **kw), devices8[:n], loss_fn=loss)
+    vax = vocab_axes(m.hp)
+    state, placed, accum = (t["embed"]["wte"] for t in (m.state_specs(), m.param_specs, m.grad_accum_specs()))
+    # a table read whole as `wte.astype(dtype)[tokens]` is a copied leaf like any other
+    copied = name in ("whole_vocabulary", "ulysses")
+    assert m.table_spec() == state
+    assert m.copied_leaves()["embed"]["wte"] == copied
+    assert (state != placed) == (split or copied), (state, placed)
+    if split:
+        assert state == accum == jax.sharding.PartitionSpec(vax.tp[0], vax.dp[0] if len(vax.dp) == 1 else vax.dp)
+        assert table_split_axes(state, vax) == tuple(vax.dp)
+        # the opt state's moments lie where the table does
+        tx = adam()
+        mu = m.opt_state_shardings(tx, m.abstract_params())
+        assert any(s.spec == state for s in jax.tree.leaves(mu) if len(s.spec) == 2)
+    elif name not in ("embed_sdp", "zero3") and not copied:
+        assert table_split_axes(state, vax) == ()
+
+
+def parents_form(cfg, hp, devices):
+    """The model as the parent of PR 52 built it: the table stored whole over
+    dp as `param_specs` lays it out, its float32 gradient summed over dp and
+    the updated table gathered after the update."""
+    m = construct_hybrid_parallel_model(cfg, hp, devices)
+    m.table_as_stored, m._stored = False, None
+    assert m.table_spec() == m.param_specs["embed"]["wte"]
+    return m
+
+
+def repeated_ids_batch():
+    """Ids repeated over a whole row and half a row, of both tp halves."""
+    batch = lm_batch()
+    batch["tokens"] = batch["tokens"].at[0].set(7).at[1, : S // 2].set(V - 3)
+    batch["labels"] = jnp.roll(batch["tokens"], -1, 1)
+    return batch
+
+
+@pytest.mark.parametrize("kw", [dict(), dict(chunks=2), dict(sequence_parallel=False)],
+                         ids=["the_cells_flags", "chunks2", "no_megatron_sp"])
+def test_the_split_table_trains_as_the_parents_form_does(kw, devices4):
+    """A tiny Qwen (qkv bias, untied head) under the four-chip cell's flags
+    (tp 2 x dp 2, ZeRO-2, the vocabulary split, full recomputation), three
+    Adam steps: the first loss to the bit (the same rows), the rest within
+    1e-6 (the same float32 addends in another order), every leaf within
+    float32 rounding, and the table comes back split as it went in (what the
+    compiled step holds: tests/ops/test_tpu_compile.py)."""
+    cfg, hp = tiny_llama(qkv_bias=True), layout("zero2", checkpoint=1, **kw)
+    batch = repeated_ids_batch()
+    _, want_losses, want = train(cfg, hp, devices4, model=parents_form(cfg, hp, devices4), batch=batch)
+    m, losses, params = train(cfg, hp, devices4, batch=batch)
+    assert losses[0] == want_losses[0], (losses, want_losses)
+    assert max(abs(a - b) for a, b in zip(losses, want_losses)) < 1e-6, (losses, want_losses)
+    for (path, a), b in zip(leaf_paths(params).items(), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=2e-6, err_msg=path)
+    table = params["embed"]["wte"]
+    assert table.dtype == jnp.float32 and table.sharding.spec == m.table_spec() != m.param_specs["embed"]["wte"]
+
+
+def test_a_checkpoint_crosses_between_the_split_table_and_the_whole_one(tmp_path, devices4):
+    """A checkpoint holds logical arrays: one written with the table split
+    over dp restores into the parent's layout (whole over dp) and back."""
+    cfg, hp = tiny_llama(), layout("zero2")
+    split = construct_hybrid_parallel_model(cfg, hp, devices4)
+    whole = parents_form(cfg, hp, devices4)
+    params = split.init_params(jax.random.PRNGKey(3))
+    want = np.asarray(params["embed"]["wte"])
+    ckpt.save_checkpoint(str(tmp_path / "a"), 1, params, None, hp=hp)
+    as_whole, _, _ = ckpt.load_checkpoint(str(tmp_path / "a"), 1, target=whole, tx=None)
+    ckpt.save_checkpoint(str(tmp_path / "b"), 1, as_whole, None, hp=hp)
+    back, _, _ = ckpt.load_checkpoint(str(tmp_path / "b"), 1, target=split, tx=None)
+    for got, m in ((as_whole, whole), (back, split)):
+        table = got["embed"]["wte"]
+        assert table.sharding.is_equivalent_to(m.shardings()["embed"]["wte"], 2)
+        np.testing.assert_array_equal(np.asarray(table), want)
+    assert as_whole["embed"]["wte"].sharding.spec != back["embed"]["wte"].sharding.spec
+
+
 # ------------------------------------------- nothing to copy: the parent's step
 # sha256 of `make_train_step(adam).lower(...).as_text()` (StableHLO) for
 # layouts in which no leaf is copied: the step is to stay that text. The
 # parent is PR 30's step (the head and its loss under their written backward,
 # models/parts/embed_head._head_matmul and _token_nll; before it, from the commit before
-# the compute copy, 9c3c713, to PR 29, the text was one other). A PR that
+# the compute copy, 9c3c713, to PR 29, the text was one other; PR 52 stores
+# the looked-up table split over dp under ZeRO-2 whatever the compute dtype,
+# so `tp2dp2_zero2_fp32` is PR 52's step and the other six PR 30's). A PR that
 # changes the step on purpose prints the new digests with
 # `pytest -k lowers_to -s` and replaces these.
 PARENT_STEP_SHA256 = {
@@ -147,7 +274,7 @@ PARENT_STEP_SHA256 = {
     "dp4_ddp": "70995ecd487ae2884bbf396bf49771cba3a7ac9bbcefc2b5870fa7933dd6a908",
     "tp2dp2_ddp_chunks2": "5b6cd9e54b8928e4dd99a65f9ba070bf576b707a8f9da29745c241ae8be70815",
     "tp4_zero2_dp1": "3c537facf5b745a4add1697b6547b8e81cd6332accea54f6bac0b3945fd14a09",
-    "tp2dp2_zero2_fp32": "43cd4a705cf689de5ffd06efef9ee69e6eac2ffded20dee61b23cbd98874b19e",
+    "tp2dp2_zero2_fp32": "d9177b1e44e79bc78a27e6c00e4b2d5672f47102bf219aa3474269cf6df30149",  # PR 52: the table split
     "gpipe_pp2dp2_zero2": "59b8a51df20da0d2597b18a0c2b563aadb02a74d9a6f42afbbe132a129d0d597",
     "tp2dp2_zero2_manual_tp": "e72629bd031daad6af1e4a377f78d497457a3631b97ed8e6ae58b28847749727",
 }
@@ -181,7 +308,15 @@ def test_a_layout_with_nothing_to_copy_lowers_to_the_parents_step(name, devices8
     m = construct_hybrid_parallel_model(cfg, hp, devices8[:n])
     assert not any(jax.tree.leaves(m.copied_leaves()))
     params = m.abstract_params()
-    assert jax.tree.map(lambda s: s.spec, m.shardings()) == m.param_specs
+    stored = jax.tree.map(lambda s: s.spec, m.shardings())
+    if name == "tp2dp2_zero2_fp32":
+        # float32 compute copies nothing, but the looked-up table is stored
+        # split over dp all the same (PR 52: this layout's step is no longer
+        # the parent's, and its digest below is PR 52's)
+        assert stored["embed"].pop("wte") == m.grad_accum_specs()["embed"]["wte"] != m.param_specs["embed"]["wte"]
+        assert stored == {**m.param_specs, "embed": {k: v for k, v in m.param_specs["embed"].items() if k != "wte"}}
+    else:
+        assert stored == m.param_specs
     assert m.compute_params(params) is params
     tx = adam()
 
@@ -352,8 +487,7 @@ def test_the_leaf_rule_holds_for_the_family(name, devices4):
     found = wide | {p for p, n in uses.items() if n > 1}
     assert found == stored, (sorted(found - stored), sorted(stored - found))
     assert any("scale" in p for p in wide)
-    table = "['embed']['wte']"
-    assert (table in stored) == (name in ("gpt", "gpt_vocab_tp2", "llama_qwen", "bert", "t5")), name
+    assert (TABLE in stored) == (name in ("gpt", "gpt_vocab_tp2", "llama_qwen", "bert", "t5")), name
     if name == "olmoe":
         assert any("router" in p for p in wide)
         assert all(uses[p] > 1 for p in uses if "['wi']" in p or "['wo_mlp']" in p)

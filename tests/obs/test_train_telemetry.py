@@ -88,6 +88,33 @@ def test_lifecycle_events_present(telemetry_run):
     assert t["run_end"][0]["summary"]["iters"] >= 1
 
 
+def test_the_compile_event_says_whether_the_table_stays_split_over_dp(telemetry_run, devices8, tmp_path, capsys):
+    """`table_rows_over_dp`: 1 where the step looks the vocabulary-split table
+    up with ids, rows and cotangents crossing dp (ZeRO-2, tp 2 x dp 2, the
+    four-chip cell's flags), 0 where it does not (the shared run: no
+    `vocab_tp`), and `cli report` says so in a line."""
+    assert "table_rows_over_dp" in T.EVENT_SCHEMAS["compile"][1]
+    assert by_type(telemetry_run[1])["compile"][0]["table_rows_over_dp"] == 0
+    R.run([telemetry_run[3]])
+    assert "the token table stays split over dp" not in capsys.readouterr().out
+    tele = str(tmp_path / "run.jsonl")
+    argv = [
+        "--model_type", "llama", "--set_model_config_manually", "1",
+        "--hidden_size", "64", "--num_attention_heads", "4", "--num_layers", "2",
+        "--vocab_size", "128", "--seq_length", "32", "--mixed_precision", "bf16",
+        "--global_train_batch_size", "4", "--train_iters", "2", "--world_size", "4",
+        "--global_tp_deg", "2", "--default_dp_type", "zero2", "--vocab_tp", "2", "--checkpoint", "1",
+        "--telemetry", tele,
+    ]
+    train(initialize_galvatron(mode="train_dist", argv=argv))
+    events, errors = T.read_events(tele)
+    assert errors == []
+    assert [e["table_rows_over_dp"] for e in events if e["type"] == "compile"] == [1]
+    assert all(np.isfinite(e["loss"]) for e in events if e["type"] == "step")
+    R.run([tele])
+    assert "the token table stays split over dp" in capsys.readouterr().out
+
+
 def test_summary_reports_mfu(telemetry_run):
     summary, _, _, _ = telemetry_run
     assert summary["model_flops_per_step"] > 0

@@ -226,6 +226,54 @@ def test_vocab_split_embedding_is_a_lookup_on_v5e(tp2dp2_step_hlo, sequence_para
     assert forward_sums == [summed_by], ops
 
 
+@pytest.mark.parametrize("sequence_parallel", [False, True], ids=["tp2dp2", "tp2dp2_megatron_sp"])
+def test_the_split_table_stays_where_zero2_updates_it_on_v5e(tp2dp2_step, sequence_parallel):
+    """The looked-up table is stored `P(tp, dp)` (runtime/model_api
+    state_specs) and what crosses dp is the lookup's: no all-gather,
+    all-reduce or reduce-scatter (alone or fused) has an operand or a result
+    of the table's float32 shapes, whole (vocab/tp, hidden) or split (vocab/tp,
+    hidden/dp); the step holds the ids' gather and the `all_to_all` pair under
+    `gt.embed`, (B, S, H/dp) rows forward and (B/dp, S, H) cotangents back; and
+    the table goes in and comes out split."""
+    model, step = tp2dp2_step[sequence_parallel]
+    cfg, text = model.cfg, step.as_text()
+    spec = model.table_spec()
+    assert spec == model.grad_accum_specs()["embed"]["wte"] != model.param_specs["embed"]["wte"]
+    rows, hidden = cfg.vocab_size // 2, cfg.hidden_size
+    table_shapes = [r"f32\[%d,%d\]" % (rows, h) for h in (hidden, hidden // 2)]
+    sums_and_gathers = re.compile(
+        r" (all-gather|all-reduce|reduce-scatter)(-start)?\(|calls=%(all-reduce-scatter|all-gather|reduce-scatter)")
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if sums_and_gathers.search(line) and any(re.search(t, line) for t in table_shapes)]
+    assert not moved, moved
+    exchanged = [(m.group(1), "transpose(" in line) for line in text.splitlines()
+                 if "gt.embed" in line and (m := re.search(r" = bf16\[([\d,]+)\]\S* all-to-all\(", line))]
+    assert sorted(exchanged) == sorted([("4,256,%d" % (hidden // 2), False), ("2,256,%d" % hidden, True)]), exchanged
+    assert [line for line in text.splitlines()
+            if "gt.embed" in line and re.search(r" = s32\[[\d,]+\]\S* all-gather\(", line)]
+    table_in = step.input_shardings[0][0]["embed"]["wte"]
+    assert table_in.is_equivalent_to(NamedSharding(model.mesh, spec), 2)
+    assert step.output_shardings[0]["embed"]["wte"].is_equivalent_to(table_in, 2)
+
+
+def test_the_cpu_step_of_that_layout_prints_no_reduce_scatter(devices8):
+    """XLA:CPU has no reduce-scatter of its own choice, and the benchmark's
+    CPU rehearsal of the four-chip cell counts on none
+    (tests/benchmarks/test_cell_from_files.py NOT_ON_THE_CPU): the lookup's
+    second form is written without `psum_scatter`."""
+    from galvatron_tpu.config.strategy import HybridParallelConfig
+    from galvatron_tpu.models.llama import llama_config
+
+    cfg = llama_config("llama-0.3b", num_layers=2, hidden_size=64, num_heads=4, ffn_hidden=128,
+                       vocab_size=256, max_seq_len=32, compute_dtype=jnp.bfloat16)
+    hp = HybridParallelConfig.uniform(4, 2, tp=2, vocab_tp=2, default_dp_type="zero2", global_bsz=4,
+                                      mixed_precision="bf16", checkpoint=1)
+    model, step = _model_and_compiled_step(cfg, hp, devices8[:4], batch_rows=4)
+    text = step.as_text()
+    assert model.table_spec() != model.param_specs["embed"]["wte"]
+    assert "reduce-scatter" not in text and " all-to-all(" in text
+
+
 def _replica_groups(line):
     """The replica groups of an HLO collective, as sets of device positions:
     `{{0,2},{1,3}}`, or the iota form `[2,2]<=[2,2]T(1,0)` (reshape `arange`
@@ -247,9 +295,10 @@ def test_zero2_gathers_a_bf16_copy_and_stores_float32_shards_on_v5e(tp2dp2_step,
     """ZeRO-2's compute copy in the compiled step (runtime/model_api
     compute_params). Over the dp groups, every bf16 all-gather carries
     `gt.param_gather` and gathers a copied leaf, each copied leaf at least
-    once; the float32 all-gathers left are the `vocab_tp` table's (looked up
-    from the stored shard) and the norm scales', after the update and under no
-    scope; nothing under `gt.param_gather` is float32. The parameters go in
+    once; the float32 all-gathers left are the norm scales', after the update
+    and under no scope (the `vocab_tp` table, looked up from the stored shard,
+    is stored split too and nothing gathers it: the test below); nothing under
+    `gt.param_gather` is float32. The parameters go in
     and come out in one layout, leaf by leaf: one compilation, donated
     buffers reused."""
     from galvatron_tpu.parallel.mesh import vocab_axes
@@ -285,9 +334,10 @@ def test_zero2_gathers_a_bf16_copy_and_stores_float32_shards_on_v5e(tp2dp2_step,
     assert sizes[True] and all("gt.param_gather" in name for _, name in gathered["bf16"])
     assert sorted({n for n, _ in gathered["bf16"]}) == sorted(set(sizes[True]))
     assert sum(n for n, _ in gathered["bf16"]) >= sum(sizes[True])
-    # float32: the table's rows a chip and the norm scales, under no scope
+    # float32: the norm scales, under no scope; of the leaves ZeRO-2 splits
+    # without a copy the table's rows a chip are the other, and stay split
     table = shapes["embed"]["wte"].size // tp
-    assert {n for n, _ in gathered["f32"]} == {table, model.cfg.hidden_size}
+    assert {n for n, _ in gathered["f32"]} == {model.cfg.hidden_size}
     assert sorted(set(sizes[False])) == sorted({table, model.cfg.hidden_size})
     assert not [name for _, name in gathered["f32"] if "gt." in name]
 
