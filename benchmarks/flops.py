@@ -65,7 +65,13 @@ def mfu_pct(tokens_per_s_chip: float, flops_a_token: float, peak_flops_per_s: fl
 #   dq       q k^T again, do v^T, ds k                    3
 # The causal half is counted once (what the model needs), although the
 # kernels run whole blocks on the diagonal.
-FLASH_KERNEL_MATMULS = {"fwd": 2, "dkv": 4, "dq": 3}
+# Beside jax's three kernels stands the MODEL's form, for a kernel of another
+# cut (`gt.attn.core`, benchmarks/layer_metrics/flash_ms.py): what attention
+# needs whatever implements it, one forward 2 (q k^T, p v) and one backward 5
+# (the scores again, dP = do v^T, dV = p^T do, dQ = ds k, dK = ds^T q). jax's
+# two backward kernels make the scores and dP twice, 7: a kernel that does
+# less than they do cannot pass 100 % on the model's count.
+FLASH_KERNEL_MATMULS = {"fwd": 2, "dkv": 4, "dq": 3, "core_fwd": 2, "core_bwd": 5}
 # what the algorithm has to read and write, in (batch, heads, seq, head_dim)
 # tensors of the compute dtype: forward reads q k v and writes o; dkv reads
 # q k v do and writes dk dv; dq reads q k v do and writes dq. The per-row
@@ -73,12 +79,15 @@ FLASH_KERNEL_MATMULS = {"fwd": 2, "dkv": 4, "dq": 3}
 # left out; the kernel as written moves them broadcast over 128 or 512 lanes
 # (fp32[b,h,s,128]: as many bytes again as q k v o), which is its own cost,
 # not the algorithm's
-FLASH_KERNEL_TENSORS = {"fwd": 4, "dkv": 6, "dq": 5}
+# the model's backward reads q k v o do and writes dq dk dv
+FLASH_KERNEL_TENSORS = {"fwd": 4, "dkv": 6, "dq": 5, "core_fwd": 4, "core_bwd": 8}
 
 
 def flash_kernel_cost(kind: str, batch: int, heads: int, seq: int, head_dim: int,
                       causal: bool = True, dtype_bytes: int = 2) -> Dict[str, float]:
-    """FLOPs and HBM bytes of ONE call of a flash kernel at these shapes."""
+    """FLOPs and HBM bytes of ONE call of a flash kernel at these shapes
+    (`core_fwd`, `core_bwd`: of one forward or backward of one layer's
+    attention over `batch` rows)."""
     flops = (FLASH_KERNEL_MATMULS[kind] * 2.0 * batch * heads * seq * seq * head_dim
              * (0.5 if causal else 1.0))
     nbytes = FLASH_KERNEL_TENSORS[kind] * float(batch * heads * seq * head_dim) * dtype_bytes

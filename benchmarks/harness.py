@@ -23,7 +23,7 @@ import time
 import types
 from typing import Any, Callable, Dict, List, Mapping, Optional
 
-from benchmarks import cells, flops, trace as trace_mod, window
+from benchmarks import cells, flops, stages, trace as trace_mod, window
 
 STEP_NAMES = ("plain_step", "train_step")  # the jitted step's names in the program
 TRACED_STEPS = 3
@@ -139,7 +139,11 @@ def reference_loss(cell: cells.Cell, args) -> float:
     # the architecture's switches as the program holds them (norm, activation,
     # positions, tying, eps, rope base); the tree says where biases are
     fields = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
-    return float(jax.jit(lambda p, b: ref.loss(p, b, fields))(params, batch))
+    # a pipelined model keeps its layers stacked by stage: the reference gets
+    # the per-layer tree (benchmarks/stages.py)
+    division = tuple(hp.pp_division)
+    return float(jax.jit(lambda p, b: ref.loss(
+        stages.per_layer_tree(p, division), b, fields))(params, batch))
 
 
 def expected_first_loss(cell: cells.Cell) -> float:

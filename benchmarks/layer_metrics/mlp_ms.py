@@ -12,7 +12,7 @@ it)."""
 
 from benchmarks import scopes
 
-END = r"(?![a-z_.])"  # where a scope's name ends: `gt.mlp`, not `gt.mlp_in`
+END = scopes.END  # where a scope's name ends: `gt.mlp`, not `gt.mlp_in`
 MLP = r"gt\.mlp" + END
 
 

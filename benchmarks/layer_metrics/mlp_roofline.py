@@ -4,8 +4,9 @@ backward of the up (and gate) and down projections at the cell's tokens a
 device, for every layer, over the chip's peak FLOP/s (benchmarks/flops.py's
 convention: 2 FLOPs a multiply-add, backward = 2 x forward, recomputation NOT
 counted; under tp a device multiplies `ffn / tp` columns of all its replica's
-tokens). A recomputed forward and the activation's passes are in the time
-and not in the count, so the share is a lower bound of the matmuls' own and
+tokens, under pp its stage's `layers / pp` layers). A recomputed forward, the
+activation's passes and a pipeline's padding ticks are in the time and not in
+the count, so the share is a lower bound of the matmuls' own and
 cannot pass 100 %. Compute bound at these shapes (the kernels are read once
 for 8192 tokens). None where there is no trace or no such scope."""
 
@@ -26,7 +27,11 @@ def read(run):
     took = mlp_ms.read(run)
     if not took:
         return None
-    batch, heads, seq, _ = flash_roofline.kernel_shapes(run)
-    tp = run["cell"].fields["num_heads"] // heads
-    least = mlp_train_flops(run["cell"].fields, batch * seq, tp) / run["peak"]["bf16_flops_per_s"]
+    cell = run["cell"]
+    lay = flash_roofline.layout(cell)
+    # a device's tokens of a whole step (all its microbatches) through its
+    # stage's share of the layers
+    tokens = cell.traffic["global_batch"] // lay["dp"] * cell.traffic["seq_length"]
+    least = (mlp_train_flops(cell.fields, tokens, lay["tp"]) / lay["pp"]
+             / run["peak"]["bf16_flops_per_s"])
     return 100.0 * least * 1e3 / took

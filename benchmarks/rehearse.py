@@ -26,7 +26,7 @@ def rehearse(workload: str, topo_devices) -> dict:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
-    from benchmarks import cells, harness
+    from benchmarks import cells, harness, stages
     from galvatron_tpu.cli.arguments import (hp_config_from_args, initialize_galvatron,
                                              model_config_from_args)
     from galvatron_tpu.cli.train import optimizer_args_from
@@ -58,8 +58,9 @@ def rehearse(workload: str, topo_devices) -> dict:
     hlo = step.as_text()
     ref = cells.load_module(ROOT, "benchmarks/references/%s.py" % cell.config["reference"])
     fields = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
-    ref_mem = harness.step_memory(
-        jax.jit(lambda p, b: ref.loss(p, b, fields)).lower(p_sds, batch).compile())
+    division = tuple(hp.pp_division)  # a pipelined tree is unstacked as the harness does it
+    ref_mem = harness.step_memory(jax.jit(lambda p, b: ref.loss(
+        stages.per_layer_tree(p, division), b, fields)).lower(p_sds, batch).compile())
     return {
         "workload": workload, "compile_only": True, **harness.step_memory(step),
         "tpu_custom_calls": hlo.count("tpu_custom_call"),

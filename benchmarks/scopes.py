@@ -25,6 +25,10 @@ from typing import Any, Mapping, Optional
 from benchmarks.trace import ops_matching
 
 SCOPE = r"gt\.[a-z_]+"
+# where a scope's name ends in a label: `gt.mlp/...`, and `gt.mlp_/...` where a
+# transform wraps the name itself (`vmap(gt.mlp)` under the pipeline's vmapped
+# stage body), but not `gt.mlp_in`, `gt.mlp.in` or `gt.mlpx`
+END = r"(?![a-z.]|_[a-z])"
 # the transform around a scope's name, as the label carries it
 BACKWARD = r"transpose_[a-z_]*"
 REMAT = r"rematted_computation"
@@ -38,6 +42,21 @@ HEAD_LOSS = r"gt\.head_loss"
 OPTIMIZER = r"gt\.optimizer"
 GUARD = r"gt\.guard"
 UNSCOPED = r"^(?!.*%s)" % SCOPE
+# The GPipe schedule (parallel/pipeline.pipeline_apply) is ONE `lax.scan` over
+# `chunks + pp - 1` ticks whose body runs every stage's layers, and it stands
+# under no scope of its own: its ops are the only ones whose `while` body hangs
+# directly off the transform's wrapper (`jvp()/while/body/...`, backward
+# `transpose(jvp())/while/body/...`; a layer run's scan is
+# `jvp(gt.layers.r<k>)/while/body/...`). The nested scopes (`gt.mlp`,
+# `gt.attn.proj`) and the flash kernels' names are inside it as elsewhere
+TICK_BODY = r":(?:transpose_)?jvp_/while/body/"
+# Stated here BEFORE the program has it: the scope a later PR puts around an
+# attention kernel of the repo's own (nested in `gt.layers.r<k>`, as the other
+# parts), so that `flash_ms` and `flash_roofline` find the kernel whatever its
+# custom calls are named and price it by the MODEL's work
+# (layer_metrics/flash_ms.py). No program names it yet; where the program's
+# `obs/tracing.py` comes to, it spells it so.
+ATTN_CORE = r"gt\.attn\.core" + END
 
 
 def has_scopes(run: Mapping[str, Any]) -> bool:

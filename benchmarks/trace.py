@@ -49,16 +49,35 @@ _CALLS = re.compile(r"calls=%([\w.\-]+)")
 _MATMUL = ("dot_general", "conv_general")
 
 
+def _whole_instructions(text: str) -> List[str]:
+    """The text's lines with an instruction's continuation lines joined to
+    its first: a `pallas_call` given `metadata=` prints its backend config
+    over several lines, and its `op_name` stands on the last. A line that
+    starts an instruction, opens a computation or closes one stands alone;
+    anything else after an instruction belongs to it."""
+    out: List[str] = []
+    inside = False  # the last line of `out` is an instruction that may go on
+    for line in text.splitlines():
+        instruction = _INSTRUCTION.match(line)
+        if instruction or _COMPUTATION.match(line) or not inside or line.strip() in ("", "}"):
+            out.append(line)
+            inside = bool(instruction)
+        else:
+            out[-1] += " " + line.strip()
+    return out
+
+
 def origins_from_hlo(text: str) -> Dict[str, str]:
     """{instruction name: the jax op it came from} out of a compiled
-    program's text: an instruction's own `op_name`, and for a fusion that
+    program's text: an instruction's own `op_name` (wherever in the
+    instruction it stands: `_whole_instructions`), and for a fusion that
     carries none, that of the computation it calls (its matmul if it has
     one, else its root)."""
     own: Dict[str, str] = {}
     calls: Dict[str, str] = {}
     of_computation: Dict[str, Tuple[int, str]] = {}
     computation = None
-    for line in text.splitlines():
+    for line in _whole_instructions(text):
         m = _COMPUTATION.match(line)
         if m:
             computation = m.group(1)

@@ -53,10 +53,10 @@ def test_the_cell_reports_its_nine_metrics_and_the_others_do_not():
         if metric["name"] in READERS:
             assert metric["workloads"] == [CELL] and metric["moves"] == "tokens_per_s_chip"
             assert metric["layer"] in ("model: models/base.py", "kernels: ops/moe.py")
-    assert cell.chips == 1 and cell.tokens_a_step == 8192 and cell.workload["traffic"] == "b1-s8k"
+    assert cell.chips == 1 and cell.tokens_a_step == 8192 and cell.workload["traffic"] == "b1-s8k-lrw2k"
     assert cell.config["reduced"].keys() == {"num_hidden_layers", "n_routed_experts", "vocab_size"}
-    # the four-chip slot stays one cell in seven
-    assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1 and len(manifest["workloads"]) == 7
+    # no count of cells: at most a quarter of them, rounded down, take four chips
+    assert 1 <= sum(w["chips"] == 4 for w in manifest["workloads"]) <= max(1, len(manifest["workloads"]) // 4)
 
 
 def test_every_width_is_the_published_one():

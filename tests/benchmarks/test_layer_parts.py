@@ -164,6 +164,9 @@ def test_the_patterns_are_the_programs_names_and_no_longer_ones():
         assert scopes.ms_a_step(run_of({label("fusion.1", fwd + name + "/mul"): 1e-3}), pattern) == 1.0
         for other in (name + "_in", name + ".in", name + "x"):
             assert not scopes.ms_a_step(run_of({label("fusion.1", fwd + other + "/mul"): 1e-3}), pattern)
+        # a transform's wrapper around the name itself (the pipeline's vmapped stage body) is the name
+        wrapped = "jit(train_step)/jvp()/while/body/closed_call/vmap(%s)/mul" % name
+        assert scopes.ms_a_step(run_of({label("fusion.1", wrapped): 1e-3}), pattern) == 1.0
     # a nested scope is any of the program's but a layer run's own
     for name in (tracing.MLP, tracing.ATTN_PROJ, tracing.ATTN_LATENT, tracing.ATTN_LINEAR, tracing.ATTN_DELTA,
                  tracing.MOE_ROUTER, tracing.MOE_SHARED):
@@ -171,7 +174,11 @@ def test_the_patterns_are_the_programs_names_and_no_longer_ones():
         assert layers_rest_ms.parts(run) == {"flash": 0.0, "rest": pytest.approx(2.0), name: pytest.approx(1.0)}
     # every attention scope a mixer's table row states is one the parts would name
     stated = {s for mixer in M.MIXERS.values() for s in mixer.scopes}
-    assert stated == {tracing.ATTN_PROJ, tracing.ATTN_LATENT, tracing.ATTN_LINEAR, tracing.ATTN_DELTA}
+    # a superset of PR 37's four: each later mixer states its own
+    assert stated >= {tracing.ATTN_PROJ, tracing.ATTN_LATENT, tracing.ATTN_LINEAR, tracing.ATTN_DELTA}
+    for name in stated:
+        assert name.startswith("gt.attn.") and layers_rest_ms.parts(run_of({
+            label("fusion.1", fwd + name + "/mul"): 1e-3}))[name] == pytest.approx(1.0)
 
 
 # ------------------------------------------------------------ hand arithmetic
@@ -270,18 +277,25 @@ def test_the_script_prints_the_parts_by_phase(parts_run):
 # ------------------------------------------------------------- the manifest
 def test_the_manifest_lists_the_five_readers_last_and_each_in_its_cells():
     manifest = cells.load_json(REPO, cells.MANIFEST)
-    entries = manifest["per_layer"][-5:]
-    assert [m["name"] for m in entries] == list(READERS)  # new entries go last
+    # the entries of those names, in that order, wherever they stand (new entries go last)
+    entries = [m for m in manifest["per_layer"] if m["name"] in READERS]
+    assert [m["name"] for m in entries] == list(READERS)
     by_name = {m["name"]: m for m in entries}
     for m in entries:
         assert (m["source"], m["layer"], m["moves"]) == (
             "device_trace", "model: models/base.py", "tokens_per_s_chip")
         assert (m["unit"], m["better"]) == (("%", "higher") if m["name"] == "mlp_roofline" else ("ms", "lower"))
-    assert by_name["mlp_ms"]["workloads"] == by_name["mlp_remat_ms"]["workloads"] == DENSE + ["glm47f-c1-s8k"]
-    assert by_name["mlp_roofline"]["workloads"] == DENSE
-    assert by_name["attn_proj_ms"]["workloads"] == DENSE + ["olmoe-c1-s4k", "qwen3next-c1-s8k"]
+    # PR 37's cells first and in their order; a later cell is appended (the pipeline's: PR 53)
+    assert by_name["mlp_ms"]["workloads"] == by_name["mlp_remat_ms"]["workloads"]
+    for name, first in (("mlp_ms", DENSE + ["glm47f-c1-s8k"]), ("mlp_roofline", DENSE),
+                        ("attn_proj_ms", DENSE + ["olmoe-c1-s4k", "qwen3next-c1-s8k"])):
+        assert by_name[name]["workloads"][:len(first)] == first
+        later = by_name[name]["workloads"][len(first):]
+        # what came later runs the dense decoder too (the same configuration as a DENSE cell)
+        assert {cells.load_cell(REPO, w).workload["config"] for w in later} <= {
+            cells.load_cell(REPO, w).workload["config"] for w in DENSE}
     assert "workloads" not in by_name["layers_rest_ms"]  # every model has layer runs
     for workload in manifest["workloads"]:
         names = {m["name"] for m in cells.load_cell(REPO, workload["name"]).metrics("per_layer")}
         assert "layers_rest_ms" in names
-        assert set(READERS) <= names or workload["name"] not in DENSE
+        assert set(READERS) <= names or workload["name"] not in by_name["mlp_roofline"]["workloads"]

@@ -44,6 +44,10 @@ def test_the_one_chip_recording_has_no_copy_and_the_manifest_lists_the_four_chip
         os.path.join(FIXTURES, "qwen7-c1-s2k-scoped.trace_events.json.gz")), harness.STEP_NAMES)
     assert read("param_gather_ms", {"trace": r}) is None
     manifest = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    entry = manifest["per_layer"][-1]
-    assert entry["name"] == "param_gather_ms" and entry["workloads"] == ["qwen7-c4-tp2dp2"]
-    assert entry["layer"] in {m["layer"] for m in manifest["per_layer"][:-1]}
+    # by name, wherever it stands: new entries go last, so it is the last no more
+    (entry,) = [m for m in manifest["per_layer"] if m["name"] == "param_gather_ms"]
+    # the four-chip cells that run ZeRO-2 over a dp axis (a pipeline's pp2 x tp2 has none)
+    zero2_over_dp = [w["name"] for w in manifest["workloads"] if w["chips"] == 4 and
+                     "zero2" in cells.load_cell(REPO, w["name"]).traffic["train_flags"]]
+    assert entry["workloads"] == zero2_over_dp == ["qwen7-c4-tp2dp2"]
+    assert entry["layer"] in {m["layer"] for m in manifest["per_layer"] if m is not entry}

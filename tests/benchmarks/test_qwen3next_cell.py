@@ -48,7 +48,8 @@ def test_the_cell_reports_its_eight_metrics_and_the_others_do_not():
         if other["name"] != CELL:
             theirs = [m["name"] for m in cells.load_cell(REPO, other["name"]).metrics("per_layer")]
             assert not set(READERS) & set(theirs)
-    assert [m["name"] for m in manifest["per_layer"]][-8:] == list(READERS)  # new entries go last
+    # the entries of those names, in that order, wherever they stand (new entries go last)
+    assert [m["name"] for m in manifest["per_layer"] if m["name"] in READERS] == list(READERS)
     for metric in manifest["per_layer"]:
         if metric["name"] in READERS:
             assert metric["workloads"] == [CELL] and metric["moves"] == "tokens_per_s_chip"
@@ -59,8 +60,8 @@ def test_the_cell_reports_its_eight_metrics_and_the_others_do_not():
     assert cell.traffic["train_flags"] == ["--checkpoint", "1", "--lr_warmup_iters", "2000"]
     assert cell.traffic["warmup_steps"] == 6
     assert cell.config["reduced"].keys() == {"num_hidden_layers", "num_experts", "vocab_size"}
-    # one four-chip cell in eight
-    assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1 and len(manifest["workloads"]) == 8
+    # no count of cells: at most a quarter of them, rounded down, take four chips
+    assert 1 <= sum(w["chips"] == 4 for w in manifest["workloads"]) <= max(1, len(manifest["workloads"]) // 4)
 
 
 def test_every_width_is_the_published_one():

@@ -244,3 +244,48 @@ def test_reduction_of_the_trace_recorded_on_the_chip():
     calls = {k: c for k, (_, c) in cells.load_module(
         REPO, "benchmarks/layer_metrics/flash_ms.py").per_kernel(run).items()}
     assert calls == {"fwd": 4.0, "dkv": 2.0, "dq": 2.0}
+
+
+# ------------------------------------------- an instruction over several lines
+def test_an_instruction_is_read_whole_so_a_kernel_given_metadata_falls_under_its_scope():
+    """A `pallas_call` given `metadata=` (jax's splash kernels) prints its HLO
+    instruction over three lines and its `op_name` stands on the last. The
+    fixture is the compiled text of such a kernel's forward and backward for a
+    described v5e (PR 53; the Mosaic bodies elided), under `gt.layers.r0` and
+    `gt.attn.core`."""
+    path = os.path.join(REPO, "benchmarks", "fixtures", "splash_mha-instructions.hlo.txt")
+    text = open(path).read()
+    first = [line for line in text.splitlines() if line.startswith("  %splash_mha_fwd_residuals.1 = ")]
+    assert len(first) == 1 and "op_name" not in first[0]  # what the parent searched, and found nothing in
+    origins = trace.origins_from_hlo(text)
+    scope = "jit(f)/%s(gt.layers.r0%s/gt.attn.core/jit(_splash_attention)/%s/%s/pallas_call"
+    for name, wrap, close in (("splash_mha_fwd_residuals", "jvp", ")"),
+                              ("splash_mha_dkv_no_residuals", "transpose(jvp", "))"),
+                              ("splash_mha_dq_no_residuals", "transpose(jvp", "))")):
+        assert origins[name + ".1"] == scope % (wrap, close, name, name)
+    # the instructions of one line beside them read as before
+    assert origins["iota.1"].endswith("gt.attn.core/jit(_splash_attention)/broadcast_in_dim")
+    assert "tuple.5" not in origins
+    label = trace._label("%splash_mha_fwd_residuals.1 = (f32[128,128]{1,0}) custom-call(...)", origins)
+    assert label == ("splash_mha_fwd_residuals.1:jvp_gt.layers.r0_/gt.attn.core/jit__splash_attention_/"
+                     "splash_mha_fwd_residuals/splash_mha_fwd_residuals/pallas_call")
+    # joining changes nothing where every instruction stands on one line
+    one_line = "\n".join(line for line in trace._whole_instructions(text))
+    assert trace.origins_from_hlo(one_line) == origins
+    assert trace._whole_instructions(one_line) == one_line.splitlines()
+
+
+def test_a_fusion_still_takes_its_computations_principal_op_after_the_join():
+    text = "\n".join([
+        "%fused_computation.1 (p: f32[8]) -> f32[8] {",
+        '  %mul.1 = f32[8] multiply(%p, %p), metadata={op_name="jit(f)/gt.mlp/mul"}',
+        '  ROOT %dot.2 = f32[8] dot(%mul.1, %p), frontend_attributes={a={',
+        '"k":"v"',
+        '}}, metadata={op_name="jit(f)/gt.mlp/dot_general"}',
+        "}",
+        "",
+        "ENTRY %main (x: f32[8]) -> f32[8] {",
+        "  %fusion.3 = f32[8] fusion(%x), kind=kOutput, calls=%fused_computation.1",
+        "}"])
+    origins = trace.origins_from_hlo(text)
+    assert origins["dot.2"] == origins["fusion.3"] == "jit(f)/gt.mlp/dot_general"
