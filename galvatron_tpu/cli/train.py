@@ -30,6 +30,7 @@ from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import launch, telemetry, tracing
 from galvatron_tpu.ops import attention as attention_ops
 from galvatron_tpu.ops import linear_attention, moe
+from galvatron_tpu.parallel import pipeline
 from galvatron_tpu.profiler.runtime import (
     RuntimeProfiler,
     compiled_step_memory_mb,
@@ -50,7 +51,8 @@ launch.IMPORTS.done()  # the program is imported: the import record closes and g
 # the model code is traced; `compiled_step` reads what the lowering added
 KERNEL_FORMS = dict(
     delta_rule=linear_attention.TOOK, moe_rows=moe.ROWS_TOOK, moe_windows=moe.WINDOWS_TOOK,
-    kernels_relaid=mlp.RELAID, windows=attention_ops.TOOK, lookups=embed_head.LOOKUPS_TOOK)
+    kernels_relaid=mlp.RELAID, windows=attention_ops.TOOK, lookups=embed_head.LOOKUPS_TOOK,
+    vocab_split=pipeline.VOCAB_SPLIT_TOOK)
 
 
 # In-process memo of AOT-compiled train-step executables, keyed by (device
@@ -608,6 +610,11 @@ def _train(args, started: launch.Launch) -> dict:
                 # staying split over it (`embed_head.vocab_parallel_lookup`'s
                 # second form), else 0
                 table_rows_over_dp=int(forms.took["lookups"]["rows_over_dp"] > 0),
+                # the mesh axes the scan pipeline's vocabulary layers are
+                # stored and computed split over (`mesh.pipeline_vocab_axes`:
+                # pp, then the vocabulary's tp axes); absent at pp = 1, under
+                # vocab-SP and in the 1F1B engines, which traced no such loss
+                vocab_split_axes=next((list(axes) for axes in forms.took["vocab_split"]), None),
             )
             _aot["fn"] = compiled
         return _aot["fn"](*step_args)

@@ -458,7 +458,7 @@ def convert_hf_t5(state_dict: Dict[str, Any], cfg: T5Config) -> Params:
 def t5_vocab_pipeline_specs(cfg: T5Config, hp: HybridParallelConfig, *, storage: bool) -> Params:
     """Specs for the non-stage params under the enc-dec pipeline.
     storage=True: the wte vocab dim shards over ('pp',) + vocab_tp (state is
-    1/(pp*vtp) per device, cf. pipeline_1f1b.vocab_param_specs); False: the
+    1/(pp*vtp) per device, cf. pipeline.vocab_param_specs); False: the
     within-stage layout the schedule computes in."""
     vax = vocab_axes(hp)
     vocab_ax = S._ax(((PP_AXIS,) if storage else ()) + (() if vax.ulysses else tuple(vax.tp)))

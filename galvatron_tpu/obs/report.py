@@ -445,6 +445,9 @@ def render(analysis: Dict[str, Any]) -> str:
             lines.append("gated kernels whose gradient is relaid to the state's layout: %d" % comp["kernel_grads_relaid"])
         if comp.get("table_rows_over_dp"):
             lines.append("the token table stays split over dp: its lookup sends ids, rows and cotangents")
+        if comp.get("vocab_split_axes"):
+            lines.append("the pipeline's vocabulary layers are stored and computed split over: %s"
+                         % ", ".join(comp["vocab_split_axes"]))
     an = analysis["anomalies"]
     lines.append(
         "resilience: %d anomalies skipped, %d rollbacks, %d retries"

@@ -25,7 +25,7 @@ keep in sync.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -175,6 +175,22 @@ def vocab_axes(config: HybridParallelConfig) -> LayerAxes:
         True,
         bool(config.embed_sdp),
     )
+
+
+def pipeline_vocab_axes(config: HybridParallelConfig) -> LayerAxes:
+    """The axes the vocabulary layers are stored and computed under where
+    stages share them: `vocab_axes` with the pp axis leading its tp axes, so
+    the table's rows and the head's columns are split over ``('pp',) + tp``
+    and each chip owns its rows (Megatron's vocabulary parallelism over
+    pipeline stages, arXiv:2411.05288). The pp axis is idle for these layers
+    otherwise: every stage would hold and compute a whole tp-share. Unchanged
+    at pp = 1, and under vocab-SP, where the tp axes carry the sequence and
+    the vocabulary is dense. Activations BETWEEN layers keep `vocab_axes`'
+    layout (`seq_axes` of this one would put pp on a Megatron-SP sequence)."""
+    vax = vocab_axes(config)
+    if config.pp > 1 and not vax.ulysses:
+        vax = replace(vax, tp=(PP_AXIS,) + tuple(vax.tp))
+    return vax
 
 
 def _axes_from_strategy(

@@ -34,6 +34,7 @@ CP inside pp>1.
 
 from __future__ import annotations
 
+import collections
 from functools import partial
 from typing import Any, Dict, List, Optional
 
@@ -44,9 +45,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from galvatron_tpu.config.strategy import HybridParallelConfig
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.parallel import spec as S
-from galvatron_tpu.parallel.mesh import PP_AXIS, layer_axes, vocab_axes
+from galvatron_tpu.parallel.mesh import PP_AXIS, layer_axes, pipeline_vocab_axes, vocab_axes
 
 Params = Dict[str, Any]
+
+# the mesh axes a traced scan-pipeline loss split its vocabulary layers over
+# (`pipeline_vocab_axes`, where they lead with pp) -> the traces that did since
+# the process began: the trainer's compile report reads what a step's trace added
+VOCAB_SPLIT_TOOK = collections.Counter()
 
 
 def validate_pipeline_config(hp: HybridParallelConfig):
@@ -115,6 +121,32 @@ def stack_layer_specs(cfg, hp: HybridParallelConfig):
         spec_j = layer_param_specs(cfg, ax)
         out.append(jax.tree.map(lambda sp: P(PP_AXIS, *sp), spec_j, is_leaf=lambda x: isinstance(x, P)))
     return out
+
+
+def vocab_param_specs(cfg, hp: HybridParallelConfig) -> Params:
+    """The model's parameter specs with the vocabulary layers as a pipeline
+    stores them, for both engines: the vocabulary dim of the token table, of
+    an untied head's kernel and of an MLM head's bias is split over
+    ``('pp',) + vocab_tp`` (`pipeline_vocab_axes`), so a chip holds
+    1/(pp * vocab_tp) of their state and no stage holds what another holds.
+    The scan pipeline computes them in that layout too (`make_pipelined_loss`);
+    the 1F1B engine gathers a within-stage copy once a step. Under vocab-SP
+    the vocabulary is dense: the scan reads it as `model_param_specs` lays it
+    out, the 1F1B engine, which gathers anyway, stores it over pp alone."""
+    from galvatron_tpu.models import base as M
+
+    specs = M.model_param_specs(cfg, hp)
+    vax = pipeline_vocab_axes(hp)
+    if vax.ulysses and hp.pipeline_type != "pipedream_flush":
+        return specs
+    vocab_ax = S._ax((PP_AXIS,) if vax.ulysses else vax.tp)
+    if cfg.input_type != "patches":
+        specs["embed"]["wte"] = P(vocab_ax, S._ax(vax.dp) if vax.zero3 else None)
+    if cfg.head_type in ("lm", "mlm") and not cfg.tie_embeddings:
+        specs["lm_head"]["kernel"] = P(None, vocab_ax)
+    if cfg.head_type == "mlm":
+        specs["head"]["bias"] = P(vocab_ax)
+    return specs
 
 
 def stack_params(layer_params: List[Params], hp: HybridParallelConfig) -> List[Params]:
@@ -226,9 +258,13 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
                                                        vocab_parallel_cross_entropy)
 
     validate_pipeline_config(hp)
-    vax = vocab_axes(hp)
+    # activations between the layers lie as the stage's vocabulary axes have
+    # them; the table's rows and the logits' columns are split over pp too
+    vax, pvax = vocab_axes(hp), pipeline_vocab_axes(hp)
 
     def loss_fn(params, batch):
+        if pvax != vax:
+            VOCAB_SPLIT_TOOK[tuple(pvax.tp)] += 1
         num_mb = hp.chunks
         with jax.named_scope(tracing.EMBED):
             if cfg.input_type == "patches":
@@ -238,8 +274,8 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
             else:
                 inputs = batch["tokens"]
                 positions = batch["positions"]
-                x = embed_tokens(params["embed"], inputs, positions, cfg, mesh, vax,
-                                   token_type_ids=batch.get("token_type_ids"))
+                x = embed_tokens(params["embed"], inputs, positions, cfg, mesh, pvax,
+                                 token_type_ids=batch.get("token_type_ids"))
         B = x.shape[0]
         mb = B // num_mb
 
@@ -266,8 +302,9 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
             # the bias' trailing dim is key positions, not the activation
             # sequence layout — keep it (and the singleton dims) unsharded
             bias_mb = split(M.padding_attn_bias(batch["attn_mask"]), seq_dim=None)
-        # embed all microbatches up-front (replicated across pp groups; the
-        # vocab layers' own parallelism comes from vocab_tp/vocab_sp axes)
+        # embed all microbatches up-front: every chip looks its own rows of
+        # the table up and the sum over pp x vocab_tp hands every stage the
+        # whole batch (under vocab-SP: replicated across pp groups)
         outs = pipeline_apply(params["stages"], split(x), split(positions), cfg, hp, mesh,
                               attn_bias_mb=bias_mb)
         h = outs.reshape((B,) + x.shape[1:])
@@ -276,7 +313,7 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
             logits = model_head(params, h, cfg)
             if cfg.head_type == "classification":
                 return softmax_nll(logits, batch["labels"])
-            logits = S.constrain(logits, mesh, S.logits_spec(vax))
+            logits = S.constrain(logits, mesh, S.logits_spec(pvax))
             return vocab_parallel_cross_entropy(
                 logits, batch["labels"], batch.get("loss_mask"))
 

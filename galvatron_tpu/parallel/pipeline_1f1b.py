@@ -39,8 +39,9 @@ the ``pp`` mesh axis and *auto* (GSPMD) over the within-stage axes:
 - the embedding and the head/loss run once per tick on every stage
   (redundantly — the last stage is the critical path either way), computing
   in the within-stage vocab_tp layout; their parameters are STORED with the
-  vocab dimension sharded over ``('pp',) + vocab_tp`` (1/(pp*vtp) state per
-  device, vs the reference's full replication per pp group,
+  vocab dimension sharded over ``('pp',) + vocab_tp``
+  (pipeline.vocab_param_specs, the scan pipeline's layout too: 1/(pp*vtp)
+  state per device, vs the reference's full replication per pp group,
   GPTModel_sequential.py:201-248) and gathered to the within-stage layout
   once per step at the shard_map boundary.
 """
@@ -211,26 +212,6 @@ def build_schedule(pp: int, chunks: int) -> Schedule:
         emb_mb=emb_mb, emb_valid=emb_valid,
         inject_mb=np.clip(fwd_mb[:, 0], 0, chunks - 1),
     )
-
-
-# ============================================================== vocab sharding
-def vocab_param_specs(cfg, hp: HybridParallelConfig) -> Params:
-    """Override specs for the vocab layers under the 1f1b pipeline: the vocab
-    dim is sharded over ('pp',) + vocab_tp, so embed/head state is split
-    across pipeline groups instead of replicated per group."""
-    from galvatron_tpu.models import base as M
-
-    vax = vocab_axes(hp)
-    specs = M.model_param_specs(cfg, hp)
-    z3 = S._ax(vax.dp) if vax.zero3 else None
-    vocab_ax = S._ax((PP_AXIS,) + (() if vax.ulysses else tuple(vax.tp)))
-    if cfg.input_type != "patches":
-        specs["embed"]["wte"] = P(vocab_ax, z3)
-    if cfg.head_type in ("lm", "mlm") and not cfg.tie_embeddings:
-        specs["lm_head"]["kernel"] = P(None, vocab_ax)
-    if cfg.head_type == "mlm":
-        specs["head"]["bias"] = P(vocab_ax)
-    return specs
 
 
 # ==================================================================== engine
@@ -720,7 +701,7 @@ def make_loss_and_grad(cfg, hp: HybridParallelConfig, mesh: Mesh):
         )
 
         # Gather the vocab layers from their pp-sharded STORAGE layout
-        # (vocab_param_specs: vocab over ('pp',) + vocab_tp — state is
+        # (pipeline.vocab_param_specs: vocab over ('pp',) + vocab_tp — state is
         # 1/(pp*vtp) per device) into the within-stage layout the schedule
         # computes in. This one cross-stage all-gather per step happens HERE,
         # before any divergence, where it is safe.

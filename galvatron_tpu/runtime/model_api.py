@@ -32,7 +32,7 @@ from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.models.parts.embed_head import table_is_looked_up
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.parallel import spec as S
-from galvatron_tpu.parallel.mesh import build_mesh, layer_axes, vocab_axes
+from galvatron_tpu.parallel.mesh import build_mesh, layer_axes, pipeline_vocab_axes, vocab_axes
 from galvatron_tpu.runtime.optimizer import opt_state_specs
 
 Params = Dict[str, Any]
@@ -558,12 +558,9 @@ def construct_hybrid_parallel_model(
     local_loss = None
     if hp.pp > 1 and hp.pipeline_type == "pipedream_flush":
         from galvatron_tpu.parallel import pipeline_1f1b
-        from galvatron_tpu.parallel.pipeline import (
-            make_pipelined_loss,
-            stack_layer_specs,
-        )
+        from galvatron_tpu.parallel.pipeline import make_pipelined_loss, stack_layer_specs, vocab_param_specs
 
-        specs = pipeline_1f1b.vocab_param_specs(cfg, hp)
+        specs = vocab_param_specs(cfg, hp)
         specs["stages"] = stack_layer_specs(cfg, hp)
         del specs["layers"]
         grad_fn = pipeline_1f1b.make_loss_and_grad(cfg, hp, mesh)
@@ -579,8 +576,9 @@ def construct_hybrid_parallel_model(
             eval_loss = None
         fwd = None
     elif hp.pp > 1:
-        from galvatron_tpu.parallel.pipeline import make_pipelined_loss, stack_layer_specs
+        from galvatron_tpu.parallel.pipeline import make_pipelined_loss, stack_layer_specs, vocab_param_specs
 
+        specs = vocab_param_specs(cfg, hp)
         specs["stages"] = stack_layer_specs(cfg, hp)
         del specs["layers"]
         base_loss = make_pipelined_loss(cfg, hp, mesh)
@@ -607,6 +605,7 @@ def construct_hybrid_parallel_model(
         # custom losses have no constraint-free local form; pp>1 never takes
         # the quantized path (GLS013)
         local_loss = None
+    looked_up = table_is_looked_up(pipeline_vocab_axes(hp))
     # the pp = 1 losses above read the table in the layout THIS model stores it in
     model = HybridParallelModel(
         cfg=cfg,
@@ -620,8 +619,7 @@ def construct_hybrid_parallel_model(
         local_loss_fn=local_loss,
         loss_parts_fn=loss_parts,
         cast_first=None if loss_fn is not None else S.cast_first_tree(
-            specs, table_stored=table_is_looked_up(vocab_axes(hp)) or cfg.tie_embeddings),
-        table_as_stored=(cfg.input_type != "patches" and table_is_looked_up(vocab_axes(hp))
-                         and not cfg.tie_embeddings),
+            specs, table_stored=looked_up or cfg.tie_embeddings),
+        table_as_stored=cfg.input_type != "patches" and looked_up and not cfg.tie_embeddings,
     )
     return model

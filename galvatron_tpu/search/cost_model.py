@@ -350,10 +350,18 @@ class MemoryCostModel:
                     acts = (a_f + a_l) * other_bsz / self.chunks
                     per_stage = [states + transient + acts] * self.pp_size
                 else:
-                    # scan pipeline embeds the whole batch up-front; embed on
-                    # the first stage, head on the last
-                    per_stage[0] = ms_f * ratio + a_f * other_bsz
-                    per_stage[-1] += ms_l * ratio + a_l * other_bsz
+                    # scan pipeline (pipeline.make_pipelined_loss): the table
+                    # and the head are STORED and COMPUTED split over
+                    # ('pp',) + vocab_tp (mesh.pipeline_vocab_axes), 1/pp of
+                    # both measured per-vtp states on EVERY stage and nothing
+                    # transient beside them. Every stage embeds the whole
+                    # batch up-front and runs the head and its loss over the
+                    # whole batch on its 1/pp of the columns. Under vocab-SP
+                    # the vocabulary is dense: every stage holds and computes
+                    # both layers whole.
+                    over_pp = 1 if vsp else self.pp_size
+                    per_stage = [(ms_f + ms_l) * ratio / over_pp
+                                 + (a_f + a_l / over_pp) * other_bsz] * self.pp_size
             self.other_memory_cost[vtp] = [x + ta.runtime_context_mem for x in per_stage]
 
     def get_memory_cost(self) -> Dict[str, Any]:

@@ -266,7 +266,9 @@ def test_a_checkpoint_crosses_between_the_split_table_and_the_whole_one(tmp_path
 # models/parts/embed_head._head_matmul and _token_nll; before it, from the commit before
 # the compute copy, 9c3c713, to PR 29, the text was one other; PR 52 stores
 # the looked-up table split over dp under ZeRO-2 whatever the compute dtype,
-# so `tp2dp2_zero2_fp32` is PR 52's step and the other six PR 30's). A PR that
+# so `tp2dp2_zero2_fp32` is PR 52's step; PR 54 splits the scan pipeline's
+# table and head over pp, so `gpipe_pp2dp2_zero2` is PR 54's; the other five
+# are PR 30's). A PR that
 # changes the step on purpose prints the new digests with
 # `pytest -k lowers_to -s` and replaces these.
 PARENT_STEP_SHA256 = {
@@ -275,7 +277,7 @@ PARENT_STEP_SHA256 = {
     "tp2dp2_ddp_chunks2": "5b6cd9e54b8928e4dd99a65f9ba070bf576b707a8f9da29745c241ae8be70815",
     "tp4_zero2_dp1": "3c537facf5b745a4add1697b6547b8e81cd6332accea54f6bac0b3945fd14a09",
     "tp2dp2_zero2_fp32": "d9177b1e44e79bc78a27e6c00e4b2d5672f47102bf219aa3474269cf6df30149",  # PR 52: the table split
-    "gpipe_pp2dp2_zero2": "59b8a51df20da0d2597b18a0c2b563aadb02a74d9a6f42afbbe132a129d0d597",
+    "gpipe_pp2dp2_zero2": "633ee69f9744f8b3d766bc364c9281162bda216a03446958eb3090ef311c6693",  # PR 54: the table over pp
     "tp2dp2_zero2_manual_tp": "e72629bd031daad6af1e4a377f78d497457a3631b97ed8e6ae58b28847749727",
 }
 

@@ -19,7 +19,7 @@ from galvatron_tpu.config.strategy import HybridParallelConfig
 from galvatron_tpu.models import base as M
 from galvatron_tpu.models.parts.embed_head import table_split_axes, vocab_parallel_lookup
 from galvatron_tpu.parallel import spec as S
-from galvatron_tpu.parallel.mesh import build_mesh, vocab_axes
+from galvatron_tpu.parallel.mesh import build_mesh, mesh_axis_size, pipeline_vocab_axes
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 
 pytestmark = [pytest.mark.parallel, pytest.mark.distributed]
@@ -29,9 +29,10 @@ V, H, B, SEQ = 64, 16, 4, 8
 
 def _layout(devices8, vtp, dp, **kw):
     hp = HybridParallelConfig.uniform(
-        vtp * dp * kw.get("pp", 1) * kw.get("vocab_cp", 1), 2, tp=vtp, vocab_tp=vtp,
+        vtp * dp * kw.get("pp", 1) * kw.get("vocab_cp", 1), max(2, kw.get("pp", 1)), tp=vtp, vocab_tp=vtp,
         cp=kw.get("vocab_cp", 1), global_bsz=B, **kw)
-    return build_mesh(hp, devices8[: hp.world_size]), vocab_axes(hp)
+    # (the axes the vocabulary layers compute under: `vocab_axes` at pp = 1)
+    return build_mesh(hp, devices8[: hp.world_size]), pipeline_vocab_axes(hp)
 
 
 def _tokens(vtp, shape=(B, SEQ)):
@@ -95,7 +96,6 @@ LAYOUTS = {
     "megatron_sp": dict(sequence_parallel=True),  # sum lands in sequence shards
     "embed_sdp": dict(embed_sdp=1),               # ZeRO-3 on the table's hidden dim
     "vocab_cp2": dict(vocab_cp=2),                # tokens split over the sequence too
-    "pp2": dict(pp=2, chunks=2),                  # GPipe embeds on the full mesh
 }
 
 
@@ -104,6 +104,33 @@ def test_split_lookup_under_layout(devices8, name):
     mesh, vax = _layout(devices8, 2, 2, **LAYOUTS[name])
     hlo = _check(mesh, vax, _tokens(2), jnp.bfloat16)[0]
     assert _count(hlo, "dot") == 0 and _count(hlo, "collective-permute") == 0
+
+
+# name -> (vocab_tp, dp, the pipeline): the scan pipeline's table is split over
+# ('pp',) + vocab_tp (mesh.pipeline_vocab_axes) and looked up on the full mesh
+OVER_PP = {
+    "pp2_vtp2_dp2": (2, 2, dict(pp=2, chunks=2)),                            # the benchmark cell's, with a dp axis
+    "pp2_vtp1_dp2_zero2": (1, 2, dict(pp=2, chunks=2, default_dp_type="zero2")),  # pp alone
+    "pp4_vtp2": (2, 1, dict(pp=4, chunks=4)),
+    "pp2_vtp2_dp2_embed_sdp": (2, 2, dict(pp=2, chunks=2, embed_sdp=1)),     # and its rows over dp
+    "pp2_vtp2_dp2_megatron_sp": (2, 2, dict(pp=2, chunks=2, sequence_parallel=True)),
+}
+
+
+@pytest.mark.parametrize("name", list(OVER_PP))
+def test_split_lookup_over_pp_and_vocab_tp(devices8, name):
+    """Ids of every shard's first and last row: each chip owns 1/(pp x vocab_tp)
+    of the rows, the sum runs over pp and tp, and neither the table nor its
+    gradient is gathered, summed over pp or permuted."""
+    vtp, dp, kw = OVER_PP[name]
+    mesh, vax = _layout(devices8, vtp, dp, **kw)
+    assert vax.tp[0] == "pp" and len(vax.tp) == (2 if vtp > 1 else 1)
+    shards = mesh_axis_size(mesh, vax.tp)
+    assert shards == kw["pp"] * vtp
+    hlo, _, grad = _check(mesh, vax, _tokens(shards), jnp.bfloat16)
+    assert grad.sharding.is_equivalent_to(NamedSharding(mesh, S.vocab_embed_spec(vax)), 2), grad.sharding
+    assert _count(hlo, "dot") == 0 and _count(hlo, "scatter") == 1
+    assert _count(hlo, "collective-permute") == 0 and _count(hlo, "all-gather") == (1 if vax.zero3 else 0)
 
 
 # ------------------------------------------ the table split over the ZeRO axes

@@ -115,6 +115,31 @@ def test_the_compile_event_says_whether_the_table_stays_split_over_dp(telemetry_
     assert "the token table stays split over dp" in capsys.readouterr().out
 
 
+def test_the_compile_event_names_the_axes_the_pipelines_vocabulary_is_split_over(telemetry_run, devices8, tmp_path, capsys):
+    """`vocab_split_axes`: pp, then the vocabulary's tp axes, where the scan
+    pipeline stores and computes its vocabulary layers split over them (pp2 x
+    tp2 on four devices, the pipelined cell's flags); absent at pp = 1 (the
+    shared run), and `cli report` says so in a line."""
+    assert "vocab_split_axes" in T.EVENT_SCHEMAS["compile"][1]
+    assert "vocab_split_axes" not in by_type(telemetry_run[1])["compile"][0]
+    tele = str(tmp_path / "run.jsonl")
+    argv = [
+        "--model_type", "llama", "--set_model_config_manually", "1",
+        "--hidden_size", "64", "--num_attention_heads", "4", "--num_layers", "2",
+        "--vocab_size", "128", "--seq_length", "32", "--mixed_precision", "bf16",
+        "--global_train_batch_size", "4", "--train_iters", "2", "--world_size", "4",
+        "--pp_deg", "2", "--global_tp_deg", "2", "--chunks", "2", "--vocab_tp", "2", "--checkpoint", "1",
+        "--telemetry", tele,
+    ]
+    train(initialize_galvatron(mode="train_dist", argv=argv))
+    events, errors = T.read_events(tele)
+    assert errors == []
+    assert [e["vocab_split_axes"] for e in events if e["type"] == "compile"] == [["pp", "m0"]]
+    assert all(np.isfinite(e["loss"]) for e in events if e["type"] == "step")
+    R.run([tele])
+    assert "vocabulary layers are stored and computed split over: pp, m0" in capsys.readouterr().out
+
+
 def test_summary_reports_mfu(telemetry_run):
     summary, _, _, _ = telemetry_run
     assert summary["model_flops_per_step"] > 0

@@ -349,6 +349,61 @@ def test_zero2_gathers_a_bf16_copy_and_stores_float32_shards_on_v5e(tp2dp2_step,
 
 
 @pytest.fixture(scope="module")
+def pp2tp2_cell_step(v5e_2x2):
+    """The pipelined benchmark cell `qwen7-c4-pp2tp2` at its own size (four
+    layers at Qwen2.5-7B's widths, 8 x 2048 tokens, pp2 x tp2, GPipe, 4
+    microbatches, `--vocab_tp 2`) compiled for the described 2x2 from the
+    cell's own files and flags: (model, step). About half a minute."""
+    from benchmarks import cells
+    from galvatron_tpu.cli.arguments import hp_config_from_args, initialize_galvatron, model_config_from_args
+
+    cell = cells.load_cell(REPO, "qwen7-c4-pp2tp2")
+    cells.register_family(cell)
+    args = initialize_galvatron(mode="train_dist", argv=cells.train_argv(cell, 0))
+    _, cfg = model_config_from_args(args)
+    assert cfg.max_seq_len == cell.traffic["seq_length"]
+    return _model_and_compiled_step(cfg, hp_config_from_args(args, cfg.num_layers, cell.chips), v5e_2x2,
+                                    batch_rows=cell.traffic["global_batch"])
+
+
+def test_the_pipelined_cell_splits_its_vocabulary_over_pp_on_v5e(pp2tp2_cell_step):
+    """The scan pipeline's vocabulary layers take the pp axis
+    (`mesh.pipeline_vocab_axes`): the table and the head go in and come out
+    split over pp x vocab_tp, a quarter of each a chip; the step holds under
+    9.6 GiB a chip (14.78 while every stage held and computed a whole tp-half
+    of both: PERF.md, PR 54); and NO collective of the compiled step has an
+    operand of a table's size or of a quarter, a half of it: what crosses pp
+    for these layers is activations (the lookup's sum, the head's input
+    gradient, the loss's maximum and sum)."""
+    model, step = pp2tp2_cell_step
+    cfg = model.cfg
+    split = P(("pp", "m0"), None)
+    assert model.param_specs["embed"]["wte"] == model.table_spec() == split
+    assert model.param_specs["lm_head"]["kernel"] == P(None, ("pp", "m0"))
+    table_in = step.input_shardings[0][0]["embed"]["wte"]
+    assert table_in.is_equivalent_to(NamedSharding(model.mesh, split), 2)
+    assert step.output_shardings[0]["embed"]["wte"].is_equivalent_to(table_in, 2)
+
+    ma = step.memory_analysis()
+    total = ma.argument_size_in_bytes + ma.temp_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert total < 9.6 * 2**30, "%.3f GiB" % (total / 2**30)
+
+    table = cfg.vocab_size * cfg.hidden_size
+    kinds = collections.Counter()
+    for line in step.as_text().splitlines():
+        op = re.search(r" = (.*?) (all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)(?:-start)?\(", line)
+        if not op:
+            continue
+        kinds[op.group(2)] += 1
+        for dims in re.findall(r"\w+\[([\d,]+)\]", op.group(1)):
+            n = int(np.prod([int(d) for d in dims.split(",")]))
+            assert n not in (table, table // 2, table // 4), line[:300]
+            # (the largest is the embedded batch, whole: 8 x 2048 x 3584 in bf16)
+            assert n <= 5 * 2 * 2048 * cfg.hidden_size, line[:300]
+    assert kinds["all-reduce"] and kinds["collective-permute"] and "tpu_custom_call" in step.as_text()
+
+
+@pytest.fixture(scope="module")
 def one_chip_head_ops(v5e_2x2):
     """The operations under `gt.head_loss` of a narrow LLaMA's train step
     (float32 parameters, bf16 compute, an untied (512, 32000) head) compiled

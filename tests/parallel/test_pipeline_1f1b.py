@@ -173,28 +173,35 @@ def test_1f1b_vit_classification(devices8):
 # ------------------------------------------------------------- memory bound
 @pytest.mark.slow
 def test_1f1b_peak_memory_below_gpipe(devices8):
-    """The 1F1B watermark (bounded stash) must beat the gpipe scan's
-    (all-chunks residuals) at pp=4, chunks=8 — the reference's motivation for
-    the schedule (pipeline.py:375-701, cost_model.py:85-97)."""
+    """The 1F1B watermark (a stash of min(pp + 1, chunks) stage inputs) against
+    the gpipe scan's (every tick's residuals) at pp=4 — the reference's
+    motivation for the schedule (pipeline.py:375-701, cost_model.py:85-97) —
+    held on what the schedules differ in: twice the microbatches of 2 rows (8
+    -> 16) add their residuals to the scan's temporaries and next to nothing
+    to the stash, and at 16 the stash is under 3/4 of them. (At 8 the two
+    stand at 0.85 since the scan's vocabulary layers are split over pp and
+    its temporaries no longer carry whole-vocabulary gradients on every
+    stage: 0.75 with them, which is what this test held before.)"""
     cfg = TransformerConfig(hidden_size=128, num_heads=4, num_layers=4,
                               vocab_size=256, max_seq_len=128, compute_dtype=jnp.float32)
-    Bm, Sm = 16, 128
+    Sm = 128
 
-    def temp_bytes(ptype):
-        hp = HybridParallelConfig.uniform(8, 4, pp=4, global_bsz=Bm, chunks=8,
+    def temp_bytes(ptype, chunks):
+        hp = HybridParallelConfig.uniform(8, 4, pp=4, global_bsz=2 * chunks, chunks=chunks,
                                           pipeline_type=ptype, checkpoint=1)
         m = construct_hybrid_parallel_model(cfg, hp, devices8)
         p = jax.eval_shape(m._init_fn, jax.random.PRNGKey(0))
-        tok = jax.ShapeDtypeStruct((Bm, Sm), jnp.int32)
+        tok = jax.ShapeDtypeStruct((2 * chunks, Sm), jnp.int32)
         batch = dict(tokens=tok, positions=tok, labels=tok)
         tx = optax.sgd(1e-3)
         st = jax.eval_shape(tx.init, p)
         ma = m.make_train_step(tx).lower(p, st, batch).compile().memory_analysis()
         return ma.temp_size_in_bytes
 
-    gpipe = temp_bytes("gpipe")
-    f1b = temp_bytes("pipedream_flush")
-    assert f1b < 0.75 * gpipe, (f1b, gpipe)
+    gpipe, f1b = ({chunks: temp_bytes(ptype, chunks) for chunks in (8, 16)}
+                  for ptype in ("gpipe", "pipedream_flush"))
+    assert f1b[16] < 0.75 * gpipe[16], (f1b, gpipe)
+    assert f1b[16] - f1b[8] < 0.05 * (gpipe[16] - gpipe[8]), (f1b, gpipe)
 
 
 @pytest.mark.slow

@@ -94,6 +94,40 @@ def test_other_memory_has_vtp_candidates_and_stages():
     assert other[1][0] > 0 and other[1][-1] > 0
 
 
+def _other(strategy, use_zero2, vsp, pipeline_type="gpipe"):
+    model = MemoryCostModel(
+        strategy, global_batch_size=8, mbsz=1, min_tp=1, max_tp=4, vsp=vsp,
+        model_args=ModelArgs(parameter_size=48.0, layer_num=8), train_args=TrainArgs(),
+        parallel_args=ParallelArgs(chunks=2, use_zero2_for_dp=use_zero2, pipeline_type=pipeline_type),
+        profile_model_args=ProfileModelArgs(
+            tp_activation_per_bsz_dict=ACT, other_memory_pp_off=OTHER_OFF, other_memory_pp_on=OTHER_ON))
+    return model, model.get_memory_cost()["other"]
+
+
+@pytest.mark.parametrize("strategy,use_zero2,vsp", [
+    ([2, 2, 2, {}], False, 0), ([2, 2, 2, {}], True, 0), ([4, 1, 2, {}], True, 0), ([2, 2, 2, {"sp": 1}], False, 1),
+], ids=["pp2tp2dp2-ddp", "pp2tp2dp2-zero2", "pp4dp2-zero2", "pp2-vocab-sp"])
+def test_scan_pipeline_holds_the_vocabulary_over_pp_on_every_stage(strategy, use_zero2, vsp):
+    """What parallel/pipeline.py holds: the table's and the head's measured
+    states split over ('pp',) + vocab_tp, `(ms_f + ms_l) * ratio / pp` on EVERY
+    stage, the embedded batch whole and the head's activations on 1/pp of the
+    columns; under vocab-SP both layers whole on every stage. The 1F1B branch
+    stores the same share and keeps its transient copy beside it."""
+    pp, tp, dp = strategy[:3]
+    first, last = OTHER_ON["first_stage"], OTHER_ON["last_stage"]
+    context = TrainArgs().runtime_context_mem
+    model, other = _other(strategy, use_zero2, vsp)
+    flush = _other(strategy, use_zero2, vsp, "pipedream_flush")[1]
+    assert sorted(other) == sorted(flush) and len(other) >= 2
+    for vtp, stages in other.items():
+        ratio = model.zero2_ratio(tp * dp if vsp else tp * dp // vtp) if use_zero2 else 1.0
+        ms = first["model_states"][1 if vsp else vtp] + last["model_states"][1 if vsp else vtp]
+        a_f, a_l = first["activation"][vtp], last["activation"][vtp]
+        over_pp, bsz = 1 if vsp else pp, 8 * vtp / (tp * dp)
+        assert stages == pytest.approx([ms * ratio / over_pp + (a_f + a_l / over_pp) * bsz + context] * pp)
+        assert flush[vtp] == pytest.approx([ms * ratio / pp + 0.5 * ms + (a_f + a_l) * bsz / 2 + context] * pp)
+
+
 def test_time_comm_overhead_positive():
     # strategies at the same pp pay for their collectives vs a no-comm run
     t_tp = tk([1, 8, 1, {}])
