@@ -25,12 +25,15 @@ from galvatron_tpu.cli.arguments import (
     initialize_galvatron,
     model_config_from_args,
 )
+from galvatron_tpu.models import base as model_base
 from galvatron_tpu.models.parts import embed_head, mlp
+from galvatron_tpu.obs import compiled as obs_compiled
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import launch, telemetry, tracing
 from galvatron_tpu.ops import attention as attention_ops
 from galvatron_tpu.ops import linear_attention, moe
 from galvatron_tpu.parallel import pipeline
+from galvatron_tpu.parallel.mesh import layer_axes
 from galvatron_tpu.profiler.runtime import (
     RuntimeProfiler,
     compiled_step_memory_mb,
@@ -52,7 +55,7 @@ launch.IMPORTS.done()  # the program is imported: the import record closes and g
 KERNEL_FORMS = dict(
     delta_rule=linear_attention.TOOK, moe_rows=moe.ROWS_TOOK, moe_windows=moe.WINDOWS_TOOK,
     kernels_relaid=mlp.RELAID, windows=attention_ops.TOOK, lookups=embed_head.LOOKUPS_TOOK,
-    vocab_split=pipeline.VOCAB_SPLIT_TOOK)
+    vocab_split=pipeline.VOCAB_SPLIT_TOOK, scan_grads=model_base.SCAN_GRADS_IN_ZERO_LAYOUT)
 
 
 # In-process memo of AOT-compiled train-step executables, keyed by (device
@@ -69,6 +72,21 @@ _STEP_EXECUTABLES_MAX = 16
 def _step_exec_key(mesh, lowered):
     devs = tuple(int(d.id) for d in mesh.devices.flat)
     return (devs, hashlib.sha256(lowered.as_text().encode()).hexdigest())
+
+
+def _scan_grad_sums_mb(model, compiled) -> dict:
+    """`obs/compiled.dp_grad_sums_mb` of the compiled step over the dp groups
+    of the model's layers: {} on one chip, in a layout without a dp axis,
+    where the executable gives no text, and where no telemetry sink would
+    hear of it (printing the step's text is the launch's to pay for)."""
+    dp_axes = {layer_axes(model.hp, i).dp for i in range(len(model.hp.layers))} - {()}
+    if model.mesh.devices.size == 1 or not dp_axes or telemetry.active_sink() is None:
+        return {}
+    try:
+        text = compiled.as_text()
+    except Exception:  # an executable read back without its modules
+        return {}
+    return obs_compiled.dp_grad_sums_mb(text, [obs_compiled.axis_groups(model.mesh, dp) for dp in dp_axes])
 
 
 def _compile_step(lowered, counters: launch.JitCounters):
@@ -615,6 +633,18 @@ def _train(args, started: launch.Launch) -> dict:
                 # pp, then the vocabulary's tp axes); absent at pp = 1, under
                 # vocab-SP and in the 1F1B engines, which traced no such loss
                 vocab_split_axes=next((list(axes) for axes in forms.took["vocab_split"]), None),
+                # the stacked leaves, over the step's scanned runs, whose
+                # cotangent was asked for in ZeRO's layout as traced
+                # (`models/base.run_layers`): a run's kernels and biases where
+                # ZeRO-2 splits the state over dp > 1; 0 on one chip, under
+                # ddp, ZeRO-3's own leaves, pp > 1 and the manual TP path
+                scan_grads_in_zero_layout=len(forms.took["scan_grads"]),
+                # and what the COMPILER made of it: MB a chip and a layer of
+                # the weight gradients over 1 MB that the compiled step sums
+                # over dp inside a scanned run's backward, whole onto every
+                # chip (`dp_grad_all_reduce_mb`) or into ZeRO's shards
+                # (`dp_grad_reduce_scatter_mb`); absent without a dp axis
+                **_scan_grad_sums_mb(model, compiled),
             )
             _aot["fn"] = compiled
         return _aot["fn"](*step_args)

@@ -17,6 +17,7 @@ no part. The config is `models/config.TransformerConfig`."""
 
 from __future__ import annotations
 
+import collections
 from functools import partial
 from typing import Dict, List, Optional, Tuple
 
@@ -290,6 +291,29 @@ def stacked_layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params
     )
 
 
+def stacked_layer_grad_specs(cfg: TransformerConfig, axes: LayerAxes, stacked: Params, mesh: Mesh) -> Params:
+    """The specs of a run's stacked COTANGENT where ZeRO may split the state:
+    each layer's leaf as `spec.zero_split_spec` lays its gradient out over the
+    run's ZeRO axes (what runtime/model_api.grad_accum_specs gives the same
+    leaf, by the same two functions), behind the unsharded layer axis.
+    `stacked`: the run's stacked leaves, for their shapes. A leaf ZeRO does
+    not split further (ddp, dp = 1, a ZeRO-3 leaf, one no dim of which
+    divides) gets `stacked_layer_param_specs`' spec back, equal to it."""
+    zax, mesh_shape = S.zero_axes(axes), dict(mesh.shape)
+    return jax.tree.map(
+        lambda sp, t: P(None, *S.zero_split_spec(sp, t.shape[1:], zax, mesh_shape)),
+        layer_param_specs(cfg, axes), stacked, is_leaf=lambda t: isinstance(t, P),
+    )
+
+
+# the stacked leaves whose cotangent a scanned run asked for in ZeRO's layout
+# since the process began, by run and path, counted as they are traced
+# (`run_layers`): the trainer's compile report reads how many a step's trace
+# added. What the compiler made of the request is the compiled step's to say
+# (obs/compiled.dp_grad_sums_mb)
+SCAN_GRADS_IN_ZERO_LAYOUT = collections.Counter()
+
+
 def _at(tree: Params, path: Tuple[str, ...], fn) -> Params:
     """`tree` with `fn` applied to the leaf at `path`; the rest shared."""
     return {**tree, path[0]: _at(tree[path[0]], path[1:], fn) if path[1:] else fn(tree[path[0]])}
@@ -337,6 +361,7 @@ def run_layers(
     attn_bias: Optional[jax.Array] = None,
     scan: Optional[bool] = None,
     collect_kv: bool = False,
+    zero_splits_state: bool = False,
 ):
     """The encoder stack with per-layer sharding constraints and remat.
 
@@ -355,6 +380,23 @@ def run_layers(
     depth-constant trace. The collecting path is GSPMD-only and forward-only
     (no manual-TP shard_map body, no remat): serve lints away the layouts
     that would need either.
+
+    ``zero_splits_state``: the caller's answer to
+    `runtime/model_api.HybridParallelModel._zero_splits_state`, the ONE
+    predicate for "ZeRO's dp axes may split a leaf of the state further than
+    `param_specs` does" (not under pp > 1, the manual TP path, the quantized
+    sync, the 1F1B engines or a custom loss, whose code sums a leaf's
+    gradient itself in `param_specs`' layout; whoever calls without a model
+    has no split state). Where it holds, a scanned run reads its stacked
+    leaves through `spec.constrain_grad_as`: the forward's constraint as
+    ever, the cotangent's to `stacked_layer_grad_specs`, so that the scan's
+    body ends a leaf's sum over dp in the shards the step accumulates
+    (a reduce-scatter a layer) and not whole on every chip (an all-reduce of
+    which `to_accum` keeps a slice). Every leaf of the run alike, a routed
+    block's experts' kernels too (`spec.cast_first_tree`'s `routed` leaves):
+    `ops/moe.moe_ffn`'s manual region has summed their cotangents over dp at
+    its boundary, whole, and the constraint is then the slice `to_accum`
+    would take after the scan, taken a layer earlier.
 
     A config with ``layer_aux`` returns ``(x, auxs)``, the layers' auxiliary
     terms (the routers', the linear mixers' counters) a layer or a scanned
@@ -411,17 +453,24 @@ def run_layers(
                 auxs.append(aux)
         return x
 
-    def one_run(x, run):
+    def one_run(x, run, k):
         if not scanned(run):
             return unrolled(x, run.layer_indices)
         lcfg = cfg.layer_config(kinds[run.start])  # a run is of one kind
         axes = layer_axes(hp, run.start) if use_hp else None
         stacked = stack_layer_run([layers[i] for i in run.layer_indices])
         if use_hp:
-            stacked = jax.tree.map(
-                lambda t, sp: S.constrain(t, mesh, sp),
-                stacked, stacked_layer_param_specs(lcfg, axes),
-            )
+            read_as = stacked_layer_param_specs(lcfg, axes)
+            # the gradient where ZeRO keeps it, or (no split state) where the
+            # forward reads the leaf: the plain constraint
+            summed_as = stacked_layer_grad_specs(lcfg, axes, stacked, mesh) if zero_splits_state else read_as
+
+            def constrained(path, t, sp, grad_sp):
+                if sp != grad_sp:
+                    SCAN_GRADS_IN_ZERO_LAYOUT[(k, jax.tree_util.keystr(path))] += 1
+                return S.constrain_grad_as(t, mesh, sp, grad_sp)
+
+            stacked = jax.tree_util.tree_map_with_path(constrained, stacked, read_as, summed_as)
         if collect_kv:
             body = partial(layer_forward, cfg=lcfg, mesh=mesh, axes=axes,
                            attn_bias=attn_bias, return_kv=True)
@@ -461,7 +510,7 @@ def run_layers(
     for k, run in enumerate(runs):
         # one scope a run, scanned or unrolled; k is the `layer_run` event's
         with jax.named_scope(tracing.layers_scope(k)):
-            x = one_run(x, run)
+            x = one_run(x, run, k)
     if collect_kv:
         return x, kvs
     if cfg.layer_aux:
@@ -506,7 +555,8 @@ def model_forward(params, tokens, positions, cfg, hp=None, mesh=None, **inputs) 
     in parallel/pipeline.py). `table_spec`, here and in the loss functions
     below: the spec the token table is STORED in where that is not
     `param_specs`' (runtime/model_api.state_specs), which chooses the form of
-    a vocabulary-split table's lookup (embed_head.vocab_parallel_lookup)."""
+    a vocabulary-split table's lookup (embed_head.vocab_parallel_lookup);
+    `zero_splits_state`: `run_layers`'."""
     return _forward(params, tokens, positions, cfg, hp, mesh, **inputs)[0]
 
 
@@ -520,6 +570,7 @@ def _forward(
     token_type_ids: Optional[jax.Array] = None,
     attn_mask: Optional[jax.Array] = None,
     table_spec: Optional[P] = None,
+    zero_splits_state: bool = False,
 ):
     """-> (logits, the last layer's output before the final norm, the routed
     blocks' auxiliary terms as `run_layers` lists them or None)."""
@@ -536,7 +587,7 @@ def _forward(
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
     bias = padding_attn_bias(attn_mask) if attn_mask is not None else None
-    x = run_layers(params, x, positions, cfg, hp, mesh, attn_bias=bias)
+    x = run_layers(params, x, positions, cfg, hp, mesh, attn_bias=bias, zero_splits_state=zero_splits_state)
     x, auxs = x if cfg.layer_aux else (x, None)
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
@@ -602,7 +653,7 @@ PART_FOLDS = {EXPERT_LOAD: jnp.maximum, "router_bias_abs_max": jnp.maximum,
 
 
 def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
-               table_spec: Optional[P] = None):
+               table_spec: Optional[P] = None, zero_splits_state: bool = False):
     """batch: dict(tokens, positions, labels, loss_mask?, token_type_ids?,
     attn_mask?). Serves lm and mlm heads (token-level CE).
 
@@ -621,7 +672,7 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
     logits, hidden, auxs = _forward(
         params, batch["tokens"], batch["positions"], cfg, hp, mesh,
         token_type_ids=batch.get("token_type_ids"), attn_mask=batch.get("attn_mask"),
-        table_spec=table_spec,
+        table_spec=table_spec, zero_splits_state=zero_splits_state,
     )
     labels, mask = batch["labels"], batch.get("loss_mask")
     with jax.named_scope(tracing.HEAD_LOSS):
@@ -693,12 +744,14 @@ def update_router_bias(params: Params, counts: jax.Array, rate: float) -> Params
     return out
 
 
-def classification_loss_fn(params, batch, cfg, hp=None, mesh=None, table_spec: Optional[P] = None):
+def classification_loss_fn(params, batch, cfg, hp=None, mesh=None, table_spec: Optional[P] = None,
+                           zero_splits_state: bool = False):
     """batch: dict(pixels | tokens, labels). Mean softmax CE over classes
     (reference vit/swin `Cls_` heads)."""
     inputs = batch.get("pixels", batch.get("tokens"))
     logits = model_forward(params, inputs, batch.get("positions"), cfg, hp, mesh,
-                           attn_mask=batch.get("attn_mask"), table_spec=table_spec)
+                           attn_mask=batch.get("attn_mask"), table_spec=table_spec,
+                           zero_splits_state=zero_splits_state)
     with jax.named_scope(tracing.HEAD_LOSS):
         return softmax_nll(logits, batch["labels"])
 

@@ -448,6 +448,12 @@ def render(analysis: Dict[str, Any]) -> str:
         if comp.get("vocab_split_axes"):
             lines.append("the pipeline's vocabulary layers are stored and computed split over: %s"
                          % ", ".join(comp["vocab_split_axes"]))
+        if comp.get("scan_grads_in_zero_layout"):
+            lines.append("stacked leaves of scanned runs whose gradient is summed into ZeRO's shards: %d"
+                         % comp["scan_grads_in_zero_layout"])
+        if "dp_grad_all_reduce_mb" in comp:
+            lines.append("a scanned layer's weight gradients over dp, MB a chip: %s all-reduced, %s reduce-scattered"
+                         % (_fmt(comp["dp_grad_all_reduce_mb"]), _fmt(comp.get("dp_grad_reduce_scatter_mb"))))
     an = analysis["anomalies"]
     lines.append(
         "resilience: %d anomalies skipped, %d rollbacks, %d retries"

@@ -96,7 +96,8 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
          "cache_hit", "linear_kernel_layers", "linear_pass_kernel_layers",
          "kda_kernel_layers", "kda_pass_kernel_layers", "moe_row_kernel_blocks", "expert_window_rows",
          "shortconv_layers", "kernel_grads_relaid", "window_kernel_layers",
-         "window_operands_as_projected", "table_rows_over_dp", "vocab_split_axes"),
+         "window_operands_as_projected", "table_rows_over_dp", "vocab_split_axes",
+         "scan_grads_in_zero_layout", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb"),
     ),
     # where the start went, once the first step has drained (obs/launch.py):
     # `launch_ms` the phases of cli/train._train by name (obs/tracing.py's

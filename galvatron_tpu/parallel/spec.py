@@ -5,7 +5,15 @@ This module replaces three reference subsystems at once:
 - per-layer FSDP wrapping with ShardingStrategy {NO_SHARD, SHARD_GRAD_OP,
   FULL_SHARD} (reference: galvatron/core/runtime/parallel.py:92-199) — here,
   ZeRO-3 is a parameter sharding over the layer's dp sub-axes and ZeRO-1/2 is
-  an optimizer-state/grad-accumulator sharding (see runtime/optimizer.py);
+  an optimizer-state/grad-accumulator sharding: `zero_split_spec` is the one
+  statement of where dp goes on a leaf, read by the moments
+  (runtime/optimizer.opt_state_specs), the accumulated gradient and the
+  stored state (runtime/model_api.grad_accum_specs) and a scanned run's
+  cotangent (models/base.stacked_layer_grad_specs). That the per-microbatch
+  sum over dp ENDS in those shards, a reduce-scatter and not an all-reduce
+  that is sliced afterwards, is made true where the sum is made: for the
+  scanned layers inside the backward scan's body, by `constrain_grad_as` on
+  the run's stacked leaves (models/base.run_layers);
 - Megatron Column/RowParallelLinear weight partitioning with per-layer groups
   (reference: site_package/megatron/core/tensor_parallel/layers.py:126-228) —
   here, a column kernel is `P(..., tp)` and a row kernel `P(tp, ...)`;
@@ -18,6 +26,7 @@ This module replaces three reference subsystems at once:
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -26,6 +35,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from galvatron_tpu.parallel.mesh import LayerAxes
 
 Axes = Union[None, str, Tuple[str, ...]]
+
+
+def _entry_axes(e: Axes) -> Tuple[str, ...]:
+    if e is None:
+        return ()
+    if isinstance(e, str):
+        return (e,)
+    return tuple(e)
 
 
 def _ax(axes: Sequence[str]) -> Axes:
@@ -97,6 +114,39 @@ def vocab_embed_spec(ax: LayerAxes) -> P:
     return P(_ax(ax.tp), _ax(_zero3_axes(ax) or ()))
 
 
+# ------------------------------------------------ where ZeRO keeps a gradient
+def zero_axes(ax: LayerAxes) -> Tuple[str, ...]:
+    """The dp axes ZeRO splits a layer's moments and gradient over: the
+    layer's dp axes under ZeRO-1/2/3, none under ddp."""
+    return tuple(ax.dp) if ax.zero_opt else ()
+
+
+def zero_split_spec(param_spec: P, shape, dp_axes, mesh_shape) -> P:
+    """Where ZeRO puts dp on a leaf, stated once: the dp sub-axes go on the
+    first dim that is unsharded and divisible, the flat-param shard analogue
+    of FSDP SHARD_GRAD_OP (reference parallel.py:107-111, cost_model.py:99-110).
+    The layout of Adam's moments (runtime/optimizer.opt_state_specs), of the
+    accumulated gradient and a copied leaf of the state
+    (runtime/model_api.grad_accum_specs) and of a scanned run's cotangent
+    (models/base.stacked_layer_grad_specs). `param_spec` itself, the very
+    object, where nothing is to split: no dp axes (ddp, dp = 1), a leaf
+    already split over dp (ZeRO-3), a leaf no dim of which divides."""
+    if not dp_axes:
+        return param_spec
+    entries = list(param_spec) + [None] * (len(shape) - len(param_spec))
+    dp_size = 1
+    for a in dp_axes:
+        dp_size *= mesh_shape[a]
+    used = {x for e in entries for x in _entry_axes(e)}
+    if any(a in used for a in dp_axes):
+        return param_spec  # already dp-sharded (zero3 param)
+    for i, e in enumerate(entries):
+        if e is None and shape[i] % dp_size == 0:
+            entries[i] = _ax(dp_axes)
+            return P(*entries)
+    return param_spec
+
+
 # ------------------------------------------------- how the models read a leaf
 def cast_first_tree(param_specs, *, table_stored: bool):
     """Tree of bools shaped like `param_specs`: True where a model reads the
@@ -154,12 +204,27 @@ def constrain(x, mesh: Mesh, spec: P):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def _entry_axes(e: Axes) -> Tuple[str, ...]:
-    if e is None:
-        return ()
-    if isinstance(e, str):
-        return (e,)
-    return tuple(e)
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _constrain_both_ways(x, sharding: NamedSharding, grad_sharding: NamedSharding):
+    return jax.lax.with_sharding_constraint(x, sharding)
+
+
+_constrain_both_ways.defvjp(
+    lambda x, sharding, grad_sharding: (_constrain_both_ways(x, sharding, grad_sharding), None),
+    lambda sharding, grad_sharding, _, g: (jax.lax.with_sharding_constraint(g, grad_sharding),))
+
+
+def constrain_grad_as(x, mesh: Mesh, spec: P, grad_spec: P):
+    """`constrain(x, mesh, spec)` whose cotangent is constrained to
+    `grad_spec` instead. The transpose of a sharding constraint is the same
+    constraint on the cotangent: a leaf the forward reads whole over dp has
+    its gradient pinned whole over dp, and a sum over dp that could end in
+    the shards ZeRO keeps (a reduce-scatter) has to end whole on every chip
+    (an all-reduce). Equal specs give the plain constraint, and the jaxpr it
+    always gave."""
+    if spec == grad_spec:
+        return constrain(x, mesh, spec)
+    return _constrain_both_ways(x, NamedSharding(mesh, spec), NamedSharding(mesh, grad_spec))
 
 
 def meet_spec(a: P, b: P, ndim: int) -> P:

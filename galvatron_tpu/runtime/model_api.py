@@ -113,7 +113,11 @@ class HybridParallelModel:
         gradient itself, in the dtype and the layout the leaf comes in:
         GPipe's scan (pp > 1) over the microbatches, the manual TP path's
         regions over dp, the 1F1B engines (`grad_fn`) and the quantized grad
-        sync, whose regions are written for the `param_specs` layout."""
+        sync, whose regions are written for the `param_specs` layout.
+        The model's own losses are handed the answer (`zero_splits_state`)
+        and `models/base.run_layers` then asks for a scanned run's stacked
+        cotangent in ZeRO's layout too: one predicate for the state's split
+        and for the gradient summed into it."""
         from galvatron_tpu.parallel import quant_collectives as QC
 
         return not (self.cast_first is None or self.hp.pp > 1 or self.grad_fn is not None
@@ -271,7 +275,7 @@ class HybridParallelModel:
         """Per-param dp axes over which to shard adam moments (ZeRO-1/2/3)."""
 
         def for_axes(ax, tree):
-            zax = tuple(ax.dp) if ax.zero_opt else ()
+            zax = S.zero_axes(ax)
             return jax.tree.map(lambda _: zax, tree)
 
         ps = self.param_specs
@@ -290,15 +294,26 @@ class HybridParallelModel:
         return out
 
     def grad_accum_specs(self):
-        """Accumulated-grad shardings: dp-sharded wherever ZeRO applies, so the
-        per-microbatch reduction is a reduce-scatter not an all-reduce
-        (reference grad_reduce.py:47-64 no-sync + flush semantics)."""
+        """Accumulated-grad shardings: dp-sharded wherever ZeRO applies
+        (`parallel/spec.zero_split_spec`), so that the per-microbatch
+        reduction can be a reduce-scatter and not an all-reduce (reference
+        grad_reduce.py:47-64 no-sync + flush semantics). The step asks for
+        this layout AFTER the backward (`to_accum`), which decides nothing
+        about a sum the backward has already finished. What makes the
+        reduce-scatter true: the head's and the unrolled layers' cotangents
+        reach `to_accum` unconstrained, so the compiler ends their sums in
+        this layout; a scanned run's stacked cotangent is asked for in this
+        layout INSIDE the scan's body (`models/base.run_layers`, through
+        `stacked_layer_grad_specs` and `spec.constrain_grad_as`, where
+        `_zero_splits_state` holds), and `to_accum` is then a no-op on it.
+        Everywhere else (GPipe, the manual TP path, the 1F1B engines, the
+        quantized sync) the model's code sums a gradient whole over dp and
+        this is a slice of the sum."""
         shapes = self.abstract_params()
         mesh_shape = dict(self.mesh.shape)
-        from galvatron_tpu.runtime.optimizer import _shard_moment_spec
 
         return jax.tree.map(
-            lambda spec, shp, zax: _shard_moment_spec(spec, shp.shape, tuple(zax), mesh_shape),
+            lambda spec, shp, zax: S.zero_split_spec(spec, shp.shape, tuple(zax), mesh_shape),
             self.param_specs,
             shapes,
             self.zero_axes_tree(),
@@ -584,14 +599,16 @@ def construct_hybrid_parallel_model(
         base_loss = make_pipelined_loss(cfg, hp, mesh)
         fwd = None
     elif cfg.head_type == "classification":
-        base_loss = lambda p, b: M.classification_loss_fn(p, b, cfg, hp, mesh, model.table_spec())
+        base_loss = lambda p, b: M.classification_loss_fn(
+            p, b, cfg, hp, mesh, model.table_spec(), zero_splits_state=model._zero_splits_state())
         fwd = lambda p, b: M.model_forward(
             p, b.get("pixels", b.get("tokens")), b.get("positions"), cfg, hp, mesh,
             attn_mask=b.get("attn_mask"), table_spec=model.table_spec(),
         )
         local_loss = lambda p, b: M.classification_loss_fn(p, b, cfg)
     else:
-        base_loss = lambda p, b: M.lm_loss_fn(p, b, cfg, hp, mesh, table_spec=model.table_spec())
+        base_loss = lambda p, b: M.lm_loss_fn(
+            p, b, cfg, hp, mesh, table_spec=model.table_spec(), zero_splits_state=model._zero_splits_state())
         fwd = lambda p, b: M.model_forward(
             p, b["tokens"], b["positions"], cfg, hp, mesh,
             token_type_ids=b.get("token_type_ids"), attn_mask=b.get("attn_mask"),
@@ -600,7 +617,8 @@ def construct_hybrid_parallel_model(
         local_loss = lambda p, b: M.lm_loss_fn(p, b, cfg)
         if loss_fn is None and getattr(cfg, "layer_aux", False):
             loss_parts = lambda p, b: M.lm_loss_fn(
-                p, b, cfg, hp, mesh, with_parts=True, table_spec=model.table_spec())
+                p, b, cfg, hp, mesh, with_parts=True, table_spec=model.table_spec(),
+                zero_splits_state=model._zero_splits_state())
     if hp.pp > 1 or loss_fn is not None:
         # custom losses have no constraint-free local form; pp>1 never takes
         # the quantized path (GLS013)

@@ -3,8 +3,8 @@
 Replaces apex FusedAdam + Megatron's OptimizerParamScheduler (reference:
 galvatron/core/runtime/utils.py:137-167). On TPU, optax adamw is XLA-fused;
 ZeRO-1/2 optimizer-state sharding is a *sharding of the adam moments over the
-per-layer dp sub-axes* (see zero_opt_specs) rather than a different optimizer
-wrapper — GSPMD inserts the gather/scatter around the elementwise update."""
+per-layer dp sub-axes* (`opt_state_specs`, by `parallel/spec.zero_split_spec`)
+rather than a different optimizer wrapper — GSPMD inserts the gather/scatter around the elementwise update."""
 
 from __future__ import annotations
 
@@ -17,6 +17,7 @@ import optax
 from jax.sharding import PartitionSpec as P
 
 from galvatron_tpu.models.parts.mlp import ROUTER_BIAS
+from galvatron_tpu.parallel.spec import zero_split_spec
 
 
 @dataclass
@@ -106,31 +107,7 @@ def get_optimizer_and_scheduler(args: Optional[OptimizerArgs] = None):
 
 
 # ------------------------------------------------------------- state sharding
-def _shard_moment_spec(param_spec: P, shape, dp_axes, mesh_shape) -> P:
-    """ZeRO-1/2: place the dp sub-axes on the first dim of the moment that is
-    unsharded and divisible — the flat-param shard analogue of FSDP
-    SHARD_GRAD_OP (reference parallel.py:107-111, cost_model.py:99-110)."""
-    if not dp_axes:
-        return param_spec
-    entries = list(param_spec) + [None] * (len(shape) - len(param_spec))
-    dp_size = 1
-    for a in dp_axes:
-        dp_size *= mesh_shape[a]
-    used = set()
-    for e in entries:
-        if e is None:
-            continue
-        for x in (e if isinstance(e, tuple) else (e,)):
-            used.add(x)
-    if any(a in used for a in dp_axes):
-        return param_spec  # already dp-sharded (zero3 param)
-    for i, e in enumerate(entries):
-        if e is None and shape[i] % dp_size == 0:
-            entries[i] = dp_axes if len(dp_axes) > 1 else dp_axes[0]
-            return P(*entries)
-    return param_spec
-
-
+# (ZeRO-1/2: a moment lies where `parallel/spec.zero_split_spec` puts dp on its leaf)
 def opt_state_specs(tx_state, param_specs, param_shapes, zero_axes_tree, mesh):
     """Build a sharding-spec pytree for an optax state.
 
@@ -139,7 +116,7 @@ def opt_state_specs(tx_state, param_specs, param_shapes, zero_axes_tree, mesh):
 
     def moment_spec(ps, shape, zax):
         shp = shape.shape if hasattr(shape, "shape") else shape
-        return _shard_moment_spec(ps, shp, tuple(zax), dict(mesh.shape))
+        return zero_split_spec(ps, shp, tuple(zax), dict(mesh.shape))
 
     def map_state(state):
         if isinstance(state, optax.ScaleByAdamState):

@@ -88,27 +88,65 @@ def train(cfg, hp, devices, steps=3, model=None, batch=None):
 
 
 # ------------------------------------------------------------- no value changes
-@pytest.mark.parametrize("dp_type,kw", [
-    ("zero2", dict()), ("zero2", dict(chunks=2)), ("zero2", dict(tp=1, pp=2, chunks=2)),
-    ("zero3", dict(tp=1))],
-    ids=["tp2dp2", "tp2dp2_chunks2", "gpipe_pp2dp2_chunks2", "dp4_zero3"])
-def test_zero_trains_as_ddp_does(dp_type, kw, devices4):
+def first_gradient(m):
+    """The first step's gradient as the step takes it: of the loss on the
+    copy the step reads, before `to_accum` widens it."""
+    params = m.init_params(jax.random.PRNGKey(0))
+    grad = jax.jit(lambda p, b: jax.grad(m.loss_fn)(m.compute_params(p), b))
+    return leaf_paths(grad(params, m.shard_batch(lm_batch())))
+
+
+@pytest.mark.parametrize("dp_type,kw,dp_summed_first", [
+    ("zero2", dict(), "['wi']['kernel']"), ("zero2", dict(sequence_parallel=False), None),
+    ("zero2", dict(chunks=2), None), ("zero2", dict(tp=1, pp=2, chunks=2), None),
+    ("zero3", dict(tp=1), None)],
+    ids=["tp2dp2", "tp2dp2_no_megatron_sp", "tp2dp2_chunks2", "gpipe_pp2dp2_chunks2", "dp4_zero3"])
+def test_zero_trains_as_ddp_does(dp_type, kw, dp_summed_first, devices4):
     """bf16 compute, three steps, one seed: ZeRO with the copy against ddp.
     Tolerance: tests/models/test_parallel_correctness.py's. GPipe (pp > 1)
     takes no copy: its scan sums a stage's gradient over the microbatches in
     the dtype the stage reads, float32 only while the cast is inside it.
     Under `zero3` the layers' leaves are split over dp as ZeRO-3 has them
-    and the vocabulary layers' (ZeRO-2 there without `embed_sdp`) are copied."""
+    and the vocabulary layers' (ZeRO-2 there without `embed_sdp`) are copied.
+
+    `dp_summed_first` (PR 55): under Megatron-SP, at this size, XLA:CPU
+    contracts the gated up kernel's weight gradient over the sequence AS IT
+    LIES, split over tp, so the matmul leaves a partial product over tp AND
+    dp, and both sums round to bf16. ddp sums over tp inside the scan's body
+    and over dp after the scan; ZeRO-2, whose scanned cotangent is asked for
+    in ZeRO's shards (models/base.run_layers), sums over dp first, in the
+    body. (a + b) + (c + d) against (a + c) + (b + d), each sum rounded:
+    the same precision, and not the same bits. Every other leaf's partial
+    product is over dp alone, and without Megatron-SP this one's too (the
+    case beside it: equal to the bit, so the whole state within the bound).
+    That leaf's first gradient is held within one step of bf16 at its own
+    scale instead, since Adam's normalisation turns a last bit of a small
+    gradient into 4e-3 on the parameter in two updates, and the third step
+    carries that to every leaf; every other leaf's first gradient is ddp's to
+    the bit, and the loss keeps its bound over the three steps."""
     cfg = tiny_llama()
     m, losses, params = train(cfg, layout(dp_type, **kw), devices4)
-    _, want_losses, want = train(cfg, layout("ddp", **kw), devices4)
+    ddp, want_losses, want = train(cfg, layout("ddp", **kw), devices4)
     assert losses[-1] < losses[0]
     assert max(abs(a - b) for a, b in zip(losses, want_losses)) < 5e-5, (losses, want_losses)
-    worst = max(float(jnp.max(jnp.abs(np.asarray(a) - np.asarray(b))))
-                for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(want)))
-    # (ZeRO-3's table, split over dp on the vocabulary, ends 1.6e-4 from ddp's
-    # with or without the copy: another partitioning of its scatter-add)
-    assert worst < (5e-4 if dp_type == "zero3" else 5e-5), worst
+    if dp_summed_first is None:
+        worst = max(float(jnp.max(jnp.abs(np.asarray(a) - np.asarray(b))))
+                    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(want)))
+        # (ZeRO-3's table, split over dp on the vocabulary, ends 1.6e-4 from ddp's
+        # with or without the copy: another partitioning of its scatter-add)
+        assert worst < (5e-4 if dp_type == "zero3" else 5e-5), worst
+    else:
+        got, ref = first_gradient(m), first_gradient(ddp)
+        reordered = [p for p in got if dp_summed_first in p]
+        assert len(reordered) == cfg.num_layers
+        for p, g in got.items():
+            a, b = (np.asarray(t.astype(jnp.float32)) for t in (g, ref[p]))
+            if p not in reordered:
+                np.testing.assert_array_equal(a, b, err_msg=p)
+                continue
+            assert g.dtype == BF16 and (a != b).any(), p  # (the day they are equal, take the bound above)
+            one_step = 2.0 ** (np.floor(np.log2(np.abs(b).max())) - 7)  # bf16: 8 bits of significand
+            assert np.abs(a - b).max() <= one_step, (p, np.abs(a - b).max(), one_step)
 
     # the state: every leaf float32, in `state_specs`; a copied leaf, the
     # looked-up table of a split state, and no other, leaves `param_specs`
@@ -267,8 +305,11 @@ def test_a_checkpoint_crosses_between_the_split_table_and_the_whole_one(tmp_path
 # the compute copy, 9c3c713, to PR 29, the text was one other; PR 52 stores
 # the looked-up table split over dp under ZeRO-2 whatever the compute dtype,
 # so `tp2dp2_zero2_fp32` is PR 52's step; PR 54 splits the scan pipeline's
-# table and head over pp, so `gpipe_pp2dp2_zero2` is PR 54's; the other five
-# are PR 30's). A PR that
+# table and head over pp, so `gpipe_pp2dp2_zero2` is PR 54's; PR 55 asks for a
+# scanned run's stacked cotangent in ZeRO's layout wherever the state may be
+# split, a copy or none, so `tp2dp2_zero2_fp32` is PR 55's; the other five
+# are PR 30's, the manual TP path's among them: its regions sum a leaf's
+# gradient themselves and `_zero_splits_state` keeps ZeRO off it). A PR that
 # changes the step on purpose prints the new digests with
 # `pytest -k lowers_to -s` and replaces these.
 PARENT_STEP_SHA256 = {
@@ -276,7 +317,8 @@ PARENT_STEP_SHA256 = {
     "dp4_ddp": "70995ecd487ae2884bbf396bf49771cba3a7ac9bbcefc2b5870fa7933dd6a908",
     "tp2dp2_ddp_chunks2": "5b6cd9e54b8928e4dd99a65f9ba070bf576b707a8f9da29745c241ae8be70815",
     "tp4_zero2_dp1": "3c537facf5b745a4add1697b6547b8e81cd6332accea54f6bac0b3945fd14a09",
-    "tp2dp2_zero2_fp32": "d9177b1e44e79bc78a27e6c00e4b2d5672f47102bf219aa3474269cf6df30149",  # PR 52: the table split
+    # PR 52: the table split; PR 55: the scanned gradient in ZeRO's layout
+    "tp2dp2_zero2_fp32": "1c3ae4c9bb5451857c2cc110b427187a9ac9a747e47a01287e13438ccc4c68e9",
     "gpipe_pp2dp2_zero2": "633ee69f9744f8b3d766bc364c9281162bda216a03446958eb3090ef311c6693",  # PR 54: the table over pp
     "tp2dp2_zero2_manual_tp": "e72629bd031daad6af1e4a377f78d497457a3631b97ed8e6ae58b28847749727",
 }
@@ -313,8 +355,9 @@ def test_a_layout_with_nothing_to_copy_lowers_to_the_parents_step(name, devices8
     stored = jax.tree.map(lambda s: s.spec, m.shardings())
     if name == "tp2dp2_zero2_fp32":
         # float32 compute copies nothing, but the looked-up table is stored
-        # split over dp all the same (PR 52: this layout's step is no longer
-        # the parent's, and its digest below is PR 52's)
+        # split over dp all the same (PR 52) and the scanned layers' gradient
+        # is summed into ZeRO's shards (PR 55: this layout's step is no
+        # longer the parent's, and its digest above is PR 55's)
         assert stored["embed"].pop("wte") == m.grad_accum_specs()["embed"]["wte"] != m.param_specs["embed"]["wte"]
         assert stored == {**m.param_specs, "embed": {k: v for k, v in m.param_specs["embed"].items() if k != "wte"}}
     else:
@@ -387,6 +430,13 @@ def reads(jaxpr, tracked, wide, uses):
         if name in VIEWS:
             if 0 in held:
                 tracked[eqn.outvars[0]] = held[0]
+            continue
+        if name == "custom_vjp_call" and len(eqn.invars) == 1 and all(
+                e.primitive.name in VIEWS for e in eqn.params["call_jaxpr"].eqns):
+            # an identity that says where the cotangent lies (`spec.constrain_grad_as`
+            # on a scanned run's stacked leaf): the leaf goes on as it is, read
+            # at no more places and no wider
+            tracked[eqn.outvars[0]] = held[0]
             continue
         if name == "concatenate":  # `jnp.stack` of a run's layers
             tracked[eqn.outvars[0]] = frozenset().union(*held.values())

@@ -88,16 +88,11 @@ def test_lifecycle_events_present(telemetry_run):
     assert t["run_end"][0]["summary"]["iters"] >= 1
 
 
-def test_the_compile_event_says_whether_the_table_stays_split_over_dp(telemetry_run, devices8, tmp_path, capsys):
-    """`table_rows_over_dp`: 1 where the step looks the vocabulary-split table
-    up with ids, rows and cotangents crossing dp (ZeRO-2, tp 2 x dp 2, the
-    four-chip cell's flags), 0 where it does not (the shared run: no
-    `vocab_tp`), and `cli report` says so in a line."""
-    assert "table_rows_over_dp" in T.EVENT_SCHEMAS["compile"][1]
-    assert by_type(telemetry_run[1])["compile"][0]["table_rows_over_dp"] == 0
-    R.run([telemetry_run[3]])
-    assert "the token table stays split over dp" not in capsys.readouterr().out
-    tele = str(tmp_path / "run.jsonl")
+@pytest.fixture(scope="module")
+def zero2_tp2dp2_run(devices8, tmp_path_factory):
+    """Two steps under the four-chip cell's flags (ZeRO-2, tp 2 x dp 2, the
+    vocabulary split, full recomputation) at tiny widths: the telemetry file."""
+    tele = str(tmp_path_factory.mktemp("zero2_tp2dp2") / "run.jsonl")
     argv = [
         "--model_type", "llama", "--set_model_config_manually", "1",
         "--hidden_size", "64", "--num_attention_heads", "4", "--num_layers", "2",
@@ -107,12 +102,49 @@ def test_the_compile_event_says_whether_the_table_stays_split_over_dp(telemetry_
         "--telemetry", tele,
     ]
     train(initialize_galvatron(mode="train_dist", argv=argv))
-    events, errors = T.read_events(tele)
+    return tele
+
+
+def test_the_compile_event_says_whether_the_table_stays_split_over_dp(telemetry_run, zero2_tp2dp2_run, capsys):
+    """`table_rows_over_dp`: 1 where the step looks the vocabulary-split table
+    up with ids, rows and cotangents crossing dp (ZeRO-2, tp 2 x dp 2, the
+    four-chip cell's flags), 0 where it does not (the shared run: no
+    `vocab_tp`), and `cli report` says so in a line."""
+    assert "table_rows_over_dp" in T.EVENT_SCHEMAS["compile"][1]
+    assert by_type(telemetry_run[1])["compile"][0]["table_rows_over_dp"] == 0
+    R.run([telemetry_run[3]])
+    assert "the token table stays split over dp" not in capsys.readouterr().out
+    events, errors = T.read_events(zero2_tp2dp2_run)
     assert errors == []
     assert [e["table_rows_over_dp"] for e in events if e["type"] == "compile"] == [1]
     assert all(np.isfinite(e["loss"]) for e in events if e["type"] == "step")
-    R.run([tele])
+    R.run([zero2_tp2dp2_run])
     assert "the token table stays split over dp" in capsys.readouterr().out
+
+
+def test_the_compile_event_counts_the_scanned_gradients_in_zeros_layout(telemetry_run, zero2_tp2dp2_run, capsys):
+    """`scan_grads_in_zero_layout`: the stacked leaves of the step's scanned
+    runs whose cotangent was asked for in ZeRO's layout (models/base.run_layers):
+    under ZeRO-2 over dp 2 every leaf of the one run of two LLaMA layers (two
+    norm scales, four kernels: each has a dim that halves); 0 under ddp (the
+    shared run, dp 8). Beside it what the compiled step sums over dp inside
+    the scan's backward, in MB (obs/compiled.dp_grad_sums_mb): there wherever
+    the layout has a dp axis and a sink listens, and under ZeRO-2 0.0 at these
+    widths, where no leaf reaches 1 MB and XLA:CPU prints no reduce-scatter
+    (tests/ops/test_tpu_compile.py reads it at the four-chip cell's)."""
+    fields = ("scan_grads_in_zero_layout", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb")
+    assert set(fields) <= set(T.EVENT_SCHEMAS["compile"][1])
+    ddp = by_type(telemetry_run[1])["compile"][0]
+    # (ddp all-reduces its scanned gradients whole; XLA:CPU sums some of them as one operand over 1 MB)
+    assert ddp[fields[0]] == 0 and ddp[fields[1]] >= 0.0 and ddp[fields[2]] == 0.0
+    R.run([telemetry_run[3]])
+    assert "summed into ZeRO's shards" not in capsys.readouterr().out
+    zero2 = by_type(T.read_events(zero2_tp2dp2_run)[0])["compile"][0]
+    assert [zero2[f] for f in fields] == [6, 0.0, 0.0]
+    R.run([zero2_tp2dp2_run])
+    out = capsys.readouterr().out
+    assert "stacked leaves of scanned runs whose gradient is summed into ZeRO's shards: 6" in out
+    assert "weight gradients over dp, MB a chip: 0 all-reduced, 0 reduce-scattered" in out
 
 
 def test_the_compile_event_names_the_axes_the_pipelines_vocabulary_is_split_over(telemetry_run, devices8, tmp_path, capsys):

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from galvatron_tpu.obs.compiled import axis_groups, replica_groups
 from galvatron_tpu.ops import attention as A
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -274,22 +275,6 @@ def test_the_cpu_step_of_that_layout_prints_no_reduce_scatter(devices8):
     assert "reduce-scatter" not in text and " all-to-all(" in text
 
 
-def _replica_groups(line):
-    """The replica groups of an HLO collective, as sets of device positions:
-    `{{0,2},{1,3}}`, or the iota form `[2,2]<=[2,2]T(1,0)` (reshape `arange`
-    to the dims after `<=`, transpose, reshape to groups x members)."""
-    iota = r"\[[\d,]+\]<=\[[\d,]+\](?:T\([\d,]+\))?"
-    text = re.search(r"replica_groups=(\{\{[\d,{}]*\}\}|%s)" % iota, line).group(1)
-    if text.startswith("{"):
-        return {frozenset(int(i) for i in g.split(",")) for g in re.findall(r"\{([\d,]+)\}", text)}
-    groups, dims, perm = re.fullmatch(r"\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?", text).groups()
-    ints = lambda t: [int(i) for i in t.split(",")]  # noqa: E731
-    ids = np.arange(np.prod(ints(dims))).reshape(ints(dims))
-    if perm:
-        ids = ids.transpose(ints(perm))
-    return {frozenset(row.tolist()) for row in ids.reshape(ints(groups))}
-
-
 @pytest.mark.parametrize("sequence_parallel", [False, True], ids=["tp2dp2", "tp2dp2_megatron_sp"])
 def test_zero2_gathers_a_bf16_copy_and_stores_float32_shards_on_v5e(tp2dp2_step, sequence_parallel):
     """ZeRO-2's compute copy in the compiled step (runtime/model_api
@@ -305,17 +290,13 @@ def test_zero2_gathers_a_bf16_copy_and_stores_float32_shards_on_v5e(tp2dp2_step,
 
     model, step = tp2dp2_step[sequence_parallel]
     vax = vocab_axes(model.hp)
-    positions = np.arange(model.mesh.devices.size).reshape(model.mesh.devices.shape)
-    dp_dims = [model.mesh.axis_names.index(a) for a in vax.dp]
-    dp_groups = {frozenset(row.tolist()) for row in
-                 np.moveaxis(positions, dp_dims, range(-len(dp_dims), 0)).reshape(
-                     -1, int(np.prod([positions.shape[d] for d in dp_dims])))}
+    dp_groups = axis_groups(model.mesh, vax.dp)
     assert dp_groups == {frozenset({0, 2}), frozenset({1, 3})}
 
     gathered = {"bf16": [], "f32": []}  # (elements a chip, op_name) of the dp all-gathers
     for line in step.as_text().splitlines():
         out = re.search(r" = \(?(?:(?:bf16|f32)\[[\d,]*\]\S*(?:, )?)+\)? all-gather(?:-start)?\(", line)
-        if not out or _replica_groups(line) != dp_groups:
+        if not out or replica_groups(line) != dp_groups:
             continue
         shapes = re.findall(r"(bf16|f32)\[([\d,]*)\]", out.group(0))
         name = re.search(r'op_name="([^"]*)"', line)
@@ -348,22 +329,57 @@ def test_zero2_gathers_a_bf16_copy_and_stores_float32_shards_on_v5e(tp2dp2_step,
         assert i.is_equivalent_to(o, a.ndim) and i.is_equivalent_to(w, a.ndim), (a.shape, i, o, w)
 
 
+def _cell_model_and_step(workload, devices):
+    """A benchmark cell's train step at its own size, compiled for `devices`
+    from the cell's own files and flags: (model, step)."""
+    from benchmarks import cells
+    from galvatron_tpu.cli.arguments import hp_config_from_args, initialize_galvatron, model_config_from_args
+
+    cell = cells.load_cell(REPO, workload)
+    cells.register_family(cell)
+    args = initialize_galvatron(mode="train_dist", argv=cells.train_argv(cell, 0))
+    _, cfg = model_config_from_args(args)
+    assert cfg.max_seq_len == cell.traffic["seq_length"]
+    return _model_and_compiled_step(cfg, hp_config_from_args(args, cfg.num_layers, cell.chips), devices,
+                                    batch_rows=cell.traffic["global_batch"])
+
+
+def test_the_four_chip_cell_sums_its_scanned_gradients_into_zeros_shards_on_v5e(v5e_2x2):
+    """`qwen7-c4-tp2dp2` at its own size (four layers at Qwen2.5-7B's widths,
+    tp 2 x dp 2, ZeRO-2; about a minute): the one scanned run asks for the
+    cotangent of its nine stacked leaves in ZeRO's layout (two norm scales, q
+    and k/v with a bias each, wo, wi, wo_mlp), and what the `compile` event
+    then reads off the compiled step (cli/train._scan_grad_sums_mb): no
+    weight gradient over 1 MB is all-reduced over the dp pairs inside the
+    backward scan's body, and the layer's five kernels, 233.0 MB a chip in
+    bf16, go through reduce-scatters there (before PR 55: 233.0 all-reduced,
+    0 reduce-scattered, and the step kept half of the sum afterwards)."""
+    from galvatron_tpu.cli.train import _scan_grad_sums_mb
+    from galvatron_tpu.models import base as M
+    from galvatron_tpu.obs import telemetry
+
+    before = sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values())
+    model, step = _cell_model_and_step("qwen7-c4-tp2dp2", v5e_2x2)
+    assert sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values()) - before == 9
+    h, f, heads, kv, d = 3584, 18944, 28, 4, 128
+    kernels = 2 * (h * heads * d + h * 2 * kv * d + heads * d * h + h * 2 * f + f * h) // 2  # bf16, a tp half
+    assert _scan_grad_sums_mb(model, step) == {}  # nobody listens: the step's text is not printed
+    sink = telemetry.install(telemetry.MemorySink())
+    try:
+        assert _scan_grad_sums_mb(model, step) == {
+            "dp_grad_all_reduce_mb": 0.0, "dp_grad_reduce_scatter_mb": kernels / 1e6}
+    finally:
+        telemetry.uninstall(sink)
+    assert round(kernels / 1e6, 1) == 233.0
+
+
 @pytest.fixture(scope="module")
 def pp2tp2_cell_step(v5e_2x2):
     """The pipelined benchmark cell `qwen7-c4-pp2tp2` at its own size (four
     layers at Qwen2.5-7B's widths, 8 x 2048 tokens, pp2 x tp2, GPipe, 4
     microbatches, `--vocab_tp 2`) compiled for the described 2x2 from the
     cell's own files and flags: (model, step). About half a minute."""
-    from benchmarks import cells
-    from galvatron_tpu.cli.arguments import hp_config_from_args, initialize_galvatron, model_config_from_args
-
-    cell = cells.load_cell(REPO, "qwen7-c4-pp2tp2")
-    cells.register_family(cell)
-    args = initialize_galvatron(mode="train_dist", argv=cells.train_argv(cell, 0))
-    _, cfg = model_config_from_args(args)
-    assert cfg.max_seq_len == cell.traffic["seq_length"]
-    return _model_and_compiled_step(cfg, hp_config_from_args(args, cfg.num_layers, cell.chips), v5e_2x2,
-                                    batch_rows=cell.traffic["global_batch"])
+    return _cell_model_and_step("qwen7-c4-pp2tp2", v5e_2x2)
 
 
 def test_the_pipelined_cell_splits_its_vocabulary_over_pp_on_v5e(pp2tp2_cell_step):
