@@ -99,6 +99,15 @@ def _compile_step(lowered, counters: launch.JitCounters):
         return lowered.compile(), counters.cache_hits > hits
 
 
+def shared_counts(cfg) -> dict:
+    """{mamba_layers, shared_readers} of a config whose layers publish and read (models/config.py
+    `shared`): the Mamba-1 layers, and the layers that read an earlier layer's tensor; 0, 0 for any other."""
+    if not hasattr(cfg, "shared"):
+        return {"mamba_layers": 0, "shared_readers": 0}
+    return {"mamba_layers": sum(kind.startswith("mamba1") for kind in cfg.layer_kinds()),
+            "shared_readers": sum(bool(read) for _, read in cfg.shared())}
+
+
 def optimizer_args_from(args) -> OptimizerArgs:
     return OptimizerArgs(
         lr=args.lr,
@@ -628,6 +637,10 @@ def _train(args, started: launch.Launch) -> dict:
                 # staying split over it (`embed_head.vocab_parallel_lookup`'s
                 # second form), else 0
                 table_rows_over_dp=int(forms.took["lookups"]["rows_over_dp"] > 0),
+                # the layers whose token mixer is a Mamba-1 selective scan (models/parts/mamba.py),
+                # and the layers that read a tensor an EARLIER layer published beside the residual
+                # stream (`TransformerConfig.shared`); absent where the model has none
+                **{k: v or None for k, v in shared_counts(cfg).items()},
                 # the mesh axes the scan pipeline's vocabulary layers are
                 # stored and computed split over (`mesh.pipeline_vocab_axes`:
                 # pp, then the vocabulary's tp axes); absent at pp = 1, under
@@ -831,6 +844,7 @@ def _train(args, started: launch.Launch) -> dict:
         )
 
     losses = []
+    last_shared = {}  # the last accepted step's telemetry.SHARED_STEP_FIELDS, for the summary
     loss_iters = []  # iteration of each accepted loss (rollback truncation)
     valid_losses = []  # (iteration, mean valid loss)
     # (iteration, metrics, dispatch_ms, data_wait_ms) dispatched, not yet drained
@@ -873,7 +887,8 @@ def _train(args, started: launch.Launch) -> dict:
             # a routed-experts config's step hands these back beside the loss
             **{k: float(metrics[k])
                for k in (telemetry.EXPERT_STEP_FIELDS + telemetry.SHARE_STEP_FIELDS
-                         + telemetry.LINEAR_STEP_FIELDS + telemetry.SSM_STEP_FIELDS)
+                         + telemetry.LINEAR_STEP_FIELDS + telemetry.SSM_STEP_FIELDS
+                         + telemetry.SHARED_STEP_FIELDS)
                if isinstance(metrics, dict) and k in metrics},
         )
 
@@ -926,6 +941,8 @@ def _train(args, started: launch.Launch) -> dict:
         if verdict == "ok":
             losses.append(loss)
             loss_iters.append(d_it)
+            if isinstance(metrics, dict) and telemetry.SHARED_STEP_FIELDS[0] in metrics:
+                last_shared.update({k: float(metrics[k]) for k in telemetry.SHARED_STEP_FIELDS if k in metrics})
             return d_it, False
         # the jitted step already kept the old params/opt_state
         # (guard_anomalies select); only account and maybe roll back
@@ -1402,6 +1419,8 @@ def _train(args, started: launch.Launch) -> dict:
     prof.resilience_counters = res.as_dict()
     summary = prof.summary()
     summary["losses"] = losses
+    if last_shared:  # a model whose layers publish: its two layer counts and the last step's counters
+        summary.update(shared_counts(cfg), **last_shared)
     summary["resilience"] = res.as_dict()
     if tuner is not None:
         summary["autotune"] = {"plans": tuner.plans, "swaps": tuner.swaps}

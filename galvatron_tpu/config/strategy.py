@@ -346,6 +346,11 @@ def model_layer_kinds(model_cfg) -> Optional[Sequence[str]]:
     a single kind of layer or does not say (T5, Swin, duck-typed configs)."""
     kinds = getattr(model_cfg, "layer_kinds", None)
     kinds = kinds() if callable(kinds) else None
+    shared = getattr(model_cfg, "shared", None)
+    if kinds and callable(shared):
+        # a layer that hands a tensor on to later layers (`TransformerConfig.shared`) is a run of
+        # its own, never scanned: its kind is a plain layer's (one part serves both), its KEY is not
+        kinds = tuple(kind + " -> " + ", ".join(out) if out else kind for kind, (out, _) in zip(kinds, shared()))
     return kinds if kinds and len(set(kinds)) > 1 else None
 
 

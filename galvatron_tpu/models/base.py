@@ -55,14 +55,16 @@ def refuse_unsupported(cfg, hp=None, asker=None, autotune=None) -> None:
 
 
 # ===================================================================== init
-def init_layer_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
+def init_layer_params(rng: jax.Array, cfg: TransformerConfig, index: int = 0) -> Params:
     """One layer's tree: its two norms, its token mixer's leaves (`MIXERS`)
-    and its MLP half's (`MLP_HALVES`)."""
+    and its MLP half's (`MLP_HALVES`). `index`: the layer's PUBLISHED index,
+    for the leaves a part sets from the layer's place in the stack
+    (`LayerPart.place`: differential attention's `lambda_init`; no other)."""
     ks = jax.random.split(rng, 5)
     p: Params = {"ln1": _norm_params(cfg), "ln2": _norm_params(cfg)}
     p.update(MIXERS[cfg.mixer].init(ks, cfg))
     p.update(MLP_HALVES[cfg.mlp_half].init(ks, cfg))
-    return p
+    return MIXERS[cfg.mixer].place(p, cfg, index)
 
 
 def init_model_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
@@ -90,8 +92,8 @@ def init_model_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         embed["norm"] = _norm_params(cfg)
     params: Params = {
         "embed": embed,
-        "layers": [init_layer_params(ks[2 + i], cfg.layer_config(kind))
-                   for i, kind in enumerate(cfg.layer_kinds())],
+        "layers": [init_layer_params(ks[2 + i], cfg.layer_config(kind), index)
+                   for i, (kind, index) in enumerate(zip(cfg.layer_kinds(), cfg.published_indices()))],
     }
     if cfg.mtp_layers:
         # HF's names: enorm, hnorm, eh_proj, the block, shared_head.norm; the
@@ -139,6 +141,8 @@ def layer_forward(
     attn_bias: Optional[jax.Array] = None,
     return_kv: bool = False,
     attn_sharding: Optional[KernelSharding] = None,
+    shared: Optional[Dict[str, jax.Array]] = None,
+    publish: Tuple[str, ...] = (),
 ):
     """One transformer block on (B, S_local, H) activations: x + Mixer(norm
     x), then + MLP(norm x), the two halves `MIXERS[cfg.mixer]`'s and
@@ -158,17 +162,29 @@ def layer_forward(
 
     A config with ``layer_aux`` (a part of it hands back counters) returns
     ``(x, aux)``: the block's output, and its router's auxiliary terms
-    (ops/moe.py) and its mixer's counters in one dict."""
+    (ops/moe.py) and its mixer's counters in one dict.
+
+    A layer's input is the residual stream and, for a few mixers, named
+    tensors EARLIER layers published (`TransformerConfig.shared`): ``shared``
+    is handed to a mixer that reads (`LayerPart.reads`), and a mixer asked to
+    ``publish`` names hands them back, so that the layer returns one value
+    more, ``{name: tensor}``, after the others. Neither is given to any
+    other part, whose call is what it always was."""
     if return_kv:
         refuse_unsupported(cfg, asker="serve")
     if mesh is not None and axes is not None:
         attn_sharding = KernelSharding.for_layer(mesh, axes)
-    kv_out, aux = None, {}
+    kv_out, aux, published = None, {}, {}
     for norm, half in (("ln1", MIXERS[cfg.mixer]), ("ln2", MLP_HALVES[cfg.mlp_half])):
         residual = x
         y = _norm(x, p[norm], cfg) if cfg.pre_norm else x
-        o, kv, said = half.forward(p, y, positions, cfg, mesh=mesh, axes=axes, attn_bias=attn_bias,
-                                   attn_sharding=attn_sharding, return_kv=return_kv)
+        how = {"shared": shared} if half.reads else {}
+        if publish and half.publishes:
+            how["publish"] = publish
+        o, kv, said, *handed = half.forward(p, y, positions, cfg, mesh=mesh, axes=axes, attn_bias=attn_bias,
+                                            attn_sharding=attn_sharding, return_kv=return_kv, **how)
+        for names in handed:
+            published.update(names)
         kv_out = kv_out or kv
         aux = {**(said or {}), **aux}  # the MLP half's terms before the mixer's
         if mesh is not None and axes is not None:
@@ -180,9 +196,10 @@ def layer_forward(
             x = _norm(x, p[norm], cfg)
     if return_kv:
         return x, kv_out
-    if cfg.layer_aux:
-        return x, aux
-    return x
+    out = (x, aux) if cfg.layer_aux else (x,)
+    if publish:
+        out += (published,)
+    return out if len(out) > 1 else x
 
 
 def decode_layer_forward(
@@ -401,7 +418,18 @@ def run_layers(
     A config with ``layer_aux`` returns ``(x, auxs)``, the layers' auxiliary
     terms (the routers', the linear mixers' counters) a layer or a scanned
     run, for `_fold_aux`. Any other config carries nothing and traces what
-    it did."""
+    it did.
+
+    **What layers publish** (`TransformerConfig.shared`: a Mamba-1 layer's
+    memory, a full differential layer's keys and values) is carried beside
+    ``x`` in a dict that is empty for every config none of whose mixers
+    reads, which therefore traces the step it did. A layer that publishes a
+    name a later layer reads is a run of its own (`config/strategy.
+    model_layer_kinds` keys it apart from a plain layer of its kind), never scanned (its
+    tensors are outputs of ONE layer, and of its `jax.checkpoint` where it is
+    rematerialised: saved, not recomputed by each reader); a scanned run of
+    readers closes over the dict, a constant of the scan whose cotangent the
+    scan sums over its layers."""
     use_hp = hp is not None and mesh is not None
     refuse_unsupported(cfg, hp)  # GLS018, for whoever comes here past construct_hybrid_parallel_model
     layers = params["layers"]
@@ -411,16 +439,22 @@ def run_layers(
     auxs: List[Dict[str, jax.Array]] = []  # routed: a layer's, or a scanned run's stacked
 
     kinds = cfg.layer_kinds()
+    shares = cfg.shared()  # a layer: (the names it hands on, the names it reads)
+    shared: Dict[str, jax.Array] = {}  # the latest of each name published so far
     if use_hp:
         runs = layer_runs(hp, model_layer_kinds(cfg))
     else:
         # no strategy info: one homogeneous run a kind of layer
-        starts = [i for i in range(len(layers)) if i == 0 or kinds[i] != kinds[i - 1]]
+        keys = model_layer_kinds(cfg) or kinds  # (a layer that publishes: a run of its own)
+        starts = [i for i in range(len(layers)) if i == 0 or keys[i] != keys[i - 1]]
         runs = [LayerRun(start=a, stop=b, strategy=LayerStrategy())
                 for a, b in zip(starts, starts[1:] + [len(layers)])]
 
     def scanned(run):
-        return scan and run.length >= 2
+        return scan and run.length >= 2  # (a layer that hands a tensor on is a run of ONE: `model_layer_kinds`)
+
+    def read_by(i):  # the call's share of `shared`, for a layer that reads
+        return {"shared": {name: shared[name] for name in shares[i][1]}} if shares[i][1] else {}
 
     layers = _gated_grads_as_stored(layers, runs, scanned, cfg, mesh if use_hp else None)
 
@@ -443,11 +477,21 @@ def run_layers(
             # the per-layer serialized policy decides (checkpoint=1 layers
             # default to "full"); the global --remat_policy flag was folded
             # in at construction (config/strategy precedence rule)
+            publish = shares[i][0]
+            if publish:  # static, so bound before the layer is wrapped for recomputation
+                fwd = partial(fwd, publish=publish)
             if use_hp:
                 pol = hp.layers[i].effective_remat_policy
                 if pol != "none":
                     fwd = _remat(fwd, pol)
-            x = fwd(lp, x, positions)
+            x = fwd(lp, x, positions, **read_by(i))
+            if publish:
+                *x, handed = x
+                x = x[0] if len(x) == 1 else tuple(x)
+                shared.update(handed)
+                if cfg.layer_aux:  # the step's counter: what outlives its layer, in MiB
+                    auxs.append({"published_mib": jnp.float32(
+                        sum(t.size * t.dtype.itemsize for t in handed.values()) / 2 ** 20)})
             if lcfg.layer_aux:
                 x, aux = x
                 auxs.append(aux)
@@ -496,10 +540,12 @@ def run_layers(
             if run_pol != "none":
                 body = _remat(body, run_pol)
 
+        reads = read_by(run.start)  # a run is of one kind: every layer of it reads the same
+
         def step(carry, lp, _body=body, _axes=axes):
             if use_hp:
                 carry = S.constrain(carry, mesh, S.act_spec(_axes))
-            out = _body(lp, carry, positions)
+            out = _body(lp, carry, positions, **reads)
             return out if lcfg.layer_aux else (out, None)
 
         x, run_aux = jax.lax.scan(step, x, stacked)
@@ -538,6 +584,8 @@ def _fold_aux(auxs: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
         "bias_abs_max": lambda n: total(n, jnp.max),
         "state_abs_max": lambda n: total(n, jnp.max),
         "ssm_state_abs_max": lambda n: total(n, jnp.max),
+        "selscan_state_abs_max": lambda n: total(n, jnp.max),
+        "published_mib": lambda n: total(n, jnp.sum),
         "rows_held": lambda n: total(n, jnp.sum),
         "window_fallbacks": lambda n: total(n, jnp.sum),
         "counts": lambda n: jnp.concatenate([jnp.atleast_2d(a[n]) for a in auxs if n in a]),
@@ -649,7 +697,8 @@ ROUTER_COUNTS = "router_counts"  # (routed blocks, E): the step's; no metric
 # weighted as the loss is)
 PART_FOLDS = {EXPERT_LOAD: jnp.maximum, "router_bias_abs_max": jnp.maximum,
               ROUTER_COUNTS: jnp.add, "expert_rows_held": jnp.add, "expert_window_fallbacks": jnp.add,
-              "linear_state_abs_max": jnp.maximum, "ssm_state_abs_max": jnp.maximum}
+              "linear_state_abs_max": jnp.maximum, "ssm_state_abs_max": jnp.maximum,
+              "selscan_state_abs_max": jnp.maximum, "published_mib": jnp.add}
 
 
 def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
@@ -666,7 +715,7 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
     returns `(loss, parts)`: the terms, the worst block's expert load and
     the other counters of the `step` event (`telemetry.EXPERT_STEP_FIELDS`,
     `LINEAR_STEP_FIELDS` for linear-attention layers, `SSM_STEP_FIELDS` for
-    state-space layers),
+    state-space layers, `SHARED_STEP_FIELDS` for Mamba-1 layers and what layers publish),
     and for a router with a bias the blocks' assignment counts
     (`ROUTER_COUNTS`), which the train step moves the bias by."""
     logits, hidden, auxs = _forward(
@@ -701,6 +750,9 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
         parts["linear_state_abs_max"] = aux["state_abs_max"]
     if "ssm_state_abs_max" in aux:  # the state-space mixers' counter (telemetry.SSM_STEP_FIELDS)
         parts["ssm_state_abs_max"] = aux["ssm_state_abs_max"]
+    for name in ("selscan_state_abs_max", "published_mib"):  # telemetry.SHARED_STEP_FIELDS
+        if name in aux:
+            parts[name] = aux[name]
     if "load_max_over_mean" in aux:  # a router (linear layers over dense MLPs have none)
         parts[EXPERT_LOAD] = aux["load_max_over_mean"]
     if "rows_held" in aux:

@@ -3,7 +3,8 @@
 One config covers the reference's model zoo: GPT (learned pos, pre-LN, gelu),
 LLaMA (rope, rmsnorm, swiglu, GQA), BERT/ViT (bidirectional, post-LN), T5
 (relative bias, enc-dec glue in models/t5.py), and the sparse-expert,
-latent-attention, linear-attention, state-space and short-convolution families. A layer is two
+latent-attention, linear-attention, state-space, short-convolution, window-attention and
+selective-scan / shared-memory (SambaY) families. A layer is two
 entries of the tables in `models/parts`, a token mixer and an MLP half:
 `mixers()` and `mlp_halves()` name them a layer, and what an entry asks of the
 config (`validate`) and hands back (`counters`) is the entry's to say."""
@@ -18,7 +19,8 @@ import jax.numpy as jnp
 
 
 # HF's words in `layer_types` -> the `MIXERS` key
-_MIXER_ALIASES = {"mamba": "ssm", "full_attention": "attention", "sliding_attention": "window"}
+_MIXER_ALIASES = {"mamba": "ssm", "full_attention": "attention", "sliding_attention": "window",
+                  "cross_attention": "cross"}
 
 
 @dataclass
@@ -154,6 +156,22 @@ class TransformerConfig:
     # "yarn" (`ops/rope.YARN_KEYS`); any other `rope_type` is refused by name
     rope_scaling: Optional[Mapping[str, Any]] = None
     attn_head_gate: bool = False  # attn x sigmoid(y Wg) a HEAD, Wg (hidden, heads)
+    # --- what Phi-4-mini-flash's published config adds (phi4flash, SambaY): Mamba-1
+    # selective-scan layers (`models/parts/mamba.py`, the mixer "mamba1"; ops/selective_scan.py),
+    # DIFFERENTIAL softmax attention, and a cross-decoder whose layers have no keys, values or
+    # scan of their own: gated memory units (the mixer "gmu") read ONE Mamba-1 layer's scan
+    # output and cross layers (the mixer "cross") ONE full-attention layer's keys and values ---
+    mamba_d_state: int = 0  # N: a channel's state
+    mamba_d_conv: int = 0  # taps of the causal convolution on x, with a bias
+    mamba_expand: int = 0  # d_inner = this x hidden_size channels
+    mamba_dt_rank: int = 0  # R: dt is projected down to it and up again
+    # attention as a difference of two softmax maps (arXiv:2410.05258): consecutive heads
+    # (2j, 2j + 1) pair up, q and k a map each and v the pair's two heads side by side;
+    # `lambda` from four learned vectors a layer and a constant of the LAYER'S PUBLISHED INDEX
+    diff_attention: bool = False
+    # the PUBLISHED index of each layer run, where a cut in depth is no prefix of the stack:
+    # layer i of the program is entry `layer_indices[i]` of `layer_types` (None: 0, 1, 2, ...)
+    layer_indices: Optional[List[int]] = None
     # which `MIXERS` entry ONE layer runs. `layer_config(kind)` sets it; a
     # model's own config leaves it and states the pattern above
     mixer: str = "attention"
@@ -182,10 +200,19 @@ class TransformerConfig:
                     "the %d layers (or more: the first so many are run), and no "
                     "full_attention_interval beside it; got %r"
                     % (", ".join('"%s"' % n for n in named), self.num_layers, self.layer_types))
+        if self.layer_indices is not None:
+            self.layer_indices = list(self.layer_indices)
+            known = len(self.layer_types or ())
+            if (len(self.layer_indices) != self.num_layers or sorted(set(self.layer_indices)) != self.layer_indices
+                    or not 0 <= self.layer_indices[0] <= self.layer_indices[-1] < known):
+                raise ValueError(
+                    "layer_indices names the published index, an entry of layer_types' %d, of each of the %d "
+                    "layers run, in rising order; got %r" % (known, self.num_layers, self.layer_indices))
         for part in self.parts():  # each part's own clause (latent attention's may set head_dim)
             part.validate(self)
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.num_heads
+        self.shared()  # a layer that reads what no earlier layer publishes is refused by name
         if self.input_type == "patches":
             n_patches = (self.image_size // self.patch_size) ** 2
             self.max_seq_len = n_patches + (1 if self.use_cls_token else 0)
@@ -217,12 +244,46 @@ class TransformerConfig:
         and is linear elsewhere), or one mixer for all (`mixer`). Nothing
         else reads the two statements."""
         if self.layer_types is not None:
-            return tuple(_MIXER_ALIASES.get(t, t) for t in self.layer_types[:self.num_layers])
+            return tuple(_MIXER_ALIASES.get(self.layer_types[i], self.layer_types[i])
+                         for i in self.published_indices())
         every = self.full_attention_interval
         if every:
             return tuple("attention" if (i + 1) % every == 0 else "linear"
                          for i in range(self.num_layers))
         return (self.mixer,) * self.num_layers
+
+    def published_indices(self) -> Tuple[int, ...]:
+        """The published index of each layer run: `layer_indices`, else 0, 1, 2, ..."""
+        return tuple(self.layer_indices if self.layer_indices is not None else range(self.num_layers))
+
+    def shared(self) -> Tuple[Tuple[Tuple[str, ...], Tuple[str, ...]], ...]:
+        """What each layer (hands on, reads) of the named tensors layers SHARE
+        beside the residual stream (`LayerPart.publishes`, `.reads`): a layer
+        hands on the names its mixer publishes that a LATER layer reads before
+        another publishes them anew, so a reader gets the latest of each name.
+        Empty pairs for every config none of whose mixers reads (all but
+        Phi-4-mini-flash's family), which the stack then runs as it always did. A stated pattern (`layer_types`) in
+        which a layer reads what no earlier layer publishes is refused."""
+        from galvatron_tpu.models.parts import MIXERS
+
+        mixers = self.mixers()
+        reads = [MIXERS[m].reads for m in mixers]
+        out, have = [], set()
+        for i, m in enumerate(mixers):
+            missing = [n for n in reads[i] if n not in have]
+            if missing and self.layer_types is not None:  # (one layer's config states no pattern)
+                raise ValueError(
+                    "layer %d (published %d, mixer %r) reads %s, which no earlier layer publishes: among %r"
+                    % (i, self.published_indices()[i], m, " and ".join('"%s"' % n for n in missing), mixers[:i]))
+            def read_next(name):  # before a later layer publishes the name anew
+                for later in range(i + 1, len(mixers)):
+                    if name in reads[later] or name in MIXERS[mixers[later]].publishes:
+                        return name in reads[later]
+                return False
+
+            out.append((tuple(n for n in MIXERS[m].publishes if read_next(n)), reads[i]))
+            have.update(MIXERS[m].publishes)
+        return tuple(out)
 
     def mlp_halves(self) -> Tuple[str, ...]:
         """The `MLP_HALVES` key of each layer: "routed" but for the leading
@@ -246,7 +307,10 @@ class TransformerConfig:
         """The kind of each layer, what `config/strategy.layer_runs` splits
         runs on beside the layout. A kind names the layer's two halves: its
         MLP half, "dense" or "routed", after its token mixer where that is
-        not softmax attention ("linear.routed", "ssm.dense", "kda.routed", "conv.dense")."""
+        not softmax attention ("linear.routed", "ssm.dense", "kda.routed", "conv.dense"). A
+        layer that publishes (`shared`) is of the kind of one that does not: one
+        part serves both, an output nobody reads is dead code, and what sets a
+        publishing layer apart, that it is never scanned, is `run_layers`' to see."""
         return tuple(h if m == "attention" else m + "." + h
                      for m, h in zip(self.mixers(), self.mlp_halves()))
 
@@ -265,7 +329,7 @@ class TransformerConfig:
                 ffn_hidden=self.dense_ffn_hidden or self.ffn_hidden)
         if self.mixers() != (self.mixer,) * self.num_layers:
             cfg = dataclasses.replace(cfg, mixer=mixer or "attention", full_attention_interval=0,
-                                      layer_types=None)
+                                      layer_types=None, layer_indices=None)
         if mixer == "window":  # the window layers' own heads and rope as the fields every part reads
             cfg = dataclasses.replace(
                 cfg, mixer="window", rope_scaling=None,

@@ -13,13 +13,16 @@ from typing import Any, Iterator, Optional, Tuple
 
 from galvatron_tpu.models.parts.attention import ATTENTION
 from galvatron_tpu.models.parts.conv import CONV
+from galvatron_tpu.models.parts.cross import CROSS
 from galvatron_tpu.models.parts.kda import KDA
 from galvatron_tpu.models.parts.linear import LINEAR
+from galvatron_tpu.models.parts.mamba import GMU, MAMBA
 from galvatron_tpu.models.parts.mlp import DENSE, ROUTED
 from galvatron_tpu.models.parts.ssm import SSM
 from galvatron_tpu.models.parts.window import WINDOW
 
-MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA, "conv": CONV, "window": WINDOW}
+MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA, "conv": CONV, "window": WINDOW,
+          "mamba1": MAMBA, "gmu": GMU, "cross": CROSS}
 MLP_HALVES = {"dense": DENSE, "routed": ROUTED}
 
 # how an asker's sentence starts, and what joins the parts' statements in it

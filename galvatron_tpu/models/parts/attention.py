@@ -4,6 +4,7 @@ attention (MLA) with it: the two share the one attention call."""
 from __future__ import annotations
 
 import contextlib
+import math
 from typing import Optional
 
 import jax
@@ -12,7 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.models.parts.common import (LayerPart, Params, _dense, _dense_init, _norm, _norm_scale,
-                                               _proj_std)
+                                               _proj_std, no_form)
 from galvatron_tpu.obs import tracing
 from galvatron_tpu.ops.attention import KernelSharding, core_attention, window_takes_kernels
 from galvatron_tpu.ops.norms import rms_norm
@@ -23,6 +24,14 @@ from galvatron_tpu.parallel.mesh import LayerAxes
 
 def _validate(cfg: TransformerConfig) -> None:
     checked_scaling(cfg.rope_scaling)  # a `rope_type` with no form is refused by name
+    if cfg.diff_attention and (cfg.latent_attention or cfg.attn_output_gate or cfg.attn_head_gate or cfg.qk_norm
+                               or cfg.num_heads % 2 or cfg.num_kv_heads % 2 or not cfg.causal
+                               or cfg.position_type == "rope"):
+        raise ValueError("diff_attention (a difference of two softmax maps a PAIR of heads) wants an even number "
+                         "of query heads and of key heads, causal attention without rope (the one form written: "
+                         "SambaY's has no positions), and stands alone: not beside latent attention, a gate or a "
+                         "QK-norm; got %d heads on %d, position_type %r"
+                         % (cfg.num_heads, cfg.num_kv_heads, cfg.position_type))
     if cfg.attn_head_gate and (cfg.attn_output_gate or cfg.latent_attention):
         raise ValueError("attn_head_gate (a gate a head, Wg (hidden, heads)) stands alone: not beside "
                          "attn_output_gate (a gate a head AND dim, projected with q) nor latent attention")
@@ -58,7 +67,23 @@ _LATENT = dict(
 _HEAD_GATE = dict(serve="no per-head output gate on a decoded token's attention")
 
 
+# a layer's `lambda_init` is a constant of its PUBLISHED index, which a pipeline's stacked
+# stages, the decode engine and the cost models do not carry; the pairs of heads have not
+# been split over tensor-parallel ranks
+_DIFF = no_form(
+    "differential attention layers",
+    serve="no form of differential attention (two softmax maps a pair of heads, a constant of the layer's index)",
+    autotune="a differential attention layer as softmax attention",
+    pp="carry no tensor a layer publishes for later layers across stages (a full layer's keys and values), nor "
+       "a constant of a layer's published index",
+    tp="differential attention layers (the pairs of heads have not been split over tensor-parallel ranks)",
+    quant="a layer whose constant no gradient moves",
+)
+
+
 def _unsupported(cfg: TransformerConfig):
+    if cfg.diff_attention:
+        return _DIFF
     said = _LATENT if cfg.latent_attention or cfg.mtp_layers else {}
     return {**_HEAD_GATE, **said} if cfg.attn_head_gate else said
 
@@ -115,7 +140,106 @@ def _init_attention(ks, cfg: TransformerConfig) -> Params:
         p["k_norm"] = {"scale": jnp.ones((nkv * hd,), cfg.param_dtype)}
     if cfg.attn_head_gate:
         p["wg"] = {"kernel": _dense_init(jax.random.fold_in(ks[0], 1), (h, nh), cfg.init_std, cfg.param_dtype)}
+    if cfg.diff_attention:
+        p["diff"] = init_diff(jax.random.fold_in(ks[0], 2), cfg)
     return p
+
+
+# =============================================================== differential
+LAMBDA_INIT = "lambda_init"  # a float32 scalar a layer that no gradient moves (runtime/optimizer.NO_GRADIENT_KEYS)
+
+
+def lambda_init(index: int) -> float:
+    """Differential attention's constant of the layer's PUBLISHED 0-based index (arXiv:2410.05258, 3)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * index)
+
+
+def init_diff(key, cfg: TransformerConfig) -> Params:
+    """Differential attention's own leaves, under `diff`: the four `lambda`
+    vectors (head_dim,) ~ N(0, 0.1^2), the sub-norm's weight over a pair's 2 x
+    head_dim dims (1), and `lambda_init`, which `place_diff` sets."""
+    ks = jax.random.split(key, 4)
+    p = {name: 0.1 * jax.random.normal(k, (cfg.head_dim,), jnp.float32)
+         for name, k in zip(("lq1", "lk1", "lq2", "lk2"), ks)}
+    p["subln"] = {"scale": jnp.ones((2 * cfg.head_dim,), cfg.param_dtype)}
+    p[LAMBDA_INIT] = jnp.zeros((), jnp.float32)
+    return p
+
+
+def place_diff(p: Params, cfg: TransformerConfig, index: int) -> Params:
+    """`LayerPart.place`: the layer's `lambda_init` from its published index."""
+    if "diff" not in p:
+        return p
+    return {**p, "diff": {**p["diff"], LAMBDA_INIT: jnp.asarray(lambda_init(index), jnp.float32)}}
+
+
+def diff_specs(axes: LayerAxes) -> Params:
+    r1 = S.replicated_1d_spec(axes)
+    return {"lq1": r1, "lk1": r1, "lq2": r1, "lk2": r1, "subln": {"scale": r1}, LAMBDA_INIT: P()}
+
+
+def diff_arranged(q: jax.Array, k: jax.Array, v: jax.Array):
+    """The heads handed to ONE attention call at twice the head's width. Query
+    heads (2j, 2j + 1) are a pair (q1, q2), key heads (2m, 2m + 1) a pair (k1,
+    k2) that serves g = nh / nkv query pairs, the pair's value [v_2m | v_2m+1].
+    Map s of pair j is `softmax(q_s k_s^T) v`: q_s and k_s padded with zeros to
+    the value's width (zeros add nothing to a score), the value repeated for
+    both maps, the query heads ordered (m, s, j') so that a key head's g query
+    heads lie side by side as GQA wants them. At head_dim 64 the call runs at
+    128, a whole lane tile. (B, S, nh, hd), (B, S, nkv, hd) x 2 -> (B, S, nh, 2
+    hd), (B, S, nkv, 2 hd) x 2."""
+    b, s, nh, hd = q.shape
+    nkv = k.shape[2]
+    g = nh // nkv
+    pad = ((0, 0),) * 3 + ((0, hd),)
+    qa = q.reshape(b, s, nkv // 2, g, 2, hd).transpose(0, 1, 2, 4, 3, 5).reshape(b, s, nh, hd)
+    va = jnp.broadcast_to(v.reshape(b, s, nkv // 2, 1, 2 * hd), (b, s, nkv // 2, 2, 2 * hd))
+    return jnp.pad(qa, pad), jnp.pad(k, pad), va.reshape(b, s, nkv, 2 * hd)
+
+
+def diff_combined(p: Params, attn: jax.Array, nkv: int, cfg: TransformerConfig) -> jax.Array:
+    """(B, S, nh, 2 hd) as `diff_arranged` ordered the heads -> (B, S, nh x hd):
+    `RMSNorm(a_1 - lambda a_2; w) (1 - lambda_init)` a pair, float32, with
+    `lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`."""
+    b, s, nh, wide = attn.shape
+    init = jax.lax.stop_gradient(p[LAMBDA_INIT].astype(jnp.float32))
+    lam = jnp.exp(jnp.sum(p["lq1"] * p["lk1"])) - jnp.exp(jnp.sum(p["lq2"] * p["lk2"])) + init
+    maps = attn.reshape(b, s, nkv // 2, 2, nh // nkv, wide).astype(jnp.float32)
+    o = rms_norm(maps[:, :, :, 0] - lam * maps[:, :, :, 1], p["subln"]["scale"], cfg.layernorm_eps) * (1.0 - init)
+    return o.astype(attn.dtype).reshape(b, s, nh * wide // 2)
+
+
+def diff_attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, *,
+                         attn_bias=None, attn_sharding=None, scope: str = tracing.ATTN_PROJ,
+                         window: Optional[int] = None, shared=None, publish=(), **_):
+    """Differential attention on normed activations (B, S, H) (arXiv:2410.05258;
+    `diff_arranged`, `diff_combined`): a full layer, a window layer (`window`)
+    or, with another layer's keys and values (`shared`: {"k", "v"}, as projected),
+    a cross layer, which projects q alone. -> out, None, None and, where a later
+    layer reads them (`publish`), this layer's k and v. The projections under `scope`, the pairing, lambda, the
+    subtraction, the sub-norm and its factor under `gt.attn.diff`, the call where
+    every attention call is (a window's under `gt.attn.band`). One chip and dp:
+    every other layout is refused (GLS018)."""
+    dtype = cfg.compute_dtype
+    with jax.named_scope(scope):
+        if shared is None:
+            q, k, v = qkv_projection(p, y, cfg, dtype)
+        else:
+            q = jnp.einsum("bsh,hnd->bsnd", y, p["wq"]["kernel"].astype(dtype))
+            if "bias" in p["wq"]:
+                q = q + p["wq"]["bias"].astype(dtype)
+            k, v = shared["k"], shared["v"]
+    with jax.named_scope(tracing.ATTN_DIFF):
+        qa, ka, va = diff_arranged(q, k, v)
+    with jax.named_scope(tracing.ATTN_WINDOW_BAND) if window is not None else contextlib.nullcontext():
+        attn = core_attention(qa, ka, va, causal=cfg.causal, bias=attn_bias, impl=cfg.attn_impl,
+                              bias_type="key_padding", sharding=attn_sharding, window=window,
+                              sm_scale=cfg.attention_multiplier or cfg.head_dim ** -0.5)
+    with jax.named_scope(tracing.ATTN_DIFF):
+        attn = diff_combined(p["diff"], attn, k.shape[2], cfg)
+    with jax.named_scope(scope):
+        o = _dense(attn, p["wo"], dtype)
+    return (o, None, None, {"k": k, "v": v}) if publish else (o, None, None)
 
 
 @jax.custom_vjp
@@ -215,7 +339,7 @@ def latent_qkv_projection(p: Params, y: jax.Array, positions: jax.Array,
 
 def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, *,
                     mesh, axes, attn_bias, attn_sharding, return_kv: bool,
-                    scope: Optional[str] = None, window: Optional[int] = None):
+                    scope: Optional[str] = None, window: Optional[int] = None, publish=()):
     """Softmax attention on normed activations (B, S_local, H) -> the
     output projection's result, the post-rope (k, v) where asked, and no
     counters. Seq-sharded activations (megatron-sp / ulysses) are re-gathered
@@ -228,6 +352,9 @@ def attention_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transfor
     PROJECTED: q's rope, where it is the half-split turn of whole heads at the
     plain frequencies, and the head's gate are the kernels' (`q_rope`,
     `head_gate`) and make no pass of their own."""
+    if cfg.diff_attention:  # the differential form, its own function: (`publish`: its k and v, handed on)
+        return diff_attention_mixer(p, y, positions, cfg, attn_bias=attn_bias, attn_sharding=attn_sharding,
+                                    scope=scope or tracing.ATTN_PROJ, window=window, publish=publish)
     dtype = cfg.compute_dtype
     if cfg.position_type == "rope" and mesh is not None and axes is not None:
         # Pin positions to THIS layer's sharding so each layer derives its
@@ -395,9 +522,12 @@ def _attention_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
         sp["k_norm"] = {"scale": r1}
     if cfg.attn_head_gate:
         sp["wg"] = {"kernel": P(z3, tp)}
+    if cfg.diff_attention:
+        sp["diff"] = diff_specs(axes)
     return sp
 
 
+# (a full layer's k and v are handed on in the differential form alone: `cross` reads no other)
 ATTENTION = LayerPart(_init_attention, attention_mixer, _attention_specs,
-                      (tracing.ATTN_PROJ, tracing.ATTN_LATENT), validate=_validate,
-                      unsupported=_unsupported, decode=attention_decode)
+                      (tracing.ATTN_PROJ, tracing.ATTN_LATENT, tracing.ATTN_DIFF), validate=_validate,
+                      unsupported=_unsupported, decode=attention_decode, publishes=("k", "v"), place=place_diff)

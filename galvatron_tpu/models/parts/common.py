@@ -43,6 +43,15 @@ class LayerPart:
     # another tiling than the train state stores them in; the stack reads them
     # through `parts/mlp.grad_as_stored` where that pays (models/base.run_layers)
     gated_kernels: Callable = lambda cfg: ()
+    # the named tensors the part can hand on to later layers beside the residual stream, and
+    # those it reads of earlier layers' (`TransformerConfig.shared`): a part that publishes
+    # takes `publish=` (the names a later layer reads; absent where none does) and then hands
+    # back a fourth value, {name: tensor}; a part that reads takes `shared=` {name: tensor}
+    publishes: Tuple[str, ...] = ()
+    reads: Tuple[str, ...] = ()
+    # (layer tree, cfg, the layer's PUBLISHED index) -> the tree with the leaves set that depend
+    # on the layer's place in the published stack (`init_layer_params`)
+    place: Callable = lambda p, cfg, index: p
 
 
 def no_form(name: str, **says: str) -> Mapping[str, str]:

@@ -44,5 +44,7 @@ def window_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: Transformer
     return attention_mixer(p, y, positions, cfg, scope=tracing.ATTN_WINDOW, window=cfg.sliding_window, **how)
 
 
-WINDOW = LayerPart(ATTENTION.init, window_mixer, ATTENTION.specs, (tracing.ATTN_WINDOW, tracing.ATTN_WINDOW_BAND),
-                   validate=_validate, unsupported=lambda cfg: UNSUPPORTED)
+# (a window layer publishes nothing: a cross layer reads a FULL layer's keys and values)
+WINDOW = LayerPart(ATTENTION.init, window_mixer, ATTENTION.specs,
+                   (tracing.ATTN_WINDOW, tracing.ATTN_WINDOW_BAND, tracing.ATTN_DIFF),
+                   validate=_validate, unsupported=lambda cfg: UNSUPPORTED, place=ATTENTION.place)

@@ -64,14 +64,19 @@ def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
 # of `models/parts.MIXERS` (`MIXER_FWD_FLOPS` below; this module imports no jax).
 def attention_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, num_kv_heads: int,
                                 seq_len: int, causal: bool = True, gated: bool = False,
-                                latent: Optional[Mapping[str, int]] = None, head_gate: bool = False):
+                                latent: Optional[Mapping[str, int]] = None, head_gate: bool = False,
+                                diff: bool = False):
     """Softmax attention: q, fused kv (GQA-scaled) and out projections (q
     twice as wide beside an output gate, or a (hidden, heads) gate a head
     beside it; latent attention's five by their
     shapes, or four where q has no low rank), and scores (q k^T) + weighted
     sum (p v), each 2 S q_dim, half of it under a causal mask; latent
     attention's scores at its q/k width (nope + rope) and its sum at v's,
-    whatever width the one attention call pads them to."""
+    whatever width the one attention call pads them to. `diff`: differential
+    attention as its mathematics needs it, two score maps of head_dim a pair
+    of heads (as many as ordinary attention's) and two `p v` products at the
+    pair's 2 x head_dim (twice ordinary attention's): 1.5 x the ordinary core,
+    whatever heads an implementation pads."""
     q_dim = num_heads * head_dim
     if latent:
         ql, kvl = latent["q_lora_rank"], latent["kv_lora_rank"]
@@ -84,11 +89,11 @@ def attention_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, n
         return proj, 2.0 * seq_len * num_heads * ((nope + rope) + vd) * (0.5 if causal else 1.0)
     proj = (2.0 * hidden * q_dim * (2 if gated else 1) + (2.0 * hidden * num_heads if head_gate else 0.0)
             + 2.0 * hidden * (2 * num_kv_heads * head_dim) + 2.0 * q_dim * hidden)
-    return proj, 2.0 * (2.0 * seq_len * q_dim) * (0.5 if causal else 1.0)
+    return proj, (3.0 if diff else 2.0) * (2.0 * seq_len * q_dim) * (0.5 if causal else 1.0)
 
 
 def window_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, num_kv_heads: int,
-                             window: int, head_gate: bool, seq_len: int):
+                             window: int, head_gate: bool, seq_len: int, diff: bool = False):
     """Softmax attention over a window: the attention row's projections at
     the window layer's heads, and scores + weighted sum over the keys a query
     SEES, the exact band: query i sees min(i + 1, window) keys, a mean of
@@ -97,7 +102,7 @@ def window_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, num_
                                           num_kv_heads=num_kv_heads, seq_len=seq_len, head_gate=head_gate)
     w = min(window, seq_len)
     keys = (w * seq_len - w * (w - 1) / 2.0) / seq_len
-    return proj, 2.0 * 2.0 * keys * num_heads * head_dim
+    return proj, (3.0 if diff else 2.0) * 2.0 * keys * num_heads * head_dim
 
 
 def linear_fwd_flops_a_token(*, hidden: int, num_key_heads: int, num_value_heads: int,
@@ -148,6 +153,33 @@ def conv_fwd_flops_a_token(*, hidden: int):
     return 2.0 * hidden * (3 * hidden) + 2.0 * hidden * hidden, 0.0
 
 
+def mamba1_fwd_flops_a_token(*, hidden: int, expand: int, d_state: int, dt_rank: int):
+    """A Mamba-1 mixer: hidden -> [x | z] (2 x inner, inner = expand x
+    hidden), inner -> [dt_r | B | C] (dt_rank + 2 x d_state), dt_rank -> inner,
+    inner -> hidden; and the selective scan as the RECURRENCE needs it, a
+    multiply-add into the state and one out of it a (channel, state) a token
+    (`4 inner d_state`), whatever chunk an implementation cuts the sequence
+    into. The convolution's taps, the decay and the D skip are no matmul."""
+    inner = expand * hidden
+    proj = 2.0 * hidden * (2 * inner) + 2.0 * inner * (dt_rank + 2 * d_state) + 2.0 * dt_rank * inner \
+        + 2.0 * inner * hidden
+    return proj, 4.0 * inner * d_state
+
+
+def gmu_fwd_flops_a_token(*, hidden: int, expand: int):
+    """A gated memory unit: hidden -> inner and inner -> hidden; the gate on
+    another layer's memory is no matmul, so the core counts nothing."""
+    inner = expand * hidden
+    return 2.0 * hidden * inner + 2.0 * inner * hidden, 0.0
+
+
+def cross_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, seq_len: int):
+    """A cross layer: q's and the output's projections alone (K and V are
+    another layer's), and a differential core over the causal half."""
+    q_dim = num_heads * head_dim
+    return 2.0 * hidden * q_dim + 2.0 * q_dim * hidden, 3.0 * (2.0 * seq_len * q_dim) * 0.5
+
+
 # the row of each `MIXERS` key, and the config fields its keyword arguments read
 # ("seq_len": no field, the sequence length the count is asked at)
 _DELTA_DIMS = {k: "linear_" + k for k in ("num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
@@ -159,7 +191,11 @@ MIXER_FWD_FLOPS = {
     "conv": (conv_fwd_flops_a_token, {}),
     "window": (window_fwd_flops_a_token, {
         "num_heads": "num_heads", "head_dim": "head_dim", "num_kv_heads": "num_kv_heads",
-        "window": "sliding_window", "head_gate": "attn_head_gate", "seq_len": "seq_len"}),
+        "window": "sliding_window", "head_gate": "attn_head_gate", "seq_len": "seq_len",
+        "diff": "diff_attention"}),
+    "mamba1": (mamba1_fwd_flops_a_token, {k: "mamba_" + k for k in ("expand", "d_state", "dt_rank")}),
+    "gmu": (gmu_fwd_flops_a_token, {"expand": "mamba_expand"}),
+    "cross": (cross_fwd_flops_a_token, {"num_heads": "num_heads", "head_dim": "head_dim", "seq_len": "seq_len"}),
 }
 
 
@@ -184,6 +220,7 @@ def layer_fwd_flops(
     mixer: str = "attention",
     mixer_dims: Optional[Mapping[str, int]] = None,
     head_gate: bool = False,
+    diff: bool = False,
 ) -> float:
     """Forward model FLOPs of ONE transformer block over `tokens` tokens
     (default: one sequence). Matmul terms only (2 FLOPs per MAC); norms and
@@ -199,7 +236,8 @@ def layer_fwd_flops(
     an output gate. `mixer` with `mixer_dims`: the layer's token mixer is
     that row of `MIXER_FWD_FLOPS` on those sizes, in place of attention.
     `shared_gate`: the shared expert's (hidden, 1) gate; `head_gate`: the
-    attention output's (hidden, heads) gate."""
+    attention output's (hidden, heads) gate; `diff`: differential attention's
+    core (1.5 x the ordinary one)."""
     tokens = float(seq_len if tokens is None else tokens)
     ffn = ffn_hidden or 4 * hidden
     if mixer != "attention":
@@ -208,7 +246,7 @@ def layer_fwd_flops(
         proj, attn = attention_fwd_flops_a_token(
             hidden=hidden, num_heads=num_heads, head_dim=head_dim or hidden // num_heads,
             num_kv_heads=num_kv_heads or num_heads, seq_len=seq_len, causal=causal,
-            gated=attn_gate, latent=latent, head_gate=head_gate)
+            gated=attn_gate, latent=latent, head_gate=head_gate, diff=diff)
     # MLP: swiglu projects to 2*ffn (gate+up) then back; gelu/relu ffn both ways
     mlp = (2.0 * hidden * (2 * ffn) + 2.0 * ffn * hidden) if swiglu \
         else (2.0 * hidden * ffn + 2.0 * ffn * hidden)
@@ -255,6 +293,7 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         mixer_dims={k: seq if field == "seq_len" else getattr(cfg, field)
                     for k, field in MIXER_FWD_FLOPS[mixer][1].items()},
         head_gate=bool(getattr(cfg, "attn_head_gate", False)),
+        diff=bool(getattr(cfg, "diff_attention", False)),
     )
 
 

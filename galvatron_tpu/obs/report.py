@@ -436,6 +436,11 @@ def render(analysis: Dict[str, Any]) -> str:
                          % comp["expert_window_rows"])
         if "shortconv_layers" in comp:
             lines.append("layers whose token mixer is a gated short convolution: %d" % comp["shortconv_layers"])
+        if "mamba_layers" in comp:
+            lines.append("layers whose token mixer is a Mamba-1 selective scan: %d" % comp["mamba_layers"])
+        if "shared_readers" in comp:
+            lines.append("layers that read a tensor an earlier layer published (a memory, keys and values): %d"
+                         % comp["shared_readers"])
         if "window_kernel_layers" in comp:
             lines.append("window attention layers whose band runs as Pallas kernels: %d" % comp["window_kernel_layers"])
         if "window_kernel_layers" in comp and "window_operands_as_projected" in comp:

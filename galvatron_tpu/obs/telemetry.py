@@ -70,6 +70,12 @@ LINEAR_STEP_FIELDS = ("linear_decay_mean", "linear_state_abs_max")
 # state-space layers' counter (models/parts/ssm.ssm_mixer): the largest magnitude
 # of any head's state at any chunk's end, the worst layer's
 SSM_STEP_FIELDS = ("ssm_state_abs_max",)
+# Mamba-1 layers' counter (models/parts/mamba.mamba_mixer): the largest magnitude of any
+# (channel, state) at any chunk's end, the worst layer's; and what the layers PUBLISH for
+# later layers beside the residual stream (models/base.run_layers: a Mamba-1 layer's memory,
+# a full differential layer's keys and values), MiB a step over the step's microbatches: the
+# tensors that outlive their layers, which recomputation cannot drop
+SHARED_STEP_FIELDS = ("selscan_state_abs_max", "published_mib")
 
 # type -> (required field names, optional field names). Unknown types and
 # unknown keys are rejected; None-valued optional fields are dropped at emit
@@ -97,7 +103,8 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
          "kda_kernel_layers", "kda_pass_kernel_layers", "moe_row_kernel_blocks", "expert_window_rows",
          "shortconv_layers", "kernel_grads_relaid", "window_kernel_layers",
          "window_operands_as_projected", "table_rows_over_dp", "vocab_split_axes",
-         "scan_grads_in_zero_layout", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb"),
+         "scan_grads_in_zero_layout", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb",
+         "mamba_layers", "shared_readers"),
     ),
     # where the start went, once the first step has drained (obs/launch.py):
     # `launch_ms` the phases of cli/train._train by name (obs/tracing.py's
@@ -117,7 +124,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         ("loss", "iter_ms", "dispatch_ms", "data_wait_ms", "host_blocked_ms",
          "hbm_in_use_mb", "hbm_peak_mb", "mfu", "model_flops_per_s",
          "grad_norm") + EXPERT_STEP_FIELDS + SHARE_STEP_FIELDS + LINEAR_STEP_FIELDS
-        + SSM_STEP_FIELDS,
+        + SSM_STEP_FIELDS + SHARED_STEP_FIELDS,
     ),
     "eval": (("iter", "split", "loss"), ()),
     # lifecycle: checkpointing

@@ -111,6 +111,24 @@ ATTN_KDA = "gt.attn.kda_mixer"
 # taps, C * v; forward, recomputed and backward). Neither name begins the other
 ATTN_CONV_PROJ = "gt.attn.shortconv"
 ATTN_CONV_GATE = "gt.attn.conv_gate"
+# a Mamba-1 mixer (models/parts/mamba.mamba_mixer), inside gt.layers.r<k>, in two
+# disjoint scopes that add up to the mixer, as the state-space mixer's: the
+# selective scan (ops/selective_scan.selective_scan: the chunks' sums, the carried
+# states, the positions' loop; forward, recomputed and backward) and everything
+# else of it (the projections, the convolution and its bias, dt, the gate)
+ATTN_SELSCAN = "gt.attn.selscan"
+ATTN_MAMBA = "gt.attn.mamba"
+# a gated memory unit (models/parts/mamba.gmu_mixer): its two matmuls and the gate on
+# ANOTHER layer's scan output
+ATTN_GMU = "gt.attn.gmu"
+# differential attention's own arithmetic around the attention calls (models/parts/
+# attention.diff_attention): the heads' pairing and padding, lambda, the subtraction,
+# the sub-norm and its factor; the projections stay under gt.attn.proj / gt.attn.window
+# / gt.attn.cross and the calls where they were (the flash kernels by name, gt.attn.band)
+ATTN_DIFF = "gt.attn.diff"
+# a cross layer's q and output projections (models/parts/cross.cross_mixer): it has no
+# keys or values of its own
+ATTN_CROSS = "gt.attn.cross"
 # the multi-token-prediction module, top level: its norms, the (2h, h)
 # projection and its block; its pass through the head and its cross entropy
 # run under HEAD_LOSS, beside the main ones

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import PartitionSpec as P
 
+from galvatron_tpu.models.parts.attention import LAMBDA_INIT
 from galvatron_tpu.models.parts.mlp import ROUTER_BIAS
 from galvatron_tpu.parallel.spec import zero_split_spec
 
@@ -55,9 +56,11 @@ def _no_weight_decay(path, _leaf) -> bool:
 
 # Leaves of the parameter tree that no gradient moves, by their key: a
 # sigmoid router's `e_score_correction_bias` (models/parts/mlp.ROUTER_BIAS), which
-# the train step itself steps once a step (models/base.update_router_bias).
+# the train step itself steps once a step (models/base.update_router_bias), and
+# a differential attention layer's `lambda_init` (models/parts/attention.LAMBDA_INIT),
+# a constant of the layer's published index that nothing ever moves.
 # The optimizer never sees them: no clipping share, no Adam moments, no decay.
-NO_GRADIENT_KEYS = (ROUTER_BIAS,)
+NO_GRADIENT_KEYS = (ROUTER_BIAS, LAMBDA_INIT)
 
 
 def _without_no_gradient_leaves(tree):

@@ -449,7 +449,8 @@ def test_every_leafs_gradient_is_the_same_with_the_window_kernels_on_q_as_projec
 
 # ------------------------------------------------ the table, FLOPs, the counters
 def test_one_table_maps_the_window_mixer_to_what_it_brings():
-    assert M.MIXERS["window"].scopes == (tracing.ATTN_WINDOW, tracing.ATTN_WINDOW_BAND) == (
+    # (and `gt.attn.diff`, which the differential form alone names: Phi-4-mini-flash's window layers)
+    assert M.MIXERS["window"].scopes[:2] == (tracing.ATTN_WINDOW, tracing.ATTN_WINDOW_BAND) == (
         "gt.attn.window", "gt.attn.band")
     scopes = [s for part in M.MIXERS.values() for s in part.scopes]
     assert not any(a != b and (a.startswith(b) or b.startswith(a)) for a in M.MIXERS["window"].scopes for b in scopes)

@@ -34,6 +34,7 @@ LAYERS = 2
 DENSE = dict(hidden_size=64, num_heads=4, num_layers=LAYERS, vocab_size=128, max_seq_len=64)
 DELTA = dict(linear_num_key_heads=2, linear_num_value_heads=2, linear_key_head_dim=16,
              linear_value_head_dim=16, linear_conv_kernel=4)
+MAMBA1 = dict(mamba_d_state=4, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=4)
 # a tiny config built of each entry (beside dense MLPs under softmax attention, which have every form)
 BUILT_OF = {
     ("MIXERS", "attention"): DENSE,
@@ -46,6 +47,9 @@ BUILT_OF = {
     ("MIXERS", "conv"): dict(DENSE, layer_types=["conv", "attention"], short_conv_kernel=3),
     ("MIXERS", "window"): dict(DENSE, layer_types=["sliding_attention", "full_attention"], sliding_window=8,
                                window_num_heads=8, head_dim=16),
+    ("MIXERS", "mamba1"): dict(DENSE, layer_types=["mamba1", "attention"], **MAMBA1),
+    ("MIXERS", "gmu"): dict(DENSE, layer_types=["mamba1", "gmu"], **MAMBA1),
+    ("MIXERS", "cross"): dict(DENSE, layer_types=["full_attention", "cross_attention"], diff_attention=True),
     ("MLP_HALVES", "dense"): DENSE,
     ("MLP_HALVES", "routed"): dict(DENSE, num_experts=4, experts_per_token=2),
 }
@@ -191,10 +195,11 @@ def test_every_key_of_the_mixers_table_is_a_word_of_layer_types():
     "full_attention" and "sliding_attention"): a part added to the table is
     accepted with no clause added, and the refusal names them all."""
     fields = {**DELTA, "ssm_num_heads": 4, "ssm_head_dim": 16, "ssm_state_dim": 8, "ssm_conv_kernel": 4,
-              "short_conv_kernel": 3, "sliding_window": 8}
+              "short_conv_kernel": 3, "sliding_window": 8, **MAMBA1, "diff_attention": True}
+    reads_after = {"gmu": "mamba1", "cross": "attention"}  # a reader stands after a layer that publishes
     for key in parts.MIXERS:
-        cfg = TransformerConfig(**DENSE, layer_types=[key, "attention"], **fields)
-        assert cfg.mixers() == (key, "attention")
+        pattern = [reads_after[key], key] if key in reads_after else [key, "attention"]
+        assert TransformerConfig(**DENSE, layer_types=pattern, **fields).mixers() == tuple(pattern)
     assert TransformerConfig(**DENSE, layer_types=["mamba", "attention"], **fields).mixers() == ("ssm", "attention")
     assert TransformerConfig(**DENSE, layer_types=["sliding_attention", "full_attention"], **fields).mixers() == (
         "window", "attention")

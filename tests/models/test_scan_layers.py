@@ -326,3 +326,50 @@ def test_stacked_specs_match_stacked_shapes():
     for t, sp in zip(flat_t, flat_s):
         assert len(sp) <= t.ndim
         assert sp[0] is None  # the stacked layer axis is never sharded
+
+
+# ------------------------------------------- a config that publishes nothing
+# `run_layers` carries what layers PUBLISH for later layers in a dict beside x
+# (Phi-4-mini-flash's family, PR 57). For a config none of whose mixers reads,
+# the dict is empty and nothing of it is traced: the equations of the stack's
+# gradient, scanned and unrolled, are the counts recorded on PR 57's PARENT
+# (scripts of that PR printed both trees' jaxprs: their texts were equal).
+def _dense4():
+    return make_cfg(4)
+
+
+def _hybrid10():
+    from galvatron_tpu.models.granite_hybrid import granite_hybrid_config
+
+    return granite_hybrid_config(hidden_size=64, num_heads=4, num_kv_heads=2, ffn_hidden=96, num_layers=10,
+                                 vocab_size=128, max_seq_len=S, ssm_num_heads=4, ssm_head_dim=32, ssm_state_dim=16,
+                                 compute_dtype=jnp.float32, attn_impl="xla")
+
+
+RECORDED = {  # (config, layout): (equations scanned, equations unrolled)
+    ("dense4", "tp2"): (160, 958),
+    ("dense4", "dp_remat"): (159, 350),
+    ("hybrid10", "dp_remat"): (405, 1020),
+}
+
+
+@pytest.mark.parametrize("name,layout", sorted(RECORDED))
+def test_a_config_that_publishes_nothing_traces_what_it_did(name, layout, devices8):
+    cfg = {"dense4": _dense4, "hybrid10": _hybrid10}[name]()
+    n = cfg.num_layers
+    assert cfg.shared() == (((), ()),) * n  # no layer hands a tensor on, none reads one
+    hp = (HybridParallelConfig.uniform(8, n, tp=2, global_bsz=B) if layout == "tp2"
+          else HybridParallelConfig.uniform(8, n, global_bsz=B, checkpoint=1))
+    mesh = build_mesh(hp, devices8)
+    params = {"layers": [M.init_layer_params(jax.random.PRNGKey(i), cfg.layer_config(kind))
+                         for i, kind in enumerate(cfg.layer_kinds())]}
+    x, positions = make_inputs()
+
+    def equations(scan):
+        def out(p, xx):
+            y = M.run_layers(p, xx, positions, cfg, hp, mesh, scan=scan)
+            return jnp.sum((y[0] if cfg.layer_aux else y) ** 2)
+
+        return len(jax.make_jaxpr(jax.grad(out))(params, x).eqns)
+
+    assert (equations(True), equations(False)) == RECORDED[(name, layout)]
