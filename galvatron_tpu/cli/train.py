@@ -31,7 +31,7 @@ from galvatron_tpu.obs import compiled as obs_compiled
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import launch, telemetry, tracing
 from galvatron_tpu.ops import attention as attention_ops
-from galvatron_tpu.ops import linear_attention, moe
+from galvatron_tpu.ops import linear_attention, moe, selective_scan
 from galvatron_tpu.parallel import pipeline
 from galvatron_tpu.parallel.mesh import layer_axes
 from galvatron_tpu.profiler.runtime import (
@@ -55,7 +55,8 @@ launch.IMPORTS.done()  # the program is imported: the import record closes and g
 KERNEL_FORMS = dict(
     delta_rule=linear_attention.TOOK, moe_rows=moe.ROWS_TOOK, moe_windows=moe.WINDOWS_TOOK,
     kernels_relaid=mlp.RELAID, windows=attention_ops.TOOK, lookups=embed_head.LOOKUPS_TOOK,
-    vocab_split=pipeline.VOCAB_SPLIT_TOOK, scan_grads=model_base.SCAN_GRADS_IN_ZERO_LAYOUT)
+    vocab_split=pipeline.VOCAB_SPLIT_TOOK, scan_grads=model_base.SCAN_GRADS_IN_ZERO_LAYOUT,
+    selscan=selective_scan.TOOK)
 
 
 # In-process memo of AOT-compiled train-step executables, keyed by (device
@@ -580,6 +581,7 @@ def _train(args, started: launch.Launch) -> dict:
             linear_layers = sum(kind.startswith("linear") for kind in cfg.layer_kinds()) if scalar_rule else 0
             kda_layers = sum(kind.startswith("kda") for kind in cfg.layer_kinds()) if kda_rule else 0
             window_layers = sum(kind.startswith("window") for kind in cfg.layer_kinds()) if windows_took else 0
+            shared = shared_counts(cfg)
             telemetry.emit(
                 "compile",
                 trace_ms=trace_ms,
@@ -640,7 +642,12 @@ def _train(args, started: launch.Launch) -> dict:
                 # the layers whose token mixer is a Mamba-1 selective scan (models/parts/mamba.py),
                 # and the layers that read a tensor an EARLIER layer published beside the residual
                 # stream (`TransformerConfig.shared`); absent where the model has none
-                **{k: v or None for k, v in shared_counts(cfg).items()},
+                **{k: v or None for k, v in shared.items()},
+                # the Mamba-1 layers whose scan the step runs as Pallas kernels
+                # (`selective_scan.selective_scan`): all or none, the layers being
+                # alike; absent where the step traced no such layer
+                selscan_kernel_layers=(shared["mamba_layers"] * (not forms.took["selscan"]["xla"])
+                                       if forms.took["selscan"] else None),
                 # the mesh axes the scan pipeline's vocabulary layers are
                 # stored and computed split over (`mesh.pipeline_vocab_axes`:
                 # pp, then the vocabulary's tp axes); absent at pp = 1, under

@@ -87,7 +87,8 @@ def _init_mamba(ks, cfg: TransformerConfig) -> Params:
     return {"mamba": p}
 
 
-def mamba_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, *, publish=(), **_):
+def mamba_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, *, publish=(),
+                attn_sharding=None, **_):
     """Mamba-1 on normed activations (B, S, H) (arXiv:2312.00752; HF
     `Phi4FlashMambaMixer`), p the layer's tree:
 
@@ -101,7 +102,8 @@ def mamba_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerC
     end) and, where a later layer reads it (`publish`), `m` as the layer's
     MEMORY: the scan's output with the D skip, before the gate. Scopes: the scan
     under `gt.attn.selscan`, all else under `gt.attn.mamba`. No position enters:
-    the order is the recurrence's."""
+    the order is the recurrence's. `attn_sharding` tells the scan where its
+    operands lie: on TPUs it runs as Pallas kernels (`selective_scan`)."""
     p, dtype = p["mamba"], cfg.compute_dtype
     inner, n, r = d_inner(cfg), cfg.mamba_d_state, cfg.mamba_dt_rank
     with jax.named_scope(tracing.ATTN_MAMBA):
@@ -114,7 +116,8 @@ def mamba_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerC
                              + p["wdt"]["bias"].astype(jnp.float32))
         a = -jnp.exp(p["A_log"].astype(jnp.float32))
     with jax.named_scope(tracing.ATTN_SELSCAN):
-        m, _, peak = selective_scan(x, dt, a, dbc[..., r:r + n], dbc[..., r + n:], p["D"])
+        m, _, peak = selective_scan(x, dt, a, dbc[..., r:r + n], dbc[..., r + n:], p["D"],
+                                    sharding=attn_sharding)
     with jax.named_scope(tracing.ATTN_MAMBA):
         out = _dense(m * jax.nn.silu(xz[..., inner:]), p["wout"], dtype)
     said = {"selscan_state_abs_max": peak}

@@ -438,6 +438,8 @@ def render(analysis: Dict[str, Any]) -> str:
             lines.append("layers whose token mixer is a gated short convolution: %d" % comp["shortconv_layers"])
         if "mamba_layers" in comp:
             lines.append("layers whose token mixer is a Mamba-1 selective scan: %d" % comp["mamba_layers"])
+        if "selscan_kernel_layers" in comp:
+            lines.append("Mamba-1 layers whose selective scan runs as Pallas kernels: %d" % comp["selscan_kernel_layers"])
         if "shared_readers" in comp:
             lines.append("layers that read a tensor an earlier layer published (a memory, keys and values): %d"
                          % comp["shared_readers"])

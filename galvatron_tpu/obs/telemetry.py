@@ -104,7 +104,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
          "shortconv_layers", "kernel_grads_relaid", "window_kernel_layers",
          "window_operands_as_projected", "table_rows_over_dp", "vocab_split_axes",
          "scan_grads_in_zero_layout", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb",
-         "mamba_layers", "shared_readers"),
+         "mamba_layers", "shared_readers", "selscan_kernel_layers"),
     ),
     # where the start went, once the first step has drained (obs/launch.py):
     # `launch_ms` the phases of cli/train._train by name (obs/tracing.py's

@@ -24,14 +24,16 @@ A seed reads:
   `wkv` kernel and bias (through layer 19's queries);
 - **the scan's core**: layer 16's x, dt, A, B, C, D as the program makes them
   (bf16 operands, float32 dt), through `ops/selective_scan.selective_scan` as
-  the step runs it: the relative error of m over the whole sequence and over
+  the step runs it (since PR 58 the kernels `selscan_fwd` / `selscan_bwd` here,
+  where jax finds a TPU): the relative error of m over the whole sequence and over
   the LAST chunk's tokens, where 8192 tokens of carried state have piled up,
   against the reference's token-by-token recurrence in float32 on the chip,
   and of the final states against the same recurrence in FLOAT64 ON THE HOST.
 
 **Two controls on the first seed, each of which must FAIL at least one limit**:
 the same core with its carried state rounded to bfloat16 after every token
-(`state_dtype`, by `jax.lax.reduce_precision`), the next lower precision; and
+(`state_dtype`, by `jax.lax.reduce_precision`), the next lower precision (the
+XLA form's: the kernels hold a float32 state and nothing else); and
 the program's gradients against a reference whose readers see the memory and K,
 V behind a `stop_gradient` (`switch_off` "reader_cotangents"): what a step that
 dropped a reader's cotangent would compute. Writes

@@ -48,9 +48,14 @@ def test_trains_on_one_device_and_the_summary_holds_the_counters(one_device):
     assert one_device["published_mib"] == pytest.approx(2 * 48 * (128 + 2 * 32) * 2 / 2 ** 20)
 
 
-def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, tmp_path):
-    tele = str(tmp_path / "phi4.jsonl")
-    s = run(["--world_size", "2", "--default_dp_type", "zero2", "--telemetry", tele])
+@pytest.fixture(scope="module")
+def dp2_zero2(tmp_path_factory):
+    tele = str(tmp_path_factory.mktemp("phi4") / "phi4.jsonl")
+    return run(["--world_size", "2", "--default_dp_type", "zero2", "--telemetry", tele]), tele
+
+
+def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, dp2_zero2):
+    s, tele = dp2_zero2
     np.testing.assert_allclose(s["losses"], one_device["losses"], rtol=2e-4)
     events, errors = T.read_events(tele)
     assert errors == []
@@ -64,6 +69,22 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, tmp_p
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(k, k, k + 1) for k in range(8)]
     compiles = [e for e in events if e["type"] == "compile"]
     assert [(e["mamba_layers"], e["shared_readers"]) for e in compiles] == [(3, 2)]
+
+
+def test_the_compile_event_says_how_many_scans_run_as_kernels(dp2_zero2, capsys):
+    """`selscan_kernel_layers`: the Mamba-1 layers whose scan the step runs as
+    `selscan_fwd` / `selscan_bwd`; 0 on the CPU, where `selective_scan` takes
+    the XLA form, and `cli report` prints it beside the layers' count."""
+    from galvatron_tpu.obs import report
+
+    assert "selscan_kernel_layers" in T.EVENT_SCHEMAS["compile"][1]
+    events, errors = T.read_events(dp2_zero2[1])
+    assert errors == []
+    assert [e["selscan_kernel_layers"] for e in events if e["type"] == "compile"] == [0]
+    report.run([dp2_zero2[1]])
+    out = capsys.readouterr().out
+    assert "layers whose token mixer is a Mamba-1 selective scan: 3" in out
+    assert "Mamba-1 layers whose selective scan runs as Pallas kernels: 0" in out
 
 
 @pytest.mark.parametrize("flags,named", [

@@ -122,6 +122,15 @@ def test_the_compile_event_says_whether_the_table_stays_split_over_dp(telemetry_
     assert "the token table stays split over dp" in capsys.readouterr().out
 
 
+def test_a_model_with_no_mamba_layer_says_nothing_of_scan_kernels(telemetry_run, capsys):
+    """`selscan_kernel_layers` is absent where the step traced no selective
+    scan (the shared dense run), and `cli report` prints no line for it
+    (tests/cli/test_phi4flash_train.py holds the family's own 0 on the CPU)."""
+    assert "selscan_kernel_layers" not in by_type(telemetry_run[1])["compile"][0]
+    R.run([telemetry_run[3]])
+    assert "selective scan" not in capsys.readouterr().out
+
+
 def test_the_compile_event_counts_the_scanned_gradients_in_zeros_layout(telemetry_run, zero2_tp2dp2_run, capsys):
     """`scan_grads_in_zero_layout`: the stacked leaves of the step's scanned
     runs whose cotangent was asked for in ZeRO's layout (models/base.run_layers):
