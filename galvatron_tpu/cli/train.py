@@ -25,14 +25,9 @@ from galvatron_tpu.cli.arguments import (
     initialize_galvatron,
     model_config_from_args,
 )
-from galvatron_tpu.models import base as model_base
-from galvatron_tpu.models.parts import embed_head, mlp
 from galvatron_tpu.obs import compiled as obs_compiled
 from galvatron_tpu.obs import flops as obs_flops
-from galvatron_tpu.obs import launch, telemetry, tracing
-from galvatron_tpu.ops import attention as attention_ops
-from galvatron_tpu.ops import linear_attention, moe, selective_scan
-from galvatron_tpu.parallel import pipeline
+from galvatron_tpu.obs import forms, launch, telemetry, tracing
 from galvatron_tpu.parallel.mesh import layer_axes
 from galvatron_tpu.profiler.runtime import (
     RuntimeProfiler,
@@ -49,15 +44,6 @@ from galvatron_tpu.runtime.prefetch import PrefetchIterator, PrefetchStalledErro
 from galvatron_tpu.utils.compile_cache import enable_persistent_cache
 
 launch.IMPORTS.done()  # the program is imported: the import record closes and gives its monitoring id back
-
-# the ops modules' counters of which form each part of the step took, bumped as
-# the model code is traced; `compiled_step` reads what the lowering added
-KERNEL_FORMS = dict(
-    delta_rule=linear_attention.TOOK, moe_rows=moe.ROWS_TOOK, moe_windows=moe.WINDOWS_TOOK,
-    kernels_relaid=mlp.RELAID, windows=attention_ops.TOOK, lookups=embed_head.LOOKUPS_TOOK,
-    vocab_split=pipeline.VOCAB_SPLIT_TOOK, scan_grads=model_base.SCAN_GRADS_IN_ZERO_LAYOUT,
-    selscan=selective_scan.TOOK)
-
 
 # In-process memo of AOT-compiled train-step executables, keyed by (device
 # ids, sha256 of the lowered StableHLO). Repeated train() calls in one
@@ -545,7 +531,7 @@ def _train(args, started: launch.Launch) -> dict:
             return step_fn(*step_args)
         if _aot["fn"] is None:
             with control.span(tracing.COMPILE):
-                with launch.CounterDeltas(**KERNEL_FORMS) as forms:
+                with forms.recording() as took:
                     with started.phase(control, tracing.COMPILE_TRACE) as traced_in:
                         traced = step_fn.trace(*step_args)
                     with started.phase(control, tracing.COMPILE_LOWER) as lowered_in:
@@ -571,17 +557,7 @@ def _train(args, started: launch.Launch) -> dict:
             # this program
             trace_ms, compile_ms = traced_in.ms + lowered_in.ms, keyed_in.ms + loaded_in.ms
             prof.record_compile(trace_ms=trace_ms, compile_ms=compile_ms, cache_hit=cache_hit)
-            delta_rule_took, moe_rows_took = forms.took["delta_rule"], forms.took["moe_rows"]
-            moe_windows_took, kernels_relaid = forms.took["moe_windows"], forms.took["kernels_relaid"]
-            windows_took = forms.took["windows"]
             prof.compiled_memory_mb = compiled_step_memory_mb(compiled) or None
-            # (asked only of a model that traced such a layer: T5's config has no kinds)
-            scalar_rule = delta_rule_took["xla"] or delta_rule_took["pallas"]
-            kda_rule = delta_rule_took["kda_xla"] or delta_rule_took["kda_pallas"]
-            linear_layers = sum(kind.startswith("linear") for kind in cfg.layer_kinds()) if scalar_rule else 0
-            kda_layers = sum(kind.startswith("kda") for kind in cfg.layer_kinds()) if kda_rule else 0
-            window_layers = sum(kind.startswith("window") for kind in cfg.layer_kinds()) if windows_took else 0
-            shared = shared_counts(cfg)
             telemetry.emit(
                 "compile",
                 trace_ms=trace_ms,
@@ -589,77 +565,14 @@ def _train(args, started: launch.Launch) -> dict:
                 compiled_memory_mb=prof.compiled_memory_mb,
                 xla_flops_per_step=obs_flops.xla_flops(compiled),
                 cache_hit=(memo_hit or cache_hit) or None,
-                # the linear layers whose delta rule the step runs as Pallas
-                # kernels (ops/linear_attention.py): all of them or none, the
-                # layers being alike; absent where the model has none
-                linear_kernel_layers=linear_layers * (not delta_rule_took["xla"]) if scalar_rule else None,
-                # the Kimi-Delta-Attention layers whose per-channel rule the
-                # step runs as Pallas kernels (`linear_attention.kda_rule`):
-                # all or none; absent where the model has none
-                kda_kernel_layers=kda_layers * (not delta_rule_took["kda_xla"]) if kda_rule else None,
-                # and those whose convolution, norms and gated norm around it
-                # run as Pallas passes (`linear_attention.mixer_form`)
-                linear_pass_kernel_layers=(
-                    linear_layers * (not (delta_rule_took["conv_norm_xla"] or delta_rule_took["gated_norm_xla"]))
-                    if scalar_rule else None),
-                # and the Kimi-Delta-Attention layers' (with the per-channel gate's pass)
-                kda_pass_kernel_layers=(
-                    kda_layers * (not any(delta_rule_took["kda_%s_xla" % name]
-                                          for name in ("conv_norm", "gate", "gated_norm")))
-                    if kda_rule else None),
-                # the routed blocks whose rows the step moves with the Pallas
-                # row movers (`moe.rows_form`): all of them or none, the blocks
-                # being alike in width and length; absent where the model has none
-                moe_row_kernel_blocks=(cfg.routed_layers * (not moe_rows_took["xla"])
-                                       if moe_rows_took else None),
-                # the rows of the window that the experts of a share work on
-                # (`moe.window_rows`; the longest, the blocks being alike), 0
-                # where none is built; absent where all experts are held
-                expert_window_rows=max(moe_windows_took) if moe_windows_took else None,
-                # the layers whose token mixer is a gated short convolution
-                # (models/parts/conv.py); absent where the step traced none
-                shortconv_layers=(sum(kind.startswith("conv") for kind in cfg.layer_kinds())
-                                  if delta_rule_took["short_conv"] else None),
-                # the window attention layers whose band the step runs as Pallas
-                # kernels (`ops/attention._windowed`): all or none, the layers
-                # being alike; absent where the step traced no window layer
-                window_kernel_layers=window_layers * (not windows_took["window_xla"]) if windows_took else None,
-                # and those of them whose kernels read q where the projection
-                # wrote it, rope and the head's gate fused: all or none again;
-                # 0 off a TPU and where the step traced no window layer
-                window_operands_as_projected=window_layers * (
-                    windows_took["window_as_projected"] == windows_took["window_pallas"] > 0),
-                # the gated (hidden, 2, ffn) kernels the step read through
-                # `parts/mlp.grad_as_stored`, as traced (`models/base.run_layers`):
-                # all of a model's or none; 0 off a TPU, where every such layer
-                # is scanned and where there is none
-                kernel_grads_relaid=len(kernels_relaid),
-                # 1 where the step looks the vocabulary-split token table up
-                # with ids, rows and cotangents crossing dp and the table
-                # staying split over it (`embed_head.vocab_parallel_lookup`'s
-                # second form), else 0
-                table_rows_over_dp=int(forms.took["lookups"]["rows_over_dp"] > 0),
+                # which form each part of the step took as it was traced (obs/forms.py):
+                # part -> form -> how often; a part the step has none of is absent
+                forms=took,
                 # the layers whose token mixer is a Mamba-1 selective scan (models/parts/mamba.py),
                 # and the layers that read a tensor an EARLIER layer published beside the residual
                 # stream (`TransformerConfig.shared`); absent where the model has none
-                **{k: v or None for k, v in shared.items()},
-                # the Mamba-1 layers whose scan the step runs as Pallas kernels
-                # (`selective_scan.selective_scan`): all or none, the layers being
-                # alike; absent where the step traced no such layer
-                selscan_kernel_layers=(shared["mamba_layers"] * (not forms.took["selscan"]["xla"])
-                                       if forms.took["selscan"] else None),
-                # the mesh axes the scan pipeline's vocabulary layers are
-                # stored and computed split over (`mesh.pipeline_vocab_axes`:
-                # pp, then the vocabulary's tp axes); absent at pp = 1, under
-                # vocab-SP and in the 1F1B engines, which traced no such loss
-                vocab_split_axes=next((list(axes) for axes in forms.took["vocab_split"]), None),
-                # the stacked leaves, over the step's scanned runs, whose
-                # cotangent was asked for in ZeRO's layout as traced
-                # (`models/base.run_layers`): a run's kernels and biases where
-                # ZeRO-2 splits the state over dp > 1; 0 on one chip, under
-                # ddp, ZeRO-3's own leaves, pp > 1 and the manual TP path
-                scan_grads_in_zero_layout=len(forms.took["scan_grads"]),
-                # and what the COMPILER made of it: MB a chip and a layer of
+                **{k: v or None for k, v in shared_counts(cfg).items()},
+                # what the COMPILER made of `forms`' scan_grads: MB a chip and a layer of
                 # the weight gradients over 1 MB that the compiled step sums
                 # over dp inside a scanned run's backward, whole onto every
                 # chip (`dp_grad_all_reduce_mb`) or into ZeRO's shards

@@ -17,7 +17,6 @@ no part. The config is `models/config.TransformerConfig`."""
 
 from __future__ import annotations
 
-import collections
 from functools import partial
 from typing import Dict, List, Optional, Tuple
 
@@ -38,9 +37,9 @@ from galvatron_tpu.models.parts import MIXERS, MLP_HALVES, unsupported_reason
 from galvatron_tpu.models.parts.common import Params, _dense, _dense_init, _norm, _norm_params
 from galvatron_tpu.models.parts.embed_head import (embed_patches, embed_tokens, head_logits, model_head, softmax_nll,
                                                    vocab_parallel_cross_entropy)
-from galvatron_tpu.models.parts.mlp import RELAID, ROUTER_BIAS, grad_as_stored
-from galvatron_tpu.obs import tracing
-from galvatron_tpu.ops.attention import KernelSharding
+from galvatron_tpu.models.parts.mlp import ROUTER_BIAS, grad_as_stored
+from galvatron_tpu.obs import forms, tracing
+from galvatron_tpu.ops.kernels import KernelSharding
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import LayerAxes, layer_axes, vocab_axes
 
@@ -323,14 +322,6 @@ def stacked_layer_grad_specs(cfg: TransformerConfig, axes: LayerAxes, stacked: P
     )
 
 
-# the stacked leaves whose cotangent a scanned run asked for in ZeRO's layout
-# since the process began, by run and path, counted as they are traced
-# (`run_layers`): the trainer's compile report reads how many a step's trace
-# added. What the compiler made of the request is the compiled step's to say
-# (obs/compiled.dp_grad_sums_mb)
-SCAN_GRADS_IN_ZERO_LAYOUT = collections.Counter()
-
-
 def _at(tree: Params, path: Tuple[str, ...], fn) -> Params:
     """`tree` with `fn` applied to the leaf at `path`; the rest shared."""
     return {**tree, path[0]: _at(tree[path[0]], path[1:], fn) if path[1:] else fn(tree[path[0]])}
@@ -348,7 +339,8 @@ def _gated_grads_as_stored(layers: List[Params], runs, scanned, cfg: Transformer
     among nine scanned ones: 60 copies). Where every such run is scanned (the
     Qwen cells) the compiler lays the scan's gradient buffer out after the
     state by itself, and the program stays as it is. All of a model's gated
-    kernels or none; `RELAID` counts each, by layer and path, as it is traced."""
+    kernels or none; each is said to `obs/forms` (`GATED_KERNEL_GRADS`), by layer
+    and path, as it is traced."""
     kinds = cfg.layer_kinds()
 
     def gated(run):  # a run is of one kind
@@ -364,7 +356,7 @@ def _gated_grads_as_stored(layers: List[Params], runs, scanned, cfg: Transformer
         for path in gated(run):
             for i in run.layer_indices:
                 layers[i] = _at(layers[i], path, partial(grad_as_stored, held_in=held_in))
-                RELAID[(i,) + path] += 1
+                forms.took(forms.GATED_KERNEL_GRADS, "as_stored", key=(i,) + path)
     return layers
 
 
@@ -511,7 +503,8 @@ def run_layers(
 
             def constrained(path, t, sp, grad_sp):
                 if sp != grad_sp:
-                    SCAN_GRADS_IN_ZERO_LAYOUT[(k, jax.tree_util.keystr(path))] += 1
+                    # (what the compiler made of the request is the compiled step's to say: obs/compiled.dp_grad_sums_mb)
+                    forms.took(forms.SCAN_GRADS, "zero_layout", key=(k, jax.tree_util.keystr(path)))
                 return S.constrain_grad_as(t, mesh, sp, grad_sp)
 
             stacked = jax.tree_util.tree_map_with_path(constrained, stacked, read_as, summed_as)

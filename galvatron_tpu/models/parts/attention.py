@@ -15,7 +15,8 @@ from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.models.parts.common import (LayerPart, Params, _dense, _dense_init, _norm, _norm_scale,
                                                _proj_std, no_form)
 from galvatron_tpu.obs import tracing
-from galvatron_tpu.ops.attention import KernelSharding, core_attention, window_takes_kernels
+from galvatron_tpu.ops.attention import core_attention, window_takes_kernels
+from galvatron_tpu.ops.kernels import KernelSharding
 from galvatron_tpu.ops.norms import rms_norm
 from galvatron_tpu.ops.rope import apply_rotary, checked_scaling, half_split_tables
 from galvatron_tpu.parallel import spec as S

@@ -8,7 +8,7 @@ from jax.sharding import PartitionSpec as P
 
 from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.models.parts.common import LayerPart, Params, _dense, _dense_init, _proj_std, no_form
-from galvatron_tpu.obs import tracing
+from galvatron_tpu.obs import forms, tracing
 from galvatron_tpu.ops import linear_attention
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import LayerAxes
@@ -62,7 +62,7 @@ def conv_mixer(p: Params, y: jax.Array, positions, cfg: TransformerConfig, **_):
     two matmuls under `gt.attn.shortconv`, the pass between them under
     `gt.attn.conv_gate`. No position enters: the order is the convolution's."""
     p, dtype, h = p["conv"], cfg.compute_dtype, cfg.hidden_size
-    linear_attention.TOOK["short_conv"] += 1  # the trainer's compile report: `shortconv_layers`
+    forms.took(forms.SHORT_CONV, "xla")
     with jax.named_scope(tracing.ATTN_CONV_PROJ):
         bcu = _dense(y, p["win"], dtype)
     with jax.named_scope(tracing.ATTN_CONV_GATE):

@@ -3,7 +3,6 @@ lookup, and the head with its written backward and the cross entropies."""
 
 from __future__ import annotations
 
-import collections
 from functools import partial
 from typing import Optional, Tuple
 
@@ -13,13 +12,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.models.parts.common import Params, _dense, _norm
+from galvatron_tpu.obs import forms
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import LayerAxes, mesh_axis_size
-
-
-# form ("rows_over_dp", "table_whole") -> the lookups traced in it since the
-# process began: the trainer's compile report reads what a step's trace added
-LOOKUPS_TOOK = collections.Counter()
 
 
 def vocab_parallel_lookup(wte: jax.Array, tokens: jax.Array, dtype, mesh: Mesh, vax: LayerAxes,
@@ -64,7 +59,7 @@ def vocab_parallel_lookup(wte: jax.Array, tokens: jax.Array, dtype, mesh: Mesh, 
     if table_spec is None:
         table_spec = S.vocab_embed_spec(vax)
     over = table_split_axes(table_spec, vax)
-    LOOKUPS_TOOK["rows_over_dp" if over else "table_whole"] += 1
+    forms.took(forms.TABLE_LOOKUP, "rows_over_dp" if over else "table_whole")
 
     # serve hands in (1, ctx) and (slots, 1): rows the dp axes do not divide stay whole
     split_rows = tokens.shape[0] % mesh_axis_size(mesh, vax.batch_axes) == 0

@@ -14,7 +14,7 @@ from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.models.parts.common import LayerPart, Params, _dense, _dense_init, _proj_std, _unit, no_form
 from galvatron_tpu.models.parts.linear import validate_delta_heads
 from galvatron_tpu.obs import tracing
-from galvatron_tpu.ops.attention import KernelSharding
+from galvatron_tpu.ops.kernels import KernelSharding
 from galvatron_tpu.ops.linear_attention import Heads, causal_conv, kda_kernel_mixer, kda_layout, kda_rule, mixer_form
 from galvatron_tpu.ops.norms import rms_norm
 from galvatron_tpu.parallel import spec as S

@@ -12,7 +12,7 @@ from jax.sharding import PartitionSpec as P
 from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.models.parts.common import LayerPart, Params, _dense, _dense_init, _proj_std, _unit, no_form
 from galvatron_tpu.obs import tracing
-from galvatron_tpu.ops.attention import KernelSharding
+from galvatron_tpu.ops.kernels import KernelSharding
 from galvatron_tpu.ops.linear_attention import (Heads, causal_conv, gated_delta_rule, kernel_mixer, linear_layout,
                                                 mixer_form)
 from galvatron_tpu.ops.norms import rms_norm

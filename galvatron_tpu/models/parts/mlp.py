@@ -3,7 +3,6 @@ shared expert and its gate beside them."""
 
 from __future__ import annotations
 
-import collections
 import math
 from functools import partial
 
@@ -32,12 +31,6 @@ def _init_dense(ks, cfg: TransformerConfig) -> Params:
         p["wi"]["bias"] = jnp.zeros(cfg.mlp_fan_in, cfg.param_dtype)
         p["wo_mlp"]["bias"] = jnp.zeros((h,), cfg.param_dtype)
     return p
-
-
-# the kernels read through `grad_as_stored` since the process began, by layer
-# and path, counted as they are traced (`models/base.run_layers`): the
-# trainer's compile report reads how many a step's trace added
-RELAID = collections.Counter()
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1,))

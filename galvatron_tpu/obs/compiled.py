@@ -1,11 +1,11 @@
 """What the compiler made of a request, read off a compiled step's text.
 
-A trace-time counter (`models/base.SCAN_GRADS_IN_ZERO_LAYOUT`, the `compile`
-event's `scan_grads_in_zero_layout`) says what the program ASKED for; whether
+What the trace says (`obs/forms.SCAN_GRADS`, in the `compile` event's
+`forms`) is what the program ASKED for; whether
 a scanned run's weight gradients are then summed over dp into the shards
 ZeRO keeps, or whole onto every chip, is the optimized HLO's to say. The
 trainer's `compile` event (cli/train.py) and the tests that hold the compiled
-step (tests/ops/test_tpu_compile.py) read it through the same function."""
+step (tests/ops/test_tpu_compile_steps.py) read it through the same function."""
 
 from __future__ import annotations
 

@@ -38,18 +38,15 @@ requests, hits, misses and read seconds, backend compile seconds. Registered
 when the launch starts and unregistered when its first step has drained,
 before any measured step.
 
-`CounterDeltas` is the one reader of the ops modules' form counters around
-the step's lowering. stdlib-only at module scope: this module exists before
-jax is imported.
+stdlib-only at module scope: this module exists before jax is imported.
 """
 
 from __future__ import annotations
 
-import collections
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Mapping, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 # ----------------------------------------------------------------- imports
 PACKAGES = ("jax", "jaxlib", "google", "orbax", "tensorstore", "grpc", "flax", "optax", "numpy",
@@ -325,22 +322,3 @@ class Launch:
             ms[name] = ms.get(name, 0.0) + (end - start)
         ms[TOTAL] = self.total_ms
         return {"launch_ms": ms, "launch_imports": self.imports.as_dict(), "launch_jit": self._jit}
-
-
-# ------------------------------------------------------------ kernel forms
-class CounterDeltas:
-    """What the ops modules' form counters (`collections.Counter`s that the
-    model code bumps as it is traced) gained inside the `with`:
-    `.took[name]` is `counter - its value on entry`."""
-
-    def __init__(self, **counters: Mapping):
-        self._counters = counters
-        self.took: Dict[str, collections.Counter] = {}
-
-    def __enter__(self):
-        self._before = {name: collections.Counter(c) for name, c in self._counters.items()}
-        return self
-
-    def __exit__(self, *exc_info):
-        self.took = {name: c - self._before[name] for name, c in self._counters.items()}
-        return False

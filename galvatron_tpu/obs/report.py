@@ -416,48 +416,13 @@ def render(analysis: Dict[str, Any]) -> str:
                _fmt(comp.get("compiled_memory_mb")),
                _fmt(comp.get("xla_flops_per_step")))
         )
-        if "linear_kernel_layers" in comp:
-            lines.append("linear layers whose delta rule runs as Pallas kernels: %d"
-                         % comp["linear_kernel_layers"])
-        if "linear_pass_kernel_layers" in comp:
-            lines.append("linear layers whose convolution and norms run as Pallas passes: %d"
-                         % comp["linear_pass_kernel_layers"])
-        if "kda_kernel_layers" in comp:
-            lines.append("Kimi-Delta-Attention layers whose rule runs as Pallas kernels: %d"
-                         % comp["kda_kernel_layers"])
-        if "kda_pass_kernel_layers" in comp:
-            lines.append("Kimi-Delta-Attention layers whose convolution, gate and norms run as Pallas passes: %d"
-                         % comp["kda_pass_kernel_layers"])
-        if "moe_row_kernel_blocks" in comp:
-            lines.append("routed blocks whose rows move through the Pallas row movers: %d"
-                         % comp["moe_row_kernel_blocks"])
-        if "expert_window_rows" in comp:
-            lines.append("rows of the window a share's experts work on (0: the whole range): %d"
-                         % comp["expert_window_rows"])
-        if "shortconv_layers" in comp:
-            lines.append("layers whose token mixer is a gated short convolution: %d" % comp["shortconv_layers"])
+        for part, took in sorted(comp.get("forms", {}).items()):
+            lines.append("%s: %s" % (part, ", ".join("%s x %d" % (form, n) for form, n in sorted(took.items()))))
         if "mamba_layers" in comp:
             lines.append("layers whose token mixer is a Mamba-1 selective scan: %d" % comp["mamba_layers"])
-        if "selscan_kernel_layers" in comp:
-            lines.append("Mamba-1 layers whose selective scan runs as Pallas kernels: %d" % comp["selscan_kernel_layers"])
         if "shared_readers" in comp:
             lines.append("layers that read a tensor an earlier layer published (a memory, keys and values): %d"
                          % comp["shared_readers"])
-        if "window_kernel_layers" in comp:
-            lines.append("window attention layers whose band runs as Pallas kernels: %d" % comp["window_kernel_layers"])
-        if "window_kernel_layers" in comp and "window_operands_as_projected" in comp:
-            lines.append("window attention layers whose kernels read q as projected, rope and gate fused: %d"
-                         % comp["window_operands_as_projected"])
-        if "kernel_grads_relaid" in comp:
-            lines.append("gated kernels whose gradient is relaid to the state's layout: %d" % comp["kernel_grads_relaid"])
-        if comp.get("table_rows_over_dp"):
-            lines.append("the token table stays split over dp: its lookup sends ids, rows and cotangents")
-        if comp.get("vocab_split_axes"):
-            lines.append("the pipeline's vocabulary layers are stored and computed split over: %s"
-                         % ", ".join(comp["vocab_split_axes"]))
-        if comp.get("scan_grads_in_zero_layout"):
-            lines.append("stacked leaves of scanned runs whose gradient is summed into ZeRO's shards: %d"
-                         % comp["scan_grads_in_zero_layout"])
         if "dp_grad_all_reduce_mb" in comp:
             lines.append("a scanned layer's weight gradients over dp, MB a chip: %s all-reduced, %s reduce-scattered"
                          % (_fmt(comp["dp_grad_all_reduce_mb"]), _fmt(comp.get("dp_grad_reduce_scatter_mb"))))

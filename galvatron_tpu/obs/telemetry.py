@@ -95,16 +95,13 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
          "activation"),
     ),
     # one-off program build cost + the compiler-reported working set the
-    # MemoryCostModel prediction is checked against
+    # MemoryCostModel prediction is checked against; `forms`: which form each
+    # part of the step took as it was traced, part -> form -> count (obs/forms.py)
     "compile": (
         (),
         ("trace_ms", "compile_ms", "compiled_memory_mb", "xla_flops_per_step",
-         "cache_hit", "linear_kernel_layers", "linear_pass_kernel_layers",
-         "kda_kernel_layers", "kda_pass_kernel_layers", "moe_row_kernel_blocks", "expert_window_rows",
-         "shortconv_layers", "kernel_grads_relaid", "window_kernel_layers",
-         "window_operands_as_projected", "table_rows_over_dp", "vocab_split_axes",
-         "scan_grads_in_zero_layout", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb",
-         "mamba_layers", "shared_readers", "selscan_kernel_layers"),
+         "cache_hit", "forms", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb",
+         "mamba_layers", "shared_readers"),
     ),
     # where the start went, once the first step has drained (obs/launch.py):
     # `launch_ms` the phases of cli/train._train by name (obs/tracing.py's

@@ -26,25 +26,18 @@ and the head's gate in the kernels where the caller hands them on
 
 from __future__ import annotations
 
-import collections
 import logging
-import math
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
+from galvatron_tpu.obs import forms
 from galvatron_tpu.ops import window_attention
+from galvatron_tpu.ops.kernels import KernelSharding, lies_on_tpu
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
-
-# how many windowed attention calls were traced in each form since the process
-# began ("window_pallas" | "window_xla"; "window_as_projected": those of the
-# first that read q where the projection wrote it), as `linear_attention.TOOK`;
-# the trainer's compile report reads the difference (`window_kernel_layers`,
-# `window_operands_as_projected`)
-TOOK = collections.Counter()
 
 
 def repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
@@ -128,38 +121,6 @@ def _pallas_window(q, k, v, *, window: int, sm_scale: float, q_rope=None, head_g
     q3, k3, v3 = (t.reshape(t.shape[0], t.shape[1], -1) for t in (q, k, v))
     return window_attention.window_attention(q3, k3, v3, q_rope, head_gate, window, sm_scale, block,
                                              q.shape[3]).reshape(q.shape)
-
-
-class KernelSharding(NamedTuple):
-    """How attention's (B, S, nh, hd) operands are laid out over a mesh:
-    batch over ``batch_axes``, heads over ``head_axes``, sequence and head_dim
-    whole on every device. GSPMD cannot partition a Mosaic kernel, so on a
-    mesh of more than one device the flash kernel runs inside a
-    `jax.shard_map` — each device runs the kernel on its own batch rows and
-    heads, with no collective. The region is manual over EVERY mesh axis
-    (Mosaic refuses a kernel while any axis is left auto): the remaining
-    axis is 'pp', which is either size 1, already manual in the enclosing
-    1F1B schedule, or the stage dim the GPipe engine maps with
-    ``jax.vmap(spmd_axis_name='pp')``, which puts it into these specs."""
-
-    mesh: Mesh
-    batch_axes: Tuple[str, ...] = ()
-    head_axes: Tuple[str, ...] = ()
-
-    @classmethod
-    def for_layer(cls, mesh: Mesh, axes) -> "KernelSharding":
-        """The layout of one layer's attention operands (axes: LayerAxes):
-        batch over its dp axes, heads over its tp axes."""
-        return cls(mesh, tuple(axes.batch_axes), tuple(axes.tp))
-
-    @property
-    def on_tpu(self) -> bool:
-        return self.mesh.devices.flat[0].platform == "tpu"
-
-    def divides(self, batch: int, heads: int) -> bool:
-        shape = self.mesh.shape
-        return (batch % math.prod(shape[a] for a in self.batch_axes) == 0
-                and heads % math.prod(shape[a] for a in self.head_axes) == 0)
 
 
 def _sharded_kernel(kernel, q, k, v, sharding: KernelSharding, segment_ids=None, beside=()):
@@ -316,7 +277,7 @@ def core_attention(
         and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
     )
     # the pallas kernel is TPU-only
-    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
+    on_tpu = lies_on_tpu(sharding)
     if sharding is not None and sharding.mesh.size == 1:
         sharding = None  # one device: the kernel needs no manual region
     # under a manual region the kernel sees whole batch rows and heads only
@@ -379,8 +340,7 @@ def window_takes_kernels(q_shape, k_shape, *, window: int, biased: bool = False,
     device, and a query block that divides the sequence and reaches the window
     in a few key blocks (`window_attention.block_for`). What such a call may
     bring: `core_attention`'s `q_rope` and `head_gate`."""
-    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
-    return bool(impl != "xla" and on_tpu and q_shape[3] % 128 == 0 and not biased
+    return bool(impl != "xla" and lies_on_tpu(sharding) and q_shape[3] % 128 == 0 and not biased
                 and _whole_heads_a_device(sharding, q_shape, k_shape)
                 and window_attention.block_for(q_shape[1], window) > 0)
 
@@ -391,9 +351,10 @@ def _windowed(q, k, v, *, window: int, causal: bool, sm_scale: float, bias, impl
     the window kernels (`_pallas_window`) wherever they have a form
     (`window_takes_kernels`); the band mask on XLA's logits everywhere else
     (the CPU; `impl="xla"`), said once a shape where a TPU takes it at a
-    tileable length. Decided by what the call observes, counted in `TOOK`:
-    "window_pallas" or "window_xla" a call, and "window_as_projected" beside
-    the first where q came unturned with its tables (`q_rope`)."""
+    tileable length. Decided by what the call observes and said to `obs/forms`:
+    `WINDOW_ATTENTION`'s "pallas" or "xla" a call, and `WINDOW_OPERANDS`'s
+    "as_projected" beside the first where q came unturned with its tables
+    (`q_rope`)."""
     if not causal or q.shape[1] != k.shape[1] or window < 1:
         raise ValueError("a window of %d keys is causal self-attention's (query i sees keys i - window < j <= i); "
                          "got causal=%s, %d queries on %d keys" % (window, causal, q.shape[1], k.shape[1]))
@@ -404,17 +365,16 @@ def _windowed(q, k, v, *, window: int, causal: bool, sm_scale: float, bias, impl
     if not kernel and (q_rope is not None or head_gate is not None):
         raise ValueError("q_rope and head_gate ride the window kernels alone: ask `window_takes_kernels` first, "
                          "and turn q and multiply by the gate around a call it refuses")
-    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
-    if on_tpu and q.shape[1] % 128 == 0 and impl == "auto" and not kernel:
+    if lies_on_tpu(sharding) and q.shape[1] % 128 == 0 and impl == "auto" and not kernel:
         _say_fallback_once(q.shape, k.shape[1], bias is not None, _whole_heads_a_device(sharding, q.shape, k.shape),
                            window)
-    TOOK["window_pallas" if kernel else "window_xla"] += 1
+    forms.took(forms.WINDOW_ATTENTION, "pallas" if kernel else "xla")
     if not kernel:
         n_rep = q.shape[2] // k.shape[2]
         return _xla_attention(q, repeat_kv(k, n_rep), repeat_kv(v, n_rep), causal=True, sm_scale=sm_scale,
                               bias=bias, window=window)
     if q_rope is not None:
-        TOOK["window_as_projected"] += 1
+        forms.took(forms.WINDOW_OPERANDS, "as_projected")
     if sharding is None or sharding.mesh.size == 1:  # one device: the kernels need no manual region
         return _pallas_window(q, k, v, window=window, sm_scale=sm_scale, q_rope=q_rope, head_gate=head_gate)
     bd, hd = sharding.batch_axes or None, sharding.head_axes or None

@@ -138,14 +138,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from galvatron_tpu.obs import tracing
-from galvatron_tpu.ops.attention import KernelSharding
+from galvatron_tpu.obs import forms, tracing
+from galvatron_tpu.ops.kernels import (NN, NT, TILE, TN, KernelSharding, dot, on_kernels, rows_a_device,
+                                       traced_once)
 
 CHUNK = 64
-# how many calls of `gated_delta_rule` took which form since the process began,
-# counted as they are traced: the trainer's compile report reads the difference
-# (and "short_conv": the mixers that are `causal_conv` between two gates)
-TOOK = collections.Counter()
 STARTS = "gdn_chunk_starts"  # the residual a head's backward keeps
 _BASE = 16  # the diagonal blocks inverted by forward substitution
 _F32 = jnp.float32
@@ -328,24 +325,9 @@ def _xla_rule(q, k, v, g, beta, chunk):
 # state in VMEM scratch from the step before; nothing of a tile but `T` and
 # the state it started from (both only for the backward) is written to HBM.
 
-TILE = 2 * CHUNK
 _ROWS = 8  # a tile's per-token scalars, rows of one float32 (8, 128) (`_scalars`)
 _BLOCK = 8  # tiles a grid step walks where its operands are 2 bytes wide
 _VMEM = 64 * 2**20  # what a kernel may hold of the chip's 128 MiB: a block's tiles' matrices at once
-_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))  # a product's contracted axes
-
-
-def _dot(a, b, dims):
-    """A product accumulated in float32, over a leading batch axis where the
-    operands have one; float32 operands are multiplied as float32 (Mosaic's
-    `contract_precision<fp32>`), as `_mm` does. `dims`: the contracted axes
-    of a matrix, `_NN`, `_NT` or `_TN`."""
-    exact = a.dtype == _F32 or b.dtype == _F32
-    batched = a.ndim == 3
-    contract = tuple((axis + batched,) for (axis,) in dims)
-    return jax.lax.dot_general(a, b, (contract, (((0,), (0,)) if batched else ((), ()))),
-                               preferred_element_type=_F32,
-                               precision=jax.lax.Precision.HIGHEST if exact else None)
 
 
 def _iota(shape, axis):
@@ -390,8 +372,8 @@ def _inverses(a, inv):
     size = _BASE
     for below in inv["below"]:
         lower_halves = jnp.concatenate(pieces[1::2], axis=1)
-        moved = _dot(_dot(lower_halves, jnp.where(below, a, 0.0), _NN),
-                     jnp.concatenate(pieces, axis=1), _NN)
+        moved = dot(dot(lower_halves, jnp.where(below, a, 0.0), NN),
+                    jnp.concatenate(pieces, axis=1), NN)
         pieces = [jnp.concatenate([pieces[2 * b], pieces[2 * b + 1] - moved[:, b * size:(b + 1) * size]],
                                   axis=1) for b in range(len(pieces) // 2)]
         size *= 2
@@ -407,7 +389,7 @@ def _columns(rows, inv):
     identity is the transpose (exact: one factor is 1, the others 0), one for
     the block."""
     n = rows.shape[0]
-    cols = _dot(inv["eye"], rows.reshape(n * _ROWS, TILE), _NT)
+    cols = dot(inv["eye"], rows.reshape(n * _ROWS, TILE), NT)
     return jnp.stack([cols[:, tile * _ROWS:(tile + 1) * _ROWS] for tile in range(n)])
 
 
@@ -422,12 +404,12 @@ def _local(q, k, v, rows, inv, inverse=None):
     from_start = jnp.exp(g_col)
     k32 = k.astype(_F32)
     decay = jnp.exp(jnp.where(inv["lower"], g_col - g_row, -jnp.inf))
-    kk = _dot(k, k, _NT)
+    kk = dot(k, k, NT)
     if inverse is None:
         inverse = _inverses(jnp.where(inv["strict"], beta * decay * kk, 0.0), inv)
     rhs = jnp.concatenate([k32 * (beta * from_start), v.astype(_F32) * beta], axis=2)  # [Rw | Ru]
     return dict(beta=beta, from_start=from_start, to_end=jnp.exp(g_end - g_col), decay=decay,
-                kk=kk, qk=_dot(q, k, _NT), inverse=inverse, rhs=rhs, wu=_dot(inverse, rhs, _NN),
+                kk=kk, qk=dot(q, k, NT), inverse=inverse, rhs=rhs, wu=dot(inverse, rhs, NN),
                 k32=k32)
 
 
@@ -451,9 +433,9 @@ def _walk(here, dk, keeps, s_ref, starts_ref, wu_ref, kd_ref, u_ref):
     def tile(i, carry):
         state = s_ref[...]
         starts_ref[i] = state
-        u = wu_ref[i][:, dk:] - _dot(wu_ref[i][:, :dk], state, _NN)  # U0 - W S_0
+        u = wu_ref[i][:, dk:] - dot(wu_ref[i][:, :dk], state, NN)  # U0 - W S_0
         u_ref[i] = u
-        s_ref[...] = keeps(i, state) * state + _dot(kd_ref[i], u, _TN)
+        s_ref[...] = keeps(i, state) * state + dot(kd_ref[i], u, TN)
         return carry
 
     jax.lax.fori_loop(0, here, tile, None)
@@ -467,9 +449,9 @@ def _walk_back(here, keeps, ds_ref, dends_ref, fo_ref, kd_ref, du_ref, qgdo_ref,
         i = here - 1 - j
         dstate = ds_ref[...]
         dends_ref[i] = dstate
-        du = fo_ref[i] + _dot(kd_ref[i], dstate, _NN)
+        du = fo_ref[i] + dot(kd_ref[i], dstate, NN)
         du_ref[i] = du
-        ds_ref[...] = keeps(i, dstate) * dstate + qgdo_ref[i] - _dot(w_ref[i], du, _TN)
+        ds_ref[...] = keeps(i, dstate) * dstate + qgdo_ref[i] - dot(w_ref[i], du, TN)
         return carry
 
     jax.lax.fori_loop(0, here, tile, None)
@@ -502,8 +484,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, block, tiles, keep)
     _walk(_here(step, block, tiles), dk, lambda i, state: _keeps(rows_ref, i, state.shape[1]),
           s_ref, starts_ref, wu_ref, kd_ref, u_ref)
     p = (m["decay"] * m["qk"]).astype(dt)
-    o = (_dot((q.astype(_F32) * m["from_start"]).astype(dt), starts_ref[...].astype(dt), _NN)
-         + _dot(p, u_ref[...].astype(dt), _NN))
+    o = (dot((q.astype(_F32) * m["from_start"]).astype(dt), starts_ref[...].astype(dt), NN)
+         + dot(p, u_ref[...].astype(dt), NN))
     o_ref[...] = o.reshape(o_ref.shape).astype(dt)
 
     @pl.when(step == pl.num_programs(2) - 1)
@@ -540,9 +522,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, starts_ref, t_ref, do_ref, dlast_
     kd = k32 * to_end
     p = (decay * m["qk"]).astype(dt)
     w = m["wu"][:, :, :dk]
-    u = m["wu"][:, :, dk:] - _dot(w, states, _NN)
-    fo_ref[...] = _dot(p, do, _TN)  # P^T do
-    qgdo_ref[...] = _dot(qg32.astype(dt), do, _TN)
+    u = m["wu"][:, :, dk:] - dot(w, states, NN)
+    fo_ref[...] = dot(p, do, TN)  # P^T do
+    qgdo_ref[...] = dot(qg32.astype(dt), do, TN)
     kd_ref[...] = kd
     w_ref[...] = w
 
@@ -565,19 +547,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, starts_ref, t_ref, do_ref, dlast_
         return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=2, keepdims=True)
 
     du, dends = du_ref[...], dends_ref[...]
-    dkd = _dot(u, dends, _NT)
-    dqg = _dot(do, states.astype(dt), _NT)
-    dp = _dot(do, u.astype(dt), _NT)
-    dwu = jnp.concatenate([-_dot(du, states, _NT), du], axis=2)  # [dW | dU0]
-    drhs = _dot(inverse, dwu, _TN)
+    dkd = dot(u, dends, NT)
+    dqg = dot(do, states.astype(dt), NT)
+    dp = dot(do, u.astype(dt), NT)
+    dwu = jnp.concatenate([-dot(du, states, NT), du], axis=2)  # [dW | dU0]
+    drhs = dot(inverse, dwu, TN)
     da = jnp.where(inv["strict"],
-                   -_dot(_dot(inverse, _dot(dwu, rhs, _NT), _TN), inverse, _NT), 0.0)
+                   -dot(dot(inverse, dot(dwu, rhs, NT), TN), inverse, NT), 0.0)
     drw, dru = drhs[:, :, :dk], drhs[:, :, dk:]
     dqk = (dp * decay).astype(dt)
     dkk = da * (beta * decay)  # k k^T reads k on both sides
-    dq_ref[...] = (dqg * from_start + _dot(dqk, k, _NN)).reshape(dq_ref.shape).astype(dq_ref.dtype)
-    dk_ref[...] = (drw * (beta * from_start) + dkd * to_end + _dot(dqk, q, _TN)
-                   + _dot(dkk, k32, _NN) + _dot(dkk, k32, _TN)).reshape(dk_ref.shape).astype(dk_ref.dtype)
+    dq_ref[...] = (dqg * from_start + dot(dqk, k, NN)).reshape(dq_ref.shape).astype(dq_ref.dtype)
+    dk_ref[...] = (drw * (beta * from_start) + dkd * to_end + dot(dqk, q, TN)
+                   + dot(dkk, k32, NN) + dot(dkk, k32, TN)).reshape(dk_ref.shape).astype(dk_ref.dtype)
     dv_ref[...] = (dru * beta).reshape(dv_ref.shape).astype(dv_ref.dtype)
     # beta and G: the sums over a token's row, and D's two sides
     dbeta = (rows_sum(dru * v.astype(_F32)) + rows_sum(drw * k32) * from_start
@@ -756,8 +738,8 @@ def _kernel_form(q, k, v, g, beta):
 
 
 def _sharded_kernel_form(q, k, v, g, beta, sharding: Optional[KernelSharding]):
-    """`_kernel_form` a device on its own rows of the batch (`_rows_a_device`)."""
-    return _rows_a_device(_kernel_form, sharding, (q, k, v, g, beta), (), (4, 4))
+    """`_kernel_form` a device on its own rows of the batch (`kernels.rows_a_device`)."""
+    return rows_a_device(_kernel_form, sharding, (q, k, v, g, beta), (), (4, 4))
 
 
 # --- the per-channel rule, the kernel form ----------------------------------
@@ -855,15 +837,15 @@ def _kda_local(q, k, v, g, rows, inv, sums, pairs, inverse=None, kk=None):
     n, dt, dv = g.shape[0], v.dtype, v.shape[2]
     beta = _columns(rows, inv)[:, :, 1:2]
     parts = _thirds(g)
-    e = jnp.exp(jnp.stack([_whole(_dot(sums, parts[tile], _NN)) for tile in range(n)]))
+    e = jnp.exp(jnp.stack([_whole(dot(sums, parts[tile], NN)) for tile in range(n)]))
     k32, q32 = k.astype(_F32), q.astype(_F32)
     qk = inv["eye"] * jnp.sum(q32 * k32, axis=2, keepdims=True)  # t = j: no decay
     made = jnp.zeros((n, TILE, TILE), _F32) if kk is None else None
     for l, pair in enumerate(pairs):
         ke = k32 * _level(e, l)
-        qk = qk + jnp.where(pair, _dot((q32 * _level(e, l)).astype(dt), ke.astype(dt), _NT), 0.0)
+        qk = qk + jnp.where(pair, dot((q32 * _level(e, l)).astype(dt), ke.astype(dt), NT), 0.0)
         if kk is None:
-            made = made + jnp.where(pair, _dot(ke, ke, _NT), 0.0)
+            made = made + jnp.where(pair, dot(ke, ke, NT), 0.0)
     kk = made if kk is None else kk
     from_start, to_end = _level(e, len(pairs)), _level(e, len(pairs) + 1)
     if inverse is None:
@@ -871,9 +853,9 @@ def _kda_local(q, k, v, g, rows, inv, sums, pairs, inverse=None, kk=None):
     rhs = jnp.concatenate([k32 * (beta * from_start), v.astype(_F32) * beta], axis=2)  # [Rw | Ru]
     # e^{G_C} as the state's rows want it, (n, d_k, d_v): g's sum over a tile's tokens in every column
     ones, dk = jnp.ones((n, TILE, dv), jnp.bfloat16), g.shape[2]
-    keep = jnp.exp(sum(_dot(parts[:, :, at:at + dk], ones, _TN) for at in range(0, 3 * dk, dk)))
+    keep = jnp.exp(sum(dot(parts[:, :, at:at + dk], ones, TN) for at in range(0, 3 * dk, dk)))
     return dict(beta=beta, e=e, from_start=from_start, to_end=to_end, kk=kk, qk=qk, inverse=inverse,
-                rhs=rhs, wu=_dot(inverse, rhs, _NN), k32=k32, q32=q32, keep=keep)
+                rhs=rhs, wu=dot(inverse, rhs, NN), k32=k32, q32=q32, keep=keep)
 
 
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, o_ref, *rest, block, tiles, keep):
@@ -901,8 +883,8 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, o_ref, *rest, block, t
         s_ref[...] = jnp.zeros_like(s_ref)
 
     _walk(_here(step, block, tiles), dk, lambda i, _: keep_ref[i], s_ref, starts_ref, wu_ref, kd_ref, u_ref)
-    o = (_dot((m["q32"] * m["from_start"]).astype(dt), starts_ref[...].astype(dt), _NN)
-         + _dot(m["qk"].astype(dt), u_ref[...].astype(dt), _NN))
+    o = (dot((m["q32"] * m["from_start"]).astype(dt), starts_ref[...].astype(dt), NN)
+         + dot(m["qk"].astype(dt), u_ref[...].astype(dt), NN))
     o_ref[...] = o.reshape(o_ref.shape).astype(dt)
 
     @pl.when(step == pl.num_programs(2) - 1)
@@ -935,9 +917,9 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, t_ref, kk_
     qg32 = q32 * from_start
     kd = k32 * to_end
     w = m["wu"][:, :, :dk]
-    u = m["wu"][:, :, dk:] - _dot(w, states, _NN)
-    fo_ref[...] = _dot(m["qk"].astype(dt), do, _TN)  # P^T do
-    qgdo_ref[...] = _dot(qg32.astype(dt), do, _TN)
+    u = m["wu"][:, :, dk:] - dot(w, states, NN)
+    fo_ref[...] = dot(m["qk"].astype(dt), do, TN)  # P^T do
+    qgdo_ref[...] = dot(qg32.astype(dt), do, TN)
     kd_ref[...] = kd
     w_ref[...] = w
     keep_ref[...] = m["keep"]
@@ -954,13 +936,13 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, t_ref, kk_
         return jnp.sum(x, axis=2, keepdims=True)
 
     du, dends = du_ref[...], dends_ref[...]
-    dkd = _dot(u, dends, _NT)
-    dqg = _dot(do, states.astype(dt), _NT)
-    dp = _dot(do, u.astype(dt), _NT)
-    dwu = jnp.concatenate([-_dot(du, states, _NT), du], axis=2)  # [dW | dU0]
-    drhs = _dot(inverse, dwu, _TN)
+    dkd = dot(u, dends, NT)
+    dqg = dot(do, states.astype(dt), NT)
+    dp = dot(do, u.astype(dt), NT)
+    dwu = jnp.concatenate([-dot(du, states, NT), du], axis=2)  # [dW | dU0]
+    drhs = dot(inverse, dwu, TN)
     da = jnp.where(inv["strict"],
-                   -_dot(_dot(inverse, _dot(dwu, rhs, _NT), _TN), inverse, _NT), 0.0)
+                   -dot(dot(inverse, dot(dwu, rhs, NT), TN), inverse, NT), 0.0)
     drw, dru = drhs[:, :, :dk], drhs[:, :, dk:]
     dkk = da * beta
     diagonal = rows_sum(dp * inv["eye"])  # qk's t = j
@@ -974,17 +956,17 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, t_ref, kk_
         # the pair's later tokens read `dkk`'s rows, its earlier tokens the columns: one product
         both = jnp.where(pair, dkk, 0.0) + jnp.where(pair_t, dkk_t, 0.0)
         dqk_l = jnp.where(pair, dp, 0.0).astype(dt)
-        dke = _dot(both, ke, _NN) + _dot(dqk_l, qe.astype(dt), _TN)
-        dqe = _dot(dqk_l, ke.astype(dt), _NN)
+        dke = dot(both, ke, NN) + dot(dqk_l, qe.astype(dt), TN)
+        dqe = dot(dqk_l, ke.astype(dt), NN)
         dq = dq + dqe * scale
         dk_ = dk_ + dke * scale
         dx.append(dke * ke + dqe * qe)
     dx += [dqg * qg32 + drw * rhs[:, :, :dk], dkd * kd]  # e^G's, e^{G_C - G}'s
     dx = jnp.concatenate(dx, axis=1)
     # e^{G_C} scales the state's rows: its exponent is the sum of ALL the tile's g
-    dend = _dot(jnp.ones((n, _ROWS, dv), _F32), dends * states * m["keep"], _NT)[:, 0:1, :]
+    dend = dot(jnp.ones((n, _ROWS, dv), _F32), dends * states * m["keep"], NT)[:, 0:1, :]
     dx = _thirds(dx)
-    dg = jnp.stack([_whole(_dot(sums_t, dx[tile], _NN)) for tile in range(n)]) + dend
+    dg = jnp.stack([_whole(dot(sums_t, dx[tile], NN)) for tile in range(n)]) + dend
     dq_ref[...] = dq.reshape(dq_ref.shape).astype(dq_ref.dtype)
     dk_ref[...] = dk_.reshape(dk_ref.shape).astype(dk_ref.dtype)
     dv_ref[...] = (dru * beta).reshape(dv_ref.shape).astype(dv_ref.dtype)
@@ -993,21 +975,8 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, t_ref, kk_
     drows_ref[:, 1:2, :] = jnp.sum(dbeta * inv["eye"], axis=1, keepdims=True)
 
 
-def _traced_once(*static):
-    """A caller of kernels -> one traced, and lowered, once for each value of
-    its `static` arguments, its operands' shapes and the passes' tile sizes. A
-    step calls it with the same shapes in every run of layers, in the first
-    forward and in the recomputation, and tracing a kernel's unrolled body
-    again each time is what a start pays for the kernels: 0.6 s a call on the
-    chip's host, 28 s a start of the Kimi cell (PERF.md section 6, PR 44)."""
-    def wrap(fn):
-        def once(sizes, *args):
-            return fn(*args)
-
-        once.__name__ = fn.__name__
-        jitted = jax.jit(once, static_argnums=(0,) + tuple(i + 1 for i in static))
-        return functools.wraps(fn)(lambda *args: jitted((_TOKENS, _LANES, _AT_ONCE), *args))
-    return wrap
+# (the trace reads the passes' tile sizes, which scripts/linear_passes_sweep.py sets)
+_traced_once = functools.partial(traced_once, sizes=lambda: (_TOKENS, _LANES, _AT_ONCE))
 
 
 def _beta_rows(beta):
@@ -1385,7 +1354,7 @@ Segment = collections.namedtuple("Segment", "start width d lanes scale serves")
 # What the passes are told of the mixer that calls them: its heads, q's, k's
 # and v's columns of the projection's output, z's columns of the array the
 # output gate lies in, the gate's activation (a key of `_GATES`) and the names
-# `TOOK` counts the passes under.
+# (obs/forms.py's parts) the passes are counted under.
 Layout = collections.namedtuple("Layout", "heads qkv z gate counted")
 
 
@@ -1403,7 +1372,7 @@ def linear_layout(heads: Heads) -> Layout:
     serving value / key heads, SiLU(z) the output gate."""
     qkv = _qkv(heads)
     v = qkv[2]  # z lies behind v, as wide and cut into the same blocks
-    return Layout(heads, qkv, v._replace(start=v.start + v.width), "silu", ("conv_norm", "gated_norm"))
+    return Layout(heads, qkv, v._replace(start=v.start + v.width), "silu", (forms.CONV_NORM, forms.GATED_NORM))
 
 
 def kda_layout(heads: Heads) -> Layout:
@@ -1411,7 +1380,7 @@ def kda_layout(heads: Heads) -> Layout:
     gate an array of its own, sigmoid(z); and the per-channel gate's pass."""
     values = heads.value_heads * heads.d_v
     return Layout(heads, _qkv(heads), Segment(0, values, heads.d_v, _lanes(heads.d_v, values), None, 1),
-                  "sigmoid", ("kda_conv_norm", "kda_gate", "kda_gated_norm"))
+                  "sigmoid", (forms.KDA_CONV_NORM, forms.KDA_GATE, forms.KDA_GATED_NORM))
 
 
 def _taps_rows(taps):  # (channels, K) -> (_TAPS, channels) float32, a tap a row
@@ -1648,17 +1617,6 @@ def _kda_kernel_mixer_bwd(layout, eps, residuals, cotangents):
 _kda_kernel_mixer.defvjp(_kda_kernel_mixer_fwd, _kda_kernel_mixer_bwd)
 
 
-def _on_kernels(sharding, batch, fits):
-    """("pallas" | "xla", the sharding a manual region needs or None): the
-    kernels where the operands lie on TPUs, the shapes fit and the call sits on
-    one device or, with `sharding`, on whole rows of the batch a device with
-    all heads on each."""
-    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
-    if sharding is not None and sharding.mesh.size == 1:
-        sharding = None  # one device: the kernels need no manual region
-    return on_tpu and fits and (sharding is None or sharding.divides(batch, 1)), sharding
-
-
 def mixer_form(x: jax.Array, taps: jax.Array, layout: Layout, *, impl: str = "auto",
                sharding: Optional[KernelSharding] = None) -> str:
     """The form the passes around the core take for this projection's output
@@ -1669,38 +1627,17 @@ def mixer_form(x: jax.Array, taps: jax.Array, layout: Layout, *, impl: str = "au
     core would take its own (TPUs, heads multiples of 128 wide, one device or
     whole rows of the batch a device) and the sequence is a multiple of the
     passes' smallest tile of tokens, the taps at most `_TAPS` and a block of
-    whole heads divides every segment. Counted in `TOOK`, a pass a key
+    whole heads divides every segment. Said to `obs/forms`, a pass a part
     (`layout.counted`)."""
     if impl == "auto":
         heads = layout.heads
         fits = (heads.d_k % TILE == 0 and heads.d_v % TILE == 0 and _tokens(x.shape[1]) is not None
                 and taps.shape[1] <= _TAPS and heads.value_heads % heads.key_heads == 0
                 and all(seg.lanes is not None for seg in layout.qkv + (layout.z,)))
-        impl = "pallas" if _on_kernels(sharding, x.shape[0], fits)[0] else "xla"
-    for name in layout.counted:
-        TOOK[name + "_" + impl] += 1
+        impl = "pallas" if on_kernels(sharding, x.shape[0], fits)[0] else "xla"
+    for part in layout.counted:
+        forms.took(part, impl)
     return impl
-
-
-def _rows_a_device(rule, sharding, operands, weights, out_ranks):
-    """`rule(*operands)` a device on its own rows of the batch, under a manual
-    region (GSPMD cannot partition a Mosaic kernel; see `KernelSharding` and
-    `ops/attention._sharded_kernel`, whose pattern this is); as it is
-    where `_on_kernels` found one device (`sharding` None). `weights`: which
-    operands lie whole on every device; `out_ranks`: the results' ranks, each
-    over the batch."""
-    if sharding is None:
-        return rule(*operands)
-    rows = sharding.batch_axes or None
-    ctx = jax.sharding.get_abstract_mesh()
-    use_mesh = sharding.mesh if ctx.empty else ctx
-    return jax.shard_map(
-        rule, mesh=use_mesh,
-        in_specs=tuple(P(*(None,) * x.ndim) if i in weights else P(rows, *(None,) * (x.ndim - 1))
-                       for i, x in enumerate(operands)),
-        out_specs=tuple(P(rows, *(None,) * (rank - 1)) for rank in out_ranks),
-        axis_names=set(use_mesh.axis_names) - set(use_mesh.manual_axes), check_vma=False,
-    )(*operands)
 
 
 def kernel_mixer(qkvz: jax.Array, taps: jax.Array, scale: jax.Array, g: jax.Array, beta: jax.Array,
@@ -1712,9 +1649,9 @@ def kernel_mixer(qkvz: jax.Array, taps: jax.Array, scale: jax.Array, g: jax.Arra
     beta (B, S, Hv) float32 -> RMSNorm(o) x SiLU(z) (B, S, Hv d_v) in qkvz's
     dtype and the final states (B, Hv, d_k, d_v) float32. Its ops carry
     `gt.attn.linear` or, the core's, `gt.attn.delta`: call it under neither."""
-    TOOK["pallas"] += 1  # the core's form
-    return _rows_a_device(functools.partial(_kernel_mixer, layout, eps), _on_kernels(sharding, qkvz.shape[0], True)[1],
-                          (qkvz, taps, scale, g, beta), (1, 2), (3, 4))
+    forms.took(forms.DELTA_RULE, "pallas")  # the core's form
+    return rows_a_device(functools.partial(_kernel_mixer, layout, eps), on_kernels(sharding, qkvz.shape[0], True)[1],
+                         (qkvz, taps, scale, g, beta), (1, 2), (3, 4))
 
 
 def kda_kernel_mixer(qkv: jax.Array, taps: jax.Array, scale: jax.Array, f: jax.Array, dt_bias: jax.Array,
@@ -1729,10 +1666,10 @@ def kda_kernel_mixer(qkv: jax.Array, taps: jax.Array, scale: jax.Array, f: jax.A
     states (B, H, d_k, d_v) float32 and the mean of exp(g) a row of the batch
     (a counter: no gradient). Its ops carry `gt.attn.kda_mixer` or, the
     core's, `gt.attn.kda_rule`: call it under neither."""
-    TOOK["kda_pallas"] += 1  # the core's form
-    return _rows_a_device(functools.partial(_kda_kernel_mixer, layout, eps),
-                          _on_kernels(sharding, qkv.shape[0], True)[1],
-                          (qkv, taps, scale, f, dt_bias, a_log, z, beta), (1, 2, 4, 5), (3, 4, 1))
+    forms.took(forms.KDA_RULE, "pallas")  # the core's form
+    return rows_a_device(functools.partial(_kda_kernel_mixer, layout, eps),
+                         on_kernels(sharding, qkv.shape[0], True)[1],
+                         (qkv, taps, scale, f, dt_bias, a_log, z, beta), (1, 2, 4, 5), (3, 4, 1))
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
@@ -1754,11 +1691,11 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, bet
     if s % chunk:
         raise ValueError("gated_delta_rule: a sequence of %d tokens is no multiple of the "
                          "chunk of %d" % (s, chunk))
-    kernels, sharding = _on_kernels(
+    kernels, sharding = on_kernels(
         sharding, v.shape[0], chunk == CHUNK and q.shape[3] % TILE == 0 and v.shape[3] % TILE == 0)
     if impl == "auto":
         impl = "pallas" if kernels else "xla"
-    TOOK[impl] += 1
+    forms.took(forms.DELTA_RULE, impl)
     if impl == "xla":
         return _xla_rule(q, k, v, g, beta, chunk)
     return _sharded_kernel_form(q, k, v, g, beta, sharding)
@@ -1781,13 +1718,13 @@ def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
     (`sharding`'s mesh says so; with none, the default backend), d_k and d_v
     are multiples of 128 and the call sits on one device or, with `sharding`,
     on whole rows of the batch a device; everything else, the CPU among it,
-    the XLA form. Counted in `TOOK` as "kda_pallas" / "kda_xla". Any length: a
+    the XLA form. Said to `obs/forms` as `KDA_RULE`'s "pallas" / "xla". Any length: a
     rest (of a tile; of `chunk` in the XLA form) is padded with tokens that
     neither forget nor write (g = beta = 0)."""
-    kernels, sharding = _on_kernels(sharding, v.shape[0], q.shape[3] % TILE == 0 and v.shape[3] % TILE == 0)
+    kernels, sharding = on_kernels(sharding, v.shape[0], q.shape[3] % TILE == 0 and v.shape[3] % TILE == 0)
     if impl == "auto":
         impl = "pallas" if kernels else "xla"
-    TOOK["kda_" + impl] += 1
+    forms.took(forms.KDA_RULE, impl)
     if impl == "pallas":
         return _sharded_kernel_form(q, k, v, g, beta, sharding)
     s = v.shape[1]

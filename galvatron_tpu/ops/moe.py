@@ -73,7 +73,6 @@ assignment's row, the combine's sum over k and both of their backwards.
 
 from __future__ import annotations
 
-import collections
 import functools
 import math
 from typing import Dict, NamedTuple, Optional, Tuple
@@ -84,8 +83,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from galvatron_tpu.obs import tracing
-from galvatron_tpu.ops.attention import KernelSharding
+from galvatron_tpu.obs import forms, tracing
+from galvatron_tpu.ops.kernels import KernelSharding, lies_on_tpu
 
 # load_max_over_mean always; load_balance, router_z (the softmax router's
 # losses); counts (E,) and bias_abs_max (a router with a bias); rows_held (a
@@ -203,16 +202,12 @@ PACK_TILE = 512  # rows a grid step of `moe_rows_pack`
 # the most a block may be for the movers to take it: every assignment's index
 # is prefetched into SMEM, 1 MiB on a v5e, of which these are three quarters;
 # hidden 8192 does not fit the packing pass's scoped VMEM, and nothing between
-# was measured (tests/ops/test_tpu_compile.py compiles the kernels AT the bounds)
+# was measured (tests/ops/test_tpu_compile_routed.py compiles the kernels AT the bounds)
 ROWS_MAX_ASSIGNMENTS = 196608
 ROWS_MAX_HIDDEN = 4096
 _GROUP = 16  # rows the arithmetic takes at a time: a bf16 tile's sublanes
 _LANES = 128
 _HIGH = 0xFFFF0000
-# how many routed blocks took which form of the row movers since the process
-# began, counted as they are traced: the trainer's compile report reads the
-# difference (as `linear_attention.TOOK`)
-ROWS_TOOK = collections.Counter()
 
 
 def rows_form(on_tpu: bool, dtype, hidden: int, tokens: int, k: int) -> str:
@@ -563,9 +558,6 @@ def router_logits(y: jax.Array, router_kernel: jax.Array) -> jax.Array:
 # `--checkpoint 1` the layer's recomputation then has nothing of the block
 # left to make; without it the block pays one more forward of its experts).
 WINDOW_OVER_EVEN = 1.5  # x `k x tokens x held / experts`; three cells' steps read 0.91 to 1.08 of it
-# rows of the window -> the blocks of a share traced with it since the process
-# began (0: no window), as `ROWS_TOOK`; the trainer's compile report reads it
-WINDOWS_TOOK = collections.Counter()
 
 
 def window_rows(assignments: int, num_experts: int, held: Optional[Tuple[int, int]]) -> int:
@@ -725,7 +717,7 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
     tokens = y.shape[0]
     num_experts = router_kernel.shape[-1]
     form = rows_form(on_tpu and y.dtype == dtype, y.dtype, y.shape[1], tokens, k)
-    ROWS_TOOK[form] += 1
+    forms.took(forms.MOE_ROWS, form)
     with jax.named_scope(tracing.MOE_ROUTER):
         logits = router_logits(y, router_kernel)
         if score == "softmax":
@@ -772,7 +764,7 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
         with jax.named_scope(tracing.MOE_COMBINE):
             out = _combine(form, out, weights, order, inv_order).astype(dtype)
     if held is not None:
-        WINDOWS_TOOK[length] += 1
+        forms.took(forms.EXPERT_WINDOW, length)  # (0: no window)
     with jax.named_scope(tracing.MOE_ROUTER):
         total = jnp.float32(tokens)
         counts_f = counts.astype(jnp.float32)
@@ -829,7 +821,7 @@ def moe_ffn(y: jax.Array, router_kernel: jax.Array, wi: jax.Array, wo: jax.Array
     statistics are summed over the batch axes; there is no expert
     parallelism here, so the experts' kernels enter the region whole."""
     b, s, h = y.shape
-    on_tpu = sharding.on_tpu if sharding is not None else jax.default_backend() == "tpu"
+    on_tpu = lies_on_tpu(sharding)
     kw = dict(k=experts_per_token, norm_topk_prob=norm_topk_prob, activate=activate,
               dtype=dtype, on_tpu=on_tpu, score=score, scale=scale, held=held)
     if sharding is None or sharding.mesh.size == 1:

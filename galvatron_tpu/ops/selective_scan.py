@@ -78,7 +78,6 @@ layers read `m` alone.
 
 from __future__ import annotations
 
-import collections
 import functools
 import operator
 from typing import Optional, Tuple
@@ -88,8 +87,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from galvatron_tpu.ops.attention import KernelSharding
-from galvatron_tpu.ops.linear_attention import _NN, _NT, TILE, _dot, _on_kernels, _rows_a_device, _traced_once
+from galvatron_tpu.obs import forms
+from galvatron_tpu.ops.kernels import NN, NT, TILE, KernelSharding, dot, on_kernels, rows_a_device, traced_once
 
 CHUNK = 128  # (scripts/selscan_sweep.py: 64, 128 and 256 lie within a tenth of one another; 128 holds the least)
 BLOCK = 8  # positions whose states the backward holds at once (divides the chunk, or the chunk is one block)
@@ -281,9 +280,6 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 CHANNELS = 512  # the channels a grid step holds (scripts/selscan_sweep.py)
 UNROLL = 8  # positions a trip of the kernels' loops
 _VMEM = 64 * 2**20  # what a kernel may hold of the chip's 128 MiB (the backward's chunk of states and decays is 8 MiB)
-# how many calls of `selective_scan` took which form since the process began,
-# counted as they are traced: the trainer's compile report reads the difference
-TOOK = collections.Counter()
 
 
 def _columns_to_lanes(rows_ref, out_ref):
@@ -296,7 +292,7 @@ def _columns_to_lanes(rows_ref, out_ref):
     n, t = rows.shape
     at = jax.lax.broadcasted_iota(jnp.int32, (t, n, t), 0) == jax.lax.broadcasted_iota(jnp.int32, (t, n, t), 2)
     alone = jnp.where(at, rows[None], 0.0).reshape(t * n, t)
-    out_ref[...] = _dot(alone, jnp.ones((t, out_ref.shape[2]), _F32), _NN).reshape(out_ref.shape)
+    out_ref[...] = dot(alone, jnp.ones((t, out_ref.shape[2]), _F32), NN).reshape(out_ref.shape)
 
 
 def _begin(bt_ref, ct_ref, b_scr, c_scr, carried):
@@ -347,7 +343,7 @@ def _over_tiles(prod, lanes):
 def _lane_sums(parts_ref):
     """(rows, 128) float32 -> (1, rows): every row summed over its lanes, a
     product with ones on the MXU (idle here), float32 throughout."""
-    return _dot(jnp.ones((8, parts_ref.shape[1]), _F32), parts_ref[...], _NT)[0:1]
+    return dot(jnp.ones((8, parts_ref.shape[1]), _F32), parts_ref[...], NT)[0:1]
 
 
 def _sums_in_the_chunk(dt_ref):
@@ -355,7 +351,7 @@ def _sums_in_the_chunk(dt_ref):
     product with a triangle of ones on the MXU (idle here), float32."""
     t = dt_ref.shape[0]
     lower = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) >= jax.lax.broadcasted_iota(jnp.int32, (t, t), 1))
-    return _dot(lower.astype(_F32), dt_ref[...], _NN)
+    return dot(lower.astype(_F32), dt_ref[...], NN)
 
 
 def _fwd_kernel(x_ref, dt_ref, bt_ref, ct_ref, a_ref, d_ref, m_ref, end_ref, b_scr, c_scr, u_scr, y_scr, left_scr,
@@ -497,7 +493,7 @@ def _whole_chunks(chunk, *ts):
     return chunk, tuple(jnp.pad(t, ((0, 0), (0, -s % chunk), (0, 0))) for t in ts)
 
 
-@_traced_once(0, 1)
+@traced_once(0, 1)
 def _kernel_forward(chunk, block, x, dt, a, b, c, d):
     """`_forward` as `selscan_fwd`; the counter a row of the batch."""
     s = x.shape[1]
@@ -515,7 +511,7 @@ def _kernel_forward(chunk, block, x, dt, a, b, c, d):
             starts)
 
 
-@_traced_once(0, 1)
+@traced_once(0, 1)
 def _kernel_backward(chunk, block, x, dt, a, b, c, d, starts, gm):
     """`_scan_bwd` as `selscan_bwd` and what XLA adds up of its shares."""
     s, n = x.shape[1], a.shape[1]
@@ -571,20 +567,20 @@ def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: j
     default backend), the channels are whole blocks of `CHANNELS`, N is a
     multiple of 8, the chunk whole tiles of 128 tokens, the state float32 and
     the call sits on one device or, with `sharding`, on whole rows of the
-    batch a device; everything else, the CPU among it, the XLA form. Counted
-    in `TOOK` as "pallas" / "xla"."""
+    batch a device; everything else, the CPU among it, the XLA form. Said to
+    `obs/forms` as `SELECTIVE_SCAN`'s "pallas" / "xla"."""
     chunk, state_dtype = int(chunk), jnp.dtype(state_dtype)
     fits = (x.shape[2] % CHANNELS == 0 and a.shape[1] % 8 == 0 and min(chunk, x.shape[1]) % TILE == 0
             and state_dtype == _F32)
-    kernels, sharding = _on_kernels(sharding, x.shape[0], fits)
+    kernels, sharding = on_kernels(sharding, x.shape[0], fits)
     if impl == "auto":
         impl = "pallas" if kernels else "xla"
-    TOOK[impl] += 1
+    forms.took(forms.SELECTIVE_SCAN, impl)
     if impl == "xla":
         return _scan(x, dt.astype(_F32), a, b, c, d, chunk, state_dtype)
     if x.shape[2] % CHANNELS or state_dtype != _F32:
         raise ValueError("selective_scan: the kernels hold a float32 state for blocks of %d channels; got %d "
                          "channels and a state in %s" % (CHANNELS, x.shape[2], state_dtype.name))
-    m, final, peak = _rows_a_device(lambda *operands: _kernel_scan(*operands, chunk, CHANNELS), sharding,
-                                    (x, dt.astype(_F32), a, b, c, d), (2, 5), (3, 3, 1))
+    m, final, peak = rows_a_device(lambda *operands: _kernel_scan(*operands, chunk, CHANNELS), sharding,
+                                   (x, dt.astype(_F32), a, b, c, d), (2, 5), (3, 3, 1))
     return m, final, jnp.max(peak)

@@ -34,7 +34,6 @@ CP inside pp>1.
 
 from __future__ import annotations
 
-import collections
 from functools import partial
 from typing import Any, Dict, List, Optional
 
@@ -43,17 +42,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from galvatron_tpu.config.strategy import HybridParallelConfig
-from galvatron_tpu.obs import tracing
+from galvatron_tpu.obs import forms, tracing
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import PP_AXIS, layer_axes, pipeline_vocab_axes, vocab_axes
 
 Params = Dict[str, Any]
-
-# the mesh axes a traced scan-pipeline loss split its vocabulary layers over
-# (`pipeline_vocab_axes`, where they lead with pp) -> the traces that did since
-# the process began: the trainer's compile report reads what a step's trace added
-VOCAB_SPLIT_TOOK = collections.Counter()
-
 
 def validate_pipeline_config(hp: HybridParallelConfig):
     if hp.pp <= 1:
@@ -188,7 +181,7 @@ def pipeline_apply(
 ) -> jax.Array:
     """Run the scan pipeline; returns (num_mb, mb, S, H) last-stage outputs."""
     from galvatron_tpu.models.base import layer_forward
-    from galvatron_tpu.ops.attention import KernelSharding
+    from galvatron_tpu.ops.kernels import KernelSharding
 
     pp, num_mb = hp.pp, hp.chunks
     lps = layers_per_stage(hp)
@@ -264,7 +257,7 @@ def make_pipelined_loss(cfg, hp: HybridParallelConfig, mesh: Mesh):
 
     def loss_fn(params, batch):
         if pvax != vax:
-            VOCAB_SPLIT_TOOK[tuple(pvax.tp)] += 1
+            forms.took(forms.VOCAB_SPLIT, ",".join(pvax.tp))
         num_mb = hp.chunks
         with jax.named_scope(tracing.EMBED):
             if cfg.input_type == "patches":

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
-"""A cell's `compile` telemetry event: the counters that say which form each
-part of the step took (`window_kernel_layers`, `window_operands_as_projected`,
-`moe_row_kernel_blocks`, ...). The benchmark installs its telemetry sink after
+"""A cell's `compile` telemetry event: `forms` says which form each part of
+the step took as it was traced (obs/forms.py: part -> form -> count). The
+benchmark installs its telemetry sink after
 the step is compiled and so does not keep the event; this runs the cell's own
 training command with a sink from the start and ends the run after two steps
 (the benchmark's seam: `fault_hooks.on_step` sets `train_iters`),

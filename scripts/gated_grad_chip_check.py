@@ -47,7 +47,7 @@ def leaves_after_steps(workload: str, rule: bool, keep: str, steps: int) -> dict
     from galvatron_tpu.cli.arguments import hp_config_from_args, initialize_galvatron, model_config_from_args
     from galvatron_tpu.cli.train import build_data_iterator, optimizer_args_from
     from galvatron_tpu.models import base as M
-    from galvatron_tpu.models.parts import mlp
+    from galvatron_tpu.obs import forms
     from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
     from galvatron_tpu.runtime.optimizer import get_optimizer_and_scheduler
 
@@ -69,12 +69,14 @@ def leaves_after_steps(workload: str, rule: bool, keep: str, steps: int) -> dict
     data = build_data_iterator(args, fam, cfg, hp)
     step = model.make_train_step(tx, guard_anomalies=guard)
     cap = (np.float32(np.inf),) if guard else ()
-    took, losses, norms = sum(mlp.RELAID.values()), [], []
-    for _ in range(steps):
-        params, opt, metrics = step(params, opt, model.shard_batch(next(data)), *cap)
-        losses.append(float(metrics["loss"]))
-        norms.append(float(metrics["grad_norm"]))
-    return {"workload": workload, "rule": rule, "steps": steps, "clip_grad": args.clip_grad, "guard": guard, "kernels_relaid": sum(mlp.RELAID.values()) - took,
+    losses, norms = [], []
+    with forms.recording() as took:
+        for _ in range(steps):
+            params, opt, metrics = step(params, opt, model.shard_batch(next(data)), *cap)
+            losses.append(float(metrics["loss"]))
+            norms.append(float(metrics["grad_norm"]))
+    return {"workload": workload, "rule": rule, "steps": steps, "clip_grad": args.clip_grad, "guard": guard,
+            "kernels_relaid": took[forms.GATED_KERNEL_GRADS]["as_stored"],
             "losses": losses, "grad_norms": norms,
             "leaves": {jax.tree_util.keystr(path): hashlib.sha1(np.asarray(leaf).tobytes()).hexdigest()
                        for path, leaf in jax.tree_util.tree_leaves_with_path((params, opt))}}

@@ -37,6 +37,7 @@ def main(workload: str) -> int:
     from benchmarks import cells
     from galvatron_tpu.cli.arguments import hp_config_from_args, initialize_galvatron, model_config_from_args
     from galvatron_tpu.models import base as M
+    from galvatron_tpu.obs import forms
     from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 
     if jax.devices()[0].platform != "tpu":
@@ -64,9 +65,9 @@ def main(workload: str) -> int:
             return value, jax.tree.map(lambda g, leaf, s: jax.lax.with_sharding_constraint(g.astype(leaf.dtype), s),
                                        grads, p, accum)
 
-        before = sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values())
-        value, grads = jax.jit(widened)(params, batch)
-        return float(value), grads, sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values()) - before
+        with forms.recording() as took:
+            value, grads = jax.jit(widened)(params, batch)
+        return float(value), grads, took[forms.SCAN_GRADS]["zero_layout"]
 
     loss_asked, asked, leaves_asked = gradient(True)
     loss_plain, plain, leaves_plain = gradient(False)

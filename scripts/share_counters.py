@@ -4,7 +4,8 @@ and what its `compile` and `step` events say of the experts' window:
 
     chiprun -- python3 scripts/share_counters.py <cell> <seed> [<steps>]
 
-One line of JSON: `expert_window_rows`, `moe_row_kernel_blocks`, and over the
+One line of JSON: the `compile` event's `forms` of the experts' window and
+the row movers (obs/forms.py: `expert_window`, `moe_rows`), and over the
 steps the sum of `expert_window_fallbacks` and the range of
 `expert_rows_held_over_even`. The cell's own flags (benchmarks/cells.train_argv),
 so the step is the benchmark's and comes out of its compile cache."""
@@ -22,7 +23,7 @@ def main(workload: str, seed: int, steps: int = 40) -> None:
     from benchmarks import cells
     from galvatron_tpu.cli.arguments import initialize_galvatron
     from galvatron_tpu.cli.train import train
-    from galvatron_tpu.obs import telemetry
+    from galvatron_tpu.obs import forms, telemetry
 
     cell = cells.load_cell(ROOT, workload)
     cells.register_family(cell)
@@ -43,8 +44,7 @@ def main(workload: str, seed: int, steps: int = 40) -> None:
     over_even = [e["expert_rows_held_over_even"] for e in step_events]
     print(json.dumps({
         "workload": workload, "seed": seed, "steps": len(step_events), "errors": len(errors),
-        "expert_window_rows": [e.get("expert_window_rows") for e in compiles],
-        "moe_row_kernel_blocks": [e.get("moe_row_kernel_blocks") for e in compiles],
+        **{part: [e["forms"].get(part) for e in compiles] for part in (forms.EXPERT_WINDOW, forms.MOE_ROWS)},
         "expert_window_fallbacks": sum(e["expert_window_fallbacks"] for e in step_events),
         "steps_that_fell_back": sum(e["expert_window_fallbacks"] > 0 for e in step_events),
         "expert_rows_held_over_even": [min(over_even), max(over_even)],

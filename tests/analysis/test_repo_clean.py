@@ -91,10 +91,17 @@ def test_no_mention_of_what_was_removed():
     describes or drives the program names them any more; the records
     (CHANGES.md, PERF.md, ROADMAP.md) may, as history. So are the four
     functions that said which layouts a part supports, the two that asked
-    the layer pattern and the hand-kept digest defaults (PR 45: models/parts)."""
+    the layer pattern and the hand-kept digest defaults (PR 45: models/parts),
+    and the `compile` fields that said which form a part took (PR 59:
+    obs/forms.py, the event's one field `forms`)."""
     gone = ("bench.py", "_bench_util", "--no_async_loop", "--donate_step",
             "GALVATRON_PEAK_FLOPS", "expert_layout_reason", "linear_layers_reason", "_has_linear",
-            "_has_mixer", "assert_expert_layout_supported", "_DIGEST_DEFAULTS")
+            "_has_mixer", "assert_expert_layout_supported", "_DIGEST_DEFAULTS",
+            # PR 59: the `compile` fields that the module counters filled (obs/forms.py: one field, `forms`)
+            "linear_kernel_layers", "linear_pass_kernel_layers", "kda_kernel_layers", "kda_pass_kernel_layers",
+            "moe_row_kernel_blocks", "expert_window_rows", "shortconv_layers", "kernel_grads_relaid",
+            "window_kernel_layers", "window_operands_as_projected", "table_rows_over_dp", "vocab_split_axes",
+            "selscan_kernel_layers")
     files = [os.path.join(REPO, "README.md"), os.path.join(REPO, "COVERAGE.md")]
     for top in (PACKAGE, os.path.join(REPO, "scripts"), os.path.join(REPO, ".claude")):
         for ext in ("py", "md", "sh"):

@@ -11,6 +11,7 @@ import pytest
 from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.cli.arguments import initialize_galvatron
 from galvatron_tpu.cli.train import train
+from galvatron_tpu.obs import forms
 from galvatron_tpu.obs import telemetry as T
 
 TINY = [
@@ -54,9 +55,9 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counter(one_device, tmp_pa
     runs = [e for e in events if e["type"] == "layer_run" and e["run"] >= 0]
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 5), (1, 5, 6), (2, 6, 10)]
     # no linear layer: the compile report has nothing to say of the delta rule's kernels
-    assert all("linear_kernel_layers" not in e for e in events if e["type"] == "compile")
+    assert all(forms.DELTA_RULE not in e["forms"] for e in events if e["type"] == "compile")
     # ten gated kernels, and off a TPU none is read through `grad_as_stored` (models/base.run_layers)
-    assert [e["kernel_grads_relaid"] for e in events if e["type"] == "compile"] == [0]
+    assert [forms.GATED_KERNEL_GRADS in e["forms"] for e in events if e["type"] == "compile"] == [False]
 
 
 @pytest.mark.parametrize("flags", [

@@ -15,6 +15,7 @@ from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.cli.arguments import initialize_galvatron
 from galvatron_tpu.cli.train import train
 from galvatron_tpu.data.dataset import write_indexed_dataset
+from galvatron_tpu.obs import forms
 from galvatron_tpu.obs import telemetry as T
 
 TINY = [
@@ -69,10 +70,11 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, count
     runs = [e for e in events if e["type"] == "layer_run" and e["run"] >= 0]
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 1), (1, 1, 3), (2, 3, 4), (3, 4, 5)]
     # the scalar rule's kernels are not this model's: the compile report says nothing of them
-    assert all("linear_kernel_layers" not in e for e in events if e["type"] == "compile")
+    assert all(forms.DELTA_RULE not in e["forms"] for e in events if e["type"] == "compile")
     # its own are: off a TPU none of the four KDA layers takes `kda_fwd` / `kda_bwd`, and the report says so
-    assert [e["kda_kernel_layers"] for e in events if e["type"] == "compile"] == [0]
-    assert [e["kda_pass_kernel_layers"] for e in events if e["type"] == "compile"] == [0]  # nor the passes around it
+    assert [set(e["forms"][forms.KDA_RULE]) for e in events if e["type"] == "compile"] == [{"xla"}]
+    assert [{form for part in (forms.KDA_CONV_NORM, forms.KDA_GATE, forms.KDA_GATED_NORM) for form in e["forms"][part]}
+            for e in events if e["type"] == "compile"] == [{"xla"}]  # nor the passes around it
 
 
 @pytest.mark.parametrize("flags", [

@@ -16,6 +16,7 @@ from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.cli.arguments import initialize_galvatron
 from galvatron_tpu.cli.train import train
 from galvatron_tpu.data.dataset import write_indexed_dataset
+from galvatron_tpu.obs import forms
 from galvatron_tpu.obs import telemetry as T
 
 TINY = [
@@ -70,13 +71,13 @@ def test_dp2_zero2_follows_one_device_and_logs_the_compile_counter(one_device, c
     runs = [e for e in events if e["type"] == "layer_run" and e["run"] >= 0]
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 1), (1, 1, 4), (2, 4, 5)]
     compiles = [e for e in events if e["type"] == "compile"]
-    assert [e["window_kernel_layers"] for e in compiles] == [0]  # off a TPU the band is a mask on XLA's logits
-    assert [e["window_operands_as_projected"] for e in compiles] == [0]  # so nothing reads q as projected
-    assert [e["moe_row_kernel_blocks"] for e in compiles] == [0]  # and the rows move by XLA's gathers
-    assert all("kda_kernel_layers" not in e and "linear_kernel_layers" not in e and "shortconv_layers" not in e
-               for e in compiles)
-    assert all("expert_window_rows" not in e for e in compiles)  # all 256 experts are held: no window of rows
-    assert [e["kernel_grads_relaid"] for e in compiles] == [0]  # off a TPU the compiler lays the gradients out
+    took = [e["forms"] for e in compiles]
+    assert [set(t[forms.WINDOW_ATTENTION]) for t in took] == [{"xla"}]  # off a TPU the band is a mask on XLA's logits
+    assert [forms.WINDOW_OPERANDS in t for t in took] == [False]  # so nothing reads q as projected
+    assert [set(t[forms.MOE_ROWS]) for t in took] == [{"xla"}]  # and the rows move by XLA's gathers
+    assert all(forms.KDA_RULE not in t and forms.DELTA_RULE not in t and forms.SHORT_CONV not in t for t in took)
+    assert all(forms.EXPERT_WINDOW not in t for t in took)  # all 256 experts are held: no window of rows
+    assert [forms.GATED_KERNEL_GRADS in t for t in took] == [False]  # off a TPU the compiler lays the gradients out
 
 
 def test_a_model_without_window_layers_logs_no_window_counter(counting, tmp_path):
@@ -87,8 +88,8 @@ def test_a_model_without_window_layers_logs_no_window_counter(counting, tmp_path
         "--seq_length", "48", "--mixed_precision", "fp32", "--global_train_batch_size", "2", "--train_iters", "1",
         "--world_size", "1", "--telemetry", tele] + counting))
     compiles = [e for e in T.read_events(tele)[0] if e["type"] == "compile"]
-    assert len(compiles) == 1 and "window_kernel_layers" not in compiles[0]
-    assert compiles[0]["window_operands_as_projected"] == 0  # a count, 0 where there is nothing to count
+    assert len(compiles) == 1 and forms.WINDOW_ATTENTION not in compiles[0]["forms"]
+    assert forms.WINDOW_OPERANDS not in compiles[0]["forms"]
 
 
 @pytest.mark.parametrize("flags", [

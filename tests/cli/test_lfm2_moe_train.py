@@ -15,6 +15,7 @@ from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.cli.arguments import initialize_galvatron
 from galvatron_tpu.cli.train import train
 from galvatron_tpu.data.dataset import write_indexed_dataset
+from galvatron_tpu.obs import forms
 from galvatron_tpu.obs import telemetry as T
 
 TINY = [
@@ -69,11 +70,12 @@ def test_dp2_zero2_follows_one_device_and_logs_the_compile_counter(one_device, c
     runs = [e for e in events if e["type"] == "layer_run" and e["run"] >= 0]
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 2), (1, 2, 3), (2, 3, 5)]
     compiles = [e for e in events if e["type"] == "compile"]
-    assert [e["shortconv_layers"] for e in compiles] == [4]  # four of the five layers convolve
-    assert [e["moe_row_kernel_blocks"] for e in compiles] == [0]  # off a TPU the rows move by XLA's gathers
-    assert all("kda_kernel_layers" not in e and "linear_kernel_layers" not in e for e in compiles)
-    assert all("expert_window_rows" not in e for e in compiles)  # all 32 experts are held: no window to speak of
-    assert [e["kernel_grads_relaid"] for e in compiles] == [0]  # off a TPU the compiler lays the gradients out
+    took = [e["forms"] for e in compiles]
+    assert [set(t[forms.SHORT_CONV]) for t in took] == [{"xla"}]  # the conv layers' mixers were traced, in their one form
+    assert [set(t[forms.MOE_ROWS]) for t in took] == [{"xla"}]  # off a TPU the rows move by XLA's gathers
+    assert all(forms.KDA_RULE not in t and forms.DELTA_RULE not in t for t in took)
+    assert all(forms.EXPERT_WINDOW not in t for t in took)  # all 32 experts are held: no window to speak of
+    assert [forms.GATED_KERNEL_GRADS in t for t in took] == [False]  # off a TPU the compiler lays the gradients out
 
 
 @pytest.mark.parametrize("flags", [
@@ -109,7 +111,7 @@ def test_a_share_of_the_experts_reports_its_window(tmp_path):
         "--train_iters", "2", "--world_size", "1", "--telemetry", tele]))
     events, errors = T.read_events(tele)
     assert errors == []
-    assert [e["expert_window_rows"] for e in events if e["type"] == "compile"] == [1536]
+    assert [set(e["forms"][forms.EXPERT_WINDOW]) for e in events if e["type"] == "compile"] == [{"1536"}]
     steps = [e for e in events if e["type"] == "step"]
     assert len(steps) == 2
     for e in steps:

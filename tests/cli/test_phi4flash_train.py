@@ -16,6 +16,7 @@ from galvatron_tpu.cli.arguments import initialize_galvatron
 from galvatron_tpu.cli.lint import run as lint
 from galvatron_tpu.cli.train import train
 from galvatron_tpu.models.registry import family_names
+from galvatron_tpu.obs import forms
 from galvatron_tpu.obs import telemetry as T
 
 TINY = [
@@ -72,19 +73,19 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, dp2_z
 
 
 def test_the_compile_event_says_how_many_scans_run_as_kernels(dp2_zero2, capsys):
-    """`selscan_kernel_layers`: the Mamba-1 layers whose scan the step runs as
-    `selscan_fwd` / `selscan_bwd`; 0 on the CPU, where `selective_scan` takes
-    the XLA form, and `cli report` prints it beside the layers' count."""
+    """`forms`' `selective_scan`: the form the Mamba-1 layers' scans took,
+    "pallas" where the step runs `selscan_fwd` / `selscan_bwd`; "xla" alone on
+    the CPU, and `cli report` prints it beside the layers' count."""
     from galvatron_tpu.obs import report
 
-    assert "selscan_kernel_layers" in T.EVENT_SCHEMAS["compile"][1]
+    assert "forms" in T.EVENT_SCHEMAS["compile"][1]
     events, errors = T.read_events(dp2_zero2[1])
     assert errors == []
-    assert [e["selscan_kernel_layers"] for e in events if e["type"] == "compile"] == [0]
+    assert [set(e["forms"][forms.SELECTIVE_SCAN]) for e in events if e["type"] == "compile"] == [{"xla"}]
     report.run([dp2_zero2[1]])
     out = capsys.readouterr().out
     assert "layers whose token mixer is a Mamba-1 selective scan: 3" in out
-    assert "Mamba-1 layers whose selective scan runs as Pallas kernels: 0" in out
+    assert "selective_scan: xla x " in out
 
 
 @pytest.mark.parametrize("flags,named", [

@@ -11,6 +11,7 @@ import pytest
 from galvatron_tpu.analysis.diagnostics import DiagnosticError
 from galvatron_tpu.cli.arguments import initialize_galvatron
 from galvatron_tpu.cli.train import train
+from galvatron_tpu.obs import forms
 from galvatron_tpu.obs import telemetry as T
 
 TINY = [
@@ -56,11 +57,12 @@ def test_dp2_zero2_follows_one_device_and_reports_its_counters(one_device, tmp_p
     runs = [e for e in events if e["type"] == "layer_run" and e["run"] >= 0]
     assert [(e["run"], e["start"], e["stop"]) for e in runs] == [(0, 0, 3), (1, 3, 4)]
     # off a TPU none of the three linear layers takes the Pallas kernels, and the compile report says so
-    assert [e["linear_kernel_layers"] for e in events if e["type"] == "compile"] == [0]
-    assert [e["linear_pass_kernel_layers"] for e in events if e["type"] == "compile"] == [0]  # nor the passes around it
-    assert all("kda_kernel_layers" not in e and "kda_pass_kernel_layers" not in e
+    assert [set(e["forms"][forms.DELTA_RULE]) for e in events if e["type"] == "compile"] == [{"xla"}]
+    assert [set(e["forms"][forms.CONV_NORM]) | set(e["forms"][forms.GATED_NORM])
+            for e in events if e["type"] == "compile"] == [{"xla"}]  # nor the passes around it
+    assert all(forms.KDA_RULE not in e["forms"] and forms.KDA_CONV_NORM not in e["forms"]
                for e in events if e["type"] == "compile")  # no KDA layer: nothing said
-    assert [e["moe_row_kernel_blocks"] for e in events if e["type"] == "compile"] == [0]  # nor do the four routed blocks' rows move by DMA
+    assert [set(e["forms"][forms.MOE_ROWS]) for e in events if e["type"] == "compile"] == [{"xla"}]  # nor do the four routed blocks' rows move by DMA
 
 
 @pytest.mark.parametrize("flags", [

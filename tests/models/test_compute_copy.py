@@ -265,7 +265,7 @@ def test_the_split_table_trains_as_the_parents_form_does(kw, devices4):
     Adam steps: the first loss to the bit (the same rows), the rest within
     1e-6 (the same float32 addends in another order), every leaf within
     float32 rounding, and the table comes back split as it went in (what the
-    compiled step holds: tests/ops/test_tpu_compile.py)."""
+    compiled step holds: tests/ops/test_tpu_compile_steps.py)."""
     cfg, hp = tiny_llama(qkv_bias=True), layout("zero2", checkpoint=1, **kw)
     batch = repeated_ids_batch()
     _, want_losses, want = train(cfg, hp, devices4, model=parents_form(cfg, hp, devices4), batch=batch)

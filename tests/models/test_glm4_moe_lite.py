@@ -268,7 +268,7 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
 def test_a_held_share_sends_no_gradient_to_rows_it_does_not_hold():
     """`grouped_matmul(first_group=)`: the other groups' rows come back zero
     and send none back (off a TPU through zero kernels; on one the megablox
-    kernels skip them: tests/ops/test_tpu_compile.py)."""
+    kernels skip them: tests/ops/test_tpu_compile_share.py)."""
     rows = jax.random.normal(jax.random.PRNGKey(0), (12, 4))
     kernels = jax.random.normal(jax.random.PRNGKey(1), (2, 4, 3))
     sizes = jnp.array([3, 2, 4, 3], jnp.int32)  # groups 1 and 2 are held: rows 3 to 9

@@ -23,7 +23,6 @@ and the norms' scales moved off their start, so that the attention's logits,
 the positions and the gate move the loss by far more than the tolerance.
 """
 
-import collections
 import dataclasses
 import math
 import os
@@ -46,8 +45,7 @@ from galvatron_tpu.models.parts import unsupported_reason
 from galvatron_tpu.models.parts.common import ASKERS
 from galvatron_tpu.models.registry import get_family
 from galvatron_tpu.obs import flops as obs_flops
-from galvatron_tpu.obs import telemetry, tracing
-from galvatron_tpu.ops import attention as attention_ops
+from galvatron_tpu.obs import forms, telemetry, tracing
 from galvatron_tpu.ops.moe import moe_ffn
 from galvatron_tpu.ops.rope import apply_rotary, rope_frequencies
 from galvatron_tpu.runtime import construct_hybrid_parallel_model
@@ -281,14 +279,12 @@ def test_the_scanned_stack_is_the_unrolled_one():
     params = params_of(cfg)
     hp = HybridParallelConfig.uniform(1, cfg.num_layers, global_bsz=BATCH, checkpoint=1)
     model = construct_hybrid_parallel_model(cfg, hp, jax.devices()[:1])
-    before = collections.Counter(attention_ops.TOOK)
-    with jax.default_matmul_precision("highest"):
+    with forms.recording() as took, jax.default_matmul_precision("highest"):
         scanned = jax.jit(jax.value_and_grad(model.loss_fn))(params, model.shard_batch(batch))
         plain = jax.jit(jax.value_and_grad(lambda p: M.lm_loss_fn(p, batch, cfg)))(params)
     assert float(scanned[0]) == pytest.approx(float(plain[0]), abs=1e-6)
     assert max(leaf_errors(scanned[1], plain[1]).values()) < 1e-5
-    took = attention_ops.TOOK - before
-    assert took["window_xla"] > 0 and not took["window_pallas"]  # off a TPU: the band mask
+    assert set(took[forms.WINDOW_ATTENTION]) == {"xla"}  # off a TPU: the band mask
 
 
 # --------------------------------------------------------- yarn, the mixers
@@ -408,7 +404,7 @@ def two_forms(window_kernels_as_on_a_tpu):
     the CPU runs them (rope, XLA's band, the gate's product) and as a TPU does
     (`window_takes_kernels` answered as on a TPU, the kernels interpreted: q
     read where the flat projection wrote it, turned in the kernel, the head's
-    gate in its epilogue) -> {form: ((loss, parts), grads)}, what `TOOK` counted."""
+    gate in its epilogue) -> {form: ((loss, parts), grads)}, what `obs/forms` heard."""
     import unittest.mock as mock
 
     import jax.experimental.pallas.tpu as pltpu
@@ -420,19 +416,19 @@ def two_forms(window_kernels_as_on_a_tpu):
 
     def run():
         with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(lambda p: M.lm_loss_fn(p, batch, cfg, with_parts=True), has_aux=True)(params)
+            return jax.jit(jax.value_and_grad(lambda p: M.lm_loss_fn(p, batch, cfg, with_parts=True), has_aux=True))(params)
 
     out = {"xla": run()}
-    before = collections.Counter(attention_ops.TOOK)
-    with window_kernels_as_on_a_tpu(), mock.patch.object(window_attention, "BLOCK", 128), \
+    with forms.recording() as took, window_kernels_as_on_a_tpu(), mock.patch.object(window_attention, "BLOCK", 128), \
          pltpu.force_tpu_interpret_mode():
         out["kernels"] = run()
-    return out, attention_ops.TOOK - before
+    return out, took
 
 
 def test_the_loss_is_the_same_with_the_window_kernels_on_q_as_projected(two_forms):
     out, took = two_forms
-    assert took["window_pallas"] == took["window_as_projected"] > 0 and "window_xla" not in took
+    assert took[forms.WINDOW_ATTENTION]["pallas"] == took[forms.WINDOW_OPERANDS]["as_projected"] > 0
+    assert "xla" not in took[forms.WINDOW_ATTENTION]
     (loss, parts), (want, want_parts) = out["kernels"][0], out["xla"][0]
     assert abs(float(loss) - float(want)) < F32_TOL
     for name in want_parts:
@@ -472,7 +468,7 @@ def test_one_table_maps_the_window_mixer_to_what_it_brings():
     wide = obs_flops.window_fwd_flops_a_token(hidden=64, num_heads=6, head_dim=16, num_kv_heads=2, window=SEQ + 9,
                                               head_gate=True, seq_len=SEQ)[1]
     assert wide == pytest.approx(2 * 2 * (SEQ + 1) / 2 * 6 * 16)
-    assert {"window_kernel_layers", "window_operands_as_projected"} <= set(telemetry.EVENT_SCHEMAS["compile"][1])
+    assert "forms" in telemetry.EVENT_SCHEMAS["compile"][1]
 
 
 # ------------------------------------------------------------ GLS018, by name

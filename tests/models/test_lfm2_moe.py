@@ -47,7 +47,7 @@ from galvatron_tpu.models.parts.conv import conv_mixer
 from galvatron_tpu.models.parts.mlp import ROUTER_BIAS
 from galvatron_tpu.models.registry import get_family
 from galvatron_tpu.obs import flops as obs_flops
-from galvatron_tpu.obs import telemetry, tracing
+from galvatron_tpu.obs import forms, telemetry, tracing
 from galvatron_tpu.ops.moe import moe_ffn
 from galvatron_tpu.runtime import construct_hybrid_parallel_model
 
@@ -404,7 +404,7 @@ def test_one_table_maps_the_conv_mixer_to_what_it_brings():
     head = 2 * 64 * VOCAB
     assert obs_flops.train_step_flops(cfg, 1) == 3 * SEQ * (
         kinds["conv.dense"] + 3 * kinds["conv.routed"] + kinds["routed"] + head)
-    assert "shortconv_layers" in telemetry.EVENT_SCHEMAS["compile"][1]
+    assert forms.SHORT_CONV == "short_conv" and "forms" in telemetry.EVENT_SCHEMAS["compile"][1]
 
 
 def test_the_step_moves_the_bias_and_no_gradient_does():

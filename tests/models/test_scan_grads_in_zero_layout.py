@@ -21,6 +21,7 @@ from galvatron_tpu.config.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import base as M
 from galvatron_tpu.models import registry
 from galvatron_tpu.models.llama import llama_config
+from galvatron_tpu.obs import forms
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import layer_axes
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
@@ -93,17 +94,17 @@ def test_a_scanned_step_gives_the_unrolled_steps_loss_and_gradient(name, devices
     the state back in `state_specs`."""
     make_hp, n = LAYOUTS[name]
     cfg = tiny_qwen(jnp.float32)
-    before = sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values())
-    m, loss, new, grads = one_sgd_step(cfg, make_hp(), devices8[:n])
+    with forms.recording() as took:
+        m, loss, new, grads = one_sgd_step(cfg, make_hp(), devices8[:n])
     runs = 2 if name.startswith("two_runs") else 1
-    traces = 2 if m.hp.chunks == 2 else 1  # the microbatch loop traces the loss a chunk
     # a Qwen layer's leaves: two norm scales, wqkv and its bias, wo, wi, wo_mlp: each has a dim to split
-    assert sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values()) - before == 7 * runs * traces
+    # (a leaf the microbatch loop traces a second time is the same leaf)
+    assert took[forms.SCAN_GRADS] == {"zero_layout": 7 * runs}
     unrolled = make_hp()
     unrolled.scan_layers = False
-    before = sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values())
-    _, want_loss, _, want = one_sgd_step(cfg, unrolled, devices8[:n])
-    assert sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values()) == before
+    with forms.recording() as took:
+        _, want_loss, _, want = one_sgd_step(cfg, unrolled, devices8[:n])
+    assert forms.SCAN_GRADS not in took
     assert abs(loss - want_loss) < 2e-5, (loss, want_loss)
     for (path, a), b in zip(leaf_paths(grads).items(), jax.tree.leaves(want)):
         assert np.abs(b).max() > 0, path

@@ -323,17 +323,6 @@ def test_the_jit_counters_count_while_a_with_is_open_and_only_then():
     assert counters.jit_traces > got["jit_traces"] and listeners() == before
 
 
-def test_counter_deltas_read_what_the_with_added():
-    took, relaid = collections.Counter(xla=2), collections.Counter()
-    with launch.CounterDeltas(rule=took, relaid=relaid) as forms:
-        took["pallas"] += 3
-        took["xla"] += 1
-        assert forms.took == {}
-    assert forms.took == {"rule": collections.Counter(pallas=3, xla=1), "relaid": collections.Counter()}
-    assert forms.took["rule"]["kda_xla"] == 0 and not forms.took["relaid"]
-    assert took == collections.Counter(xla=3, pallas=3)  # the counters themselves are left alone
-
-
 # ----------------------------------------------------- the event, the table
 def test_the_launch_event_is_in_the_schema_and_refuses_another_key():
     assert telemetry.EVENT_SCHEMAS["launch"] == ((), ("launch_ms", "launch_imports", "launch_jit"))

@@ -12,6 +12,7 @@ import pytest
 from galvatron_tpu.cli.arguments import initialize_galvatron
 from galvatron_tpu.cli.train import train
 from galvatron_tpu.obs import flops as F
+from galvatron_tpu.obs import forms
 from galvatron_tpu.obs import report as R
 from galvatron_tpu.obs import telemetry as T
 
@@ -78,11 +79,12 @@ def test_lifecycle_events_present(telemetry_run):
     comp = t["compile"][0]
     assert comp["trace_ms"] > 0 and comp["compile_ms"] >= 0
     assert comp["compiled_memory_mb"] > 0
-    assert "linear_kernel_layers" not in comp  # a model without linear-attention layers says nothing of them
-    assert "linear_pass_kernel_layers" not in comp and "kda_kernel_layers" not in comp
-    assert "kda_pass_kernel_layers" not in comp
-    assert "moe_row_kernel_blocks" not in comp  # nor, without a routed block, of the row movers
-    assert "expert_window_rows" not in comp  # nor, without a share of the experts, of its window
+    took = comp["forms"]
+    assert forms.DELTA_RULE not in took  # a model without linear-attention layers says nothing of them
+    assert forms.CONV_NORM not in took and forms.KDA_RULE not in took
+    assert forms.KDA_CONV_NORM not in took
+    assert forms.MOE_ROWS not in took  # nor, without a routed block, of the row movers
+    assert forms.EXPERT_WINDOW not in took  # nor, without a share of the experts, of its window
     assert t["checkpoint_save"][0]["iteration"] == ITERS
     assert t["layer_run"], "per-LayerRun predictions missing"
     assert t["run_end"][0]["summary"]["iters"] >= 1
@@ -106,63 +108,63 @@ def zero2_tp2dp2_run(devices8, tmp_path_factory):
 
 
 def test_the_compile_event_says_whether_the_table_stays_split_over_dp(telemetry_run, zero2_tp2dp2_run, capsys):
-    """`table_rows_over_dp`: 1 where the step looks the vocabulary-split table
-    up with ids, rows and cotangents crossing dp (ZeRO-2, tp 2 x dp 2, the
-    four-chip cell's flags), 0 where it does not (the shared run: no
-    `vocab_tp`), and `cli report` says so in a line."""
-    assert "table_rows_over_dp" in T.EVENT_SCHEMAS["compile"][1]
-    assert by_type(telemetry_run[1])["compile"][0]["table_rows_over_dp"] == 0
+    """`forms`' `table_lookup`: "rows_over_dp" where the step looks the
+    vocabulary-split table up with ids, rows and cotangents crossing dp
+    (ZeRO-2, tp 2 x dp 2, the four-chip cell's flags), not where it does not
+    (the shared run: no `vocab_tp`), and `cli report` says so in a line."""
+    assert "forms" in T.EVENT_SCHEMAS["compile"][1]
+    assert "rows_over_dp" not in by_type(telemetry_run[1])["compile"][0]["forms"].get(forms.TABLE_LOOKUP, {})
     R.run([telemetry_run[3]])
-    assert "the token table stays split over dp" not in capsys.readouterr().out
+    assert "rows_over_dp" not in capsys.readouterr().out
     events, errors = T.read_events(zero2_tp2dp2_run)
     assert errors == []
-    assert [e["table_rows_over_dp"] for e in events if e["type"] == "compile"] == [1]
+    assert [e["forms"][forms.TABLE_LOOKUP] for e in events if e["type"] == "compile"] == [{"rows_over_dp": 1}]
     assert all(np.isfinite(e["loss"]) for e in events if e["type"] == "step")
     R.run([zero2_tp2dp2_run])
-    assert "the token table stays split over dp" in capsys.readouterr().out
+    assert "table_lookup: rows_over_dp x 1" in capsys.readouterr().out
 
 
 def test_a_model_with_no_mamba_layer_says_nothing_of_scan_kernels(telemetry_run, capsys):
-    """`selscan_kernel_layers` is absent where the step traced no selective
-    scan (the shared dense run), and `cli report` prints no line for it
-    (tests/cli/test_phi4flash_train.py holds the family's own 0 on the CPU)."""
-    assert "selscan_kernel_layers" not in by_type(telemetry_run[1])["compile"][0]
+    """`forms` holds no `selective_scan` where the step traced none (the
+    shared dense run), and `cli report` prints no line for it
+    (tests/cli/test_phi4flash_train.py holds the family's own "xla" on the CPU)."""
+    assert forms.SELECTIVE_SCAN not in by_type(telemetry_run[1])["compile"][0]["forms"]
     R.run([telemetry_run[3]])
-    assert "selective scan" not in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert "selective scan" not in out and forms.SELECTIVE_SCAN not in out
 
 
 def test_the_compile_event_counts_the_scanned_gradients_in_zeros_layout(telemetry_run, zero2_tp2dp2_run, capsys):
-    """`scan_grads_in_zero_layout`: the stacked leaves of the step's scanned
+    """`forms`' `scan_grads`: the stacked leaves of the step's scanned
     runs whose cotangent was asked for in ZeRO's layout (models/base.run_layers):
     under ZeRO-2 over dp 2 every leaf of the one run of two LLaMA layers (two
-    norm scales, four kernels: each has a dim that halves); 0 under ddp (the
+    norm scales, four kernels: each has a dim that halves); none under ddp (the
     shared run, dp 8). Beside it what the compiled step sums over dp inside
     the scan's backward, in MB (obs/compiled.dp_grad_sums_mb): there wherever
     the layout has a dp axis and a sink listens, and under ZeRO-2 0.0 at these
     widths, where no leaf reaches 1 MB and XLA:CPU prints no reduce-scatter
-    (tests/ops/test_tpu_compile.py reads it at the four-chip cell's)."""
-    fields = ("scan_grads_in_zero_layout", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb")
+    (tests/ops/test_tpu_compile_steps.py reads it at the four-chip cell's)."""
+    fields = ("dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb")
     assert set(fields) <= set(T.EVENT_SCHEMAS["compile"][1])
     ddp = by_type(telemetry_run[1])["compile"][0]
     # (ddp all-reduces its scanned gradients whole; XLA:CPU sums some of them as one operand over 1 MB)
-    assert ddp[fields[0]] == 0 and ddp[fields[1]] >= 0.0 and ddp[fields[2]] == 0.0
+    assert forms.SCAN_GRADS not in ddp["forms"] and ddp[fields[0]] >= 0.0 and ddp[fields[1]] == 0.0
     R.run([telemetry_run[3]])
-    assert "summed into ZeRO's shards" not in capsys.readouterr().out
+    assert forms.SCAN_GRADS not in capsys.readouterr().out
     zero2 = by_type(T.read_events(zero2_tp2dp2_run)[0])["compile"][0]
-    assert [zero2[f] for f in fields] == [6, 0.0, 0.0]
+    assert zero2["forms"][forms.SCAN_GRADS] == {"zero_layout": 6} and [zero2[f] for f in fields] == [0.0, 0.0]
     R.run([zero2_tp2dp2_run])
     out = capsys.readouterr().out
-    assert "stacked leaves of scanned runs whose gradient is summed into ZeRO's shards: 6" in out
+    assert "scan_grads: zero_layout x 6" in out
     assert "weight gradients over dp, MB a chip: 0 all-reduced, 0 reduce-scattered" in out
 
 
 def test_the_compile_event_names_the_axes_the_pipelines_vocabulary_is_split_over(telemetry_run, devices8, tmp_path, capsys):
-    """`vocab_split_axes`: pp, then the vocabulary's tp axes, where the scan
+    """`forms`' `vocab_split`: pp, then the vocabulary's tp axes, where the scan
     pipeline stores and computes its vocabulary layers split over them (pp2 x
     tp2 on four devices, the pipelined cell's flags); absent at pp = 1 (the
     shared run), and `cli report` says so in a line."""
-    assert "vocab_split_axes" in T.EVENT_SCHEMAS["compile"][1]
-    assert "vocab_split_axes" not in by_type(telemetry_run[1])["compile"][0]
+    assert forms.VOCAB_SPLIT not in by_type(telemetry_run[1])["compile"][0]["forms"]
     tele = str(tmp_path / "run.jsonl")
     argv = [
         "--model_type", "llama", "--set_model_config_manually", "1",
@@ -175,10 +177,10 @@ def test_the_compile_event_names_the_axes_the_pipelines_vocabulary_is_split_over
     train(initialize_galvatron(mode="train_dist", argv=argv))
     events, errors = T.read_events(tele)
     assert errors == []
-    assert [e["vocab_split_axes"] for e in events if e["type"] == "compile"] == [["pp", "m0"]]
+    assert [set(e["forms"][forms.VOCAB_SPLIT]) for e in events if e["type"] == "compile"] == [{"pp,m0"}]
     assert all(np.isfinite(e["loss"]) for e in events if e["type"] == "step")
     R.run([tele])
-    assert "vocabulary layers are stored and computed split over: pp, m0" in capsys.readouterr().out
+    assert "vocab_split: pp,m0 x " in capsys.readouterr().out
 
 
 def test_summary_reports_mfu(telemetry_run):

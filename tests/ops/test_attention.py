@@ -1,7 +1,6 @@
 """Attention op correctness: ring attention vs dense reference, zigzag layout,
 GQA, rope."""
 
-import collections
 import unittest.mock as mock
 
 import numpy as np
@@ -12,23 +11,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from galvatron_tpu.ops.attention import core_attention, repeat_kv
-from galvatron_tpu.ops.ring_attention import (
-    inverse_permutation,
-    ring_attention,
-    zigzag_permutation,
-)
 from galvatron_tpu.ops.rope import apply_rotary
-from galvatron_tpu.parallel.mesh import LayerAxes
+from tests.ops.attention_operands import _rand_qkv
 
 pytestmark = [pytest.mark.parallel]
-
-
-def _rand_qkv(rng, b=2, s=32, nh=4, nkv=None, hd=16):
-    kq, kk, kv = jax.random.split(rng, 3)
-    q = jax.random.normal(kq, (b, s, nh, hd), jnp.float32)
-    k = jax.random.normal(kk, (b, s, nkv or nh, hd), jnp.float32)
-    v = jax.random.normal(kv, (b, s, nkv or nh, hd), jnp.float32)
-    return q, k, v
 
 
 def test_xla_attention_causal_matches_manual():
@@ -47,117 +33,6 @@ def test_gqa_repeat():
     out = core_attention(q, k, v, causal=True, impl="xla")
     out2 = core_attention(q, repeat_kv(k, 4), repeat_kv(v, 4), causal=True, impl="xla")
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2), atol=1e-6)
-
-
-@pytest.mark.parametrize("zigzag", [False, True])
-@pytest.mark.parametrize("causal", [True, False])
-def test_ring_attention_matches_dense(devices8, zigzag, causal):
-    b, s, nh, hd = 2, 32, 4, 16
-    cp = 4
-    q, k, v = _rand_qkv(jax.random.PRNGKey(2), b=b, s=s, nh=nh, hd=hd)
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    dense = core_attention(q, k, v, causal=causal, impl="xla")
-
-    if zigzag:
-        idx = zigzag_permutation(s, cp)
-        qp, kp, vp = q[:, idx], k[:, idx], v[:, idx]
-        pos_p = positions[:, idx]
-    else:
-        qp, kp, vp, pos_p = q, k, v, positions
-
-    mesh = Mesh(np.array(devices8).reshape(2, 4), ("m0", "m1"))
-    axes = LayerAxes(dp=("m0",), cp=("m1",), tp=())
-    sharded = lambda t, spec: jax.device_put(t, NamedSharding(mesh, spec))
-    out = ring_attention(
-        sharded(qp, P("m0", "m1", None, None)),
-        sharded(kp, P("m0", "m1", None, None)),
-        sharded(vp, P("m0", "m1", None, None)),
-        sharded(pos_p, P("m0", "m1")),
-        mesh=mesh, axes=axes, causal=causal,
-    )
-    out = np.asarray(out)
-    if zigzag:
-        inv = inverse_permutation(zigzag_permutation(s, cp))
-        out = out[:, inv]
-    np.testing.assert_allclose(out, np.asarray(dense), atol=3e-5)
-
-
-def test_ring_attention_padding_bias_matches_dense(devices8):
-    """BERT-style padded batches under CP: the additive key bias rotates with
-    K/V around the ring (the reference's ring path is causal-only,
-    transformer.py:2335-2670 — this is a capability beyond it)."""
-    b, s, nh, hd = 2, 32, 4, 16
-    q, k, v = _rand_qkv(jax.random.PRNGKey(5), b=b, s=s, nh=nh, hd=hd)
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    mask = np.ones((b, s), np.float32)
-    mask[:, -8:] = 0.0
-    bias = jnp.asarray((1.0 - mask)[:, None, None, :] * -1e9)
-    dense = core_attention(q, k, v, causal=False, bias=bias, impl="xla")
-
-    mesh = Mesh(np.array(devices8).reshape(2, 4), ("m0", "m1"))
-    axes = LayerAxes(dp=("m0",), cp=("m1",), tp=())
-    sharded = lambda t, spec: jax.device_put(t, NamedSharding(mesh, spec))
-    out = ring_attention(
-        sharded(q, P("m0", "m1", None, None)),
-        sharded(k, P("m0", "m1", None, None)),
-        sharded(v, P("m0", "m1", None, None)),
-        sharded(positions, P("m0", "m1")),
-        mesh=mesh, axes=axes, causal=False, bias=sharded(bias, P("m0", None, None, "m1")),
-    )
-    # padded queries attend to garbage (all keys masked would be fully
-    # masked rows) — compare only valid query positions
-    np.testing.assert_allclose(
-        np.asarray(out)[:, :24], np.asarray(dense)[:, :24], atol=3e-5
-    )
-
-
-def _ring_mem_setup(devices8):
-    """Shared scaffolding for the ring-attention compiled-memory gates: one
-    mesh/axes/abstract-input recipe so both tests measure the same config."""
-    mesh = Mesh(np.array(devices8).reshape(2, 4), ("m0", "m1"))
-    axes = LayerAxes(dp=("m0",), cp=("m1",), tp=())
-
-    def structs(s, b=2, nh=4, hd=16):
-        q = jax.ShapeDtypeStruct((b, s, nh, hd), jnp.float32,
-                                 sharding=NamedSharding(mesh, P("m0", "m1", None, None)))
-        pos = jax.ShapeDtypeStruct((b, s), jnp.int32,
-                                   sharding=NamedSharding(mesh, P("m0", "m1")))
-        return q, pos
-
-    return mesh, axes, structs
-
-
-def test_ring_attention_blockwise_memory_scales_linearly(devices8):
-    """The per-step working set must be O(sq * key_chunk), not O(S^2/cp):
-    doubling S must scale the compiled temp bytes ~linearly (the round-2
-    full-logits implementation scaled quadratically)."""
-    from galvatron_tpu.ops import ring_attention as R
-
-    mesh, axes, structs = _ring_mem_setup(devices8)
-
-    def temp_bytes(s):
-        q, pos = structs(s)
-
-        def f(q, k, v, pos):
-            return R.ring_attention(q, k, v, pos, mesh=mesh, axes=axes, causal=True)
-
-        compiled = jax.jit(f).lower(q, q, q, pos).compile()
-        return compiled.memory_analysis().temp_size_in_bytes
-
-    t1 = temp_bytes(2048)
-    t2 = temp_bytes(4096)
-    assert t2 < 3.0 * t1, (t1, t2)
-
-
-def test_zigzag_permutation_roundtrip():
-    idx = zigzag_permutation(32, 4)
-    inv = inverse_permutation(idx)
-    x = np.arange(32)
-    assert (x[idx][inv] == x).all()
-    # shard 0 holds chunks 0 and 7 (balanced causal load)
-    chunk = 32 // 8
-    shard0 = idx[: 2 * chunk]
-    assert set(shard0) == set(range(0, chunk)) | set(range(7 * chunk, 32))
 
 
 def test_rope_rotation_invariants():
@@ -187,117 +62,6 @@ def test_flash_block_sizes_divide_sequence():
         for cap in (512, 1024):
             b = _flash_divisor(s, cap)
             assert s % b == 0 and b <= cap, (s, cap, b)
-
-
-@pytest.mark.parametrize("mode", ["causal", "bias", "gqa_zigzag"])
-def test_ring_custom_vjp_matches_autodiff(devices8, mode):
-    """The hand-scheduled ring backward (custom_vjp re-walking the ring with
-    rotating dk/dv/dbias accumulators, the reference's zigzag backward
-    pattern transformer.py:2423-2553) must produce the same gradients as
-    autodiff through the unrolled forward — for causal, padded-bias, and
-    GQA+zigzag compositions."""
-    b, s, nh, hd = 2, 32, 4, 16
-    nkv = 2 if mode == "gqa_zigzag" else None
-    q, k, v = _rand_qkv(jax.random.PRNGKey(7), b=b, s=s, nh=nh, nkv=nkv, hd=hd)
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    causal = mode != "bias"
-    bias = None
-    if mode == "bias":
-        m = np.ones((b, s), np.float32)
-        m[:, -8:] = 0.0
-        bias = jnp.asarray((1.0 - m)[:, None, None, :] * -1e9)
-    if mode == "gqa_zigzag":
-        idx = zigzag_permutation(s, 4)
-        q, k, v, positions = q[:, idx], k[:, idx], v[:, idx], positions[:, idx]
-
-    mesh = Mesh(np.array(devices8).reshape(2, 4), ("m0", "m1"))
-    axes = LayerAxes(dp=("m0",), cp=("m1",), tp=())
-    sharded = lambda t, spec: jax.device_put(t, NamedSharding(mesh, spec))
-    args = [
-        sharded(q, P("m0", "m1", None, None)),
-        sharded(k, P("m0", "m1", None, None)),
-        sharded(v, P("m0", "m1", None, None)),
-    ]
-    pos_s = sharded(positions, P("m0", "m1"))
-    bias_s = sharded(bias, P("m0", None, None, "m1")) if bias is not None else None
-    # downstream-style scalar loss with a non-uniform cotangent
-    w = jax.random.normal(jax.random.PRNGKey(9), (b, s, nh, hd))
-
-    def loss(qkv, use_custom):
-        out = ring_attention(
-            *qkv, pos_s, mesh=mesh, axes=axes, causal=causal, bias=bias_s,
-            use_custom_vjp=use_custom,
-        )
-        return jnp.sum(out.astype(jnp.float32) * w)
-
-    l_c, g_c = jax.value_and_grad(lambda t: loss(t, True))(tuple(args))
-    l_a, g_a = jax.value_and_grad(lambda t: loss(t, False))(tuple(args))
-    np.testing.assert_allclose(float(l_c), float(l_a), rtol=1e-6)
-    for name, gc, ga in zip("qkv", g_c, g_a):
-        np.testing.assert_allclose(
-            np.asarray(gc), np.asarray(ga), atol=2e-4, rtol=1e-4,
-            err_msg="grad mismatch for %s (%s)" % (name, mode),
-        )
-
-
-def test_ring_custom_vjp_bias_grad_matches_autodiff(devices8):
-    """The rotating dbias accumulator: gradient w.r.t. the additive key bias
-    itself (a trainable-relative-bias shape) matches autodiff."""
-    b, s, nh, hd = 2, 32, 4, 16
-    q, k, v = _rand_qkv(jax.random.PRNGKey(11), b=b, s=s, nh=nh, hd=hd)
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    bias = jax.random.normal(jax.random.PRNGKey(12), (b, 1, 1, s)) * 0.5
-    mesh = Mesh(np.array(devices8).reshape(2, 4), ("m0", "m1"))
-    axes = LayerAxes(dp=("m0",), cp=("m1",), tp=())
-    sharded = lambda t, spec: jax.device_put(t, NamedSharding(mesh, spec))
-    qs = sharded(q, P("m0", "m1", None, None))
-    ks = sharded(k, P("m0", "m1", None, None))
-    vs = sharded(v, P("m0", "m1", None, None))
-    pos_s = sharded(positions, P("m0", "m1"))
-    w = jax.random.normal(jax.random.PRNGKey(13), (b, s, nh, hd))
-
-    def loss(bb, use_custom):
-        out = ring_attention(
-            qs, ks, vs, pos_s, mesh=mesh, axes=axes, causal=False,
-            bias=sharded(bb, P("m0", None, None, "m1")), use_custom_vjp=use_custom,
-        )
-        return jnp.sum(out.astype(jnp.float32) * w)
-
-    g_c = jax.grad(lambda bb: loss(bb, True))(bias)
-    g_a = jax.grad(lambda bb: loss(bb, False))(bias)
-    np.testing.assert_allclose(np.asarray(g_c), np.asarray(g_a),
-                               atol=2e-4, rtol=1e-4)
-
-
-def test_ring_custom_vjp_bias_grad_with_tp_sharded_heads(devices8):
-    """tp x cp compose: heads are tp-sharded while the bias enters the
-    shard_map tp-invariant, so the custom backward must psum the local
-    head-sum over tp (autodiff inserts that reduction automatically — the
-    hand-written rule has to match it)."""
-    b, s, nh, hd = 2, 32, 4, 16
-    q, k, v = _rand_qkv(jax.random.PRNGKey(21), b=b, s=s, nh=nh, hd=hd)
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    bias = jax.random.normal(jax.random.PRNGKey(22), (b, 1, 1, s)) * 0.5
-    mesh = Mesh(np.array(devices8).reshape(2, 2, 2), ("m0", "m1", "m2"))
-    axes = LayerAxes(dp=("m0",), cp=("m1",), tp=("m2",))
-    sharded = lambda t, spec: jax.device_put(t, NamedSharding(mesh, spec))
-    qs = sharded(q, P("m0", "m1", "m2", None))
-    ks = sharded(k, P("m0", "m1", "m2", None))
-    vs = sharded(v, P("m0", "m1", "m2", None))
-    pos_s = sharded(positions, P("m0", "m1"))
-    w = jax.random.normal(jax.random.PRNGKey(23), (b, s, nh, hd))
-
-    def loss(bb, use_custom):
-        out = ring_attention(
-            qs, ks, vs, pos_s, mesh=mesh, axes=axes, causal=True,
-            bias=sharded(bb, P("m0", None, None, "m1")), use_custom_vjp=use_custom,
-        )
-        return jnp.sum(out.astype(jnp.float32) * w)
-
-    g_c = jax.grad(lambda bb: loss(bb, True))(bias)
-    g_a = jax.grad(lambda bb: loss(bb, False))(bias)
-    np.testing.assert_allclose(np.asarray(g_c), np.asarray(g_a),
-                               atol=2e-4, rtol=1e-4)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -429,35 +193,6 @@ def test_explicit_flash_with_untileable_padded_batch_falls_back():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
-def test_ring_custom_vjp_backward_memory_beats_autodiff(devices8):
-    """The point of the hand-written ring backward: probabilities recompute
-    from the saved lse, so no per-chunk residuals survive the forward.
-    Compiled temp bytes of the gradient program must stay bounded where
-    autodiff's transpose-of-scan residuals grow superlinearly (measured on
-    this mesh: S=4096 custom 28 MB vs autodiff 247 MB)."""
-    from galvatron_tpu.ops import ring_attention as R
-
-    mesh, axes, structs = _ring_mem_setup(devices8)
-
-    def temp_bytes(s, use_custom):
-        q, pos = structs(s)
-
-        def loss(q_, k_, v_, pos_):
-            out = R.ring_attention(q_, k_, v_, pos_, mesh=mesh, axes=axes,
-                                   causal=True, use_custom_vjp=use_custom)
-            return jnp.sum(out.astype(jnp.float32) ** 2)
-
-        g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-        return g.lower(q, q, q, pos).compile().memory_analysis().temp_size_in_bytes
-
-    big_custom = temp_bytes(4096, True)
-    big_auto = temp_bytes(4096, False)
-    assert big_custom < 0.4 * big_auto, (big_custom, big_auto)
-    # and the custom backward never costs meaningfully MORE than autodiff
-    small_custom, small_auto = temp_bytes(2048, True), temp_bytes(2048, False)
-    assert small_custom < 1.1 * small_auto, (small_custom, small_auto)
-
-
 def test_explicit_flash_key_padding_on_cpu_falls_back():
     """ADVICE r5: impl="flash" with a key-padding bias at kernel-tileable
     shapes must still fall back to XLA off-TPU (jax.default_backend() is
@@ -556,281 +291,3 @@ def test_auto_dispatch_takes_the_kernel_at_head_dim_64_on_a_tpu_and_says_a_fallb
     caplog.clear()
     A.core_attention(*narrow, causal=True)
     assert not caplog.records
-
-
-# ------------------------------------------------------- a window of keys (Laguna)
-def _per_token_window(q, k, v, window, scale):
-    """Query i on the keys i - window < j <= i, a loop a batch row, head and token, float64."""
-    q, k, v = (np.asarray(t, np.float64) for t in (q, k, v))
-    b, s, nh, hd = q.shape
-    group = nh // k.shape[2]
-    out = np.zeros_like(q)
-    for row in range(b):
-        for h in range(nh):
-            for i in range(s):
-                first = max(0, i - window + 1)
-                scores = k[row, first:i + 1, h // group] @ q[row, i, h] * scale
-                p = np.exp(scores - scores.max())
-                out[row, i, h] = (p / p.sum()) @ v[row, first:i + 1, h // group]
-    return out
-
-
-@pytest.mark.parametrize("window", [1, 5, 16, 40, 41, 64])
-def test_the_band_is_the_per_token_loop_and_a_window_of_the_whole_sequence_is_causal(window):
-    """GQA 4 on 2 over 40 tokens: the band mask against a loop a token (a
-    window of 1 is the token's own value), and at 40 keys and more plain causal
-    attention, bit for bit the same logits."""
-    from galvatron_tpu.ops import attention as A
-
-    q, k, v = _rand_qkv(jax.random.PRNGKey(7), b=2, s=40, nh=4, nkv=2, hd=16)
-    with jax.default_matmul_precision("highest"):
-        got = A.core_attention(q, k, v, window=window, sm_scale=0.4)
-        causal = A.core_attention(q, k, v, causal=True, sm_scale=0.4, impl="xla")
-    np.testing.assert_allclose(np.asarray(got), _per_token_window(q, k, v, window, 0.4), atol=2e-5)
-    if window == 1:
-        np.testing.assert_allclose(np.asarray(got), np.asarray(A.repeat_kv(v, 2)), atol=1e-6)
-    if window >= 40:
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(causal))
-    else:
-        assert float(jnp.max(jnp.abs(got - causal))) > 1e-3
-
-
-def test_a_window_is_causal_self_attentions_and_counts_its_form():
-    from galvatron_tpu.ops import attention as A
-
-    q, k, v = _rand_qkv(jax.random.PRNGKey(8), b=1, s=32, nh=2, hd=16)
-    with pytest.raises(ValueError, match="a window of 4 keys is causal self-attention's"):
-        A.core_attention(q, k, v, causal=False, window=4)
-    with pytest.raises(ValueError, match="32 queries on 16 keys"):
-        A.core_attention(q, k[:, :16], v[:, :16], window=4)
-    with pytest.raises(ValueError, match="a window of 0 keys"):
-        A.core_attention(q, k, v, window=0)
-    before = collections.Counter(A.TOOK)
-    A.core_attention(q, k, v, window=4)
-    A.core_attention(q, k, v, window=4, impl="flash")  # off a TPU the kernels have no form: the band mask
-    assert A.TOOK - before == {"window_xla": 2}
-    A.core_attention(q, k, v, causal=True)  # no window: not counted
-    assert A.TOOK - before == {"window_xla": 2}
-
-
-@pytest.mark.parametrize("window,block", [(160, 128), (128, 128), (129, 128), (300, 128), (64, 256), (1, 128), (600, 256)])
-def test_the_window_kernels_are_the_band_mask(window, block):
-    """GQA 4 on 2 heads of 128 at 512 tokens: the repo's band kernels
-    (`ops/window_attention.py`, interpret mode), forward and the three
-    gradients against the band mask on XLA's logits in float32, at windows
-    that end on a block's edge (128), one past it (129), inside a block, over
-    three blocks before the query's own (300 at 128) and wider than the
-    sequence (600: plain causal attention)."""
-    import jax.experimental.pallas.tpu as pltpu
-
-    from galvatron_tpu.ops import attention as A
-    from galvatron_tpu.ops import window_attention as W
-
-    q, k, v = _rand_qkv(jax.random.PRNGKey(9), b=2, s=512, nh=4, nkv=2, hd=128)
-    scale = 0.05
-    assert W.block_for(512, window, block) == block
-
-    def grads(kernel):
-        def f(q, k, v):
-            if kernel:  # as projected: a head a block of 128 lanes of a (batch, seq, heads x 128) array
-                out = W.window_attention(*(t.reshape(2, 512, -1) for t in (q, k, v)), None, None, window, scale,
-                                         block, 128).reshape(q.shape)
-                return jnp.sum(jnp.sin(out)), out
-            out = A.core_attention(q, k, v, window=window, sm_scale=scale, impl="xla")
-            return jnp.sum(jnp.sin(out)), out
-        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
-
-    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
-        got, want = grads(True), grads(False)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=3e-5)
-
-
-def test_the_window_kernels_block_reaches_the_window_in_a_few_key_blocks():
-    from galvatron_tpu.ops import window_attention as W
-
-    assert W.BLOCK == 512 and W.block_for(8192, 512) == 512 and W.block_for(16384, 512) == 512
-    assert W.block_for(8192 + 256, 512) == 256 and W.block_for(8192 + 128, 200) == 128  # the largest that divides
-    assert W.block_for(8192, 512 * 3 + 1) == 512 and W.block_for(8192, 512 * 3 + 2) == 0  # three blocks before its own
-    assert W.block_for(8192 + 128, 512) == 0  # 128-token blocks would need four before their own
-    assert W.block_for(100, 16) == 0  # no whole 128-token tile
-
-
-def test_auto_dispatch_takes_the_window_kernels_on_a_tpu_and_says_a_fallback_once(caplog):
-    """On a TPU at a tileable length and heads of 128 `impl="auto"` takes the
-    window kernels with k and v at their OWN heads; what falls back (heads of
-    64) is logged, once a shape, with the window it names."""
-    import logging
-
-    from galvatron_tpu.ops import attention as A
-    from galvatron_tpu.ops import window_attention
-
-    calls = []
-
-    def spy(q_, k_, v_, **kw):
-        calls.append((q_.shape[2], k_.shape[2], kw["window"]))
-        return A._xla_attention(q_, A.repeat_kv(k_, 2), A.repeat_kv(v_, 2), causal=True, sm_scale=kw["sm_scale"],
-                                window=kw["window"])
-
-
-    q, k, v = _rand_qkv(jax.random.PRNGKey(5), b=1, s=256, nh=4, nkv=2, hd=128)
-    narrow = _rand_qkv(jax.random.PRNGKey(6), b=1, s=256, nh=4, nkv=2, hd=64)
-    A._FALLBACKS_SAID.clear()
-    before = collections.Counter(A.TOOK)
-    with mock.patch.object(A, "_pallas_window", spy), \
-         mock.patch.object(jax, "default_backend", lambda: "tpu"), \
-         caplog.at_level(logging.WARNING, logger=A.__name__):
-        out = A.core_attention(q, k, v, window=32)
-        for _ in range(2):
-            A.core_attention(*narrow, window=32)
-        A.core_attention(q, k, v, window=32, impl="xla")  # asked for: not a fallback, nothing said
-    assert calls == [(4, 2, 32)] and A.TOOK - before == {"window_pallas": 1, "window_xla": 3}
-    assert window_attention.block_for(256, 32) == 256
-    np.testing.assert_allclose(np.asarray(out), np.asarray(A.core_attention(q, k, v, window=32, impl="xla")), atol=2e-5)
-    said = [r.getMessage() for r in caplog.records if "XLA attention on a TPU" in r.getMessage()]
-    assert len(said) == 1 and "a window of 32" in said[0] and "head_dim 64" in said[0] and "window kernel" in said[0]
-
-
-# --------------------------------- the window kernels read q as projected (PR 50)
-def _as_projected_cases():
-    """(window, block) pairs the kernels have a form of at 768 tokens (a window
-    of 512 at 128-token blocks would need four blocks before a step's own),
-    each with and without a group, the gate and the rope in the kernel: all
-    eight at a window of 512, and each of the three factors both ways at the
-    other windows."""
-    every = [(g, gate, rope) for g in (1, 8) for gate in (False, True) for rope in (False, True)]
-    some = [(8, True, True), (1, False, True), (8, True, False), (1, True, True), (8, False, False)]
-    return ([(512, 256) + c for c in every] + [(128, 128) + c for c in some] + [(128, 256) + c for c in some[:3]]
-            + [(640, 256) + c for c in some])
-
-
-@pytest.mark.parametrize("window,block,group,gate,rope", _as_projected_cases())
-def test_the_as_projected_window_kernels_are_xlas_band_between_rope_and_gate(window, block, group, gate, rope):
-    """`ops/window_attention.py` on operands as the projections wrote them
-    ((batch, seq, heads x 128), interpret mode), q unturned with its tables
-    (`rope`) or turned before the call, the head's gate in the epilogue (`gate`)
-    or multiplied after the call: output and the gradients of q, k, v and the
-    gate logits against `apply_rotary`, the band mask on XLA's logits and the
-    gate's product, float32, rows at positions that differ by row."""
-    import jax.experimental.pallas.tpu as pltpu
-
-    from galvatron_tpu.ops import attention as A
-    from galvatron_tpu.ops import rope as R
-    from galvatron_tpu.ops import window_attention as W
-
-    b, s, nkv, hd, scale = 2, 768, 1, 128, 0.05
-    q, k, v = _rand_qkv(jax.random.PRNGKey(window + group), b=b, s=s, nh=nkv * group, nkv=nkv, hd=hd)
-    logits = jax.random.normal(jax.random.PRNGKey(3), (b, s, nkv * group))
-    positions = jnp.arange(s)[None] + jnp.array([[0], [11]])
-    assert W.block_for(s, window, block) == block
-
-    def grads(kernel):
-        def f(q, k, v, logits):
-            k = R.apply_rotary(k, positions)
-            if not kernel:
-                out = A.core_attention(R.apply_rotary(q, positions), k, v, window=window, sm_scale=scale, impl="xla")
-                out = out * jax.nn.sigmoid(logits)[..., None]
-                return jnp.sum(jnp.sin(out)), out
-            tables = R.half_split_tables(positions, hd) if rope else None
-            q = q if rope else R.apply_rotary(q, positions)
-            out = W.window_attention(*(t.reshape(b, s, -1) for t in (q, k, v)), tables, logits if gate else None,
-                                     window, scale, block, hd).reshape(q.shape)
-            out = out if gate else out * jax.nn.sigmoid(logits)[..., None]
-            return jnp.sum(jnp.sin(out)), out
-        return jax.grad(f, argnums=(0, 1, 2, 3), has_aux=True)(q, k, v, logits)
-
-    with pltpu.force_tpu_interpret_mode(), jax.default_matmul_precision("highest"):
-        got, want = grads(True), grads(False)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=3e-5)
-
-
-@pytest.mark.parametrize("case,fields,kernels,tables,gates", [
-    ("whole_head_half_split", {}, True, True, True),
-    ("half_rope", {"window_partial_rotary_factor": 0.5}, True, False, True),
-    ("no_head_gate", {"attn_head_gate": False}, True, True, False),
-    ("head_dim_64", {"head_dim": 64}, False, False, False),
-    ("a_bias", {}, False, False, False),
-    ("impl_xla", {"attn_impl": "xla"}, False, False, False),
-])
-def test_a_window_layer_hands_the_kernels_what_they_fuse_and_keeps_the_rest(window_kernels_as_on_a_tpu, case, fields,
-                                                                            kernels, tables, gates):
-    """What `attention_mixer` hands the window call by what it observes: on a
-    TPU at heads of 128 the kernels take q UNTURNED with the rotation's tables
-    and the gate logits where the layer's rope is the half-split turn of whole
-    heads; a rope on half a head keeps `apply_rotary` before the call (the gate
-    still rides); a bias, heads of 64 or `impl="xla"` keep XLA's band, rope
-    before it and the gate's product after it. Counted in `TOOK`, and the
-    mixer's output the same either way."""
-    from galvatron_tpu.models import base as M
-    from galvatron_tpu.models.laguna import laguna_config
-    from galvatron_tpu.ops import attention as A
-
-    cfg = laguna_config(**{**dict(hidden_size=64, num_heads=2, window_num_heads=4, num_kv_heads=2, head_dim=128,
-                                  ffn_hidden=32, dense_ffn_hidden=32, num_layers=5, vocab_size=128, max_seq_len=256,
-                                  num_experts=8, experts_per_token=2, sliding_window=40, init_std=0.2,
-                                  compute_dtype=jnp.float32), **fields})
-    lcfg = cfg.layer_config("window.routed")
-    lp = M.init_layer_params(jax.random.PRNGKey(0), lcfg)
-    y = jax.random.normal(jax.random.PRNGKey(1), (1, 256, 64))
-    positions = jnp.arange(256)[None] + 3
-    bias = jnp.zeros((1, 1, 1, 256)) if case == "a_bias" else None
-    seen = []
-
-    def spy(q_, k_, v_, **kw):  # the kernels' call, answered by XLA's band on what they would compute
-        seen.append((kw.get("q_rope") is not None, kw.get("head_gate") is not None))
-        if kw.get("q_rope") is not None:
-            q_ = apply_rotary(q_, positions, lcfg.rope_theta)
-        out = A._xla_attention(q_, A.repeat_kv(k_, 2), A.repeat_kv(v_, 2), causal=True, sm_scale=kw["sm_scale"],
-                               window=kw["window"])
-        return out if kw.get("head_gate") is None else out * jax.nn.sigmoid(kw["head_gate"])[..., None]
-
-    run = lambda: M.MIXERS["window"].forward(  # noqa: E731
-        lp, y, positions, lcfg, mesh=None, axes=None, attn_bias=bias, attn_sharding=None, return_kv=False)[0]
-    want = run()  # the CPU's path
-    before = collections.Counter(A.TOOK)
-    with mock.patch.object(A, "_pallas_window", spy), window_kernels_as_on_a_tpu():
-        got = run()
-    assert seen == ([(tables, gates)] if kernels else [])
-    took = {"window_pallas": 1, **({"window_as_projected": 1} if tables else {})} if kernels else {"window_xla": 1}
-    assert A.TOOK - before == took
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-
-
-@pytest.mark.parametrize("how", [dict(interleaved=True), dict(rotary_dim=64), dict(scaling={
-    "rope_type": "yarn", "factor": 8, "original_max_position_embeddings": 16, "beta_fast": 4, "beta_slow": 1,
-    "attention_factor": 1.2})], ids=["interleaved", "half_rope", "yarn"])
-def test_a_rotation_that_is_no_product_with_two_tables_has_none(how):
-    """`half_split_tables` is `apply_rotary`'s half-split turn of whole heads
-    at the plain frequencies as `x * cos + roll(x, half) * sin`, and None for
-    every other rotation: the caller turns q itself then."""
-    from galvatron_tpu.ops.rope import half_split_tables
-
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 3, 128))
-    positions = jnp.arange(24)[None] * jnp.array([[1], [5]])
-    cos, sin = half_split_tables(positions, 128, 500.0)
-    assert cos.dtype == sin.dtype == jnp.float32 and cos.shape == sin.shape == (2, 24, 128)
-    turned = x * cos[:, :, None] + jnp.roll(x, 64, axis=-1) * sin[:, :, None]
-    np.testing.assert_allclose(np.asarray(turned), np.asarray(apply_rotary(x, positions, 500.0)), atol=1e-6)
-    assert half_split_tables(positions, 128, 500.0, rotary_dim=128) is not None
-    assert half_split_tables(positions, 128, 500.0, **how) is None
-
-
-def test_tables_and_gate_logits_ride_the_window_kernels_alone(window_kernels_as_on_a_tpu):
-    from galvatron_tpu.ops import attention as A
-    from galvatron_tpu.ops.rope import half_split_tables
-
-    q, k, v = _rand_qkv(jax.random.PRNGKey(8), b=1, s=128, nh=2, hd=128)
-    tables, logits = half_split_tables(jnp.arange(128)[None], 128), jnp.zeros((1, 128, 2))
-    assert not A.window_takes_kernels(q.shape, k.shape, window=4)  # off a TPU
-    with pytest.raises(ValueError, match="ride the window kernels alone"):
-        A.core_attention(q, k, v, window=4, q_rope=tables)
-    with pytest.raises(ValueError, match="ride the window kernels alone"):
-        A.core_attention(q, k, v, window=4, head_gate=logits)
-    with pytest.raises(ValueError, match="without a window the caller turns q"):
-        A.core_attention(q, k, v, head_gate=logits)
-    with window_kernels_as_on_a_tpu() as on_a_tpu:
-        pass
-    assert on_a_tpu(q.shape, k.shape, window=4) and not on_a_tpu(q.shape, k.shape, window=4, biased=True)
-    assert not on_a_tpu(q.shape, k.shape, window=4, impl="xla") and not on_a_tpu((1, 100, 2, 128), k.shape, window=4)
-    assert not on_a_tpu((1, 128, 2, 64), (1, 128, 2, 64), window=4)

@@ -1,16 +1,16 @@
 """What the TPU compiler makes of a scanned run's weight gradients over dp
 (PR 55), compiled for a DESCRIBED v5e 2x2 with no chip attached, as
-tests/ops/test_tpu_compile.py does: `obs/compiled.dp_grad_sums_mb` reads the
+tests/ops/test_tpu_compile_steps.py does: `obs/compiled.dp_grad_sums_mb` reads the
 compiled step's text here the way the trainer's `compile` event reads it
 (`dp_grad_all_reduce_mb`, `dp_grad_reduce_scatter_mb`)."""
 
 import jax.numpy as jnp
 import pytest
 
-from galvatron_tpu.models import base as M
 from galvatron_tpu.obs import compiled as C
+from galvatron_tpu.obs import forms
 from galvatron_tpu.parallel.mesh import vocab_axes
-from tests.ops.test_tpu_compile import _model_and_compiled_step, v5e_2x2  # noqa: F401  (the fixture)
+from tests.ops.tpu_compile import _model_and_compiled_step, v5e_2x2  # noqa: F401  (the fixture)
 
 # name -> (layout flags, whether the scanned cotangent is asked for in ZeRO's
 # layout, the kinds of sum the backward body may hold over dp)
@@ -42,10 +42,10 @@ def test_the_scanned_layers_gradients_are_summed_into_zeros_shards_on_v5e(v5e_2x
     cfg = llama_config("llama-0.3b", num_layers=2, hidden_size=1024, num_heads=8, ffn_hidden=2048,
                        vocab_size=32000, max_seq_len=256, compute_dtype=jnp.bfloat16)
     hp = HybridParallelConfig.uniform(4, 2, tp=2, vocab_tp=2, global_bsz=8, mixed_precision="bf16", **flags)
-    before = sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values())
-    model, step = _model_and_compiled_step(cfg, hp, v5e_2x2, batch_rows=8)
-    # two norm scales and four kernels a layer, once a traced microbatch
-    assert sum(M.SCAN_GRADS_IN_ZERO_LAYOUT.values()) - before == (6 * hp.chunks if asked else 0)
+    with forms.recording() as took:
+        model, step = _model_and_compiled_step(cfg, hp, v5e_2x2, batch_rows=8)
+    # two norm scales and four kernels a layer (the same leaves in every traced microbatch)
+    assert took[forms.SCAN_GRADS]["zero_layout"] == (6 if asked else 0)
     dp_groups = C.axis_groups(model.mesh, vocab_axes(hp).dp)
     assert dp_groups == {frozenset({0, 2}), frozenset({1, 3})}
     sums = C.scan_grad_sums(step.as_text(), [dp_groups])

@@ -18,7 +18,6 @@ cases under `pltpu.force_tpu_interpret_mode()` at 256 channels in blocks of
 recurrence AND to the XLA form by the same limit (measured worst 3.9e-7).
 """
 
-import collections
 import contextlib
 
 import jax
@@ -28,6 +27,7 @@ import pytest
 
 from jax.experimental.pallas import tpu as pltpu
 
+from galvatron_tpu.obs import forms
 from galvatron_tpu.ops import selective_scan as op
 from galvatron_tpu.ops.selective_scan import CHUNK, selective_scan
 
@@ -77,10 +77,6 @@ def taking(form, monkeypatch):
         yield
 
 
-def took_since(before):
-    return dict(op.TOOK - before)
-
-
 def worst(got, want):
     return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
 
@@ -100,10 +96,9 @@ def test_the_chunked_scan_is_the_recurrence(tokens, chunk, form, monkeypatch):
     backward's `BLOCK`: outputs, final states and the counter. The kernels on
     a batch of 2 and two blocks of channels, against the XLA form too."""
     ops = operands(tokens, form=form)
-    before = collections.Counter(op.TOOK)
-    with taking(form, monkeypatch):
+    with forms.recording() as took, taking(form, monkeypatch):
         m, last, peak = selective_scan(*ops, chunk=chunk, impl=form)
-    assert took_since(before) == {form: 1}
+    assert took == {forms.SELECTIVE_SCAN: {form: 1}}
     want_m, want_last = recurrence(*ops)
     assert m.shape == want_m.shape and last.shape == want_last.shape
     assert worst(m, want_m) < TOL and worst(last, want_last) < TOL
@@ -161,12 +156,12 @@ def test_the_carried_state_is_float32_under_bf16_compute(form, monkeypatch):
 @pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16], ids=["float32_state", "bf16_state"])
 def test_auto_takes_the_xla_form_off_a_tpu_and_says_so(state_dtype, monkeypatch):
     """On the CPU, and for a state that is not float32 anywhere, `impl="auto"`
-    is the XLA form at widths the kernels would take, and `TOOK` counts it."""
+    is the XLA form at widths the kernels would take, and says so."""
     monkeypatch.setattr(op, "CHANNELS", KERNEL_BLOCK)
     ops = operands(CHUNK, seed=4, form="pallas")
-    before = collections.Counter(op.TOOK)
-    got = selective_scan(*ops, state_dtype=state_dtype)
-    assert took_since(before) == {"xla": 1}
+    with forms.recording() as took:
+        got = selective_scan(*ops, state_dtype=state_dtype)
+    assert took == {forms.SELECTIVE_SCAN: {"xla": 1}}
     want = selective_scan(*ops, state_dtype=state_dtype, impl="xla")
     assert all(bool(jnp.all(g == w)) for g, w in zip(got, want))
 

@@ -7,7 +7,6 @@ parameter, `mu` and `nu` into the gradient's and back every step. The state,
 its shardings and a checkpoint are untouched. Compile-only checks against a
 described v5e (no chip: nothing runs there) and value checks on the CPU."""
 
-import collections
 import importlib.util
 import os
 import re
@@ -25,7 +24,7 @@ from galvatron_tpu.models.gpt import gpt_config
 from galvatron_tpu.models.llama import llama_config
 from galvatron_tpu.models.olmoe import olmoe_config
 from galvatron_tpu.models.parts import mlp
-from galvatron_tpu.obs import report, telemetry
+from galvatron_tpu.obs import forms, report, telemetry
 from galvatron_tpu.parallel.mesh import build_mesh
 from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
 from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
@@ -115,10 +114,10 @@ def test_unrolled_swiglu_layers_no_longer_move_their_state_for_v5e(v5e, monkeypa
         _left_to_the_compiler(monkeypatch)
     model = construct_hybrid_parallel_model(_swiglu(), _alone(), v5e[:1])
     tx = _tx()
-    took = sum(mlp.RELAID.values())
-    step = _compiled(model, tx)
+    with forms.recording() as took:
+        step = _compiled(model, tx)
     text = step.as_text()
-    assert sum(mlp.RELAID.values()) - took == (2 if relaid else 0)
+    assert took[forms.GATED_KERNEL_GRADS]["as_stored"] == (2 if relaid else 0)
     assert len(_moved(text, "%d,2,%d" % (H, F))) == (0 if relaid else 6)
     entry = re.findall(r"%%(?:params|opt_state)\S* = f32\[%d,2,%d\](\{[^ ]*\}) parameter\(" % (H, F), text)
     assert len(entry) == 6 and all(lay.startswith("{2,1,0:T(2,128)") for lay in entry), entry
@@ -147,9 +146,9 @@ def test_a_scanned_run_is_left_as_it_is_for_v5e(v5e):
     lays out after the state by itself (one fused relayout of a layer's
     gradient in the loop, no copy of the state): nothing is asked for."""
     model = construct_hybrid_parallel_model(_swiglu(), _scanned(), v5e[:1])
-    took = sum(mlp.RELAID.values())
-    text = _compiled(model, _tx()).as_text()
-    assert sum(mlp.RELAID.values()) == took
+    with forms.recording() as took:
+        text = _compiled(model, _tx()).as_text()
+    assert forms.GATED_KERNEL_GRADS not in took
     assert not _moved(text, "%d,2,%d" % (H, F))  # no copy of a leaf of the state
     in_the_loop = [op for op in compiled_steps.copy_ops(text) if op[:2] == ("f32", "1,%d,2,%d" % (H, F))]
     assert len(in_the_loop) == 1 and not in_the_loop[0][4]  # the layer's gradient, fused into the stack's write
@@ -190,9 +189,9 @@ def test_tp2_dp2_zero2_with_unrolled_layers_compiles_for_a_v5e_2x2(v5e, monkeypa
     hp = HybridParallelConfig(world_size=4, pp=1, global_bsz=4, default_dp_type="zero2",
                               layers=[LayerStrategy(tp=2, checkpoint=i % 2) for i in range(2)])
     model = construct_hybrid_parallel_model(_swiglu(), hp, v5e)
-    took = sum(mlp.RELAID.values())
-    step = _compiled(model, _tx(), batch=4)
-    assert sum(mlp.RELAID.values()) - took == (2 if relaid else 0)
+    with forms.recording() as took:
+        step = _compiled(model, _tx(), batch=4)
+    assert took[forms.GATED_KERNEL_GRADS]["as_stored"] == (2 if relaid else 0)
     shares = [op for op in compiled_steps.copy_ops(step.as_text())
               if op[0] == "f32" and op[4] and op[1].endswith(",2,%d" % (F // 2))]
     SHARDED[relaid] = (len(shares), step.memory_analysis().temp_size_in_bytes)
@@ -236,11 +235,11 @@ def test_the_rule_reads_the_parts_statement_the_runs_and_the_platform(v5e, name)
     runs = layer_runs(hp, M.model_layer_kinds(cfg))
 
     def relaid(mesh, scan=hp.scan_layers):
-        took = collections.Counter(mlp.RELAID)
-        out = jax.eval_shape(lambda ls: M._gated_grads_as_stored(
-            ls, runs, lambda run: scan and run.length >= 2, cfg, mesh), layers)
+        with forms.recording() as took:
+            out = jax.eval_shape(lambda ls: M._gated_grads_as_stored(
+                ls, runs, lambda run: scan and run.length >= 2, cfg, mesh), layers)
         assert jax.tree.structure(out) == jax.tree.structure(layers)
-        return len(mlp.RELAID - took)
+        return took[forms.GATED_KERNEL_GRADS]["as_stored"]
 
     on_chip = build_mesh(hp, v5e[:1])
     assert relaid(on_chip) == (kernels if unrolled else 0)  # all of a model's gated kernels or none
@@ -287,8 +286,8 @@ def test_a_relaid_gradient_is_the_gradient_the_jaxpr_states(dtype, held_in):
 
 
 def test_the_compile_event_counts_the_kernels_and_the_report_prints_it():
-    assert "kernel_grads_relaid" in telemetry.EVENT_SCHEMAS["compile"][1]
+    assert "forms" in telemetry.EVENT_SCHEMAS["compile"][1]
     events = [{"v": 1, "t": 0.0, "seq": 0, "type": "compile", "trace_ms": 1.0, "compile_ms": 2.0,
-               "kernel_grads_relaid": 10}]
+               "forms": {forms.GATED_KERNEL_GRADS: {"as_stored": 10}}}]
     text = report.render(report.analyze(events))
-    assert "gated kernels whose gradient is relaid to the state's layout: 10" in text
+    assert "gated_kernel_grads: as_stored x 10" in text
