@@ -34,7 +34,6 @@ from galvatron_tpu.profiler.runtime import (
     compiled_step_memory_mb,
     device_memory_stats,
 )
-from galvatron_tpu.runtime import checkpoint as ckpt
 from galvatron_tpu.runtime import health as hlth
 from galvatron_tpu.runtime import resilience as rsl
 from galvatron_tpu.runtime.dataloader import get_train_iterator
@@ -54,6 +53,79 @@ launch.IMPORTS.done()  # the program is imported: the import record closes and g
 # an exact-text hit on the same devices is semantically the same program.
 _STEP_EXECUTABLES: "OrderedDict" = OrderedDict()
 _STEP_EXECUTABLES_MAX = 16
+
+
+def _import_checkpoint():
+    """The import itself: `runtime/checkpoint` with `orbax.checkpoint`, `tensorstore`, `grpc` and the
+    `google.cloud.logging` orbax pulls beneath it (12 of a warm start's 17 s of import on the chip's
+    host: PERF.md section 5). No module-scope import of it may come back into this module or into what
+    this module imports (tests/obs/test_checkpoint_import.py); the tests slow and break it here."""
+    from galvatron_tpu.runtime import checkpoint
+
+    return checkpoint
+
+
+class CheckpointModule:
+    """`runtime/checkpoint` for one `train()`, imported when the run first needs it; calling it is
+    the one way `_train` reaches the module. Which of four ways it went, by what `args` say:
+
+    `never`       neither --load nor --save: nobody calls it, and the process never holds orbax
+    `at_load`     --load: the restore calls it inside `gt/launch/restore`; such a start pays the
+                  import as every start did before
+    `background`  --save: `start()` once the first step is dispatched (the device is busy and the
+                  host waits; not before, so the step's trace and lowering have the interpreter to
+                  themselves) imports on a daemon thread, and every call joins it first: a save
+                  waits for what is LEFT of the import, a preemption's save among them
+    `at_use`      a use before any of that (a save before the first step, a second use after a
+                  failed import): on the spot
+
+    `import_s` is what the import took where it ran (None while it runs), `waited_s` what the
+    first use waited for the thread. An exception of the import is raised at the first use, with
+    the traceback it had on its thread."""
+
+    def __init__(self):
+        self.how = "never"
+        self.import_s: Optional[float] = None
+        self.waited_s: Optional[float] = None
+        self._module = None
+        self._error: Optional[BaseException] = None
+        self._thread: Optional[threading.Thread] = None
+
+    def _import(self):
+        t = time.perf_counter()
+        try:
+            self._module = _import_checkpoint()
+        except BaseException as e:  # handed to the thread that uses the module
+            self._error = e
+        self.import_s = time.perf_counter() - t
+
+    def start(self):
+        """Import on a helper thread from now on, unless the run has imported it already."""
+        if self.how == "never":
+            self.how = "background"
+            self._thread = threading.Thread(target=self._import, name="gt-checkpoint-import", daemon=True)
+            self._thread.start()
+
+    def join(self):
+        if self._thread is not None:
+            self._thread.join()
+
+    def __call__(self, how: str = "at_use"):
+        if self._thread is not None:
+            t = time.perf_counter()
+            self._thread.join()
+            if self.waited_s is None:
+                self.waited_s = time.perf_counter() - t
+        elif self._module is None:
+            self.how, self._error = how, None
+            self._import()
+        if self._error is not None:
+            raise self._error
+        return self._module
+
+    def fields(self) -> dict:
+        """The summary's and the `launch` event's `checkpoint_import`."""
+        return {"how": self.how, "import_s": self.import_s, "waited_s": self.waited_s}
 
 
 def _step_exec_key(mesh, lowered):
@@ -210,6 +282,7 @@ def _train(args, started: launch.Launch) -> dict:
     # the launch's phases (obs/launch.py), consecutive from here to the first
     # step's drain; what runs between two of them is `launch_unspanned_pct`
     started.begin(control, tracing.LAUNCH_PLAN)
+    ckpt = CheckpointModule()  # runtime/checkpoint, imported when this run first needs it
     cache_path = enable_persistent_cache()
     if jax.process_index() == 0:
         print("persistent compilation cache: %s" % cache_path)
@@ -434,7 +507,7 @@ def _train(args, started: launch.Launch) -> dict:
                 hp=None, params_target=None, params_shardings=None,
                 opt_state_target=None, opt_state_shardings=None,
             )
-        return ckpt.load_checkpoint(ckpt_dir, iteration, **kwargs)
+        return ckpt("at_load").load_checkpoint(ckpt_dir, iteration, **kwargs)
 
     start_iter = 0
     if args.load:
@@ -754,8 +827,9 @@ def _train(args, started: launch.Launch) -> dict:
         if emergency:
             meta["emergency"] = True
             meta["signal"] = preempt.signal_name if preempt else None
+        save_checkpoint = ckpt().save_checkpoint  # what is left of the import is no attempt of the save's
         rsl.with_retry(
-            lambda: ckpt.save_checkpoint(
+            lambda: save_checkpoint(
                 args.save, iteration, params, opt_state, hp, train_meta=meta,
                 keep_latest_k=getattr(args, "keep_latest_k", 0) or None,
                 provenance=provenance,
@@ -823,7 +897,7 @@ def _train(args, started: launch.Launch) -> dict:
         if started.open:
             # the first step has drained: the launch is over, its listeners go
             prof.launch = started.finish()
-            telemetry.emit("launch", **prof.launch)
+            telemetry.emit("launch", **prof.launch, checkpoint_import=ckpt.fields())
         if wd is not None:
             # a drain is the loop's liveness signal AND the deadline's
             # training data (the learned budget tracks the steady step time)
@@ -970,7 +1044,7 @@ def _train(args, started: launch.Launch) -> dict:
                 return True
             if not need_rollback:
                 continue
-            intact = ckpt.intact_iterations(args.save) if args.save else []
+            intact = ckpt().intact_iterations(args.save) if args.save else []
             if res.rollbacks >= guard.cfg.max_rollbacks or not intact:
                 raise rsl.TrainingAnomalyError(
                     "persistent training anomalies at iteration %d "
@@ -1289,6 +1363,10 @@ def _train(args, started: launch.Launch) -> dict:
                     params, opt_state, metrics = compiled_step(params, opt_state, batch)
             disp_ms = prof.dispatched(it)
             inflight.append((it, metrics, disp_ms, fetch.ms))
+            if args.save:
+                # the first step is on the device: a run that will save imports the
+                # checkpoint module behind its first steps (once: CheckpointModule.start)
+                ckpt.start()
             if wd is not None:
                 wd.arm(it, "inflight", inflight=len(inflight))
             it += 1
@@ -1327,6 +1405,7 @@ def _train(args, started: launch.Launch) -> dict:
         # still in flight behind the last dispatch
         prof.loop_fence((params, opt_state))
     finally:
+        ckpt.join()  # the helper thread of a run that ends before its first save
         close_stream()
         control.close()
         prof.close()
@@ -1342,6 +1421,8 @@ def _train(args, started: launch.Launch) -> dict:
     if last_shared:  # a model whose layers publish: its two layer counts and the last step's counters
         summary.update(shared_counts(cfg), **last_shared)
     summary["resilience"] = res.as_dict()
+    # how this run came by runtime/checkpoint, as it stands now (the `launch` event: at the first drain)
+    summary["checkpoint_import"] = ckpt.fields()
     if tuner is not None:
         summary["autotune"] = {"plans": tuner.plans, "swaps": tuner.swaps}
     if wd is not None:

@@ -14,7 +14,8 @@ is the measurement substrate that closes it at runtime:
 - ``obs.launch``      — where a start went: the program's import by package,
   the phases of ``cli/train._train`` up to the first drained step, and jax's
   trace / lower / compilation-cache counters on the way (the ``launch`` event,
-  the summary's ``launch_ms`` / ``launch_imports`` / ``launch_jit``).
+  the summary's ``launch_ms`` / ``launch_imports`` / ``launch_jit``; beside them
+  ``checkpoint_import``, how the run came by ``runtime/checkpoint``: cli/train.py).
 - ``obs.report``      — offline analysis of a telemetry JSONL
   (``python -m galvatron_tpu.cli report``): steady-state detection, MFU,
   lifecycle timeline, divergence table.

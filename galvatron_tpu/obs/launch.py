@@ -13,7 +13,11 @@ subcommand), so it covers the import of the program as
 pay it: total seconds, modules loaded, SELF seconds by top-level package
 (finding, creating and executing a module less the imports nested in it:
 `python -X importtime`'s "self") and the INCLUSIVE seconds of
-`CHECKPOINT_MODULE`. It is two `sys.monitoring` events on ONE code object,
+`CHECKPOINT_MODULE`: 0.0 since PR 60, when the trainer stopped importing that
+module with itself (a run imports it when it first loads or saves,
+`cli/train.CheckpointModule`, whose `checkpoint_import` stands beside this
+record in the summary and the `launch` event), and the guard against its
+coming back. It is two `sys.monitoring` events on ONE code object,
 importlib's `_find_and_load`, which the interpreter calls for every module it does not
 hold yet: nothing stands in `sys.meta_path` or `builtins`, no spec or loader
 is touched, it imports nothing itself, only the thread that installed it is
@@ -53,7 +57,7 @@ PACKAGES = ("jax", "jaxlib", "google", "orbax", "tensorstore", "grpc", "flax", "
             "galvatron_tpu")
 OTHER = "other"
 # the module whose import is also read inclusively: the one that pulls
-# `orbax.checkpoint`, which a run that neither loads nor saves pays for nothing
+# `orbax.checkpoint`, which no import of the program may pay (module docstring)
 CHECKPOINT_MODULE = "galvatron_tpu.runtime.checkpoint"
 
 

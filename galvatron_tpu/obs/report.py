@@ -348,8 +348,11 @@ def _fmt(v) -> str:
     return str(v)
 
 
-def _render_launch(launch: Dict[str, Any]) -> List[str]:
-    """The `launch` event as a table: why this (re)launch took what it took."""
+def _render_launch(launch: Dict[str, Any], summary: Optional[Dict[str, Any]] = None) -> List[str]:
+    """The `launch` event as a table: why this (re)launch took what it took.
+    `checkpoint_import` is read from the run's summary where the stream has
+    one: the event is written at the first drain, when a helper thread's
+    import may still be under way."""
     ms = dict(launch.get("launch_ms") or {})
     total = ms.pop("total", None)
     spanned = sum(ms.values())
@@ -365,6 +368,10 @@ def _render_launch(launch: Dict[str, Any]) -> List[str]:
             ", ".join("%s %.2f" % kv for kv in by_package)))
         lines.append("  of it galvatron_tpu.runtime.checkpoint (orbax), inclusive: %s s"
                      % _fmt(imports["checkpoint_s"]))
+    imported = (summary or {}).get("checkpoint_import") or launch.get("checkpoint_import")
+    if imported:
+        lines.append("  the run's own import of it: %s, %s s where it ran, the first use waited %s s" % (
+            imported["how"], _fmt(imported.get("import_s")), _fmt(imported.get("waited_s"))))
     jit = launch.get("launch_jit")
     if jit:
         lines.append(
@@ -406,7 +413,7 @@ def render(analysis: Dict[str, Any]) -> str:
            _fmt(steady.get("mfu")))
     )
     if analysis.get("launch"):
-        lines.extend(_render_launch(analysis["launch"]))
+        lines.extend(_render_launch(analysis["launch"], analysis.get("summary")))
     comp = analysis["compile"]
     if comp:
         lines.append(

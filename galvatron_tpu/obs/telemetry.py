@@ -108,8 +108,11 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
     # gt/launch/* and gt/compile/*) and `total`, its entry to that drain;
     # `launch_imports` the program's import (total_s, modules, by_package_s,
     # checkpoint_s); `launch_jit` what jax traced, lowered and asked its
-    # compilation cache for on the way (counts, seconds, top_traced)
-    "launch": ((), ("launch_ms", "launch_imports", "launch_jit")),
+    # compilation cache for on the way (counts, seconds, top_traced);
+    # `checkpoint_import` how the run came by runtime/checkpoint, as it stood
+    # at that drain (cli/train.CheckpointModule: how, import_s, waited_s; the
+    # run_end summary's field of the same name is the run's last word)
+    "launch": ((), ("launch_ms", "launch_imports", "launch_jit", "checkpoint_import")),
     # the per-step record (emitted at drain time under the dispatch-ahead
     # loop; iter_ms is dispatch->drain latency, which overlaps across steps)
     "step": (

@@ -11,7 +11,9 @@ training command with a sink from the start and ends the run after two steps
 and prints the event as one line of JSON, its last line of output (one cell a
 process: a second model does not fit beside the first's state). The line
 before it is the run's `launch` event (`launch_ms`, `launch_imports`,
-`launch_jit`: where the start went, obs/launch.py)."""
+`launch_jit`: where the start went, obs/launch.py; `checkpoint_import`: how
+the run came by `runtime/checkpoint`, "never" here, where nothing is loaded
+or saved)."""
 
 from __future__ import annotations
 
