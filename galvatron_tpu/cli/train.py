@@ -37,7 +37,7 @@ from galvatron_tpu.profiler.runtime import (
 from galvatron_tpu.runtime import health as hlth
 from galvatron_tpu.runtime import resilience as rsl
 from galvatron_tpu.runtime.dataloader import get_train_iterator
-from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model
+from galvatron_tpu.runtime.model_api import construct_hybrid_parallel_model, scan_stacks_are_tight
 from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
 from galvatron_tpu.runtime.prefetch import PrefetchIterator, PrefetchStalledError
 from galvatron_tpu.utils.compile_cache import enable_persistent_cache
@@ -165,6 +165,20 @@ def shared_counts(cfg) -> dict:
         return {"mamba_layers": 0, "shared_readers": 0}
     return {"mamba_layers": sum(kind.startswith("mamba1") for kind in cfg.layer_kinds()),
             "shared_readers": sum(bool(read) for _, read in cfg.shared())}
+
+
+def eva_counts(cfg) -> dict:
+    """{eva_layers, eva_windows, eva_pooled_keys} of a config with EVA attention layers (models/parts/eva.py):
+    how many they are, the windows a sequence of `max_seq_len` is cut into, and a sequence's pooled keys, the
+    chunks of all windows but the last (what a query of the last window meets beside its own window's keys);
+    0, 0, 0 for any other."""
+    kinds = cfg.layer_kinds() if hasattr(cfg, "layer_kinds") else ()
+    layers = sum(kind.startswith("eva.") for kind in kinds)
+    if not layers:
+        return {"eva_layers": 0, "eva_windows": 0, "eva_pooled_keys": 0}
+    windows = -(-cfg.max_seq_len // cfg.eva_window)
+    return {"eva_layers": layers, "eva_windows": windows,
+            "eva_pooled_keys": (windows - 1) * (cfg.eva_window // cfg.eva_chunk)}
 
 
 def optimizer_args_from(args) -> OptimizerArgs:
@@ -578,6 +592,8 @@ def _train(args, started: launch.Launch) -> dict:
                 telemetry.runtime_log(
                     "sdc_check=vote downgraded to digest: %s" % reason)
                 sdc_mode = "digest"
+        # (decided here, before anything is traced: the `compile` event's `forms` says `scan_grads`: `compute_dtype`)
+        model.hp.narrow_scan_grads = scan_stacks_are_tight(model, tx)
         fn = model.make_train_step(
             tx, guard_anomalies=guard is not None, sdc_check=sdc_mode)
         if hooks is not None and hooks.wrap_step_fn:
@@ -645,6 +661,9 @@ def _train(args, started: launch.Launch) -> dict:
                 # and the layers that read a tensor an EARLIER layer published beside the residual
                 # stream (`TransformerConfig.shared`); absent where the model has none
                 **{k: v or None for k, v in shared_counts(cfg).items()},
+                # the layers whose token mixer is EVA attention (models/parts/eva.py), the windows a
+                # sequence is cut into and a sequence's pooled keys; absent where the model has none
+                **{k: v or None for k, v in eva_counts(cfg).items()},
                 # what the COMPILER made of `forms`' scan_grads: MB a chip and a layer of
                 # the weight gradients over 1 MB that the compiled step sums
                 # over dp inside a scanned run's backward, whole onto every
@@ -882,7 +901,7 @@ def _train(args, started: launch.Launch) -> dict:
             **{k: float(metrics[k])
                for k in (telemetry.EXPERT_STEP_FIELDS + telemetry.SHARE_STEP_FIELDS
                          + telemetry.LINEAR_STEP_FIELDS + telemetry.SSM_STEP_FIELDS
-                         + telemetry.SHARED_STEP_FIELDS)
+                         + telemetry.SHARED_STEP_FIELDS + telemetry.EVA_STEP_FIELDS)
                if isinstance(metrics, dict) and k in metrics},
         )
 

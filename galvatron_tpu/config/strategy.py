@@ -401,6 +401,14 @@ class HybridParallelConfig:
     # "remat_policy" key. A non-default value shadowed by serialized
     # per-layer policies is inert and warns GLS103 (strategy_lint).
     remat_policy: str = "full"
+    # A scanned run's stacked COTANGENT in the compute dtype: the run casts
+    # the leaves it reads through a cast (parallel/spec.cast_first_tree)
+    # BEFORE it stacks them, so the scan's backward stacks what the matmuls
+    # yield and each layer's slice is widened on its way to the update. No
+    # flag sets it: the launch does as it builds the step, where the state and
+    # the float32 stacks would leave the device too little for everything
+    # else (runtime/model_api.scan_stacks_are_tight).
+    narrow_scan_grads: bool = False
     tp_comm_mode: str = "gspmd"  # TP_COMM_MODES: TP-collective execution path
     tp_comm_quant: str = "none"  # COMM_DTYPES: wire precision of the manual
     # TP ring payloads (parallel/tp_shard_map.py); requires a manual

@@ -33,9 +33,10 @@ from galvatron_tpu.config.strategy import (
     model_layer_kinds,
 )
 from galvatron_tpu.models.config import TransformerConfig
-from galvatron_tpu.models.parts import MIXERS, MLP_HALVES, unsupported_reason
+from galvatron_tpu.models.parts import COUNTERS, MIXERS, MLP_HALVES, unsupported_reason
 from galvatron_tpu.models.parts.common import Params, _dense, _dense_init, _norm, _norm_params
-from galvatron_tpu.models.parts.embed_head import (embed_patches, embed_tokens, head_logits, model_head, softmax_nll,
+from galvatron_tpu.models.parts.embed_head import (embed_patches, embed_tokens, head_logits, model_head,
+                                                   next_tokens_cross_entropy, softmax_nll,
                                                    vocab_parallel_cross_entropy)
 from galvatron_tpu.models.parts.mlp import ROUTER_BIAS, grad_as_stored
 from galvatron_tpu.obs import forms, tracing
@@ -123,7 +124,7 @@ def init_model_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         }
     if cfg.head_type in ("lm", "mlm") and not cfg.tie_embeddings:
         params["lm_head"] = {
-            "kernel": _dense_init(ks[n + 2], (h, cfg.vocab_size), cfg.init_std, cfg.param_dtype)
+            "kernel": _dense_init(ks[n + 2], (h, cfg.pred_heads * cfg.vocab_size), cfg.init_std, cfg.param_dtype)
         }
     return params
 
@@ -407,6 +408,15 @@ def run_layers(
     its boundary, whole, and the constraint is then the slice `to_accum`
     would take after the scan, taken a layer earlier.
 
+    ``hp.narrow_scan_grads`` (set by the launch where the state and the
+    float32 stacks would leave the device too little for the rest of the
+    step, `runtime/model_api.scan_stacks_are_tight`; no flag): a scanned run casts the leaves it reads through a cast
+    (`spec.cast_first_tree`) to the compute dtype BEFORE stacking them. The
+    forward reads the same values; the scan's backward stacks the cotangents
+    as the matmuls yield them, in the compute dtype (what an unrolled layer
+    hands the update), where it otherwise stacks them widened: half the bytes
+    of the one array of the step that is as large as the float32 parameters.
+
     A config with ``layer_aux`` returns ``(x, auxs)``, the layers' auxiliary
     terms (the routers', the linear mixers' counters) a layer or a scanned
     run, for `_fold_aux`. Any other config carries nothing and traces what
@@ -494,7 +504,15 @@ def run_layers(
             return unrolled(x, run.layer_indices)
         lcfg = cfg.layer_config(kinds[run.start])  # a run is of one kind
         axes = layer_axes(hp, run.start) if use_hp else None
-        stacked = stack_layer_run([layers[i] for i in run.layer_indices])
+        members = [layers[i] for i in run.layer_indices]
+        if use_hp and hp.narrow_scan_grads:
+            # a layer at a time, before the stack: the cotangent is then sliced and widened a layer at a time
+            # too, and no float32 stack stands beside the state (the body's own cast of such a leaf is the identity)
+            cast_first = S.cast_first_tree(layer_param_specs(lcfg, axes), table_stored=False)
+            members = [jax.tree.map(lambda first, t: t.astype(lcfg.compute_dtype) if first else t, cast_first, lp)
+                       for lp in members]
+            forms.took(forms.SCAN_GRADS, "compute_dtype", key=(k, "narrow"))
+        stacked = stack_layer_run(members)
         if use_hp:
             read_as = stacked_layer_param_specs(lcfg, axes)
             # the gradient where ZeRO keeps it, or (no split state) where the
@@ -583,6 +601,8 @@ def _fold_aux(auxs: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
         "window_fallbacks": lambda n: total(n, jnp.sum),
         "counts": lambda n: jnp.concatenate([jnp.atleast_2d(a[n]) for a in auxs if n in a]),
     }
+    folds = {"mean": mean, "max": lambda n: total(n, jnp.max), "sum": lambda n: total(n, jnp.sum)}
+    fold.update({name: folds[how] for name, how in COUNTERS.items()})  # the parts' own (parts/__init__.COUNTERS)
     return {name: fold[name](name) for name in dict.fromkeys(n for a in auxs for n in a)}
 
 
@@ -692,6 +712,7 @@ PART_FOLDS = {EXPERT_LOAD: jnp.maximum, "router_bias_abs_max": jnp.maximum,
               ROUTER_COUNTS: jnp.add, "expert_rows_held": jnp.add, "expert_window_fallbacks": jnp.add,
               "linear_state_abs_max": jnp.maximum, "ssm_state_abs_max": jnp.maximum,
               "selscan_state_abs_max": jnp.maximum, "published_mib": jnp.add}
+# (a part's counter folded as a "mean", `parts.COUNTERS`, is weighted as a loss term is)
 
 
 def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
@@ -708,7 +729,8 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
     returns `(loss, parts)`: the terms, the worst block's expert load and
     the other counters of the `step` event (`telemetry.EXPERT_STEP_FIELDS`,
     `LINEAR_STEP_FIELDS` for linear-attention layers, `SSM_STEP_FIELDS` for
-    state-space layers, `SHARED_STEP_FIELDS` for Mamba-1 layers and what layers publish),
+    state-space layers, `SHARED_STEP_FIELDS` for Mamba-1 layers and what layers publish,
+    and the counters the parts' own table names, `parts.COUNTERS`),
     and for a router with a bias the blocks' assignment counts
     (`ROUTER_COUNTS`), which the train step moves the bias by."""
     logits, hidden, auxs = _forward(
@@ -718,7 +740,10 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
     )
     labels, mask = batch["labels"], batch.get("loss_mask")
     with jax.named_scope(tracing.HEAD_LOSS):
-        loss = vocab_parallel_cross_entropy(logits, labels, mask)
+        if cfg.pred_heads > 1:  # a head of several predictions a position: the mean of the heads' means
+            loss = next_tokens_cross_entropy(logits, labels, mask, cfg.pred_heads)
+        else:
+            loss = vocab_parallel_cross_entropy(logits, labels, mask)
     parts = {"loss_ce": loss}
     if cfg.mtp_layers:
         logits2, aux = mtp_logits(params, hidden, batch, cfg, hp, mesh, table_spec)
@@ -743,7 +768,7 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
         parts["linear_state_abs_max"] = aux["state_abs_max"]
     if "ssm_state_abs_max" in aux:  # the state-space mixers' counter (telemetry.SSM_STEP_FIELDS)
         parts["ssm_state_abs_max"] = aux["ssm_state_abs_max"]
-    for name in ("selscan_state_abs_max", "published_mib"):  # telemetry.SHARED_STEP_FIELDS
+    for name in ("selscan_state_abs_max", "published_mib", *COUNTERS):  # telemetry.SHARED_STEP_FIELDS, the parts' own
         if name in aux:
             parts[name] = aux[name]
     if "load_max_over_mean" in aux:  # a router (linear layers over dense MLPs have none)

@@ -3,8 +3,8 @@
 One config covers the reference's model zoo: GPT (learned pos, pre-LN, gelu),
 LLaMA (rope, rmsnorm, swiglu, GQA), BERT/ViT (bidirectional, post-LN), T5
 (relative bias, enc-dec glue in models/t5.py), and the sparse-expert,
-latent-attention, linear-attention, state-space, short-convolution, window-attention and
-selective-scan / shared-memory (SambaY) families. A layer is two
+latent-attention, linear-attention, state-space, short-convolution, window-attention,
+selective-scan / shared-memory (SambaY) and compressed-context (EVA) families. A layer is two
 entries of the tables in `models/parts`, a token mixer and an MLP half:
 `mixers()` and `mlp_halves()` name them a layer, and what an entry asks of the
 config (`validate`) and hands back (`counters`) is the entry's to say."""
@@ -169,6 +169,14 @@ class TransformerConfig:
     # (2j, 2j + 1) pair up, q and k a map each and v the pair's two heads side by side;
     # `lambda` from four learned vectors a layer and a constant of the LAYER'S PUBLISHED INDEX
     diff_attention: bool = False
+    # --- what EvaByte's published config adds (evabyte): EVA attention (`models/parts/eva.py`, the
+    # mixer "eva"; ops/eva_attention.py), exact over the query's own window of `eva_window` positions
+    # and over ONE pooled key and value for each `eva_chunk` positions before that window, in one
+    # softmax; and a head that emits `pred_heads` predictions a position from one matmul, head i the
+    # token i + 1 places on (1: the ordinary head, whose arithmetic is bit for bit what it was) ---
+    eva_window: int = 0
+    eva_chunk: int = 0
+    pred_heads: int = 1
     # the PUBLISHED index of each layer run, where a cut in depth is no prefix of the stack:
     # layer i of the program is entry `layer_indices[i]` of `layer_types` (None: 0, 1, 2, ...)
     layer_indices: Optional[List[int]] = None
@@ -188,6 +196,11 @@ class TransformerConfig:
         if self.qk_norm not in (False, True, "head"):
             raise ValueError("qk_norm=%r: False, True (the whole projection) or \"head\""
                              % (self.qk_norm,))
+        if self.pred_heads < 1 or (self.pred_heads > 1 and (
+                self.tie_embeddings or self.mtp_layers or self.head_type != "lm")):
+            raise ValueError("pred_heads=%d: 1 or more heads of vocab_size columns each in ONE untied lm head "
+                             "(tie_embeddings False), and no multi-token-prediction module beside them"
+                             % self.pred_heads)
         if self.layer_types is not None:
             from galvatron_tpu.models.parts import MIXERS  # looked up on use, as `parts()` does
 

@@ -14,6 +14,7 @@ from typing import Any, Iterator, Optional, Tuple
 from galvatron_tpu.models.parts.attention import ATTENTION
 from galvatron_tpu.models.parts.conv import CONV
 from galvatron_tpu.models.parts.cross import CROSS
+from galvatron_tpu.models.parts.eva import COUNTERS as EVA_COUNTERS, EVA
 from galvatron_tpu.models.parts.kda import KDA
 from galvatron_tpu.models.parts.linear import LINEAR
 from galvatron_tpu.models.parts.mamba import GMU, MAMBA
@@ -22,8 +23,11 @@ from galvatron_tpu.models.parts.ssm import SSM
 from galvatron_tpu.models.parts.window import WINDOW
 
 MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA, "conv": CONV, "window": WINDOW,
-          "mamba1": MAMBA, "gmu": GMU, "cross": CROSS}
+          "mamba1": MAMBA, "gmu": GMU, "cross": CROSS, "eva": EVA}
 MLP_HALVES = {"dense": DENSE, "routed": ROUTED}
+# counter -> how the stack folds the layers' values into the step's ("mean" | "max" | "sum"), for a counter a part
+# hands back under the `step` event's own name: the stack (models/base._fold_aux, lm_loss_fn) reads the table
+COUNTERS = {**EVA_COUNTERS}
 
 # how an asker's sentence starts, and what joins the parts' statements in it
 _SAYS = {

@@ -192,7 +192,10 @@ _head_matmul.defvjp(_head_matmul_fwd, _head_matmul_bwd)
 
 def head_logits(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
     """`x` times the vocabulary kernel (`lm_head.kernel`, or the tied table
-    transposed) in the compute dtype."""
+    transposed) in the compute dtype. A head of several predictions a position
+    (`pred_heads` > 1) is the same ONE matmul on `pred_heads` x vocab_size
+    columns, head i's the i-th run of vocab_size (`next_tokens_cross_entropy`
+    splits them)."""
     tied = cfg.tie_embeddings
     stored = params["embed"]["wte"] if tied else params["lm_head"]["kernel"]
     logits = _head_matmul(x, stored.astype(cfg.compute_dtype), tied)
@@ -282,6 +285,23 @@ def vocab_parallel_cross_entropy(logits: jax.Array, labels: jax.Array,
         return jnp.mean(losses)
     loss_mask = loss_mask.astype(jnp.float32)
     return jnp.sum(losses * loss_mask) / jnp.maximum(jnp.sum(loss_mask), 1.0)
+
+
+def next_tokens_cross_entropy(logits: jax.Array, labels: jax.Array, loss_mask: Optional[jax.Array],
+                              heads: int) -> jax.Array:
+    """The loss of a head that predicts the next `heads` tokens of every
+    position from one matmul: logits (B, S, heads x V), head i the i-th run of V
+    columns, read in float32; `labels[t]` is the token after position t, so head
+    i's target at t is `labels[t + i]`, and a target past the sequence's end
+    (the last i positions) is masked, as is one `loss_mask` masks at t + i.
+    -> the mean over the heads of each head's mean cross entropy (equal weights)."""
+    b, s, _ = logits.shape
+    counted = jnp.ones((b, s), jnp.float32) if loss_mask is None else loss_mask.astype(jnp.float32)
+    inside = jnp.arange(s)[None, :, None] < s - jnp.arange(heads)  # (1, S, heads): t + i is a position
+    targets = jnp.stack([jnp.roll(labels, -i, axis=1) for i in range(heads)], axis=2)
+    counted = jnp.stack([jnp.roll(counted, -i, axis=1) for i in range(heads)], axis=2) * inside
+    losses = _token_nll(logits.astype(jnp.float32).reshape(b, s, heads, -1), targets)
+    return jnp.mean(jnp.sum(losses * counted, axis=(0, 1)) / jnp.maximum(jnp.sum(counted, axis=(0, 1)), 1.0))
 
 
 def softmax_nll(logits: jax.Array, labels: jax.Array) -> jax.Array:

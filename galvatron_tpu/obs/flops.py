@@ -180,6 +180,26 @@ def cross_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, seq_l
     return 2.0 * hidden * q_dim + 2.0 * q_dim * hidden, 3.0 * (2.0 * seq_len * q_dim) * 0.5
 
 
+def eva_pairs(seq_len: int, window: int, chunk: int) -> int:
+    """The (query, key) pairs a head of EVA attention scores over one sequence, EXACTLY: query t meets the
+    `(t mod W) + 1` keys of its own window up to itself and the `(t // W) C` pooled keys of every earlier
+    window (C = W / chunk); the sum over t, with `full` whole windows and a last one of `rest` positions."""
+    full, rest = divmod(seq_len, window)
+    own = full * (window * (window + 1) // 2) + rest * (rest + 1) // 2
+    return own + (window // chunk) * (window * (full * (full - 1) // 2) + rest * full)
+
+
+def eva_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, window: int, chunk: int, seq_len: int):
+    """An EVA attention mixer: q, k, v and out projections (as many key heads as query heads); and the
+    core as its mathematics needs it: scores and weighted sum over the pairs a query MEETS (`eva_pairs`,
+    exact: its window's keys up to itself and the pooled keys of earlier windows), and the pooling's two
+    weighted sums of a chunk's keys and values (2 head_dim multiply-adds a position a head). The chunk's
+    weights `<phi, k>` are a dot product a position on the vector unit, no matmul."""
+    q_dim = num_heads * head_dim
+    pairs_a_token = eva_pairs(seq_len, window, chunk) / float(seq_len)
+    return 2.0 * hidden * q_dim * 4, 2.0 * (2.0 * pairs_a_token * q_dim) + 2.0 * (2.0 * q_dim)
+
+
 # the row of each `MIXERS` key, and the config fields its keyword arguments read
 # ("seq_len": no field, the sequence length the count is asked at)
 _DELTA_DIMS = {k: "linear_" + k for k in ("num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
@@ -196,6 +216,8 @@ MIXER_FWD_FLOPS = {
     "mamba1": (mamba1_fwd_flops_a_token, {k: "mamba_" + k for k in ("expand", "d_state", "dt_rank")}),
     "gmu": (gmu_fwd_flops_a_token, {"expand": "mamba_expand"}),
     "cross": (cross_fwd_flops_a_token, {"num_heads": "num_heads", "head_dim": "head_dim", "seq_len": "seq_len"}),
+    "eva": (eva_fwd_flops_a_token, {"num_heads": "num_heads", "head_dim": "head_dim", "window": "eva_window",
+                                    "chunk": "eva_chunk", "seq_len": "seq_len"}),
 }
 
 
@@ -305,7 +327,8 @@ def head_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None) -> floa
     tokens = float(tokens if tokens is not None else getattr(cfg, "max_seq_len", 0) or 0)
     head_type = getattr(cfg, "head_type", "lm")
     if head_type in ("lm", "mlm"):
-        vocab = getattr(cfg, "vocab_size", 0) or 0
+        # (a head of several predictions a position is one matmul on that many times the columns)
+        vocab = (getattr(cfg, "vocab_size", 0) or 0) * getattr(cfg, "pred_heads", 1)
         extra = 2.0 * hidden * hidden if head_type == "mlm" else 0.0  # transform dense
         return tokens * (2.0 * hidden * vocab + extra)
     if head_type == "classification":

@@ -33,12 +33,15 @@ SHORT_CONV = "short_conv"  # models/parts/conv.conv_mixer: "xla", its one form
 SELECTIVE_SCAN = "selective_scan"  # ops/selective_scan.selective_scan: "pallas" | "xla" a call
 WINDOW_ATTENTION = "window_attention"  # ops/attention._windowed: "pallas" | "xla" a call
 WINDOW_OPERANDS = "window_operands"  # and, of the first, "as_projected": q unturned with its tables
+EVA_ATTENTION = "eva_attention"  # ops/eva_attention.aggregate: "pallas" | "xla" a call
 MOE_ROWS = "moe_rows"  # ops/moe._local_moe: `rows_form`'s "kernel" | "xla" a routed block
 EXPERT_WINDOW = "expert_window"  # a block of a share: the rows of its window ("0": the whole range)
 GATED_KERNEL_GRADS = "gated_kernel_grads"  # models/base._gated_grads_as_stored: "as_stored" a leaf
 TABLE_LOOKUP = "table_lookup"  # models/parts/embed_head.vocab_parallel_lookup: "rows_over_dp" | "table_whole"
 VOCAB_SPLIT = "vocab_split"  # parallel/pipeline's scan engine: the mesh axes, as "pp,m0"
-SCAN_GRADS = "scan_grads"  # models/base.run_layers: "zero_layout" a stacked leaf asked for in ZeRO's
+# models/base.run_layers: "zero_layout" a stacked leaf asked for in ZeRO's; "compute_dtype" a scanned run whose
+# cotangents are stacked in the compute dtype (the launch's answer to a device with little room beside the state)
+SCAN_GRADS = "scan_grads"
 
 
 class _Heard(threading.local):

@@ -427,6 +427,9 @@ def render(analysis: Dict[str, Any]) -> str:
             lines.append("%s: %s" % (part, ", ".join("%s x %d" % (form, n) for form, n in sorted(took.items()))))
         if "mamba_layers" in comp:
             lines.append("layers whose token mixer is a Mamba-1 selective scan: %d" % comp["mamba_layers"])
+        if "eva_layers" in comp:
+            lines.append("layers whose token mixer is EVA attention: %d (a sequence: %d windows, %d pooled keys)"
+                         % (comp["eva_layers"], comp.get("eva_windows", 0), comp.get("eva_pooled_keys", 0)))
         if "shared_readers" in comp:
             lines.append("layers that read a tensor an earlier layer published (a memory, keys and values): %d"
                          % comp["shared_readers"])

@@ -76,6 +76,10 @@ SSM_STEP_FIELDS = ("ssm_state_abs_max",)
 # a full differential layer's keys and values), MiB a step over the step's microbatches: the
 # tensors that outlive their layers, which recomputation cannot drop
 SHARED_STEP_FIELDS = ("selscan_state_abs_max", "published_mib")
+# EVA attention layers' counter (models/parts/eva.eva_mixer): the share of the softmax's mass that
+# falls on POOLED keys, the mean over the layers, heads and the queries past the first window (which
+# sees none), read off the two partial sums the aggregation holds; 0 where no query is past it
+EVA_STEP_FIELDS = ("eva_pooled_mass",)
 
 # type -> (required field names, optional field names). Unknown types and
 # unknown keys are rejected; None-valued optional fields are dropped at emit
@@ -101,7 +105,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         (),
         ("trace_ms", "compile_ms", "compiled_memory_mb", "xla_flops_per_step",
          "cache_hit", "forms", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb",
-         "mamba_layers", "shared_readers"),
+         "mamba_layers", "shared_readers", "eva_layers", "eva_windows", "eva_pooled_keys"),
     ),
     # where the start went, once the first step has drained (obs/launch.py):
     # `launch_ms` the phases of cli/train._train by name (obs/tracing.py's
@@ -124,7 +128,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         ("loss", "iter_ms", "dispatch_ms", "data_wait_ms", "host_blocked_ms",
          "hbm_in_use_mb", "hbm_peak_mb", "mfu", "model_flops_per_s",
          "grad_norm") + EXPERT_STEP_FIELDS + SHARE_STEP_FIELDS + LINEAR_STEP_FIELDS
-        + SSM_STEP_FIELDS + SHARED_STEP_FIELDS,
+        + SSM_STEP_FIELDS + SHARED_STEP_FIELDS + EVA_STEP_FIELDS,
     ),
     "eval": (("iter", "split", "loss"), ()),
     # lifecycle: checkpointing

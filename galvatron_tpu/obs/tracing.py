@@ -129,6 +129,17 @@ ATTN_DIFF = "gt.attn.diff"
 # a cross layer's q and output projections (models/parts/cross.cross_mixer): it has no
 # keys or values of its own
 ATTN_CROSS = "gt.attn.cross"
+# an EVA attention mixer (models/parts/eva.eva_mixer), inside gt.layers.r<k>, in three disjoint
+# scopes that add up to the mixer: the pooling of each chunk's keys and values (ops/eva_attention.pooled,
+# forward and backward), the aggregation (ops/eva_attention.aggregate: the two kernels, or XLA's windows;
+# forward, recomputed and backward) and everything else of it (the projections, rope, `wo`). The
+# first name begins the other two, which go on with `_` and a letter: where no reader's pattern ends
+# a name (benchmarks/scopes.END), and one word to the readers that name a run's parts by
+# `gt.<word>.<word>` (ISSUE 61 spelled them `gt.attn.eva.prep`, `.agg`: a third dotted word is one
+# those readers cut off, so the parts would not add up). NOT gt.attn.core: the benchmark prices what runs under that as whole causal attention
+ATTN_EVA = "gt.attn.eva"
+ATTN_EVA_PREP = "gt.attn.eva_prep"
+ATTN_EVA_AGG = "gt.attn.eva_agg"
 # the multi-token-prediction module, top level: its norms, the (2h, h)
 # projection and its block; its pass through the head and its cross entropy
 # run under HEAD_LOSS, beside the main ones

@@ -558,6 +558,68 @@ class HybridParallelModel:
         return jax.jit(tx.init, out_shardings=self.opt_state_shardings(tx, params))(params)
 
 
+# The share of a device's memory that a step must be left with beyond its
+# state and its scanned runs' stacks, for the float32 stacks to stay. The
+# benchmark's cells take 2.6 to 4.9 GiB of a v5e's 15.75 there (activations,
+# the head, the update's temporaries; PERF.md section 6, PR 61), EvaByte's four
+# layers at 8192 positions ask 2.18 of the 1.98 that 13.77 GiB leave: the
+# compiler refuses that step, 15.95 of 15.75.
+SCAN_STACKS_ROOM = 0.15
+
+
+def device_memory_limit(device) -> Optional[int]:
+    """What the device's allocator may hand out, in bytes (`memory_stats()`'s
+    `bytes_limit`); None where the backend does not say (the CPU, a described
+    topology's devices)."""
+    try:
+        return (device.memory_stats() or {}).get("bytes_limit") or None
+    except Exception:  # a device that is described and not attached
+        return None
+
+
+def scan_stacks_are_tight(model: "HybridParallelModel", tx: optax.GradientTransformation,
+                          limit_bytes: Optional[int] = None) -> bool:
+    """Whether the model's scanned runs should stack their cotangents in the
+    compute dtype (`hp.narrow_scan_grads`, models/base.run_layers): decided by
+    what the launch can observe before anything is compiled, a device's share
+    of the state (parameters and the optimizer's, as they are stored), of
+    what a scanned run stacks beside it (its leaves' cotangents in the dtype
+    they are stored in, and the copy the scan reads: the compute dtype's for a
+    leaf read through a cast, `spec.cast_first_tree`), and the device's memory
+    (`device_memory_limit`, or `limit_bytes`). True where the two leave less
+    than `SCAN_STACKS_ROOM` of the memory for everything else. False where
+    nothing is scanned, under pp (a stage's layers are stacked otherwise) and
+    where the device does not say what it holds."""
+    hp, cfg = model.hp, model.cfg
+    if limit_bytes is None:
+        limit_bytes = device_memory_limit(model.mesh.devices.flat[0])
+    if not (limit_bytes and hp.scan_layers and hp.pp == 1 and isinstance(cfg, TransformerConfig)):
+        return False
+
+    def a_device(leaf, sharding, itemsize=None):
+        size = 1
+        for n in sharding.shard_shape(leaf.shape):
+            size *= n
+        return size * (itemsize or leaf.dtype.itemsize)
+
+    params, shardings = model.abstract_params(), model.shardings()
+    state = sum(jax.tree.leaves(jax.tree.map(a_device, params, shardings))) + sum(jax.tree.leaves(jax.tree.map(
+        a_device, jax.eval_shape(tx.init, params), model.opt_state_shardings(tx, params))))
+    kinds, narrow = cfg.layer_kinds(), jnp.dtype(cfg.compute_dtype).itemsize
+    stacks = 0
+    for run in M.layer_runs(hp, M.model_layer_kinds(cfg)):
+        if run.length < 2:
+            continue
+        cast_first = S.cast_first_tree(M.layer_param_specs(
+            cfg.layer_config(kinds[run.start]), layer_axes(hp, run.start)), table_stored=False)
+        for i in run.layer_indices:
+            stacks += sum(jax.tree.leaves(jax.tree.map(
+                lambda first, leaf, sharding: a_device(leaf, sharding) + a_device(
+                    leaf, sharding, min(narrow, leaf.dtype.itemsize) if first else None),
+                cast_first, params["layers"][i], shardings["layers"][i])))
+    return state + stacks > (1.0 - SCAN_STACKS_ROOM) * limit_bytes
+
+
 def construct_hybrid_parallel_model(
     cfg: TransformerConfig,
     hp: HybridParallelConfig,

@@ -62,6 +62,11 @@ def dump(root: str, out: str, workloads) -> None:
         hp = hp_config_from_args(args, cfg.num_layers, cell.chips)
         model = construct_hybrid_parallel_model(cfg, hp, topo[:cell.chips])
         tx, _ = get_optimizer_and_scheduler(optimizer_args_from(args))
+        try:  # the launch's own decision, at the memory a v5e reports (a described device reports none)
+            from galvatron_tpu.runtime.model_api import scan_stacks_are_tight
+            hp.narrow_scan_grads = scan_stacks_are_tight(model, tx, V5E_BYTES)
+        except ImportError:  # a checkout from before PR 61
+            pass
 
         def sds(tree, shardings):
             return jax.tree.map(
@@ -86,6 +91,7 @@ def dump(root: str, out: str, workloads) -> None:
 
 
 MEMORY_SIZES = ("argument", "output", "alias", "temp")
+V5E_BYTES = 16909336064  # `memory_stats()["bytes_limit"]` of a v5e (my chip run, PR 61)
 ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2,
             "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8, "u64": 8}
 COPY_OVER = 4 << 20  # bytes of the array a `copy` has to move to be listed

@@ -50,6 +50,7 @@ BUILT_OF = {
     ("MIXERS", "mamba1"): dict(DENSE, layer_types=["mamba1", "attention"], **MAMBA1),
     ("MIXERS", "gmu"): dict(DENSE, layer_types=["mamba1", "gmu"], **MAMBA1),
     ("MIXERS", "cross"): dict(DENSE, layer_types=["full_attention", "cross_attention"], diff_attention=True),
+    ("MIXERS", "eva"): dict(DENSE, mixer="eva", eva_window=32, eva_chunk=8, position_type="rope"),
     ("MLP_HALVES", "dense"): DENSE,
     ("MLP_HALVES", "routed"): dict(DENSE, num_experts=4, experts_per_token=2),
 }
@@ -197,9 +198,11 @@ def test_every_key_of_the_mixers_table_is_a_word_of_layer_types():
     fields = {**DELTA, "ssm_num_heads": 4, "ssm_head_dim": 16, "ssm_state_dim": 8, "ssm_conv_kernel": 4,
               "short_conv_kernel": 3, "sliding_window": 8, **MAMBA1, "diff_attention": True}
     reads_after = {"gmu": "mamba1", "cross": "attention"}  # a reader stands after a layer that publishes
+    eva = dict(eva_window=32, eva_chunk=8, position_type="rope")  # (rope, which differential attention refuses)
     for key in parts.MIXERS:
         pattern = [reads_after[key], key] if key in reads_after else [key, "attention"]
-        assert TransformerConfig(**DENSE, layer_types=pattern, **fields).mixers() == tuple(pattern)
+        own = eva if key == "eva" else fields
+        assert TransformerConfig(**DENSE, layer_types=pattern, **own).mixers() == tuple(pattern)
     assert TransformerConfig(**DENSE, layer_types=["mamba", "attention"], **fields).mixers() == ("ssm", "attention")
     assert TransformerConfig(**DENSE, layer_types=["sliding_attention", "full_attention"], **fields).mixers() == (
         "window", "attention")
