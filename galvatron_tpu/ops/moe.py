@@ -177,19 +177,25 @@ def _k_major(x):
 
 
 # ---------------------------------------------------------- the row movers
-# A TPU's DMA engine moves whole (8, 128) tiles of 32-bit words, and Mosaic
-# slices an array in HBM by whole tiles only: a row of a (rows, hidden) bf16
-# array is 16 pieces of 256 B, each interleaved with its neighbour row's, and
-# cannot be copied alone. So a source of rows is first PACKED (`_pack_rows`,
-# one streaming pass): column c beside column c + hidden / 2 in one uint32, a
-# row's hidden / 2 words as `hidden / 256` sublane rows of 128: with hidden a
-# multiple of 2048 a row is whole tiles, contiguous in HBM (4 KB at 2048), and
-# one DMA moves it. The movers queue one such copy a row into a VMEM buffer,
-# a whole grid step's rows ahead of the arithmetic (the next step's copies are
-# issued between the current step's vector work), read the buffer back with a
-# sublane stride (lane tile q of 16 rows at once: the (rows, hidden) layout
-# again, so the gathered rows are never written to HBM), and unpack a word's
-# halves with a shift and a mask.
+# A (rows, hidden) bf16 array lies in HBM in (16, 128) tiles: a row is pieces
+# of 256 B, each interleaved with its 15 neighbour rows', and cannot be copied
+# alone. So a source of rows is first PACKED (`_pack_rows`, one streaming pass): column c
+# beside column c + hidden / 2 in one uint32, a row's hidden / 2 words as
+# `hidden / 256` sublane rows of 128. An array of 128 32-bit words a row lies
+# in HBM row after row, its (8, 128) tiles one after another, so a packed row
+# is `hidden x 2` contiguous bytes WHEREVER it starts (4 KB at 2048, one tile;
+# 4.5 KB at Kimi-Linear's 2304, a tile and an eighth that straddles a tile's
+# edge seven times in eight), and one DMA moves it: Mosaic takes a slice of
+# `hidden / 256` sublane rows at a dynamic start that is no multiple of 8, in
+# HBM and in VMEM (jax 0.9.0; PR 40 held it did not and took multiples of 2048
+# alone; PR 63 measured the rows end to end against a pitch of whole tiles, 16
+# sublane rows a row at 2304: equal to the bit both, and end to end the faster
+# in all three kernels, PERF.md). The movers queue one such copy a row into a
+# VMEM buffer, a whole grid step's rows ahead of the arithmetic (the next
+# step's copies are issued between the current step's vector work), read the
+# buffer back with a sublane stride (lane tile q of 16 rows at once: the
+# (rows, hidden) layout again, so the gathered rows are never written to HBM),
+# and unpack a word's halves with a shift and a mask.
 # The three kernels are jitted and inlined: a step calls each from several
 # traces (a `custom_vjp`'s primal and its forward rule, every scanned run) and
 # is traced anew at every start; a plain function traces its kernel's body
@@ -202,9 +208,12 @@ PACK_TILE = 512  # rows a grid step of `moe_rows_pack`
 # the most a block may be for the movers to take it: every assignment's index
 # is prefetched into SMEM, 1 MiB on a v5e, of which these are three quarters;
 # hidden 8192 does not fit the packing pass's scoped VMEM, and nothing between
-# was measured (tests/ops/test_tpu_compile_routed.py compiles the kernels AT the bounds)
+# was measured (tests/ops/test_tpu_compile_routed.py compiles the kernels AT the
+# bounds, and at every width between them); and the least: no narrower row was
+# measured, and no cell has one
 ROWS_MAX_ASSIGNMENTS = 196608
 ROWS_MAX_HIDDEN = 4096
+ROWS_MIN_HIDDEN = 2048
 _GROUP = 16  # rows the arithmetic takes at a time: a bf16 tile's sublanes
 _LANES = 128
 _HIGH = 0xFFFF0000
@@ -212,12 +221,13 @@ _HIGH = 0xFFFF0000
 
 def rows_form(on_tpu: bool, dtype, hidden: int, tokens: int, k: int) -> str:
     """"kernel" where the movers take a block's shape: on a TPU, bf16 rows
-    that pack into whole tiles (hidden a multiple of 2048), whole grid steps
-    of tokens and of assignments, no more of either than the kernels hold.
-    "xla" everywhere else: the CPU, float32 rows (8 KB: the packing is
-    bf16's), every other width and length."""
-    takes = (on_tpu and dtype == jnp.bfloat16 and hidden % (16 * _LANES) == 0
-             and hidden <= ROWS_MAX_HIDDEN and k * tokens <= ROWS_MAX_ASSIGNMENTS
+    that pack into whole 128-lane rows of words (hidden a multiple of 256)
+    from `ROWS_MIN_HIDDEN` to `ROWS_MAX_HIDDEN` wide, whole grid steps of
+    tokens and of assignments, no more of them than the kernels hold. "xla"
+    everywhere else: the CPU, float32 rows (the packing is bf16's), every
+    other width and length."""
+    takes = (on_tpu and dtype == jnp.bfloat16 and hidden % (2 * _LANES) == 0
+             and ROWS_MIN_HIDDEN <= hidden <= ROWS_MAX_HIDDEN and k * tokens <= ROWS_MAX_ASSIGNMENTS
              and tokens % ROWS_BACK_TILE == 0 and (k * tokens) % ROWS_OUT_TILE == 0
              and tokens % PACK_TILE == 0)
     return "kernel" if takes else "xla"

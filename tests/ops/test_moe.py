@@ -95,13 +95,18 @@ def test_a_share_sends_no_gradient_through_rows_it_does_not_hold(k):
 
 def test_a_shape_the_row_movers_refuse_takes_the_xla_form():
     """What `_local_moe` can observe decides: off a TPU, at float32 rows, at a
-    hidden size that does not pack into whole tiles, at a length that is not
-    whole grid steps, the block is the XLA form, and equals the per-token
-    loop as ever."""
+    hidden size that is no whole 128-lane rows of words or under the floor, at
+    a length that is not whole grid steps, the block is the XLA form, and
+    equals the per-token loop as ever. Every width from the floor to the bound
+    that is whole word rows is the movers' (PR 63: Kimi-Linear's 2304 among
+    them; a packed row need not be whole tiles)."""
     bf16 = jnp.bfloat16
     assert moe.rows_form(True, bf16, 2048, 8192, 10) == "kernel"
+    for hidden in (2304, 2560, 3072, 3584, 4096):
+        assert moe.rows_form(True, bf16, hidden, 8192, 8) == "kernel", hidden
     for refused in ((False, bf16, 2048, 8192, 10), (True, jnp.float32, 2048, 8192, 10),
-                    (True, bf16, 1920, 8192, 10), (True, bf16, 1024, 8192, 10),
+                    (False, bf16, 2304, 8192, 8), (True, jnp.float32, 2304, 8192, 8),
+                    (True, bf16, 1920, 8192, 10), (True, bf16, 1024, 8192, 10), (True, bf16, 2304 + 128, 8192, 8),
                     (True, bf16, 2048, 8192 + 64, 10), (True, bf16, 2048, 96, 2),
                     # more than the kernels hold (tests/ops/test_tpu_compile_routed.py compiles AT the bounds)
                     (True, bf16, 4096, 32768, 8), (True, bf16, 8192, 8192, 8)):

@@ -14,7 +14,11 @@ from tests.ops.moe_cases import EXPERTS, HELD, KS, ROUTERS, WIDTH
 
 
 # ------------------------------------------------- the row movers (PR 40)
-MOVER_HIDDEN = 2048  # the least a bf16 row packs into whole (8, 128) tiles of words at
+# 2048: a bf16 row packs into ONE (8, 128) tile of words, the least the movers take; 2304 (Kimi-Linear's, PR 63):
+# 9 sublane rows of words, so all rows but one in eight straddle a tile's edge
+MOVER_CASES = ([(2048, k, router, held) for k in KS for router in sorted(ROUTERS) for held in (None, HELD)]
+               + [(2304, 8, "sigmoid", HELD), (2304, 8, "softmax", None), (2304, 2, "softmax", HELD),
+                  (2304, 4, "sigmoid", None)])
 
 
 def _grid(x, step, most):
@@ -22,17 +26,17 @@ def _grid(x, step, most):
     return jnp.clip(jnp.round(x / step), -most / step, most / step) * step
 
 
-def _recorded_block(k, router, held, tokens, monkeypatch):
+def _recorded_block(hidden, k, router, held, tokens, monkeypatch):
     """The block's own operands of `_dispatch` and `_combine` at bf16 rows of
-    2048: (y, order, inv_order) and (out, weights, order, inv_order), as a run
-    of the XLA form hands them over."""
+    `hidden`: (y, order, inv_order) and (out, weights, order, inv_order), as a
+    run of the XLA form hands them over."""
     kw = ROUTERS[router]
     keys = jax.random.split(jax.random.PRNGKey(1000 * k + len(router)), 5)
     n = EXPERTS if held is None else held[1]
-    y = jax.random.normal(keys[0], (1, tokens, MOVER_HIDDEN), jnp.float32).astype(jnp.bfloat16)
-    operands = (y, jax.random.normal(keys[1], (MOVER_HIDDEN, EXPERTS), jnp.float32) * 0.02,
-                jax.random.normal(keys[2], (n, MOVER_HIDDEN, 2 * WIDTH), jnp.float32) * 0.02,
-                jax.random.normal(keys[3], (n, WIDTH, MOVER_HIDDEN), jnp.float32) * 0.2)
+    y = jax.random.normal(keys[0], (1, tokens, hidden), jnp.float32).astype(jnp.bfloat16)
+    operands = (y, jax.random.normal(keys[1], (hidden, EXPERTS), jnp.float32) * 0.02,
+                jax.random.normal(keys[2], (n, hidden, 2 * WIDTH), jnp.float32) * 0.02,
+                jax.random.normal(keys[3], (n, WIDTH, hidden), jnp.float32) * 0.2)
     seen = {}
     for name in ("_dispatch", "_combine"):
         def recording(form, *args, name=name, committed=getattr(moe, name)):
@@ -54,16 +58,16 @@ def _both_ways(form, y, order, inv_order, out, weights, g_rows, g_tokens):
     return (rows, back(g_rows)[0], summed) + combine_back(g_tokens)
 
 
-@pytest.mark.parametrize("held", [None, HELD], ids=["all_held", "a_share"])
-@pytest.mark.parametrize("router", sorted(ROUTERS))
-@pytest.mark.parametrize("k", KS)
-def test_the_row_movers_equal_the_xla_forms_to_the_bit(k, router, held, monkeypatch):
+@pytest.mark.parametrize("hidden, k, router, held", MOVER_CASES,
+                         ids=["%d-%d-%s-%s" % (h, k, r, "all_held" if held is None else "a_share")
+                              for h, k, r, held in MOVER_CASES])
+def test_the_row_movers_equal_the_xla_forms_to_the_bit(hidden, k, router, held, monkeypatch):
     """`moe_rows_pack`, `moe_rows_back` and `moe_rows_out`, interpreted on the
     CPU, against the XLA forms they stand in for on a TPU: `_dispatch`'s rows
     and `dy`, `_combine`'s output, `d_out` and `d_w`, on the block's own
     routing and weights. A permutation, float32 sums over k in one order and
     one rounding leave no room for a tolerance. Two things no form fixes are
-    kept from showing: the order of a row's 2048 products in `sum(out x g)`,
+    kept from showing: the order of a row's `hidden` products in `sum(out x g)`,
     and whether a compiler rounds a multiply and the add after it once or
     twice (the CPU's contracts them inside the interpreted kernel and not in
     the XLA form). So the COMBINE's operands lie on binary grids coarse enough
@@ -71,7 +75,7 @@ def test_the_row_movers_equal_the_xla_forms_to_the_bit(k, router, held, monkeypa
     cotangent, which is only ever added, is any bf16, so the order of j shows
     there."""
     tokens = {1: 128, 2: 64, 4: 32, 6: 64, 8: 16}[k]  # the fewest that are whole grid steps of assignments
-    (y, order, inv_order), (out, weights, _, _) = _recorded_block(k, router, held, tokens, monkeypatch)
+    (y, order, inv_order), (out, weights, _, _) = _recorded_block(hidden, k, router, held, tokens, monkeypatch)
     if held is not None:  # rows of experts held elsewhere come back zero and are moved all the same
         empty = ~np.any(np.asarray(out, np.float32), axis=1)
         assert 0 < empty.sum() < empty.size
