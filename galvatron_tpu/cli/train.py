@@ -559,6 +559,7 @@ def _train(args, started: launch.Launch) -> dict:
         seq_len=getattr(cfg, "max_seq_len", None),
         mixed_precision=hp.mixed_precision,
         activation=getattr(cfg, "activation", None),
+        loop_steps=getattr(cfg, "loop_steps", 1) if getattr(cfg, "loop_steps", 1) > 1 else None,
     )
 
     # ------------------------------------------------------- online autotuner
@@ -901,7 +902,8 @@ def _train(args, started: launch.Launch) -> dict:
             **{k: float(metrics[k])
                for k in (telemetry.EXPERT_STEP_FIELDS + telemetry.SHARE_STEP_FIELDS
                          + telemetry.LINEAR_STEP_FIELDS + telemetry.SSM_STEP_FIELDS
-                         + telemetry.SHARED_STEP_FIELDS + telemetry.EVA_STEP_FIELDS)
+                         + telemetry.SHARED_STEP_FIELDS + telemetry.EVA_STEP_FIELDS
+                         + telemetry.LOOP_STEP_FIELDS)
                if isinstance(metrics, dict) and k in metrics},
         )
 

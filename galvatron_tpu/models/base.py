@@ -17,6 +17,7 @@ no part. The config is `models/config.TransformerConfig`."""
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Dict, List, Optional, Tuple
 
@@ -33,10 +34,10 @@ from galvatron_tpu.config.strategy import (
     model_layer_kinds,
 )
 from galvatron_tpu.models.config import TransformerConfig
-from galvatron_tpu.models.parts import COUNTERS, MIXERS, MLP_HALVES, unsupported_reason
+from galvatron_tpu.models.parts import COUNTERS, MIXERS, MLP_HALVES, loop, unsupported_reason
 from galvatron_tpu.models.parts.common import Params, _dense, _dense_init, _norm, _norm_params
 from galvatron_tpu.models.parts.embed_head import (embed_patches, embed_tokens, head_logits, model_head,
-                                                   next_tokens_cross_entropy, softmax_nll,
+                                                   next_tokens_cross_entropy, softmax_nll, token_cross_entropies,
                                                    vocab_parallel_cross_entropy)
 from galvatron_tpu.models.parts.mlp import ROUTER_BIAS, grad_as_stored
 from galvatron_tpu.obs import forms, tracing
@@ -62,6 +63,8 @@ def init_layer_params(rng: jax.Array, cfg: TransformerConfig, index: int = 0) ->
     (`LayerPart.place`: differential attention's `lambda_init`; no other)."""
     ks = jax.random.split(rng, 5)
     p: Params = {"ln1": _norm_params(cfg), "ln2": _norm_params(cfg)}
+    if cfg.post_norm:  # HF's `input_layernorm_2`, `post_attention_layernorm_2` (names assumed)
+        p.update({"ln1_post": _norm_params(cfg), "ln2_post": _norm_params(cfg)})
     p.update(MIXERS[cfg.mixer].init(ks, cfg))
     p.update(MLP_HALVES[cfg.mlp_half].init(ks, cfg))
     return MIXERS[cfg.mixer].place(p, cfg, index)
@@ -108,6 +111,8 @@ def init_model_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
     # post-LN models (BERT) normalise inside each block; no final norm
     if cfg.pre_norm:
         params["final_norm"] = _norm_params(cfg)
+    if cfg.exit_gate:
+        params["exit_gate"] = loop.init_exit_gate(jax.random.fold_in(rng, n + 1), cfg)
     if cfg.head_type == "classification":
         params["head"] = {
             "kernel": _dense_init(ks[n + 4], (h, cfg.num_classes), cfg.init_std, cfg.param_dtype),
@@ -146,7 +151,9 @@ def layer_forward(
 ):
     """One transformer block on (B, S_local, H) activations: x + Mixer(norm
     x), then + MLP(norm x), the two halves `MIXERS[cfg.mixer]`'s and
-    `MLP_HALVES[cfg.mlp_half]`'s.
+    `MLP_HALVES[cfg.mlp_half]`'s. With ``cfg.post_norm`` (sandwich norms) a
+    half's OUTPUT is normed by a norm of its own (`ln1_post`, `ln2_post`,
+    under `gt.norm.post`) before it joins the stream: x + norm(Mixer(norm x)).
 
     Under GSPMD the parallel form is implied by weight shardings plus the
     activation constraints here and in the mixer.
@@ -189,6 +196,9 @@ def layer_forward(
         aux = {**(said or {}), **aux}  # the MLP half's terms before the mixer's
         if mesh is not None and axes is not None:
             o = S.constrain(o, mesh, S.act_spec(axes))
+        if cfg.post_norm:
+            with jax.named_scope(tracing.NORM_POST):
+                o = _norm(o, p[norm + "_post"], cfg)
         if cfg.residual_multiplier != 1.0:
             o = o * cfg.residual_multiplier
         x = residual + o
@@ -634,7 +644,9 @@ def _forward(
     zero_splits_state: bool = False,
 ):
     """-> (logits, the last layer's output before the final norm, the routed
-    blocks' auxiliary terms as `run_layers` lists them or None)."""
+    blocks' auxiliary terms as `run_layers` lists them or None). A looped
+    stack (`cfg.loop_steps` > 1): (the LAST pass's logits, every pass's normed
+    state (T, B, S, H), None)."""
     use_hp = hp is not None and mesh is not None
     vax = vocab_axes(hp) if use_hp else None
     if positions is None and cfg.input_type != "patches":
@@ -648,6 +660,13 @@ def _forward(
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
     bias = padding_attn_bias(attn_mask) if attn_mask is not None else None
+    if cfg.loop_steps > 1:
+        states = looped_states(params, x, positions, cfg, hp, mesh, bias, zero_splits_state)
+        with jax.named_scope(tracing.HEAD_LOSS):  # the LAST pass's: what a caller of the forward alone reads
+            logits = head_logits(params, states[-1], cfg)
+            if use_hp:
+                logits = S.constrain(logits, mesh, S.logits_spec(vax))
+        return logits, states, None
     x = run_layers(params, x, positions, cfg, hp, mesh, attn_bias=bias, zero_splits_state=zero_splits_state)
     x, auxs = x if cfg.layer_aux else (x, None)
     if use_hp:
@@ -659,6 +678,48 @@ def _forward(
         if use_hp and cfg.head_type in ("lm", "mlm"):
             logits = S.constrain(logits, mesh, S.logits_spec(vax))
     return logits, x, auxs
+
+
+def over_passes(one_pass, x: jax.Array, steps: int, scan: bool) -> jax.Array:
+    """`one_pass` applied `steps` times from `x` on -> its results stacked, (steps, ...): ONE traced body under
+    `jax.lax.scan` where the layers are scanned, else as many Python calls (`--no_scan_layers` unrolls both)."""
+    if scan:
+        return jax.lax.scan(lambda h, _: (one_pass(h),) * 2, x, None, length=steps)[1]
+    states = []
+    for _ in range(steps):
+        x = one_pass(x)
+        states.append(x)
+    return jnp.stack(states)
+
+
+def looped_states(params: Params, x: jax.Array, positions: jax.Array, cfg: TransformerConfig,
+                  hp: Optional[HybridParallelConfig], mesh: Optional[Mesh], attn_bias: Optional[jax.Array],
+                  zero_splits_state: bool) -> jax.Array:
+    """A looped stack (`cfg.loop_steps` = T > 1): h_t = final_norm(run_layers(h_{t-1})) for t = 1 .. T over the
+    SAME `params["layers"]` and the same final norm, from the embedding's rows on; the normed state feeds the
+    head and re-enters the stack -> (T, B, S, H).
+
+    One traced body: a `lax.scan` over t whose body is `run_layers` (its runs traced, stacked and lowered once,
+    so trace, lowering and the compiled program stay those of a model of `num_layers` layers) and the norm.
+    The layers' leaves are constants of that scan: its transpose holds each leaf's gradient as ONE sum over
+    the passes in the dtype the leaf is read in (float32 for the stored parameters), and a pass's own
+    cotangents (a scanned run's stacked ones among them) are transient beside it. `--checkpoint 1` keeps a
+    layer APPLICATION's input, `num_layers` x T of them. The scanned loop is entered under the first run's name
+    and, inside it, `gt.loop`: the transforms wrap the OUTERMOST name alone of those that stand outside the
+    outermost scan, and the trace's readers tell a run's backward by the wrapper on its name (`obs/tracing.LOOP`)."""
+    use_hp = hp is not None and mesh is not None
+    vax = vocab_axes(hp) if use_hp else None
+    scan = hp.scan_layers if hp is not None else True
+
+    def one_pass(h):
+        h = run_layers(params, h, positions, cfg, hp, mesh, attn_bias=attn_bias, zero_splits_state=zero_splits_state)
+        if use_hp:
+            h = S.constrain(h, mesh, S.act_spec(vax))
+        return _norm(h, params["final_norm"], cfg)
+
+    outermost = jax.named_scope(tracing.layers_scope(0)) if scan else contextlib.nullcontext()
+    with outermost, jax.named_scope(tracing.LOOP):
+        return over_passes(one_pass, x, cfg.loop_steps, scan)
 
 
 def mtp_logits(params: Params, hidden: jax.Array, batch, cfg: TransformerConfig,
@@ -739,6 +800,9 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
         table_spec=table_spec, zero_splits_state=zero_splits_state,
     )
     labels, mask = batch["labels"], batch.get("loss_mask")
+    if cfg.loop_steps > 1:
+        loss, parts = looped_loss(params, hidden, logits, labels, mask, cfg, hp, mesh)
+        return (loss, parts) if with_parts else loss
     with jax.named_scope(tracing.HEAD_LOSS):
         if cfg.pred_heads > 1:  # a head of several predictions a position: the mean of the heads' means
             loss = next_tokens_cross_entropy(logits, labels, mask, cfg.pred_heads)
@@ -783,6 +847,30 @@ def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
         parts["router_bias_abs_max"] = aux["bias_abs_max"]
         parts[ROUTER_COUNTS] = aux["counts"]
     return (loss, parts) if with_parts else loss
+
+
+def looped_loss(params: Params, states: jax.Array, last_logits: jax.Array, labels: jax.Array,
+                mask: Optional[jax.Array], cfg: TransformerConfig, hp: Optional[HybridParallelConfig],
+                mesh: Optional[Mesh]) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """A looped stack's objective from every pass's normed state (T, B, S, H): the T heads and cross entropies
+    one after another through `head_logits` and `token_cross_entropies` (a pass's logits at a time; the last
+    pass's are `_forward`'s), each a cross entropy a POSITION in float32, weighted by the exit gate's
+    distribution over the passes, summed, less `exit_entropy_coef` x its entropy (`parts/loop.expected_loss`;
+    no gate: the last pass's cross entropy). -> (loss, its parts: `loop.PARTS` beside `loss_ce`)."""
+    use_hp = hp is not None and mesh is not None
+    vax = vocab_axes(hp) if use_hp else None
+    nll = []
+    with jax.named_scope(tracing.HEAD_LOSS):
+        for t in range(cfg.loop_steps):
+            logits = last_logits
+            if t < cfg.loop_steps - 1:
+                logits = head_logits(params, states[t], cfg)
+                if use_hp:
+                    logits = S.constrain(logits, mesh, S.logits_spec(vax))
+            nll.append(token_cross_entropies(logits, labels))
+    with jax.named_scope(tracing.EXIT):
+        p = loop.exit_distribution(params.get("exit_gate"), states)
+        return loop.expected_loss(p, jnp.stack(nll), mask, cfg.exit_entropy_coef)
 
 
 def router_bias_leaves(params: Params) -> List[Dict[str, jax.Array]]:
@@ -833,6 +921,8 @@ def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     r1 = S.replicated_1d_spec(axes)
     norm = {"scale": r1} if cfg.norm_type == "rmsnorm" else {"scale": r1, "bias": r1}
     sp: Params = {"ln1": dict(norm), "ln2": dict(norm)}
+    if cfg.post_norm:
+        sp.update({"ln1_post": dict(norm), "ln2_post": dict(norm)})
     sp.update(MIXERS[cfg.mixer].specs(cfg, axes))
     sp.update(MLP_HALVES[cfg.mlp_half].specs(cfg, axes))
     return sp
@@ -867,6 +957,8 @@ def model_param_specs(cfg: TransformerConfig, hp: HybridParallelConfig) -> Param
         }
     if cfg.pre_norm:
         specs["final_norm"] = dict(norm_spec)
+    if cfg.exit_gate:
+        specs["exit_gate"] = loop.exit_gate_specs()
     vocab_col = P(None, None) if vax.ulysses else P(None, S._ax(vax.tp))
     if cfg.head_type == "classification":
         specs["head"] = {"kernel": P(None, None), "bias": P(None)}

@@ -4,7 +4,7 @@ One config covers the reference's model zoo: GPT (learned pos, pre-LN, gelu),
 LLaMA (rope, rmsnorm, swiglu, GQA), BERT/ViT (bidirectional, post-LN), T5
 (relative bias, enc-dec glue in models/t5.py), and the sparse-expert,
 latent-attention, linear-attention, state-space, short-convolution, window-attention,
-selective-scan / shared-memory (SambaY) and compressed-context (EVA) families. A layer is two
+selective-scan / shared-memory (SambaY), compressed-context (EVA) and looped (LoopLM) families. A layer is two
 entries of the tables in `models/parts`, a token mixer and an MLP half:
 `mixers()` and `mlp_halves()` name them a layer, and what an entry asks of the
 config (`validate`) and hands back (`counters`) is the entry's to say."""
@@ -180,6 +180,17 @@ class TransformerConfig:
     # the PUBLISHED index of each layer run, where a cut in depth is no prefix of the stack:
     # layer i of the program is entry `layer_indices[i]` of `layer_types` (None: 0, 1, 2, ...)
     layer_indices: Optional[List[int]] = None
+    # --- what Ouro's published config adds (ouro, LoopLM): the WHOLE stack applied `loop_steps` times over
+    # the same parameters, the final norm after every pass (the normed state feeds the head and re-enters the
+    # stack; models/base.looped_states), a norm on each half's OUTPUT before it joins the stream (`post_norm`,
+    # sandwich norms: the leaves `ln1_post`, `ln2_post`), and an exit gate: a Linear(hidden, 1) on each of
+    # the first `loop_steps` - 1 normed states whose sigmoids make the distribution the passes' cross
+    # entropies are weighted by, less `exit_entropy_coef` x its entropy (models/parts/loop.py). The
+    # defaults are the model without them, whose step none of these touches ---
+    loop_steps: int = 1
+    post_norm: bool = False
+    exit_gate: bool = False
+    exit_entropy_coef: float = 0.0
     # which `MIXERS` entry ONE layer runs. `layer_config(kind)` sets it; a
     # model's own config leaves it and states the pattern above
     mixer: str = "attention"
@@ -201,6 +212,14 @@ class TransformerConfig:
             raise ValueError("pred_heads=%d: 1 or more heads of vocab_size columns each in ONE untied lm head "
                              "(tie_embeddings False), and no multi-token-prediction module beside them"
                              % self.pred_heads)
+        if self.loop_steps < 1 or (self.loop_steps == 1 and (self.exit_gate or self.exit_entropy_coef)) or (
+                self.exit_entropy_coef and not self.exit_gate):
+            raise ValueError("loop_steps=%d, exit_gate=%r, exit_entropy_coef=%r: a gate weighs the passes of a stack "
+                             "run 2 or more times, and the entropy is the gate's distribution's"
+                             % (self.loop_steps, self.exit_gate, self.exit_entropy_coef))
+        if (self.loop_steps > 1 or self.post_norm) and not self.pre_norm:
+            raise ValueError("loop_steps=%d, post_norm=%r: the loop re-enters a pre-norm stack through its final "
+                             "norm, and a post-norm block has no sandwich form" % (self.loop_steps, self.post_norm))
         if self.layer_types is not None:
             from galvatron_tpu.models.parts import MIXERS  # looked up on use, as `parts()` does
 
@@ -226,6 +245,17 @@ class TransformerConfig:
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.num_heads
         self.shared()  # a layer that reads what no earlier layer publishes is refused by name
+        shares = any(handed or read for handed, read in self.shared())
+        if self.loop_steps > 1 and (self.layer_aux or shares or self.mtp_layers or self.pred_heads > 1
+                                    or self.head_type != "lm"):
+            raise ValueError(
+                "loop_steps=%d: the loop carries the residual stream alone through a stack of layers without "
+                "counters or published tensors, to ONE lm head of one prediction a position and no "
+                "multi-token-prediction module; this config has %s" % (self.loop_steps, " and ".join(
+                    what for what, has in (("layers that hand back counters", self.layer_aux),
+                                           ("layers that publish", shares),
+                                           ("an MTP module", self.mtp_layers), ("pred_heads > 1", self.pred_heads > 1),
+                                           ("head_type %r" % self.head_type, self.head_type != "lm")) if has)))
         if self.input_type == "patches":
             n_patches = (self.image_size // self.patch_size) ** 2
             self.max_seq_len = n_patches + (1 if self.use_cls_token else 0)

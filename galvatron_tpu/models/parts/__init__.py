@@ -17,6 +17,7 @@ from galvatron_tpu.models.parts.cross import CROSS
 from galvatron_tpu.models.parts.eva import COUNTERS as EVA_COUNTERS, EVA
 from galvatron_tpu.models.parts.kda import KDA
 from galvatron_tpu.models.parts.linear import LINEAR
+from galvatron_tpu.models.parts import loop
 from galvatron_tpu.models.parts.mamba import GMU, MAMBA
 from galvatron_tpu.models.parts.mlp import DENSE, ROUTED
 from galvatron_tpu.models.parts.ssm import SSM
@@ -72,7 +73,8 @@ def unsupported_reason(cfg, hp=None, asker: Optional[str] = None, autotune: Opti
     parts = getattr(cfg, "parts", None)
     if not callable(parts):  # T5's, Swin's and duck-typed configs are built of none of them
         return None
-    says = [part.unsupported(cfg) for part in parts()]
+    layers_say = [part.unsupported(cfg) for part in parts()]
+    says = layers_say + [loop.unsupported(cfg)]  # (the loop wraps the stack and is no entry of the tables)
     askers = [(asker, ())] if asker in ("serve", "search", "profile") else []
     if (autotune or "off") != "off":
         askers.append(("autotune", autotune))
@@ -82,6 +84,7 @@ def unsupported_reason(cfg, hp=None, asker: Optional[str] = None, autotune: Opti
         said = [s[name] for s in says if name in s]
         if said:
             start, glue = _SAYS[name]
-            return (start % numbers + glue.join(said)
-                    + "; such a config runs on one chip and under dp with ZeRO-1/2/3")
+            # (a looped or sandwich-norm stack of parts that have every form runs under GSPMD tp too)
+            return (start % numbers + glue.join(said) + "; such a config runs on "
+                    + ("one chip and under dp with ZeRO-1/2/3" if any(layers_say) else loop.RUNS))
     return None

@@ -271,6 +271,12 @@ def _token_nll_bwd(res, g):
 _token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
 
 
+def token_cross_entropies(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    """The float32 cross entropy of every position, (B, S): `vocab_parallel_cross_entropy` before its mean,
+    for an objective that weighs positions itself (a looped stack's exit distribution, models/base.looped_loss)."""
+    return _token_nll(logits, labels)
+
+
 def vocab_parallel_cross_entropy(logits: jax.Array, labels: jax.Array,
                                  loss_mask: Optional[jax.Array] = None) -> jax.Array:
     """Token-mean cross entropy, safe for vocab-sharded logits.

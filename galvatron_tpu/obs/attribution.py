@@ -163,7 +163,10 @@ def predict_layer_runs(
     # pricing, so calibrations against chunk-less runs are unchanged.
     chunks = max(1, int(hp.chunks or 1))
     mb_bsz = hp.global_bsz / chunks
-    tick_factor = (chunks + hp.pp - 1) / hp.pp
+    # a looped stack (`loop_steps` = T > 1) applies every layer T times a step over the same weights: T x a
+    # layer's time and kept activations, its parameters and optimizer state once
+    passes = getattr(cfg, "loop_steps", 1)
+    tick_factor = (chunks + hp.pp - 1) / hp.pp * passes
 
     out: List[Dict[str, Any]] = []
     for idx, run in enumerate(runs):
@@ -185,12 +188,13 @@ def predict_layer_runs(
             per_layer_hidden_ms = min(per_layer_comm_ms,
                                       (tcm.fct + tcm.bct) * scale)
             per_layer_ms -= per_layer_hidden_ms
-        per_layer_mb = MemoryCostModel(
+        memory = MemoryCostModel(
             strategy, global_batch_size=hp.global_bsz,
             mbsz=max(1, hp.global_bsz // max(1, hp.chunks)),
             min_tp=1, max_tp=per_stage, model_args=ma, train_args=ta,
             parallel_args=pa, profile_model_args=pma,
-        ).get_memory_cost()["enc_total"]
+        ).get_memory_cost()
+        per_layer_mb = memory["model_states"] + memory["activation"] * passes
         entry: Dict[str, Any] = {
             "run": idx,
             "start": run.start,

@@ -283,7 +283,8 @@ def layer_fwd_flops(
 def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
                                 seq_len: Optional[int] = None) -> Optional[float]:
     """Duck-typed entry for TransformerConfig-shaped configs; None when the
-    config lacks the transformer fields (custom families)."""
+    config lacks the transformer fields (custom families). A looped stack
+    (`loop_steps` = T > 1) applies every layer T times a step: T x the layer."""
     hidden = getattr(cfg, "hidden_size", None)
     heads = getattr(cfg, "num_heads", None)
     seq = seq_len or getattr(cfg, "max_seq_len", None)
@@ -294,7 +295,7 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         latent = {k: getattr(cfg, k) for k in (
             "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")}
     mixer = getattr(cfg, "mixer", "attention")
-    return layer_fwd_flops(
+    return getattr(cfg, "loop_steps", 1) * layer_fwd_flops(
         hidden=hidden,
         num_heads=heads,
         seq_len=seq,
@@ -322,7 +323,8 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
 def head_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None) -> float:
     """Embed/head projection FLOPs over `tokens` tokens: the vocab matmul for
     lm/mlm heads (embedding lookups are gathers, ~0 FLOPs), the class
-    projection for classification heads."""
+    projection for classification heads. A looped stack (`loop_steps` = T > 1)
+    runs the head on every pass's state: T x the vocab matmul (the lookup once)."""
     hidden = getattr(cfg, "hidden_size", 0) or 0
     tokens = float(tokens if tokens is not None else getattr(cfg, "max_seq_len", 0) or 0)
     head_type = getattr(cfg, "head_type", "lm")
@@ -330,7 +332,7 @@ def head_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None) -> floa
         # (a head of several predictions a position is one matmul on that many times the columns)
         vocab = (getattr(cfg, "vocab_size", 0) or 0) * getattr(cfg, "pred_heads", 1)
         extra = 2.0 * hidden * hidden if head_type == "mlm" else 0.0  # transform dense
-        return tokens * (2.0 * hidden * vocab + extra)
+        return getattr(cfg, "loop_steps", 1) * tokens * (2.0 * hidden * vocab + extra)
     if head_type == "classification":
         classes = getattr(cfg, "num_classes", 0) or 0
         # one pooled vector per sample; callers pass tokens=batch*seq, the

@@ -290,6 +290,9 @@ def analyze(
                 [e.get("host_blocked_ms") for e in steps]),
             "median_data_wait_ms": _median(
                 [e.get("data_wait_ms") for e in steps]),
+            # a looped stack's terms as the last step that has them reports them (telemetry.LOOP_STEP_FIELDS)
+            "loop": next(({k: e[k] for k in ("loss_ce",) + T.LOOP_STEP_FIELDS if k in e}
+                          for e in reversed(steps) if any(k in e for k in T.LOOP_STEP_FIELDS)), None),
         },
         "steady": steady,
         "launch": {k: v for k, v in (by_type.get("launch") or [{}])[-1].items()
@@ -404,6 +407,9 @@ def render(analysis: Dict[str, Any]) -> str:
         % (steps["n"], _fmt(steps["first_iter"]), _fmt(steps["last_iter"]),
            _fmt(steps["first_loss"]), _fmt(steps["last_loss"]))
     )
+    if steps.get("loop"):
+        lines.append("looped stack (%s passes a step), last step: %s" % (
+            _fmt(run.get("loop_steps")), ", ".join("%s %s" % (k, _fmt(v)) for k, v in steps["loop"].items())))
     lines.append(
         "steady state (%s): step %s ms over %s steps from iter %s "
         "| steps/s %s | model FLOP/s %s | MFU %s"

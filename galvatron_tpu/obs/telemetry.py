@@ -80,6 +80,11 @@ SHARED_STEP_FIELDS = ("selscan_state_abs_max", "published_mib")
 # falls on POOLED keys, the mean over the layers, heads and the queries past the first window (which
 # sees none), read off the two partial sums the aggregation holds; 0 where no query is past it
 EVA_STEP_FIELDS = ("eva_pooled_mass",)
+# a looped stack's terms (models/base.looped_loss, beside `loss_ce`, the cross entropy the exit gate's
+# distribution weighs): the first and the last pass's plain mean cross entropies, the mean pass a position
+# exits at (sum_t t p_t, 1 .. loop_steps) and the mean entropy of that distribution; folded over the
+# microbatches as loss terms are
+LOOP_STEP_FIELDS = ("loss_ce_first", "loss_ce_last", "exit_step_mean", "exit_entropy")
 
 # type -> (required field names, optional field names). Unknown types and
 # unknown keys are rejected; None-valued optional fields are dropped at emit
@@ -96,7 +101,9 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
          # profiler's file tag without the live model config
          "model_type", "hidden_size", "num_heads", "num_kv_heads",
          "ffn_hidden", "vocab_size", "seq_len", "mixed_precision",
-         "activation"),
+         "activation",
+         # > 1: the stack is applied so many times a step over the same weights (a looped model)
+         "loop_steps"),
     ),
     # one-off program build cost + the compiler-reported working set the
     # MemoryCostModel prediction is checked against; `forms`: which form each
@@ -128,7 +135,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         ("loss", "iter_ms", "dispatch_ms", "data_wait_ms", "host_blocked_ms",
          "hbm_in_use_mb", "hbm_peak_mb", "mfu", "model_flops_per_s",
          "grad_norm") + EXPERT_STEP_FIELDS + SHARE_STEP_FIELDS + LINEAR_STEP_FIELDS
-        + SSM_STEP_FIELDS + SHARED_STEP_FIELDS + EVA_STEP_FIELDS,
+        + SSM_STEP_FIELDS + SHARED_STEP_FIELDS + EVA_STEP_FIELDS + LOOP_STEP_FIELDS,
     ),
     "eval": (("iter", "split", "loss"), ()),
     # lifecycle: checkpointing

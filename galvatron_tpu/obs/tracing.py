@@ -144,6 +144,20 @@ ATTN_EVA_AGG = "gt.attn.eva_agg"
 # projection and its block; its pass through the head and its cross entropy
 # run under HEAD_LOSS, beside the main ones
 MTP = "gt.mtp"
+# a looped stack (`loop_steps` > 1; models/base.looped_states): every pass of the stack and the final norm
+# after it, forward, recomputation and backward. The ENCLOSING scope of the layer runs, not a part of one:
+# the scanned loop is entered under the first run's name (`layers_scope(0)`) and, inside it, this one, because
+# of the names that stand outside the outermost scan the transforms wrap the OUTERMOST alone, and the readers
+# tell a run's backward by the wrapper on its name (`transpose(jvp(gt.layers.r0))/gt.loop/while/body/closed_call/
+# gt.layers.r0/while/body/...`: seen on the chip, PR 64); the runs inside the body keep their own names
+LOOP = "gt.loop"
+# a sandwich norm (`post_norm`; models/base.layer_forward): the norm of each half's OUTPUT before it joins
+# the residual stream, inside gt.layers.r<k> beside the halves' own scopes
+NORM_POST = "gt.norm.post"
+# the exit gate of a looped stack (models/parts/loop.py): the gate's logits, the distribution over the
+# passes, its entropy and the weighting of the passes' cross entropies; the heads and the cross entropies
+# themselves run under HEAD_LOSS
+EXIT = "gt.exit"
 
 
 def attn_core_scope() -> str:

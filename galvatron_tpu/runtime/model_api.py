@@ -67,9 +67,9 @@ class HybridParallelModel:
     # the quantized path with GLS013.
     loss_parts_fn: Optional[Callable] = None  # (params, batch) -> (loss,
     # parts): a routed-experts config's objective with its terms and the
-    # expert load (models/base.lm_loss_fn with_parts), which the step hands
-    # back in `metrics` beside the loss; None (a dense config) leaves the
-    # step as it is
+    # expert load, a looped stack's with its passes' (models/base.lm_loss_fn
+    # with_parts), which the step hands back in `metrics` beside the loss;
+    # None (a dense config) leaves the step as it is
     cast_first: Optional[Params] = None  # tree of bools like param_specs:
     # the leaves this family's loss reads only through a cast to the compute
     # dtype that comes first (parallel/spec.cast_first_tree); None (a custom
@@ -677,7 +677,7 @@ def construct_hybrid_parallel_model(
             table_spec=model.table_spec(),
         )
         local_loss = lambda p, b: M.lm_loss_fn(p, b, cfg)
-        if loss_fn is None and getattr(cfg, "layer_aux", False):
+        if loss_fn is None and (getattr(cfg, "layer_aux", False) or getattr(cfg, "loop_steps", 1) > 1):
             loss_parts = lambda p, b: M.lm_loss_fn(
                 p, b, cfg, hp, mesh, with_parts=True, table_spec=model.table_spec(),
                 zero_splits_state=model._zero_splits_state())
