@@ -903,7 +903,7 @@ def _train(args, started: launch.Launch) -> dict:
                for k in (telemetry.EXPERT_STEP_FIELDS + telemetry.SHARE_STEP_FIELDS
                          + telemetry.LINEAR_STEP_FIELDS + telemetry.SSM_STEP_FIELDS
                          + telemetry.SHARED_STEP_FIELDS + telemetry.EVA_STEP_FIELDS
-                         + telemetry.LOOP_STEP_FIELDS)
+                         + telemetry.LOOP_STEP_FIELDS + telemetry.HYPER_STEP_FIELDS)
                if isinstance(metrics, dict) and k in metrics},
         )
 

@@ -34,7 +34,7 @@ from galvatron_tpu.config.strategy import (
     model_layer_kinds,
 )
 from galvatron_tpu.models.config import TransformerConfig
-from galvatron_tpu.models.parts import COUNTERS, MIXERS, MLP_HALVES, loop, unsupported_reason
+from galvatron_tpu.models.parts import COUNTERS, MIXERS, MLP_HALVES, hyper, loop, unsupported_reason
 from galvatron_tpu.models.parts.common import Params, _dense, _dense_init, _norm, _norm_params
 from galvatron_tpu.models.parts.embed_head import (embed_patches, embed_tokens, head_logits, model_head,
                                                    next_tokens_cross_entropy, softmax_nll, token_cross_entropies,
@@ -67,6 +67,7 @@ def init_layer_params(rng: jax.Array, cfg: TransformerConfig, index: int = 0) ->
         p.update({"ln1_post": _norm_params(cfg), "ln2_post": _norm_params(cfg)})
     p.update(MIXERS[cfg.mixer].init(ks, cfg))
     p.update(MLP_HALVES[cfg.mlp_half].init(ks, cfg))
+    p.update(hyper.init_layer(jax.random.fold_in(rng, 5), cfg))  # (nothing for one residual stream)
     return MIXERS[cfg.mixer].place(p, cfg, index)
 
 
@@ -154,6 +155,10 @@ def layer_forward(
     `MLP_HALVES[cfg.mlp_half]`'s. With ``cfg.post_norm`` (sandwich norms) a
     half's OUTPUT is normed by a norm of its own (`ln1_post`, `ln2_post`,
     under `gt.norm.post`) before it joins the stream: x + norm(Mixer(norm x)).
+    With ``cfg.hc_mult`` = n > 1 (hyper-connections, `parts/hyper.py`) x is the
+    n residual streams side by side, (B, S_local, n H): a half reads ONE vector
+    out of them, `Mixer(norm(read(x)))`, and writes `H_res x + H_post o` back,
+    its coefficients a token from x itself (all under `gt.hc`).
 
     Under GSPMD the parallel form is implied by weight shardings plus the
     activation constraints here and in the mixer.
@@ -182,8 +187,14 @@ def layer_forward(
     if mesh is not None and axes is not None:
         attn_sharding = KernelSharding.for_layer(mesh, axes)
     kv_out, aux, published = None, {}, {}
+    col_errs = []  # hyper-connections' counter, a half
     for norm, half in (("ln1", MIXERS[cfg.mixer]), ("ln2", MLP_HALVES[cfg.mlp_half])):
         residual = x
+        if cfg.hc_mult > 1:
+            with jax.named_scope(tracing.HC):
+                mix, col_err = hyper.coefficients(p[hyper.LEAVES[norm]], x, cfg)
+                x = hyper.read(mix, x)
+            col_errs.append(col_err)
         y = _norm(x, p[norm], cfg) if cfg.pre_norm else x
         how = {"shared": shared} if half.reads else {}
         if publish and half.publishes:
@@ -201,11 +212,17 @@ def layer_forward(
                 o = _norm(o, p[norm + "_post"], cfg)
         if cfg.residual_multiplier != 1.0:
             o = o * cfg.residual_multiplier
-        x = residual + o
+        if cfg.hc_mult > 1:
+            with jax.named_scope(tracing.HC):
+                x = hyper.write(mix, residual, o)
+        else:
+            x = residual + o
         if not cfg.pre_norm:
             x = _norm(x, p[norm], cfg)
     if return_kv:
         return x, kv_out
+    if col_errs:
+        aux = {**aux, **hyper.counters(col_errs)}
     out = (x, aux) if cfg.layer_aux else (x,)
     if publish:
         out += (published,)
@@ -643,7 +660,8 @@ def _forward(
     table_spec: Optional[P] = None,
     zero_splits_state: bool = False,
 ):
-    """-> (logits, the last layer's output before the final norm, the routed
+    """-> (logits, the last layer's output before the final norm (of n
+    hyper-connected streams their sum), the routed
     blocks' auxiliary terms as `run_layers` lists them or None). A looped
     stack (`cfg.loop_steps` > 1): (the LAST pass's logits, every pass's normed
     state (T, B, S, H), None)."""
@@ -659,6 +677,9 @@ def _forward(
                              token_type_ids=token_type_ids, table_spec=table_spec)
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
+    if cfg.hc_mult > 1:  # hyper-connections: the layers carry n residual streams side by side
+        with jax.named_scope(tracing.HC):
+            embedded, x = x, hyper.widen(x, cfg.hc_mult)
     bias = padding_attn_bias(attn_mask) if attn_mask is not None else None
     if cfg.loop_steps > 1:
         states = looped_states(params, x, positions, cfg, hp, mesh, bias, zero_splits_state)
@@ -669,6 +690,10 @@ def _forward(
         return logits, states, None
     x = run_layers(params, x, positions, cfg, hp, mesh, attn_bias=bias, zero_splits_state=zero_splits_state)
     x, auxs = x if cfg.layer_aux else (x, None)
+    if cfg.hc_mult > 1:  # the streams' sum is what the final norm reads; their gain through the stack a counter
+        with jax.named_scope(tracing.HC):
+            x = hyper.contract(x, cfg.hc_mult)
+            auxs = auxs + [{hyper.GAIN: hyper.stream_gain(embedded, x, cfg.hc_mult)}]
     if use_hp:
         x = S.constrain(x, mesh, S.act_spec(vax))
     # the head is the first half of gt.head_loss; the loss functions below
@@ -773,7 +798,8 @@ PART_FOLDS = {EXPERT_LOAD: jnp.maximum, "router_bias_abs_max": jnp.maximum,
               ROUTER_COUNTS: jnp.add, "expert_rows_held": jnp.add, "expert_window_fallbacks": jnp.add,
               "linear_state_abs_max": jnp.maximum, "ssm_state_abs_max": jnp.maximum,
               "selscan_state_abs_max": jnp.maximum, "published_mib": jnp.add}
-# (a part's counter folded as a "mean", `parts.COUNTERS`, is weighted as a loss term is)
+# (a part's counter folded as a "mean", `parts.COUNTERS`, is weighted as a loss term is; as a "max", the worst microbatch's)
+PART_FOLDS.update({name: jnp.maximum for name, how in COUNTERS.items() if how == "max"})
 
 
 def lm_loss_fn(params, batch, cfg, hp=None, mesh=None, with_parts: bool = False,
@@ -925,6 +951,7 @@ def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
         sp.update({"ln1_post": dict(norm), "ln2_post": dict(norm)})
     sp.update(MIXERS[cfg.mixer].specs(cfg, axes))
     sp.update(MLP_HALVES[cfg.mlp_half].specs(cfg, axes))
+    sp.update(hyper.layer_specs(cfg))
     return sp
 
 

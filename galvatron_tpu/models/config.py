@@ -4,7 +4,7 @@ One config covers the reference's model zoo: GPT (learned pos, pre-LN, gelu),
 LLaMA (rope, rmsnorm, swiglu, GQA), BERT/ViT (bidirectional, post-LN), T5
 (relative bias, enc-dec glue in models/t5.py), and the sparse-expert,
 latent-attention, linear-attention, state-space, short-convolution, window-attention,
-selective-scan / shared-memory (SambaY), compressed-context (EVA) and looped (LoopLM) families. A layer is two
+selective-scan / shared-memory (SambaY), compressed-context (EVA), looped (LoopLM) and hyper-connected (mHC) families. A layer is two
 entries of the tables in `models/parts`, a token mixer and an MLP half:
 `mixers()` and `mlp_halves()` name them a layer, and what an entry asks of the
 config (`validate`) and hands back (`counters`) is the entry's to say."""
@@ -191,6 +191,20 @@ class TransformerConfig:
     post_norm: bool = False
     exit_gate: bool = False
     exit_entropy_coef: float = 0.0
+    # --- what Xing4.0's published config adds (xing4): manifold-constrained hyper-connections
+    # (models/parts/hyper.py; arXiv:2512.24880). A token's state is `hc_mult` residual streams of hidden_size;
+    # each half of a layer reads one vector out of them (H_pre), and writes H_res X + H_post o back, H_res made
+    # doubly stochastic by `hc_sinkhorn_iters` Sinkhorn-Knopp steps from exp of a logit clipped to
+    # `hc_res_clamp` (min, max); `hc_eps` guards the streams' RMS and the steps' denominators. The three
+    # learned gates START at `hc_init_gate` (no published config says where): 0.01 is the identity-like start
+    # of arXiv:2409.19606, at which an untrained half IS a pre-norm residual half; at 1 `x~ Phi` comes through
+    # whole and every token mixes its streams its own way from the first step. The defaults are one stream,
+    # whose `residual + o` none of these touches ---
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 1e-6
+    hc_res_clamp: Optional[List[float]] = None  # [min, max]
+    hc_init_gate: float = 0.01
     # which `MIXERS` entry ONE layer runs. `layer_config(kind)` sets it; a
     # model's own config leaves it and states the pattern above
     mixer: str = "attention"
@@ -242,6 +256,9 @@ class TransformerConfig:
                     "layers run, in rising order; got %r" % (known, self.num_layers, self.layer_indices))
         for part in self.parts():  # each part's own clause (latent attention's may set head_dim)
             part.validate(self)
+        from galvatron_tpu.models.parts import hyper  # looked up on use, as `parts()` does
+
+        hyper.validate(self)  # (hyper-connections' clause; it states the clamp as a list of two floats)
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.num_heads
         self.shared()  # a layer that reads what no earlier layer publishes is refused by name
@@ -390,10 +407,10 @@ class TransformerConfig:
     @property
     def layer_aux(self) -> bool:
         """Whether a layer hands back auxiliary terms beside its output (a
-        router's losses and loads, a linear or state-space mixer's counters):
-        of a layer's config its own layer, of a model's config any of its
-        layers."""
-        return any(part.counters for part in self.parts())
+        router's losses and loads, a linear or state-space mixer's counters,
+        hyper-connections' column error): of a layer's config its own layer,
+        of a model's config any of its layers."""
+        return any(part.counters for part in self.parts()) or self.hc_mult > 1
 
     @property
     def rotary_dim(self) -> int:

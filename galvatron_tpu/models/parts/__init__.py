@@ -15,6 +15,7 @@ from galvatron_tpu.models.parts.attention import ATTENTION
 from galvatron_tpu.models.parts.conv import CONV
 from galvatron_tpu.models.parts.cross import CROSS
 from galvatron_tpu.models.parts.eva import COUNTERS as EVA_COUNTERS, EVA
+from galvatron_tpu.models.parts import hyper
 from galvatron_tpu.models.parts.kda import KDA
 from galvatron_tpu.models.parts.linear import LINEAR
 from galvatron_tpu.models.parts import loop
@@ -28,7 +29,7 @@ MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA, "con
 MLP_HALVES = {"dense": DENSE, "routed": ROUTED}
 # counter -> how the stack folds the layers' values into the step's ("mean" | "max" | "sum"), for a counter a part
 # hands back under the `step` event's own name: the stack (models/base._fold_aux, lm_loss_fn) reads the table
-COUNTERS = {**EVA_COUNTERS}
+COUNTERS = {**EVA_COUNTERS, **hyper.COUNTERS}
 
 # how an asker's sentence starts, and what joins the parts' statements in it
 _SAYS = {
@@ -74,7 +75,9 @@ def unsupported_reason(cfg, hp=None, asker: Optional[str] = None, autotune: Opti
     if not callable(parts):  # T5's, Swin's and duck-typed configs are built of none of them
         return None
     layers_say = [part.unsupported(cfg) for part in parts()]
-    says = layers_say + [loop.unsupported(cfg)]  # (the loop wraps the stack and is no entry of the tables)
+    # (the loop wraps the stack and hyper-connections both halves of every layer: neither is an entry of the tables)
+    streams_say = hyper.unsupported(cfg)
+    says = layers_say + [loop.unsupported(cfg), streams_say]
     askers = [(asker, ())] if asker in ("serve", "search", "profile") else []
     if (autotune or "off") != "off":
         askers.append(("autotune", autotune))
@@ -86,5 +89,5 @@ def unsupported_reason(cfg, hp=None, asker: Optional[str] = None, autotune: Opti
             start, glue = _SAYS[name]
             # (a looped or sandwich-norm stack of parts that have every form runs under GSPMD tp too)
             return (start % numbers + glue.join(said) + "; such a config runs on "
-                    + ("one chip and under dp with ZeRO-1/2/3" if any(layers_say) else loop.RUNS))
+                    + ("one chip and under dp with ZeRO-1/2/3" if any(layers_say) or streams_say else loop.RUNS))
     return None

@@ -315,8 +315,11 @@ def latent_qkv_projection(p: Params, y: jax.Array, positions: jax.Array,
     The rotated half of k is one vector a token, the same for every head.
     Kimi-Linear's (HF `KimiMLAAttention`) has no low-rank q (`q_lora_rank` 0:
     q_h = y Wq_h) and no positions (`position_type` "none": q_rope_h and kr
-    enter as they are). q and k are (nope + rope) wide, v `v_head_dim`: the
-    caller pads them to the one attention call's `head_dim`."""
+    enter as they are). Xing4.0's (DeepSeek-V3's own head) turns the rope dims
+    under yarn (`rope_scaling`: the scaled frequencies of a head of `qk_rope`
+    dims, cos and sin x its `attention_factor`); the softmax's scale is the
+    caller's. q and k are (nope + rope) wide, v `v_head_dim`: the caller pads
+    them to the one attention call's `head_dim`."""
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     eps, theta = cfg.layernorm_eps, cfg.rope_theta
     if cfg.q_lora_rank:
@@ -328,8 +331,8 @@ def latent_qkv_projection(p: Params, y: jax.Array, positions: jax.Array,
     ckv = rms_norm(ckv_kr[..., :cfg.kv_lora_rank], p["kv_a_norm"]["scale"], eps)
     kv = jnp.einsum("bsr,rnd->bsnd", ckv, p["wkv_b"]["kernel"].astype(dtype))
     if cfg.position_type == "rope":
-        q_rope = apply_rotary(q[..., nope:], positions, theta)
-        k_rope = apply_rotary(ckv_kr[:, :, None, cfg.kv_lora_rank:], positions, theta)
+        q_rope = apply_rotary(q[..., nope:], positions, theta, scaling=cfg.rope_scaling)
+        k_rope = apply_rotary(ckv_kr[:, :, None, cfg.kv_lora_rank:], positions, theta, scaling=cfg.rope_scaling)
         q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
     else:
         k_rope = ckv_kr[:, :, None, cfg.kv_lora_rank:]

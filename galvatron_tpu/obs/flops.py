@@ -200,6 +200,14 @@ def eva_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, window:
     return 2.0 * hidden * q_dim * 4, 2.0 * (2.0 * pairs_a_token * q_dim) + 2.0 * (2.0 * q_dim)
 
 
+def hyper_fwd_flops_a_token(*, hidden: int, streams: int) -> float:
+    """Hyper-connections around ONE layer (models/parts/hyper.py: `streams` = n residual streams, two halves):
+    a half's coefficients `x~ Phi` 2 n hidden (n^2 + 2n), its read 2 n hidden, its write 2 n^2 hidden + 2 n
+    hidden. The Sinkhorn steps and the sigmoids are no matmul."""
+    n = streams
+    return 2.0 * (2.0 * n * hidden * (n * n + 2 * n) + 2.0 * n * hidden + 2.0 * n * n * hidden + 2.0 * n * hidden)
+
+
 # the row of each `MIXERS` key, and the config fields its keyword arguments read
 # ("seq_len": no field, the sequence length the count is asked at)
 _DELTA_DIMS = {k: "linear_" + k for k in ("num_key_heads", "num_value_heads", "key_head_dim", "value_head_dim")}
@@ -295,7 +303,10 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
         latent = {k: getattr(cfg, k) for k in (
             "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")}
     mixer = getattr(cfg, "mixer", "attention")
-    return getattr(cfg, "loop_steps", 1) * layer_fwd_flops(
+    streams = getattr(cfg, "hc_mult", 1)  # > 1: hyper-connections around both halves, over the same tokens
+    around = (float(seq if tokens is None else tokens) * hyper_fwd_flops_a_token(hidden=hidden, streams=streams)
+              if streams > 1 else 0.0)
+    return around + getattr(cfg, "loop_steps", 1) * layer_fwd_flops(
         hidden=hidden,
         num_heads=heads,
         seq_len=seq,

@@ -43,6 +43,7 @@ VOCAB_SPLIT = "vocab_split"  # parallel/pipeline's scan engine: the mesh axes, a
 # models/base.run_layers: "zero_layout" a stacked leaf asked for in ZeRO's; "compute_dtype" a scanned run whose
 # cotangents are stacked in the compute dtype (the launch's answer to a device with little room beside the state)
 SCAN_GRADS = "scan_grads"
+HYPER = "hyper"  # models/parts/hyper.coefficients: "xla", its one form, a half of a hyper-connected layer
 
 
 class _Heard(threading.local):

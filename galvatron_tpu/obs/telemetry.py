@@ -85,6 +85,11 @@ EVA_STEP_FIELDS = ("eva_pooled_mass",)
 # exits at (sum_t t p_t, 1 .. loop_steps) and the mean entropy of that distribution; folded over the
 # microbatches as loss terms are
 LOOP_STEP_FIELDS = ("loss_ce_first", "loss_ce_last", "exit_step_mean", "exit_entropy")
+# hyper-connections' counters (models/parts/hyper.py): the worst token's and half's |column sum of H_res - 1|
+# (rows sum to 1 by construction: says the Sinkhorn steps converged) and the RMS of the n streams' sum after
+# the last layer over its RMS before the first (the signal's gain through the mixes, what the manifold
+# constraint bounds)
+HYPER_STEP_FIELDS = ("hc_res_col_err", "hc_stream_gain")
 
 # type -> (required field names, optional field names). Unknown types and
 # unknown keys are rejected; None-valued optional fields are dropped at emit
@@ -135,7 +140,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
         ("loss", "iter_ms", "dispatch_ms", "data_wait_ms", "host_blocked_ms",
          "hbm_in_use_mb", "hbm_peak_mb", "mfu", "model_flops_per_s",
          "grad_norm") + EXPERT_STEP_FIELDS + SHARE_STEP_FIELDS + LINEAR_STEP_FIELDS
-        + SSM_STEP_FIELDS + SHARED_STEP_FIELDS + EVA_STEP_FIELDS + LOOP_STEP_FIELDS,
+        + SSM_STEP_FIELDS + SHARED_STEP_FIELDS + EVA_STEP_FIELDS + LOOP_STEP_FIELDS + HYPER_STEP_FIELDS,
     ),
     "eval": (("iter", "split", "loss"), ()),
     # lifecycle: checkpointing

@@ -158,6 +158,16 @@ NORM_POST = "gt.norm.post"
 # passes, its entropy and the weighting of the passes' cross entropies; the heads and the cross entropies
 # themselves run under HEAD_LOSS
 EXIT = "gt.exit"
+# hyper-connections (`hc_mult` > 1; models/parts/hyper.py), inside gt.layers.r<k> around BOTH halves of a layer:
+# gt.hc holds all of it and, nested in it, three disjoint scopes that add up to it: a half's coefficients (the
+# mean square over the n streams, `x~ Phi`, the sigmoids), the Sinkhorn steps that make H_res, and the mixes
+# (what a half reads out of the streams and writes back into them; the widening after the embedding and the
+# streams' sum before the final norm, which stand outside the layer runs). The halves' own scopes
+# (gt.attn.*, gt.mlp, gt.moe.*) lie inside none of them
+HC = "gt.hc"
+HC_COEF = "gt.hc.coef"
+HC_SINKHORN = "gt.hc.sinkhorn"
+HC_MIX = "gt.hc.mix"
 
 
 def attn_core_scope() -> str:

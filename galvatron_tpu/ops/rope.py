@@ -19,10 +19,17 @@ YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_sl
 def checked_scaling(scaling):
     """`scaling` as `rope_frequencies` takes it, or a ValueError that names
     what it has no form of: None, or a mapping whose `rope_type` is "yarn" with
-    yarn's numbers (`YARN_KEYS`) and nothing else."""
+    yarn's numbers (`YARN_KEYS`) and nothing else. A raw DeepSeek-style mapping
+    (`type`, `mscale`, `mscale_all_dim`) is refused with where to map it."""
     if scaling is None:
         return None
     kind = scaling.get("rope_type")
+    if kind is None and "type" in scaling:
+        # DeepSeek's spelling (`type`, `mscale`, `mscale_all_dim`) is a family file's to map: `mscale_all_dim`
+        # scales the SOFTMAX, which is no number of the rotation's
+        raise ValueError("rope_scaling states type=%r in DeepSeek's spelling (type, mscale, mscale_all_dim): a family "
+                         "file maps it onto rope_type, %s and the config's attention_multiplier "
+                         "(models/xing4.yarn_from_deepseek)" % (scaling["type"], ", ".join(YARN_KEYS)))
     if kind != "yarn":
         raise ValueError("rope_scaling rope_type=%r has no form here: ops/rope.py knows \"yarn\" "
                          "(and no scaling at all, rope_scaling=None)" % (kind,))

@@ -167,7 +167,8 @@ def cast_first_tree(param_specs, *, table_stored: bool):
     its convolution's taps and its gate's `A_log` and `dt_bias` (float32
     arithmetic); an EVA attention layer's `phi` and `mu` (the dict "eva": the
     pooling is float32); a looped stack's exit gate (the dict "exit_gate":
-    float32 logits); and with `table_stored` the
+    float32 logits); hyper-connections' leaves (the dicts "hc1", "hc2": float32
+    coefficients); and with `table_stored` the
     token table: one that `vocab_parallel_lookup` takes into its manual
     region (rows gathered from the stored shard, the gradient scatter-added
     in the stored dtype), or a tied one, whose uses' cotangents are each
@@ -186,7 +187,7 @@ def cast_first_tree(param_specs, *, table_stored: bool):
             routed = ("router", "wi", "wo_mlp") if "router" in node else ()
             return {
                 k: walk(v, stored or norm or k in routed or k.endswith("rel_bias")
-                        or k in ("conv", "A_log", "dt_bias", "eva", "exit_gate")
+                        or k in ("conv", "A_log", "dt_bias", "eva", "exit_gate", "hc1", "hc2")
                         or (table_stored and k == "wte"))
                 for k, v in node.items()
             }
