@@ -12,9 +12,10 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from galvatron_tpu.models.config import TransformerConfig
+from galvatron_tpu.models.parts.attention import _written_out
 from galvatron_tpu.models.parts.common import (LayerPart, Params, _activation, _dense, _dense_init, _proj_std,
                                                no_form)
-from galvatron_tpu.obs import tracing
+from galvatron_tpu.obs import forms, tracing
 from galvatron_tpu.ops.moe import moe_ffn, swiglu
 from galvatron_tpu.parallel import spec as S
 from galvatron_tpu.parallel.mesh import LayerAxes
@@ -66,16 +67,59 @@ def _grad_as_stored_bwd(held_in, _, g):
 grad_as_stored.defvjp(lambda kernel, held_in: (kernel, None), _grad_as_stored_bwd)
 
 
+@jax.custom_vjp
+def _matmul_of_written_out(x: jax.Array, kernel: jax.Array) -> jax.Array:
+    """x @ kernel whose FORWARD product reads x as an array of its own (an
+    optimization barrier), so that x's producer is no operand fusion of this
+    matmul; the backward is the matmul's own transpose. An exact GELU (`erf`
+    as a polynomial: 28 multiplies, an exponential and two divisions an
+    element) folded into the down projection's operand holds that matmul at
+    47 % of a v5e's MXU (11.8 ms a layer of `gpt67-c1-s2k`); reading an array
+    it runs at 94 % (5.96 ms; PERF.md section 6, PR 67).
+
+    The residual is x AS IT CAME, not the barrier's result. Under `jax.grad`
+    the first forward and `jax.checkpoint`'s recomputation are one jvp'd jaxpr
+    (this rule's `fwd`), so a barrier on the VALUE (`attention._written_out`,
+    the form for a value whose recomputation writes it anyway, or that is not
+    recomputed) is in both: on the activation, the recomputed up projection
+    then writes three (4, 2048, 16384) arrays where it wrote one,
+    `gpt67-c1-s2k`'s `temp` 5,838,919,680 -> 7,246,128,640 B and
+    `step_hbm_gib` 12.334 -> 13.644 (rehearsal, ISSUE 67). Here the
+    recomputation's product is dead and goes with its barrier, and what the
+    backward keeps is the unbarriered activation, which XLA fuses and stores
+    as it did: `temp` to the byte."""
+    return jax.lax.optimization_barrier(x) @ kernel
+
+
+_matmul_of_written_out.defvjp(lambda x, kernel: (jax.lax.optimization_barrier(x) @ kernel, (x, kernel)),
+                              lambda res, g: jax.vjp(jnp.matmul, *res)[1](g))
+
+
 def dense_mlp(p: Params, y: jax.Array, cfg: TransformerConfig, dtype) -> jax.Array:
-    """The dense MLP half on normed activations (B, S, H)."""
+    """The dense MLP half on normed activations (B, S, H). An activation that
+    is transcendental and not gated (the GELUs) is a pass of its own in the
+    forward, from an array into an array: the down projection reads it
+    written out (`_matmul_of_written_out`), and it reads the pre-activation
+    written out (`_written_out`: the one array the recomputed up projection
+    writes in any case, so the barrier costs the recomputation nothing). As
+    the up projection's epilogue the GELU costs the same 1.9 ms a layer, but
+    is taken of the matmul's float32 sum before it is rounded to the compute
+    dtype, which the recomputation's is: with the pre-activation written out
+    every loss is the unbarriered program's to the bit. SiLU x gate and ReLU
+    cost an operand fusion nothing and stay folded into it."""
     wi_out = jnp.einsum("bsh,h...->bs...", y, p["wi"]["kernel"].astype(dtype))
     if "bias" in p["wi"]:
         wi_out = wi_out + p["wi"]["bias"].astype(dtype)
+    written_out = cfg.activation in ("gelu", "gelu_exact")
+    forms.took(forms.MLP_ACTIVATION, "written_out" if written_out else "folded")
     if cfg.activation == "swiglu":
         hmid = jax.nn.silu(wi_out[:, :, 0]) * wi_out[:, :, 1]
     else:
-        hmid = _activation(wi_out, cfg)
-    return _dense(hmid, p["wo_mlp"], dtype)
+        hmid = _activation(_written_out(wi_out) if written_out else wi_out, cfg)
+    if not written_out:
+        return _dense(hmid, p["wo_mlp"], dtype)
+    out = _matmul_of_written_out(hmid, p["wo_mlp"]["kernel"].astype(dtype))  # and the bias as `_dense` adds it
+    return out + p["wo_mlp"]["bias"].astype(dtype) if "bias" in p["wo_mlp"] else out
 
 
 def _dense_forward(p: Params, y: jax.Array, positions, cfg: TransformerConfig, **_):

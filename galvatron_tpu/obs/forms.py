@@ -44,6 +44,7 @@ VOCAB_SPLIT = "vocab_split"  # parallel/pipeline's scan engine: the mesh axes, a
 # cotangents are stacked in the compute dtype (the launch's answer to a device with little room beside the state)
 SCAN_GRADS = "scan_grads"
 HYPER = "hyper"  # models/parts/hyper.coefficients: "xla", its one form, a half of a hyper-connected layer
+MLP_ACTIVATION = "mlp_activation"  # models/parts/mlp.dense_mlp: "written_out" (a GELU) | "folded" into the down matmul, a call
 
 
 class _Heard(threading.local):

@@ -58,8 +58,9 @@ def _opcodes(comps: dict, line: str) -> list:
 def head_ops(hlo: str, scope: str = SCOPE) -> list:
     """The operations outside fused computations whose `op_name` carries
     `scope`, in program order: name, opcode, what the op_name ends in, whether
-    it is the backward (`transpose(`), its output and operand shapes, the
-    opcodes of the fusion it calls, and XLA's cycle estimate."""
+    it is the backward (`transpose(`) and of it the recomputation
+    (`rematted_computation`), its output and operand shapes, the opcodes of
+    the fusion it calls, and XLA's cycle estimate."""
     comps = computations(hlo)
     shapes = {}
     for lines in comps.values():
@@ -87,6 +88,7 @@ def head_ops(hlo: str, scope: str = SCOPE) -> list:
                 "name": m.group("name"), "code": m.group("code"),
                 "op_name": op_name.group(1).rsplit(scope, 1)[1].lstrip("/)"),
                 "backward": "transpose(" in op_name.group(1),
+                "recomputed": "rematted_computation" in op_name.group(1),
                 "out": _SHAPE.findall(m.group("shape")),
                 "operands": [s for a in re.findall(r"%([\w.-]+)", args)
                              for s in _SHAPE.findall(shapes.get(a, ""))],

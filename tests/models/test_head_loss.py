@@ -253,4 +253,8 @@ def test_forward_only_callers_lower_to_the_plain_forms_text(family, devices8, pl
     got = texts()
     plain_form()
     assert got == texts()
-    assert not [t for t in got if "optimization_barrier" in t]
+    # (a GELU's MLP holds a barrier of its own in every caller, models/parts/mlp._matmul_of_written_out:
+    # on the activation, (.., ffn), never on a kernel)
+    barriers = [line for t in got for line in t.splitlines() if "optimization_barrier" in line]
+    assert all(re.search(r": tensor<(\d+x)+%dxbf16>$" % cfg.ffn_hidden, b) for b in barriers), barriers
+    assert bool(barriers) == (family == "gpt_tied")

@@ -277,24 +277,29 @@ def test_the_pipelined_cell_splits_its_vocabulary_over_pp_on_v5e(pp2tp2_cell_ste
     assert kinds["all-reduce"] and kinds["collective-permute"] and "tpu_custom_call" in step.as_text()
 
 
-@pytest.fixture(scope="module")
-def one_chip_head_ops(v5e_2x2):
-    """The operations under `gt.head_loss` of a narrow LLaMA's train step
-    (float32 parameters, bf16 compute, an untied (512, 32000) head) compiled
-    for one described chip, as `scripts/head_fusions.py` lists them."""
+def _head_fusions():
+    """scripts/head_fusions.py, which reads a compiled step's operations under a scope."""
     import importlib.util
-
-    from galvatron_tpu.config.strategy import HybridParallelConfig
-    from galvatron_tpu.models.llama import llama_config
 
     spec = importlib.util.spec_from_file_location(
         "head_fusions", os.path.join(REPO, "scripts", "head_fusions.py"))
     head_fusions = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(head_fusions)
+    return head_fusions
+
+
+@pytest.fixture(scope="module")
+def one_chip_head_ops(v5e_2x2):
+    """The operations under `gt.head_loss` of a narrow LLaMA's train step
+    (float32 parameters, bf16 compute, an untied (512, 32000) head) compiled
+    for one described chip, as `scripts/head_fusions.py` lists them."""
+    from galvatron_tpu.config.strategy import HybridParallelConfig
+    from galvatron_tpu.models.llama import llama_config
+
     cfg = llama_config("llama-0.3b", num_layers=2, hidden_size=512, num_heads=4, ffn_hidden=1024,
                        vocab_size=32000, max_seq_len=256, compute_dtype=jnp.bfloat16)
     hp = HybridParallelConfig.uniform(1, 2, global_bsz=4, mixed_precision="bf16")
-    return head_fusions.head_ops(_compile_train_step(cfg, hp, v5e_2x2[:1], batch_rows=4).as_text())
+    return _head_fusions().head_ops(_compile_train_step(cfg, hp, v5e_2x2[:1], batch_rows=4).as_text())
 
 
 def test_the_heads_matmuls_read_one_bf16_kernel_on_v5e(one_chip_head_ops):
@@ -325,3 +330,36 @@ def test_the_cross_entropy_sweeps_the_logits_once_each_way_on_v5e(one_chip_head_
     assert len(with_exp) <= 3 and sum(o["exp"] for o in with_exp) <= 3, with_exp
     assert [o["matmul"] for o in with_exp if not o["backward"]] == [False], with_exp
     assert all(o["matmul"] for o in with_exp if o["backward"]), with_exp
+
+
+def test_a_gelu_is_written_out_in_the_forward_alone_on_v5e(v5e_2x2):
+    """models/parts/mlp.dense_mlp's two barriers in the compiled step: one
+    layer at Cerebras-GPT-6.7B's widths (hidden 4096, ffn 16384, the exact
+    GELU) under `--checkpoint 1`, 256 tokens (the compiler folds the GELU into
+    the down projection at any batch; about 8 s). In the FORWARD the `erf`
+    (its `exponential`) is a fusion of its own and no fusion nested in either
+    projection's holds one: both matmuls read and write arrays (the down
+    projection 47 % of the MXU -> 94 %, PERF.md section 6, PR 67). The
+    RECOMPUTED up projection writes ONE array of the activation's size, as
+    without the rules: a barrier on the activation's VALUE is in the
+    recomputation too, which then writes the activation beside the
+    pre-activation (`step_hbm_gib` + 10.6 % in `gpt67-c1-s2k`, ISSUE 67)."""
+    from galvatron_tpu.config.strategy import HybridParallelConfig
+    from galvatron_tpu.models.gpt import gpt_config
+
+    tokens, ffn = 256, 16384
+    cfg = gpt_config("gpt-6.7b", num_layers=1, hidden_size=4096, num_heads=32, head_dim=128, ffn_hidden=ffn,
+                     vocab_size=1024, max_seq_len=tokens, activation="gelu_exact", compute_dtype=jnp.bfloat16)
+    hp = HybridParallelConfig.uniform(1, 1, checkpoint=1, global_bsz=1, mixed_precision="bf16")
+    with forms.recording() as took:
+        step = _compile_train_step(cfg, hp, v5e_2x2[:1], batch_rows=1)
+    assert set(took[forms.MLP_ACTIVATION]) == {"written_out"}
+    ops = _head_fusions().head_ops(step.as_text(), scope="gt.mlp")
+    up, down = [o for o in ops if o["matmul"] and not o["backward"]]
+    assert up["op_name"].endswith("->bs.../dot_general") and down["op_name"] == "dot_general", (up, down)
+    assert (up["exp"], down["exp"]) == (0, 0), (up, down)
+    assert [o["exp"] for o in ops if o["exp"] and not o["backward"]] == [1], ops
+    recomputed, = [o for o in ops if o["matmul"] and o["recomputed"]]
+    activation_sized = [s for s in recomputed["out"]
+                        if np.prod([int(d) for d in re.findall(r"\d+", s.partition("[")[2])]) == tokens * ffn]
+    assert recomputed["op_name"].endswith("->bs.../dot_general") and len(activation_sized) == 1, recomputed
