@@ -25,6 +25,7 @@ from galvatron_tpu.cli.arguments import (
     initialize_galvatron,
     model_config_from_args,
 )
+from galvatron_tpu.config.strategy import model_layer_kinds
 from galvatron_tpu.obs import compiled as obs_compiled
 from galvatron_tpu.obs import flops as obs_flops
 from galvatron_tpu.obs import forms, launch, telemetry, tracing
@@ -133,19 +134,43 @@ def _step_exec_key(mesh, lowered):
     return (devs, hashlib.sha256(lowered.as_text().encode()).hexdigest())
 
 
-def _scan_grad_sums_mb(model, compiled) -> dict:
-    """`obs/compiled.dp_grad_sums_mb` of the compiled step over the dp groups
-    of the model's layers: {} on one chip, in a layout without a dp axis,
-    where the executable gives no text, and where no telemetry sink would
-    hear of it (printing the step's text is the launch's to pay for)."""
-    dp_axes = {layer_axes(model.hp, i).dp for i in range(len(model.hp.layers))} - {()}
-    if model.mesh.devices.size == 1 or not dp_axes or telemetry.active_sink() is None:
+def _step_census(model, compiled) -> dict:
+    """The compiled step's collectives, from ONE `as_text()` and one walk of it
+    (`obs/compiled.py`): `rows` (`step_collectives`: the summary's
+    `step_collectives` and the `compile` event's `collectives`), `census_ms` what
+    printing and walking the text took, and `dp_grad_mb` (`dp_grad_sums_mb` over
+    the dp groups of the model's layers: the event's two `dp_grad_*_mb`; {} in a
+    layout without a dp axis). {} on one chip and where the executable gives no
+    text."""
+    if model.mesh.devices.size == 1:
         return {}
+    t = time.perf_counter()
     try:
         text = compiled.as_text()
     except Exception:  # an executable read back without its modules
         return {}
-    return obs_compiled.dp_grad_sums_mb(text, [obs_compiled.axis_groups(model.mesh, dp) for dp in dp_axes])
+    walked = obs_compiled.walk(text)
+    rows = obs_compiled.step_collectives(walked, model.mesh, model.hp, model_layer_kinds(getattr(model, "cfg", None)))
+    dp_axes = {layer_axes(model.hp, i).dp for i in range(len(model.hp.layers))} - {()}
+    dp_grad_mb = obs_compiled.dp_grad_sums_mb(
+        walked, [obs_compiled.axis_groups(model.mesh, dp) for dp in dp_axes]) if dp_axes else {}
+    return {"rows": rows, "census_ms": (time.perf_counter() - t) * 1e3, "dp_grad_mb": dp_grad_mb}
+
+
+def _census_at_compile(model, compiled) -> dict:
+    """`_step_census` where a telemetry sink would hear of it as the step
+    compiles, {} where none listens: printing the step's text is then not the
+    launch's to pay for, and the run's end does it (the summary's
+    `step_collectives`)."""
+    return _step_census(model, compiled) if telemetry.active_sink() is not None else {}
+
+
+def _scan_grad_sums_mb(model, compiled) -> dict:
+    """The `compile` event's `dp_grad_all_reduce_mb` / `dp_grad_reduce_scatter_mb`
+    as `_census_at_compile` has them (tests/ops/test_tpu_compile_steps.py holds
+    the four-chip cell to them): {} on one chip, in a layout without a dp axis,
+    where the executable gives no text, and where no telemetry sink listens."""
+    return _census_at_compile(model, compiled).get("dp_grad_mb", {})
 
 
 def _compile_step(lowered, counters: launch.JitCounters):
@@ -614,7 +639,7 @@ def _train(args, started: launch.Launch) -> dict:
     # output shardings (model_api.make_train_step), so that is a bug, not a
     # reason to compile again. Only step fns wrapped by fault hooks, which
     # have no jit surface to lower, are called as they are.
-    _aot = {"fn": None}
+    _aot = {"fn": None, "census": {}}
 
     def compiled_step(*step_args):
         if not hasattr(step_fn, "trace"):
@@ -648,6 +673,7 @@ def _train(args, started: launch.Launch) -> dict:
             trace_ms, compile_ms = traced_in.ms + lowered_in.ms, keyed_in.ms + loaded_in.ms
             prof.record_compile(trace_ms=trace_ms, compile_ms=compile_ms, cache_hit=cache_hit)
             prof.compiled_memory_mb = compiled_step_memory_mb(compiled) or None
+            _aot["census"] = census = _census_at_compile(model, compiled)
             telemetry.emit(
                 "compile",
                 trace_ms=trace_ms,
@@ -670,7 +696,10 @@ def _train(args, started: launch.Launch) -> dict:
                 # over dp inside a scanned run's backward, whole onto every
                 # chip (`dp_grad_all_reduce_mb`) or into ZeRO's shards
                 # (`dp_grad_reduce_scatter_mb`); absent without a dp axis
-                **_scan_grad_sums_mb(model, compiled),
+                **census.get("dp_grad_mb", {}),
+                # every collective of the compiled step, a row an instruction the device trace
+                # names (obs/compiled.step_collectives); absent on one chip. From the same walk
+                collectives=census.get("rows"),
             )
             _aot["fn"] = compiled
         return _aot["fn"](*step_args)
@@ -1178,7 +1207,7 @@ def _train(args, started: launch.Launch) -> dict:
             hp, cfg, optimizer_args_from(args), mesh=model.mesh,
             memory_budget_gb=getattr(args, "elastic_memory_gb", None))
         step_fn = build_step_fn()
-        _aot["fn"] = None  # re-lower; the executable memo absorbs repeats
+        _aot.update(fn=None, census={})  # re-lower; the executable memo absorbs repeats
         if sdc_ladder is not None:
             # the convicted device is out of the new mesh; surviving devices
             # start with a clean slate
@@ -1444,6 +1473,12 @@ def _train(args, started: launch.Launch) -> dict:
     summary["resilience"] = res.as_dict()
     # how this run came by runtime/checkpoint, as it stands now (the `launch` event: at the first drain)
     summary["checkpoint_import"] = ckpt.fields()
+    # the compiled step's collectives (obs/compiled.step_collectives): counted as the step
+    # compiled where a sink listened then, else HERE, once the last step has drained and a
+    # trace has stopped, so that neither the launch nor a step waits for the text
+    census = _aot["census"] or (_step_census(model, _aot["fn"]) if _aot["fn"] is not None else {})
+    if census:
+        summary["step_collectives"] = {"rows": census["rows"], "census_ms": census["census_ms"]}
     if tuner is not None:
         summary["autotune"] = {"plans": tuner.plans, "swaps": tuner.swaps}
     if wd is not None:

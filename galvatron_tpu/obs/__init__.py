@@ -16,6 +16,11 @@ is the measurement substrate that closes it at runtime:
   trace / lower / compilation-cache counters on the way (the ``launch`` event,
   the summary's ``launch_ms`` / ``launch_imports`` / ``launch_jit``; beside them
   ``checkpoint_import``, how the run came by ``runtime/checkpoint``: cli/train.py).
+- ``obs.compiled``    — what the compiler made of the step, read off its text
+  once: every collective by kind, mesh axes, role, wire bytes, scope and the
+  instruction that runs it, the fused and the hidden ones among them (the
+  ``compile`` event's ``collectives`` and ``dp_grad_*_mb``, the summary's
+  ``step_collectives``; ``cli report`` prints the table).
 - ``obs.report``      — offline analysis of a telemetry JSONL
   (``python -m galvatron_tpu.cli report``): steady-state detection, MFU,
   lifecycle timeline, divergence table.

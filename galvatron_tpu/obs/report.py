@@ -58,9 +58,10 @@ TIMELINE_TYPES = (
 # sdc_check is off it for the same reason: it is a per-interval heartbeat,
 # not a lifecycle transition — only mismatches and quarantines are.
 
-# timeline rendering: the watchdog's stack dump and a migration's full
-# strategy JSON are post-mortem payloads, not one-line timeline material
-_TIMELINE_ELIDED_KEYS = ("stacks", "from_strategy", "to_strategy")
+# timeline rendering: the watchdog's stack dump, a migration's full
+# strategy JSON and the compiled step's collectives (a table of their own
+# above) are post-mortem payloads, not one-line timeline material
+_TIMELINE_ELIDED_KEYS = ("stacks", "from_strategy", "to_strategy", "collectives")
 
 
 # ---------------------------------------------------------- steady state
@@ -389,6 +390,33 @@ def _render_launch(launch: Dict[str, Any], summary: Optional[Dict[str, Any]] = N
     return lines
 
 
+def _render_collectives(rows: List[Dict[str, Any]], census_ms: Optional[float] = None) -> List[str]:
+    """The compiled step's collectives (`obs/compiled.step_collectives`) as a
+    table: a line a (role, kind, form, scope, phase) with its instructions,
+    its operand and its wire MB (1e6 bytes a chip, each instruction once,
+    however often the step runs it), then what share the compiler hid
+    inside matmuls."""
+    groups: Dict[Tuple[str, ...], List[float]] = {}
+    for row in rows:
+        key = (row["role"], row["kind"], row["form"], row.get("scope") or "-", row.get("phase") or "-")
+        n, operand, wire = groups.get(key, (0, 0.0, 0.0))
+        groups[key] = [n + 1, operand + row["operand_bytes"], wire + row["wire_bytes"]]
+    lines = ["collectives of the compiled step: %d instructions%s" % (
+        len(rows), "" if census_ms is None else ", counted in %s ms" % _fmt(census_ms))]
+    lines.append("  %-8s %-18s %-7s %-18s %-6s %5s %11s %11s" % (
+        "role", "kind", "form", "scope", "phase", "n", "operand MB", "wire MB"))
+    for key, (n, operand, wire) in sorted(groups.items()):
+        lines.append("  %-8s %-18s %-7s %-18s %-6s %5d %11.2f %11.2f" % (key + (n, operand / 1e6, wire / 1e6)))
+    total = sum(row["wire_bytes"] for row in rows)
+    # (a collective's bytes stand on one of its rows: that row is the collective)
+    carriers = [row for row in rows if row["wire_bytes"]]
+    hidden = [row for row in carriers if row["form"] == "hidden"]
+    lines.append("  hidden: %d of %d collectives, %s %% of the wire bytes" % (
+        len(hidden), len(carriers),
+        _fmt(100.0 * sum(row["wire_bytes"] for row in hidden) / total if total else None)))
+    return lines
+
+
 def render(analysis: Dict[str, Any]) -> str:
     run = analysis["run"]
     steps = analysis["steps"]
@@ -442,6 +470,9 @@ def render(analysis: Dict[str, Any]) -> str:
         if "dp_grad_all_reduce_mb" in comp:
             lines.append("a scanned layer's weight gradients over dp, MB a chip: %s all-reduced, %s reduce-scattered"
                          % (_fmt(comp["dp_grad_all_reduce_mb"]), _fmt(comp.get("dp_grad_reduce_scatter_mb"))))
+    census = (analysis.get("summary") or {}).get("step_collectives") or {}
+    if census.get("rows") or comp.get("collectives"):
+        lines.extend(_render_collectives(census.get("rows") or comp["collectives"], census.get("census_ms")))
     an = analysis["anomalies"]
     lines.append(
         "resilience: %d anomalies skipped, %d rollbacks, %d retries"

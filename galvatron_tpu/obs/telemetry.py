@@ -112,11 +112,16 @@ EVENT_SCHEMAS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
     ),
     # one-off program build cost + the compiler-reported working set the
     # MemoryCostModel prediction is checked against; `forms`: which form each
-    # part of the step took as it was traced, part -> form -> count (obs/forms.py)
+    # part of the step took as it was traced, part -> form -> count (obs/forms.py);
+    # `collectives`: every collective of the compiled step on more than one chip, a
+    # row an instruction the device trace names (obs/compiled.step_collectives:
+    # instruction, kind, form, group, axes, role, operand_bytes, wire_bytes, scope,
+    # phase), where a sink listens as the step compiles; the run_end summary's
+    # `step_collectives` {rows, census_ms} holds the same rows for every such run
     "compile": (
         (),
         ("trace_ms", "compile_ms", "compiled_memory_mb", "xla_flops_per_step",
-         "cache_hit", "forms", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb",
+         "cache_hit", "forms", "dp_grad_all_reduce_mb", "dp_grad_reduce_scatter_mb", "collectives",
          "mamba_layers", "shared_readers", "eva_layers", "eva_windows", "eva_pooled_keys"),
     ),
     # where the start went, once the first step has drained (obs/launch.py):
