@@ -37,6 +37,9 @@ WINDOW_OPERANDS = "window_operands"  # and, of the first, "as_projected": q untu
 EVA_ATTENTION = "eva_attention"  # ops/eva_attention.aggregate: "pallas" | "xla" a call
 MOE_ROWS = "moe_rows"  # ops/moe._local_moe: `rows_form`'s "kernel" | "xla" a routed block
 EXPERT_WINDOW = "expert_window"  # a block of a share: the rows of its window ("0": the whole range)
+# ops/moe._local_moe, a block on the megablox kernels: "<kernel> <K>x<N> r<even rows a group>: <tm>x<tk>x<tn>",
+# once a distinct call (`ops/moe.gmm_tiling`; K, N: the dims the call's K and N tiles run over)
+GMM_TILES = "gmm_tiles"
 GATED_KERNEL_GRADS = "gated_kernel_grads"  # models/base._gated_grads_as_stored: "as_stored" a leaf
 TABLE_LOOKUP = "table_lookup"  # models/parts/embed_head.vocab_parallel_lookup: "rows_over_dp" | "table_whole"
 VOCAB_SPLIT = "vocab_split"  # parallel/pipeline's scan engine: the mesh axes, as "pp,m0"

@@ -7,9 +7,9 @@ outputs weighted by the router's probabilities. **Dropless**: there is no
 capacity factor, no padding to a fixed capacity and no dropped token; the
 `tokens x k` assignments are sorted by expert, the rows gathered in that
 order, and each expert multiplies its own ragged group of rows
-(`grouped_matmul`: on a TPU the Pallas megablox kernels at a measured tiling,
-elsewhere `jax.lax.ragged_dot`; `scripts/moe_gmm_sweep.py` is the chip
-measurement behind the choice).
+(`grouped_matmul`: on a TPU the Pallas megablox kernels, each call at the
+tiling its shapes take, `gmm_tiling`; elsewhere `jax.lax.ragged_dot`;
+`scripts/moe_gmm_sweep.py` is the chip measurement behind the rule).
 
 The router runs in float32 at `highest` matmul precision whatever the
 compute dtype: the choice of experts is a discrete function of its logits,
@@ -101,35 +101,159 @@ def moe_aux_names(score: str, bias: bool, held: bool) -> Tuple[str, ...]:
             + (("rows_held", "window_fallbacks") if held else ()))
 
 
-# (rows, K, N) tiles of the megablox kernels, measured on a v5e at OLMoE's
-# shapes (65536 rows, K and N of 1024 and 2048; scripts/moe_gmm_sweep.py):
-# forward + backward of the two matmuls 17.5 ms against 24.2 for XLA:TPU's own
-# `ragged_dot` kernel and 188 at megablox's default 128-tiles; larger tiles
-# run out of the 16 MiB of scoped VMEM, as these do for 4-byte operands, whose
-# K and N tiles are therefore half the size
+# The megablox kernels' tiles (rows, K, N). `GMM_TILING` is what every call took
+# until PR 69, measured on a v5e at OLMoE's shapes (65536 rows in 64 groups, K
+# and N of 1024 and 2048: forward + backward of the two matmuls 17.5 ms against
+# 24.2 for XLA:TPU's own `ragged_dot` kernel and 188 at megablox's default
+# 128-tiles; PERF.md, PR 27), and is still where `gmm_tiling` starts from and
+# what OLMoE's calls take. A tile wider than its array is not refused, it is
+# MASKED and multiplied (a K or N of 512 at twice the passes, 2304 at 3 x 1024),
+# and a 512-row tile that straddles a group's edge is visited once a group: so
+# since PR 69 each call's tiling is chosen from that call's shapes. What
+# `scripts/moe_gmm_sweep.py` measured on a v5e at the seven routed cells' shapes
+# (`chiprun_out/moe_gmm_sweep.json`, each kernel alone at every candidate, uneven
+# groups; ms a call, the parent's tiling -> the rule's):
+# - a tile is a multiple of 128 that DIVIDES its dim (Laguna's 512-wide experts,
+#   `gmm` of the down projection: 0.540 -> 0.193);
+# - groups of at most 512 rows at an even routing take 256-row tiles (`gmm` of
+#   Laguna's up projection at (512 | 256 | 128, 2048, 1024): 0.534, 0.368, 0.379)
+#   and `tgmm`, whose row tile is the contracted dim, 128 (0.633 -> 0.514 -> 0.501);
+# - `gmm` holds its contracted dim WHOLE where the blocks fit (one K step: a
+#   group's kernel block stays in VMEM from row tile to row tile and the float32
+#   sums are never read back; Laguna's up projection at (256, 1024 | 2048, 1024):
+#   0.439, 0.368), then widens the N tile while they fit (Kimi-Linear's down
+#   projection at (256, 1024, 768 | 1152 | 2304): 0.113, 0.109, 0.105); `tgmm`
+#   widens its result tile's N (GLM's up projection at (128, 1024, 1024 | 1536):
+#   0.540, 0.512);
+# - "fit": the blocks a grid step holds by the kernels' own specs (operands and
+#   result twice, the float32 sums once) inside `GMM_VMEM`, the largest count
+#   that compiled in every kernel at every shape of the sweep; a count of 16 MiB
+#   ran out of the scoped VMEM at OLMoE's shapes ((512, 2048, 1024)), which is
+#   why OLMoE's calls, 1024 rows a group, K and N that 1024 divides, come out at
+#   (512, 1024, 1024) as before. 4-byte operands were not timed: they keep tiles
+#   of half the width, as before.
 GMM_TILING = (512, 1024, 1024)
+GMM_KERNELS = ("gmm", "gmm_t", "tgmm")  # a grouped matmul's forward, its rows' cotangent, its kernels'
+GROUP_ROW_TILE = 256  # of groups of at most `GMM_TILING[0]` rows at an even routing
+TGMM_ROW_TILE = 128  # and of their `tgmm`
+GMM_WHOLE = 2304  # the widest K or N the sweep held in one tile (Kimi-Linear's hidden size)
+GMM_VMEM = 29 << 19  # 14.5 MiB of the 16 MiB of scoped VMEM
+
+
+def row_tile(even_rows: float) -> int:
+    """The row tile of a block whose groups hold `even_rows` rows at an even
+    routing (`k x tokens / experts`): what its `gmm` calls take. A power of
+    two no larger than `GMM_TILING[0]`, on which a share's window starts and
+    of which it is whole tiles."""
+    cap = GMM_TILING[0]
+    return cap if even_rows > cap else min(cap, GROUP_ROW_TILE)
+
+
+def _tiles_of(dim: int, most: int):
+    """The multiples of 128 that divide `dim`, up to `most`, ascending."""
+    return [tile for tile in range(128, min(dim, most) + 1, 128) if dim % tile == 0]
+
+
+def _fit(dim: int, cap: int) -> int:
+    """The largest multiple of 128 that divides `dim` and is no larger than
+    `cap`: no block is wider than its array and megablox masks no rest. A dim
+    that is no multiple of 128 keeps `cap` (no cell has one)."""
+    return (_tiles_of(dim, cap) or [cap])[-1]
+
+
+def gmm_blocks_bytes(kernel: str, tiling: Tuple[int, int, int], itemsize: int = 2) -> int:
+    """What a grid step of a megablox kernel holds in VMEM by its own specs:
+    the two operand blocks and the result block twice (the pipeline's two
+    buffers) and the float32 sums once. `gmm`: (tm, tk), (tk, tn) -> (tm, tn);
+    `tgmm`: (tm, tk), (tm, tn) -> (tk, tn)."""
+    tm, tk, tn = tiling
+    if kernel == "tgmm":
+        return 2 * itemsize * (tm * tk + tm * tn + tk * tn) + 4 * tk * tn
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def gmm_tiling(kernel: str, k: int, n: int, even_rows: float, itemsize: int = 2) -> Tuple[int, int, int]:
+    """(tm, tk, tn) of ONE megablox call, from that call's shapes: `kernel`
+    (`GMM_KERNELS`), the dim its K tiles run over and the dim its N tiles run
+    over (`gmm` of (G, K, N) kernels: K, N; `gmm_t`, the same kernels
+    transposed: N, K; `tgmm`, whose row tile is the contracted dim and whose
+    result tile is (tk, tn): K, N), the rows a group holds at an even routing
+    and the operands' width in bytes. The rule and what it was measured
+    against: the comment above `GMM_TILING`."""
+    assert kernel in GMM_KERNELS, kernel
+    wide = itemsize // 2  # 1 for bf16, 2 for float32
+    tm, tk, tn = row_tile(even_rows), _fit(k, GMM_TILING[1] // wide), _fit(n, GMM_TILING[2] // wide)
+    if wide != 1:
+        return tm, tk, tn
+
+    def fit(*tiling):
+        return gmm_blocks_bytes(kernel, tiling, itemsize) <= GMM_VMEM
+
+    if kernel == "tgmm":
+        if even_rows <= GMM_TILING[0]:
+            tm = min(tm, TGMM_ROW_TILE)
+    elif k % 128 == 0 and k <= GMM_WHOLE and fit(tm, k, tn):
+        tk = k
+    tn = max([tn] + [wider for wider in _tiles_of(n, GMM_WHOLE) if fit(tm, tk, wider)])
+    return tm, tk, tn
+
+
+def matmul_calls(k: int, n: int):
+    """((kernel, the dim its K tiles run over, the dim its N tiles run over), ...)
+    of the three megablox calls of a grouped matmul of (G, K, N) kernels."""
+    return tuple(zip(GMM_KERNELS, ((k, n), (n, k), (k, n))))
+
+
+def _takes_megablox(on_tpu: bool, rows: int, even_rows: float) -> bool:
+    return on_tpu and rows % row_tile(even_rows) == 0
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _megablox(rows, kernels, group_sizes, first_group, even_rows):
+    """`grouped_matmul` on the megablox `gmm`. The rule is written here and
+    not left to megablox's own, which hands its forward's ONE tiling to the
+    transposed `gmm` and to `tgmm`: each takes its own (`grouped_matmul_bwd`)."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    tiling = gmm_tiling("gmm", *kernels.shape[1:], even_rows, rows.dtype.itemsize)
+    offset = None if first_group is None else jnp.int32(first_group)
+    return megablox.backend.gmm(rows, kernels, group_sizes, rows.dtype, tiling, offset)
+
+
+def _megablox_fwd(rows, kernels, group_sizes, first_group, even_rows):
+    return _megablox(rows, kernels, group_sizes, first_group, even_rows), (rows, kernels, group_sizes)
+
+
+def _megablox_bwd(first_group, even_rows, res, g):
+    rows, kernels, group_sizes = res
+    return grouped_matmul_bwd(rows, kernels, group_sizes, g, True, first_group, even_rows) + (None,)
+
+
+_megablox.defvjp(_megablox_fwd, _megablox_bwd)
 
 
 def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array,
-                   on_tpu: bool = False, first_group: Optional[int] = None) -> jax.Array:
+                   on_tpu: bool = False, first_group: Optional[int] = None,
+                   even_rows: Optional[float] = None) -> jax.Array:
     """(M, K) rows sorted by group x (G, K, N) kernels -> (M, N): row i is
     multiplied by the kernel of the group it falls in. Groups may be empty.
-    On a TPU, where the rows fill whole tiles, the megablox kernels (their
-    names carry the caller's scope into a trace; XLA's `ragged-dot` custom
-    call carries none); otherwise `jax.lax.ragged_dot`.
+    On a TPU, where the rows fill whole tiles, the megablox kernels at the
+    tiling their shapes take (`gmm_tiling`; their names carry the caller's
+    scope into a trace; XLA's `ragged-dot` custom call carries none);
+    otherwise `jax.lax.ragged_dot`.
 
     `first_group`: the kernels are those of groups `first_group` to
     `first_group + G` of more groups than G (`group_sizes` counts them all);
     the rows of the other groups come back zero, and send no gradient. The
     megablox kernels visit the held groups' tiles alone (`group_offset`,
-    their own form of a sharded expert dim)."""
-    if on_tpu and rows.shape[0] % GMM_TILING[0] == 0:
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
+    their own form of a sharded expert dim).
 
-        tm, tk, tn = GMM_TILING
-        wide = rows.dtype.itemsize // 2  # 1 for bf16, 2 for float32
-        offset = None if first_group is None else jnp.int32(first_group)
-        return gmm(rows, kernels, group_sizes, rows.dtype, (tm, tk // wide, tn // wide), offset)
+    `even_rows`: the rows a group holds at an even routing, where the rows
+    are a window of the assignments; None: they are all of them."""
+    if even_rows is None:
+        even_rows = rows.shape[0] / group_sizes.shape[0]
+    if _takes_megablox(on_tpu, rows.shape[0], even_rows):
+        return _megablox(rows, kernels, group_sizes, first_group, even_rows)
     if first_group is not None:
         # ragged_dot has no such form: zero kernels stand in the other groups' places
         after = group_sizes.shape[0] - first_group - kernels.shape[0]
@@ -138,26 +262,28 @@ def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array,
 
 
 def grouped_matmul_bwd(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array, g: jax.Array,
-                       on_tpu: bool = False, first_group: Optional[int] = None):
+                       on_tpu: bool = False, first_group: Optional[int] = None,
+                       even_rows: Optional[float] = None):
     """The cotangents of `grouped_matmul`'s rows and kernels for the
     cotangent `g` of its result, for a rule that is written out: on a TPU the
     two calls megablox's own rule makes (`gmm` with the kernels transposed,
-    `tgmm`), made here so that they carry the caller's scope as the forward's
-    do (a `jax.vjp` taken under a scope names its backward after the scope's
-    TRANSFORM, and the readers of a trace tell kernels by the scope's words);
-    elsewhere `ragged_dot`'s transposes."""
-    if on_tpu and rows.shape[0] % GMM_TILING[0] == 0:
+    `tgmm`), each at its own tiling, made here so that they carry the caller's
+    scope as the forward's do (a `jax.vjp` taken under a scope names its
+    backward after the scope's TRANSFORM, and the readers of a trace tell
+    kernels by the scope's words); elsewhere `ragged_dot`'s transposes."""
+    if even_rows is None:
+        even_rows = rows.shape[0] / group_sizes.shape[0]
+    if _takes_megablox(on_tpu, rows.shape[0], even_rows):
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
-        tm, tk, tn = GMM_TILING
-        wide = rows.dtype.itemsize // 2
-        tiling = (tm, tk // wide, tn // wide)
+        (_, k, n), wide = kernels.shape, rows.dtype.itemsize
         offset = None if first_group is None else jnp.int32(first_group)
-        d_rows = megablox.backend.gmm(g, kernels, group_sizes, rows.dtype, tiling, offset, transpose_rhs=True)
-        d_kernels = megablox.backend.tgmm(rows.swapaxes(0, 1), g, group_sizes, kernels.dtype, tiling, offset,
-                                          kernels.shape[0])
+        d_rows = megablox.backend.gmm(g, kernels, group_sizes, rows.dtype, gmm_tiling("gmm_t", n, k, even_rows, wide),
+                                      offset, transpose_rhs=True)
+        d_kernels = megablox.backend.tgmm(rows.swapaxes(0, 1), g, group_sizes, kernels.dtype,
+                                          gmm_tiling("tgmm", k, n, even_rows, wide), offset, kernels.shape[0])
         return d_rows, d_kernels
-    return jax.vjp(lambda rows, kernels: grouped_matmul(rows, kernels, group_sizes, on_tpu, first_group),
+    return jax.vjp(lambda rows, kernels: grouped_matmul(rows, kernels, group_sizes, on_tpu, first_group, even_rows),
                    rows, kernels)[1](g)
 
 
@@ -555,7 +681,12 @@ def router_logits(y: jax.Array, router_kernel: jax.Array) -> jax.Array:
 # buys: megablox fills the rows it skips of every call's result with a select
 # over ALL of it, the activation and its backward run over all of it, and with
 # 8 of 32 experts held three quarters of those rows are another chip's (45 of
-# the experts' 92 ms a step there; PERF.md, PR 47).
+# the experts' 92 ms a step there; PERF.md, PR 47). The window's own tile
+# stays `GMM_TILING[0]` whatever row tile the calls take (every one divides
+# it): at 256-row tiles Qwen3-Next's window is 7936 rows and not 8192, XLA's
+# memory-space assignment then leaves the combine's backward's packed source
+# in HBM, and `moe_rows_out` reads it at half the pace (4.5 ms a step;
+# PERF.md, PR 69).
 # A `cond` chooses, and a `cond` has a price the block is shaped by. What
 # passes into one waits through both of its branches and what comes out of one
 # is a buffer of its own, and the step's memory is the larger branch's: so
@@ -572,8 +703,9 @@ WINDOW_OVER_EVEN = 1.5  # x `k x tokens x held / experts`; three cells' steps re
 
 def window_rows(assignments: int, num_experts: int, held: Optional[Tuple[int, int]]) -> int:
     """Rows of the window a share's experts work on: `WINDOW_OVER_EVEN` times
-    the even share in whole row tiles, and a tile for the alignment. 0 where
-    none is built: all experts held, or a window no shorter than the range."""
+    the even share in whole 512-row tiles (`GMM_TILING[0]`, which every
+    call's row tile divides), and a tile for the alignment. 0 where none is
+    built: all experts held, or a window no shorter than the range."""
     if held is None:
         return 0
     tile = GMM_TILING[0]
@@ -583,23 +715,36 @@ def window_rows(assignments: int, num_experts: int, held: Optional[Tuple[int, in
 
 def _gmm_in(share, rows, wi, sizes):
     with jax.named_scope(tracing.MOE_GMM_IN):
-        return grouped_matmul(rows, wi, sizes, share.on_tpu, first_group=share.held[0])
+        return grouped_matmul(rows, wi, sizes, share.on_tpu, share.held[0], share.even)
 
 
 def _gmm_out(share, mid, wo, sizes):
     with jax.named_scope(tracing.MOE_GMM_OUT):
-        return grouped_matmul(mid, wo, sizes, share.on_tpu, first_group=share.held[0])
+        return grouped_matmul(mid, wo, sizes, share.on_tpu, share.held[0], share.even)
+
+
+def _say_tilings(wi_shape, wo_shape, even_rows: float, itemsize: int) -> None:
+    """The tilings a block's grouped matmuls take, to whoever records the
+    step's forms: one entry a distinct (kernel, the dims its K and N tiles
+    run over, even rows a group), as `grouped_matmul` and
+    `grouped_matmul_bwd` choose them."""
+    for _, k, n in (wi_shape, wo_shape):
+        for kernel, dims in matmul_calls(k, n):
+            form = "%s %dx%d r%g: %dx%dx%d" % ((kernel,) + dims + (even_rows,)
+                                               + gmm_tiling(kernel, *dims, even_rows, itemsize))
+            forms.took(forms.GMM_TILES, form, key=form)
 
 
 class _Share(NamedTuple):
     """What `_windowed_block` is built from, static at trace time (and the
-    key its two rules are traced once under: the tiles are in it for that)."""
+    key its two rules are traced once under: the window's tile is in it for that)."""
     activate: object
     on_tpu: bool
     form: str  # of the row movers (`rows_form`)
     held: Tuple[int, int]
     length: int  # of the window, rows
-    tiling: Tuple[int, int, int]  # `GMM_TILING`
+    even: float  # rows a group at an even routing: what the kernels' tilings are chosen by
+    tile: int  # `GMM_TILING[0]`: the window starts on one and is whole ones
 
 
 def _place_window(counts, held: Tuple[int, int], length: int, tile: int):
@@ -618,7 +763,7 @@ def _two_ways(share: _Share, counts):
     the sorted rows, each as (`take`, `lay`, the rows an expert): the window's
     rows cut out of an array of all and laid back into zeros, or all the rows
     as they are."""
-    first_row, sizes, fits = _place_window(counts, share.held, share.length, share.tiling[0])
+    first_row, sizes, fits = _place_window(counts, share.held, share.length, share.tile)
 
     def take(x):
         return jax.lax.dynamic_slice_in_dim(x, first_row, share.length)
@@ -674,7 +819,7 @@ def _windowed_fwd(share, y, wi, wo, counts, weights, order, inv_order):
 @functools.partial(jax.jit, static_argnums=0)
 def _windowed_bwd(share, res, g):
     y, wi, wo, counts, weights, order, inv_order = res
-    form, on_tpu, first = share.form, share.on_tpu, share.held[0]
+    form, on_tpu, first, even = share.form, share.on_tpu, share.held[0], share.even
 
     def backward(take, lay, sizes, again):
         """The block's forward up to the experts' result, and back from the
@@ -699,10 +844,10 @@ def _windowed_bwd(share, res, g):
         activate_bwd = jax.vjp(share.activate, mid)[1]  # taken under no scope: the scope's words stay plain
         with jax.named_scope(tracing.MOE_EXPERTS):
             with jax.named_scope(tracing.MOE_GMM_OUT):
-                d_act, d_wo = grouped_matmul_bwd(act, wo, sizes, take(d_out), on_tpu, first)
+                d_act, d_wo = grouped_matmul_bwd(act, wo, sizes, take(d_out), on_tpu, first, even)
             d_mid, = activate_bwd(d_act)
             with jax.named_scope(tracing.MOE_GMM_IN):
-                d_part, d_wi = grouped_matmul_bwd(part, wi, sizes, d_mid, on_tpu, first)
+                d_part, d_wi = grouped_matmul_bwd(part, wi, sizes, d_mid, on_tpu, first, even)
             d_rows = lay(d_part, rows.shape[0])
         with jax.named_scope(tracing.MOE_DISPATCH):
             return _sum_over_k(form, d_rows, inv_order, y.shape[0], None), d_wi, d_wo, d_weights
@@ -756,9 +901,12 @@ def _local_moe(y, router_kernel, bias, wi, wo, *, k: int, norm_topk_prob: bool, 
         inv_order = _permuted(slot, order)
         counts = jnp.sum(flat[:, None] == jnp.arange(num_experts, dtype=flat.dtype),
                          axis=0, dtype=jnp.int32)
+    even = k * tokens / num_experts  # rows a group at an even routing
     length = window_rows(k * tokens, num_experts, held)
+    if _takes_megablox(on_tpu, length or k * tokens, even):
+        _say_tilings(wi.shape, wo.shape, even, y.dtype.itemsize)
     if length:
-        share = _Share(activate, on_tpu, form, held, length, GMM_TILING)
+        share = _Share(activate, on_tpu, form, held, length, even, GMM_TILING[0])
         with jax.named_scope(tracing.MOE_EXPERTS):  # the kernels' casts are the experts', as without a window
             kernels = wi.astype(dtype), wo.astype(dtype)
         out = _windowed_block(share, y, *kernels, counts, weights, order, inv_order).astype(dtype)

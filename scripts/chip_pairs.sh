@@ -4,7 +4,7 @@
 #   chiprun --timeout 3000 -- bash scripts/chip_pairs.sh <out> <trace> <cell>:<seed> ...
 # For each cell: parent, change on <seed>, then change, parent on <seed> + 1. The result lines and
 # each run's run.json land under chiprun_out/<out>/. CHANGE=<dir> runs another checkout as the
-# change (the committed files alone, unpacked into _final/).
+# change (the committed files alone, unpacked into _final/); ONE=1 runs the first pair of a cell alone.
 out=$1; trace=$2; shift 2
 change=${CHANGE:-.}
 mkdir -p chiprun_out/$out
@@ -25,6 +25,7 @@ for pair in "$@"; do
   cell=${pair%%:*}; seed=${pair##*:}
   run _parent parent $cell $seed
   run $change change $cell $seed
+  [ -n "$ONE" ] && continue
   run $change change $cell $((seed + 1))
   run _parent parent $cell $((seed + 1))
 done

@@ -5,7 +5,7 @@ and what its `compile` and `step` events say of the experts' window:
     chiprun -- python3 scripts/share_counters.py <cell> <seed> [<steps>]
 
 One line of JSON: the `compile` event's `forms` of the experts' window and
-the row movers (obs/forms.py: `expert_window`, `moe_rows`), and over the
+the row movers and the grouped matmuls' tilings (obs/forms.py: `expert_window`, `moe_rows`, `gmm_tiles`), and over the
 steps the sum of `expert_window_fallbacks` and the range of
 `expert_rows_held_over_even`. The cell's own flags (benchmarks/cells.train_argv),
 so the step is the benchmark's and comes out of its compile cache."""
@@ -44,7 +44,7 @@ def main(workload: str, seed: int, steps: int = 40) -> None:
     over_even = [e["expert_rows_held_over_even"] for e in step_events]
     print(json.dumps({
         "workload": workload, "seed": seed, "steps": len(step_events), "errors": len(errors),
-        **{part: [e["forms"].get(part) for e in compiles] for part in (forms.EXPERT_WINDOW, forms.MOE_ROWS)},
+        **{part: [e["forms"].get(part) for e in compiles] for part in (forms.EXPERT_WINDOW, forms.MOE_ROWS, forms.GMM_TILES)},
         "expert_window_fallbacks": sum(e["expert_window_fallbacks"] for e in step_events),
         "steps_that_fell_back": sum(e["expert_window_fallbacks"] > 0 for e in step_events),
         "expert_rows_held_over_even": [min(over_even), max(over_even)],
