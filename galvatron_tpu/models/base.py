@@ -9,8 +9,9 @@ Megatron ParallelAttention/ParallelMLP with per-layer NCCL groups, here the
 per-layer parallel strategy enters only through PartitionSpecs
 (parallel/spec.py) and sharding constraints at layer boundaries.
 
-A layer is its two norms, a token mixer and an MLP half, two entries of the
-tables `MIXERS` and `MLP_HALVES` (models/parts): this module initialises,
+A layer is a token mixer and an MLP half, each behind its norm, two entries of the
+tables `MIXERS` and `MLP_HALVES` (models/parts; a layer of ONE half has the
+absent entry for the other, which gets no norm and is not run): this module initialises,
 runs and lays out the layers, the embedding before them and the head and the
 losses after them (the multi-token-prediction module among those), and names
 no part. The config is `models/config.TransformerConfig`."""
@@ -35,7 +36,7 @@ from galvatron_tpu.config.strategy import (
 )
 from galvatron_tpu.models.config import TransformerConfig
 from galvatron_tpu.models.parts import COUNTERS, MIXERS, MLP_HALVES, hyper, loop, unsupported_reason
-from galvatron_tpu.models.parts.common import Params, _dense, _dense_init, _norm, _norm_params
+from galvatron_tpu.models.parts.common import LayerPart, Params, _dense, _dense_init, _norm, _norm_params
 from galvatron_tpu.models.parts.embed_head import (embed_patches, embed_tokens, head_logits, model_head,
                                                    next_tokens_cross_entropy, softmax_nll, token_cross_entropies,
                                                    vocab_parallel_cross_entropy)
@@ -55,16 +56,24 @@ def refuse_unsupported(cfg, hp=None, asker=None, autotune=None) -> None:
         raise D.DiagnosticError([D.make("GLS018", reason)])
 
 
+def _halves(cfg: TransformerConfig) -> Tuple[Tuple[str, LayerPart], ...]:
+    """(the norm's name, the table's entry) of each half ONE layer's config has, the mixer's first: both, or
+    of a layer of one half (the other `absent`) the one."""
+    return tuple((norm, half) for norm, half in (("ln1", MIXERS[cfg.mixer]), ("ln2", MLP_HALVES[cfg.mlp_half]))
+                 if not half.absent)
+
+
 # ===================================================================== init
 def init_layer_params(rng: jax.Array, cfg: TransformerConfig, index: int = 0) -> Params:
-    """One layer's tree: its two norms, its token mixer's leaves (`MIXERS`)
-    and its MLP half's (`MLP_HALVES`). `index`: the layer's PUBLISHED index,
+    """One layer's tree: its token mixer's leaves (`MIXERS`) and its MLP
+    half's (`MLP_HALVES`), and a norm for each of the two that is there
+    (`_halves`). `index`: the layer's PUBLISHED index,
     for the leaves a part sets from the layer's place in the stack
     (`LayerPart.place`: differential attention's `lambda_init`; no other)."""
     ks = jax.random.split(rng, 5)
-    p: Params = {"ln1": _norm_params(cfg), "ln2": _norm_params(cfg)}
+    p: Params = {norm: _norm_params(cfg) for norm, _ in _halves(cfg)}
     if cfg.post_norm:  # HF's `input_layernorm_2`, `post_attention_layernorm_2` (names assumed)
-        p.update({"ln1_post": _norm_params(cfg), "ln2_post": _norm_params(cfg)})
+        p.update({norm + "_post": _norm_params(cfg) for norm, _ in _halves(cfg)})
     p.update(MIXERS[cfg.mixer].init(ks, cfg))
     p.update(MLP_HALVES[cfg.mlp_half].init(ks, cfg))
     p.update(hyper.init_layer(jax.random.fold_in(rng, 5), cfg))  # (nothing for one residual stream)
@@ -152,7 +161,8 @@ def layer_forward(
 ):
     """One transformer block on (B, S_local, H) activations: x + Mixer(norm
     x), then + MLP(norm x), the two halves `MIXERS[cfg.mixer]`'s and
-    `MLP_HALVES[cfg.mlp_half]`'s. With ``cfg.post_norm`` (sandwich norms) a
+    `MLP_HALVES[cfg.mlp_half]`'s; a layer of ONE half (`_halves`) is the one
+    of the two that is there. With ``cfg.post_norm`` (sandwich norms) a
     half's OUTPUT is normed by a norm of its own (`ln1_post`, `ln2_post`,
     under `gt.norm.post`) before it joins the stream: x + norm(Mixer(norm x)).
     With ``cfg.hc_mult`` = n > 1 (hyper-connections, `parts/hyper.py`) x is the
@@ -188,7 +198,7 @@ def layer_forward(
         attn_sharding = KernelSharding.for_layer(mesh, axes)
     kv_out, aux, published = None, {}, {}
     col_errs = []  # hyper-connections' counter, a half
-    for norm, half in (("ln1", MIXERS[cfg.mixer]), ("ln2", MLP_HALVES[cfg.mlp_half])):
+    for norm, half in _halves(cfg):
         residual = x
         if cfg.hc_mult > 1:
             with jax.named_scope(tracing.HC):
@@ -468,6 +478,10 @@ def run_layers(
     auxs: List[Dict[str, jax.Array]] = []  # routed: a layer's, or a scanned run's stacked
 
     kinds = cfg.layer_kinds()
+    halves = [MIXERS[m] for m in cfg.mixers()] + [MLP_HALVES[h] for h in cfg.mlp_halves()]
+    absent = sum(half.absent for half in halves)
+    if absent:  # a model with layers of ONE half
+        forms.took(forms.HALVES, "%d of %d" % (len(halves) - absent, len(halves)), key="halves")
     shares = cfg.shared()  # a layer: (the names it hands on, the names it reads)
     shared: Dict[str, jax.Array] = {}  # the latest of each name published so far
     if use_hp:
@@ -943,12 +957,12 @@ def classification_loss_fn(params, batch, cfg, hp=None, mesh=None, table_spec: O
 # ============================================================== param specs
 def layer_param_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
     """PartitionSpec tree matching init_layer_params output: the norms whole,
-    the two halves as their table entries lay them out."""
+    the halves as their table entries lay them out."""
     r1 = S.replicated_1d_spec(axes)
     norm = {"scale": r1} if cfg.norm_type == "rmsnorm" else {"scale": r1, "bias": r1}
-    sp: Params = {"ln1": dict(norm), "ln2": dict(norm)}
+    sp: Params = {name: dict(norm) for name, _ in _halves(cfg)}
     if cfg.post_norm:
-        sp.update({"ln1_post": dict(norm), "ln2_post": dict(norm)})
+        sp.update({name + "_post": dict(norm) for name, _ in _halves(cfg)})
     sp.update(MIXERS[cfg.mixer].specs(cfg, axes))
     sp.update(MLP_HALVES[cfg.mlp_half].specs(cfg, axes))
     sp.update(hyper.layer_specs(cfg))

@@ -4,8 +4,10 @@ One config covers the reference's model zoo: GPT (learned pos, pre-LN, gelu),
 LLaMA (rope, rmsnorm, swiglu, GQA), BERT/ViT (bidirectional, post-LN), T5
 (relative bias, enc-dec glue in models/t5.py), and the sparse-expert,
 latent-attention, linear-attention, state-space, short-convolution, window-attention,
-selective-scan / shared-memory (SambaY), compressed-context (EVA), looped (LoopLM) and hyper-connected (mHC) families. A layer is two
-entries of the tables in `models/parts`, a token mixer and an MLP half:
+selective-scan / shared-memory (SambaY), compressed-context (EVA), looped (LoopLM), hyper-connected (mHC) and
+one-half-a-block (Nemotron-H) families. A layer is two
+entries of the tables in `models/parts`, a token mixer and an MLP half, either of which may be the absent
+one ("none": a published block that is a mixer ALONE or an MLP alone):
 `mixers()` and `mlp_halves()` name them a layer, and what an entry asks of the
 config (`validate`) and hands back (`counters`) is the entry's to say."""
 
@@ -34,7 +36,7 @@ class TransformerConfig:
     ffn_hidden: Optional[int] = None
     head_dim: Optional[int] = None
     norm_type: str = "layernorm"  # layernorm | rmsnorm
-    activation: str = "gelu"  # gelu | swiglu | relu
+    activation: str = "gelu"  # gelu | gelu_exact | swiglu | relu | relu2 (relu squared; no gate matrix)
     position_type: str = "learned"  # learned | rope | none
     causal: bool = True
     pre_norm: bool = True
@@ -87,7 +89,8 @@ class TransformerConfig:
     v_head_dim: int = 0
     first_dense_layers: int = 0  # leading layers whose MLP half is dense, of width
     dense_ffn_hidden: Optional[int] = None  # this (ffn_hidden is then ONE expert's)
-    num_shared_experts: int = 0  # dense SwiGLU(s) of the experts' width beside the routed ones
+    num_shared_experts: int = 0  # dense MLP(s) of the experts' kind and width beside the routed ones
+    shared_expert_ffn: Optional[int] = None  # the shared expert's width where it is its own (None: shared x ffn_hidden)
     router_score: str = "softmax"  # softmax | sigmoid (scores an expert independently)
     routed_scaling_factor: float = 1.0  # x the chosen experts' weights
     # `noaux_tc`: the choice of experts adds a bias to the scores that no
@@ -128,7 +131,10 @@ class TransformerConfig:
     layer_types: Optional[List[str]] = None
     ssm_num_heads: int = 0
     ssm_head_dim: int = 0
-    ssm_state_dim: int = 0  # a head's state is (ssm_head_dim, ssm_state_dim); B and C one group
+    ssm_state_dim: int = 0  # a head's state is (ssm_head_dim, ssm_state_dim)
+    # B and C are shared by the ssm_num_heads / ssm_groups consecutive heads of a GROUP, and the gated norm
+    # runs over a group's channels (1: Granite's, every head reads the same B and C and the norm all channels)
+    ssm_groups: int = 1
     ssm_conv_kernel: int = 0  # taps of the causal convolution on [x | B | C], with a bias
     # each a Python float whose default is the model without it: a factor of
     # 1.0 is not multiplied by, so every other model's arithmetic is bit for
@@ -205,9 +211,15 @@ class TransformerConfig:
     hc_eps: float = 1e-6
     hc_res_clamp: Optional[List[float]] = None  # [min, max]
     hc_init_gate: float = 0.01
-    # which `MIXERS` entry ONE layer runs. `layer_config(kind)` sets it; a
-    # model's own config leaves it and states the pattern above
+    # --- what Nemotron-H's published config adds (nemotron_h): a block is ONE half, `x + f(norm x)` with a
+    # Mamba-2 mixer, an attention mixer or an MLP alone. `layer_types` then names "none" where a block has no
+    # mixer, and this list, as long, the `MLP_HALVES` key of each published layer ("none": no MLP half) ---
+    mlp_types: Optional[List[str]] = None
+    # which `MIXERS` entry ONE layer runs, and which `MLP_HALVES` entry where that is not what the widths say
+    # (None: "routed" with experts, else "dense"). `layer_config(kind)` sets them; a model's own config
+    # leaves them and states the pattern above
     mixer: str = "attention"
+    mlp: Optional[str] = None
 
 
     def __post_init__(self):
@@ -246,6 +258,19 @@ class TransformerConfig:
                     "the %d layers (or more: the first so many are run), and no "
                     "full_attention_interval beside it; got %r"
                     % (", ".join('"%s"' % n for n in named), self.num_layers, self.layer_types))
+        if self.mlp_types is not None:
+            from galvatron_tpu.models.parts import MLP_HALVES
+
+            self.mlp_types = list(self.mlp_types)
+            both_absent = [i for i, (m, h) in enumerate(zip(self.layer_types or (), self.mlp_types))
+                           if m == h == "none"]
+            if (self.layer_types is None or len(self.mlp_types) != len(self.layer_types)
+                    or set(self.mlp_types) - set(MLP_HALVES) or both_absent):
+                raise ValueError(
+                    "mlp_types names the MLP half, one of %s, of each published layer beside layer_types' %d "
+                    "mixers, and a layer has at least one of the two; got %r%s"
+                    % (", ".join('"%s"' % n for n in sorted(MLP_HALVES)), len(self.layer_types or ()),
+                       self.mlp_types, " (published layers %s have neither)" % both_absent if both_absent else ""))
         if self.layer_indices is not None:
             self.layer_indices = list(self.layer_indices)
             known = len(self.layer_types or ())
@@ -346,8 +371,14 @@ class TransformerConfig:
         return tuple(out)
 
     def mlp_halves(self) -> Tuple[str, ...]:
-        """The `MLP_HALVES` key of each layer: "routed" but for the leading
+        """The `MLP_HALVES` key of each layer: read from the pattern where one
+        is stated (`mlp_types`, a published layer an entry; ONE layer's config
+        names its own, `mlp`); else "routed" but for the leading
         `first_dense_layers` of a model with experts, "dense" without."""
+        if self.mlp_types is not None:
+            return tuple(self.mlp_types[i] for i in self.published_indices())
+        if self.mlp is not None:
+            return (self.mlp,) * self.num_layers
         lead = min(self.first_dense_layers, self.num_layers) if self.routed else self.num_layers
         return ("dense",) * lead + ("routed",) * (self.num_layers - lead)
 
@@ -366,8 +397,9 @@ class TransformerConfig:
     def layer_kinds(self) -> Tuple[str, ...]:
         """The kind of each layer, what `config/strategy.layer_runs` splits
         runs on beside the layout. A kind names the layer's two halves: its
-        MLP half, "dense" or "routed", after its token mixer where that is
-        not softmax attention ("linear.routed", "ssm.dense", "kda.routed", "conv.dense"). A
+        MLP half, "dense", "routed" or "none", after its token mixer where that is
+        not softmax attention ("linear.routed", "ssm.dense", "kda.routed", "conv.dense"; of a layer of ONE
+        half "ssm.none", "none.routed", and "none" for attention alone). A
         layer that publishes (`shared`) is of the kind of one that does not: one
         part serves both, an output nobody reads is dead code, and what sets a
         publishing layer apart, that it is never scanned, is `run_layers`' to see."""
@@ -387,6 +419,8 @@ class TransformerConfig:
             cfg = dataclasses.replace(
                 cfg, num_experts=0, experts_held=0, num_shared_experts=0, router_bias=False,
                 ffn_hidden=self.dense_ffn_hidden or self.ffn_hidden)
+        if self.mlp_types is not None:  # (before the mixers' list goes, which this one is held to)
+            cfg = dataclasses.replace(cfg, mlp_types=None, mlp=mlp)
         if self.mixers() != (self.mixer,) * self.num_layers:
             cfg = dataclasses.replace(cfg, mixer=mixer or "attention", full_attention_interval=0,
                                       layer_types=None, layer_indices=None)
@@ -402,7 +436,7 @@ class TransformerConfig:
     @property
     def mlp_half(self) -> str:
         """The `MLP_HALVES` key of ONE layer's config (`layer_config`), beside its `mixer`."""
-        return "routed" if self.routed else "dense"
+        return self.mlp or ("routed" if self.routed else "dense")
 
     @property
     def layer_aux(self) -> bool:
@@ -421,6 +455,11 @@ class TransformerConfig:
         """(first, count) of the experts this program holds."""
         return (self.experts_held_start, self.experts_held) if self.experts_held \
             else (0, self.num_experts)
+
+    @property
+    def shared_ffn(self) -> int:
+        """The shared expert's width: its own where the config states one, else the shared experts' side by side."""
+        return self.shared_expert_ffn or self.num_shared_experts * self.ffn_hidden
 
     @property
     def routed_layers(self) -> int:

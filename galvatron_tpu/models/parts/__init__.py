@@ -1,9 +1,11 @@
 """The parts a layer is built of, in two tables of one shape, and the one
 function that says whether a (config, layout, asker) has a form of them.
 
-A layer is `norms + MIXERS[m] + MLP_HALVES[h]`; its kind
+A layer is `MIXERS[m] + MLP_HALVES[h]`, each half behind a norm of its own; its kind
 (`TransformerConfig.layer_kinds`) names the two, "<m>.<h>", softmax attention
-going unnamed. Adding a part is adding one module with one entry
+going unnamed. Either may be the absent half ("none", `parts/absent.py`: no
+leaves, no norm, nothing added to the stream), so a published block of ONE
+half is a layer too. Adding a part is adding one module with one entry
 (`common.LayerPart`) and its line here: the stack (`models/base.py`), the
 config's validation and the refusals read the tables and name no part."""
 
@@ -11,6 +13,7 @@ from __future__ import annotations
 
 from typing import Any, Iterator, Optional, Tuple
 
+from galvatron_tpu.models.parts.absent import ABSENT
 from galvatron_tpu.models.parts.attention import ATTENTION
 from galvatron_tpu.models.parts.conv import CONV
 from galvatron_tpu.models.parts.cross import CROSS
@@ -25,8 +28,8 @@ from galvatron_tpu.models.parts.ssm import SSM
 from galvatron_tpu.models.parts.window import WINDOW
 
 MIXERS = {"attention": ATTENTION, "linear": LINEAR, "ssm": SSM, "kda": KDA, "conv": CONV, "window": WINDOW,
-          "mamba1": MAMBA, "gmu": GMU, "cross": CROSS, "eva": EVA}
-MLP_HALVES = {"dense": DENSE, "routed": ROUTED}
+          "mamba1": MAMBA, "gmu": GMU, "cross": CROSS, "eva": EVA, "none": ABSENT}
+MLP_HALVES = {"dense": DENSE, "routed": ROUTED, "none": ABSENT}
 # counter -> how the stack folds the layers' values into the step's ("mean" | "max" | "sum"), for a counter a part
 # hands back under the `step` event's own name: the stack (models/base._fold_aux, lm_loss_fn) reads the table
 COUNTERS = {**EVA_COUNTERS, **hyper.COUNTERS}

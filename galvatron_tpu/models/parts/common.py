@@ -52,6 +52,8 @@ class LayerPart:
     # (layer tree, cfg, the layer's PUBLISHED index) -> the tree with the leaves set that depend
     # on the layer's place in the published stack (`init_layer_params`)
     place: Callable = lambda p, cfg, index: p
+    # the half a layer of ONE half lacks (`parts/absent.py`): the stack gives it no norm and does not run it
+    absent: bool = False
 
 
 def no_form(name: str, **says: str) -> Mapping[str, str]:
@@ -105,6 +107,8 @@ def _activation(x, cfg: TransformerConfig):
         return jax.nn.gelu(x, approximate=False)
     if cfg.activation == "relu":
         return jax.nn.relu(x)
+    if cfg.activation == "relu2":  # relu(x)^2 (Nemotron-H's `mlp_hidden_act`); relu2(0) = 0, so a zero column stays one
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(cfg.activation)
 
 

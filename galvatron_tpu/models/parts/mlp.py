@@ -105,8 +105,8 @@ def dense_mlp(p: Params, y: jax.Array, cfg: TransformerConfig, dtype) -> jax.Arr
     the up projection's epilogue the GELU costs the same 1.9 ms a layer, but
     is taken of the matmul's float32 sum before it is rounded to the compute
     dtype, which the recomputation's is: with the pre-activation written out
-    every loss is the unbarriered program's to the bit. SiLU x gate and ReLU
-    cost an operand fusion nothing and stay folded into it."""
+    every loss is the unbarriered program's to the bit. SiLU x gate, ReLU and
+    its square cost an operand fusion nothing and stay folded into it."""
     wi_out = jnp.einsum("bsh,h...->bs...", y, p["wi"]["kernel"].astype(dtype))
     if "bias" in p["wi"]:
         wi_out = wi_out + p["wi"]["bias"].astype(dtype)
@@ -148,8 +148,10 @@ def _dense_specs(cfg: TransformerConfig, axes: LayerAxes) -> Params:
 def _init_routed(ks, cfg: TransformerConfig) -> Params:
     # one kernel a matrix with the experts leading: (E, h, 2F) the gate's
     # columns beside the up projection's (flat: a TPU tiles the minor
-    # dims, and a (2, F) pair there costs a copy a use), (E, F, h) down;
-    # the router (h, E) stays float32 in the forward
+    # dims, and a (2, F) pair there costs a copy a use; (E, h, F) where the
+    # activation has no gate: "relu2", Nemotron-H's), (E, F, h) down;
+    # the router (h, E) stays float32 in the forward. The shared expert is a
+    # dense MLP of the same activation, `cfg.shared_ffn` wide
     h, proj_std = cfg.hidden_size, _proj_std(cfg)
     e, fan_in = cfg.num_experts, math.prod(cfg.mlp_fan_in)
     kr = jax.random.fold_in(ks[2], 1)
@@ -160,7 +162,7 @@ def _init_routed(ks, cfg: TransformerConfig) -> Params:
     p["wi"] = {"kernel": _dense_init(ks[2], (held, h, fan_in), cfg.init_std, cfg.param_dtype)}
     p["wo_mlp"] = {"kernel": _dense_init(ks[3], (held, cfg.ffn_hidden, h), proj_std, cfg.param_dtype)}
     if cfg.num_shared_experts:
-        wide = cfg.num_shared_experts * cfg.ffn_hidden
+        wide = cfg.shared_ffn
         ksh = jax.random.split(jax.random.fold_in(ks[3], 1), 2)
         shared_in = (h, 2, wide) if cfg.activation == "swiglu" else (h, wide)
         p["shared"] = {

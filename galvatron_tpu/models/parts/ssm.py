@@ -1,4 +1,5 @@
-"""Mamba-2, the state-space token mixer (Granite-4.0-H's nine layers in ten)."""
+"""Mamba-2, the state-space token mixer (Granite-4.0-H's nine layers in ten, one group of B and C;
+Nemotron-H's `M` blocks, `ssm_groups` of them and a gated norm a group)."""
 
 from __future__ import annotations
 
@@ -19,17 +20,17 @@ from galvatron_tpu.parallel.mesh import LayerAxes
 
 
 def _validate(cfg: TransformerConfig) -> None:
-    if (min(cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_dim, cfg.ssm_conv_kernel) < 1
-            or cfg.routed or cfg.mtp_layers):
+    if (min(cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_dim, cfg.ssm_conv_kernel, cfg.ssm_groups) < 1
+            or cfg.ssm_num_heads % cfg.ssm_groups or cfg.mtp_layers):
         raise ValueError(
             "state-space layers want ssm_num_heads, ssm_head_dim, ssm_state_dim and a "
-            "convolution kernel of 1 or more, a dense MLP half and no "
-            "multi-token-prediction module; got heads %d x %d, state %d, kernel %d"
-            % (cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_dim, cfg.ssm_conv_kernel))
+            "convolution kernel of 1 or more, ssm_groups that divide the heads and no "
+            "multi-token-prediction module; got heads %d x %d in %d groups, state %d, kernel %d"
+            % (cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state_dim, cfg.ssm_conv_kernel))
 
 
 # the scan's state runs along the whole sequence, the gated norm over all of
-# a layer's channels
+# a layer's channels (a group's, where B and C have groups: no layout splits them yet either)
 UNSUPPORTED = no_form(
     "state-space layers",
     serve="no convolution window or scan state of a state-space layer (serve/kv_cache.py holds keys and values)",
@@ -44,14 +45,14 @@ UNSUPPORTED = no_form(
 def _init_ssm(ks, cfg: TransformerConfig) -> Params:
     """The Mamba-2 mixer's leaves, under `ssm` (HF `GraniteMoeHybridMambaLayer`:
     in_proj, conv1d, dt_bias, A_log, D, norm, out_proj). `win`'s columns lie
-    [z | x | B | C | dt] as HF's. Initialised as the Mamba-2 reference does: A
+    [z | x | B | C | dt] as HF's, B and C `ssm_groups` x d_state columns each, group by group. Initialised as the Mamba-2 reference does: A
     = exp(A_log) ~ U(1, 16), dt = softplus(dt_bias) log-uniform in [0.001,
     0.1], D = 1, so that exp(dt A) spans 0.2 to 0.999 a token and state
     crosses chunks; the taps and their bias U(-1, 1) / sqrt(taps), PyTorch's
     default for a convolution of that fan-in."""
     h, taps, nh = cfg.hidden_size, cfg.ssm_conv_kernel, cfg.ssm_num_heads
     inner = nh * cfg.ssm_head_dim
-    conv_dim = inner + 2 * cfg.ssm_state_dim
+    conv_dim = inner + 2 * cfg.ssm_groups * cfg.ssm_state_dim
     kgate = jax.random.split(ks[4], 4)
     step = jnp.exp(jax.random.uniform(kgate[2], (nh,), jnp.float32, math.log(1e-3), math.log(0.1)))
     p = {
@@ -80,14 +81,18 @@ def ssm_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerCon
         out = (RMSNorm(y silu(z); w)) Wout            the gate BEFORE the norm, the
                                                       norm over ALL the mixer's channels
 
-    B and C are one group's: every head reads the same. -> out, None, and the
+    B and C are one group's: every head reads the same. With `ssm_groups` = G > 1
+    (Nemotron-H) B and C are (G, d_state) a token, head n reads group n // (heads / G), and
+    the norm runs over each group's inner / G channels apart (HF `MambaRMSNormGated`
+    with `group_size`); G = 1 traces what it always did. -> out, None, and the
     layer's counter: the largest magnitude of any head's state at any chunk's
     end. Scopes: the scan under `gt.attn.ssd`, all else under `gt.attn.ssm`.
     No position enters: the order is the recurrence's. The convolution and
     the gated norm are XLA's (`causal_conv`; the Pallas passes of
     ops/linear_attention.py norm a head's 128 lanes and know no bias)."""
     p, dtype = p["ssm"], cfg.compute_dtype
-    nh, hd, ds = cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_dim
+    nh, hd, groups = cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_groups
+    ds = groups * cfg.ssm_state_dim  # B's columns, and C's
     inner = nh * hd
     b, s, _ = y.shape
     with jax.named_scope(tracing.ATTN_SSM):
@@ -99,11 +104,17 @@ def ssm_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerCon
                              + p["dt_bias"].astype(jnp.float32))
         a = -jnp.exp(p["A_log"].astype(jnp.float32))
     with jax.named_scope(tracing.ATTN_SSD):
-        o, _, peak = ssd_scan(xbc[..., :inner].reshape(b, s, nh, hd), dt, a,
-                              xbc[..., inner:inner + ds], xbc[..., inner + ds:], p["D"])
+        x, bm, cm = xbc[..., :inner].reshape(b, s, nh, hd), xbc[..., inner:inner + ds], xbc[..., inner + ds:]
+        if groups > 1:
+            bm, cm = (t.reshape(b, s, groups, cfg.ssm_state_dim) for t in (bm, cm))
+        o, _, peak = ssd_scan(x, dt, a, bm, cm, p["D"])
     with jax.named_scope(tracing.ATTN_SSM):
         o = o.reshape(b, s, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        o = rms_norm(o, p["norm"]["scale"], cfg.layernorm_eps).astype(dtype)
+        if groups > 1:  # a group's channels a norm
+            o = rms_norm(o.reshape(b, s, groups, inner // groups), p["norm"]["scale"].reshape(groups, -1),
+                         cfg.layernorm_eps).reshape(b, s, inner).astype(dtype)
+        else:
+            o = rms_norm(o, p["norm"]["scale"], cfg.layernorm_eps).astype(dtype)
         out = _dense(o, p["wout"], dtype)
     return out, None, {"ssm_state_abs_max": peak}
 

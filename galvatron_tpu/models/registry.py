@@ -102,7 +102,7 @@ def flash_variant(family: ModelFamily) -> ModelFamily:
 
 
 # every family registers itself where its module ends; a new one adds its name
-_FAMILY_MODULES = ("xing4", "ouro", "evabyte", "phi4flash", "laguna", "lfm2_moe", "kimi_linear", "granite_hybrid", "qwen3_next", "glm4_moe_lite", "olmoe", "gpt", "llama",
+_FAMILY_MODULES = ("nemotron_h", "xing4", "ouro", "evabyte", "phi4flash", "laguna", "lfm2_moe", "kimi_linear", "granite_hybrid", "qwen3_next", "glm4_moe_lite", "olmoe", "gpt", "llama",
                    "bert", "vit", "t5", "swin")
 _LOADED = False
 

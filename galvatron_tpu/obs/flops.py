@@ -134,15 +134,15 @@ def kda_fwd_flops_a_token(*, hidden: int, num_key_heads: int, num_value_heads: i
     return proj, 6.0 * num_value_heads * key_head_dim * value_head_dim
 
 
-def ssm_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, state_dim: int):
-    """A Mamba-2 mixer: hidden -> [z | x | B | C | dt] (2 x inner + 2 x state
-    + heads), inner -> hidden; and the scan as the RECURRENCE needs it, two
+def ssm_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, state_dim: int, groups: int = 1):
+    """A Mamba-2 mixer: hidden -> [z | x | B | C | dt] (2 x inner + 2 x groups x state
+    + heads: B and C a group), inner -> hidden; and the scan as the RECURRENCE needs it, two
     (d_head, d_state) products a head a token (dt x B^T into the state, h C
     out of it: 4 d_head d_state), whatever chunk an implementation cuts the
     sequence into and at any sequence length. The convolution's taps, the
     decay and the D skip are no matmul."""
     inner = num_heads * head_dim
-    proj = 2.0 * hidden * (2 * inner + 2 * state_dim + num_heads) + 2.0 * inner * hidden
+    proj = 2.0 * hidden * (2 * inner + 2 * groups * state_dim + num_heads) + 2.0 * inner * hidden
     return proj, 4.0 * num_heads * head_dim * state_dim
 
 
@@ -200,6 +200,11 @@ def eva_fwd_flops_a_token(*, hidden: int, num_heads: int, head_dim: int, window:
     return 2.0 * hidden * q_dim * 4, 2.0 * (2.0 * pairs_a_token * q_dim) + 2.0 * (2.0 * q_dim)
 
 
+def absent_fwd_flops_a_token(*, hidden: int):
+    """The absent mixer of a layer that is an MLP alone: nothing."""
+    return 0.0, 0.0
+
+
 def hyper_fwd_flops_a_token(*, hidden: int, streams: int) -> float:
     """Hyper-connections around ONE layer (models/parts/hyper.py: `streams` = n residual streams, two halves):
     a half's coefficients `x~ Phi` 2 n hidden (n^2 + 2n), its read 2 n hidden, its write 2 n^2 hidden + 2 n
@@ -215,7 +220,8 @@ MIXER_FWD_FLOPS = {
     "attention": (attention_fwd_flops_a_token, {}),
     "linear": (linear_fwd_flops_a_token, _DELTA_DIMS),
     "kda": (kda_fwd_flops_a_token, _DELTA_DIMS),
-    "ssm": (ssm_fwd_flops_a_token, {k: "ssm_" + k for k in ("num_heads", "head_dim", "state_dim")}),
+    "ssm": (ssm_fwd_flops_a_token, {k: "ssm_" + k for k in ("num_heads", "head_dim", "state_dim", "groups")}),
+    "none": (absent_fwd_flops_a_token, {}),
     "conv": (conv_fwd_flops_a_token, {}),
     "window": (window_fwd_flops_a_token, {
         "num_heads": "num_heads", "head_dim": "head_dim", "num_kv_heads": "num_kv_heads",
@@ -251,6 +257,8 @@ def layer_fwd_flops(
     mixer_dims: Optional[Mapping[str, int]] = None,
     head_gate: bool = False,
     diff: bool = False,
+    mlp_half: Optional[str] = None,
+    shared_ffn: Optional[int] = None,
 ) -> float:
     """Forward model FLOPs of ONE transformer block over `tokens` tokens
     (default: one sequence). Matmul terms only (2 FLOPs per MAC); norms and
@@ -267,7 +275,9 @@ def layer_fwd_flops(
     that row of `MIXER_FWD_FLOPS` on those sizes, in place of attention.
     `shared_gate`: the shared expert's (hidden, 1) gate; `head_gate`: the
     attention output's (hidden, heads) gate; `diff`: differential attention's
-    core (1.5 x the ordinary one)."""
+    core (1.5 x the ordinary one). `mlp_half` "none": a layer that is a mixer
+    alone, whose MLP half counts nothing; `shared_ffn`: the shared expert's
+    width where it is not `num_shared_experts` x the experts'."""
     tokens = float(seq_len if tokens is None else tokens)
     ffn = ffn_hidden or 4 * hidden
     if mixer != "attention":
@@ -280,9 +290,12 @@ def layer_fwd_flops(
     # MLP: swiglu projects to 2*ffn (gate+up) then back; gelu/relu ffn both ways
     mlp = (2.0 * hidden * (2 * ffn) + 2.0 * ffn * hidden) if swiglu \
         else (2.0 * hidden * ffn + 2.0 * ffn * hidden)
-    if num_experts:
+    if mlp_half == "none":
+        mlp = 0.0
+    elif num_experts:
         sent = experts_per_token * (experts_held or num_experts) / num_experts
-        mlp = (sent + num_shared_experts) * mlp + 2.0 * hidden * num_experts
+        shared = num_shared_experts * mlp if shared_ffn is None else mlp * shared_ffn / ffn
+        mlp = sent * mlp + shared + 2.0 * hidden * num_experts
         if shared_gate:
             mlp += 2.0 * hidden
     return tokens * (proj + attn + mlp)
@@ -328,6 +341,8 @@ def layer_fwd_flops_from_config(cfg: Any, tokens: Optional[float] = None,
                     for k, field in MIXER_FWD_FLOPS[mixer][1].items()},
         head_gate=bool(getattr(cfg, "attn_head_gate", False)),
         diff=bool(getattr(cfg, "diff_attention", False)),
+        mlp_half=getattr(cfg, "mlp", None),
+        shared_ffn=getattr(cfg, "shared_expert_ffn", None) if getattr(cfg, "num_shared_experts", 0) else None,
     )
 
 

@@ -30,6 +30,8 @@ KDA_CONV_NORM = "kda_conv_norm"
 KDA_GATE = "kda_gate"
 KDA_GATED_NORM = "kda_gated_norm"
 SHORT_CONV = "short_conv"  # models/parts/conv.conv_mixer: "xla", its one form
+# ops/ssd.ssd_scan: "<groups of B and C> group(s) x <heads whose masks are alive together> heads at once" a call
+SSD = "ssd"
 SELECTIVE_SCAN = "selective_scan"  # ops/selective_scan.selective_scan: "pallas" | "xla" a call
 CAUSAL_ATTENTION = "causal_attention"  # ops/attention.core_attention without a window: "pallas" | "jax_flash" | "xla" a call
 WINDOW_ATTENTION = "window_attention"  # ops/attention._windowed: "pallas" | "xla" a call
@@ -47,6 +49,8 @@ VOCAB_SPLIT = "vocab_split"  # parallel/pipeline's scan engine: the mesh axes, a
 # cotangents are stacked in the compute dtype (the launch's answer to a device with little room beside the state)
 SCAN_GRADS = "scan_grads"
 HYPER = "hyper"  # models/parts/hyper.coefficients: "xla", its one form, a half of a hyper-connected layer
+# models/base.run_layers, a model with layers of ONE half: "<halves run> of <2 x layers>", once a trace
+HALVES = "halves"
 MLP_ACTIVATION = "mlp_activation"  # models/parts/mlp.dense_mlp: "written_out" (a GELU) | "folded" into the down matmul, a call
 
 

@@ -131,7 +131,23 @@ def moe_aux_names(score: str, bias: bool, held: bool) -> Tuple[str, ...]:
 #   ran out of the scoped VMEM at OLMoE's shapes ((512, 2048, 1024)), which is
 #   why OLMoE's calls, 1024 rows a group, K and N that 1024 divides, come out at
 #   (512, 1024, 1024) as before. 4-byte operands were not timed: they keep tiles
-#   of half the width, as before.
+#   of half the width, as before;
+# - **a dim that NO multiple of 128 divides** (PR 71: Nemotron-H's experts are
+#   1856 = 14.5 x 128 wide, the N of the up projection and the K of the down)
+#   is ONE block, the dim whole, where the blocks fit: a block as wide as its
+#   array is whole tiles to Mosaic, masked nowhere, and multiplies no padding,
+#   where the 1024-wide tile it would otherwise keep is 2 x 1024 over 1856 with
+#   megablox's mask on the rest. `chiprun_out/moe_gmm_sweep.json`,
+#   `nemo3n-c1-s8k` (3072 even rows in 8 groups, uneven, ms a call, the masked
+#   1024 -> the dim whole): `gmm` of the up projection (256, 896, 1024 | 1856)
+#   0.351 -> 0.277, its `gmm_t` (256, 1024 | 1856, 896) 0.354 -> 0.273, its
+#   `tgmm` (128, 896, 1024 | 1856) 0.356 -> 0.278; the down projection's 0.331 ->
+#   0.279, 0.345 -> 0.301, 0.364 -> 0.320: a block's six kinds of call 2.101 ->
+#   1.728 (the parent's one tiling 3.049; the best of every candidate 1.605,
+#   with 128-row tiles and a K of 2688 whole, whose blocks are past `GMM_VMEM`).
+#   The rival, the kernels padded to 1920 = 15 x 128 columns of zeros in the
+#   compute copy (`relu(0)^2 = 0`, so exact), read 1.892 at its best tilings
+#   and costs the pad and the slice besides: not taken.
 GMM_TILING = (512, 1024, 1024)
 GMM_KERNELS = ("gmm", "gmm_t", "tgmm")  # a grouped matmul's forward, its rows' cotangent, its kernels'
 GROUP_ROW_TILE = 256  # of groups of at most `GMM_TILING[0]` rows at an even routing
@@ -150,14 +166,20 @@ def row_tile(even_rows: float) -> int:
 
 
 def _tiles_of(dim: int, most: int):
-    """The multiples of 128 that divide `dim`, up to `most`, ascending."""
+    """The multiples of 128 that divide `dim`, up to `most`, ascending; of a
+    dim that none divides, the dim itself where it is no wider than `most`
+    (ONE block, as wide as its array)."""
+    if dim % 128:
+        return [dim] if dim <= most else []
     return [tile for tile in range(128, min(dim, most) + 1, 128) if dim % tile == 0]
 
 
 def _fit(dim: int, cap: int) -> int:
     """The largest multiple of 128 that divides `dim` and is no larger than
     `cap`: no block is wider than its array and megablox masks no rest. A dim
-    that is no multiple of 128 keeps `cap` (no cell has one)."""
+    that no multiple of 128 divides and that is wider than `cap` starts from
+    `cap`, the rest of its last tile masked (Nemotron-H's 1856: `gmm_tiling`
+    then takes it whole where the blocks fit)."""
     return (_tiles_of(dim, cap) or [cap])[-1]
 
 
@@ -189,10 +211,10 @@ def gmm_tiling(kernel: str, k: int, n: int, even_rows: float, itemsize: int = 2)
     def fit(*tiling):
         return gmm_blocks_bytes(kernel, tiling, itemsize) <= GMM_VMEM
 
-    if kernel == "tgmm":
-        if even_rows <= GMM_TILING[0]:
-            tm = min(tm, TGMM_ROW_TILE)
-    elif k % 128 == 0 and k <= GMM_WHOLE and fit(tm, k, tn):
+    if kernel == "tgmm" and even_rows <= GMM_TILING[0]:
+        tm = min(tm, TGMM_ROW_TILE)
+    # `gmm` holds its contracted dim whole where the blocks fit; every kernel a K that no multiple of 128 divides
+    if (kernel != "tgmm" or k % 128) and k <= GMM_WHOLE and fit(tm, k, tn):
         tk = k
     tn = max([tn] + [wider for wider in _tiles_of(n, GMM_WHOLE) if fit(tm, tk, wider)])
     return tm, tk, tn

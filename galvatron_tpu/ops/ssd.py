@@ -7,7 +7,10 @@ A head carries a state `h` (d_head x d_state) along the sequence, `h_0 = 0`:
     h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T     A < 0 a head, dt_t > 0
     y_t = h_t C_t + D x_t
 
-`B_t` and `C_t` (d_state,) are shared by every head (ONE group). It is the
+`B_t` and `C_t` (d_state,) are shared by the heads of a GROUP: by every head
+where the model has one group (Granite-4.0-H: `bm`, `cm` of (B, S, d_state)),
+by `heads / groups` consecutive heads where it has several (Nemotron-H's 8
+groups of 8 heads: (B, S, groups, d_state)). It is the
 gated delta rule (ops/linear_attention.py) WITHOUT the rank-one erase: what a
 token writes does not depend on the state, so no triangular system is solved
 and a chunk's map of the state is a scalar decay and a sum.
@@ -19,19 +22,26 @@ and a chunk's map of the state is a scalar decay and a sum.
     h_c' = e^{G_last} h_c + sum_j e^{G_last - G_j} dt_j x_j B_j^T
 
 `h_c` the state the chunk starts from. `C B^T` (tokens x tokens a chunk) is
-the same for all heads and made once; a head's part is its decay mask
+the same for all heads of a group and made once a group; a head's part is its decay mask
 `e^{G_t - G_j}`, masked BEFORE the exponential (above the diagonal the
 difference is positive and grows with the chunk). What runs along the
 sequence is one multiply-add of the states a chunk (`_carry`).
 
-The heads are worked `HEADS_AT_ONCE` at a time (`lax.map`), so the masks
-alive at once are (group, chunks, CHUNK, CHUNK) and never all heads': 64
+The heads are worked `HEADS_AT_ONCE` at a time (`lax.map`; of a model with
+groups the heads worked at once lie in ONE group, so at most a group's, and
+the map runs over the groups' B and C beside the heads'), so the masks
+alive at once are (heads at once, chunks, CHUNK, CHUNK) and never all heads': 64
 heads x 32 chunks x 128 x 128 float32 would be 128 MiB a tensor at 4096
 tokens. On the chip (PERF.md, PR 39; scripts/ssd_sweep.py) a layer at the
 Granite-4.0-H cell's widths takes 1.28 ms forward and 3.22 forward + backward
 at chunks of 128 and 16 heads at a time; 8 heads 1.30 / 3.44, chunks of 64
 and 256 and all 64 heads at once are slower, and so is a form with two
-64-wide heads side by side in a tile's 128 lanes (1.93 / 4.26).
+64-wide heads side by side in a tile's 128 lanes (1.93 / 4.26). With 8 groups
+of 8 heads at 8192 tokens (Nemotron-H's; PERF.md, PR 71; `scripts/ssd_sweep.py
+--groups 8 --tokens 8192`, `chiprun_out/ssd_sweep_groups.json`) a block's scan
+takes 2.25 / 5.79 ms at chunks of 128 and a group's 8 heads at once, 2.51 /
+6.81 at 4 heads, 4.83 / 14.43 at chunks of 64 and 1.94 / 5.45 at chunks of 256
+(2 ms of that cell's 288 ms step: `CHUNK` stays one number for both cells).
 
 **The backward** is autodiff's through a group's arithmetic, made again from
 x, dt, A, B, C and the chunks' STARTING STATES, which alone are kept
@@ -65,6 +75,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from galvatron_tpu.obs import forms
 
 CHUNK = 128
 HEADS_AT_ONCE = 16
@@ -125,34 +137,46 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array, cm: jax.A
              *, chunk: int = CHUNK, heads_at_once: int = HEADS_AT_ONCE,
              state_dtype=_F32) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x (B, S, H, P); dt (B, S, H) float32, after its softplus; a (H,) < 0;
-    bm, cm (B, S, d_state), shared by the heads; d (H,) the skip -> y (B, S,
+    bm, cm (B, S, d_state), shared by all the heads, or (B, S, groups,
+    d_state), head n reading group n // (H / groups); d (H,) the skip -> y (B, S,
     H, P) in x's dtype, the final states (B, H, P, d_state) float32, and the
     largest magnitude of any head's state at any chunk's end (a scalar).
 
     `chunk`: tokens a chunk (any: the mathematics holds for all, and a
     sequence that is no multiple is padded with `dt = 0`); `heads_at_once`:
-    the heads whose masks are alive together (the largest divisor of H up to
-    it); `state_dtype`: what the carried state is rounded to a chunk."""
+    the heads whose masks are alive together (the largest divisor of a
+    group's heads up to it); `state_dtype`: what the carried state is rounded to a chunk."""
     b, s, h, p = x.shape
+    groups = 1 if bm.ndim == 3 else bm.shape[2]
+    assert h % groups == 0, (h, groups)
     chunk = min(chunk, s)
     pad = -s % chunk
     if pad:
         x, dt, bm, cm = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                          for t in (x, dt, bm, cm))
     n = (s + pad) // chunk
-    group = max(g for g in range(1, min(heads_at_once, h) + 1) if h % g == 0)
+    group = max(g for g in range(1, min(heads_at_once, h // groups) + 1) if (h // groups) % g == 0)
+    forms.took(forms.SSD, "%d group%s x %d heads at once" % (groups, "" if groups == 1 else "s", group))
 
     def heads_first(t):  # (B, S', H, ...) -> (H / G, N, B, G, C, ...)
         t = t.reshape((b, n, chunk, h // group, group) + t.shape[3:])
         return t.transpose((3, 1, 0, 4, 2) + tuple(range(5, t.ndim)))
 
-    bm, cm = (t.reshape(b, n, chunk, -1).transpose(1, 0, 2, 3) for t in (bm, cm))
+    shared = bm.ndim == 3  # one group: every batch of heads reads the same B and C
+    if shared:
+        bm, cm = (t.reshape(b, n, chunk, -1).transpose(1, 0, 2, 3) for t in (bm, cm))
+    else:
+        # (B, S', groups, d_state) -> (H / G, N, B, C, d_state): a batch of heads its group's B and C
+        bm, cm = (t.reshape(b, n, chunk, groups, -1).transpose(3, 1, 0, 2, 4) for t in (bm, cm))
+        if h // groups > group:  # several batches of heads a group
+            bm, cm = (jnp.repeat(t, h // groups // group, axis=0) for t in (bm, cm))
     core = jax.checkpoint(functools.partial(_group_core, state_dtype=state_dtype),
                           policy=jax.checkpoint_policies.save_only_these_names(STARTS))
     y, last, peak = jax.lax.map(
-        lambda g: core(*g, bm, cm),
+        (lambda g: core(*g, bm, cm)) if shared else (lambda g: core(*g)),
         (heads_first(x), heads_first(dt.astype(_F32)),
-         a.astype(_F32).reshape(h // group, group), d.astype(_F32).reshape(h // group, group)))
+         a.astype(_F32).reshape(h // group, group), d.astype(_F32).reshape(h // group, group))
+        + (() if shared else (bm, cm)))
     # (H / G, N, B, G, C, P) -> (B, S', H, P)
     y = y.transpose(2, 1, 4, 0, 3, 5).reshape(b, s + pad, h, p)[:, :s]
     return y, last.transpose(1, 0, 2, 3, 4).reshape(b, h, p, -1), jnp.max(peak)

@@ -64,6 +64,11 @@ CELLS = {
     "xing4-c1-s4k": dict(tokens=4096, k=4, experts=64, held=8, hidden=3584, width=2048, ffn=1024),
     "lfm2moe-c1-s8k": dict(tokens=16384, k=4, experts=32, held=8, hidden=2048, width=3584, ffn=1792),
     "olmoe-c1-s4k": dict(tokens=8192, k=8, experts=64, held=64, hidden=2048, width=2048, ffn=1024),
+    # two matrices an expert and no gate: the up projection is as wide as the expert, 1856 = 14.5 x 128, which no
+    # multiple of 128 divides; hidden 2688 = 21 x 128. And the same block with the kernels padded to 1920 = 15 x 128
+    # columns of zeros (PR 71: the rule's rival for a dim that is no multiple of 128)
+    "nemo3n-c1-s8k": dict(tokens=8192, k=6, experts=128, held=8, hidden=2688, width=1856, ffn=1856),
+    "nemo3n-c1-s8k-pad1920": dict(tokens=8192, k=6, experts=128, held=8, hidden=2688, width=1920, ffn=1920),
 }
 KERNELS = ("gmm", "gmm_t", "tgmm")
 VMEM_TRY = 24 << 20  # a tiling whose blocks alone (`moe.gmm_blocks_bytes`) are over this is not sent to the compiler
@@ -75,9 +80,16 @@ def even_rows(cell) -> float:
 
 def dim_tiles(dim: int):
     """Candidate tiles of a K or N: the multiples of 128 that divide it, from
-    768 up to 2304 (a narrower dim: itself), the largest three. (512-wide
-    tiles of a wider dim lost to 1024 at OLMoE's shapes: PERF.md, PR 27.)"""
-    fits = [t for t in range(128, dim + 1, 128) if dim % t == 0 and (t >= 768 or t == dim) and t <= 2304]
+    768 up to 2304 (a narrower dim: itself; one up to 2688: itself too), the
+    largest three. (512-wide tiles of a wider dim lost to 1024 at OLMoE's
+    shapes: PERF.md, PR 27.) Of a dim that no multiple of 128 divides (1856):
+    the least multiple of 128 that covers it in as many tiles as 1024-wide ones
+    would, the rest of whose last tile megablox masks, and the dim itself as ONE
+    block (a block as wide as its array need be no multiple of 128)."""
+    if dim % 128:
+        return sorted({math.ceil(dim / math.ceil(dim / 1024) / 128) * 128, dim})
+    fits = [t for t in range(128, dim + 1, 128)
+            if dim % t == 0 and (t >= 768 or t == dim) and (t <= 2304 or t == dim <= 2688)]
     return sorted(fits)[-3:]
 
 

@@ -339,7 +339,7 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
 
 # ------------------------------------------------ the table, FLOPs, the counters
 def test_one_table_maps_the_kda_mixer_to_what_it_brings():
-    assert set(M.MIXERS) == {"attention", "linear", "ssm", "kda", "conv", "window", "mamba1", "gmu", "cross", "eva"}
+    assert set(M.MIXERS) == {"attention", "linear", "ssm", "kda", "conv", "window", "mamba1", "gmu", "cross", "eva", "none"}
     assert M.MIXERS["kda"].scopes == (tracing.ATTN_KDA, tracing.ATTN_KDA_RULE) == (
         "gt.attn.kda_mixer", "gt.attn.kda_rule")
     assert not any(a != b and a.startswith(b) for a in M.MIXERS["kda"].scopes for b in M.MIXERS["kda"].scopes)

@@ -53,6 +53,9 @@ BUILT_OF = {
     ("MIXERS", "eva"): dict(DENSE, mixer="eva", eva_window=32, eva_chunk=8, position_type="rope"),
     ("MLP_HALVES", "dense"): DENSE,
     ("MLP_HALVES", "routed"): dict(DENSE, num_experts=4, experts_per_token=2),
+    # the absent half (PR 71), one entry of both tables: a mixer alone, then an MLP alone; it states no phrase
+    ("MIXERS", "none"): dict(DENSE, layer_types=["attention", "none"], mlp_types=["none", "dense"]),
+    ("MLP_HALVES", "none"): dict(DENSE, layer_types=["attention", "none"], mlp_types=["none", "dense"]),
 }
 
 
@@ -140,7 +143,7 @@ def test_a_configs_validate_clauses_are_exactly_its_entries(family, monkeypatch)
     (("MIXERS", "attention", "latent"), dict(head_dim=8), "latent attention runs as ONE attention call"),
     (("MIXERS", "linear"), dict(linear_conv_kernel=0), "linear-attention layers"),
     (("MIXERS", "kda"), dict(linear_num_value_heads=4), "equal under \"kda\""),
-    (("MIXERS", "ssm"), dict(num_experts=4, experts_per_token=2), "a dense MLP half"),
+    (("MIXERS", "ssm"), dict(ssm_groups=3), "ssm_groups that divide the heads"),
     (("MIXERS", "ssm"), dict(ssm_state_dim=0), "state-space layers want"),
     (("MIXERS", "conv"), dict(short_conv_kernel=0), "short-convolution layers want short_conv_kernel"),
     (("MIXERS", "window"), dict(sliding_window=0), "window layers want sliding_window"),

@@ -351,7 +351,7 @@ def test_the_other_families_kinds_are_what_they_were():
 
 
 def test_one_table_maps_a_mixer_to_what_it_brings():
-    assert set(M.MIXERS) == {"attention", "linear", "ssm", "kda", "conv", "window", "mamba1", "gmu", "cross", "eva"}
+    assert set(M.MIXERS) == {"attention", "linear", "ssm", "kda", "conv", "window", "mamba1", "gmu", "cross", "eva", "none"}
     assert set(obs_flops.MIXER_FWD_FLOPS) == set(M.MIXERS)  # a FLOPs row a key, and no other
     assert M.MIXERS["linear"].scopes == (tracing.ATTN_LINEAR, tracing.ATTN_DELTA)
     cfg = tiny()

@@ -1,5 +1,6 @@
 """The tiling of each megablox call is chosen from that call's shapes (PR 69: `ops/moe.gmm_tiling`): the rule over the
-seven routed cells' shapes, against the chip sweep it was written from, and what it says to `obs/forms`."""
+seven routed cells' shapes, against the chip sweep it was written from, and what it says to `obs/forms`; and (PR 71)
+the rule for a dim that NO multiple of 128 divides, Nemotron-H's 1856-wide experts, against its own sweep."""
 
 import json
 import math
@@ -155,3 +156,62 @@ def test_forms_hears_no_tiling_where_the_kernels_do_not_run():
     """The CPU, and rows that are no whole row tiles, take `jax.lax.ragged_dot`: no tiling is chosen and none said."""
     assert forms.GMM_TILES not in _block(2048, 2, 16, None, 512, 256, on_tpu=False)
     assert forms.GMM_TILES not in _block(200, 2, 16, None, 512, 256, on_tpu=True)
+
+
+# ------------------------------------------- a dim that no multiple of 128 divides (PR 71: nemo3n-c1-s8k)
+NEMO = dict(tokens=8192, k=6, experts=128, held=8, hidden=2688, ffn=1856)  # two matrices an expert: the up projection is 1856 wide
+NEMO_TILINGS = {("in", "gmm"): (256, 896, 1856), ("in", "gmm_t"): (256, 1856, 896), ("in", "tgmm"): (128, 896, 1856),
+                ("out", "gmm"): (256, 1856, 896), ("out", "gmm_t"): (256, 896, 1856), ("out", "tgmm"): (128, 1856, 896)}
+
+
+def _nemo_calls():
+    for site, (k, n) in (("in", (NEMO["hidden"], NEMO["ffn"])), ("out", (NEMO["ffn"], NEMO["hidden"]))):
+        for kernel, dims in moe.matmul_calls(k, n):
+            yield site, kernel, dims
+
+
+@pytest.mark.parametrize("site,kernel,dims", list(_nemo_calls()))
+def test_a_dim_that_no_multiple_of_128_divides_is_one_block(site, kernel, dims):
+    """1856 = 14.5 x 128 is taken WHOLE wherever a call's tiles run over it (a block as wide as its array: no mask,
+    no padding multiplied), 2688 = 21 x 128 in tiles of 896 that divide it; the row tiles are the rule's (384 even
+    rows a group: 256, `tgmm` 128) and divide the window; the blocks lie inside `GMM_VMEM`."""
+    even = NEMO["tokens"] * NEMO["k"] / NEMO["experts"]
+    tiling = moe.gmm_tiling(kernel, *dims, even)
+    assert even == 384 and tiling == NEMO_TILINGS[site, kernel]
+    for tile, dim in zip(tiling[1:], dims):
+        assert dim % tile == 0 and (tile % 128 == 0 or tile == dim == 1856)
+    assert moe.gmm_blocks_bytes(kernel, tiling) <= moe.GMM_VMEM
+    window = moe.window_rows(NEMO["tokens"] * NEMO["k"], NEMO["experts"], (0, NEMO["held"]))
+    assert window == 5120 and window % tiling[0] == 0
+    # float32 operands (the chip check's float32 passes) keep tiles of half the width: 1856 is then wider than the
+    # cap and stays masked, as every such dim did before this rule
+    assert moe.gmm_tiling(kernel, *dims, even, itemsize=4)[1:] == tuple(384 if d == 2688 else 512 for d in dims)
+
+
+def test_a_narrow_dim_that_128_does_not_divide_is_whole_too_and_one_too_wide_for_a_block_stays_masked():
+    assert moe._tiles_of(200, 1024) == [200] and moe._fit(200, 1024) == 200
+    assert moe._tiles_of(1856, 1024) == [] and moe._fit(1856, 1024) == 1024  # where `gmm_tiling` starts from
+    assert moe.gmm_tiling("gmm", 2048, 4000, 384.0) == (256, 2048, 1024)  # wider than `GMM_WHOLE`: megablox masks the rest
+
+
+@pytest.mark.skipif(not os.path.exists(SWEEP), reason="the chip sweep's record is not in this checkout")
+def test_the_rule_for_1856_picks_what_its_chip_sweep_timed():
+    """`chiprun_out/moe_gmm_sweep.json`, `nemo3n-c1-s8k` (scripts/moe_gmm_sweep.py on a v5e, PR 71): every picked
+    tiling compiled and ran; each is faster than the masked 1024-wide tile it replaces and than the parent's one
+    tiling; a block's six kinds of call add up to under the same block padded to 1920 columns at ITS best tilings; and
+    the whole is within 10 % of the best of every candidate (which holds 128-row tiles and a K of 2688 whole, past
+    `GMM_VMEM`)."""
+    cells = json.load(open(SWEEP))["cells"]
+    record, padded = cells["nemo3n-c1-s8k"], cells["nemo3n-c1-s8k-pad1920"]
+    assert record["shapes"]["even_rows_a_group"] == 384
+    total = best = 0.0
+    for entry in record["kernels"]:
+        dims = dict(moe.matmul_calls(entry["K"], entry["N"]))[entry["kernel"]]
+        picked = moe.gmm_tiling(entry["kernel"], *dims, 384.0)
+        assert picked == NEMO_TILINGS[entry["site"], entry["kernel"]]
+        timed = {tuple(r["tiling"]): r["device_ms"] for r in entry["rows"] if r["groups"] == "uneven" and "device_ms" in r}
+        masked = tuple(1024 if tile == 1856 else tile for tile in picked)
+        assert timed[picked] < 0.93 * timed[masked] and timed[picked] < 0.7 * timed[512, 1024, 1024], (entry["site"], entry["kernel"])
+        total, best = total + timed[picked], best + min(timed.values())
+    rival = sum(min(r["device_ms"] for r in e["rows"] if r["groups"] == "uneven" and "device_ms" in r) for e in padded["kernels"])
+    assert total < rival and total < 1.10 * best

@@ -1,5 +1,6 @@
 """Compile-only checks against a DESCRIBED TPU v5e 2x2 (no chip attached; how and why: tests/ops/tpu_compile.py):
-a Laguna and a Kimi-Linear routed block's megablox calls at the tilings their shapes take (PR 69: `ops/moe.gmm_tiling`)."""
+a Laguna and a Kimi-Linear routed block's megablox calls at the tilings their shapes take (PR 69: `ops/moe.gmm_tiling`),
+and a Nemotron-H block's, whose experts' width no multiple of 128 divides (PR 71: 1856 as ONE block)."""
 
 import re
 
@@ -23,7 +24,14 @@ BLOCKS = {
                  router=dict(score="sigmoid", norm_topk_prob=True, scale=2.446)),
     "olmoe_4k": dict(tokens=4096, hidden=2048, ffn=1024, experts=64, held=None, k=8,
                      router=dict(score="softmax", norm_topk_prob=False)),
+    # the cell nemo3n-c1-s8k: two matrices an expert (`gate` False: the up projection is as wide as the expert), squared ReLU
+    "nemo3n": dict(tokens=8192, hidden=2688, ffn=1856, experts=128, held=8, k=6, gate=False,
+                   router=dict(score="sigmoid", norm_topk_prob=True, scale=2.5)),
 }
+
+
+def _up_width(b):
+    return b["ffn"] * (2 if b.get("gate", True) else 1)
 
 
 def _pallas_calls(jaxpr, inside=""):
@@ -50,14 +58,16 @@ def block(request, v5e_2x2):
 
     def loss(y, router, wi, wo, *bias):
         out, _ = moe.moe_ffn(y, router, wi, wo, experts_per_token=b["k"], dtype=y.dtype, sharding=on_chip,
-                             bias=bias[0] if bias else None, held=b["held"] and (0, b["held"]), **b["router"])
+                             bias=bias[0] if bias else None, held=b["held"] and (0, b["held"]),
+                             activate=moe.swiglu if b.get("gate", True) else lambda mid: jnp.square(jax.nn.relu(mid)),
+                             **b["router"])
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     f32 = jnp.float32
     count = b["held"] or b["experts"]
     operands = (jax.ShapeDtypeStruct((1, b["tokens"], b["hidden"]), jnp.bfloat16, sharding=one),
                 jax.ShapeDtypeStruct((b["hidden"], b["experts"]), f32, sharding=one),
-                jax.ShapeDtypeStruct((count, b["hidden"], 2 * b["ffn"]), f32, sharding=one),
+                jax.ShapeDtypeStruct((count, b["hidden"], _up_width(b)), f32, sharding=one),
                 jax.ShapeDtypeStruct((count, b["ffn"], b["hidden"]), f32, sharding=one))
     if sigmoid:
         operands += (jax.ShapeDtypeStruct((b["experts"],), f32, sharding=one),)
@@ -86,7 +96,7 @@ def test_a_blocks_megablox_calls_compile_at_the_tiles_their_shapes_take(block):
             seen.add(held)
     even = b["tokens"] * b["k"] / b["experts"]
     assert even <= 512 and moe.row_tile(even) == 256
-    wide, h, f = 2 * b["ffn"], b["hidden"], b["ffn"]
+    wide, h, f = _up_width(b), b["hidden"], b["ffn"]
     tilings = {moe.gmm_tiling(kernel, *dims, even) for k, n in ((h, wide), (f, h)) for kernel, dims in moe.matmul_calls(k, n)}
     assert {tm for tm, _, _ in tilings} == {256, 128}
     for tm, tk, tn in tilings:  # each chosen tiling's lhs block is among the blocks the calls hold
