@@ -70,7 +70,7 @@ def _init_ssm(ks, cfg: TransformerConfig) -> Params:
     return {"ssm": p}
 
 
-def ssm_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, **_):
+def ssm_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerConfig, attn_sharding=None, **_):
     """Mamba-2 on normed activations (B, S, H) (HF `GraniteMoeHybridMambaLayer`;
     arXiv:2405.21060), p the layer's tree:
 
@@ -87,9 +87,11 @@ def ssm_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerCon
     with `group_size`); G = 1 traces what it always did. -> out, None, and the
     layer's counter: the largest magnitude of any head's state at any chunk's
     end. Scopes: the scan under `gt.attn.ssd`, all else under `gt.attn.ssm`.
-    No position enters: the order is the recurrence's. The convolution and
-    the gated norm are XLA's (`causal_conv`; the Pallas passes of
-    ops/linear_attention.py norm a head's 128 lanes and know no bias)."""
+    No position enters: the order is the recurrence's. `attn_sharding` tells
+    the scan where its operands lie: on TPUs it runs as Pallas kernels
+    (`ssd_scan`). The convolution and the gated norm are XLA's (`causal_conv`;
+    the Pallas passes of ops/linear_attention.py norm a head's 128 lanes and
+    know no bias)."""
     p, dtype = p["ssm"], cfg.compute_dtype
     nh, hd, groups = cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_groups
     ds = groups * cfg.ssm_state_dim  # B's columns, and C's
@@ -107,7 +109,7 @@ def ssm_mixer(p: Params, y: jax.Array, positions: jax.Array, cfg: TransformerCon
         x, bm, cm = xbc[..., :inner].reshape(b, s, nh, hd), xbc[..., inner:inner + ds], xbc[..., inner + ds:]
         if groups > 1:
             bm, cm = (t.reshape(b, s, groups, cfg.ssm_state_dim) for t in (bm, cm))
-        o, _, peak = ssd_scan(x, dt, a, bm, cm, p["D"])
+        o, _, peak = ssd_scan(x, dt, a, bm, cm, p["D"], sharding=attn_sharding)
     with jax.named_scope(tracing.ATTN_SSM):
         o = o.reshape(b, s, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
         if groups > 1:  # a group's channels a norm

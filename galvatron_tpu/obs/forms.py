@@ -30,7 +30,8 @@ KDA_CONV_NORM = "kda_conv_norm"
 KDA_GATE = "kda_gate"
 KDA_GATED_NORM = "kda_gated_norm"
 SHORT_CONV = "short_conv"  # models/parts/conv.conv_mixer: "xla", its one form
-# ops/ssd.ssd_scan: "<groups of B and C> group(s) x <heads whose masks are alive together> heads at once" a call
+# ops/ssd.ssd_scan, a call: "pallas: <groups of B and C> group(s) x <heads a grid step holds> heads a block" (the
+# kernels) | "<groups> group(s) x <heads whose masks are alive together> heads at once" (the XLA form)
 SSD = "ssd"
 SELECTIVE_SCAN = "selective_scan"  # ops/selective_scan.selective_scan: "pallas" | "xla" a call
 CAUSAL_ATTENTION = "causal_attention"  # ops/attention.core_attention without a window: "pallas" | "jax_flash" | "xla" a call

@@ -18,7 +18,9 @@ time). A seed reads:
   all, and the worst layer's reading for EACH Mamba leaf (`win`, `conv.kernel`,
   `conv.bias`, `dt_bias`, `A_log`, `D`, `norm.scale`, `wout`);
 - **the scan's core**: layer 0's x, dt, A, B, C, D as the program makes them
-  (bf16 operands, float32 dt), through `ops/ssd.ssd_scan` as the step runs it:
+  (bf16 operands, float32 dt), through `ops/ssd.ssd_scan` as the step runs it
+  (since PR 72 the kernels `ssd_fwd` / `ssd_bwd` here; the XLA form's readings
+  beside them):
   the relative error of y over the whole sequence and over the LAST 128
   tokens, where 4096 tokens of carried state have piled up, against the
   reference's token-by-token recurrence in float32 on the chip, and of the
@@ -29,7 +31,12 @@ time). A seed reads:
 **A control in the next lower precision, on the first seed, which must FAIL at
 least one limit**: the same core with its carried state rounded to bfloat16
 after every chunk (`state_dtype`, by `jax.lax.reduce_precision`: a cast there
-and back the TPU compiler takes out). Writes
+and back the TPU compiler takes out). **The two forms of the scan through the cell's own train step** (on the first
+seed): three optimizer steps with the kernels and three with the XLA form
+(`impl="xla"` handed to the mixer's call here, nowhere in the program), the
+losses side by side; they may differ by the cell's `reference_loss.abs` (2e-3)
+at most, and `obs/forms` must have heard the kernels in the one and not in the
+other. Writes
 `chiprun_out/granite4h_chip_check.json`; its LAST line of output is the
 verdict with each measure's largest reading over the seeds beside its limit;
 exits 1 unless the program passes on every seed and the control fails. Refuses
@@ -40,6 +47,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -59,6 +67,12 @@ MAMBA_LEAVES = ("win", "conv']['kernel", "conv']['bias", "dt_bias", "A_log", "['
 #   worst_leaf         0.0288   (layer 2's A_log; the median leaf 0.016: what a bf16 stream does to a gradient)
 #   worst_mamba_leaf   0.0288   (win 0.0162, conv.kernel 0.0164, conv.bias 0.0148, dt_bias 0.0237, A_log 0.0288,
 #                               D 0.0207, norm.scale 0.0153, wout 0.0148: the worst layer's, over the seeds)
+# Since PR 72 the program's scan is the kernels' (`ssd_fwd`, `ssd_bwd`) and the same limits hold it (my chip run,
+# PR 72, call 10, the three seeds): loss 1.3e-5, core_state 3.8e-6 (the XLA form on the same operands 3.2e-6; the
+# control 1.68e-3), core_y 1.701e-3, core_y_last_chunk 1.709e-3, worst_leaf = worst_mamba_leaf 0.0250 (layer 7's
+# dt_bias; A_log 0.0236), three optimizer steps of the two forms 2.0e-5 apart. (Call 9, a kernel whose two sums of
+# `dW * W` read a float32 and a bfloat16 `dt x`, read dt_bias 0.151 and A_log 0.146 with every other measure as here:
+# `worst_mamba_leaf` is the measure that tells.)
 # `core_state` tells a bf16 state from a float32 one by nearly three orders of
 # magnitude: its limit lies between the two readings, 22 x over the one and
 # 1 / 24 of the other. The control moves neither the core's output nor (so)
@@ -87,6 +101,7 @@ def main(argv=None) -> int:
     from galvatron_tpu.models.parts.common import _norm
     from galvatron_tpu.models.parts.embed_head import embed_tokens
     from galvatron_tpu.models.parts import ssm as part
+    from galvatron_tpu.obs import forms
     from galvatron_tpu.ops import ssd
     from galvatron_tpu.runtime import construct_hybrid_parallel_model
 
@@ -146,13 +161,48 @@ def main(argv=None) -> int:
                     "core_state": rel(np.asarray(state[0], np.float64) - exact_state, exact_state),
                     "state_abs_max": float(peak)}
 
-        out = {"program": error(),
+        with forms.recording() as took:
+            program = error()
+        out = {"program": program, "program_form": sorted(took.get(forms.SSD, {})), "xla_form": error(impl="xla"),
                "recurrence_float32_on_chip_state": rel(np.asarray(state_on_chip, np.float64) - exact_state,
                                                        exact_state),
                "decay_mean": float(jnp.mean(jnp.exp(operands[1] * operands[2]))),
                "y_rms": float(jnp.sqrt(jnp.mean(exact * exact)))}
         if with_control:
             out["control_bf16_state"] = error(state_dtype=jnp.bfloat16)
+        return out
+
+    def three_steps(seed):
+        """Three optimizer steps of the cell's own train step on one seed, the
+        scan in each form: the losses, and which form `obs/forms` heard."""
+        from galvatron_tpu.runtime.optimizer import OptimizerArgs, get_optimizer_and_scheduler
+
+        tokens = jax.random.randint(jax.random.PRNGKey(seed + 1), (1, seq), 0, cfg.vocab_size)
+        batch = model.shard_batch(dict(
+            tokens=tokens, positions=jnp.arange(seq)[None], labels=jnp.roll(tokens, -1, 1),
+            loss_mask=jnp.ones((1, seq), jnp.float32).at[:, -1].set(0.0)))
+        out = {}
+        for form in ("pallas", "xla"):
+            part.ssd_scan = committed_scan if form == "pallas" else functools.partial(committed_scan, impl="xla")
+            try:
+                tx, _ = get_optimizer_and_scheduler(OptimizerArgs(lr=1e-4, warmup_steps=0, total_steps=8))
+                params = model.init_params(jax.random.PRNGKey(seed))
+                opt = model.init_opt_state(tx, params)
+                step = model.make_train_step(tx)
+                with forms.recording() as took:
+                    losses = []
+                    for _ in range(3):
+                        params, opt, mets = step(params, opt, batch)
+                        losses.append(float(mets["loss"]))
+            finally:
+                part.ssd_scan = committed_scan
+            out[form] = {"losses": losses, "forms": sorted(took.get(forms.SSD, {}))}
+            del params, opt
+        out["largest_difference"] = max(abs(a - b) for a, b in zip(out["pallas"]["losses"], out["xla"]["losses"]))
+        out["ok"] = (out["largest_difference"] <= LIMITS["loss"]
+                     and all(f.startswith("pallas") for f in out["pallas"]["forms"])
+                     and not any(f.startswith("pallas") for f in out["xla"]["forms"]))
+        print(json.dumps({"three_steps": out}), flush=True)
         return out
 
     reference_grad = jax.jit(jax.value_and_grad(lambda p, b: ref.loss(p, b, fields)))
@@ -191,11 +241,13 @@ def main(argv=None) -> int:
         return row
 
     rows = [one_seed(seed, i == 0) for i, seed in enumerate(seeds)]
+    steps = three_steps(seeds[0])
     largest = {k: max(r["measures"][k] for r in rows) for k in LIMITS}
     verdict = {"cell": CELL, "seeds": seeds, "device": jax.devices()[0].device_kind,
                "largest": largest, "limits": LIMITS, "program_passes": all(r["passes"] for r in rows),
                "control_fails": rows[0]["control_fails"],
-               "ok": all(r["passes"] for r in rows) and bool(rows[0]["control_fails"])}
+               "program_form": rows[0]["core"]["program_form"], "three_steps_of_both_forms": steps,
+               "ok": all(r["passes"] for r in rows) and bool(rows[0]["control_fails"]) and steps["ok"]}
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "granite4h_chip_check.json"), "w") as f:
         json.dump({"rows": rows, "verdict": verdict}, f, indent=1)

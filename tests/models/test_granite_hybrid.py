@@ -198,12 +198,22 @@ def test_each_multiplier_and_the_absent_positions_matter(case, piece):
     """Switched off in the reference alone (a multiplier taken as the model
     without it, positions turned on), the agreement breaks by far."""
     cfg, params, batch, ((loss, _), grads), _ = case
+    # the gradients of the leaves from `first` up alone, so the reference's backward stops there (6 s a
+    # case through all ten layers, PR 72): the attention layer and what follows it where the piece is the
+    # attention's, the last layer and the final norm for the others; the largest of a part of the leaves
+    # is no more than the largest of all
+    first = 5 if piece in ("attention_multiplier", "nope") else 9
+    top = lambda tree: {"layers": tree["layers"][first:], "final_norm": tree["final_norm"]}  # noqa: E731
+
+    def off(leaves):
+        p = {**params, "layers": params["layers"][:first] + leaves["layers"], "final_norm": leaves["final_norm"]}
+        return REF.loss(p, batch, fields_of(cfg), switch_off=(piece,))
+
     with jax.default_matmul_precision("highest"):
-        off_loss, off_grads = jax.jit(jax.value_and_grad(
-            lambda p: REF.loss(p, batch, fields_of(cfg), switch_off=(piece,))))(params)
-    # the gradients by far (measured 0.8 to 1.6 by the worst leaf); the loss too, but for
+        off_loss, off_grads = jax.jit(jax.value_and_grad(off))(top(params))
+    # the gradients by far (measured 0.29 to 101 by the worst of these leaves); the loss too, but for
     # the positions: at a model's start a rotation of q and k hardly moves a softmax
-    assert max(leaf_errors(grads, off_grads).values()) > 0.1
+    assert max(leaf_errors(top(grads), off_grads).values()) > 0.1
     assert piece == "nope" or abs(float(off_loss) - float(loss)) > 2 * F32_TOL
 
 
@@ -307,7 +317,8 @@ def _first_loss_and_count(cfg, seq=64):  # the delta rule's chunk
     tok = jax.random.randint(jax.random.PRNGKey(1), (2, seq), 0, cfg.vocab_size)
     batch = dict(tokens=tok, positions=jnp.broadcast_to(jnp.arange(seq), (2, seq)), labels=jnp.roll(tok, -1, 1))
     digest = sum(float(jnp.sum(jnp.abs(leaf))) for leaf in jax.tree.leaves(params))
-    return float(M.lm_loss_fn(params, batch, cfg)), digest, len(jax.tree.leaves(params))
+    # jitted: the delta rule's and the routed block's ops dispatched one by one took 10 s a family (PR 72)
+    return float(jax.jit(lambda p, b: M.lm_loss_fn(p, b, cfg))(params, batch)), digest, len(jax.tree.leaves(params))
 
 
 SMALL = dict(num_layers=2, hidden_size=64, num_heads=2, num_kv_heads=2, ffn_hidden=32, vocab_size=128,
@@ -359,7 +370,7 @@ def test_one_table_maps_the_mixer_to_what_it_brings():
 
 
 def test_the_step_hands_back_the_counter_and_the_event_takes_it():
-    cfg = tiny()
+    cfg = tiny(num_layers=2)  # two Mamba-2 layers, one scanned run: the counter's way out of a step knows no depth
     hp = HybridParallelConfig.uniform(1, cfg.num_layers, global_bsz=BATCH, checkpoint=1)
     model = construct_hybrid_parallel_model(cfg, hp, jax.devices()[:1])
     import optax
